@@ -8,7 +8,7 @@ PY ?= python
 	lint-schema chaos telemetry-check monitor-check control-check control-bench \
 	prefix-check tier-check fleet-check fleet-obs-check graph-check bench \
 	bench-e2e bench-fleet bench-replay serve-bench bench-trend dryrun \
-	chip-validate bench-8b cost golden host-profile clean
+	chip-smoke bench-8b cost golden host-profile clean
 
 all: native compile-check
 
@@ -41,7 +41,7 @@ test-fast: native
 # the cheapest smoke layer
 compile-check:
 	$(PY) -m compileall -q sutro_tpu tests bench.py bench_e2e.py \
-		bench_interactive.py
+		bench_interactive.py chip_smoke.py
 
 # graftlint: engine-aware static analysis (lock discipline, jit purity,
 # thread/exception hygiene) gated against the committed baseline —
@@ -211,13 +211,13 @@ bench-e2e:
 	$(PY) bench_e2e.py
 
 # interactive-tier latency legs (TTFT/ITL idle vs co-resident batch)
-# -> BENCH_INTERACTIVE.json; CI runs the CPU smoke, the chip run uses
-# the same entry point without SUTRO_E2E_CPU
+# -> BENCH_INTERACTIVE.json; CI runs the CPU rehearsal, a chip run
+# uses the same entry point without JAX_PLATFORMS=cpu
 serve-bench:
-	SUTRO_E2E_CPU=1 JAX_PLATFORMS=cpu $(PY) bench_interactive.py
+	JAX_PLATFORMS=cpu $(PY) bench_interactive.py
 
 # warn-only trend report over the accumulated bench artifacts
-# (BENCH_r*.json, BENCH_E2E.json, BENCH_INTERACTIVE.json)
+# (BENCH_E2E.json, BENCH_INTERACTIVE.json)
 # -> BENCH_TREND.md; >15% regressions in graded metrics print WARN
 # lines but never fail the build
 bench-trend:
@@ -227,10 +227,11 @@ bench-trend:
 dryrun:
 	$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
-# one-shot post-outage chip queue: numerics, batch/xrow/MULTI sweeps,
-# sampling sweep, bf16-logits A/B, 8B-class bench -> CHIP_VALIDATION.json
-chip-validate:
-	$(PY) benchmarks/chip_validation.py
+# the quickest proof that the system still starts on the chip: one
+# process, qwen3-4b through LocalEngine + the HTTP daemon on a TPU
+# (exits non-zero without one; README "Tests & benchmarks")
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # realistically-sized models + HBM roofline fractions -> BENCH_8B.json
 bench-8b:

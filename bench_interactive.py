@@ -44,8 +44,6 @@ def main() -> None:
     arm_from_env()
     import jax
 
-    if os.environ.get("SUTRO_E2E_CPU") == "1":
-        jax.config.update("jax_platforms", "cpu")
     on_tpu = jax.default_backend() not in ("cpu",)
 
     if on_tpu:
@@ -356,7 +354,10 @@ def main() -> None:
 
     out = {
         "backend": jax.default_backend(),
-        "n_chips": max(jax.device_count(), 1),
+        # devices the runner's mesh spans (the per-chip divisor) and
+        # every device the host has
+        "n_chips": eng.ecfg.mesh_devices(jax.device_count()),
+        "host_devices": jax.device_count(),
         "model": model,
         "interactive_slots": ecfg["interactive_slots"],
         "legs": results,

@@ -260,7 +260,7 @@ def main() -> None:
 
     import jax
 
-    if args.smoke or os.environ.get("SUTRO_E2E_CPU") == "1":
+    if args.smoke:
         jax.config.update("jax_platforms", "cpu")
     on_tpu = jax.default_backend() not in ("cpu",)
     smoke = args.smoke or not on_tpu

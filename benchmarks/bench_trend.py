@@ -70,8 +70,8 @@ GATE_MARGIN = 3.0
 # (script, extra env) — the producers behind the graded artifacts.
 # Both are the CPU smoke variants the Makefile runs in CI.
 CHEAP_LEGS = (
-    ("bench_e2e.py", {}),
-    ("bench_interactive.py", {"SUTRO_E2E_CPU": "1"}),
+    ("bench_e2e.py", {"JAX_PLATFORMS": "cpu"}),
+    ("bench_interactive.py", {"JAX_PLATFORMS": "cpu"}),
 )
 # artifacts the producers rewrite; characterization restores them so a
 # variance pass never silently moves the repo's committed numbers

@@ -1000,16 +1000,14 @@ def engine() -> None:
 
 @engine.command("info")
 def engine_info() -> None:
-    import jax
-
+    """The device report (engine/runner.py device_report — the same
+    facts chip_smoke.py prints) plus the configured KV geometry."""
     from .engine.config import load_engine_config
+    from .engine.runner import device_report
 
-    devices = jax.devices()
     ecfg = load_engine_config()
-    click.echo(f"backend: {jax.default_backend()}")
-    click.echo(f"devices: {[str(d) for d in devices]}")
-    dp, pp, sp, ep, tp = ecfg.resolved_mesh(len(devices))
-    click.echo(f"mesh: dp={dp} pp={pp} sp={sp} ep={ep} tp={tp}")
+    for key, value in device_report(ecfg).items():
+        click.echo(f"{key}: {value}")
     click.echo(
         f"kv: page_size={ecfg.kv_page_size} max_pages_per_seq="
         f"{ecfg.max_pages_per_seq} decode_batch={ecfg.decode_batch_size}"

@@ -588,14 +588,25 @@ class LocalEngine:
         if not df["row_id"].is_monotonic_increasing:
             df = df.sort_values("row_id")  # streamed results are
             #                                already row-ordered
-        out: Dict[str, Any] = {"outputs": df["outputs"].tolist()}
+        def nulls_to_none(values: list) -> list:
+            # a null cell comes back from pandas as None or as NaN,
+            # depending on the column's dtype; the wire contract (and
+            # JSON) knows only null
+            return [
+                None if isinstance(v, float) and v != v else v
+                for v in values
+            ]
+
+        out: Dict[str, Any] = {
+            # a quarantined row's output is null
+            "outputs": nulls_to_none(df["outputs"].tolist())
+        }
         if "error" in df.columns and df["error"].notna().any():
             # quarantined rows (row-level failure domain): 1:1 with
             # outputs, None for clean rows
             out["errors"] = [
-                None if v is None or (isinstance(v, float) and v != v)
-                else str(v)
-                for v in df["error"].tolist()
+                None if v is None else str(v)
+                for v in nulls_to_none(df["error"].tolist())
             ]
         if include_inputs:
             out["inputs"] = self.jobs.read_inputs(job_id)
@@ -1899,7 +1910,9 @@ class LocalEngine:
         from .dphost import DPWorld, EmbResult
 
         dp = DPWorld.from_env()
-        n_chips = max(jax.device_count(), 1) * (dp.world if dp else 1)
+        n_chips = self.ecfg.mesh_devices(jax.device_count()) * (
+            dp.world if dp else 1
+        )
         # batch the progress bus (a 1M-row job would otherwise pay one
         # bus publish per row) — shared rule with the generation path
         from .metrics import BatchedProgress
@@ -2216,7 +2229,7 @@ class _GenSession:
         # under engine-level DP the merged progress stream carries POD
         # throughput, so per-chip numbers divide by pod chips
         # (homogeneous slices), not this rank's
-        self.n_chips = max(jax.device_count(), 1) * (
+        self.n_chips = eng.ecfg.mesh_devices(jax.device_count()) * (
             dp.world if dp else 1
         )
         self._dp = dp is not None
@@ -2497,7 +2510,9 @@ class _GenSession:
         def _count_chunk(df) -> None:
             counted["output_tokens"] += int(
                 sum(
-                    len(self.tok.encode(o)) if o else 0
+                    # a quarantined row's output is null, which a
+                    # pandas column hands back as None or as NaN
+                    len(self.tok.encode(o)) if isinstance(o, str) else 0
                     for o in df["outputs"].tolist()
                 )
             )

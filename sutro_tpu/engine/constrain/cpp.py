@@ -88,6 +88,17 @@ def _load_lib():
     return lib
 
 
+def is_available() -> bool:
+    """True when the native mask core builds and loads here (the
+    device report says which FSM a run used; MaskCache falls back to
+    the pure-Python walk otherwise)."""
+    try:
+        _load_lib()
+    except (OSError, RuntimeError, subprocess.SubprocessError):
+        return False
+    return True
+
+
 def _bitmap_to_u32(bm: np.ndarray) -> np.ndarray:
     return np.packbits(bm.astype(np.uint8), bitorder="little").view(np.uint32)
 

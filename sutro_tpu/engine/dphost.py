@@ -113,7 +113,8 @@ knows the job inputs could connect; on networks where that matters, set
 ``SUTRO_DP_SECRET`` to the same random value on every rank — it is
 mixed into the key derivation (api.py), making the key underivable from
 job content alone. It is an authentication tag, not encryption: use an
-actually-private network (or tunnel) for confidential row data.
+actually-private network (or an encrypted link) for confidential row
+data.
 """
 
 from __future__ import annotations

@@ -66,7 +66,12 @@ class KVCache:
 def alloc_cache(
     mcfg: ModelConfig, ecfg: EngineConfig, num_pages: int,
     dtype: jnp.dtype = jnp.bfloat16,
+    sharding: "jax.sharding.NamedSharding | None" = None,
 ) -> KVCache:
+    """Zeroed page pools. With ``sharding`` (parallel/sharding.py
+    ``cache_shardings``) every pool is allocated sharded — never whole
+    on one device first; the int8 per-token scale pools are
+    shard-invariant (full-KD amax) and replicate across that mesh."""
     shape = (
         mcfg.num_layers,
         num_pages,
@@ -74,17 +79,25 @@ def alloc_cache(
         mcfg.num_kv_heads * mcfg.head_dim,
     )
     if getattr(ecfg, "kv_quantize", None) == "int8":
+        rep = None
+        if sharding is not None:
+            rep = jax.sharding.NamedSharding(
+                sharding.mesh, jax.sharding.PartitionSpec()
+            )
         return KVCache(
-            k_pages=jnp.zeros(shape, jnp.int8),
-            v_pages=jnp.zeros(shape, jnp.int8),
-            k_scale=jnp.zeros(shape[:3], jnp.float32),
-            v_scale=jnp.zeros(shape[:3], jnp.float32),
+            k_pages=jnp.zeros(shape, jnp.int8, device=sharding),
+            v_pages=jnp.zeros(shape, jnp.int8, device=sharding),
+            k_scale=jnp.zeros(shape[:3], jnp.float32, device=rep),
+            v_scale=jnp.zeros(shape[:3], jnp.float32, device=rep),
         )
     if getattr(ecfg, "kv_quantize", None):
         raise ValueError(
             f"Unknown kv_quantize mode {ecfg.kv_quantize!r} (only 'int8')"
         )
-    return KVCache(k_pages=jnp.zeros(shape, dtype), v_pages=jnp.zeros(shape, dtype))
+    return KVCache(
+        k_pages=jnp.zeros(shape, dtype, device=sharding),
+        v_pages=jnp.zeros(shape, dtype, device=sharding),
+    )
 
 
 def _quantize_tokens(x: jax.Array):
@@ -200,10 +213,12 @@ def write_kv(
     start: jax.Array,          # [B] int32 — global position of chunk token 0
     valid_len: jax.Array,      # [B] int32 — real tokens in chunk
     use_pallas: bool = False,
+    kernel_mesh=None,          # mesh whose "model" axis shards KD
 ) -> KVCache:
     """Scatter a chunk's K/V into pages. Padding positions are routed to
     garbage page 0. With ``use_pallas`` the write is a true in-place DMA
-    (ops/pallas_kv.py) instead of an XLA scatter over the full pool."""
+    (ops/pallas_kv.py) instead of an XLA scatter over the full pool;
+    under ``kernel_mesh`` each "model" shard writes its own KV heads."""
     if k_chunk.ndim == 4:  # already fused (decode window buffers)
         L, B, T, KD = k_chunk.shape
     else:
@@ -216,6 +231,10 @@ def write_kv(
         # the unquantized fallback below (shared index helper), plus
         # the scale scatter. The in-place Pallas write kernel is
         # bf16-only — the XLA path serves the quantized cache.
+        if use_pallas:
+            from ..ops import lowering
+
+            lowering.record_reference("kv_write")
         kq, ks = _quantize_tokens(k_chunk.reshape(L, B, T, KD))
         vq, vs = _quantize_tokens(v_chunk.reshape(L, B, T, KD))
         flat = _flat_slots(page_table, start, valid_len, T, PS)
@@ -234,16 +253,33 @@ def write_kv(
             v_scale=vs_flat.reshape(L, NP, PS),
         )
     if use_pallas:
+        from jax.sharding import PartitionSpec as P
+
+        from ..ops.lowering import shard_over_model
         from ..ops.pallas_kv import kv_write_pallas
 
-        k_pages, v_pages = kv_write_pallas(
-            cache.k_pages,
-            cache.v_pages,
-            k_chunk.reshape(L, B, T, KD).astype(cache.k_pages.dtype),
-            v_chunk.reshape(L, B, T, KD).astype(cache.v_pages.dtype),
-            page_table.astype(jnp.int32),
-            start.astype(jnp.int32),
-            valid_len.astype(jnp.int32),
+        kd = P(None, None, None, "model")
+        k_pages, v_pages = shard_over_model(
+            kernel_mesh,
+            kv_write_pallas,
+            dict(
+                k_pages=cache.k_pages,
+                v_pages=cache.v_pages,
+                k_new=k_chunk.reshape(L, B, T, KD).astype(
+                    cache.k_pages.dtype
+                ),
+                v_new=v_chunk.reshape(L, B, T, KD).astype(
+                    cache.v_pages.dtype
+                ),
+                page_table=page_table.astype(jnp.int32),
+                start=start.astype(jnp.int32),
+                valid_len=valid_len.astype(jnp.int32),
+            ),
+            dict(
+                k_pages=kd, v_pages=kd, k_new=kd, v_new=kd,
+                page_table=P(), start=P(), valid_len=P(),
+            ),
+            (kd, kd),
         )
         return KVCache(k_pages=k_pages, v_pages=v_pages)
 

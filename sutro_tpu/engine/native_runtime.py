@@ -16,11 +16,14 @@ force the fallback.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 from typing import List, Optional
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
@@ -51,7 +54,13 @@ def _load_lib():
             timeout=120,
         )
         lib = ctypes.CDLL(_LIB_PATH)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        # no toolchain / build or dlopen failed: the scheduler runs on
+        # the pure-Python allocator — said once here, and reported by
+        # runner.device_report(), never silent
+        logger.warning(
+            "native runtime unavailable (%s); using the Python allocator", e
+        )
         _lib_failed = True
         return None
 

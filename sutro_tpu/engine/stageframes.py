@@ -93,7 +93,7 @@ def _send(
     JSON document with the HTTP status attached. Non-2xx is a protocol
     answer (400 ``INVALID_GRAPH`` carries the structured error body),
     not a transport error — callers branch on ``_status`` without
-    exceptions, same failure taxonomy as the fleet frames."""
+    exceptions, same failure classes as the fleet frames."""
     import requests
 
     resp = requests.post(url, json=payload, timeout=timeout)

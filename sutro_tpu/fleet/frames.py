@@ -214,7 +214,7 @@ def _send(
 ) -> Any:
     """One router->replica HTTP exchange; returns the decoded JSON
     document. Raises OSError-shaped errors (requests' ConnectionError
-    subclasses IOError) so callers share one failure taxonomy with the
+    subclasses IOError) so callers share one set of failure classes with the
     engine's transient-retry policy."""
     import requests
 

@@ -23,9 +23,9 @@ functional and cache-layout-agnostic.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
-
+import functools
 import os
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,9 +50,30 @@ def _w(lp: Dict[str, Any], name: str, dtype) -> jax.Array:
 
 
 def init_params(
-    cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat16
+    cfg: ModelConfig,
+    key: jax.Array,
+    dtype: jnp.dtype = jnp.bfloat16,
+    shardings: Optional[Any] = None,
 ) -> Params:
-    """Random init with per-layer stacking on axis 0 (scan layout)."""
+    """Random init with per-layer stacking on axis 0 (scan layout).
+
+    The whole tree comes out of ONE jitted program, so each leaf's
+    ``normal * scale -> cast`` chain fuses and the float32 draw never
+    exists as an array on the device (eagerly, qwen3-4b's ``w_gate``
+    alone is 3.6 GB in f32 with two such alive during the multiply —
+    on top of the 8 GB being built, that does not fit a 16 GB chip).
+    ``shardings`` (a pytree of shardings matching the result, e.g.
+    ``param_shardings(jax.eval_shape(init_params, ...), mesh)``) makes
+    every leaf land sharded: a model that needs four chips never sits
+    whole on the first. Values do not depend on the sharding."""
+    dtype = jnp.dtype(dtype)
+    if shardings is None:
+        return _init_params_jit(cfg, key, dtype)
+    build = functools.partial(_init_params, cfg, dtype=dtype)
+    return jax.jit(build, out_shardings=shardings)(key)
+
+
+def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     H, L = cfg.hidden_size, cfg.num_layers
     NHD, KVD = cfg.q_size, cfg.kv_size
     F, Dh = cfg.intermediate_size, cfg.head_dim
@@ -116,6 +137,10 @@ def init_params(
     if not cfg.tie_embeddings and cfg.head == "lm":
         params["lm_head"] = dense((H, cfg.vocab_size), H)
     return params
+
+
+# one cached program per (config, dtype): runners are built often (tests)
+_init_params_jit = jax.jit(_init_params, static_argnums=(0, 2))
 
 
 # ---------------------------------------------------------------------------
@@ -257,6 +282,7 @@ def layer_apply(
     ep_mesh=None,  # Mesh with "expert" axis > 1 => shard_map EP MLP
     pfx_groups: Optional[tuple] = None,  # shared-prefix decode groups
     #                                      (ops/attention.py)
+    kernel_mesh=None,  # Mesh: Pallas calls shard_map over its "model" axis
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """One decoder block. Shared by the scanned ``forward`` and the
     pipeline-parallel stage loop (parallel/pipeline.py). Returns
@@ -291,6 +317,7 @@ def layer_apply(
         win_k=wk_l, win_v=wv_l, win_len=win_len,
         kv_chunk=kv_chunk,
         pfx_groups=pfx_groups,
+        kernel_mesh=kernel_mesh,
     )
     attn = attn.reshape(B, T, cfg.q_size) @ _w(lp, "wo", h.dtype)
     if cfg.attn_bias:
@@ -334,12 +361,19 @@ def embed_tokens(cfg: ModelConfig, params: Params, ids: jax.Array) -> jax.Array:
 
 
 def head_apply(
-    cfg: ModelConfig, params: Params, h: jax.Array, valid_len: jax.Array
+    cfg: ModelConfig, params: Params, h: jax.Array, valid_len: jax.Array,
+    logit_positions: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """final norm + lm/embedding head. h: [B, T, H].
 
     Returns ``(out, h_normed)`` — the head output plus the post-final-norm
-    hidden states (the ``hidden`` of the forward contract)."""
+    hidden states (the ``hidden`` of the forward contract).
+
+    ``logit_positions`` ([B] int32) runs the LM head on that ONE
+    position per row and returns logits ``[B, 1, V]``: prefill samples
+    only from the last valid position, and the full ``[B, T, V]`` tensor
+    (8 x 512 x 151,936 in bf16 then f32 is ~3.7 GB at Qwen3's vocab)
+    is the largest transient of the whole program."""
     T = h.shape[1]
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, cfg.norm_zero_centered)
     if cfg.head == "embedding":
@@ -366,7 +400,12 @@ def head_apply(
         lm_head = params["embed"].T
     else:
         lm_head = materialize(lm_head, h.dtype)
-    logits = h @ lm_head.astype(h.dtype)
+    h_head = h
+    if logit_positions is not None:
+        h_head = jnp.take_along_axis(
+            h, logit_positions[:, None, None], axis=1
+        )
+    logits = h_head @ lm_head.astype(h.dtype)
     # SUTRO_LOGITS_BF16=1 keeps the [*, V] logits in the activation
     # dtype: sampling's full-vocab passes (ops/sampling.py) then read
     # half the HBM bytes. Default OFF — bf16 argmax can flip near-ties
@@ -412,6 +451,12 @@ def forward(
     # the job-shared pages at member rows' table heads + per-row
     # prefix token counts (0 = row not in that group)
     pfx_groups: Optional[tuple] = None,
+    # [B] int32: LM-head logits for this one position per row only
+    # ([B, 1, V] instead of [B, T, V]) — see head_apply
+    logit_positions: Optional[jax.Array] = None,
+    # Mesh whose "model" axis the Pallas calls are shard_mapped over
+    # (tensor parallelism; ops/lowering.shard_over_model)
+    kernel_mesh=None,
 ) -> Tuple[jax.Array, jax.Array, Tuple[jax.Array, jax.Array]]:
     """Run the trunk over a chunk.
 
@@ -471,12 +516,12 @@ def forward(
             use_pallas=use_pallas, ring_mesh=ring_mesh,
             wk_l=wk_l, wv_l=wv_l, win_len=win_len,
             kv_chunk=kv_chunk, ep_mesh=ep_mesh,
-            pfx_groups=pfx_groups,
+            pfx_groups=pfx_groups, kernel_mesh=kernel_mesh,
         )
 
     h, (k_all, v_all) = jax.lax.scan(layer_step, h, xs)
 
-    out, h = head_apply(cfg, params, h, valid_len)
+    out, h = head_apply(cfg, params, h, valid_len, logit_positions)
     return out, h, (k_all, v_all)
 
 

@@ -6,9 +6,11 @@ The reference has no kernels at all (SURVEY §2.3); this is the TPU-native
 hot path. Two implementations sit behind one signature:
 
 - a pure-``jnp`` path (XLA fuses it well; used on CPU tests and as the
-  always-correct fallback), and
-- Pallas flash/paged kernels (ops/pallas_attention.py), dispatched with
-  ``use_pallas=True`` on TPU.
+  reference the kernels are tested against), and
+- Pallas flash/paged kernels (ops/pallas_flash.py, ops/pallas_paged.py),
+  dispatched with ``use_pallas=True`` on TPU. Their shape gates send
+  unsupported calls to the jnp path; every such call is counted
+  (ops/lowering.py) so a chip run can tell which path it built.
 
 Semantics handled here, uniformly: GQA head grouping, causal masking within
 the chunk, past-length masking, per-layer sliding windows (Gemma3 5:1
@@ -21,10 +23,74 @@ from __future__ import annotations
 
 from typing import Optional
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from . import lowering
 
 NEG_INF = -1e30
+
+
+# how each kernel operand shards over the mesh's "model" axis: heads
+# and the fused KV-head-major KD axis split in whole-KV-head blocks
+# (parallel/sharding.py), everything else replicates
+_PAGED_SPECS = dict(
+    q=P(None, "model", None),
+    k_pages=P(None, None, "model"), v_pages=P(None, None, "model"),
+    page_table=P(), past_len=P(),
+    k_cur=P(None, "model", None), v_cur=P(None, "model", None),
+    window=P(), sink=P("model"),
+    win_k=P(None, None, "model"), win_v=P(None, None, "model"),
+    win_len=P(), k_scale=P(), v_scale=P(),
+)
+_FLASH_SPECS = dict(
+    q=P(None, None, "model", None), k=P(None, None, "model", None),
+    v=P(None, None, "model", None), window=P(), sink=P("model"),
+)
+
+
+def _prefix_carry(
+    q, k_pages, v_pages, k_scale, v_scale, pfx_groups, q_pos, win
+) -> dict:
+    """The paged kernel's initial online-softmax carry over the
+    job-shared prefix groups (Hydragen-style split decode): each
+    group's prefix attention is computed ONCE for the whole batch and
+    the per-row carries combine exactly, because groups have DISJOINT
+    member rows — cold rows contribute (-inf, 0, 0) to max/sum/sum."""
+    from .pallas_paged import (
+        prefix_attention_carry,
+        prefix_attention_carry_pallas,
+        prefix_carry_supported,
+    )
+
+    PS = k_pages.shape[1]
+    # in-place carry kernel when shapes allow: the shared pages are
+    # read straight from the HBM pool (page-indexed BlockSpecs);
+    # otherwise the XLA gather computes the identical carry
+    in_place = prefix_carry_supported(q, k_pages, k_scale)
+    m0 = l0 = acc0 = None
+    pfx_cnt = jnp.zeros_like(q_pos)
+    for pages_g, len_g in pfx_groups:
+        if in_place:
+            mg, lg, ag = prefix_attention_carry_pallas(
+                q, k_pages, v_pages, pages_g, len_g, q_pos, win,
+            )
+        else:
+            mg, lg, ag = prefix_attention_carry(
+                q, k_pages, v_pages, pages_g, len_g, q_pos, win,
+                k_scale=k_scale, v_scale=v_scale,
+            )
+        if m0 is None:
+            m0, l0, acc0 = mg, lg, ag
+        else:
+            m0 = jnp.maximum(m0, mg)
+            l0 = l0 + lg
+            acc0 = acc0 + ag
+        pfx_cnt = pfx_cnt + len_g // PS
+    return dict(pfx_cnt=pfx_cnt, m0=m0, l0=l0, acc0=acc0)
 
 
 def chunk_attention(
@@ -73,6 +139,11 @@ def chunk_attention(
     # still contain the prefix pages, so its full-table gather computes
     # the identical function).
     pfx_groups: Optional[tuple] = None,
+    # mesh whose "model" axis shards the heads: the Pallas calls run as
+    # a shard_map over it (ops/lowering.shard_over_model). The
+    # shared-prefix carry is not partitioned; under a mesh its groups
+    # are ignored like on the fallback path (same function).
+    kernel_mesh=None,
 ) -> jax.Array:
     """Returns [B, T, NH, Dh]."""
     B, T = q.shape[:2]
@@ -98,64 +169,46 @@ def chunk_attention(
                     jnp.asarray(0, jnp.int32) if window is None
                     else jnp.asarray(window, jnp.int32)
                 )
-                pfx_kw = {}
-                if pfx_groups:
-                    from .pallas_paged import (
-                        prefix_attention_carry,
-                        prefix_attention_carry_pallas,
-                        prefix_carry_supported,
-                    )
-
-                    PS = past_k_pages.shape[1]
-                    q_pos = past_len + (
-                        win_len if win_len is not None else 0
-                    )
-                    # in-place carry kernel when shapes allow: the
-                    # shared pages are read straight from the HBM pool
-                    # (page-indexed BlockSpecs); otherwise the XLA
-                    # gather computes the identical carry
-                    in_place = prefix_carry_supported(
-                        q[:, 0], past_k_pages, past_k_scale
-                    )
-                    # groups have DISJOINT member rows, so per-row
-                    # carries combine exactly: cold rows contribute
-                    # (-inf, 0, 0) to max/sum/sum
-                    m0 = l0 = acc0 = None
-                    pfx_cnt = jnp.zeros_like(past_len)
-                    for pages_g, len_g in pfx_groups:
-                        if in_place:
-                            mg, lg, ag = prefix_attention_carry_pallas(
-                                q[:, 0], past_k_pages, past_v_pages,
-                                pages_g, len_g, q_pos, win,
-                            )
-                        else:
-                            mg, lg, ag = prefix_attention_carry(
-                                q[:, 0], past_k_pages, past_v_pages,
-                                pages_g, len_g, q_pos, win,
-                                k_scale=past_k_scale,
-                                v_scale=past_v_scale,
-                            )
-                        if m0 is None:
-                            m0, l0, acc0 = mg, lg, ag
-                        else:
-                            m0 = jnp.maximum(m0, mg)
-                            l0 = l0 + lg
-                            acc0 = acc0 + ag
-                        pfx_cnt = pfx_cnt + len_g // PS
-                    pfx_kw = dict(
-                        pfx_cnt=pfx_cnt, m0=m0, l0=l0, acc0=acc0
-                    )
-                out = paged_decode_attention(
-                    q[:, 0], past_k_pages, past_v_pages, page_table,
-                    past_len, k[:, 0], v[:, 0], win, sink,
-                    win_k=win_k, win_v=win_v, win_len=win_len,
-                    kv_chunk=1 if pfx_groups else kv_chunk,
+                ops = dict(
+                    q=q[:, 0], k_pages=past_k_pages, v_pages=past_v_pages,
+                    page_table=page_table, past_len=past_len,
+                    k_cur=k[:, 0], v_cur=v[:, 0], window=win,
+                )
+                optional = dict(
+                    sink=sink, win_k=win_k, win_v=win_v, win_len=win_len,
                     k_scale=past_k_scale, v_scale=past_v_scale,
-                    **pfx_kw,
+                )
+                split = bool(pfx_groups) and kernel_mesh is None
+                if split:
+                    optional.update(
+                        _prefix_carry(
+                            q[:, 0], past_k_pages, past_v_pages,
+                            past_k_scale, past_v_scale, pfx_groups,
+                            past_len + (
+                                win_len if win_len is not None else 0
+                            ),
+                            win,
+                        )
+                    )
+                ops.update(
+                    {k_: v_ for k_, v_ in optional.items() if v_ is not None}
+                )
+                out = lowering.shard_over_model(
+                    kernel_mesh,
+                    functools.partial(
+                        paged_decode_attention,
+                        kv_chunk=1 if split else kv_chunk,
+                    ),
+                    ops, _PAGED_SPECS, P(None, "model", None),
                 )
                 return out[:, None]
         from ..engine.kvcache import gather_kv_layer
 
+        if use_pallas:
+            # T>1 over a paged past (chunked prefill, verify forwards)
+            # gathers by design; T==1 lands here only when the shape
+            # gate refused the kernel
+            lowering.record_reference("paged_decode")
         past_k, past_v = gather_kv_layer(
             past_k_pages, past_v_pages, page_table, k.shape[2],
             k_scale_l=past_k_scale, v_scale_l=past_v_scale,
@@ -163,15 +216,20 @@ def chunk_attention(
         )
 
     if use_pallas:
-        from . import pallas_attention as pa
+        from .pallas_flash import flash_prefill, flash_prefill_supported
 
-        out = pa.try_chunk_attention(
-            q, k, v, positions=positions, valid_len=valid_len,
-            past_k=past_k, past_v=past_v, past_len=past_len,
-            window=window, sink=sink,
-        )
-        if out is not None:
-            return out
+        if past_k is None and flash_prefill_supported(q, k, window, sink):
+            ops = dict(q=q, k=k, v=v)
+            if window is not None:
+                ops["window"] = jnp.asarray(window, jnp.int32)
+            if sink is not None:
+                ops["sink"] = sink
+            return lowering.shard_over_model(
+                kernel_mesh, flash_prefill, ops, _FLASH_SPECS,
+                P(None, None, "model", None),
+            )
+        if T > 1:
+            lowering.record_reference("flash_prefill")
 
     B, T, NH, Dh = q.shape
     KVH = k.shape[2]

@@ -1,0 +1,72 @@
+"""Which implementation each Pallas dispatch site took, counted while
+tracing.
+
+``use_pallas=True`` does not by itself mean a kernel ran: the shape
+gates in ops/attention.py send unsupported calls to the jnp reference,
+and tests run the kernels in interpret mode. A run on the chip must be
+able to say which of the three happened, so every kernel wrapper and
+every gate that falls through records it here:
+
+- ``lowered``: the kernel body was traced with ``interpret=False`` — it
+  goes to Mosaic when the program compiles;
+- ``interpreted``: traced with ``interpret=True`` (CPU tests);
+- ``reference``: ``use_pallas=True`` was asked for and the jnp / XLA
+  path ran instead (by a shape gate, or by design: T>1 over a paged
+  past gathers the pages).
+
+Counts are per TRACE, not per execution — jit caches traces, so a count
+says "this path was built into a program at least that many times",
+which is what a bring-up check needs (chip_smoke.py, `sutro engine
+info`). Process-wide like the jit caches it mirrors.
+"""
+
+from __future__ import annotations
+
+import threading
+from typing import Dict
+
+import jax
+
+KERNELS = ("paged_decode", "flash_prefill", "kv_write")
+PATHS = ("lowered", "interpreted", "reference")
+
+_lock = threading.Lock()
+_counts: Dict[str, Dict[str, int]] = {
+    k: dict.fromkeys(PATHS, 0) for k in KERNELS
+}
+
+
+def record_kernel(kernel: str, *, interpret: bool) -> None:
+    """Called from a kernel wrapper's traced body."""
+    with _lock:
+        _counts[kernel]["interpreted" if interpret else "lowered"] += 1
+
+
+def record_reference(kernel: str) -> None:
+    """Called where a ``use_pallas=True`` call takes the jnp/XLA path."""
+    with _lock:
+        _counts[kernel]["reference"] += 1
+
+
+def snapshot() -> Dict[str, Dict[str, int]]:
+    with _lock:
+        return {k: dict(v) for k, v in _counts.items()}
+
+
+def shard_over_model(mesh, fn, operands: dict, specs: dict, out_specs):
+    """Call a Pallas wrapper ``fn(**operands)`` once per shard of the
+    mesh's ``model`` axis (heads / the fused KV axis), or bare when
+    ``mesh`` is None. XLA cannot partition a Mosaic call ("Mosaic
+    kernels cannot be automatically partitioned"), and the kernels are
+    independent per KV head, so tensor parallelism runs them as a
+    shard_map with no collective inside. ``specs`` holds a
+    PartitionSpec per possible operand; only those present are used."""
+    if mesh is None:
+        return fn(**operands)
+    return jax.shard_map(
+        lambda ops: fn(**ops),
+        mesh=mesh,
+        in_specs=({k: specs[k] for k in operands},),
+        out_specs=out_specs,
+        check_vma=False,
+    )(operands)

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .moe import _act, _grouped, _route
-from .shard_compat import shard_map as _shard_map
 
 
 def moe_mlp_ep(
@@ -131,7 +130,7 @@ def moe_mlp_ep(
         return out.reshape(Bl, Tl, H)
 
     opt = lambda spec, v: None if v is None else spec  # noqa: E731
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

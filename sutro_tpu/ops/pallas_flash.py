@@ -47,11 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept
-# either so the kernels build across the jax versions we run on
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
+from . import lowering
 
 NEG_INF = -1e30
 
@@ -174,6 +170,7 @@ def flash_prefill(
     interpret: bool = False,
 ) -> jax.Array:
     """Returns [B, T, NH, Dh] causal self-attention over the chunk."""
+    lowering.record_kernel("flash_prefill", interpret=interpret)
     B, T, NH, Dh = q.shape
     KVH = k.shape[2]
     G = NH // KVH
@@ -232,7 +229,7 @@ def flash_prefill(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, T, Dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary"
             ),

@@ -33,12 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept
-# either so the kernels build across the jax versions we run on
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 BLOCK_M = 32
 BLOCK_F = 128
 
@@ -116,7 +110,7 @@ def grouped_matmul(
             ),
         ),
         out_shape=jax.ShapeDtypeStruct((MP, F), lhs.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

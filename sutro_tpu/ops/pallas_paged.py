@@ -41,11 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept
-# either so the kernels build across the jax versions we run on
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
+from . import lowering
 
 NEG_INF = -1e30
 
@@ -636,7 +632,7 @@ def prefix_attention_carry_pallas(
             jax.ShapeDtypeStruct((B * NH, KD), jnp.float32),
         ],
         # the carry threads scratch state page to page: sequential grid
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -755,6 +751,7 @@ def paged_decode_attention(
     window whose K/V have NOT been written to the page pool yet — the
     bulk page write happens once per window, outside the step scan, so
     the multi-GB pool is never copied per step."""
+    lowering.record_kernel("paged_decode", interpret=interpret)
     B, NH, Dh = q.shape
     NP, PS, KD = k_pages.shape
     KVH = k_cur.shape[1]
@@ -884,7 +881,7 @@ def paged_decode_attention(
         # out rows, scratch reinitialized per step) and "parallel" lets
         # megacore TPUs split the grid; the cross-row handoff threads
         # DMA state between steps and needs sequential "arbitrary" rows
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "arbitrary" if cross_row else "parallel",
             ),

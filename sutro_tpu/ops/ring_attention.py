@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .attention import NEG_INF
-from .shard_compat import shard_map as _shard_map
 
 
 def _ring_body(
@@ -161,7 +160,7 @@ def ring_self_attention(
     spec_qkv = P(None, axis_name, h, None)
     spec_bt = P(None, axis_name)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(
             ring_attention_local,
             axis_name=axis_name,

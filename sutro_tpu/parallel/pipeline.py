@@ -45,7 +45,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.configs import ModelConfig
-from ..ops.shard_compat import pcast as _pcast, shard_map as _shard_map
 from ..models import transformer
 from .sharding import param_shardings
 
@@ -163,7 +162,7 @@ def pipeline_forward(
         )
         return out, k_out, v_out
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         stage,
         mesh=mesh,
         in_specs=(P("pipe"), P("pipe"), P("pipe"), P(), P(), P()),
@@ -252,7 +251,7 @@ def pipeline_decode(
         v_out = jnp.zeros_like(k_out)
         # the carry becomes pipe-varying after the first stage's layers;
         # mark it varying from the start so scan carry types line up
-        buf = _pcast(h0, ("pipe",), to="varying")
+        buf = jax.lax.pcast(h0, ("pipe",), to="varying")
         y = buf
         for t in range(S):
             active = s == t
@@ -261,7 +260,7 @@ def pipeline_decode(
                 run_stage,
                 lambda x: (
                     x,
-                    _pcast(
+                    jax.lax.pcast(
                         (jnp.zeros((Lb, B, T, KVH, Dh), h0.dtype),
                          jnp.zeros((Lb, B, T, KVH, Dh), h0.dtype)),
                         ("pipe",),
@@ -289,7 +288,7 @@ def pipeline_decode(
         wv_all = jnp.zeros((L, B, 0, KVH * Dh), h0.dtype)
         win_len = jnp.asarray(0, jnp.int32)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         stage,
         mesh=mesh,
         in_specs=(

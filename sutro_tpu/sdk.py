@@ -520,15 +520,20 @@ class Sutro(EmbeddingTemplates, ClassificationTemplates, EvalTemplates):
 
     def _iter_sse(self, resp: Any):
         """Parse an SSE chat stream (``data:`` frames until [DONE])."""
+        done = False
         for raw in resp.iter_lines():
-            if not raw:
+            if done or not raw:
+                # after [DONE] keep reading to the end of the body:
+                # closing with the terminating chunk unread resets the
+                # connection under the server's keep-alive handler
                 continue
             line = raw.decode() if isinstance(raw, bytes) else raw
             if not line.startswith("data:"):
                 continue  # ": ping" heartbeats / comments
             data = line[5:].strip()
             if data == "[DONE]":
-                return
+                done = True
+                continue
             yield json.loads(data)
 
     # ------------------------------------------------------------------

@@ -14,7 +14,7 @@ tests/test_doctor.py):
   device info, so each window's attempted token rate grades against
   the chip's HBM roofline (engine/roofline.py) and prefill spans grade
   as MFU;
-- **one named verdict** from a fixed taxonomy, most-specific first:
+- **one named verdict** from a fixed list, most-specific first:
 
   ========================  ============================================
   verdict                   meaning
@@ -61,7 +61,7 @@ IO_STAGES = ("flush", "finalize")
 #: round envelopes — excluded from attribution (they CONTAIN stages)
 ENVELOPE_STAGES = ("dp_round",)
 
-#: the verdict taxonomy, in priority order (OBSERVABILITY.md "Doctor")
+#: the verdict list, in priority order (OBSERVABILITY.md "Doctor")
 VERDICTS = (
     "insufficient_data",
     "warming_up",
@@ -228,7 +228,7 @@ _TERMINAL_STATUSES = ("SUCCEEDED", "FAILED", "CANCELLED")
 
 # -- per-request verdicts (forensics traces, telemetry/traces.py) ------
 
-#: the per-request taxonomy, in priority order
+#: the per-request verdict list, in priority order
 REQUEST_VERDICTS = (
     "insufficient_data",
     "queue_wait_bound",
@@ -367,7 +367,7 @@ def diagnose(
     in_flight: bool = False,
 ) -> Dict[str, Any]:
     """Analyze one merged job telemetry document into a diagnosis with
-    a named bottleneck verdict (see module docstring for the taxonomy)
+    a named bottleneck verdict (see module docstring for the list)
     and human-readable evidence lines.
 
     ``in_flight`` marks a diagnosis over a RUNNING job's live span

@@ -492,7 +492,7 @@ def test_self_scan_matches_committed_baseline():
     assert not stale, stale
     # pin the accepted-debt count: growing it needs a conscious
     # baseline regeneration in the same commit
-    assert len(active) == sum(baseline.values()) == 18
+    assert len(active) == sum(baseline.values()) == 16
 
 
 def run_cli(args, cwd):

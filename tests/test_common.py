@@ -89,30 +89,57 @@ def test_catalog_no_duplicates():
     assert len(names) == len(set(names))
 
 
-def test_compile_cache_optout_and_respect(monkeypatch):
-    """enable_compile_cache: SUTRO_COMPILE_CACHE=0 disables; an
-    explicit user cache dir is respected (not overwritten)."""
+def _arm_cache_rule(monkeypatch):
+    """Fresh latch, CPU opt-in (the suite runs on CPU, where the cache
+    is otherwise off), and a recorder in place of jax.config.update so
+    the suite's own session cache dir is never moved."""
     import jax
 
     from sutro_tpu.engine import config as cfgmod
 
     monkeypatch.setattr(cfgmod, "_CACHE_ENABLED", False)
+    monkeypatch.setenv("SUTRO_COMPILE_CACHE", "1")
+    updates = {}
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: updates.__setitem__(k, v)
+    )
+    return cfgmod, updates
+
+
+def test_compile_cache_optout(monkeypatch):
+    """SUTRO_COMPILE_CACHE=0 disables: nothing is configured."""
+    cfgmod, updates = _arm_cache_rule(monkeypatch)
     monkeypatch.setenv("SUTRO_COMPILE_CACHE", "0")
-    before = jax.config.jax_compilation_cache_dir
     cfgmod.enable_compile_cache()
     assert cfgmod._CACHE_ENABLED is False
-    assert jax.config.jax_compilation_cache_dir == before
+    assert updates == {}
 
-    monkeypatch.delenv("SUTRO_COMPILE_CACHE")
-    monkeypatch.setattr(cfgmod, "_CACHE_ENABLED", False)
-    jax.config.update("jax_compilation_cache_dir", "/tmp/user-chosen")
-    try:
+
+def test_compile_cache_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set => the program sets no cache
+    directory in code (JAX binds the variable itself at import)."""
+    cfgmod, updates = _arm_cache_rule(monkeypatch)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    cfgmod.enable_compile_cache()
+    assert "jax_compilation_cache_dir" not in updates
+
+
+def test_compile_cache_default_is_fixed_in_checkout(monkeypatch, tmp_path):
+    """Unset => one fixed directory inside the checkout, the same
+    whatever SUTRO_HOME says: the path is part of the cache key, so a
+    cache that follows a temp SUTRO_HOME never hits."""
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parent.parent
+    seen = []
+    for home in ("home-a", "home-b"):
+        cfgmod, updates = _arm_cache_rule(monkeypatch)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("SUTRO_HOME", str(tmp_path / home))
         cfgmod.enable_compile_cache()
-        assert (
-            jax.config.jax_compilation_cache_dir == "/tmp/user-chosen"
-        )
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+        seen.append(updates["jax_compilation_cache_dir"])
+    assert seen == [str(repo / ".xla_cache")] * 2
+    assert (repo / ".xla_cache").is_dir()
 
 
 def test_metrics_bus_conflates_slow_subscribers():

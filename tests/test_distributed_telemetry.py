@@ -10,7 +10,7 @@ full 2-process engine acceptance lives in test_dphost.py):
 2. a real coordinator/worker round over localhost with telemetry
    riding the channel, including graceful degradation against
    old-frame peers in BOTH directions;
-3. the bottleneck doctor — verdict taxonomy unit cases and the
+3. the bottleneck doctor — verdict-list unit cases and the
    golden-pinned diagnosis of a deterministic merged document.
 """
 

@@ -22,16 +22,6 @@ from tests.conftest import free_low_port as _free_port
 
 
 def test_two_process_mesh_collectives():
-    import jax
-    import pytest
-
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        pytest.skip(
-            "cross-process collectives on the CPU backend need jax >= "
-            "0.5 (XLA:CPU gloo collectives); this jax raises "
-            "'Multiprocess computations aren't implemented on the CPU "
-            "backend'"
-        )
     port = _free_port()
     procs = []
     for pid in range(2):

@@ -620,3 +620,36 @@ def test_prefix_carry_supported_flags():
     assert not prefix_carry_supported(
         q, good, k_scale=jnp.zeros((8, 8), jnp.float32)
     )
+
+
+def test_lowering_counters_tell_the_three_paths_apart():
+    """ops/lowering.py is how a chip run proves which path it built:
+    an interpret-mode kernel call is counted but NOT as lowered for the
+    TPU, and a ``use_pallas=True`` call that a shape gate sends to the
+    jnp reference is counted as such (chip_smoke.py asserts on these)."""
+    from sutro_tpu.ops import lowering
+    from sutro_tpu.ops.attention import chunk_attention
+
+    # shapes no other test traces, so the jitted wrapper's body runs
+    B, T, NH, KVH, Dh = 3, 128, 2, 1, 128
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.standard_normal((B, T, NH, Dh)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, T, KVH, Dh)), jnp.float32)
+    before = lowering.snapshot()["flash_prefill"]
+    flash_prefill(q, k, k, interpret=True)
+    mid = lowering.snapshot()["flash_prefill"]
+    assert mid["interpreted"] == before["interpreted"] + 1
+    assert mid["lowered"] == before["lowered"]
+    assert mid["reference"] == before["reference"]
+
+    # T=24 fails the flash gate (T % 128): use_pallas=True runs the jnp
+    # reference, and says so
+    pos = jnp.broadcast_to(jnp.arange(24, dtype=jnp.int32)[None], (B, 24))
+    out = chunk_attention(
+        q[:, :24], k[:, :24], k[:, :24], positions=pos,
+        valid_len=jnp.full((B,), 24, jnp.int32), use_pallas=True,
+    )
+    assert out.shape == (B, 24, NH, Dh)
+    after = lowering.snapshot()["flash_prefill"]
+    assert after["reference"] == mid["reference"] + 1
+    assert after["lowered"] == mid["lowered"]

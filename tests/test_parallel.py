@@ -214,13 +214,6 @@ def test_moe_ep_gspmd_fallback_under_sp_pp(eight_devices, sp, pp):
     is unsupported). The fallback COMBINATION must still generate
     greedy tokens identical to single-device; its perf remains
     chip-gated (PARITY.md), but correctness is pinned here."""
-    from sutro_tpu.ops.shard_compat import HAS_NEW_SHARD_MAP
-
-    if pp > 1 and not HAS_NEW_SHARD_MAP:
-        pytest.skip(
-            "pp through the jitted runner needs partial-auto shard_map "
-            "support (XLA:CPU rejects PartitionId on legacy jax)"
-        )
     cfg = MODEL_CONFIGS["tiny-moe"]
     prompt = np.arange(11, dtype=np.int32) % 200
 
@@ -255,3 +248,100 @@ def test_moe_ep_gspmd_fallback_under_sp_pp(eight_devices, sp, pp):
         make_mesh(1, 2, 2, eight_devices, sp=sp, pp=pp)
     )
     assert single == sharded
+
+
+def test_tp_pallas_kernels_match_single_device_xla(
+    eight_devices, monkeypatch
+):
+    """XLA cannot partition a Mosaic call, so under a tp mesh the
+    Pallas kernels run as a shard_map over ``model`` (ops/lowering.py
+    shard_over_model). Greedy prefill + single-step + fused-window
+    decode through the sharded runner (kernels in interpret mode on
+    CPU) must match the single-device XLA path — and the kernels must
+    actually have been traced, not bypassed."""
+    from sutro_tpu.ops import lowering
+    from tests.test_prefix_split import _force_interpret
+
+    _force_interpret(monkeypatch)
+    cfg = MODEL_CONFIGS["tiny-dense"]  # KVH=2 divides tp=2
+    prompt = (np.arange(13, dtype=np.int32) * 7) % 200
+
+    def run(mesh, use_pallas):
+        runner = ModelRunner(
+            cfg, _ecfg(use_pallas=use_pallas, decode_multi_step=3),
+            mesh=mesh,
+        )
+        assert runner.use_pallas is use_pallas
+        table = np.zeros((8,), np.int32)
+        table[:4] = [1, 2, 3, 4]
+        tables = np.stack([table] + [np.zeros_like(table)] * 3)
+        tok = int(np.argmax(runner.prefill(prompt, table)))
+        out = [tok]
+        pos = len(prompt)
+        zeros, ones = np.zeros(4, np.float32), np.ones(4, np.float32)
+        toks, _ = runner.decode_step(
+            np.array([tok, 0, 0, 0], np.int32),
+            np.array([pos, 0, 0, 0], np.int32),
+            tables, jax.random.PRNGKey(0), zeros, ones,
+        )
+        out.append(int(toks[0]))
+        win, _ = runner.decode_multi(
+            np.array([out[-1], 0, 0, 0], np.int32),
+            np.array([pos + 1, 0, 0, 0], np.int32),
+            tables, jax.random.PRNGKey(1), zeros, ones, 3,
+        )
+        return out + [int(t) for t in win[:, 0]]
+
+    before = lowering.snapshot()
+    sharded = run(make_mesh(1, 1, 2, eight_devices[:2]), True)
+    after = lowering.snapshot()
+    for kernel in ("paged_decode", "kv_write"):
+        assert (
+            after[kernel]["interpreted"] > before[kernel]["interpreted"]
+        ), kernel
+    assert sharded == run(None, False)
+
+
+def test_pallas_kernels_refuse_meshes_they_are_not_partitioned_for(
+    eight_devices,
+):
+    """The kernels shard over ``model`` only: a mesh that shards another
+    axis takes the XLA path when Pallas is on auto, and asking for the
+    kernels there raises at construction, not at the first compile."""
+    from sutro_tpu.engine.runner import resolve_pallas
+
+    mesh = make_mesh(2, 1, 2, eight_devices[:4])
+    use, why = resolve_pallas(_ecfg(use_pallas=None), mesh)
+    assert use is False and "cpu" in why
+    with pytest.raises(ValueError, match="'model' axis only"):
+        resolve_pallas(_ecfg(use_pallas=True), mesh)
+    assert resolve_pallas(
+        _ecfg(use_pallas=True), make_mesh(1, 1, 2, eight_devices[:2])
+    )[0]
+
+
+def test_flash_prefill_shards_over_model_axis(eight_devices):
+    """Flash prefill is independent per KV head, so its shard_map over
+    ``model`` (chunk_attention's kernel_mesh) must equal the unsharded
+    kernel — heads split in whole-KV-head blocks."""
+    import functools
+
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from sutro_tpu.ops import attention, lowering
+    from sutro_tpu.ops.pallas_flash import flash_prefill
+
+    B, T, NH, KVH, Dh = 1, 128, 4, 2, 128
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((B, T, NH, Dh)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, T, KVH, Dh)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, T, KVH, Dh)), jnp.float32)
+    flash = functools.partial(flash_prefill, interpret=True)
+    want = flash(q, k, v)
+    got = lowering.shard_over_model(
+        make_mesh(1, 1, 2, eight_devices[:2]), flash,
+        dict(q=q, k=k, v=v), attention._FLASH_SPECS,
+        P(None, None, "model", None),
+    )
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)

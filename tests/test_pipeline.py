@@ -10,7 +10,6 @@ import pytest
 from sutro_tpu.engine.runner import ModelRunner
 from sutro_tpu.models import transformer
 from sutro_tpu.models.configs import MODEL_CONFIGS
-from sutro_tpu.ops.shard_compat import HAS_NEW_SHARD_MAP
 from sutro_tpu.parallel.mesh import make_mesh
 from sutro_tpu.parallel.pipeline import (
     pipeline_forward,
@@ -23,11 +22,6 @@ from sutro_tpu.parallel.pipeline import (
 @pytest.mark.parametrize("model", ["tiny-dense", "tiny-oss"])
 @pytest.mark.parametrize("pp,tp,m", [(2, 1, 2), (2, 1, 4), (2, 2, 2)])
 def test_pipeline_forward_parity(eight_devices, model, pp, tp, m):
-    if tp > 1 and not HAS_NEW_SHARD_MAP:
-        pytest.skip(
-            "pp x tp needs partial-auto shard_map (jax.shard_map); "
-            "this jax only emulates full-manual meshes"
-        )
     cfg = MODEL_CONFIGS[model]
     params = transformer.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     B, T = 4, 16
@@ -63,11 +57,6 @@ def test_pp_runner_generation_matches_single_device(
 ):
     """Greedy prefill+decode through the engine runner must be identical
     with the layer stack pipeline-sharded (pp=2) and pp x tp (2x2)."""
-    if not HAS_NEW_SHARD_MAP:
-        pytest.skip(
-            "pp through the jitted runner needs partial-auto shard_map "
-            "support (XLA:CPU rejects PartitionId on legacy jax)"
-        )
     cfg = MODEL_CONFIGS["tiny-dense"]
     prompt = (np.arange(17, dtype=np.int32) * 5) % 199
 

@@ -2,7 +2,6 @@
 analytic bytes-per-step / roofline fractions computed from the model
 config, present in every bench record."""
 
-import json
 import os
 import subprocess
 import sys
@@ -80,27 +79,18 @@ def test_param_bytes_counts_quantized_width():
     assert roofline.param_count_of(params) == 20
 
 
-@pytest.mark.slow
-def test_bench_record_carries_grading_fields(tmp_path):
-    """bench.py's printed line and record carry the self-grading fields
-    (None off-TPU — unknown hardware is never graded against a made-up
-    roofline)."""
+def test_bench_refuses_to_measure_without_a_tpu(tmp_path):
+    """bench.py never times a stand-in model on the CPU under a device
+    metric's name: without a TPU it exits non-zero, names the platform
+    it found, and prints no result line."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
-    # run from tmp so the baseline file write does not touch the repo
-    code = (
-        "import jax; jax.config.update('jax_platforms', 'cpu');\n"
-        "import runpy, sys; sys.argv=['bench.py'];\n"
-        f"runpy.run_path({str(REPO / 'bench.py')!r}, run_name='__main__')"
-    )
     r = subprocess.run(
-        [sys.executable, "-c", code],
-        env=env, capture_output=True, text=True, timeout=900,
+        [sys.executable, str(REPO / "bench.py")],
+        env=env, capture_output=True, text=True, timeout=300,
         cwd=tmp_path,
     )
-    assert r.returncode == 0, r.stdout + r.stderr
-    line = json.loads(r.stdout.strip().splitlines()[-1])
-    assert "pct_hbm_roofline" in line
-    assert "mfu_prefill" in line
-    assert line["pct_hbm_roofline"] is None  # cpu: unknown hardware
+    assert r.returncode != 0, r.stdout + r.stderr
+    assert "'cpu'" in r.stderr
+    assert "tok/s/chip" not in r.stdout

@@ -1,8 +1,8 @@
-"""sutro_tpu.engine.softdeadline: the un-wedgeable-queue primitive.
+"""sutro_tpu.engine.softdeadline: the clean-unwind deadline.
 
 Each case runs a small subprocess (no jax import — the module is pure
-stdlib) and asserts the exit discipline that chip_validation.py and
-chip_day.sh rely on: rc=124 on deadline/TERM with a CLEAN unwind
+stdlib) and asserts the exit discipline time-boxed scripts and
+``sutro serve`` rely on: rc=124 on deadline/TERM with a CLEAN unwind
 (atexit-visible), teardown never aborted by the re-signal loop, and
 inherited-SIG_IGN dispositions overridden (non-interactive shells
 launch children with SIGINT ignored)."""
