@@ -1,0 +1,102 @@
+"""The CPU rehearsal of every cell's control flow, end to end, as a
+process of its own (tiny-dense files kept apart from BENCHMARK.json):
+the last line parses and has the contract's keys, every line is tagged,
+no device metric's name is printed, a refused chat lands in ``failed``
+and never in ``correct``. And off the chip the real cells refuse."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parents[2]
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+TAG = "[CPU REHEARSAL - not a device run] "
+DEVICE_WORDS = [
+    m["name"] for m in BENCH["per_layer"] if m["source"] == "device_trace"
+] + ["busy_s", "window_s", "breakdown"]
+
+
+def run(*flags, cwd=REPO, timeout=600):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    return subprocess.run(
+        [sys.executable, *BENCH["command"][1:], *flags], env=env,
+        capture_output=True, text=True, timeout=timeout, cwd=cwd,
+    )
+
+
+def result_of(proc):
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-3000:]
+    lines = proc.stdout.splitlines()
+    assert lines and all(ln.startswith(TAG) for ln in lines)
+    for word in DEVICE_WORDS:
+        assert word not in proc.stdout, word
+    result = json.loads(lines[-1][len(TAG):])
+    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert result["device"]["platform"] == "cpu"
+    assert set(result["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    for m in result["metrics"].values():
+        assert set(m) == {"value", "unit"} and isinstance(m["value"], float)
+    return result
+
+
+@pytest.mark.parametrize("cell,trace,expect", [
+    ("tiny.generate-jobs", 0, {"out_tokens_per_s_per_chip", "setup_s"}),
+    ("tiny.generate-jobs", 1, {"tokens_per_dispatch", "engine_host_us_per_row"}),
+    ("tiny.classify-jobs", 1, {"tokens_per_dispatch"}),
+    ("tiny.chat-over-jobs", 0, {"out_tokens_per_s_per_chip", "ttft_p95_ms",
+                                "tpot_p95_ms", "setup_s"}),
+])
+def test_rehearsal_of_a_cell(cell, trace, expect):
+    result = result_of(run(
+        "--workload", cell, "--seed", str(2**31 + 5), "--seconds", "10",
+        "--trace", str(trace), "--cpu-rehearsal",
+    ))
+    assert result["correct"] is True
+    # a chat that gets no token by the drain's end on a busy CPU is a
+    # failed operation by design, never a wrong output; jobs never fail
+    late_chats_allowed = 2 if "chat" in cell else 0
+    assert result["failed"] <= late_chats_allowed
+    assert result["attempted"] > 0
+    assert expect <= set(result["metrics"])
+    assert all(result["metrics"][name]["value"] > 0 for name in expect)
+
+
+def test_a_refused_chat_lands_in_failed_not_in_correct():
+    result = result_of(run(
+        "--workload", "tiny.refused-over-jobs", "--seed", "3", "--seconds", "10",
+        "--trace", "0", "--cpu-rehearsal",
+    ))
+    assert result["correct"] is True
+    assert 0 < result["failed"] < result["attempted"]
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_a_real_cell_refuses_to_run_without_its_chips(cell):
+    proc = run("--workload", cell, "--seed", "1", "--seconds", "1", "--trace", "0",
+               timeout=300)
+    assert proc.returncode != 0
+    assert "only runs on the chip" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
+def test_alone_with_only_the_benchmark_files_it_refuses(tmp_path):
+    shutil.copy(REPO / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    for p in BENCH["paths"]:
+        shutil.copytree(REPO / p, tmp_path / p,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    proc = run("--workload", BENCH["workloads"][0]["name"], "--seed", "1",
+               "--seconds", "1", "--trace", "0", cwd=tmp_path, timeout=300)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+
+
+def test_an_unknown_cell_is_an_error():
+    proc = run("--workload", "no-such-cell", "--seed", "1", "--seconds", "1",
+               "--trace", "0", timeout=120)
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
