@@ -480,6 +480,8 @@ _TEL_OPS = (
     ("registry", "Gauge", "set", "gauge_set"),
     ("registry", "Histogram", "observe", "hist_observe"),
     ("spans", "FlightRecorder", "record", "recorder_record"),
+    # what the scheduler's cursor has just opened (a dict store)
+    ("spans", "FlightRecorder", "mark_open", "recorder_mark_open"),
     ("spans", "JobCounters", "add", "jobctr_add"),
     ("spans", "JobCounters", "set", "jobctr_set"),
     ("distributed", "WorkerTelemetry", "begin", "tele_begin"),
@@ -491,6 +493,11 @@ _TEL_OPS = (
     ("traces", "TraceStore", "start_trace", "trace_start"),
     ("traces", "Trace", "add", "trace_add"),
     ("traces", "Trace", "end", "trace_end"),
+    # the scheduler's phase cursor (engine/profiling.py): a transition
+    # reads the wall and the thread-CPU clock once; ``_annotate`` is
+    # the one place a jax.profiler.TraceAnnotation is constructed
+    ("profiling", "StepTimer", "_switch", "cursor_switch"),
+    ("profiling", "StepTimer", "_annotate", "trace_annotation"),
 )
 
 # Histogram.observe splits by exemplar: capturing the (value,
@@ -504,7 +511,9 @@ class _Census:
     exit. Counts land in the shared ``counts`` dict."""
 
     def __init__(self, mods, counts):
-        self.mods = mods
+        import sutro_tpu.engine.profiling as eng_profiling
+
+        self.mods = dict(mods, profiling=eng_profiling)
         self.counts = counts
         self._restore = []
 
@@ -648,6 +657,7 @@ def run_telemetry_compare(assert_budget: bool) -> dict:
     import time as _time
 
     import sutro_tpu.engine.api as api_mod
+    import sutro_tpu.engine.profiling as eng_profiling
     import sutro_tpu.telemetry as tel
     import sutro_tpu.telemetry.distributed as tel_distributed
     import sutro_tpu.telemetry.registry as tel_registry
@@ -693,6 +703,9 @@ def run_telemetry_compare(assert_budget: bool) -> dict:
                 "decode_window", None, 0.0, 0.003, {"jobs": ("a", "b")}
             )
         ),
+        "recorder_mark_open": _unit_us(
+            lambda: srec.mark_open("batch_build", 1.0, {"jobs": ("a",)})
+        ),
         "jobctr_add": _unit_us(lambda: sjc.add("rows_ok")),
         "jobctr_set": _unit_us(lambda: sjc.set("input_tokens", 123.0)),
         # exemplar capture: same entry point, plus the keep-policy
@@ -704,6 +717,14 @@ def run_telemetry_compare(assert_budget: bool) -> dict:
         ),
         "monotonic": _unit_us(_time.monotonic),
     }
+    unit_us["cursor_switch"] = unit_us["monotonic"] + _unit_us(
+        _time.thread_time
+    )
+    # one phase transition's annotation: constructed, entered, exited
+    stimer = eng_profiling.StepTimer(cursor=True)
+    unit_us["trace_annotation"] = _unit_us(
+        lambda: stimer._annotate("batch_build").__exit__(None, None, None)
+    )
     # forensics trace ops on a scratch store: start prices the create
     # path (fresh ids, ring eviction included); add round-robins over
     # enough traces that none hits the per-trace span cap (the capped
@@ -828,7 +849,8 @@ def run_telemetry_compare(assert_budget: bool) -> dict:
     }
     # span sites read the clock around the timed region: ~2 monotonic
     # reads per recorded span, 1 per bare histogram observe (with or
-    # without exemplar), 1 per trace span append
+    # without exemplar), 1 per trace span append (the scheduler's
+    # cursor reads its clocks once a transition: ``cursor_switch``)
     ops_us = sum(on_counts[k] * unit_us[k] for k in on_counts)
     ops_us += (
         2 * on_counts["recorder_record"]

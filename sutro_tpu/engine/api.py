@@ -239,10 +239,20 @@ class LocalEngine:
                 from .constrain import schema_constraint_factory
                 from .constrain.fsm import constraint_room
 
+                t_probe = time.monotonic()
                 probe = schema_constraint_factory(
                     payload["output_schema"],
                     self._get_tokenizer(engine_key, mcfg),
                 )()
+                if telemetry.ENABLED:
+                    # a whole schema index over the vocabulary, built on
+                    # the SUBMITTING thread: seconds at 151,936 ids
+                    dt = time.monotonic() - t_probe
+                    telemetry.stage_observe("constraint_prep", dt)
+                    telemetry.RECORDER.record(
+                        "constraint_prep", None, t_probe, dt,
+                        {"thread": "submit", "scope": "job"},
+                    )
                 # same room rule the scheduler's truncation reserve uses
                 room = constraint_room(probe)
                 if int(sampling["max_new_tokens"]) < room:
@@ -1428,7 +1438,8 @@ class LocalEngine:
                 _key2, mcfg2, meta2 = resolve_model(rec2.model)
                 tok2 = self._get_tokenizer(_key2, mcfg2)
                 s2 = _GenSession(
-                    self, jid, rec2, _key2, mcfg2, meta2, tok2, seq=seq
+                    self, jid, rec2, _key2, mcfg2, meta2, tok2, seq=seq,
+                    on_loop_thread=False,
                 )
                 self.jobs.set_status(jid, JobStatus.RUNNING)
                 build["session"] = s2
@@ -2089,7 +2100,7 @@ class _GenSession:
 
     def __init__(
         self, eng: "LocalEngine", job_id: str, rec, engine_key: str,
-        mcfg, meta, tok, seq: int = 0,
+        mcfg, meta, tok, seq: int = 0, on_loop_thread: bool = True,
     ):
         from .scheduler import JobCtx
 
@@ -2184,10 +2195,34 @@ class _GenSession:
         constraint_factory = None
         if rec.output_schema:
             from .constrain import schema_constraint_factory
+            from .profiling import host_leaf
 
-            constraint_factory = schema_constraint_factory(
-                rec.output_schema, tok
+            # the job's schema index over the whole vocabulary: seconds
+            # of pure Python at 151,936 ids, before the job's first row
+            # is admitted. A session's FIRST job builds it on the thread
+            # that then runs the scheduler (``constraint_compile``: the
+            # device waits); an attached job builds it on the attach
+            # thread WHILE the loop runs (``constraint_prep``: it holds
+            # the GIL against the scheduler, whose phases then show
+            # wall far over ``cpu_s``)
+            stage = (
+                "constraint_compile" if on_loop_thread
+                else "constraint_prep"
             )
+            with host_leaf(stage):
+                t_fac = time.monotonic()
+                constraint_factory = schema_constraint_factory(
+                    rec.output_schema, tok
+                )
+                if self._tel_on:
+                    dt = time.monotonic() - t_fac
+                    attrs = {"scope": "job", "rows": len(inputs)}
+                    if not on_loop_thread:
+                        attrs["thread"] = "attach"
+                    telemetry.stage_observe(stage, dt)
+                    telemetry.RECORDER.record(
+                        stage, job_id, t_fac, dt, attrs
+                    )
             # (the schema-feasibility cap raise happens at submit time
             # so quota and dry-run cost account for the effective cap)
 

@@ -47,6 +47,7 @@ from ..interfaces import JobStatus
 from ..validation import config_dir
 from . import faults
 from .faults import retry_transient
+from .profiling import host_leaf
 
 # ---------------------------------------------------------------------------
 # Cost model (USD per 1M tokens). The reference surfaces only a server-side
@@ -446,27 +447,33 @@ class JobStore:
         half-landed attempt is harmless."""
         if not rows:
             return
-        t0 = time.monotonic()
-        retry_transient(
-            lambda: self._flush_partial_once(job_id, rows),
-            attempts=self.io_retries,
-            base=self.io_backoff,
-            cap=self.io_backoff_cap,
-            retry_on=(OSError,),
-            on_retry=lambda attempt, delay, exc: self.append_failure_log(
-                job_id,
-                {"event": "io_retry", "site": "jobstore.flush_partial",
-                 "attempt": attempt,
-                 "error": f"{type(exc).__name__}: {exc}"},
-            ),
-            what=f"flush_partial[{job_id}]",
-        )
-        if telemetry.ENABLED:
-            dt = time.monotonic() - t0
-            telemetry.stage_observe("flush", dt)
-            telemetry.RECORDER.record(
-                "flush", job_id, t0, dt, {"rows": len(rows)}
+        # under the scheduler's ``emit`` phase (on_result runs on its
+        # thread) the flush is a leaf of its own, recorded here
+        with host_leaf("flush"):
+            t0 = time.monotonic()
+            retry_transient(
+                lambda: self._flush_partial_once(job_id, rows),
+                attempts=self.io_retries,
+                base=self.io_backoff,
+                cap=self.io_backoff_cap,
+                retry_on=(OSError,),
+                on_retry=lambda attempt, delay, exc: (
+                    self.append_failure_log(
+                        job_id,
+                        {"event": "io_retry",
+                         "site": "jobstore.flush_partial",
+                         "attempt": attempt,
+                         "error": f"{type(exc).__name__}: {exc}"},
+                    )
+                ),
+                what=f"flush_partial[{job_id}]",
             )
+            if telemetry.ENABLED:
+                dt = time.monotonic() - t0
+                telemetry.stage_observe("flush", dt)
+                telemetry.RECORDER.record(
+                    "flush", job_id, t0, dt, {"rows": len(rows)}
+                )
 
     def _flush_partial_once(
         self, job_id: str, rows: List[Dict[str, Any]]
@@ -653,29 +660,32 @@ class JobStore:
         (bounded, backed off), so ``on_chunk`` observers must reset
         when they see the bucket starting at row 0 again.
         """
-        t0 = time.monotonic()
-        retry_transient(
-            lambda: self._write_results_streamed_once(
-                job_id, num_rows, on_chunk
-            ),
-            attempts=self.io_retries,
-            base=self.io_backoff,
-            cap=self.io_backoff_cap,
-            retry_on=(OSError,),
-            on_retry=lambda attempt, delay, exc: self.append_failure_log(
-                job_id,
-                {"event": "io_retry", "site": "jobstore.finalize",
-                 "attempt": attempt,
-                 "error": f"{type(exc).__name__}: {exc}"},
-            ),
-            what=f"finalize[{job_id}]",
-        )
-        if telemetry.ENABLED:
-            dt = time.monotonic() - t0
-            telemetry.stage_observe("finalize", dt)
-            telemetry.RECORDER.record(
-                "finalize", job_id, t0, dt, {"rows": num_rows}
+        with host_leaf("finalize"):
+            t0 = time.monotonic()
+            retry_transient(
+                lambda: self._write_results_streamed_once(
+                    job_id, num_rows, on_chunk
+                ),
+                attempts=self.io_retries,
+                base=self.io_backoff,
+                cap=self.io_backoff_cap,
+                retry_on=(OSError,),
+                on_retry=lambda attempt, delay, exc: (
+                    self.append_failure_log(
+                        job_id,
+                        {"event": "io_retry", "site": "jobstore.finalize",
+                         "attempt": attempt,
+                         "error": f"{type(exc).__name__}: {exc}"},
+                    )
+                ),
+                what=f"finalize[{job_id}]",
             )
+            if telemetry.ENABLED:
+                dt = time.monotonic() - t0
+                telemetry.stage_observe("finalize", dt)
+                telemetry.RECORDER.record(
+                    "finalize", job_id, t0, dt, {"rows": num_rows}
+                )
 
     def _write_results_streamed_once(
         self,

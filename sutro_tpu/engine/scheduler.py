@@ -51,6 +51,14 @@ _TEL_STAGE = {
     "decode": "decode_window",
     "admit_sample": "admit",
 }
+# the scheduler's own host phases (the timer's cursor; OBSERVABILITY.md
+# "Scheduler phases"): histogram + flight recorder only — no exemplar
+# and no fan-out into per-request traces, which nobody would read
+_QUIET_STAGES = frozenset((
+    "sched_poll", "job_start", "admit_host", "constraint_compile",
+    "fsm_mask", "fsm_plan", "batch_build", "emit", "sched_idle",
+    "sched_other",
+))
 
 
 @jax.jit
@@ -580,27 +588,54 @@ class ContinuousBatcher:
         # the decode/prefill loops stash {stage: {batch, steps, ...}}
         # here right before dispatch; the sink folds it into the span
         self._tel_attrs: Dict[str, Dict[str, Any]] = {}
+        # with telemetry on the timer is also the scheduler's phase
+        # cursor: every instant of run_multi belongs to one named phase
         self.timer = StepTimer(
-            sink=self._tel_sink if self._tel_on else None
+            sink=self._tel_sink if self._tel_on else None,
+            cursor=self._tel_on,
+            stage_names=_TEL_STAGE,
+            opened=self._tel_opened if self._tel_on else None,
+        )
+        # tokens committed by the accept loops (the accept span's attr)
+        self._n_accepted = 0
+
+    def _tel_opened(self, phase: Optional[str], t0: float) -> None:
+        """The phase this thread is in NOW, for whoever snapshots the
+        flight recorder before it ends (a plan walk can run for
+        seconds)."""
+        telemetry.RECORDER.mark_open(
+            phase and _TEL_STAGE.get(phase, phase), t0,
+            {"jobs": self._tel_jobs} if self._tel_jobs else None,
         )
 
-    def _tel_sink(self, phase: str, t0: float, dt: float) -> None:
+    def _tel_sink(
+        self, phase: str, t0: float, dt: float, cpu_s: float,
+        attrs: Optional[Dict[str, Any]],
+    ) -> None:
         stage = _TEL_STAGE.get(phase, phase)
+        quiet = stage in _QUIET_STAGES
         # stage exemplar: point the aggregate histogram at one live
         # request's trace so a slow-bucket sample is resolvable
         telemetry.stage_observe(
             stage, dt,
-            exemplar=self._tel_traces[0] if self._tel_traces else None,
+            exemplar=(
+                self._tel_traces[0]
+                if self._tel_traces and not quiet else None
+            ),
         )
         extra = self._tel_attrs.get(stage)
-        attrs = None
-        if self._tel_jobs or extra:
-            attrs = dict(extra or ())
-            if self._tel_jobs:
-                attrs["jobs"] = self._tel_jobs
-        telemetry.RECORDER.record(stage, None, t0, dt, attrs)
-        for tid in self._tel_traces:
-            telemetry.TRACES.add(tid, stage, t0, dt, extra)
+        if attrs:
+            extra = {**extra, **attrs} if extra else attrs
+        rec = dict(extra) if extra else {}
+        # wall minus CPU in a pure-Python phase is time the scheduler
+        # thread waited for the GIL (prep thread, tokenizer, streams)
+        rec["cpu_s"] = round(cpu_s, 6)
+        if self._tel_jobs:
+            rec["jobs"] = self._tel_jobs
+        telemetry.RECORDER.record(stage, None, t0, dt, rec)
+        if not quiet:
+            for tid in self._tel_traces:
+                telemetry.TRACES.add(tid, stage, t0, dt, extra)
 
     # ------------------------------------------------------------------
 
@@ -1045,24 +1080,21 @@ class ContinuousBatcher:
             req.constraint = c
             req.prepped_constraint = None
             return
-        t0 = time.perf_counter()
-        req.constraint = req.constraint_factory()
-        dt = time.perf_counter() - t0
-        self.prep_inline_s += dt
-        if self._tel_on:
-            telemetry.stage_observe("constraint_compile", dt)
-            telemetry.RECORDER.record(
-                "constraint_compile", None, time.monotonic() - dt, dt,
-                {"jobs": self._tel_jobs, "row": req.row_id}
-                if self._tel_jobs else {"row": req.row_id},
-            )
+        t0 = time.monotonic()
+        try:
+            # scheduler-thread builds only: the prep thread's are
+            # ``constraint_prep``
+            with self.timer.host("constraint_compile", row=req.row_id):
+                req.constraint = req.constraint_factory()
+        finally:
+            self.prep_inline_s += time.monotonic() - t0
 
     def _prep_worker(self, q) -> None:
         while True:
             req = q.get()
             if req is None:
                 return
-            t0 = time.perf_counter()
+            t0 = time.monotonic()
             try:
                 if (
                     req.constraint is None
@@ -1079,13 +1111,19 @@ class ContinuousBatcher:
             except Exception:
                 logger.exception("admission prep failed; admission "
                                  "will rebuild inline")
-            dt = time.perf_counter() - t0
+            dt = time.monotonic() - t0
             with self._prep_lock:
                 self.prep_overlap_s += dt
             if self._tel_on:
                 # overlapped builds hide behind device windows but are
-                # still real work on the timeline
-                telemetry.stage_observe("constraint_compile", dt)
+                # still real work on the timeline — and they hold the
+                # GIL against the scheduler thread, whose phases show
+                # it as wall over cpu_s
+                telemetry.stage_observe("constraint_prep", dt)
+                telemetry.RECORDER.record(
+                    "constraint_prep", None, t0, dt,
+                    {"row": req.row_id, "thread": "prep"},
+                )
 
     def _prep_pump(self, order: List["JobCtx"]) -> None:
         """Queue the NEXT admission group's lazy constraints for the
@@ -1155,6 +1193,7 @@ class ContinuousBatcher:
             b[1].prefix.tokens if b[1].prefix is not None else 0
             for b in batch
         ]
+        self.timer.count("rows", len(batch))
         try:
             if self._tel_on:
                 self._tel_attrs["prefill"] = {
@@ -1389,12 +1428,16 @@ class ContinuousBatcher:
         FF = getattr(self.ecfg, "constrain_fastforward", 0)
         if FF <= 0 or self._step < self._ff_probe_step:
             return False
+        tm = self.timer
+        # the plan walk, failed probes included, up to the dispatch
+        tm.enter("fsm_plan", rows=len(active), planned=0, engaged=False)
         PS = self.ecfg.kv_page_size
         MAXC = 32
         flagged = self._needs_mask & set(active)
         plans = {}
         total = 0
         for i in active:
+            tm.tick()
             s = self.slots[i]
             c = s.req.constraint
             plan_fn = getattr(c, "plan_fastforward", None)
@@ -1416,9 +1459,11 @@ class ContinuousBatcher:
                 continue
             plans[i] = p
             total += len(p[1])
+        tm.note(planned=len(plans))
         if total < 2 * len(active):
             self._ff_fail_backoff()
             return False
+        tm.note(engaged=True)
         # a flagged row WITH a plan takes its masked step as the plan's
         # first position
         self._needs_mask -= set(plans)
@@ -1461,6 +1506,8 @@ class ContinuousBatcher:
                 cand, cand_n, np.asarray(past_len, np.int32), table,
             )
         self._step += 1
+        tm.enter("accept")
+        n0 = self._n_accepted
         for i in active:
             s = self.slots[i]
             ctx = s.job
@@ -1506,6 +1553,7 @@ class ContinuousBatcher:
                     self._needs_mask.add(i)
                     continue
             self._accept_token(i, tok, float(pl[i, 0]))
+        tm.enter("accept", tokens=self._n_accepted - n0)
         return True
 
     def _ff_fail_backoff(self) -> None:
@@ -1664,9 +1712,13 @@ class ContinuousBatcher:
         falls back to fused windows — only when fewer than half the
         active rows draft: the verify dispatch is host-synchronous, so
         at low draft coverage the RTT-hiding pipelined windows win."""
+        tm = self.timer
+        tm.enter("fsm_plan", rows=len(active), planned=0, engaged=False)
         dmap = self._spec_drafts(active)
+        tm.note(planned=len(dmap))
         if not self._spec_enough(len(dmap), active):
             return False
+        tm.note(engaged=True)
         SN = self.ecfg.spec_ngram_draft
         drafts = np.zeros((self.B, SN), np.int32)
         dlens = np.zeros((self.B,), np.int32)
@@ -1680,10 +1732,13 @@ class ContinuousBatcher:
                 np.asarray(past_len, np.int32), table,
             )
         self._step += 1
+        tm.enter("accept")
+        n0 = self._n_accepted
         for i in active:
             self._spec_accept_row(
                 i, int(dlens[i]), drafts[i], toks_v[i], logp_v[i]
             )
+        tm.enter("accept", tokens=self._n_accepted - n0)
         # acceptance-based exit (coverage got us here; acceptance keeps
         # us here): once the rolling window has seen enough drafts,
         # leave the host-synchronous spec path unless it beats a plain
@@ -1723,22 +1778,26 @@ class ContinuousBatcher:
         unconstrained slots). Single assembly path for BOTH the masked
         single-step and the speculative window's allowed0 recovery, so
         the two cannot drift."""
-        allowed = np.ones((self.B, self.vocab), bool)
-        for i in list(rows):
-            s = self.slots[i]
-            if s is None:
-                continue  # failed earlier in this assembly pass
-            c = s.req.constraint
-            if c is not None:
-                rem = self._remaining(s.req, len(s.out_ids), s.pos)
-                try:
-                    allowed[i] = self._constraint_mask(c, rem)
-                except Exception as e:  # noqa: BLE001 — row isolation
-                    # one row's broken FSM must not take the batch down:
-                    # release it into the retry/quarantine path; its
-                    # all-True mask row samples a token that the (slot,
-                    # gen) / None-slot checks then discard
-                    self._fail_slot(i, e)
+        rows = list(rows)
+        with self.timer.host("fsm_mask", rows=len(rows)):
+            allowed = np.ones((self.B, self.vocab), bool)
+            for i in rows:
+                self.timer.tick()
+                s = self.slots[i]
+                if s is None:
+                    continue  # failed earlier in this assembly pass
+                c = s.req.constraint
+                if c is not None:
+                    rem = self._remaining(s.req, len(s.out_ids), s.pos)
+                    try:
+                        allowed[i] = self._constraint_mask(c, rem)
+                    except Exception as e:  # noqa: BLE001 — row isolation
+                        # one row's broken FSM must not take the batch
+                        # down: release it into the retry/quarantine
+                        # path; its all-True mask row samples a token
+                        # that the (slot, gen) / None-slot checks then
+                        # discard
+                        self._fail_slot(i, e)
         return allowed
 
     def _remaining(self, req: GenRequest, emitted: int, pos: int) -> int:
@@ -1765,11 +1824,15 @@ class ContinuousBatcher:
         top_k = np.array([r.top_k for r in reqs], np.int32)
         allowed = None
         if any(r.constraint is not None for r in reqs):
-            allowed = np.ones((n, self.vocab), bool)
-            for i, r in enumerate(reqs):
-                if r.constraint is not None:
-                    rem = self._remaining(r, 0, len(r.prompt_ids))
-                    allowed[i] = self._constraint_mask(r.constraint, rem)
+            with self.timer.host("fsm_mask", rows=n):
+                allowed = np.ones((n, self.vocab), bool)
+                for i, r in enumerate(reqs):
+                    self.timer.tick()
+                    if r.constraint is not None:
+                        rem = self._remaining(r, 0, len(r.prompt_ids))
+                        allowed[i] = self._constraint_mask(
+                            r.constraint, rem
+                        )
         row_seeds = None
         if any(r.row_seed is not None for r in reqs):
             sub = self._fixed_key  # per-row keys derive from row_seed
@@ -1987,6 +2050,7 @@ class ContinuousBatcher:
             self._fail_slot(i, e)
             return 2
         s.last_token = tok
+        self._n_accepted += 1
         if s.job is not None:
             s.job.stats["out"] += 1
         self._deliver_token(s, tok, float(logp))
@@ -2004,12 +2068,18 @@ class ContinuousBatcher:
     def _emit(self, i: int, reason: Optional[str] = None) -> None:
         """Release slot ``i`` and stream its result through its job."""
         ctx = self.slots[i].job
-        res = self._release(i)
-        if reason is not None:
-            res.finish_reason = reason
-        if ctx is not None:
-            ctx.stats["rows"] += 1
-            ctx.on_result(res)
+        tm = self.timer
+        # the engine's result handling and store appends run right here,
+        # on the scheduler thread
+        # (a run of rows finishing in one accept loop is one span)
+        with tm.host("emit", merge=True):
+            tm.count("rows")
+            res = self._release(i)
+            if reason is not None:
+                res.finish_reason = reason
+            if ctx is not None:
+                ctx.stats["rows"] += 1
+                ctx.on_result(res)
 
     def _token_ok(
         self, c: TokenConstraint, tok: int, remaining: int
@@ -2250,7 +2320,8 @@ class ContinuousBatcher:
         with self.timer.time("decode"):
             toks = np.asarray(toks_dev)
             logps = np.asarray(logps_dev)
-        t_acc = time.monotonic() if self._tel_on else 0.0
+        self.timer.enter("accept")
+        n0 = self._n_accepted
         plain: List[int] = []
         rest: List[int] = []
         for idx, i in enumerate(w_active):
@@ -2276,24 +2347,7 @@ class ContinuousBatcher:
                 self._accept_token(
                     i, int(toks[j][i]), float(logps[j][i])
                 )
-        if self._tel_on:
-            self._tel_accept(t_acc)
-
-    def _tel_accept(self, t0: float) -> None:
-        """Record the host-side token-acceptance leg of one window as
-        an ``accept`` span (the decode span covers only the device
-        dispatch/fetch)."""
-        dt = time.monotonic() - t0
-        telemetry.stage_observe(
-            "accept", dt,
-            exemplar=self._tel_traces[0] if self._tel_traces else None,
-        )
-        telemetry.RECORDER.record(
-            "accept", None, t0, dt,
-            {"jobs": self._tel_jobs} if self._tel_jobs else None,
-        )
-        for tid in self._tel_traces:
-            telemetry.TRACES.add(tid, "accept", t0, dt)
+        self.timer.enter("accept", tokens=self._n_accepted - n0)
 
     def _trace_resume(self, ctx: JobCtx, req: GenRequest) -> None:
         """Close a preempt_suspend pair: the row a preemption suspended
@@ -2359,6 +2413,7 @@ class ContinuousBatcher:
             s.out_ids.extend(col_t.tolist())  # C-speed, yields ints
             s.logprob_sum += float(lw[:n_take, col].sum())
             s.pos += n_take
+            self._n_accepted += n_take
             s.last_token = int(col_t[-1])
             if self.native is not None:
                 self.native.note_bulk(i, s.last_token, n_take)
@@ -2431,6 +2486,7 @@ class ContinuousBatcher:
         """Prepare a job for the session: truncation policy pass, the
         shortest-first admission order, and the job's shared-prefix
         prefill."""
+        self.timer.wake("job_start")
         pending = []
         # lazy-constraint jobs share one factory: probe its room ONCE
         # per job instead of instantiating an FSM per row here
@@ -2555,6 +2611,7 @@ class ContinuousBatcher:
         ``emit_cancel`` the job's live slots are released as
         ``cancelled`` results and its pending rows dropped (the
         jobstore layer records never-run rows)."""
+        self.timer.wake("emit")
         if emit_cancel:
             for i, s in enumerate(self.slots):
                 if s is not None and s.job is ctx:
@@ -2590,6 +2647,20 @@ class ContinuousBatcher:
             # hibernation entry must not shadow those fresh requests
             self._purge_hibernated(ctx)
         ctx.prefix_ready = False  # a resumed ctx re-detects its prefix
+
+    def _after_step(
+        self, live: List[JobCtx], on_job_done, path: str, n_active: int
+    ) -> None:
+        """The tail every decode path shares: count the iteration by
+        the path it took, finish drained jobs, tick progress streams."""
+        if self._tel_on:
+            telemetry.SCHED_ITERATIONS_TOTAL.inc(1.0, path)
+            telemetry.SCHED_DISPATCH_ROWS_TOTAL.inc(float(n_active))
+        self.timer.enter("emit")
+        self._sweep_done(live, on_job_done)
+        for ctx in live:
+            if not ctx.done:
+                self._job_progress(ctx)
 
     def _sweep_done(self, live: List[JobCtx], on_job_done) -> None:
         for ctx in live:
@@ -3003,7 +3074,9 @@ class ContinuousBatcher:
                     # shared-prefix KV: prefill this job's common prefix
                     # once, right when its rows first stand a chance of
                     # admission
+                    self.timer.enter("job_start")
                     self._setup_prefix(ctx)
+                    self.timer.enter("admit_host")
                     ctx.prefix_ready = True
                 req = ctx.pending[-1]
                 shared = ctx.prefix.tokens if ctx.prefix else 0
@@ -3140,6 +3213,11 @@ class ContinuousBatcher:
         # first spec probe
         self._spec_cov_key = -1
         live: List[JobCtx] = []
+        # the phase cursor (engine/profiling.py): from here to the
+        # finally below every instant of this thread belongs to exactly
+        # one named phase; each ``enter`` closes the one before it
+        tm = self.timer
+        tm.begin()
         try:
             for ctx in jobs:
                 self._start_job(ctx)
@@ -3148,6 +3226,7 @@ class ContinuousBatcher:
             # entries are (toks_dev, logps_dev, active, gens, K)
             pipe: List[Any] = []
             while True:
+                tm.enter("sched_poll")
                 if poll_new is not None:
                     while True:
                         nctx = poll_new()
@@ -3155,6 +3234,7 @@ class ContinuousBatcher:
                             break
                         self._start_job(nctx)
                         live.append(nctx)
+                        tm.enter("sched_poll")
                 for ctx in live:
                     if (
                         not ctx.done
@@ -3194,6 +3274,10 @@ class ContinuousBatcher:
                 order = sorted(
                     ajobs, key=lambda c: (c.priority, c.seq)
                 )
+                if tm.dozing and any(c.pending for c in order):
+                    # a feeder handed a held-open ctx rows (poll_new)
+                    tm.wake("admit_host")
+                tm.enter("admit_host")
                 admitted = self._admit_pending(order)
                 # double-buffered admission: hand the NEXT group's lazy
                 # constraint builds to the prep thread now — they
@@ -3203,6 +3287,7 @@ class ContinuousBatcher:
                 # admits advance while the decode batch below keeps its
                 # cadence (bounded degradation, never a pause)
                 self._prefill_tick()
+                tm.enter("emit")
                 # Immediately-finished rows (e.g. first token was stop).
                 for i, s in enumerate(self.slots):
                     if (
@@ -3262,14 +3347,20 @@ class ContinuousBatcher:
                     for ctx in live:
                         if not ctx.done:
                             self._job_progress(ctx)
+                    if self._tel_on:
+                        telemetry.SCHED_ITERATIONS_TOTAL.inc(1.0, "idle")
                     if not admitted and not any(
                         s is not None for s in self.slots
                     ) and all(not c.pending for c in live if not c.done):
                         # Only held-open stage-graph ctxs remain and no
                         # feeder can run on THIS thread until poll_new /
-                        # cancel checks fire — doze instead of spinning.
+                        # cancel checks fire — doze instead of spinning:
+                        # ONE sched_idle span, however many spins
+                        tm.doze()
                         time.sleep(0.0005)
                     continue
+                n_active = len(active)
+                tm.enter("batch_build", active=n_active)
                 if self.native is not None:
                     # dense arrays live in the C++ core, always current
                     nat = self.native
@@ -3356,9 +3447,11 @@ class ContinuousBatcher:
                     # _spec_ngram_step recomputes real ones at engage)
                     if self._spec_cov_key != self._spec_probe_step:
                         self._spec_cov_key = self._spec_probe_step
+                        tm.enter("fsm_plan", rows=len(active))
                         self._spec_cov_ok = self._spec_coverage_ok(
                             active
                         )
+                        tm.enter("batch_build")
                     if not self._spec_cov_ok:
                         self._spec_fail_backoff()
                         spec_probe = False
@@ -3366,13 +3459,13 @@ class ContinuousBatcher:
                     if self._spec_ngram_step(
                         active, last, past_len, table
                     ):
-                        self._sweep_done(live, on_job_done)
-                        for ctx in live:
-                            if not ctx.done:
-                                self._job_progress(ctx)
+                        self._after_step(
+                            live, on_job_done, "spec", n_active
+                        )
                         continue
                     self._spec_fail_backoff()
                     spec_probe = False
+                    tm.enter("batch_build")
 
                 # Pipelined fused windows: when no row needs host work
                 # between steps, window k+1 is dispatched chained off
@@ -3423,10 +3516,9 @@ class ContinuousBatcher:
                         # — windows drain one per iteration, then other
                         # paths resume
                         self._process_pipelined(pipe.pop(0))
-                        self._sweep_done(live, on_job_done)
-                        for ctx in live:
-                            if not ctx.done:
-                                self._job_progress(ctx)
+                        self._after_step(
+                            live, on_job_done, "pipelined", n_active
+                        )
                         continue
                     # pipe empty and nothing dispatchable (capacity
                     # below one window): fall through to single-step
@@ -3512,11 +3604,12 @@ class ContinuousBatcher:
                             active, last, past_len, table
                         )
                     ):
-                        self._sweep_done(live, on_job_done)
-                        for ctx in live:
-                            if not ctx.done:
-                                self._job_progress(ctx)
+                        self._after_step(
+                            live, on_job_done, "fastforward", n_active
+                        )
                         continue
+                    # a failed probe stays ``fsm_plan`` up to here
+                    tm.enter("batch_build")
                     # speculative window: sample unmasked, verify
                     # host-side, commit only each row's FSM-valid
                     # prefix. Rows whose previous window rejected take
@@ -3537,7 +3630,9 @@ class ContinuousBatcher:
                             )
                         )
                     self._step += K
-                    t_acc = time.monotonic() if self._tel_on else 0.0
+                    path = "window"
+                    tm.enter("accept")
+                    n0 = self._n_accepted
                     accepted = np.zeros((self.B,), np.int32)
                     finished: List[int] = []
                     for i in active:
@@ -3584,12 +3679,12 @@ class ContinuousBatcher:
                             if rc:
                                 finished.append(i)
                                 break
-                    if self._tel_on:
-                        self._tel_accept(t_acc)
+                    tm.enter("accept", tokens=self._n_accepted - n0)
                     # pages are still reserved for every row (releases
                     # were deferred), so the accepted K/V lands safely
                     with self.timer.time("decode"):
                         self.runner.commit_window(handle, accepted)
+                    tm.enter("emit")
                     for i in finished:
                         self._emit(i)
                 elif K > 1:
@@ -3599,6 +3694,9 @@ class ContinuousBatcher:
                             top_k=top_k, pfx=self._split_pfx(active),
                         )
                     self._step += K
+                    path = "multi"
+                    tm.enter("accept")
+                    n0 = self._n_accepted
                     for j in range(K):
                         for i in active:
                             if self.slots[i] is None:
@@ -3613,7 +3711,9 @@ class ContinuousBatcher:
                         ]
                         if not active:
                             break
+                    tm.enter("accept", tokens=self._n_accepted - n0)
                 else:
+                    path = "single"
                     allowed = None
                     if has_constraint:
                         # masked step: per-row FSM vocab masks (fused
@@ -3682,18 +3782,19 @@ class ContinuousBatcher:
                     # masked single-step crossed every flagged row's
                     # rejected scaffold token
                     self._needs_mask.clear()
+                    tm.enter("accept")
+                    n0 = self._n_accepted
                     for i in active:
                         if self.slots[i] is None:
                             continue  # failed during mask assembly
                         self._accept_token(
                             i, int(toks[i]), float(logps[i])
                         )
-                self._sweep_done(live, on_job_done)
-                for ctx in live:
-                    if not ctx.done:
-                        self._job_progress(ctx)
+                    tm.enter("accept", tokens=self._n_accepted - n0)
+                self._after_step(live, on_job_done, path, n_active)
             return "completed"
         finally:
+            tm.wake("sched_other")
             # every exit path (completed / yielded / raise) returns any
             # live job's shared-prefix pages to the pool (_finish_job
             # and _suspend_job already None the refs they freed) and
@@ -3712,3 +3813,4 @@ class ContinuousBatcher:
                     [h.key for h in self._hibernated.values() if h.key]
                 )
                 self._hibernated.clear()
+            tm.end()

@@ -1,4 +1,4 @@
-"""Observability: optional LangSmith tracing + engine-level profiling.
+"""Observability: optional LangSmith tracing.
 
 Re-design of the reference's ``sutro/observability.py``
 (/root/reference/sutro/observability.py:1-304). Mechanism kept:
@@ -13,10 +13,8 @@ Re-design of the reference's ``sutro/observability.py``
 - all trace failures reduce to warnings.
 
 Differences: ``langsmith`` is an optional dependency here (absent in this
-environment — every hook degrades to a no-op), and the TPU build adds what
-the reference lacks entirely (SURVEY §5.1): engine-side profiling via
-``jax.profiler`` trace capture plus per-chip token throughput, which feeds
-the ``tokens`` progress updates.
+environment — every hook degrades to a no-op). Device traces are
+``EngineConfig.profile_dir`` (engine/profiling.py ``job_trace``).
 
 The reference's hardcoded trace name bug ("clay-query-match-judge",
 sdk.py:566) is intentionally not reproduced.
@@ -24,7 +22,6 @@ sdk.py:566) is intentionally not reproduced.
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 import uuid
@@ -155,22 +152,3 @@ def _complete_batch_traces(
     except Exception as e:
         logger.warning("batch trace complete failed: %s", e)
 
-
-# ---------------------------------------------------------------------------
-# Engine-level profiling (TPU addition; SURVEY §5.1 "TPU build" note)
-# ---------------------------------------------------------------------------
-
-
-@contextlib.contextmanager
-def profile_trace(out_dir: Optional[str] = None):
-    """Capture a jax.profiler trace around a block when
-    ``SUTRO_PROFILE=1`` (view with TensorBoard/XProf)."""
-    if os.environ.get("SUTRO_PROFILE") != "1":
-        yield
-        return
-    import jax
-
-    out = out_dir or os.path.expanduser("~/.sutro/profiles")
-    os.makedirs(out, exist_ok=True)
-    with jax.profiler.trace(out):
-        yield

@@ -106,11 +106,11 @@ TRACES = TraceStore(
 
 STAGE_SECONDS = REGISTRY.histogram(
     "sutro_stage_seconds",
-    "Engine stage latency (tokenize, constraint_compile, prefill, "
-    "decode_window, admit, accept, flush, finalize, dp_round, embed)",
+    "Engine stage latency: every name of STAGES below (the engine's "
+    "stages and the scheduler thread's phases)",
     labels=("stage",),
     unit="seconds",
-    max_series=16,
+    max_series=32,
 )
 ROWS_TOTAL = REGISTRY.counter(
     "sutro_rows_total",
@@ -374,6 +374,22 @@ FLEET_ROUTE_SECONDS = REGISTRY.histogram(
     max_series=8,
 )
 
+# -- the scheduler's own accounting (engine/scheduler.py run_multi) ------
+SCHED_ITERATIONS_TOTAL = REGISTRY.counter(
+    "sutro_sched_iterations_total",
+    "Scheduler loop iterations by the decode path each took: pipelined "
+    "| window | fastforward | spec | multi | single, or idle when no "
+    "row was ready to decode",
+    labels=("path",),
+    max_series=8,
+)
+SCHED_DISPATCH_ROWS_TOTAL = REGISTRY.counter(
+    "sutro_sched_dispatch_rows_total",
+    "Active decode rows summed over the iterations whose path is not "
+    "idle (over iterations x decode_batch_size: batch occupancy)",
+    unit="rows",
+)
+
 # Span names the engine emits — OBSERVABILITY.md's span schema section
 # and tests key off this tuple, so additions land in one place.
 STAGES = (
@@ -392,6 +408,21 @@ STAGES = (
     # migration worker and surface as kv_demote queue time only)
     "kv_demote",
     "kv_promote",
+    # the scheduler thread's own phases (engine/profiling.py StepTimer's
+    # cursor): with the device-dispatch stages above they tile every
+    # instant of run_multi, so sums of their seconds double count nothing
+    "sched_poll",
+    "job_start",
+    "admit_host",
+    "fsm_mask",
+    "fsm_plan",
+    "batch_build",
+    "emit",
+    "sched_idle",
+    "sched_other",
+    # lazy constraint builds on the admission-prep thread: they overlap
+    # the phases above (constraint_compile is scheduler-thread builds)
+    "constraint_prep",
 )
 
 

@@ -26,9 +26,11 @@ tests/test_doctor.py):
                             others — the pod waits on that slice
   ``io_bound``              flush+finalize dominate both compute and
                             the rest of the host pipeline
-  ``host_bound_admit``      host-side admission work (tokenize,
-                            constraint compile, accept) exceeds device
-                            time — the chip starves behind the host
+  ``host_bound_admit``      host-side work (tokenize and the scheduler
+                            thread's phases: masks, plans, accept,
+                            emit, ...) exceeds device time — the chip
+                            starves behind the host; the evidence names
+                            the largest phase
   ``decode_below_roofline``  device-bound but the median decode window
                             runs under 40% of the HBM roofline
   ``healthy``               none of the above
@@ -53,9 +55,21 @@ DOCTOR_VERSION = 1
 
 #: stages whose duration is device dispatch/fetch (the chip working)
 DEVICE_STAGES = ("prefill", "decode_window", "admit", "embed")
+#: the scheduler thread's own phases that are host work
+#: (engine/profiling.py StepTimer's cursor): leaves of one timeline, so
+#: their sum double counts nothing. ``sched_idle`` is NOT host work and
+#: ``constraint_prep`` runs on another thread, under these.
+SCHED_HOST_STAGES = (
+    "sched_poll", "job_start", "admit_host", "constraint_compile",
+    "fsm_mask", "fsm_plan", "batch_build", "accept", "emit",
+    "sched_other",
+)
 #: host-side pipeline stages (the chip idle or overlapped)
-HOST_STAGES = ("tokenize", "constraint_compile", "accept", "flush",
-               "finalize", "kv_demote", "kv_promote")
+HOST_STAGES = ("tokenize", "flush", "finalize", "kv_demote",
+               "kv_promote") + SCHED_HOST_STAGES
+#: spans that overlap the stages above or are nobody's work: counted
+#: neither as host nor as device time
+UNCOUNTED_STAGES = ("sched_idle", "constraint_prep")
 #: I/O subset of the host stages (jobstore writes)
 IO_STAGES = ("flush", "finalize")
 #: round envelopes — excluded from attribution (they CONTAIN stages)
@@ -535,7 +549,7 @@ def diagnose(
     )
     host_s = round(sum(a["host_s"] for a in processes.values()), 6)
     io_s = round(sum(a["io_s"] for a in processes.values()), 6)
-    admit_s = round(host_s - io_s, 6)  # tokenize+constraint+accept
+    admit_s = round(host_s - io_s, 6)  # tokenize + scheduler phases
 
     if verdict is None and io_s > device_s and io_s > admit_s:
         verdict = "io_bound"
@@ -548,7 +562,7 @@ def diagnose(
         top = ""
         top_s = -1.0
         for a in processes.values():
-            for st in ("tokenize", "constraint_compile", "accept"):
+            for st in ("tokenize",) + SCHED_HOST_STAGES:
                 v = a["stages"].get(st, {}).get("total_s", 0.0)
                 if v > top_s:
                     top, top_s = st, v

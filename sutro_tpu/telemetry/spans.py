@@ -41,6 +41,12 @@ class FlightRecorder:
         self.dropped = 0  # ring evictions are implicit; this counts
         #                   records only when the ring was full
         self._full = False
+        # spans still RUNNING, one a thread: {thread id: (name, t0_mono,
+        # attrs)}. A phase lands in the ring when it ends; a reader who
+        # looks while a 9 s plan walk runs would see nothing under it.
+        # The scheduler's phase cursor marks what it opens here (a dict
+        # store a transition) and snapshots show it up to "now".
+        self._open: Dict[int, Any] = {}
 
     @property
     def capacity(self) -> int:
@@ -63,6 +69,19 @@ class FlightRecorder:
         self._buf.append(
             (name, job_id, t0_mono - self.epoch_mono, dur_s, attrs)
         )
+
+    def mark_open(
+        self, name: Optional[str], t0_mono: float = 0.0,
+        attrs: Optional[Dict[str, Any]] = None,
+    ) -> None:
+        """The calling thread is inside span ``name`` since ``t0_mono``
+        (None: inside none). Not a ring write: whoever closes the span
+        records it as usual."""
+        tid = threading.get_ident()
+        if name is None:
+            self._open.pop(tid, None)
+        else:
+            self._open[tid] = (name, t0_mono, attrs)
 
     class _SpanCtx:
         __slots__ = ("rec", "name", "job_id", "attrs", "t0")
@@ -112,9 +131,16 @@ class FlightRecorder:
         """Spans (oldest first) as dicts: name, job_id, t0_s (relative
         to the recorder epoch), dur_s, attrs. Filtered to one job when
         ``job_id`` is given (scheduler spans tagged with the job in
-        ``attrs['jobs']`` count)."""
+        ``attrs['jobs']`` count). A span still running on some thread
+        (``mark_open``) comes last, up to now, with ``attrs.open``."""
         out = []
-        for entry in list(self._buf):
+        now = time.monotonic()
+        running = [
+            (name, None, t0 - self.epoch_mono, now - t0,
+             dict(attrs or (), open=True))
+            for name, t0, attrs in list(self._open.values())
+        ]
+        for entry in list(self._buf) + running:
             if not self._matches(entry, job_id):
                 continue
             name, jid, t0, dur, attrs = entry
@@ -135,6 +161,7 @@ class FlightRecorder:
 
     def clear(self) -> None:
         self._buf.clear()
+        self._open.clear()
         self._full = False
         self.dropped = 0
         self.epoch_mono = time.monotonic()
