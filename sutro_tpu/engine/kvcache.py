@@ -20,8 +20,11 @@ scheduler ... paged-KV decode attention"). Layout:
 ``write_kv`` lands a chunk's K/V into pages (Pallas in-place RMW kernel
 on TPU, XLA scatter fallback elsewhere); ``gather_kv_layer`` produces one
 layer's contiguous ``[B, CTX, KVH, Dh]`` view for the non-Pallas
-attention fallback. Both are pure functions over pytrees, jitted as part
-of the runner's step functions.
+attention fallback by ONE gather on ``[layer, page_table]`` of the
+stacked pool. No reader is ever handed a per-layer ``[NP, PS, KD]``
+slice: the stack is a constant of the layer scan and the layer is an
+index (models/transformer.py). Both are pure functions over pytrees,
+jitted as part of the runner's step functions.
 """
 
 from __future__ import annotations
@@ -301,30 +304,35 @@ def write_kv(
 
 
 def gather_kv_layer(
-    k_pages_l: jax.Array,  # [NP, PS, KVH*Dh] — one layer's pages
-    v_pages_l: jax.Array,
+    k_pages: jax.Array,  # [L, NP, PS, KVH*Dh] — the stacked pool
+    v_pages: jax.Array,
+    layer: jax.Array,  # scalar int32 — the layer to read
     page_table: jax.Array,  # [B, MP] int32
     kv_heads: int,
-    k_scale_l: "jax.Array | None" = None,  # [NP, PS] (int8 KV mode)
-    v_scale_l: "jax.Array | None" = None,
+    k_scale: "jax.Array | None" = None,  # [L, NP, PS] (int8 KV mode)
+    v_scale: "jax.Array | None" = None,
     out_dtype=None,  # dequant target (compute dtype); None => float32
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-layer page gather: [B, MP] table -> ([B, CTX, KVH, Dh]) x2,
-    CTX = MP * PS. Used inside the layer scan so only one layer's context
-    view is ever live (the XLA fallback when the Pallas paged kernel does
-    not run — the kernel reads pages in place and skips this copy).
+    CTX = MP * PS, as ONE gather on ``[layer, page_table]`` of the
+    stack (never a slice of the layer's pool followed by a gather: the
+    slice would be a copy of it). Used inside the layer scan so only one
+    layer's context view is ever live (the XLA fallback when the Pallas
+    paged kernel does not run — the kernel reads pages in place and
+    skips this copy).
     With int8 KV scales the gathered pages are dequantized here, INTO
     the caller's compute dtype — a float32 view would quadruple the
     gathered context's bytes and promote the whole fallback attention
     to f32, doubling the HBM traffic the int8 cache exists to halve."""
-    NP, PS, KD = k_pages_l.shape
+    _, NP, PS, KD = k_pages.shape
     B, MP = page_table.shape
-    k = jnp.take(k_pages_l, page_table.reshape(-1), axis=0)
-    v = jnp.take(v_pages_l, page_table.reshape(-1), axis=0)
-    if k_scale_l is not None:
+    pages = page_table.reshape(-1)
+    k = k_pages[layer, pages]  # [B*MP, PS, KD]
+    v = v_pages[layer, pages]
+    if k_scale is not None:
         dt = out_dtype or jnp.float32
-        ks = jnp.take(k_scale_l, page_table.reshape(-1), axis=0)
-        vs = jnp.take(v_scale_l, page_table.reshape(-1), axis=0)
+        ks = k_scale[layer, pages]
+        vs = v_scale[layer, pages]
         k = (k.astype(jnp.float32) * ks[..., None]).astype(dt)
         v = (v.astype(jnp.float32) * vs[..., None]).astype(dt)
     return (

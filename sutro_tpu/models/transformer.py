@@ -267,10 +267,15 @@ def layer_apply(
     valid_len: jax.Array,        # [B]
     window: jax.Array,           # scalar int32
     theta: jax.Array,            # scalar fp32 RoPE base
-    kp_l: Optional[jax.Array] = None,   # this layer's K page pool
-    vp_l: Optional[jax.Array] = None,
-    ks_l: Optional[jax.Array] = None,   # this layer's per-token dequant
-    vs_l: Optional[jax.Array] = None,   # scales [NP, PS] (int8 KV mode)
+    # paged past: the WHOLE stacked pool [L, NP, PS, KVH*Dh] (and, in
+    # int8 KV mode, the stacked per-token dequant scales [L, NP, PS])
+    # plus this block's index into it. Never a per-layer slice: every
+    # reader indexes [layer, page] itself (ops/attention.py)
+    k_pages: Optional[jax.Array] = None,
+    v_pages: Optional[jax.Array] = None,
+    k_scale: Optional[jax.Array] = None,
+    v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,  # scalar int32
     page_table: Optional[jax.Array] = None,
     past_len: Optional[jax.Array] = None,
     use_pallas: bool = False,
@@ -308,8 +313,8 @@ def layer_apply(
         q, k, v,
         positions=positions,
         valid_len=valid_len,
-        past_k_pages=kp_l, past_v_pages=vp_l,
-        past_k_scale=ks_l, past_v_scale=vs_l,
+        past_k_pages=k_pages, past_v_pages=v_pages, layer=layer,
+        past_k_scale=k_scale, past_v_scale=v_scale,
         page_table=page_table, past_len=past_len,
         window=window, sink=sink,
         use_pallas=use_pallas,
@@ -432,11 +437,14 @@ def forward(
     paged_past: Optional[Tuple[jax.Array, ...]] = None,
     # paged_past: (k_pages, v_pages, page_table), or with an int8 KV
     # cache (k_pages, v_pages, k_scale, v_scale, page_table) — pages
-    # [L, NP, PS, KVH*Dh] (FUSED trailing axis, engine/kvcache.py)
-    # scanned per layer, per-token scales [L, NP, PS], table [B, MP].
-    # Attention reads pages directly (Pallas) or gathers one layer's
-    # view at a time (XLA fallback) — the full [L, B, CTX, ...] gather
-    # is never materialized.
+    # [L, NP, PS, KVH*Dh] (FUSED trailing axis, engine/kvcache.py),
+    # per-token scales [L, NP, PS], table [B, MP]. The stacks are
+    # CONSTANTS of the layer scan, which carries the layer's index:
+    # attention DMAs pool[layer, page] in place (Pallas) or gathers
+    # [layer, page_table] one layer at a time (XLA fallback). A pool
+    # among the scan's xs would reach the kernel as a per-layer slice,
+    # which XLA copies out in full before a custom call; the full
+    # [L, B, CTX, ...] gather is never materialized either.
     past_len: Optional[jax.Array] = None,  # [B] int32 — valid past tokens
     use_pallas: bool = False,
     ring_mesh=None,  # Mesh with "seq" axis > 1 => ring-attention prefill
@@ -470,48 +478,26 @@ def forward(
     thetas = rope_thetas(cfg)
 
     win_len = None if window_past is None else window_past[2]
-    quantized = False
+    k_pages = v_pages = k_scale = v_scale = page_table = None
     if paged_past is not None:
-        if len(paged_past) == 5:
-            # int8 KV: (k_pages, v_pages, k_scale, v_scale, table) —
-            # per-token dequant scales scan with their layer's pages
+        if len(paged_past) == 5:  # int8 KV
             k_pages, v_pages, k_scale, v_scale, page_table = paged_past
-            quantized = True
-            xs = [
-                params["layers"], windows, thetas, k_pages, v_pages,
-                k_scale, v_scale,
-            ]
         else:
             k_pages, v_pages, page_table = paged_past
-            xs = [params["layers"], windows, thetas, k_pages, v_pages]
-        if window_past is not None:
-            xs += [window_past[0], window_past[1]]
-        xs = tuple(xs)
-    else:
-        page_table = None
-        xs = (params["layers"], windows, thetas)
+    layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    xs = (params["layers"], windows, thetas, layers)
+    if window_past is not None:
+        xs += (window_past[0], window_past[1])
 
     def layer_step(h, xs_l):
-        wk_l = wv_l = ks_l = vs_l = None
-        if paged_past is not None:
-            rest = list(xs_l[3:])
-            lp, window, theta = xs_l[:3]
-            kp_l, vp_l = rest[0], rest[1]
-            rest = rest[2:]
-            if quantized:
-                ks_l, vs_l = rest[0], rest[1]
-                rest = rest[2:]
-            if window_past is not None:
-                wk_l, wv_l = rest[0], rest[1]
-        else:
-            lp, window, theta = xs_l
-            kp_l = vp_l = None
+        lp, window, theta, layer = xs_l[:4]
+        wk_l, wv_l = xs_l[4:] if window_past is not None else (None, None)
         return layer_apply(
             cfg, lp, h,
             positions=positions, valid_len=valid_len,
             window=window, theta=theta,
-            kp_l=kp_l, vp_l=vp_l,
-            ks_l=ks_l, vs_l=vs_l,
+            k_pages=k_pages, v_pages=v_pages,
+            k_scale=k_scale, v_scale=v_scale, layer=layer,
             page_table=page_table, past_len=past_len,
             use_pallas=use_pallas, ring_mesh=ring_mesh,
             wk_l=wk_l, wv_l=wv_l, win_len=win_len,

@@ -36,11 +36,14 @@ NEG_INF = -1e30
 
 # how each kernel operand shards over the mesh's "model" axis: heads
 # and the fused KV-head-major KD axis split in whole-KV-head blocks
-# (parallel/sharding.py), everything else replicates
+# (parallel/sharding.py), everything else replicates. The pools are the
+# stacked [L, NP, PS, KD] arrays, so each shard's kernel sees its own
+# [L, NP, PS, KD/tp] stack and indexes the layer itself
 _PAGED_SPECS = dict(
     q=P(None, "model", None),
-    k_pages=P(None, None, "model"), v_pages=P(None, None, "model"),
-    page_table=P(), past_len=P(),
+    k_pages=P(None, None, None, "model"),
+    v_pages=P(None, None, None, "model"),
+    layer=P(), page_table=P(), past_len=P(),
     k_cur=P(None, "model", None), v_cur=P(None, "model", None),
     window=P(), sink=P("model"),
     win_k=P(None, None, "model"), win_v=P(None, None, "model"),
@@ -53,7 +56,7 @@ _FLASH_SPECS = dict(
 
 
 def _prefix_carry(
-    q, k_pages, v_pages, k_scale, v_scale, pfx_groups, q_pos, win
+    q, k_pages, v_pages, layer, k_scale, v_scale, pfx_groups, q_pos, win
 ) -> dict:
     """The paged kernel's initial online-softmax carry over the
     job-shared prefix groups (Hydragen-style split decode): each
@@ -66,21 +69,22 @@ def _prefix_carry(
         prefix_carry_supported,
     )
 
-    PS = k_pages.shape[1]
+    PS = k_pages.shape[2]
     # in-place carry kernel when shapes allow: the shared pages are
-    # read straight from the HBM pool (page-indexed BlockSpecs);
-    # otherwise the XLA gather computes the identical carry
+    # read straight from the stacked HBM pool ((layer, page)-indexed
+    # BlockSpecs); otherwise one XLA gather on [layer, pages] computes
+    # the identical carry
     in_place = prefix_carry_supported(q, k_pages, k_scale)
     m0 = l0 = acc0 = None
     pfx_cnt = jnp.zeros_like(q_pos)
     for pages_g, len_g in pfx_groups:
         if in_place:
             mg, lg, ag = prefix_attention_carry_pallas(
-                q, k_pages, v_pages, pages_g, len_g, q_pos, win,
+                q, k_pages, v_pages, layer, pages_g, len_g, q_pos, win,
             )
         else:
             mg, lg, ag = prefix_attention_carry(
-                q, k_pages, v_pages, pages_g, len_g, q_pos, win,
+                q, k_pages, v_pages, layer, pages_g, len_g, q_pos, win,
                 k_scale=k_scale, v_scale=v_scale,
             )
         if m0 is None:
@@ -103,14 +107,16 @@ def chunk_attention(
     past_k: Optional[jax.Array] = None, # [B, CTX, KVH, Dh]
     past_v: Optional[jax.Array] = None,
     past_len: Optional[jax.Array] = None,  # [B]
-    # paged past (decode): one layer's page pool + table; mutually
-    # exclusive with past_k/past_v. Pools carry the FUSED [NP, PS,
-    # KVH*Dh] layout (engine/kvcache.py). The Pallas paged kernel reads
-    # pages in place; the fallback gathers this layer's contiguous view.
-    past_k_pages: Optional[jax.Array] = None,  # [NP, PS, KVH*Dh]
+    # paged past: the WHOLE stacked page pool + the layer to read + the
+    # table; mutually exclusive with past_k/past_v. Pools carry the
+    # FUSED [L, NP, PS, KVH*Dh] layout (engine/kvcache.py) and are never
+    # sliced per layer: the Pallas paged kernel DMAs pool[layer, page]
+    # in place; the fallback gathers [layer, page_table] once.
+    past_k_pages: Optional[jax.Array] = None,  # [L, NP, PS, KVH*Dh]
     past_v_pages: Optional[jax.Array] = None,
-    # int8 KV mode: per-token dequant scales for this layer's pages
-    past_k_scale: Optional[jax.Array] = None,  # [NP, PS] f32
+    layer: Optional[jax.Array] = None,         # scalar int32
+    # int8 KV mode: per-token dequant scales, stacked like the pages
+    past_k_scale: Optional[jax.Array] = None,  # [L, NP, PS] f32
     past_v_scale: Optional[jax.Array] = None,
     page_table: Optional[jax.Array] = None,    # [B, MP] int32
     window: Optional[jax.Array] = None,    # scalar int32; 0 => full attention
@@ -171,6 +177,7 @@ def chunk_attention(
                 )
                 ops = dict(
                     q=q[:, 0], k_pages=past_k_pages, v_pages=past_v_pages,
+                    layer=layer,
                     page_table=page_table, past_len=past_len,
                     k_cur=k[:, 0], v_cur=v[:, 0], window=win,
                 )
@@ -182,7 +189,7 @@ def chunk_attention(
                 if split:
                     optional.update(
                         _prefix_carry(
-                            q[:, 0], past_k_pages, past_v_pages,
+                            q[:, 0], past_k_pages, past_v_pages, layer,
                             past_k_scale, past_v_scale, pfx_groups,
                             past_len + (
                                 win_len if win_len is not None else 0
@@ -210,8 +217,8 @@ def chunk_attention(
             # gate refused the kernel
             lowering.record_reference("paged_decode")
         past_k, past_v = gather_kv_layer(
-            past_k_pages, past_v_pages, page_table, k.shape[2],
-            k_scale_l=past_k_scale, v_scale_l=past_v_scale,
+            past_k_pages, past_v_pages, layer, page_table, k.shape[2],
+            k_scale=past_k_scale, v_scale=past_v_scale,
             out_dtype=q.dtype,
         )
 

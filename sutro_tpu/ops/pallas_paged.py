@@ -15,17 +15,22 @@ table slot, used or not, and ~µs of grid overhead per tiny block; at
 - the page walk lives INSIDE the kernel as a ``fori_loop`` bounded by the
   row's ACTUAL page count (``ceil(past_len/PS)``) — unused table slots
   cost nothing;
-- pages are fetched from the HBM-resident pool (``memory_space=ANY``)
-  with double-buffered ``make_async_copy``: the DMA for page ``i+1``
-  overlaps compute on page ``i``;
+- the operand is the WHOLE stacked pool ``[L, NP, PS, KVH*Dh]``
+  (``memory_space=ANY``, HBM-resident) and the layer is a scalar-prefetch
+  index: pages are fetched as ``pool.at[layer, page]`` with
+  double-buffered ``make_async_copy`` (the DMA for page ``i+1`` overlaps
+  compute on page ``i``). No caller slices a layer out of the stack: XLA
+  cannot fuse a slice into a custom call's operand and would copy the
+  layer's pool (76 MB at 579 pages) before every call;
 - KV heads are processed by a static in-kernel loop, one ``[G, PS]``
   score tile per head, accumulating ``(m, l, acc)`` in VMEM scratch;
 - the current token's K/V, the optional multi-step decode window buffer
   (tokens sampled in the current fused window, not yet written to the
   pool — see engine/runner.decode_multi), and the optional gpt-oss
   attention sink all join the softmax in the finalization step;
-- per-layer sliding windows (Gemma3 / gpt-oss) are dynamic operands, so
-  one compiled kernel serves every layer of the ``lax.scan``.
+- the layer index and per-layer sliding windows (Gemma3 / gpt-oss) are
+  dynamic operands, so one compiled kernel serves every layer of the
+  ``lax.scan``.
 
 All math is float32.
 """
@@ -48,8 +53,9 @@ NEG_INF = -1e30
 
 def _paged_decode_kernel(
     # scalar prefetch: page_table [B*MP], past_len [B], window [1],
-    # then — in shared-prefix (Hydragen-style) mode — pfx_pages_cnt [B],
-    # and — when the caller carries a decode window buffer — win_len [1]
+    # layer [1] (which layer of the stacked pool this call reads), then
+    # — in shared-prefix (Hydragen-style) mode — pfx_pages_cnt [B], and
+    # — when the caller carries a decode window buffer — win_len [1]
     *refs,
     max_pages_per_seq: int,
     page_size: int,
@@ -67,6 +73,7 @@ def _paged_decode_kernel(
     page_table_ref = next(it)
     past_len_ref = next(it)
     window_ref = next(it)
+    layer_ref = next(it)
     pfx_cnt_ref = next(it) if prefix else None
     win_len_ref = next(it) if window_slots else None
     q_ref = next(it)
@@ -111,6 +118,9 @@ def _paged_decode_kernel(
     # fused-window tokens not yet written back
     pos = past + (win_len_ref[0] if window_slots else 0)
     win = window_ref[0]
+    # the pools are the whole [L, NP, PS, KD] stacks, resident in HBM:
+    # every DMA below indexes [layer, page] itself
+    layer = layer_ref[0]
 
     # Block-diagonal queries: fold the per-KV-head loop into ONE score
     # matmul and ONE value matmul per chunk. Row i (= head i, KV head
@@ -177,12 +187,14 @@ def _paged_decode_kernel(
     def k_dma(row, i, slot):
         if CH == 1:  # per-page walk: any table layout
             return pltpu.make_async_copy(
-                k_pool_ref.at[page_table_ref[row * MP + i]],
+                k_pool_ref.at[layer, page_table_ref[row * MP + i]],
                 kbuf.at[slot, 0],
                 ksem.at[slot],
             )
         return pltpu.make_async_copy(
-            k_pool_ref.at[pl.ds(page_table_ref[row * MP] + i * CH, CH)],
+            k_pool_ref.at[
+                layer, pl.ds(page_table_ref[row * MP] + i * CH, CH)
+            ],
             kbuf.at[slot],
             ksem.at[slot],
         )
@@ -190,31 +202,33 @@ def _paged_decode_kernel(
     def v_dma(row, i, slot):
         if CH == 1:
             return pltpu.make_async_copy(
-                v_pool_ref.at[page_table_ref[row * MP + i]],
+                v_pool_ref.at[layer, page_table_ref[row * MP + i]],
                 vbuf.at[slot, 0],
                 vsem.at[slot],
             )
         return pltpu.make_async_copy(
-            v_pool_ref.at[pl.ds(page_table_ref[row * MP] + i * CH, CH)],
+            v_pool_ref.at[
+                layer, pl.ds(page_table_ref[row * MP] + i * CH, CH)
+            ],
             vbuf.at[slot],
             vsem.at[slot],
         )
 
     def _scale_dmas(row, i, slot):
         # int8 KV: the per-token dequant scales ride their own (tiny)
-        # DMAs — pools arrive pre-shaped [NP, 1, PS] so the fetched
+        # DMAs — pools arrive pre-shaped [L, NP, 1, PS] so the fetched
         # chunk lands lane-major [CH, 1, PS] and each page's scale row
         # is a legal [1, PS] broadcast against a score slice (merging
         # sublanes into lanes in-kernel is unsupported)
         if CH == 1:
             return (
                 pltpu.make_async_copy(
-                    ks_pool_ref.at[page_table_ref[row * MP + i]],
+                    ks_pool_ref.at[layer, page_table_ref[row * MP + i]],
                     ksbuf.at[slot, 0],
                     kssem.at[slot],
                 ),
                 pltpu.make_async_copy(
-                    vs_pool_ref.at[page_table_ref[row * MP + i]],
+                    vs_pool_ref.at[layer, page_table_ref[row * MP + i]],
                     vsbuf.at[slot, 0],
                     vssem.at[slot],
                 ),
@@ -222,12 +236,12 @@ def _paged_decode_kernel(
         start = page_table_ref[row * MP] + i * CH
         return (
             pltpu.make_async_copy(
-                ks_pool_ref.at[pl.ds(start, CH)],
+                ks_pool_ref.at[layer, pl.ds(start, CH)],
                 ksbuf.at[slot],
                 kssem.at[slot],
             ),
             pltpu.make_async_copy(
-                vs_pool_ref.at[pl.ds(start, CH)],
+                vs_pool_ref.at[layer, pl.ds(start, CH)],
                 vsbuf.at[slot],
                 vssem.at[slot],
             ),
@@ -400,22 +414,24 @@ def _paged_decode_kernel(
 
 def prefix_attention_carry(
     q: jax.Array,            # [B, NH, Dh] current-step queries
-    k_pages: jax.Array,      # [NP, PS, KVH*Dh] one layer's page pool
+    k_pages: jax.Array,      # [L, NP, PS, KVH*Dh] the stacked page pool
     v_pages: jax.Array,
+    layer: jax.Array,        # scalar int32 — the layer to read
     pfx_pages: jax.Array,    # [Pp] int32 — the SHARED prefix's pages
     pfx_len: jax.Array,      # [B] int32 — prefix tokens per row (0 for
     #                          rows outside the prefix group)
     q_pos: jax.Array,        # [B] int32 — each query's global position
     window: jax.Array,       # scalar int32; 0 => full attention
-    k_scale: Optional[jax.Array] = None,  # [NP, PS] int8-KV scales
+    k_scale: Optional[jax.Array] = None,  # [L, NP, PS] int8-KV scales
     v_scale: Optional[jax.Array] = None,
 ):
     """Online-softmax carry ``(m0, l0, acc0)`` of attention over a
     job-shared page-aligned prefix, computed ONCE for the whole batch
     (Hydragen / cascade-inference decomposition: the prefix K/V is the
-    same physical pages for every member row, so one [Pp] gather reads
-    them from HBM once per layer per step instead of once per row
-    inside the paged kernel's per-row walk).
+    same physical pages for every member row, so one [Pp] gather on
+    ``[layer, pfx_pages]`` of the stack reads them from HBM once per
+    layer per step instead of once per row inside the paged kernel's
+    per-row walk).
 
     Returned in the paged kernel's spaces for direct carry injection
     (``paged_decode_attention(..., pfx_cnt, m0, l0, acc0)``): m0/l0
@@ -427,18 +443,18 @@ def prefix_attention_carry(
     the prefix pages in-row (same f32 math, different summation order).
     """
     B, NH, Dh = q.shape
-    NP, PS, KD = k_pages.shape
+    _, NP, PS, KD = k_pages.shape
     KVH = KD // Dh
     G = NH // KVH
     scale = Dh ** -0.5
     Pp = pfx_pages.shape[0]
     Lp = Pp * PS
 
-    kp = k_pages[pfx_pages].astype(jnp.float32)      # [Pp, PS, KD]
-    vp = v_pages[pfx_pages].astype(jnp.float32)
+    kp = k_pages[layer, pfx_pages].astype(jnp.float32)  # [Pp, PS, KD]
+    vp = v_pages[layer, pfx_pages].astype(jnp.float32)
     if k_scale is not None:
-        kp = kp * k_scale[pfx_pages][..., None].astype(jnp.float32)
-        vp = vp * v_scale[pfx_pages][..., None].astype(jnp.float32)
+        kp = kp * k_scale[layer, pfx_pages][..., None].astype(jnp.float32)
+        vp = vp * v_scale[layer, pfx_pages][..., None].astype(jnp.float32)
     kp = kp.reshape(Lp, KVH, Dh)
     vp = vp.reshape(Lp, KVH, Dh)
 
@@ -475,12 +491,15 @@ def prefix_attention_carry(
 
 
 def _prefix_carry_kernel(
-    # scalar prefetch: pfx_pages [Pp] int32 (drives the K/V index maps)
+    # scalar prefetch: pfx_pages [Pp] int32 and layer [1] int32 (they
+    # drive the K/V index maps)
     pages_ref,
+    layer_ref,
     q_bd_ref,      # [B*NH, KD] f32 block-diagonal queries (resident)
-    k_page_ref,    # [1, PS, KD] — THE prefix page for this grid step,
-    #                fetched in place from the HBM pool by the
-    #                page-indexed BlockSpec index map (no gather)
+    k_page_ref,    # [1, 1, PS, KD] — THE prefix page for this grid
+    #                step, fetched in place from the stacked HBM pool by
+    #                the (layer, page)-indexed BlockSpec index map (no
+    #                gather, no per-layer slice)
     v_page_ref,
     ok_ref,        # [1, B, PS] f32 0/1 — combined len+window mask
     m_out_ref,     # [B*NH, 128] f32 (lane-broadcast; caller takes [:,0])
@@ -489,9 +508,10 @@ def _prefix_carry_kernel(
     m_ref, l_ref, acc_ref,  # VMEM scratch carries across grid steps
     *, scale: float, n_heads: int,
 ):
+    del pages_ref, layer_ref  # read by the index maps only
     p = pl.program_id(0)
     BNH, KD = acc_ref.shape
-    PS = k_page_ref.shape[1]
+    PS = k_page_ref.shape[2]
     B = BNH // n_heads
 
     @pl.when(p == 0)
@@ -501,8 +521,8 @@ def _prefix_carry_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q_bd = q_bd_ref[...].astype(jnp.float32)            # [BNH, KD]
-    k = k_page_ref[0].astype(jnp.float32)               # [PS, KD]
-    v = v_page_ref[0].astype(jnp.float32)
+    k = k_page_ref[0, 0].astype(jnp.float32)            # [PS, KD]
+    v = v_page_ref[0, 0].astype(jnp.float32)
     # [B, PS] row mask -> every head of row b shares it: sublane
     # broadcast then leading-dim collapse (the only reshape Mosaic
     # supports — the lane dim PS is untouched)
@@ -546,15 +566,16 @@ def prefix_carry_supported(
     second kernel variant for a cache whose pages are read once per
     step either way)."""
     Dh = q.shape[-1]
-    PS = k_pages.shape[1]
+    PS = k_pages.shape[2]
     return Dh % 128 == 0 and PS % 8 == 0 and k_scale is None
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def prefix_attention_carry_pallas(
     q: jax.Array,            # [B, NH, Dh]
-    k_pages: jax.Array,      # [NP, PS, KVH*Dh]
+    k_pages: jax.Array,      # [L, NP, PS, KVH*Dh]
     v_pages: jax.Array,
+    layer: jax.Array,        # scalar int32
     pfx_pages: jax.Array,    # [Pp] int32
     pfx_len: jax.Array,      # [B] int32
     q_pos: jax.Array,        # [B] int32
@@ -564,14 +585,14 @@ def prefix_attention_carry_pallas(
 ):
     """``prefix_attention_carry`` with the shared pages read IN PLACE:
     grid ``(Pp,)`` over the prefix's pages, each step's K/V block
-    fetched straight out of the HBM page pool by a page-indexed
-    BlockSpec index map (``pages_ref[p]``) — the [Pp, PS, KD] gather
+    fetched straight out of the stacked HBM page pool by a BlockSpec
+    index map on ``(layer[0], pages[p])`` — the [Pp, PS, KD] gather
     copy the XLA path materializes per layer per step never exists.
     Sequential grid; the online-softmax carry lives in VMEM scratch and
     writes back on the last page. Bit-comparable to the XLA path: same
     f32 math in the same per-page order."""
     B, NH, Dh = q.shape
-    NP, PS, KD = k_pages.shape
+    _, NP, PS, KD = k_pages.shape
     KVH = KD // Dh
     G = NH // KVH
     scale = Dh ** -0.5
@@ -599,21 +620,24 @@ def prefix_attention_carry_pallas(
         ok.astype(jnp.float32).reshape(B, Pp, PS).swapaxes(0, 1)
     )
 
+    page_spec = pl.BlockSpec(
+        # THE in-place read: this step's block is HBM page pages[p] of
+        # layer lyr[0] of the stack, DMA'd by the pipeline itself
+        (1, 1, PS, KD), lambda p, pages, lyr: (lyr[0], pages[p], 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(Pp,),
         in_specs=[
-            pl.BlockSpec((B * NH, KD), lambda p, pages: (0, 0)),
-            # THE in-place read: this step's block is HBM page
-            # pages[p] of the pool, DMA'd by the pipeline itself
-            pl.BlockSpec((1, PS, KD), lambda p, pages: (pages[p], 0, 0)),
-            pl.BlockSpec((1, PS, KD), lambda p, pages: (pages[p], 0, 0)),
-            pl.BlockSpec((1, B, PS), lambda p, pages: (p, 0, 0)),
+            pl.BlockSpec((B * NH, KD), lambda p, *s: (0, 0)),
+            page_spec,
+            page_spec,
+            pl.BlockSpec((1, B, PS), lambda p, *s: (p, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((B * NH, 128), lambda p, pages: (0, 0)),
-            pl.BlockSpec((B * NH, 128), lambda p, pages: (0, 0)),
-            pl.BlockSpec((B * NH, KD), lambda p, pages: (0, 0)),
+            pl.BlockSpec((B * NH, 128), lambda p, *s: (0, 0)),
+            pl.BlockSpec((B * NH, 128), lambda p, *s: (0, 0)),
+            pl.BlockSpec((B * NH, KD), lambda p, *s: (0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((B * NH, 128), jnp.float32),
@@ -636,7 +660,11 @@ def prefix_attention_carry_pallas(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(pfx_pages.astype(jnp.int32), q_bd, k_pages, v_pages, ok_pg)
+    )(
+        pfx_pages.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        q_bd, k_pages, v_pages, ok_pg,
+    )
     m0 = m_o[:, 0].reshape(B, NH)
     l0 = l_o[:, 0].reshape(B, NH)
     # the kernel's value matmul fills every lane; only each row's own
@@ -694,7 +722,7 @@ def paged_decode_supported(
     """Shape/size gate for the compiled TPU path (interpret mode has no
     such constraints — tests call paged_decode_attention(interpret=True))."""
     Dh = q.shape[-1]
-    PS = k_pages.shape[1]
+    PS = k_pages.shape[2]
     ctx_capacity = page_table.shape[1] * PS
     return (
         Dh % 128 == 0 and PS % 8 == 0
@@ -708,8 +736,9 @@ def paged_decode_supported(
 )
 def paged_decode_attention(
     q: jax.Array,          # [B, NH, Dh] — current-step queries
-    k_pages: jax.Array,    # [NP, PS, KVH*Dh] — one layer's FUSED page pool
+    k_pages: jax.Array,    # [L, NP, PS, KVH*Dh] — the stacked FUSED pool
     v_pages: jax.Array,
+    layer: jax.Array,      # scalar int32 — the layer this call reads
     page_table: jax.Array, # [B, MP] int32
     past_len: jax.Array,   # [B] int32 — tokens already in the cache
     k_cur: jax.Array,      # [B, KVH, Dh] — current token K (post-RoPE)
@@ -724,7 +753,7 @@ def paged_decode_attention(
     interpret: bool = False,
     cross_row: Optional[bool] = None,  # None => PALLAS_PAGED_XROW
     # int8 KV mode: pages are int8 and these carry the per-token
-    # dequant scales [NP, PS] f32 (engine/kvcache.py)
+    # dequant scales [L, NP, PS] f32 (engine/kvcache.py)
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     # shared-prefix (Hydragen-style) mode: rows whose table head holds a
@@ -740,11 +769,15 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Returns [B, NH, Dh] attention outputs for one decode step.
 
-    The page pools carry the fused ``[NP, PS, KVH*Dh]`` layout
-    (engine/kvcache.py): the kernel's block-diagonal matmuls contract
-    over exactly that axis. The small per-step tensors (k_cur, win_k,
-    sink) are reshaped into the fused layout HERE, outside the kernel,
-    where XLA reshapes are free.
+    The page pools are the WHOLE stacked ``[L, NP, PS, KVH*Dh]``
+    arrays (engine/kvcache.py) and stay in HBM; ``layer`` rides the
+    scalar prefetch and every page DMA indexes ``[layer, page]``. A
+    per-layer slice as the operand would be materialized by XLA before
+    the custom call (76 MB a layer at the 4B cell's pool, K and V, every
+    layer of every step). The kernel's block-diagonal matmuls contract
+    over the fused trailing axis. The small per-step tensors (k_cur,
+    win_k, sink) are reshaped into the fused layout HERE, outside the
+    kernel, where XLA reshapes are free.
 
     ``win_k/win_v/win_len`` carry the multi-step decode window buffer
     (engine/runner decode_multi): tokens sampled earlier in the fused
@@ -753,7 +786,7 @@ def paged_decode_attention(
     the multi-GB pool is never copied per step."""
     lowering.record_kernel("paged_decode", interpret=interpret)
     B, NH, Dh = q.shape
-    NP, PS, KD = k_pages.shape
+    L, NP, PS, KD = k_pages.shape
     KVH = k_cur.shape[1]
     MP = page_table.shape[1]
     scale = Dh ** -0.5
@@ -787,8 +820,8 @@ def paged_decode_attention(
         prefix=prefix,
     )
 
-    # index maps take *s so the scalar-prefetch arity (3 without a
-    # window buffer, 4 with) needs no per-case lambdas
+    # index maps take *s so the scalar-prefetch arity (4 to 6) needs
+    # no per-case lambdas
     in_specs = [
         pl.BlockSpec((1, NH, Dh), lambda b, *s: (b, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),  # K pool stays in HBM
@@ -798,6 +831,7 @@ def paged_decode_attention(
         page_table.reshape(-1).astype(jnp.int32),
         past_len.astype(jnp.int32),
         jnp.asarray(window, jnp.int32).reshape(1),
+        jnp.asarray(layer, jnp.int32).reshape(1),
     ]
     if prefix:
         scalars.append(pfx_cnt.astype(jnp.int32))
@@ -807,15 +841,15 @@ def paged_decode_attention(
         v_pages,
     ]
     if quantized:
-        # pre-shaped [NP, 1, PS]: the kernel's scale chunks land
-        # lane-major (see _scale_dmas)
+        # pre-shaped [L, NP, 1, PS] (a bitcast of the stack): the
+        # kernel's scale chunks land lane-major (see _scale_dmas)
         in_specs += [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ]
         operands += [
-            k_scale.astype(jnp.float32).reshape(NP, 1, PS),
-            v_scale.astype(jnp.float32).reshape(NP, 1, PS),
+            k_scale.astype(jnp.float32).reshape(L, NP, 1, PS),
+            v_scale.astype(jnp.float32).reshape(L, NP, 1, PS),
         ]
     in_specs += [
         pl.BlockSpec((1, 1, KD), lambda b, *s: (b, 0, 0)),

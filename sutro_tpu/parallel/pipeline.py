@@ -228,12 +228,15 @@ def pipeline_decode(
 
         def layer_body(carry, xs_l):
             hh = carry
-            lp, w, th, kp_l, vp_l, wk_l, wv_l = xs_l
+            lp, w, th, layer, wk_l, wv_l = xs_l
+            # the stage-local pool [Lb, NP, PS, KD] is a constant of the
+            # stage's layer scan and ``layer`` the index into it, as in
+            # transformer.forward: no reader gets a per-layer slice
             hh, kv = transformer.layer_apply(
                 cfg, lp, hh,
                 positions=positions, valid_len=valid_len,
                 window=w, theta=th,
-                kp_l=kp_l, vp_l=vp_l,
+                k_pages=kp_local, v_pages=vp_local, layer=layer,
                 page_table=page_table, past_len=past_len,
                 use_pallas=use_pallas,
                 wk_l=wk_l, wv_l=wv_l, win_len=win_len,
@@ -243,8 +246,8 @@ def pipeline_decode(
         def run_stage(x):
             return jax.lax.scan(
                 layer_body, x,
-                (layers_local, windows_l, thetas_l, kp_local, vp_local,
-                 wk_local, wv_local),
+                (layers_local, windows_l, thetas_l,
+                 jnp.arange(Lb, dtype=jnp.int32), wk_local, wv_local),
             )
 
         k_out = jnp.zeros((Lb, B, T, KVH, Dh), h0.dtype)

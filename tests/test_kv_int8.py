@@ -20,6 +20,10 @@ from sutro_tpu.models.configs import MODEL_CONFIGS
 from sutro_tpu.ops.attention import chunk_attention
 from sutro_tpu.ops.pallas_paged import paged_decode_attention
 
+# pools are stacked [L, NP, PS, KVH*Dh] and readers index a MIDDLE layer
+N_LAYERS = 3
+LAYER = jnp.asarray(1, jnp.int32)
+
 
 def _ecfg(**kw):
     base = dict(
@@ -51,8 +55,9 @@ def test_write_then_gather_roundtrip_error_bound():
         jnp.zeros((B,), jnp.int32), jnp.full((B,), T, jnp.int32),
     )
     gk, gv = gather_kv_layer(
-        cache.k_pages[0], cache.v_pages[0], jnp.asarray(table), KVH,
-        k_scale_l=cache.k_scale[0], v_scale_l=cache.v_scale[0],
+        cache.k_pages, cache.v_pages, jnp.asarray(0, jnp.int32),
+        jnp.asarray(table), KVH,
+        k_scale=cache.k_scale, v_scale=cache.v_scale,
     )
     got = np.asarray(gk)[:, :T].reshape(B, T, KVH, Dh)
     want = np.asarray(k[0])
@@ -70,8 +75,9 @@ def _quantized_case(rng, *, B=3, NH=4, KVH=2, Dh=16, PS=8, MP=6, NP=32):
     q = jnp.asarray(rng.standard_normal((B, 1, NH, Dh)), jnp.float32)
     k_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
     v_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
-    kf = jnp.asarray(rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32)
-    vf = jnp.asarray(rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32)
+    pool = (N_LAYERS, NP, PS, KVH * Dh)
+    kf = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    vf = jnp.asarray(rng.standard_normal(pool), jnp.float32)
     kq, ks = _quantize_tokens(kf)
     vq, vs = _quantize_tokens(vf)
     table = np.zeros((B, MP), np.int32)
@@ -97,13 +103,13 @@ def test_paged_kernel_int8_matches_dequant_reference(window):
         q, k_cur, v_cur,
         positions=past_len[:, None],
         valid_len=jnp.ones((B,), jnp.int32),
-        past_k_pages=kq, past_v_pages=vq,
+        past_k_pages=kq, past_v_pages=vq, layer=LAYER,
         past_k_scale=ks, past_v_scale=vs,
         page_table=table, past_len=past_len, window=win,
         use_pallas=False,
     )
     got = paged_decode_attention(
-        q[:, 0], kq, vq, table, past_len, k_cur[:, 0], v_cur[:, 0],
+        q[:, 0], kq, vq, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
         win, None, interpret=True, k_scale=ks, v_scale=vs,
     )
     np.testing.assert_allclose(
@@ -126,13 +132,13 @@ def test_paged_kernel_int8_chunked(kv_chunk):
         q, k_cur, v_cur,
         positions=past_len[:, None],
         valid_len=jnp.ones((B,), jnp.int32),
-        past_k_pages=kq, past_v_pages=vq,
+        past_k_pages=kq, past_v_pages=vq, layer=LAYER,
         past_k_scale=ks, past_v_scale=vs,
         page_table=table, past_len=past_len, window=win,
         use_pallas=False,
     )
     got = paged_decode_attention(
-        q[:, 0], kq, vq, table, past_len, k_cur[:, 0], v_cur[:, 0],
+        q[:, 0], kq, vq, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
         win, None, interpret=True, kv_chunk=kv_chunk,
         k_scale=ks, v_scale=vs,
     )
@@ -151,8 +157,9 @@ def test_decode_attention_close_to_unquantized():
     q = jnp.asarray(rng.standard_normal((B, 1, NH, Dh)), jnp.float32)
     k_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
     v_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
-    kf = jnp.asarray(rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32)
-    vf = jnp.asarray(rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32)
+    pool = (N_LAYERS, NP, PS, KVH * Dh)
+    kf = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    vf = jnp.asarray(rng.standard_normal(pool), jnp.float32)
     kq, ks = _quantize_tokens(kf)
     vq, vs = _quantize_tokens(vf)
     table = jnp.asarray(
@@ -162,7 +169,7 @@ def test_decode_attention_close_to_unquantized():
     kw = dict(
         positions=past_len[:, None],
         valid_len=jnp.ones((B,), jnp.int32),
-        page_table=table, past_len=past_len,
+        layer=LAYER, page_table=table, past_len=past_len,
         window=jnp.asarray(0, jnp.int32), use_pallas=False,
     )
     exact = chunk_attention(

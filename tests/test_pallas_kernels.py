@@ -13,6 +13,13 @@ import pytest
 from sutro_tpu.ops.attention import chunk_attention
 from sutro_tpu.ops.pallas_paged import paged_decode_attention
 
+# every pool here is the stacked [L, NP, PS, KVH*Dh] array the engine
+# holds, random in every layer, and every reader is told to read a
+# MIDDLE layer: a kernel that ignored its layer index would read layer
+# 0's pages and fail the comparison
+N_LAYERS = 3
+LAYER = jnp.asarray(1, jnp.int32)
+
 
 def _make_decode_case(
     rng, *, B=3, NH=4, KVH=2, Dh=16, PS=8, MP=6, NP=32, past=None
@@ -20,12 +27,12 @@ def _make_decode_case(
     q = jnp.asarray(rng.standard_normal((B, 1, NH, Dh)), jnp.float32)
     k_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
     v_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
-    # pools carry the fused [NP, PS, KVH*Dh] layout (engine/kvcache.py)
+    # pools carry the fused [L, NP, PS, KVH*Dh] layout (engine/kvcache.py)
     k_pages = jnp.asarray(
-        rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32
+        rng.standard_normal((N_LAYERS, NP, PS, KVH * Dh)), jnp.float32
     )
     v_pages = jnp.asarray(
-        rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32
+        rng.standard_normal((N_LAYERS, NP, PS, KVH * Dh)), jnp.float32
     )
     # distinct pages per row
     table = np.zeros((B, MP), np.int32)
@@ -58,12 +65,12 @@ def test_paged_decode_matches_reference(window, with_sink):
         q, k_cur, v_cur,
         positions=positions,
         valid_len=jnp.ones((B,), jnp.int32),
-        past_k_pages=kp, past_v_pages=vp, page_table=table,
+        past_k_pages=kp, past_v_pages=vp, layer=LAYER, page_table=table,
         past_len=past_len, window=win, sink=sink,
         use_pallas=False,
     )
     got = paged_decode_attention(
-        q[:, 0], kp, vp, table, past_len, k_cur[:, 0], v_cur[:, 0],
+        q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
         win, sink, interpret=True,
     )
     np.testing.assert_allclose(
@@ -77,7 +84,7 @@ def test_paged_decode_zero_past():
     q, k_cur, v_cur, kp, vp, table, _ = _make_decode_case(rng)
     past_len = jnp.zeros((q.shape[0],), jnp.int32)
     got = paged_decode_attention(
-        q[:, 0], kp, vp, table, past_len, k_cur[:, 0], v_cur[:, 0],
+        q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
         jnp.asarray(0, jnp.int32), None, interpret=True,
     )
     # softmax over a single key == that key's value
@@ -233,13 +240,13 @@ def test_paged_decode_with_window_buffer(window):
         q, k_cur, v_cur,
         positions=positions,
         valid_len=jnp.ones((B,), jnp.int32),
-        past_k_pages=kp, past_v_pages=vp, page_table=table,
+        past_k_pages=kp, past_v_pages=vp, layer=LAYER, page_table=table,
         past_len=past_len, window=win, sink=None,
         use_pallas=False,
         win_k=win_k, win_v=win_v, win_len=win_len,
     )
     got = paged_decode_attention(
-        q[:, 0], kp, vp, table, past_len, k_cur[:, 0], v_cur[:, 0],
+        q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
         win, None, win_k=win_k, win_v=win_v, win_len=win_len,
         interpret=True,
     )
@@ -288,8 +295,9 @@ def test_paged_decode_chunked_contiguous(kv_chunk):
     q = jnp.asarray(rng.standard_normal((B, 1, NH, Dh)), jnp.float32)
     k_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
     v_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32)
+    pool = (N_LAYERS, NP, PS, KVH * Dh)
+    kp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
     # ascending contiguous runs per row
     table = np.zeros((B, MP), np.int32)
     starts = [1, 11, 21]
@@ -303,12 +311,12 @@ def test_paged_decode_chunked_contiguous(kv_chunk):
         q, k_cur, v_cur,
         positions=past_len[:, None],
         valid_len=jnp.ones((B,), jnp.int32),
-        past_k_pages=kp, past_v_pages=vp, page_table=table,
+        past_k_pages=kp, past_v_pages=vp, layer=LAYER, page_table=table,
         past_len=past_len, window=win, sink=None,
         use_pallas=False,
     )
     got = paged_decode_attention(
-        q[:, 0], kp, vp, table, past_len, k_cur[:, 0], v_cur[:, 0],
+        q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
         win, None, kv_chunk=kv_chunk, interpret=True,
     )
     np.testing.assert_allclose(
@@ -327,8 +335,9 @@ def test_paged_decode_cross_row_handoff(kv_chunk):
     q = jnp.asarray(rng.standard_normal((B, 1, NH, Dh)), jnp.float32)
     k_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
     v_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32)
+    pool = (N_LAYERS, NP, PS, KVH * Dh)
+    kp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
     table = np.zeros((B, MP), np.int32)
     starts = [1, 11, 21, 31]
     for b in range(B):
@@ -339,11 +348,11 @@ def test_paged_decode_cross_row_handoff(kv_chunk):
     win = jnp.asarray(0, jnp.int32)
 
     base = paged_decode_attention(
-        q[:, 0], kp, vp, table, past_len, k_cur[:, 0], v_cur[:, 0],
+        q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
         win, None, kv_chunk=kv_chunk, interpret=True, cross_row=False,
     )
     xrow = paged_decode_attention(
-        q[:, 0], kp, vp, table, past_len, k_cur[:, 0], v_cur[:, 0],
+        q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
         win, None, kv_chunk=kv_chunk, interpret=True, cross_row=True,
     )
     np.testing.assert_array_equal(np.asarray(xrow), np.asarray(base))
@@ -421,23 +430,23 @@ def test_paged_decode_prefix_carry_injection(window, quantized):
     v_cur = jnp.asarray(rng.standard_normal((B, KVH, Dh)), jnp.float32)
     if quantized:
         k_pages = jnp.asarray(
-            rng.integers(-127, 127, (NP, PS, KVH * Dh)), jnp.int8
+            rng.integers(-127, 127, (N_LAYERS, NP, PS, KVH * Dh)), jnp.int8
         )
         v_pages = jnp.asarray(
-            rng.integers(-127, 127, (NP, PS, KVH * Dh)), jnp.int8
+            rng.integers(-127, 127, (N_LAYERS, NP, PS, KVH * Dh)), jnp.int8
         )
         k_scale = jnp.asarray(
-            rng.uniform(0.005, 0.02, (NP, PS)), jnp.float32
+            rng.uniform(0.005, 0.02, (N_LAYERS, NP, PS)), jnp.float32
         )
         v_scale = jnp.asarray(
-            rng.uniform(0.005, 0.02, (NP, PS)), jnp.float32
+            rng.uniform(0.005, 0.02, (N_LAYERS, NP, PS)), jnp.float32
         )
     else:
         k_pages = jnp.asarray(
-            rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32
+            rng.standard_normal((N_LAYERS, NP, PS, KVH * Dh)), jnp.float32
         )
         v_pages = jnp.asarray(
-            rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32
+            rng.standard_normal((N_LAYERS, NP, PS, KVH * Dh)), jnp.float32
         )
         k_scale = v_scale = None
     # rows 0..2 share prefix pages [1, 2, 3]; row 3 is NOT in the group
@@ -462,7 +471,7 @@ def test_paged_decode_prefix_carry_injection(window, quantized):
     win = jnp.asarray(window, jnp.int32)
 
     ref = paged_decode_attention(
-        q, k_pages, v_pages, table, past_len, k_cur, v_cur, win, None,
+        q, k_pages, v_pages, LAYER, table, past_len, k_cur, v_cur, win, None,
         interpret=True, cross_row=False,
         k_scale=k_scale, v_scale=v_scale,
     )
@@ -472,12 +481,12 @@ def test_paged_decode_prefix_carry_injection(window, quantized):
     )
     pfx_cnt = jnp.asarray([n_pfx, n_pfx, n_pfx, 0], jnp.int32)
     m0, l0, acc0 = prefix_attention_carry(
-        q, k_pages, v_pages, jnp.asarray(pfx_pages), pfx_len,
+        q, k_pages, v_pages, LAYER, jnp.asarray(pfx_pages), pfx_len,
         past_len,  # q_pos: no window buffer, query sits at past_len
         win, k_scale=k_scale, v_scale=v_scale,
     )
     got = paged_decode_attention(
-        q, k_pages, v_pages, table, past_len, k_cur, v_cur, win, None,
+        q, k_pages, v_pages, LAYER, table, past_len, k_cur, v_cur, win, None,
         interpret=True, cross_row=False,
         k_scale=k_scale, v_scale=v_scale,
         pfx_cnt=pfx_cnt, m0=m0, l0=l0, acc0=acc0,
@@ -505,10 +514,10 @@ def test_prefix_carry_pallas_matches_xla_gather(window):
     n_pfx = 3
     q = jnp.asarray(rng.standard_normal((B, NH, Dh)), jnp.float32)
     k_pages = jnp.asarray(
-        rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32
+        rng.standard_normal((N_LAYERS, NP, PS, KVH * Dh)), jnp.float32
     )
     v_pages = jnp.asarray(
-        rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32
+        rng.standard_normal((N_LAYERS, NP, PS, KVH * Dh)), jnp.float32
     )
     pfx_pages = jnp.asarray([1, 2, 3], jnp.int32)
     pfx_len = jnp.asarray(
@@ -518,10 +527,10 @@ def test_prefix_carry_pallas_matches_xla_gather(window):
     win = jnp.asarray(window, jnp.int32)
 
     m_ref, l_ref, a_ref = prefix_attention_carry(
-        q, k_pages, v_pages, pfx_pages, pfx_len, q_pos, win
+        q, k_pages, v_pages, LAYER, pfx_pages, pfx_len, q_pos, win
     )
     m_got, l_got, a_got = prefix_attention_carry_pallas(
-        q, k_pages, v_pages, pfx_pages, pfx_len, q_pos, win,
+        q, k_pages, v_pages, LAYER, pfx_pages, pfx_len, q_pos, win,
         interpret=True,
     )
     np.testing.assert_allclose(
@@ -554,10 +563,10 @@ def test_paged_decode_with_pallas_carry_injection(window):
     k_cur = jnp.asarray(rng.standard_normal((B, KVH, Dh)), jnp.float32)
     v_cur = jnp.asarray(rng.standard_normal((B, KVH, Dh)), jnp.float32)
     k_pages = jnp.asarray(
-        rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32
+        rng.standard_normal((N_LAYERS, NP, PS, KVH * Dh)), jnp.float32
     )
     v_pages = jnp.asarray(
-        rng.standard_normal((NP, PS, KVH * Dh)), jnp.float32
+        rng.standard_normal((N_LAYERS, NP, PS, KVH * Dh)), jnp.float32
     )
     pfx_pages = np.array([1, 2, 3], np.int32)
     table = np.zeros((B, MP), np.int32)
@@ -580,7 +589,7 @@ def test_paged_decode_with_pallas_carry_injection(window):
     win = jnp.asarray(window, jnp.int32)
 
     ref = paged_decode_attention(
-        q, k_pages, v_pages, table, past_len, k_cur, v_cur, win, None,
+        q, k_pages, v_pages, LAYER, table, past_len, k_cur, v_cur, win, None,
         interpret=True, cross_row=False,
     )
     pfx_len = jnp.asarray(
@@ -588,11 +597,11 @@ def test_paged_decode_with_pallas_carry_injection(window):
     )
     pfx_cnt = jnp.asarray([n_pfx, n_pfx, n_pfx, 0], jnp.int32)
     m0, l0, acc0 = prefix_attention_carry_pallas(
-        q, k_pages, v_pages, jnp.asarray(pfx_pages), pfx_len,
+        q, k_pages, v_pages, LAYER, jnp.asarray(pfx_pages), pfx_len,
         past_len, win, interpret=True,
     )
     got = paged_decode_attention(
-        q, k_pages, v_pages, table, past_len, k_cur, v_cur, win, None,
+        q, k_pages, v_pages, LAYER, table, past_len, k_cur, v_cur, win, None,
         interpret=True, cross_row=False,
         pfx_cnt=pfx_cnt, m0=m0, l0=l0, acc0=acc0,
     )
@@ -608,17 +617,17 @@ def test_prefix_carry_supported_flags():
     from sutro_tpu.ops.pallas_paged import prefix_carry_supported
 
     q = jnp.zeros((2, 4, 128), jnp.float32)          # Dh lane-aligned
-    good = jnp.zeros((8, 8, 256), jnp.float32)
+    good = jnp.zeros((2, 8, 8, 256), jnp.float32)
     assert prefix_carry_supported(q, good)
     assert not prefix_carry_supported(
         jnp.zeros((2, 4, 16), jnp.float32),          # Dh = 16
-        jnp.zeros((8, 8, 32), jnp.float32),
+        jnp.zeros((2, 8, 8, 32), jnp.float32),
     )
     assert not prefix_carry_supported(
-        q, jnp.zeros((8, 6, 256), jnp.float32)       # PS % 8 != 0
+        q, jnp.zeros((2, 8, 6, 256), jnp.float32)    # PS % 8 != 0
     )
     assert not prefix_carry_supported(
-        q, good, k_scale=jnp.zeros((8, 8), jnp.float32)
+        q, good, k_scale=jnp.zeros((2, 8, 8), jnp.float32)
     )
 
 
