@@ -9,8 +9,10 @@ of timing and of which requests shared a batch.
    validates (``generators/batch_jobs.py``); rows that ended on
    ``length`` are counted and reported, not parsed;
 3. numbers: the system's logits, prefill then decode steps through its
-   paged cache, against the plain float32 reference on the same weights,
-   and every Pallas kernel on the path lowered, none interpreted.
+   paged cache, against the plain float32 reference on the same weights
+   (position by position for a dense model, by ``routed_rule`` for one
+   that routes), and every Pallas kernel on the path lowered, none
+   interpreted.
 
 Never here: equality of tokens or text between two requests, paths or
 runs; whether a chat overlapped a job; any latency. A request that
@@ -58,29 +60,19 @@ def accounting(log) -> Tuple[List[str], Dict[str, int]]:
     return problems, facts
 
 
-def numbers(sut, cfg: Dict[str, Any], seed: int) -> Tuple[List[str], Dict[str, Any]]:
-    """Check 3. Returns (problems, facts); the facts carry the measured
-    errors so a run's earlier lines show how close the system came."""
-    reference = importlib.import_module(
-        "perfbench.reference." + cfg.get("reference", "qwen3_dense")
-    )
-    tol_table = json.loads((HERE / "reference" / "tolerance.json").read_text())
-    dtype = sut.serving_dtype()
-    tol = float(tol_table[dtype])
-    rng = np.random.default_rng([int(seed), 0x1095])
-    # byte-range ids: what the tokenizer produces from text
-    ids = rng.integers(0, 256, N_PREFILL + N_DECODE).astype(np.int32)
-    got = sut.logits_through_cache(ids, N_PREFILL, N_DECODE)
-    positions = list(range(N_PREFILL - 1, N_PREFILL + N_DECODE))
-    want = np.asarray(
-        reference.logits_at(cfg, sut.weights(), ids, positions), np.float32
-    )
-    problems: List[str] = []
-    errs = []
-    for j, pos in enumerate(positions):
-        scale = float(np.abs(want[j]).max())
-        err = float(np.abs(got[j] - want[j]).max()) / max(scale, 1e-30)
-        errs.append(err)
+def position_errors(got, want) -> np.ndarray:
+    """``max |system - reference| / max |reference|`` over the
+    vocabulary, one number a position (leading axes kept)."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    scale = np.maximum(np.abs(want).max(axis=-1).astype(np.float64), 1e-30)
+    return np.abs(got - want).max(axis=-1).astype(np.float64) / scale
+
+
+def elementwise_rule(errs, tol: float, dtype: str, positions) -> List[str]:
+    """Every position under the dtype's tolerance: the rule of a model
+    whose every operation is continuous in its inputs."""
+    problems = []
+    for j, (err, pos) in enumerate(zip(errs, positions)):
         if not np.isfinite(err) or err > tol:
             kind = "prefill" if j == 0 else f"decode step {j}"
             problems.append(
@@ -88,8 +80,118 @@ def numbers(sut, cfg: Dict[str, Any], seed: int) -> Tuple[List[str], Dict[str, A
                 f"float32 reference by {err:.4g} of its largest magnitude "
                 f"(limit {tol} for {dtype})"
             )
-    facts = {"rel_err_prefill": errs[0], "rel_err_decode_max": max(errs[1:]),
-             "tolerance": tol, "dtype": dtype}
+    return problems
+
+
+# what a configuration file's ``numbers`` key may ask of the routed rule:
+# no file can switch the check off
+ROUTED_KEYS = {"sequences", "quantile", "cap", "why"}
+MIN_SEQUENCES, QUANTILE_RANGE, MAX_CAP = 4, (0.1, 0.5), 0.5
+
+
+def routed_spec(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The routed rule's parameters from the configuration's own file,
+    refused where they ask for more room than this module allows."""
+    spec = cfg.get("numbers")
+    name = cfg.get("name")
+    if not isinstance(spec, dict) or set(spec) != ROUTED_KEYS:
+        raise ValueError(
+            f"configuration {name!r} routes and needs a 'numbers' key with "
+            f"exactly {sorted(ROUTED_KEYS)}"
+        )
+    sequences, quantile, cap = spec["sequences"], spec["quantile"], spec["cap"]
+    lo, hi = QUANTILE_RANGE
+    if not isinstance(sequences, int) or sequences < MIN_SEQUENCES:
+        raise ValueError(f"{name!r}: numbers.sequences must be an int >= {MIN_SEQUENCES}")
+    if not isinstance(quantile, float) or not lo <= quantile <= hi:
+        raise ValueError(f"{name!r}: numbers.quantile must be within {lo}..{hi}")
+    if not isinstance(cap, float) or not 0.0 < cap <= MAX_CAP:
+        raise ValueError(f"{name!r}: numbers.cap must be in (0, {MAX_CAP}]")
+    if not isinstance(spec["why"], str) or not spec["why"].strip():
+        raise ValueError(f"{name!r}: numbers.why must say where the values come from")
+    return spec
+
+
+def routed_rule(errs, tol: float, dtype: str, spec: Dict[str, Any],
+                where) -> Tuple[List[str], Dict[str, Any]]:
+    """The rule of a model that routes (``reference/README.md``): the
+    ``quantile`` of the errors over all positions, which is the error of
+    the positions whose routing no rounding flipped, is held to the SAME
+    tolerance as a dense model's every position; every position, the
+    flipped ones too, is under ``cap``. ``where[i]`` names position i."""
+    errs = np.asarray(errs, np.float64).ravel()
+    problems = []
+    quantile, cap = float(spec["quantile"]), float(spec["cap"])
+    finite = np.isfinite(errs)
+    for i in np.flatnonzero(~finite):
+        problems.append(f"numbers: {where[i]}: logits are not finite")
+    read = float(np.quantile(errs[finite], quantile)) if finite.any() else float("inf")
+    if read > tol:
+        problems.append(
+            f"numbers: the {quantile} quantile of {errs.size} positions' "
+            f"errors against the float32 reference is {read:.4g} of the "
+            f"largest logit (limit {tol} for {dtype})"
+        )
+    for i in np.flatnonzero(finite & (errs > cap)):
+        problems.append(
+            f"numbers: {where[i]}: logits differ from the float32 reference "
+            f"by {errs[i]:.4g} of its largest magnitude (cap {cap})"
+        )
+    worst = int(np.argmax(np.where(finite, errs, np.inf)))
+    facts = {
+        "rule": "routed", "positions": int(errs.size), "quantile": quantile,
+        "cap": cap, "rel_err_quantile": read, "rel_err_max": float(errs[worst]),
+        "worst": where[worst],
+        "share_over_tolerance": float(np.mean(~finite | (errs > tol))),
+    }
+    return problems, facts
+
+
+def numbers(sut, cfg: Dict[str, Any], seed: int) -> Tuple[List[str], Dict[str, Any]]:
+    """Check 3. Returns (problems, facts); the facts carry the measured
+    errors so a run's earlier lines show how close the system came. The
+    rule is the reference family's: a module that says ``ROUTED`` is
+    held by ``routed_rule`` over several sequences, any other position
+    by position over one."""
+    reference = importlib.import_module(
+        "perfbench.reference." + cfg.get("reference", "qwen3_dense")
+    )
+    tol_table = json.loads((HERE / "reference" / "tolerance.json").read_text())
+    dtype = sut.serving_dtype()
+    tol = float(tol_table[dtype])
+    rng = np.random.default_rng([int(seed), 0x1095])
+    positions = list(range(N_PREFILL - 1, N_PREFILL + N_DECODE))
+    routed = bool(getattr(reference, "ROUTED", False))
+    spec = routed_spec(cfg) if routed else None
+    # byte-range ids: what the tokenizer produces from text
+    ids = rng.integers(
+        0, 256, (spec["sequences"] if routed else 1, N_PREFILL + N_DECODE)
+    ).astype(np.int32)
+    if not routed:
+        got = sut.logits_through_cache(ids[0], N_PREFILL, N_DECODE)
+        want = reference.logits_at(cfg, sut.weights(), ids[0], positions)
+        errs = [float(e) for e in position_errors(got, want)]
+        problems = elementwise_rule(errs, tol, dtype, positions)
+        facts = {"rel_err_prefill": errs[0], "rel_err_decode_max": max(errs[1:]),
+                 "tolerance": tol, "dtype": dtype}
+    else:
+        got = sut.logits_through_cache(ids, N_PREFILL, N_DECODE)
+        want, ties = [], []
+        for seq in ids:
+            w, t = reference.logits_and_near_ties(cfg, sut.weights(), seq, positions)
+            want.append(np.asarray(w, np.float32))
+            ties.append(np.asarray(t))
+        errs = position_errors(got, np.stack(want))
+        where = [f"sequence {s} position {pos}"
+                 for s in range(len(ids)) for pos in positions]
+        problems, facts = routed_rule(errs, tol, dtype, spec, where)
+        ties = np.stack(ties).ravel()
+        facts.update(
+            tolerance=tol, dtype=dtype, sequences=len(ids),
+            near_tie_margin=float(reference.TIE_MARGIN),
+            near_ties_mean=float(ties.mean()),
+            near_ties_at_worst=int(ties[where.index(facts["worst"])]),
+        )
     paths = sut.kernel_paths()
     facts["kernel_paths"] = paths
     if sut.uses_kernels():

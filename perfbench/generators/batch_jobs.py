@@ -205,11 +205,19 @@ class BatchJobs:
         deadline = t_end + self.drain_s
         for th in self._threads:
             th.join(timeout=max(deadline - time.monotonic(), 0.0))
-        with self._lock:
-            running = list(self._live)
-        for job_id in running:
-            self.env.sut.cancel_job(job_id)
+        # a client caught inside a submit has no job to cancel yet: keep
+        # cancelling what appears until every client has ended
+        cancelled: set = set()
+        give_up = time.monotonic() + 30.0
+        while any(th.is_alive() for th in self._threads):
+            with self._lock:
+                running = [j for j in self._live if j not in cancelled]
+            for job_id in running:
+                self.env.sut.cancel_job(job_id)
+                cancelled.add(job_id)
+            if time.monotonic() >= give_up:
+                break
+            time.sleep(0.2)
         for th in self._threads:
-            th.join(timeout=30.0)
             if th.is_alive():
                 self.env.log.note(f"batch client {th.name} did not end")

@@ -7,7 +7,7 @@ row, or a program without the phase cursor)."""
 from .sched_host_share import phase_seconds
 
 LAYER, UNIT, BETTER = "scheduler", "us/token", "lower"
-SOURCE, MOVES = "program_counter", "out_tokens_per_s_per_chip"
+SOURCE, MOVES = "program_counter", "job_turnaround_s"
 
 PHASES = ("fsm_mask", "fsm_plan", "constraint_compile", "accept")
 

@@ -37,6 +37,9 @@ class Reading:
     sut: Any = None
     trace: Optional[Dict[str, Any]] = None        # trace_reduce.reduce_trace
     trace_span: Optional[Tuple[float, float]] = None  # monotonic s
+    # the recorder at the window's end (``spans`` is the recorder at the
+    # trace's end in a traced run); the ring holds the newest 4096
+    window_spans: Optional[List[Tuple[str, float, float, Dict]]] = None
 
     @property
     def seconds(self) -> float:

@@ -54,17 +54,23 @@ def _rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def layer(dims: Dict[str, Any], layers: Dict[str, Any], index, h, positions):
-    """One decoder block over a whole sequence. ``h`` [T, H] float32;
-    ``layers`` holds the stacked weights, ``index`` picks the layer."""
-    NH, KVH, Dh = dims["heads"], dims["kv_heads"], dims["head_dim"]
-    eps, theta = dims["eps"], dims["theta"]
+def layer_weight(layers: Dict[str, Any], index):
+    """``w(name)``: layer ``index`` of a stacked weight, in float32."""
 
     def w(name):
         return jax.lax.dynamic_index_in_dim(
             layers[name], index, axis=0, keepdims=False
         ).astype(F32)
 
+    return w
+
+
+def attention(dims: Dict[str, Any], w, h, positions):
+    """The attention half of a block: ``h + Attn(RMSNorm(h)) Wo`` over a
+    whole sequence. ``h`` [T, H] float32; ``w(name)`` gives the layer's
+    weight."""
+    NH, KVH, Dh = dims["heads"], dims["kv_heads"], dims["head_dim"]
+    eps, theta = dims["eps"], dims["theta"]
     T = h.shape[0]
     x = _rms(h, w("attn_norm"), eps)
     q = (x @ w("wq")).reshape(T, NH, Dh)
@@ -79,8 +85,15 @@ def layer(dims: Dict[str, Any], layers: Dict[str, Any], index, h, positions):
     causal = positions[:, None] >= positions[None, :]
     scores = jnp.where(causal[None], scores, -jnp.inf)
     attn = jnp.einsum("nts,snd->tnd", jax.nn.softmax(scores, axis=-1), v)
-    h = h + attn.reshape(T, NH * Dh) @ w("wo")
-    x = _rms(h, w("mlp_norm"), eps)
+    return h + attn.reshape(T, NH * Dh) @ w("wo")
+
+
+def layer(dims: Dict[str, Any], layers: Dict[str, Any], index, h, positions):
+    """One decoder block over a whole sequence. ``h`` [T, H] float32;
+    ``layers`` holds the stacked weights, ``index`` picks the layer."""
+    w = layer_weight(layers, index)
+    h = attention(dims, w, h, positions)
+    x = _rms(h, w("mlp_norm"), dims["eps"])
     return h + (jax.nn.silu(x @ w("w_gate")) * (x @ w("w_up"))) @ w("w_down")
 
 

@@ -43,7 +43,7 @@ REPO = HERE.parent
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
-from perfbench import correctness, trace_reduce  # noqa: E402
+from perfbench import bytes_and_flops, correctness, trace_reduce  # noqa: E402
 from perfbench.clientlog import ClientLog  # noqa: E402
 from perfbench.reading import Reading  # noqa: E402
 
@@ -93,12 +93,19 @@ def load_cell(bench: Dict[str, Any], workload: str):
 def metrics_for(bench: Dict[str, Any], cell: Dict[str, Any], group: str):
     """The cell's metrics of one group (``end_to_end`` / ``per_layer``).
     A metric with a ``workloads`` key exists only in those cells; a
-    rehearsal cell says which real cell it ``stands_for``."""
+    per-layer metric without one exists in every cell that reports the
+    end-to-end metric it ``moves``. A rehearsal cell says which real
+    cell it ``stands_for``."""
     name = cell.get("stands_for", cell["name"])
-    return [
-        m for m in bench[group]
-        if "workloads" not in m or name in m["workloads"]
-    ]
+
+    def listed(m):
+        return "workloads" not in m or name in m["workloads"]
+
+    e2e = [m for m in bench["end_to_end"] if listed(m)]
+    if group == "end_to_end":
+        return e2e
+    reported = {m["name"] for m in e2e}
+    return [m for m in bench[group] if listed(m) and m["moves"] in reported]
 
 
 def read_metrics(metrics: List[Dict[str, Any]], package: str, reading: Reading,
@@ -190,7 +197,7 @@ def measure(sut, cfg, traffic_name: str, traffic_dir: Path, seed: int,
     t1 = t0 + seconds
     time.sleep(max(t1 - time.monotonic(), 0.0))
     reg1 = sut.registry()
-    spans = sut.recorder_spans()
+    spans = window_spans = sut.recorder_spans()
     gen.stop(t1)
     raw = reduced = None
     trace_span = None
@@ -204,7 +211,7 @@ def measure(sut, cfg, traffic_name: str, traffic_dir: Path, seed: int,
         device_kind=sut.device_kind, cfg=cfg, traffic=traffic, reg0=reg0,
         reg1=reg1, spans=spans, compiles=list(sut.compiles),
         memory_peak_bytes=sut.memory_peak_bytes(), sut=sut, trace=reduced,
-        trace_span=trace_span,
+        trace_span=trace_span, window_spans=window_spans,
     )
     return reading, env, problems, facts, raw
 
@@ -280,8 +287,9 @@ def main(argv=None) -> int:
             for m in wanted:
                 if m["name"] not in metrics and m["source"] not in skip:
                     env.log.note(
-                        f"no reading for per-layer metric {m['name']}: list "
-                        "the cells that have it under its workloads key"
+                        f"no reading for per-layer metric {m['name']}: its "
+                        "reader found nothing in this run, so the line "
+                        "leaves it out"
                     )
         else:
             wanted = metrics_for(bench, cell, "end_to_end")
@@ -322,6 +330,10 @@ def main(argv=None) -> int:
             "workload": cell["name"], "seed": args.seed, "seconds": seconds,
             "trace": args.trace, "n_chips": reading.n_chips,
             "numbers": num_facts, "accounting": acc_facts,
+            # the configuration file's sizes describe the served model
+            "params": {"from_shapes": bytes_and_flops.param_count(cfg),
+                       "per_token": bytes_and_flops.active_param_count(cfg),
+                       "served": sut.weight_count()},
             "compile_seconds_total": sum(c[2] for c in sut.compiles),
             "compiles": len(sut.compiles),
             "compiled_during_window": [
@@ -330,6 +342,19 @@ def main(argv=None) -> int:
             ],
             "jobs": len(env.log.jobs), "chats": len(env.log.chats),
             "modules": (reading.trace or {}).get("module_s"),
+        }
+        # for the run file only: when each job ran and each progress
+        # update came, in seconds from the window's start
+        timeline = {
+            "jobs": [
+                [j["submitted"] - reading.t0,
+                 None if j["ended"] is None else j["ended"] - reading.t0,
+                 j["status"], j["rows"], j["warm"]] for j in env.log.jobs
+            ],
+            "updates": [
+                [t - reading.t0, n] for t, n in env.log.cumulative_tokens()
+                if t >= reading.t0 - 1.0
+            ][:400],
         }
         say(json.dumps({"facts": facts}, default=str))
         for note in env.log.notes:
@@ -340,7 +365,8 @@ def main(argv=None) -> int:
             say(f"failed: job {j['job_id']} ended {j['status']}")
         for p in problems[:40]:
             say(f"INCORRECT: {p}")
-        write_run_file(cell, args, result, facts, problems, raw if args.keep_trace else None)
+        write_run_file(cell, args, result, dict(facts, timeline=timeline), problems,
+                       raw if args.keep_trace else None)
         if env.log.fatals:
             for f in env.log.fatals:
                 print(f"perfbench: FATAL: {f}", file=sys.stderr)

@@ -237,9 +237,12 @@ class System:
         program, then ``n_decode`` single-token decode steps through the
         paged cache, feeding ``ids`` (not samples). Returns float32
         logits ``[1 + n_decode, V]``: at the last prefill position and
-        at each decode step. A second runner shares the engine's weights
-        and mesh and has a small pool of its own, so no page the engine
-        holds is touched."""
+        at each decode step; for ``ids`` of several sequences ``[S, T]``,
+        ``[S, 1 + n_decode, V]``, every sequence through the same small
+        runner and the same jitted step (one compile however many). A
+        second runner shares the engine's weights and mesh and has a
+        small pool of its own, so no page the engine holds is touched;
+        it lives for this call only."""
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -257,7 +260,6 @@ class System:
         table = np.zeros((MP,), np.int32)
         n_pages = -(-(n_prefill + n_decode) // self.ecfg.kv_page_size)
         table[:n_pages] = np.arange(1, n_pages + 1)
-        out = [np.asarray(r.prefill(ids[:n_prefill], table), np.float32)]
         kv_chunk = r._chunk_for_table(table)
 
         @jax.jit
@@ -274,18 +276,31 @@ class System:
             return logits[0, 0].astype(jnp.float32), cache
 
         table_dev = jnp.asarray(table[None], jnp.int32)
-        cache = r.cache
-        for j in range(n_decode):
-            logits, cache = step(
-                r.params, cache,
-                jnp.asarray(ids[None, n_prefill + j : n_prefill + j + 1]),
-                jnp.asarray([n_prefill + j], jnp.int32), table_dev,
-            )
-            out.append(np.asarray(logits))
-        return np.stack(out)
+
+        def one(seq):
+            # each sequence overwrites the same pages from position 0
+            out = [np.asarray(r.prefill(seq[:n_prefill], table), np.float32)]
+            cache = r.cache
+            for j in range(n_decode):
+                logits, cache = step(
+                    r.params, cache,
+                    jnp.asarray(seq[None, n_prefill + j : n_prefill + j + 1]),
+                    jnp.asarray([n_prefill + j], jnp.int32), table_dev,
+                )
+                out.append(np.asarray(logits))
+            return np.stack(out)
+
+        if ids.ndim == 1:
+            return one(ids)
+        return np.stack([one(seq) for seq in ids])
 
     def weights(self):
         return self.runner().params
+
+    def weight_count(self) -> int:
+        """Parameters the runner serves (logical sizes, however sharded)."""
+        leaves = self.jax.tree_util.tree_leaves(self.weights())
+        return sum(int(x.size) for x in leaves)
 
     # -- the end -------------------------------------------------------------
 

@@ -68,8 +68,10 @@ def test_metric_entry(m):
     else:
         assert 1 <= len(m["layer"]) <= 200 and "\n" not in m["layer"]
         target = next(e for e in E2E if e["name"] == m["moves"])
-        # the metric it moves is reported wherever this one is
-        assert set(m.get("workloads", cells)) <= set(target.get("workloads", cells))
+        # the metric it moves is reported wherever this one is; one that
+        # lists no cell goes wherever the metric it moves goes
+        goes = set(target.get("workloads", cells))
+        assert set(m.get("workloads", goes)) <= goes
     if m["name"].endswith("_roofline") or "mfu" in m["name"]:
         assert m["unit"] == "%"
 
@@ -95,15 +97,23 @@ def test_metric_has_a_reader_that_agrees(m):
         assert (mod.LAYER, mod.MOVES) == (m["layer"], m["moves"])
 
 
-@pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
-def test_config_entry_and_file(c):
+@pytest.mark.parametrize(
+    "c,cells",
+    [(c, CELLS) for c in BENCH["configs"]]
+    + [(c, REHEARSAL["workloads"]) for c in REHEARSAL["configs"]],
+    ids=lambda x: x["name"] if "file" in x else "",
+)
+def test_config_entry_and_file(c, cells):
     assert set(c) == {"name", "source", "file", "reduced", "why"}
     assert NAME.match(c["name"]) and 1 <= len(c["why"]) <= 200
     assert any(c["file"].startswith(p + "/") for p in BENCH["paths"])
     doc = json.loads((REPO / c["file"]).read_text())
-    assert doc["name"] == c["name"] and doc["source"] == c["source"]
+    assert doc["name"] == c["name"]
+    # a rehearsal file's source goes on to say it is no published model
+    assert doc["source"] == c["source"] or (
+        cells is not CELLS and doc["source"].startswith(c["source"]))
     assert doc["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
-    assert any(w["config"] == c["name"] for w in CELLS)
+    assert any(w["config"] == c["name"] for w in cells)
     from perfbench.sut import config_field_names
 
     assert set(doc["engine"]) <= config_field_names()
@@ -116,6 +126,19 @@ def test_config_entry_and_file(c):
         doc["hidden_size"], doc["num_hidden_layers"], doc["num_attention_heads"],
         doc["num_key_value_heads"], doc["head_dim"], doc["intermediate_size"],
         doc["vocab_size"], doc["tie_word_embeddings"])
+    # and the routed ones, when the file has them; a file that routes
+    # says how its numbers are checked, a dense one does not
+    assert (m.moe_experts, m.moe_top_k) == (
+        int(doc.get("num_experts") or 0), int(doc.get("num_experts_per_tok") or 0))
+    if m.moe_experts:
+        from perfbench import correctness
+
+        assert m.moe_intermediate_size == doc["moe_intermediate_size"]
+        assert doc["norm_topk_prob"] is True     # ops/moe.py renormalises
+        assert doc["reference"] != "qwen3_dense"
+        assert correctness.routed_spec(doc)["sequences"] >= 4
+    else:
+        assert "numbers" not in doc
 
 
 @pytest.mark.parametrize(
@@ -148,12 +171,14 @@ def test_cells_cover_configs_once_and_few_take_four_chips():
 
 @pytest.mark.parametrize("cell", CELLS, ids=lambda w: w["name"])
 def test_every_cell_reports_enough(cell):
-    def present(group):
-        return [m["name"] for m in group
-                if cell["name"] in m.get("workloads", [cell["name"]])]
+    from perfbench import run
 
-    assert "setup_s" in present(E2E) and len(present(E2E)) >= 2
-    assert len(present(LAYER)) >= 1
+    e2e = [m["name"] for m in run.metrics_for(BENCH, cell, "end_to_end")]
+    layer = run.metrics_for(BENCH, cell, "per_layer")
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert len(layer) >= 1 and all(m["moves"] in e2e for m in layer)
+    # setup_s aside, something moves every end-to-end metric of the cell
+    assert set(e2e) - {"setup_s"} <= {m["moves"] for m in layer}
 
 
 def test_run_py_names_no_model_cell_or_metric():

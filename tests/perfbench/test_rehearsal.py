@@ -1,8 +1,9 @@
 """The CPU rehearsal of every cell's control flow, end to end, as a
-process of its own (tiny-dense files kept apart from BENCHMARK.json):
-the last line parses and has the contract's keys, every line is tagged,
-no device metric's name is printed, a refused chat lands in ``failed``
-and never in ``correct``. And off the chip the real cells refuse."""
+process of its own (tiny-dense and tiny-moe files kept apart from
+BENCHMARK.json): the last line parses and has the contract's keys,
+every line is tagged, no device metric's name is printed, a refused chat
+lands in ``failed`` and never in ``correct``. And off the chip the real
+cells refuse."""
 
 import json
 import os
@@ -48,7 +49,8 @@ def result_of(proc):
 @pytest.mark.parametrize("cell,trace,expect", [
     ("tiny.generate-jobs", 0, {"out_tokens_per_s_per_chip", "setup_s"}),
     ("tiny.generate-jobs", 1, {"tokens_per_dispatch", "engine_host_us_per_row"}),
-    ("tiny.classify-jobs", 1, {"tokens_per_dispatch"}),
+    ("tiny.classify-jobs", 0, {"job_turnaround_s", "setup_s"}),
+    ("tiny.classify-jobs", 1, {"decode_burst_tokens_per_s", "constraint_build_share"}),
     ("tiny.chat-over-jobs", 0, {"out_tokens_per_s_per_chip", "ttft_p95_ms",
                                 "tpot_p95_ms", "setup_s"}),
 ])
@@ -65,6 +67,32 @@ def test_rehearsal_of_a_cell(cell, trace, expect):
     assert result["attempted"] > 0
     assert expect <= set(result["metrics"])
     assert all(result["metrics"][name]["value"] > 0 for name in expect)
+
+
+def test_rehearsal_of_the_routed_cell():
+    """tiny-moe (4 experts, top-2): ``correct`` is decided by the routed
+    rule, and the configuration file's sizes count exactly the
+    parameters the program serves."""
+    proc = run(
+        "--workload", "tiny-moe.generate-jobs", "--seed", str(2**31 + 9),
+        "--seconds", "8", "--trace", "1", "--cpu-rehearsal",
+    )
+    result = result_of(proc)
+    assert result["correct"] is True and result["failed"] == 0
+    assert {"tokens_per_dispatch", "engine_host_us_per_row"} <= set(result["metrics"])
+    facts = next(
+        json.loads(ln[len(TAG):])["facts"] for ln in proc.stdout.splitlines()
+        if ln.startswith(TAG + '{"facts"')
+    )
+    numbers = facts["numbers"]
+    assert numbers["rule"] == "routed" and numbers["positions"] == 4 * 9
+    assert numbers["dtype"] == "float32" and numbers["tolerance"] == 0.002
+    # float32 against float32: no routing flips, every position agrees
+    assert numbers["rel_err_max"] < 2e-4 and numbers["share_over_tolerance"] == 0.0
+    # 2 blocks of attention, a router and 4 experts of 3 x 128 x 128, untied
+    params = facts["params"]
+    assert params["from_shapes"] == params["served"] == 624_384
+    assert params["per_token"] == 624_384 - 2 * 2 * 3 * 128 * 128
 
 
 def test_a_refused_chat_lands_in_failed_not_in_correct():
