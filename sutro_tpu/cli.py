@@ -999,7 +999,12 @@ def engine() -> None:
 
 
 @engine.command("info")
-def engine_info() -> None:
+@click.option(
+    "--model", default=None,
+    help="also print this model's cache geometry: the layers the page "
+    "pool spans and the per-sequence state beside it",
+)
+def engine_info(model: Optional[str]) -> None:
     """The device report (engine/runner.py device_report — the same
     facts chip_smoke.py prints) plus the configured KV geometry."""
     from .engine.config import load_engine_config
@@ -1012,6 +1017,22 @@ def engine_info() -> None:
         f"kv: page_size={ecfg.kv_page_size} max_pages_per_seq="
         f"{ecfg.max_pages_per_seq} decode_batch={ecfg.decode_batch_size}"
     )
+    if model:
+        import jax.numpy as jnp
+
+        from .engine.api import resolve_model
+
+        _, m, _ = resolve_model(model)
+        width = jnp.dtype(ecfg.activation_dtype).itemsize
+        # what a runner's device_info reports once its pool exists
+        click.echo(
+            f"model: {m.name} layers={m.num_layers} attn_layers="
+            f"{m.num_attn_layers} (the pool's layers) state_layers="
+            f"{m.num_conv_layers} kv_bytes_per_token="
+            f"{m.num_attn_layers * 2 * m.kv_size * width} "
+            f"state_bytes_per_page="
+            f"{m.num_conv_layers * m.conv_state_len * m.hidden_size * width}"
+        )
 
 
 @engine.command("models")

@@ -68,6 +68,7 @@ ModelOptions = Union[
         "gemma-3-27b-it",
         "gpt-oss-20b",
         "gpt-oss-120b",
+        "lfm2-24b-a2b",
         "qwen-3-embedding-0.6b",
         "qwen-3-embedding-6b",
         "qwen-3-embedding-8b",
@@ -105,6 +106,7 @@ def model_catalog() -> Dict[str, Dict[str, Any]]:
     add("gemma-3-27b-it", "gemma3-27b")
     add("gpt-oss-20b", "gpt-oss-20b")
     add("gpt-oss-120b", "gpt-oss-120b")
+    add("lfm2-24b-a2b", "lfm2-24b-a2b")
     add("qwen-3-embedding-0.6b", "qwen3-emb-0.6b", embedding=True)
     add("qwen-3-embedding-6b", "qwen3-emb-6b", embedding=True)
     add("qwen-3-embedding-8b", "qwen3-emb-8b", embedding=True)

@@ -4,7 +4,9 @@ TPU-native replacement for the server-side KV management the reference
 delegates to its remote fleet (SURVEY §2.3 row 1: "continuous-batching
 scheduler ... paged-KV decode attention"). Layout:
 
-- ``k_pages`` / ``v_pages``: ``[L, NP, PS, KVH*Dh]`` device arrays. Page 0
+- ``k_pages`` / ``v_pages``: ``[L, NP, PS, KVH*Dh]`` device arrays, L the
+  model's ATTENTION layers (``ModelConfig.num_attn_layers``: every layer
+  of a homogeneous model; a conv layer has no K/V). Page 0
   is a reserved garbage page — padding tokens scatter there, so the write
   path needs no masks or dynamic shapes. The KV-head and head-dim axes are
   stored FUSED as one trailing axis: the Pallas decode kernel's
@@ -16,6 +18,19 @@ scheduler ... paged-KV decode attention"). Layout:
   jitted step as a device argument. Pages are allocated/freed by a
   host-side free list (allocation is control-plane work; the device only
   ever sees dense int32 tables).
+
+- ``conv``: ``[NP, L_conv * (K-1) * H]``, for a model with conv layers
+  (models/transformer.py ``conv_mixer``): a second kind of per-sequence
+  state, kept PER PAGE, page-major and flat (a row a page: reads and
+  writes are a gather and a scatter of whole rows on the major axis,
+  and no axis of 2 or 8 is padded up to a tile). Row ``p`` holds, layer
+  by layer, the state after the
+  last token its sequence wrote into page p; a sequence at position
+  ``start`` reads the page that holds position ``start - 1`` (zeros at
+  ``start`` 0). Whatever shares, moves, hibernates or frees a page
+  thereby carries the state with it: a whole-page prefix hit restores
+  the state exactly, a released page needs no reset (a new sequence
+  starts from zeros, and writes a page's state before it reads it).
 
 ``write_kv`` lands a chunk's K/V into pages (Pallas in-place RMW kernel
 on TPU, XLA scatter fallback elsewhere); ``gather_kv_layer`` produces one
@@ -37,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.configs import ModelConfig
+from ..models.transformer import MixedChunk
 from .config import EngineConfig
 
 
@@ -52,6 +68,8 @@ class KVCache:
     # layer vs KD int8 bytes (<1% at KD=1024).
     k_scale: "jax.Array | None" = None  # [L, NP, PS] f32
     v_scale: "jax.Array | None" = None
+    # conv layers' per-sequence state, per page (module docstring)
+    conv: "jax.Array | None" = None     # [NP, L_conv * (K-1) * H]
 
     @property
     def page_size(self) -> int:
@@ -76,18 +94,31 @@ def alloc_cache(
     on one device first; the int8 per-token scale pools are
     shard-invariant (full-KD amax) and replicate across that mesh."""
     shape = (
-        mcfg.num_layers,
+        mcfg.num_attn_layers,
         num_pages,
         ecfg.kv_page_size,
         mcfg.num_kv_heads * mcfg.head_dim,
     )
+    rep = None
+    if sharding is not None:
+        rep = jax.sharding.NamedSharding(
+            sharding.mesh, jax.sharding.PartitionSpec()
+        )
+    conv = None
+    if mcfg.num_conv_layers:
+        # in the activation dtype (what the mixer computes g in);
+        # replicated under a mesh, like the conv weights
+        conv = jnp.zeros(
+            (
+                num_pages,
+                mcfg.num_conv_layers * mcfg.conv_state_len
+                * mcfg.hidden_size,
+            ),
+            jnp.dtype(ecfg.activation_dtype), device=rep,
+        )
     if getattr(ecfg, "kv_quantize", None) == "int8":
-        rep = None
-        if sharding is not None:
-            rep = jax.sharding.NamedSharding(
-                sharding.mesh, jax.sharding.PartitionSpec()
-            )
         return KVCache(
+            conv=conv,
             k_pages=jnp.zeros(shape, jnp.int8, device=sharding),
             v_pages=jnp.zeros(shape, jnp.int8, device=sharding),
             k_scale=jnp.zeros(shape[:3], jnp.float32, device=rep),
@@ -100,6 +131,7 @@ def alloc_cache(
     return KVCache(
         k_pages=jnp.zeros(shape, dtype, device=sharding),
         v_pages=jnp.zeros(shape, dtype, device=sharding),
+        conv=conv,
     )
 
 
@@ -208,9 +240,76 @@ def _flat_slots(
     return jnp.where(valid, page_idx * PS + pos % PS, 0)
 
 
+def _scatter_rows(pool: jax.Array, flat: jax.Array, rows: jax.Array):
+    """``pool`` [L, NP, PS, ...] with ``rows`` [L, B, T, ...] written at
+    the flat positions ``flat`` [B, T] of every layer: ONE index on the
+    major axis of the pool seen as [L * NP * PS, ...] (a bitcast). As
+    ``[:, flat]`` on [L, NP * PS, ...] the TPU compiler re-lays the
+    whole pool out for the scatter (layer axis moved inward) and back:
+    two copies of the pool a program."""
+    L, NP, PS = pool.shape[:3]
+    at = jnp.arange(L, dtype=jnp.int32)[:, None, None] * (NP * PS) + flat[None]
+    out = pool.reshape((L * NP * PS,) + pool.shape[3:]).at[at].set(
+        rows.astype(pool.dtype)
+    )
+    return out.reshape(pool.shape)
+
+
+def read_conv_state(
+    cache: KVCache, page_table: jax.Array, start: jax.Array,
+    layers: int, hidden: int,
+) -> "jax.Array | None":
+    """[L_conv, B, K-1, H]: each row's conv state at position ``start``
+    ([B] int32), read from the page that holds position ``start - 1``;
+    zeros for a row at ``start`` 0. None for a cache without conv
+    state. ``layers`` and ``hidden`` are the model's (the pool's rows
+    are flat)."""
+    if cache.conv is None:
+        return None
+    slot = jnp.maximum(start - 1, 0) // cache.page_size
+    page = jnp.take_along_axis(page_table, slot[:, None], axis=1)[:, 0]
+    state = cache.conv[page].reshape(page.shape[0], layers, -1, hidden)
+    state = jnp.where((start > 0)[:, None, None, None], state, 0)
+    return state.transpose(1, 0, 2, 3)
+
+
+def write_conv_state(
+    conv: jax.Array,           # [NP, L_conv * (K-1) * H] — the pool
+    g_ext: jax.Array,          # [L_conv, B, K-1+T, H] (MixedChunk.conv)
+    page_table: jax.Array,     # [B, MP] int32
+    start: jax.Array,          # [B] int32 — global position of chunk token 0
+    valid_len: jax.Array,      # [B] int32 — tokens of the chunk that count
+    page_size: int,
+) -> jax.Array:
+    """Commit a chunk's conv state for ANY accepted length: for every
+    page the chunk's first ``valid_len`` tokens touch, the state after
+    the last of them in that page, ``g_ext[n : n+K-1]`` for n the
+    tokens up to there. A row with ``valid_len`` 0 writes the garbage
+    page. A gather and a scatter of a few columns a row: no second pass
+    over the chunk."""
+    Lc, B, W, H = g_ext.shape
+    K1 = conv.shape[1] // (Lc * H)
+    T, PS = W - K1, page_size
+    M = (T + PS - 2) // PS + 1           # pages a chunk of T can touch
+    end = start + valid_len
+    slot = start[:, None] // PS + jnp.arange(M, dtype=jnp.int32)[None, :]
+    n = jnp.minimum((slot + 1) * PS, end[:, None]) - start[:, None]  # [B, M]
+    touched = (slot * PS < end[:, None]) & (valid_len[:, None] > 0)
+    page = jnp.take_along_axis(
+        page_table, jnp.minimum(slot, page_table.shape[1] - 1), axis=1
+    )
+    page = jnp.where(touched, page, 0)
+    cols = jnp.clip(n, 0, T)[..., None] + jnp.arange(K1, dtype=jnp.int32)
+    vals = jnp.take_along_axis(
+        g_ext[:, :, None], cols[None, ..., None], axis=3
+    )                                     # [Lc, B, M, K1, H]
+    rows = vals.transpose(1, 2, 0, 3, 4).reshape(B, M, Lc * K1 * H)
+    return conv.at[page].set(rows.astype(conv.dtype))
+
+
 def write_kv(
     cache: KVCache,
-    k_chunk: jax.Array,        # [L, B, T, KVH, Dh] or fused [L, B, T, KD]
+    k_chunk: "jax.Array | MixedChunk",  # [L, B, T, KVH, Dh] or fused [L, B, T, KD]
     v_chunk: jax.Array,
     page_table: jax.Array,     # [B, MP] int32
     start: jax.Array,          # [B] int32 — global position of chunk token 0
@@ -221,7 +320,25 @@ def write_kv(
     """Scatter a chunk's K/V into pages. Padding positions are routed to
     garbage page 0. With ``use_pallas`` the write is a true in-place DMA
     (ops/pallas_kv.py) instead of an XLA scatter over the full pool;
-    under ``kernel_mesh`` each "model" shard writes its own KV heads."""
+    under ``kernel_mesh`` each "model" shard writes its own KV heads.
+
+    A ``MixedChunk`` in K's place (a model with conv layers) commits the
+    conv state for the same ``valid_len`` beside the K/V; one whose
+    ``conv`` is None commits K/V alone (a verify forward, whose accepted
+    length is decided later: ``ModelRunner.commit_verified``)."""
+    if isinstance(k_chunk, MixedChunk):
+        conv = cache.conv
+        if k_chunk.conv is not None:
+            conv = write_conv_state(
+                conv, k_chunk.conv, page_table, start, valid_len,
+                cache.page_size,
+            )
+        if k_chunk.k is not None:  # None: state alone (commit_verified)
+            cache = write_kv(
+                cache, k_chunk.k, v_chunk, page_table, start, valid_len,
+                use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+            )
+        return dataclasses.replace(cache, conv=conv)
     if k_chunk.ndim == 4:  # already fused (decode window buffers)
         L, B, T, KD = k_chunk.shape
     else:
@@ -241,19 +358,12 @@ def write_kv(
         kq, ks = _quantize_tokens(k_chunk.reshape(L, B, T, KD))
         vq, vs = _quantize_tokens(v_chunk.reshape(L, B, T, KD))
         flat = _flat_slots(page_table, start, valid_len, T, PS)
-        k_flat = cache.k_pages.reshape(L, NP * PS, KD)
-        v_flat = cache.v_pages.reshape(L, NP * PS, KD)
-        ks_flat = cache.k_scale.reshape(L, NP * PS)
-        vs_flat = cache.v_scale.reshape(L, NP * PS)
-        k_flat = k_flat.at[:, flat].set(kq)
-        v_flat = v_flat.at[:, flat].set(vq)
-        ks_flat = ks_flat.at[:, flat].set(ks)
-        vs_flat = vs_flat.at[:, flat].set(vs)
-        return KVCache(
-            k_pages=k_flat.reshape(L, NP, PS, KD),
-            v_pages=v_flat.reshape(L, NP, PS, KD),
-            k_scale=ks_flat.reshape(L, NP, PS),
-            v_scale=vs_flat.reshape(L, NP, PS),
+        return dataclasses.replace(
+            cache,
+            k_pages=_scatter_rows(cache.k_pages, flat, kq),
+            v_pages=_scatter_rows(cache.v_pages, flat, vq),
+            k_scale=_scatter_rows(cache.k_scale, flat, ks),
+            v_scale=_scatter_rows(cache.v_scale, flat, vs),
         )
     if use_pallas:
         from jax.sharding import PartitionSpec as P
@@ -284,22 +394,17 @@ def write_kv(
             ),
             (kd, kd),
         )
-        return KVCache(k_pages=k_pages, v_pages=v_pages)
+        return dataclasses.replace(cache, k_pages=k_pages, v_pages=v_pages)
 
     flat = _flat_slots(page_table, start, valid_len, T, PS)          # [B, T]
-
-    k_flat = cache.k_pages.reshape(L, NP * PS, KD)
-    v_flat = cache.v_pages.reshape(L, NP * PS, KD)
-    # advanced indexing [L dim kept, flat [B,T]] -> [L, B, T, KD]
-    k_flat = k_flat.at[:, flat].set(
-        k_chunk.reshape(L, B, T, KD).astype(k_flat.dtype)
-    )
-    v_flat = v_flat.at[:, flat].set(
-        v_chunk.reshape(L, B, T, KD).astype(v_flat.dtype)
-    )
-    return KVCache(
-        k_pages=k_flat.reshape(L, NP, PS, KD),
-        v_pages=v_flat.reshape(L, NP, PS, KD),
+    return dataclasses.replace(
+        cache,
+        k_pages=_scatter_rows(
+            cache.k_pages, flat, k_chunk.reshape(L, B, T, KD)
+        ),
+        v_pages=_scatter_rows(
+            cache.v_pages, flat, v_chunk.reshape(L, B, T, KD)
+        ),
     )
 
 
@@ -324,15 +429,20 @@ def gather_kv_layer(
     the caller's compute dtype — a float32 view would quadruple the
     gathered context's bytes and promote the whole fallback attention
     to f32, doubling the HBM traffic the int8 cache exists to halve."""
-    _, NP, PS, KD = k_pages.shape
+    L, NP, PS, KD = k_pages.shape
     B, MP = page_table.shape
-    pages = page_table.reshape(-1)
-    k = k_pages[layer, pages]  # [B*MP, PS, KD]
-    v = v_pages[layer, pages]
+    # rows of the stack seen flat, [L * NP, PS, KD] (a bitcast): ONE
+    # index on the major axis. Indexed as [layer, pages] the TPU
+    # compiler re-lays the whole pool out for the gather (layer axis
+    # moved inward) and back for the next write: two copies of the pool
+    # a program, and their bytes among its temporaries
+    pages = layer * NP + page_table.reshape(-1)
+    k = k_pages.reshape(L * NP, PS, KD)[pages]  # [B*MP, PS, KD]
+    v = v_pages.reshape(L * NP, PS, KD)[pages]
     if k_scale is not None:
         dt = out_dtype or jnp.float32
-        ks = k_scale[layer, pages]
-        vs = v_scale[layer, pages]
+        ks = k_scale.reshape(L * NP, PS)[pages]
+        vs = v_scale.reshape(L * NP, PS)[pages]
         k = (k.astype(jnp.float32) * ks[..., None]).astype(dt)
         v = (v.astype(jnp.float32) * vs[..., None]).astype(dt)
     return (

@@ -65,8 +65,12 @@ from . import faults
 logger = logging.getLogger("sutro.kvtier")
 
 # payload dict keys: int8 values + f32 per-token scales, [L, n, PS, KD]
-# and [L, n, PS] — the canonical below-HBM page format
+# and [L, n, PS] — the canonical below-HBM page format — and, for a
+# model with conv layers, the pages' conv state "c" [L_conv, n, K-1, H]
+# AS IT IS (a few columns a page: quantizing it would move every later
+# token of the sequence, where K/V's rounding moves one attention term)
 _PAYLOAD_KEYS = ("k", "v", "ks", "vs")
+_STATE_KEY = "c"
 
 
 def quantize_payload(raw: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
@@ -75,9 +79,13 @@ def quantize_payload(raw: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
     verbatim (f32 amax / 127, 1e-8 floor, symmetric clip) so a
     demote->promote round trip through a bf16 pool drifts no more than
     the round-4 ``kv_quantize="int8"`` bound."""
-    if raw["k"].dtype == np.int8:
-        return raw  # int8 pool: already values+scales, bit-exact
     out: Dict[str, np.ndarray] = {}
+    if _STATE_KEY in raw:
+        # float32 holds a bf16 state exactly, and survives np.savez
+        out[_STATE_KEY] = np.asarray(raw[_STATE_KEY], np.float32)
+    if raw["k"].dtype == np.int8:
+        # int8 pool: already values+scales, bit-exact
+        return {**raw, **out} if out else raw
     for vk, sk in (("k", "ks"), ("v", "vs")):
         xf = np.asarray(raw[vk], np.float32)
         amax = np.max(np.abs(xf), axis=-1)
@@ -574,7 +582,7 @@ class KVTierPool:
                     raise ValueError("key mismatch (hash collision?)")
                 payload = {
                     k: np.array(z[k])
-                    for k in _PAYLOAD_KEYS
+                    for k in _PAYLOAD_KEYS + (_STATE_KEY,)
                     if k in z.files
                 }
         except FileNotFoundError:

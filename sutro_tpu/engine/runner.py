@@ -19,6 +19,7 @@ engine/scheduler.py; this module is stateless apart from params + cache.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 from typing import Any, Optional, Tuple
@@ -27,11 +28,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..models import transformer
 from ..models.configs import ModelConfig
+from ..models.transformer import MixedChunk
 from . import faults
 from .config import EngineConfig
-from .kvcache import KVCache, alloc_cache, write_kv
+from .kvcache import KVCache, alloc_cache, read_conv_state, write_kv
 from ..ops.sampling import NEG_INF, sample, cumulative_logprob
 
 
@@ -190,6 +193,15 @@ class ModelRunner:
         self.sp = int(mesh.shape.get("seq", 1)) if mesh is not None else 1
         # GPipe pipeline stages when the mesh carries a "pipe" axis
         self.pp = int(mesh.shape.get("pipe", 1)) if mesh is not None else 1
+        if not mcfg.homogeneous and (
+            self.sp > 1 or self.pp > 1 or ecfg.quantize
+        ):
+            # the ring and pipeline wrappers drive ``layer_apply`` over
+            # one stack, and quantize_params names that stack's leaves
+            raise NotImplementedError(
+                f"{mcfg.name} has layers of several kinds: not under "
+                "sequence or pipeline parallelism, nor quantize"
+            )
         # explicit shard_map EP for MoE MLPs (ops/moe_ep.py). Not under
         # sp/pp: those paths already wrap layers in their own shard_map
         # and nesting is unsupported — they keep GSPMD MoE semantics.
@@ -299,17 +311,30 @@ class ModelRunner:
             sharding=self._cache_sharding,
         )
 
+    @property
+    def has_state(self) -> bool:
+        """The model keeps per-sequence state beside K/V (conv layers):
+        callers of the verify dispatches commit it at the accepted
+        length (``commit_verified``)."""
+        return self.mcfg.num_conv_layers > 0
+
     def _page_bytes_per_device(self, dtype) -> int:
-        """One KV page (K and V, every layer, plus int8 scales) as it
-        sits on ONE device under the pool's sharding."""
-        L, PS = self.mcfg.num_layers, self.ecfg.kv_page_size
+        """One KV page (K and V, every ATTENTION layer, plus int8
+        scales, plus the page's conv state) as it sits on ONE device
+        under the pool's sharding."""
+        L, PS = self.mcfg.num_attn_layers, self.ecfg.kv_page_size
         shape = (L, 1, PS, self.mcfg.num_kv_heads * self.mcfg.head_dim)
         if self._cache_sharding is not None:
             shape = self._cache_sharding.shard_shape(shape)
+        state = (
+            self.mcfg.num_conv_layers * self.mcfg.conv_state_len
+            * self.mcfg.hidden_size
+            * jnp.dtype(self.ecfg.activation_dtype).itemsize
+        )
         if self.ecfg.kv_quantize == "int8":
             # int8 values + replicated f32 per-token scales
-            return 2 * (int(np.prod(shape)) + L * PS * 4)
-        return 2 * int(np.prod(shape)) * dtype.itemsize
+            return 2 * (int(np.prod(shape)) + L * PS * 4) + state
+        return 2 * int(np.prod(shape)) * dtype.itemsize + state
 
     def _pages_that_fit(self, want: int, dtype) -> int:
         """``want`` pages, or as many as the device's memory limit holds
@@ -387,6 +412,14 @@ class ModelRunner:
                 )
             ),
             "num_layers": int(self.mcfg.num_layers),
+            # the layers the pool spans (a conv layer has no K/V), and
+            # the second kind of per-sequence state beside it
+            "attn_layers": int(self.mcfg.num_attn_layers),
+            "pool_layers": int(self.cache.k_pages.shape[0]),
+            "state_layers": int(self.mcfg.num_conv_layers),
+            "state_bytes": int(
+                0 if self.cache.conv is None else self.cache.conv.nbytes
+            ),
             "kv_heads": int(self.mcfg.num_kv_heads),
             "head_dim": int(self.mcfg.head_dim),
             "kv_dtype_bytes": (
@@ -397,6 +430,46 @@ class ModelRunner:
         }
         self._device_info = info
         return info
+
+    @staticmethod
+    def _route_stats(chunk):
+        """[4] float32 from a forward's chunk: over the routed layers,
+        the mean number of distinct experts the dispatch's rows chose,
+        the mean rows of the busiest expert, the mean rows an expert,
+        and the row-expert pairs in all. None for a model that does not
+        count its routing. Computed inside the dispatch's own program
+        and fetched with its tokens."""
+        route = chunk.route if isinstance(chunk, MixedChunk) else None
+        if route is None:
+            return None
+        r = route.astype(jnp.float32)                       # [L_moe, E]
+        return jnp.stack([
+            jnp.mean(jnp.sum(r > 0, axis=-1).astype(jnp.float32)),
+            jnp.mean(jnp.max(r, axis=-1)),
+            jnp.mean(r),
+            jnp.sum(r),
+        ])
+
+    def _state_at(self, cache: KVCache, page_table, start):
+        """The conv layers' state of each row at ``start`` ([L_conv, B,
+        K-1, H]); None for a model that keeps none."""
+        return read_conv_state(
+            cache, page_table, start,
+            self.mcfg.num_conv_layers, self.mcfg.hidden_size,
+        )
+
+    def _count_state_commit(self, path: str) -> None:
+        if telemetry.ENABLED and self.has_state:
+            telemetry.STATE_COMMITS_TOTAL.inc(1.0, path)
+
+    def take_route_stats(self):
+        """The routing counts of the last dispatch that was fetched
+        (``_route_stats``; [4], or [steps, 4] for a fused window), as
+        numpy, once; None when there are none. The program that made
+        them has already been waited for by whoever fetched its tokens,
+        so this is a copy of a few floats and no wait of its own."""
+        stats, self._route_dev = getattr(self, "_route_dev", None), None
+        return None if stats is None else np.asarray(stats)
 
     @staticmethod
     def _paged(cache: KVCache, page_table):
@@ -429,24 +502,35 @@ class ModelRunner:
         if c.quantized:
             out["ks"] = np.asarray(c.k_scale[:, ids])
             out["vs"] = np.asarray(c.v_scale[:, ids])
+        if c.conv is not None:
+            # the pages' conv state rides with them, in its own dtype
+            # (the tiers keep it as it is: kvtier.quantize_payload), as
+            # [L_conv, n, K-1, H]: pages on axis 1, like K/V
+            m = self.mcfg
+            out["c"] = np.asarray(c.conv[ids]).reshape(
+                len(out["k"][0]), m.num_conv_layers, m.conv_state_len,
+                m.hidden_size,
+            ).transpose(1, 0, 2, 3)
         return out
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
-    def _upload_pages_jit(self, cache: KVCache, ids, k, v):
+    def _upload_pages_jit(self, cache: KVCache, ids, k, v, c=None):
         return KVCache(
             k_pages=cache.k_pages.at[:, ids].set(k),
             v_pages=cache.v_pages.at[:, ids].set(v),
             k_scale=cache.k_scale,
             v_scale=cache.v_scale,
+            conv=cache.conv if c is None else cache.conv.at[ids].set(c),
         )
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
-    def _upload_pages_q_jit(self, cache: KVCache, ids, k, v, ks, vs):
+    def _upload_pages_q_jit(self, cache: KVCache, ids, k, v, ks, vs, c=None):
         return KVCache(
             k_pages=cache.k_pages.at[:, ids].set(k),
             v_pages=cache.v_pages.at[:, ids].set(v),
             k_scale=cache.k_scale.at[:, ids].set(ks),
             v_scale=cache.v_scale.at[:, ids].set(vs),
+            conv=cache.conv if c is None else cache.conv.at[ids].set(c),
         )
 
     def write_pages(self, page_ids, payload: dict) -> None:
@@ -458,11 +542,27 @@ class ModelRunner:
         the parity contract, tests/test_kv_tiers.py)."""
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         c = self.cache
+        state = None
+        if c.conv is not None:
+            if "c" not in payload:
+                # pages without their conv state would resume a
+                # sequence from a wrong state: the caller re-prefills
+                raise ValueError(
+                    "page payload carries no conv state for a model "
+                    "that keeps one"
+                )
+            state = jnp.asarray(
+                np.asarray(payload["c"]).transpose(1, 0, 2, 3).reshape(
+                    len(ids), -1
+                )
+            ).astype(c.conv.dtype)
+            self._count_state_commit("resume")
         if c.quantized:
             self.cache = self._upload_pages_q_jit(
                 c, ids,
                 jnp.asarray(payload["k"]), jnp.asarray(payload["v"]),
                 jnp.asarray(payload["ks"]), jnp.asarray(payload["vs"]),
+                state,
             )
             return
         pool_dt = c.k_pages.dtype
@@ -476,6 +576,7 @@ class ModelRunner:
             c, ids,
             jnp.asarray(vals["k"]).astype(pool_dt),
             jnp.asarray(vals["v"]).astype(pool_dt),
+            state,
         )
 
     # ------------------------------------------------------------------
@@ -518,7 +619,7 @@ class ModelRunner:
             use_pallas=self.use_pallas,
             kernel_mesh=self.kernel_mesh,
         )
-        return logits[:, 0], cache
+        return logits[:, 0], cache, self._route_stats(k)
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
     def _prefill_chunk_jit(
@@ -537,13 +638,14 @@ class ModelRunner:
             kernel_mesh=self.kernel_mesh,
             ep_mesh=self.ep_mesh,
             logit_positions=jnp.maximum(valid_len - 1, 0),
+            conv_state=self._state_at(cache, page_table, start),
         )
         cache = write_kv(
             cache, k, v, page_table, start, valid_len,
             use_pallas=self.use_pallas,
             kernel_mesh=self.kernel_mesh,
         )
-        return logits[:, 0], cache
+        return logits[:, 0], cache, self._route_stats(k)
 
     def prefill(
         self, token_ids: np.ndarray, page_table: np.ndarray,
@@ -581,7 +683,8 @@ class ModelRunner:
                 seg = token_ids[off : off + C]
                 ids = np.zeros((1, C), np.int32)
                 ids[0, : len(seg)] = seg
-                logits, self.cache = self._prefill_chunk_jit(
+                self._count_state_commit("chunk")
+                logits, self.cache, self._route_dev = self._prefill_chunk_jit(
                     self.params,
                     self.cache,
                     jnp.asarray(ids),
@@ -595,7 +698,8 @@ class ModelRunner:
             T = -(-T // self.sp) * self.sp
         ids = np.zeros((1, T), np.int32)
         ids[0, :n] = token_ids
-        logits, self.cache = self._prefill_jit(
+        self._count_state_commit("prefill")
+        logits, self.cache, self._route_dev = self._prefill_jit(
             self.params,
             self.cache,
             jnp.asarray(ids),
@@ -634,7 +738,8 @@ class ModelRunner:
             ids[i, : len(r)] = r
             lens[i] = len(r)
             tables[i] = page_tables[i]
-        logits, self.cache = self._prefill_jit(
+        self._count_state_commit("prefill")
+        logits, self.cache, self._route_dev = self._prefill_jit(
             self.params,
             self.cache,
             jnp.asarray(ids),
@@ -669,7 +774,8 @@ class ModelRunner:
             lens[i] = len(r)
             st[i] = starts[i]
             tables[i] = page_tables[i]
-        logits, self.cache = self._prefill_chunk_jit(
+        self._count_state_commit("chunk")
+        logits, self.cache, self._route_dev = self._prefill_chunk_jit(
             self.params,
             self.cache,
             jnp.asarray(ids),
@@ -686,6 +792,7 @@ class ModelRunner:
     def _trunk_decode(
         self, params, cache: KVCache, ids, positions, past_len,
         page_table, window_past=None, kv_chunk: int = 1, pfx=None,
+        conv_state=None,
     ):
         """One decode trunk forward over the paged past — the plain
         scanned forward, or the stage-local pipeline schedule under
@@ -694,8 +801,15 @@ class ModelRunner:
         ``pfx`` = tuple of (pages [Pp_g] int32, pfx_len [B] int32)
         groups enabling Hydragen-style split decode over job-shared
         table-head prefixes (ops/attention.py); the prefix cache is
-        disabled under pp, so the pipeline path never sees one."""
+        disabled under pp, so the pipeline path never sees one.
+
+        ``conv_state`` ([L_conv, B, K-1, H]) is the conv layers' state
+        at ``positions`` for a caller that carries it itself (the fused
+        window's scan); otherwise it is read from the cache at
+        ``past_len``."""
         B = ids.shape[0]
+        if conv_state is None:
+            conv_state = self._state_at(cache, page_table, past_len)
         ones = jnp.ones((B,), jnp.int32)
         if self.pp > 1:
             from ..parallel.pipeline import pipeline_decode
@@ -716,6 +830,7 @@ class ModelRunner:
             kv_chunk=kv_chunk,
             ep_mesh=self.ep_mesh,
             pfx_groups=pfx,
+            conv_state=conv_state,
         )
 
     def _chunk_for_table(self, page_table: np.ndarray) -> int:
@@ -777,7 +892,7 @@ class ModelRunner:
             allowed=allowed, row_seeds=row_seeds,
         )
         logp = cumulative_logprob(step_logits, tok)
-        return tok, logp, cache
+        return tok, logp, cache, self._route_stats(k)
 
     def decode_step(
         self,
@@ -812,7 +927,8 @@ class ModelRunner:
                 jnp.asarray(freq, jnp.float32),
                 jnp.asarray(rep, jnp.float32),
             )
-        tok, logp, self.cache = self._decode_jit(
+        self._count_state_commit("window")
+        tok, logp, self.cache, self._route_dev = self._decode_jit(
             self.params,
             self.cache,
             jnp.asarray(last_tokens[:, None], jnp.int32),
@@ -880,7 +996,8 @@ class ModelRunner:
             use_pallas=self.use_pallas,
             kernel_mesh=self.kernel_mesh,
         )
-        return toks, logps, cache
+        route = wk.route if isinstance(wk, MixedChunk) else None
+        return toks, logps, cache, route
 
     def _window_scan(
         self, params, cache: KVCache, last, past_len, page_table,
@@ -892,7 +1009,12 @@ class ModelRunner:
         Returns (toks [steps, B], logps [steps, B], wk, wv) with the
         window K/V NOT yet committed to pages — callers decide the
         commit (full window for unconstrained decode, verified prefix
-        for speculative constrained decode).
+        for speculative constrained decode). For a model with layers of
+        several kinds ``wk`` is a ``MixedChunk``: the K window, the conv
+        layers' state before the window followed by each step's gated
+        input (carried through the scan like the K/V window, so ANY
+        accepted prefix commits by ``write_kv``), and the routing
+        counts of each step ([steps, 4], ``_route_stats``).
 
         ``allowed0`` ([B, V] bool, optional) masks the FIRST step's
         logits only: a row whose previous window rejected a token takes
@@ -900,7 +1022,7 @@ class ModelRunner:
         scaffold token), so one adversarial row no longer degrades the
         whole batch to masked single-steps."""
         B = last.shape[0]
-        L = self.mcfg.num_layers
+        L = self.mcfg.num_attn_layers
         KVH, Dh = self.mcfg.num_kv_heads, self.mcfg.head_dim
         KD = KVH * Dh
         # window buffers hold UNQUANTIZED step K/V (they are read by
@@ -916,15 +1038,35 @@ class ModelRunner:
         # on TPU — a 2x memory expansion on multi-GB buffers at large B
         wk0 = jnp.zeros((L, B, steps, KD), dtype)
         wv0 = jnp.zeros((L, B, steps, KD), dtype)
+        mixed = not self.mcfg.homogeneous
+        K1 = self.mcfg.conv_state_len
+        wc0 = None
+        if self.has_state:
+            state = self._state_at(cache, page_table, past_len)
+            wc0 = jnp.concatenate(
+                [state, jnp.zeros(state.shape[:2] + (steps,)
+                                  + state.shape[3:], state.dtype)],
+                axis=2,
+            )
 
         def body(carry, step_idx):
-            wk, wv, last = carry
+            wk, wv, wc, last = carry
             logits, _, (k, v) = self._trunk_decode(
                 params, cache, last[:, None],
                 (past_len + step_idx)[:, None], past_len, page_table,
                 window_past=(wk, wv, step_idx), kv_chunk=kv_chunk,
                 pfx=pfx,
+                conv_state=None if wc is None
+                else jax.lax.dynamic_slice_in_dim(wc, step_idx, K1, axis=2),
             )
+            route = self._route_stats(k)
+            if mixed:
+                if wc is not None:
+                    wc = jax.lax.dynamic_update_slice(
+                        wc, k.conv[:, :, K1:].astype(wc.dtype),
+                        (0, 0, K1 + step_idx, 0),
+                    )
+                k = k.k
             wk = jax.lax.dynamic_update_slice(
                 wk, k.astype(dtype).reshape(L, B, 1, KD),
                 (0, 0, step_idx, 0),
@@ -952,13 +1094,15 @@ class ModelRunner:
                 temperature=temperature, top_p=top_p, top_k=top_k,
             )
             logp = cumulative_logprob(step_logits, tok)
-            return (wk, wv, tok), (tok, logp)
+            return (wk, wv, wc, tok), (tok, logp, route)
 
-        (wk, wv, _), (toks, logps) = jax.lax.scan(
+        (wk, wv, wc, _), (toks, logps, route) = jax.lax.scan(
             body,
-            (wk0, wv0, last),
+            (wk0, wv0, wc0, last),
             jnp.arange(steps, dtype=jnp.int32),
         )
+        if mixed:
+            wk = MixedChunk(k=wk, conv=wc, route=route)
         return toks, logps, wk, wv
 
     def decode_multi(
@@ -978,6 +1122,7 @@ class ModelRunner:
             last_tokens, past_len, page_table, rng, temperature, top_p,
             steps, top_k=top_k, pfx=pfx,
         )
+        self._route_dev = self.window_route
         return np.asarray(toks), np.asarray(logps)
 
     def decode_multi_async(
@@ -1005,7 +1150,10 @@ class ModelRunner:
         B = past_len.shape[0]
         if top_k is None:
             top_k = np.zeros((B,), np.int32)
-        toks, logps, self.cache = self._decode_multi_jit(
+        # the window's routing counts stay on the device beside its
+        # tokens; whoever fetches the tokens fetches them
+        self._count_state_commit("window")
+        toks, logps, self.cache, self.window_route = self._decode_multi_jit(
             self.params,
             self.cache,
             jnp.asarray(last_tokens, jnp.int32),
@@ -1041,7 +1189,16 @@ class ModelRunner:
             use_pallas=self.use_pallas,
             kernel_mesh=self.kernel_mesh,
             ep_mesh=self.ep_mesh,
+            conv_state=self._state_at(cache, page_table, start),
         )
+        # K/V of every input is written (rejected positions are dead
+        # stores past the accepted length); conv state is ONE value a
+        # page, so it waits for the accepted length: the chunk's gated
+        # inputs go back to the caller (``commit_verified``)
+        pending = None
+        if isinstance(k, MixedChunk):
+            pending = k.conv
+            k = dataclasses.replace(k, conv=None)
         cache = write_kv(
             cache, k, v, page_table, start, valid_len,
             use_pallas=self.use_pallas,
@@ -1052,7 +1209,7 @@ class ModelRunner:
         plain_lp = jnp.take_along_axis(
             jax.nn.log_softmax(lg, axis=-1), plain[..., None], axis=-1
         )[..., 0]
-        return lg, plain, plain_lp, cache
+        return lg, plain, plain_lp, cache, pending
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
     def _verify_cand_jit(
@@ -1070,7 +1227,7 @@ class ModelRunner:
         single-step path (which samples under the mask but reports
         unmasked logprobs), so a row's cumulative_logprob no longer
         depends on which path committed each token."""
-        lg, plain, plain_lp, cache = self._verify_forward(
+        lg, plain, plain_lp, cache, pending = self._verify_forward(
             params, cache, ids, valid_len, page_table, start
         )
         g = jnp.take_along_axis(lg, cand, axis=2)             # [B, C, M]
@@ -1087,7 +1244,7 @@ class ModelRunner:
             jnp.take_along_axis(lg, ctok[..., None], axis=-1)[..., 0]
             - lse_v
         )
-        return ctok.astype(jnp.int32), clp, plain, plain_lp, cache
+        return ctok.astype(jnp.int32), clp, plain, plain_lp, cache, pending
 
     def verify_candidates(
         self,
@@ -1108,7 +1265,7 @@ class ModelRunner:
         ids = np.zeros((B, K + 1), np.int32)
         ids[:, 0] = last_tokens
         ids[:, 1:] = drafts
-        ct, cl, pt, pl, self.cache = self._verify_cand_jit(
+        ct, cl, pt, pl, self.cache, pending = self._verify_cand_jit(
             self.params,
             self.cache,
             jnp.asarray(ids),
@@ -1118,6 +1275,7 @@ class ModelRunner:
             jnp.asarray(cand, jnp.int32),
             jnp.asarray(cand_n, jnp.int32),
         )
+        self._hold_verified(pending, page_table, past_len)
         return (
             np.asarray(ct), np.asarray(cl),
             np.asarray(pt), np.asarray(pl),
@@ -1134,10 +1292,10 @@ class ModelRunner:
         All input positions' K/V are written to pages — rejected
         positions become dead stores beyond the row's accepted ``pos``
         (masked by past_len, overwritten as decode proceeds)."""
-        _, toks, logp, cache = self._verify_forward(
+        _, toks, logp, cache, pending = self._verify_forward(
             params, cache, ids, valid_len, page_table, start
         )
-        return toks, logp, cache
+        return toks, logp, cache, pending
 
     def verify_greedy(
         self,
@@ -1157,7 +1315,7 @@ class ModelRunner:
         ids = np.zeros((B, K + 1), np.int32)
         ids[:, 0] = last_tokens
         ids[:, 1:] = drafts
-        toks, logp, self.cache = self._verify_jit(
+        toks, logp, self.cache, pending = self._verify_jit(
             self.params,
             self.cache,
             jnp.asarray(ids),
@@ -1165,7 +1323,40 @@ class ModelRunner:
             jnp.asarray(page_table, jnp.int32),
             jnp.asarray(past_len, jnp.int32),
         )
+        self._hold_verified(pending, page_table, past_len)
         return np.asarray(toks), np.asarray(logp)
+
+    def _hold_verified(self, pending, page_table, past_len) -> None:
+        """Keep a verify dispatch's conv inputs until the caller has
+        decided each row's accepted length."""
+        self._verified = None if pending is None else (
+            pending,
+            np.array(page_table, np.int32, copy=True),
+            np.array(past_len, np.int32, copy=True),
+        )
+
+    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+    def _commit_state_jit(self, cache: KVCache, conv, page_table, start, n):
+        return write_kv(cache, MixedChunk(k=None, conv=conv), None,
+                        page_table, start, n)
+
+    def commit_verified(self, accepted: np.ndarray) -> None:
+        """After ``verify_candidates`` / ``verify_greedy`` on a model
+        that keeps conv state (``has_state``): commit each row's state
+        after its first ``accepted[b]`` INPUT tokens (0: the row keeps
+        the state it had). One gather and scatter, no second forward. A
+        model without such state needs no call, and a call is a no-op."""
+        held, self._verified = getattr(self, "_verified", None), None
+        if held is None:
+            return
+        conv, page_table, past_len = held
+        self._count_state_commit("verify")
+        self.cache = self._commit_state_jit(
+            self.cache, conv,
+            jnp.asarray(page_table, jnp.int32),
+            jnp.asarray(past_len, jnp.int32),
+            jnp.asarray(accepted, jnp.int32),
+        )
 
     @functools.partial(jax.jit, static_argnums=(0,))
     def _merge_last_jit(self, prev_last, refresh_mask, refresh_vals):
@@ -1258,11 +1449,13 @@ class ModelRunner:
             np.array(past_len, np.int32, copy=True),
             np.array(page_table, np.int32, copy=True),
         )
+        self._route_dev = wk.route if isinstance(wk, MixedChunk) else None
         return np.asarray(toks), np.asarray(logps), handle
 
     def commit_window(self, handle, accepted: np.ndarray) -> None:
         """Write each row's accepted window prefix into the page pool."""
         wk, wv, past_len, page_table = handle
+        self._count_state_commit("window")
         self.cache = self._commit_window_jit(
             self.cache, wk, wv,
             jnp.asarray(page_table, jnp.int32),

@@ -588,6 +588,9 @@ class ContinuousBatcher:
         # the decode/prefill loops stash {stage: {batch, steps, ...}}
         # here right before dispatch; the sink folds it into the span
         self._tel_attrs: Dict[str, Dict[str, Any]] = {}
+        # the last routing counts fetched, by stage: a span dispatched
+        # before its own counts are back carries the previous ones
+        self._route_attrs: Dict[str, Dict[str, Any]] = {}
         # with telemetry on the timer is also the scheduler's phase
         # cursor: every instant of run_multi belongs to one named phase
         self.timer = StepTimer(
@@ -938,6 +941,17 @@ class ContinuousBatcher:
             hits.append((key, p))
         if not hits:
             return handle
+        if getattr(self.runner, "has_state", False) and any(
+            "c" not in p for _, p in hits
+        ):
+            # pages without their conv state cannot restore the state
+            # at the position the tail would resume from: refuse the
+            # hit, pay the plain tail prefill, and count it
+            if self._tel_on:
+                telemetry.STATE_FALLBACK_PREFILL_TOKENS_TOTAL.inc(
+                    float(len(hits) * PS), "tier_payload_without_state"
+                )
+            return handle
         n = len(hits)
         if self.native is not None:
             pages = self.native.alloc_pages(n)
@@ -1225,6 +1239,7 @@ class ContinuousBatcher:
                         [r.prompt_ids.astype(np.int32) for r in reqs],
                         np.stack([b[4] for b in batch]),
                     )
+                self._note_route("prefill")
             self.prefill_tokens += sum(
                 len(r.prompt_ids) - s for r, s in zip(reqs, starts)
             )
@@ -1329,6 +1344,7 @@ class ContinuousBatcher:
                 s.ptable[None, :],
                 [s.prefill_pos],
             )
+            self._note_route("prefill")
         self.prefill_tokens += len(seg)
         s.prefill_pos += len(seg)
         if s.prefill_pos < len(req.prompt_ids):
@@ -1508,6 +1524,7 @@ class ContinuousBatcher:
         self._step += 1
         tm.enter("accept")
         n0 = self._n_accepted
+        gens = list(self._gen)
         for i in active:
             s = self.slots[i]
             ctx = s.job
@@ -1553,6 +1570,7 @@ class ContinuousBatcher:
                     self._needs_mask.add(i)
                     continue
             self._accept_token(i, tok, float(pl[i, 0]))
+        self._commit_verified(active, past_len, gens)
         tm.enter("accept", tokens=self._n_accepted - n0)
         return True
 
@@ -1734,10 +1752,12 @@ class ContinuousBatcher:
         self._step += 1
         tm.enter("accept")
         n0 = self._n_accepted
+        gens = list(self._gen)
         for i in active:
             self._spec_accept_row(
                 i, int(dlens[i]), drafts[i], toks_v[i], logp_v[i]
             )
+        self._commit_verified(active, past_len, gens)
         tm.enter("accept", tokens=self._n_accepted - n0)
         # acceptance-based exit (coverage got us here; acceptance keeps
         # us here): once the rolling window has seen enough drafts,
@@ -1753,6 +1773,44 @@ class ContinuousBatcher:
             self._spec_win_drafted = 0
             self._spec_win_accepted = 0
         return True
+
+    def _commit_verified(self, active, past_len, gens) -> None:
+        """After a verify dispatch's accept loop, for a model that keeps
+        conv state beside K/V: commit each row's state at the inputs
+        its acceptance consumed (``pos`` advanced by exactly that), so
+        a partial accept rolls the state back by a gather and no second
+        forward. A row released in the loop commits nothing: its pages
+        are free, and whoever takes them writes before it reads."""
+        if not getattr(self.runner, "has_state", False):
+            return
+        n = np.zeros((self.B,), np.int32)
+        for i in active:
+            s = self.slots[i]
+            if s is not None and self._gen[i] == gens[i]:
+                n[i] = s.pos - int(past_len[i])
+        self.runner.commit_verified(n)
+
+    def _note_route(self, stage: str, stats=None) -> None:
+        """Fold the routing counts of the dispatch just fetched (a
+        model that counts them: runner.take_route_stats) into
+        ``stage``'s span attrs and the routed-rows counter. Called
+        inside the stage's timed block, after its tokens were fetched:
+        the counts came back with them."""
+        if stats is None:
+            take = getattr(self.runner, "take_route_stats", None)
+            stats = take() if take is not None else None
+        if stats is None or not self._tel_on:
+            return
+        a = np.asarray(stats, np.float64).reshape(-1, 4)
+        self._route_attrs[stage] = {
+            "experts_touched": round(float(a[:, 0].mean()), 2),
+            "expert_rows_max": round(float(a[:, 1].mean()), 2),
+            "expert_rows_mean": round(float(a[:, 2].mean()), 3),
+        }
+        self._tel_attrs[stage] = {
+            **(self._tel_attrs.get(stage) or {}), **self._route_attrs[stage]
+        }
+        telemetry.MOE_ROUTED_ROWS_TOTAL.inc(float(a[:, 3].sum()))
 
     def _pad_mask(self, mask: np.ndarray) -> np.ndarray:
         """Constraint masks are sized to the *tokenizer* vocab; pad to the
@@ -2228,7 +2286,7 @@ class ContinuousBatcher:
         but not yet processed, per slot — only windows whose (slot, gen)
         snapshot still matches count."""
         proj = np.zeros((self.B,), np.int32)
-        for _, _, w_active, w_gens, wK in pipe:
+        for _, _, w_active, w_gens, wK, _ in pipe:
             for idx, i in enumerate(w_active):
                 if self._gen[i] == w_gens[idx]:
                     proj[i] += wK
@@ -2267,7 +2325,7 @@ class ContinuousBatcher:
         their host-known token via a device-side merge — no host sync
         anywhere on this path."""
         if pipe:
-            prev_toks, _, p_active, p_gens, _ = pipe[-1]
+            prev_toks, _, p_active, p_gens, _, _ = pipe[-1]
             chained = {
                 i
                 for idx, i in enumerate(p_active)
@@ -2301,6 +2359,9 @@ class ContinuousBatcher:
                 list(active),
                 [self._gen[i] for i in active],
                 K,
+                # the window's routing counts, on the device beside its
+                # tokens (None for a model that does not count them)
+                getattr(self.runner, "window_route", None),
             )
         )
 
@@ -2316,10 +2377,12 @@ class ContinuousBatcher:
         (round-5 host-overhead profile: the per-token Python loop cost
         ~26 ms per B=128 window, 2× the device window itself); rows with
         any per-token machinery keep the exact per-token loop."""
-        toks_dev, logps_dev, w_active, w_gens, wK = entry
+        toks_dev, logps_dev, w_active, w_gens, wK, route_dev = entry
         with self.timer.time("decode"):
             toks = np.asarray(toks_dev)
             logps = np.asarray(logps_dev)
+            if route_dev is not None:
+                self._note_route("decode_window", route_dev)
         self.timer.enter("accept")
         n0 = self._n_accepted
         plain: List[int] = []
@@ -2699,6 +2762,19 @@ class ContinuousBatcher:
         ctx = s.job
         PS = self.ecfg.kv_page_size
         end = -(-s.pos // PS)  # ceil: the partial tail page rides along
+        if getattr(self.runner, "has_state", False):
+            # conv state is ONE value a page, the state after the last
+            # token written there; windows in flight when the row is
+            # preempted have written the tail page past ``pos``, so its
+            # state is ahead of the row. Whole pages hold the state
+            # after their last position whatever ran past them: capture
+            # those, and the resume prefills the tail again (counted)
+            end = s.pos // PS
+            tail = s.pos - max(s.shared_n, end) * PS
+            if tail > 0 and self._tel_on:
+                telemetry.STATE_FALLBACK_PREFILL_TOKENS_TOTAL.inc(
+                    float(tail), "hibernated_tail_page"
+                )
         own_aligned = [
             int(p) for p in s.pages[s.shared_n : max(s.shared_n, end)]
         ]
@@ -2770,6 +2846,16 @@ class ContinuousBatcher:
                 payload is not None
                 and int(payload["k"].shape[1]) == hib.n_pages
             )
+            if ok and getattr(self.runner, "has_state", False) and (
+                "c" not in payload
+            ):
+                # K/V without the conv state would resume the row from
+                # a wrong state: regenerate instead, and count it
+                ok = False
+                if self._tel_on:
+                    telemetry.STATE_FALLBACK_PREFILL_TOKENS_TOTAL.inc(
+                        float(hib.pos), "tier_payload_without_state"
+                    )
         start = shared + hib.n_pages * PS
         if ok and hib.pos > start and (
             getattr(self.runner, "sp", 1) != 1
@@ -3495,6 +3581,7 @@ class ContinuousBatcher:
                                 sum(int(past_len[i]) for i in active)
                                 / max(len(active), 1), 1,
                             ),
+                            **self._route_attrs.get("decode_window", {}),
                         }
                     # a pending spec probe suspends refill so the pipe
                     # drains (one window per iteration) and the probe
@@ -3577,6 +3664,7 @@ class ContinuousBatcher:
                             sum(int(past_len[i]) for i in active)
                             / max(len(active), 1), 1,
                         ),
+                        **self._route_attrs.get("decode_window", {}),
                     }
                 self._key, sub = jax.random.split(self._key)
                 # row-seeded sampling needs a batch-independent base key
@@ -3629,6 +3717,7 @@ class ContinuousBatcher:
                                 pfx=self._split_pfx(active),
                             )
                         )
+                        self._note_route("decode_window")
                     self._step += K
                     path = "window"
                     tm.enter("accept")
@@ -3693,6 +3782,7 @@ class ContinuousBatcher:
                             last, past_len, table, sub, temp, top_p, K,
                             top_k=top_k, pfx=self._split_pfx(active),
                         )
+                        self._note_route("decode_window")
                     self._step += K
                     path = "multi"
                     tm.enter("accept")
@@ -3778,6 +3868,7 @@ class ContinuousBatcher:
                             penalties=penalties,
                             pfx=self._split_pfx(active),
                         )
+                        self._note_route("decode_window")
                     self._step += 1
                     # masked single-step crossed every flagged row's
                     # rejected scaffold token

@@ -108,6 +108,11 @@ def load_checkpoint(
             return stack(fmt, transpose)
         return None
 
+    if not mcfg.homogeneous:
+        params = _load_mixed(mcfg, get, dtype)
+        _validate_mixed(params, mcfg)
+        return params
+
     p = "model.layers.{i}."
     layers: Dict[str, Any] = {
         "attn_norm": stack(p + "input_layernorm.weight"),
@@ -209,6 +214,91 @@ def load_checkpoint(
 
     _validate(params, mcfg)
     return params
+
+
+def _load_mixed(mcfg: ModelConfig, get, dtype) -> Dict[str, Any]:
+    """A model whose layers are of several kinds (the ``lfm2_moe``
+    tensor names): each layer's tensors go onto ITS kind's stack, in
+    layer order (models/transformer.py ``_init_mixed_layers``).
+    ``Linear`` weights are transposed to [in, out]; the depthwise conv
+    weight [H, 1, K] becomes [H, K]."""
+    stacks: Dict[str, Dict[str, list]] = {}
+
+    def put(kind: str, name: str, arr: np.ndarray) -> None:
+        stacks.setdefault(kind, {}).setdefault(name, []).append(arr)
+
+    for i, (mixer, ffn) in enumerate(zip(mcfg.mixers, mcfg.ffns)):
+        p = f"model.layers.{i}."
+        if mixer == "conv":
+            put("conv", "attn_norm", get(p + "operator_norm.weight"))
+            put("conv", "w_in", get(p + "conv.in_proj.weight", True))
+            w = get(p + "conv.conv.weight")
+            put("conv", "w_conv", w.reshape(w.shape[0], w.shape[-1]))
+            put("conv", "w_out", get(p + "conv.out_proj.weight", True))
+        else:
+            a = p + "self_attn."
+            put("attn", "attn_norm", get(p + "operator_norm.weight"))
+            put("attn", "wq", get(a + "q_proj.weight", True))
+            put("attn", "wk", get(a + "k_proj.weight", True))
+            put("attn", "wv", get(a + "v_proj.weight", True))
+            put("attn", "wo", get(a + "out_proj.weight", True))
+            put("attn", "q_norm", get(a + "q_layernorm.weight"))
+            put("attn", "k_norm", get(a + "k_layernorm.weight"))
+        f = p + "feed_forward."
+        put(ffn, "mlp_norm", get(p + "ffn_norm.weight"))
+        if ffn == "dense":
+            put("dense", "w_gate", get(f + "w1.weight", True))
+            put("dense", "w_up", get(f + "w3.weight", True))
+            put("dense", "w_down", get(f + "w2.weight", True))
+        else:
+            put("moe", "router", get(f + "gate.weight", True))
+            put("moe", "router_bias", get(f + "expert_bias"))
+            for name, sub in (("we_gate", "w1"), ("we_up", "w3"),
+                              ("we_down", "w2")):
+                put("moe", name, np.stack([
+                    get(f + f"experts.{e}.{sub}.weight", True)
+                    for e in range(mcfg.moe_experts)
+                ]))
+    layers = {
+        kind: {
+            name: jnp.asarray(
+                np.stack(arrs),
+                jnp.float32 if name == "router_bias" else dtype,
+            )
+            for name, arrs in leaves.items()
+        }
+        for kind, leaves in stacks.items()
+    }
+    try:
+        final = get("model.embedding_norm.weight")
+    except KeyError:
+        final = get("model.norm.weight")
+    return {
+        "embed": jnp.asarray(get("model.embed_tokens.weight"), dtype),
+        "final_norm": jnp.asarray(final, dtype),
+        "layers": layers,
+    }
+
+
+def _validate_mixed(params: Dict[str, Any], mcfg: ModelConfig) -> None:
+    """Every leaf's shape against what ``init_params`` would build."""
+    import jax
+
+    from ..models.transformer import init_params
+
+    want = jax.eval_shape(
+        lambda key: init_params(mcfg, key), jax.random.PRNGKey(0)
+    )
+    got_flat = dict(jax.tree_util.tree_leaves_with_path(params))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(want):
+        got = got_flat.get(path)
+        if got is None or tuple(got.shape) != tuple(leaf.shape):
+            raise ValueError(
+                f"Checkpoint shape mismatch at "
+                f"{jax.tree_util.keystr(path)}: got "
+                f"{None if got is None else tuple(got.shape)}, want "
+                f"{tuple(leaf.shape)} for model {mcfg.name}"
+            )
 
 
 def _validate(params: Dict[str, Any], mcfg: ModelConfig) -> None:

@@ -3,8 +3,8 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers all
-four families:
+config-driven decoder-only transformer (models/transformer.py) covers six
+families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
 - Qwen3 MoE (30b-a3b, 235b-a22b): + top-k softmax router, no shared expert
@@ -12,6 +12,10 @@ four families:
 - Gemma 3: GQA + QK-norm, GeGLU-ish gated MLP, pre+post norms, 5:1
   local:global sliding-window attention, embedding scaling
 - gpt-oss (20b/120b): MoE + attention sinks + alternating sliding window
+- LFM2-MoE (24b-a2b): layers of two kinds (``layer_types``): a gated
+  short convolution with K-1 columns of per-sequence state, or GQA
+  attention; leading dense SwiGLU layers, then a sigmoid router with a
+  selection-only bias
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -43,6 +47,26 @@ class ModelConfig:
     moe_intermediate_size: int = 0
     # gpt-oss: router + per-expert projection biases
     moe_bias: bool = False
+    # the first ``num_dense_layers`` layers of a routed model carry a
+    # dense MLP of ``intermediate_size`` instead of experts
+    num_dense_layers: int = 0
+    # The router's form (ops/moe.py ``_route``). "softmax": softmax over
+    # the top-k logits (Qwen3-MoE, gpt-oss). "sigmoid": per-expert
+    # sigmoid scores; the weights are the chosen experts' scores,
+    # divided by their sum (+1e-6) when ``router_renorm``, times
+    # ``router_scale``. ``router_select_bias`` adds a per-expert
+    # parameter (``router_bias`` [E]) to the scores for the top-k
+    # SELECTION only; it never enters the weights.
+    router_score: str = "softmax"
+    router_select_bias: bool = False
+    router_renorm: bool = True
+    router_scale: float = 1.0
+    # Per-layer mixer kinds, "attention" | "conv"; empty => attention
+    # everywhere. A "conv" layer is the gated short convolution of the
+    # LFM2 family: depthwise, causal, ``conv_kernel`` taps, and K-1
+    # columns of per-sequence state beside the paged K/V.
+    layer_types: Tuple[str, ...] = ()
+    conv_kernel: int = 0
     # Sliding window attention: 0 => full attention everywhere.
     sliding_window: int = 0
     # "none" | "alternate" (gpt-oss: even layers sliding) |
@@ -81,6 +105,41 @@ class ModelConfig:
     @property
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        """Each layer's mixer kind, in order."""
+        return self.layer_types or ("attention",) * self.num_layers
+
+    @property
+    def ffns(self) -> Tuple[str, ...]:
+        """Each layer's FFN kind, "dense" | "moe", in order."""
+        if not self.moe_experts:
+            return ("dense",) * self.num_layers
+        d = self.num_dense_layers
+        return ("dense",) * d + ("moe",) * (self.num_layers - d)
+
+    @property
+    def homogeneous(self) -> bool:
+        """Every layer the same block: the parameters are one stack
+        ``params["layers"][name] [L, ...]`` and the walk is one scan."""
+        return len(set(zip(self.mixers, self.ffns))) == 1 and (
+            self.mixers[0] == "attention"
+        )
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers with K/V: what the page pool spans."""
+        return self.mixers.count("attention")
+
+    @property
+    def num_conv_layers(self) -> int:
+        return self.mixers.count("conv")
+
+    @property
+    def conv_state_len(self) -> int:
+        """Columns of conv state a sequence keeps a conv layer (K-1)."""
+        return max(self.conv_kernel - 1, 0) if self.num_conv_layers else 0
 
     def window_for_layer(self, layer: int) -> int:
         """Per-layer attention window (0 = full); SURVEY §5.7 long-context."""
@@ -162,6 +221,33 @@ def _gpt_oss(name: str, h: int, l: int, nh: int, nkv: int,
     )
 
 
+#: LFM2-24B-A2B's published ``layer_types`` (config.json): conv, conv,
+#: then (full_attention, conv, conv, conv) nine times, then
+#: full_attention, conv
+_LFM2_24B_LAYERS: Tuple[str, ...] = (
+    ("conv", "conv") + ("attention", "conv", "conv", "conv") * 9
+    + ("attention", "conv")
+)
+
+
+def _lfm2_moe(name: str, layer_types: Tuple[str, ...], *, h: int = 2048,
+              nh: int = 32, nkv: int = 8, inter: int = 11776,
+              experts: int = 64, top_k: int = 4, moe_inter: int = 1536,
+              dense_layers: int = 2, vocab: int = 65_536,
+              template: str = "chatml") -> ModelConfig:
+    return ModelConfig(
+        name=name, vocab_size=vocab, hidden_size=h,
+        num_layers=len(layer_types), num_heads=nh, num_kv_heads=nkv,
+        head_dim=h // nh, intermediate_size=inter, norm_eps=1e-5,
+        rope_theta=1_000_000.0, qk_norm=True, tie_embeddings=True,
+        moe_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_inter, num_dense_layers=dense_layers,
+        router_score="sigmoid", router_select_bias=True,
+        router_renorm=True, router_scale=1.0,
+        layer_types=layer_types, conv_kernel=3, chat_template=template,
+    )
+
+
 MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # Qwen3 dense
     "qwen3-0.6b": _qwen3("qwen3-0.6b", 1024, 28, 16, 8, 3072),
@@ -183,6 +269,13 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # gpt-oss
     "gpt-oss-20b": _gpt_oss("gpt-oss-20b", 2880, 24, 64, 8, 32, 4, 2880),
     "gpt-oss-120b": _gpt_oss("gpt-oss-120b", 2880, 36, 64, 8, 128, 4, 2880),
+    # LFM2-MoE: as published, and its first ten layers (both dense
+    # layers and two whole periods: what one 16 GB chip holds in bf16
+    # with every expert of its 8 routed layers)
+    "lfm2-24b-a2b": _lfm2_moe("lfm2-24b-a2b", _LFM2_24B_LAYERS),
+    "lfm2-24b-a2b-l10": _lfm2_moe(
+        "lfm2-24b-a2b-l10", _LFM2_24B_LAYERS[:10]
+    ),
     # Embeddings (Qwen3 trunk + last-token-pool head)
     "qwen3-emb-0.6b": _qwen3("qwen3-emb-0.6b", 1024, 28, 16, 8, 3072, head="embedding"),
     "qwen3-emb-6b": _qwen3("qwen3-emb-6b", 4096, 36, 32, 8, 12288, tie=False, head="embedding"),
@@ -206,6 +299,12 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         moe_bias=True,
         attention_sink=True, sliding_window=8, sliding_pattern="alternate",
         tie_embeddings=False, activation="swiglu_oss", chat_template="plain",
+    ),
+    "tiny-lfm2": _lfm2_moe(
+        "tiny-lfm2",
+        ("conv", "conv", "attention", "conv", "conv", "conv"),
+        h=128, nh=4, nkv=2, inter=256, experts=16, top_k=4, moe_inter=64,
+        vocab=512, template="plain",
     ),
     "tiny-emb": ModelConfig(
         name="tiny-emb", vocab_size=512, hidden_size=128, num_layers=2,

@@ -6,26 +6,35 @@ models remotely — SURVEY §0). Design choices are TPU-first:
 - Parameters are plain pytrees (nested dicts of ``jnp`` arrays) with all
   per-layer tensors **stacked on a leading layer axis**, so the layer loop
   is a single ``lax.scan`` (one trace, fast compiles) and shardings can be
-  annotated per-leaf by path rules (parallel/sharding.py).
+  annotated per-leaf by path rules (parallel/sharding.py). A model whose
+  layers are of several kinds (``ModelConfig.layer_types``,
+  ``num_dense_layers``) stacks its parameters PER KIND,
+  ``params["layers"][kind][name] [L_kind, ...]`` for the mixers "attn"
+  and "conv" and the FFNs "dense" and "moe", and walks the config's own
+  list of layers, scanning each repeated group (``_mixed_trunk``).
 - Static shapes everywhere: decode attends over a fixed ``CTX`` window
   gathered from the paged KV cache and masks invalid positions; prefill is
   bucketed by the runner. No data-dependent Python control flow.
 - All matmuls run in ``bfloat16`` on the MXU; softmax/norms accumulate in
   ``float32``.
-- One code path covers Qwen3 (dense+MoE), Llama 3, Gemma 3, and gpt-oss via
-  ``ModelConfig`` flags (QK-norm, sliding windows, attention sinks, post
-  norms, MoE) — see models/configs.py.
+- One code path covers Qwen3 (dense+MoE), Llama 3, Gemma 3, gpt-oss and
+  LFM2-MoE via ``ModelConfig`` fields (QK-norm, sliding windows, attention
+  sinks, post norms, MoE and its router's form, per-layer mixer kinds) —
+  see models/configs.py.
 
-The forward returns the chunk's per-layer K/V; the *caller* (engine/runner)
-scatters them into the paged cache. That keeps this module purely
-functional and cache-layout-agnostic.
+The forward returns the chunk's K/V for each ATTENTION layer and, for a
+model with conv layers, each conv layer's carried state followed by the
+chunk's gated inputs; the *caller* (engine/runner) scatters them into the
+paged cache. That keeps this module purely functional and
+cache-layout-agnostic.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +46,23 @@ from ..ops.attention import chunk_attention
 from ..ops.quant import materialize
 
 Params = Dict[str, Any]
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class MixedChunk:
+    """What ``forward`` hands back in the place of the chunk's K for a
+    model whose layers are of several kinds. It rides where K rides
+    (``logits, hidden, (k, v)``: the runner's entry points and
+    ``kvcache.write_kv`` pass the pair along unopened), so a caller
+    that commits a chunk's K/V commits the conv state with it."""
+
+    k: jax.Array  # [L_attn, B, T, KVH, Dh], or fused [L_attn, B, T, KD]
+    # each conv layer's carried state, then the chunk's gated inputs
+    # g_1..g_T: the state after n <= T tokens is columns n..n+K-2
+    conv: Optional[jax.Array] = None   # [L_conv, B, K-1+T, H]
+    # rows each expert got, every routed layer (padding rows too)
+    route: Optional[jax.Array] = None  # [L_moe, E] int32
 
 
 def _w(lp: Dict[str, Any], name: str, dtype) -> jax.Array:
@@ -73,6 +99,62 @@ def init_params(
     return jax.jit(build, out_shardings=shardings)(key)
 
 
+def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
+    """Per-kind stacks of a model with layers of several kinds: the
+    mixers "attn" [L_attn, ...] and "conv" [L_conv, ...], the FFNs
+    "dense" [L_dense, ...] and "moe" [L_moe, ...]; each mixer stack
+    carries its pre-norm (``attn_norm``), each FFN stack its
+    ``mlp_norm``. The selection bias is drawn small and NON-zero, so
+    that choosing by ``score + bias`` and weighting by ``score`` differ
+    on random weights as they do on trained ones."""
+    H, Dh = cfg.hidden_size, cfg.head_dim
+    NHD, KVD = cfg.q_size, cfg.kv_size
+    La, Lc = cfg.num_attn_layers, cfg.num_conv_layers
+    Ld, Lm = cfg.ffns.count("dense"), cfg.ffns.count("moe")
+    out: Dict[str, Any] = {}
+    if La:
+        out["attn"] = {
+            "attn_norm": jnp.ones((La, H), dtype),
+            "wq": dense((La, H, NHD), H),
+            "wk": dense((La, H, KVD), H),
+            "wv": dense((La, H, KVD), H),
+            "wo": dense((La, NHD, H), NHD),
+        }
+        if cfg.qk_norm:
+            out["attn"]["q_norm"] = jnp.ones((La, Dh), dtype)
+            out["attn"]["k_norm"] = jnp.ones((La, Dh), dtype)
+    if Lc:
+        K = cfg.conv_kernel
+        out["conv"] = {
+            "attn_norm": jnp.ones((Lc, H), dtype),
+            "w_in": dense((Lc, H, 3 * H), H),    # [B | C | z] thirds
+            "w_conv": dense((Lc, H, K), K),      # depthwise taps
+            "w_out": dense((Lc, H, H), H),
+        }
+    if Ld:
+        F = cfg.intermediate_size
+        out["dense"] = {
+            "mlp_norm": jnp.ones((Ld, H), dtype),
+            "w_gate": dense((Ld, H, F), H),
+            "w_up": dense((Ld, H, F), H),
+            "w_down": dense((Ld, F, H), F),
+        }
+    if Lm:
+        E, Fm = cfg.moe_experts, cfg.moe_intermediate_size
+        out["moe"] = {
+            "mlp_norm": jnp.ones((Lm, H), dtype),
+            "router": dense((Lm, H, E), H),
+            "we_gate": dense((Lm, E, H, Fm), H),
+            "we_up": dense((Lm, E, H, Fm), H),
+            "we_down": dense((Lm, E, Fm, H), Fm),
+        }
+        if cfg.router_select_bias:
+            out["moe"]["router_bias"] = (
+                dense((Lm, E), 1) * 0.02
+            ).astype(jnp.float32)
+    return out
+
+
 def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     H, L = cfg.hidden_size, cfg.num_layers
     NHD, KVD = cfg.q_size, cfg.kv_size
@@ -84,6 +166,17 @@ def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             jax.random.normal(next(keys), shape, jnp.float32)
             * (scale_dim ** -0.5)
         ).astype(dtype)
+
+    if not cfg.homogeneous:
+        _check_mixed(cfg)
+        params = {
+            "embed": dense((cfg.vocab_size, H), H),
+            "final_norm": jnp.ones((H,), dtype),
+            "layers": _init_mixed_layers(cfg, dense, dtype),
+        }
+        if not cfg.tie_embeddings and cfg.head == "lm":
+            params["lm_head"] = dense((H, cfg.vocab_size), H)
+        return params
 
     layers: Dict[str, Any] = {
         "attn_norm": jnp.ones((L, H), dtype),
@@ -218,10 +311,41 @@ def apply_rope(
     ).astype(x.dtype)
 
 
+def _router_form(cfg: ModelConfig, lp: Dict[str, Any]) -> Optional[dict]:
+    """``ops/moe._route``'s keywords for this config; None for its
+    default (softmax over the top-k)."""
+    if (cfg.router_score, cfg.router_renorm, cfg.router_scale) == (
+        "softmax", True, 1.0
+    ) and not cfg.router_select_bias:
+        return None
+    return dict(
+        score=cfg.router_score,
+        select_bias=lp["router_bias"] if cfg.router_select_bias else None,
+        renorm=cfg.router_renorm,
+        scale=cfg.router_scale,
+    )
+
+
 def _mlp(
-    cfg: ModelConfig, lp: Dict[str, Any], x: jax.Array, ep_mesh=None
-) -> jax.Array:
-    if cfg.moe_experts:
+    cfg: ModelConfig, lp: Dict[str, Any], x: jax.Array, ep_mesh=None,
+    use_pallas: bool = False, return_counts: bool = False,
+    expert_stacks: Optional[Tuple[Dict[str, Any], jax.Array]] = None,
+):
+    """The layer's FFN over normed ``x``: routed when the layer's
+    parameters hold a router, dense otherwise. ``return_counts`` (routed
+    layers, not under EP) also returns the rows each expert got.
+    ``expert_stacks`` = (every routed layer's ``we_*`` stacked, this
+    layer's index) for a caller that must not slice the experts out
+    (ops/moe.py ``moe_mlp``: ``layer``)."""
+    layer = None
+    if expert_stacks is not None:
+        stacks, layer = expert_stacks
+        if ep_mesh is None:
+            lp = {**lp, **stacks}
+        else:  # the EP path shards one layer's experts: a slice
+            lp = {**lp, **jax.tree_util.tree_map(lambda a: a[layer], stacks)}
+            layer = None
+    if "router" in lp:
         kwargs = dict(
             top_k=cfg.moe_top_k,
             activation=cfg.activation,
@@ -229,6 +353,8 @@ def _mlp(
             bias_gate=lp.get("we_gate_b"),
             bias_up=lp.get("we_up_b"),
             bias_down=lp.get("we_down_b"),
+            route=_router_form(cfg, lp),
+            use_pallas=use_pallas,
         )
         args = (
             x,
@@ -243,8 +369,13 @@ def _mlp(
             # all-gathering them for the ragged grouped GEMM
             from ..ops.moe_ep import moe_mlp_ep
 
-            return moe_mlp_ep(*args, mesh=ep_mesh, **kwargs)
-        return moe_mlp(*args, **kwargs)
+            out = moe_mlp_ep(*args, mesh=ep_mesh, **kwargs)
+            if return_counts:
+                return out, jnp.zeros((lp["router"].shape[-1],), jnp.int32)
+            return out
+        return moe_mlp(
+            *args, return_counts=return_counts, layer=layer, **kwargs
+        )
     gate = x @ _w(lp, "w_gate", x.dtype)
     up = x @ _w(lp, "w_up", x.dtype)
     if cfg.activation == "gelu":
@@ -256,6 +387,95 @@ def _mlp(
     else:
         act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
     return (act * up) @ _w(lp, "w_down", x.dtype)
+
+
+def attention_mixer(
+    cfg: ModelConfig,
+    lp: Dict[str, Any],          # one attention layer's params
+    x: jax.Array,                # [B, T, H], normed
+    *,
+    positions: jax.Array,        # [B, T]
+    valid_len: jax.Array,        # [B]
+    window: jax.Array,           # scalar int32
+    theta: jax.Array,            # scalar fp32 RoPE base
+    k_pages=None, v_pages=None, k_scale=None, v_scale=None,
+    layer=None,                  # this layer's index INTO THE POOL
+    page_table=None, past_len=None, use_pallas: bool = False,
+    ring_mesh=None, wk_l=None, wv_l=None, win_len=None,
+    kv_chunk: int = 1, pfx_groups=None, kernel_mesh=None,
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """GQA attention over the chunk and its paged past, through the
+    output projection: ``(out [B, T, H], (k_chunk, v_chunk))``. The one
+    attention block of every model (``layer_apply`` and the mixed walk
+    both call it)."""
+    B, T = x.shape[:2]
+    q = x @ _w(lp, "wq", x.dtype)
+    k = x @ _w(lp, "wk", x.dtype)
+    v = x @ _w(lp, "wv", x.dtype)
+    if cfg.attn_bias:
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
+    k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps, cfg.norm_zero_centered)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_zero_centered)
+    q = apply_rope(q, positions, theta, cfg)
+    k = apply_rope(k, positions, theta, cfg)
+    sink = lp.get("sink") if cfg.attention_sink else None
+    attn = chunk_attention(
+        q, k, v,
+        positions=positions,
+        valid_len=valid_len,
+        past_k_pages=k_pages, past_v_pages=v_pages, layer=layer,
+        past_k_scale=k_scale, past_v_scale=v_scale,
+        page_table=page_table, past_len=past_len,
+        window=window, sink=sink,
+        use_pallas=use_pallas,
+        ring_mesh=ring_mesh,
+        win_k=wk_l, win_v=wv_l, win_len=win_len,
+        kv_chunk=kv_chunk,
+        pfx_groups=pfx_groups,
+        kernel_mesh=kernel_mesh,
+    )
+    attn = attn.reshape(B, T, cfg.q_size) @ _w(lp, "wo", x.dtype)
+    if cfg.attn_bias:
+        attn = attn + lp["bo"]
+    return attn, (k, v)
+
+
+def conv_mixer(
+    cfg: ModelConfig,
+    lp: Dict[str, Any],          # one conv layer's params
+    x: jax.Array,                # [B, T, H], normed
+    state: jax.Array,            # [B, K-1, H]: g of the K-1 tokens before
+) -> Tuple[jax.Array, jax.Array]:
+    """The gated short convolution of the LFM2 family over a chunk:
+
+        [B | C | z] = x W_in          (thirds, in this order)
+        g_t = B_t * z_t
+        c_t = sum_j w_conv[:, j] * g_{t-(K-1)+j}     causal, depthwise
+        out_t = (C_t * c_t) W_out
+
+    ``state`` holds g of the K-1 positions before the chunk (zeros at a
+    sequence's start). Returns ``(out [B, T, H], g_ext [B, K-1+T, H])``
+    with ``g_ext = [state, g_1..g_T]``: the state after n <= T tokens is
+    ``g_ext[:, n : n+K-1]``, so a caller commits ANY accepted length by
+    a gather. Products in the activation dtype, the K-tap sum in
+    float32. Padding tokens sit after the valid ones and the conv is
+    causal, so they need no mask."""
+    T, H = x.shape[1], x.shape[2]
+    K = cfg.conv_kernel
+    bcz = x @ _w(lp, "w_in", x.dtype)
+    g = bcz[..., :H] * bcz[..., 2 * H:]
+    g_ext = jnp.concatenate([state.astype(g.dtype), g], axis=1)
+    taps = lp["w_conv"].astype(jnp.float32)               # [H, K]
+    c = sum(
+        g_ext[:, j : j + T].astype(jnp.float32) * taps[:, j]
+        for j in range(K)
+    )
+    y = bcz[..., H : 2 * H] * c.astype(x.dtype)
+    return y @ _w(lp, "w_out", x.dtype), g_ext
 
 
 def layer_apply(
@@ -289,44 +509,24 @@ def layer_apply(
     #                                      (ops/attention.py)
     kernel_mesh=None,  # Mesh: Pallas calls shard_map over its "model" axis
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """One decoder block. Shared by the scanned ``forward`` and the
-    pipeline-parallel stage loop (parallel/pipeline.py). Returns
+    """One decoder block of a homogeneous model (attention, then its
+    MLP). Shared by the scanned ``forward`` and the pipeline-parallel
+    stage loop (parallel/pipeline.py). Returns
     ``(h, (k_chunk, v_chunk))``."""
-    B, T = h.shape[:2]
     resid = h
     x = rms_norm(h, lp["attn_norm"], cfg.norm_eps, cfg.norm_zero_centered)
-    q = x @ _w(lp, "wq", x.dtype)
-    k = x @ _w(lp, "wk", x.dtype)
-    v = x @ _w(lp, "wv", x.dtype)
-    if cfg.attn_bias:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
-    k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.qk_norm:
-        q = rms_norm(q, lp["q_norm"], cfg.norm_eps, cfg.norm_zero_centered)
-        k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_zero_centered)
-    q = apply_rope(q, positions, theta, cfg)
-    k = apply_rope(k, positions, theta, cfg)
-    sink = lp.get("sink") if cfg.attention_sink else None
-    attn = chunk_attention(
-        q, k, v,
-        positions=positions,
-        valid_len=valid_len,
-        past_k_pages=k_pages, past_v_pages=v_pages, layer=layer,
-        past_k_scale=k_scale, past_v_scale=v_scale,
+    attn, (k, v) = attention_mixer(
+        cfg, lp, x,
+        positions=positions, valid_len=valid_len,
+        window=window, theta=theta,
+        k_pages=k_pages, v_pages=v_pages,
+        k_scale=k_scale, v_scale=v_scale, layer=layer,
         page_table=page_table, past_len=past_len,
-        window=window, sink=sink,
-        use_pallas=use_pallas,
-        ring_mesh=ring_mesh,
-        win_k=wk_l, win_v=wv_l, win_len=win_len,
-        kv_chunk=kv_chunk,
-        pfx_groups=pfx_groups,
+        use_pallas=use_pallas, ring_mesh=ring_mesh,
+        wk_l=wk_l, wv_l=wv_l, win_len=win_len,
+        kv_chunk=kv_chunk, pfx_groups=pfx_groups,
         kernel_mesh=kernel_mesh,
     )
-    attn = attn.reshape(B, T, cfg.q_size) @ _w(lp, "wo", h.dtype)
-    if cfg.attn_bias:
-        attn = attn + lp["bo"]
     if cfg.post_norms:
         attn = rms_norm(
             attn, lp["post_attn_norm"], cfg.norm_eps, cfg.norm_zero_centered
@@ -334,13 +534,195 @@ def layer_apply(
     h = resid + attn
     resid = h
     x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, cfg.norm_zero_centered)
-    x = _mlp(cfg, lp, x, ep_mesh=ep_mesh)
+    x = _mlp(cfg, lp, x, ep_mesh=ep_mesh, use_pallas=use_pallas)
     if cfg.post_norms:
         x = rms_norm(
             x, lp["post_mlp_norm"], cfg.norm_eps, cfg.norm_zero_centered
         )
     h = resid + x
     return h, (k, v)
+
+
+# ---------------------------------------------------------------------------
+# Layers of several kinds
+# ---------------------------------------------------------------------------
+
+_MIXER_STACK = {"attention": "attn", "conv": "conv"}
+
+
+def _check_mixed(cfg: ModelConfig) -> None:
+    """What the mixed walk does not implement it refuses."""
+    if len(cfg.mixers) != cfg.num_layers:
+        raise ValueError(
+            f"{cfg.name}: layer_types has {len(cfg.mixers)} entries for "
+            f"{cfg.num_layers} layers"
+        )
+    unknown = set(cfg.mixers) - set(_MIXER_STACK)
+    if unknown:
+        raise ValueError(f"{cfg.name}: unknown layer kinds {sorted(unknown)}")
+    if cfg.num_conv_layers and cfg.conv_kernel < 2:
+        raise ValueError(f"{cfg.name}: conv layers need conv_kernel >= 2")
+    unsupported = {
+        "sliding windows": cfg.sliding_pattern != "none",
+        "post norms": cfg.post_norms,
+        "zero-centered norms": cfg.norm_zero_centered,
+        "attention sinks": cfg.attention_sink,
+        "projection biases": cfg.attn_bias or cfg.moe_bias,
+        "rope scaling": bool(cfg.rope_scaling_factor),
+    }
+    bad = [k for k, v in unsupported.items() if v]
+    if bad:
+        raise NotImplementedError(
+            f"{cfg.name}: layers of several kinds with {', '.join(bad)}"
+        )
+
+
+def layer_groups(cfg: ModelConfig) -> List[Tuple[int, int, int]]:
+    """The config's list of layers cut into ``(first layer, period,
+    repeats)`` groups, taken greedily from the front: at each layer the
+    period whose pattern of (mixer, FFN) kinds repeats over the most
+    layers (the shortest on a tie); a layer that starts no repeat is a
+    group of one. The walk scans a group's repeats and unrolls its
+    period, so the program's size follows the number of groups and
+    periods, not the number of layers."""
+    kinds = list(zip(cfg.mixers, cfg.ffns))
+    L = len(kinds)
+    groups, i = [], 0
+    while i < L:
+        best = (1, 1)  # (period, repeats)
+        for p in range(1, (L - i) // 2 + 1):
+            r = 1
+            while kinds[i + r * p : i + (r + 1) * p] == kinds[i : i + p]:
+                r += 1
+            if r >= 2 and p * r > best[0] * best[1]:
+                best = (p, r)
+        groups.append((i, best[0], best[1]))
+        i += best[0] * best[1]
+    return groups
+
+
+def _index_in_kind(kinds) -> List[int]:
+    seen: Dict[str, int] = {}
+    out = []
+    for k in kinds:
+        out.append(seen.get(k, 0))
+        seen[k] = out[-1] + 1
+    return out
+
+
+def _mixed_trunk(
+    cfg: ModelConfig, params: Params, h: jax.Array, *,
+    positions, valid_len, conv_state, k_pages, v_pages, k_scale, v_scale,
+    page_table, past_len, window_past, use_pallas, kv_chunk, ep_mesh,
+    pfx_groups, kernel_mesh,
+):
+    """The walk over a config's own list of layers, parameters stacked
+    per kind. Every stack (and the page pool, the window buffers, the
+    conv state) is a CONSTANT of each group's scan and the layer is an
+    index into it, as the pool is in the homogeneous scan: a stack
+    among the scan's xs would have to be sliced out per group first,
+    which copies it. Returns ``(h, k, v, conv, route)``: K/V stacked
+    over the attention layers, ``g_ext`` over the conv layers, expert
+    row counts over the routed layers (None for a kind with no layer).
+    """
+    _check_mixed(cfg)
+    stacks = params["layers"]
+    B, T = h.shape[:2]
+    K1 = cfg.conv_state_len
+    if cfg.num_conv_layers and conv_state is None:
+        conv_state = jnp.zeros(
+            (cfg.num_conv_layers, B, K1, cfg.hidden_size), h.dtype
+        )
+    win_len = None if window_past is None else window_past[2]
+    window = jnp.int32(0)
+    theta = jnp.float32(cfg.rope_theta)
+    mixers, ffns = cfg.mixers, cfg.ffns
+    mixer_at, ffn_at = _index_in_kind(mixers), _index_in_kind(ffns)
+
+    def take(stack, idx):
+        return jax.tree_util.tree_map(lambda a: a[idx], stack)
+
+    def block(h, mixer, m_idx, ffn, f_idx):
+        lp = take(stacks[_MIXER_STACK[mixer]], m_idx)
+        x = rms_norm(h, lp["attn_norm"], cfg.norm_eps, False)
+        out = {}
+        if mixer == "conv":
+            with jax.named_scope("conv_mixer"):
+                y, out["conv"] = conv_mixer(cfg, lp, x, conv_state[m_idx])
+        else:
+            with jax.named_scope("attn_mixer"):
+                y, (out["k"], out["v"]) = attention_mixer(
+                    cfg, lp, x,
+                    positions=positions, valid_len=valid_len,
+                    window=window, theta=theta,
+                    k_pages=k_pages, v_pages=v_pages,
+                    k_scale=k_scale, v_scale=v_scale, layer=m_idx,
+                    page_table=page_table, past_len=past_len,
+                    use_pallas=use_pallas,
+                    wk_l=None if window_past is None
+                    else window_past[0][m_idx],
+                    wv_l=None if window_past is None
+                    else window_past[1][m_idx],
+                    win_len=win_len, kv_chunk=kv_chunk,
+                    pfx_groups=pfx_groups, kernel_mesh=kernel_mesh,
+                )
+        h = h + y
+        experts = {
+            k: v for k, v in stacks[ffn].items() if k.startswith("we_")
+        }
+        fp = take(
+            {k: v for k, v in stacks[ffn].items() if k not in experts},
+            f_idx,
+        )
+        x = rms_norm(h, fp["mlp_norm"], cfg.norm_eps, False)
+        if ffn == "moe":
+            with jax.named_scope("moe_ffn"):
+                # the experts stay whole stacks: the layer is an index
+                # into them, never a slice (ops/moe.py)
+                y, out["route"] = _mlp(
+                    cfg, fp, x, ep_mesh=ep_mesh, use_pallas=use_pallas,
+                    return_counts=True, expert_stacks=(experts, f_idx),
+                )
+        else:
+            with jax.named_scope("dense_ffn"):
+                y = _mlp(cfg, fp, x)
+        return h + y, out
+
+    outs: Dict[str, list] = {"k": [], "v": [], "conv": [], "route": []}
+    for first, period, repeats in layer_groups(cfg):
+        span = range(first, first + period)
+        per_mixer = {m: [mixers[l] for l in span].count(m) for m in _MIXER_STACK}
+        per_ffn = {f: [ffns[l] for l in span].count(f) for f in ("dense", "moe")}
+
+        def body(h, rep, span=span, per_mixer=per_mixer, per_ffn=per_ffn):
+            ys: Dict[str, list] = {k: [] for k in outs}
+            for l in span:
+                h, out = block(
+                    h,
+                    mixers[l], mixer_at[l] + rep * per_mixer[mixers[l]],
+                    ffns[l], ffn_at[l] + rep * per_ffn[ffns[l]],
+                )
+                for k, val in out.items():
+                    ys[k].append(val)
+            return h, {k: jnp.stack(v) for k, v in ys.items() if v}
+
+        if repeats == 1:
+            h, ys = body(h, 0)
+        else:
+            h, ys = jax.lax.scan(
+                body, h, jnp.arange(repeats, dtype=jnp.int32)
+            )
+            # [repeats, in a period, ...] -> [layers of the kind, ...]
+            ys = {
+                k: v.reshape((-1,) + v.shape[2:]) for k, v in ys.items()
+            }
+        for k, v in ys.items():
+            outs[k].append(v)
+    cat = {
+        k: (jnp.concatenate(v) if len(v) > 1 else v[0]) if v else None
+        for k, v in outs.items()
+    }
+    return h, cat["k"], cat["v"], cat["conv"], cat["route"]
 
 
 def rope_thetas(cfg: ModelConfig) -> jax.Array:
@@ -437,7 +819,8 @@ def forward(
     paged_past: Optional[Tuple[jax.Array, ...]] = None,
     # paged_past: (k_pages, v_pages, page_table), or with an int8 KV
     # cache (k_pages, v_pages, k_scale, v_scale, page_table) — pages
-    # [L, NP, PS, KVH*Dh] (FUSED trailing axis, engine/kvcache.py),
+    # [L, NP, PS, KVH*Dh] (L the ATTENTION layers: every layer of a
+    # homogeneous model; FUSED trailing axis, engine/kvcache.py),
     # per-token scales [L, NP, PS], table [B, MP]. The stacks are
     # CONSTANTS of the layer scan, which carries the layer's index:
     # attention DMAs pool[layer, page] in place (Pallas) or gathers
@@ -465,25 +848,50 @@ def forward(
     # Mesh whose "model" axis the Pallas calls are shard_mapped over
     # (tensor parallelism; ops/lowering.shard_over_model)
     kernel_mesh=None,
-) -> Tuple[jax.Array, jax.Array, Tuple[jax.Array, jax.Array]]:
+    # [L_conv, B, K-1, H]: each conv layer's state before the chunk, for
+    # a model that has such layers (None: every row starts a sequence)
+    conv_state: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array, Tuple[Any, jax.Array]]:
     """Run the trunk over a chunk.
 
     Returns ``(logits_or_emb, final_hidden, (k_chunk, v_chunk))`` where the
-    chunk K/V are stacked ``[L, B, T, KVH, Dh]`` (post-RoPE, ready for cache
-    scatter by the runner).
+    chunk K/V are stacked ``[L_attn, B, T, KVH, Dh]`` over the ATTENTION
+    layers (every layer of a homogeneous model; post-RoPE, ready for
+    cache scatter by the runner). For a model with layers of several
+    kinds ``k_chunk`` is a ``MixedChunk``: K, and with it what commits
+    the conv state and what counts the routing.
     """
     h = embed_tokens(cfg, params, ids)
 
-    windows = jnp.asarray(cfg.window_array(), jnp.int32)  # [L]
-    thetas = rope_thetas(cfg)
-
-    win_len = None if window_past is None else window_past[2]
     k_pages = v_pages = k_scale = v_scale = page_table = None
     if paged_past is not None:
         if len(paged_past) == 5:  # int8 KV
             k_pages, v_pages, k_scale, v_scale, page_table = paged_past
         else:
             k_pages, v_pages, page_table = paged_past
+
+    if not cfg.homogeneous:
+        if ring_mesh is not None:
+            raise NotImplementedError(
+                f"{cfg.name}: ring attention with layers of several kinds"
+            )
+        h, k_all, v_all, conv, route = _mixed_trunk(
+            cfg, params, h,
+            positions=positions, valid_len=valid_len, conv_state=conv_state,
+            k_pages=k_pages, v_pages=v_pages,
+            k_scale=k_scale, v_scale=v_scale,
+            page_table=page_table, past_len=past_len,
+            window_past=window_past, use_pallas=use_pallas,
+            kv_chunk=kv_chunk, ep_mesh=ep_mesh,
+            pfx_groups=pfx_groups, kernel_mesh=kernel_mesh,
+        )
+        out, h = head_apply(cfg, params, h, valid_len, logit_positions)
+        return out, h, (MixedChunk(k=k_all, conv=conv, route=route), v_all)
+
+    windows = jnp.asarray(cfg.window_array(), jnp.int32)  # [L]
+    thetas = rope_thetas(cfg)
+
+    win_len = None if window_past is None else window_past[2]
     layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     xs = (params["layers"], windows, thetas, layers)
     if window_past is not None:

@@ -1,7 +1,8 @@
 """Mixture-of-experts MLP.
 
 Covers Qwen3-MoE (30b-a3b, 235b-a22b) and gpt-oss (20b/120b) from the
-reference catalog (/root/reference/sutro/common.py:28-39). Two execution
+reference catalog (/root/reference/sutro/common.py:28-39), and the
+LFM2-MoE router. Two execution
 paths behind one call:
 
 - ``dense``: computes every expert for every token and combines with the
@@ -11,9 +12,21 @@ paths behind one call:
   grouped GEMMs via ``jax.lax.ragged_dot`` — the MXU-friendly path for
   large E. Static shapes: the expanded token count is exactly ``N * top_k``.
 
-Router convention: softmax over the top-k logits (equivalent to
-renormalized top-k of the full softmax — matches Qwen3's
-``norm_topk_prob=True`` and gpt-oss).
+Router forms (``_route``, one definition for this file and
+ops/moe_ep.py; ``ModelConfig.router_*`` says which):
+
+- ``score="softmax"``: softmax over the top-k logits (equivalent to
+  renormalized top-k of the full softmax — matches Qwen3's
+  ``norm_topk_prob=True`` and gpt-oss); with ``renorm=False`` the top-k
+  of the full softmax, as they are.
+- ``score="sigmoid"``: per-expert sigmoid scores; the top-k is taken on
+  the scores PLUS ``select_bias`` ([E], selection only), the weights
+  are the chosen experts' unbiased scores, divided by their sum + 1e-6
+  when ``renorm``, times ``scale``.
+
+The grouped products are ``jax.lax.ragged_dot`` unless the caller asks
+for the Pallas kernel (``use_pallas``, the engine's switch) and the
+shapes allow it (ops/pallas_gmm.py): one switch for every kernel.
 
 Expert parallelism shards the expert axis of ``we_*`` over the mesh
 "expert" axis; XLA turns the resulting gather/scatter into all-to-alls over
@@ -26,10 +39,14 @@ import jax
 import jax.numpy as jnp
 
 
-def _grouped(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array):
-    """Grouped GEMM: the Pallas MXU kernel on TPU when shapes allow
-    (ops/pallas_gmm.py), ``jax.lax.ragged_dot`` otherwise."""
-    if jax.default_backend() == "tpu":
+def _grouped(
+    lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+    use_pallas: bool = False,
+):
+    """Grouped GEMM: ``jax.lax.ragged_dot``, or the Pallas MXU kernel
+    when the engine runs its kernels, on a TPU, and the shapes allow
+    (ops/pallas_gmm.py)."""
+    if use_pallas and jax.default_backend() == "tpu":
         from .pallas_gmm import grouped_matmul, grouped_matmul_supported
 
         if grouped_matmul_supported(lhs, rhs):
@@ -42,17 +59,43 @@ def _route(
     router: jax.Array,    # [H, E]
     router_b,             # [E] or None
     top_k: int,
+    *,
+    score: str = "softmax",
+    select_bias=None,     # [E] or None: added for the top-k only
+    renorm: bool = True,
+    scale: float = 1.0,
 ):
-    """Shared routing: fp32 logits -> top-k -> renormalized softmax,
-    plus the flattened [N*top_k] expansion (token, expert, prob) used by
-    the grouped-GEMM paths. One definition so the EP path
-    (ops/moe_ep.py) can never diverge from the single-device reference."""
+    """Shared routing: fp32 logits -> top-k -> weights (module
+    docstring: the router's forms), plus the flattened [N*top_k]
+    expansion (token, expert, prob) used by the grouped-GEMM paths. One
+    definition so the EP path (ops/moe_ep.py) can never diverge from
+    the single-device reference."""
     N = xt.shape[0]
     logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)  # [N, E]
     if router_b is not None:
         logits = logits + router_b.astype(jnp.float32)
-    top_logits, top_idx = jax.lax.top_k(logits, top_k)            # [N, K]
-    probs = jax.nn.softmax(top_logits, axis=-1)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        chosen_by = scores
+        if select_bias is not None:
+            chosen_by = scores + select_bias.astype(jnp.float32)
+        _, top_idx = jax.lax.top_k(chosen_by, top_k)              # [N, K]
+        probs = jnp.take_along_axis(scores, top_idx, axis=-1)
+        if renorm:
+            probs = probs / (jnp.sum(probs, axis=-1, keepdims=True) + 1e-6)
+    elif score != "softmax":
+        raise ValueError(f"unknown router score {score!r}")
+    elif select_bias is not None:
+        raise ValueError("a selection bias goes with sigmoid scores")
+    elif renorm:
+        top_logits, top_idx = jax.lax.top_k(logits, top_k)        # [N, K]
+        probs = jax.nn.softmax(top_logits, axis=-1)
+    else:
+        probs, top_idx = jax.lax.top_k(
+            jax.nn.softmax(logits, axis=-1), top_k
+        )
+    if scale != 1.0:
+        probs = probs * scale
     M = N * top_k
     flat_expert = top_idx.reshape(M)
     flat_token = jnp.repeat(jnp.arange(N), top_k)
@@ -87,15 +130,43 @@ def moe_mlp(
     bias_gate: "jax.Array | None" = None,  # [E, F]  (gpt-oss)
     bias_up: "jax.Array | None" = None,    # [E, F]
     bias_down: "jax.Array | None" = None,  # [E, H]
-) -> jax.Array:
+    route: "dict | None" = None,  # ``_route``'s keywords: the router's form
+    use_pallas: bool = False,
+    return_counts: bool = False,
+    layer: "jax.Array | None" = None,
+):
+    """The routed MLP over ``x``. With ``return_counts`` also the rows
+    each expert got, [E] int32 (every row of ``x`` counts, padding
+    too: it is what the grouped products compute).
+
+    With ``layer`` (scalar int32) the ``we_*`` are the STACKS of every
+    routed layer, [L, E, H, F] / [L, E, F, H], and this layer's experts
+    are groups ``layer*E .. (layer+1)*E`` of the stack seen flat,
+    [L*E, ...]: the grouped products take the whole stack with the
+    other layers' groups empty. A ``we[layer]`` slice would reach
+    ``ragged_dot`` as a copy of the layer's experts (1.2 GB a layer at
+    64 x 2048 x 1536 in bf16, every step): the TPU's grouped product
+    reads its right-hand side in place and cannot take a slice fused
+    into it."""
     B, T, H = x.shape
     E = router.shape[-1]
     N = B * T
     xt = x.reshape(N, H)
+    if layer is not None and (method == "dense" or (method == "auto" and E <= 8)
+                              or use_pallas):
+        # small E, or the Pallas kernel (whose padded layout grows with
+        # the group count): one layer's experts, sliced
+        we_gate, we_up, we_down = we_gate[layer], we_up[layer], we_down[layer]
+        layer = None
 
     top_idx, probs, flat_expert, flat_token, flat_prob = _route(
-        xt, router, router_b, top_k
+        xt, router, router_b, top_k, **(route or {})
     )
+
+    def result(out):
+        if not return_counts:
+            return out
+        return out, jnp.bincount(flat_expert, length=E).astype(jnp.int32)
 
     if method == "auto":
         method = "dense" if E <= 8 else "ragged"
@@ -113,7 +184,7 @@ def moe_mlp(
         if bias_down is not None:
             y = y + bias_down[None].astype(y.dtype)
         out = jnp.einsum("ne,neh->nh", gates.astype(y.dtype), y)
-        return out.reshape(B, T, H)
+        return result(out.reshape(B, T, H))
 
     # ragged grouped-GEMM path
     order = jnp.argsort(flat_expert)                      # stable order by expert
@@ -121,17 +192,25 @@ def moe_mlp(
     sorted_token = flat_token[order]
     sorted_prob = flat_prob[order]
     group_sizes = jnp.bincount(sorted_expert, length=E).astype(jnp.int32)
+    if layer is not None:
+        L = we_gate.shape[0]
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((L * E,), jnp.int32), group_sizes, (layer * E,)
+        )
+        we_gate = we_gate.reshape((L * E,) + we_gate.shape[2:])
+        we_up = we_up.reshape((L * E,) + we_up.shape[2:])
+        we_down = we_down.reshape((L * E,) + we_down.shape[2:])
 
     lhs = xt[sorted_token]                                # [M, H]
-    g = _grouped(lhs, we_gate, group_sizes)               # [M, F]
-    u = _grouped(lhs, we_up, group_sizes)
+    g = _grouped(lhs, we_gate, group_sizes, use_pallas)   # [M, F]
+    u = _grouped(lhs, we_up, group_sizes, use_pallas)
     if bias_gate is not None:
         g = g + bias_gate[sorted_expert].astype(g.dtype)
         u = u + bias_up[sorted_expert].astype(u.dtype)
     a, u = _act(g, u, activation)
-    y = _grouped(a * u, we_down, group_sizes)             # [M, H]
+    y = _grouped(a * u, we_down, group_sizes, use_pallas)  # [M, H]
     if bias_down is not None:
         y = y + bias_down[sorted_expert].astype(y.dtype)
     y = y * sorted_prob[:, None].astype(y.dtype)
     out = jnp.zeros((N, H), y.dtype).at[sorted_token].add(y)
-    return out.reshape(B, T, H)
+    return result(out.reshape(B, T, H))

@@ -58,6 +58,8 @@ def moe_mlp_ep(
     bias_gate: Optional[jax.Array] = None,  # [E, F]
     bias_up: Optional[jax.Array] = None,    # [E, F]
     bias_down: Optional[jax.Array] = None,  # [E, H]
+    route: Optional[dict] = None,  # ``_route``'s keywords (ops/moe.py)
+    use_pallas: bool = False,
 ) -> jax.Array:
     B, T, H = x.shape
     E = router.shape[-1]
@@ -74,7 +76,10 @@ def moe_mlp_ep(
     dp = int(mesh.shape.get("data", 1))
     x_spec = P("data", None, None) if B % max(dp, 1) == 0 else P()
 
-    def body(x_s, router, wg, wu, wd, rb, bg, bu, bd):
+    route = dict(route or {})
+    select_bias = route.pop("select_bias", None)
+
+    def body(x_s, router, wg, wu, wd, rb, bg, bu, bd, sb):
         Bl, Tl, _ = x_s.shape
         El = wg.shape[0]
         N = Bl * Tl
@@ -84,7 +89,7 @@ def moe_mlp_ep(
         xt = x_s.reshape(N, H)
 
         _, _, flat_expert, flat_token, flat_prob = _route(
-            xt, router, rb, K
+            xt, router, rb, K, select_bias=sb, **route
         )
         loc = flat_expert - eidx * El                        # local id
         owned = jnp.logical_and(loc >= 0, loc < El)
@@ -104,13 +109,13 @@ def moe_mlp_ep(
         s_eidx = jnp.minimum(s_key, El - 1)                  # bias index
 
         lhs = xt[s_token] * (s_weight > 0)[:, None].astype(xt.dtype)
-        g = _grouped(lhs, wg, group_sizes)                   # [M, F/tp]
-        u = _grouped(lhs, wu, group_sizes)
+        g = _grouped(lhs, wg, group_sizes, use_pallas)       # [M, F/tp]
+        u = _grouped(lhs, wu, group_sizes, use_pallas)
         if bg is not None:
             g = g + bg[s_eidx].astype(g.dtype)
             u = u + bu[s_eidx].astype(u.dtype)
         a, u = _act(g, u, activation)
-        y = _grouped(a * u, wd, group_sizes)                 # [M, H]
+        y = _grouped(a * u, wd, group_sizes, use_pallas)     # [M, H]
         if bd is not None:
             # gate/up biases live on the tp-sharded F axis (distinct
             # slices per shard), but bias_down lands on the unsharded H
@@ -143,11 +148,12 @@ def moe_mlp_ep(
             opt(P("expert", "model"), bias_gate),
             opt(P("expert", "model"), bias_up),
             opt(P("expert", None), bias_down),
+            opt(P(), select_bias),
         ),
         out_specs=x_spec,
         check_vma=False,
     )
     return fn(
         x, router, we_gate, we_up, we_down,
-        router_b, bias_gate, bias_up, bias_down,
+        router_b, bias_gate, bias_up, bias_down, select_bias,
     )

@@ -332,6 +332,29 @@ KV_MIGRATIONS_TOTAL = REGISTRY.counter(
     labels=("dir",),  # demote | promote | disk_write | disk_read
     max_series=8,
 )
+MOE_ROUTED_ROWS_TOTAL = REGISTRY.counter(
+    "sutro_moe_routed_rows_total",
+    "Row-expert pairs the routed layers' grouped products computed, "
+    "summed over routed layers (counted on the device inside each "
+    "dispatch, fetched with its tokens)",
+    unit="rows",
+)
+STATE_COMMITS_TOTAL = REGISTRY.counter(
+    "sutro_state_commits_total",
+    "Dispatches that committed per-sequence conv state beside K/V, by "
+    "the path that committed it",
+    labels=("path",),  # prefill | chunk | window | verify | resume
+    unit="dispatches",
+    max_series=8,
+)
+STATE_FALLBACK_PREFILL_TOKENS_TOTAL = REGISTRY.counter(
+    "sutro_state_fallback_prefill_tokens_total",
+    "Prompt tokens prefilled again because a path could not restore the "
+    "conv state at the position it resumed from",
+    labels=("reason",),  # hibernated_tail_page | tier_payload_without_state
+    unit="tokens",
+    max_series=8,
+)
 KV_RESUMES_TOTAL = REGISTRY.counter(
     "sutro_kv_resumes_total",
     "Preempted-row resumes by mechanism: 'upload' re-admits from a "
