@@ -37,6 +37,7 @@ from ..interfaces import JobStatus
 from ..models.configs import MODEL_CONFIGS, ModelConfig
 from . import faults
 from .config import EngineConfig, load_engine_config
+from .constrain.fsm import FactoryTable, constraint_room
 from .datasets import DatasetStore
 from .jobstore import JobRecord, JobStore, estimate_cost
 from .metrics import MetricsBus, Throughput
@@ -133,6 +134,10 @@ class LocalEngine:
         self._lock = threading.Lock()
         self._runner_cache: Dict[str, Tuple[ModelRunner, BaseTokenizer]] = {}
         self._tok_cache: Dict[str, BaseTokenizer] = {}
+        # one constraint factory per (schema, tokenizer), kept for the
+        # engine's life: the submit probe, the session, the gateway and
+        # the stage graph all ask this table and build nothing themselves
+        self.constraint_factories = FactoryTable()
         # Engine-lifetime radix prefix stores, one per resident runner
         # (engine/prefixstore.py): keep template-shell KV pages warm
         # ACROSS batcher sessions so repeat jobs/requests prefill only
@@ -236,22 +241,24 @@ class LocalEngine:
             # the effective cap is what gets admitted, estimated, and
             # persisted. Schema compile errors surface when the job runs.
             try:
-                from .constrain import schema_constraint_factory
-                from .constrain.fsm import constraint_room
-
+                # asks the engine's table: a schema it has not seen is
+                # built HERE, on the submitting thread (the epsilon
+                # elimination of the schema's NFA for the native core:
+                # seconds for a long counted string), and kept, so the
+                # session that runs the job finds it; a seen one costs
+                # microseconds
                 t_probe = time.monotonic()
-                probe = schema_constraint_factory(
+                factory, how = self.constraint_factories.factory_for(
                     payload["output_schema"],
                     self._get_tokenizer(engine_key, mcfg),
-                )()
+                )
+                probe = factory()
                 if telemetry.ENABLED:
-                    # a whole schema index over the vocabulary, built on
-                    # the SUBMITTING thread: seconds at 151,936 ids
                     dt = time.monotonic() - t_probe
                     telemetry.stage_observe("constraint_prep", dt)
                     telemetry.RECORDER.record(
                         "constraint_prep", None, t_probe, dt,
-                        {"thread": "submit", "scope": "job"},
+                        {"thread": "submit", "scope": "job", "cache": how},
                     )
                 # same room rule the scheduler's truncation reserve uses
                 room = constraint_room(probe)
@@ -2194,29 +2201,31 @@ class _GenSession:
 
         constraint_factory = None
         if rec.output_schema:
-            from .constrain import schema_constraint_factory
             from .profiling import host_leaf
 
-            # the job's schema index over the whole vocabulary: seconds
-            # of pure Python at 151,936 ids, before the job's first row
-            # is admitted. A session's FIRST job builds it on the thread
-            # that then runs the scheduler (``constraint_compile``: the
-            # device waits); an attached job builds it on the attach
-            # thread WHILE the loop runs (``constraint_prep``: it holds
-            # the GIL against the scheduler, whose phases then show
-            # wall far over ``cpu_s``)
+            # the job's factory, from the engine's table: a hit unless
+            # the submit probe could not build it (then the build runs
+            # here and a bad schema fails the job with its own error).
+            # A session's FIRST job asks on the thread that then runs
+            # the scheduler (``constraint_compile``: on a miss the
+            # device waits); an attached job asks on the attach thread
+            # WHILE the loop runs (``constraint_prep``: a miss holds the
+            # GIL against the scheduler, whose phases then show wall far
+            # over ``cpu_s``)
             stage = (
                 "constraint_compile" if on_loop_thread
                 else "constraint_prep"
             )
             with host_leaf(stage):
                 t_fac = time.monotonic()
-                constraint_factory = schema_constraint_factory(
+                constraint_factory, how = eng.constraint_factories.factory_for(
                     rec.output_schema, tok
                 )
                 if self._tel_on:
                     dt = time.monotonic() - t_fac
-                    attrs = {"scope": "job", "rows": len(inputs)}
+                    attrs = {
+                        "scope": "job", "rows": len(inputs), "cache": how,
+                    }
                     if not on_loop_thread:
                         attrs["thread"] = "attach"
                     telemetry.stage_observe(stage, dt)

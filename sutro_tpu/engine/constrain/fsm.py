@@ -5,9 +5,11 @@ protocol): ``allowed_tokens()`` yields a [V] bool mask for the sampling op
 (ops/sampling.py), ``advance(token)`` consumes the sampled token's bytes.
 
 Performance model (SURVEY §7.3 "vectorized constrained decoding"): masks
-are cached per NFA state-set in a job-wide ``MaskCache`` shared by every
-row, so the steady-state cost per decode step is one dict lookup — string
-content, for instance, is a single self-looping state. Computing a mask
+are cached per NFA state-set in an engine-wide ``MaskCache``: one per
+(schema, tokenizer), kept by ``FactoryTable`` across jobs and shared by
+every row of every job on that schema, so the steady-state cost per
+decode step is one dict lookup — string content, for instance, is a
+single self-looping state. Computing a mask
 for a *new* state simulates every vocab token's bytes; the optional C++
 core (native/fsm.cpp, loaded via ctypes in cpp.py) accelerates exactly
 that inner loop, with this pure-Python path as the always-available
@@ -16,11 +18,15 @@ fallback.
 
 from __future__ import annotations
 
+import json
 import logging
-from typing import Dict, FrozenSet, List, Optional
+import threading
+from collections import OrderedDict
+from typing import Dict, FrozenSet, List, Optional, Tuple
 
 import numpy as np
 
+from ... import telemetry
 from .nfa import NFA
 
 logger = logging.getLogger(__name__)
@@ -57,10 +63,12 @@ class TokenTable:
             for tid, tb in enumerate(self.token_bytes):
                 if tb:
                     b2t.setdefault(tb, []).append(tid)
-            self._b2t = b2t
+            # length before dict: the table is shared by every thread
+            # that plans, and one that sees ``_b2t`` must see its length
             self._max_tok_len = max(
                 (len(b) for b in b2t), default=0
             )
+            self._b2t = b2t
         for ln in range(
             min(self._max_tok_len, len(data) - start), 0, -1
         ):
@@ -70,12 +78,24 @@ class TokenTable:
                     yield tid, ln
 
 
+def token_table(tokenizer) -> TokenTable:
+    """The tokenizer's ONE ``TokenTable``, built on first ask and hung
+    on the tokenizer itself: every schema on that tokenizer reads the
+    same byte strings. (A lost race builds a second, equal table.)"""
+    table = getattr(tokenizer, "_sutro_token_table", None)
+    if table is None:
+        table = tokenizer._sutro_token_table = TokenTable(tokenizer)
+    return table
+
+
 INF_DIST = np.int32(0x7FFFFFFF)
 
 
 class MaskCache:
     """state-set -> (vocab mask, per-token post-walk byte distance to
-    accept), shared across all rows of a job. The distance array is what
+    accept), shared across all rows of every job on one (schema,
+    tokenizer). The arrays handed out are the cached ones, read-only: a
+    caller that wants to change one copies it. The distance array is what
     makes budget-aware decoding O(V) per step: the scheduler ANDs the
     cached mask with ``dist_after <= remaining - 1`` instead of ever
     re-walking tokens."""
@@ -121,6 +141,8 @@ class MaskCache:
             for sid in self.table.stop_ids:
                 m[sid] = True
                 dist[sid] = 0
+        m.setflags(write=False)
+        dist.setflags(write=False)
         self._cache[states] = (m, dist)
         return m, dist
 
@@ -314,7 +336,9 @@ class ConstraintFactory:
         from .schema import compile_schema
 
         self.nfa = compile_schema(schema)
-        self.table = TokenTable(tokenizer)
+        # held: a ``FactoryTable`` key is this instance's ``id``
+        self.tokenizer = tokenizer
+        self.table = token_table(tokenizer)
         self.masks = MaskCache(self.nfa, self.table)
 
     def __call__(self) -> TokenFSM:
@@ -322,7 +346,90 @@ class ConstraintFactory:
 
 
 def schema_constraint_factory(schema: Dict, tokenizer) -> ConstraintFactory:
+    """A factory built from scratch: the epsilon elimination of the
+    schema's NFA for the native core is seconds for a schema with a long
+    counted string. The engine asks its ``FactoryTable`` instead."""
     return ConstraintFactory(schema, tokenizer)
+
+
+class FactoryTable:
+    """One ``ConstraintFactory`` per (schema, tokenizer), built at most
+    once and kept: the engine owns one table, and the submit probe, the
+    session, the gateway's constrained chat and the stage graph all ask
+    it. ``factory_for`` answers ``(factory, how)``: ``"hit"`` (kept), ``"miss"``
+    (this call built it) or ``"wait"`` (another thread's build of the
+    same key was in flight and this call waited for it).
+
+    Sharing one factory between rows, jobs and threads is safe because
+    nothing a row does writes to it: ``ConstraintFactory.__call__`` makes
+    a fresh ``TokenFSM`` a row and all row state (``states``,
+    ``_complete``) lives there; ``MaskCache._cache`` only grows, a state
+    set's value is a pure function of (nfa, table), so two threads that
+    compute one entry store equal arrays, and the arrays are read-only
+    (``allowed_tokens`` builds ``fits`` fresh, the scheduler copies a
+    mask into its own ``[B, V]`` array); the native core takes
+    ``const FsmCore*`` in ``fsm_mask`` and ``fsm_advance``.
+
+    The key is the schema's canonical text (``sort_keys``: the caller's
+    key order does not matter; a field the compiler ignores is at worst
+    a miss) and the tokenizer INSTANCE, which the factory holds so that
+    its ``id`` is never reused while the entry lives. Least recently
+    used out first; a factory in use by a running job outlives its
+    entry. A build that raises leaves nothing behind: the next ask
+    builds again and fails with its own error."""
+
+    # Entries kept. A factory is its native core (36 B a lifted edge:
+    # 115 MB for the classify template's 3.19 M) plus 5 B a vocabulary
+    # id for each state set any row has visited (760 KB at 151,936 ids;
+    # some hundreds of sets a job through a 400-character scratchpad),
+    # so four of that size are 1-2 GB of host memory. A pipeline's
+    # classify, score and rank templates and one schema of its own fit.
+    MAX_ENTRIES = 4
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._entries: "OrderedDict[Tuple[str, int], ConstraintFactory]" = (
+            OrderedDict()
+        )
+        self._building: Dict[Tuple[str, int], threading.Event] = {}
+
+    def factory_for(
+        self, schema: Dict, tokenizer
+    ) -> Tuple[ConstraintFactory, str]:
+        key = (json.dumps(schema, sort_keys=True), id(tokenizer))
+        how = "hit"
+        while True:
+            with self._lock:
+                factory = self._entries.get(key)
+                if factory is not None:
+                    self._entries.move_to_end(key)
+                    break
+                flight = self._building.get(key)
+                if flight is None:
+                    flight = self._building[key] = threading.Event()
+                    how = "miss"
+                    break
+            # single flight: wait for the one build; if it raised, the
+            # loop finds neither entry nor flight and this call builds
+            flight.wait()
+            how = "wait"
+        if how == "miss":
+            try:
+                factory = schema_constraint_factory(schema, tokenizer)
+                with self._lock:
+                    self._entries[key] = factory
+                    if len(self._entries) > self.MAX_ENTRIES:
+                        self._entries.popitem(last=False)
+            finally:
+                with self._lock:
+                    del self._building[key]
+                flight.set()
+        if telemetry.ENABLED:
+            telemetry.CONSTRAINT_FACTORY_TOTAL.inc(1.0, how)
+        return factory, how
+
+    def __len__(self) -> int:
+        return len(self._entries)
 
 
 # constraint type names whose missing-min_tokens warning already fired

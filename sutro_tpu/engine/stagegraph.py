@@ -945,9 +945,7 @@ class StageGraphRunner:
         )
         st.constraint_factory = None
         if st.rec.output_schema:
-            from .constrain import schema_constraint_factory
-
-            st.constraint_factory = schema_constraint_factory(
+            st.constraint_factory, _ = eng.constraint_factories.factory_for(
                 st.rec.output_schema, tok
             )
         # resumed rows: already durable — never re-fed, and their

@@ -246,11 +246,10 @@ class InteractiveGateway:
         max_new = int(sreq.max_tokens or ecfg.max_new_tokens)
         constraint_factory = None
         if sreq.output_schema:
-            from ..engine.constrain import schema_constraint_factory
             from ..engine.constrain.fsm import constraint_room
 
             try:
-                constraint_factory = schema_constraint_factory(
+                constraint_factory, _ = self.eng.constraint_factories.factory_for(
                     sreq.output_schema, tok
                 )
                 # same feasibility raise the batch submit path applies:

@@ -355,6 +355,15 @@ STATE_FALLBACK_PREFILL_TOKENS_TOTAL = REGISTRY.counter(
     unit="tokens",
     max_series=8,
 )
+CONSTRAINT_FACTORY_TOTAL = REGISTRY.counter(
+    "sutro_constraint_factory_total",
+    "Asks of the engine's constraint-factory table (one factory per "
+    "(schema, tokenizer), kept across jobs): hit = kept, miss = this "
+    "ask built it, wait = served by another thread's build in flight",
+    labels=("result",),  # hit | miss | wait
+    unit="lookups",
+    max_series=4,
+)
 KV_RESUMES_TOTAL = REGISTRY.counter(
     "sutro_kv_resumes_total",
     "Preempted-row resumes by mechanism: 'upload' re-admits from a "
