@@ -141,10 +141,6 @@ def main() -> None:
     # candidate here before flipping the engine-wide default
     if os.environ.get("SUTRO_E2E_MULTI"):
         ecfg["decode_multi_step"] = int(os.environ["SUTRO_E2E_MULTI"])
-    # n-gram speculative decoding A/B (greedy workloads; scheduler
-    # path, so the A/B belongs here rather than bench.py's raw loop)
-    if os.environ.get("SUTRO_E2E_SPEC"):
-        ecfg["spec_ngram_draft"] = int(os.environ["SUTRO_E2E_SPEC"])
     # Hydragen-style split decode over the job's shared prefix A/B
     # (Pallas path only; templated workloads here all share a system
     # prompt, which is exactly the case it accelerates)
@@ -166,10 +162,7 @@ def main() -> None:
     # spurious config-identical "classify+t0" duplicate.
     def ab_for(workload: str) -> str:
         decode = workload in ("classify", "generate", "longgen")
-        greedy_unconstrained = workload in ("generate", "longgen")
         ab = ""
-        if os.environ.get("SUTRO_E2E_SPEC") and greedy_unconstrained:
-            ab += f"+spec{int(os.environ['SUTRO_E2E_SPEC'])}"
         if os.environ.get("SUTRO_PREFIX_SPLIT") == "1" and decode:
             ab += "+psplit"
         if os.environ.get("SUTRO_E2E_FF") and workload == "classify":
@@ -333,10 +326,8 @@ def main() -> None:
     # -- generate (unconstrained, fused multi-step decode) --------------
     if "generate" in workloads:
         t0 = time.monotonic()
-        # SUTRO_E2E_GEN_TEMP=0 makes the batch all-greedy — REQUIRED
-        # for the n-gram spec-decode A/B (the spec gate sits out for
-        # sampled or constrained rows, so classify legs can't measure
-        # it); default keeps the engine's sampled path
+        # SUTRO_E2E_GEN_TEMP=0 makes the batch all-greedy; default
+        # keeps the engine's sampled path
         gen_sp = {}
         if os.environ.get("SUTRO_E2E_GEN_TEMP"):
             gen_sp = {

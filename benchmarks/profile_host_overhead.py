@@ -3,8 +3,8 @@ STUB runner (no device, no compiles — pure Python/numpy bookkeeping).
 
 Why it matters: a fused B=64 window computed in ~10.9 ms per step on a
 v5e (qwen3-0.6b, 2026-07; PERF.md). The scheduler's host work
-between dispatches — admission checks, stop-sequence scans, n-gram
-bookkeeping, result assembly — happens on the critical path whenever
+between dispatches — admission checks, stop-sequence scans, result
+assembly — happens on the critical path whenever
 the pipeline is not deep enough to hide it. This profile isolates that
 cost per (window, batch) so regressions in host bookkeeping are
 visible without chip access, and the number slots directly into the
@@ -149,12 +149,6 @@ class _StubRunner:
                 ct[b, L] = cand[b, L, 0]  # boundary: 1st admitted
         zeros = np.zeros((B, K + 1), np.float32)
         return ct, zeros, ct.copy(), zeros.copy()
-
-    def verify_greedy(self, last, drafts, dlens, past_len, table):
-        B, K = drafts.shape
-        ct = np.zeros((B, K + 1), np.int32)
-        ct[:, :K] = drafts
-        return ct, np.zeros((B, K + 1), np.float32)
 
 
 def mk_ecfg(B):

@@ -8,9 +8,6 @@ prompt, so the engine:
 - optionally computes the prefix's DECODE attention once per step for
   the whole batch (prefix_split, Hydragen-style carry injection —
   Pallas path, chip-A/B gated);
-- optionally speculates greedy rows from their own prompt/output
-  n-grams (spec_ngram_draft — exact for greedy, acceptance-rate
-  metrics in the job perf record);
 - stores the KV cache int8 with per-token scales (kv_quantize) for
   2x page capacity / half the decode HBM traffic;
 - co-batches a small interactive job into the SAME decode batch
@@ -26,7 +23,6 @@ def main() -> None:
     so, model, _ = example_client(
         __doc__,
         engine_config=dict(
-            spec_ngram_draft=6,      # n-gram speculative decoding
             kv_quantize="int8",      # int8 KV cache
             # prefix_split=True,     # flip after the chip A/B
         ),
@@ -51,10 +47,6 @@ def main() -> None:
         job_priority=0,
     )
     print(so.await_job_completion(jid))
-    rec = so.engine.get_job(jid)
-    spec = (rec.get("perf") or {}).get("spec_ngram")
-    if spec:
-        print("speculation acceptance:", spec)
 
 
 if __name__ == "__main__":

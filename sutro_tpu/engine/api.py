@@ -2566,15 +2566,6 @@ class _GenSession:
         )
         output_tokens = counted["output_tokens"]
         perf = dict(batcher.timer.summary())
-        drafted = self.ctx.stats.get("spec_drafted", 0)
-        if drafted:
-            # n-gram speculative acceptance rate (the VERDICT's metric)
-            accepted = self.ctx.stats.get("spec_accepted", 0)
-            perf["spec_ngram"] = {
-                "drafted": drafted,
-                "accepted": accepted,
-                "acceptance_rate": round(accepted / drafted, 3),
-            }
         ff = self.ctx.stats.get("ff_forced", 0)
         if ff:
             # FSM fast-forward: scaffold tokens committed through

@@ -72,7 +72,8 @@ class EngineConfig:
     max_model_len: int = 8192
     decode_multi_step: int = 8      # decode steps fused into one device
                                     # program when no row needs host-side
-                                    # FSM masks/seeds (runner.decode_multi);
+                                    # FSM masks/seeds
+                                    # (runner.decode_multi_async);
                                     # amortizes dispatch+fetch latency.
                                     # NOTE: a lockstep runner loop
                                     # measured 16 fastest on qwen3-0.6b
@@ -84,25 +85,13 @@ class EngineConfig:
     decode_lookahead: int = 2       # fused windows in flight at once on the
                                     # unconstrained decode path: window k+1
                                     # chains off window k's device-resident
-                                    # tokens, so the host<->device round
-                                    # trip is hidden behind device compute
-                                    # (scheduler pipelined windows); 1 =
-                                    # synchronous (process before dispatch)
-    spec_ngram_draft: int = 0       # >0 enables prompt-lookup (n-gram)
-                                    # speculative decoding for plain
-                                    # GREEDY unconstrained rows: draft up
-                                    # to this many tokens from the row's
-                                    # own prompt/output history and
-                                    # verify them in ONE parallel forward
-                                    # (classify rationales echo prompt
-                                    # text heavily). Exact for greedy.
-                                    # Default OFF: the verify path is
-                                    # host-synchronous; the default
-                                    # assumes a host<->device round
-                                    # trip of ~135 ms, under which the
-                                    # pipelined fused windows win
-                                    # unless acceptance is high —
-                                    # re-measure (ROADMAP 1.6)
+                                    # tokens, so the device runs it while
+                                    # the host accepts window k (the host
+                                    # is 2-4 % of a generate window on a
+                                    # host that holds the chip: PERF.md
+                                    # §5); 1 = the same path at a depth of
+                                    # one (dispatch, fetch and accept in
+                                    # one iteration)
     constrain_fastforward: int = 16  # FSM fast-forward ("jump
                                     # decoding") width: when a schema's
                                     # FSM forces exactly one next token

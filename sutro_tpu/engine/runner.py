@@ -1140,11 +1140,11 @@ class ModelRunner:
         """Like ``decode_multi`` but returns DEVICE arrays without
         blocking: dispatch is async, so callers can chain the next
         window off ``toks[-1]`` (still on device) before this window's
-        results ever cross the host link. That hides the host<->device
-        round trip. The design assumes a round trip of ~135 ms against
-        ~16 ms of device compute per step (qwen3-0.6b, 2026-07); on a
-        host that holds the chip itself it is far smaller — re-measure,
-        ROADMAP 1.6."""
+        results are fetched, and the device runs that window while the
+        host accepts this one. On a host that holds the chip the host's
+        share is small (2-4 % of a generate window, PERF.md §5: ledger
+        PR 25, 29); what the chaining buys is that the device never
+        waits for it."""
         if faults.ACTIVE is not None:
             faults.inject("runner.decode")
         B = past_len.shape[0]
@@ -1170,16 +1170,15 @@ class ModelRunner:
         return toks, logps
 
     # ------------------------------------------------------------------
-    # n-gram speculative verification (greedy prompt-lookup decoding)
+    # masked-candidate verification (FSM fast-forward)
     # ------------------------------------------------------------------
 
     def _verify_forward(
         self, params, cache: KVCache, ids, valid_len, page_table, start
     ):
-        """Shared verify trunk: one forward over [B, C] known tokens
+        """The verify trunk: one forward over [B, C] known tokens
         against the paged past, K/V written for the inputs, plus the
-        plain greedy choice per position. Both verify jits build on
-        this so the dispatch wiring cannot drift between them."""
+        plain greedy choice per position."""
         C = ids.shape[1]
         positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
         logits, _, (k, v) = transformer.forward(
@@ -1259,8 +1258,7 @@ class ModelRunner:
         """Returns (cand_toks, cand_logps, plain_toks, plain_logps),
         each [B, K+1]. Input row b is ``[last, d0..d_{L-1}]`` with
         valid_len L+1 (K/V written for inputs; an accepted output
-        token's K/V is written by the next dispatch that consumes it,
-        as in verify_greedy)."""
+        token's K/V is written by the next dispatch that consumes it)."""
         B, K = drafts.shape
         ids = np.zeros((B, K + 1), np.int32)
         ids[:, 0] = last_tokens
@@ -1281,51 +1279,6 @@ class ModelRunner:
             np.asarray(pt), np.asarray(pl),
         )
 
-    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-    def _verify_jit(
-        self, params, cache: KVCache, ids, valid_len, page_table, start
-    ):
-        """One parallel forward over ``[B, 1+K]`` tokens (each row's
-        last token + its n-gram draft) against the paged past: returns
-        the per-position GREEDY tokens and their logprobs. Device-side
-        argmax keeps the [B, C, V] logits tensor off the host link.
-        All input positions' K/V are written to pages — rejected
-        positions become dead stores beyond the row's accepted ``pos``
-        (masked by past_len, overwritten as decode proceeds)."""
-        _, toks, logp, cache, pending = self._verify_forward(
-            params, cache, ids, valid_len, page_table, start
-        )
-        return toks, logp, cache, pending
-
-    def verify_greedy(
-        self,
-        last_tokens: np.ndarray,   # [B] int32
-        drafts: np.ndarray,        # [B, K] int32 (pad anything)
-        draft_len: np.ndarray,     # [B] int32 — valid draft tokens
-        past_len: np.ndarray,      # [B] int32
-        page_table: np.ndarray,    # [B, MP] int32
-    ):
-        """Greedy verification dispatch: row b's inputs are
-        ``[last, d0..d_{L-1}]`` (L = draft_len[b]); position t's output
-        is the model's next token AFTER input t. The scheduler accepts
-        the longest matching draft prefix plus the standard bonus token
-        at the first mismatch. Rows with draft_len 0 just take a plain
-        greedy step (their padding positions carry valid_len)."""
-        B, K = drafts.shape
-        ids = np.zeros((B, K + 1), np.int32)
-        ids[:, 0] = last_tokens
-        ids[:, 1:] = drafts
-        toks, logp, self.cache, pending = self._verify_jit(
-            self.params,
-            self.cache,
-            jnp.asarray(ids),
-            jnp.asarray(draft_len + 1, jnp.int32),
-            jnp.asarray(page_table, jnp.int32),
-            jnp.asarray(past_len, jnp.int32),
-        )
-        self._hold_verified(pending, page_table, past_len)
-        return np.asarray(toks), np.asarray(logp)
-
     def _hold_verified(self, pending, page_table, past_len) -> None:
         """Keep a verify dispatch's conv inputs until the caller has
         decided each row's accepted length."""
@@ -1341,9 +1294,8 @@ class ModelRunner:
                         page_table, start, n)
 
     def commit_verified(self, accepted: np.ndarray) -> None:
-        """After ``verify_candidates`` / ``verify_greedy`` on a model
-        that keeps conv state (``has_state``): commit each row's state
-        after its first ``accepted[b]`` INPUT tokens (0: the row keeps
+        """After ``verify_candidates`` on a model that keeps conv state
+        (``has_state``): commit each row's state after its first ``accepted[b]`` INPUT tokens (0: the row keeps
         the state it had). One gather and scatter, no second forward. A
         model without such state needs no call, and a call is a no-op."""
         held, self._verified = getattr(self, "_verified", None), None

@@ -30,7 +30,9 @@ import dataclasses
 import inspect
 import logging
 import time
-from typing import Any, Callable, Dict, List, Optional, Protocol, Tuple
+from typing import (
+    Any, Callable, Dict, List, NamedTuple, Optional, Protocol, Tuple,
+)
 
 import jax
 import numpy as np
@@ -280,7 +282,7 @@ class JobCtx:
     # Interactive serving tier (serving/gateway.py): ``on_token`` streams
     # every accepted token to the request's channel the moment the single
     # commit point (_accept_token) records it — all decode paths (single
-    # step, fused windows, speculative verify, fast-forward) converge
+    # step, fused windows, speculative window, fast-forward) converge
     # there, so per-token streaming needs exactly one hook. ``interactive``
     # marks the ctx as a latency-priority request that may preempt batch
     # rows inside the EngineConfig.interactive_slots budget.
@@ -348,12 +350,6 @@ class _Slot:
     prefilling: bool = False
     prefill_pos: int = 0     # next global position to prefill
     ptable: Optional[np.ndarray] = None  # real [MP] table for chunks
-    # n-gram speculative draft state (built lazily at the first draft
-    # lookup, maintained incrementally per accepted token): the full
-    # token history and a bigram -> (last, previous) occurrence index,
-    # so per-step draft lookups are O(K), not O(seq_len)
-    hist: Optional[List[int]] = None
-    bigram_idx: Optional[Dict[Tuple[int, int], Tuple[int, Optional[int]]]] = None
     out_ids: List[int] = dataclasses.field(default_factory=list)
     logprob_sum: float = 0.0
     # rolling decoded-byte tail for stop-sequence detection (window =
@@ -398,6 +394,36 @@ class _Hib:
     n_pages: int             # own aligned pages stored under ``key``
 
 
+class _DecodeFacts(NamedTuple):
+    """What the rows of one decode batch need from the step, seen once
+    where ``batch_build`` walks them. ``_choose_path`` reads these and
+    nothing else."""
+    has_constraint: bool      # a row decodes under an FSM
+    has_row_seed: bool        # a row's stream must reproduce by itself
+    has_penalty: bool         # a row's logits depend on its own counts
+    all_greedy: bool          # every row at temperature <= 0
+    constrained_greedy: bool  # every constrained row at temperature <= 0
+    flagged: bool             # a slot owes its FSM-masked step (_needs_mask)
+    room: int                 # least reserved page room of a row, in steps
+    wrapped: bool             # the runner has a ring or pipeline wrapper
+    #                           (sp or pp > 1): no verify forward there
+
+
+class _DecodeBatch(NamedTuple):
+    """One iteration's decode batch: the active slots, the dense [B]
+    operands every runner entry point takes, and the facts that choose
+    the path."""
+    active: List[int]
+    last: np.ndarray          # [B] int32
+    past_len: np.ndarray      # [B] int32
+    table: np.ndarray         # [B, MP] int32
+    temp: np.ndarray          # [B] float32
+    top_p: np.ndarray         # [B] float32
+    top_k: np.ndarray         # [B] int32
+    row_seeds: np.ndarray     # [B] int32
+    facts: _DecodeFacts
+
+
 class ContinuousBatcher:
     def __init__(
         self,
@@ -421,6 +447,11 @@ class ContinuousBatcher:
         self.token_bytes = token_bytes
         self.B = self.ecfg.decode_batch_size
         self.MP = self.ecfg.max_pages_per_seq
+        # the runner has a ring (sp) or pipeline (pp) wrapper: the paged
+        # prefill from start > 0 and the verify forward have none
+        self._wrapped = (
+            getattr(runner, "sp", 1) != 1 or getattr(runner, "pp", 1) != 1
+        )
         # hot-loop caches: max_context() and the stop-id membership are
         # consulted per accepted token (O(B*K) per window)
         self._max_ctx = self.ecfg.max_context()
@@ -514,10 +545,6 @@ class ContinuousBatcher:
         self._needs_mask: set = set()
         # penalty id-buffer growth events already logged (power-of-two K)
         self._pk_grown: set = set()
-        # n-gram speculative decoding acceptance counters (greedy
-        # prompt-lookup path; rate = accepted / drafted)
-        self.spec_drafted = 0
-        self.spec_accepted = 0
         # FSM fast-forward ("jump decoding"): forced scaffold tokens
         # committed through parallel verify forwards instead of
         # step-by-step windows. The probe backoff bounds the O(B x V)
@@ -525,24 +552,6 @@ class ContinuousBatcher:
         self.ff_forced = 0
         self._ff_probe_step = 0
         self._ff_backoff = 0
-        # next step at which the n-gram speculative path may probe;
-        # bumped with exponential backoff on failed probes / poor
-        # acceptance so the pipelined windows keep RTT hidden between
-        # attempts
-        self._spec_probe_step = 0
-        self._spec_backoff = 0
-        # coverage pre-check result, computed ONCE per probe epoch
-        # (keyed by _spec_probe_step): while a probe waits for the pipe
-        # to drain, recomputing O(B*K) throwaway drafts every drain
-        # iteration would repeat up to decode_lookahead times per probe
-        self._spec_cov_key = -1
-        self._spec_cov_ok = False
-        # rolling acceptance window: engagement is decided by draft
-        # COVERAGE, but staying engaged requires the accepted tokens to
-        # actually beat a plain step (exit when the window's acceptance
-        # rate drops below 1/SN, i.e. < ~1 extra token per row-step)
-        self._spec_win_drafted = 0
-        self._spec_win_accepted = 0
         # shared-prefix KV reuse (one per run; see _setup_prefix)
         self._prefix: Optional[_SharedPrefix] = None
         # preemptive priority ladder (engine/control.py): installed by
@@ -688,10 +697,7 @@ class ContinuousBatcher:
             return
         if len(pending) < (1 if store is not None else 2):
             return
-        if (
-            getattr(self.runner, "sp", 1) > 1
-            or getattr(self.runner, "pp", 1) > 1
-        ):
+        if self._wrapped:
             return
         PS = ecfg.kv_page_size
         first = pending[0].prompt_ids
@@ -1371,53 +1377,6 @@ class ContinuousBatcher:
         self._record_token(s, first, float(logps[0]))
         self._deliver_token(s, first, float(logps[0]))
 
-    @staticmethod
-    def _hist_push(s: _Slot, tok: int) -> None:
-        """Append one token to the slot's draft history, updating the
-        bigram occurrence index — (last, previous) per bigram, so the
-        lookup can skip the terminal pair itself. O(1) per token."""
-        h = s.hist
-        if h:
-            key = (h[-1], tok)
-            cur = s.bigram_idx.get(key)
-            s.bigram_idx[key] = (
-                len(h) - 1,
-                cur[0] if cur is not None else None,
-            )
-        h.append(tok)
-
-    def _ngram_draft(self, s: _Slot, K: int) -> Optional[np.ndarray]:
-        """Prompt-lookup draft for a greedy row: find the most recent
-        PRIOR occurrence of the sequence's last bigram in its own
-        prompt+output history and propose the tokens that followed it
-        (classify rationales echo prompt text heavily — the VERDICT's
-        observation). Capped so the verify dispatch's K/V writes stay
-        inside the row's reserved pages. None = no draft this step.
-        The history + bigram index build once per row and extend
-        incrementally (_record_token), so this is O(K) per step."""
-        cap = len(s.pages) * self.ecfg.kv_page_size - s.pos - 1
-        K = min(K, cap)
-        if K < 1:
-            return None
-        if s.hist is None:
-            s.hist = []
-            s.bigram_idx = {}
-            for t in list(s.req.prompt_ids) + list(s.out_ids):
-                self._hist_push(s, int(t))
-        h = s.hist
-        if len(h) < 3:
-            return None
-        cur = s.bigram_idx.get((h[-2], h[-1]))
-        if cur is None:
-            return None
-        j = cur[0]
-        if j == len(h) - 2:  # the terminal pair itself: use the prior
-            j = cur[1]
-            if j is None:
-                return None
-        d = h[j + 2 : j + 2 + K]
-        return np.asarray(d, np.int32) if d else None
-
     def _fastforward_step(self, active, last, past_len, table) -> bool:
         """FSM fast-forward ("jump decoding") via masked-candidate
         verification: each constrained row PLANS a jump along its
@@ -1486,7 +1445,7 @@ class ContinuousBatcher:
         self._ff_backoff = 0
         # static shapes: pad to the configured width regardless of this
         # step's plans — a data-dependent K would retrace the verify
-        # program per distinct length (the n-gram path pads the same way)
+        # program per distinct length
         K = FF
         C = K + 1
         drafts = np.zeros((self.B, K), np.int32)
@@ -1500,22 +1459,6 @@ class ContinuousBatcher:
             for j, cs in enumerate(cands):
                 cand[i, j, : len(cs)] = cs
                 cand_n[i, j] = len(cs)
-        # unconstrained greedy riders carry their own n-gram drafts
-        # when speculation is opted in (spec_ngram_draft > 0): verified
-        # against the PLAIN greedy outputs with the spec accept rule,
-        # they take up to K+1 tokens from the dispatch instead of 1
-        spec_riders = set()
-        SN = getattr(self.ecfg, "spec_ngram_draft", 0)
-        if SN > 0:
-            for i in active:
-                if i in plans or self.slots[i].req.constraint is not None:
-                    continue
-                d = self._ngram_draft(self.slots[i], min(SN, K))
-                if d is None:
-                    continue
-                spec_riders.add(i)
-                drafts[i, : len(d)] = d
-                dlens[i] = len(d)
         with self.timer.time("decode"):
             ct, cl, pt, pl = self.runner.verify_candidates(
                 np.asarray(last, np.int32), drafts, dlens,
@@ -1552,13 +1495,6 @@ class ContinuousBatcher:
                         ctx.stats.get("ff_forced", 0) + jumped
                     )
                 continue
-            if i in spec_riders:
-                # n-gram draft verified against the plain greedy
-                # outputs (shared spec accept rule)
-                self._spec_accept_row(
-                    i, int(dlens[i]), drafts[i], pt[i], pl[i]
-                )
-                continue
             # unplanned rider: plain greedy step at position 0
             tok = int(pt[i, 0])
             c = s.req.constraint
@@ -1582,52 +1518,6 @@ class ContinuousBatcher:
         KS = max(self.ecfg.decode_multi_step, 1)
         self._ff_backoff = min(max(self._ff_backoff * 2, 2 * KS), 32 * KS)
         self._ff_probe_step = self._step + self._ff_backoff
-
-    def _spec_accept_row(self, i, L, drafts_row, toks_row, logps_row):
-        """THE spec accept rule (one definition shared by the n-gram
-        step and fast-forward spec riders so it cannot drift): accept
-        the longest matching draft prefix plus the bonus token at the
-        first mismatch, maintaining the drafted/accepted counters and
-        per-job stats."""
-        s = self.slots[i]
-        ctx = s.job
-        self.spec_drafted += L
-        if ctx is not None and L:
-            ctx.stats["spec_drafted"] = (
-                ctx.stats.get("spec_drafted", 0) + L
-            )
-        for j in range(L + 1):
-            tok = int(toks_row[j])
-            matched = j < L and int(drafts_row[j]) == tok
-            if matched:
-                self.spec_accepted += 1
-                if ctx is not None:
-                    ctx.stats["spec_accepted"] = (
-                        ctx.stats.get("spec_accepted", 0) + 1
-                    )
-            if (
-                self._accept_token(i, tok, float(logps_row[j]))
-                or not matched
-            ):
-                # row finished, or the bonus token at the first
-                # mismatch was consumed — later positions are
-                # conditioned on a rejected prefix
-                break
-
-    def _spec_fail_backoff(self) -> None:
-        """Push the next speculative probe out with exponential backoff
-        (4..64 window lengths): batches that never draft — or draft but
-        never accept — settle into long pipelined stretches with only
-        rare, cheap probes instead of paying a recurring drain bubble."""
-        KS = max(self.ecfg.decode_multi_step, 1)
-        self._spec_backoff = min(
-            max(self._spec_backoff * 2, 4 * KS), 64 * KS
-        )
-        self._spec_probe_step = self._step + self._spec_backoff
-        # a disengagement ends the acceptance window: the next
-        # engagement's exit decision must not be skewed by stale counts
-        self._spec_win_drafted = 0
-        self._spec_win_accepted = 0
 
     def _split_pfx(self, active):
         """Operands for Hydragen-style split decode (Pallas path,
@@ -1693,86 +1583,6 @@ class ContinuousBatcher:
                 )
             )
         return tuple(groups)
-
-    def _spec_enough(self, n_draft: int, active) -> bool:
-        """THE engagement threshold (one definition so the in-loop
-        pre-check and _spec_ngram_step cannot drift): at least half the
-        active rows draft."""
-        return 2 * n_draft >= len(active)
-
-    def _spec_drafts(self, active) -> dict:
-        """All active rows' n-gram drafts for this step ({slot: draft};
-        rows with none absent). Computed ONCE per engaged step and
-        reused for both the threshold and the verify operands."""
-        SN = self.ecfg.spec_ngram_draft
-        out = {}
-        for i in active:
-            d = self._ngram_draft(self.slots[i], SN)
-            if d is not None:
-                out[i] = d
-        return out
-
-    def _spec_coverage_ok(self, active) -> bool:
-        """Engagement rule for the in-loop pre-check (drafts here are
-        throwaway: positions advance during the pipe drain, so the
-        engage-time drafts are recomputed by _spec_ngram_step)."""
-        return self._spec_enough(len(self._spec_drafts(active)), active)
-
-    def _spec_ngram_step(self, active, last, past_len, table) -> bool:
-        """One prompt-lookup speculative step for an all-greedy batch:
-        verify every drafting row's tokens in ONE parallel forward and
-        accept each row's longest matching prefix plus the standard
-        bonus token at the first mismatch (>= 1 token per row, up to
-        K+1 — exact greedy either way). Rows with no draft this step
-        ride along as draft_len-0 plain greedy steps (verify_greedy
-        supports them natively), so one draftless row cannot disable
-        speculation for the rest of the batch. Returns False — caller
-        falls back to fused windows — only when fewer than half the
-        active rows draft: the verify dispatch is host-synchronous, so
-        at low draft coverage the RTT-hiding pipelined windows win."""
-        tm = self.timer
-        tm.enter("fsm_plan", rows=len(active), planned=0, engaged=False)
-        dmap = self._spec_drafts(active)
-        tm.note(planned=len(dmap))
-        if not self._spec_enough(len(dmap), active):
-            return False
-        tm.note(engaged=True)
-        SN = self.ecfg.spec_ngram_draft
-        drafts = np.zeros((self.B, SN), np.int32)
-        dlens = np.zeros((self.B,), np.int32)
-        for i, d in dmap.items():
-            drafts[i, : len(d)] = d
-            dlens[i] = len(d)
-        d0, a0 = self.spec_drafted, self.spec_accepted
-        with self.timer.time("decode"):
-            toks_v, logp_v = self.runner.verify_greedy(
-                np.asarray(last, np.int32), drafts, dlens,
-                np.asarray(past_len, np.int32), table,
-            )
-        self._step += 1
-        tm.enter("accept")
-        n0 = self._n_accepted
-        gens = list(self._gen)
-        for i in active:
-            self._spec_accept_row(
-                i, int(dlens[i]), drafts[i], toks_v[i], logp_v[i]
-            )
-        self._commit_verified(active, past_len, gens)
-        tm.enter("accept", tokens=self._n_accepted - n0)
-        # acceptance-based exit (coverage got us here; acceptance keeps
-        # us here): once the rolling window has seen enough drafts,
-        # leave the host-synchronous spec path unless it beats a plain
-        # step (>= 1 accepted token per SN drafted, i.e. rate >= 1/SN)
-        self._spec_win_drafted += self.spec_drafted - d0
-        self._spec_win_accepted += self.spec_accepted - a0
-        if self._spec_win_drafted >= 8 * SN:
-            if self._spec_win_accepted * SN < self._spec_win_drafted:
-                self._spec_fail_backoff()
-            else:
-                self._spec_backoff = 0
-            self._spec_win_drafted = 0
-            self._spec_win_accepted = 0
-        return True
 
     def _commit_verified(self, active, past_len, gens) -> None:
         """After a verify dispatch's accept loop, for a model that keeps
@@ -1967,8 +1777,6 @@ class ContinuousBatcher:
 
     def _record_token(self, slot: _Slot, tok: int, logp: float) -> None:
         slot.out_ids.append(tok)
-        if slot.hist is not None:  # n-gram draft history (incremental)
-            self._hist_push(slot, tok)
         slot.logprob_sum += float(logp)
         if slot.req.constraint is not None and tok not in self.stop_ids:
             slot.req.constraint.advance(tok)
@@ -2278,8 +2086,177 @@ class ContinuousBatcher:
         )
 
     # ------------------------------------------------------------------
+    # one decode iteration: build the batch, choose a path, take it
+    # ------------------------------------------------------------------
+
+    def _build_batch(self, active: List[int]) -> _DecodeBatch:
+        """The dense operands of this iteration's decode dispatch and
+        the facts of its rows, in one walk over the active slots."""
+        if self.native is not None:
+            # dense arrays live in the C++ core, always current
+            nat = self.native
+            last, past_len, table = nat.last, nat.past_len, nat.table
+            temp, top_p, top_k = nat.temp, nat.top_p, nat.top_k
+        else:
+            last = np.zeros((self.B,), np.int32)
+            past_len = np.zeros((self.B,), np.int32)
+            table = np.zeros((self.B, self.MP), np.int32)
+            temp = np.zeros((self.B,), np.float32)
+            top_p = np.ones((self.B,), np.float32)
+            top_k = np.zeros((self.B,), np.int32)
+        has_constraint = has_row_seed = has_penalty = False
+        all_greedy = constrained_greedy = True
+        PS = self.ecfg.kv_page_size
+        room = self.MP * PS
+        row_seeds = np.zeros((self.B,), np.int32)
+        for i in active:
+            s = self.slots[i]
+            r = s.req
+            if r.has_penalties():
+                has_penalty = True
+            if self.native is None:
+                last[i] = s.last_token
+                past_len[i] = s.pos
+                table[i, : len(s.pages)] = s.pages
+                temp[i] = r.temperature
+                top_p[i] = r.top_p
+                top_k[i] = r.top_k
+            if r.row_seed is not None:
+                has_row_seed = True
+                row_seeds[i] = _step_seed(r.row_seed, len(s.out_ids))
+            else:
+                # mixed batch: unseeded rows still need fresh
+                # per-step keys (the batch-wide rng is pinned to
+                # _fixed_key when any row is seeded)
+                row_seeds[i] = _step_seed(
+                    0x5EED0000 ^ (i + 1), self._step
+                )
+            greedy = r.temperature <= 0.0
+            if not greedy:
+                all_greedy = False
+            if r.constraint is not None:
+                has_constraint = True
+                if not greedy:
+                    constrained_greedy = False
+            room = min(room, len(s.pages) * PS - s.pos)
+        return _DecodeBatch(
+            active, last, past_len, table, temp, top_p, top_k, row_seeds,
+            _DecodeFacts(
+                has_constraint=has_constraint,
+                has_row_seed=has_row_seed,
+                has_penalty=has_penalty,
+                all_greedy=all_greedy,
+                constrained_greedy=constrained_greedy,
+                flagged=bool(self._needs_mask),
+                room=room,
+                wrapped=self._wrapped,
+            ),
+        )
+
+    def _choose_path(self, f: _DecodeFacts, in_flight: int) -> str:
+        """THE choice of a decode path, from the facts of the batch and
+        the number of fused windows in flight; every gate is read here
+        and nowhere else. Returns what to try: ``pipelined`` (refill the
+        pipe, fetch its oldest window), ``drain`` (fetch only),
+        ``fastforward`` (the constrained window, the fast-forward probe
+        ahead of it), ``window`` or ``single``. The path's method
+        returns the label its iteration is counted under."""
+        KS = self.ecfg.decode_multi_step
+        # Fuse K decode steps into one device program when no row needs
+        # host work between steps: one dispatch + one fetch per window
+        # instead of per token.
+        fused = (
+            KS > 1
+            and not f.has_row_seed
+            and not f.has_penalty  # counts update host-side
+        )
+        # all-or-nothing: every distinct K is a separate XLA compilation
+        # of the fused window (steps is static), so near-capacity tails
+        # run single-step instead of walking through K-1 recompiles
+        fits = f.room >= KS
+        # Pipelined fused windows: window k+1 is dispatched chained off
+        # window k's device-resident tokens BEFORE window k's results
+        # are fetched, so the device runs the next window while the
+        # host accepts this one (the host is 2-4 % of a generate window:
+        # PERF.md §5). Page capacity at dispatch covers every in-flight
+        # window, and (slot, generation) snapshots make stale windows'
+        # tokens discardable after a slot is released/reused
+        # mid-pipeline.
+        pipe_ok = fused and not f.has_constraint and not f.flagged
+        if pipe_ok and (in_flight or fits):
+            return "pipelined"
+        if in_flight:
+            # pipe_ok went false (e.g. a constrained row admitted
+            # mid-pipeline): windows drain one per iteration, then
+            # other paths resume
+            return "drain"
+        # (a plain batch with nothing in flight and room for less than
+        # one window takes the single step, below)
+        #
+        # Constrained rows fuse K steps into one device program too
+        # when they are GREEDY (classify-style jobs): the window samples
+        # unmasked, the host verifies tokens against each row's FSM,
+        # and only the longest valid prefix is committed to pages —
+        # exact for greedy (masked argmax == unmasked argmax when the
+        # unmasked argmax is valid). A rejecting row takes its
+        # FSM-masked step as the FIRST step of its next window
+        # (allowed0) — per-row recovery; other rows keep full window
+        # cadence.
+        # Flagged rows are fine here: the window FSM-masks their first
+        # step (allowed0); only the non-greedy constrained fallback
+        # needs the masked single-step, and it clears the flags itself.
+        if fused and fits and f.has_constraint and f.constrained_greedy:
+            # FSM fast-forward first: when enough rows sit in a forced
+            # scaffold run, one parallel verify commits the whole run
+            # per row — the window would reject its unmasked samples
+            # there. Flagged SINGLETON rows are candidates too (the
+            # peel is their masked step); a flagged row in a
+            # non-singleton state sends the batch to the window's
+            # allowed0 recovery instead. The verify forward has no
+            # ring/pipeline wrapper.
+            if not f.wrapped and f.all_greedy:
+                return "fastforward"
+            return "window"
+        return "single"
+
+    def _note_window(self, b: _DecodeBatch, steps: int) -> None:
+        """Window attribution for the doctor's roofline grade:
+        occupancy x fused steps over the span's duration is the
+        window's attempted token rate."""
+        if self._tel_on:
+            n = len(b.active)
+            self._tel_attrs["decode_window"] = {
+                "batch": n,
+                "steps": steps,
+                "avg_ctx": round(
+                    sum(int(b.past_len[i]) for i in b.active) / max(n, 1),
+                    1,
+                ),
+                **self._route_attrs.get("decode_window", {}),
+            }
+
+    # ------------------------------------------------------------------
     # pipelined fused windows (unconstrained decode fast path)
     # ------------------------------------------------------------------
+
+    def _pipelined_step(
+        self, pipe: List[Any], b: _DecodeBatch, refill: bool
+    ) -> str:
+        """Keep ``decode_lookahead`` fused windows in flight (entries of
+        ``pipe``: toks_dev, logps_dev, active, gens, K, route_dev) and
+        fetch the oldest. At a depth of one the window dispatched here
+        is the one fetched: dispatch, fetch and accept in one
+        iteration. Without ``refill`` the pipe only drains."""
+        KS = self.ecfg.decode_multi_step
+        self._note_window(b, KS)
+        if refill:
+            while len(pipe) < max(self.ecfg.decode_lookahead, 1):
+                proj = self._pipe_projection(pipe)
+                if not self._pipe_capacity_ok(b.active, proj, KS):
+                    break
+                self._dispatch_pipelined(pipe, b, proj, KS)
+        self._process_pipelined(pipe.pop(0))
+        return "pipelined"
 
     def _pipe_projection(self, pipe) -> np.ndarray:
         """[B] extra decode steps already dispatched (in-flight windows)
@@ -2314,16 +2291,17 @@ class ContinuousBatcher:
         return True
 
     def _dispatch_pipelined(
-        self, pipe, active, last, past, table, temp, top_p, top_k,
-        K: int,
+        self, pipe, b: _DecodeBatch, proj: np.ndarray, K: int
     ) -> None:
         """Dispatch one fused window WITHOUT waiting for in-flight ones.
 
-        ``past`` must already include the in-flight projection. The last
+        ``proj`` is the in-flight projection (``_pipe_projection``): the
+        window starts that many steps past each row's ``pos``. The last
         tokens chain from the previous window's device-resident sample
         row; slots admitted (or re-admitted) since that dispatch take
         their host-known token via a device-side merge — no host sync
         anywhere on this path."""
+        active = b.active
         if pipe:
             prev_toks, _, p_active, p_gens, _, _ = pipe[-1]
             chained = {
@@ -2341,15 +2319,15 @@ class ContinuousBatcher:
                 for i in chained:
                     refresh[i] = False
                 last_arg = self.runner.merge_last(
-                    prev_toks[-1], refresh, np.asarray(last, np.int32)
+                    prev_toks[-1], refresh, np.asarray(b.last, np.int32)
                 )
         else:
-            last_arg = last
+            last_arg = b.last
         self._key, sub = jax.random.split(self._key)
         with self.timer.time("decode"):
             toks_dev, logps_dev = self.runner.decode_multi_async(
-                last_arg, past, table, sub, temp, top_p, K, top_k=top_k,
-                pfx=self._split_pfx(active),
+                last_arg, b.past_len + proj, b.table, sub, b.temp,
+                b.top_p, K, top_k=b.top_k, pfx=self._split_pfx(active),
             )
         self._step += K
         pipe.append(
@@ -2372,8 +2350,8 @@ class ContinuousBatcher:
         re-admitted) are discarded. Accounting and results stream
         through each slot's job (_accept_token).
 
-        PLAIN rows — no constraint, no penalties, no stop sequences, no
-        n-gram draft history — take a vectorized window-acceptance path
+        PLAIN rows — no constraint, no penalties, no stop sequences —
+        take a vectorized window-acceptance path
         (round-5 host-overhead profile: the per-token Python loop cost
         ~26 ms per B=128 window, 2× the device window itself); rows with
         any per-token machinery keep the exact per-token loop."""
@@ -2394,7 +2372,6 @@ class ContinuousBatcher:
             r = s.req
             if (
                 r.constraint is None
-                and s.hist is None
                 and not r.stop_seqs
                 and not r.has_penalties()
             ):
@@ -2490,6 +2467,181 @@ class ContinuousBatcher:
                         )
             if limit <= wK:
                 self._emit(i)
+
+    # ------------------------------------------------------------------
+    # host-synchronous paths: the constrained window, the single step
+    # ------------------------------------------------------------------
+
+    def _window_step(self, b: _DecodeBatch, probe: bool) -> str:
+        """The constrained greedy batch: the FSM fast-forward probe
+        when ``probe`` says the batch may take it, else (or when the
+        probe disengages) the speculative window: sample unmasked,
+        verify host-side, commit only each row's FSM-valid prefix. Rows
+        whose previous window rejected take their FSM-masked step as
+        the window's FIRST step (allowed0) — per-row recovery, full
+        cadence for everyone else."""
+        tm = self.timer
+        active = b.active
+        K = self.ecfg.decode_multi_step
+        self._note_window(b, K)
+        self._key, sub = jax.random.split(self._key)
+        if probe and self._fastforward_step(
+            active, b.last, b.past_len, b.table
+        ):
+            return "fastforward"
+        # a failed probe stays ``fsm_plan`` up to here
+        tm.enter("batch_build")
+        allowed0 = None
+        flagged: set = self._needs_mask & set(active)
+        if flagged:
+            allowed0 = self._fsm_masks(flagged)
+            self._needs_mask -= flagged
+        with tm.time("decode"):
+            toks_w, logps_w, handle = self.runner.decode_window(
+                b.last, b.past_len, b.table, sub, b.temp, b.top_p, K,
+                top_k=b.top_k, allowed0=allowed0,
+                pfx=self._split_pfx(active),
+            )
+            self._note_route("decode_window")
+        self._step += K
+        tm.enter("accept")
+        n0 = self._n_accepted
+        accepted = np.zeros((self.B,), np.int32)
+        finished: List[int] = []
+        for i in active:
+            s = self.slots[i]
+            if s is None:
+                continue  # failed during mask assembly
+            c = s.req.constraint
+            for j in range(K):
+                tok = int(toks_w[j][i])
+                # a flagged row's step-0 token was chosen UNDER its FSM
+                # mask — accept without re-verifying, exactly like the
+                # masked single-step this replaces. Re-checking would
+                # livelock in the budget-infeasible corner where
+                # allowed_tokens degrades to unfiltered but
+                # token_allowed still returns False (fsm.py degrade
+                # semantics).
+                if c is not None and not (j == 0 and i in flagged):
+                    rem = self._remaining(s.req, len(s.out_ids), s.pos)
+                    try:
+                        tok_ok = self._token_ok(c, tok, rem)
+                    except Exception as e:  # noqa: BLE001 — row isolation
+                        self._fail_slot(i, e)
+                        break
+                    if not tok_ok:
+                        # this row's NEXT window opens with its
+                        # FSM-masked step (allowed0) so it crosses the
+                        # scaffold token; other rows keep full window
+                        # cadence
+                        self._needs_mask.add(i)
+                        break
+                rc = self._accept_token(
+                    i, tok, float(logps_w[j][i]), release=False,
+                )
+                if rc == 2:
+                    break  # row failed: token NOT committed
+                accepted[i] += 1
+                if rc:
+                    finished.append(i)
+                    break
+        tm.enter("accept", tokens=self._n_accepted - n0)
+        # pages are still reserved for every row (releases were
+        # deferred), so the accepted K/V lands safely
+        with tm.time("decode"):
+            self.runner.commit_window(handle, accepted)
+        tm.enter("emit")
+        for i in finished:
+            self._emit(i)
+        return "window"
+
+    def _penalty_operands(self, active: List[int]):
+        """The single step's penalty operands: per row the packed seen
+        bits and the distinct generated ids with their counts."""
+        # Distinct generated ids carried per row. K is a jit shape, so
+        # grow it in power-of-two buckets: exact presence/frequency
+        # semantics at any generation length, with at most log2 extra
+        # compiles.
+        PK = 256
+        max_distinct = max(
+            (
+                len(self.slots[i].counts)
+                for i in active
+                if self.slots[i].req.has_penalties()
+            ),
+            default=0,
+        )
+        while PK < max_distinct:
+            PK *= 2
+        if PK > 256 and PK not in self._pk_grown:
+            self._pk_grown.add(PK)
+            logger.info(
+                "penalty id buffer grown to K=%d (a row has %d distinct "
+                "generated ids)", PK, max_distinct,
+            )
+        nb = (self.vocab + 7) // 8
+        seen_packed = np.zeros((self.B, nb), np.uint8)
+        ids_p = np.full((self.B, PK), -1, np.int32)
+        cnt_p = np.zeros((self.B, PK), np.float32)
+        pres = np.zeros((self.B,), np.float32)
+        freq = np.zeros((self.B,), np.float32)
+        rep = np.ones((self.B,), np.float32)
+        for i in active:
+            s = self.slots[i]
+            if not s.req.has_penalties():
+                continue
+            pres[i] = s.req.presence_penalty
+            freq[i] = s.req.frequency_penalty
+            rep[i] = s.req.repetition_penalty
+            if s.seen_bits is not None:
+                seen_packed[i] = s.seen_bits  # memcpy
+            assert len(s.counts) <= PK  # growth above
+            for j, t in enumerate(s.counts):
+                ids_p[i, j] = t
+                cnt_p[i, j] = s.counts[t]
+        return seen_packed, ids_p, cnt_p, pres, freq, rep
+
+    def _single_step(self, b: _DecodeBatch) -> str:
+        """One decode step with whatever its rows need from the host
+        between steps: FSM masks (sampled constrained rows), per-row
+        seeds, penalties; also the near-capacity tail of any batch."""
+        f = b.facts
+        active = b.active
+        self._note_window(b, 1)
+        self._key, sub = jax.random.split(self._key)
+        allowed = None
+        if f.has_constraint:
+            # masked step: per-row FSM vocab masks (fused windows verify
+            # tokens instead; their allowed0 recovery masks come from
+            # the same helper)
+            allowed = self._fsm_masks(active)
+        penalties = (
+            self._penalty_operands(active) if f.has_penalty else None
+        )
+        with self.timer.time("decode"):
+            toks, logps = self.runner.decode_step(
+                b.last, b.past_len, b.table,
+                # row-seeded sampling needs a batch-independent base
+                # key so a row's stream reproduces regardless of batch
+                # composition
+                self._fixed_key if f.has_row_seed else sub,
+                b.temp, b.top_p, top_k=b.top_k, allowed=allowed,
+                row_seeds=b.row_seeds if f.has_row_seed else None,
+                penalties=penalties, pfx=self._split_pfx(active),
+            )
+            self._note_route("decode_window")
+        self._step += 1
+        # masked single-step crossed every flagged row's rejected
+        # scaffold token
+        self._needs_mask.clear()
+        self.timer.enter("accept")
+        n0 = self._n_accepted
+        for i in active:
+            if self.slots[i] is None:
+                continue  # failed during mask assembly
+            self._accept_token(i, int(toks[i]), float(logps[i]))
+        self.timer.enter("accept", tokens=self._n_accepted - n0)
+        return "single"
 
     # ------------------------------------------------------------------
 
@@ -2661,9 +2813,6 @@ class ContinuousBatcher:
                 (ctx.stats["in"] + ctx.stats["out"]) / elapsed
             ),
         }
-        if ctx.stats.get("spec_drafted"):
-            payload["spec_drafted"] = ctx.stats["spec_drafted"]
-            payload["spec_accepted"] = ctx.stats.get("spec_accepted", 0)
         ctx.on_progress(payload)
 
     def _finish_job(
@@ -2857,10 +3006,7 @@ class ContinuousBatcher:
                         float(hib.pos), "tier_payload_without_state"
                     )
         start = shared + hib.n_pages * PS
-        if ok and hib.pos > start and (
-            getattr(self.runner, "sp", 1) != 1
-            or getattr(self.runner, "pp", 1) != 1
-        ):
+        if ok and hib.pos > start and self._wrapped:
             # aligned-capture entry on a sharded runner: the sub-page
             # tail would need prefill(start>0), which sp/pp forbids —
             # treat as a miss and regenerate rather than assert
@@ -3181,8 +3327,7 @@ class ContinuousBatcher:
                     # runner.prefill's start>0 assert) — under sp/pp,
                     # long rows keep the stop-the-world full-sequence
                     # path below
-                    and getattr(self.runner, "sp", 1) == 1
-                    and getattr(self.runner, "pp", 1) == 1
+                    and not self._wrapped
                 ):
                     if batch:
                         break  # flush the short-row batch first
@@ -3294,10 +3439,6 @@ class ContinuousBatcher:
         newly-submitted same-model jobs mid-session. ``should_yield``
         preempts the WHOLE session (returns "yielded"; non-done jobs'
         slots are dropped for row-granular resume)."""
-        # fresh session: a coverage verdict cached by a previous
-        # run()/run_multi() on this batcher must not gate this one's
-        # first spec probe
-        self._spec_cov_key = -1
         live: List[JobCtx] = []
         # the phase cursor (engine/profiling.py): from here to the
         # finally below every instant of this thread belongs to exactly
@@ -3447,441 +3588,18 @@ class ContinuousBatcher:
                     continue
                 n_active = len(active)
                 tm.enter("batch_build", active=n_active)
-                if self.native is not None:
-                    # dense arrays live in the C++ core, always current
-                    nat = self.native
-                    last, past_len, table = (
-                        nat.last, nat.past_len, nat.table
+                batch = self._build_batch(active)
+                plan = self._choose_path(batch.facts, len(pipe))
+                if plan in ("pipelined", "drain"):
+                    path = self._pipelined_step(
+                        pipe, batch, refill=plan == "pipelined"
                     )
-                    temp, top_p, top_k = nat.temp, nat.top_p, nat.top_k
+                elif plan in ("fastforward", "window"):
+                    path = self._window_step(
+                        batch, probe=plan == "fastforward"
+                    )
                 else:
-                    last = np.zeros((self.B,), np.int32)
-                    past_len = np.zeros((self.B,), np.int32)
-                    table = np.zeros((self.B, self.MP), np.int32)
-                    temp = np.zeros((self.B,), np.float32)
-                    top_p = np.ones((self.B,), np.float32)
-                    top_k = np.zeros((self.B,), np.int32)
-                has_constraint = False
-                has_row_seed = False
-                has_penalty = False
-                row_seeds = np.zeros((self.B,), np.int32)
-                for i in active:
-                    s = self.slots[i]
-                    if s.req.has_penalties():
-                        has_penalty = True
-                    if self.native is None:
-                        last[i] = s.last_token
-                        past_len[i] = s.pos
-                        table[i, : len(s.pages)] = s.pages
-                        temp[i] = s.req.temperature
-                        top_p[i] = s.req.top_p
-                        top_k[i] = s.req.top_k
-                    if s.req.row_seed is not None:
-                        has_row_seed = True
-                        row_seeds[i] = _step_seed(
-                            s.req.row_seed, len(s.out_ids)
-                        )
-                    else:
-                        # mixed batch: unseeded rows still need fresh
-                        # per-step keys (the batch-wide rng is pinned to
-                        # _fixed_key when any row is seeded)
-                        row_seeds[i] = _step_seed(
-                            0x5EED0000 ^ (i + 1), self._step
-                        )
-                    if s.req.constraint is not None:
-                        has_constraint = True
-
-                # Prompt-lookup speculative decoding (opt-in,
-                # spec_ngram_draft > 0): when the whole batch is plain
-                # greedy and no windows are in flight, verify rows'
-                # n-gram drafts in one parallel forward — up to K+1
-                # tokens per row per dispatch vs the fused window's K
-                # sequential steps. Host-synchronous: assumes a
-                # host<->device round trip of ~135 ms, under which the
-                # pipelined windows below win unless draft coverage is
-                # decent — re-measure, ROADMAP 1.6 (chip A/B: bench_e2e
-                # SUTRO_E2E_SPEC). While a probe is pending the
-                # pipeline refill below is suspended so the pipe can
-                # DRAIN — a standing `not pipe` requirement against an
-                # always-refilled pipe would lock speculation out
-                # permanently after its first miss; a failed probe
-                # backs off a few window lengths and pipelining
-                # resumes at full lookahead in the meantime.
-                spec_probe = (
-                    getattr(self.ecfg, "spec_ngram_draft", 0) > 0
-                    and self._step >= self._spec_probe_step
-                    and not has_constraint
-                    and not has_row_seed
-                    and not has_penalty
-                    # the verify forward has no ring/pipeline wrapper
-                    # (same gate as the prefix cache and piggyback)
-                    and getattr(self.runner, "sp", 1) == 1
-                    and getattr(self.runner, "pp", 1) == 1
-                    and all(
-                        self.slots[i].req.temperature <= 0.0
-                        for i in active
-                    )
-                )
-                if spec_probe and pipe:
-                    # host-only coverage pre-check BEFORE paying the
-                    # pipeline drain: if the engagement rule fails right
-                    # now, fail the probe in place and keep the pipe
-                    # full — no drain bubble for batches that never
-                    # draft. Computed once per probe epoch and cached
-                    # across the drain iterations (drafts advance
-                    # during the drain, but they are throwaway here —
-                    # _spec_ngram_step recomputes real ones at engage)
-                    if self._spec_cov_key != self._spec_probe_step:
-                        self._spec_cov_key = self._spec_probe_step
-                        tm.enter("fsm_plan", rows=len(active))
-                        self._spec_cov_ok = self._spec_coverage_ok(
-                            active
-                        )
-                        tm.enter("batch_build")
-                    if not self._spec_cov_ok:
-                        self._spec_fail_backoff()
-                        spec_probe = False
-                if spec_probe and not pipe:
-                    if self._spec_ngram_step(
-                        active, last, past_len, table
-                    ):
-                        self._after_step(
-                            live, on_job_done, "spec", n_active
-                        )
-                        continue
-                    self._spec_fail_backoff()
-                    spec_probe = False
-                    tm.enter("batch_build")
-
-                # Pipelined fused windows: when no row needs host work
-                # between steps, window k+1 is dispatched chained off
-                # window k's device-resident tokens BEFORE window k's
-                # results cross the host link, hiding the host<->device
-                # round trip behind device compute (assumes a round trip
-                # of ~135 ms; re-measure, ROADMAP 1.6).
-                # Page-capacity at dispatch covers every in-flight
-                # window, and (slot, generation) snapshots make stale
-                # windows' tokens discardable after a slot is
-                # released/reused mid-pipeline.
-                KS = self.ecfg.decode_multi_step
-                pipe_ok = (
-                    KS > 1
-                    and self.ecfg.decode_lookahead > 1
-                    and not has_constraint
-                    and not has_row_seed
-                    and not has_penalty
-                    and not self._needs_mask
-                )
-                if pipe_ok or pipe:
-                    if self._tel_on:
-                        self._tel_attrs["decode_window"] = {
-                            "batch": len(active),
-                            "steps": KS,
-                            "avg_ctx": round(
-                                sum(int(past_len[i]) for i in active)
-                                / max(len(active), 1), 1,
-                            ),
-                            **self._route_attrs.get("decode_window", {}),
-                        }
-                    # a pending spec probe suspends refill so the pipe
-                    # drains (one window per iteration) and the probe
-                    # above gets its `not pipe` opening
-                    if pipe_ok and not spec_probe:
-                        while len(pipe) < self.ecfg.decode_lookahead:
-                            proj = self._pipe_projection(pipe)
-                            if not self._pipe_capacity_ok(
-                                active, proj, KS
-                            ):
-                                break
-                            self._dispatch_pipelined(
-                                pipe, active, last, past_len + proj,
-                                table, temp, top_p, top_k, KS,
-                            )
-                    if pipe:
-                        # drain-one: also covers pipe_ok going false
-                        # (e.g. a constrained row admitted mid-pipeline)
-                        # — windows drain one per iteration, then other
-                        # paths resume
-                        self._process_pipelined(pipe.pop(0))
-                        self._after_step(
-                            live, on_job_done, "pipelined", n_active
-                        )
-                        continue
-                    # pipe empty and nothing dispatchable (capacity
-                    # below one window): fall through to single-step
-
-                # Fuse K decode steps into one device program when no
-                # row needs host work between steps: one dispatch + one
-                # fetch per window instead of per token. Constrained
-                # rows fuse too when they are GREEDY (classify-style
-                # jobs): the window samples unmasked, the host verifies
-                # tokens against each row's FSM, and only the longest
-                # valid prefix is committed to pages — exact for greedy
-                # (masked argmax == unmasked argmax when the unmasked
-                # argmax is valid). A rejecting row takes its FSM-masked
-                # step as the FIRST step of its next window (allowed0)
-                # — per-row recovery; other rows keep full window
-                # cadence.
-                K = 1
-                if (
-                    self.ecfg.decode_multi_step > 1
-                    and not has_row_seed
-                    and not has_penalty  # counts update host-side
-                    # flagged rows are fine here: the speculative window
-                    # FSM-masks their first step (allowed0); only the
-                    # non-greedy constrained fallback needs the masked
-                    # single-step, and it clears the flags itself
-                    and (not self._needs_mask or has_constraint)
-                    and (
-                        not has_constraint
-                        or all(
-                            self.slots[i].req.temperature <= 0.0
-                            for i in active
-                            if self.slots[i].req.constraint is not None
-                        )
-                    )
-                ):
-                    cap = min(
-                        len(self.slots[i].pages) * self.ecfg.kv_page_size
-                        - self.slots[i].pos
-                        for i in active
-                    )
-                    # all-or-nothing: every distinct K is a separate XLA
-                    # compilation of the fused window (steps is static),
-                    # so near-capacity tails run single-step instead of
-                    # walking through K-1 recompiles
-                    if cap >= self.ecfg.decode_multi_step:
-                        K = self.ecfg.decode_multi_step
-
-                if self._tel_on:
-                    # window attribution for the doctor's roofline
-                    # grade: occupancy x fused steps over the span's
-                    # duration is the window's attempted token rate
-                    self._tel_attrs["decode_window"] = {
-                        "batch": len(active),
-                        "steps": K,
-                        "avg_ctx": round(
-                            sum(int(past_len[i]) for i in active)
-                            / max(len(active), 1), 1,
-                        ),
-                        **self._route_attrs.get("decode_window", {}),
-                    }
-                self._key, sub = jax.random.split(self._key)
-                # row-seeded sampling needs a batch-independent base key
-                # so a row's stream reproduces regardless of batch
-                # composition
-                rng = self._fixed_key if has_row_seed else sub
-                if K > 1 and has_constraint:
-                    # FSM fast-forward first: when enough rows sit in a
-                    # forced scaffold run, one parallel verify commits
-                    # the whole run per row — the speculative window
-                    # below would reject its unmasked samples there.
-                    # Flagged SINGLETON rows are candidates too (the
-                    # peel is their masked step); a flagged row in a
-                    # non-singleton state sends the batch to the
-                    # window's allowed0 recovery instead. The verify
-                    # forward has no ring/pipeline wrapper.
-                    if (
-                        getattr(self.runner, "sp", 1) == 1
-                        and getattr(self.runner, "pp", 1) == 1
-                        and all(
-                            self.slots[i].req.temperature <= 0.0
-                            for i in active
-                        )
-                        and self._fastforward_step(
-                            active, last, past_len, table
-                        )
-                    ):
-                        self._after_step(
-                            live, on_job_done, "fastforward", n_active
-                        )
-                        continue
-                    # a failed probe stays ``fsm_plan`` up to here
-                    tm.enter("batch_build")
-                    # speculative window: sample unmasked, verify
-                    # host-side, commit only each row's FSM-valid
-                    # prefix. Rows whose previous window rejected take
-                    # their FSM-masked step as the window's FIRST step
-                    # (allowed0) — per-row recovery, full cadence for
-                    # everyone else.
-                    allowed0 = None
-                    flagged: set = self._needs_mask & set(active)
-                    if flagged:
-                        allowed0 = self._fsm_masks(flagged)
-                        self._needs_mask -= flagged
-                    with self.timer.time("decode"):
-                        toks_w, logps_w, handle = (
-                            self.runner.decode_window(
-                                last, past_len, table, sub, temp, top_p,
-                                K, top_k=top_k, allowed0=allowed0,
-                                pfx=self._split_pfx(active),
-                            )
-                        )
-                        self._note_route("decode_window")
-                    self._step += K
-                    path = "window"
-                    tm.enter("accept")
-                    n0 = self._n_accepted
-                    accepted = np.zeros((self.B,), np.int32)
-                    finished: List[int] = []
-                    for i in active:
-                        s = self.slots[i]
-                        if s is None:
-                            continue  # failed during mask assembly
-                        c = s.req.constraint
-                        for j in range(K):
-                            tok = int(toks_w[j][i])
-                            # a flagged row's step-0 token was chosen
-                            # UNDER its FSM mask — accept without
-                            # re-verifying, exactly like the masked
-                            # single-step this replaces. Re-checking
-                            # would livelock in the budget-infeasible
-                            # corner where allowed_tokens degrades to
-                            # unfiltered but token_allowed still returns
-                            # False (fsm.py degrade semantics).
-                            if c is not None and not (
-                                j == 0 and i in flagged
-                            ):
-                                rem = self._remaining(
-                                    s.req, len(s.out_ids), s.pos
-                                )
-                                try:
-                                    tok_ok = self._token_ok(c, tok, rem)
-                                except Exception as e:  # noqa: BLE001 — row isolation
-                                    self._fail_slot(i, e)
-                                    break
-                                if not tok_ok:
-                                    # this row's NEXT window opens with
-                                    # its FSM-masked step (allowed0) so
-                                    # it crosses the scaffold token;
-                                    # other rows keep full window
-                                    # cadence
-                                    self._needs_mask.add(i)
-                                    break
-                            rc = self._accept_token(
-                                i, tok, float(logps_w[j][i]),
-                                release=False,
-                            )
-                            if rc == 2:
-                                break  # row failed: token NOT committed
-                            accepted[i] += 1
-                            if rc:
-                                finished.append(i)
-                                break
-                    tm.enter("accept", tokens=self._n_accepted - n0)
-                    # pages are still reserved for every row (releases
-                    # were deferred), so the accepted K/V lands safely
-                    with self.timer.time("decode"):
-                        self.runner.commit_window(handle, accepted)
-                    tm.enter("emit")
-                    for i in finished:
-                        self._emit(i)
-                elif K > 1:
-                    with self.timer.time("decode"):
-                        toks_w, logps_w = self.runner.decode_multi(
-                            last, past_len, table, sub, temp, top_p, K,
-                            top_k=top_k, pfx=self._split_pfx(active),
-                        )
-                        self._note_route("decode_window")
-                    self._step += K
-                    path = "multi"
-                    tm.enter("accept")
-                    n0 = self._n_accepted
-                    for j in range(K):
-                        for i in active:
-                            if self.slots[i] is None:
-                                continue  # finished earlier this window
-                            self._accept_token(
-                                i, int(toks_w[j][i]),
-                                float(logps_w[j][i]),
-                            )
-                        active = [
-                            i for i in active
-                            if self.slots[i] is not None
-                        ]
-                        if not active:
-                            break
-                    tm.enter("accept", tokens=self._n_accepted - n0)
-                else:
-                    path = "single"
-                    allowed = None
-                    if has_constraint:
-                        # masked step: per-row FSM vocab masks (fused
-                        # windows verify tokens instead; their allowed0
-                        # recovery masks come from the same helper)
-                        allowed = self._fsm_masks(active)
-                    penalties = None
-                    if has_penalty:
-                        # Distinct generated ids carried per row. K is a
-                        # jit shape, so grow it in power-of-two buckets:
-                        # exact presence/frequency semantics at any
-                        # generation length, with at most log2 extra
-                        # compiles.
-                        PK = 256
-                        max_distinct = max(
-                            (
-                                len(self.slots[i].counts)
-                                for i in active
-                                if self.slots[i].req.has_penalties()
-                            ),
-                            default=0,
-                        )
-                        while PK < max_distinct:
-                            PK *= 2
-                        if PK > 256 and PK not in self._pk_grown:
-                            self._pk_grown.add(PK)
-                            logger.info(
-                                "penalty id buffer grown to K=%d (a row "
-                                "has %d distinct generated ids)",
-                                PK, max_distinct,
-                            )
-                        nb = (self.vocab + 7) // 8
-                        seen_packed = np.zeros((self.B, nb), np.uint8)
-                        ids_p = np.full((self.B, PK), -1, np.int32)
-                        cnt_p = np.zeros((self.B, PK), np.float32)
-                        pres = np.zeros((self.B,), np.float32)
-                        freq = np.zeros((self.B,), np.float32)
-                        rep = np.ones((self.B,), np.float32)
-                        for i in active:
-                            s = self.slots[i]
-                            if not s.req.has_penalties():
-                                continue
-                            pres[i] = s.req.presence_penalty
-                            freq[i] = s.req.frequency_penalty
-                            rep[i] = s.req.repetition_penalty
-                            if s.seen_bits is not None:
-                                seen_packed[i] = s.seen_bits  # memcpy
-                            assert len(s.counts) <= PK  # growth above
-                            for j, t in enumerate(s.counts):
-                                ids_p[i, j] = t
-                                cnt_p[i, j] = s.counts[t]
-                        penalties = (
-                            seen_packed, ids_p, cnt_p, pres, freq, rep
-                        )
-                    with self.timer.time("decode"):
-                        toks, logps = self.runner.decode_step(
-                            last, past_len, table, rng, temp, top_p,
-                            top_k=top_k, allowed=allowed,
-                            row_seeds=(
-                                row_seeds if has_row_seed else None
-                            ),
-                            penalties=penalties,
-                            pfx=self._split_pfx(active),
-                        )
-                        self._note_route("decode_window")
-                    self._step += 1
-                    # masked single-step crossed every flagged row's
-                    # rejected scaffold token
-                    self._needs_mask.clear()
-                    tm.enter("accept")
-                    n0 = self._n_accepted
-                    for i in active:
-                        if self.slots[i] is None:
-                            continue  # failed during mask assembly
-                        self._accept_token(
-                            i, int(toks[i]), float(logps[i])
-                        )
-                    tm.enter("accept", tokens=self._n_accepted - n0)
+                    path = self._single_step(batch)
                 self._after_step(live, on_job_done, path, n_active)
             return "completed"
         finally:

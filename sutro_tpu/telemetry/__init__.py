@@ -410,8 +410,8 @@ FLEET_ROUTE_SECONDS = REGISTRY.histogram(
 SCHED_ITERATIONS_TOTAL = REGISTRY.counter(
     "sutro_sched_iterations_total",
     "Scheduler loop iterations by the decode path each took: pipelined "
-    "| window | fastforward | spec | multi | single, or idle when no "
-    "row was ready to decode",
+    "| window | fastforward | single, or idle when no row was ready to "
+    "decode",
     labels=("path",),
     max_series=8,
 )

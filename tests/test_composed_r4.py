@@ -1,12 +1,13 @@
 """Round-4 feature composition: shared-prefix KV caching + cross-job
-co-batching + piggybacked chunked prefill + n-gram speculative decoding
-+ int8 KV cache in ONE engine session. Each feature is pinned exact in
+co-batching + piggybacked chunked prefill + pipelined fused windows +
+int8 KV cache in ONE engine session. Each feature is pinned exact in
 isolation by its own test file; this asserts the COMPOSITION:
 
 - fp leg: with full-precision KV, the composed co-batched session must
   produce outputs bit-identical to solo runs with prefix cache,
-  speculation, and piggyback all DISABLED — the three features are
-  exactness-preserving and must stay so when stacked.
+  pipelining (windows in flight: 1) and piggyback all DISABLED — the
+  three features are exactness-preserving and must stay so when
+  stacked.
 - int8 leg: with kv_quantize="int8" the comparison baseline must share
   the same KV READ PATTERN (same config, solo): chunked/prefix prefill
   re-reads earlier K/V from quantized pages where a whole-prompt
@@ -57,7 +58,6 @@ def _ecfg(**kw):
         use_pallas=False,
         param_dtype="float32",
         activation_dtype="float32",
-        spec_ngram_draft=6,
         decode_multi_step=4,
         decode_lookahead=2,
         prefill_chunk=16,
@@ -140,7 +140,7 @@ def test_composed_fp_exact_vs_plain(byte_tok):
     a_texts = [PREFIX + s for s in A_SUFFIXES]
     on_a, on_b = _cobatch(_ecfg(), tok)
     plain = _ecfg(
-        prefix_cache=False, spec_ngram_draft=0, prefill_chunk=512
+        prefix_cache=False, decode_lookahead=1, prefill_chunk=512
     )
     assert on_a == _solo(plain, tok, a_texts)
     assert on_b == _solo(plain, tok, B_TEXTS)

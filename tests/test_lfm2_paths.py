@@ -218,10 +218,12 @@ def test_a_speculative_window_commits_any_accepted_prefix(runner, step):
 
 # -- (e) verify, a partial accept, then decoding on ----------------------------
 
-@pytest.mark.parametrize("verify", ["greedy", "candidates"])
-def test_verify_with_one_and_three_of_four_accepted(runner, step, verify):
+@pytest.mark.parametrize(
+    "accepted", [[1, 3], [5, 0]], ids=["one-and-three", "all-and-none"]
+)
+def test_verify_with_a_part_of_its_inputs_accepted(runner, step, accepted):
     seqs = [sequence(11, 24), sequence(12, 24)]
-    starts, accepted = [15, 11], [1, 3]
+    starts = [15, 11]
     tables = np.stack([table_of(1, 2, 3, 4), table_of(5, 6, 7, 8)])
     runner.prefill_batch([s[:n] for s, n in zip(seqs, starts)], tables)
     tables4 = np.concatenate([tables, np.zeros((2, MP), np.int32)])
@@ -230,14 +232,11 @@ def test_verify_with_one_and_three_of_four_accepted(runner, step, verify):
     drafts[0], drafts[1] = seqs[0][16:20], seqs[1][12:16]
     dlens = np.array([4, 4, 0, 0], np.int32)
     past = np.array(starts + [0, 0], np.int32)
-    if verify == "greedy":
-        runner.verify_greedy(last, drafts, dlens, past, tables4)
-    else:
-        runner.verify_candidates(
-            last, drafts, dlens, np.zeros((4, 5, 2), np.int32),
-            np.zeros((4, 5), np.int32), past, tables4,
-        )
-    # the host accepts 1 and 3 of the inputs [last, d0..d3]
+    runner.verify_candidates(
+        last, drafts, dlens, np.zeros((4, 5, 2), np.int32),
+        np.zeros((4, 5), np.int32), past, tables4,
+    )
+    # the host accepts that many of the inputs [last, d0..d3]
     runner.commit_verified(np.array(accepted + [0, 0], np.int32))
     for b in (0, 1):
         n = starts[b] + accepted[b]
@@ -252,10 +251,11 @@ def test_a_verify_that_is_never_committed_leaves_the_state_alone(runner, step):
     table = table_of(1, 2, 3)
     tables4 = np.concatenate([table[None], np.zeros((3, MP), np.int32)])
     runner.prefill(seq[:12], table)
-    runner.verify_greedy(
+    runner.verify_candidates(
         np.array([seq[12], 0, 0, 0], np.int32), np.zeros((4, 4), np.int32),
-        np.array([4, 0, 0, 0], np.int32), np.array([12, 0, 0, 0], np.int32),
-        tables4,
+        np.array([4, 0, 0, 0], np.int32), np.zeros((4, 5, 2), np.int32),
+        np.zeros((4, 5), np.int32),                    # cand_n 0: no plan
+        np.array([12, 0, 0, 0], np.int32), tables4,
     )
     runner.commit_verified(np.zeros((4,), np.int32))   # nothing accepted
     got = step([seq[12]], [12], table)[0]

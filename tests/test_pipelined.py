@@ -3,8 +3,9 @@
 The pipelined path dispatches window k+1 off window k's device-resident
 tokens before window k's results reach the host. For greedy decoding the
 sampled tokens are rng-independent, so every row's output must be
-IDENTICAL to the synchronous (lookahead=1) path — including across slot
-reuse (rows finishing mid-pipeline and new rows admitted into their
+IDENTICAL to the same path at a depth of one (lookahead=1: dispatch and
+fetch in one iteration) and to single steps (decode_multi_step=1, which
+shares no window code) — including across slot reuse (rows finishing mid-pipeline and new rows admitted into their
 slots) and constrained rows forcing a mid-job drain.
 """
 
@@ -52,25 +53,32 @@ def _greedy_reqs(tok, texts, max_new):
     ]
 
 
-def test_pipelined_matches_sync_greedy():
+_STAGGERED = {}
+
+
+def _staggered(lookahead, multi=4):
+    """One run of the staggered greedy job per configuration."""
     texts = ["alpha", "bravo", "charlie", "delta", "echo", "foxtrot"]
     # staggered budgets force rows to finish mid-pipeline and slots to be
     # reused while windows for the old occupants are still in flight
     max_new = [5, 17, 9, 23, 7, 13]
+    if (lookahead, multi) not in _STAGGERED:
+        res = _run(
+            lookahead, lambda tok: _greedy_reqs(tok, texts, max_new),
+            multi=multi,
+        )
+        assert set(res) == set(range(len(texts)))
+        _STAGGERED[lookahead, multi] = {
+            i: (r.token_ids, r.finish_reason) for i, r in res.items()
+        }
+    return _STAGGERED[lookahead, multi]
 
-    def reqs(tok):
-        return _greedy_reqs(tok, texts, max_new)
 
-    sync = _run(1, reqs)
-    piped = _run(2, reqs)
-    assert set(sync) == set(piped) == set(range(len(texts)))
-    for i in sync:
-        assert piped[i].token_ids == sync[i].token_ids, f"row {i}"
-        assert piped[i].finish_reason == sync[i].finish_reason
-
-    deep = _run(3, reqs)
-    for i in sync:
-        assert deep[i].token_ids == sync[i].token_ids, f"row {i} (depth 3)"
+@pytest.mark.parametrize("depth", [2, 3])
+def test_pipelined_matches_sync_greedy(depth):
+    piped = _staggered(depth)
+    assert piped == _staggered(1), "against the same path at depth one"
+    assert piped == _staggered(1, multi=1), "against single steps"
 
 
 def test_pipelined_capacity_bounded():

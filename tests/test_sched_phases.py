@@ -60,14 +60,6 @@ def _requests(tok, schema=None, **kw):
     ]
 
 
-def _stub_drafts(monkeypatch):
-    """Every row always drafts, so the n-gram path engages."""
-    def stub(self, s, K):
-        return np.full((K,), s.last_token, np.int32)
-
-    monkeypatch.setattr(ContinuousBatcher, "_ngram_draft", stub)
-
-
 def _idle_session(b, seconds):
     """A held-open ctx with nothing pending: the loop dozes until the
     hold drops."""
@@ -100,14 +92,17 @@ PATHS = {
         dict(decode_multi_step=1), ENUMS,
         dict(max_new_tokens=80, temperature=0.0), {"fsm_mask": {}},
     ),
-    "spec": (
-        dict(spec_ngram_draft=6), None,
-        dict(max_new_tokens=16, temperature=0.0),
-        {"fsm_plan": {"engaged": True}},
+    # windows in flight: 1, the same path at a depth of one
+    "pipelined-depth-one": (
+        dict(decode_lookahead=1), None,
+        dict(max_new_tokens=24, temperature=0.7), {},
     ),
     "idle": (dict(), None, None, {"sched_idle": {}}),
 }
-COUNTED_AS = {"fastforward-failed-probe": "window"}
+COUNTED_AS = {
+    "fastforward-failed-probe": "window",
+    "pipelined-depth-one": "pipelined",
+}
 
 
 def _scheduler_spans():
@@ -122,12 +117,10 @@ def _scheduler_spans():
 
 
 @pytest.mark.parametrize("path", sorted(PATHS))
-def test_phases_tile_the_scheduler_thread(path, byte_tok, monkeypatch):
+def test_phases_tile_the_scheduler_thread(path, byte_tok):
     engine_kw, schema, req_kw, must_show = PATHS[path]
     telemetry.reset_for_tests()
     assert telemetry.enabled()
-    if path == "spec":
-        _stub_drafts(monkeypatch)
     b = ContinuousBatcher(
         ModelRunner(MODEL_CONFIGS["tiny-dense"], _ecfg(**engine_kw)),
         stop_ids=byte_tok.stop_ids(),
@@ -204,10 +197,14 @@ def test_timer_summary_is_bounded_and_exact_in_count_and_total():
     assert 1.0 <= summ["p50_ms"] <= 10.0 and summ["p99_ms"] <= 10.0
 
 
-def test_cursor_folds_tails_and_leaves_nest():
+def test_cursor_folds_tails_and_leaves_nest(monkeypatch):
     """``time`` switches and restores; the sliver of the outer phase
     after it joins what follows; a ``host_leaf`` is cut out of the
     phase around it; re-entering the running phase extends it."""
+    # the sliver is a few microseconds of this thread; on a loaded
+    # machine it now and then outlasts the 50 us rule (2 of 25 runs),
+    # and then it is a span of its own
+    monkeypatch.setattr(profiling, "FOLD_S", 1e-3)
     got = []
     timer = profiling.StepTimer(
         sink=lambda *a: got.append(a), cursor=True
