@@ -83,7 +83,6 @@ def main(which: str) -> int:
         "wall_s": round(time.monotonic() - t0, 1),
         "runner": runner.device_info(),
         "alloc_pages": runner.alloc_pages,
-        "kv_chunk": runner.kv_chunk,
         "bytes_in_use_per_device": in_use,
         "bytes_in_use_max_over_min": round(spread, 3),
         "kernel_paths": lowering.snapshot(),

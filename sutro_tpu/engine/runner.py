@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Any, Optional, Tuple
 
 import jax
@@ -51,6 +50,21 @@ def next_bucket(n: int, lo: int = 16, hi: int = 1 << 20) -> int:
 #: forwards, XLA scratch). At qwen3-4b on a 16 GB v5e the largest
 #: program temporaries measured ~2 GB (PERF.md "Bring-up"); 20% is 3.1 GB.
 HBM_RESERVE_FRACTION = 0.2
+
+
+def _pool_margin_pages(max_pages_per_seq: int, page_bytes: int) -> int:
+    """Pages a memory-bound pool leaves to the device beside the
+    reserve. The decode kernel's chunked fetch read up to a chunk (the
+    largest divisor of the table under 1 MiB) past a row's last page,
+    and the pool kept that many pages less one out of the allocators'
+    hands. Nothing reads past a row's pages now and the pool no longer
+    holds them; the count a pool that fills the device hands out stays
+    what it was (579 pages for qwen3-4b on one v5e), so that admission
+    is unchanged. Handing them out is ROADMAP 1.3's to measure."""
+    chunk = min(max_pages_per_seq, max(1, (1 << 20) // max(page_bytes, 1)))
+    while chunk > 1 and max_pages_per_seq % chunk:
+        chunk -= 1
+    return max(chunk, 1) - 1
 
 
 def resolve_pallas(
@@ -270,28 +284,13 @@ class ModelRunner:
             # re-uploads them
             params = jax.device_put(params)
         self.params = params
-        # contiguous-KV chunked fetch: pages per decode-kernel DMA when
-        # a batch's page runs are contiguous (contiguous-first
-        # allocators make that the common case). Beat the per-page walk
-        # on v5e (2521 vs 2430 tok/s, qwen3-0.6b, 2026-07); default ON,
-        # opt out with SUTRO_KV_CHUNK=0.
-        from ..ops.pallas_paged import chunk_pages_for
-
         tp = int(mesh.shape.get("model", 1)) if mesh is not None else 1
-        self.kv_chunk = (
-            chunk_pages_for(
-                ecfg.kv_page_size,
-                ecfg.max_pages_per_seq,
-                kv_heads=max(mcfg.num_kv_heads // tp, 1),
-                head_dim=mcfg.head_dim,
-                dtype_bytes=(
-                    1 if ecfg.kv_quantize == "int8" else dtype.itemsize
-                ),
-            )
-            if self.use_pallas
-            and os.environ.get("SUTRO_KV_CHUNK", "1") != "0"
-            else 1
-        )
+        self._margin_pages = _pool_margin_pages(
+            ecfg.max_pages_per_seq,
+            ecfg.kv_page_size * max(mcfg.num_kv_heads // tp, 1)
+            * mcfg.head_dim
+            * (1 if ecfg.kv_quantize == "int8" else dtype.itemsize),
+        ) if self.use_pallas else 0
         # pages the allocators may hand out (page 0 is the garbage
         # page). ``num_pages`` sizes it explicitly; otherwise it is the
         # worst case (every slot at full context), bounded by what the
@@ -301,11 +300,12 @@ class ModelRunner:
             num_pages if num_pages is not None
             else self._pages_that_fit(worst_case, dtype)
         )
-        # slack for the final chunk's masked over-read — these pages
-        # exist in the pool but are NEVER allocatable, so a run ending
-        # at the allocatable boundary still has kv_chunk-1 valid pages
-        # beyond it
-        self.num_pages = self.alloc_pages + self.kv_chunk - 1
+        # the decode kernel fetches a row's own pages and no other, so
+        # the pool holds what the allocators hand out and nothing more
+        self.num_pages = self.alloc_pages
+        # (fetched, needed) K/V pages of the decode dispatches since the
+        # last take_kv_pages
+        self._kv_pages = None
         self.cache = alloc_cache(
             mcfg, ecfg, self.num_pages, dtype=dtype,
             sharding=self._cache_sharding,
@@ -358,7 +358,7 @@ class ModelRunner:
         in_use = int(stats.get("bytes_in_use") or 0)
         reserve = int(limit * HBM_RESERVE_FRACTION)
         page = self._page_bytes_per_device(dtype)
-        fit = (limit - in_use - reserve) // page - (self.kv_chunk - 1)
+        fit = (limit - in_use - reserve) // page - self._margin_pages
         floor = 1 + self.ecfg.max_pages_per_seq
         if fit < floor:
             from .roofline import param_bytes_of
@@ -798,6 +798,10 @@ class ModelRunner:
         scanned forward, or the stage-local pipeline schedule under
         ``pipe > 1`` (parallel/pipeline.pipeline_decode).
 
+        ``kv_chunk`` selects nothing: the decode kernel has one fetch
+        schedule, read from ``past_len`` and the table. The name stays
+        because the benchmark passes it (perfbench/sut.py).
+
         ``pfx`` = tuple of (pages [Pp_g] int32, pfx_len [B] int32)
         groups enabling Hydragen-style split decode over job-shared
         table-head prefixes (ops/attention.py); the prefix cache is
@@ -827,33 +831,68 @@ class ModelRunner:
             window_past=window_past,
             use_pallas=self.use_pallas,
             kernel_mesh=self.kernel_mesh,
-            kv_chunk=kv_chunk,
             ep_mesh=self.ep_mesh,
             pfx_groups=pfx,
             conv_state=conv_state,
         )
 
     def _chunk_for_table(self, page_table: np.ndarray) -> int:
-        """Static pages-per-DMA for this decode batch: the configured
-        chunk when every row's table is one ascending run (zeros after),
-        else 1 (per-page walk). At most two kernel specializations."""
-        if self.kv_chunk <= 1:
-            return 1
-        t = np.asarray(page_table)
-        if t.ndim == 1:
-            t = t[None]
-        nxt, prev = t[:, 1:], t[:, :-1]
-        if bool(((nxt == prev + 1) | (nxt == 0)).all()):
-            return self.kv_chunk
+        """1, whatever the table: the decode kernel fetches page by page
+        in any layout (ops/pallas_paged.py). What is left of the choice
+        between a chunked and a per-page schedule, kept because the
+        benchmark calls it and hands the result to ``_trunk_decode``
+        (perfbench/sut.py)."""
         return 1
 
+    def _count_kv_pages(self, past_len, page_table, steps: int, pfx) -> None:
+        """K/V pages a decode dispatch's attention fetches against the
+        pages its rows' tokens fill, every step and attention layer of
+        it folded in. Host arithmetic from what the dispatch is given:
+        the Pallas kernel fetches a row's pages up to its last token's
+        (past its shared prefix under a prefix split, whose pages the
+        carry reads once for the batch); the gathered-page path fetches
+        every row's whole table."""
+        if not telemetry.ENABLED:
+            return
+        from ..ops import pallas_paged
+
+        PS = self.ecfg.kv_page_size
+        past = np.asarray(past_len, np.int64)
+        table = np.asarray(page_table)
+        needed = past / PS
+        head = jax.ShapeDtypeStruct((1, 1, self.mcfg.head_dim), jnp.float32)
+        if self.use_pallas and (
+            pallas_paged.paged_decode_supported(
+                head, self.cache.k_pages, table
+            )
+        ):
+            fetched = -(-past // PS)
+            if pfx and self.kernel_mesh is None:
+                shared = sum(np.asarray(n, np.int64) // PS for _, n in pfx)
+                fetched, needed = fetched - shared, needed - shared
+        else:
+            fetched = np.full(past.shape, table.shape[-1], np.int64)
+        times = float(steps * self.mcfg.num_attn_layers)
+        fetched = times * float(np.maximum(fetched, 0).sum())
+        needed = times * float(np.maximum(needed, 0).sum())
+        telemetry.KV_PAGES_FETCHED_TOTAL.inc(fetched)
+        telemetry.KV_PAGES_NEEDED_TOTAL.inc(needed)
+        f0, n0 = self._kv_pages or (0.0, 0.0)
+        self._kv_pages = (f0 + fetched, n0 + needed)
+
+    def take_kv_pages(self):
+        """``(fetched, needed)`` of the decode dispatches since the last
+        take (``_count_kv_pages``), or None when there were none."""
+        pages, self._kv_pages = self._kv_pages, None
+        return pages
+
     @functools.partial(
-        jax.jit, static_argnums=(0, 12), donate_argnums=(2,)
+        jax.jit, static_argnums=(0,), donate_argnums=(2,)
     )
     def _decode_jit(
         self, params, cache: KVCache, ids, past_len, page_table,
         rng, temperature, top_p, top_k, allowed_packed, row_seeds,
-        kv_chunk: int = 1, penalties=None, pfx=None,
+        penalties=None, pfx=None,
     ):
         B = ids.shape[0]
         allowed = None
@@ -866,7 +905,7 @@ class ModelRunner:
         positions = past_len[:, None]  # current token position == past length
         logits, _, (k, v) = self._trunk_decode(
             params, cache, ids, positions, past_len, page_table,
-            kv_chunk=kv_chunk, pfx=pfx,
+            pfx=pfx,
         )
         cache = write_kv(
             cache, k, v, page_table, past_len, jnp.ones((B,), jnp.int32),
@@ -928,6 +967,7 @@ class ModelRunner:
                 jnp.asarray(rep, jnp.float32),
             )
         self._count_state_commit("window")
+        self._count_kv_pages(past_len, page_table, 1, pfx)
         tok, logp, self.cache, self._route_dev = self._decode_jit(
             self.params,
             self.cache,
@@ -942,7 +982,6 @@ class ModelRunner:
             if allowed is None
             else jnp.asarray(np.packbits(np.asarray(allowed, bool), axis=1)),
             None if row_seeds is None else jnp.asarray(row_seeds, jnp.int32),
-            self._chunk_for_table(page_table),
             penalties,
             self._pfx_jnp(pfx),
         )
@@ -984,11 +1023,16 @@ class ModelRunner:
         carried window buffer ([L, B, steps, KVH*Dh] fused, in-place
         dynamic_update_slice) that attention reads alongside the pages,
         and the pool takes ONE bulk write per window out here where
-        donation makes it truly in-place."""
+        donation makes it truly in-place.
+
+        ``kv_chunk`` (static) selects nothing, like ``_trunk_decode``'s:
+        the benchmark's ahead-of-time compiles pass it by position
+        (tests/perfbench/test_aot_v5e.py)."""
+        del kv_chunk
         B = last.shape[0]
         toks, logps, wk, wv = self._window_scan(
             params, cache, last, past_len, page_table, rng,
-            temperature, top_p, steps, top_k, kv_chunk, pfx=pfx,
+            temperature, top_p, steps, top_k, pfx=pfx,
         )
         cache = write_kv(
             cache, wk, wv, page_table, past_len,
@@ -1002,7 +1046,7 @@ class ModelRunner:
     def _window_scan(
         self, params, cache: KVCache, last, past_len, page_table,
         rng, temperature, top_p, steps: int, top_k,
-        kv_chunk: int = 1, allowed0=None, pfx=None,
+        allowed0=None, pfx=None,
     ):
         """The shared fused-window scan: ``steps`` trunk forwards over
         invariant pages + the carried window buffer, sampling on-device.
@@ -1054,8 +1098,7 @@ class ModelRunner:
             logits, _, (k, v) = self._trunk_decode(
                 params, cache, last[:, None],
                 (past_len + step_idx)[:, None], past_len, page_table,
-                window_past=(wk, wv, step_idx), kv_chunk=kv_chunk,
-                pfx=pfx,
+                window_past=(wk, wv, step_idx), pfx=pfx,
                 conv_state=None if wc is None
                 else jax.lax.dynamic_slice_in_dim(wc, step_idx, K1, axis=2),
             )
@@ -1153,6 +1196,7 @@ class ModelRunner:
         # the window's routing counts stay on the device beside its
         # tokens; whoever fetches the tokens fetches them
         self._count_state_commit("window")
+        self._count_kv_pages(past_len, page_table, steps, pfx)
         toks, logps, self.cache, self.window_route = self._decode_multi_jit(
             self.params,
             self.cache,
@@ -1164,8 +1208,7 @@ class ModelRunner:
             jnp.asarray(top_p, jnp.float32),
             steps,
             jnp.asarray(top_k, jnp.int32),
-            self._chunk_for_table(page_table),
-            self._pfx_jnp(pfx),
+            pfx=self._pfx_jnp(pfx),
         )
         return toks, logps
 
@@ -1329,11 +1372,11 @@ class ModelRunner:
     # speculative window decode (constrained rows)
     # ------------------------------------------------------------------
 
-    @functools.partial(jax.jit, static_argnums=(0, 8, 11))
+    @functools.partial(jax.jit, static_argnums=(0, 8))
     def _decode_window_jit(
         self, params, cache: KVCache, last, past_len, page_table,
         rng, temperature, steps: int, top_p, top_k,
-        kv_chunk: int = 1, allowed0=None, pfx=None,
+        allowed0=None, pfx=None,
     ):
         """Like ``_decode_multi_jit`` but WITHOUT the page commit: the
         sampled window and its K/V buffers return to the host, which
@@ -1342,7 +1385,7 @@ class ModelRunner:
         read-only input here, so a rejected suffix costs nothing."""
         return self._window_scan(
             params, cache, last, past_len, page_table, rng,
-            temperature, top_p, steps, top_k, kv_chunk,
+            temperature, top_p, steps, top_k,
             allowed0=allowed0, pfx=pfx,
         )
 
@@ -1379,6 +1422,7 @@ class ModelRunner:
         B = len(last_tokens)
         if top_k is None:
             top_k = np.zeros((B,), np.int32)
+        self._count_kv_pages(past_len, page_table, steps, pfx)
         toks, logps, wk, wv = self._decode_window_jit(
             self.params,
             self.cache,
@@ -1390,7 +1434,6 @@ class ModelRunner:
             steps,
             jnp.asarray(top_p, jnp.float32),
             jnp.asarray(top_k, jnp.int32),
-            self._chunk_for_table(page_table),
             None if allowed0 is None else jnp.asarray(allowed0, bool),
             self._pfx_jnp(pfx),
         )

@@ -462,9 +462,8 @@ class ContinuousBatcher:
         # or SUTRO_NATIVE_RUNTIME=0.
         from .native_runtime import maybe_native_runtime
 
-        # allocators see alloc_pages, NOT num_pages: the difference is
-        # the chunked-DMA over-read slack at the pool end, which must
-        # stay unallocatable (runner._chunk_for_table / pallas_paged)
+        # what the runner's pool hands out (runner.alloc_pages; a stub
+        # runner has only num_pages)
         alloc_pages = getattr(runner, "alloc_pages", runner.num_pages)
         self.native = maybe_native_runtime(
             alloc_pages, self.B, self.MP, self.ecfg.kv_page_size,
@@ -638,6 +637,17 @@ class ContinuousBatcher:
         extra = self._tel_attrs.get(stage)
         if attrs:
             extra = {**extra, **attrs} if extra else attrs
+        if stage == "decode_window":
+            # what the dispatches made under this span fetched of the
+            # pool, and what their rows' tokens fill (runner)
+            take = getattr(self.runner, "take_kv_pages", None)
+            pages = take() if take is not None else None
+            if pages is not None:
+                extra = {
+                    **(extra or {}),
+                    "kv_pages_fetched": round(pages[0], 1),
+                    "kv_pages_needed": round(pages[1], 1),
+                }
         rec = dict(extra) if extra else {}
         # wall minus CPU in a pure-Python phase is time the scheduler
         # thread waited for the GIL (prep thread, tokenizer, streams)

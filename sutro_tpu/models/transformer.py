@@ -402,7 +402,7 @@ def attention_mixer(
     layer=None,                  # this layer's index INTO THE POOL
     page_table=None, past_len=None, use_pallas: bool = False,
     ring_mesh=None, wk_l=None, wv_l=None, win_len=None,
-    kv_chunk: int = 1, pfx_groups=None, kernel_mesh=None,
+    pfx_groups=None, kernel_mesh=None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """GQA attention over the chunk and its paged past, through the
     output projection: ``(out [B, T, H], (k_chunk, v_chunk))``. The one
@@ -434,7 +434,6 @@ def attention_mixer(
         use_pallas=use_pallas,
         ring_mesh=ring_mesh,
         win_k=wk_l, win_v=wv_l, win_len=win_len,
-        kv_chunk=kv_chunk,
         pfx_groups=pfx_groups,
         kernel_mesh=kernel_mesh,
     )
@@ -503,7 +502,6 @@ def layer_apply(
     wk_l: Optional[jax.Array] = None,   # this layer's fused-decode
     wv_l: Optional[jax.Array] = None,   # window buffer [B, W, KVH*Dh]
     win_len: Optional[jax.Array] = None,
-    kv_chunk: int = 1,
     ep_mesh=None,  # Mesh with "expert" axis > 1 => shard_map EP MLP
     pfx_groups: Optional[tuple] = None,  # shared-prefix decode groups
     #                                      (ops/attention.py)
@@ -524,7 +522,7 @@ def layer_apply(
         page_table=page_table, past_len=past_len,
         use_pallas=use_pallas, ring_mesh=ring_mesh,
         wk_l=wk_l, wv_l=wv_l, win_len=win_len,
-        kv_chunk=kv_chunk, pfx_groups=pfx_groups,
+        pfx_groups=pfx_groups,
         kernel_mesh=kernel_mesh,
     )
     if cfg.post_norms:
@@ -613,7 +611,7 @@ def _index_in_kind(kinds) -> List[int]:
 def _mixed_trunk(
     cfg: ModelConfig, params: Params, h: jax.Array, *,
     positions, valid_len, conv_state, k_pages, v_pages, k_scale, v_scale,
-    page_table, past_len, window_past, use_pallas, kv_chunk, ep_mesh,
+    page_table, past_len, window_past, use_pallas, ep_mesh,
     pfx_groups, kernel_mesh,
 ):
     """The walk over a config's own list of layers, parameters stacked
@@ -663,7 +661,7 @@ def _mixed_trunk(
                     else window_past[0][m_idx],
                     wv_l=None if window_past is None
                     else window_past[1][m_idx],
-                    win_len=win_len, kv_chunk=kv_chunk,
+                    win_len=win_len,
                     pfx_groups=pfx_groups, kernel_mesh=kernel_mesh,
                 )
         h = h + y
@@ -835,7 +833,6 @@ def forward(
     # win_len scalar) — K/V of window tokens not yet in the page pool
     # (runner.decode_multi writes pages once per window, not per step)
     window_past: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
-    kv_chunk: int = 1,  # static: pages per decode-kernel DMA
     ep_mesh=None,  # Mesh with "expert" axis > 1 => shard_map EP MLP
     # shared-prefix decode (Hydragen-style carry injection, see
     # ops/attention.py): tuple of (pages [Pp_g], pfx_len [B]) groups —
@@ -882,7 +879,7 @@ def forward(
             k_scale=k_scale, v_scale=v_scale,
             page_table=page_table, past_len=past_len,
             window_past=window_past, use_pallas=use_pallas,
-            kv_chunk=kv_chunk, ep_mesh=ep_mesh,
+            ep_mesh=ep_mesh,
             pfx_groups=pfx_groups, kernel_mesh=kernel_mesh,
         )
         out, h = head_apply(cfg, params, h, valid_len, logit_positions)
@@ -909,7 +906,7 @@ def forward(
             page_table=page_table, past_len=past_len,
             use_pallas=use_pallas, ring_mesh=ring_mesh,
             wk_l=wk_l, wv_l=wv_l, win_len=win_len,
-            kv_chunk=kv_chunk, ep_mesh=ep_mesh,
+            ep_mesh=ep_mesh,
             pfx_groups=pfx_groups, kernel_mesh=kernel_mesh,
         )
 

@@ -23,8 +23,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -131,8 +129,6 @@ def chunk_attention(
     win_k: Optional[jax.Array] = None,
     win_v: Optional[jax.Array] = None,
     win_len: Optional[jax.Array] = None,
-    kv_chunk: int = 1,  # static: pages per decode-kernel DMA (>1 means
-                        # the caller guarantees contiguous page runs)
     # shared-prefix (Hydragen-style) decode: each group is a
     # ``(pages [Pp_g] int32, pfx_len [B] int32)`` pair — member rows'
     # tables START with the group's shared pages (pfx_len 0 = row not
@@ -202,10 +198,7 @@ def chunk_attention(
                 )
                 out = lowering.shard_over_model(
                     kernel_mesh,
-                    functools.partial(
-                        paged_decode_attention,
-                        kv_chunk=1 if split else kv_chunk,
-                    ),
+                    paged_decode_attention,
                     ops, _PAGED_SPECS, P(None, "model", None),
                 )
                 return out[:, None]

@@ -6,24 +6,37 @@ view of the page pool per layer — a pure HBM copy that dominates decode
 time. This kernel reads K/V pages **in place** with flash-style online
 softmax across pages.
 
-Design (second generation — the first used grid ``(B, MP)`` with one
-BlockSpec-fetched page per grid step, which cost a block DMA for every
-table slot, used or not, and ~µs of grid overhead per tiny block; at
-28 layers x B=64 x MP=8 that grid tax dominated the whole decode step):
+Design (third generation; the first used grid ``(B, MP)`` with one
+BlockSpec-fetched page per grid step and paid a block DMA and ~us of
+grid overhead for every table slot, used or not; the second walked the
+pages inside the kernel but fetched a FIXED chunk of pages a row, two
+slots to a row, and started every row from an empty pipeline: a
+280-token row fetched 1 MiB and computed over 512 or 1,024 masked
+columns, and its fetch and its arithmetic ran one after the other,
+PERF.md §6 PR 31):
 
-- grid ``(B,)``: one grid step per decode row;
-- the page walk lives INSIDE the kernel as a ``fori_loop`` bounded by the
-  row's ACTUAL page count (``ceil(past_len/PS)``) — unused table slots
-  cost nothing;
+- grid ``(B,)``: one grid step per decode row, in order;
 - the operand is the WHOLE stacked pool ``[L, NP, PS, KVH*Dh]``
   (``memory_space=ANY``, HBM-resident) and the layer is a scalar-prefetch
-  index: pages are fetched as ``pool.at[layer, page]`` with
-  double-buffered ``make_async_copy`` (the DMA for page ``i+1`` overlaps
-  compute on page ``i``). No caller slices a layer out of the stack: XLA
-  cannot fuse a slice into a custom call's operand and would copy the
-  layer's pool (76 MB at 579 pages) before every call;
-- KV heads are processed by a static in-kernel loop, one ``[G, PS]``
-  score tile per head, accumulating ``(m, l, acc)`` in VMEM scratch;
+  index: pages are fetched as ``pool.at[layer, page]``. No caller slices
+  a layer out of the stack: XLA cannot fuse a slice into a custom call's
+  operand and would copy the layer's pool (76 MB at 579 pages) before
+  every call;
+- **bytes follow the row**: a row fetches ``ceil(past_len / PS)`` pages
+  (from ``pfx_cnt`` on under a prefix split), one DMA a page (a page is
+  one contiguous block of the fused pool), through its table, so any
+  layout is the same code: an ascending run, scattered pages, a shared
+  prefix at the table's head;
+- **the ring does not drain**: the call's fetches are ONE sequence over
+  (row, page), and ``ring_shape`` slots of it are in flight. The cursor
+  lives in SMEM from grid step to grid step; when a row's last page has
+  been started the ring goes on with the next row's first pages, so a
+  row's arithmetic, its finalize and the grid's step to the next row run
+  under the fetches of the rows after it;
+- **arithmetic follows the row**: scores and the value product run over
+  the groups of pages that landed (``GROUP_TOKENS`` columns a group),
+  one ``[NH, GT]`` block-diagonal score matmul and one value matmul a
+  group for all KV heads, accumulating ``(m, l, acc)`` in VMEM scratch;
 - the current token's K/V, the optional multi-step decode window buffer
   (tokens sampled in the current fused window, not yet written to the
   pool — see engine/runner.decode_multi), and the optional gpt-oss
@@ -61,9 +74,9 @@ def _paged_decode_kernel(
     page_size: int,
     scale: float,
     kvh: int,
+    ring_pages: int,
+    group_pages: int,
     window_slots: int = 0,
-    chunk_pages: int = 1,
-    cross_row: bool = False,
     quantized: bool = False,
     prefix: bool = False,
 ):
@@ -98,22 +111,25 @@ def _paged_decode_kernel(
     vsbuf = next(it) if quantized else None
     kssem = next(it) if quantized else None
     vssem = next(it) if quantized else None
+    ring = next(it)
     m_ref = next(it)
     l_ref = next(it)
     acc_ref = next(it)
 
     b = pl.program_id(0)
+    B = pl.num_programs(0)
     MP = max_pages_per_seq
     PS = page_size
-    CH = chunk_pages
-    CT = CH * PS  # tokens per fetched chunk
+    D = ring_pages
+    GP = group_pages  # pages of the largest group, a power of two
+    # GP, GP/2, ... 1
+    group_sizes = [GP >> i for i in range(GP.bit_length())]
     NH = q_ref.shape[1]
     Dh = q_ref.shape[2]
     G = NH // kvh
     KD = kvh * Dh
 
     past = past_len_ref[b]
-    nchunks = (past + CT - 1) // CT
     # current token's global position: tokens already in pages plus any
     # fused-window tokens not yet written back
     pos = past + (win_len_ref[0] if window_slots else 0)
@@ -122,14 +138,106 @@ def _paged_decode_kernel(
     # every DMA below indexes [layer, page] itself
     layer = layer_ref[0]
 
+    # -- the fetch ring ------------------------------------------------
+    # The call's fetches are ONE sequence: row 0's pages, then row 1's,
+    # ... each row's from its first page (past the shared prefix, whose
+    # carry arrives computed) to the page that holds its last token.
+    # ``ring`` (SMEM, kept from grid step to grid step) is the cursor:
+    # [0] the row and [1] the page of the next fetch to start, [2] its
+    # slot number, [3] the slot number the consumer has read up to.
+    # Slot numbers only grow; slot number t lives in ring slot t % D. A
+    # row's pages take consecutive slot numbers from a multiple of the
+    # power of two that covers them (at most GP), so that every group
+    # the arithmetic takes (below) is one contiguous slab of the ring,
+    # aligned to its own size; the numbers that alignment skips are
+    # not fetched.
+
+    def first_page(row):
+        return pfx_cnt_ref[row] if prefix else 0
+
+    def pages_of(row):
+        """Pages row fetches: up to its last token's, from first_page."""
+        n = (past_len_ref[row] + PS - 1) // PS - first_page(row)
+        return jnp.maximum(n, 0)
+
+    def aligned(t, n):
+        """``t`` rounded up to where a row of ``n`` pages may start."""
+        a = jnp.int32(1)
+        for size in group_sizes[::-1][1:]:
+            a = jnp.where(n > size // 2, size, a)
+        return (t + a - 1) // a * a
+
+    def page_dmas(row, j, t):
+        """The copies of the j-th page ``row`` fetches into the ring
+        slot of slot number ``t``: K, V and, under int8, their scales
+        (pre-shaped [L, NP, 1, PS] so a page's scales land lane-major,
+        a legal [1, PS] broadcast against a score slice; merging
+        sublanes into lanes in-kernel is unsupported)."""
+        s = jax.lax.rem(t, D)
+        page = page_table_ref[row * MP + first_page(row) + j]
+        dmas = [
+            pltpu.make_async_copy(
+                k_pool_ref.at[layer, page], kbuf.at[s], ksem.at[s]
+            ),
+            pltpu.make_async_copy(
+                v_pool_ref.at[layer, page], vbuf.at[s], vsem.at[s]
+            ),
+        ]
+        if quantized:
+            dmas += [
+                pltpu.make_async_copy(
+                    ks_pool_ref.at[layer, page], ksbuf.at[s], kssem.at[s]
+                ),
+                pltpu.make_async_copy(
+                    vs_pool_ref.at[layer, page], vsbuf.at[s], vssem.at[s]
+                ),
+            ]
+        return dmas
+
+    def top_up(limit):
+        """Start fetches, in sequence order, while their slot number is
+        under ``limit`` (the consumer's position + D: the slot's last
+        occupant has been read) and rows remain. It runs on from a
+        row's last page into the next row's first, over rows that fetch
+        nothing, so the ring never drains between rows."""
+
+        def more(c):
+            row, _, t = c
+            return jnp.logical_and(row < B, t < limit)
+
+        def step(c):
+            row, j, t = c
+            n = pages_of(row)
+            t = jnp.where(j == 0, aligned(t, n), t)
+            go = jnp.logical_and(j < n, t < limit)
+
+            @pl.when(go)
+            def _start():
+                for dma in page_dmas(row, j, t):
+                    dma.start()
+
+            started = go.astype(jnp.int32)
+            j, t = j + started, t + started
+            done = j >= n
+            return jnp.where(done, row + 1, row), jnp.where(done, 0, j), t
+
+        row, j, t = jax.lax.while_loop(
+            more, step, (ring[0], ring[1], ring[2])
+        )
+        ring[0], ring[1], ring[2] = row, j, t
+
+    @pl.when(b == 0)
+    def _open_ring():
+        for i in range(4):
+            ring[i] = 0
+
     # Block-diagonal queries: fold the per-KV-head loop into ONE score
-    # matmul and ONE value matmul per chunk. Row i (= head i, KV head
+    # matmul and ONE value matmul per group. Row i (= head i, KV head
     # i // G) of q_bd carries q[i] in column block i // G of the fused
-    # [KVH*Dh] axis and zeros elsewhere, so q_bd @ k_chunk.T computes
+    # [KVH*Dh] axis and zeros elsewhere, so q_bd @ k_group.T computes
     # every head's scores in a single MXU op (the off-block FLOPs are
-    # wasted but free — the kernel is bound by op count / latency, not
-    # MXU throughput: 2*KVH tiny per-head dots per chunk cost ~3x more
-    # wall time than these two). Mosaic cannot merge (KVH, Dh) into the
+    # wasted: 2*KVH tiny per-head dots per group cost ~3x more wall
+    # time than these two). Mosaic cannot merge (KVH, Dh) into the
     # lane dim in-kernel, so the page pool arrives pre-fused [.., KD]
     # and lane-space masks are built from iota instead of reshapes.
     q = q_ref[0].astype(jnp.float32)                      # [NH, Dh]
@@ -138,27 +246,22 @@ def _paged_decode_kernel(
     blk_kd = (row_head == col_head).astype(jnp.float32)   # [NH, KD]
     q_rep = jnp.concatenate([q] * kvh, axis=1)            # [NH, KD]
     q_bd = q_rep * blk_kd
-    # selector S[kd, d] = (kd % Dh == d): one dot extracts each row's
-    # own head block from fused-lane space back to [NH, Dh]
-    sel_kd = jax.lax.broadcasted_iota(jnp.int32, (KD, Dh), 0)
-    sel_d = jax.lax.broadcasted_iota(jnp.int32, (KD, Dh), 1)
-    S = (sel_kd % Dh == sel_d).astype(jnp.float32)        # [KD, Dh]
 
     # Shared-prefix (Hydragen-style) mode: the first pfx_cnt pages of
     # this row's table hold a prefix whose K/V is SHARED with other
     # rows. Their attention was computed ONCE for the whole batch
     # outside the kernel (prefix_attention_carry — the pages are read
     # from HBM once instead of once per row) and arrives as the initial
-    # online-softmax carry; the page walk below starts AFTER them.
+    # online-softmax carry; the row's fetches start AFTER them.
     # Non-member rows carry (m=-inf, l=0, acc=0) — exactly the cold
     # init — and start at page 0. Online softmax is associative, so the
     # result is bit-comparable to walking the prefix pages in-row.
     if prefix:
         m_ref[...] = jnp.broadcast_to(
-            m0_ref[0][:, None].astype(jnp.float32), m_ref.shape
+            m0_ref[0, 0][:, None].astype(jnp.float32), m_ref.shape
         )
         l_ref[...] = jnp.broadcast_to(
-            l0_ref[0][:, None].astype(jnp.float32), l_ref.shape
+            l0_ref[0, 0][:, None].astype(jnp.float32), l_ref.shape
         )
         acc_ref[...] = acc0_ref[0].astype(jnp.float32)
     else:
@@ -166,127 +269,31 @@ def _paged_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # CH == 1: each chunk is one table-walked page (any layout).
-    # CH > 1: the row's pages are one ascending run (contiguous-first
-    # allocator) — chunk i is pages [start + i*CH, start + (i+1)*CH),
-    # ONE DMA for CH pages instead of CH DMAs. The caller guarantees
-    # CH-1 slack pages at the pool end so the final chunk's over-read
-    # stays in bounds (over-read tokens are masked by ``tok < past``).
-    #
-    # cross_row: row b also starts row b+1's FIRST chunk after its own
-    # page walk drains (all kbuf/vbuf reads done), so the next grid
-    # step's warmup latency hides behind this row's finalize + the grid
-    # transition. Slots are row-parity offset (chunk i of row r lives in
-    # slot (r+i)%2) so the handed-over chunk lands where the next row's
-    # walk expects it and never collides with a buffer still being read.
-    # Requires "arbitrary" grid semantics (cross-step scratch flow).
+    n_pages = pages_of(b)
+    i0 = first_page(b)
+    t0 = aligned(ring[3], n_pages)  # this row's first slot number
 
-    def _slot(row, i):
-        return jax.lax.rem(row + i, 2) if cross_row else jax.lax.rem(i, 2)
+    # Arithmetic follows the row: its pages are taken in groups of the
+    # sizes of their count's binary digits, largest first (13 pages: a
+    # group of 8, of 4, of 1, when GP is 8), so that no column past the
+    # row's last page is computed and the m / l / acc update is paid a
+    # few times a row, not once a page. One body a size.
 
-    def k_dma(row, i, slot):
-        if CH == 1:  # per-page walk: any table layout
-            return pltpu.make_async_copy(
-                k_pool_ref.at[layer, page_table_ref[row * MP + i]],
-                kbuf.at[slot, 0],
-                ksem.at[slot],
-            )
-        return pltpu.make_async_copy(
-            k_pool_ref.at[
-                layer, pl.ds(page_table_ref[row * MP] + i * CH, CH)
-            ],
-            kbuf.at[slot],
-            ksem.at[slot],
-        )
-
-    def v_dma(row, i, slot):
-        if CH == 1:
-            return pltpu.make_async_copy(
-                v_pool_ref.at[layer, page_table_ref[row * MP + i]],
-                vbuf.at[slot, 0],
-                vsem.at[slot],
-            )
-        return pltpu.make_async_copy(
-            v_pool_ref.at[
-                layer, pl.ds(page_table_ref[row * MP] + i * CH, CH)
-            ],
-            vbuf.at[slot],
-            vsem.at[slot],
-        )
-
-    def _scale_dmas(row, i, slot):
-        # int8 KV: the per-token dequant scales ride their own (tiny)
-        # DMAs — pools arrive pre-shaped [L, NP, 1, PS] so the fetched
-        # chunk lands lane-major [CH, 1, PS] and each page's scale row
-        # is a legal [1, PS] broadcast against a score slice (merging
-        # sublanes into lanes in-kernel is unsupported)
-        if CH == 1:
-            return (
-                pltpu.make_async_copy(
-                    ks_pool_ref.at[layer, page_table_ref[row * MP + i]],
-                    ksbuf.at[slot, 0],
-                    kssem.at[slot],
-                ),
-                pltpu.make_async_copy(
-                    vs_pool_ref.at[layer, page_table_ref[row * MP + i]],
-                    vsbuf.at[slot, 0],
-                    vssem.at[slot],
-                ),
-            )
-        start = page_table_ref[row * MP] + i * CH
-        return (
-            pltpu.make_async_copy(
-                ks_pool_ref.at[layer, pl.ds(start, CH)],
-                ksbuf.at[slot],
-                kssem.at[slot],
-            ),
-            pltpu.make_async_copy(
-                vs_pool_ref.at[layer, pl.ds(start, CH)],
-                vsbuf.at[slot],
-                vssem.at[slot],
-            ),
-        )
-
-    def _start_chunk(row, i, slot):
-        k_dma(row, i, slot).start()
-        v_dma(row, i, slot).start()
-        if quantized:
-            for dma in _scale_dmas(row, i, slot):
-                dma.start()
-
-    def _chunks_of(row):
-        return (past_len_ref[row] + CT - 1) // CT
-
-    # shared-prefix mode: skip the prefix pages (their carry was
-    # injected above). Requires CH == 1 and no cross_row (wrapper
-    # enforces both), so chunk index == page index.
-    i0 = pfx_cnt_ref[b] if prefix else 0
-
-    # warmup: row 0 fetches its own first chunk; under cross_row every
-    # later row's first chunk was started by its predecessor
-    self_warm = (b == 0) if cross_row else (nchunks > i0)
-
-    @pl.when(jnp.logical_and(self_warm, nchunks > i0))
-    def _warmup():
-        _start_chunk(b, i0, _slot(b, i0))
-
-    def page_step(i, _):
-        slot = _slot(b, i)
-        nxt = _slot(b, i + 1)
-
-        @pl.when(i + 1 < nchunks)
-        def _prefetch_next():
-            _start_chunk(b, i + 1, nxt)
-
-        k_dma(b, i, slot).wait()
-        v_dma(b, i, slot).wait()
-        if quantized:
-            for dma in _scale_dmas(b, i, slot):
+    def group(done, size):
+        """Scores and values of ``size`` pages from the row's
+        ``done``-th on, folded into (m, l, acc)."""
+        GT = size * PS
+        t = t0 + done
+        # the pages before this group have been read: refill their slots
+        top_up(t + D)
+        for o in range(size):
+            for dma in page_dmas(b, done + o, t + o):
                 dma.wait()
-
-        chunk_start = i * CT
-        tok = chunk_start + jax.lax.broadcasted_iota(
-            jnp.int32, (NH, CT), 1
+        # the row started at a multiple of a power of two >= size, and
+        # the larger groups came first: the slab is aligned to ``size``
+        slot = pl.multiple_of(jax.lax.rem(t, D), size)
+        tok = (i0 + done) * PS + jax.lax.broadcasted_iota(
+            jnp.int32, (NH, GT), 1
         )
         ok = tok < past
         # windowless (win <= 0) ORed in instead of a boolean select —
@@ -294,22 +301,22 @@ def _paged_decode_kernel(
         ok = jnp.logical_and(
             ok, jnp.logical_or(pos - tok < win, win <= 0)
         )
-        # [CH, PS, KD] -> [CT, KD]: leading-dim collapse only (the lane
-        # dim KD is untouched — Mosaic supports this shape cast)
-        k = kbuf[slot].reshape(CT, KD).astype(jnp.float32)
-        v = vbuf[slot].reshape(CT, KD).astype(jnp.float32)
+        # [size, PS, KD] -> [GT, KD]: leading-dim collapse only (the
+        # lane dim KD is untouched — Mosaic supports this shape cast)
+        k = kbuf[pl.ds(slot, size)].reshape(GT, KD).astype(jnp.float32)
+        v = vbuf[pl.ds(slot, size)].reshape(GT, KD).astype(jnp.float32)
         s = jax.lax.dot_general(
             q_bd, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale                                        # [NH, CT]
+        ) * scale                                        # [NH, GT]
         if quantized:
             # K dequant folds into the scores: q.(k_int*ks) = (q.k_int)*ks
             # — one [1, PS] lane-broadcast multiply per page of the
-            # chunk (CH is static), lane-concatenated back to [NH, CT]
+            # group, lane-concatenated back to [NH, GT]
             s = jnp.concatenate(
                 [
-                    s[:, pg * PS : (pg + 1) * PS] * ksbuf[slot, pg]
-                    for pg in range(CH)
+                    s[:, pg * PS : (pg + 1) * PS] * ksbuf[slot + pg]
+                    for pg in range(size)
                 ],
                 axis=1,
             )
@@ -318,7 +325,7 @@ def _paged_decode_kernel(
         m_prev = m_ref[:, 0]                             # [NH]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
         alpha = jnp.exp(m_prev - m_new)                  # [NH]
-        p = jnp.exp(s - m_new[:, None])                  # [NH, CT]
+        p = jnp.exp(s - m_new[:, None])                  # [NH, GT]
         l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
         l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
         if quantized:
@@ -327,8 +334,8 @@ def _paged_decode_kernel(
             # p.(v_int*vs) = (p*vs).v_int
             pv = jnp.concatenate(
                 [
-                    p[:, pg * PS : (pg + 1) * PS] * vsbuf[slot, pg]
-                    for pg in range(CH)
+                    p[:, pg * PS : (pg + 1) * PS] * vsbuf[slot + pg]
+                    for pg in range(size)
                 ],
                 axis=1,
             )
@@ -341,23 +348,25 @@ def _paged_decode_kernel(
             preferred_element_type=jnp.float32,
         )
         m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
+
+    def whole_groups(gi, _):
+        group(gi * GP, GP)
         return 0
 
-    jax.lax.fori_loop(i0, nchunks, page_step, 0)
+    n_whole = n_pages // GP
+    jax.lax.fori_loop(0, n_whole, whole_groups, 0)
+    for size in group_sizes[1:]:
+        # the pages under this binary digit of the row's count
 
-    if cross_row:
-        # hand off: start the NEXT row's first chunk now that every DMA
-        # of this row has been waited (both slots idle). The matching
-        # wait is the next grid step's page_step(0) on slot (b+1)%2 —
-        # predicated on the same ``nchunks > 0`` so semaphores balance.
-        nb = b + 1
-        # clamp the probe: logical_and evaluates both operands, so the
-        # last row must not read past_len_ref[B] (OOB SMEM on hardware)
-        nb_c = jnp.minimum(nb, pl.num_programs(0) - 1)
+        @pl.when(jax.lax.rem(n_pages, 2 * size) >= size)
+        def _digit(size=size):
+            group(n_pages // (2 * size) * (2 * size), size)
 
-        @pl.when(jnp.logical_and(nb < pl.num_programs(0), _chunks_of(nb_c) > 0))
-        def _handoff():
-            _start_chunk(nb, 0, _slot(nb, 0))
+    # the row's pages have been read: the fetches that take their slots
+    # are the next rows', and they run under this row's finalize and the
+    # grid's step to the next row
+    ring[3] = t0 + n_pages
+    top_up(ring[3] + D)
 
     # finalize: fused-window tokens + current token + attention sink,
     # in the same block-diagonal space (2 dots total, not 2 per head)
@@ -366,10 +375,8 @@ def _paged_decode_kernel(
     v_cur = v_cur_ref[0].astype(jnp.float32)             # [1, KD]
     sink = sink_ref[0].astype(jnp.float32)               # [NH]
 
-    s_self = jax.lax.dot_general(
-        q_bd, k_cur, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0] * scale                                      # [NH]
+    # one key: a lane reduction, not a matmul with a single column
+    s_self = jnp.sum(q_bd * k_cur, axis=1) * scale       # [NH]
     m_prev = m_ref[:, 0]
     m_new = jnp.maximum(m_prev, jnp.maximum(s_self, sink))
     if W:
@@ -403,11 +410,13 @@ def _paged_decode_kernel(
             preferred_element_type=jnp.float32,
         )
     # extract each row's own head block from the block-diagonal acc:
-    # zero the off-blocks, then sum the lane blocks with the selector dot
-    acc_bd = jax.lax.dot_general(
-        acc * blk_kd, S, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                    # [NH, Dh]
+    # lane block j belongs to the query heads of KV head j
+    own = jax.lax.broadcasted_iota(jnp.int32, (NH, Dh), 0) // G
+    acc_bd = jnp.zeros((NH, Dh), jnp.float32)
+    for j in range(kvh):
+        acc_bd = acc_bd + jnp.where(
+            own == j, acc[:, j * Dh : (j + 1) * Dh], 0.0
+        )
     out = acc_bd / jnp.maximum(l, 1e-30)[:, None]
     out_ref[0] = out.astype(out_ref.dtype)
 
@@ -683,37 +692,33 @@ PALLAS_PAGED_MIN_CTX = int(
     os.environ.get("SUTRO_PAGED_MIN_CTX", "0")
 )
 
-# Cross-row DMA warmup: each row starts the next row's first chunk as
-# soon as its own page walk drains, hiding per-row first-fetch latency
-# behind finalize + grid transition. Costs "arbitrary" grid semantics
-# (rows run sequentially on one core) — free on single-TensorCore chips
-# (v5e); on megacore parts (v4/v5p) "parallel" row-splitting may win
-# instead. Default OFF until chip-validated (interpret mode cannot model
-# DMA/semaphore timing): SUTRO_KV_XROW=1 enables.
-PALLAS_PAGED_XROW = os.environ.get("SUTRO_KV_XROW", "0") == "1"
+# The fetch ring's size. Pages in flight cost VMEM (K and V a slot) and
+# buy cover for the DMA latency: ~2 us x 819 GB/s = 1.6 MB in flight
+# keeps v5e's HBM busy. 4 MiB is what the two 1 MiB chunk slots of the
+# second-generation schedule cost; 32 slots bound the semaphores where
+# pages are small (a tp=4 shard's 32 KB page: 2 MiB in flight).
+RING_BYTES = 4 << 20
+RING_MAX_PAGES = 32
+# Score columns of the largest group the arithmetic takes at once. A
+# group costs about the same whatever its width up to here (the chain
+# matmul, max, exp, sum, matmul, rescale is latency, PERF.md §6 PR 31),
+# so a row is taken in as few groups as its page count has binary
+# digits; past 512 columns the float32 operands of one group outgrow
+# what is worth keeping in VMEM beside the ring.
+GROUP_TOKENS = 512
 
 
-def chunk_pages_for(
-    page_size: int,
-    max_pages_per_seq: int,
-    kv_heads: int = 8,
-    head_dim: int = 128,
-    dtype_bytes: int = 2,
-    budget_bytes: int = 1 << 20,
-) -> int:
-    """Pages fetched per DMA in contiguous-KV mode: the largest divisor
-    of MP whose chunk stays under ``budget_bytes`` PER double-buffer
-    slot (4 buffers total: K+V x 2 slots — 1 MiB each keeps the scratch
-    well inside ~16 MiB VMEM alongside m/l/acc). Callers enabling
-    chunked fetch must (a) allocate slots as contiguous page runs and
-    (b) leave ``chunk-1`` unallocatable slack pages at the pool end for
-    the final chunk's masked over-read (engine/runner)."""
-    page_bytes = max(page_size * kv_heads * head_dim * dtype_bytes, 1)
-    budget = max(1, budget_bytes // page_bytes)
-    ch = min(max_pages_per_seq, budget)
-    while ch > 1 and max_pages_per_seq % ch:
-        ch -= 1
-    return max(ch, 1)
+def ring_shape(page_size: int, kd: int, dtype_bytes: int, max_pages: int):
+    """``(ring_pages, group_pages)`` of the fetch ring for pages of
+    ``[page_size, kd]``: the pages of the largest compute group (a
+    power of two, at most a row's table) and the ring's slots (a
+    multiple of that group, at least two of it so that one lands while
+    one is read)."""
+    group = max(1, min(GROUP_TOKENS // page_size, max_pages))
+    group = 1 << (group.bit_length() - 1)
+    page_bytes = max(page_size * kd * dtype_bytes, 1)
+    pages = min(RING_BYTES // (2 * page_bytes), RING_MAX_PAGES)
+    return max(pages // group, 2) * group, group
 
 
 def paged_decode_supported(
@@ -730,10 +735,7 @@ def paged_decode_supported(
     )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("kv_chunk", "interpret", "cross_row"),
-)
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(
     q: jax.Array,          # [B, NH, Dh] — current-step queries
     k_pages: jax.Array,    # [L, NP, PS, KVH*Dh] — the stacked FUSED pool
@@ -749,9 +751,7 @@ def paged_decode_attention(
     win_v: Optional[jax.Array] = None,
     win_len: Optional[jax.Array] = None,  # scalar int32 — valid slots
     *,
-    kv_chunk: int = 1,  # pages per DMA (>1 requires contiguous runs)
     interpret: bool = False,
-    cross_row: Optional[bool] = None,  # None => PALLAS_PAGED_XROW
     # int8 KV mode: pages are int8 and these carry the per-token
     # dequant scales [L, NP, PS] f32 (engine/kvcache.py)
     k_scale: Optional[jax.Array] = None,
@@ -760,8 +760,7 @@ def paged_decode_attention(
     # job-shared prefix skip those pages (pfx_cnt[b] of them) and start
     # from the injected online-softmax carry (prefix_attention_carry) —
     # the shared pages are then read from HBM once per step for the
-    # whole batch instead of once per row. Forces kv_chunk=1, no
-    # cross_row.
+    # whole batch instead of once per row.
     pfx_cnt: Optional[jax.Array] = None,   # [B] int32 pages to skip
     m0: Optional[jax.Array] = None,        # [B, NH] f32
     l0: Optional[jax.Array] = None,        # [B, NH] f32
@@ -797,25 +796,18 @@ def paged_decode_attention(
     else:
         sink_g = sink.astype(jnp.float32).reshape(1, NH)
 
-    if cross_row is None:
-        cross_row = PALLAS_PAGED_XROW
     quantized = k_scale is not None
     prefix = pfx_cnt is not None
-    if prefix:
-        # carry injection needs chunk index == page index, and the
-        # cross-row handoff fetches the next row's chunk 0 which a
-        # prefix row would skip
-        assert kv_chunk == 1, "shared-prefix mode requires kv_chunk=1"
-        cross_row = False
+    D, GP = ring_shape(PS, KD, k_pages.dtype.itemsize, MP)
     kernel = functools.partial(
         _paged_decode_kernel,
         max_pages_per_seq=MP,
         page_size=PS,
         scale=scale,
         kvh=KVH,
+        ring_pages=D,
+        group_pages=GP,
         window_slots=W,
-        chunk_pages=kv_chunk,
-        cross_row=cross_row,
         quantized=quantized,
         prefix=prefix,
     )
@@ -841,8 +833,8 @@ def paged_decode_attention(
         v_pages,
     ]
     if quantized:
-        # pre-shaped [L, NP, 1, PS] (a bitcast of the stack): the
-        # kernel's scale chunks land lane-major (see _scale_dmas)
+        # pre-shaped [L, NP, 1, PS] (a bitcast of the stack): a page's
+        # scales land lane-major (see page_dmas)
         in_specs += [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -867,35 +859,38 @@ def paged_decode_attention(
         ]
         operands += [win_k, win_v]
     if prefix:
+        # m0 / l0 as [B, 1, NH]: a row's block is then the array's whole
+        # last two dims (a (1, NH) block of [B, NH] is no legal tile)
         in_specs += [
-            pl.BlockSpec((1, NH), lambda b, *s: (b, 0)),
-            pl.BlockSpec((1, NH), lambda b, *s: (b, 0)),
+            pl.BlockSpec((1, 1, NH), lambda b, *s: (b, 0, 0)),
+            pl.BlockSpec((1, 1, NH), lambda b, *s: (b, 0, 0)),
             pl.BlockSpec((1, NH, KD), lambda b, *s: (b, 0, 0)),
         ]
         operands += [
-            m0.astype(jnp.float32),
-            l0.astype(jnp.float32),
+            m0.astype(jnp.float32).reshape(B, 1, NH),
+            l0.astype(jnp.float32).reshape(B, 1, NH),
             acc0.astype(jnp.float32),
         ]
     in_specs.append(pl.BlockSpec((1, NH), lambda b, *s: (0, 0)))
     operands.append(sink_g)
 
     scratch_shapes = [
-        # K/V double-buffers: [2, chunk, PS, KD]
-        pltpu.VMEM((2, kv_chunk, PS, KD), k_pages.dtype),
-        pltpu.VMEM((2, kv_chunk, PS, KD), v_pages.dtype),
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SemaphoreType.DMA((2,)),
+        # the K/V ring: D page slots
+        pltpu.VMEM((D, PS, KD), k_pages.dtype),
+        pltpu.VMEM((D, PS, KD), v_pages.dtype),
+        pltpu.SemaphoreType.DMA((D,)),
+        pltpu.SemaphoreType.DMA((D,)),
     ]
     if quantized:
         scratch_shapes += [
-            # per-token scale double-buffers, lane-major [.., 1, PS]
-            pltpu.VMEM((2, kv_chunk, 1, PS), jnp.float32),
-            pltpu.VMEM((2, kv_chunk, 1, PS), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            # per-token scales of each slot, lane-major [.., 1, PS]
+            pltpu.VMEM((D, 1, PS), jnp.float32),
+            pltpu.VMEM((D, 1, PS), jnp.float32),
+            pltpu.SemaphoreType.DMA((D,)),
+            pltpu.SemaphoreType.DMA((D,)),
         ]
     scratch_shapes += [
+        pltpu.SMEM((4,), jnp.int32),                 # the ring's cursor
         pltpu.VMEM((NH, 128), jnp.float32),          # m
         pltpu.VMEM((NH, 128), jnp.float32),          # l
         pltpu.VMEM((NH, KD), jnp.float32),           # block-diag acc
@@ -911,14 +906,10 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NH, Dh), q.dtype),
-        # without cross-row warmup, batch rows are independent (disjoint
-        # out rows, scratch reinitialized per step) and "parallel" lets
-        # megacore TPUs split the grid; the cross-row handoff threads
-        # DMA state between steps and needs sequential "arbitrary" rows
+        # the ring's cursor and its fetches in flight pass from row to
+        # row: the grid runs in order (nothing lost on one-core v5e)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(
-                "arbitrary" if cross_row else "parallel",
-            ),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
     )(*scalars, *operands)

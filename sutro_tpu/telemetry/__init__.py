@@ -339,6 +339,21 @@ MOE_ROUTED_ROWS_TOTAL = REGISTRY.counter(
     "dispatch, fetched with its tokens)",
     unit="rows",
 )
+KV_PAGES_FETCHED_TOTAL = REGISTRY.counter(
+    "sutro_kv_pages_fetched_total",
+    "K/V pages the decode dispatches' attention fetched, a row, a step "
+    "and an attention layer at a time (host arithmetic per dispatch: "
+    "the paged kernel fetches a row's pages up to its last token's, "
+    "the gathered-page path its whole table)",
+    unit="pages",
+)
+KV_PAGES_NEEDED_TOTAL = REGISTRY.counter(
+    "sutro_kv_pages_needed_total",
+    "Pages the same rows' cached tokens fill (tokens / page size, "
+    "fractional), counted as sutro_kv_pages_fetched_total counts: "
+    "fetched over needed is the decode attention's over-read",
+    unit="pages",
+)
 STATE_COMMITS_TOTAL = REGISTRY.counter(
     "sutro_state_commits_total",
     "Dispatches that committed per-sequence conv state beside K/V, by "

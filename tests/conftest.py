@@ -69,6 +69,27 @@ def monkeypatch_module():
     mp.undo()
 
 
+@pytest.fixture
+def paged_ring(monkeypatch):
+    """``paged_ring(ring_pages, group_pages)`` gives the paged decode
+    kernel a fetch ring of that size for one test, so that tiny pages
+    wrap the ring and split rows into several groups as real ones do
+    (``ops/pallas_paged.ring_shape`` would give them 32 slots). The
+    size is read while the kernel is traced: the traces go before and
+    after."""
+    from sutro_tpu.ops import pallas_paged
+
+    def set_ring(ring_pages: int, group_pages: int) -> None:
+        monkeypatch.setattr(
+            pallas_paged, "ring_shape",
+            lambda *a, **k: (ring_pages, group_pages),
+        )
+        pallas_paged.paged_decode_attention.clear_cache()
+
+    yield set_ring
+    pallas_paged.paged_decode_attention.clear_cache()
+
+
 @pytest.fixture(scope="session")
 def tiny_ecfg() -> EngineConfig:
     return EngineConfig(

@@ -117,10 +117,11 @@ def test_paged_kernel_int8_matches_dequant_reference(window):
     )
 
 
-@pytest.mark.parametrize("kv_chunk", [2, 3])
-def test_paged_kernel_int8_chunked(kv_chunk):
-    """Chunked contiguous fetch with scale DMAs: per-page scale slices
-    still land on the right score columns."""
+@pytest.mark.parametrize("group_pages", [2, 4])
+def test_paged_kernel_int8_chunked(group_pages, paged_ring):
+    """Groups of several pages with scale DMAs, in a ring that wraps:
+    per-page scale slices still land on the right score columns."""
+    paged_ring(2 * group_pages, group_pages)
     rng = np.random.default_rng(11)
     MP = 6
     q, k_cur, v_cur, kq, ks, vq, vs, table, past_len = _quantized_case(
@@ -139,8 +140,7 @@ def test_paged_kernel_int8_chunked(kv_chunk):
     )
     got = paged_decode_attention(
         q[:, 0], kq, vq, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
-        win, None, interpret=True, kv_chunk=kv_chunk,
-        k_scale=ks, v_scale=vs,
+        win, None, interpret=True, k_scale=ks, v_scale=vs,
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref[:, 0]), atol=2e-5, rtol=2e-5

@@ -285,77 +285,153 @@ def test_grouped_matmul_matches_ragged_dot(sizes):
     )
 
 
-@pytest.mark.parametrize("kv_chunk", [2, 3])
-def test_paged_decode_chunked_contiguous(kv_chunk):
-    """Contiguous-KV mode: fetching kv_chunk pages per DMA over an
-    ascending page run must match the per-page walk and the jnp
-    reference (over-read past the run is masked by past_len)."""
+# ---------------------------------------------------------------------------
+# the paged decode kernel's fetch ring
+# ---------------------------------------------------------------------------
+
+_R = dict(B=8, NH=4, KVH=2, Dh=16, PS=8, MP=6, NP=64, N_PFX=2)
+# one batch holds every length that matters to a page walk: nothing, one
+# token, a page less one, a page, a page and one, the whole table, and
+# an EMPTY row between two full ones (the ring hands over across a row
+# that fetches nothing)
+_R_PAST = [0, 1, 7, 8, 9, 48, 0, 48]
+
+
+def _ring_tables(layout: str, rng) -> np.ndarray:
+    """``run``: one ascending run a row, as the allocator's first fit
+    gives; ``scattered``: the same pages in any order; ``shared``: every
+    row's table starts with the same N_PFX pages (a job's shared prefix)
+    and goes on with its own run."""
+    B, MP, n_pfx = _R["B"], _R["MP"], _R["N_PFX"]
+    table = np.zeros((B, MP), np.int32)
+    nxt = 1 + n_pfx
+    for b in range(B):
+        own = MP - n_pfx if layout == "shared" else MP
+        run = np.arange(nxt, nxt + own)
+        nxt += own
+        if layout == "scattered":
+            rng.shuffle(run)
+        if layout == "shared":
+            table[b, :n_pfx] = np.arange(1, 1 + n_pfx)
+        table[b, MP - own:] = run
+    assert nxt <= _R["NP"]
+    return table
+
+
+def _quantize_tokens(x):
+    """int8 values + per-token scales, as write_kv stores them."""
+    scale = jnp.max(jnp.abs(x), axis=-1) / 127.0
+    q = jnp.round(x / jnp.maximum(scale, 1e-12)[..., None]).astype(jnp.int8)
+    return q, scale.astype(jnp.float32)
+
+
+_RING_MODES = ("plain", "window", "sink", "window_buffer", "int8")
+_RING_CASES = [
+    # (layout, mode, ring pages, pages a group)
+    *[(lay, m, 4, 2) for lay in ("run", "scattered", "shared")
+      for m in _RING_MODES],
+    # the carry of a shared prefix: those rows' fetches start past it
+    ("shared", "prefix_carry", 4, 2),
+    ("shared", "prefix_carry", 2, 1),
+    # other rings: two slots of one page; groups of up to four (a table
+    # of six pages is a group of four and one of two) in a ring of two
+    # of them; and one that never wraps, as real pages get
+    ("run", "plain", 2, 1),
+    ("scattered", "window_buffer", 8, 4),
+    ("run", "int8", 8, 4),
+    ("shared", "plain", 32, 4),
+]
+
+
+@pytest.mark.parametrize(
+    "layout,mode,ring_pages,group_pages", _RING_CASES,
+    ids=[f"{a}-{m}-ring{d}x{g}" for a, m, d, g in _RING_CASES],
+)
+def test_paged_decode_fetch_ring(
+    layout, mode, ring_pages, group_pages, paged_ring
+):
+    """The kernel's one fetch schedule against the jnp reference: a ring
+    of page fetches over the batch's (row, page) sequence, whatever the
+    table's layout, with rows of every length in one batch."""
+    from sutro_tpu.ops.pallas_paged import prefix_attention_carry
+
+    paged_ring(ring_pages, group_pages)
     rng = np.random.default_rng(31)
-    B, NH, KVH, Dh, PS, MP, NP = 3, 4, 2, 16, 8, 6, 64
+    B, NH, KVH, Dh, PS, MP, NP, n_pfx = (
+        _R[k] for k in ("B", "NH", "KVH", "Dh", "PS", "MP", "NP", "N_PFX")
+    )
     q = jnp.asarray(rng.standard_normal((B, 1, NH, Dh)), jnp.float32)
     k_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
     v_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
     pool = (N_LAYERS, NP, PS, KVH * Dh)
     kp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
     vp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
-    # ascending contiguous runs per row
-    table = np.zeros((B, MP), np.int32)
-    starts = [1, 11, 21]
-    for b in range(B):
-        table[b] = np.arange(starts[b], starts[b] + MP)
-    table = jnp.asarray(table)
-    past_len = jnp.asarray([5, 17, MP * PS - 1], jnp.int32)
-    win = jnp.asarray(0, jnp.int32)
+    table = jnp.asarray(_ring_tables(layout, rng))
+    past_len = jnp.asarray(_R_PAST, jnp.int32)
+    win = jnp.asarray(5 if mode == "window" else 0, jnp.int32)
+    sink = (
+        jnp.asarray(rng.standard_normal(NH), jnp.float32)
+        if mode == "sink" else None
+    )
+    ks = vs = None
+    if mode == "int8":
+        kp, ks = _quantize_tokens(kp)
+        vp, vs = _quantize_tokens(vp)
+    wkw, win_len = {}, jnp.asarray(0, jnp.int32)
+    if mode == "window_buffer":
+        win_len = jnp.asarray(3, jnp.int32)
+        wkw = dict(
+            win_k=jnp.asarray(
+                rng.standard_normal((B, 4, KVH * Dh)), jnp.float32
+            ),
+            win_v=jnp.asarray(
+                rng.standard_normal((B, 4, KVH * Dh)), jnp.float32
+            ),
+            win_len=win_len,
+        )
+    carry = {}
+    if mode == "prefix_carry":
+        # rows that hold the whole prefix take its carry; a row shorter
+        # than the prefix walks what it has itself
+        pfx_len = jnp.where(past_len >= n_pfx * PS, n_pfx * PS, 0)
+        m0, l0, acc0 = prefix_attention_carry(
+            q[:, 0], kp, vp, LAYER,
+            jnp.arange(1, 1 + n_pfx, dtype=jnp.int32), pfx_len,
+            past_len, win,
+        )
+        carry = dict(pfx_cnt=pfx_len // PS, m0=m0, l0=l0, acc0=acc0)
 
     ref = chunk_attention(
         q, k_cur, v_cur,
-        positions=past_len[:, None],
+        positions=(past_len + win_len)[:, None],
         valid_len=jnp.ones((B,), jnp.int32),
-        past_k_pages=kp, past_v_pages=vp, layer=LAYER, page_table=table,
-        past_len=past_len, window=win, sink=None,
-        use_pallas=False,
+        past_k_pages=kp, past_v_pages=vp, layer=LAYER,
+        past_k_scale=ks, past_v_scale=vs, page_table=table,
+        past_len=past_len, window=win, sink=sink,
+        use_pallas=False, **wkw,
     )
     got = paged_decode_attention(
         q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
-        win, None, kv_chunk=kv_chunk, interpret=True,
+        win, sink, interpret=True, k_scale=ks, v_scale=vs, **wkw, **carry,
     )
+    assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref[:, 0]), atol=2e-5, rtol=2e-5
     )
 
 
-@pytest.mark.parametrize("kv_chunk", [1, 2])
-def test_paged_decode_cross_row_handoff(kv_chunk):
-    """cross_row mode (row b prefetches row b+1's first chunk) must be
-    bit-identical to the independent-row kernel, including across a
-    zero-past row in the middle (handoff predicate skips it) and ragged
-    chunk counts (slot parity never collides)."""
-    rng = np.random.default_rng(77)
-    B, NH, KVH, Dh, PS, MP, NP = 4, 4, 2, 16, 8, 6, 64
-    q = jnp.asarray(rng.standard_normal((B, 1, NH, Dh)), jnp.float32)
-    k_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
-    v_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, Dh)), jnp.float32)
-    pool = (N_LAYERS, NP, PS, KVH * Dh)
-    kp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal(pool), jnp.float32)
-    table = np.zeros((B, MP), np.int32)
-    starts = [1, 11, 21, 31]
-    for b in range(B):
-        table[b] = np.arange(starts[b], starts[b] + MP)
-    table = jnp.asarray(table)
-    # odd/even chunk counts + an empty row mid-batch
-    past_len = jnp.asarray([5, 0, 17, MP * PS - 1], jnp.int32)
-    win = jnp.asarray(0, jnp.int32)
+def test_ring_shape_of_the_cells():
+    """The ring the benchmark's cells get: 16 slots of 128 KB pages on
+    one chip (4 MiB of K and V), 32 of a tp=4 shard's 32 KB pages; up to
+    eight pages of 64 tokens a group, a power of two; never under two
+    of the largest group."""
+    from sutro_tpu.ops.pallas_paged import ring_shape
 
-    base = paged_decode_attention(
-        q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
-        win, None, kv_chunk=kv_chunk, interpret=True, cross_row=False,
-    )
-    xrow = paged_decode_attention(
-        q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
-        win, None, kv_chunk=kv_chunk, interpret=True, cross_row=True,
-    )
-    np.testing.assert_array_equal(np.asarray(xrow), np.asarray(base))
+    assert ring_shape(64, 1024, 2, 16) == (16, 8)
+    assert ring_shape(64, 256, 2, 16) == (32, 8)
+    assert ring_shape(128, 2048, 2, 8) == (8, 4)
+    assert ring_shape(256, 4096, 2, 4) == (4, 2)
+    assert ring_shape(8, 32, 4, 6) == (32, 4)
 
 
 # ---------------------------------------------------------------------------
@@ -472,7 +548,7 @@ def test_paged_decode_prefix_carry_injection(window, quantized):
 
     ref = paged_decode_attention(
         q, k_pages, v_pages, LAYER, table, past_len, k_cur, v_cur, win, None,
-        interpret=True, cross_row=False,
+        interpret=True,
         k_scale=k_scale, v_scale=v_scale,
     )
 
@@ -487,7 +563,7 @@ def test_paged_decode_prefix_carry_injection(window, quantized):
     )
     got = paged_decode_attention(
         q, k_pages, v_pages, LAYER, table, past_len, k_cur, v_cur, win, None,
-        interpret=True, cross_row=False,
+        interpret=True,
         k_scale=k_scale, v_scale=v_scale,
         pfx_cnt=pfx_cnt, m0=m0, l0=l0, acc0=acc0,
     )
@@ -590,7 +666,7 @@ def test_paged_decode_with_pallas_carry_injection(window):
 
     ref = paged_decode_attention(
         q, k_pages, v_pages, LAYER, table, past_len, k_cur, v_cur, win, None,
-        interpret=True, cross_row=False,
+        interpret=True,
     )
     pfx_len = jnp.asarray(
         [n_pfx * PS, n_pfx * PS, n_pfx * PS, 0], jnp.int32
@@ -602,7 +678,7 @@ def test_paged_decode_with_pallas_carry_injection(window):
     )
     got = paged_decode_attention(
         q, k_pages, v_pages, LAYER, table, past_len, k_cur, v_cur, win, None,
-        interpret=True, cross_row=False,
+        interpret=True,
         pfx_cnt=pfx_cnt, m0=m0, l0=l0, acc0=acc0,
     )
     np.testing.assert_allclose(

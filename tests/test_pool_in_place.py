@@ -42,12 +42,12 @@ N_PFX = 3  # shared-prefix pages at the head of rows 0..2's tables
 
 CASES = {
     "chunk1": dict(),
-    "chunk2": dict(kv_chunk=2),
-    "chunk3_window5": dict(kv_chunk=3, window=5),
+    "chunk2": dict(group=2),
+    "chunk4_window5": dict(group=4, window=5),
     "window_buffer": dict(win_buf=True),
-    "window_buffer_chunk2": dict(win_buf=True, kv_chunk=2),
+    "window_buffer_chunk2": dict(win_buf=True, group=2),
     "int8": dict(int8=True),
-    "int8_chunk2_window_buffer": dict(int8=True, kv_chunk=2, win_buf=True),
+    "int8_chunk2_window_buffer": dict(int8=True, group=2, win_buf=True),
     "prefix_carry_xla": dict(prefix="xla"),
     "prefix_carry_pallas": dict(prefix="pallas", window=7),
     "prefix_carry_int8": dict(prefix="xla", int8=True),
@@ -55,7 +55,7 @@ CASES = {
 
 
 def _tables(prefix: bool) -> np.ndarray:
-    """Ascending contiguous runs a row (what kv_chunk > 1 needs); in
+    """Ascending contiguous runs a row; in
     prefix mode rows 0..2 start with the shared pages [1, 2, 3]."""
     table = np.zeros((B, MP), np.int32)
     nxt = 1 + (N_PFX if prefix else 0)
@@ -71,11 +71,13 @@ def _tables(prefix: bool) -> np.ndarray:
 
 @pytest.mark.parametrize("layer", [1, L - 1])
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_paged_decode_reads_its_layer_of_the_stack(case, layer):
+def test_paged_decode_reads_its_layer_of_the_stack(case, layer, paged_ring):
     """``paged_decode_attention`` on the whole stack + a layer index equals
     ``gather_kv_layer`` of that layer + the jnp attention, and differs
-    from what any other layer would give."""
+    from what any other layer would give. ``group`` pages a compute
+    group, in a ring of two groups (it wraps); else one page a group."""
     spec = CASES[case]
+    paged_ring(2 * spec.get("group", 1), spec.get("group", 1))
     rng = np.random.default_rng(25)
     q = jnp.asarray(rng.standard_normal((B, 1, NH, DH)), jnp.float32)
     k_cur = jnp.asarray(rng.standard_normal((B, 1, KVH, DH)), jnp.float32)
@@ -132,8 +134,8 @@ def test_paged_decode_reads_its_layer_of_the_stack(case, layer):
 
     got = paged_decode_attention(
         q[:, 0], kf, vf, lyr, table, past_len, k_cur[:, 0], v_cur[:, 0],
-        win, None, kv_chunk=spec.get("kv_chunk", 1), interpret=True,
-        cross_row=False, k_scale=ks, v_scale=vs, **wkw, **carry,
+        win, None, interpret=True,
+        k_scale=ks, v_scale=vs, **wkw, **carry,
     )
     want = reference(layer)
     np.testing.assert_allclose(
