@@ -1028,11 +1028,18 @@ def engine_info(model: Optional[str]) -> None:
         click.echo(
             f"model: {m.name} layers={m.num_layers} attn_layers="
             f"{m.num_attn_layers} (the pool's layers) state_layers="
-            f"{m.num_conv_layers} kv_bytes_per_token="
+            f"{m.num_conv_layers + m.num_mamba_layers} kv_bytes_per_token="
             f"{m.num_attn_layers * 2 * m.kv_size * width} "
             f"state_bytes_per_page="
             f"{m.num_conv_layers * m.conv_state_len * m.hidden_size * width}"
         )
+        if m.num_mamba_layers:
+            from .engine.kvcache import state_bytes_per_slot
+
+            click.echo(
+                "state_bytes_per_slot="
+                f"{state_bytes_per_slot(m, ecfg)} (a slot a live sequence)"
+            )
 
 
 @engine.command("models")

@@ -32,6 +32,22 @@ scheduler ... paged-KV decode attention"). Layout:
   the state exactly, a released page needs no reset (a new sequence
   starts from zeros, and writes a page's state before it reads it).
 
+- ``ssm`` ``[L_m, NS, N, I]`` and ``ssm_conv`` ``[NS, L_m * (K-1) * Cd]``,
+  for a model with mamba layers (``mamba_mixer``): a THIRD kind of
+  per-sequence state, too large to keep a page (a matrix a head: tens
+  of MB a sequence), kept a SLOT a live sequence. Slot 0 is the garbage
+  slot. ``state_slot`` ``[NP]`` int32 maps a page to a slot, on the
+  device: a sequence at position ``start`` finds its slot through the
+  page that holds position ``start - 1`` (its first page at ``start``
+  0), as ``read_conv_state`` finds its page, and ``write_kv`` re-points
+  the page that holds the chunk's last accepted token. The host
+  (``StateSlots``) only hands a slot to a sequence's first page and
+  takes it back with the row; a page's entry is always written before
+  it is read, so a freed page or slot needs no reset. The state axis N
+  is MAJOR and the channels I = heads x head_dim minor: a decode step
+  reduces every slot against its row's C over N with no cross-lane
+  work, reading the pool where it lies; no forward writes it.
+
 ``write_kv`` lands a chunk's K/V into pages (Pallas in-place RMW kernel
 on TPU, XLA scatter fallback elsewhere); ``gather_kv_layer`` produces one
 layer's contiguous ``[B, CTX, KVH, Dh]`` view for the non-Pallas
@@ -52,7 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.configs import ModelConfig
-from ..models.transformer import MixedChunk
+from ..models.transformer import MixedChunk, StatePast, over_state, per_channel
 from .config import EngineConfig
 
 
@@ -70,6 +86,10 @@ class KVCache:
     v_scale: "jax.Array | None" = None
     # conv layers' per-sequence state, per page (module docstring)
     conv: "jax.Array | None" = None     # [NP, L_conv * (K-1) * H]
+    # mamba layers' per-sequence state, per SLOT, and the page -> slot map
+    ssm: "jax.Array | None" = None         # [L_m, NS, N, I]
+    ssm_conv: "jax.Array | None" = None    # [NS, L_m * (K-1) * Cd]
+    state_slot: "jax.Array | None" = None  # [NP] int32
 
     @property
     def page_size(self) -> int:
@@ -83,13 +103,22 @@ class KVCache:
     def quantized(self) -> bool:
         return self.k_scale is not None
 
+    @property
+    def num_state_slots(self) -> int:
+        """Slots of the mamba state pool, the garbage slot included (0
+        for a model that keeps no such state)."""
+        return 0 if self.ssm is None else self.ssm.shape[1]
+
 
 def alloc_cache(
     mcfg: ModelConfig, ecfg: EngineConfig, num_pages: int,
     dtype: jnp.dtype = jnp.bfloat16,
     sharding: "jax.sharding.NamedSharding | None" = None,
 ) -> KVCache:
-    """Zeroed page pools. With ``sharding`` (parallel/sharding.py
+    """Zeroed page pools; for a model with mamba layers also the state
+    pools: beside the garbage slot a slot a row of the decode batch, and
+    never more than there are pages, since a sequence holds at least
+    one (``default_state_slots``). With ``sharding`` (parallel/sharding.py
     ``cache_shardings``) every pool is allocated sharded — never whole
     on one device first; the int8 per-token scale pools are
     shard-invariant (full-KD amax) and replicate across that mesh."""
@@ -116,9 +145,27 @@ def alloc_cache(
             ),
             jnp.dtype(ecfg.activation_dtype), device=rep,
         )
+    state = {}
+    if mcfg.num_mamba_layers:
+        state_slots = default_state_slots(ecfg, num_pages)
+        # stored in the activation dtype, updated in float32; replicated
+        # under a mesh, like the conv state
+        act = jnp.dtype(ecfg.activation_dtype)
+        state = dict(
+            ssm=jnp.zeros(
+                (mcfg.num_mamba_layers, 1 + state_slots, mcfg.mamba_state,
+                 mcfg.mamba_inner), act, device=rep,
+            ),
+            ssm_conv=jnp.zeros(
+                (1 + state_slots, mcfg.num_mamba_layers
+                 * mcfg.mamba_conv_len * mcfg.mamba_conv_dim),
+                act, device=rep,
+            ),
+            state_slot=jnp.zeros((num_pages,), jnp.int32, device=rep),
+        )
     if getattr(ecfg, "kv_quantize", None) == "int8":
         return KVCache(
-            conv=conv,
+            conv=conv, **state,
             k_pages=jnp.zeros(shape, jnp.int8, device=sharding),
             v_pages=jnp.zeros(shape, jnp.int8, device=sharding),
             k_scale=jnp.zeros(shape[:3], jnp.float32, device=rep),
@@ -131,8 +178,72 @@ def alloc_cache(
     return KVCache(
         k_pages=jnp.zeros(shape, dtype, device=sharding),
         v_pages=jnp.zeros(shape, dtype, device=sharding),
-        conv=conv,
+        conv=conv, **state,
     )
+
+
+def default_state_slots(ecfg: EngineConfig, num_pages: int) -> int:
+    return max(min(ecfg.decode_batch_size, num_pages - 1), 1)
+
+
+def state_bytes_per_slot(mcfg: ModelConfig, ecfg: EngineConfig) -> int:
+    """Bytes of mamba state one sequence keeps (its slot of both pools)."""
+    per_layer = (
+        mcfg.mamba_state * mcfg.mamba_inner
+        + mcfg.mamba_conv_len * mcfg.mamba_conv_dim
+    )
+    return (
+        mcfg.num_mamba_layers * per_layer
+        * jnp.dtype(ecfg.activation_dtype).itemsize
+    )
+
+
+class StateSlots:
+    """Host-side allocator of the mamba state pool's slots, beside the
+    page free list: slot 0 is the garbage slot; a live sequence holds
+    one slot, bound to its FIRST page (``bind`` is idempotent for a page
+    that is bound, so a sequence written again from position 0 into the
+    same pages keeps its slot). The device finds a row's slot from its
+    pages (``KVCache.state_slot``); this class only says which slots
+    are free."""
+
+    def __init__(self, slots: int):
+        self.total = slots
+        self._free: List[int] = list(range(slots, 0, -1))  # pop() -> 1 first
+        self._of_page: dict = {}
+
+    @property
+    def free_count(self) -> int:
+        return len(self._free)
+
+    @property
+    def in_use(self) -> int:
+        return self.total - len(self._free)
+
+    def slot_of(self, page: int) -> "int | None":
+        return self._of_page.get(int(page))
+
+    def bind(self, page: int) -> "Tuple[int, bool]":
+        """``(slot, new)`` for the sequence whose first page is
+        ``page``; raises MemoryError when none is free."""
+        page = int(page)
+        slot = self._of_page.get(page)
+        if slot is not None:
+            return slot, False
+        if not self._free:
+            raise MemoryError("state pool out of slots")
+        slot = self._free.pop()
+        self._of_page[page] = slot
+        return slot, True
+
+    def release(self, page: int) -> None:
+        slot = self._of_page.pop(int(page), None)
+        if slot is not None:
+            self._free.append(slot)
+
+    def reset(self) -> None:
+        self._free = list(range(self.total, 0, -1))
+        self._of_page.clear()
 
 
 def _quantize_tokens(x: jax.Array):
@@ -307,6 +418,126 @@ def write_conv_state(
     return conv.at[page].set(rows.astype(conv.dtype))
 
 
+def state_slots_at(
+    cache: KVCache, page_table: jax.Array, start: jax.Array
+) -> jax.Array:
+    """[B] int32: each row's slot of the mamba state pool, through the
+    page that holds position ``start - 1`` (the row's first page at
+    ``start`` 0, which the host bound)."""
+    at = jnp.maximum(start - 1, 0) // cache.page_size
+    page = jnp.take_along_axis(page_table, at[:, None], axis=1)[:, 0]
+    return cache.state_slot[page]
+
+
+def read_state(
+    cache: KVCache, page_table: jax.Array, start: jax.Array,
+    layers: int, conv_dim: int,
+) -> "StatePast | None":
+    """A mamba model's state as ``transformer.forward`` reads it, for
+    rows at ``start`` ([B] int32): the pool itself, each row's slot, and
+    the rows' conv columns (a gather of a few KB a layer; zeros for a
+    row at ``start`` 0). None for a cache without such state."""
+    if cache.ssm is None:
+        return None
+    slots = state_slots_at(cache, page_table, start)
+    fresh = start <= 0
+    conv = cache.ssm_conv[slots].reshape(slots.shape[0], layers, -1, conv_dim)
+    conv = jnp.where(fresh[:, None, None, None], 0, conv)
+    return StatePast(
+        ssm=cache.ssm, slots=slots, fresh=fresh,
+        conv=conv.transpose(1, 0, 2, 3),
+    )
+
+
+def write_state(
+    cache: KVCache,
+    chunk: dict,               # MixedChunk.ssm
+    page_table: jax.Array,     # [B, MP] int32
+    start: jax.Array,          # [B] int32 — global position of chunk token 0
+    valid_len: jax.Array,      # [B] int32 — tokens of the chunk that count
+) -> KVCache:
+    """Commit a chunk's mamba state for its first ``valid_len`` tokens
+    and point the page that holds the last of them at the row's slot. A
+    row with ``valid_len`` 0 keeps its state. Two forms
+    (``MixedChunk.ssm``): "final", the state a prefill computed, is
+    scattered to the rows' slots; the tokens' own ``dt, dA, x, B`` (a
+    decode window, a verify chunk) advance EVERY slot of the pool in one
+    elementwise pass, in place: ``S <- decay S + sum_t c_t x_t B_t^T``
+    with decay 1 and no tokens for a slot no row advances, so the state
+    is neither gathered nor scattered."""
+    slots = state_slots_at(cache, page_table, start)
+    moved = valid_len > 0
+    slots = jnp.where(moved, slots, 0)
+    ssm, B = cache.ssm, slots.shape[0]
+    L, NS = ssm.shape[:2]
+    f32 = jnp.float32
+    ext = chunk["conv"]                                   # [L, B, K-1+T', Cd]
+    K1 = cache.ssm_conv.shape[1] // (L * ext.shape[-1])
+    if "final" in chunk:
+        at = jnp.arange(L, dtype=jnp.int32)[:, None] * NS + slots[None]
+        ssm = ssm.reshape((L * NS,) + ssm.shape[2:]).at[at].set(
+            chunk["final"].astype(ssm.dtype)
+        ).reshape(ssm.shape)
+        cols = ext                          # the columns after the chunk
+    else:
+        n = valid_len
+        W, Hm = chunk["dt"].shape[2:]
+        I = chunk["x"].shape[-1]
+        P = I // Hm
+        G = chunk["B"].shape[-1] // ssm.shape[2]
+        took = jnp.arange(W, dtype=jnp.int32)[None, :] < n[:, None]   # [B, W]
+        # slot-major: each slot takes ITS row's tokens, or none
+        row = jnp.zeros((NS,), jnp.int32).at[slots].set(
+            jnp.arange(B, dtype=jnp.int32)
+        )
+        live = jnp.zeros((NS,), bool).at[slots].set(moved).at[0].set(False)
+        fresh = start <= 0
+        after = (n[:, None] + jnp.arange(K1, dtype=jnp.int32))[..., None]
+
+        def layer(pool, xs):
+            # one layer at a time: the temporaries are a layer's, and
+            # the pool is updated where it lies
+            l, dt, dA, x, Bm, ext = xs
+            dt, dA = dt.astype(f32), dA.astype(f32)
+            cum = jnp.cumsum(dA, axis=1)                              # [B, W, Hm]
+            total = jnp.sum(jnp.where(took[..., None], dA, 0.0), axis=1)
+            coef = jnp.where(
+                took[..., None],
+                jnp.exp(jnp.minimum(total[:, None] - cum, 0.0)) * dt, 0.0,
+            )
+            decay = jnp.where(fresh[:, None], 0.0, jnp.exp(total))    # [B, Hm]
+            cx = per_channel(coef, P) * x.astype(f32)                # [B, W, I]
+            decay = jnp.where(
+                live[:, None], per_channel(decay, P)[row], 1.0
+            )                                                         # [NS, I]
+            cx = jnp.where(live[:, None, None], cx[row], 0.0)         # [NS, W, I]
+            Bs = Bm[row].astype(f32)                                  # [NS, W, G*N]
+            new = pool[l].astype(f32) * decay[:, None, :]
+            for w in range(W):
+                new = new + over_state(Bs[:, w], G, I) * cx[:, w, None, :]
+            pool = jax.lax.dynamic_update_index_in_dim(
+                pool, new.astype(pool.dtype), l, axis=0
+            )
+            return pool, jnp.take_along_axis(ext, after, axis=1)
+
+        ssm, cols = jax.lax.scan(
+            layer, ssm,
+            (jnp.arange(L, dtype=jnp.int32), chunk["dt"], chunk["dA"],
+             chunk["x"], chunk["B"], ext),
+        )
+    rows = cols.transpose(1, 0, 2, 3).reshape(B, -1)      # [B, L * K1 * Cd]
+    ssm_conv = cache.ssm_conv.at[slots].set(rows.astype(cache.ssm_conv.dtype))
+    # the page of the last accepted token now leads to the slot
+    last = jnp.maximum(start + valid_len - 1, 0) // cache.page_size
+    page = jnp.take_along_axis(
+        page_table, jnp.minimum(last, page_table.shape[1] - 1)[:, None], axis=1
+    )[:, 0]
+    state_slot = cache.state_slot.at[jnp.where(moved, page, 0)].set(slots)
+    return dataclasses.replace(
+        cache, ssm=ssm, ssm_conv=ssm_conv, state_slot=state_slot
+    )
+
+
 def write_kv(
     cache: KVCache,
     k_chunk: "jax.Array | MixedChunk",  # [L, B, T, KVH, Dh] or fused [L, B, T, KD]
@@ -327,6 +558,10 @@ def write_kv(
     ``conv`` is None commits K/V alone (a verify forward, whose accepted
     length is decided later: ``ModelRunner.commit_verified``)."""
     if isinstance(k_chunk, MixedChunk):
+        if k_chunk.ssm is not None:
+            cache = write_state(
+                cache, k_chunk.ssm, page_table, start, valid_len
+            )
         conv = cache.conv
         if k_chunk.conv is not None:
             conv = write_conv_state(

@@ -33,7 +33,10 @@ from ..models.configs import ModelConfig
 from ..models.transformer import MixedChunk
 from . import faults
 from .config import EngineConfig
-from .kvcache import KVCache, alloc_cache, read_conv_state, write_kv
+from .kvcache import (
+    KVCache, StateSlots, alloc_cache, default_state_slots, read_conv_state,
+    read_state, state_bytes_per_slot, write_kv,
+)
 from ..ops.sampling import NEG_INF, sample, cumulative_logprob
 
 
@@ -310,13 +313,98 @@ class ModelRunner:
             mcfg, ecfg, self.num_pages, dtype=dtype,
             sharding=self._cache_sharding,
         )
+        # the host's side of the mamba state pool (kvcache.StateSlots):
+        # which slots are free. None for a model that keeps no such state
+        self.state_slots = (
+            StateSlots(self.cache.num_state_slots - 1)
+            if self.cache.ssm is not None else None
+        )
+        self._unbound: list = []  # (page, slot) the device has yet to learn
 
     @property
     def has_state(self) -> bool:
-        """The model keeps per-sequence state beside K/V (conv layers):
-        callers of the verify dispatches commit it at the accepted
-        length (``commit_verified``)."""
-        return self.mcfg.num_conv_layers > 0
+        """The model keeps per-sequence state beside K/V (conv or mamba
+        layers): callers of the verify dispatches commit it at the
+        accepted length (``commit_verified``)."""
+        return self.mcfg.num_conv_layers > 0 or self.mcfg.num_mamba_layers > 0
+
+    def state_step_bytes(self, rows: int) -> int:
+        """Bytes of mamba state a decode step reads for ``rows`` live
+        rows (it reads each row's slot once and writes none: the window
+        commits); 0 for a model that keeps none."""
+        if self.state_slots is None:
+            return 0
+        return rows * state_bytes_per_slot(self.mcfg, self.ecfg)
+
+    # -- mamba state slots (kvcache.StateSlots) -------------------------
+
+    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+    def _bind_slots_jit(self, cache: KVCache, pages, slots):
+        return dataclasses.replace(
+            cache, state_slot=cache.state_slot.at[pages].set(slots)
+        )
+
+    def bind_state(self, first_pages, flush: bool = True) -> None:
+        """Give each sequence that starts at the head of one of
+        ``first_pages`` a slot of the state pool (one that is bound
+        keeps its slot). The device learns of new bindings in ONE small
+        dispatch, at once or (``flush`` False: admission, a row at a
+        time) before the next prefill, which is the first program to
+        read them. Raises MemoryError when the pool has no free slot:
+        admission asks ``state_slots.free_count`` first."""
+        if self.state_slots is None:
+            return
+        for p in first_pages:
+            if int(p) > 0:
+                slot, new = self.state_slots.bind(p)
+                if new:
+                    self._unbound.append((int(p), slot))
+        self._note_state_slots()
+        if not flush or not self._unbound:
+            return
+        # one shape for every admission batch; pads: page 0 <- slot 0
+        n = max(self.ecfg.prefill_batch_size, next_bucket(len(self._unbound)))
+        pages, slots = np.zeros((2, n), np.int32)
+        pages[: len(self._unbound)], slots[: len(self._unbound)] = (
+            np.array(self._unbound, np.int32).T
+        )
+        self._unbound.clear()
+        self.cache = self._bind_slots_jit(
+            self.cache, jnp.asarray(pages), jnp.asarray(slots)
+        )
+
+    def release_state(self, first_page) -> None:
+        """Take back the slot of the sequence that started at
+        ``first_page``. The device is not told: a page's entry is
+        written before it is read (kvcache.py)."""
+        if self.state_slots is not None:
+            self.state_slots.release(int(first_page))
+            self._unbound = [
+                u for u in self._unbound if u[0] != int(first_page)
+            ]
+            self._note_state_slots()
+
+    def reset_state_slots(self) -> None:
+        """Every slot free: a new session's pages are all free."""
+        if self.state_slots is not None:
+            self.state_slots.reset()
+            self._unbound.clear()
+            self._note_state_slots()
+
+    def _note_state_slots(self) -> None:
+        if telemetry.ENABLED:
+            telemetry.STATE_SLOTS.set(float(self.state_slots.in_use), "in_use")
+            telemetry.STATE_SLOTS.set(float(self.state_slots.total), "total")
+
+    def _bind_fresh(self, page_tables, starts) -> None:
+        """The prefill entry points' own ask: rows that start a sequence
+        get a slot if whoever admitted them bound none."""
+        if self.state_slots is None:
+            return
+        tables = np.asarray(page_tables).reshape(-1, np.shape(page_tables)[-1])
+        self.bind_state(
+            [t[0] for t, st in zip(tables, starts) if int(st) == 0]
+        )
 
     def _page_bytes_per_device(self, dtype) -> int:
         """One KV page (K and V, every ATTENTION layer, plus int8
@@ -356,6 +444,11 @@ class ModelRunner:
         if not limit:
             return want
         in_use = int(stats.get("bytes_in_use") or 0)
+        if self.mcfg.num_mamba_layers:
+            # the state pool comes first: a slot a row of the batch
+            in_use += (1 + default_state_slots(self.ecfg, want)) * (
+                state_bytes_per_slot(self.mcfg, self.ecfg)
+            )
         reserve = int(limit * HBM_RESERVE_FRACTION)
         page = self._page_bytes_per_device(dtype)
         fit = (limit - in_use - reserve) // page - self._margin_pages
@@ -416,10 +509,16 @@ class ModelRunner:
             # the second kind of per-sequence state beside it
             "attn_layers": int(self.mcfg.num_attn_layers),
             "pool_layers": int(self.cache.k_pages.shape[0]),
-            "state_layers": int(self.mcfg.num_conv_layers),
-            "state_bytes": int(
-                0 if self.cache.conv is None else self.cache.conv.nbytes
+            "state_layers": int(
+                self.mcfg.num_conv_layers + self.mcfg.num_mamba_layers
             ),
+            "state_bytes": int(sum(
+                0 if pool is None else pool.nbytes
+                for pool in (
+                    self.cache.conv, self.cache.ssm, self.cache.ssm_conv
+                )
+            )),
+            "state_slots": int(self.cache.num_state_slots),
             "kv_heads": int(self.mcfg.num_kv_heads),
             "head_dim": int(self.mcfg.head_dim),
             "kv_dtype_bytes": (
@@ -456,6 +555,14 @@ class ModelRunner:
         return read_conv_state(
             cache, page_table, start,
             self.mcfg.num_conv_layers, self.mcfg.hidden_size,
+        )
+
+    def _state_past(self, cache: KVCache, page_table, start):
+        """The mamba layers' state of each row at ``start``
+        (``transformer.StatePast``); None for a model that keeps none."""
+        return read_state(
+            cache, page_table, start,
+            self.mcfg.num_mamba_layers, self.mcfg.mamba_conv_dim,
         )
 
     def _count_state_commit(self, path: str) -> None:
@@ -515,17 +622,17 @@ class ModelRunner:
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
     def _upload_pages_jit(self, cache: KVCache, ids, k, v, c=None):
-        return KVCache(
+        return dataclasses.replace(
+            cache,
             k_pages=cache.k_pages.at[:, ids].set(k),
             v_pages=cache.v_pages.at[:, ids].set(v),
-            k_scale=cache.k_scale,
-            v_scale=cache.v_scale,
             conv=cache.conv if c is None else cache.conv.at[ids].set(c),
         )
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
     def _upload_pages_q_jit(self, cache: KVCache, ids, k, v, ks, vs, c=None):
-        return KVCache(
+        return dataclasses.replace(
+            cache,
             k_pages=cache.k_pages.at[:, ids].set(k),
             v_pages=cache.v_pages.at[:, ids].set(v),
             k_scale=cache.k_scale.at[:, ids].set(ks),
@@ -542,6 +649,12 @@ class ModelRunner:
         the parity contract, tests/test_kv_tiers.py)."""
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         c = self.cache
+        if c.ssm is not None:
+            # a slot's state is in no page: the caller prefills again
+            raise ValueError(
+                "pages cannot restore the state of a model that keeps "
+                "it a slot a sequence"
+            )
         state = None
         if c.conv is not None:
             if "c" not in payload:
@@ -613,6 +726,7 @@ class ModelRunner:
                 ring_mesh=self.mesh if self.sp > 1 else None,
                 ep_mesh=self.ep_mesh,
                 logit_positions=last,
+                ssm_pending=False,
             )
         cache = write_kv(
             cache, k, v, page_table, start, valid_len,
@@ -639,6 +753,8 @@ class ModelRunner:
             ep_mesh=self.ep_mesh,
             logit_positions=jnp.maximum(valid_len - 1, 0),
             conv_state=self._state_at(cache, page_table, start),
+            state_past=self._state_past(cache, page_table, start),
+            ssm_pending=False,
         )
         cache = write_kv(
             cache, k, v, page_table, start, valid_len,
@@ -673,6 +789,7 @@ class ModelRunner:
         assert start == 0 or (self.sp == 1 and self.pp == 1), (
             "suffix prefill is unsupported under sp/pp"
         )
+        self._bind_fresh(page_table, [start])
         if start > 0 and n <= C:
             return self.prefill_batch_at(
                 [token_ids], page_table[None, :], [start]
@@ -738,6 +855,7 @@ class ModelRunner:
             ids[i, : len(r)] = r
             lens[i] = len(r)
             tables[i] = page_tables[i]
+        self._bind_fresh(tables[:n], [0] * n)
         self._count_state_commit("prefill")
         logits, self.cache, self._route_dev = self._prefill_jit(
             self.params,
@@ -774,6 +892,7 @@ class ModelRunner:
             lens[i] = len(r)
             st[i] = starts[i]
             tables[i] = page_tables[i]
+        self._bind_fresh(tables[:n], st[:n])
         self._count_state_commit("chunk")
         logits, self.cache, self._route_dev = self._prefill_chunk_jit(
             self.params,
@@ -792,7 +911,7 @@ class ModelRunner:
     def _trunk_decode(
         self, params, cache: KVCache, ids, positions, past_len,
         page_table, window_past=None, kv_chunk: int = 1, pfx=None,
-        conv_state=None,
+        conv_state=None, state_past=None,
     ):
         """One decode trunk forward over the paged past — the plain
         scanned forward, or the stage-local pipeline schedule under
@@ -810,10 +929,14 @@ class ModelRunner:
         ``conv_state`` ([L_conv, B, K-1, H]) is the conv layers' state
         at ``positions`` for a caller that carries it itself (the fused
         window's scan); otherwise it is read from the cache at
-        ``past_len``."""
+        ``past_len``. ``state_past`` likewise for the mamba layers'
+        (``transformer.StatePast``): a decode step reads the pool where
+        it lies and advances nothing; ``write_kv`` commits."""
         B = ids.shape[0]
         if conv_state is None:
             conv_state = self._state_at(cache, page_table, past_len)
+        if state_past is None:
+            state_past = self._state_past(cache, page_table, past_len)
         ones = jnp.ones((B,), jnp.int32)
         if self.pp > 1:
             from ..parallel.pipeline import pipeline_decode
@@ -834,6 +957,7 @@ class ModelRunner:
             ep_mesh=self.ep_mesh,
             pfx_groups=pfx,
             conv_state=conv_state,
+            state_past=state_past, ssm_pending=True,
         )
 
     def _chunk_for_table(self, page_table: np.ndarray) -> int:
@@ -1083,24 +1207,54 @@ class ModelRunner:
         wk0 = jnp.zeros((L, B, steps, KD), dtype)
         wv0 = jnp.zeros((L, B, steps, KD), dtype)
         mixed = not self.mcfg.homogeneous
-        K1 = self.mcfg.conv_state_len
-        wc0 = None
-        if self.has_state:
-            state = self._state_at(cache, page_table, past_len)
-            wc0 = jnp.concatenate(
+        K1 = self.mcfg.conv_state_len or self.mcfg.mamba_conv_len
+        wc0 = ws0 = past = None
+
+        def window_of(state):  # [L, B, K-1, C] -> [L, B, K-1 + steps, C]
+            return jnp.concatenate(
                 [state, jnp.zeros(state.shape[:2] + (steps,)
                                   + state.shape[3:], state.dtype)],
                 axis=2,
             )
 
+        if self.mcfg.num_conv_layers:
+            wc0 = window_of(self._state_at(cache, page_table, past_len))
+        if self.mcfg.num_mamba_layers:
+            # the pool is a constant of the scan, like the pages: the
+            # window's tokens ride in buffers and write_kv commits them
+            past = self._state_past(cache, page_table, past_len)
+            m = self.mcfg
+            act = jnp.dtype(self.ecfg.activation_dtype)
+            ws0 = {
+                "conv": window_of(past.conv),
+                **{
+                    name: jnp.zeros(
+                        (m.num_mamba_layers, B, steps, width), dt
+                    )
+                    for name, width, dt in (
+                        ("dt", m.mamba_heads, jnp.float32),
+                        ("dA", m.mamba_heads, jnp.float32),
+                        ("x", m.mamba_inner, act),
+                        ("B", m.mamba_groups * m.mamba_state, act),
+                    )
+                },
+            }
+
         def body(carry, step_idx):
-            wk, wv, wc, last = carry
+            wk, wv, wc, ws, last = carry
             logits, _, (k, v) = self._trunk_decode(
                 params, cache, last[:, None],
                 (past_len + step_idx)[:, None], past_len, page_table,
                 window_past=(wk, wv, step_idx), pfx=pfx,
                 conv_state=None if wc is None
                 else jax.lax.dynamic_slice_in_dim(wc, step_idx, K1, axis=2),
+                state_past=None if ws is None else dataclasses.replace(
+                    past,
+                    conv=jax.lax.dynamic_slice_in_dim(
+                        ws["conv"], step_idx, K1, axis=2
+                    ),
+                    window=(ws["dt"], ws["dA"], ws["x"], ws["B"], step_idx),
+                ),
             )
             route = self._route_stats(k)
             if mixed:
@@ -1109,6 +1263,15 @@ class ModelRunner:
                         wc, k.conv[:, :, K1:].astype(wc.dtype),
                         (0, 0, K1 + step_idx, 0),
                     )
+                if ws is not None:
+                    ws = {
+                        name: jax.lax.dynamic_update_slice(
+                            buf,
+                            k.ssm[name][:, :, -1:].astype(buf.dtype),
+                            (0, 0, step_idx + (K1 if name == "conv" else 0), 0),
+                        )
+                        for name, buf in ws.items()
+                    }
                 k = k.k
             wk = jax.lax.dynamic_update_slice(
                 wk, k.astype(dtype).reshape(L, B, 1, KD),
@@ -1137,15 +1300,15 @@ class ModelRunner:
                 temperature=temperature, top_p=top_p, top_k=top_k,
             )
             logp = cumulative_logprob(step_logits, tok)
-            return (wk, wv, wc, tok), (tok, logp, route)
+            return (wk, wv, wc, ws, tok), (tok, logp, route)
 
-        (wk, wv, wc, _), (toks, logps, route) = jax.lax.scan(
+        (wk, wv, wc, ws, _), (toks, logps, route) = jax.lax.scan(
             body,
-            (wk0, wv0, wc0, last),
+            (wk0, wv0, wc0, ws0, last),
             jnp.arange(steps, dtype=jnp.int32),
         )
         if mixed:
-            wk = MixedChunk(k=wk, conv=wc, route=route)
+            wk = MixedChunk(k=wk, conv=wc, route=route, ssm=ws)
         return toks, logps, wk, wv
 
     def decode_multi(
@@ -1232,15 +1395,19 @@ class ModelRunner:
             kernel_mesh=self.kernel_mesh,
             ep_mesh=self.ep_mesh,
             conv_state=self._state_at(cache, page_table, start),
+            state_past=self._state_past(cache, page_table, start),
+            ssm_pending=True,
         )
         # K/V of every input is written (rejected positions are dead
         # stores past the accepted length); conv state is ONE value a
         # page, so it waits for the accepted length: the chunk's gated
         # inputs go back to the caller (``commit_verified``)
         pending = None
-        if isinstance(k, MixedChunk):
-            pending = k.conv
-            k = dataclasses.replace(k, conv=None)
+        if isinstance(k, MixedChunk) and (
+            k.conv is not None or k.ssm is not None
+        ):
+            pending = MixedChunk(k=None, conv=k.conv, ssm=k.ssm)
+            k = dataclasses.replace(k, conv=None, ssm=None)
         cache = write_kv(
             cache, k, v, page_table, start, valid_len,
             use_pallas=self.use_pallas,
@@ -1332,9 +1499,8 @@ class ModelRunner:
         )
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
-    def _commit_state_jit(self, cache: KVCache, conv, page_table, start, n):
-        return write_kv(cache, MixedChunk(k=None, conv=conv), None,
-                        page_table, start, n)
+    def _commit_state_jit(self, cache: KVCache, held, page_table, start, n):
+        return write_kv(cache, held, None, page_table, start, n)
 
     def commit_verified(self, accepted: np.ndarray) -> None:
         """After ``verify_candidates`` on a model that keeps conv state
@@ -1344,10 +1510,10 @@ class ModelRunner:
         held, self._verified = getattr(self, "_verified", None), None
         if held is None:
             return
-        conv, page_table, past_len = held
+        chunk, page_table, past_len = held
         self._count_state_commit("verify")
         self.cache = self._commit_state_jit(
-            self.cache, conv,
+            self.cache, chunk,
             jnp.asarray(page_table, jnp.int32),
             jnp.asarray(past_len, jnp.int32),
             jnp.asarray(accepted, jnp.int32),

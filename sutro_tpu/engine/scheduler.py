@@ -425,6 +425,10 @@ class _DecodeBatch(NamedTuple):
 
 
 class ContinuousBatcher:
+    # the runner keeps its state a slot a sequence (set in __init__)
+    _slot_state = False
+    _tier_refused = False
+
     def __init__(
         self,
         runner: ModelRunner,
@@ -480,6 +484,17 @@ class ContinuousBatcher:
         # a mismatched page size) resets to empty instead of poisoning
         # the run — the ids are already free here, so forgetting the
         # tree is the only consistent move.
+        # A model that keeps its state a SLOT a sequence
+        # (runner.state_slots, kvcache.StateSlots): no page holds the
+        # state at the end of a shared prefix or of a hibernated row, so
+        # those paths fall back to prefilling again, and say so
+        # (sutro_state_fallback_prefill_tokens_total). A new session's
+        # pages are all free, so every slot is too.
+        self._slot_state = getattr(runner, "state_slots", None) is not None
+        self._tier_refused = self._slot_state and kv_tier is not None
+        if self._slot_state:
+            prefix_store = kv_tier = None
+            runner.reset_state_slots()
         self._prefix_store = None
         if (
             prefix_store is not None
@@ -722,6 +737,14 @@ class ContinuousBatcher:
                 lcp = int(neq[0])
         shared = (lcp // PS) * PS
         if shared < PS:
+            return
+        if self._slot_state:
+            # the rows would start from the state after the shared
+            # pages, and only the row that wrote them has it (a snapshot
+            # a stored prefix is not kept): every row prefills its own
+            self._count_state_fallback(
+                shared * (len(pending) - 1), "prefix_without_state_snapshot"
+            )
             return
         n_pages = shared // PS
         # warm head from the radix store (pins the matched path);
@@ -1006,6 +1029,17 @@ class ContinuousBatcher:
             )
         return h
 
+    def _count_state_fallback(self, tokens: int, reason: str) -> None:
+        if self._tel_on and tokens > 0:
+            telemetry.STATE_FALLBACK_PREFILL_TOKENS_TOTAL.inc(
+                float(tokens), reason
+            )
+
+    def _release_state(self, own_pages) -> None:
+        """With a row's pages goes its state slot (bound to the first)."""
+        if self._slot_state and len(own_pages):
+            self.runner.release_state(own_pages[0])
+
     def _reserve(
         self, req: GenRequest, ctx: JobCtx, reserved: int = 0,
         exclude=frozenset(),
@@ -1022,6 +1056,14 @@ class ContinuousBatcher:
         pages and only the remainder is allocated per slot."""
         n = len(req.prompt_ids)
         pfx = ctx.prefix
+        if self._slot_state and self.runner.state_slots.free_count < 1:
+            # admission waits for a state slot as it waits for pages
+            if self._tel_on and any(
+                s is None and i not in exclude
+                for i, s in enumerate(self.slots)
+            ):
+                telemetry.STATE_SLOT_WAITS_TOTAL.inc(1.0)
+            return None
 
         def _admit_native():
             if pfx is not None:
@@ -1092,6 +1134,9 @@ class ContinuousBatcher:
                 table[pfx.n_pages : pfx.n_pages + own] = pages
             else:
                 table[: len(pages)] = pages
+        if self._slot_state:
+            # the device is told with the row's prefill
+            self.runner.bind_state([table[0]], flush=False)
         return free_idx, pages, table
 
     # -- double-buffered admission prep --------------------------------
@@ -1207,6 +1252,7 @@ class ContinuousBatcher:
         """Roll back a reservation whose prefill never armed a slot (a
         raised prefill would otherwise leak the slot's pages forever in a
         long-lived daemon)."""
+        self._release_state(pages)
         if self.native is not None:
             self.native.release(slot_idx)
         else:
@@ -1234,6 +1280,7 @@ class ContinuousBatcher:
                         )
                     ),
                     "batch": len(batch),
+                    **self._state_attrs(len(batch)),
                 }
             with self.timer.time("prefill"):
                 if len(batch) == 1:
@@ -1353,7 +1400,9 @@ class ContinuousBatcher:
         C = self.ecfg.prefill_chunk
         seg = req.prompt_ids[s.prefill_pos : s.prefill_pos + C]
         if self._tel_on:
-            self._tel_attrs["prefill"] = {"tokens": int(len(seg))}
+            self._tel_attrs["prefill"] = {
+                "tokens": int(len(seg)), **self._state_attrs(1),
+            }
         with self.timer.time("prefill"):
             logits = self.runner.prefill_batch_at(
                 [np.asarray(seg, np.int32)],
@@ -1887,6 +1936,7 @@ class ContinuousBatcher:
         bookkeeping; the in-flight-window dead-store argument documented
         there covers the pages freed here too."""
         slot = self.slots[i]
+        self._release_state(slot.pages[slot.shared_n :])
         if self.native is not None:
             self.native.release(i)
         else:
@@ -2052,6 +2102,7 @@ class ContinuousBatcher:
             and not slot.prefilling
         ):
             kept = self._checkpoint_slot(slot)
+        self._release_state(slot.pages[slot.shared_n :])
         if self.native is not None:
             self.native.release(i)
             if kept and not self.native.reserve_pages(
@@ -2229,6 +2280,17 @@ class ContinuousBatcher:
             return "window"
         return "single"
 
+    def _state_attrs(self, rows: int) -> Dict[str, int]:
+        """Span attrs of a dispatch that advances ``rows`` rows' slot
+        state: the rows, and the bytes of state a step of it reads
+        (runner.state_step_bytes). Nothing for any other model."""
+        if not self._slot_state:
+            return {}
+        return {
+            "state_rows": int(rows),
+            "state_bytes": int(self.runner.state_step_bytes(rows)),
+        }
+
     def _note_window(self, b: _DecodeBatch, steps: int) -> None:
         """Window attribution for the doctor's roofline grade:
         occupancy x fused steps over the span's duration is the
@@ -2242,6 +2304,7 @@ class ContinuousBatcher:
                     sum(int(b.past_len[i]) for i in b.active) / max(n, 1),
                     1,
                 ),
+                **self._state_attrs(n),
                 **self._route_attrs.get("decode_window", {}),
             }
 
@@ -2914,6 +2977,12 @@ class ContinuousBatcher:
         corrupt row. Returns True when the slot was hibernated and its
         ORIGINAL request (live constraint and all) re-queued."""
         if not self._can_hibernate:
+            if self._tier_refused and self.slots[i] is not None:
+                # the tier is there, the row's state is in no page: the
+                # caller's plain suspend regenerates the row
+                self._count_state_fallback(
+                    self.slots[i].pos, "hibernate_without_slot_state"
+                )
             return False
         s = self.slots[i]
         if s is None or s.prefilling or s.job is None:

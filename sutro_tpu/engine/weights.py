@@ -222,6 +222,12 @@ def _load_mixed(mcfg: ModelConfig, get, dtype) -> Dict[str, Any]:
     layer order (models/transformer.py ``_init_mixed_layers``).
     ``Linear`` weights are transposed to [in, out]; the depthwise conv
     weight [H, 1, K] becomes [H, K]."""
+    if mcfg.num_mamba_layers:
+        raise NotImplementedError(
+            f"{mcfg.name}: loading a checkpoint with mamba layers (the "
+            "granitemoehybrid tensor names) is not written; the model "
+            "runs on seeded random weights"
+        )
     stacks: Dict[str, Dict[str, list]] = {}
 
     def put(kind: str, name: str, arr: np.ndarray) -> None:

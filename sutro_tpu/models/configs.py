@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers six
+config-driven decoder-only transformer (models/transformer.py) covers seven
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -16,6 +16,10 @@ families:
   short convolution with K-1 columns of per-sequence state, or GQA
   attention; leading dense SwiGLU layers, then a sigmoid router with a
   selection-only bias
+- Granite 4.0-H (micro): Mamba-2 layers (a state that is a matrix a
+  head and K-1 conv columns, kept a SLOT a sequence) beside GQA
+  attention without rotary embedding; a softmax scale of its own and
+  three scalar multipliers (embedding, residual, logits)
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -61,12 +65,30 @@ class ModelConfig:
     router_select_bias: bool = False
     router_renorm: bool = True
     router_scale: float = 1.0
-    # Per-layer mixer kinds, "attention" | "conv"; empty => attention
-    # everywhere. A "conv" layer is the gated short convolution of the
-    # LFM2 family: depthwise, causal, ``conv_kernel`` taps, and K-1
-    # columns of per-sequence state beside the paged K/V.
+    # Per-layer mixer kinds, "attention" | "conv" | "mamba"; empty =>
+    # attention everywhere. A "conv" layer is the gated short
+    # convolution of the LFM2 family: depthwise, causal, ``conv_kernel``
+    # taps, and K-1 columns of per-sequence state beside the paged K/V.
     layer_types: Tuple[str, ...] = ()
     conv_kernel: int = 0
+    # A "mamba" layer is Mamba-2 (models/transformer.py ``mamba_mixer``):
+    # ``mamba_heads`` heads of ``mamba_head_dim``, each with a state
+    # [head_dim, mamba_state]; B and C shared by the heads of one of
+    # ``mamba_groups`` groups; a depthwise causal conv of ``mamba_conv``
+    # taps over [x | B | C]; the chunked scan at ``mamba_chunk`` tokens.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state: int = 0
+    mamba_groups: int = 1
+    mamba_conv: int = 0
+    mamba_chunk: int = 256
+    # Granite's scalar multipliers (1.0: none) and softmax scale (None:
+    # 1/sqrt(head_dim)); "nope" applies no rotary embedding
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
+    position_embedding: str = "rope"
     # Sliding window attention: 0 => full attention everywhere.
     sliding_window: int = 0
     # "none" | "alternate" (gpt-oss: even layers sliding) |
@@ -140,6 +162,25 @@ class ModelConfig:
     def conv_state_len(self) -> int:
         """Columns of conv state a sequence keeps a conv layer (K-1)."""
         return max(self.conv_kernel - 1, 0) if self.num_conv_layers else 0
+
+    @property
+    def num_mamba_layers(self) -> int:
+        return self.mixers.count("mamba")
+
+    @property
+    def mamba_inner(self) -> int:
+        """Width of a mamba layer's x, z and y (heads x head_dim)."""
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of a mamba layer's conv: [x | B | C]."""
+        return self.mamba_inner + 2 * self.mamba_groups * self.mamba_state
+
+    @property
+    def mamba_conv_len(self) -> int:
+        """Conv columns a sequence keeps a mamba layer (taps - 1)."""
+        return max(self.mamba_conv - 1, 0) if self.num_mamba_layers else 0
 
     def window_for_layer(self, layer: int) -> int:
         """Per-layer attention window (0 = full); SURVEY §5.7 long-context."""
@@ -248,6 +289,36 @@ def _lfm2_moe(name: str, layer_types: Tuple[str, ...], *, h: int = 2048,
     )
 
 
+#: granite-4.0-h-micro's published ``layer_types`` (config.json):
+#: attention at layers 5, 15, 25 and 35, Mamba-2 everywhere else
+_GRANITE_H_MICRO_LAYERS: Tuple[str, ...] = (
+    ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+) * 4
+
+
+def _granite_hybrid(name: str, layer_types: Tuple[str, ...], *,
+                    h: int = 2048, nh: int = 32, nkv: int = 8,
+                    inter: int = 8192, m_heads: int = 64,
+                    m_head_dim: int = 64, m_state: int = 128,
+                    m_chunk: int = 256, vocab: int = 100_352,
+                    template: str = "chatml") -> ModelConfig:
+    """The published ``granitemoehybrid`` keys of a dense member
+    (``num_local_experts`` 0: the FFN is the shared SwiGLU alone)."""
+    return ModelConfig(
+        name=name, vocab_size=vocab, hidden_size=h,
+        num_layers=len(layer_types), num_heads=nh, num_kv_heads=nkv,
+        head_dim=h // nh, intermediate_size=inter, norm_eps=1e-5,
+        rope_theta=10_000.0, qk_norm=False, tie_embeddings=True,
+        layer_types=layer_types,
+        mamba_heads=m_heads, mamba_head_dim=m_head_dim,
+        mamba_state=m_state, mamba_groups=1, mamba_conv=4,
+        mamba_chunk=m_chunk,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=8.0, attention_multiplier=0.015625,
+        position_embedding="nope", chat_template=template,
+    )
+
+
 MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # Qwen3 dense
     "qwen3-0.6b": _qwen3("qwen3-0.6b", 1024, 28, 16, 8, 3072),
@@ -275,6 +346,10 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     "lfm2-24b-a2b": _lfm2_moe("lfm2-24b-a2b", _LFM2_24B_LAYERS),
     "lfm2-24b-a2b-l10": _lfm2_moe(
         "lfm2-24b-a2b-l10", _LFM2_24B_LAYERS[:10]
+    ),
+    # Granite 4.0-H: Mamba-2 + NoPE GQA, dense; whole on one v5e
+    "granite-4.0-h-micro": _granite_hybrid(
+        "granite-4.0-h-micro", _GRANITE_H_MICRO_LAYERS
     ),
     # Embeddings (Qwen3 trunk + last-token-pool head)
     "qwen3-emb-0.6b": _qwen3("qwen3-emb-0.6b", 1024, 28, 16, 8, 3072, head="embedding"),
@@ -305,6 +380,14 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         ("conv", "conv", "attention", "conv", "conv", "conv"),
         h=128, nh=4, nkv=2, inter=256, experts=16, top_k=4, moe_inter=64,
         vocab=512, template="plain",
+    ),
+    # a chunk of 8 so that short test prompts cross chunk boundaries
+    "tiny-granite": _granite_hybrid(
+        "tiny-granite",
+        ("mamba", "mamba", "attention", "mamba", "mamba", "attention",
+         "mamba"),
+        h=128, nh=4, nkv=2, inter=256, m_heads=8, m_head_dim=32,
+        m_state=16, m_chunk=8, vocab=512, template="plain",
     ),
     "tiny-emb": ModelConfig(
         name="tiny-emb", vocab_size=512, hidden_size=128, num_layers=2,

@@ -9,9 +9,10 @@ models remotely — SURVEY §0). Design choices are TPU-first:
   annotated per-leaf by path rules (parallel/sharding.py). A model whose
   layers are of several kinds (``ModelConfig.layer_types``,
   ``num_dense_layers``) stacks its parameters PER KIND,
-  ``params["layers"][kind][name] [L_kind, ...]`` for the mixers "attn"
-  and "conv" and the FFNs "dense" and "moe", and walks the config's own
-  list of layers, scanning each repeated group (``_mixed_trunk``).
+  ``params["layers"][kind][name] [L_kind, ...]`` for the mixers "attn",
+  "conv" and "mamba" and the FFNs "dense" and "moe", and walks the
+  config's own list of layers, scanning each repeated group
+  (``_mixed_trunk``).
 - Static shapes everywhere: decode attends over a fixed ``CTX`` window
   gathered from the paged KV cache and masks invalid positions; prefill is
   bucketed by the runner. No data-dependent Python control flow.
@@ -63,6 +64,32 @@ class MixedChunk:
     conv: Optional[jax.Array] = None   # [L_conv, B, K-1+T, H]
     # rows each expert got, every routed layer (padding rows too)
     route: Optional[jax.Array] = None  # [L_moe, E] int32
+    # what commits the mamba layers' state (``mamba_mixer``), stacked
+    # over those layers. Always "conv": [L_m, B, K-1+T', Cd]. Then
+    # either "final" [L_m, B, N, I], the state after the chunk's
+    # ``valid_len`` tokens (T' = 0: "conv" is the columns after them),
+    # or, for a chunk whose accepted length is decided later (T' = T),
+    # the tokens' "dt", "dA" [L_m, B, T, Hm], "x" [L_m, B, T, I] and
+    # "B" [L_m, B, T, G*N], from which ``kvcache.write_kv`` computes
+    # the state after ANY n <= T of them
+    ssm: Optional[Dict[str, jax.Array]] = None
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class StatePast:
+    """A mamba model's per-sequence state as ``forward`` reads it. The
+    pool is a CONSTANT of every scan (like the page pool): no forward
+    writes it; ``kvcache.write_kv`` commits a chunk."""
+
+    ssm: jax.Array      # [L_m, NS, N, I]: the slot pool
+    slots: jax.Array    # [B] int32: each row's slot (0: the garbage slot)
+    fresh: jax.Array    # [B] bool: the row starts a sequence (state 0)
+    conv: jax.Array     # [L_m, B, K-1, Cd]: conv columns before the chunk
+    # inside a fused window: the window's earlier tokens, not yet
+    # committed, as (dt, dA [L_m, B, W, Hm], x [L_m, B, W, I],
+    # B [L_m, B, W, G*N], step index)
+    window: Optional[Tuple[jax.Array, ...]] = None
 
 
 def _w(lp: Dict[str, Any], name: str, dtype) -> jax.Array:
@@ -112,6 +139,8 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
     La, Lc = cfg.num_attn_layers, cfg.num_conv_layers
     Ld, Lm = cfg.ffns.count("dense"), cfg.ffns.count("moe")
     out: Dict[str, Any] = {}
+    if cfg.num_mamba_layers:
+        out["mamba"] = _init_mamba_layers(cfg, dense, dtype)
     if La:
         out["attn"] = {
             "attn_norm": jnp.ones((La, H), dtype),
@@ -153,6 +182,36 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
                 dense((Lm, E), 1) * 0.02
             ).astype(jnp.float32)
     return out
+
+
+def _init_mamba_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
+    """The "mamba" stack. ``a_log``, ``dt_bias`` and ``d_skip`` as the
+    Mamba-2 reference code draws them: A uniform in [1, 16], dt
+    log-uniform in [0.001, 0.1] through the inverse softplus, D = 1, so
+    that the heads' decays span short and long memory. float32, like
+    the router's selection bias."""
+    L, H = cfg.num_mamba_layers, cfg.hidden_size
+    I, Cd, Hm = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.mamba_heads
+    u = (dense((L, Hm), 1), dense((L, Hm), 1))  # normal draws -> uniform
+    ua, ud = (
+        jax.scipy.stats.norm.cdf(x.astype(jnp.float32)) for x in u
+    )
+    dt = jnp.exp(ud * (np.log(0.1) - np.log(0.001)) + np.log(0.001))
+    return {
+        "attn_norm": jnp.ones((L, H), dtype),
+        # the published in_proj's columns [z | x B C | dt] as two
+        # matrices: its last ``heads`` columns (dt) apart, so that each
+        # is a whole number of 128-lane tiles wide
+        "w_in": dense((L, H, I + Cd), H),        # [z | x B C]
+        "w_dt": dense((L, H, Hm), H),
+        "w_conv": dense((L, Cd, cfg.mamba_conv), cfg.mamba_conv),
+        "b_conv": dense((L, Cd), 16),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),       # softplus^-1(dt)
+        "a_log": jnp.log(1.0 + 15.0 * ua),
+        "d_skip": jnp.ones((L, Hm), jnp.float32),
+        "gate_norm": jnp.ones((L, I), dtype),
+        "w_out": dense((L, I, H), I),
+    }
 
 
 def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
@@ -420,8 +479,15 @@ def attention_mixer(
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps, cfg.norm_zero_centered)
         k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_zero_centered)
-    q = apply_rope(q, positions, theta, cfg)
-    k = apply_rope(k, positions, theta, cfg)
+    if cfg.position_embedding != "nope":
+        q = apply_rope(q, positions, theta, cfg)
+        k = apply_rope(k, positions, theta, cfg)
+    if cfg.attention_multiplier is not None:
+        # every attention path scales by 1/sqrt(Dh): fold the ratio
+        # into q (a power of two for the published multipliers)
+        q = q * jnp.asarray(
+            cfg.attention_multiplier * cfg.head_dim ** 0.5, q.dtype
+        )
     sink = lp.get("sink") if cfg.attention_sink else None
     attn = chunk_attention(
         q, k, v,
@@ -475,6 +541,227 @@ def conv_mixer(
     )
     y = bcz[..., H : 2 * H] * c.astype(x.dtype)
     return y @ _w(lp, "w_out", x.dtype), g_ext
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2
+# ---------------------------------------------------------------------------
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def per_channel(per_head: jax.Array, head_dim: int) -> jax.Array:
+    """[..., Hm] -> [..., Hm * head_dim]: a head's value at each of its
+    channels (the state's minor axis is the channels, fused)."""
+    return jnp.repeat(per_head, head_dim, axis=-1)
+
+
+def ssd_chunked(
+    x: jax.Array,    # [B, T, Hm, P] float32
+    dt: jax.Array,   # [B, T, Hm]    float32, 0 past a row's valid_len
+    dA: jax.Array,   # [B, T, Hm]    float32 = dt * A (<= 0)
+    Bm: jax.Array,   # [B, T, G, N]  float32: a group's heads share it
+    Cm: jax.Array,   # [B, T, G, N]  float32
+    S0: jax.Array,   # [B, N, Hm, P] float32: the state before the chunk
+    chunk: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence ``S_t = exp(dA_t) S_{t-1} + dt_t x_t B_t^T``,
+    ``y_t = S_t C_t`` over T tokens in chunks of ``chunk``: within a
+    chunk the masked ``C B^T`` product, between chunks the carried
+    state. Returns ``(y [B, T, Hm, P], S_T)``. A token with ``dt`` 0
+    neither decays nor feeds the state, so ``S_T`` is the state after a
+    row's valid tokens when its padding has ``dt`` 0."""
+    B, T, Hm, P = x.shape
+    G, N = Bm.shape[2:]
+    K = Hm // G                                   # heads a group
+    Q = min(chunk, T)
+    pad = -T % Q
+    if pad:
+        x, dt, dA, Bm, Cm = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (x, dt, dA, Bm, Cm)
+        )
+    nc = (T + pad) // Q
+
+    def split(a, tail):  # [B, nc*Q, ...] -> [nc, B, Q, *tail]
+        return jnp.moveaxis(a.reshape((B, nc, Q) + tail), 1, 0)
+
+    tri = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def step(S, c):
+        x, dt, dA, Bm, Cm = c      # x [B,Q,G,K,P]; dt, dA [B,Q,G,K]
+        cum = jnp.cumsum(dA, axis=1)
+        # w[t, s] = exp(cum_t - cum_s) dt_s (C_t . B_s), s <= t
+        seg = cum[:, :, None] - cum[:, None, :]               # [B, t, s, G, K]
+        decay = jnp.where(
+            tri[None, :, :, None, None], jnp.exp(jnp.minimum(seg, 0.0)), 0.0
+        )
+        g = jnp.einsum("btgn,bsgn->btsg", Cm, Bm, precision=_HI)
+        w = g[..., None] * decay * dt[:, None]
+        y = jnp.einsum("btsgk,bsgkp->btgkp", w, x, precision=_HI)
+        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+            "btgn,bngkp->btgkp", Cm, S, precision=_HI
+        )
+        left = jnp.exp(cum[:, -1:] - cum) * dt                # [B, Q, G, K]
+        S = jnp.exp(cum[:, -1])[:, None, :, :, None] * S + jnp.einsum(
+            "bsgn,bsgkp->bngkp", Bm, left[..., None] * x, precision=_HI
+        )
+        return S, y
+
+    S, y = jax.lax.scan(
+        step, S0.reshape(B, N, G, K, P),
+        (split(x, (G, K, P)), split(dt, (G, K)), split(dA, (G, K)),
+         split(Bm, (G, N)), split(Cm, (G, N))),
+    )
+    y = jnp.moveaxis(y, 0, 1).reshape(B, nc * Q, Hm, P)
+    return y[:, :T], S.reshape(B, N, Hm, P)
+
+
+def ssd_pending(
+    cfg: ModelConfig,
+    pool: jax.Array,   # [NS, N, I]: this layer's slots, read in place
+    slots: jax.Array,  # [B] int32
+    fresh: jax.Array,  # [B] bool
+    x: jax.Array,      # [B, W, I]   float32: the uncommitted tokens,
+    dt: jax.Array,     # [B, W, Hm]  the chunk's own among them
+    dA: jax.Array,     # [B, W, Hm]
+    Bm: jax.Array,     # [B, W, G*N]
+    Cq: jax.Array,     # [B, T, G*N]: C of the chunk's own tokens
+    q0,                # index among the W of the chunk's first token
+) -> jax.Array:
+    """``y`` [B, T, I] of a short chunk whose state is NOT advanced:
+    the committed state is read once, where it lies (every slot of the
+    pool times its row's C, reduced over N on the major axis: no
+    gather of the state, no write), and the uncommitted tokens up to
+    each query enter through the masked ``C B^T`` product. Tokens after
+    a query are masked out, so buffers may hold anything there."""
+    B, W, I = x.shape
+    T = Cq.shape[1]
+    Hm, P, G = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups
+    NS = pool.shape[0]
+    cum = jnp.cumsum(dA, axis=1)                              # [B, W, Hm]
+    cum_q = jax.lax.dynamic_slice_in_dim(cum, q0, T, axis=1)  # [B, T, Hm]
+    seen = (
+        jnp.arange(W, dtype=jnp.int32)[None, :]
+        <= q0 + jnp.arange(T, dtype=jnp.int32)[:, None]
+    )                                                         # [T, W]
+    seg = cum_q[:, :, None, :] - cum[:, None, :, :]           # [B, T, W, Hm]
+    decay = jnp.where(
+        seen[None, :, :, None], jnp.exp(jnp.minimum(seg, 0.0)), 0.0
+    )
+    g = jnp.einsum(
+        "btgn,bsgn->btsg",
+        Cq.reshape(B, T, G, -1), Bm.reshape(B, W, G, -1), precision=_HI,
+    )
+    w = jnp.repeat(g, Hm // G, axis=-1) * decay * dt[:, None, :, :]
+    y = jnp.einsum(
+        "btsh,bshp->bthp", w, x.reshape(B, W, Hm, P), precision=_HI
+    ).reshape(B, T, I)
+    # the committed state: slot-major, so each slot takes ITS row's C
+    row = jnp.zeros((NS,), jnp.int32).at[slots].set(
+        jnp.arange(B, dtype=jnp.int32)
+    )
+    Cs = Cq[row]                                              # [NS, T, G*N]
+    state = pool.astype(jnp.float32)
+    yS = jnp.stack([
+        jnp.sum(state * over_state(Cs[:, t], G, I), axis=1)
+        for t in range(T)
+    ], axis=1)                                                # [NS, T, I]
+    inter = per_channel(jnp.exp(cum_q), P) * yS[slots]
+    return y + jnp.where(fresh[:, None, None], 0.0, inter)
+
+
+def over_state(c: jax.Array, groups: int, inner: int) -> jax.Array:
+    """[NS, G*N] -> [NS, N, 1 or I]: a token's B (or C) as it multiplies
+    a state laid out [NS, N, I]; one group broadcasts over the channels,
+    several each over its heads' channels."""
+    c = jnp.swapaxes(c.reshape(c.shape[0], groups, -1), 1, 2)  # [NS, N, G]
+    return c if groups == 1 else jnp.repeat(c, inner // groups, axis=-1)
+
+
+def mamba_mixer(
+    cfg: ModelConfig,
+    lp: Dict[str, Any],          # one mamba layer's params
+    u: jax.Array,                # [B, T, H], normed
+    *,
+    valid_len: jax.Array,        # [B]
+    past: StatePast,
+    layer,                       # this layer's index among the mamba layers
+    pending: bool,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One Mamba-2 mixer over a chunk (``ModelConfig.mamba_*``):
+
+        [z | xBC] = u W_in ;  dt = u W_dt
+        xBC = silu(conv1d(xBC) + b)      causal, depthwise, K taps
+        [x | B | C] = xBC
+        dt = softplus(dt + dt_bias),  A = -exp(a_log)   a head
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T ;  y_t = S_t C_t + D x_t
+        out = (RMSNorm(y * silu(z)) * w_norm) W_out
+
+    The recurrence and the gate norm in float32. Tokens past a row's
+    ``valid_len`` get ``dt`` 0, so the state after the chunk is the
+    state after ``valid_len`` tokens. ``pending`` False (prefill): the
+    chunked scan from the row's state (gathered from its slot) to the
+    state after the chunk, returned as "final". ``pending`` True (a
+    decode step, a verify chunk): the state is read in place and not
+    advanced (``ssd_pending``); the tokens' dt, dA, x, B are returned
+    and ``kvcache.write_kv`` commits the accepted ones."""
+    Bsz, T = u.shape[:2]
+    I, Cd, Hm = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.mamba_heads
+    P, G, N = cfg.mamba_head_dim, cfg.mamba_groups, cfg.mamba_state
+    K = cfg.mamba_conv
+    f32 = jnp.float32
+    zx = u @ _w(lp, "w_in", u.dtype)
+    z, xbc = zx[..., :I], zx[..., I:]
+    dt = u @ _w(lp, "w_dt", u.dtype)
+    ext = jnp.concatenate([past.conv[layer].astype(xbc.dtype), xbc], axis=1)
+    taps = lp["w_conv"].astype(f32)                           # [Cd, K]
+    c = sum(ext[:, j : j + T].astype(f32) * taps[:, j] for j in range(K))
+    xbc = jax.nn.silu(c + lp["b_conv"].astype(f32))           # [B, T, Cd] f32
+    x, Bm, Cm = xbc[..., :I], xbc[..., I : I + G * N], xbc[..., I + G * N :]
+    dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    live = jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
+    dt = jnp.where(live[..., None], dt, 0.0)
+    dA = dt * -jnp.exp(lp["a_log"].astype(f32))
+    out: Dict[str, jax.Array] = {}
+    if pending:
+        out["ssm_conv"] = ext
+        cur = {"ssm_dt": dt, "ssm_dA": dA, "ssm_x": x.astype(u.dtype),
+               "ssm_B": Bm.astype(u.dtype)}
+        out.update(cur)
+        q0 = 0
+        xs, dts, dAs, Bs = x, dt, dA, Bm
+        if past.window is not None:
+            # the window's earlier tokens, and this one in its place
+            *bufs, q0 = past.window
+            dts, dAs, xs, Bs = (
+                jax.lax.dynamic_update_slice_in_dim(
+                    b[layer].astype(f32), a, q0, axis=1
+                )
+                for b, a in zip(bufs, (dt, dA, x, Bm))
+            )
+        y = ssd_pending(
+            cfg, past.ssm[layer], past.slots, past.fresh,
+            xs, dts, dAs, Bs, Cm, q0,
+        )
+    else:
+        S0 = past.ssm[layer][past.slots].astype(f32)          # [B, N, I]
+        S0 = jnp.where(past.fresh[:, None, None], 0.0, S0)
+        y, S = ssd_chunked(
+            x.reshape(Bsz, T, Hm, P), dt, dA,
+            Bm.reshape(Bsz, T, G, N), Cm.reshape(Bsz, T, G, N),
+            S0.reshape(Bsz, N, Hm, P), cfg.mamba_chunk,
+        )
+        y = y.reshape(Bsz, T, I)
+        out["ssm_final"] = S.reshape(Bsz, N, I)
+        # the conv columns after valid_len tokens
+        cols = valid_len[:, None] + jnp.arange(K - 1, dtype=jnp.int32)
+        out["ssm_conv"] = jnp.take_along_axis(ext, cols[..., None], axis=1)
+    y = y + per_channel(lp["d_skip"].astype(f32), P) * x
+    y = y * jax.nn.silu(z.astype(f32))
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+    y = (y * lp["gate_norm"].astype(f32)).astype(u.dtype)
+    return y @ _w(lp, "w_out", u.dtype), out
 
 
 def layer_apply(
@@ -545,7 +832,7 @@ def layer_apply(
 # Layers of several kinds
 # ---------------------------------------------------------------------------
 
-_MIXER_STACK = {"attention": "attn", "conv": "conv"}
+_MIXER_STACK = {"attention": "attn", "conv": "conv", "mamba": "mamba"}
 
 
 def _check_mixed(cfg: ModelConfig) -> None:
@@ -560,6 +847,14 @@ def _check_mixed(cfg: ModelConfig) -> None:
         raise ValueError(f"{cfg.name}: unknown layer kinds {sorted(unknown)}")
     if cfg.num_conv_layers and cfg.conv_kernel < 2:
         raise ValueError(f"{cfg.name}: conv layers need conv_kernel >= 2")
+    if cfg.num_mamba_layers and (
+        cfg.mamba_conv < 2 or cfg.mamba_heads % cfg.mamba_groups
+        or min(cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state) < 1
+    ):
+        raise ValueError(
+            f"{cfg.name}: mamba layers need mamba_conv >= 2, heads, a "
+            "head_dim and a state size, and heads a multiple of groups"
+        )
     unsupported = {
         "sliding windows": cfg.sliding_pattern != "none",
         "post norms": cfg.post_norms,
@@ -599,6 +894,11 @@ def layer_groups(cfg: ModelConfig) -> List[Tuple[int, int, int]]:
     return groups
 
 
+# ``mamba_mixer``'s outputs as the walk stacks them (MixedChunk.ssm's
+# keys, prefixed)
+_SSM_KEYS = ("ssm_conv", "ssm_final", "ssm_dt", "ssm_dA", "ssm_x", "ssm_B")
+
+
 def _index_in_kind(kinds) -> List[int]:
     seen: Dict[str, int] = {}
     out = []
@@ -612,21 +912,37 @@ def _mixed_trunk(
     cfg: ModelConfig, params: Params, h: jax.Array, *,
     positions, valid_len, conv_state, k_pages, v_pages, k_scale, v_scale,
     page_table, past_len, window_past, use_pallas, ep_mesh,
-    pfx_groups, kernel_mesh,
+    pfx_groups, kernel_mesh, state_past=None, ssm_pending=False,
 ):
     """The walk over a config's own list of layers, parameters stacked
     per kind. Every stack (and the page pool, the window buffers, the
     conv state) is a CONSTANT of each group's scan and the layer is an
     index into it, as the pool is in the homogeneous scan: a stack
     among the scan's xs would have to be sliced out per group first,
-    which copies it. Returns ``(h, k, v, conv, route)``: K/V stacked
-    over the attention layers, ``g_ext`` over the conv layers, expert
-    row counts over the routed layers (None for a kind with no layer).
+    which copies it. Returns ``(h, k, v, conv, route, ssm)``: K/V
+    stacked over the attention layers, ``g_ext`` over the conv layers,
+    expert row counts over the routed layers (None for a kind with no
+    layer), and what commits the mamba layers' state (``MixedChunk``).
     """
     _check_mixed(cfg)
     stacks = params["layers"]
     B, T = h.shape[:2]
     K1 = cfg.conv_state_len
+    r = cfg.residual_multiplier
+    if cfg.num_mamba_layers and state_past is None:
+        # no cache: every row starts a sequence, in the garbage slot
+        state_past = StatePast(
+            ssm=jnp.zeros(
+                (cfg.num_mamba_layers, 1, cfg.mamba_state, cfg.mamba_inner),
+                h.dtype,
+            ),
+            slots=jnp.zeros((B,), jnp.int32),
+            fresh=jnp.ones((B,), bool),
+            conv=jnp.zeros(
+                (cfg.num_mamba_layers, B, cfg.mamba_conv_len,
+                 cfg.mamba_conv_dim), h.dtype,
+            ),
+        )
     if cfg.num_conv_layers and conv_state is None:
         conv_state = jnp.zeros(
             (cfg.num_conv_layers, B, K1, cfg.hidden_size), h.dtype
@@ -647,6 +963,13 @@ def _mixed_trunk(
         if mixer == "conv":
             with jax.named_scope("conv_mixer"):
                 y, out["conv"] = conv_mixer(cfg, lp, x, conv_state[m_idx])
+        elif mixer == "mamba":
+            with jax.named_scope("mamba_mixer"):
+                y, ssm = mamba_mixer(
+                    cfg, lp, x, valid_len=valid_len, past=state_past,
+                    layer=m_idx, pending=ssm_pending,
+                )
+                out.update(ssm)
         else:
             with jax.named_scope("attn_mixer"):
                 y, (out["k"], out["v"]) = attention_mixer(
@@ -664,7 +987,7 @@ def _mixed_trunk(
                     win_len=win_len,
                     pfx_groups=pfx_groups, kernel_mesh=kernel_mesh,
                 )
-        h = h + y
+        h = h + (y if r == 1.0 else y * jnp.asarray(r, y.dtype))
         experts = {
             k: v for k, v in stacks[ffn].items() if k.startswith("we_")
         }
@@ -684,9 +1007,11 @@ def _mixed_trunk(
         else:
             with jax.named_scope("dense_ffn"):
                 y = _mlp(cfg, fp, x)
-        return h + y, out
+        return h + (y if r == 1.0 else y * jnp.asarray(r, y.dtype)), out
 
-    outs: Dict[str, list] = {"k": [], "v": [], "conv": [], "route": []}
+    outs: Dict[str, list] = {
+        k: [] for k in ("k", "v", "conv", "route") + _SSM_KEYS
+    }
     for first, period, repeats in layer_groups(cfg):
         span = range(first, first + period)
         per_mixer = {m: [mixers[l] for l in span].count(m) for m in _MIXER_STACK}
@@ -720,7 +1045,8 @@ def _mixed_trunk(
         k: (jnp.concatenate(v) if len(v) > 1 else v[0]) if v else None
         for k, v in outs.items()
     }
-    return h, cat["k"], cat["v"], cat["conv"], cat["route"]
+    ssm = {k[4:]: cat[k] for k in _SSM_KEYS if cat[k] is not None}
+    return h, cat["k"], cat["v"], cat["conv"], cat["route"], ssm or None
 
 
 def rope_thetas(cfg: ModelConfig) -> jax.Array:
@@ -742,6 +1068,8 @@ def embed_tokens(cfg: ModelConfig, params: Params, ids: jax.Array) -> jax.Array:
     h = params["embed"][ids]  # [B, T, H] gather
     if cfg.embed_scale:
         h = (h.astype(jnp.float32) * (cfg.hidden_size ** 0.5)).astype(h.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
     return h
 
 
@@ -791,6 +1119,8 @@ def head_apply(
             h, logit_positions[:, None, None], axis=1
         )
     logits = h_head @ lm_head.astype(h.dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     # SUTRO_LOGITS_BF16=1 keeps the [*, V] logits in the activation
     # dtype: sampling's full-vocab passes (ops/sampling.py) then read
     # half the HBM bytes. Default OFF — bf16 argmax can flip near-ties
@@ -848,6 +1178,11 @@ def forward(
     # [L_conv, B, K-1, H]: each conv layer's state before the chunk, for
     # a model that has such layers (None: every row starts a sequence)
     conv_state: Optional[jax.Array] = None,
+    # a mamba model's slot pool and each row's place in it (None: every
+    # row starts a sequence), and whether the chunk's accepted length
+    # is decided later (``mamba_mixer``; None: a chunk of one token)
+    state_past: Optional[StatePast] = None,
+    ssm_pending: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, Tuple[Any, jax.Array]]:
     """Run the trunk over a chunk.
 
@@ -872,7 +1207,7 @@ def forward(
             raise NotImplementedError(
                 f"{cfg.name}: ring attention with layers of several kinds"
             )
-        h, k_all, v_all, conv, route = _mixed_trunk(
+        h, k_all, v_all, conv, route, ssm = _mixed_trunk(
             cfg, params, h,
             positions=positions, valid_len=valid_len, conv_state=conv_state,
             k_pages=k_pages, v_pages=v_pages,
@@ -881,9 +1216,14 @@ def forward(
             window_past=window_past, use_pallas=use_pallas,
             ep_mesh=ep_mesh,
             pfx_groups=pfx_groups, kernel_mesh=kernel_mesh,
+            state_past=state_past,
+            ssm_pending=(
+                ids.shape[1] == 1 if ssm_pending is None else ssm_pending
+            ),
         )
         out, h = head_apply(cfg, params, h, valid_len, logit_positions)
-        return out, h, (MixedChunk(k=k_all, conv=conv, route=route), v_all)
+        chunk = MixedChunk(k=k_all, conv=conv, route=route, ssm=ssm)
+        return out, h, (chunk, v_all)
 
     windows = jnp.asarray(cfg.window_array(), jnp.int32)  # [L]
     thetas = rope_thetas(cfg)

@@ -62,6 +62,13 @@ SESSION_CAP = 512
 SESSION_IDLE_CHECKPOINT_S = 30.0
 
 
+#: request ids are unique in the PROCESS, not in a gateway: a trace is
+#: named after its request (``tr-<rid>``) in the process-wide trace
+#: store, and two gateways in one process (a fleet's replicas under
+#: test) would otherwise mint the same name for different requests
+_REQUEST_IDS = itertools.count(1)
+
+
 @dataclasses.dataclass
 class _ChatSession:
     """Server-side transcript of one sticky conversation: the exact
@@ -166,7 +173,7 @@ class InteractiveGateway:
         of minting ``tr-<rid>`` — the cross-process propagation that
         lets the router stitch its spans with ours."""
         t_submit = time.monotonic()
-        rid = f"ivr-{next(self._counter)}"
+        rid = f"ivr-{next(_REQUEST_IDS)}"
         if faults.ACTIVE is not None:
             try:
                 faults.inject("serving.admit", job=rid)

@@ -356,8 +356,9 @@ KV_PAGES_NEEDED_TOTAL = REGISTRY.counter(
 )
 STATE_COMMITS_TOTAL = REGISTRY.counter(
     "sutro_state_commits_total",
-    "Dispatches that committed per-sequence conv state beside K/V, by "
-    "the path that committed it",
+    "Dispatches that committed per-sequence state (conv columns a "
+    "page, or a mamba model's slot) beside K/V, by the path that "
+    "committed it",
     labels=("path",),  # prefill | chunk | window | verify | resume
     unit="dispatches",
     max_series=8,
@@ -365,10 +366,27 @@ STATE_COMMITS_TOTAL = REGISTRY.counter(
 STATE_FALLBACK_PREFILL_TOKENS_TOTAL = REGISTRY.counter(
     "sutro_state_fallback_prefill_tokens_total",
     "Prompt tokens prefilled again because a path could not restore the "
-    "conv state at the position it resumed from",
-    labels=("reason",),  # hibernated_tail_page | tier_payload_without_state
+    "per-sequence state at the position it resumed from",
+    # hibernated_tail_page | tier_payload_without_state (state a page);
+    # prefix_without_state_snapshot | hibernate_without_slot_state
+    # (state a slot: no page holds it)
+    labels=("reason",),
     unit="tokens",
     max_series=8,
+)
+STATE_SLOTS = REGISTRY.gauge(
+    "sutro_state_slots",
+    "Slots of the mamba state pool (one a live sequence; the garbage "
+    "slot not counted): in use, and in all",
+    labels=("state",),  # in_use | total
+    unit="slots",
+    max_series=4,
+)
+STATE_SLOT_WAITS_TOTAL = REGISTRY.counter(
+    "sutro_state_slot_waits_total",
+    "Admissions that waited for a free state slot with a batch row "
+    "and pages free",
+    unit="admissions",
 )
 CONSTRAINT_FACTORY_TOTAL = REGISTRY.counter(
     "sutro_constraint_factory_total",
