@@ -502,6 +502,7 @@ def main(argv=None) -> int:
         # counted BEFORE the kernel check below traces the kernels
         # itself: these are the paths the jobs above built
         kernels = lowering.snapshot()
+        xla_decode = lowering.xla_decode_count()
         if not rehearsal:
             check(runner.use_pallas is True, runner.pallas_reason)
             for name, paths in kernels.items():
@@ -548,6 +549,7 @@ def main(argv=None) -> int:
                  "generate_sdk": gen_sdk},
         "chat": chat,
         "kernel_paths": kernels,
+        "paged_decode_xla": xla_decode,
         "kernel_rel_err_vs_reference": kernel_errors,
         "compile_seconds": dict(
             sorted(compile_s.items(), key=lambda kv: -kv[1])

@@ -50,11 +50,12 @@ scheduler ... paged-KV decode attention"). Layout:
 
 ``write_kv`` lands a chunk's K/V into pages (Pallas in-place RMW kernel
 on TPU, XLA scatter fallback elsewhere); ``gather_kv_layer`` produces one
-layer's contiguous ``[B, CTX, KVH, Dh]`` view for the non-Pallas
-attention fallback by ONE gather on ``[layer, page_table]`` of the
-stacked pool. No reader is ever handed a per-layer ``[NP, PS, KD]``
-slice: the stack is a constant of the layer scan and the layer is an
-index (models/transformer.py). Both are pure functions over pytrees,
+layer's contiguous ``[B, CTX, KVH, Dh]`` view for a chunk's attention
+over a paged past by ONE gather on ``[layer, page_table]`` of the
+stacked pool (``gather_pages``; a decode step keeps what that returns
+fused: ops/attention.py). No reader is ever handed a per-layer
+``[NP, PS, KD]`` slice: the stack is a constant of the layer scan and
+the layer is an index (models/transformer.py). Both are pure functions over pytrees,
 jitted as part of the runner's step functions.
 """
 
@@ -643,29 +644,24 @@ def write_kv(
     )
 
 
-def gather_kv_layer(
+def gather_pages(
     k_pages: jax.Array,  # [L, NP, PS, KVH*Dh] — the stacked pool
     v_pages: jax.Array,
     layer: jax.Array,  # scalar int32 — the layer to read
     page_table: jax.Array,  # [B, MP] int32
-    kv_heads: int,
     k_scale: "jax.Array | None" = None,  # [L, NP, PS] (int8 KV mode)
     v_scale: "jax.Array | None" = None,
     out_dtype=None,  # dequant target (compute dtype); None => float32
 ) -> Tuple[jax.Array, jax.Array]:
-    """Per-layer page gather: [B, MP] table -> ([B, CTX, KVH, Dh]) x2,
-    CTX = MP * PS, as ONE gather on ``[layer, page_table]`` of the
-    stack (never a slice of the layer's pool followed by a gather: the
-    slice would be a copy of it). Used inside the layer scan so only one
-    layer's context view is ever live (the XLA fallback when the Pallas
-    paged kernel does not run — the kernel reads pages in place and
-    skips this copy).
+    """Every row's pages of one layer, ``[B * MP, PS, KD]`` x2 in the
+    pool's own fused layout, as ONE gather on ``[layer, page_table]`` of
+    the stack (never a slice of the layer's pool followed by a gather:
+    the slice would be a copy of it).
     With int8 KV scales the gathered pages are dequantized here, INTO
     the caller's compute dtype — a float32 view would quadruple the
-    gathered context's bytes and promote the whole fallback attention
-    to f32, doubling the HBM traffic the int8 cache exists to halve."""
+    gathered context's bytes and promote the whole XLA attention to
+    f32, doubling the HBM traffic the int8 cache exists to halve."""
     L, NP, PS, KD = k_pages.shape
-    B, MP = page_table.shape
     # rows of the stack seen flat, [L * NP, PS, KD] (a bitcast): ONE
     # index on the major axis. Indexed as [layer, pages] the TPU
     # compiler re-lays the whole pool out for the gather (layer axis
@@ -680,6 +676,31 @@ def gather_kv_layer(
         vs = v_scale.reshape(L * NP, PS)[pages]
         k = (k.astype(jnp.float32) * ks[..., None]).astype(dt)
         v = (v.astype(jnp.float32) * vs[..., None]).astype(dt)
+    return k, v
+
+
+def gather_kv_layer(
+    k_pages: jax.Array,  # [L, NP, PS, KVH*Dh] — the stacked pool
+    v_pages: jax.Array,
+    layer: jax.Array,  # scalar int32 — the layer to read
+    page_table: jax.Array,  # [B, MP] int32
+    kv_heads: int,
+    k_scale: "jax.Array | None" = None,  # [L, NP, PS] (int8 KV mode)
+    v_scale: "jax.Array | None" = None,
+    out_dtype=None,  # dequant target (compute dtype); None => float32
+) -> Tuple[jax.Array, jax.Array]:
+    """Per-layer page gather: [B, MP] table -> ([B, CTX, KVH, Dh]) x2,
+    CTX = MP * PS (``gather_pages``, head-split). Used inside the layer
+    scan so only one layer's context view is ever live. This view
+    serves a chunk over a paged past (T > 1: chunked prefill, verify
+    forwards); one decode step reads its pages in place (the Pallas
+    paged kernel) or keeps the gathered pages fused
+    (``ops/attention.paged_decode_xla``)."""
+    PS, KD = k_pages.shape[2:]
+    B, MP = page_table.shape
+    k, v = gather_pages(
+        k_pages, v_pages, layer, page_table, k_scale, v_scale, out_dtype
+    )
     return (
         k.reshape(B, MP * PS, kv_heads, KD // kv_heads),
         v.reshape(B, MP * PS, kv_heads, KD // kv_heads),

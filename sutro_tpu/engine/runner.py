@@ -111,6 +111,7 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
     builds the native helpers on demand, as the engine would)."""
     import jaxlib
 
+    from ..ops import lowering
     from ..parallel.mesh import auto_mesh
     from .config import enable_compile_cache, load_engine_config
     from .constrain import cpp as native_fsm
@@ -136,6 +137,10 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         "mesh_devices": dp * pp * sp * ep * tp,
         "use_pallas": use_pallas,
         "pallas_reason": why,
+        # what this process has traced so far (ops/lowering.py): each
+        # kernel by path, and the XLA decode attention beside them
+        "kernel_paths": lowering.snapshot(),
+        "paged_decode_xla": lowering.xla_decode_count(),
         "compile_cache_dir": enable_compile_cache(),
         "native_runtime": native_runtime.is_available(),
         "native_fsm": native_fsm.is_available(),
@@ -182,9 +187,17 @@ class ModelRunner:
         # before any weight is built: a combination that cannot run
         # raises here
         self.use_pallas, self.pallas_reason = resolve_pallas(ecfg, mesh)
-        #: mesh the Pallas calls are shard_mapped over ("model" axis);
-        #: None on one device, where they are called bare
-        self.kernel_mesh = mesh if self.use_pallas else None
+        #: mesh the per-shard attention calls are shard_mapped over
+        #: ("model" axis): the Pallas kernels, and the XLA decode path
+        #: (ops/attention.paged_decode_xla), whose products over the
+        #: fused KV axis GSPMD would answer with an all-gather of the
+        #: context. None on one device, where they are called bare, and
+        #: on a mesh that shards more than "model", which GSPMD
+        #: partitions whole
+        model_only = mesh is not None and all(
+            n > 1 if a == "model" else n == 1 for a, n in mesh.shape.items()
+        )
+        self.kernel_mesh = mesh if self.use_pallas or model_only else None
         if (
             mesh is not None
             and getattr(ecfg, "kv_quantize", None)

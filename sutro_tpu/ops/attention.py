@@ -3,13 +3,16 @@
 ``chunk_attention`` is the single attention entry point for both prefill
 (T=chunk, no past) and decode (T=1, past gathered from the paged KV cache).
 The reference has no kernels at all (SURVEY §2.3); this is the TPU-native
-hot path. Two implementations sit behind one signature:
+hot path. Three implementations sit behind one signature:
 
 - a pure-``jnp`` path (XLA fuses it well; used on CPU tests and as the
-  reference the kernels are tested against), and
+  reference the kernels are tested against),
+- ``paged_decode_xla``: one decode step (T=1) over a paged past in plain
+  XLA, on the gathered pages in the layout the pool gave them, for every
+  call the paged kernel does not take, and
 - Pallas flash/paged kernels (ops/pallas_flash.py, ops/pallas_paged.py),
   dispatched with ``use_pallas=True`` on TPU. Their shape gates send
-  unsupported calls to the jnp path; every such call is counted
+  unsupported calls to the XLA paths; every such call is counted
   (ops/lowering.py) so a chip run can tell which path it built.
 
 Semantics handled here, uniformly: GQA head grouping, causal masking within
@@ -95,6 +98,129 @@ def _prefix_carry(
     return dict(pfx_cnt=pfx_cnt, m0=m0, l0=l0, acc0=acc0)
 
 
+@jax.named_scope("paged_decode_xla")
+def paged_decode_xla(
+    q: jax.Array,          # [B, NH, Dh] — current-step queries
+    k_pages: jax.Array,    # [L, NP, PS, KVH*Dh] — the stacked FUSED pool
+    v_pages: jax.Array,
+    layer: jax.Array,      # scalar int32 — the layer this call reads
+    page_table: jax.Array, # [B, MP] int32
+    past_len: jax.Array,   # [B] int32 — tokens already in the cache
+    k_cur: jax.Array,      # [B, KVH, Dh] — current token K (post-RoPE)
+    v_cur: jax.Array,
+    window: Optional[jax.Array] = None,  # scalar int32; 0 => full attention
+    sink: Optional[jax.Array] = None,    # [NH] logits or None
+    win_k: Optional[jax.Array] = None,   # [B, W, KVH*Dh] fused-window K
+    win_v: Optional[jax.Array] = None,
+    win_len: Optional[jax.Array] = None,  # scalar int32 — valid slots
+    k_scale: Optional[jax.Array] = None,  # [L, NP, PS] int8-KV scales
+    v_scale: Optional[jax.Array] = None,
+) -> jax.Array:
+    """One decode step's attention in plain XLA, ``[B, NH, Dh]``: what
+    ``paged_decode_attention`` computes, for the calls the kernel does
+    not take (heads that are no multiple of 128 lanes, ``use_pallas``
+    off, a mesh that shards more than ``model``, the CPU).
+
+    The gathered pages are used WHERE THEY LIE, ``[B, MP*PS, KD]`` with
+    the fused ``KVH*Dh`` axis on the lanes. A head-split view ``[B, S,
+    KVH, Dh]`` of them is a relayout (a minor axis of 64 is half a lane
+    tile), a concatenation with the window's buffer and the current
+    token copies the context for nine more positions, and a float32
+    cast is a third copy: ~20 ms of a 55 ms step at granite's shapes
+    (PERF.md §6, PR 33). Instead the queries are spread block-diagonally
+    over the fused axis (row n carries q[n] in lane block n // G, zeros
+    elsewhere: the form the Pallas kernel uses inside a row), so scores
+    are ``[NH, KD] x [KD, S]`` and values ``[NH, S] x [S, KD]`` a row,
+    KVH times the needed FLOPs and no relayout; the diagonal blocks of
+    the small ``[B, NH, KD]`` result are picked out at the end. Past
+    pages, the window's buffer and the current token are three segments
+    with their own scores and masks under ONE softmax (shared maximum
+    and sum), never one concatenated context. Operands reach the MXU in
+    the dtype they have, accumulation is float32, the probabilities are
+    rounded to the values' dtype as the MXU's default precision rounds
+    them anyway; masks, softmax and sink are float32."""
+    from ..engine.kvcache import gather_pages
+
+    lowering.record_xla_decode()
+    B, NH, Dh = q.shape
+    PS, KD = k_pages.shape[2:]
+    KVH = KD // Dh
+    G = NH // KVH
+    S = page_table.shape[1] * PS
+    scale = Dh ** -0.5
+    f32 = jnp.float32
+
+    # ONE gather a pool (int8 K/V dequantized into the compute dtype);
+    # [B*MP, PS, KD] -> [B, S, KD] merges major axes only
+    kp, vp = gather_pages(
+        k_pages, v_pages, layer, page_table, k_scale, v_scale, q.dtype
+    )
+    kp = kp.reshape(B, S, KD)
+    vp = vp.reshape(B, S, KD)
+
+    # block-diagonal queries [B, NH, KD]: row n lives in lane block n // G
+    block_of_row = jnp.arange(NH, dtype=jnp.int32)[:, None] // G  # [NH, 1]
+    q_bd = jnp.where(
+        block_of_row == jnp.arange(KD, dtype=jnp.int32)[None, :] // Dh,
+        jnp.tile(q, (1, 1, KVH)), jnp.zeros((), q.dtype),
+    )
+
+    def scores(keys, allowed):   # [B, X, KD], [B|1, X] -> [B, NH, X] f32
+        s = jnp.einsum(
+            "bnc,bxc->bnx", q_bd, keys, preferred_element_type=f32
+        ) * scale
+        return jnp.where(allowed[:, None, :], s, NEG_INF)
+
+    def values(p, vals):         # [B, NH, X] f32, [B, X, KD] -> [B, NH, KD]
+        return jnp.einsum(
+            "bnx,bxc->bnc", p.astype(vals.dtype), vals,
+            preferred_element_type=f32,
+        )
+
+    wl = jnp.asarray(0 if win_len is None else win_len, jnp.int32)
+    win = jnp.asarray(0 if window is None else window, jnp.int32)
+    span = jnp.where(win > 0, win, jnp.iinfo(jnp.int32).max)
+    q_pos = past_len + wl                                   # [B]
+    t = jnp.arange(S, dtype=jnp.int32)[None]
+    segs = [(
+        scores(kp, (t < past_len[:, None]) & (q_pos[:, None] - t < span)),
+        vp,
+    )]
+    if win_k is not None and win_k.shape[1] > 0:
+        # window tokens sit at past_len + slot, valid while slot < win_len
+        slot = jnp.arange(win_k.shape[1], dtype=jnp.int32)[None]
+        segs.append(
+            (scores(win_k, (slot < wl) & (wl - slot < span)), win_v)
+        )
+    # the current token: always attended, a product of [B, NH, Dh] alone
+    s_cur = jnp.einsum(
+        "bkgd,bkd->bkg", q.reshape(B, KVH, G, Dh), k_cur,
+        preferred_element_type=f32,
+    ).reshape(B, NH) * scale
+
+    m = s_cur
+    for s, _ in segs:
+        m = jnp.maximum(m, jnp.max(s, axis=-1))
+    if sink is not None:
+        sink = sink.astype(f32)[None]
+        m = jnp.maximum(m, sink)
+    p_cur = jnp.exp(s_cur - m)
+    denom = p_cur if sink is None else p_cur + jnp.exp(sink - m)
+    acc = None                                              # [B, NH, KD] f32
+    for s, vals in segs:
+        p = jnp.exp(s - m[..., None])
+        denom = denom + jnp.sum(p, axis=-1)
+        acc = values(p, vals) if acc is None else acc + values(p, vals)
+    # row n's output is the lane block n // G of its accumulator
+    own = block_of_row == jnp.arange(KVH, dtype=jnp.int32)[None, :]
+    out = jnp.sum(
+        jnp.where(own[None, :, :, None], acc.reshape(B, NH, KVH, Dh), 0.0),
+        axis=2,
+    )
+    out = out + p_cur[..., None] * jnp.repeat(v_cur.astype(f32), G, axis=1)
+    return (out / denom[..., None]).astype(q.dtype)
+
+
 def chunk_attention(
     q: jax.Array,                       # [B, T, NH, Dh]
     k: jax.Array,                       # [B, T, KVH, Dh] (chunk, post-RoPE)
@@ -109,7 +235,7 @@ def chunk_attention(
     # table; mutually exclusive with past_k/past_v. Pools carry the
     # FUSED [L, NP, PS, KVH*Dh] layout (engine/kvcache.py) and are never
     # sliced per layer: the Pallas paged kernel DMAs pool[layer, page]
-    # in place; the fallback gathers [layer, page_table] once.
+    # in place; the XLA paths gather [layer, page_table] once.
     past_k_pages: Optional[jax.Array] = None,  # [L, NP, PS, KVH*Dh]
     past_v_pages: Optional[jax.Array] = None,
     layer: Optional[jax.Array] = None,         # scalar int32
@@ -162,27 +288,33 @@ def chunk_attention(
             positions=positions, valid_len=valid_len,
             window=window, sink=sink,
         )
-    if past_k_pages is not None:
-        if use_pallas and T == 1:
+    if past_k_pages is not None and T == 1:
+        # one decode step: the Pallas kernel where its gate takes the
+        # shape, else the same function in plain XLA; either way one
+        # call a shard of the mesh's "model" axis (XLA cannot partition
+        # a Mosaic call, and would answer the XLA form's block-diagonal
+        # products with an all-gather of the context)
+        win = (
+            jnp.asarray(0, jnp.int32) if window is None
+            else jnp.asarray(window, jnp.int32)
+        )
+        ops = dict(
+            q=q[:, 0], k_pages=past_k_pages, v_pages=past_v_pages,
+            layer=layer,
+            page_table=page_table, past_len=past_len,
+            k_cur=k[:, 0], v_cur=v[:, 0], window=win,
+        )
+        optional = dict(
+            sink=sink, win_k=win_k, win_v=win_v, win_len=win_len,
+            k_scale=past_k_scale, v_scale=past_v_scale,
+        )
+        decode = paged_decode_xla
+        if use_pallas:
             from .pallas_paged import paged_decode_attention, paged_decode_supported
 
             if paged_decode_supported(q[:, 0], past_k_pages, page_table):
-                win = (
-                    jnp.asarray(0, jnp.int32) if window is None
-                    else jnp.asarray(window, jnp.int32)
-                )
-                ops = dict(
-                    q=q[:, 0], k_pages=past_k_pages, v_pages=past_v_pages,
-                    layer=layer,
-                    page_table=page_table, past_len=past_len,
-                    k_cur=k[:, 0], v_cur=v[:, 0], window=win,
-                )
-                optional = dict(
-                    sink=sink, win_k=win_k, win_v=win_v, win_len=win_len,
-                    k_scale=past_k_scale, v_scale=past_v_scale,
-                )
-                split = bool(pfx_groups) and kernel_mesh is None
-                if split:
+                decode = paged_decode_attention
+                if pfx_groups and kernel_mesh is None:
                     optional.update(
                         _prefix_carry(
                             q[:, 0], past_k_pages, past_v_pages, layer,
@@ -193,22 +325,20 @@ def chunk_attention(
                             win,
                         )
                     )
-                ops.update(
-                    {k_: v_ for k_, v_ in optional.items() if v_ is not None}
-                )
-                out = lowering.shard_over_model(
-                    kernel_mesh,
-                    paged_decode_attention,
-                    ops, _PAGED_SPECS, P(None, "model", None),
-                )
-                return out[:, None]
+            else:
+                lowering.record_reference("paged_decode")
+        ops.update({k_: v_ for k_, v_ in optional.items() if v_ is not None})
+        out = lowering.shard_over_model(
+            kernel_mesh, decode, ops, _PAGED_SPECS, P(None, "model", None),
+        )
+        return out[:, None]
+    if past_k_pages is not None:
+        if use_pallas:
+            # a chunk over a paged past (chunked prefill, verify
+            # forwards) gathers by design
+            lowering.record_reference("paged_decode")
         from ..engine.kvcache import gather_kv_layer
 
-        if use_pallas:
-            # T>1 over a paged past (chunked prefill, verify forwards)
-            # gathers by design; T==1 lands here only when the shape
-            # gate refused the kernel
-            lowering.record_reference("paged_decode")
         past_k, past_v = gather_kv_layer(
             past_k_pages, past_v_pages, layer, page_table, k.shape[2],
             k_scale=past_k_scale, v_scale=past_v_scale,

@@ -34,6 +34,7 @@ _lock = threading.Lock()
 _counts: Dict[str, Dict[str, int]] = {
     k: dict.fromkeys(PATHS, 0) for k in KERNELS
 }
+_xla_decode = 0
 
 
 def record_kernel(kernel: str, *, interpret: bool) -> None:
@@ -46,6 +47,22 @@ def record_reference(kernel: str) -> None:
     """Called where a ``use_pallas=True`` call takes the jnp/XLA path."""
     with _lock:
         _counts[kernel]["reference"] += 1
+
+
+def record_xla_decode() -> None:
+    """Called from ``ops/attention.paged_decode_xla``'s traced body."""
+    global _xla_decode
+    with _lock:
+        _xla_decode += 1
+
+
+def xla_decode_count() -> int:
+    """Traces of the XLA paged-decode path (``paged_decode_xla``): the
+    decode attention of every call the Pallas kernel does not take. A
+    count of its own, not a path of ``KERNELS``: it is no kernel, and
+    it runs by design wherever ``use_pallas`` is off."""
+    with _lock:
+        return _xla_decode
 
 
 def snapshot() -> Dict[str, Dict[str, int]]:
