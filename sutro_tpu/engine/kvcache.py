@@ -48,6 +48,30 @@ scheduler ... paged-KV decode attention"). Layout:
   reduces every slot against its row's C over N with no cross-lane
   work, reading the pool where it lies; no forward writes it.
 
+- ``wk_pages`` / ``wv_pages``: ``[L_win, NP_w, PS, KVH*Dh]``, for a
+  model whose attention layers are of two kinds (``layer_types`` with
+  "swa" layers: a sliding window of ``W`` positions): K/V a POOL A
+  KIND. A window layer can see a row's last W positions and nothing
+  older, so its pool holds those and no more: about W / PS pages a
+  sequence whatever its length, where the full layers' pool holds
+  the whole context. There is ONE space of page ids (the full pool's,
+  what the allocators and every page table speak) and a device map
+  ``window_page`` ``[NP]`` int32 from a page id to the window pool's
+  page that holds the same positions of the window layers (0: none;
+  page 0 of the window pool is its garbage page). Every program looks
+  its rows' window pages up itself (``window_table``), as it finds a
+  state slot. The host (``WindowPages``, owned by the runner) binds a
+  page id to a window page before the dispatch that writes it and
+  takes the window page back once its last position is older than
+  the row's committed length less W: from then on no query reads it
+  (every reader starts at the page of position ``past_len - W + 1``:
+  ops/attention.py ``live_pages``, the paged kernel's ``first_page``).
+  A token written through an unbound page id lands on the garbage
+  page: a prefill binds only what the window still holds at its end.
+  With ``NP_w == NP`` the map is the identity and nothing is ever
+  bound or released (the trivial setting: a runner given its pool's
+  size, a mesh).
+
 ``write_kv`` lands a chunk's K/V into pages (Pallas in-place RMW kernel
 on TPU, XLA scatter fallback elsewhere); ``gather_kv_layer`` produces one
 layer's contiguous ``[B, CTX, KVH, Dh]`` view for a chunk's attention
@@ -91,6 +115,11 @@ class KVCache:
     ssm: "jax.Array | None" = None         # [L_m, NS, N, I]
     ssm_conv: "jax.Array | None" = None    # [NS, L_m * (K-1) * Cd]
     state_slot: "jax.Array | None" = None  # [NP] int32
+    # window attention layers' K/V, a pool of their own, and the page id
+    # -> window page map (module docstring)
+    wk_pages: "jax.Array | None" = None    # [L_win, NP_w, PS, KVH*Dh]
+    wv_pages: "jax.Array | None" = None
+    window_page: "jax.Array | None" = None  # [NP] int32
 
     @property
     def page_size(self) -> int:
@@ -99,6 +128,12 @@ class KVCache:
     @property
     def num_pages(self) -> int:
         return self.k_pages.shape[1]
+
+    @property
+    def num_window_pages(self) -> int:
+        """Pages of the window pool, its garbage page included (0 for a
+        model with one pool)."""
+        return 0 if self.wk_pages is None else self.wk_pages.shape[1]
 
     @property
     def quantized(self) -> bool:
@@ -115,8 +150,12 @@ def alloc_cache(
     mcfg: ModelConfig, ecfg: EngineConfig, num_pages: int,
     dtype: jnp.dtype = jnp.bfloat16,
     sharding: "jax.sharding.NamedSharding | None" = None,
+    window_pages: "int | None" = None,
 ) -> KVCache:
-    """Zeroed page pools; for a model with mamba layers also the state
+    """Zeroed page pools; for a model with window attention layers a
+    pool a kind, the window layers' of ``window_pages`` pages (None: as
+    many as the full pool, under the identity map: nothing to bind or
+    release); for a model with mamba layers also the state
     pools: beside the garbage slot a slot a row of the decode batch, and
     never more than there are pages, since a sequence holds at least
     one (``default_state_slots``). With ``sharding`` (parallel/sharding.py
@@ -163,6 +202,23 @@ def alloc_cache(
                 act, device=rep,
             ),
             state_slot=jnp.zeros((num_pages,), jnp.int32, device=rep),
+        )
+    if mcfg.num_window_layers:
+        if getattr(ecfg, "kv_quantize", None):
+            raise NotImplementedError(
+                f"{mcfg.name} keeps K/V a pool a kind: the window pool "
+                "has no int8 scale pools (kv_quantize)"
+            )
+        same = window_pages is None or window_pages == num_pages
+        wshape = (mcfg.num_window_layers,
+                  num_pages if same else window_pages) + shape[2:]
+        state.update(
+            wk_pages=jnp.zeros(wshape, dtype, device=sharding),
+            wv_pages=jnp.zeros(wshape, dtype, device=sharding),
+            window_page=(
+                jnp.arange(num_pages, dtype=jnp.int32, device=rep) if same
+                else jnp.zeros((num_pages,), jnp.int32, device=rep)
+            ),
         )
     if getattr(ecfg, "kv_quantize", None) == "int8":
         return KVCache(
@@ -245,6 +301,103 @@ class StateSlots:
     def reset(self) -> None:
         self._free = list(range(self.total, 0, -1))
         self._of_page.clear()
+
+
+def window_table(cache: KVCache, page_table: jax.Array) -> "jax.Array | None":
+    """Each row's pages IN THE WINDOW POOL, ``[B, MP]``: the map applied
+    to the row's table (0, the window pool's garbage page, for a page id
+    the host has bound to none). None for a model with one pool."""
+    if cache.window_page is None:
+        return None
+    return cache.window_page[page_table]
+
+
+def window_span_pages(window: int, in_flight: int, page_size: int) -> int:
+    """The most window pages one sequence holds at once: its last
+    ``window`` positions and the ``in_flight`` tokens dispatched past
+    what the host has seen committed, however they lie on pages."""
+    return (window + in_flight + page_size - 2) // page_size + 1
+
+
+def first_live_page(past_len, window: int, page_size: int):
+    """The slot of a row's table that holds the oldest position a query
+    at ``past_len`` (numpy or jax, any shape) sees through ``window``:
+    what every reader of a window pool starts at, and what the host
+    releases behind."""
+    return (past_len - window + 1).clip(0) // page_size
+
+
+class WindowPages:
+    """Host-side allocator of the window pool's pages, beside the page
+    free list (module docstring): which window page a page id is bound
+    to, which are free, and how many the admitted rows may still ask
+    for. ``budget`` is admission's: a row reserves the most it will
+    hold at once (``window_span_pages``), keyed by its first own page,
+    so that a bind between two dispatches cannot find the pool empty.
+    ``delta`` hands the device what changed since it was last asked."""
+
+    def __init__(self, window_pages: int, num_pages: int):
+        self.total = window_pages - 1          # page 0 is the garbage page
+        self._free: List[int] = list(range(self.total, 0, -1))
+        self.of_page = np.zeros((num_pages,), np.int32)
+        self._reserved: dict = {}
+        self._dirty: set = set()
+        self.released_total = 0
+
+    @property
+    def free_count(self) -> int:
+        return len(self._free)
+
+    @property
+    def in_use(self) -> int:
+        return self.total - len(self._free)
+
+    @property
+    def budget_free(self) -> int:
+        return self.total - sum(self._reserved.values())
+
+    def set_budget(self, first_page: int, n: int) -> None:
+        self._reserved[int(first_page)] = int(n)
+
+    def bind(self, pages) -> None:
+        """A window page for each page id of ``pages`` that has none
+        (0 excepted). Raises MemoryError when the pool is out."""
+        ids = np.unique(np.asarray(pages, np.int64))
+        for p in ids[(ids > 0) & (self.of_page[ids] == 0)].tolist():
+            if not self._free:
+                raise MemoryError("window KV pool out of pages")
+            self.of_page[p] = self._free.pop()
+            self._dirty.add(p)
+
+    def release(self, pages) -> int:
+        """Take back the window pages of ``pages`` (those that have
+        one); returns how many."""
+        ids = np.unique(np.asarray(pages, np.int64))
+        bound = ids[(ids > 0) & (self.of_page[ids] > 0)].tolist()
+        for p in bound:
+            self._free.append(int(self.of_page[p]))
+            self.of_page[p] = 0
+            self._dirty.add(p)
+        return len(bound)
+
+    def release_row(self, own_pages) -> None:
+        """A row's pages go back to the allocator: with them its window
+        pages and its reservation."""
+        if len(own_pages):
+            self._reserved.pop(int(own_pages[0]), None)
+            self.release(own_pages)
+
+    def delta(self) -> "Tuple[np.ndarray, np.ndarray] | None":
+        """``(page ids, window pages)`` the device has yet to learn."""
+        if not self._dirty:
+            return None
+        ids = np.fromiter(self._dirty, np.int32, len(self._dirty))
+        self._dirty.clear()
+        return ids, self.of_page[ids]
+
+    def reset(self) -> None:
+        self.release(np.nonzero(self.of_page)[0])
+        self._reserved.clear()
 
 
 def _quantize_tokens(x: jax.Array):
@@ -575,6 +728,31 @@ def write_kv(
                 use_pallas=use_pallas, kernel_mesh=kernel_mesh,
             )
         return dataclasses.replace(cache, conv=conv)
+    if cache.wk_pages is not None:
+        # a pool a kind: the chunk stacks the full layers' K/V, then the
+        # window layers' (transformer._mixed_trunk); each part goes to
+        # its own pool, the window layers' through the page id -> window
+        # page map (an unbound page id: the garbage page)
+        Lf = cache.k_pages.shape[0]
+        full = dataclasses.replace(
+            cache, wk_pages=None, wv_pages=None, window_page=None
+        )
+        win = dataclasses.replace(
+            full, k_pages=cache.wk_pages, v_pages=cache.wv_pages
+        )
+        full = write_kv(
+            full, k_chunk[:Lf], v_chunk[:Lf], page_table, start, valid_len,
+            use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+        )
+        win = write_kv(
+            win, k_chunk[Lf:], v_chunk[Lf:],
+            window_table(cache, page_table), start, valid_len,
+            use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+        )
+        return dataclasses.replace(
+            cache, k_pages=full.k_pages, v_pages=full.v_pages,
+            wk_pages=win.k_pages, wv_pages=win.v_pages,
+        )
     if k_chunk.ndim == 4:  # already fused (decode window buffers)
         L, B, T, KD = k_chunk.shape
     else:

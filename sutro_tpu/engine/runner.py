@@ -34,8 +34,9 @@ from ..models.transformer import MixedChunk
 from . import faults
 from .config import EngineConfig
 from .kvcache import (
-    KVCache, StateSlots, alloc_cache, default_state_slots, read_conv_state,
-    read_state, state_bytes_per_slot, write_kv,
+    KVCache, StateSlots, WindowPages, alloc_cache, default_state_slots,
+    first_live_page, read_conv_state, read_state, state_bytes_per_slot,
+    window_span_pages, window_table, write_kv,
 )
 from ..ops.sampling import NEG_INF, sample, cumulative_logprob
 
@@ -160,6 +161,7 @@ class ModelRunner:
         num_pages: Optional[int] = None,
         mesh: Optional[jax.sharding.Mesh] = None,
         shardings: Optional[Any] = None,
+        window_pages: Optional[int] = None,
     ):
         self.mcfg = mcfg
         self.ecfg = ecfg
@@ -312,10 +314,40 @@ class ModelRunner:
         # worst case (every slot at full context), bounded by what the
         # device's memory can hold beside the weights.
         worst_case = 1 + ecfg.decode_batch_size * ecfg.max_pages_per_seq
-        self.alloc_pages = (
-            num_pages if num_pages is not None
-            else self._pages_that_fit(worst_case, dtype)
+        # a model with window attention layers keeps K/V a pool a kind
+        # (kvcache.py): the window layers' pool holds a row's window and
+        # the tokens in flight, whatever its context. Sized with the
+        # full pool from what fits, or by ``window_pages``; a runner
+        # given ``num_pages`` alone, and any mesh, runs the mechanism at
+        # its trivial setting: a window pool as large as the full one
+        # under the identity map, nothing bound or released
+        # in flight: the fused windows dispatched past what the host
+        # has seen committed, or a verify chunk's inputs
+        in_flight = max(
+            (ecfg.decode_lookahead + 1) * ecfg.decode_multi_step,
+            ecfg.constrain_fastforward + 1,
         )
+        self.window_span = min(
+            ecfg.max_pages_per_seq,
+            window_span_pages(
+                mcfg.sliding_window, in_flight, ecfg.kv_page_size
+            ),
+        ) if mcfg.num_window_layers else 0
+        two_pools = (
+            mcfg.num_window_layers > 0 and mesh is None
+            and (num_pages is None or window_pages is not None)
+        )
+        if num_pages is not None:
+            self.alloc_pages = num_pages
+        else:
+            self.alloc_pages, fit_window = self._pages_that_fit(
+                worst_case,
+                1 + ecfg.decode_batch_size * self.window_span
+                if two_pools else 0,
+                dtype,
+            )
+            if two_pools and window_pages is None:
+                window_pages = fit_window
         # the decode kernel fetches a row's own pages and no other, so
         # the pool holds what the allocators hand out and nothing more
         self.num_pages = self.alloc_pages
@@ -325,6 +357,14 @@ class ModelRunner:
         self.cache = alloc_cache(
             mcfg, ecfg, self.num_pages, dtype=dtype,
             sharding=self._cache_sharding,
+            window_pages=window_pages if two_pools else None,
+        )
+        # the host's side of the window pool (kvcache.WindowPages); None
+        # for a model with one pool and at the trivial setting
+        self.window_pool = (
+            WindowPages(self.cache.num_window_pages, self.num_pages)
+            if two_pools and self.cache.num_window_pages != self.num_pages
+            else None
         )
         # the host's side of the mamba state pool (kvcache.StateSlots):
         # which slots are free. None for a model that keeps no such state
@@ -419,11 +459,120 @@ class ModelRunner:
             [t[0] for t, st in zip(tables, starts) if int(st) == 0]
         )
 
-    def _page_bytes_per_device(self, dtype) -> int:
+    # -- window pool pages (kvcache.WindowPages) ------------------------
+
+    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+    def _bind_window_jit(self, cache: KVCache, pages, wpages):
+        return dataclasses.replace(
+            cache, window_page=cache.window_page.at[pages].set(wpages)
+        )
+
+    def window_budget(self, total_tokens: int, chunked: bool) -> int:
+        """Window pages admission reserves for a row of ``total_tokens``
+        (prompt and new): the most it holds at once. A row whose
+        prefill runs in chunks over a paged past holds, at a chunk's
+        dispatch, the window before the chunk and the window at its
+        end."""
+        PS = self.ecfg.kv_page_size
+        return min(
+            -(-total_tokens // PS), self.window_span * (2 if chunked else 1)
+        )
+
+    def _bind_window(self, page_tables, starts, lens) -> None:
+        """Before a dispatch that writes ``lens[b]`` tokens from
+        position ``starts[b]`` through ``page_tables[b]``: bind a window
+        page to each page id that holds one of those tokens the window
+        at the chunk's end still sees (the others land on the garbage
+        page), and tell the device what changed since it was last told,
+        releases included, in ONE small dispatch."""
+        pool = self.window_pool
+        if pool is None:
+            return
+        PS, W = self.ecfg.kv_page_size, self.mcfg.sliding_window
+        tables = np.asarray(page_tables).reshape(-1, np.shape(page_tables)[-1])
+        st = np.asarray(starts, np.int64).reshape(-1, 1)
+        n = np.asarray(lens, np.int64).reshape(-1, 1)
+        j = np.arange(tables.shape[1])[None, :]
+        keep = (
+            (j >= np.maximum(st, st + n - W + 1) // PS)
+            & (j <= (st + n - 1) // PS) & (n > 0)
+        )
+        pool.bind(tables[keep])
+        self._flush_window()
+
+    def _flush_window(self) -> None:
+        """Tell the device the bindings that changed (a two-pool
+        runner's own: ``window_pool`` is there)."""
+        pool = self.window_pool
+        delta = pool.delta()
+        if delta is None:
+            return
+        ids, wpages = delta
+        m = next_bucket(len(ids), lo=64)
+        pad = np.zeros((2, m), np.int32)   # pads: page id 0 <- 0
+        pad[0, : len(ids)], pad[1, : len(ids)] = ids, wpages
+        self.cache = self._bind_window_jit(
+            self.cache, jnp.asarray(pad[0]), jnp.asarray(pad[1])
+        )
+        if telemetry.ENABLED:
+            telemetry.KV_PAGES.set(float(pool.in_use), "window", "used")
+            telemetry.KV_PAGES.set(float(pool.free_count), "window", "free")
+
+    def release_window_behind(self, page_tables, committed) -> int:
+        """Take back the window pages whose last position is older than
+        ``committed[b] - window``: no query at ``committed[b]`` or later
+        sees them, and every dispatch in flight was given a length of
+        at least that. The caller passes COMMITTED lengths (never a
+        projection over tokens in flight). The device learns with the
+        next bind; it never reads such a page meanwhile. Returns the
+        pages released."""
+        pool = self.window_pool
+        if pool is None:
+            return 0
+        PS, W = self.ecfg.kv_page_size, self.mcfg.sliding_window
+        tables = np.asarray(page_tables).reshape(-1, np.shape(page_tables)[-1])
+        first = first_live_page(
+            np.asarray(committed, np.int64).reshape(-1, 1), W, PS
+        )
+        behind = np.arange(tables.shape[1])[None, :] < first
+        ids = tables[behind]
+        n = pool.release(ids[pool.of_page[ids] > 0])
+        if n:
+            pool.released_total += n
+            if telemetry.ENABLED:
+                telemetry.KV_WINDOW_PAGES_RELEASED_TOTAL.inc(float(n))
+        return n
+
+    def release_window_row(self, own_pages) -> None:
+        """A row's pages go back to the page allocator."""
+        if self.window_pool is not None:
+            self.window_pool.release_row(own_pages)
+
+    def reset_window_pool(self) -> None:
+        """A new session's pages are all free."""
+        if self.window_pool is not None:
+            self.window_pool.reset()
+            self._flush_window()
+
+    def _window_pool_of(self, cache: KVCache, page_table):
+        """``transformer.forward``'s ``window_pool``; None for a model
+        with one pool."""
+        if cache.wk_pages is None:
+            return None
+        return (
+            cache.wk_pages, cache.wv_pages, window_table(cache, page_table)
+        )
+
+    def _page_bytes_per_device(self, dtype, window: bool = False) -> int:
         """One KV page (K and V, every ATTENTION layer, plus int8
         scales, plus the page's conv state) as it sits on ONE device
-        under the pool's sharding."""
-        L, PS = self.mcfg.num_attn_layers, self.ecfg.kv_page_size
+        under the pool's sharding; ``window``: a page of the window
+        layers' pool."""
+        PS = self.ecfg.kv_page_size
+        L = (
+            self.mcfg.num_window_layers if window
+            else self.mcfg.num_attn_layers
+        )
         shape = (L, 1, PS, self.mcfg.num_kv_heads * self.mcfg.head_dim)
         if self._cache_sharding is not None:
             shape = self._cache_sharding.shard_shape(shape)
@@ -437,9 +586,13 @@ class ModelRunner:
             return 2 * (int(np.prod(shape)) + L * PS * 4) + state
         return 2 * int(np.prod(shape)) * dtype.itemsize + state
 
-    def _pages_that_fit(self, want: int, dtype) -> int:
-        """``want`` pages, or as many as the device's memory limit holds
-        beside what is already resident (the weights) and the reserve.
+    def _pages_that_fit(self, want: int, want_window: int, dtype):
+        """``(pages, window pages)``: ``want`` pages (and ``want_window``
+        of the window layers' pool, 0 for a model with one pool or at
+        the trivial setting, where a page carries both kinds), or as
+        many as the device's memory limit holds
+        beside what is already resident (the weights) and the reserve,
+        divided between the kinds in the proportion asked for.
         The scheduler admits against free pages, so a pool smaller than
         the worst case is a supported state; a pool too small for ONE
         full-context row is not, and raises here with the budget —
@@ -455,7 +608,7 @@ class ModelRunner:
         stats = dev.memory_stats() or {}
         limit = int(stats.get("bytes_limit") or 0)
         if not limit:
-            return want
+            return want, want_window
         in_use = int(stats.get("bytes_in_use") or 0)
         if self.mcfg.num_mamba_layers:
             # the state pool comes first: a slot a row of the batch
@@ -464,7 +617,21 @@ class ModelRunner:
             )
         reserve = int(limit * HBM_RESERVE_FRACTION)
         page = self._page_bytes_per_device(dtype)
-        fit = (limit - in_use - reserve) // page - self._margin_pages
+        avail = limit - in_use - reserve
+        wpage = fit_window = 0
+        if self.mcfg.num_window_layers:
+            wpage = self._page_bytes_per_device(dtype, window=True)
+            if not want_window:
+                page, wpage = page + wpage, 0   # one id, both kinds
+        need = want * page + want_window * wpage
+        if want_window:
+            # short of memory both pools shrink together; the window
+            # pool never under one row's span
+            fit_window = want_window if need <= avail else max(
+                int(want_window * avail / need), 1 + self.window_span
+            )
+            avail -= fit_window * wpage
+        fit = avail // page - self._margin_pages
         floor = 1 + self.ecfg.max_pages_per_seq
         if fit < floor:
             from .roofline import param_bytes_of
@@ -479,7 +646,7 @@ class ModelRunner:
                 f"{self.ecfg.max_pages_per_seq}: {floor} pages x "
                 f"{page / 1e6:.1f} MB) needs {floor * page / gb:.2f} GB"
             )
-        return min(want, fit)
+        return min(want, fit), fit_window
 
     def device_info(self) -> dict:
         """Device + model facts: what the bottleneck doctor grades decode
@@ -532,6 +699,10 @@ class ModelRunner:
                 )
             )),
             "state_slots": int(self.cache.num_state_slots),
+            # K/V a pool a kind: the window layers and their pool's pages
+            # (0: one pool; equal to pool_pages: the trivial setting)
+            "window_layers": int(self.mcfg.num_window_layers),
+            "window_pool_pages": int(self.cache.num_window_pages),
             "kv_heads": int(self.mcfg.num_kv_heads),
             "head_dim": int(self.mcfg.head_dim),
             "kv_dtype_bytes": (
@@ -615,6 +786,13 @@ class ModelRunner:
         free/reuse the pages the moment this returns."""
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         c = self.cache
+        if c.wk_pages is not None:
+            # a page id's window page may be gone, and the tiers' payload
+            # has no place for a second pool: the caller prefills again
+            raise ValueError(
+                "pages of a model that keeps K/V a pool a kind do not "
+                "move to the tiers (no window pages in the payload)"
+            )
         out = {
             "k": np.asarray(c.k_pages[:, ids]),
             "v": np.asarray(c.v_pages[:, ids]),
@@ -662,6 +840,11 @@ class ModelRunner:
         the parity contract, tests/test_kv_tiers.py)."""
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         c = self.cache
+        if c.wk_pages is not None:
+            raise ValueError(
+                "pages cannot restore the window pages of a model that "
+                "keeps K/V a pool a kind"
+            )
         if c.ssm is not None:
             # a slot's state is in no page: the caller prefills again
             raise ValueError(
@@ -768,6 +951,7 @@ class ModelRunner:
             conv_state=self._state_at(cache, page_table, start),
             state_past=self._state_past(cache, page_table, start),
             ssm_pending=False,
+            window_pool=self._window_pool_of(cache, page_table),
         )
         cache = write_kv(
             cache, k, v, page_table, start, valid_len,
@@ -814,6 +998,7 @@ class ModelRunner:
                 ids = np.zeros((1, C), np.int32)
                 ids[0, : len(seg)] = seg
                 self._count_state_commit("chunk")
+                self._bind_window(page_table, [start + off], [len(seg)])
                 logits, self.cache, self._route_dev = self._prefill_chunk_jit(
                     self.params,
                     self.cache,
@@ -822,6 +1007,11 @@ class ModelRunner:
                     table_dev,
                     jnp.asarray([start + off], jnp.int32),
                 )
+                # the chunk is dispatched: what slid out behind its end
+                # goes back before the next chunk binds
+                self.release_window_behind(
+                    page_table, [start + off + len(seg)]
+                )
             return np.asarray(logits[0])
         T = next_bucket(max(n, 1), lo=16, hi=self.ecfg.max_context())
         if T % self.sp:  # ring prefill shards T over the seq axis
@@ -829,6 +1019,7 @@ class ModelRunner:
         ids = np.zeros((1, T), np.int32)
         ids[0, :n] = token_ids
         self._count_state_commit("prefill")
+        self._bind_window(page_table, [0], [n])
         logits, self.cache, self._route_dev = self._prefill_jit(
             self.params,
             self.cache,
@@ -870,6 +1061,7 @@ class ModelRunner:
             tables[i] = page_tables[i]
         self._bind_fresh(tables[:n], [0] * n)
         self._count_state_commit("prefill")
+        self._bind_window(tables[:n], [0] * n, lens[:n])
         logits, self.cache, self._route_dev = self._prefill_jit(
             self.params,
             self.cache,
@@ -907,6 +1099,7 @@ class ModelRunner:
             tables[i] = page_tables[i]
         self._bind_fresh(tables[:n], st[:n])
         self._count_state_commit("chunk")
+        self._bind_window(tables[:n], st[:n], lens[:n])
         logits, self.cache, self._route_dev = self._prefill_chunk_jit(
             self.params,
             self.cache,
@@ -915,6 +1108,7 @@ class ModelRunner:
             jnp.asarray(tables),
             jnp.asarray(st),
         )
+        self.release_window_behind(tables[:n], st[:n] + lens[:n])
         return np.asarray(logits[:n])
 
     # ------------------------------------------------------------------
@@ -971,6 +1165,7 @@ class ModelRunner:
             pfx_groups=pfx,
             conv_state=conv_state,
             state_past=state_past, ssm_pending=True,
+            window_pool=self._window_pool_of(cache, page_table),
         )
 
     def _chunk_for_table(self, page_table: np.ndarray) -> int:
@@ -996,22 +1191,36 @@ class ModelRunner:
         PS = self.ecfg.kv_page_size
         past = np.asarray(past_len, np.int64)
         table = np.asarray(page_table)
-        needed = past / PS
         head = jax.ShapeDtypeStruct((1, 1, self.mcfg.head_dim), jnp.float32)
-        if self.use_pallas and (
-            pallas_paged.paged_decode_supported(
-                head, self.cache.k_pages, table
-            )
+        kernel = self.use_pallas and pallas_paged.paged_decode_supported(
+            head, self.cache.k_pages, table
+        )
+        fetched = needed = 0.0
+        # a kind of attention layers at a time: (layers, window); a
+        # window layer NEEDS the pages of its window, and fetches from
+        # the page of its oldest visible position on
+        W = self.mcfg.sliding_window
+        for layers, win in (
+            (self.mcfg.num_attn_layers, 0), (self.mcfg.num_window_layers, W),
         ):
-            fetched = -(-past // PS)
-            if pfx and self.kernel_mesh is None:
-                shared = sum(np.asarray(n, np.int64) // PS for _, n in pfx)
-                fetched, needed = fetched - shared, needed - shared
-        else:
-            fetched = np.full(past.shape, table.shape[-1], np.int64)
-        times = float(steps * self.mcfg.num_attn_layers)
-        fetched = times * float(np.maximum(fetched, 0).sum())
-        needed = times * float(np.maximum(needed, 0).sum())
+            if not layers:
+                continue
+            first = first_live_page(past, win, PS) if win else 0
+            need = (np.minimum(past, win - 1) if win else past) / PS
+            if kernel:
+                got = -(-past // PS) - first
+                if pfx and self.kernel_mesh is None and not win:
+                    shared = sum(np.asarray(n, np.int64) // PS for _, n in pfx)
+                    got, need = got - shared, need - shared
+            elif win:
+                got = np.full(past.shape, min(
+                    table.shape[-1], window_span_pages(win, 0, PS)
+                ), np.int64)
+            else:
+                got = np.full(past.shape, table.shape[-1], np.int64)
+            times = float(steps * layers)
+            fetched += times * float(np.maximum(got, 0).sum())
+            needed += times * float(np.maximum(need, 0).sum())
         telemetry.KV_PAGES_FETCHED_TOTAL.inc(fetched)
         telemetry.KV_PAGES_NEEDED_TOTAL.inc(needed)
         f0, n0 = self._kv_pages or (0.0, 0.0)
@@ -1105,6 +1314,7 @@ class ModelRunner:
             )
         self._count_state_commit("window")
         self._count_kv_pages(past_len, page_table, 1, pfx)
+        self._bind_window(page_table, past_len, np.ones((B,), np.int32))
         tok, logp, self.cache, self._route_dev = self._decode_jit(
             self.params,
             self.cache,
@@ -1203,7 +1413,7 @@ class ModelRunner:
         scaffold token), so one adversarial row no longer degrades the
         whole batch to masked single-steps."""
         B = last.shape[0]
-        L = self.mcfg.num_attn_layers
+        L = self.mcfg.num_kv_layers   # full layers, then window layers
         KVH, Dh = self.mcfg.num_kv_heads, self.mcfg.head_dim
         KD = KVH * Dh
         # window buffers hold UNQUANTIZED step K/V (they are read by
@@ -1373,6 +1583,7 @@ class ModelRunner:
         # tokens; whoever fetches the tokens fetches them
         self._count_state_commit("window")
         self._count_kv_pages(past_len, page_table, steps, pfx)
+        self._bind_window(page_table, past_len, np.full((B,), steps))
         toks, logps, self.cache, self.window_route = self._decode_multi_jit(
             self.params,
             self.cache,
@@ -1410,6 +1621,7 @@ class ModelRunner:
             conv_state=self._state_at(cache, page_table, start),
             state_past=self._state_past(cache, page_table, start),
             ssm_pending=True,
+            window_pool=self._window_pool_of(cache, page_table),
         )
         # K/V of every input is written (rejected positions are dead
         # stores past the accepted length); conv state is ONE value a
@@ -1486,6 +1698,7 @@ class ModelRunner:
         ids = np.zeros((B, K + 1), np.int32)
         ids[:, 0] = last_tokens
         ids[:, 1:] = drafts
+        self._bind_window(page_table, past_len, np.asarray(draft_len) + 1)
         ct, cl, pt, pl, self.cache, pending = self._verify_cand_jit(
             self.params,
             self.cache,
@@ -1630,6 +1843,7 @@ class ModelRunner:
         """Write each row's accepted window prefix into the page pool."""
         wk, wv, past_len, page_table = handle
         self._count_state_commit("window")
+        self._bind_window(page_table, past_len, accepted)
         self.cache = self._commit_window_jit(
             self.cache, wk, wv,
             jnp.asarray(page_table, jnp.int32),

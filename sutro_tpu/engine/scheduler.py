@@ -428,6 +428,8 @@ class ContinuousBatcher:
     # the runner keeps its state a slot a sequence (set in __init__)
     _slot_state = False
     _tier_refused = False
+    # the runner's window pool (K/V a pool a kind), set in __init__
+    _window_pool = None
 
     def __init__(
         self,
@@ -491,10 +493,26 @@ class ContinuousBatcher:
         # (sutro_state_fallback_prefill_tokens_total). A new session's
         # pages are all free, so every slot is too.
         self._slot_state = getattr(runner, "state_slots", None) is not None
-        self._tier_refused = self._slot_state and kv_tier is not None
+        # A model that keeps K/V a POOL A KIND (runner.window_pool,
+        # kvcache.WindowPages): a window layer's pages go back as they
+        # slide out, so a page shared between rows or moved to a tier
+        # has no window page to go with it. The same fallbacks, under
+        # reasons of their own. At the trivial setting (no window_pool:
+        # a page id carries both kinds) prefixes work through the map;
+        # the tiers' payload still has no place for the second pool.
+        self._window_pool = getattr(runner, "window_pool", None)
+        two_kinds = getattr(runner.mcfg, "num_window_layers", 0) > 0
+        self._tier_refused = (
+            (self._slot_state or two_kinds) and kv_tier is not None
+        )
+        if self._slot_state or two_kinds:
+            kv_tier = None
+        if self._slot_state or self._window_pool is not None:
+            prefix_store = None
         if self._slot_state:
-            prefix_store = kv_tier = None
             runner.reset_state_slots()
+        if self._window_pool is not None:
+            runner.reset_window_pool()
         self._prefix_store = None
         if (
             prefix_store is not None
@@ -744,6 +762,13 @@ class ContinuousBatcher:
             # a stored prefix is not kept): every row prefills its own
             self._count_state_fallback(
                 shared * (len(pending) - 1), "prefix_without_state_snapshot"
+            )
+            return
+        if self._window_pool is not None:
+            # a shared page's window page would be released by the
+            # first row to slide past it: every row prefills its own
+            self._count_state_fallback(
+                shared * (len(pending) - 1), "prefix_without_window_pages"
             )
             return
         n_pages = shared // PS
@@ -1036,9 +1061,12 @@ class ContinuousBatcher:
             )
 
     def _release_state(self, own_pages) -> None:
-        """With a row's pages goes its state slot (bound to the first)."""
+        """With a row's pages goes its state slot (bound to the first),
+        and its window pages and their reservation."""
         if self._slot_state and len(own_pages):
             self.runner.release_state(own_pages[0])
+        if self._window_pool is not None:
+            self.runner.release_window_row(own_pages)
 
     def _reserve(
         self, req: GenRequest, ctx: JobCtx, reserved: int = 0,
@@ -1064,6 +1092,15 @@ class ContinuousBatcher:
             ):
                 telemetry.STATE_SLOT_WAITS_TOTAL.inc(1.0)
             return None
+        need_w = 0
+        if self._window_pool is not None:
+            # admission waits for pages of BOTH kinds: the row reserves
+            # the most window pages it will hold at once
+            need_w = self.runner.window_budget(
+                self._max_total(req), n > self.ecfg.prefill_chunk
+            )
+            if need_w > self._window_pool.budget_free:
+                return None
 
         def _admit_native():
             if pfx is not None:
@@ -1137,6 +1174,8 @@ class ContinuousBatcher:
         if self._slot_state:
             # the device is told with the row's prefill
             self.runner.bind_state([table[0]], flush=False)
+        if need_w and len(pages):
+            self._window_pool.set_budget(pages[0], need_w)
         return free_idx, pages, table
 
     # -- double-buffered admission prep --------------------------------
@@ -1281,6 +1320,7 @@ class ContinuousBatcher:
                     ),
                     "batch": len(batch),
                     **self._state_attrs(len(batch)),
+                    **self._kv_attrs(len(r.prompt_ids) for r in reqs),
                 }
             with self.timer.time("prefill"):
                 if len(batch) == 1:
@@ -1402,6 +1442,7 @@ class ContinuousBatcher:
         if self._tel_on:
             self._tel_attrs["prefill"] = {
                 "tokens": int(len(seg)), **self._state_attrs(1),
+                **self._kv_attrs((s.prefill_pos + len(seg),)),
             }
         with self.timer.time("prefill"):
             logits = self.runner.prefill_batch_at(
@@ -2200,6 +2241,8 @@ class ContinuousBatcher:
                 if not greedy:
                     constrained_greedy = False
             room = min(room, len(s.pages) * PS - s.pos)
+        if self._window_pool is not None and active:
+            self._slide_windows(active, past_len, table)
         return _DecodeBatch(
             active, last, past_len, table, temp, top_p, top_k, row_seeds,
             _DecodeFacts(
@@ -2213,6 +2256,27 @@ class ContinuousBatcher:
                 wrapped=self._wrapped,
             ),
         )
+
+    def _slide_windows(self, active, past_len, table) -> None:
+        """Give back the window pages that have slid out behind each
+        active row's COMMITTED length (``past_len`` here is what the
+        host has accepted: tokens of windows in flight are not in it,
+        so a page one of them still reads is never released), and note
+        how much of its K/V a window layer holds."""
+        rows = np.asarray(active, np.int64)
+        self.runner.release_window_behind(table[rows], past_len[rows])
+        if self._tel_on:
+            PS = self.ecfg.kv_page_size
+            pool = self._window_pool
+            telemetry.KV_WINDOW_PAGES_HELD_TOTAL.inc(float(pool.in_use))
+            telemetry.KV_WINDOW_PAGES_WHOLE_TOTAL.inc(
+                float((-(-past_len[rows].astype(np.int64) // PS)).sum())
+            )
+            free = self.free_page_count
+            telemetry.KV_PAGES.set(float(free), "full", "free")
+            telemetry.KV_PAGES.set(
+                float(self.runner.alloc_pages - 1 - free), "full", "used"
+            )
 
     def _choose_path(self, f: _DecodeFacts, in_flight: int) -> str:
         """THE choice of a decode path, from the facts of the batch and
@@ -2291,6 +2355,25 @@ class ContinuousBatcher:
             "state_bytes": int(self.runner.state_step_bytes(rows)),
         }
 
+    def _kv_attrs(self, ctx) -> Dict[str, float]:
+        """Span attrs of a dispatch of a model that keeps K/V a pool a
+        kind: the mean over its rows of the tokens a full layer reads
+        (the context) and a window layer reads (the context, at most
+        the window). ``ctx`` is an iterable of the rows' contexts, not
+        walked for any other model: nothing for those."""
+        mcfg = self.runner.mcfg
+        if not getattr(mcfg, "num_window_layers", 0):
+            return {}
+        c = np.fromiter(ctx, np.float64)
+        if not c.size:
+            return {}
+        return {
+            "kv_tokens_full": round(float(c.mean()), 1),
+            "kv_tokens_window": round(
+                float(np.minimum(c, mcfg.sliding_window).mean()), 1
+            ),
+        }
+
     def _note_window(self, b: _DecodeBatch, steps: int) -> None:
         """Window attribution for the doctor's roofline grade:
         occupancy x fused steps over the span's duration is the
@@ -2305,6 +2388,7 @@ class ContinuousBatcher:
                     1,
                 ),
                 **self._state_attrs(n),
+                **self._kv_attrs(b.past_len[i] for i in b.active),
                 **self._route_attrs.get("decode_window", {}),
             }
 
@@ -2978,10 +3062,13 @@ class ContinuousBatcher:
         ORIGINAL request (live constraint and all) re-queued."""
         if not self._can_hibernate:
             if self._tier_refused and self.slots[i] is not None:
-                # the tier is there, the row's state is in no page: the
+                # the tier is there, the row's state (or its window
+                # layers' K/V) is in no page the tier can take: the
                 # caller's plain suspend regenerates the row
                 self._count_state_fallback(
-                    self.slots[i].pos, "hibernate_without_slot_state"
+                    self.slots[i].pos,
+                    "hibernate_without_slot_state" if self._slot_state
+                    else "hibernate_without_window_pages",
                 )
             return False
         s = self.slots[i]

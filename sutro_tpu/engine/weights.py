@@ -222,6 +222,16 @@ def _load_mixed(mcfg: ModelConfig, get, dtype) -> Dict[str, Any]:
     layer order (models/transformer.py ``_init_mixed_layers``).
     ``Linear`` weights are transposed to [in, out]; the depthwise conv
     weight [H, 1, K] becomes [H, K]."""
+    if mcfg.num_window_layers:
+        # the block is Qwen3-MoE's, and loading it under those names
+        # would rotate every layer alike and window none
+        raise NotImplementedError(
+            f"{mcfg.name}: loading a checkpoint of model_type 'mellum' is "
+            "not written: its tensor names (per-layer self_attn / "
+            "mlp.experts, whether q_norm / k_norm exist) and the reading "
+            "of rope_parameters by layer kind are unconfirmed; the model "
+            "runs on seeded random weights"
+        )
     if mcfg.num_mamba_layers:
         raise NotImplementedError(
             f"{mcfg.name}: loading a checkpoint with mamba layers (the "

@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers seven
+config-driven decoder-only transformer (models/transformer.py) covers eight
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -20,6 +20,12 @@ families:
   head and K-1 conv columns, kept a SLOT a sequence) beside GQA
   attention without rotary embedding; a softmax scale of its own and
   three scalar multipliers (embedding, residual, logits)
+- Mellum 2 (12b-a2.5b): attention layers of two kinds (``layer_types``
+  "swa" | "attention"): three sliding-window layers (window 1,024,
+  plain rotary embedding) to one full layer (YaRN with a stated
+  attention factor), every FFN routed (64 experts, top-8, softmax over
+  all then renormalised); K/V is kept a pool a kind
+  (engine/kvcache.py)
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -65,8 +71,12 @@ class ModelConfig:
     router_select_bias: bool = False
     router_renorm: bool = True
     router_scale: float = 1.0
-    # Per-layer mixer kinds, "attention" | "conv" | "mamba"; empty =>
-    # attention everywhere. A "conv" layer is the gated short
+    # Per-layer mixer kinds, "attention" | "swa" | "conv" | "mamba";
+    # empty => attention everywhere. A "swa" layer is attention over the
+    # last ``sliding_window`` positions, with the plain rotary embedding
+    # of ``local_rope_theta`` (``rope_theta`` when that is None) whatever
+    # scaling the full layers have; its K/V lives in a pool of its own
+    # kind (engine/kvcache.py). A "conv" layer is the gated short
     # convolution of the LFM2 family: depthwise, causal, ``conv_kernel``
     # taps, and K-1 columns of per-sequence state beside the paged K/V.
     layer_types: Tuple[str, ...] = ()
@@ -110,6 +120,9 @@ class ModelConfig:
     rope_original_max: int = 0
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
+    # what cos and sin are multiplied by under YaRN, where the published
+    # file states it (None: 0.1 ln(factor) + 1)
+    rope_attention_factor: Optional[float] = None
     # activation: "silu" (SwiGLU) | "gelu" (GeGLU) | "swiglu_oss" (clamped)
     activation: str = "silu"
     # head: "lm" | "embedding" (pooled, normalized)
@@ -119,6 +132,16 @@ class ModelConfig:
     pooling: str = "mean"
     # chat template key for engine/tokenizer.render_chat
     chat_template: str = "chatml"
+    # How SEEDED random weights are drawn where no checkpoint is loaded
+    # (models/transformer.py ``init_params``; a model of several layer
+    # kinds). Every matrix is normal with variance 1 / fan-in. For the
+    # embedding that makes the first block's output fifty times the
+    # token's own vector: every row's hidden state is then nearly the
+    # same, and a softmax router sends most rows of a batch to the same
+    # few experts (PERF.md section 6, PR 34). ``seeded_unit_embedding``
+    # draws the embedding's elements at unit variance instead, so that
+    # a row's hidden state stays its token's
+    seeded_unit_embedding: bool = False
 
     @property
     def q_size(self) -> int:
@@ -151,8 +174,20 @@ class ModelConfig:
 
     @property
     def num_attn_layers(self) -> int:
-        """Layers with K/V: what the page pool spans."""
+        """Layers that keep K/V over the whole context: what the page
+        pool spans."""
         return self.mixers.count("attention")
+
+    @property
+    def num_window_layers(self) -> int:
+        """"swa" layers: what the WINDOW page pool spans."""
+        return self.mixers.count("swa")
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers with K/V of either kind: a chunk's K/V is stacked
+        over them, the full layers first."""
+        return self.num_attn_layers + self.num_window_layers
 
     @property
     def num_conv_layers(self) -> int:
@@ -183,8 +218,15 @@ class ModelConfig:
         return max(self.mamba_conv - 1, 0) if self.num_mamba_layers else 0
 
     def window_for_layer(self, layer: int) -> int:
-        """Per-layer attention window (0 = full); SURVEY §5.7 long-context."""
-        if self.sliding_window <= 0 or self.sliding_pattern == "none":
+        """Per-layer attention window (0 = full); SURVEY §5.7
+        long-context. By the layer's kind where the config lists its
+        layers, else by ``sliding_pattern``: one per-layer list either
+        way (``window_array``)."""
+        if self.sliding_window <= 0:
+            return 0
+        if self.layer_types:
+            return self.sliding_window if self.mixers[layer] == "swa" else 0
+        if self.sliding_pattern == "none":
             return 0
         if self.sliding_pattern == "alternate":
             return self.sliding_window if layer % 2 == 0 else 0
@@ -319,6 +361,37 @@ def _granite_hybrid(name: str, layer_types: Tuple[str, ...], *,
     )
 
 
+#: Mellum2-12B-A2.5B's published ``layer_types`` (config.json):
+#: sliding_attention x 3, full_attention, seven times
+_MELLUM2_LAYERS: Tuple[str, ...] = ("swa", "swa", "swa", "attention") * 7
+
+
+def _mellum2(name: str, layer_types: Tuple[str, ...], *, h: int = 2304,
+             nh: int = 32, nkv: int = 4, hd: int = 128, inter: int = 7168,
+             experts: int = 64, top_k: int = 8, moe_inter: int = 896,
+             window: int = 1024, rope_original: int = 8192,
+             vocab: int = 98_304, template: str = "chatml") -> ModelConfig:
+    """The published ``mellum`` keys: Qwen3-MoE's block (QK-norm, softmax
+    router renormalised over the chosen, no shared expert; every layer
+    routed, so ``intermediate_size`` is unused) with ``layer_types`` of
+    sliding and full attention and a rotary embedding a kind: YaRN
+    (factor 16 over ``rope_original``, attention factor as stated) on
+    the full layers, plain on the window layers, theta 500,000 both."""
+    return ModelConfig(
+        name=name, vocab_size=vocab, hidden_size=h,
+        num_layers=len(layer_types), num_heads=nh, num_kv_heads=nkv,
+        head_dim=hd, intermediate_size=inter, norm_eps=1e-6,
+        qk_norm=True, tie_embeddings=False,
+        moe_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_inter,
+        rope_theta=500_000.0, local_rope_theta=500_000.0,
+        rope_scaling_factor=16.0, rope_original_max=rope_original,
+        rope_attention_factor=1.2772588722239782,
+        sliding_window=window, layer_types=layer_types,
+        chat_template=template, seeded_unit_embedding=True,
+    )
+
+
 MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # Qwen3 dense
     "qwen3-0.6b": _qwen3("qwen3-0.6b", 1024, 28, 16, 8, 3072),
@@ -350,6 +423,12 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # Granite 4.0-H: Mamba-2 + NoPE GQA, dense; whole on one v5e
     "granite-4.0-h-micro": _granite_hybrid(
         "granite-4.0-h-micro", _GRANITE_H_MICRO_LAYERS
+    ),
+    # Mellum 2: as published (24.3 GB in bf16), and its first eight
+    # layers (two whole periods, every expert: 7.6 GB, one v5e)
+    "mellum2-12b-a2.5b": _mellum2("mellum2-12b-a2.5b", _MELLUM2_LAYERS),
+    "mellum2-12b-a2.5b-l8": _mellum2(
+        "mellum2-12b-a2.5b-l8", _MELLUM2_LAYERS[:8]
     ),
     # Embeddings (Qwen3 trunk + last-token-pool head)
     "qwen3-emb-0.6b": _qwen3("qwen3-emb-0.6b", 1024, 28, 16, 8, 3072, head="embedding"),
@@ -388,6 +467,14 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
          "mamba"),
         h=128, nh=4, nkv=2, inter=256, m_heads=8, m_head_dim=32,
         m_state=16, m_chunk=8, vocab=512, template="plain",
+    ),
+    # window 8 at a test's page size of 4, YaRN over an original 16, so
+    # that a short test crosses the window, a page and a release
+    "tiny-mellum2": _mellum2(
+        "tiny-mellum2", ("swa", "swa", "swa", "attention"),
+        h=128, nh=4, nkv=2, hd=32, inter=256, experts=8, top_k=2,
+        moe_inter=64, window=8, rope_original=16, vocab=512,
+        template="plain",
     ),
     "tiny-emb": ModelConfig(
         name="tiny-emb", vocab_size=512, hidden_size=128, num_layers=2,

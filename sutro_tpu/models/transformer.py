@@ -141,17 +141,20 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     if cfg.num_mamba_layers:
         out["mamba"] = _init_mamba_layers(cfg, dense, dtype)
-    if La:
-        out["attn"] = {
-            "attn_norm": jnp.ones((La, H), dtype),
-            "wq": dense((La, H, NHD), H),
-            "wk": dense((La, H, KVD), H),
-            "wv": dense((La, H, KVD), H),
-            "wo": dense((La, NHD, H), NHD),
+    # full and window attention layers: the same block, a stack a kind
+    for kind, Lk in (("attn", La), ("swa", cfg.num_window_layers)):
+        if not Lk:
+            continue
+        out[kind] = {
+            "attn_norm": jnp.ones((Lk, H), dtype),
+            "wq": dense((Lk, H, NHD), H),
+            "wk": dense((Lk, H, KVD), H),
+            "wv": dense((Lk, H, KVD), H),
+            "wo": dense((Lk, NHD, H), NHD),
         }
         if cfg.qk_norm:
-            out["attn"]["q_norm"] = jnp.ones((La, Dh), dtype)
-            out["attn"]["k_norm"] = jnp.ones((La, Dh), dtype)
+            out[kind]["q_norm"] = jnp.ones((Lk, Dh), dtype)
+            out[kind]["k_norm"] = jnp.ones((Lk, Dh), dtype)
     if Lc:
         K = cfg.conv_kernel
         out["conv"] = {
@@ -229,7 +232,10 @@ def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     if not cfg.homogeneous:
         _check_mixed(cfg)
         params = {
-            "embed": dense((cfg.vocab_size, H), H),
+            # ``ModelConfig.seeded_unit_embedding``: unit elements, or 1 / H
+            "embed": dense(
+                (cfg.vocab_size, H), 1 if cfg.seeded_unit_embedding else H
+            ),
             "final_norm": jnp.ones((H,), dtype),
             "layers": _init_mixed_layers(cfg, dense, dtype),
         }
@@ -328,14 +334,16 @@ def _yarn_inv_freq(cfg: ModelConfig, half: int) -> Tuple[np.ndarray, float]:
             dim * np.log(orig / (n_rot * 2 * np.pi))
         ) / (2 * np.log(base))
 
-    low = np.floor(find_dim(cfg.rope_beta_fast))
-    high = np.ceil(find_dim(cfg.rope_beta_slow))
+    low = max(np.floor(find_dim(cfg.rope_beta_fast)), 0)
+    high = min(np.ceil(find_dim(cfg.rope_beta_slow)), dim - 1)
     rng = np.arange(half, dtype=np.float64)
     ramp = np.clip((rng - low) / max(high - low, 1e-3), 0.0, 1.0)
     extrap_factor = 1.0 - ramp
     inv_freq = interp * (1 - extrap_factor) + extrap * extrap_factor
-    attn_scale = 0.1 * float(np.log(factor)) + 1.0
-    return inv_freq.astype(np.float32), attn_scale
+    attn_scale = cfg.rope_attention_factor
+    if attn_scale is None:
+        attn_scale = 0.1 * float(np.log(factor)) + 1.0
+    return inv_freq.astype(np.float32), float(attn_scale)
 
 
 def apply_rope(
@@ -343,20 +351,28 @@ def apply_rope(
     positions: jax.Array,
     theta: jax.Array,
     cfg: Optional[ModelConfig] = None,
+    yarn: Optional[bool] = None,
 ) -> jax.Array:
-    """rotate-half RoPE. x: [B, T, N, Dh]; positions: [B, T]."""
+    """rotate-half RoPE. x: [B, T, N, Dh]; positions: [B, T]. ``yarn``
+    says whether this layer takes the config's YaRN scaling: the walk
+    over layer kinds knows each layer's kind and says (a window layer:
+    False, plain ``theta``); None leaves it to the config, for the one
+    scan of a homogeneous model, whose layers all take it."""
     dh = x.shape[-1]
     half = dh // 2
     scale = 1.0
-    if cfg is not None and cfg.rope_scaling_factor:
-        if cfg.local_rope_theta:
-            # YaRN frequencies derive from the GLOBAL base only; a
-            # config mixing per-layer thetas with YaRN would silently
-            # mis-rotate local layers (the traced per-layer theta is
-            # unused on this path)
+    if yarn is None:
+        yarn = cfg is not None and bool(cfg.rope_scaling_factor)
+        if yarn and cfg.local_rope_theta:
+            # the scan's per-layer theta is traced and the YaRN
+            # frequencies are static, from the GLOBAL base: a scanned
+            # model that mixes the two would mis-rotate its local
+            # layers. Listed ``layer_types`` ("swa") carry both
             raise NotImplementedError(
-                "YaRN rope_scaling with local_rope_theta is unsupported"
+                "YaRN rope_scaling with local_rope_theta needs the "
+                "layers listed by kind (layer_types), not sliding_pattern"
             )
+    if yarn:
         freq, scale = _yarn_inv_freq(cfg, half)
         freq = jnp.asarray(freq)
     else:
@@ -462,11 +478,15 @@ def attention_mixer(
     page_table=None, past_len=None, use_pallas: bool = False,
     ring_mesh=None, wk_l=None, wv_l=None, win_len=None,
     pfx_groups=None, kernel_mesh=None,
+    yarn: Optional[bool] = None, live_window: int = 0,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """GQA attention over the chunk and its paged past, through the
     output projection: ``(out [B, T, H], (k_chunk, v_chunk))``. The one
     attention block of every model (``layer_apply`` and the mixed walk
-    both call it)."""
+    both call it). ``yarn``: ``apply_rope``'s. ``live_window`` (static;
+    a "swa" layer's window) says the pool and table handed in are the
+    WINDOW pool's, which holds a row's last ``live_window`` positions
+    and nothing older (ops/attention.py)."""
     B, T = x.shape[:2]
     q = x @ _w(lp, "wq", x.dtype)
     k = x @ _w(lp, "wk", x.dtype)
@@ -480,8 +500,8 @@ def attention_mixer(
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps, cfg.norm_zero_centered)
         k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_zero_centered)
     if cfg.position_embedding != "nope":
-        q = apply_rope(q, positions, theta, cfg)
-        k = apply_rope(k, positions, theta, cfg)
+        q = apply_rope(q, positions, theta, cfg, yarn)
+        k = apply_rope(k, positions, theta, cfg, yarn)
     if cfg.attention_multiplier is not None:
         # every attention path scales by 1/sqrt(Dh): fold the ratio
         # into q (a power of two for the published multipliers)
@@ -502,6 +522,7 @@ def attention_mixer(
         win_k=wk_l, win_v=wv_l, win_len=win_len,
         pfx_groups=pfx_groups,
         kernel_mesh=kernel_mesh,
+        live_window=live_window,
     )
     attn = attn.reshape(B, T, cfg.q_size) @ _w(lp, "wo", x.dtype)
     if cfg.attn_bias:
@@ -832,7 +853,9 @@ def layer_apply(
 # Layers of several kinds
 # ---------------------------------------------------------------------------
 
-_MIXER_STACK = {"attention": "attn", "conv": "conv", "mamba": "mamba"}
+_MIXER_STACK = {
+    "attention": "attn", "swa": "swa", "conv": "conv", "mamba": "mamba",
+}
 
 
 def _check_mixed(cfg: ModelConfig) -> None:
@@ -855,13 +878,20 @@ def _check_mixed(cfg: ModelConfig) -> None:
             f"{cfg.name}: mamba layers need mamba_conv >= 2, heads, a "
             "head_dim and a state size, and heads a multiple of groups"
         )
+    if cfg.num_window_layers and cfg.sliding_window < 1:
+        raise ValueError(f"{cfg.name}: swa layers need a sliding_window")
+    # supported in the walk: a window by the layer's kind ("swa", its
+    # K/V a pool of its own) and a rotary embedding a kind (YaRN on the
+    # full layers, plain ``local_rope_theta`` on the window layers). A
+    # window by ``sliding_pattern`` belongs to the one scan of a
+    # homogeneous model
     unsupported = {
-        "sliding windows": cfg.sliding_pattern != "none",
+        "sliding windows by sliding_pattern (list the layers' kinds instead)":
+            cfg.sliding_pattern != "none",
         "post norms": cfg.post_norms,
         "zero-centered norms": cfg.norm_zero_centered,
         "attention sinks": cfg.attention_sink,
         "projection biases": cfg.attn_bias or cfg.moe_bias,
-        "rope scaling": bool(cfg.rope_scaling_factor),
     }
     bad = [k for k, v in unsupported.items() if v]
     if bad:
@@ -913,6 +943,7 @@ def _mixed_trunk(
     positions, valid_len, conv_state, k_pages, v_pages, k_scale, v_scale,
     page_table, past_len, window_past, use_pallas, ep_mesh,
     pfx_groups, kernel_mesh, state_past=None, ssm_pending=False,
+    window_pool=None,
 ):
     """The walk over a config's own list of layers, parameters stacked
     per kind. Every stack (and the page pool, the window buffers, the
@@ -923,6 +954,11 @@ def _mixed_trunk(
     stacked over the attention layers, ``g_ext`` over the conv layers,
     expert row counts over the routed layers (None for a kind with no
     layer), and what commits the mamba layers' state (``MixedChunk``).
+    K/V of the "swa" layers follows that of the full layers in the
+    stack (and in the fused window's buffers): ``kvcache.write_kv``
+    splits it by the pools' depths. ``window_pool`` = (wk_pages,
+    wv_pages, wtable): the window layers' pool and each row's pages IN
+    it (``kvcache.window_table``).
     """
     _check_mixed(cfg)
     stacks = params["layers"]
@@ -948,8 +984,22 @@ def _mixed_trunk(
             (cfg.num_conv_layers, B, K1, cfg.hidden_size), h.dtype
         )
     win_len = None if window_past is None else window_past[2]
-    window = jnp.int32(0)
-    theta = jnp.float32(cfg.rope_theta)
+    La = cfg.num_attn_layers
+    # per attention kind: (window, theta, YaRN?, pool, table, where its
+    # layers start in the stacked K/V and the fused window's buffers)
+    attn_kind = {
+        "attention": (
+            jnp.int32(0), jnp.float32(cfg.rope_theta),
+            bool(cfg.rope_scaling_factor), k_pages, v_pages, page_table, 0,
+        ),
+    }
+    if cfg.num_window_layers:
+        wk_pages, wv_pages, wtable = window_pool or (None, None, None)
+        attn_kind["swa"] = (
+            jnp.int32(cfg.sliding_window),
+            jnp.float32(cfg.local_rope_theta or cfg.rope_theta),
+            False, wk_pages, wv_pages, wtable, La,
+        )
     mixers, ffns = cfg.mixers, cfg.ffns
     mixer_at, ffn_at = _index_in_kind(mixers), _index_in_kind(ffns)
 
@@ -971,21 +1021,31 @@ def _mixed_trunk(
                 )
                 out.update(ssm)
         else:
-            with jax.named_scope("attn_mixer"):
-                y, (out["k"], out["v"]) = attention_mixer(
+            window, theta, yarn, kp, vp, table, at = attn_kind[mixer]
+            swa = mixer == "swa"
+            scope = ("attn_window" if swa else "attn_full") if (
+                cfg.num_window_layers
+            ) else "attn_mixer"
+            key = ("wk", "wv") if swa else ("k", "v")
+            with jax.named_scope(scope):
+                y, (out[key[0]], out[key[1]]) = attention_mixer(
                     cfg, lp, x,
                     positions=positions, valid_len=valid_len,
                     window=window, theta=theta,
-                    k_pages=k_pages, v_pages=v_pages,
-                    k_scale=k_scale, v_scale=v_scale, layer=m_idx,
-                    page_table=page_table, past_len=past_len,
+                    k_pages=kp, v_pages=vp,
+                    k_scale=None if swa else k_scale,
+                    v_scale=None if swa else v_scale, layer=m_idx,
+                    page_table=table, past_len=past_len,
                     use_pallas=use_pallas,
                     wk_l=None if window_past is None
-                    else window_past[0][m_idx],
+                    else window_past[0][at + m_idx],
                     wv_l=None if window_past is None
-                    else window_past[1][m_idx],
+                    else window_past[1][at + m_idx],
                     win_len=win_len,
-                    pfx_groups=pfx_groups, kernel_mesh=kernel_mesh,
+                    # a shared prefix's carry reads the full pool's pages
+                    pfx_groups=None if swa else pfx_groups,
+                    kernel_mesh=kernel_mesh, yarn=yarn,
+                    live_window=cfg.sliding_window if swa else 0,
                 )
         h = h + (y if r == 1.0 else y * jnp.asarray(r, y.dtype))
         experts = {
@@ -1010,7 +1070,7 @@ def _mixed_trunk(
         return h + (y if r == 1.0 else y * jnp.asarray(r, y.dtype)), out
 
     outs: Dict[str, list] = {
-        k: [] for k in ("k", "v", "conv", "route") + _SSM_KEYS
+        k: [] for k in ("k", "v", "wk", "wv", "conv", "route") + _SSM_KEYS
     }
     for first, period, repeats in layer_groups(cfg):
         span = range(first, first + period)
@@ -1046,6 +1106,12 @@ def _mixed_trunk(
         for k, v in outs.items()
     }
     ssm = {k[4:]: cat[k] for k in _SSM_KEYS if cat[k] is not None}
+    if cat["wk"] is not None:
+        # the window layers' K/V after the full layers'
+        for full, win in (("k", "wk"), ("v", "wv")):
+            cat[full] = cat[win] if cat[full] is None else (
+                jnp.concatenate([cat[full], cat[win]])
+            )
     return h, cat["k"], cat["v"], cat["conv"], cat["route"], ssm or None
 
 
@@ -1183,6 +1249,10 @@ def forward(
     # is decided later (``mamba_mixer``; None: a chunk of one token)
     state_past: Optional[StatePast] = None,
     ssm_pending: Optional[bool] = None,
+    # (wk_pages, wv_pages [L_win, NP_w, PS, KD], wtable [B, MP]): the
+    # "swa" layers' pool and each row's pages in it, with ``paged_past``
+    # for a model that has such layers (engine/kvcache.py)
+    window_pool: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, jax.Array, Tuple[Any, jax.Array]]:
     """Run the trunk over a chunk.
 
@@ -1220,6 +1290,7 @@ def forward(
             ssm_pending=(
                 ids.shape[1] == 1 if ssm_pending is None else ssm_pending
             ),
+            window_pool=window_pool,
         )
         out, h = head_apply(cfg, params, h, valid_len, logit_positions)
         chunk = MixedChunk(k=k_all, conv=conv, route=route, ssm=ssm)

@@ -20,10 +20,18 @@ the chunk, past-length masking, per-layer sliding windows (Gemma3 5:1
 local:global, gpt-oss alternating — SURVEY §5.7), and gpt-oss learnable
 attention sinks (an extra per-head softmax logit that absorbs probability
 mass).
+
+``live_window`` (static) marks a layer whose pool is the WINDOW pool of a
+model that keeps K/V a pool a kind (engine/kvcache.py): the pool holds a
+row's last ``live_window`` positions and the pages before them may belong
+to another sequence by now, so every path reads from the page of position
+``max(past_len - live_window + 1, 0)`` on and nothing before it
+(``live_pages``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -98,6 +106,26 @@ def _prefix_carry(
     return dict(pfx_cnt=pfx_cnt, m0=m0, l0=l0, acc0=acc0)
 
 
+def live_pages(page_table, past_len, live_window: int, page_size: int):
+    """``(table [B, MPw], first [B])``: the slots of each row's table
+    that can hold a position a query at ``past_len`` or later sees
+    through a window of ``live_window``, from slot ``first`` on. MPw is
+    static: the pages a window spans however it lies on them. A slot
+    past the table's end repeats its last one; its positions (counted
+    from the unclipped slot) are past every ``past_len``, so it is
+    masked like any page not yet written."""
+    from ..engine.kvcache import first_live_page, window_span_pages
+
+    MP = page_table.shape[1]
+    span = min(MP, window_span_pages(live_window, 0, page_size))
+    first = first_live_page(past_len, live_window, page_size)
+    slots = first[:, None] + jnp.arange(span, dtype=jnp.int32)[None]
+    return (
+        jnp.take_along_axis(page_table, jnp.minimum(slots, MP - 1), axis=1),
+        first,
+    )
+
+
 @jax.named_scope("paged_decode_xla")
 def paged_decode_xla(
     q: jax.Array,          # [B, NH, Dh] — current-step queries
@@ -115,6 +143,7 @@ def paged_decode_xla(
     win_len: Optional[jax.Array] = None,  # scalar int32 — valid slots
     k_scale: Optional[jax.Array] = None,  # [L, NP, PS] int8-KV scales
     v_scale: Optional[jax.Array] = None,
+    live_window: int = 0,  # static: gather the window's pages only
 ) -> jax.Array:
     """One decode step's attention in plain XLA, ``[B, NH, Dh]``: what
     ``paged_decode_attention`` computes, for the calls the kernel does
@@ -146,6 +175,9 @@ def paged_decode_xla(
     PS, KD = k_pages.shape[2:]
     KVH = KD // Dh
     G = NH // KVH
+    first = None
+    if live_window:
+        page_table, first = live_pages(page_table, past_len, live_window, PS)
     S = page_table.shape[1] * PS
     scale = Dh ** -0.5
     f32 = jnp.float32
@@ -182,6 +214,8 @@ def paged_decode_xla(
     span = jnp.where(win > 0, win, jnp.iinfo(jnp.int32).max)
     q_pos = past_len + wl                                   # [B]
     t = jnp.arange(S, dtype=jnp.int32)[None]
+    if first is not None:
+        t = t + first[:, None] * PS
     segs = [(
         scores(kp, (t < past_len[:, None]) & (q_pos[:, None] - t < span)),
         vp,
@@ -272,6 +306,8 @@ def chunk_attention(
     # shared-prefix carry is not partitioned; under a mesh its groups
     # are ignored like on the fallback path (same function).
     kernel_mesh=None,
+    # static: the paged past is a WINDOW pool's (module docstring)
+    live_window: int = 0,
 ) -> jax.Array:
     """Returns [B, T, NH, Dh]."""
     B, T = q.shape[:2]
@@ -308,12 +344,14 @@ def chunk_attention(
             sink=sink, win_k=win_k, win_v=win_v, win_len=win_len,
             k_scale=past_k_scale, v_scale=past_v_scale,
         )
-        decode = paged_decode_xla
+        decode = functools.partial(paged_decode_xla, live_window=live_window)
         if use_pallas:
             from .pallas_paged import paged_decode_attention, paged_decode_supported
 
             if paged_decode_supported(q[:, 0], past_k_pages, page_table):
                 decode = paged_decode_attention
+                if live_window:
+                    decode = functools.partial(decode, window_start=True)
                 if pfx_groups and kernel_mesh is None:
                     optional.update(
                         _prefix_carry(
@@ -339,6 +377,11 @@ def chunk_attention(
             lowering.record_reference("paged_decode")
         from ..engine.kvcache import gather_kv_layer
 
+        past_first = None
+        if live_window:
+            page_table, past_first = live_pages(
+                page_table, past_len, live_window, past_k_pages.shape[2]
+            )
         past_k, past_v = gather_kv_layer(
             past_k_pages, past_v_pages, layer, page_table, k.shape[2],
             k_scale=past_k_scale, v_scale=past_v_scale,
@@ -370,14 +413,14 @@ def chunk_attention(
         ctx = past_k.shape[1]
         key_segs = [past_k, k]
         val_segs = [past_v, v]
-        pos_segs = [
-            jnp.broadcast_to(
-                jnp.arange(ctx, dtype=jnp.int32)[None], (B, ctx)
-            ),
-            positions,
-        ]
+        past_pos = jnp.broadcast_to(
+            jnp.arange(ctx, dtype=jnp.int32)[None], (B, ctx)
+        )
+        if past_k_pages is not None and live_window:
+            past_pos = past_pos + past_first[:, None] * past_k_pages.shape[2]
+        pos_segs = [past_pos, positions]
         valid_segs = [
-            jnp.arange(ctx, dtype=jnp.int32)[None] < past_len[:, None],
+            past_pos < past_len[:, None],
             jnp.arange(T, dtype=jnp.int32)[None] < valid_len[:, None],
         ]
         if win_k is not None and win_k.shape[1] > 0:

@@ -26,7 +26,14 @@ ops/moe_ep.py; ``ModelConfig.router_*`` says which):
 
 The grouped products are ``jax.lax.ragged_dot`` unless the caller asks
 for the Pallas kernel (``use_pallas``, the engine's switch) and the
-shapes allow it (ops/pallas_gmm.py): one switch for every kernel.
+shapes allow it (ops/pallas_gmm.py): one switch for every kernel. The
+shape decides, not a second switch: experts that arrive as the STACK of
+every routed layer (``layer``: a model whose layers are of several
+kinds) are read in place by ``ragged_dot`` at every size, whatever
+``use_pallas`` says. The Pallas kernel pads a row tile a group, and the
+stack's groups are every layer's experts (512 at 8 x 64), so it would
+want one layer's experts sliced out: a copy of them (793 MB a layer at
+64 x 3 x 2304 x 896 in bf16) every step (PERF.md section 6, PR 28 and 34).
 
 Expert parallelism shards the expert axis of ``we_*`` over the mesh
 "expert" axis; XLA turns the resulting gather/scatter into all-to-alls over
@@ -152,12 +159,14 @@ def moe_mlp(
     E = router.shape[-1]
     N = B * T
     xt = x.reshape(N, H)
-    if layer is not None and (method == "dense" or (method == "auto" and E <= 8)
-                              or use_pallas):
-        # small E, or the Pallas kernel (whose padded layout grows with
-        # the group count): one layer's experts, sliced
+    if layer is not None and (
+        method == "dense" or (method == "auto" and E <= 8)
+    ):
+        # small E (the dense path): one layer's experts, sliced
         we_gate, we_up, we_down = we_gate[layer], we_up[layer], we_down[layer]
         layer = None
+    # the stack is ragged_dot's, in place (module docstring)
+    use_pallas = use_pallas and layer is None
 
     top_idx, probs, flat_expert, flat_token, flat_prob = _route(
         xt, router, router_b, top_k, **(route or {})

@@ -43,7 +43,15 @@ PERF.md §6 PR 31):
   attention sink all join the softmax in the finalization step;
 - the layer index and per-layer sliding windows (Gemma3 / gpt-oss) are
   dynamic operands, so one compiled kernel serves every layer of the
-  ``lax.scan``.
+  ``lax.scan``: such a layer's pages before its window are fetched and
+  masked (its pool keeps them anyway);
+- a layer whose pool is the WINDOW pool (``window_start``, static: a
+  "swa" layer of a model that lists its layers by kind,
+  engine/kvcache.py) fetches from the page that holds position
+  ``max(pos - window + 1, 0)`` on, the later of that and the shared
+  prefix's end: the pages before it have slid out of the window, and
+  the host may have given them to another sequence. A call without it
+  (every call of a model with one pool) compiles to the program it had.
 
 All math is float32.
 """
@@ -79,6 +87,7 @@ def _paged_decode_kernel(
     window_slots: int = 0,
     quantized: bool = False,
     prefix: bool = False,
+    window_start: bool = False,
 ):
     # ref layout varies with (window_slots, quantized, prefix) — walk an
     # index instead of a per-case tuple unpack
@@ -153,7 +162,16 @@ def _paged_decode_kernel(
     # not fetched.
 
     def first_page(row):
-        return pfx_cnt_ref[row] if prefix else 0
+        first = pfx_cnt_ref[row] if prefix else 0
+        if window_start:
+            # the page of the oldest position the row's query can see
+            # (every row's query is win_len past its pages' tokens)
+            oldest = past_len_ref[row] + (
+                win_len_ref[0] if window_slots else 0
+            ) - win + 1
+            slid = jnp.where(win > 0, jnp.maximum(oldest, 0) // PS, 0)
+            first = jnp.maximum(first, slid)
+        return first
 
     def pages_of(row):
         """Pages row fetches: up to its last token's, from first_page."""
@@ -735,7 +753,7 @@ def paged_decode_supported(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window_start"))
 def paged_decode_attention(
     q: jax.Array,          # [B, NH, Dh] — current-step queries
     k_pages: jax.Array,    # [L, NP, PS, KVH*Dh] — the stacked FUSED pool
@@ -765,6 +783,9 @@ def paged_decode_attention(
     m0: Optional[jax.Array] = None,        # [B, NH] f32
     l0: Optional[jax.Array] = None,        # [B, NH] f32
     acc0: Optional[jax.Array] = None,      # [B, NH, KVH*Dh] f32 (block-diag)
+    # the pool holds a row's last ``window`` positions only: fetch from
+    # the page of position max(pos - window + 1, 0) on (module docstring)
+    window_start: bool = False,
 ) -> jax.Array:
     """Returns [B, NH, Dh] attention outputs for one decode step.
 
@@ -810,6 +831,7 @@ def paged_decode_attention(
         window_slots=W,
         quantized=quantized,
         prefix=prefix,
+        window_start=window_start,
     )
 
     # index maps take *s so the scalar-prefetch arity (4 to 6) needs

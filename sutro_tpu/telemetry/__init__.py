@@ -369,10 +369,42 @@ STATE_FALLBACK_PREFILL_TOKENS_TOTAL = REGISTRY.counter(
     "per-sequence state at the position it resumed from",
     # hibernated_tail_page | tier_payload_without_state (state a page);
     # prefix_without_state_snapshot | hibernate_without_slot_state
-    # (state a slot: no page holds it)
+    # (state a slot: no page holds it); prefix_without_window_pages |
+    # hibernate_without_window_pages (K/V a pool a kind: a shared or
+    # tiered page has no window page)
     labels=("reason",),
     unit="tokens",
     max_series=8,
+)
+KV_PAGES = REGISTRY.gauge(
+    "sutro_kv_pages",
+    "Pages of the K/V pools of a model that keeps K/V a pool a kind "
+    "(garbage pages not counted): the full layers' pool and the window "
+    "layers', free and used",
+    labels=("kind", "state"),  # full | window; free | used
+    unit="pages",
+    max_series=8,
+)
+KV_WINDOW_PAGES_RELEASED_TOTAL = REGISTRY.counter(
+    "sutro_kv_window_pages_released_total",
+    "Window-pool pages taken back from a live sequence because their "
+    "last position had slid out of the window (a row's end gives its "
+    "pages back uncounted)",
+    unit="pages",
+)
+KV_WINDOW_PAGES_HELD_TOTAL = REGISTRY.counter(
+    "sutro_kv_window_pages_held_total",
+    "Window-pool pages the decode batch's rows hold, summed over "
+    "scheduler iterations",
+    unit="pages",
+)
+KV_WINDOW_PAGES_WHOLE_TOTAL = REGISTRY.counter(
+    "sutro_kv_window_pages_whole_total",
+    "Pages the same rows' whole contexts fill (what a window layer "
+    "would hold in one pool), summed as "
+    "sutro_kv_window_pages_held_total is: held over whole is the share "
+    "of its K/V a window layer keeps",
+    unit="pages",
 )
 STATE_SLOTS = REGISTRY.gauge(
     "sutro_state_slots",

@@ -352,16 +352,48 @@ _REVIEWS = [
 ]
 
 
-def test_rows_bit_identical_cold_and_warm(live_engine, counting_builder):
+def _wait_engine_idle(eng, timeout=120.0):
+    """The shared ``live_engine`` may still be ending a job of the test
+    file that ran before this one in the same worker: its rows would
+    share the cold job's batch and not the warm one's, and its session's
+    lookup would land in the counter between two reads of it."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if all(JobStatus(j["status"]).is_terminal() for j in eng.list_jobs()):
+            return
+        time.sleep(0.05)
+    raise TimeoutError("the shared engine never went idle")
+
+
+def test_rows_bit_identical_cold_and_warm(
+    live_engine, counting_builder, monkeypatch
+):
     eng, _url, _home = live_engine
     schema = _schema("coldwarm")
+    _wait_engine_idle(eng)
+    # THIS schema's lookups, as ``test_four_sites_share_one_factory``
+    # counts them: the registry's counter is the whole process's, and
+    # whatever else the shared engine serves meanwhile (another file's
+    # job ending, a chat) counts there too
+    asked = []
+    real_get = eng.constraint_factories.factory_for
+
+    def get(sch, tok):
+        fac, how = real_get(sch, tok)
+        if sch == schema:
+            asked.append(how)
+        return fac, how
+
+    monkeypatch.setattr(eng.constraint_factories, "factory_for", get)
     before = _counts()
     cold = _submit(eng, _REVIEWS, output_schema=schema)
     assert _wait_terminal(eng, cold) == JobStatus.SUCCEEDED
-    assert _delta(before) == {"hit": 1.0, "miss": 1.0, "wait": 0.0}
+    assert asked == ["miss", "hit"]         # the submit probe, the session
     warm = _submit(eng, _REVIEWS, output_schema=schema)
     assert _wait_terminal(eng, warm) == JobStatus.SUCCEEDED
-    assert _delta(before) == {"hit": 3.0, "miss": 1.0, "wait": 0.0}
+    assert asked == ["miss", "hit", "hit", "hit"]
+    delta = _delta(before)
+    assert delta["miss"] >= 1.0 and delta["hit"] >= 3.0
     assert len(counting_builder) == 1
     out_cold, why_cold = _rows(eng, cold)
     out_warm, why_warm = _rows(eng, warm)
