@@ -139,9 +139,11 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         "use_pallas": use_pallas,
         "pallas_reason": why,
         # what this process has traced so far (ops/lowering.py): each
-        # kernel by path, and the XLA decode attention beside them
+        # kernel by path, and beside them the XLA decode attention and
+        # the routed experts' grouped product (a routed model's alone)
         "kernel_paths": lowering.snapshot(),
         "paged_decode_xla": lowering.xla_decode_count(),
+        "grouped_matmul": lowering.grouped_matmul_counts(),
         "compile_cache_dir": enable_compile_cache(),
         "native_runtime": native_runtime.is_available(),
         "native_fsm": native_fsm.is_available(),

@@ -405,10 +405,15 @@ def _mlp(
     cfg: ModelConfig, lp: Dict[str, Any], x: jax.Array, ep_mesh=None,
     use_pallas: bool = False, return_counts: bool = False,
     expert_stacks: Optional[Tuple[Dict[str, Any], jax.Array]] = None,
+    kernel_mesh=None,
 ):
     """The layer's FFN over normed ``x``: routed when the layer's
     parameters hold a router, dense otherwise. ``return_counts`` (routed
     layers, not under EP) also returns the rows each expert got.
+    ``kernel_mesh`` (the runner's: a mesh whose ``model`` axis shards
+    the operands) keeps the routed products that GSPMD partitions on
+    ``ragged_dot``: XLA cannot partition a Mosaic call, and only the EP
+    path's ``shard_map`` hands the kernel whole operands a shard.
     ``expert_stacks`` = (every routed layer's ``we_*`` stacked, this
     layer's index) for a caller that must not slice the experts out
     (ops/moe.py ``moe_mlp``: ``layer``)."""
@@ -429,7 +434,6 @@ def _mlp(
             bias_up=lp.get("we_up_b"),
             bias_down=lp.get("we_down_b"),
             route=_router_form(cfg, lp),
-            use_pallas=use_pallas,
         )
         args = (
             x,
@@ -444,12 +448,15 @@ def _mlp(
             # all-gathering them for the ragged grouped GEMM
             from ..ops.moe_ep import moe_mlp_ep
 
-            out = moe_mlp_ep(*args, mesh=ep_mesh, **kwargs)
+            out = moe_mlp_ep(
+                *args, mesh=ep_mesh, use_pallas=use_pallas, **kwargs
+            )
             if return_counts:
                 return out, jnp.zeros((lp["router"].shape[-1],), jnp.int32)
             return out
         return moe_mlp(
-            *args, return_counts=return_counts, layer=layer, **kwargs
+            *args, return_counts=return_counts, layer=layer,
+            use_pallas=use_pallas and kernel_mesh is None, **kwargs
         )
     gate = x @ _w(lp, "w_gate", x.dtype)
     up = x @ _w(lp, "w_up", x.dtype)
@@ -840,7 +847,10 @@ def layer_apply(
     h = resid + attn
     resid = h
     x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, cfg.norm_zero_centered)
-    x = _mlp(cfg, lp, x, ep_mesh=ep_mesh, use_pallas=use_pallas)
+    x = _mlp(
+        cfg, lp, x, ep_mesh=ep_mesh, use_pallas=use_pallas,
+        kernel_mesh=kernel_mesh,
+    )
     if cfg.post_norms:
         x = rms_norm(
             x, lp["post_mlp_norm"], cfg.norm_eps, cfg.norm_zero_centered
@@ -1063,6 +1073,7 @@ def _mixed_trunk(
                 y, out["route"] = _mlp(
                     cfg, fp, x, ep_mesh=ep_mesh, use_pallas=use_pallas,
                     return_counts=True, expert_stacks=(experts, f_idx),
+                    kernel_mesh=kernel_mesh,
                 )
         else:
             with jax.named_scope("dense_ffn"):

@@ -30,9 +30,13 @@ import jax
 KERNELS = ("paged_decode", "flash_prefill", "kv_write")
 PATHS = ("lowered", "interpreted", "reference")
 
+#: counted like a kernel of ``KERNELS`` but read by its own accessor
+#: (``grouped_matmul_counts``): a routed model's alone
+GROUPED = "grouped_matmul"
+
 _lock = threading.Lock()
 _counts: Dict[str, Dict[str, int]] = {
-    k: dict.fromkeys(PATHS, 0) for k in KERNELS
+    k: dict.fromkeys(PATHS, 0) for k in KERNELS + (GROUPED,)
 }
 _xla_decode = 0
 
@@ -65,9 +69,22 @@ def xla_decode_count() -> int:
         return _xla_decode
 
 
+def grouped_matmul_counts() -> Dict[str, int]:
+    """Traces of the routed experts' grouped product, by path:
+    ``ops/pallas_gmm.grouped_matmul``'s body (``lowered`` /
+    ``interpreted``) and ``ops/moe._grouped`` where a ``use_pallas=True``
+    call stays on ``jax.lax.ragged_dot`` (``reference``). A count of its
+    own, not a name in ``KERNELS`` or a key of ``snapshot()``: a dense
+    model runs no routed layer, and a bring-up check that holds every
+    key of ``snapshot()`` to ``lowered > 0`` (chip_smoke.py, the
+    benchmark's numbers check) must keep passing there."""
+    with _lock:
+        return dict(_counts[GROUPED])
+
+
 def snapshot() -> Dict[str, Dict[str, int]]:
     with _lock:
-        return {k: dict(v) for k, v in _counts.items()}
+        return {k: dict(_counts[k]) for k in KERNELS}
 
 
 def shard_over_model(mesh, fn, operands: dict, specs: dict, out_specs):

@@ -24,16 +24,19 @@ ops/moe_ep.py; ``ModelConfig.router_*`` says which):
   are the chosen experts' unbiased scores, divided by their sum + 1e-6
   when ``renorm``, times ``scale``.
 
-The grouped products are ``jax.lax.ragged_dot`` unless the caller asks
-for the Pallas kernel (``use_pallas``, the engine's switch) and the
-shapes allow it (ops/pallas_gmm.py): one switch for every kernel. The
-shape decides, not a second switch: experts that arrive as the STACK of
-every routed layer (``layer``: a model whose layers are of several
-kinds) are read in place by ``ragged_dot`` at every size, whatever
-``use_pallas`` says. The Pallas kernel pads a row tile a group, and the
-stack's groups are every layer's experts (512 at 8 x 64), so it would
-want one layer's experts sliced out: a copy of them (793 MB a layer at
-64 x 3 x 2304 x 896 in bf16) every step (PERF.md section 6, PR 28 and 34).
+The grouped products (``_grouped``) are the Pallas kernel of
+ops/pallas_gmm.py where the caller runs its kernels (``use_pallas``, the
+engine's one switch, resolved once by the runner) and the shapes are on
+the 128-lane grid, and ``jax.lax.ragged_dot`` otherwise. Both read the
+experts where they lie: experts that arrive as the STACK of every routed
+layer (``layer``: a model whose layers are of several kinds) are never
+sliced (793 MB a layer at 64 x 3 x 2304 x 896 in bf16, every step). The
+kernel indexes the flat stack from ``layer * E`` and fetches the experts
+that have rows; ``ragged_dot`` is handed the stack with the other
+layers' groups empty. ``ops/lowering.grouped_matmul_counts()`` says
+which one a process traced: ``lowered`` / ``interpreted`` count the
+kernel's traces, ``reference`` the ``use_pallas`` calls whose shapes sent
+them to ``ragged_dot`` (PERF.md section 6, PR 28, 34 and 35).
 
 Expert parallelism shards the expert axis of ``we_*`` over the mesh
 "expert" axis; XLA turns the resulting gather/scatter into all-to-alls over
@@ -42,22 +45,32 @@ ICI (see parallel/sharding.py).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from . import lowering, pallas_gmm
 
 
 def _grouped(
     lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
-    use_pallas: bool = False,
+    use_pallas: bool = False, layer: "jax.Array | None" = None,
 ):
-    """Grouped GEMM: ``jax.lax.ragged_dot``, or the Pallas MXU kernel
-    when the engine runs its kernels, on a TPU, and the shapes allow
-    (ops/pallas_gmm.py)."""
-    if use_pallas and jax.default_backend() == "tpu":
-        from .pallas_gmm import grouped_matmul, grouped_matmul_supported
-
-        if grouped_matmul_supported(lhs, rhs):
-            return grouped_matmul(lhs, rhs, group_sizes)
+    """Grouped GEMM over rows sorted by group: the Pallas kernel when the
+    caller runs its kernels and the shapes allow (ops/pallas_gmm.py),
+    else ``jax.lax.ragged_dot``. With ``layer``, ``rhs`` is the flat
+    stack ``[L*E, ...]`` and ``group_sizes`` that layer's ``[E]``."""
+    if use_pallas:
+        if pallas_gmm.grouped_matmul_supported(lhs, rhs):
+            return pallas_gmm.grouped_matmul(lhs, rhs, group_sizes, layer)
+        lowering.record_reference(lowering.GROUPED)
+    if layer is not None:
+        # the whole stack, the other layers' groups empty
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((rhs.shape[0],), jnp.int32), group_sizes,
+            (layer * group_sizes.shape[0],),
+        )
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
@@ -149,12 +162,11 @@ def moe_mlp(
     With ``layer`` (scalar int32) the ``we_*`` are the STACKS of every
     routed layer, [L, E, H, F] / [L, E, F, H], and this layer's experts
     are groups ``layer*E .. (layer+1)*E`` of the stack seen flat,
-    [L*E, ...]: the grouped products take the whole stack with the
-    other layers' groups empty. A ``we[layer]`` slice would reach
-    ``ragged_dot`` as a copy of the layer's experts (1.2 GB a layer at
-    64 x 2048 x 1536 in bf16, every step): the TPU's grouped product
-    reads its right-hand side in place and cannot take a slice fused
-    into it."""
+    [L*E, ...]: the grouped products take the whole stack and the
+    layer's index (``_grouped``). A ``we[layer]`` slice would reach them
+    as a copy of the layer's experts (1.2 GB a layer at 64 x 2048 x 1536
+    in bf16, every step): a grouped product reads its right-hand side
+    in place and cannot take a slice fused into it."""
     B, T, H = x.shape
     E = router.shape[-1]
     N = B * T
@@ -165,8 +177,6 @@ def moe_mlp(
         # small E (the dense path): one layer's experts, sliced
         we_gate, we_up, we_down = we_gate[layer], we_up[layer], we_down[layer]
         layer = None
-    # the stack is ragged_dot's, in place (module docstring)
-    use_pallas = use_pallas and layer is None
 
     top_idx, probs, flat_expert, flat_token, flat_prob = _route(
         xt, router, router_b, top_k, **(route or {})
@@ -201,23 +211,22 @@ def moe_mlp(
     sorted_token = flat_token[order]
     sorted_prob = flat_prob[order]
     group_sizes = jnp.bincount(sorted_expert, length=E).astype(jnp.int32)
-    if layer is not None:
-        L = we_gate.shape[0]
-        group_sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((L * E,), jnp.int32), group_sizes, (layer * E,)
+    if layer is not None:  # the stacks seen flat: a bitcast
+        we_gate, we_up, we_down = (
+            w.reshape((-1,) + w.shape[2:]) for w in (we_gate, we_up, we_down)
         )
-        we_gate = we_gate.reshape((L * E,) + we_gate.shape[2:])
-        we_up = we_up.reshape((L * E,) + we_up.shape[2:])
-        we_down = we_down.reshape((L * E,) + we_down.shape[2:])
+    grouped = functools.partial(
+        _grouped, group_sizes=group_sizes, use_pallas=use_pallas, layer=layer
+    )
 
     lhs = xt[sorted_token]                                # [M, H]
-    g = _grouped(lhs, we_gate, group_sizes, use_pallas)   # [M, F]
-    u = _grouped(lhs, we_up, group_sizes, use_pallas)
+    g = grouped(lhs, we_gate)                             # [M, F]
+    u = grouped(lhs, we_up)
     if bias_gate is not None:
         g = g + bias_gate[sorted_expert].astype(g.dtype)
         u = u + bias_up[sorted_expert].astype(u.dtype)
     a, u = _act(g, u, activation)
-    y = _grouped(a * u, we_down, group_sizes, use_pallas)  # [M, H]
+    y = grouped(a * u, we_down)                           # [M, H]
     if bias_down is not None:
         y = y + bias_down[sorted_expert].astype(y.dtype)
     y = y * sorted_prob[:, None].astype(y.dtype)

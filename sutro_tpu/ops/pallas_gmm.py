@@ -1,27 +1,35 @@
-"""Pallas TPU kernel: grouped matmul for expert-parallel MoE.
+"""Pallas TPU kernel: the routed experts' grouped product.
 
-SURVEY §2.3 row 4 ("EP ... Pallas grouped-matmul kernel"): the MoE MLP's
-hot op is E independent GEMMs whose row counts are data-dependent
-(tokens routed per expert). ``jax.lax.ragged_dot`` is the always-correct
-fallback; this kernel is the MXU-native path:
+``out[i] = lhs[i] @ rhs[group(i)]`` for rows that arrive SORTED BY GROUP
+(ops/moe.py's ragged path, ops/moe_ep.py): the contract of
+``jax.lax.ragged_dot`` for group sizes that sum to the row count, which
+stays the fallback. What the kernel does that ``ragged_dot`` on the TPU
+does not (PERF.md section 6, PR 35):
 
-- lhs rows arrive SORTED BY EXPERT (ops/moe.py ragged path). Each group
-  is padded (inside jit, outside the kernel) to a multiple of the row
-  tile, so a row tile never spans two experts — the classic
-  "megablox-lite" layout. Padding waste is < E*BM rows of zeros, which
-  for prefill-sized token counts is small next to the E-fold waste of
-  the dense path.
-- grid ``(row_tiles, F // BF)``; each step multiplies one [BM, H] row
-  tile by its expert's [H, BF] weight block, selected via a
-  scalar-prefetched tile->expert map (the index map reads
-  ``tile_expert[m]`` — one compiled kernel serves any routing).
-- weights stream HBM->VMEM per tile via the BlockSpec pipeline; the MXU
-  sees dense [BM, H] x [H, BF] tiles with f32 accumulation.
+- **Rows stay where they are.** Row tiles of ``tm`` rows over ``[M, K]``;
+  the grid walks the (row tile, group) pairs that intersect, VISITS, from
+  scalar-prefetched metadata built from ``group_sizes`` inside the jit
+  (``_visits``; the megablox scheme). A tile that spans several groups is
+  visited once a group and stores that group's rows alone (a select on
+  the store); a visit multiplies only the sub-tiles of ``ts`` rows its
+  group has rows in, so a large tile re-reads few weights and computes
+  little more than a small one. No padded copy of ``lhs``, no gather
+  back.
+- **The expert stack is read in place.** ``group_sizes`` is ONE layer's
+  ``[E]``; ``rhs`` may be the flat stack of every routed layer's experts,
+  ``[L*E, K, N]``, and ``layer`` (a prefetched scalar) offsets the
+  block index by ``layer * E``. Only the experts a visit names are ever
+  fetched: an empty group costs nothing, another layer's experts are
+  never touched. ``layer=None`` is offset 0.
+- **One tile policy, from the static shape** (``_tiles``): each visit
+  streams one expert's ``[K, tn]`` block HBM->VMEM through the BlockSpec
+  pipeline (double-buffered; ``tn = N`` where the block fits, so the
+  fetch is one contiguous run) and multiplies in the operands' dtype
+  with float32 accumulation.
 
-Expert parallelism composes outside: the expert axis of ``rhs`` is
-sharded over the mesh "expert" axis and XLA inserts the all-to-alls
-(parallel/sharding.py); inside each shard this kernel runs the local
-experts' GEMMs.
+The grid is static, ``tiles + E - 1`` visits, the most there can be; the
+visits past the last real one repeat its block indices (no fetch) and
+compute nothing.
 """
 
 from __future__ import annotations
@@ -33,86 +41,157 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_M = 32
-BLOCK_F = 128
+from . import lowering
 
-
-def _gmm_kernel(tile_expert_ref, x_ref, w_ref, o_ref):
-    o_ref[...] = jax.lax.dot_general(
-        x_ref[...],
-        w_ref[0],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(o_ref.dtype)
+#: rows a visit multiplies at a time: the MXU's height
+SUB_ROWS = 128
+#: bytes one buffer of an expert's ``[K, tn]`` block may take in VMEM
+RHS_BLOCK_BYTES = 8 << 20
 
 
 def grouped_matmul_supported(lhs: jax.Array, rhs: jax.Array) -> bool:
-    """Static gate for the compiled TPU path (interpret mode bypasses).
-    Requires M large relative to E*BLOCK_M: the padded layout wastes up
-    to one row tile per expert, so decode-sized calls (M ~ B*top_k)
-    would pay ~E times the FLOPs of exact ragged_dot — prefill-sized
-    calls amortize the padding away."""
-    M, H = lhs.shape
-    E, _, F = rhs.shape
-    return H % 128 == 0 and F % BLOCK_F == 0 and M >= E * BLOCK_M
+    """Static gate: shapes on the 128-lane grid, rows in whole sublanes,
+    operands of one dtype the MXU takes."""
+    M, K = lhs.shape
+    N = rhs.shape[-1]
+    return (
+        K % 128 == 0 and N % 128 == 0 and M % 8 == 0 and M > 0
+        and lhs.dtype == rhs.dtype
+        and lhs.dtype in (jnp.bfloat16, jnp.float32)
+    )
+
+
+def _tiles(M: int, K: int, N: int, itemsize: int):
+    """``(tm, ts, tn)`` from the static shape. Decode sizes (a few rows a
+    group) take one sub-tile a tile: every touched expert is read once
+    and the visits stay near the groups. Prefill sizes take tiles of four
+    sub-tiles: the visits stay near ``tiles + groups`` and the weights'
+    re-read under 2x, while a visit computes only the sub-tiles its
+    group reaches."""
+    if M <= SUB_ROWS:
+        tm = ts = M
+    else:
+        ts = SUB_ROWS
+        tm = ts * (4 if M >= 4096 else 1)
+    tn = N
+    while K * tn * itemsize > RHS_BLOCK_BYTES and tn % 256 == 0:
+        tn //= 2
+    return tm, ts, tn
+
+
+def _visits(group_sizes: jax.Array, M: int, tm: int):
+    """The (row tile, group) pairs that intersect, in row order:
+    ``(offsets [E+1], group [V], tile [V], count [1])`` with
+    ``V = tiles + E - 1``. Entries past ``count`` repeat the last visit."""
+    E = group_sizes.shape[0]
+    tiles = pl.cdiv(M, tm)
+    V = tiles + E - 1
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    first = starts // tm
+    per_group = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    visit_end = jnp.cumsum(per_group)
+    count = visit_end[-1]
+    v = jnp.minimum(jnp.arange(V, dtype=jnp.int32), jnp.maximum(count - 1, 0))
+    group = jnp.minimum(
+        jnp.searchsorted(visit_end, v, side="right", method="compare_all"),
+        E - 1,
+    ).astype(jnp.int32)
+    tile = first[group] + v - (visit_end[group] - per_group[group])
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return offsets, group, tile, count[None]
+
+
+def _kernel(offsets, group, tile, count, base, x_ref, w_ref, o_ref, *, ts):
+    del base  # the index maps' (this layer's first group in the stack)
+    v = pl.program_id(1)
+    tm, tn = o_ref.shape
+
+    @pl.when(v < count[0])
+    def _visit():
+        g = group[v]
+        lo, hi = offsets[g], offsets[g + 1]
+        row0 = tile[v] * tm
+        for s in range(tm // ts):
+            r0 = row0 + s * ts
+            rows = slice(s * ts, (s + 1) * ts)
+
+            @pl.when(jnp.logical_and(r0 < hi, r0 + ts > lo))
+            def _sub_tile():
+                acc = jax.lax.dot_general(
+                    x_ref[rows, :], w_ref[...],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                row = r0 + jax.lax.broadcasted_iota(jnp.int32, (ts, tn), 0)
+                mine = jnp.logical_and(row >= lo, row < hi)
+                # the other groups' rows of this tile keep what their own
+                # visits stored (or will store over what lies here now)
+                o_ref[rows, :] = jnp.where(
+                    mine, acc, o_ref[rows, :].astype(jnp.float32)
+                ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def grouped_matmul(
-    lhs: jax.Array,          # [M, H] — rows sorted by group
-    rhs: jax.Array,          # [E, H, F]
+    lhs: jax.Array,          # [M, K]: rows sorted by group
+    rhs: jax.Array,          # [G, K, N], G >= (layer + 1) * E
     group_sizes: jax.Array,  # [E] int32, sum == M
+    layer: "jax.Array | None" = None,  # scalar int32: groups layer*E ..
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns [M, F] with ``out[i] = lhs[i] @ rhs[g(i)]`` where ``g(i)``
-    is row i's group. Same contract as ``jax.lax.ragged_dot``."""
-    M, H = lhs.shape
-    E, _, F = rhs.shape
-    BM = BLOCK_M
-
+    """Returns ``[M, N]`` with ``out[i] = lhs[i] @ rhs[layer*E + g(i)]``
+    where ``g(i)`` is row i's group by ``group_sizes``; every row belongs
+    to a group (the sizes sum to ``M``)."""
+    lowering.record_kernel(lowering.GROUPED, interpret=interpret)
+    M, K = lhs.shape
+    N = rhs.shape[-1]
+    E = group_sizes.shape[0]
+    tm, ts, tn = _tiles(M, K, N, lhs.dtype.itemsize)
     group_sizes = group_sizes.astype(jnp.int32)
-    padded = ((group_sizes + BM - 1) // BM) * BM
-    pcum = jnp.cumsum(padded)
-    poffs = pcum - padded                                  # padded starts
-    gcum = jnp.cumsum(group_sizes)
-    gstart = gcum - group_sizes                            # true starts
-
-    # scatter rows into the group-padded layout (zeros between groups)
-    MP = ((M + E * BM + BM - 1) // BM) * BM                # static bound
-    rows = jnp.arange(M, dtype=jnp.int32)
-    row_group = jnp.searchsorted(gcum, rows, side="right").astype(jnp.int32)
-    dest = poffs[row_group] + (rows - gstart[row_group])
-    xpad = jnp.zeros((MP, H), lhs.dtype).at[dest].set(lhs)
-
-    # tile -> expert map (tiles past the last group hit expert E-1 on
-    # zero rows; their output is never gathered back)
-    n_tiles = MP // BM
-    tile_start = jnp.arange(n_tiles, dtype=jnp.int32) * BM
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(pcum, tile_start, side="right").astype(jnp.int32),
-        E - 1,
+    offsets, group, tile, count = _visits(group_sizes, M, tm)
+    base = (
+        jnp.zeros((1,), jnp.int32) if layer is None
+        else (jnp.asarray(layer, jnp.int32) * E)[None]
     )
-
-    out = pl.pallas_call(
-        _gmm_kernel,
+    itemsize = lhs.dtype.itemsize
+    # two buffers a block, the float32 product and what the select reads
+    vmem = (
+        2 * (tm * K + K * tn + tm * tn) * itemsize + 3 * ts * tn * 4
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, ts=ts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_tiles, F // BLOCK_F),
+            num_scalar_prefetch=5,
+            # the column blocks outermost: an output block's visits are
+            # consecutive, so its groups' stores meet in VMEM
+            grid=(N // tn, group.shape[0]),
             in_specs=[
-                pl.BlockSpec((BM, H), lambda m, f, te: (m, 0)),
                 pl.BlockSpec(
-                    (1, H, BLOCK_F), lambda m, f, te: (te[m], 0, f)
+                    (tm, K), lambda n, v, o, g, t, c, b: (t[v], 0)
+                ),
+                pl.BlockSpec(
+                    (None, K, tn),
+                    lambda n, v, o, g, t, c, b: (b[0] + g[v], 0, n),
                 ),
             ],
             out_specs=pl.BlockSpec(
-                (BM, BLOCK_F), lambda m, f, te: (m, f)
+                (tm, tn), lambda n, v, o, g, t, c, b: (t[v], n)
             ),
         ),
-        out_shape=jax.ShapeDtypeStruct((MP, F), lhs.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(vmem + (8 << 20), 32 << 20),
         ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * M * K * N,
+            bytes_accessed=(
+                M * K * (N // tn) + group.shape[0] * K * N + M * N
+            ) * itemsize,
+            transcendentals=0,
+        ),
+        name="grouped_matmul",
         interpret=interpret,
-    )(tile_expert, xpad, rhs)
-    return out[dest]
+    )(offsets, group, tile, count, base, lhs, rhs)

@@ -25,7 +25,7 @@ from sutro_tpu.engine.scheduler import ContinuousBatcher, GenRequest, _Slot
 from sutro_tpu.engine.tokenizer import ByteTokenizer
 from sutro_tpu.models import transformer
 from sutro_tpu.models.configs import MODEL_CONFIGS
-from sutro_tpu.ops import moe
+from sutro_tpu.ops import lowering, moe
 
 MCFG = MODEL_CONFIGS["tiny-mellum2"]
 KEYS = json.loads(
@@ -228,25 +228,96 @@ def test_the_published_28_layers_are_one_group_of_seven_periods():
     assert not big.homogeneous and MODEL_CONFIGS["qwen3-4b"].homogeneous
 
 
-def test_the_expert_stack_is_read_in_place_whatever_the_kernel_switch():
-    """``moe_mlp(layer=)`` under ``use_pallas``: no slice of one layer's
-    experts (a copy of them a step), the same numbers, and the grouped
-    product over the flat stack with the other layers' groups empty."""
-    L, E, H, F, N = 3, 16, 32, 24, 10
+def _stacked_experts(H, F, L=3, E=16, N=10):
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     x = jax.random.normal(ks[0], (1, N, H))
     router = jax.random.normal(ks[1], (H, E))
     wg, wu = (jax.random.normal(k, (L, E, H, F)) * 0.1 for k in ks[2:4])
     wd = jax.random.normal(ks[4], (L, E, F, H)) * 0.1
+    return x, router, wg, wu, wd
 
-    def f(use_pallas, layer=1):
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_the_expert_stack_is_read_in_place_whatever_the_kernel_switch(
+    use_pallas, monkeypatch
+):
+    """``moe_mlp(layer=)`` under the kernel and under ``ragged_dot``
+    alike: no slice of one layer's experts (a copy of them a step), the
+    numbers of the sliced layer, and the grouped product over the flat
+    stack: indexed from ``layer * E`` by the kernel, the other layers'
+    groups empty for ``ragged_dot``."""
+    from tests.test_prefix_split import _force_interpret
+
+    _force_interpret(monkeypatch)  # the kernels interpreted, on the CPU
+    L, E, H, F = 3, 16, 128, 256
+    x, router, wg, wu, wd = _stacked_experts(H, F, L, E)
+
+    def f(layer=1):
         return moe.moe_mlp(
             x, router, wg, wu, wd, top_k=4, use_pallas=use_pallas,
             layer=jnp.int32(layer),
         )
 
+    before = lowering.grouped_matmul_counts()
     sliced = moe.moe_mlp(x, router, wg[1], wu[1], wd[1], top_k=4)
-    np.testing.assert_allclose(f(True), sliced, rtol=2e-5, atol=2e-5)
-    text = str(jax.make_jaxpr(lambda: f(True))())
-    assert "ragged_dot" in text and f"f32[{L * E},{H},{F}]" in text
+    np.testing.assert_allclose(f(), sliced, rtol=2e-4, atol=2e-4)
+    assert not np.allclose(f(2), sliced, atol=1e-3)  # the index is read
+    text = str(jax.make_jaxpr(f)())
+    assert f"f32[{L * E},{H},{F}]" in text
     assert f"f32[{E},{H},{F}]" not in text     # no layer's experts apart
+    assert ("pallas_call" in text) == use_pallas
+    assert ("ragged_dot" in text) == (not use_pallas)
+    # ragged_dot alone is handed a group a stacked expert
+    assert (f"i32[{L * E}]" in text) == (not use_pallas)
+    after = lowering.grouped_matmul_counts()
+    assert (after["interpreted"] > before["interpreted"]) == use_pallas
+    assert after["lowered"] == before["lowered"]
+    assert after["reference"] == before["reference"]
+
+
+def test_the_grouped_product_has_one_gate_the_switch_and_the_shapes():
+    """What keeps a cell where it is: ``use_pallas=False`` traces
+    ``ragged_dot`` and no kernel (the LFM2 cell's file says so), and
+    under ``use_pallas=True`` shapes off the 128-lane grid fall back
+    and are counted as ``reference``; the three attention / K-V keys of
+    ``snapshot()`` never see the routed product."""
+    x, router, wg, wu, wd = _stacked_experts(32, 24)
+
+    def f(use_pallas):
+        return moe.moe_mlp(
+            x, router, wg, wu, wd, top_k=4, use_pallas=use_pallas,
+            layer=jnp.int32(1),
+        )
+
+    snap, before = lowering.snapshot(), lowering.grouped_matmul_counts()
+    off = str(jax.make_jaxpr(lambda: f(False))())
+    assert "ragged_dot" in off and "pallas_call" not in off
+    assert lowering.grouped_matmul_counts() == before
+    fell_back = str(jax.make_jaxpr(lambda: f(True))())
+    assert "ragged_dot" in fell_back and "pallas_call" not in fell_back
+    after = lowering.grouped_matmul_counts()
+    assert after["reference"] == before["reference"] + 3   # gate, up, down
+    assert after["lowered"] == before["lowered"]
+    assert after["interpreted"] == before["interpreted"]
+    np.testing.assert_allclose(f(True), f(False), rtol=1e-6, atol=1e-6)
+    assert lowering.snapshot() == snap
+
+
+def test_a_mesh_that_shards_the_operands_keeps_the_routed_product_on_ragged_dot():
+    """``_mlp`` is handed the runner's ``kernel_mesh``: where a mesh
+    shards the experts outside a ``shard_map`` the call stays on
+    ``ragged_dot`` (XLA cannot partition a Mosaic call), decided by what
+    it is handed and by no model's name."""
+    E, H, F = 16, 128, 128
+    x, router, wg, wu, wd = _stacked_experts(H, F, 2, E, N=12)  # top-2
+    lp = {"router": router, "we_gate": wg[0], "we_up": wu[0], "we_down": wd[0]}
+
+    def text(kernel_mesh):
+        return str(jax.make_jaxpr(lambda: transformer._mlp(
+            MODEL_CONFIGS["tiny-moe"], lp, x, use_pallas=True,
+            kernel_mesh=kernel_mesh,
+        ))())
+
+    sharded, whole = text(object()), text(None)
+    assert "ragged_dot" in sharded and "pallas_call" not in sharded
+    assert "pallas_call" in whole and "ragged_dot" not in whole

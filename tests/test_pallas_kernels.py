@@ -259,30 +259,120 @@ def test_paged_decode_with_window_buffer(window):
 # grouped matmul (MoE expert GEMM)
 # ---------------------------------------------------------------------------
 
+from sutro_tpu.ops import pallas_gmm  # noqa: E402
 from sutro_tpu.ops.pallas_gmm import grouped_matmul  # noqa: E402
 
 
-@pytest.mark.parametrize(
-    "sizes",
-    [
-        [100, 28, 0, 128],       # ragged + one empty group
-        [64, 64, 64, 64],        # tile-aligned
-        [256, 0, 0, 0],          # single hot expert
-        [1, 2, 3, 250],          # tiny groups
-    ],
-)
-def test_grouped_matmul_matches_ragged_dot(sizes):
-    rng = np.random.default_rng(13)
+def _decode_sizes():
+    """64 groups of 0-40 rows, several empty, and a run of four tiny
+    groups inside one tile of 128 rows so that it spans five."""
+    sizes = np.random.default_rng(5).integers(1, 41, 64)
+    sizes[[3, 4, 5, 17, 40, 63]] = 0
+    sizes[:10] = [90, 2, 0, 0, 0, 0, 3, 1, 2, 120]
+    sizes[62] += -sizes.sum() % 8
+    return [int(n) for n in sizes]
+
+
+_GMM_CASES = {
+    # the four cases the padded layout was held to
+    "ragged-one-empty": dict(sizes=[100, 28, 0, 128]),
+    "tile-aligned": dict(sizes=[64, 64, 64, 64]),
+    "single-hot-expert": dict(sizes=[256, 0, 0, 0]),
+    "tiny-groups": dict(sizes=[1, 2, 3, 250]),
+    # a decode step's: a tile spans five groups, groups span tiles
+    "decode-shaped": dict(sizes=_decode_sizes()),
+    "decode-shaped-bf16": dict(sizes=_decode_sizes(), dtype=jnp.bfloat16),
+    # tiles of 512 rows in sub-tiles of 128; the smallest group is 3 rows
+    # and one sub-tile holds rows of no visit's group but its own
+    "prefill-shaped": dict(sizes=[0, 3, 1000, 5, 0, 2000, 1088, 0]),
+    "prefill-shaped-bf16": dict(
+        sizes=[700, 9, 1300, 0, 2087], dtype=jnp.bfloat16
+    ),
+    # the last tile overhangs the rows; fewer rows than a tile
+    "rows-off-the-tile": dict(sizes=[100, 0, 60, 40]),
+    "fewer-rows-than-a-tile": dict(sizes=[0, 5, 0, 11]),
+    # the flat stack of three layers, read at the middle one, every
+    # other layer's experts NaN: indexed, not scanned
+    "stack-middle-layer": dict(sizes=_decode_sizes(), layers=3, layer=1),
+    "stack-last-layer-prefill": dict(
+        sizes=[0, 3, 1000, 5, 0, 2000, 1088, 0], layers=2, layer=1
+    ),
+}
+
+
+def _gmm_case(sizes, dtype=jnp.float32, layers=None, layer=None, seed=13):
+    rng = np.random.default_rng(seed)
     E, H, F = len(sizes), 128, 256
-    M = sum(sizes)
-    lhs = jnp.asarray(rng.standard_normal((M, H)), jnp.float32)
-    rhs = jnp.asarray(rng.standard_normal((E, H, F)), jnp.float32)
-    gs = jnp.asarray(sizes, jnp.int32)
-    want = jax.lax.ragged_dot(lhs, rhs, gs)
-    got = grouped_matmul(lhs, rhs, gs, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4
+    lhs = jnp.asarray(rng.standard_normal((sum(sizes), H)), dtype)
+    rhs = jnp.asarray(rng.standard_normal((E, H, F)), dtype)
+    stack, at = rhs, None
+    if layers:
+        stack = jnp.full((layers * E, H, F), jnp.nan, dtype)
+        stack = stack.at[layer * E:(layer + 1) * E].set(rhs)
+        at = jnp.int32(layer)
+    return lhs, rhs, stack, jnp.asarray(sizes, jnp.int32), at
+
+
+@pytest.mark.parametrize("case", sorted(_GMM_CASES))
+def test_grouped_matmul_matches_ragged_dot(case):
+    lhs, rhs, stack, gs, layer = _gmm_case(**_GMM_CASES[case])
+    want = np.asarray(jax.lax.ragged_dot(lhs, rhs, gs), np.float32)
+    got = np.asarray(
+        grouped_matmul(lhs, stack, gs, layer, interpret=True), np.float32
     )
+    assert np.isfinite(got).all()
+    tol = 2e-4 if lhs.dtype == jnp.float32 else 2e-2 * np.abs(want).max()
+    np.testing.assert_allclose(got, want, atol=tol, rtol=2e-4)
+
+
+@pytest.mark.parametrize("rows", [40, 640])
+def test_grouped_matmul_reads_the_expert_a_row_is_sent_to(rows):
+    """All rows to each expert in turn, that expert's product alone
+    against a plain matmul: one wrong expert among many is below the
+    sight of a rule over whole logits (PERF.md section 7 row 18)."""
+    rng = np.random.default_rng(3)
+    E, H, F = 6, 128, 128
+    lhs = jnp.asarray(rng.standard_normal((rows, H)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((2 * E, H, F)), jnp.float32)
+    for layer in (None, 1):
+        for e in range(E):
+            gs = jnp.zeros((E,), jnp.int32).at[e].set(rows)
+            got = grouped_matmul(
+                lhs, rhs, gs, None if layer is None else jnp.int32(layer),
+                interpret=True,
+            )
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(lhs @ rhs[(layer or 0) * E + e]),
+                atol=2e-4, rtol=2e-4, err_msg=f"layer {layer} expert {e}",
+            )
+
+
+def test_grouped_matmul_visits_each_touched_group_once_at_decode_sizes():
+    """The grid's metadata: at a decode step's sizes every group with
+    rows is visited once a tile it reaches (its expert fetched once when
+    it lies in one tile), empty groups never, in row order."""
+    sizes = _decode_sizes()
+    M, tm = sum(sizes), 128
+    offsets, group, tile, count = (
+        np.asarray(a) for a in pallas_gmm._visits(
+            jnp.asarray(sizes, jnp.int32), M, tm
+        )
+    )
+    n = int(count[0])
+    ends = np.cumsum(sizes)
+    want = [
+        (g, t) for g, size in enumerate(sizes) if size
+        for t in range((ends[g] - size) // tm, (ends[g] - 1) // tm + 1)
+    ]
+    assert list(zip(group[:n], tile[:n])) == want
+    assert len(group) == -(-M // tm) + len(sizes) - 1 >= n
+    # what is left of the static grid repeats the last visit: no fetch
+    assert (group[n:] == group[n - 1]).all() and (tile[n:] == tile[n - 1]).all()
+    assert list(offsets) == [0] + list(ends)
+    assert pallas_gmm._tiles(512, 2304, 896, 2) == (128, 128, 896)
+    assert pallas_gmm._tiles(16384, 2304, 896, 2) == (512, 128, 896)
+    # an expert's block over the VMEM budget is split by columns
+    assert pallas_gmm._tiles(256, 4096, 1536, 2) == (128, 128, 768)
 
 
 # ---------------------------------------------------------------------------
@@ -705,6 +795,36 @@ def test_prefix_carry_supported_flags():
     assert not prefix_carry_supported(
         q, good, k_scale=jnp.zeros((2, 8, 8), jnp.float32)
     )
+
+
+def test_the_grouped_product_is_counted_beside_the_kernels_not_among_them():
+    """``lowering.grouped_matmul_counts()`` says which grouped product a
+    process traced. It is no key of ``snapshot()``: the benchmark's
+    numbers check and chip_smoke.py hold every ``use_pallas`` engine to
+    ``lowered > 0`` for every key there, and a dense model runs no routed
+    layer. ``device_report`` shows it."""
+    from sutro_tpu.engine.config import EngineConfig
+    from sutro_tpu.engine.runner import device_report
+    from sutro_tpu.ops import lowering
+
+    snap, before = lowering.snapshot(), lowering.grouped_matmul_counts()
+    assert set(before) == {"lowered", "interpreted", "reference"}
+    lhs = jnp.ones((24, 128), jnp.float32)       # a shape no test traces
+    rhs = jnp.ones((3, 128, 384), jnp.float32)
+    gs = jnp.asarray([8, 0, 16], jnp.int32)
+    grouped_matmul(lhs, rhs, gs, interpret=True)
+    jax.make_jaxpr(grouped_matmul)(lhs, rhs, gs)   # traced for Mosaic
+    after = lowering.grouped_matmul_counts()
+    assert after == {
+        "lowered": before["lowered"] + 1,
+        "interpreted": before["interpreted"] + 1,
+        "reference": before["reference"],
+    }
+    assert lowering.snapshot() == snap
+    assert set(snap) == {"paged_decode", "flash_prefill", "kv_write"}
+    report = device_report(EngineConfig(use_pallas=False))
+    assert report["grouped_matmul"] == after
+    assert report["kernel_paths"] == snap
 
 
 def test_lowering_counters_tell_the_three_paths_apart():
