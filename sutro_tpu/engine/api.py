@@ -2571,6 +2571,21 @@ class _GenSession:
             # FSM fast-forward: scaffold tokens committed through
             # parallel verify forwards instead of per-step windows
             perf["fastforward"] = {"forced_tokens": ff}
+        row_steps = self.ctx.stats.get("row_steps", 0)
+        if row_steps:
+            # what this job's rows got of the decode dispatches they
+            # rode (OBSERVABILITY.md "What a decode dispatch yields"):
+            # row-steps = committed + the lost, a row by construction
+            lost = {
+                k[len("lost_"):]: v
+                for k, v in self.ctx.stats.items()
+                if k.startswith("lost_")
+            }
+            perf["decode_yield"] = {
+                "row_steps": row_steps,
+                "committed": row_steps - sum(lost.values()),
+                "lost": lost,
+            }
         if self._tel_on:
             self.jtel.set("input_tokens", self.input_tokens)
             self.jtel.set("output_tokens", output_tokens)

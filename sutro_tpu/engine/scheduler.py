@@ -315,7 +315,13 @@ class JobCtx:
     prefix_saved: int = 0
     prefix_paid: int = 0
     stats: Dict[str, int] = dataclasses.field(
-        default_factory=lambda: {"in": 0, "out": 0, "rows": 0}
+        default_factory=lambda: {
+            "in": 0, "out": 0, "rows": 0,
+            # the job's rows' part of the decode dispatches: row-steps,
+            # and beside it ``lost_<reason>`` keys as they occur
+            # (_lose; engine/api.py writes perf["decode_yield"])
+            "row_steps": 0,
+        }
     )
     # forensics trace (telemetry/traces.py): the propagated per-request
     # (gateway-assigned) or per-job (scheduler-assigned) trace id;
@@ -422,6 +428,19 @@ class _DecodeBatch(NamedTuple):
     top_k: np.ndarray         # [B] int32
     row_seeds: np.ndarray     # [B] int32
     facts: _DecodeFacts
+
+
+def _lose(
+    lost: Dict[str, int], ctx: Optional[JobCtx], reason: str, n: int
+) -> None:
+    """``n`` row-steps of one row of a decode dispatch committed
+    nothing: into the dispatch's tally ``lost`` and the record of the
+    row's job. Called once a row and reason, never a token."""
+    if n:
+        lost[reason] = lost.get(reason, 0) + n
+        if ctx is not None:
+            key = "lost_" + reason
+            ctx.stats[key] = ctx.stats.get(key, 0) + n
 
 
 class ContinuousBatcher:
@@ -642,6 +661,28 @@ class ContinuousBatcher:
         )
         # tokens committed by the accept loops (the accept span's attr)
         self._n_accepted = 0
+        # what the dispatch just accepted yielded: (row-steps, tokens
+        # committed, {reason: row-steps lost}), left by _close_accept
+        # for _after_step, which counts it under the iteration's path
+        # (OBSERVABILITY.md "What a decode dispatch yields")
+        self._yield: Tuple[int, int, Dict[str, int]] = (0, 0, {})
+
+    def _close_accept(
+        self, n0: int, row_steps: int, lost: Dict[str, int]
+    ) -> None:
+        """The end of a dispatch's accept loop. Its yield (row-steps,
+        the tokens committed since ``n0``, the row-steps ``_lose``
+        counted by reason) goes onto the ``accept`` span, and waits for
+        ``_after_step`` to count it under the iteration's path.
+        Row-steps = tokens + the lost, on every path."""
+        n = self._n_accepted - n0
+        self._yield = (row_steps, n, lost)
+        if lost:
+            self.timer.enter(
+                "accept", tokens=n, row_steps=row_steps, lost=lost
+            )
+        else:
+            self.timer.enter("accept", tokens=n, row_steps=row_steps)
 
     def _tel_opened(self, phase: Optional[str], t0: float) -> None:
         """The phase this thread is in NOW, for whoever snapshots the
@@ -1568,26 +1609,38 @@ class ContinuousBatcher:
         tm.enter("accept")
         n0 = self._n_accepted
         gens = list(self._gen)
+        # every row was dispatched the forward's static width C
+        lost: Dict[str, int] = {}
         for i in active:
             s = self.slots[i]
             ctx = s.job
+            if ctx is not None:
+                ctx.stats["row_steps"] += C
             if i in plans:
                 draft, cands = plans[i]
+                P = len(cands)
+                _lose(lost, ctx, "plan_short", C - P)
                 jumped = 0  # draft-matching accepts only: the final
                 #             free-choice/diverged token is an ordinary
                 #             masked step, not a jump — counting it
                 #             would overstate ff_forced
-                for j in range(len(cands)):
+                for j in range(P):
                     tok = int(ct[i, j])
                     matched = j < len(draft) and tok == draft[j]
                     if matched:
                         jumped += 1
-                    if self._accept_token(i, tok, float(cl[i, j])):
+                    rc = self._accept_token(i, tok, float(cl[i, j]))
+                    if rc == 2:
+                        _lose(lost, ctx, "failed", P - j)
+                        break
+                    if rc:
+                        _lose(lost, ctx, "finished", P - 1 - j)
                         break
                     if not matched:
                         # diverged from the draft (or the plan's final
                         # free position): later positions are
                         # conditioned on the draft, not on this token
+                        _lose(lost, ctx, "diverged", P - 1 - j)
                         break
                 self.ff_forced += jumped
                 if ctx is not None and jumped:
@@ -1596,6 +1649,7 @@ class ContinuousBatcher:
                     )
                 continue
             # unplanned rider: plain greedy step at position 0
+            _lose(lost, ctx, "no_plan", C - 1)
             tok = int(pt[i, 0])
             c = s.req.constraint
             if c is not None:
@@ -1604,10 +1658,12 @@ class ContinuousBatcher:
                     # next iteration's window opens with this row's
                     # FSM-masked step (allowed0 recovery)
                     self._needs_mask.add(i)
+                    _lose(lost, ctx, "rejected", 1)
                     continue
-            self._accept_token(i, tok, float(pl[i, 0]))
+            if self._accept_token(i, tok, float(pl[i, 0])) == 2:
+                _lose(lost, ctx, "failed", 1)
         self._commit_verified(active, past_len, gens)
-        tm.enter("accept", tokens=self._n_accepted - n0)
+        self._close_accept(n0, C * len(active), lost)
         return True
 
     def _ff_fail_backoff(self) -> None:
@@ -2017,7 +2073,6 @@ class ContinuousBatcher:
             self._fail_slot(i, e)
             return 2
         s.last_token = tok
-        self._n_accepted += 1
         if s.job is not None:
             s.job.stats["out"] += 1
         self._deliver_token(s, tok, float(logp))
@@ -2026,6 +2081,9 @@ class ContinuousBatcher:
         except Exception as e:  # noqa: BLE001 — row isolation (FSM state)
             self._fail_slot(i, e)
             return 2
+        # a token of a row that failed on it is not committed: whoever
+        # is returned 2 counts this position among the row-steps lost
+        self._n_accepted += 1
         if done:
             if release:
                 self._emit(i)
@@ -2400,7 +2458,7 @@ class ContinuousBatcher:
         self, pipe: List[Any], b: _DecodeBatch, refill: bool
     ) -> str:
         """Keep ``decode_lookahead`` fused windows in flight (entries of
-        ``pipe``: toks_dev, logps_dev, active, gens, K, route_dev) and
+        ``pipe``: toks_dev, logps_dev, active, gens, K, route_dev, jobs) and
         fetch the oldest. At a depth of one the window dispatched here
         is the one fetched: dispatch, fetch and accept in one
         iteration. Without ``refill`` the pipe only drains."""
@@ -2420,7 +2478,7 @@ class ContinuousBatcher:
         but not yet processed, per slot — only windows whose (slot, gen)
         snapshot still matches count."""
         proj = np.zeros((self.B,), np.int32)
-        for _, _, w_active, w_gens, wK, _ in pipe:
+        for _, _, w_active, w_gens, wK, _, _ in pipe:
             for idx, i in enumerate(w_active):
                 if self._gen[i] == w_gens[idx]:
                     proj[i] += wK
@@ -2460,7 +2518,7 @@ class ContinuousBatcher:
         anywhere on this path."""
         active = b.active
         if pipe:
-            prev_toks, _, p_active, p_gens, _, _ = pipe[-1]
+            prev_toks, _, p_active, p_gens, _, _, _ = pipe[-1]
             chained = {
                 i
                 for idx, i in enumerate(p_active)
@@ -2497,6 +2555,9 @@ class ContinuousBatcher:
                 # the window's routing counts, on the device beside its
                 # tokens (None for a model that does not count them)
                 getattr(self.runner, "window_route", None),
+                # whose rows they were: a window that finds its row
+                # gone counts its steps lost to that row's job
+                [self.slots[i].job for i in active],
             )
         )
 
@@ -2512,7 +2573,7 @@ class ContinuousBatcher:
         (round-5 host-overhead profile: the per-token Python loop cost
         ~26 ms per B=128 window, 2× the device window itself); rows with
         any per-token machinery keep the exact per-token loop."""
-        toks_dev, logps_dev, w_active, w_gens, wK, route_dev = entry
+        toks_dev, logps_dev, w_active, w_gens, wK, route_dev, w_jobs = entry
         with self.timer.time("decode"):
             toks = np.asarray(toks_dev)
             logps = np.asarray(logps_dev)
@@ -2522,8 +2583,14 @@ class ContinuousBatcher:
         n0 = self._n_accepted
         plain: List[int] = []
         rest: List[int] = []
+        # every slot of w_active was dispatched wK
+        lost: Dict[str, int] = {}
         for idx, i in enumerate(w_active):
+            ctx = w_jobs[idx]
+            if ctx is not None:
+                ctx.stats["row_steps"] += wK
             if self._gen[i] != w_gens[idx] or self.slots[i] is None:
+                _lose(lost, ctx, "stale", wK)
                 continue
             s = self.slots[i]
             r = s.req
@@ -2536,15 +2603,21 @@ class ContinuousBatcher:
             else:
                 rest.append(i)
         if plain:
-            self._accept_plain_window(plain, toks, logps, wK)
+            self._accept_plain_window(plain, toks, logps, wK, lost)
         for j in range(wK):
             for i in rest:
-                if self.slots[i] is None:
+                s = self.slots[i]
+                if s is None:
                     continue  # finished earlier in this window
-                self._accept_token(
+                ctx = s.job
+                rc = self._accept_token(
                     i, int(toks[j][i]), float(logps[j][i])
                 )
-        self.timer.enter("accept", tokens=self._n_accepted - n0)
+                if rc == 2:
+                    _lose(lost, ctx, "failed", wK - j)
+                elif rc:
+                    _lose(lost, ctx, "finished", wK - 1 - j)
+        self._close_accept(n0, wK * len(w_active), lost)
 
     def _trace_resume(self, ctx: JobCtx, req: GenRequest) -> None:
         """Close a preempt_suspend pair: the row a preemption suspended
@@ -2559,7 +2632,7 @@ class ContinuousBatcher:
 
     def _accept_plain_window(
         self, idxs: List[int], toks: np.ndarray, logps: np.ndarray,
-        wK: int,
+        wK: int, lost: Dict[str, int],
     ) -> None:
         """Accept a whole window for plain rows with one numpy pass per
         row instead of wK interpreter iterations. Semantics mirror
@@ -2567,7 +2640,8 @@ class ContinuousBatcher:
         including the first trigger among stop-id ("stop"),
         max_new_tokens ("length"), and context limit ("length") — at
         the same position the stop-id check wins, as in the per-token
-        order."""
+        order. The row-steps of these rows that committed nothing go
+        into ``lost`` (behind a row's end; of rows that failed)."""
         ii = np.asarray(idxs, np.int64)
         tw = toks[:, ii]                             # [K, n]
         lw = logps[:, ii].astype(np.float64)         # [K, n]
@@ -2588,6 +2662,7 @@ class ContinuousBatcher:
                         job=s.job.job_id if s.job is not None else None,
                     )
                 except Exception as e:  # noqa: BLE001 — row isolation
+                    _lose(lost, s.job, "failed", wK)
                     self._fail_slot(i, e)
                     continue
             # first k (tokens accepted) at which the row finishes —
@@ -2603,6 +2678,7 @@ class ContinuousBatcher:
                 # still land here): finish NOW with zero tokens taken —
                 # the old max(..., 1) silently accepted one token past
                 # the cap
+                _lose(lost, s.job, "finished", wK)
                 self._emit(i)
                 continue
             n_take = min(limit, wK)
@@ -2623,6 +2699,7 @@ class ContinuousBatcher:
                             s, int(col_t[k]), float(lcol[k])
                         )
             if limit <= wK:
+                _lose(lost, s.job, "finished", wK - n_take)
                 self._emit(i)
 
     # ------------------------------------------------------------------
@@ -2665,11 +2742,18 @@ class ContinuousBatcher:
         n0 = self._n_accepted
         accepted = np.zeros((self.B,), np.int32)
         finished: List[int] = []
+        # every active row was dispatched K
+        lost: Dict[str, int] = {}
         for i in active:
             s = self.slots[i]
             if s is None:
-                continue  # failed during mask assembly
+                # failed during mask assembly, its job gone with it
+                _lose(lost, None, "failed", K)
+                continue
             c = s.req.constraint
+            ctx = s.job
+            if ctx is not None:
+                ctx.stats["row_steps"] += K
             for j in range(K):
                 tok = int(toks_w[j][i])
                 # a flagged row's step-0 token was chosen UNDER its FSM
@@ -2685,6 +2769,7 @@ class ContinuousBatcher:
                         tok_ok = self._token_ok(c, tok, rem)
                     except Exception as e:  # noqa: BLE001 — row isolation
                         self._fail_slot(i, e)
+                        _lose(lost, ctx, "failed", K - j)
                         break
                     if not tok_ok:
                         # this row's NEXT window opens with its
@@ -2692,17 +2777,21 @@ class ContinuousBatcher:
                         # scaffold token; other rows keep full window
                         # cadence
                         self._needs_mask.add(i)
+                        _lose(lost, ctx, "rejected", K - j)
                         break
                 rc = self._accept_token(
                     i, tok, float(logps_w[j][i]), release=False,
                 )
                 if rc == 2:
-                    break  # row failed: token NOT committed
+                    # row failed: token NOT committed
+                    _lose(lost, ctx, "failed", K - j)
+                    break
                 accepted[i] += 1
                 if rc:
                     finished.append(i)
+                    _lose(lost, ctx, "finished", K - 1 - j)
                     break
-        tm.enter("accept", tokens=self._n_accepted - n0)
+        self._close_accept(n0, K * len(active), lost)
         # pages are still reserved for every row (releases were
         # deferred), so the accepted K/V lands safely
         with tm.time("decode"):
@@ -2793,11 +2882,19 @@ class ContinuousBatcher:
         self._needs_mask.clear()
         self.timer.enter("accept")
         n0 = self._n_accepted
+        lost: Dict[str, int] = {}  # every active row was dispatched one
         for i in active:
-            if self.slots[i] is None:
-                continue  # failed during mask assembly
-            self._accept_token(i, int(toks[i]), float(logps[i]))
-        self.timer.enter("accept", tokens=self._n_accepted - n0)
+            s = self.slots[i]
+            if s is None:
+                # failed during mask assembly, its job gone with it
+                _lose(lost, None, "failed", 1)
+                continue
+            ctx = s.job
+            if ctx is not None:
+                ctx.stats["row_steps"] += 1
+            if self._accept_token(i, int(toks[i]), float(logps[i])) == 2:
+                _lose(lost, ctx, "failed", 1)
+        self._close_accept(n0, len(active), lost)
         return "single"
 
     # ------------------------------------------------------------------
@@ -3020,11 +3117,20 @@ class ContinuousBatcher:
     def _after_step(
         self, live: List[JobCtx], on_job_done, path: str, n_active: int
     ) -> None:
-        """The tail every decode path shares: count the iteration by
-        the path it took, finish drained jobs, tick progress streams."""
+        """The tail every decode path shares: count the iteration, and
+        what the dispatch it accepted yielded, by the path it took;
+        finish drained jobs, tick progress streams."""
         if self._tel_on:
+            row_steps, tokens, lost = self._yield
+            self._yield = (0, 0, {})
             telemetry.SCHED_ITERATIONS_TOTAL.inc(1.0, path)
             telemetry.SCHED_DISPATCH_ROWS_TOTAL.inc(float(n_active))
+            telemetry.SCHED_ROW_STEPS_TOTAL.inc(float(row_steps), path)
+            telemetry.SCHED_TOKENS_COMMITTED_TOTAL.inc(float(tokens), path)
+            for reason, n in lost.items():
+                telemetry.SCHED_ROW_STEPS_LOST_TOTAL.inc(
+                    float(n), path, reason
+                )
         self.timer.enter("emit")
         self._sweep_done(live, on_job_done)
         for ctx in live:

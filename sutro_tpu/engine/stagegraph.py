@@ -647,7 +647,6 @@ class StageGraphRunner:
         st.outbox.append((rid, st.collected[rid]))
         st.n_quarantined += 1
         if self._tel_on:
-            telemetry.STAGE_ROWS_TOTAL.inc(1.0, st.name)
             telemetry.ROWS_TOTAL.inc(1.0, "quarantined")
 
     def _drop_row(self, st: _StageState, rid: int, src: str) -> None:
@@ -811,8 +810,6 @@ class StageGraphRunner:
                 st.n_quarantined += 1
             if st.t_first is None:
                 st.t_first = time.monotonic() - self.t0
-            if self._tel_on:
-                telemetry.STAGE_ROWS_TOTAL.inc(1.0, st.name)
             st.since_feed += 1
             if st.since_feed >= self.feed_every:
                 st.since_feed = 0
@@ -851,8 +848,6 @@ class StageGraphRunner:
         }
         st.complete = True
         st.t_done = time.monotonic() - self.t0
-        if self._tel_on:
-            telemetry.STAGE_ROWS_TOTAL.inc(float(len(rows)), st.name)
         self._after_stage_complete(st)
 
     def _after_stage_complete(self, st: _StageState) -> None:

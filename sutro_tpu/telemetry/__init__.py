@@ -118,14 +118,6 @@ ROWS_TOTAL = REGISTRY.counter(
     labels=("outcome",),  # ok | quarantined | cancelled
     max_series=8,
 )
-STAGE_ROWS_TOTAL = REGISTRY.counter(
-    "sutro_stage_rows_total",
-    "Stage-graph rows completed per stage (engine/stagegraph.py); "
-    "labelled by the submit payload's stage name",
-    labels=("stage",),
-    unit="rows",
-    max_series=32,
-)
 TOKENS_TOTAL = REGISTRY.counter(
     "sutro_tokens_total",
     "Tokens processed by direction (accounted at job finalize)",
@@ -485,6 +477,36 @@ SCHED_DISPATCH_ROWS_TOTAL = REGISTRY.counter(
     "Active decode rows summed over the iterations whose path is not "
     "idle (over iterations x decode_batch_size: batch occupancy)",
     unit="rows",
+)
+# what the decode dispatches yield (OBSERVABILITY.md "What a decode
+# dispatch yields"): a row-step is one position of one live row in one
+# dispatch at which a token could have been committed; per path,
+# row-steps = tokens committed + row-steps lost, summed over reasons
+SCHED_ROW_STEPS_TOTAL = REGISTRY.counter(
+    "sutro_sched_row_steps_total",
+    "Row-steps of the decode dispatches accepted, by path: the window's "
+    "steps a row (pipelined, window), the verify forward's width "
+    "constrain_fastforward + 1 a row (fastforward), one a row (single)",
+    labels=("path",),  # pipelined | window | fastforward | single
+    unit="row_steps",
+    max_series=8,
+)
+SCHED_TOKENS_COMMITTED_TOTAL = REGISTRY.counter(
+    "sutro_sched_tokens_committed_total",
+    "Tokens the accept loops committed to live rows, by path (the "
+    "prefill-sampled first token of a row is not a decode dispatch's)",
+    labels=("path",),
+    unit="tokens",
+    max_series=8,
+)
+SCHED_ROW_STEPS_LOST_TOTAL = REGISTRY.counter(
+    "sutro_sched_row_steps_lost_total",
+    "Row-steps that committed nothing, by path and reason",
+    # stale | finished | failed (any path); rejected (window, and a
+    # fastforward rider); plan_short | diverged | no_plan (fastforward)
+    labels=("path", "reason"),
+    unit="row_steps",
+    max_series=32,
 )
 
 # Span names the engine emits — OBSERVABILITY.md's span schema section

@@ -1,0 +1,346 @@
+"""What a decode dispatch yields (OBSERVABILITY.md): on each of the four
+decode paths, row-steps dispatched = tokens committed + row-steps lost,
+summed over reasons: in the registry's counters, on the ``accept``
+spans and in the job's record; and counting changes no token."""
+
+import itertools
+import time
+
+import numpy as np
+import pytest
+
+from sutro_tpu import telemetry
+from sutro_tpu.engine.config import EngineConfig
+from sutro_tpu.engine.constrain import schema_constraint_factory
+from sutro_tpu.engine.runner import ModelRunner
+from sutro_tpu.engine.scheduler import (
+    ContinuousBatcher,
+    GenRequest,
+    JobCtx,
+)
+from sutro_tpu.models.configs import MODEL_CONFIGS
+
+# a forced scaffold (the verify forward commits it), an enum leaf, then
+# free text, where the window's unmasked samples are refused
+SCHEMA = {
+    "type": "object",
+    "properties": {
+        "classification_result": {
+            "type": "string", "enum": ["positive", "negative"],
+        },
+        "note": {"type": "string", "maxLength": 12},
+    },
+    "required": ["classification_result", "note"],
+}
+PLAIN_TEXTS = ["first row", "second", "third one"]
+PATHS = ("pipelined", "window", "fastforward", "single")
+COUNTERS = (
+    "sutro_sched_row_steps_total",
+    "sutro_sched_tokens_committed_total",
+    "sutro_sched_row_steps_lost_total",
+)
+ITERATIONS = "sutro_sched_iterations_total"
+# path -> the row-steps a row of one dispatch (decode_multi_step 8,
+# constrain_fastforward 16 + 1)
+WIDTH = {"pipelined": 8, "window": 8, "fastforward": 17, "single": 1}
+
+
+def _requests(tok, scenario):
+    def req(i, text, **kw):
+        return GenRequest(
+            row_id=i, prompt_ids=np.array(tok.encode(text), np.int32), **kw
+        )
+
+    if scenario == "plain":
+        # three plain rows and one with a stop sequence that never
+        # comes (the per-token loop), with pages for several windows
+        # each; _run makes one row's early token a stop id, so with two
+        # windows in flight the window behind that row's end finds the
+        # row gone
+        rows = [
+            req(i, t, max_new_tokens=43, temperature=0.0)
+            for i, t in enumerate(PLAIN_TEXTS)
+        ]
+        return rows + [
+            req(3, "the fourth", max_new_tokens=43, temperature=0.0,
+                stop_seqs=[b"\xfe\xff\xfe"])
+        ]
+    factory = schema_constraint_factory(SCHEMA, tok)
+    if scenario == "scaffold":
+        rows = [
+            req(i, t, max_new_tokens=80, temperature=0.0,
+                constraint=factory())
+            for i, t in enumerate(["first row", "second", "third one"])
+        ]
+        # a plain greedy row rides the verify forward with no plan
+        return rows + [
+            req(100, "plain rider", max_new_tokens=12, temperature=0.0)
+        ]
+    assert scenario == "sampled"
+    return [
+        req(i, t, max_new_tokens=80, temperature=0.8, constraint=factory())
+        for i, t in enumerate(["first row", "second"])
+    ]
+
+
+_RUN_IDS = itertools.count()
+_RUNS = {}
+
+
+def _series(snap, name):
+    return dict((snap.get(name) or {}).get("series", {}))
+
+
+def _by_path(gained):
+    """{path: {iterations, row_steps, committed, lost: {reason: n}}} of
+    what the registry's series gained over a run."""
+    out = {}
+    for key, name in (("iterations", ITERATIONS), ("row_steps", COUNTERS[0]),
+                      ("committed", COUNTERS[1])):
+        for path, n in gained[name].items():
+            out.setdefault(path, {"iterations": 0, "row_steps": 0,
+                                  "committed": 0, "lost": {}})[key] = n
+    for k, n in gained[COUNTERS[2]].items():
+        path, reason = k.split(",")
+        out[path]["lost"][reason] = n
+    return out
+
+
+def _lost_total(tallies):
+    total = {}
+    for y in tallies:
+        for reason, n in y["lost"].items():
+            total[reason] = total.get(reason, 0) + n
+    return total
+
+
+def _early_stop(tok):
+    """A token the greedy model gives ONE of the plain rows inside its
+    first window and no row before it: as a stop id it ends that row
+    there while the others decode on. Learned from a run without it
+    (greedy decoding repeats itself to the token)."""
+    if "stop" not in _RUNS:
+        out = [r.token_ids for _, r in sorted(_run(tok, "plain")[1].items())]
+        _RUNS["stop"] = next(
+            t for row in out for t in row[2:7]
+            if all(t not in other[:24] for other in out if other is not row)
+            and t not in row[:2]
+        )
+    return _RUNS["stop"]
+
+
+def _run(tok, scenario, tel=True, extra_stops=()):
+    """One run of a scenario on a fresh batcher: the job's own tallies
+    (``JobCtx.stats``), its results, what the registry's counters gained
+    by path (``_by_path``) and the accept spans."""
+    was = telemetry.enabled()
+    telemetry.set_enabled(tel)
+    try:
+        ecfg = EngineConfig(
+            kv_page_size=8, max_pages_per_seq=32, max_model_len=256,
+            decode_batch_size=4, use_pallas=False, param_dtype="float32",
+            activation_dtype="float32", decode_multi_step=8,
+            decode_lookahead=2, constrain_fastforward=16,
+        )
+        b = ContinuousBatcher(
+            ModelRunner(MODEL_CONFIGS["tiny-dense"], ecfg),
+            stop_ids=list(tok.stop_ids()) + list(extra_stops),
+            token_bytes=tok.token_bytes, seed=11,
+        )
+        before = telemetry.REGISTRY.collect()
+        job_id = f"yield-{scenario}-{next(_RUN_IDS)}"
+        res = {}
+        ctx = JobCtx(
+            job_id=job_id, pending=_requests(tok, scenario),
+            on_result=lambda r: res.__setitem__(r.row_id, r),
+        )
+        assert b.run_multi(
+            [ctx], on_job_done=lambda c, outcome: None
+        ) == "completed"
+        after = telemetry.REGISTRY.collect()
+        gained = {}
+        for name in COUNTERS + (ITERATIONS,):
+            a, z = _series(before, name), _series(after, name)
+            gained[name] = {
+                k: int(z[k] - a.get(k, 0)) for k in z if z[k] != a.get(k, 0)
+            }
+        spans = [
+            s for s in telemetry.RECORDER.snapshot(job_id)
+            if s["name"] == "accept"
+        ]
+        return dict(ctx.stats), res, _by_path(gained), spans
+    finally:
+        telemetry.set_enabled(was)
+
+
+def _stops(tok, scenario):
+    return (_early_stop(tok),) if scenario == "plain" else ()
+
+
+def _scenario(tok, scenario):
+    if scenario not in _RUNS:
+        _RUNS[scenario] = _run(
+            tok, scenario, extra_stops=_stops(tok, scenario)
+        )
+    return _RUNS[scenario]
+
+
+# path -> the scenario that takes it, and the reasons it must show
+CASES = {
+    "pipelined": ("plain", {"finished", "stale"}),
+    "window": ("scaffold", {"rejected"}),
+    "fastforward": ("scaffold", {"no_plan"}),
+    "single": ("sampled", set()),
+}
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_row_steps_are_the_committed_and_the_lost(path, byte_tok):
+    scenario, reasons = CASES[path]
+    _, _, by_path, _ = _scenario(byte_tok, scenario)
+    y = by_path[path]
+    assert y["row_steps"] > 0 and y["committed"] > 0
+    assert y["row_steps"] == y["committed"] + sum(y["lost"].values()), y
+    assert reasons <= set(y["lost"]), y
+    assert all(n > 0 for n in y["lost"].values()), y
+    if path == "fastforward":
+        # a plan shorter than the forward, or one the model left
+        assert {"plan_short", "diverged"} & set(y["lost"]), y
+    if path == "single":
+        assert y["lost"] == {} and y["row_steps"] == y["committed"]
+    # only decode paths were counted (an idle iteration yields nothing)
+    assert {p for p, t in by_path.items() if t["row_steps"]} <= set(PATHS)
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_a_paths_row_steps_are_its_dispatches_widths(path, byte_tok):
+    """Row-steps come a dispatch's width a row: whole widths, and no
+    more than the path's iterations could have dispatched to a full
+    batch of four."""
+    scenario, _ = CASES[path]
+    y = _scenario(byte_tok, scenario)[2][path]
+    assert y["row_steps"] % WIDTH[path] == 0, y
+    assert 0 < y["row_steps"] <= y["iterations"] * 4 * WIDTH[path], y
+
+
+@pytest.mark.parametrize("scenario", ["plain", "scaffold", "sampled"])
+def test_committed_tokens_are_the_tokens_the_results_hold(
+    scenario, byte_tok
+):
+    _, res, by_path, _ = _scenario(byte_tok, scenario)
+    stops = set(byte_tok.stop_ids())
+    held = 0
+    for r in res.values():
+        # a result leaves out the stop id its row ended on, and holds
+        # the first token, which the prefill sampled
+        ended_on_stop = (
+            r.finish_reason == "stop"
+            and not (r.token_ids and r.token_ids[-1] in stops)
+        )
+        held += len(r.token_ids) + int(ended_on_stop) - 1
+    assert sum(y["committed"] for y in by_path.values()) == held
+
+
+@pytest.mark.parametrize("scenario", ["plain", "scaffold", "sampled"])
+def test_a_jobs_own_tallies_are_the_registrys_when_it_runs_alone(
+    scenario, byte_tok
+):
+    stats, _, by_path, _ = _scenario(byte_tok, scenario)
+    want = by_path.values()
+    assert stats["row_steps"] == sum(y["row_steps"] for y in want)
+    assert {
+        k[len("lost_"):]: v for k, v in stats.items()
+        if k.startswith("lost_")
+    } == _lost_total(want)
+
+
+@pytest.mark.parametrize("scenario", ["plain", "scaffold", "sampled"])
+def test_counting_changes_no_token(scenario, byte_tok):
+    stats_on, on, _, _ = _scenario(byte_tok, scenario)
+    stats_off, off, gained, spans = _run(
+        byte_tok, scenario, tel=False, extra_stops=_stops(byte_tok, scenario)
+    )
+    assert {
+        i: (tuple(r.token_ids), r.finish_reason) for i, r in on.items()
+    } == {
+        i: (tuple(r.token_ids), r.finish_reason) for i, r in off.items()
+    }
+    # off: nothing reaches the registry or the recorder; the job's
+    # record is still kept, and is the same
+    assert gained == {} and spans == []
+    assert stats_off == stats_on
+
+
+@pytest.mark.parametrize("scenario", ["plain", "scaffold", "sampled"])
+def test_the_accept_spans_carry_each_dispatchs_yield(scenario, byte_tok):
+    _, _, by_path, spans = _scenario(byte_tok, scenario)
+    carried = [
+        s["attrs"] for s in spans if "row_steps" in s.get("attrs", {})
+    ]
+    assert carried
+    want = by_path.values()
+    assert sum(a["row_steps"] for a in carried) == sum(
+        y["row_steps"] for y in want
+    )
+    assert sum(a["tokens"] for a in carried) == sum(
+        y["committed"] for y in want
+    )
+    for a in carried:
+        assert a["row_steps"] == a["tokens"] + sum(
+            a.get("lost", {}).values()
+        ), a
+        assert all(n > 0 for n in a.get("lost", {}).values()), a
+    assert _lost_total(
+        {"lost": a.get("lost", {})} for a in carried
+    ) == _lost_total(want)
+
+
+def test_a_failed_row_loses_its_steps_and_the_sum_still_holds(byte_tok):
+    """A row whose decode raises (the fault plan's ``row.decode``) is
+    released with its token unrecorded: its steps are ``failed``."""
+    from sutro_tpu.engine import faults
+
+    faults.configure("row.decode:error:times=1")
+    try:
+        stats, res, by_path, _ = _run(byte_tok, "plain")
+    finally:
+        faults.clear()
+    y = by_path["pipelined"]
+    assert y["lost"].get("failed", 0) > 0, y
+    assert y["row_steps"] == y["committed"] + sum(y["lost"].values()), y
+    assert stats["lost_failed"] == y["lost"]["failed"]
+    assert any(r.finish_reason == "error" for r in res.values())
+
+
+def test_the_job_record_carries_its_rows_yield(
+    tiny_ecfg, byte_tok, tmp_path, monkeypatch
+):
+    """``perf["decode_yield"]`` of a constrained job: why it is slow,
+    without a profiler; ``fastforward.forced_tokens`` stays beside it."""
+    monkeypatch.setenv("SUTRO_HOME", str(tmp_path))
+    from sutro_tpu.engine.api import LocalEngine
+    from sutro_tpu.interfaces import JobStatus
+
+    eng = LocalEngine(tiny_ecfg)
+    try:
+        job_id = eng.submit_batch_inference(
+            {"model": "tiny-dense", "inputs": ["a review", "another"],
+             "output_schema": SCHEMA,
+             "sampling_params": {"max_new_tokens": 80, "temperature": 0.0}}
+        )
+        deadline = time.monotonic() + 180
+        while time.monotonic() < deadline:
+            if JobStatus(eng.job_status(job_id)).is_terminal():
+                break
+            time.sleep(0.2)
+        rec = eng.get_job(job_id)
+    finally:
+        eng.close(timeout=10)
+    assert rec["status"] == "SUCCEEDED", rec.get("failure_reason")
+    y = rec["perf"]["decode_yield"]
+    assert y["row_steps"] == y["committed"] + sum(y["lost"].values())
+    assert y["lost"].get("rejected", 0) > 0, y
+    assert 0 < y["committed"] < y["row_steps"]
+    assert rec["perf"]["fastforward"]["forced_tokens"] > 0
+    # every token of the job but its rows' first came from a dispatch
+    assert y["committed"] <= rec["output_tokens"] + 2

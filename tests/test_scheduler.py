@@ -752,7 +752,10 @@ def test_plain_window_zero_budget_finishes_immediately(byte_tok):
     wK = 4
     toks = np.full((wK, 4), 9, np.int32)
     logps = np.full((wK, 4), -1.0, np.float32)
-    b._accept_plain_window([1], toks, logps, wK)
+    lost = {}
+    b._accept_plain_window([1], toks, logps, wK, lost)
+    # the whole window's steps committed nothing, for the row's job too
+    assert lost == {"finished": wK} and ctx.stats["lost_finished"] == wK
     assert 7 in results, "row must finish"
     assert len(results[7].token_ids) == 3  # nothing accepted past cap
     assert results[7].finish_reason == "length"
