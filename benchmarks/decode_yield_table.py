@@ -9,10 +9,15 @@ window, no profiler) and prints, from the registry's deltas over the
 window: per decode path the iterations, the row-steps dispatched, the
 tokens committed and the row-steps lost by reason (OBSERVABILITY.md
 "What a decode dispatch yields"), and whether row-steps = committed +
-lost holds on each path; for a constrained cell the share of the window
-and verify row-steps behind a refused token and the share of the tokens
-that verify forwards committed (descriptive numbers, no benchmark
-metric); the cell's end-to-end metrics; and the tokens
+lost holds on each path; for a constrained cell the share of the
+constrained paths' row-steps (window, verify forward and masked single
+step) behind a refused token, the share of the tokens that verify
+forwards committed, and the share of unmasked tokens the FSMs accepted
+as the scheduler saw it when it chose window or masked step (the
+``unmasked_ok`` attr of the window's ``decode_window`` spans: first,
+last, least, most, and how many dispatches chose from it) (descriptive
+numbers, no benchmark metric); the cell's end-to-end metrics; and the
+tokens
 the accept loops committed beside the tokens the progress stream
 counted inside the window (``Reading.window_output_tokens``, the
 divisor of the per-token metrics) and the burst rate. A builder's tool, outside the
@@ -77,6 +82,23 @@ def share(part, whole):
     return 100.0 * part / whole if whole else None
 
 
+def unmasked_ok(r):
+    """What the scheduler chose window or masked step from, dispatch by
+    dispatch over the window: the ``unmasked_ok`` attr of the
+    ``decode_window`` spans (None where the program has none)."""
+    seen = [
+        (t0, attrs["unmasked_ok"])
+        for name, t0, _t1, attrs in (r.window_spans or r.spans)
+        if name == "decode_window" and r.t0 <= t0 < r.t1
+        and "unmasked_ok" in (attrs or {})
+    ]
+    if not seen:
+        return None
+    vals = [v for _t, v in sorted(seen)]
+    return {"dispatches": len(vals), "first": vals[0], "last": vals[-1],
+            "min": min(vals), "max": max(vals)}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -116,8 +138,14 @@ def main(argv=None) -> int:
     by_path = table(r)
     committed = sum(p["committed"] for p in by_path.values())
     steps = sum(p["row_steps"] for p in by_path.values())
-    # the two constrained paths: the speculative window, the verify forward
-    spec = [by_path[p] for p in ("window", "fastforward") if p in by_path]
+    # what a constrained batch takes: the speculative window, the verify
+    # forward, and the masked single step where windows are being refused
+    estimate = unmasked_ok(r)
+    spec = [
+        by_path[p] for p in ("window", "fastforward") if p in by_path
+    ]
+    if spec and "single" in by_path:
+        spec.append(by_path["single"])
     spec_steps = sum(p["row_steps"] for p in spec)
     doc = {
         "workload": cell["name"], "seed": args.seed, "window_s": r.seconds,
@@ -133,6 +161,12 @@ def main(argv=None) -> int:
         "fastforward_committed_share": share(
             by_path.get("fastforward", {}).get("committed", 0), committed
         ),
+        "single_committed_share": share(
+            by_path.get("single", {}).get("committed", 0), committed
+        ),
+        # the share of unmasked tokens the FSMs accepted, as the
+        # scheduler's rule read it at each dispatch of the window
+        "unmasked_ok": estimate,
         # the divisor of fsm_host_us_per_token and the burst rate: the
         # progress stream's ticks clipped to the window
         "window_output_tokens": r.window_output_tokens(),

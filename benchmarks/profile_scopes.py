@@ -8,7 +8,11 @@ SCOPE, at a benchmark configuration's own sizes.
 Builds the configuration's runner (seeded weights, as the cell does),
 prefills the decode batch one row at a time with prompts cycling through
 ``--prompts``, runs a few fused decode windows, and traces the last
-prefill of each bucket and two windows with the JAX profiler. Each device
+prefill of each bucket and two windows with the JAX profiler; with
+``--masked-steps N`` also N FSM-masked greedy single steps
+(``decode_step(allowed=...)``, each row's mask 256 ids of the vocabulary,
+what a byte tokenizer's FSM allows: the step a constrained greedy batch
+takes while its windows are being refused, ``_decode_jit``). Each device
 op's self time goes to the first ``jax.named_scope`` of the mixed walk in
 its HLO ``op_name`` (``moe_ffn``, ``attn_window``, ``attn_full``,
 ``attn_mixer``, ``conv_mixer``, ``mamba_mixer``, ``dense_ffn``; ``other``
@@ -69,6 +73,7 @@ def main() -> None:
     ap.add_argument("--config", required=True)
     ap.add_argument("--prompts", type=int, nargs="+", default=[1100, 1800, 2600, 3400])
     ap.add_argument("--windows", type=int, default=4)
+    ap.add_argument("--masked-steps", type=int, default=0)
     ap.add_argument("--cpu", action="store_true")
     args = ap.parse_args()
 
@@ -97,7 +102,7 @@ def main() -> None:
     steps = ecfg.decode_multi_step
     rng = np.random.default_rng(0)
     lens = np.array([args.prompts[b % len(args.prompts)] for b in range(B)])
-    need = -(-(lens + steps * (args.windows + 1)) // PS)
+    need = -(-(lens + steps * (args.windows + 1) + args.masked_steps + 2) // PS)
     assert need.max() <= MP and 1 + need.sum() <= runner.alloc_pages, (
         need.max(), MP, need.sum(), runner.alloc_pages
     )
@@ -127,14 +132,31 @@ def main() -> None:
         )
         last, past = np.asarray(toks[-1]), past + steps
         runner.release_window_behind(tables, past)  # the scheduler's part
+    allowed = np.zeros((B, mcfg.vocab_size), bool)
+    allowed[:, :256] = True
+    greedy = np.zeros((B,), np.float32)
+
+    def masked_step(i):
+        nonlocal last, past
+        toks, _ = runner.decode_step(
+            last, past, tables, jax.random.PRNGKey(1000 + i), greedy, top_p,
+            allowed=allowed,
+        )
+        last, past = np.asarray(toks), past + 1
+        runner.release_window_behind(tables, past)
+
     window(0)
     window(1)
+    for i in range(min(args.masked_steps, 2)):  # compile outside the trace
+        masked_step(i)
     tracedir = tempfile.mkdtemp(prefix="scopes-trace-")
     with jax.profiler.trace(tracedir):
         for b in sorted(traced_rows):
             runner.prefill(prompts[b], tables[b])
         for i in range(2, args.windows):
             window(i)
+        for i in range(args.masked_steps):
+            masked_step(2 + i)
     xplane = sorted(Path(tracedir).glob("plugins/profile/*/*.xplane.pb"))[-1]
     from_dump = scopes_from_dump(dump)
     data = ProfileData.from_file(str(xplane))

@@ -2586,6 +2586,15 @@ class _GenSession:
                 "committed": row_steps - sum(lost.values()),
                 "lost": lost,
             }
+            asked = self.ctx.stats.get("unmasked_asked", 0)
+            if asked:
+                # of the rows' UNMASKED greedy tokens that were held
+                # against their FSMs, those accepted: what the
+                # scheduler chose window or masked step from
+                perf["decode_yield"]["unmasked"] = {
+                    "asked": asked,
+                    "ok": self.ctx.stats.get("unmasked_ok", 0),
+                }
         if self._tel_on:
             self.jtel.set("input_tokens", self.input_tokens)
             self.jtel.set("output_tokens", output_tokens)

@@ -356,6 +356,8 @@ class ModelRunner:
         # (fetched, needed) K/V pages of the decode dispatches since the
         # last take_kv_pages
         self._kv_pages = None
+        # the last masked decode_step's take_unmasked_ok
+        self._unmasked_ok = None
         self.cache = alloc_cache(
             mcfg, ecfg, self.num_pages, dtype=dtype,
             sharding=self._cache_sharding,
@@ -1279,7 +1281,24 @@ class ModelRunner:
             allowed=allowed, row_seeds=row_seeds,
         )
         logp = cumulative_logprob(step_logits, tok)
-        return tok, logp, cache, self._route_stats(k)
+        unmasked_ok = None
+        if allowed is not None:
+            # whether each row's UNMASKED argmax lay inside its mask:
+            # what a speculative window's verify would have found, for
+            # the scheduler's choice between window and masked step
+            # (take_unmasked_ok)
+            top = jnp.argmax(step_logits, axis=-1)
+            unmasked_ok = jnp.take_along_axis(
+                allowed, top[:, None], axis=1
+            )[:, 0]
+        return tok, logp, cache, self._route_stats(k), unmasked_ok
+
+    def take_unmasked_ok(self) -> Optional[np.ndarray]:
+        """[B] bool of the last masked ``decode_step``: whether a row's
+        unmasked argmax was a token its mask allowed; None after a step
+        without masks."""
+        ok, self._unmasked_ok = self._unmasked_ok, None
+        return ok
 
     def decode_step(
         self,
@@ -1317,7 +1336,7 @@ class ModelRunner:
         self._count_state_commit("window")
         self._count_kv_pages(past_len, page_table, 1, pfx)
         self._bind_window(page_table, past_len, np.ones((B,), np.int32))
-        tok, logp, self.cache, self._route_dev = self._decode_jit(
+        tok, logp, self.cache, self._route_dev, ok = self._decode_jit(
             self.params,
             self.cache,
             jnp.asarray(last_tokens[:, None], jnp.int32),
@@ -1334,6 +1353,7 @@ class ModelRunner:
             penalties,
             self._pfx_jnp(pfx),
         )
+        self._unmasked_ok = None if ok is None else np.asarray(ok)
         return np.asarray(tok), np.asarray(logp)
 
     @staticmethod

@@ -413,6 +413,44 @@ class _DecodeFacts(NamedTuple):
     room: int                 # least reserved page room of a row, in steps
     wrapped: bool             # the runner has a ring or pipeline wrapper
     #                           (sp or pp > 1): no verify forward there
+    constrained: float = 1.0  # the share of the rows that decode under
+    #                           an FSM (a window keeps a plain row's every
+    #                           token)
+    unmasked_ok: float = 1.0  # the share of the constrained greedy rows'
+    #                           UNMASKED tokens their FSMs accept, as the
+    #                           last few dispatches saw it (_observe)
+    stepping: bool = False    # those rows' last dispatch was a masked step
+
+
+# The constrained greedy batch, window or masked step (_choose_path). A
+# window of K steps commits a constrained row E = (1 - p^K) / (1 - p)
+# tokens (its masked first step, then unmasked tokens until the FSM refuses
+# one, each accepted with probability p) and a plain row K, for K steps of
+# the device and one round of host work; a masked step commits every row 1
+# for one step and one round. In units of a window's device step, a
+# masked step costs _STEP_COST, device and host together, and a window
+# K + _WINDOW_HOST: the window's tokens a second over the step's are
+# E * _STEP_COST / (K + _WINDOW_HOST). The two constants are the classify
+# cell's (PERF.md section 6, PR 37: a window step 20.8 ms and 16 ms of
+# host a window; a masked step 29.7 ms of device, which writes K/V a step
+# and samples under the mask, and 13 ms of host): with every row
+# constrained and K = 8 the line lies at E ~ 4.3, p ~ 0.81. Only a batch
+# near the line feels them: at p = 0 a window is worth a quarter of the
+# steps it displaces, at p = 1 nearly twice.
+_STEP_COST = 2.05
+_WINDOW_HOST = 0.77
+# a batch near the line stays where it is: the other path has to be worth
+# a tenth more (p under ~0.78 leaves the windows, over ~0.84 returns)
+_SWITCH_GAIN = 1.1
+
+
+def _window_gain(p: float, K: int, constrained: float = 1.0) -> float:
+    """A speculative window's tokens a second over a masked step's, for
+    a batch of which the share ``constrained`` decodes under FSMs that
+    accept an unmasked token with probability ``p``."""
+    E = float(K) if p >= 1.0 else (1.0 - p ** K) / (1.0 - p)
+    E = constrained * E + (1.0 - constrained) * K
+    return E * _STEP_COST / (K + _WINDOW_HOST)
 
 
 class _DecodeBatch(NamedTuple):
@@ -594,14 +632,25 @@ class ContinuousBatcher:
         # single-steps; only non-greedy constrained batches still fall
         # back to the masked single-step path
         self._needs_mask: set = set()
+        # what _choose_path's window-or-step rule reads: the running
+        # share of the constrained greedy rows' unmasked tokens that
+        # their FSMs accepted (_observe; before any observation 1.0,
+        # which says "window"), this dispatch's (accepted, asked) on the
+        # way into it, and whether those rows' last dispatch was a
+        # masked step
+        self._unmasked_ok = 1.0
+        self._asked = [0, 0]
+        self._stepping = False
         # penalty id-buffer growth events already logged (power-of-two K)
         self._pk_grown: set = set()
         # FSM fast-forward ("jump decoding"): forced scaffold tokens
         # committed through parallel verify forwards instead of
         # step-by-step windows. The probe backoff bounds the O(B x V)
-        # singleton scan on batches sitting in free-text regions.
+        # singleton scan on batches sitting in free-text regions; it
+        # counts DISPATCHES (_ff_wait: those still to go by without a
+        # probe), windows and masked steps alike
         self.ff_forced = 0
-        self._ff_probe_step = 0
+        self._ff_wait = 0
         self._ff_backoff = 0
         # shared-prefix KV reuse (one per run; see _setup_prefix)
         self._prefix: Optional[_SharedPrefix] = None
@@ -677,12 +726,35 @@ class ContinuousBatcher:
         Row-steps = tokens + the lost, on every path."""
         n = self._n_accepted - n0
         self._yield = (row_steps, n, lost)
+        ok, asked = self._asked
+        if asked:
+            # a memory of a few dispatches: half of it the newest one
+            self._unmasked_ok = 0.5 * (self._unmasked_ok + ok / asked)
+            self._asked = [0, 0]
         if lost:
             self.timer.enter(
                 "accept", tokens=n, row_steps=row_steps, lost=lost
             )
         else:
             self.timer.enter("accept", tokens=n, row_steps=row_steps)
+
+    def _observe(self, ctx: Optional[JobCtx], ok: int, asked: int) -> None:
+        """``asked`` unmasked greedy tokens of one constrained row of
+        this dispatch were held against its FSM (a window's speculative
+        positions up to its first refusal; a masked step's unmasked
+        argmax, which the step's program looked up in the mask it
+        held; a verify forward's plain argmax at each planned position
+        it reached, against the position's candidates), and ``ok`` of
+        them were valid: into this dispatch's observation, which
+        ``_close_accept`` folds into the estimate, and into the record
+        of the row's job."""
+        if asked:
+            self._asked[0] += ok
+            self._asked[1] += asked
+            if ctx is not None:
+                st = ctx.stats
+                st["unmasked_ok"] = st.get("unmasked_ok", 0) + ok
+                st["unmasked_asked"] = st.get("unmasked_asked", 0) + asked
 
     def _tel_opened(self, phase: Optional[str], t0: float) -> None:
         """The phase this thread is in NOW, for whoever snapshots the
@@ -1518,7 +1590,7 @@ class ContinuousBatcher:
         self._record_token(s, first, float(logps[0]))
         self._deliver_token(s, first, float(logps[0]))
 
-    def _fastforward_step(self, active, last, past_len, table) -> bool:
+    def _fastforward_step(self, b: _DecodeBatch) -> bool:
         """FSM fast-forward ("jump decoding") via masked-candidate
         verification: each constrained row PLANS a jump along its
         forced byte path (fsm.plan_fastforward — purely functional, no
@@ -1534,6 +1606,13 @@ class ContinuousBatcher:
         greedy steps (constrained ones verified by ``token_allowed``,
         the speculative window's rule).
 
+        The forward also returns each position's PLAIN argmax: along
+        the accepted draft that is the row's unmasked token, and the
+        position's candidates are its mask, so a verify forward says
+        for free what a window's verify would have found there
+        (``_observe``): a job's opening scaffold tells the scheduler
+        whether windows will pay before it has spent one.
+
         Exact vs the every-step-masked path: each accepted token is the
         argmax over the same budget-filtered mask, conditioned on the
         same accepted prefix; acceptance stops at the first draft
@@ -1542,8 +1621,12 @@ class ContinuousBatcher:
         distribution). Plans never mutate FSMs, so returning False
         leaves no trace."""
         FF = getattr(self.ecfg, "constrain_fastforward", 0)
-        if FF <= 0 or self._step < self._ff_probe_step:
+        if FF <= 0:
             return False
+        if self._ff_wait:
+            self._ff_wait -= 1
+            return False
+        active, last, past_len, table = b.active, b.last, b.past_len, b.table
         tm = self.timer
         # the plan walk, failed probes included, up to the dispatch
         tm.enter("fsm_plan", rows=len(active), planned=0, engaged=False)
@@ -1600,6 +1683,7 @@ class ContinuousBatcher:
             for j, cs in enumerate(cands):
                 cand[i, j, : len(cs)] = cs
                 cand_n[i, j] = len(cs)
+        self._note_window(b, self.ecfg.decode_multi_step)
         with self.timer.time("decode"):
             ct, cl, pt, pl = self.runner.verify_candidates(
                 np.asarray(last, np.int32), drafts, dlens,
@@ -1624,8 +1708,15 @@ class ContinuousBatcher:
                 #             free-choice/diverged token is an ordinary
                 #             masked step, not a jump — counting it
                 #             would overstate ff_forced
+                # the forward's plain argmax at a position reached
+                # along the draft is the row's UNMASKED token there,
+                # and the position's candidates are its mask: what a
+                # window's verify would have found, for free
+                plain_ok = asked = 0
                 for j in range(P):
                     tok = int(ct[i, j])
+                    asked += 1
+                    plain_ok += int(pt[i, j]) in cands[j]
                     matched = j < len(draft) and tok == draft[j]
                     if matched:
                         jumped += 1
@@ -1642,6 +1733,7 @@ class ContinuousBatcher:
                         # conditioned on the draft, not on this token
                         _lose(lost, ctx, "diverged", P - 1 - j)
                         break
+                self._observe(ctx, plain_ok, asked)
                 self.ff_forced += jumped
                 if ctx is not None and jumped:
                     ctx.stats["ff_forced"] = (
@@ -1654,9 +1746,12 @@ class ContinuousBatcher:
             c = s.req.constraint
             if c is not None:
                 rem = self._remaining(s.req, len(s.out_ids), s.pos)
-                if not self._token_ok(c, tok, rem):
-                    # next iteration's window opens with this row's
-                    # FSM-masked step (allowed0 recovery)
+                tok_ok = self._token_ok(c, tok, rem)
+                self._observe(ctx, int(tok_ok), 1)
+                if not tok_ok:
+                    # its next dispatch opens with this row's
+                    # FSM-masked step (allowed0 recovery, or the
+                    # masked single step)
                     self._needs_mask.add(i)
                     _lose(lost, ctx, "rejected", 1)
                     continue
@@ -1667,13 +1762,15 @@ class ContinuousBatcher:
         return True
 
     def _ff_fail_backoff(self) -> None:
-        """Exponential re-probe backoff (2..32 window lengths) after a
-        disengaged fast-forward scan: free-text regions (non-singleton
-        masks) would otherwise pay the O(rows x V) mask scan before
-        every window dispatch."""
-        KS = max(self.ecfg.decode_multi_step, 1)
-        self._ff_backoff = min(max(self._ff_backoff * 2, 2 * KS), 32 * KS)
-        self._ff_probe_step = self._step + self._ff_backoff
+        """Exponential re-probe backoff after a disengaged fast-forward
+        scan: the next probe 2, then 4, ... 32 dispatches on. Free-text
+        regions (non-singleton masks) would otherwise pay the
+        O(rows x V) mask scan before every dispatch. In dispatches, not
+        device steps: a batch on masked single steps (one step a
+        dispatch) finds its rows' closing scaffold as soon as a batch
+        on windows of K does."""
+        self._ff_backoff = min(max(self._ff_backoff * 2, 2), 32)
+        self._ff_wait = self._ff_backoff - 1
 
     def _split_pfx(self, active):
         """Operands for Hydragen-style split decode (Pallas path,
@@ -2265,6 +2362,7 @@ class ContinuousBatcher:
             top_p = np.ones((self.B,), np.float32)
             top_k = np.zeros((self.B,), np.int32)
         has_constraint = has_row_seed = has_penalty = False
+        n_constrained = 0
         all_greedy = constrained_greedy = True
         PS = self.ecfg.kv_page_size
         room = self.MP * PS
@@ -2296,6 +2394,7 @@ class ContinuousBatcher:
                 all_greedy = False
             if r.constraint is not None:
                 has_constraint = True
+                n_constrained += 1
                 if not greedy:
                     constrained_greedy = False
             room = min(room, len(s.pages) * PS - s.pos)
@@ -2312,6 +2411,9 @@ class ContinuousBatcher:
                 flagged=bool(self._needs_mask),
                 room=room,
                 wrapped=self._wrapped,
+                constrained=n_constrained / max(len(active), 1),
+                unmasked_ok=self._unmasked_ok,
+                stepping=self._stepping,
             ),
         )
 
@@ -2336,14 +2438,23 @@ class ContinuousBatcher:
                 float(self.runner.alloc_pages - 1 - free), "full", "used"
             )
 
-    def _choose_path(self, f: _DecodeFacts, in_flight: int) -> str:
+    def _choose_path(
+        self, f: _DecodeFacts, in_flight: int, probed: bool = False
+    ) -> str:
         """THE choice of a decode path, from the facts of the batch and
         the number of fused windows in flight; every gate is read here
         and nowhere else. Returns what to try: ``pipelined`` (refill the
         pipe, fetch its oldest window), ``drain`` (fetch only),
-        ``fastforward`` (the constrained window, the fast-forward probe
-        ahead of it), ``window`` or ``single``. The path's method
-        returns the label its iteration is counted under."""
+        ``fastforward`` (the fast-forward probe of a constrained greedy
+        batch; where it disengages the loop asks again with ``probed``),
+        ``window`` (the speculative window) or ``single`` (one step,
+        masked where a row has an FSM). A constrained greedy batch takes
+        the window while the FSMs accept enough of its rows' UNMASKED
+        tokens for a window to commit more tokens a second than masked
+        steps would (``f.unmasked_ok``, ``f.constrained``,
+        ``_window_gain``), and masked steps while they do not. The
+        path's method returns the label its iteration is counted
+        under."""
         KS = self.ecfg.decode_multi_step
         # Fuse K decode steps into one device program when no row needs
         # host work between steps: one dispatch + one fetch per window
@@ -2385,21 +2496,32 @@ class ContinuousBatcher:
         # FSM-masked step as the FIRST step of its next window
         # (allowed0) — per-row recovery; other rows keep full window
         # cadence.
-        # Flagged rows are fine here: the window FSM-masks their first
-        # step (allowed0); only the non-greedy constrained fallback
-        # needs the masked single-step, and it clears the flags itself.
+        # Flagged rows are fine on either side: the window FSM-masks
+        # their first step (allowed0), the single step masks every row
+        # and clears the flags itself.
         if fused and fits and f.has_constraint and f.constrained_greedy:
             # FSM fast-forward first: when enough rows sit in a forced
             # scaffold run, one parallel verify commits the whole run
-            # per row — the window would reject its unmasked samples
-            # there. Flagged SINGLETON rows are candidates too (the
-            # peel is their masked step); a flagged row in a
-            # non-singleton state sends the batch to the window's
-            # allowed0 recovery instead. The verify forward has no
+            # per row, where a window would reject its unmasked samples
+            # and masked steps would take one forward a token. Flagged
+            # SINGLETON rows are candidates too (the peel is their
+            # masked step); a flagged row in a non-singleton state
+            # makes the probe disengage. The verify forward has no
             # ring/pipeline wrapper.
-            if not f.wrapped and f.all_greedy:
+            if not probed and not f.wrapped and f.all_greedy:
                 return "fastforward"
-            return "window"
+            # A window with no draft model never beats masked steps in
+            # DEVICE time (K steps, at most K tokens a row either way):
+            # what it saves is the host's round between steps, and only
+            # while its unmasked tokens verify. So: the window where it
+            # commits more tokens a second than masked steps, by the
+            # share of unmasked tokens the FSMs have been accepting;
+            # whichever the rows took last stays until the other is
+            # worth _SWITCH_GAIN of it.
+            gain = _window_gain(f.unmasked_ok, KS, f.constrained)
+            if f.stepping:
+                return "window" if gain > _SWITCH_GAIN else "single"
+            return "window" if gain * _SWITCH_GAIN >= 1.0 else "single"
         return "single"
 
     def _state_attrs(self, rows: int) -> Dict[str, int]:
@@ -2449,6 +2571,12 @@ class ContinuousBatcher:
                 **self._kv_attrs(b.past_len[i] for i in b.active),
                 **self._route_attrs.get("decode_window", {}),
             }
+            f = b.facts
+            if f.has_constraint and f.constrained_greedy:
+                # WHY window or step: what _choose_path read
+                self._tel_attrs["decode_window"]["unmasked_ok"] = round(
+                    f.unmasked_ok, 4
+                )
 
     # ------------------------------------------------------------------
     # pipelined fused windows (unconstrained decode fast path)
@@ -2706,25 +2834,22 @@ class ContinuousBatcher:
     # host-synchronous paths: the constrained window, the single step
     # ------------------------------------------------------------------
 
-    def _window_step(self, b: _DecodeBatch, probe: bool) -> str:
-        """The constrained greedy batch: the FSM fast-forward probe
-        when ``probe`` says the batch may take it, else (or when the
-        probe disengages) the speculative window: sample unmasked,
-        verify host-side, commit only each row's FSM-valid prefix. Rows
-        whose previous window rejected take their FSM-masked step as
-        the window's FIRST step (allowed0) — per-row recovery, full
-        cadence for everyone else."""
+    def _window_step(self, b: _DecodeBatch) -> str:
+        """The speculative window of a constrained greedy batch, while
+        the FSMs accept enough of its unmasked tokens (``_choose_path``):
+        sample unmasked, verify host-side, commit only each row's
+        FSM-valid prefix. Rows whose previous window rejected take
+        their FSM-masked step as the window's FIRST step (allowed0):
+        per-row recovery, full cadence for everyone else. What the
+        verify finds (a row's speculative positions asked, and accepted,
+        up to its first refusal) is what the choice is made from
+        (``_observe``)."""
         tm = self.timer
         active = b.active
         K = self.ecfg.decode_multi_step
         self._note_window(b, K)
         self._key, sub = jax.random.split(self._key)
-        if probe and self._fastforward_step(
-            active, b.last, b.past_len, b.table
-        ):
-            return "fastforward"
-        # a failed probe stays ``fsm_plan`` up to here
-        tm.enter("batch_build")
+        self._stepping = False
         allowed0 = None
         flagged: set = self._needs_mask & set(active)
         if flagged:
@@ -2754,6 +2879,7 @@ class ContinuousBatcher:
             ctx = s.job
             if ctx is not None:
                 ctx.stats["row_steps"] += K
+            asked = ok = 0
             for j in range(K):
                 tok = int(toks_w[j][i])
                 # a flagged row's step-0 token was chosen UNDER its FSM
@@ -2771,14 +2897,17 @@ class ContinuousBatcher:
                         self._fail_slot(i, e)
                         _lose(lost, ctx, "failed", K - j)
                         break
+                    asked += 1
                     if not tok_ok:
-                        # this row's NEXT window opens with its
-                        # FSM-masked step (allowed0) so it crosses the
+                        # this row's NEXT dispatch opens with its
+                        # FSM-masked step (a window's allowed0, or the
+                        # masked single step) so it crosses the
                         # scaffold token; other rows keep full window
                         # cadence
                         self._needs_mask.add(i)
                         _lose(lost, ctx, "rejected", K - j)
                         break
+                    ok += 1
                 rc = self._accept_token(
                     i, tok, float(logps_w[j][i]), release=False,
                 )
@@ -2791,6 +2920,7 @@ class ContinuousBatcher:
                     finished.append(i)
                     _lose(lost, ctx, "finished", K - 1 - j)
                     break
+            self._observe(ctx, ok, asked)
         self._close_accept(n0, K * len(active), lost)
         # pages are still reserved for every row (releases were
         # deferred), so the accepted K/V lands safely
@@ -2849,11 +2979,17 @@ class ContinuousBatcher:
 
     def _single_step(self, b: _DecodeBatch) -> str:
         """One decode step with whatever its rows need from the host
-        between steps: FSM masks (sampled constrained rows), per-row
-        seeds, penalties; also the near-capacity tail of any batch."""
+        between steps: FSM masks (sampled constrained rows, and greedy
+        ones while a window's unmasked tokens would be refused:
+        ``_choose_path``), per-row seeds, penalties; also the
+        near-capacity tail of any batch. A masked step says of each row
+        whether its unmasked argmax lay inside its mask, which is what
+        a window's verify would have found there (``_observe``): how a
+        batch on masked steps finds its way back to windows."""
         f = b.facts
         active = b.active
         self._note_window(b, 1)
+        self._stepping = f.has_constraint and f.constrained_greedy
         self._key, sub = jax.random.split(self._key)
         allowed = None
         if f.has_constraint:
@@ -2882,6 +3018,10 @@ class ContinuousBatcher:
         self._needs_mask.clear()
         self.timer.enter("accept")
         n0 = self._n_accepted
+        unmasked_ok = None
+        if self._stepping:
+            take = getattr(self.runner, "take_unmasked_ok", None)
+            unmasked_ok = take() if take is not None else None
         lost: Dict[str, int] = {}  # every active row was dispatched one
         for i in active:
             s = self.slots[i]
@@ -2892,6 +3032,8 @@ class ContinuousBatcher:
             ctx = s.job
             if ctx is not None:
                 ctx.stats["row_steps"] += 1
+            if unmasked_ok is not None and s.req.constraint is not None:
+                self._observe(ctx, int(unmasked_ok[i]), 1)
             if self._accept_token(i, int(toks[i]), float(logps[i])) == 2:
                 _lose(lost, ctx, "failed", 1)
         self._close_accept(n0, len(active), lost)
@@ -3862,14 +4004,23 @@ class ContinuousBatcher:
                 tm.enter("batch_build", active=n_active)
                 batch = self._build_batch(active)
                 plan = self._choose_path(batch.facts, len(pipe))
-                if plan in ("pipelined", "drain"):
+                if plan == "fastforward" and not self._fastforward_step(
+                    batch
+                ):
+                    # the probe disengaged (a failed one stays
+                    # ``fsm_plan`` up to here): window or masked step
+                    tm.enter("batch_build")
+                    plan = self._choose_path(
+                        batch.facts, len(pipe), probed=True
+                    )
+                if plan == "fastforward":
+                    path = plan
+                elif plan in ("pipelined", "drain"):
                     path = self._pipelined_step(
                         pipe, batch, refill=plan == "pipelined"
                     )
-                elif plan in ("fastforward", "window"):
-                    path = self._window_step(
-                        batch, probe=plan == "fastforward"
-                    )
+                elif plan == "window":
+                    path = self._window_step(batch)
                 else:
                     path = self._single_step(batch)
                 self._after_step(live, on_job_done, path, n_active)

@@ -1,6 +1,8 @@
 """The scheduler chooses a decode path in one place
 (``ContinuousBatcher._choose_path``): a table of facts -> path with one
-case a gate, the facts ``_build_batch`` reads off real rows and the
+case a gate (the share of unmasked tokens the FSMs accept, on both
+sides of the line and inside the band where a batch stays where it is,
+among them), the facts ``_build_batch`` reads off real rows and the
 labels those runs are counted under, and a configuration that names a
 field the engine no longer has."""
 
@@ -17,6 +19,7 @@ from sutro_tpu.engine.scheduler import (
     ContinuousBatcher,
     GenRequest,
     _DecodeFacts,
+    _window_gain,
 )
 from sutro_tpu.models.configs import MODEL_CONFIGS
 
@@ -59,8 +62,20 @@ PLAIN = _DecodeFacts(
     wrapped=False,
 )
 GREEDY_SCHEMA = PLAIN._replace(has_constraint=True, all_greedy=True)
+# the same batch once its fast-forward probe has disengaged, by the
+# share of its unmasked tokens the FSMs accept: the line lies at ~0.81
+# for a window of 8, the band where a batch stays where it is ~0.78-0.84
+PROBED = dict(probed=True)
 
-# case -> (engine config, facts, windows in flight, the path to try)
+
+def _accepting(share, stepping=False, **facts):
+    return GREEDY_SCHEMA._replace(
+        unmasked_ok=share, stepping=stepping, **facts
+    )
+
+
+# case -> (engine config, facts, windows in flight, the path to try); an
+#         engine config may carry ``probed``, _choose_path's own argument
 CHOICES = {
     "sampled-and-plain": ({}, PLAIN, 0, "pipelined"),
     "sampled-and-plain-windows-in-flight": ({}, PLAIN, 2, "pipelined"),
@@ -115,15 +130,111 @@ CHOICES = {
     "windows-in-flight-after-a-seeded-row-was-admitted": (
         {}, PLAIN._replace(has_row_seed=True), 1, "drain",
     ),
+    # window or masked step, from the share of unmasked tokens accepted
+    "probed-before-any-observation": (PROBED, GREEDY_SCHEMA, 0, "window"),
+    "probed-every-unmasked-token-accepted": (
+        PROBED, _accepting(1.0), 0, "window",
+    ),
+    "probed-no-unmasked-token-accepted": (
+        PROBED, _accepting(0.0), 0, "single",
+    ),
+    "probed-well-above-the-line": (PROBED, _accepting(0.95), 0, "window"),
+    "probed-well-below-the-line": (PROBED, _accepting(0.6), 0, "single"),
+    "probed-above-the-line-after-steps": (
+        PROBED, _accepting(0.95, stepping=True), 0, "window",
+    ),
+    "probed-below-the-line-after-steps": (
+        PROBED, _accepting(0.6, stepping=True), 0, "single",
+    ),
+    # inside the band a batch stays where it is
+    "probed-just-below-the-line-on-windows": (
+        PROBED, _accepting(0.79), 0, "window",
+    ),
+    "probed-just-above-the-line-on-steps": (
+        PROBED, _accepting(0.83, stepping=True), 0, "single",
+    ),
+    "probed-just-below-the-line-on-steps": (
+        PROBED, _accepting(0.79, stepping=True), 0, "single",
+    ),
+    "probed-just-above-the-line-on-windows": (
+        PROBED, _accepting(0.83), 0, "window",
+    ),
+    "probed-under-the-band-on-windows": (
+        PROBED, _accepting(0.75), 0, "single",
+    ),
+    "probed-over-the-band-on-steps": (
+        PROBED, _accepting(0.87, stepping=True), 0, "window",
+    ),
+    # a window keeps a plain row's every token: refused rows among as
+    # many plain rows do not take the batch off windows, three in four do
+    "probed-one-refused-row-in-four": (
+        PROBED, _accepting(0.0, constrained=0.25), 0, "window",
+    ),
+    "probed-refused-rows-half-the-batch": (
+        PROBED, _accepting(0.0, constrained=0.5), 0, "window",
+    ),
+    "probed-refused-rows-three-in-four": (
+        PROBED, _accepting(0.0, constrained=0.75), 0, "single",
+    ),
+    "one-refused-row-in-four-beside-sampled-plain-rows": (
+        {}, _accepting(0.0, constrained=0.25, all_greedy=False), 0, "window",
+    ),
+    # the probe stays ahead of both choices
+    "unprobed-no-unmasked-token-accepted": (
+        {}, _accepting(0.0, stepping=True), 0, "fastforward",
+    ),
+    # no verify forward there: the same rule, window against step
+    "sp-above-one-no-unmasked-token-accepted": (
+        {}, _accepting(0.0, wrapped=True), 0, "single",
+    ),
+    "beside-sampled-plain-rows-no-unmasked-token-accepted": (
+        {}, _accepting(0.0, all_greedy=False), 0, "single",
+    ),
+    # whoever chose as before chooses as before
+    "constrained-sampled-every-unmasked-token-accepted": (
+        {},
+        PLAIN._replace(has_constraint=True, constrained_greedy=False,
+                       unmasked_ok=1.0, stepping=True),
+        0, "single",
+    ),
+    "room-under-one-window-every-unmasked-token-accepted": (
+        PROBED, _accepting(1.0, room=7), 0, "single",
+    ),
+    "a-seeded-row-every-unmasked-token-accepted": (
+        PROBED, _accepting(1.0, has_row_seed=True), 0, "single",
+    ),
+    "a-flagged-row-no-unmasked-token-accepted": (
+        PROBED, _accepting(0.0, flagged=True), 0, "single",
+    ),
+    "plain-rows-no-unmasked-token-accepted": (
+        {}, PLAIN._replace(unmasked_ok=0.0, stepping=True), 0, "pipelined",
+    ),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CHOICES))
 def test_the_choice_of_a_decode_path(case, byte_tok):
     engine_kw, facts, in_flight, want = CHOICES[case]
+    engine_kw = dict(engine_kw)
+    probed = engine_kw.pop("probed", False)
     assert _batcher(byte_tok, **engine_kw)._choose_path(
-        facts, in_flight
+        facts, in_flight, probed
     ) == want
+
+
+@pytest.mark.parametrize("K", [2, 4, 8, 16])
+def test_what_a_window_is_worth_in_masked_steps(K):
+    """Tokens a second of a window of K over a masked step's: under 1
+    where nothing verifies (K steps of the device for the one masked
+    token), over 1 where everything does (the host's rounds saved),
+    rising between, K times from end to end."""
+    gains = [_window_gain(p / 100.0, K) for p in range(0, 101)]
+    assert gains[0] < 1.0 < gains[-1]
+    assert all(a < b for a, b in zip(gains, gains[1:]))
+    assert gains[-1] / gains[0] == pytest.approx(K)
+    # a plain row is worth a window's whole width whatever the FSMs say
+    assert _window_gain(0.0, K, 0.0) == pytest.approx(gains[-1])
+    assert gains[0] < _window_gain(0.0, K, 0.5) < gains[-1]
 
 
 def _rows(tok, schema=None, **kw):
@@ -166,10 +277,22 @@ RUNS = {
         dict(has_constraint=True, constrained_greedy=False),
         {"single"}, {"single"},
     ),
+    # random weights under a byte tokenizer: the probe is asked first
+    # every iteration; its first verify forward finds none of the rows'
+    # unmasked tokens valid along the scaffold, so where it disengages
+    # the batch takes masked steps, and no window at all
+    # (tests/test_decode_yield.py rigs both sides of the line)
     "constrained-greedy": (
         {}, ENUMS, dict(max_new_tokens=64, temperature=0.0),
         dict(has_constraint=True, constrained_greedy=True, all_greedy=True),
-        {"fastforward"}, {"fastforward", "window"},
+        {"fastforward", "single"}, {"fastforward", "single"},
+    ),
+    # no verify forward to learn from: one window, refused, then steps
+    "constrained-greedy-fastforward-off": (
+        dict(constrain_fastforward=0), ENUMS,
+        dict(max_new_tokens=64, temperature=0.0),
+        dict(has_constraint=True, constrained_greedy=True, all_greedy=True),
+        {"fastforward", "window", "single"}, {"window", "single"},
     ),
     # 5 pages of 8 a row: the tail of every row has room for less than
     # a window of 8, and takes single steps
@@ -189,12 +312,20 @@ def test_the_facts_of_real_rows_and_the_path_they_take(
     engine_kw, schema, req_kw, facts, plans, labels = RUNS[case]
     telemetry.reset_for_tests()
     b = _batcher(byte_tok, **engine_kw)
+    # as a new session starts: nothing observed yet
+    b._unmasked_ok, b._stepping = 1.0, False
     seen = []
+    asked_again = []
     choose = ContinuousBatcher._choose_path
 
-    def recording(self, f, in_flight):
-        plan = choose(self, f, in_flight)
+    def recording(self, f, in_flight, probed=False):
+        plan = choose(self, f, in_flight, probed)
         seen.append((f, in_flight, plan))
+        if probed:
+            # the same iteration, after its probe disengaged
+            assert seen[-2] == (f, in_flight, "fastforward")
+            assert plan in ("window", "single")
+            asked_again.append(plan)
         return plan
 
     monkeypatch.setattr(ContinuousBatcher, "_choose_path", recording)
@@ -218,7 +349,8 @@ def test_the_facts_of_real_rows_and_the_path_they_take(
     series = telemetry.REGISTRY.collect()[
         "sutro_sched_iterations_total"]["series"]
     assert {k for k, v in series.items() if v and k != "idle"} == labels
-    assert sum(series.values()) >= len(seen)
+    assert sum(series.values()) >= len(seen) - len(asked_again)
+    assert ("fastforward" in plans) == bool(asked_again)
 
 
 @pytest.mark.parametrize("name", ["spec_ngram_draft", "no_such_field"])

@@ -1,7 +1,11 @@
 """What a decode dispatch yields (OBSERVABILITY.md): on each of the four
 decode paths, row-steps dispatched = tokens committed + row-steps lost,
 summed over reasons: in the registry's counters, on the ``accept``
-spans and in the job's record; and counting changes no token."""
+spans and in the job's record; and counting changes no token. And what
+the scheduler makes of it: a constrained greedy batch whose unmasked
+tokens its FSMs accept stays on speculative windows, one whose unmasked
+tokens are refused goes to masked single steps and comes back, with the
+same tokens and the same sums whichever path it takes (rigged logits)."""
 
 import itertools
 import time
@@ -91,6 +95,18 @@ def _series(snap, name):
     return dict((snap.get(name) or {}).get("series", {}))
 
 
+def _gained(before, after):
+    """{counter: {series: n}} of what the yield counters and the
+    iterations gained between two collects of the registry."""
+    gained = {}
+    for name in COUNTERS + (ITERATIONS,):
+        a, z = _series(before, name), _series(after, name)
+        gained[name] = {
+            k: int(z[k] - a.get(k, 0)) for k in z if z[k] != a.get(k, 0)
+        }
+    return gained
+
+
 def _by_path(gained):
     """{path: {iterations, row_steps, committed, lost: {reason: n}}} of
     what the registry's series gained over a run."""
@@ -132,9 +148,17 @@ def _early_stop(tok):
 def _run(tok, scenario, tel=True, extra_stops=()):
     """One run of a scenario on a fresh batcher: the job's own tallies
     (``JobCtx.stats``), its results, what the registry's counters gained
-    by path (``_by_path``) and the accept spans."""
+    by path (``_by_path``) and the accept spans. The ``scaffold``
+    scenario is HELD on windows, as a batch whose unmasked tokens verify
+    is: these random weights' are refused, and left to the scheduler's
+    rule the batch would take masked steps (the rigged runs below)."""
+    from sutro_tpu.engine import scheduler as sched_mod
+
     was = telemetry.enabled()
     telemetry.set_enabled(tel)
+    mp = pytest.MonkeyPatch()
+    if scenario == "scaffold":
+        mp.setattr(sched_mod, "_window_gain", lambda p, K, c: 2.0)
     try:
         ecfg = EngineConfig(
             kv_page_size=8, max_pages_per_seq=32, max_model_len=256,
@@ -157,19 +181,14 @@ def _run(tok, scenario, tel=True, extra_stops=()):
         assert b.run_multi(
             [ctx], on_job_done=lambda c, outcome: None
         ) == "completed"
-        after = telemetry.REGISTRY.collect()
-        gained = {}
-        for name in COUNTERS + (ITERATIONS,):
-            a, z = _series(before, name), _series(after, name)
-            gained[name] = {
-                k: int(z[k] - a.get(k, 0)) for k in z if z[k] != a.get(k, 0)
-            }
+        gained = _gained(before, telemetry.REGISTRY.collect())
         spans = [
             s for s in telemetry.RECORDER.snapshot(job_id)
             if s["name"] == "accept"
         ]
         return dict(ctx.stats), res, _by_path(gained), spans
     finally:
+        mp.undo()
         telemetry.set_enabled(was)
 
 
@@ -339,8 +358,237 @@ def test_the_job_record_carries_its_rows_yield(
     assert rec["status"] == "SUCCEEDED", rec.get("failure_reason")
     y = rec["perf"]["decode_yield"]
     assert y["row_steps"] == y["committed"] + sum(y["lost"].values())
-    assert y["lost"].get("rejected", 0) > 0, y
+    # random weights: the verify forward over the opening scaffold finds
+    # the rows' unmasked tokens invalid, the job takes masked steps, and
+    # no window is refused; what is lost is a forward's unfilled width
+    assert y["unmasked"]["asked"] > 0
+    assert y["unmasked"]["ok"] < 0.5 * y["unmasked"]["asked"], y
+    assert y["lost"].get("rejected", 0) == 0, y
+    assert y["lost"].get("plan_short", 0) > 0, y
     assert 0 < y["committed"] < y["row_steps"]
     assert rec["perf"]["fastforward"]["forced_tokens"] > 0
     # every token of the job but its rows' first came from a dispatch
     assert y["committed"] <= rec["output_tokens"] + 2
+
+
+# ---------------------------------------------------------------------
+# window or masked step, from the share of unmasked tokens accepted
+# ---------------------------------------------------------------------
+
+# the token the rigged model's UNMASKED argmax always is
+FAVOURITE = 65
+# rigging -> does a row that has emitted ``n`` tokens refuse FAVOURITE?
+RIGGINGS = {
+    "always-valid": lambda n: False,
+    "never-valid": lambda n: True,
+    # refused for a row's first 30 tokens, accepted from there on
+    "turns-valid": lambda n: n < 30,
+    # and the other way
+    "turns-invalid": lambda n: n >= 30,
+}
+# what holds a run on one path whatever it observes: _window_gain's value
+HOLDS = {"window": 2.0, "single": 0.0}
+
+
+class _Rigged:
+    """An FSM that accepts every token but, where the rigging says so,
+    the model's favourite. It has no forced run: the fast-forward probe
+    finds no plan, and every iteration is a window or a masked step."""
+
+    def __init__(self, vocab, refuses):
+        self.vocab, self.refuses, self.n = vocab, refuses, 0
+
+    def allowed_tokens(self):
+        m = np.ones((self.vocab,), bool)
+        m[FAVOURITE] = not self.refuses(self.n)
+        return m
+
+    def token_allowed(self, tok):
+        return tok != FAVOURITE or not self.refuses(self.n)
+
+    def advance(self, tok):
+        self.n += 1
+
+    def is_complete(self):
+        return False
+
+    def min_tokens(self):
+        return 1
+
+
+def _rigged_run(tok, rigging, hold=None):
+    """One job of four greedy constrained rows on a model whose logits
+    are rigged so that its unmasked argmax is FAVOURITE at every
+    position (every forward's logits, prefill, window, step and verify
+    alike), under ``_Rigged`` FSMs; ``hold`` keeps the scheduler's rule
+    on one path. Returns the path of each iteration in order, the
+    results, the registry's gains by path, the job's tallies and the
+    ``unmasked_ok`` attrs of its ``decode_window`` spans."""
+    key = (rigging, hold)
+    if key in _RUNS:
+        return _RUNS[key]
+    from sutro_tpu.engine import runner as runner_mod
+    from sutro_tpu.engine import scheduler as sched_mod
+
+    mp = pytest.MonkeyPatch()
+    forward = runner_mod.transformer.forward
+
+    def rigged_forward(*a, **kw):
+        logits, *rest = forward(*a, **kw)
+        return (logits.at[..., FAVOURITE].add(1e4), *rest)
+
+    paths = []
+    after = ContinuousBatcher._after_step
+
+    def recording(self, live, on_job_done, path, n_active):
+        paths.append(path)
+        return after(self, live, on_job_done, path, n_active)
+
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    try:
+        mp.setattr(runner_mod.transformer, "forward", rigged_forward)
+        mp.setattr(ContinuousBatcher, "_after_step", recording)
+        if hold is not None:
+            mp.setattr(
+                sched_mod, "_window_gain", lambda p, K, c: HOLDS[hold]
+            )
+        ecfg = EngineConfig(
+            kv_page_size=8, max_pages_per_seq=32, max_model_len=256,
+            decode_batch_size=4, use_pallas=False, param_dtype="float32",
+            activation_dtype="float32", decode_multi_step=8,
+            decode_lookahead=2, constrain_fastforward=16,
+        )
+        b = ContinuousBatcher(
+            ModelRunner(MODEL_CONFIGS["tiny-dense"], ecfg),
+            stop_ids=tok.stop_ids(), seed=11,
+        )
+        vocab = MODEL_CONFIGS["tiny-dense"].vocab_size
+        before = telemetry.REGISTRY.collect()
+        job_id = f"rigged-{rigging}-{hold}-{next(_RUN_IDS)}"
+        res = {}
+        ctx = JobCtx(
+            job_id=job_id,
+            pending=[
+                GenRequest(
+                    row_id=i, prompt_ids=np.array(tok.encode(t), np.int32),
+                    max_new_tokens=65, temperature=0.0,
+                    constraint=_Rigged(vocab, RIGGINGS[rigging]),
+                )
+                for i, t in enumerate(
+                    ["first row", "second", "third one", "the fourth"]
+                )
+            ],
+            on_result=lambda r: res.__setitem__(r.row_id, r),
+        )
+        assert b.run_multi(
+            [ctx], on_job_done=lambda c, outcome: None
+        ) == "completed"
+        gained = _gained(before, telemetry.REGISTRY.collect())
+        seen = [
+            s["attrs"].get("unmasked_ok")
+            for s in telemetry.RECORDER.snapshot(job_id)
+            if s["name"] == "decode_window"
+        ]
+    finally:
+        mp.undo()
+        telemetry.set_enabled(was)
+    _RUNS[key] = (paths, res, _by_path(gained), dict(ctx.stats), seen)
+    return _RUNS[key]
+
+
+def _tokens(res):
+    return {
+        i: (tuple(r.token_ids), r.finish_reason) for i, r in res.items()
+    }
+
+
+@pytest.mark.parametrize("rigging", sorted(RIGGINGS))
+def test_a_batch_takes_the_path_its_unmasked_tokens_earn(rigging, byte_tok):
+    paths, res, _, stats, seen = _rigged_run(byte_tok, rigging)
+    assert len(res) == 4 and set(paths) <= {"window", "single"}
+    text = "".join(p[0] for p in paths)  # "w" a window, "s" a step
+    if rigging == "always-valid":
+        # every unmasked token verifies: windows to the end, each
+        # committing its whole width
+        assert "s" not in text and len(text) <= 10, text
+        assert stats["unmasked_ok"] == stats["unmasked_asked"] > 0
+    elif rigging == "never-valid":
+        # at most two windows, then masked steps to the end
+        assert text.lstrip("w") == "s" * (len(text) - text.count("w")), text
+        assert 1 <= text.count("w") <= 2 and text.count("s") > 50, text
+        assert stats["unmasked_ok"] == 0 < stats["unmasked_asked"]
+    elif rigging == "turns-valid":
+        # steps while FAVOURITE is refused, and BACK to windows a few
+        # steps after it is not: what a masked step says of its rows'
+        # unmasked argmax is what brings the batch back
+        head, _, tail = text.partition("sw")
+        assert 1 <= head.count("w") <= 2 and head.lstrip("w") == "s" * (
+            len(head) - head.count("w")
+        ), text
+        assert tail and set(tail) == {"w"}, text
+        assert 25 <= text.count("s") <= 40, text
+    else:
+        # windows while it verifies, steps from the refusal on
+        assert text.rstrip("s").count("s") == 0, text
+        assert 3 <= text.count("w") <= 6 and text.count("s") > 25, text
+    # the span says what the choice was made from: the estimate before
+    # the dispatch, 1.0 before any observation
+    assert seen and seen[0] == 1.0 and all(
+        v is not None and 0.0 <= v <= 1.0 for v in seen
+    )
+    assert len(seen) >= len(paths)
+
+
+@pytest.mark.parametrize("hold", sorted(HOLDS))
+@pytest.mark.parametrize("rigging", sorted(RIGGINGS))
+def test_a_jobs_tokens_are_the_same_whichever_path_takes_them(
+    rigging, hold, byte_tok
+):
+    _, chosen, _, _, _ = _rigged_run(byte_tok, rigging)
+    paths, held, _, _, _ = _rigged_run(byte_tok, rigging, hold)
+    # (held on windows, a row's last tokens have room for less than a
+    # window and take single steps, as any batch's do)
+    text = "".join(p[0] for p in paths).rstrip("s" if hold == "window" else "")
+    assert set(text) == {hold[0]} and len(paths) - len(text) < 8, paths
+    assert _tokens(chosen) == _tokens(held)
+    for i, r in chosen.items():
+        assert r.cumulative_logprob == pytest.approx(
+            held[i].cumulative_logprob, rel=1e-4, abs=1e-3
+        )
+        # what the FSM refused is in no result
+        refuses = RIGGINGS[rigging]
+        assert not any(
+            t == FAVOURITE and refuses(n)
+            for n, t in enumerate(r.token_ids)
+        )
+
+
+@pytest.mark.parametrize("hold", [None] + sorted(HOLDS))
+@pytest.mark.parametrize("rigging", sorted(RIGGINGS))
+def test_row_steps_add_up_on_every_path_across_a_switch(
+    rigging, hold, byte_tok
+):
+    paths, res, by_path, stats, _ = _rigged_run(byte_tok, rigging, hold)
+    assert {p for p, y in by_path.items() if y["row_steps"]} == set(paths)
+    for path, y in by_path.items():
+        assert y["row_steps"] == y["committed"] + sum(y["lost"].values()), (
+            path, y,
+        )
+        assert y["iterations"] == paths.count(path)
+        assert y["row_steps"] % WIDTH[path] == 0
+    # every token of a row but its first came from one of them
+    assert sum(y["committed"] for y in by_path.values()) == sum(
+        len(r.token_ids) - 1 for r in res.values()
+    )
+    assert stats["row_steps"] == sum(
+        y["row_steps"] for y in by_path.values()
+    )
+    if "single" in by_path:
+        assert by_path["single"]["lost"] == {}
+    if rigging == "never-valid" and hold is None:
+        # the share the issue is about: nearly every row-step a token
+        kept = sum(y["committed"] for y in by_path.values()) / stats[
+            "row_steps"
+        ]
+        assert kept > 0.8, by_path

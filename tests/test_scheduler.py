@@ -478,23 +478,31 @@ def test_speculative_rejection_is_per_row(tiny_runner, byte_tok, monkeypatch):
             prompt_ids=np.array(byte_tok.encode("adv"), np.int32),
             max_new_tokens=40, temperature=0.0, constraint=fac(),
         ),
+    ] + [
         GenRequest(
-            row_id=1,
-            prompt_ids=np.array(byte_tok.encode("bystander"), np.int32),
+            row_id=i,
+            prompt_ids=np.array(byte_tok.encode(text), np.int32),
             # window-aligned cap: a non-multiple of decode_multi_step
             # would run its TAIL single-step by the documented
             # all-or-nothing window rule, which is not what this test
-            # measures
-            max_new_tokens=2 * tiny_runner.ecfg.decode_multi_step,
+            # measures. Long enough to outlast the const row (a token a
+            # window): once the plain rows are gone, a batch of refused
+            # windows alone is worth less than masked steps and takes
+            # them (_choose_path)
+            max_new_tokens=14 * tiny_runner.ecfg.decode_multi_step,
             temperature=0.0,
-        ),
+        )
+        for i, text in ((1, "bystander"), (2, "another one"))
     ]
     res = {}
     b.run(reqs, on_result=lambda r: res.__setitem__(r.row_id, r))
     out0 = b"".join(byte_tok.token_bytes(t) for t in res[0].token_ids)
     assert json.loads(out0.decode()) == "zqxzqxzqxzqx"
     assert res[0].finish_reason == "schema_complete"
-    assert len(res[1].token_ids) == 2 * tiny_runner.ecfg.decode_multi_step
+    for i in (1, 2):
+        assert len(res[i].token_ids) == (
+            14 * tiny_runner.ecfg.decode_multi_step
+        )
     # the invariant under test: rejections recovered inside windows,
     # never by flipping the whole batch to masked single-steps
     assert calls["single"] == 0, calls
