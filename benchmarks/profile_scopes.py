@@ -14,7 +14,8 @@ prefill of each bucket and two windows with the JAX profiler; with
 what a byte tokenizer's FSM allows: the step a constrained greedy batch
 takes while its windows are being refused, ``_decode_jit``). Each device
 op's self time goes to the first ``jax.named_scope`` of the mixed walk in
-its HLO ``op_name`` (``moe_ffn``, ``attn_window``, ``attn_full``,
+its HLO ``op_name`` (``moe_ffn``, ``shared_expert`` inside it,
+``attn_window``, ``attn_full``,
 ``attn_mixer``, ``conv_mixer``, ``mamba_mixer``, ``dense_ffn``; ``other``
 is the head, sampling, embeddings and what XLA hoisted), read from the
 event's own HLO line or, where the trace leaves it out, from the
@@ -51,6 +52,8 @@ _DEF = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 
 def scope_of(op_name: str) -> str:
     parts = op_name.split("/")
+    if "shared_expert" in parts:     # inside ``moe_ffn``: told apart
+        return "shared_expert"
     return next((p for p in parts if p in SCOPES), "other")
 
 

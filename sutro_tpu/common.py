@@ -71,6 +71,7 @@ ModelOptions = Union[
         "lfm2-24b-a2b",
         "granite-4.0-h-micro",
         "mellum2-12b-a2.5b",
+        "nemotron-3-nano-30b-a3b",
         "qwen-3-embedding-0.6b",
         "qwen-3-embedding-6b",
         "qwen-3-embedding-8b",
@@ -111,6 +112,7 @@ def model_catalog() -> Dict[str, Dict[str, Any]]:
     add("lfm2-24b-a2b", "lfm2-24b-a2b")
     add("granite-4.0-h-micro", "granite-4.0-h-micro")
     add("mellum2-12b-a2.5b", "mellum2-12b-a2.5b")
+    add("nemotron-3-nano-30b-a3b", "nemotron-3-nano-30b-a3b")
     add("qwen-3-embedding-0.6b", "qwen3-emb-0.6b", embedding=True)
     add("qwen-3-embedding-6b", "qwen3-emb-6b", embedding=True)
     add("qwen-3-embedding-8b", "qwen3-emb-8b", embedding=True)

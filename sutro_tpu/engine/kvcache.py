@@ -93,7 +93,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.configs import ModelConfig
-from ..models.transformer import MixedChunk, StatePast, over_state, per_channel
+from ..models.transformer import (
+    MixedChunk, StatePast, group_channels, over_state, per_channel,
+)
 from .config import EngineConfig
 
 
@@ -666,12 +668,19 @@ def write_state(
             )                                                         # [NS, I]
             cx = jnp.where(live[:, None, None], cx[row], 0.0)         # [NS, W, I]
             Bs = Bm[row].astype(f32)                                  # [NS, W, G*N]
-            new = pool[l].astype(f32) * decay[:, None, :]
-            for w in range(W):
-                new = new + over_state(Bs[:, w], G, I) * cx[:, w, None, :]
-            pool = jax.lax.dynamic_update_index_in_dim(
-                pool, new.astype(pool.dtype), l, axis=0
-            )
+            Bw = [over_state(Bs[:, w], G) for w in range(W)]
+            for g, ch in enumerate(group_channels(I, G)):
+                # a group's channels at a time (its B broadcasts over
+                # them), each slice read and written where it lies
+                at = (l, 0, 0, ch.start)
+                new = jax.lax.dynamic_slice(
+                    pool, at, (1, NS, pool.shape[2], ch.stop - ch.start)
+                )[0].astype(f32) * decay[:, None, ch]
+                for w in range(W):
+                    new = new + Bw[w][g] * cx[:, w, None, ch]
+                pool = jax.lax.dynamic_update_slice(
+                    pool, new.astype(pool.dtype)[None], at
+                )
             return pool, jnp.take_along_axis(ext, after, axis=1)
 
         ssm, cols = jax.lax.scan(

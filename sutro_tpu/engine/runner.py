@@ -236,6 +236,14 @@ class ModelRunner:
                 f"{mcfg.name} has layers of several kinds: not under "
                 "sequence or pipeline parallelism, nor quantize"
             )
+        if mesh is not None and mcfg.experts_held != mcfg.moe_experts:
+            # the share IS one chip's part of a layer; under a mesh the
+            # sharding rules would split the held stack again and the
+            # EP path would take its shard's experts for the router's
+            raise NotImplementedError(
+                f"{mcfg.name} holds a share of each layer's experts "
+                "(moe_experts_held): it runs on one chip, not under a mesh"
+            )
         # explicit shard_map EP for MoE MLPs (ops/moe_ep.py). Not under
         # sp/pp: those paths already wrap layers in their own shard_map
         # and nesting is unsupported — they keep GSPMD MoE semantics.
@@ -718,23 +726,29 @@ class ModelRunner:
         self._device_info = info
         return info
 
-    @staticmethod
-    def _route_stats(chunk):
-        """[4] float32 from a forward's chunk: over the routed layers,
-        the mean number of distinct experts the dispatch's rows chose,
-        the mean rows of the busiest expert, the mean rows an expert,
-        and the row-expert pairs in all. None for a model that does not
-        count its routing. Computed inside the dispatch's own program
-        and fetched with its tokens."""
+    def _route_stats(self, chunk):
+        """[6] float32 from a forward's chunk: over the routed layers
+        and the experts this chip HOLDS (``ModelConfig.moe_experts_held``;
+        every expert of any other model), the mean number of distinct
+        experts the dispatch's rows chose, the mean rows of the busiest
+        expert, the mean rows an expert, the row-expert pairs that
+        landed on them in all, the experts held a layer, and the pairs
+        routed to experts this chip does not hold. None for a model
+        that does not count its routing. Computed inside the dispatch's
+        own program and fetched with its tokens."""
         route = chunk.route if isinstance(chunk, MixedChunk) else None
         if route is None:
             return None
-        r = route.astype(jnp.float32)                       # [L_moe, E]
+        first, held = self.mcfg.moe_first_expert, self.mcfg.experts_held
+        every = route.astype(jnp.float32)                   # [L_moe, E]
+        r = every[:, first : first + held]
         return jnp.stack([
             jnp.mean(jnp.sum(r > 0, axis=-1).astype(jnp.float32)),
             jnp.mean(jnp.max(r, axis=-1)),
             jnp.mean(r),
             jnp.sum(r),
+            jnp.float32(held),
+            jnp.sum(every) - jnp.sum(r),
         ])
 
     def _state_at(self, cache: KVCache, page_table, start):
@@ -759,7 +773,7 @@ class ModelRunner:
 
     def take_route_stats(self):
         """The routing counts of the last dispatch that was fetched
-        (``_route_stats``; [4], or [steps, 4] for a fused window), as
+        (``_route_stats``; [6], or [steps, 6] for a fused window), as
         numpy, once; None when there are none. The program that made
         them has already been waited for by whoever fetched its tokens,
         so this is a copy of a few floats and no wait of its own."""
@@ -1427,7 +1441,7 @@ class ModelRunner:
         layers' state before the window followed by each step's gated
         input (carried through the scan like the K/V window, so ANY
         accepted prefix commits by ``write_kv``), and the routing
-        counts of each step ([steps, 4], ``_route_stats``).
+        counts of each step ([steps, 6], ``_route_stats``).
 
         ``allowed0`` ([B, V] bool, optional) masks the FIRST step's
         logits only: a row whose previous window rejected a token takes

@@ -1864,16 +1864,23 @@ class ContinuousBatcher:
             stats = take() if take is not None else None
         if stats is None or not self._tel_on:
             return
-        a = np.asarray(stats, np.float64).reshape(-1, 4)
+        a = np.asarray(stats, np.float64).reshape(-1, 6)
         self._route_attrs[stage] = {
             "experts_touched": round(float(a[:, 0].mean()), 2),
             "expert_rows_max": round(float(a[:, 1].mean()), 2),
             "expert_rows_mean": round(float(a[:, 2].mean()), 3),
+            # of this chip's share (runner._route_stats): the experts it
+            # holds a layer, and the assignments that landed on them
+            "experts_held": int(a[0, 4]),
+            "expert_rows_held": int(a[:, 3].sum()),
+            "expert_rows_elsewhere": int(a[:, 5].sum()),
         }
         self._tel_attrs[stage] = {
             **(self._tel_attrs.get(stage) or {}), **self._route_attrs[stage]
         }
         telemetry.MOE_ROUTED_ROWS_TOTAL.inc(float(a[:, 3].sum()))
+        if a[:, 5].any():
+            telemetry.MOE_ROWS_ELSEWHERE_TOTAL.inc(float(a[:, 5].sum()))
 
     def _pad_mask(self, mask: np.ndarray) -> np.ndarray:
         """Constraint masks are sized to the *tokenizer* vocab; pad to the

@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers eight
+config-driven decoder-only transformer (models/transformer.py) covers nine
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -26,6 +26,17 @@ families:
   attention factor), every FFN routed (64 experts, top-8, softmax over
   all then renormalised); K/V is kept a pool a kind
   (engine/kvcache.py)
+- Nemotron 3 Nano (30b-a3b; ``model_type`` nemotron_h): 52 blocks of
+  ONE sublayer each, ``h <- h + f(norm(h))``, by the published
+  ``hybrid_override_pattern``: Mamba-2 (8 groups of B and C, the gated
+  norm a group at a time), GQA attention without rotary embedding
+  (16 query heads a KV head) or a routed FFN (sigmoid router with a
+  selection bias over 128 experts, top-6, the chosen scores over their
+  sum times 2.5; experts of TWO matrices under relu^2; one shared
+  expert). ``-l14-ep2`` is one chip's share of a deployment: the first
+  14 blocks, experts 0-63 of each layer (``moe_experts_held``: the
+  router keeps its 128 outputs, the rest are another chip's) and half
+  the vocabulary
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -35,6 +46,10 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Dict, Optional, Tuple
+
+
+#: ``layer_types`` entries that name an FFN (``ModelConfig.one_sublayer``)
+FFN_KINDS = ("dense", "moe")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +86,22 @@ class ModelConfig:
     router_select_bias: bool = False
     router_renorm: bool = True
     router_scale: float = 1.0
+    # what ``router_renorm`` adds to the chosen scores' sum (LFM2: 1e-6,
+    # Nemotron-H: 1e-20)
+    router_renorm_eps: float = 1e-6
+    # An expert layer told which experts it holds: the router keeps
+    # ``moe_experts`` outputs and its top-k; the layer holds experts
+    # ``moe_first_expert .. + moe_experts_held`` (0: all of them) and
+    # computes the assignments that land on those; the rest are another
+    # chip's and are left out (ops/moe.py ``held_rows``).
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
+    # False: an expert is TWO matrices, ``down(act(up x))`` (``we_up``,
+    # ``we_down``; no ``we_gate``), as is the shared expert
+    moe_gated: bool = True
+    # width of ONE shared expert, of the experts' form, that every token
+    # takes beside its routed ones (0: none)
+    moe_shared_intermediate_size: int = 0
     # Per-layer mixer kinds, "attention" | "swa" | "conv" | "mamba";
     # empty => attention everywhere. A "swa" layer is attention over the
     # last ``sliding_window`` positions, with the plain rotary embedding
@@ -79,6 +110,10 @@ class ModelConfig:
     # kind (engine/kvcache.py). A "conv" layer is the gated short
     # convolution of the LFM2 family: depthwise, causal, ``conv_kernel``
     # taps, and K-1 columns of per-sequence state beside the paged K/V.
+    # An entry may also name an FFN kind, "moe" | "dense": the model's
+    # blocks are then ONE sublayer each, ``h <- h + f(norm(h))``: that
+    # FFN alone, and at a mixer's entry the mixer alone (Nemotron-H's
+    # ``hybrid_override_pattern``).
     layer_types: Tuple[str, ...] = ()
     conv_kernel: int = 0
     # A "mamba" layer is Mamba-2 (models/transformer.py ``mamba_mixer``):
@@ -124,6 +159,7 @@ class ModelConfig:
     # file states it (None: 0.1 ln(factor) + 1)
     rope_attention_factor: Optional[float] = None
     # activation: "silu" (SwiGLU) | "gelu" (GeGLU) | "swiglu_oss" (clamped)
+    # | "relu2" (relu(x)^2: the ungated experts of Nemotron-H)
     activation: str = "silu"
     # head: "lm" | "embedding" (pooled, normalized)
     head: str = "lm"
@@ -152,17 +188,37 @@ class ModelConfig:
         return self.num_kv_heads * self.head_dim
 
     @property
+    def one_sublayer(self) -> bool:
+        """``layer_types`` names FFN kinds too: a block is a mixer
+        alone or an FFN alone."""
+        return any(t in FFN_KINDS for t in self.layer_types)
+
+    @property
     def mixers(self) -> Tuple[str, ...]:
-        """Each layer's mixer kind, in order."""
+        """Each layer's mixer kind, in order ("none": an FFN alone)."""
+        if self.one_sublayer:
+            return tuple(
+                "none" if t in FFN_KINDS else t for t in self.layer_types
+            )
         return self.layer_types or ("attention",) * self.num_layers
 
     @property
     def ffns(self) -> Tuple[str, ...]:
-        """Each layer's FFN kind, "dense" | "moe", in order."""
+        """Each layer's FFN kind, "dense" | "moe", in order ("none": a
+        mixer alone)."""
+        if self.one_sublayer:
+            return tuple(
+                t if t in FFN_KINDS else "none" for t in self.layer_types
+            )
         if not self.moe_experts:
             return ("dense",) * self.num_layers
         d = self.num_dense_layers
         return ("dense",) * d + ("moe",) * (self.num_layers - d)
+
+    @property
+    def experts_held(self) -> int:
+        """Experts whose weights this chip holds, a routed layer."""
+        return self.moe_experts_held or self.moe_experts
 
     @property
     def homogeneous(self) -> bool:
@@ -392,6 +448,51 @@ def _mellum2(name: str, layer_types: Tuple[str, ...], *, h: int = 2304,
     )
 
 
+#: NVIDIA-Nemotron-3-Nano-30B-A3B's published
+#: ``hybrid_override_pattern`` (config.json): a block a symbol
+_NEMOTRON_3_NANO_PATTERN = (
+    "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+)
+_NEMOTRON_H_SYMBOLS = {
+    "M": "mamba", "*": "attention", "E": "moe", "-": "dense",
+}
+
+
+def _nemotron_h(name: str, pattern: str, *, h: int = 2688, nh: int = 32,
+                nkv: int = 2, hd: int = 128, m_heads: int = 64,
+                m_head_dim: int = 64, m_state: int = 128, m_groups: int = 8,
+                m_chunk: int = 128, experts: int = 128, top_k: int = 6,
+                moe_inter: int = 1856, shared_inter: int = 3712,
+                held: int = 0, first: int = 0, vocab: int = 131_072,
+                template: str = "chatml") -> ModelConfig:
+    """The published ``nemotron_h`` keys: ``pattern`` a block a symbol
+    (M Mamba-2, * attention, E routed FFN), each block ONE sublayer
+    under one norm. Mamba-2 with ``m_groups`` groups of B and C (the
+    gated norm a group at a time), inner width heads x head_dim; GQA
+    with no rotary embedding; a sigmoid router with a selection bias,
+    the chosen scores over their sum (+1e-20) times 2.5, experts of two
+    matrices under relu^2 beside one shared expert. ``held`` /
+    ``first``: the experts this chip holds of each layer (0: all)."""
+    return ModelConfig(
+        name=name, vocab_size=vocab, hidden_size=h,
+        num_layers=len(pattern), num_heads=nh, num_kv_heads=nkv,
+        head_dim=hd, intermediate_size=moe_inter, norm_eps=1e-5,
+        rope_theta=10_000.0, qk_norm=False, tie_embeddings=False,
+        layer_types=tuple(_NEMOTRON_H_SYMBOLS[c] for c in pattern),
+        mamba_heads=m_heads, mamba_head_dim=m_head_dim,
+        mamba_state=m_state, mamba_groups=m_groups, mamba_conv=4,
+        mamba_chunk=m_chunk, position_embedding="nope",
+        moe_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_inter,
+        moe_experts_held=held, moe_first_expert=first,
+        moe_gated=False, moe_shared_intermediate_size=shared_inter,
+        activation="relu2",
+        router_score="sigmoid", router_select_bias=True,
+        router_renorm=True, router_scale=2.5, router_renorm_eps=1e-20,
+        chat_template=template, seeded_unit_embedding=True,
+    )
+
+
 MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # Qwen3 dense
     "qwen3-0.6b": _qwen3("qwen3-0.6b", 1024, 28, 16, 8, 3072),
@@ -429,6 +530,18 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     "mellum2-12b-a2.5b": _mellum2("mellum2-12b-a2.5b", _MELLUM2_LAYERS),
     "mellum2-12b-a2.5b-l8": _mellum2(
         "mellum2-12b-a2.5b-l8", _MELLUM2_LAYERS[:8]
+    ),
+    # Nemotron 3 Nano: as published (31.6 B parameters), and the first
+    # pipeline stage's share on one of the two chips that divide each
+    # layer: the first 14 blocks (two whole units of MEMEM*E), experts
+    # 0-63 of each layer's 128, rows 0-65,535 of the vocabulary
+    # (4.58 B parameters, 9.17 GB in bf16)
+    "nemotron-3-nano-30b-a3b": _nemotron_h(
+        "nemotron-3-nano-30b-a3b", _NEMOTRON_3_NANO_PATTERN
+    ),
+    "nemotron-3-nano-30b-a3b-l14-ep2": _nemotron_h(
+        "nemotron-3-nano-30b-a3b-l14-ep2", _NEMOTRON_3_NANO_PATTERN[:14],
+        held=64, first=0, vocab=65_536,
     ),
     # Embeddings (Qwen3 trunk + last-token-pool head)
     "qwen3-emb-0.6b": _qwen3("qwen3-emb-0.6b", 1024, 28, 16, 8, 3072, head="embedding"),
@@ -475,6 +588,15 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         h=128, nh=4, nkv=2, hd=32, inter=256, experts=8, top_k=2,
         moe_inter=64, window=8, rope_original=16, vocab=512,
         template="plain",
+    ),
+    # the published 7-block unit twice; 2 groups of B and C, 4 query
+    # heads a KV head, 8 experts top-2 of which this chip holds 4 (the
+    # ragged path: more than ops/moe.py's dense path takes)
+    "tiny-nemotron-h": _nemotron_h(
+        "tiny-nemotron-h", "MEMEM*E" * 2, h=128, nh=8, nkv=2, hd=32,
+        m_heads=8, m_head_dim=32, m_state=16, m_groups=2, m_chunk=8,
+        experts=8, top_k=2, moe_inter=48, shared_inter=96, held=4,
+        first=0, vocab=512, template="plain",
     ),
     "tiny-emb": ModelConfig(
         name="tiny-emb", vocab_size=512, hidden_size=128, num_layers=2,

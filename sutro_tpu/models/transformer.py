@@ -12,14 +12,15 @@ models remotely — SURVEY §0). Design choices are TPU-first:
   ``params["layers"][kind][name] [L_kind, ...]`` for the mixers "attn",
   "conv" and "mamba" and the FFNs "dense" and "moe", and walks the
   config's own list of layers, scanning each repeated group
-  (``_mixed_trunk``).
+  (``_mixed_trunk``); a block may be a mixer alone or an FFN alone, under
+  the one norm of its kind's stack (``ModelConfig.one_sublayer``).
 - Static shapes everywhere: decode attends over a fixed ``CTX`` window
   gathered from the paged KV cache and masks invalid positions; prefill is
   bucketed by the runner. No data-dependent Python control flow.
 - All matmuls run in ``bfloat16`` on the MXU; softmax/norms accumulate in
   ``float32``.
-- One code path covers Qwen3 (dense+MoE), Llama 3, Gemma 3, gpt-oss and
-  LFM2-MoE via ``ModelConfig`` fields (QK-norm, sliding windows, attention
+- One code path covers Qwen3 (dense+MoE), Llama 3, Gemma 3, gpt-oss,
+  LFM2-MoE, Granite 4.0-H, Mellum 2 and Nemotron-H via ``ModelConfig`` fields (QK-norm, sliding windows, attention
   sinks, post norms, MoE and its router's form, per-layer mixer kinds) —
   see models/configs.py.
 
@@ -42,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .configs import ModelConfig
-from ..ops.moe import moe_mlp
+from ..ops.moe import moe_mlp, relu2
 from ..ops.attention import chunk_attention
 from ..ops.quant import materialize
 
@@ -131,7 +132,8 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
     mixers "attn" [L_attn, ...] and "conv" [L_conv, ...], the FFNs
     "dense" [L_dense, ...] and "moe" [L_moe, ...]; each mixer stack
     carries its pre-norm (``attn_norm``), each FFN stack its
-    ``mlp_norm``. The selection bias is drawn small and NON-zero, so
+    ``mlp_norm`` (a block of ONE sublayer has the one norm of its
+    kind's stack). The selection bias is drawn small and NON-zero, so
     that choosing by ``score + bias`` and weighting by ``score`` differ
     on random weights as they do on trained ones."""
     H, Dh = cfg.hidden_size, cfg.head_dim
@@ -172,14 +174,40 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
             "w_down": dense((Ld, F, H), F),
         }
     if Lm:
-        E, Fm = cfg.moe_experts, cfg.moe_intermediate_size
+        # the router is as wide as published; the expert stacks hold the
+        # experts this chip holds (``ModelConfig.moe_experts_held``)
+        E, Eh = cfg.moe_experts, cfg.experts_held
+        Fm, Fs = cfg.moe_intermediate_size, cfg.moe_shared_intermediate_size
         out["moe"] = {
             "mlp_norm": jnp.ones((Lm, H), dtype),
             "router": dense((Lm, H, E), H),
-            "we_gate": dense((Lm, E, H, Fm), H),
-            "we_up": dense((Lm, E, H, Fm), H),
-            "we_down": dense((Lm, E, Fm, H), Fm),
         }
+        if cfg.moe_gated:
+            out["moe"]["we_gate"] = dense((Lm, Eh, H, Fm), H)
+            out["moe"]["we_up"] = dense((Lm, Eh, H, Fm), H)
+        else:
+            # two matrices an expert: the first OUTPUT-major (ops/moe.py)
+            out["moe"]["we_up_t"] = dense((Lm, Eh, Fm, H), H)
+        # 1 / fan-in keeps a unit-variance input's variance through a
+        # matrix. The second matrix of a relu^2 expert sees a hidden row
+        # of second moment E[relu(z)^4] = 1.5, not 1, and the routed sum
+        # weights its experts by ``router_scale`` in all, not by 1: the
+        # draw divides both out, so that the block's output stays the
+        # size of its input as every other block's does. Drawn at
+        # 1 / fan-in alone, a routed block's output is three times its
+        # input's size, one selection flipped by a rounding moves the
+        # stream by a third, and bfloat16 against float32 differ by half
+        # the largest logit at one position in a hundred (PERF.md
+        # section 6, PR 40)
+        hidden = 1.5 if cfg.activation == "relu2" else 1.0
+        out["moe"]["we_down"] = dense(
+            (Lm, Eh, Fm, H), Fm * hidden * cfg.router_scale ** 2
+        )
+        if Fs:
+            if cfg.moe_gated:
+                out["moe"]["shared_gate"] = dense((Lm, H, Fs), H)
+            out["moe"]["shared_up"] = dense((Lm, H, Fs), H)
+            out["moe"]["shared_down"] = dense((Lm, Fs, H), Fs * hidden)
         if cfg.router_select_bias:
             out["moe"]["router_bias"] = (
                 dense((Lm, E), 1) * 0.02
@@ -393,12 +421,35 @@ def _router_form(cfg: ModelConfig, lp: Dict[str, Any]) -> Optional[dict]:
         "softmax", True, 1.0
     ) and not cfg.router_select_bias:
         return None
-    return dict(
+    form = dict(
         score=cfg.router_score,
         select_bias=lp["router_bias"] if cfg.router_select_bias else None,
         renorm=cfg.router_renorm,
         scale=cfg.router_scale,
     )
+    if cfg.router_renorm_eps != 1e-6:       # ``_route``'s own
+        form["renorm_eps"] = cfg.router_renorm_eps
+    return form
+
+
+def _ffn(cfg: ModelConfig, lp: Dict[str, Any], x: jax.Array, names) -> jax.Array:
+    """A dense FFN over ``x`` from the leaves ``names`` = (gate, up,
+    down); ``lp`` without the gate's leaf is the two-matrix form,
+    ``down(relu(up x)^2)``."""
+    gate_name, up_name, down_name = names
+    up = x @ _w(lp, up_name, x.dtype)
+    if gate_name not in lp:
+        return relu2(up) @ _w(lp, down_name, x.dtype)
+    gate = x @ _w(lp, gate_name, x.dtype)
+    if cfg.activation == "gelu":
+        act = jax.nn.gelu(gate.astype(jnp.float32), approximate=True).astype(x.dtype)
+    elif cfg.activation == "swiglu_oss":
+        g = jnp.clip(gate.astype(jnp.float32), max=7.0)
+        act = (g * jax.nn.sigmoid(1.702 * g)).astype(x.dtype)
+        up = jnp.clip(up.astype(jnp.float32), -7.0, 7.0).astype(x.dtype) + 1.0
+    else:
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
+    return (act * up) @ _w(lp, down_name, x.dtype)
 
 
 def _mlp(
@@ -435,13 +486,20 @@ def _mlp(
             bias_down=lp.get("we_down_b"),
             route=_router_form(cfg, lp),
         )
+        gated = "we_gate" in lp
         args = (
             x,
             lp["router"],
-            _w(lp, "we_gate", x.dtype),
-            _w(lp, "we_up", x.dtype),
+            _w(lp, "we_gate", x.dtype) if gated else None,
+            _w(lp, "we_up" if gated else "we_up_t", x.dtype),
             _w(lp, "we_down", x.dtype),
         )
+        counts = None
+        if ep_mesh is not None and not gated:
+            raise NotImplementedError(
+                f"{cfg.name}: experts of two matrices under the shard_map "
+                "expert-parallel path (ops/moe_ep.py takes three)"
+            )
         if ep_mesh is not None:
             # explicit shard_map EP: expert weights stay resident at
             # 1/(ep*tp) per shard (ops/moe_ep.py) instead of GSPMD
@@ -452,23 +510,24 @@ def _mlp(
                 *args, mesh=ep_mesh, use_pallas=use_pallas, **kwargs
             )
             if return_counts:
-                return out, jnp.zeros((lp["router"].shape[-1],), jnp.int32)
-            return out
-        return moe_mlp(
-            *args, return_counts=return_counts, layer=layer,
-            use_pallas=use_pallas and kernel_mesh is None, **kwargs
-        )
-    gate = x @ _w(lp, "w_gate", x.dtype)
-    up = x @ _w(lp, "w_up", x.dtype)
-    if cfg.activation == "gelu":
-        act = jax.nn.gelu(gate.astype(jnp.float32), approximate=True).astype(x.dtype)
-    elif cfg.activation == "swiglu_oss":
-        g = jnp.clip(gate.astype(jnp.float32), max=7.0)
-        act = (g * jax.nn.sigmoid(1.702 * g)).astype(x.dtype)
-        up = jnp.clip(up.astype(jnp.float32), -7.0, 7.0).astype(x.dtype) + 1.0
-    else:
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
-    return (act * up) @ _w(lp, "w_down", x.dtype)
+                counts = jnp.zeros((lp["router"].shape[-1],), jnp.int32)
+        else:
+            out = moe_mlp(
+                *args, return_counts=return_counts, layer=layer,
+                first_expert=cfg.moe_first_expert,
+                use_pallas=use_pallas and kernel_mesh is None, **kwargs
+            )
+            if return_counts:
+                out, counts = out
+        if "shared_up" in lp:
+            # every chip that shares the layer computes it alike: it is
+            # counted once where the shares are summed
+            with jax.named_scope("shared_expert"):
+                out = out + _ffn(
+                    cfg, lp, x, ("shared_gate", "shared_up", "shared_down")
+                )
+        return (out, counts) if return_counts else out
+    return _ffn(cfg, lp, x, ("w_gate", "w_up", "w_down"))
 
 
 def attention_mixer(
@@ -692,19 +751,44 @@ def ssd_pending(
     Cs = Cq[row]                                              # [NS, T, G*N]
     state = pool.astype(jnp.float32)
     yS = jnp.stack([
-        jnp.sum(state * over_state(Cs[:, t], G, I), axis=1)
+        jnp.concatenate([
+            jnp.sum(state[..., ch] * c, axis=1)
+            for ch, c in zip(group_channels(I, G), over_state(Cs[:, t], G))
+        ], axis=-1)
         for t in range(T)
     ], axis=1)                                                # [NS, T, I]
     inter = per_channel(jnp.exp(cum_q), P) * yS[slots]
     return y + jnp.where(fresh[:, None, None], 0.0, inter)
 
 
-def over_state(c: jax.Array, groups: int, inner: int) -> jax.Array:
-    """[NS, G*N] -> [NS, N, 1 or I]: a token's B (or C) as it multiplies
-    a state laid out [NS, N, I]; one group broadcasts over the channels,
-    several each over its heads' channels."""
+def over_state(c: jax.Array, groups: int):
+    """A token's B (or C), [NS, G*N], as it multiplies a state laid out
+    [NS, N, I]: a list of factors [NS, N, 1], one a group, each for the
+    channels that group's heads hold (``group_channels``). A caller
+    multiplies the state a group's channels at a time and joins the
+    pieces: repeated out to the channels instead, the factor is a
+    float32 copy the size of a layer's pool beside the pool's own at 8
+    groups (0.54 GB at 257 slots), and seen ``[..., G, I / G]`` the
+    state is re-tiled, which is a copy too."""
     c = jnp.swapaxes(c.reshape(c.shape[0], groups, -1), 1, 2)  # [NS, N, G]
-    return c if groups == 1 else jnp.repeat(c, inner // groups, axis=-1)
+    return [c[..., g : g + 1] for g in range(groups)]
+
+
+def group_channels(inner: int, groups: int):
+    """The slice of the I channels each group's heads hold."""
+    w = inner // groups
+    return [slice(g * w, (g + 1) * w) for g in range(groups)]
+
+
+def grouped_rms(y: jax.Array, groups: int, eps: float) -> jax.Array:
+    """``y`` [..., I] over the root of its mean square, the mean taken
+    over one of ``groups`` runs of ``I / groups`` channels at a time
+    (all of ``I`` at one group: the plain expression, no reshape)."""
+    if groups == 1:
+        return y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    g = y.reshape(y.shape[:-1] + (groups, y.shape[-1] // groups))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return g.reshape(y.shape)
 
 
 def mamba_mixer(
@@ -726,7 +810,9 @@ def mamba_mixer(
         S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T ;  y_t = S_t C_t + D x_t
         out = (RMSNorm(y * silu(z)) * w_norm) W_out
 
-    The recurrence and the gate norm in float32. Tokens past a row's
+    The norm's mean is taken over a GROUP's ``I / mamba_groups``
+    channels at a time (all of ``I`` at one group). The recurrence and
+    the gate norm in float32. Tokens past a row's
     ``valid_len`` get ``dt`` 0, so the state after the chunk is the
     state after ``valid_len`` tokens. ``pending`` False (prefill): the
     chunked scan from the row's state (gathered from its slot) to the
@@ -786,8 +872,7 @@ def mamba_mixer(
         cols = valid_len[:, None] + jnp.arange(K - 1, dtype=jnp.int32)
         out["ssm_conv"] = jnp.take_along_axis(ext, cols[..., None], axis=1)
     y = y + per_channel(lp["d_skip"].astype(f32), P) * x
-    y = y * jax.nn.silu(z.astype(f32))
-    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+    y = grouped_rms(y * jax.nn.silu(z.astype(f32)), G, cfg.norm_eps)
     y = (y * lp["gate_norm"].astype(f32)).astype(u.dtype)
     return y @ _w(lp, "w_out", u.dtype), out
 
@@ -875,9 +960,17 @@ def _check_mixed(cfg: ModelConfig) -> None:
             f"{cfg.name}: layer_types has {len(cfg.mixers)} entries for "
             f"{cfg.num_layers} layers"
         )
-    unknown = set(cfg.mixers) - set(_MIXER_STACK)
+    unknown = set(cfg.mixers) - set(_MIXER_STACK) - {"none"}
     if unknown:
         raise ValueError(f"{cfg.name}: unknown layer kinds {sorted(unknown)}")
+    if "moe" in cfg.ffns and not cfg.moe_experts:
+        raise ValueError(f"{cfg.name}: a routed block needs moe_experts")
+    if cfg.moe_first_expert + cfg.experts_held > cfg.moe_experts:
+        raise ValueError(
+            f"{cfg.name}: experts {cfg.moe_first_expert}.."
+            f"{cfg.moe_first_expert + cfg.experts_held} are not among the "
+            f"router's {cfg.moe_experts}"
+        )
     if cfg.num_conv_layers and cfg.conv_kernel < 2:
         raise ValueError(f"{cfg.name}: conv layers need conv_kernel >= 2")
     if cfg.num_mamba_layers and (
@@ -1016,10 +1109,23 @@ def _mixed_trunk(
     def take(stack, idx):
         return jax.tree_util.tree_map(lambda a: a[idx], stack)
 
+    def scaled(y):
+        return y if r == 1.0 else y * jnp.asarray(r, y.dtype)
+
     def block(h, mixer, m_idx, ffn, f_idx):
+        """One block: its mixer, then its FFN, each under its own norm
+        and residual add; "none" for the one a block of ONE sublayer
+        lacks."""
+        out = {}
+        if mixer != "none":
+            h = h + scaled(mix(h, mixer, m_idx, out))
+        if ffn != "none":
+            h = h + scaled(feed(h, ffn, f_idx, out))
+        return h, out
+
+    def mix(h, mixer, m_idx, out):
         lp = take(stacks[_MIXER_STACK[mixer]], m_idx)
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps, False)
-        out = {}
         if mixer == "conv":
             with jax.named_scope("conv_mixer"):
                 y, out["conv"] = conv_mixer(cfg, lp, x, conv_state[m_idx])
@@ -1057,7 +1163,9 @@ def _mixed_trunk(
                     kernel_mesh=kernel_mesh, yarn=yarn,
                     live_window=cfg.sliding_window if swa else 0,
                 )
-        h = h + (y if r == 1.0 else y * jnp.asarray(r, y.dtype))
+        return y
+
+    def feed(h, ffn, f_idx, out):
         experts = {
             k: v for k, v in stacks[ffn].items() if k.startswith("we_")
         }
@@ -1078,15 +1186,15 @@ def _mixed_trunk(
         else:
             with jax.named_scope("dense_ffn"):
                 y = _mlp(cfg, fp, x)
-        return h + (y if r == 1.0 else y * jnp.asarray(r, y.dtype)), out
+        return y
 
     outs: Dict[str, list] = {
         k: [] for k in ("k", "v", "wk", "wv", "conv", "route") + _SSM_KEYS
     }
     for first, period, repeats in layer_groups(cfg):
         span = range(first, first + period)
-        per_mixer = {m: [mixers[l] for l in span].count(m) for m in _MIXER_STACK}
-        per_ffn = {f: [ffns[l] for l in span].count(f) for f in ("dense", "moe")}
+        per_mixer = {m: [mixers[l] for l in span].count(m) for m in set(mixers)}
+        per_ffn = {f: [ffns[l] for l in span].count(f) for f in set(ffns)}
 
         def body(h, rep, span=span, per_mixer=per_mixer, per_ffn=per_ffn):
             ys: Dict[str, list] = {k: [] for k in outs}

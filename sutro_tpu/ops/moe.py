@@ -56,15 +56,36 @@ from . import lowering, pallas_gmm
 def _grouped(
     lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     use_pallas: bool = False, layer: "jax.Array | None" = None,
+    transposed: bool = False,
 ):
     """Grouped GEMM over rows sorted by group: the Pallas kernel when the
     caller runs its kernels and the shapes allow (ops/pallas_gmm.py),
     else ``jax.lax.ragged_dot``. With ``layer``, ``rhs`` is the flat
-    stack ``[L*E, ...]`` and ``group_sizes`` that layer's ``[E]``."""
+    stack ``[L*E, ...]`` and ``group_sizes`` that layer's ``[E]``.
+    ``transposed``: each matrix of ``rhs`` lies output-major, [N, K]."""
     if use_pallas:
-        if pallas_gmm.grouped_matmul_supported(lhs, rhs):
-            return pallas_gmm.grouped_matmul(lhs, rhs, group_sizes, layer)
+        # the kernel takes rows in whole sublanes: a few zero rows ride
+        # the last group (6 rows: one row's top-6 in the numbers check)
+        M = lhs.shape[0]
+        pad = -M % 8
+        rows = jnp.pad(lhs, ((0, pad), (0, 0))) if pad else lhs
+        if pallas_gmm.grouped_matmul_supported(rows, rhs, transposed):
+            sizes = group_sizes.at[-1].add(pad) if pad else group_sizes
+            out = pallas_gmm.grouped_matmul(
+                rows, rhs, sizes, layer, transposed=transposed
+            )
+            return out[:M] if pad else out
         lowering.record_reference(lowering.GROUPED)
+    if transposed:
+        # ``ragged_dot`` wants each matrix input-major: this layer's
+        # experts alone, transposed (a copy of them a call: the path of
+        # a CPU and of ``use_pallas`` off), behind a barrier: the TPU
+        # compiler fails on a ragged dot it has folded a transpose into
+        if layer is not None:
+            E = group_sizes.shape[0]
+            rhs = jax.lax.dynamic_slice_in_dim(rhs, layer * E, E, axis=0)
+            layer = None
+        rhs = jax.lax.optimization_barrier(jnp.swapaxes(rhs, -1, -2))
     if layer is not None:
         # the whole stack, the other layers' groups empty
         group_sizes = jax.lax.dynamic_update_slice(
@@ -84,6 +105,7 @@ def _route(
     select_bias=None,     # [E] or None: added for the top-k only
     renorm: bool = True,
     scale: float = 1.0,
+    renorm_eps: float = 1e-6,
 ):
     """Shared routing: fp32 logits -> top-k -> weights (module
     docstring: the router's forms), plus the flattened [N*top_k]
@@ -102,7 +124,7 @@ def _route(
         _, top_idx = jax.lax.top_k(chosen_by, top_k)              # [N, K]
         probs = jnp.take_along_axis(scores, top_idx, axis=-1)
         if renorm:
-            probs = probs / (jnp.sum(probs, axis=-1, keepdims=True) + 1e-6)
+            probs = probs / (jnp.sum(probs, axis=-1, keepdims=True) + renorm_eps)
     elif score != "softmax":
         raise ValueError(f"unknown router score {score!r}")
     elif select_bias is not None:
@@ -123,6 +145,53 @@ def _route(
     return top_idx, probs, flat_expert, flat_token, flat_prob
 
 
+def held_rows(flat_expert, flat_token, flat_prob, first, held: int):
+    """The expanded rows as a layer that holds experts ``first ..
+    first + held`` takes them: its own rows first, grouped by LOCAL
+    expert (a stable sort keeps token order), the others after. Returns
+    ``(local [M], token [M], weight [M], group_sizes [held])``, sorted:
+    ``local`` is ``held`` and ``weight`` 0 on a row that is not this
+    layer's. Static shapes, no capacity factor, no dropped token: the
+    tail rides the LAST held group (``local`` clipped to it indexes a
+    bias) and the caller zeroes its inputs, so what it adds is exactly
+    nothing."""
+    loc = flat_expert - first
+    owned = jnp.logical_and(loc >= 0, loc < held)
+    key = jnp.where(owned, loc, held)
+    order = jnp.argsort(key, stable=True)
+    s_key = key[order]
+    counts = jnp.bincount(s_key, length=held + 1)
+    group_sizes = (
+        counts[:held].at[held - 1].add(counts[held]).astype(jnp.int32)
+    )
+    weight = jnp.where(owned, flat_prob, 0.0)[order]
+    return s_key, flat_token[order], weight, group_sizes
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    """``relu(x)^2``, squared in float32."""
+    r = jax.nn.relu(x.astype(jnp.float32))
+    return (r * r).astype(x.dtype)
+
+
+def _hidden(gate, up: jax.Array, activation: str) -> jax.Array:
+    """An expert's hidden row: ``act(gate) * up``, or, for an expert of
+    two matrices (``gate`` None), ``relu(up)^2``."""
+    if gate is not None:
+        a, u = _act(gate, up, activation)
+        return a * u
+    if activation != "relu2":
+        raise ValueError(
+            f"an expert of two matrices takes relu2, not {activation!r}"
+        )
+    return relu2(up)
+
+
+def _each(experts, fn):
+    """``fn`` over (gate, up, down); an absent gate stays None."""
+    return [None if w is None else fn(w) for w in experts]
+
+
 def _act(gate: jax.Array, up: jax.Array, activation: str):
     if activation == "gelu":
         a = jax.nn.gelu(gate.astype(jnp.float32), approximate=True)
@@ -139,13 +208,14 @@ def _act(gate: jax.Array, up: jax.Array, activation: str):
 def moe_mlp(
     x: jax.Array,          # [B, T, H]
     router: jax.Array,     # [H, E]
-    we_gate: jax.Array,    # [E, H, F]
-    we_up: jax.Array,      # [E, H, F]
+    we_gate,               # [E, H, F]; None: experts of two matrices
+    we_up: jax.Array,      # [E, H, F]; [E, F, H] where ``we_gate`` is None
     we_down: jax.Array,    # [E, F, H]
     *,
     top_k: int,
     activation: str = "silu",
     method: str = "auto",
+    first_expert: int = 0,
     router_b: "jax.Array | None" = None,   # [E]
     bias_gate: "jax.Array | None" = None,  # [E, F]  (gpt-oss)
     bias_up: "jax.Array | None" = None,    # [E, F]
@@ -156,8 +226,13 @@ def moe_mlp(
     layer: "jax.Array | None" = None,
 ):
     """The routed MLP over ``x``. With ``return_counts`` also the rows
-    each expert got, [E] int32 (every row of ``x`` counts, padding
-    too: it is what the grouped products compute).
+    each expert got, [E] int32 over the ROUTER's experts, held here or
+    not (every row of ``x`` counts, padding too: it is what the grouped
+    products compute).
+
+    ``we_*`` with fewer experts than the router has outputs are a held
+    share that starts at ``first_expert`` (module docstring); it takes
+    the ragged path at every size, whose sort is its definition.
 
     With ``layer`` (scalar int32) the ``we_*`` are the STACKS of every
     routed layer, [L, E, H, F] / [L, E, F, H], and this layer's experts
@@ -171,11 +246,15 @@ def moe_mlp(
     E = router.shape[-1]
     N = B * T
     xt = x.reshape(N, H)
-    if layer is not None and (
-        method == "dense" or (method == "auto" and E <= 8)
-    ):
+    held = we_up.shape[-3]
+    share = held != E
+    if method == "auto":
+        method = "dense" if E <= 8 and not share else "ragged"
+    if layer is not None and method == "dense":
         # small E (the dense path): one layer's experts, sliced
-        we_gate, we_up, we_down = we_gate[layer], we_up[layer], we_down[layer]
+        we_gate, we_up, we_down = _each(
+            (we_gate, we_up, we_down), lambda w: w[layer]
+        )
         layer = None
 
     top_idx, probs, flat_expert, flat_token, flat_prob = _route(
@@ -187,46 +266,56 @@ def moe_mlp(
             return out
         return out, jnp.bincount(flat_expert, length=E).astype(jnp.int32)
 
-    if method == "auto":
-        method = "dense" if E <= 8 else "ragged"
-
     if method == "dense":
         gates = jnp.zeros((N, E), jnp.float32)
         gates = gates.at[jnp.arange(N)[:, None], top_idx].add(probs)
-        g = jnp.einsum("nh,ehf->nef", xt, we_gate)
-        u = jnp.einsum("nh,ehf->nef", xt, we_up)
+        if share:
+            gates = gates[:, first_expert : first_expert + held]
+        g = None
+        if we_gate is None:
+            u = jnp.einsum("nh,efh->nef", xt, we_up)
+        else:
+            u = jnp.einsum("nh,ehf->nef", xt, we_up)
+            g = jnp.einsum("nh,ehf->nef", xt, we_gate)
         if bias_gate is not None:
             g = g + bias_gate[None].astype(g.dtype)
             u = u + bias_up[None].astype(u.dtype)
-        a, u = _act(g, u, activation)
-        y = jnp.einsum("nef,efh->neh", a * u, we_down)
+        y = jnp.einsum("nef,efh->neh", _hidden(g, u, activation), we_down)
         if bias_down is not None:
             y = y + bias_down[None].astype(y.dtype)
         out = jnp.einsum("ne,neh->nh", gates.astype(y.dtype), y)
         return result(out.reshape(B, T, H))
 
     # ragged grouped-GEMM path
-    order = jnp.argsort(flat_expert)                      # stable order by expert
-    sorted_expert = flat_expert[order]
-    sorted_token = flat_token[order]
-    sorted_prob = flat_prob[order]
-    group_sizes = jnp.bincount(sorted_expert, length=E).astype(jnp.int32)
+    if share:
+        sorted_expert, sorted_token, sorted_prob, group_sizes = held_rows(
+            flat_expert, flat_token, flat_prob, first_expert, held
+        )
+        mine = sorted_expert < held
+        lhs = xt[sorted_token] * mine[:, None].astype(xt.dtype)
+        sorted_expert = jnp.minimum(sorted_expert, held - 1)
+    else:
+        order = jnp.argsort(flat_expert)                  # stable order by expert
+        sorted_expert = flat_expert[order]
+        sorted_token = flat_token[order]
+        sorted_prob = flat_prob[order]
+        group_sizes = jnp.bincount(sorted_expert, length=E).astype(jnp.int32)
+        lhs = xt[sorted_token]                            # [M, H]
     if layer is not None:  # the stacks seen flat: a bitcast
-        we_gate, we_up, we_down = (
-            w.reshape((-1,) + w.shape[2:]) for w in (we_gate, we_up, we_down)
+        we_gate, we_up, we_down = _each(
+            (we_gate, we_up, we_down),
+            lambda w: w.reshape((-1,) + w.shape[2:]),
         )
     grouped = functools.partial(
         _grouped, group_sizes=group_sizes, use_pallas=use_pallas, layer=layer
     )
 
-    lhs = xt[sorted_token]                                # [M, H]
-    g = grouped(lhs, we_gate)                             # [M, F]
-    u = grouped(lhs, we_up)
+    g = None if we_gate is None else grouped(lhs, we_gate)  # [M, F]
+    u = grouped(lhs, we_up, transposed=we_gate is None)
     if bias_gate is not None:
         g = g + bias_gate[sorted_expert].astype(g.dtype)
         u = u + bias_up[sorted_expert].astype(u.dtype)
-    a, u = _act(g, u, activation)
-    y = grouped(a * u, we_down)                           # [M, H]
+    y = grouped(_hidden(g, u, activation), we_down)       # [M, H]
     if bias_down is not None:
         y = y + bias_down[sorted_expert].astype(y.dtype)
     y = y * sorted_prob[:, None].astype(y.dtype)

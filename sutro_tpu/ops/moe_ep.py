@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .moe import _act, _grouped, _route
+from .moe import _act, _grouped, _route, held_rows
 
 
 def moe_mlp_ep(
@@ -84,27 +84,18 @@ def moe_mlp_ep(
         El = wg.shape[0]
         N = Bl * Tl
         K = top_k
-        M = N * K
         eidx = jax.lax.axis_index("expert")
         xt = x_s.reshape(N, H)
 
         _, _, flat_expert, flat_token, flat_prob = _route(
             xt, router, rb, K, select_bias=sb, **route
         )
-        loc = flat_expert - eidx * El                        # local id
-        owned = jnp.logical_and(loc >= 0, loc < El)
-        # owned rows first, grouped by local expert; unowned pushed to
-        # a trailing pseudo-group El (stable sort keeps token order)
-        key = jnp.where(owned, loc, El)
-        order = jnp.argsort(key, stable=True)
-        s_key = key[order]
-        s_token = flat_token[order]
-        s_weight = jnp.where(owned, flat_prob, 0.0)[order]   # [M]
-        counts = jnp.bincount(s_key, length=El + 1)
-        # unowned tail rides the last real group with zeroed inputs —
+        # this shard's share of the rows (ops/moe.py ``held_rows``, the
+        # one definition of a held share): its own first, grouped by
+        # local expert; the others zero-masked into the trailing group:
         # static shapes, no capacity factor, no dropped tokens
-        group_sizes = (
-            counts[:El].at[El - 1].add(counts[El]).astype(jnp.int32)
+        s_key, s_token, s_weight, group_sizes = held_rows(
+            flat_expert, flat_token, flat_prob, eidx * El, El
         )
         s_eidx = jnp.minimum(s_key, El - 1)                  # bias index
 

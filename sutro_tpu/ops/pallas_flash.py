@@ -53,7 +53,12 @@ NEG_INF = -1e30
 
 BLOCK_Q = 128
 BLOCK_K = 128
-MAX_GROUP = 8  # scratch is [G, BQ, *]; cap G so VMEM stays bounded
+# scratch is [G, BQ, *] in float32; cap G so VMEM stays bounded. At G = 16
+# (32 query heads over 2 K/V heads) and Dh = 128: m and l 2 x 16 x 128 x
+# 128 x 4 B = 2 MB, acc 16 x 128 x 128 x 4 B = 1 MB, the double-buffered
+# bf16 q and out blocks 4 x 0.5 MB, K and V blocks 128 KB: 5.2 MB of the
+# 16 MB a v5e core's kernel may take
+MAX_GROUP = 16
 
 
 def _flash_kernel(

@@ -47,15 +47,37 @@ from . import lowering
 SUB_ROWS = 128
 #: bytes one buffer of an expert's ``[K, tn]`` block may take in VMEM
 RHS_BLOCK_BYTES = 8 << 20
+#: and where the width has no legal halving (off the 128 grid), what one
+#: buffer of the expert's WHOLE matrix may take: two of them, the row
+#: tiles and the float32 product stay under 48 MiB of a v5e core's 128
+WHOLE_RHS_BYTES = 16 << 20
 
 
-def grouped_matmul_supported(lhs: jax.Array, rhs: jax.Array) -> bool:
-    """Static gate: shapes on the 128-lane grid, rows in whole sublanes,
-    operands of one dtype the MXU takes."""
+def grouped_matmul_supported(
+    lhs: jax.Array, rhs: jax.Array, transposed: bool = False
+) -> bool:
+    """Static gate: rows in whole sublanes, operands of one dtype the
+    MXU takes, and the expert's matrix on the 128-lane grid both ways,
+    or off it in whole sublane packs and small enough to be ONE block
+    (``WHOLE_RHS_BYTES``: a block's dimension must be on the grid or
+    the array's whole). A width N off the grid is taken only where the
+    matrix lies output-major (``transposed``: ``rhs`` is ``[G, N, K]``):
+    the device keeps an array's ALIGNED axis minor and hands a custom
+    call its operand minor-last, so ``[G, K, N]`` would be copied,
+    whole, every call."""
     M, K = lhs.shape
-    N = rhs.shape[-1]
+    if transposed:
+        N, Kr = rhs.shape[-2:]
+    else:
+        Kr, N = rhs.shape[-2:]
+    on_grid = K % 128 == 0 and N % 128 == 0
+    one_block = (
+        K % 16 == 0 and N % 16 == 0
+        and (N % 128 == 0 or transposed)
+        and K * N * lhs.dtype.itemsize <= WHOLE_RHS_BYTES
+    )
     return (
-        K % 128 == 0 and N % 128 == 0 and M % 8 == 0 and M > 0
+        (on_grid or one_block) and Kr == K and M % 8 == 0 and M > 0
         and lhs.dtype == rhs.dtype
         and lhs.dtype in (jnp.bfloat16, jnp.float32)
     )
@@ -76,6 +98,10 @@ def _tiles(M: int, K: int, N: int, itemsize: int):
     tn = N
     while K * tn * itemsize > RHS_BLOCK_BYTES and tn % 256 == 0:
         tn //= 2
+    if K * tn * itemsize > RHS_BLOCK_BYTES:
+        # a block that has no legal halving (2,688 = 21 x 128, or a
+        # matrix off the grid, taken whole): small row tiles beside it
+        tm = ts
     return tm, ts, tn
 
 
@@ -102,7 +128,8 @@ def _visits(group_sizes: jax.Array, M: int, tm: int):
     return offsets, group, tile, count[None]
 
 
-def _kernel(offsets, group, tile, count, base, x_ref, w_ref, o_ref, *, ts):
+def _kernel(offsets, group, tile, count, base, x_ref, w_ref, o_ref, *, ts,
+            transposed):
     del base  # the index maps' (this layer's first group in the stack)
     v = pl.program_id(1)
     tm, tn = o_ref.shape
@@ -120,7 +147,7 @@ def _kernel(offsets, group, tile, count, base, x_ref, w_ref, o_ref, *, ts):
             def _sub_tile():
                 acc = jax.lax.dot_general(
                     x_ref[rows, :], w_ref[...],
-                    (((1,), (0,)), ((), ())),
+                    (((1,), (1 if transposed else 0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
                 row = r0 + jax.lax.broadcasted_iota(jnp.int32, (ts, tn), 0)
@@ -132,7 +159,7 @@ def _kernel(offsets, group, tile, count, base, x_ref, w_ref, o_ref, *, ts):
                 ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "transposed"))
 def grouped_matmul(
     lhs: jax.Array,          # [M, K]: rows sorted by group
     rhs: jax.Array,          # [G, K, N], G >= (layer + 1) * E
@@ -140,13 +167,19 @@ def grouped_matmul(
     layer: "jax.Array | None" = None,  # scalar int32: groups layer*E ..
     *,
     interpret: bool = False,
+    transposed: bool = False,
 ) -> jax.Array:
     """Returns ``[M, N]`` with ``out[i] = lhs[i] @ rhs[layer*E + g(i)]``
     where ``g(i)`` is row i's group by ``group_sizes``; every row belongs
-    to a group (the sizes sum to ``M``)."""
+    to a group (the sizes sum to ``M``). ``transposed``: ``rhs`` is
+    ``[G, N, K]``, each expert's matrix output-major, and the product
+    contracts both operands' minor axis (``lhs[i] @ rhs[g].T``): how a
+    matrix whose width N is off the 128-lane grid is read where it lies
+    (the device keeps an array's aligned axis minor, and a custom call's
+    operand minor-last: ``[G, K, N]`` would be copied, whole, a call)."""
     lowering.record_kernel(lowering.GROUPED, interpret=interpret)
     M, K = lhs.shape
-    N = rhs.shape[-1]
+    N = rhs.shape[-2] if transposed else rhs.shape[-1]
     E = group_sizes.shape[0]
     tm, ts, tn = _tiles(M, K, N, lhs.dtype.itemsize)
     group_sizes = group_sizes.astype(jnp.int32)
@@ -161,7 +194,7 @@ def grouped_matmul(
         2 * (tm * K + K * tn + tm * tn) * itemsize + 3 * ts * tn * 4
     )
     return pl.pallas_call(
-        functools.partial(_kernel, ts=ts),
+        functools.partial(_kernel, ts=ts, transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             # the column blocks outermost: an output block's visits are
@@ -172,6 +205,9 @@ def grouped_matmul(
                     (tm, K), lambda n, v, o, g, t, c, b: (t[v], 0)
                 ),
                 pl.BlockSpec(
+                    (None, tn, K),
+                    lambda n, v, o, g, t, c, b: (b[0] + g[v], n, 0),
+                ) if transposed else pl.BlockSpec(
                     (None, K, tn),
                     lambda n, v, o, g, t, c, b: (b[0] + g[v], 0, n),
                 ),

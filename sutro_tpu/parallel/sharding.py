@@ -52,6 +52,11 @@ _LAYER_RULES: Dict[str, P] = {
     "we_gate": P(None, "expert", None, "model"),  # [L, E, H, F]
     "we_up": P(None, "expert", None, "model"),
     "we_down": P(None, "expert", "model", None),  # [L, E, F, H]
+    "we_up_t": P(None, "expert", "model", None),  # [L, E, F, H]: two-matrix
+    #                                               experts' first, output-major
+    "shared_gate": P(None, None, "model"),        # [L, H, Fs]
+    "shared_up": P(None, None, "model"),
+    "shared_down": P(None, "model", None),        # [L, Fs, H]
     "we_gate_b": P(None, "expert", "model"),      # [L, E, F]
     "we_up_b": P(None, "expert", "model"),
     "we_down_b": P(None, "expert", None),         # [L, E, H]

@@ -331,6 +331,14 @@ MOE_ROUTED_ROWS_TOTAL = REGISTRY.counter(
     "dispatch, fetched with its tokens)",
     unit="rows",
 )
+MOE_ROWS_ELSEWHERE_TOTAL = REGISTRY.counter(
+    "sutro_moe_rows_elsewhere_total",
+    "Row-expert pairs the routers sent to experts this chip does not "
+    "hold (a model told its share of each layer's experts: another "
+    "chip's work, left out here), summed over routed layers; beside "
+    "sutro_moe_routed_rows_total, which counts the pairs computed",
+    unit="rows",
+)
 KV_PAGES_FETCHED_TOTAL = REGISTRY.counter(
     "sutro_kv_pages_fetched_total",
     "K/V pages the decode dispatches' attention fetched, a row, a step "
