@@ -20,7 +20,8 @@ numbers, no benchmark metric); the cell's end-to-end metrics; and the
 tokens
 the accept loops committed beside the tokens the progress stream
 counted inside the window (``Reading.window_output_tokens``, the
-divisor of the per-token metrics) and the burst rate. A builder's tool, outside the
+divisor of the per-token metrics) and the burst rate; and how many admission waves the window resolved
+for how many rows (rows a host sync). A builder's tool, outside the
 harness: no metric. The JSON goes to
 ``chiprun_out/perfbench/<cell>.seed<n>.yield.json``.
 
@@ -54,6 +55,13 @@ def deltas(r, name):
     for reg in (r.reg0, r.reg1):
         keys |= set((reg.get(name) or {}).get("series", {}))
     return {k: r.counter_delta(name, k) for k in sorted(keys)}
+
+
+def admission(r):
+    waves = r.counter_delta("sutro_admit_waves_total")
+    rows = r.counter_delta("sutro_admit_wave_rows_total")
+    return {"waves": waves, "rows": rows,
+            "rows_a_sync": rows / waves if waves else None}
 
 
 def table(r):
@@ -171,6 +179,10 @@ def main(argv=None) -> int:
         # progress stream's ticks clipped to the window
         "window_output_tokens": r.window_output_tokens(),
         "dispatch_rows": r.counter_delta("sutro_sched_dispatch_rows_total"),
+        # how often admission waited for the device, and for how many
+        # rows (OBSERVABILITY.md "The admission wave"; a tree without
+        # the counters reads 0 and no ratio)
+        "admission": admission(r),
         # tokens over seconds between the window's first and last
         # progress update: the pace that differs by seed in the
         # classify cell, beside the verify forwards' share of it

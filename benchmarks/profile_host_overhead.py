@@ -70,15 +70,25 @@ class _StubRunner:
     def max_context(self) -> int:
         return self.ecfg.max_pages_per_seq * self.ecfg.kv_page_size
 
-    def prefill_batch(self, prompts, tables):
-        B = len(prompts)
-        return np.zeros((B, self.vocab), np.float32)
+    def _logits(self, n, on_device):
+        if not on_device:
+            return np.zeros((n, self.vocab), np.float32)
+        # what the scheduler asks for (ModelRunner._prefill_out): the
+        # program's own row bucket, and the routing counts (none)
+        from sutro_tpu.engine.runner import next_bucket
 
-    def prefill_batch_at(self, rows, page_tables, starts):
-        return np.zeros((len(rows), self.vocab), np.float32)
+        B = next_bucket(n, 1, 1 << 16)
+        return np.zeros((B, self.vocab), np.float32), None
 
-    def prefill(self, prompt, table, start=0):
-        return np.zeros((self.vocab,), np.float32)
+    def prefill_batch(self, prompts, tables, on_device=False):
+        return self._logits(len(prompts), on_device)
+
+    def prefill_batch_at(self, rows, page_tables, starts, on_device=False):
+        return self._logits(len(rows), on_device)
+
+    def prefill(self, prompt, table, start=0, on_device=False):
+        out = self._logits(1, on_device)
+        return out if on_device else out[0]
 
     def merge_last(self, prev_last, refresh_mask, refresh_vals):
         return np.where(
@@ -188,10 +198,12 @@ NOMINAL_INTERACTIVE_TTFT_US = 50_000.0
 
 
 def warm_admit_buckets(vocab: int, ecfg) -> None:
-    """Compile every admission-sample shape bucket up front. Group
-    sizes are power-of-two bucketed (scheduler._sample_batch), but
-    WHICH buckets a run hits depends on completion order — the two
-    warm sessions can miss one, and the timed pass then eats a ~0.4 s
+    """Compile every admission-sample shape bucket up front. The
+    sample runs over the prefill program's own logits, so its row
+    count is the prefill's power-of-two row bucket
+    (scheduler._sample_first; runner._prefill_out), but WHICH
+    buckets a run hits depends on completion order — the two warm
+    sessions can miss one, and the timed pass then eats a ~0.4 s
     XLA:CPU compile that is not steady-state host bookkeeping (seen
     reproducibly at B=128)."""
     import jax as _jax

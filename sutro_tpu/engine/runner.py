@@ -978,11 +978,26 @@ class ModelRunner:
         )
         return logits[:, 0], cache, self._route_stats(k)
 
+    def _prefill_out(self, logits, route, n: int, on_device: bool):
+        """What a prefill entry point returns. ``on_device``: the
+        program's own ``(logits [B, V], routing counts)`` where they
+        lie, with no wait for the program (rows past ``n`` are the
+        bucket's padding; the counts are None for a model that counts
+        none): the scheduler samples first tokens from them on the
+        device and fetches a whole admission wave at once. Otherwise the
+        ``n`` real rows on the host, a wait for the program, the counts
+        left for ``take_route_stats``."""
+        if on_device:
+            return logits, route
+        self._route_dev = route
+        return np.asarray(logits[:n])
+
     def prefill(
         self, token_ids: np.ndarray, page_table: np.ndarray,
-        start: int = 0,
-    ) -> np.ndarray:
-        """One prompt ([T] int32) -> last-position logits [V]. ``page_table``
+        start: int = 0, on_device: bool = False,
+    ):
+        """One prompt ([T] int32) -> last-position logits [V]
+        (``on_device``: ``_prefill_out``). ``page_table``
         is the slot's [MP] row.
 
         Long prompts (> ``prefill_chunk``) are processed in fixed-size
@@ -1006,9 +1021,11 @@ class ModelRunner:
         )
         self._bind_fresh(page_table, [start])
         if start > 0 and n <= C:
-            return self.prefill_batch_at(
-                [token_ids], page_table[None, :], [start]
-            )[0]
+            out = self.prefill_batch_at(
+                [token_ids], page_table[None, :], [start],
+                on_device=on_device,
+            )
+            return out if on_device else out[0]
         if (start > 0 or n > C) and self.sp == 1 and self.pp == 1:
             table_dev = jnp.asarray(page_table[None, :], jnp.int32)
             for off in range(0, n, C):
@@ -1017,7 +1034,7 @@ class ModelRunner:
                 ids[0, : len(seg)] = seg
                 self._count_state_commit("chunk")
                 self._bind_window(page_table, [start + off], [len(seg)])
-                logits, self.cache, self._route_dev = self._prefill_chunk_jit(
+                logits, self.cache, route = self._prefill_chunk_jit(
                     self.params,
                     self.cache,
                     jnp.asarray(ids),
@@ -1030,7 +1047,8 @@ class ModelRunner:
                 self.release_window_behind(
                     page_table, [start + off + len(seg)]
                 )
-            return np.asarray(logits[0])
+            out = self._prefill_out(logits, route, 1, on_device)
+            return out if on_device else out[0]
         T = next_bucket(max(n, 1), lo=16, hi=self.ecfg.max_context())
         if T % self.sp:  # ring prefill shards T over the seq axis
             T = -(-T // self.sp) * self.sp
@@ -1038,7 +1056,7 @@ class ModelRunner:
         ids[0, :n] = token_ids
         self._count_state_commit("prefill")
         self._bind_window(page_table, [0], [n])
-        logits, self.cache, self._route_dev = self._prefill_jit(
+        logits, self.cache, route = self._prefill_jit(
             self.params,
             self.cache,
             jnp.asarray(ids),
@@ -1046,13 +1064,16 @@ class ModelRunner:
             jnp.asarray(page_table[None, :], jnp.int32),
             jnp.asarray([0], jnp.int32),
         )
-        return np.asarray(logits[0])
+        out = self._prefill_out(logits, route, 1, on_device)
+        return out if on_device else out[0]
 
     def prefill_batch(
-        self, rows: list, page_tables: np.ndarray
-    ) -> np.ndarray:
+        self, rows: list, page_tables: np.ndarray,
+        on_device: bool = False,
+    ):
         """Batched prefill: N prompts ([Ti] int32 each) in ONE device
-        program -> last-position logits [N, V]. ``page_tables`` is
+        program -> last-position logits [N, V] (``on_device``:
+        ``_prefill_out``). ``page_tables`` is
         [N, MP]. Rows are padded to a (power-of-two x power-of-two)
         [B, T] bucket so compile count stays O(log^2); padding rows carry
         ``valid_len`` 0 and an all-zero table, so their K/V land on the
@@ -1080,7 +1101,7 @@ class ModelRunner:
         self._bind_fresh(tables[:n], [0] * n)
         self._count_state_commit("prefill")
         self._bind_window(tables[:n], [0] * n, lens[:n])
-        logits, self.cache, self._route_dev = self._prefill_jit(
+        logits, self.cache, route = self._prefill_jit(
             self.params,
             self.cache,
             jnp.asarray(ids),
@@ -1088,11 +1109,12 @@ class ModelRunner:
             jnp.asarray(tables),
             jnp.zeros((B,), jnp.int32),
         )
-        return np.asarray(logits[:n])
+        return self._prefill_out(logits, route, n, on_device)
 
     def prefill_batch_at(
-        self, rows: list, page_tables: np.ndarray, starts
-    ) -> np.ndarray:
+        self, rows: list, page_tables: np.ndarray, starts,
+        on_device: bool = False,
+    ):
         """Batched SUFFIX prefill: like ``prefill_batch`` but each row
         begins at global position ``starts[i]``, attending over pages
         that already hold its earlier positions — the per-row dispatch
@@ -1118,7 +1140,7 @@ class ModelRunner:
         self._bind_fresh(tables[:n], st[:n])
         self._count_state_commit("chunk")
         self._bind_window(tables[:n], st[:n], lens[:n])
-        logits, self.cache, self._route_dev = self._prefill_chunk_jit(
+        logits, self.cache, route = self._prefill_chunk_jit(
             self.params,
             self.cache,
             jnp.asarray(ids),
@@ -1127,7 +1149,7 @@ class ModelRunner:
             jnp.asarray(st),
         )
         self.release_window_behind(tables[:n], st[:n] + lens[:n])
-        return np.asarray(logits[:n])
+        return self._prefill_out(logits, route, n, on_device)
 
     # ------------------------------------------------------------------
     # decode

@@ -16,6 +16,9 @@ remote service keeps behind ``POST /batch-inference`` (SURVEY §2.3 row 1,
   (batch x length) bucket (compile-count bounded); each row's
   last-position logits seed its slot's first sampled token. Prompts
   longer than ``prefill_chunk`` prefill alone via the chunked path.
+  Admission waits for the device ONCE an iteration: prefills and their
+  first-token samples are dispatched back to back, the first tokens of
+  the whole wave fetched together (``_resolve_wave``).
 - Order-preserving results: completions are emitted keyed by ``row_id`` and
   re-assembled in input order by the jobstore, while execution order is
   whatever batching dictates (reference contract: README.md:221).
@@ -41,7 +44,7 @@ logger = logging.getLogger(__name__)
 
 from . import faults
 from .kvcache import PageAllocator, pages_needed
-from .runner import ModelRunner, next_bucket
+from .runner import ModelRunner
 from .. import telemetry
 from ..ops.sampling import cumulative_logprob, sample as device_sample
 
@@ -468,6 +471,18 @@ class _DecodeBatch(NamedTuple):
     facts: _DecodeFacts
 
 
+class _WaveEntry(NamedTuple):
+    """One prefill dispatch of the admission wave."""
+
+    # (slot index, slot) pairs its first tokens arm; none for a chunk
+    # that is not its row's last and only has routing counts to hand in
+    rows: Any
+    tok: Any    # [B] first tokens, on the device (None without rows)
+    logp: Any   # [B] their log-probabilities
+    route: Any  # the dispatch's routing counts, or None
+    tokens: int  # prompt tokens it prefilled
+
+
 def _lose(
     lost: Dict[str, int], ctx: Optional[JobCtx], reason: str, n: int
 ) -> None:
@@ -708,6 +723,13 @@ class ContinuousBatcher:
             stage_names=_TEL_STAGE,
             opened=self._tel_opened if self._tel_on else None,
         )
+        # the admission wave: prefills dispatched whose first tokens are
+        # still on the device, in dispatch order; _resolve_wave fetches
+        # the lot in one host sync. When its first dispatch went out,
+        # and how many waves came before it (span attrs)
+        self._wave: List[_WaveEntry] = []
+        self._wave_t0 = 0.0
+        self._wave_seq = 0
         # tokens committed by the accept loops (the accept span's attr)
         self._n_accepted = 0
         # what the dispatch just accepted yielded: (row-steps, tokens
@@ -845,6 +867,7 @@ class ContinuousBatcher:
         during lookup (fault site ``prefixstore.lookup``) degrades to
         a plain miss — the job pays full prefill but never fails.
         Without a store: per-JOB pages, exactly the pre-store path."""
+        self._resolve_wave()
         ctx.prefix = None
         pending = ctx.pending
         ecfg = self.ecfg
@@ -1413,84 +1436,171 @@ class ContinuousBatcher:
     def _admit_batch(self, batch) -> None:
         """``batch`` is a list of ``(req, ctx, slot_idx, pages, table)``
         reservations — possibly spanning JOBS (co-batched admission).
-        Runs ONE batched prefill dispatch + ONE batched first-token
-        sample for all of them, then arms the slots. Each row prefills
-        its own suffix at its job's shared-prefix offset."""
+        Dispatches ONE batched prefill and ONE batched first-token
+        sample over its logits where they lie, on the device, installs
+        the rows' slots and appends the batch to the admission wave:
+        nothing is waited for here. ``_resolve_wave`` fetches the first
+        tokens and arms the slots, a wave at a time; a row whose job
+        streams its tokens is resolved at once, so a chat's first token
+        waits for no row behind it. Each row prefills its own suffix at
+        its job's shared-prefix offset."""
         reqs = [b[0] for b in batch]
         starts = [
             b[1].prefix.tokens if b[1].prefix is not None else 0
             for b in batch
         ]
+        tokens = int(
+            sum(len(r.prompt_ids) - s for r, s in zip(reqs, starts))
+        )
         self.timer.count("rows", len(batch))
         try:
             if self._tel_on:
                 self._tel_attrs["prefill"] = {
-                    "tokens": int(
-                        sum(
-                            len(r.prompt_ids) - s
-                            for r, s in zip(reqs, starts)
-                        )
-                    ),
+                    "tokens": tokens,
                     "batch": len(batch),
+                    "wave": self._wave_seq,
                     **self._state_attrs(len(batch)),
                     **self._kv_attrs(len(r.prompt_ids) for r in reqs),
                 }
             with self.timer.time("prefill"):
                 if len(batch) == 1:
-                    logits = self.runner.prefill(
+                    logits, route = self.runner.prefill(
                         reqs[0].prompt_ids[starts[0] :].astype(np.int32),
-                        batch[0][4], start=starts[0],
-                    )[None]
+                        batch[0][4], start=starts[0], on_device=True,
+                    )
                 elif any(starts):
-                    logits = self.runner.prefill_batch_at(
+                    logits, route = self.runner.prefill_batch_at(
                         [
                             r.prompt_ids[s:].astype(np.int32)
                             for r, s in zip(reqs, starts)
                         ],
                         np.stack([b[4] for b in batch]),
-                        starts,
+                        starts, on_device=True,
                     )
                 else:
-                    logits = self.runner.prefill_batch(
+                    logits, route = self.runner.prefill_batch(
                         [r.prompt_ids.astype(np.int32) for r in reqs],
                         np.stack([b[4] for b in batch]),
+                        on_device=True,
                     )
-                self._note_route("prefill")
-            self.prefill_tokens += sum(
-                len(r.prompt_ids) - s for r, s in zip(reqs, starts)
-            )
-            toks, logps = self._sample_batch(
+            self.prefill_tokens += tokens
+            tok, logp = self._sample_first(
                 logits, reqs, [b[2] for b in batch]
             )
         except Exception:
             for _, _, slot_idx, pages, _ in batch:
                 self._unreserve(slot_idx, pages)
             raise
-        for (req, ctx, slot_idx, pages, _), tok, logp in zip(
-            batch, toks, logps
-        ):
+        rows = []
+        for req, ctx, slot_idx, pages, _ in batch:
             pfx = ctx.prefix
-            first = int(tok)
+            # the slot is taken from here on (_reserve skips it); its
+            # first token, and with it a place in a decode batch, comes
+            # with the wave
             slot = _Slot(
                 req=req,
                 pages=(list(pfx.pages) + list(pages)) if pfx else pages,
                 pos=len(req.prompt_ids),
-                last_token=first,
+                last_token=0,
                 job=ctx,
                 shared_n=pfx.n_pages if pfx else 0,
             )
             ctx.n_slots += 1
-            ctx.stats["in"] += len(req.prompt_ids)
-            ctx.stats["out"] += 1  # the prefill-sampled first token
-            self._seed_penalty_bits(slot, req)
             self.slots[slot_idx] = slot
-            if self.native is not None:
-                self.native.arm_slot(
-                    slot_idx, len(req.prompt_ids), first,
-                    req.temperature, req.top_p, req.top_k,
+            rows.append((slot_idx, slot))
+        self._to_wave(rows, tok, logp, route, tokens)
+        if any(b[1].on_token is not None for b in batch):
+            self._resolve_wave()
+
+    def _to_wave(self, rows, tok, logp, route, tokens: int) -> None:
+        if not self._wave:
+            self._wave_t0 = time.monotonic()
+        self._wave.append(_WaveEntry(rows, tok, logp, route, tokens))
+
+    def _resolve_wave(self) -> None:
+        """The admission wave's ONE host sync: fetch the first tokens
+        (and routing counts) of every prefill dispatched since the last
+        one, then arm the rows in dispatch order. Called when an
+        iteration's admission is over, right after the dispatch of a
+        row that streams, and before anything that may release or move
+        a slot (an eviction, a resume, a suspend, a cancel, a prefix
+        setup) or raise out of admission: outside admission no slot is
+        ever pending (``run_multi``'s ``finally`` resolves what a raise
+        left). Should the fetch or a row's arming raise, the rows not
+        armed yet are given up, as a failed dispatch gives up its own."""
+        rows = [r for e in self._wave for r in e.rows]
+        if not rows:
+            return  # routing counts alone wait for a wave with rows
+        wave, self._wave = self._wave, []
+        n, armed = len(rows), 0
+        try:
+            if self._tel_on:
+                self._tel_attrs["prefill"] = {
+                    "tokens": 0, "wave": self._wave_seq, "wave_rows": n,
+                    "wave_tokens": sum(e.tokens for e in wave),
+                }
+            with self.timer.time("prefill"):
+                got = jax.device_get(
+                    [(e.tok, e.logp, e.route) for e in wave]
                 )
-            self._record_token(slot, first, float(logp))
-            self._deliver_token(slot, first, float(logp))
+                routes = [g[2] for g in got if g[2] is not None]
+                if routes:
+                    self._note_route("prefill", np.stack(routes))
+                if self._tel_on:
+                    # dispatch spans time the host alone: the wave's
+                    # prompt tokens took this long to come back
+                    self._tel_attrs["prefill"]["wave_s"] = round(
+                        time.monotonic() - self._wave_t0, 6
+                    )
+            self._wave_seq += 1
+            if self._tel_on:
+                telemetry.ADMIT_WAVES_TOTAL.inc(1.0)
+                telemetry.ADMIT_WAVE_ROWS_TOTAL.inc(float(n))
+            for e, (toks, logps, _) in zip(wave, got):
+                for k, (i, s) in enumerate(e.rows):
+                    self._arm(i, s, int(toks[k]), float(logps[k]))
+                    armed += 1
+        except BaseException:
+            for i, s in rows[armed:]:
+                if self.slots[i] is s:
+                    self._drop_slot(i)
+            raise
+
+    def _arm(self, i: int, s: _Slot, first: int, logp: float) -> None:
+        """Slot ``i``'s prompt is in its pages and its first token is
+        here: the row joins the decode batch."""
+        req = s.req
+        if self.native is not None:
+            if s.prefilling:
+                row = self.native.table[i]
+                row[:] = 0
+                row[: len(s.pages)] = s.pages
+            self.native.arm_slot(
+                i, len(req.prompt_ids), first,
+                req.temperature, req.top_p, req.top_k,
+            )
+        s.prefilling = False
+        s.ptable = None
+        s.pos = len(req.prompt_ids)
+        s.last_token = first
+        self._seed_penalty_bits(s, req)
+        if s.job is not None:
+            s.job.stats["in"] += len(req.prompt_ids)
+            s.job.stats["out"] += 1  # the prefill-sampled first token
+        self._record_token(s, first, logp)
+        self._deliver_token(s, first, logp)
+
+    def _drop_slot(self, i: int) -> None:
+        """Give slot ``i`` up with no result: its pages and state go
+        back, its job counts one slot less, and windows in flight find
+        the generation changed."""
+        s = self.slots[i]
+        self._unreserve(i, s.pages[s.shared_n :])
+        if s.job is not None:
+            s.job.n_slots -= 1
+        self.slots[i] = None
+        self._gen[i] += 1
+        self._needs_mask.discard(i)
 
     def _seed_penalty_bits(self, slot: _Slot, req: GenRequest) -> None:
         if req.has_penalties():
@@ -1537,7 +1647,8 @@ class ContinuousBatcher:
 
     def _prefill_tick(self) -> None:
         """Advance the lowest-index prefilling slot by ONE chunk; on the
-        final chunk, sample its first token and join the decode batch."""
+        final chunk, sample its first token and join the admission
+        wave (_resolve_wave). Dispatch only: no chunk is waited for."""
         i = next(
             (
                 j
@@ -1554,41 +1665,25 @@ class ContinuousBatcher:
         seg = req.prompt_ids[s.prefill_pos : s.prefill_pos + C]
         if self._tel_on:
             self._tel_attrs["prefill"] = {
-                "tokens": int(len(seg)), **self._state_attrs(1),
+                "tokens": int(len(seg)), "wave": self._wave_seq,
+                **self._state_attrs(1),
                 **self._kv_attrs((s.prefill_pos + len(seg),)),
             }
         with self.timer.time("prefill"):
-            logits = self.runner.prefill_batch_at(
+            logits, route = self.runner.prefill_batch_at(
                 [np.asarray(seg, np.int32)],
                 s.ptable[None, :],
-                [s.prefill_pos],
+                [s.prefill_pos], on_device=True,
             )
-            self._note_route("prefill")
         self.prefill_tokens += len(seg)
         s.prefill_pos += len(seg)
         if s.prefill_pos < len(req.prompt_ids):
+            if route is not None:
+                self._to_wave((), None, None, route, len(seg))
             return
-        # last chunk: sample the first token and activate
-        toks, logps = self._sample_batch(logits, [req], [i])
-        first = int(toks[0])
-        if self.native is not None:
-            row = self.native.table[i]
-            row[:] = 0
-            row[: len(s.pages)] = s.pages
-            self.native.arm_slot(
-                i, len(req.prompt_ids), first,
-                req.temperature, req.top_p, req.top_k,
-            )
-        s.prefilling = False
-        s.ptable = None
-        s.pos = len(req.prompt_ids)
-        s.last_token = first
-        self._seed_penalty_bits(s, req)
-        if s.job is not None:
-            s.job.stats["in"] += len(req.prompt_ids)
-            s.job.stats["out"] += 1  # the prefill-sampled first token
-        self._record_token(s, first, float(logps[0]))
-        self._deliver_token(s, first, float(logps[0]))
+        # last chunk: sample the first token; the wave arms the slot
+        tok, logp = self._sample_first(logits, [req], [i])
+        self._to_wave([(i, s)], tok, logp, route, len(seg))
 
     def _fastforward_step(self, b: _DecodeBatch) -> bool:
         """FSM fast-forward ("jump decoding") via masked-candidate
@@ -1938,22 +2033,30 @@ class ContinuousBatcher:
             0,
         )
 
-    def _sample_batch(
+    def _sample_first(
         self,
-        logits: np.ndarray,
+        logits,
         reqs: List[GenRequest],
         slot_idxs: List[int],
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """First-token sampling for ``len(reqs)`` fresh rows in one
-        device call. ``logits`` is [n, V]."""
-        n = len(reqs)
-        temps = np.array([r.temperature for r in reqs], np.float32)
-        top_p = np.array([r.top_p for r in reqs], np.float32)
-        top_k = np.array([r.top_k for r in reqs], np.int32)
+    ):
+        """Dispatch first-token sampling for ``len(reqs)`` fresh rows
+        in one device call over ``logits``, the prefill program's
+        [B, V] where it lies: its rows past the real ones are the
+        bucket's padding, sampled greedily and never read, so the
+        program compiles once a bucket and not once a group size.
+        Returns ``(tok, logp)``, [B] each, ON THE DEVICE and on their
+        way to the host: ``_resolve_wave`` reads them."""
+        n, nb = len(reqs), logits.shape[0]
+        temps = np.zeros((nb,), np.float32)
+        top_p = np.ones((nb,), np.float32)
+        top_k = np.zeros((nb,), np.int32)
+        temps[:n] = [r.temperature for r in reqs]
+        top_p[:n] = [r.top_p for r in reqs]
+        top_k[:n] = [r.top_k for r in reqs]
         allowed = None
         if any(r.constraint is not None for r in reqs):
             with self.timer.host("fsm_mask", rows=n):
-                allowed = np.ones((n, self.vocab), bool)
+                allowed = np.ones((nb, self.vocab), bool)
                 for i, r in enumerate(reqs):
                     self.timer.tick()
                     if r.constraint is not None:
@@ -1967,56 +2070,22 @@ class ContinuousBatcher:
             # unseeded rows in a mixed batch key off their SLOT index
             # (unique across same-step admit batches) under a salt
             # distinct from the decode loop's, so no two draws alias
-            row_seeds = jax.numpy.asarray(
-                [
-                    _step_seed(r.row_seed, 0)
-                    if r.row_seed is not None
-                    else _step_seed(0x0F1E57 ^ (slot_idxs[i] + 1),
-                                    self._step)
-                    for i, r in enumerate(reqs)
-                ],
-                dtype=jax.numpy.int32,
-            )
+            row_seeds = np.zeros((nb,), np.int32)
+            row_seeds[:n] = [
+                _step_seed(r.row_seed, 0)
+                if r.row_seed is not None
+                else _step_seed(0x0F1E57 ^ (slot_idxs[i] + 1), self._step)
+                for i, r in enumerate(reqs)
+            ]
         else:
             self._key, sub = jax.random.split(self._key)
-        # bucket the group size so _admit_sample_jit compiles once per
-        # bucket, not once per distinct admission-group size (profiled
-        # round 5: each new size cost a ~1 s XLA:CPU recompile)
-        # min(): next_bucket can overshoot hi when B isn't a power of
-        # two (doubles past hi before the guard re-checks)
-        nb = min(next_bucket(n, lo=1, hi=self.B), self.B)
-        if nb > n:
-            pad = nb - n
-            logits = np.concatenate(
-                [logits, np.zeros((pad, logits.shape[1]), logits.dtype)]
-            )
-            temps = np.concatenate([temps, np.zeros((pad,), np.float32)])
-            top_p = np.concatenate([top_p, np.ones((pad,), np.float32)])
-            top_k = np.concatenate([top_k, np.zeros((pad,), np.int32)])
-            if allowed is not None:
-                allowed = np.concatenate(
-                    [allowed, np.ones((pad, self.vocab), bool)]
-                )
-            if row_seeds is not None:
-                row_seeds = jax.numpy.concatenate(
-                    [
-                        row_seeds,
-                        jax.numpy.zeros((pad,), jax.numpy.int32),
-                    ]
-                )
         with self.timer.time("admit_sample"):
-            jl = jax.numpy.asarray(logits)
             tok, logp = _admit_sample_jit(
-                jl,
-                sub,
-                temps,
-                top_p,
-                top_k,
-                None if allowed is None else jax.numpy.asarray(allowed),
-                row_seeds,
+                logits, sub, temps, top_p, top_k, allowed, row_seeds
             )
-            out = np.asarray(tok[:n]), np.asarray(logp[:n])
-        return out
+            tok.copy_to_host_async()
+            logp.copy_to_host_async()
+        return tok, logp
 
     def _deliver_token(self, slot: _Slot, tok: int, logp: float) -> None:
         """Fan one committed token out to the slot's job ``on_token``
@@ -3228,6 +3297,7 @@ class ContinuousBatcher:
         jobstore layer records never-run rows)."""
         self.timer.wake("emit")
         if emit_cancel:
+            self._resolve_wave()
             for i, s in enumerate(self.slots):
                 if s is not None and s.job is ctx:
                     self._emit(i, reason="cancelled")
@@ -3247,13 +3317,10 @@ class ContinuousBatcher:
         """Yield path: drop the job's live slots WITHOUT emitting
         results (those rows regenerate on resume; completed rows were
         already streamed) and return its shared-prefix pages."""
+        self._resolve_wave()
         for i, s in enumerate(self.slots):
             if s is not None and s.job is ctx:
-                self._unreserve(i, s.pages[s.shared_n :])
-                if s.job is not None:
-                    s.job.n_slots -= 1
-                self.slots[i] = None
-                self._gen[i] += 1
+                self._drop_slot(i)
         if ctx.prefix is not None:
             self._release_prefix(ctx.prefix)
             ctx.prefix = None
@@ -3380,11 +3447,7 @@ class ContinuousBatcher:
             shared_tokens=s.shared_n * PS,
             n_pages=len(own_aligned),
         )
-        self._unreserve(i, s.pages[s.shared_n :])
-        ctx.n_slots -= 1
-        self.slots[i] = None
-        self._gen[i] += 1
-        self._needs_mask.discard(i)
+        self._drop_slot(i)
         # the ORIGINAL request re-queues — its live constraint object
         # continues in place at resume (the stripped retry-style copy
         # is built only if the tier loses the payload)
@@ -3405,6 +3468,7 @@ class ContinuousBatcher:
         change across a session suspend — returns a FRESH request for
         the caller to admit through the normal path (the pre-tier
         full-regenerate behavior)."""
+        self._resolve_wave()
         slot_idx, own_pages, table = r
         PS = self.ecfg.kv_page_size
         shared = ctx.prefix.tokens if ctx.prefix is not None else 0
@@ -3557,6 +3621,8 @@ class ContinuousBatcher:
         budget = getattr(self.ecfg, "interactive_slots", 0)
         if not ctx.interactive or budget <= 0:
             return False
+        # a victim is chosen among ARMED rows, and its slot goes
+        self._resolve_wave()
         if self._interactive_slots_used() >= budget:
             return False  # the tier already holds its reserved share
         best: Optional[int] = None
@@ -3577,11 +3643,7 @@ class ContinuousBatcher:
         victim = s.job
         hibernated = self._hibernate_slot(best)
         if not hibernated:
-            self._unreserve(best, s.pages[s.shared_n:])
-            victim.n_slots -= 1
-            self.slots[best] = None
-            self._gen[best] += 1
-            self._needs_mask.discard(best)
+            self._drop_slot(best)
             # fresh request at the HEAD of pending (admission pops the
             # tail), so the victim's other rows keep their order and
             # this one re-admits once the batch has room again
@@ -3629,6 +3691,12 @@ class ContinuousBatcher:
         try:
             if not lad.active():
                 return False
+        except Exception:  # noqa: BLE001 — as below
+            return self._ladder_failed()
+        # a victim is chosen among ARMED rows (outside the ladder's
+        # backstop: a wave that fails is no policy error)
+        self._resolve_wave()
+        try:
             now = time.monotonic()
             best: Optional[int] = None
             best_cost = -1
@@ -3654,11 +3722,7 @@ class ContinuousBatcher:
             victim = s.job
             hibernated = self._hibernate_slot(best)
             if not hibernated:
-                self._unreserve(best, s.pages[s.shared_n:])
-                victim.n_slots -= 1
-                self.slots[best] = None
-                self._gen[best] += 1
-                self._needs_mask.discard(best)
+                self._drop_slot(best)
                 victim.pending.insert(
                     0,
                     dataclasses.replace(
@@ -3691,18 +3755,22 @@ class ContinuousBatcher:
         except Exception:  # noqa: BLE001 — policy errors must never
             # break admission; the control plane degrades itself on
             # its own sites, this is the scheduler-side backstop
-            logger.warning(
-                "priority ladder failed — disabling it", exc_info=True
-            )
-            self.ladder = None
-            return False
+            return self._ladder_failed()
+
+    def _ladder_failed(self) -> bool:
+        logger.warning(
+            "priority ladder failed — disabling it", exc_info=True
+        )
+        self.ladder = None
+        return False
 
     def _admit_pending(self, order: List[JobCtx]) -> bool:
         """Admit as many pending rows as slots/pages allow, pulling from
         jobs in (priority, seq) order; rows prefill in batches of up to
         ``prefill_batch_size`` per device dispatch (long rows chunk one
         at a time — see runner.prefill), and one batch may span jobs
-        (per-row suffix offsets)."""
+        (per-row suffix offsets). Every dispatch joins the admission
+        wave; the caller resolves it."""
         admitted = False
         while True:
             batch = []
@@ -3935,6 +4003,10 @@ class ContinuousBatcher:
                 # admits advance while the decode batch below keeps its
                 # cadence (bounded degradation, never a pause)
                 self._prefill_tick()
+                # the iteration's one wait for its admissions: every
+                # prefill above is dispatched, their first tokens come
+                # back together
+                self._resolve_wave()
                 tm.enter("emit")
                 # Immediately-finished rows (e.g. first token was stop).
                 for i, s in enumerate(self.slots):
@@ -4034,6 +4106,13 @@ class ContinuousBatcher:
             return "completed"
         finally:
             tm.wake("sched_other")
+            try:
+                # a raise out of admission: the rows dispatched before
+                # it are armed, as a sync a row always left them (on
+                # every other path the wave is empty here)
+                self._resolve_wave()
+            except Exception:  # noqa: BLE001 — the raise under way wins
+                logger.warning("admission wave lost", exc_info=True)
             # every exit path (completed / yielded / raise) returns any
             # live job's shared-prefix pages to the pool (_finish_job
             # and _suspend_job already None the refs they freed) and

@@ -486,6 +486,21 @@ SCHED_DISPATCH_ROWS_TOTAL = REGISTRY.counter(
     "idle (over iterations x decode_batch_size: batch occupancy)",
     unit="rows",
 )
+# how often admission waits for the device (OBSERVABILITY.md "The
+# admission wave"): rows over waves is the rows a host sync; 1.0 is a
+# sync a row
+ADMIT_WAVES_TOTAL = REGISTRY.counter(
+    "sutro_admit_waves_total",
+    "Admission waves resolved: host syncs that fetched the first "
+    "tokens of the prefills dispatched since the one before",
+    unit="waves",
+)
+ADMIT_WAVE_ROWS_TOTAL = REGISTRY.counter(
+    "sutro_admit_wave_rows_total",
+    "Rows those waves armed (over sutro_admit_waves_total: rows a "
+    "host sync; a job that streams its tokens cuts the wave at its row)",
+    unit="rows",
+)
 # what the decode dispatches yield (OBSERVABILITY.md "What a decode
 # dispatch yields"): a row-step is one position of one live row in one
 # dispatch at which a token could have been committed; per path,

@@ -217,9 +217,17 @@ def _grade_roofline(
             )
             if g.get("pct_hbm_roofline") is not None:
                 decode_pcts.append(float(g["pct_hbm_roofline"]))
-        elif s.get("name") == "prefill" and attrs.get("tokens"):
+        elif s.get("name") == "prefill":
+            tokens, secs = attrs.get("tokens"), dur
+            if "wave" in attrs:
+                # an admission wave's dispatch spans time the host
+                # alone: the span that resolved the wave says how long
+                # its prompt tokens took to come back
+                tokens, secs = attrs.get("wave_tokens"), attrs.get("wave_s")
+            if not tokens or not secs:
+                continue
             g = roofline.grade_prefill(
-                float(attrs["tokens"]) / dur / n_dev,
+                float(tokens) / float(secs) / n_dev,
                 n_params=int(device.get("n_params", 0)),
                 device_kind=kind,
             )

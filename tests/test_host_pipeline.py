@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from sutro_tpu.engine.config import EngineConfig
+from sutro_tpu.engine.runner import next_bucket
 from sutro_tpu.interfaces import JobStatus
 
 N_ROWS = 2048
@@ -35,14 +36,23 @@ class _StubRunner:
         )
         self._rng = np.random.default_rng(0)
 
-    def prefill_batch(self, prompts, tables):
-        return np.zeros((len(prompts), self.vocab), np.float32)
+    def _logits(self, n, on_device):
+        if not on_device:
+            return np.zeros((n, self.vocab), np.float32)
+        # what the scheduler asks for: the program's own row bucket,
+        # and the routing counts (none)
+        B = next_bucket(n, 1, 1 << 16)
+        return np.zeros((B, self.vocab), np.float32), None
 
-    def prefill_batch_at(self, rows, page_tables, starts):
-        return np.zeros((len(rows), self.vocab), np.float32)
+    def prefill_batch(self, prompts, tables, on_device=False):
+        return self._logits(len(prompts), on_device)
 
-    def prefill(self, prompt, table, start=0):
-        return np.zeros((self.vocab,), np.float32)
+    def prefill_batch_at(self, rows, page_tables, starts, on_device=False):
+        return self._logits(len(rows), on_device)
+
+    def prefill(self, prompt, table, start=0, on_device=False):
+        out = self._logits(1, on_device)
+        return out if on_device else out[0]
 
     def merge_last(self, prev_last, refresh_mask, refresh_vals):
         return np.where(

@@ -166,8 +166,10 @@ def test_job_perf_profile_recorded(tiny_ecfg, byte_tok, tmp_path, monkeypatch):
     assert rec["status"] == "SUCCEEDED", rec.get("failure_reason")
     perf = rec["perf"]
     assert perf and "decode" in perf and "prefill" in perf
-    # both rows ride ONE batched prefill dispatch (runner.prefill_batch)
-    assert perf["prefill"]["count"] == 1
+    # both rows ride ONE batched prefill dispatch (runner.prefill_batch),
+    # and the admission wave's one resolving block fetches both first
+    # tokens (a dispatch a row would read 3)
+    assert perf["prefill"]["count"] == 2
     assert perf["decode"]["p50_ms"] > 0
 
 
