@@ -16,7 +16,8 @@ takes while its windows are being refused, ``_decode_jit``). Each device
 op's self time goes to the first ``jax.named_scope`` of the mixed walk in
 its HLO ``op_name`` (``moe_ffn``, ``shared_expert`` inside it,
 ``attn_window``, ``attn_full``,
-``attn_mixer``, ``conv_mixer``, ``mamba_mixer``, ``dense_ffn``; ``other``
+``attn_mixer``, ``conv_mixer``, ``mamba_mixer``, ``mla_mixer`` and inside it
+``mla_expand`` or ``mla_absorb``, ``dense_ffn``; ``other``
 is the head, sampling, embeddings and what XLA hoisted), read from the
 event's own HLO line or, where the trace leaves it out, from the
 optimized HLO the compiler dumped (``--xla_dump_to``, set here before JAX
@@ -44,17 +45,19 @@ sys.path.insert(0, str(REPO))
 
 SCOPES = (
     "moe_ffn", "attn_window", "attn_full", "attn_mixer", "conv_mixer",
-    "mamba_mixer", "dense_ffn", "paged_decode_xla",
+    "mamba_mixer", "mla_mixer", "dense_ffn", "paged_decode_xla",
 )
+#: scopes INSIDE one of the above that are told apart: the shared expert
+#: inside ``moe_ffn``, the products of the form taken inside ``mla_mixer``
+INNER = ("shared_expert", "mla_expand", "mla_absorb")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _DEF = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 
 
 def scope_of(op_name: str) -> str:
     parts = op_name.split("/")
-    if "shared_expert" in parts:     # inside ``moe_ffn``: told apart
-        return "shared_expert"
-    return next((p for p in parts if p in SCOPES), "other")
+    inner = next((p for p in parts if p in INNER), None)
+    return inner or next((p for p in parts if p in SCOPES), "other")
 
 
 def scopes_from_dump(dump: Path) -> dict:

@@ -1027,9 +1027,10 @@ def engine_info(model: Optional[str]) -> None:
         # what a runner's device_info reports once its pool exists
         click.echo(
             f"model: {m.name} layers={m.num_layers} attn_layers="
-            f"{m.num_attn_layers} (the pool's layers) state_layers="
+            f"{m.num_attn_layers} latent_layers={m.num_latent_layers} "
+            f"(the pool's layers: {m.num_pool_layers}) state_layers="
             f"{m.num_conv_layers + m.num_mamba_layers} kv_bytes_per_token="
-            f"{m.num_attn_layers * 2 * m.kv_size * width} "
+            f"{m.num_pool_layers * (2 if m.pool_has_values else 1) * m.page_width * width} "
             f"state_bytes_per_page="
             f"{m.num_conv_layers * m.conv_state_len * m.hidden_size * width}"
         )

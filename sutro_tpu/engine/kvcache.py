@@ -72,6 +72,23 @@ scheduler ... paged-KV decode attention"). Layout:
   bound or released (the trivial setting: a runner given its pool's
   size, a mesh).
 
+- a model of LATENT layers (``ModelConfig.num_latent_layers``:
+  models/transformer.py ``mla_mixer``) keeps ONE pool and no V pool:
+  ``k_pages`` ``[L_mla, NP, PS, page_width]`` holds, a token a row, the
+  layer's normed latent values followed by the rotated key all heads
+  share, and ``v_pages`` is None. Every head reads that row for both
+  products (ops/attention.py ``latent_attention``), so nothing else of
+  a token is kept. ``ModelConfig.page_width`` is THE place that says how
+  wide a page's rows are (``num_kv_heads * head_dim`` for every other
+  model): the pools' shapes, a page's bytes (the runner's
+  ``_page_bytes_per_device``, ``_pool_margin_pages``) and the tier
+  payloads read it there. The page table, the allocators and the
+  garbage page are the same; int8 K/V (``kv_quantize``), a mesh and
+  the tiers' payloads refuse such a pool by name. Under ``use_pallas``
+  the paged decode kernel fetches such a page ONCE for both products
+  (``paged_decode_attention(v_pages=None)``) and the in-place write
+  lands one slab a segment (``pallas_kv.row_write_pallas``).
+
 ``write_kv`` lands a chunk's K/V into pages (Pallas in-place RMW kernel
 on TPU, XLA scatter fallback elsewhere); ``gather_kv_layer`` produces one
 layer's contiguous ``[B, CTX, KVH, Dh]`` view for a chunk's attention
@@ -103,7 +120,9 @@ from .config import EngineConfig
 @dataclasses.dataclass
 class KVCache:
     k_pages: jax.Array  # [L, NP, PS, KVH*Dh] — bf16, or int8 quantized
-    v_pages: jax.Array  # [L, NP, PS, KVH*Dh]
+    # [L, NP, PS, KVH*Dh]; None for a model of latent layers, whose
+    # ``k_pages`` rows [.., page_width] serve both products
+    v_pages: "jax.Array | None"
     # int8 KV mode (EngineConfig.kv_quantize): per-TOKEN dequant scales,
     # amax/127 over the fused KD axis. Per-token (not per-page) so a
     # decode append quantizes exactly once — no page rescale, no
@@ -165,11 +184,18 @@ def alloc_cache(
     on one device first; the int8 per-token scale pools are
     shard-invariant (full-KD amax) and replicate across that mesh."""
     shape = (
-        mcfg.num_attn_layers,
-        num_pages,
-        ecfg.kv_page_size,
-        mcfg.num_kv_heads * mcfg.head_dim,
+        mcfg.num_pool_layers, num_pages, ecfg.kv_page_size, mcfg.page_width,
     )
+    if mcfg.num_latent_layers and getattr(ecfg, "kv_quantize", None):
+        raise NotImplementedError(
+            f"{mcfg.name} keeps a latent row a token: the latent pool has "
+            "no int8 scale pools (kv_quantize)"
+        )
+    if mcfg.num_latent_layers and sharding is not None:
+        raise NotImplementedError(
+            f"{mcfg.name} keeps a latent row a token: every head reads the "
+            "whole row, so the latent pool does not shard over a mesh"
+        )
     rep = None
     if sharding is not None:
         rep = jax.sharding.NamedSharding(
@@ -236,7 +262,10 @@ def alloc_cache(
         )
     return KVCache(
         k_pages=jnp.zeros(shape, dtype, device=sharding),
-        v_pages=jnp.zeros(shape, dtype, device=sharding),
+        v_pages=(
+            jnp.zeros(shape, dtype, device=sharding)
+            if mcfg.pool_has_values else None
+        ),
         conv=conv, **state,
     )
 
@@ -492,6 +521,10 @@ class PageAllocator:
 
 def pages_needed(length: int, page_size: int) -> int:
     return (length + page_size - 1) // page_size
+
+
+#: tokens of a row one call of the one-pool write kernel takes
+_ROW_WRITE_TOKENS = 2048
 
 
 def _flat_slots(
@@ -769,6 +802,28 @@ def write_kv(
         KD = KVH * Dh
     PS = cache.page_size
     NP = cache.num_pages
+    if cache.v_pages is None:
+        # a latent pool: one row a token into ONE pool (it refuses a
+        # mesh at construction, so there is no shard to write)
+        if use_pallas:
+            from ..ops import pallas_kv
+
+            # the kernel keeps a row's whole run in VMEM (and a float32
+            # copy of it for the roll): 2,048 rows of 640 at a time fit
+            pool = cache.k_pages
+            for at in range(0, T, _ROW_WRITE_TOKENS):
+                part = k_chunk[:, :, at:at + _ROW_WRITE_TOKENS]
+                pool = pallas_kv.row_write_pallas(
+                    pool, part.astype(pool.dtype),
+                    page_table.astype(jnp.int32),
+                    (start + at).astype(jnp.int32),
+                    jnp.clip(valid_len - at, 0, part.shape[2]).astype(jnp.int32),
+                )
+            return dataclasses.replace(cache, k_pages=pool)
+        flat = _flat_slots(page_table, start, valid_len, T, PS)
+        return dataclasses.replace(
+            cache, k_pages=_scatter_rows(cache.k_pages, flat, k_chunk)
+        )
     if cache.quantized:
         # int8 KV: quantize per token, then the SAME flat scatter as
         # the unquantized fallback below (shared index helper), plus

@@ -144,6 +144,9 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         "kernel_paths": lowering.snapshot(),
         "paged_decode_xla": lowering.xla_decode_count(),
         "grouped_matmul": lowering.grouped_matmul_counts(),
+        # a latent layer's attention by form, whichever path computed it
+        # (``kernel_paths`` above says kernel or XLA form)
+        "latent_attention": lowering.latent_counts(),
         "compile_cache_dir": enable_compile_cache(),
         "native_runtime": native_runtime.is_available(),
         "native_fsm": native_fsm.is_available(),
@@ -236,6 +239,13 @@ class ModelRunner:
                 f"{mcfg.name} has layers of several kinds: not under "
                 "sequence or pipeline parallelism, nor quantize"
             )
+        if mesh is not None and mcfg.num_latent_layers:
+            # every head reads the whole latent row: there is no axis of
+            # it to give the shards of a tensor-parallel pool
+            raise NotImplementedError(
+                f"{mcfg.name} keeps a latent row a token (mla layers): the "
+                "latent pool runs on one chip, not under a mesh"
+            )
         if mesh is not None and mcfg.experts_held != mcfg.moe_experts:
             # the share IS one chip's part of a layer; under a mesh the
             # sharding rules would split the held stack again and the
@@ -315,8 +325,7 @@ class ModelRunner:
         tp = int(mesh.shape.get("model", 1)) if mesh is not None else 1
         self._margin_pages = _pool_margin_pages(
             ecfg.max_pages_per_seq,
-            ecfg.kv_page_size * max(mcfg.num_kv_heads // tp, 1)
-            * mcfg.head_dim
+            ecfg.kv_page_size * max(mcfg.page_width // tp, 1)
             * (1 if ecfg.kv_quantize == "int8" else dtype.itemsize),
         ) if self.use_pallas else 0
         # pages the allocators may hand out (page 0 is the garbage
@@ -576,16 +585,17 @@ class ModelRunner:
         )
 
     def _page_bytes_per_device(self, dtype, window: bool = False) -> int:
-        """One KV page (K and V, every ATTENTION layer, plus int8
+        """One KV page (K and V, every ATTENTION layer, or the one
+        latent row a token of every latent layer; plus int8
         scales, plus the page's conv state) as it sits on ONE device
         under the pool's sharding; ``window``: a page of the window
         layers' pool."""
         PS = self.ecfg.kv_page_size
         L = (
             self.mcfg.num_window_layers if window
-            else self.mcfg.num_attn_layers
+            else self.mcfg.num_pool_layers
         )
-        shape = (L, 1, PS, self.mcfg.num_kv_heads * self.mcfg.head_dim)
+        shape = (L, 1, PS, self.mcfg.page_width)
         if self._cache_sharding is not None:
             shape = self._cache_sharding.shard_shape(shape)
         state = (
@@ -596,7 +606,9 @@ class ModelRunner:
         if self.ecfg.kv_quantize == "int8":
             # int8 values + replicated f32 per-token scales
             return 2 * (int(np.prod(shape)) + L * PS * 4) + state
-        return 2 * int(np.prod(shape)) * dtype.itemsize + state
+        # K and V, or a latent pool's one row a token
+        pools = 2 if self.mcfg.pool_has_values else 1
+        return pools * int(np.prod(shape)) * dtype.itemsize + state
 
     def _pages_that_fit(self, want: int, want_window: int, dtype):
         """``(pages, window pages)``: ``want`` pages (and ``want_window``
@@ -715,6 +727,21 @@ class ModelRunner:
             # (0: one pool; equal to pool_pages: the trivial setting)
             "window_layers": int(self.mcfg.num_window_layers),
             "window_pool_pages": int(self.cache.num_window_pages),
+            # latent layers keep ONE row a token, ``latent_page_bytes``
+            # a page of every layer in use (0: the pool keeps K and V);
+            # the pool's rows are ``latent_row_lanes`` wide, whole lane
+            # tiles (``ModelConfig.page_width``; ``pool_bytes`` counts them)
+            "latent_layers": int(self.mcfg.num_latent_layers),
+            "latent_row_width": int(
+                self.mcfg.latent_width if self.mcfg.num_latent_layers else 0
+            ),
+            "latent_row_lanes": int(
+                self.mcfg.page_width if self.mcfg.num_latent_layers else 0
+            ),
+            "latent_page_bytes": int(
+                self.mcfg.num_latent_layers * self.ecfg.kv_page_size
+                * self.mcfg.latent_width * self.cache.k_pages.dtype.itemsize
+            ),
             "kv_heads": int(self.mcfg.num_kv_heads),
             "head_dim": int(self.mcfg.head_dim),
             "kv_dtype_bytes": (
@@ -771,6 +798,13 @@ class ModelRunner:
         if telemetry.ENABLED and self.has_state:
             telemetry.STATE_COMMITS_TOTAL.inc(1.0, path)
 
+    def _count_latent(self, form: str) -> None:
+        """A dispatch of a model of latent layers, by the form its
+        attention takes (``transformer.mla_mixer``): "expanded" with no
+        paged past, "absorbed" over one."""
+        if telemetry.ENABLED and self.mcfg.num_latent_layers:
+            telemetry.LATENT_ATTENTION_DISPATCHES_TOTAL.inc(1.0, form)
+
     def take_route_stats(self):
         """The routing counts of the last dispatch that was fetched
         (``_route_stats``; [6], or [steps, 6] for a fused window), as
@@ -804,6 +838,11 @@ class ModelRunner:
         free/reuse the pages the moment this returns."""
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         c = self.cache
+        if c.v_pages is None:
+            raise ValueError(
+                "pages of a latent pool do not move to the tiers (the "
+                "payload is a K and a V of one width)"
+            )
         if c.wk_pages is not None:
             # a page id's window page may be gone, and the tiers' payload
             # has no place for a second pool: the caller prefills again
@@ -858,6 +897,10 @@ class ModelRunner:
         the parity contract, tests/test_kv_tiers.py)."""
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         c = self.cache
+        if c.v_pages is None:
+            raise ValueError(
+                "a K/V page payload cannot restore the rows of a latent pool"
+            )
         if c.wk_pages is not None:
             raise ValueError(
                 "pages cannot restore the window pages of a model that "
@@ -1033,6 +1076,7 @@ class ModelRunner:
                 ids = np.zeros((1, C), np.int32)
                 ids[0, : len(seg)] = seg
                 self._count_state_commit("chunk")
+                self._count_latent("absorbed")
                 self._bind_window(page_table, [start + off], [len(seg)])
                 logits, self.cache, route = self._prefill_chunk_jit(
                     self.params,
@@ -1055,6 +1099,7 @@ class ModelRunner:
         ids = np.zeros((1, T), np.int32)
         ids[0, :n] = token_ids
         self._count_state_commit("prefill")
+        self._count_latent("expanded")
         self._bind_window(page_table, [0], [n])
         logits, self.cache, route = self._prefill_jit(
             self.params,
@@ -1100,6 +1145,7 @@ class ModelRunner:
             tables[i] = page_tables[i]
         self._bind_fresh(tables[:n], [0] * n)
         self._count_state_commit("prefill")
+        self._count_latent("expanded")
         self._bind_window(tables[:n], [0] * n, lens[:n])
         logits, self.cache, route = self._prefill_jit(
             self.params,
@@ -1139,6 +1185,7 @@ class ModelRunner:
             tables[i] = page_tables[i]
         self._bind_fresh(tables[:n], st[:n])
         self._count_state_commit("chunk")
+        self._count_latent("absorbed")
         self._bind_window(tables[:n], st[:n], lens[:n])
         logits, self.cache, route = self._prefill_chunk_jit(
             self.params,
@@ -1231,7 +1278,12 @@ class ModelRunner:
         PS = self.ecfg.kv_page_size
         past = np.asarray(past_len, np.int64)
         table = np.asarray(page_table)
-        head = jax.ShapeDtypeStruct((1, 1, self.mcfg.head_dim), jnp.float32)
+        # what the kernel's gate reads as a head: a latent layer's
+        # absorbed query is as wide as the pool's row
+        head = jax.ShapeDtypeStruct((
+            1, 1, self.mcfg.page_width if self.mcfg.num_latent_layers
+            else self.mcfg.head_dim,
+        ), jnp.float32)
         kernel = self.use_pallas and pallas_paged.paged_decode_supported(
             head, self.cache.k_pages, table
         )
@@ -1241,7 +1293,7 @@ class ModelRunner:
         # the page of its oldest visible position on
         W = self.mcfg.sliding_window
         for layers, win in (
-            (self.mcfg.num_attn_layers, 0), (self.mcfg.num_window_layers, W),
+            (self.mcfg.num_pool_layers, 0), (self.mcfg.num_window_layers, W),
         ):
             if not layers:
                 continue
@@ -1370,6 +1422,7 @@ class ModelRunner:
                 jnp.asarray(rep, jnp.float32),
             )
         self._count_state_commit("window")
+        self._count_latent("absorbed")
         self._count_kv_pages(past_len, page_table, 1, pfx)
         self._bind_window(page_table, past_len, np.ones((B,), np.int32))
         tok, logp, self.cache, self._route_dev, ok = self._decode_jit(
@@ -1472,8 +1525,8 @@ class ModelRunner:
         whole batch to masked single-steps."""
         B = last.shape[0]
         L = self.mcfg.num_kv_layers   # full layers, then window layers
-        KVH, Dh = self.mcfg.num_kv_heads, self.mcfg.head_dim
-        KD = KVH * Dh
+        KD = self.mcfg.page_width
+        latent = cache.v_pages is None   # one row a token, no V
         # window buffers hold UNQUANTIZED step K/V (they are read by
         # attention before ever touching the pool; write_kv quantizes
         # at commit) — under an int8 pool they stay in compute dtype
@@ -1486,7 +1539,7 @@ class ModelRunner:
         # unfused [.., KVH, Dh] form pads KVH up to a full sublane tile
         # on TPU — a 2x memory expansion on multi-GB buffers at large B
         wk0 = jnp.zeros((L, B, steps, KD), dtype)
-        wv0 = jnp.zeros((L, B, steps, KD), dtype)
+        wv0 = None if latent else jnp.zeros((L, B, steps, KD), dtype)
         mixed = not self.mcfg.homogeneous
         K1 = self.mcfg.conv_state_len or self.mcfg.mamba_conv_len
         wc0 = ws0 = past = None
@@ -1558,10 +1611,11 @@ class ModelRunner:
                 wk, k.astype(dtype).reshape(L, B, 1, KD),
                 (0, 0, step_idx, 0),
             )
-            wv = jax.lax.dynamic_update_slice(
-                wv, v.astype(dtype).reshape(L, B, 1, KD),
-                (0, 0, step_idx, 0),
-            )
+            if not latent:
+                wv = jax.lax.dynamic_update_slice(
+                    wv, v.astype(dtype).reshape(L, B, 1, KD),
+                    (0, 0, step_idx, 0),
+                )
             step_logits = logits[:, 0]
             sample_logits = step_logits
             if allowed0 is not None:
@@ -1640,6 +1694,7 @@ class ModelRunner:
         # the window's routing counts stay on the device beside its
         # tokens; whoever fetches the tokens fetches them
         self._count_state_commit("window")
+        self._count_latent("absorbed")
         self._count_kv_pages(past_len, page_table, steps, pfx)
         self._bind_window(page_table, past_len, np.full((B,), steps))
         toks, logps, self.cache, self.window_route = self._decode_multi_jit(
@@ -1757,6 +1812,7 @@ class ModelRunner:
         ids[:, 0] = last_tokens
         ids[:, 1:] = drafts
         self._bind_window(page_table, past_len, np.asarray(draft_len) + 1)
+        self._count_latent("absorbed")
         ct, cl, pt, pl, self.cache, pending = self._verify_cand_jit(
             self.params,
             self.cache,
@@ -1873,6 +1929,7 @@ class ModelRunner:
         if top_k is None:
             top_k = np.zeros((B,), np.int32)
         self._count_kv_pages(past_len, page_table, steps, pfx)
+        self._count_latent("absorbed")
         toks, logps, wk, wv = self._decode_window_jit(
             self.params,
             self.cache,

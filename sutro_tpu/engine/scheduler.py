@@ -500,6 +500,7 @@ class ContinuousBatcher:
     # the runner keeps its state a slot a sequence (set in __init__)
     _slot_state = False
     _tier_refused = False
+    _latent_pool = False
     # the runner's window pool (K/V a pool a kind), set in __init__
     _window_pool = None
 
@@ -574,12 +575,25 @@ class ContinuousBatcher:
         # the tiers' payload still has no place for the second pool.
         self._window_pool = getattr(runner, "window_pool", None)
         two_kinds = getattr(runner.mcfg, "num_window_layers", 0) > 0
-        self._tier_refused = (
-            (self._slot_state or two_kinds) and kv_tier is not None
+        # A model of LATENT layers keeps one row a token in one pool
+        # (kvcache.py): the tiers' payload is a K and a V of one width,
+        # and a prefix that rows share or the store keeps would send
+        # every later row through the absorbed form over pages that no
+        # test or measurement has held yet. The same fallbacks, under
+        # reasons of their own: every row prefills its own prompt.
+        self._latent_pool = (
+            getattr(runner.mcfg, "num_latent_layers", 0) > 0
         )
-        if self._slot_state or two_kinds:
+        self._tier_refused = (
+            (self._slot_state or two_kinds or self._latent_pool)
+            and kv_tier is not None
+        )
+        if self._slot_state or two_kinds or self._latent_pool:
             kv_tier = None
-        if self._slot_state or self._window_pool is not None:
+        if (
+            self._slot_state or self._window_pool is not None
+            or self._latent_pool
+        ):
             prefix_store = None
         if self._slot_state:
             runner.reset_state_slots()
@@ -905,6 +919,11 @@ class ContinuousBatcher:
             # first row to slide past it: every row prefills its own
             self._count_state_fallback(
                 shared * (len(pending) - 1), "prefix_without_window_pages"
+            )
+            return
+        if self._latent_pool:
+            self._count_state_fallback(
+                shared * (len(pending) - 1), "prefix_on_latent_pool"
             )
             return
         n_pages = shared // PS
@@ -3390,6 +3409,7 @@ class ContinuousBatcher:
                 self._count_state_fallback(
                     self.slots[i].pos,
                     "hibernate_without_slot_state" if self._slot_state
+                    else "hibernate_on_latent_pool" if self._latent_pool
                     else "hibernate_without_window_pages",
                 )
             return False

@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers nine
+config-driven decoder-only transformer (models/transformer.py) covers ten
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -37,6 +37,20 @@ families:
   14 blocks, experts 0-63 of each layer (``moe_experts_held``: the
   router keeps its 128 outputs, the rest are another chip's) and half
   the vocabulary
+- JoyAI-LLM-Flash (48b-a2.7b; ``model_type`` joyai_llm_flash, DeepSeek-V3's
+  key set): every layer's mixer is latent attention ("mla"): a query
+  projection of low rank (``q_lora_rank``, normed), ONE latent row a
+  token (``kv_lora_rank`` normed values and a ``qk_rope_head_dim``
+  rotary key shared by all heads, the pair (2i, 2i+1) turned:
+  ``rope_interleave``) that is all the cache keeps
+  (``ModelConfig.page_width``), per-head K (``qk_nope_head_dim`` + the
+  shared rotary part) and V (``v_head_dim``) expanded from it for a
+  chunk with no past and absorbed into the query and the output for
+  everything over a paged past; a leading dense SwiGLU layer, then 256
+  SwiGLU experts top-8 (sigmoid router, selection bias, the chosen
+  scores over their sum times 2.5) beside one shared expert. ``-ep16``
+  is one chip of sixteen that share every layer: experts 0-15 of each
+  routed layer's 256, every layer, the whole vocabulary
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -102,7 +116,8 @@ class ModelConfig:
     # width of ONE shared expert, of the experts' form, that every token
     # takes beside its routed ones (0: none)
     moe_shared_intermediate_size: int = 0
-    # Per-layer mixer kinds, "attention" | "swa" | "conv" | "mamba";
+    # Per-layer mixer kinds, "attention" | "swa" | "conv" | "mamba" |
+    # "mla";
     # empty => attention everywhere. A "swa" layer is attention over the
     # last ``sliding_window`` positions, with the plain rotary embedding
     # of ``local_rope_theta`` (``rope_theta`` when that is None) whatever
@@ -127,6 +142,23 @@ class ModelConfig:
     mamba_groups: int = 1
     mamba_conv: int = 0
     mamba_chunk: int = 256
+    # An "mla" layer is latent attention (models/transformer.py
+    # ``mla_mixer``): ``c_q = norm(x W_qa)`` of ``q_lora_rank``, a head's
+    # query ``qk_nope_head_dim`` wide plus ``qk_rope_head_dim`` that takes
+    # the rotary embedding; ``x W_kva`` = ``kv_lora_rank`` latent values
+    # (normed) | ONE rotary key of ``qk_rope_head_dim`` for all heads:
+    # that row is what the cache keeps (``page_width``); a head's K and
+    # V (``v_head_dim``) come from the latent values through ``w_kvb``.
+    # The softmax scale is 1 / sqrt(nope + rope). ``head_dim`` and
+    # ``num_kv_heads`` size nothing of such a layer.
+    # ``rope_interleave``: the rotary pairs are (2i, 2i+1), not
+    # (i, i + half)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
     # Granite's scalar multipliers (1.0: none) and softmax scale (None:
     # 1/sqrt(head_dim)); "nope" applies no rotary embedding
     embedding_multiplier: float = 1.0
@@ -240,10 +272,52 @@ class ModelConfig:
         return self.mixers.count("swa")
 
     @property
+    def num_latent_layers(self) -> int:
+        """"mla" layers: they keep ONE latent row a token, in the page
+        pool's place (``page_width``), and no V."""
+        return self.mixers.count("mla")
+
+    @property
+    def num_pool_layers(self) -> int:
+        """Layers the page pool spans: the full attention layers, or the
+        latent layers of a model that has those (never both)."""
+        return self.num_latent_layers or self.num_attn_layers
+
+    @property
+    def latent_width(self) -> int:
+        """What a token leaves in the cache a latent layer: its normed
+        latent values, then its rotated shared key (the leading
+        elements of a pool's row: ``page_width``)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def page_width(self) -> int:
+        """Elements one token keeps in a page of one layer of a pool
+        (K's, and as many of V where the pool has a V): THE place that
+        says it, for the pools' shapes, a page's bytes and the writes.
+        A latent row is padded with zeros to whole tiles of 128 lanes
+        (576 -> 640): the device keeps a minor axis of 576 in 640 lanes
+        anyway, so the padding costs no memory, and over a pool whose
+        rows are no whole tiles the compiler copies the pool for every
+        scatter once it passes ~3 GB (PERF.md section 6, PR 42)."""
+        if self.num_latent_layers:
+            return -(-self.latent_width // 128) * 128
+        return self.num_kv_heads * self.head_dim
+
+    @property
+    def pool_has_values(self) -> bool:
+        """The page pool keeps a V pool beside K's (a latent row serves
+        both products: one pool)."""
+        return not self.num_latent_layers
+
+    @property
     def num_kv_layers(self) -> int:
-        """Layers with K/V of either kind: a chunk's K/V is stacked
+        """Layers with K/V of any kind: a chunk's K/V is stacked
         over them, the full layers first."""
-        return self.num_attn_layers + self.num_window_layers
+        return (
+            self.num_attn_layers + self.num_window_layers
+            + self.num_latent_layers
+        )
 
     @property
     def num_conv_layers(self) -> int:
@@ -493,6 +567,41 @@ def _nemotron_h(name: str, pattern: str, *, h: int = 2688, nh: int = 32,
     )
 
 
+def _joyai(name: str, layers: int = 40, *, h: int = 2048, nh: int = 32,
+           q_rank: int = 1536, kv_rank: int = 512, nope: int = 128,
+           rope: int = 64, v_dim: int = 128, inter: int = 7168,
+           experts: int = 256, top_k: int = 8, moe_inter: int = 768,
+           dense_layers: int = 1, held: int = 0, first: int = 0,
+           vocab: int = 129_280, template: str = "chatml") -> ModelConfig:
+    """The published ``joyai_llm_flash`` keys (DeepSeek-V3's set): latent
+    attention in every layer, rotary theta 32e6 on the ``rope`` part
+    alone in interleaved pairs, no scaling; ``dense_layers`` leading
+    dense SwiGLU layers, then a sigmoid router with a selection bias
+    (``noaux_tc``; ``n_group`` 1), the chosen scores over their sum
+    (+1e-20) times 2.5, gated experts beside ONE shared expert of an
+    expert's width. ``head_dim`` is the file's (the rotary part) and
+    ``num_kv_heads`` its 32: neither sizes anything here. ``held`` /
+    ``first``: the experts this chip holds of each layer (0: all). The
+    multi-token-prediction block (``num_nextn_predict_layers`` 1) is no
+    part of the next-token logits and is not built."""
+    return ModelConfig(
+        name=name, vocab_size=vocab, hidden_size=h, num_layers=layers,
+        num_heads=nh, num_kv_heads=nh, head_dim=rope,
+        intermediate_size=inter, norm_eps=1e-6, rope_theta=32_000_000.0,
+        qk_norm=False, tie_embeddings=False,
+        layer_types=("mla",) * layers,
+        q_lora_rank=q_rank, kv_lora_rank=kv_rank, qk_nope_head_dim=nope,
+        qk_rope_head_dim=rope, v_head_dim=v_dim, rope_interleave=True,
+        moe_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_inter, num_dense_layers=dense_layers,
+        moe_experts_held=held, moe_first_expert=first,
+        moe_shared_intermediate_size=moe_inter,
+        router_score="sigmoid", router_select_bias=True,
+        router_renorm=True, router_scale=2.5, router_renorm_eps=1e-20,
+        chat_template=template, seeded_unit_embedding=True,
+    )
+
+
 MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # Qwen3 dense
     "qwen3-0.6b": _qwen3("qwen3-0.6b", 1024, 28, 16, 8, 3072),
@@ -542,6 +651,15 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     "nemotron-3-nano-30b-a3b-l14-ep2": _nemotron_h(
         "nemotron-3-nano-30b-a3b-l14-ep2", _NEMOTRON_3_NANO_PATTERN[:14],
         held=64, first=0, vocab=65_536,
+    ),
+    # JoyAI-LLM-Flash: as published (48.9 B parameters without its
+    # multi-token-prediction block), and one chip of the sixteen that
+    # share every layer: all 40 layers, the whole vocabulary, experts
+    # 0-15 of each routed layer's 256 (4.78 B parameters, 9.55 GB in
+    # bf16)
+    "joyai-llm-flash": _joyai("joyai-llm-flash"),
+    "joyai-llm-flash-ep16": _joyai(
+        "joyai-llm-flash-ep16", held=16, first=0,
     ),
     # Embeddings (Qwen3 trunk + last-token-pool head)
     "qwen3-emb-0.6b": _qwen3("qwen3-emb-0.6b", 1024, 28, 16, 8, 3072, head="embedding"),
@@ -597,6 +715,16 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         m_heads=8, m_head_dim=32, m_state=16, m_groups=2, m_chunk=8,
         experts=8, top_k=2, moe_inter=48, shared_inter=96, held=4,
         first=0, vocab=512, template="plain",
+    ),
+    # a leading dense layer, then three routed layers; every latent
+    # width small, unlike every other and unlike head_dim (8) and
+    # num_kv_heads * head_dim (32): query rank 24, latent 40 + a rotary
+    # key of 8 (a page row of 48), nope 16, v 20; 16 experts top-4 of
+    # which this chip holds 4 (a rank of four)
+    "tiny-joyai": _joyai(
+        "tiny-joyai", 4, h=128, nh=4, q_rank=24, kv_rank=40, nope=16,
+        rope=8, v_dim=20, inter=256, experts=16, top_k=4, moe_inter=48,
+        held=4, first=0, vocab=512, template="plain",
     ),
     "tiny-emb": ModelConfig(
         name="tiny-emb", vocab_size=512, hidden_size=128, num_layers=2,

@@ -10,7 +10,7 @@ models remotely — SURVEY §0). Design choices are TPU-first:
   layers are of several kinds (``ModelConfig.layer_types``,
   ``num_dense_layers``) stacks its parameters PER KIND,
   ``params["layers"][kind][name] [L_kind, ...]`` for the mixers "attn",
-  "conv" and "mamba" and the FFNs "dense" and "moe", and walks the
+  "conv", "mamba" and "mla" and the FFNs "dense" and "moe", and walks the
   config's own list of layers, scanning each repeated group
   (``_mixed_trunk``); a block may be a mixer alone or an FFN alone, under
   the one norm of its kind's stack (``ModelConfig.one_sublayer``).
@@ -20,8 +20,10 @@ models remotely — SURVEY §0). Design choices are TPU-first:
 - All matmuls run in ``bfloat16`` on the MXU; softmax/norms accumulate in
   ``float32``.
 - One code path covers Qwen3 (dense+MoE), Llama 3, Gemma 3, gpt-oss,
-  LFM2-MoE, Granite 4.0-H, Mellum 2 and Nemotron-H via ``ModelConfig`` fields (QK-norm, sliding windows, attention
-  sinks, post norms, MoE and its router's form, per-layer mixer kinds) —
+  LFM2-MoE, Granite 4.0-H, Mellum 2, Nemotron-H and JoyAI-LLM-Flash via
+  ``ModelConfig`` fields (QK-norm, sliding windows, attention sinks,
+  post norms, MoE and its router's form, per-layer mixer kinds, latent
+  attention) —
   see models/configs.py.
 
 The forward returns the chunk's K/V for each ATTENTION layer and, for a
@@ -44,10 +46,12 @@ import numpy as np
 
 from .configs import ModelConfig
 from ..ops.moe import moe_mlp, relu2
-from ..ops.attention import chunk_attention
+from ..ops.attention import chunk_attention, latent_attention
 from ..ops.quant import materialize
 
 Params = Dict[str, Any]
+
+_HI = jax.lax.Precision.HIGHEST
 
 
 @jax.tree_util.register_dataclass
@@ -59,7 +63,10 @@ class MixedChunk:
     ``kvcache.write_kv`` pass the pair along unopened), so a caller
     that commits a chunk's K/V commits the conv state with it."""
 
-    k: jax.Array  # [L_attn, B, T, KVH, Dh], or fused [L_attn, B, T, KD]
+    # [L_attn, B, T, KVH, Dh], or fused [L_attn, B, T, KD]; for a model
+    # of latent layers each token's latent ROW [L_mla, B, T, page_width]
+    # (and no V beside it: ``forward`` returns None in V's place)
+    k: jax.Array
     # each conv layer's carried state, then the chunk's gated inputs
     # g_1..g_T: the state after n <= T tokens is columns n..n+K-2
     conv: Optional[jax.Array] = None   # [L_conv, B, K-1+T, H]
@@ -157,6 +164,25 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
         if cfg.qk_norm:
             out[kind]["q_norm"] = jnp.ones((Lk, Dh), dtype)
             out[kind]["k_norm"] = jnp.ones((Lk, Dh), dtype)
+    if cfg.num_latent_layers:
+        Ll, NH = cfg.num_latent_layers, cfg.num_heads
+        Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        Dn, Dr, Dv = (
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        )
+        out["mla"] = {
+            "attn_norm": jnp.ones((Ll, H), dtype),
+            "w_qa": dense((Ll, H, Rq), H),
+            "q_norm": jnp.ones((Ll, Rq), dtype),
+            # a head's columns: [q_nope | q_pe]
+            "w_qb": dense((Ll, Rq, NH * (Dn + Dr)), Rq),
+            # [latent values | the shared rotary key]
+            "w_kva": dense((Ll, H, Rkv + Dr), H),
+            "kv_norm": jnp.ones((Ll, Rkv), dtype),
+            # a head's columns: [k_nope | v]
+            "w_kvb": dense((Ll, Rkv, NH * (Dn + Dv)), Rkv),
+            "wo": dense((Ll, NH * Dv, H), NH * Dv),
+        }
     if Lc:
         K = cfg.conv_kernel
         out["conv"] = {
@@ -596,6 +622,108 @@ def attention_mixer(
     return attn, (k, v)
 
 
+def apply_rope_interleaved(
+    x: jax.Array, positions: jax.Array, theta: float
+) -> jax.Array:
+    """The rotary embedding on pairs ``(2i, 2i+1)``, each turned by
+    ``pos * theta^(-2i/D)`` (``rope_interleave``: the published layout
+    of a latent layer's rotary part). x: [B, T, ..., D]; positions
+    [B, T]. The pair's partner is fetched by a signed permutation as a
+    [D, D] product (each output is one input times +-1: exact), which
+    keeps D on the lanes; a [..., D/2, 2] view would put an axis of 2
+    there."""
+    D = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    ang = positions.astype(jnp.float32)[..., None] * freq     # [B, T, D/2]
+    shape = ang.shape[:2] + (1,) * (x.ndim - 3) + (D,)
+    cos = jnp.repeat(jnp.cos(ang), 2, axis=-1).reshape(shape)
+    sin = jnp.repeat(jnp.sin(ang), 2, axis=-1).reshape(shape)
+    i = np.arange(D)
+    swap = np.zeros((D, D), np.float32)
+    swap[i ^ 1, i] = np.where(i % 2 == 0, -1.0, 1.0)   # (a, b) -> (-b, a)
+    xf = x.astype(jnp.float32)
+    partner = jnp.matmul(xf, jnp.asarray(swap), precision=_HI)
+    return (xf * cos + partner * sin).astype(x.dtype)
+
+
+def mla_mixer(
+    cfg: ModelConfig,
+    lp: Dict[str, Any],          # one latent layer's params
+    x: jax.Array,                # [B, T, H], normed
+    *,
+    positions: jax.Array,        # [B, T]
+    valid_len: jax.Array,        # [B]
+    pages=None,                  # [L, NP, PS, page_width]: the latent pool
+    layer=None,                  # this layer's index into it
+    page_table=None, past_len=None,
+    win_rows=None,               # [B, W, page_width]: a fused window's rows
+    win_len=None, use_pallas: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """Latent attention over a chunk (``ModelConfig.q_lora_rank`` ...):
+
+        c_q = RMSNorm(x W_qa) ;  [q_nope | q_pe] = c_q W_qb      a head
+        [c_kv | k_pe] = x W_kva ;  c_kv = RMSNorm(c_kv)
+        q_pe, k_pe = rope(q_pe), rope(k_pe)     k_pe ONE a token
+        [k_nope | v] = c_kv W_kvb                                a head
+        score = (q_nope . k_nope + q_pe . k_pe) / sqrt(nope + rope)
+        out = concat_h(softmax(score) v) W_o
+
+    Returns ``(out [B, T, H], row [B, T, page_width])``: ``row`` =
+    ``[c_kv | k_pe | 0..]`` is all the cache keeps of a token. Two forms of
+    the same numbers. With no ``pages`` (a chunk with no past) the
+    EXPANDED form: K and V a head from the chunk's own rows. Over a
+    paged past the ABSORBED form: ``W_kvb``'s K half folded into the
+    query (``q~ = q_nope W_UK^T``, as wide as the latent values) and
+    its V half applied after the sum, so every head reads the SAME
+    stored row for both products and K and V are never rebuilt from
+    the pool (ops/attention.py ``latent_attention``)."""
+    B, T = x.shape[:2]
+    NH, Rkv = cfg.num_heads, cfg.kv_lora_rank
+    Dn, Dr, Dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    c_q = rms_norm(x @ _w(lp, "w_qa", x.dtype), lp["q_norm"], cfg.norm_eps, False)
+    q = (c_q @ _w(lp, "w_qb", x.dtype)).reshape(B, T, NH, Dn + Dr)
+    kva = x @ _w(lp, "w_kva", x.dtype)
+    c_kv = rms_norm(kva[..., :Rkv], lp["kv_norm"], cfg.norm_eps, False)
+    q_pe = apply_rope_interleaved(q[..., Dn:], positions, cfg.rope_theta)
+    k_pe = apply_rope_interleaved(kva[..., Rkv:], positions, cfg.rope_theta)
+    # the pool's row: the latent values, the shared key, then zeros up
+    # to whole lane tiles (``ModelConfig.page_width``)
+    pad = cfg.page_width - cfg.latent_width
+    row = jnp.concatenate(
+        [c_kv, k_pe, jnp.zeros((B, T, pad), c_kv.dtype)], axis=-1
+    )
+    w_kvb = _w(lp, "w_kvb", x.dtype).reshape(Rkv, NH, Dn + Dv)
+    scale = (Dn + Dr) ** -0.5
+    if pages is None:
+        with jax.named_scope("mla_expand"):
+            kv = jnp.einsum("btc,cnd->btnd", c_kv, w_kvb)
+            k = jnp.concatenate(
+                [kv[..., :Dn],
+                 jnp.broadcast_to(k_pe[:, :, None], (B, T, NH, Dr))],
+                axis=-1,
+            )
+            qf = jnp.concatenate([q[..., :Dn], q_pe], axis=-1)
+            o = latent_attention(
+                qf, k, kv[..., Dn:], positions=positions,
+                valid_len=valid_len, scale=scale, use_pallas=use_pallas,
+            )
+    else:
+        with jax.named_scope("mla_absorb"):
+            q_abs = jnp.einsum("btnd,cnd->btnc", q[..., :Dn], w_kvb[..., :Dn])
+            ql = jnp.concatenate(
+                [q_abs, q_pe, jnp.zeros((B, T, NH, pad), q_abs.dtype)], axis=-1
+            )                                      # [B, T, NH, page_width]
+            o_lat = latent_attention(
+                ql, row, None, positions=positions, valid_len=valid_len,
+                scale=scale, pages=pages, layer=layer,
+                page_table=page_table, past_len=past_len,
+                win_rows=win_rows, win_len=win_len, value_width=Rkv,
+                use_pallas=use_pallas,
+            )
+            o = jnp.einsum("btnc,cnd->btnd", o_lat, w_kvb[..., Dn:])
+    return o.reshape(B, T, NH * Dv) @ _w(lp, "wo", x.dtype), row
+
+
 def conv_mixer(
     cfg: ModelConfig,
     lp: Dict[str, Any],          # one conv layer's params
@@ -633,8 +761,6 @@ def conv_mixer(
 # ---------------------------------------------------------------------------
 # Mamba-2
 # ---------------------------------------------------------------------------
-
-_HI = jax.lax.Precision.HIGHEST
 
 
 def per_channel(per_head: jax.Array, head_dim: int) -> jax.Array:
@@ -950,6 +1076,7 @@ def layer_apply(
 
 _MIXER_STACK = {
     "attention": "attn", "swa": "swa", "conv": "conv", "mamba": "mamba",
+    "mla": "mla",
 }
 
 
@@ -983,6 +1110,28 @@ def _check_mixed(cfg: ModelConfig) -> None:
         )
     if cfg.num_window_layers and cfg.sliding_window < 1:
         raise ValueError(f"{cfg.name}: swa layers need a sliding_window")
+    if cfg.num_latent_layers:
+        if cfg.num_attn_layers or cfg.num_window_layers:
+            # one pool, one page width (``ModelConfig.page_width``)
+            raise NotImplementedError(
+                f"{cfg.name}: latent (mla) layers beside attention layers "
+                "that keep K/V (a pool of another page width)"
+            )
+        if min(cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+               cfg.v_head_dim) < 1 or cfg.qk_rope_head_dim < 2 or (
+            cfg.qk_rope_head_dim % 2
+        ):
+            raise ValueError(
+                f"{cfg.name}: mla layers need q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, v_head_dim and an even qk_rope_head_dim"
+            )
+        if not cfg.rope_interleave or cfg.rope_scaling_factor or (
+            cfg.position_embedding != "rope"
+        ):
+            raise NotImplementedError(
+                f"{cfg.name}: mla layers take the interleaved rotary "
+                "embedding, unscaled (no half-split pairs, no YaRN, no nope)"
+            )
     # supported in the walk: a window by the layer's kind ("swa", its
     # K/V a pool of its own) and a rotary embedding a kind (YaRN on the
     # full layers, plain ``local_rope_theta`` on the window layers). A
@@ -1129,6 +1278,16 @@ def _mixed_trunk(
         if mixer == "conv":
             with jax.named_scope("conv_mixer"):
                 y, out["conv"] = conv_mixer(cfg, lp, x, conv_state[m_idx])
+        elif mixer == "mla":
+            with jax.named_scope("mla_mixer"):
+                y, out["k"] = mla_mixer(
+                    cfg, lp, x, positions=positions, valid_len=valid_len,
+                    pages=k_pages, layer=m_idx, page_table=page_table,
+                    past_len=past_len,
+                    win_rows=None if window_past is None
+                    else window_past[0][m_idx],
+                    win_len=win_len, use_pallas=use_pallas,
+                )
         elif mixer == "mamba":
             with jax.named_scope("mamba_mixer"):
                 y, ssm = mamba_mixer(

@@ -21,6 +21,17 @@ local:global, gpt-oss alternating — SURVEY §5.7), and gpt-oss learnable
 attention sinks (an extra per-head softmax logit that absorbs probability
 mass).
 
+``latent_attention`` is the attention of a LATENT layer (models/
+transformer.py ``mla_mixer``), whose cache keeps one row a token that
+every head reads for both products: the expanded form over a chunk with
+no past (per-head K and V of two widths), and the absorbed form over
+gathered latent pages, a fused window's rows and the chunk's own rows
+under one softmax, in plain XLA a block of queries at a time. Under
+``use_pallas`` one decode step takes the paged kernel's latent variant
+(one fetch of a row's pages for both products) and a chunk with no past
+the flash kernel at zero-padded heads (``_latent_kernels``); a chunk of
+several tokens over a paged past gathers, as ``chunk_attention``'s does.
+
 ``live_window`` (static) marks a layer whose pool is the WINDOW pool of a
 model that keeps K/V a pool a kind (engine/kvcache.py): the pool holds a
 row's last ``live_window`` positions and the pages before them may belong
@@ -470,3 +481,178 @@ def chunk_attention(
 
     out = jnp.einsum("bkgts,bskd->btkgd", weights, vals.astype(jnp.float32))
     return out.reshape(B, T, NH, Dh).astype(q.dtype)
+
+
+#: sides of the flash body's square blocks for a latent layer's expanded
+#: heads: every query head has K and V of its own, so a grid step is ONE
+#: head's two products, and at the kernel's own 128 the step's fixed
+#: cost was most of it (PERF.md section 6, PR 42)
+_LATENT_FLASH_BLOCKS = (1024, 512, 256)     # the largest that divides the chunk
+
+
+def _latent_kernels(q, k, v, *, scale, pages, layer, page_table, past_len,
+                    win_rows, win_len, value_width):
+    """``latent_attention`` through the Pallas kernels where one takes
+    the call, else None (the XLA form, counted ``reference``). ONE
+    decode step over the latent pages: the paged kernel's latent
+    variant (``v_pages`` None: a row's pages fetched once for both
+    products, every head to the one stored row). A chunk with no past:
+    the flash kernel's body with the heads' Q and K zero-padded to whole
+    tiles of 128 lanes (192 -> 256: the padded products are wasted work
+    and show in the prefill roofline) and V at its own width (128),
+    under the layer's own scale, the operands in the dtype they have.
+    A chunk of several tokens over a paged past (chunked prefill, verify
+    forwards) gathers by design, as ``chunk_attention``'s does."""
+    B, T, NH, Dq = q.shape
+    if v is None and T == 1:
+        from .pallas_paged import paged_decode_attention, paged_decode_supported
+
+        if paged_decode_supported(q[:, 0], pages, page_table):
+            win = {}
+            if win_rows is not None and win_rows.shape[1] > 0:
+                win = dict(win_k=win_rows, win_len=win_len)
+            out = paged_decode_attention(
+                q[:, 0], pages, None, layer, page_table, past_len,
+                k[:, 0, None], None, jnp.asarray(0, jnp.int32),
+                scale=scale, **win,
+            )
+            return out[:, None, :, :value_width]
+        lowering.record_reference("paged_decode")
+        return None
+    if v is None:
+        lowering.record_reference("paged_decode")
+        return None
+    from .pallas_flash import flash_prefill, flash_prefill_supported
+
+    def padded(x):
+        pad = -x.shape[-1] % 128
+        return jnp.pad(x, ((0, 0),) * 3 + ((0, pad),))
+
+    qp = padded(q)
+    block = next(
+        (b for b in _LATENT_FLASH_BLOCKS
+         if flash_prefill_supported(qp, qp, None, None, b)), None,
+    )
+    if block is None:
+        lowering.record_reference("flash_prefill")
+        return None
+    return flash_prefill(
+        qp, padded(k), padded(v), scale=scale, native=True, block=block,
+    )[..., :v.shape[-1]]
+
+
+def latent_attention(
+    q: jax.Array,                 # [B, T, NH, Dq]
+    k: jax.Array,                 # expanded [B, T, NH, Dq]; absorbed: the
+    #                               chunk's own ROWS [B, T, Dq], one a token
+    v: Optional[jax.Array],       # expanded [B, T, NH, Dv]; absorbed: None
+    *,
+    positions: jax.Array,         # [B, T] global positions of the queries
+    valid_len: jax.Array,         # [B] valid tokens in the chunk
+    scale: float,
+    pages: Optional[jax.Array] = None,   # [L, NP, PS, Dq]: the latent pool
+    layer: Optional[jax.Array] = None,
+    page_table: Optional[jax.Array] = None,  # [B, MP]
+    past_len: Optional[jax.Array] = None,    # [B]
+    win_rows: Optional[jax.Array] = None,    # [B, W, Dq]: a fused window's
+    win_len: Optional[jax.Array] = None,     # rows, at past_len + slot
+    value_width: Optional[int] = None,   # absorbed: a row's leading values
+    use_pallas: bool = False,
+    block_q: int = 512,
+) -> jax.Array:
+    """Causal attention of a latent layer, ``[B, T, NH, Dv]`` (absorbed:
+    ``[B, T, NH, value_width]``, still to go through the value half of
+    the up-projection). ``v`` None is the ABSORBED form: keys are rows
+    ``[.., Dq]`` shared by every head (the queries already carry the key
+    half of the up-projection), and a row's first ``value_width``
+    elements are its values, so ONE stored row serves both products:
+    the value product runs over the whole row (an eighth more columns,
+    dropped after) and no slice of the gathered pages is ever made. Its
+    keys come in up to three segments under one softmax, never one
+    concatenated context: the paged past (ONE gather of the rows'
+    tables on the stacked pool, ``[B, MP * PS, Dq]``, used where it
+    lies), a fused window's rows, and the chunk's own rows (causal).
+    The EXPANDED form has the chunk alone, keys and values a head.
+
+    Queries go a block of ``block_q`` at a time, so the float32 scores
+    are ``[B, NH, block_q, keys]`` and never ``[B, NH, T, keys]`` (2 GB
+    at 32 heads and a chunk of 4,096); a block of the chunk's own keys
+    ends where the block's last query does, which skips the upper half
+    of the causal square. Operands reach the MXU in the dtype they
+    have; scores, masks and the softmax are float32, the probabilities
+    are rounded to the values' dtype for their product."""
+    B, T, NH = q.shape[:3]
+    f32 = jnp.float32
+    absorbed = v is None
+    lowering.record_latent("absorbed" if absorbed else "expanded")
+    if use_pallas:
+        out = _latent_kernels(
+            q, k, v, scale=scale, pages=pages, layer=layer,
+            page_table=page_table, past_len=past_len, win_rows=win_rows,
+            win_len=win_len, value_width=value_width,
+        )
+        if out is not None:
+            return out
+    # segments: (keys, values, key positions [B, X], key validity [B, X])
+    segs = []
+    if pages is not None:
+        L, NP, PS, W = pages.shape
+        MP = page_table.shape[1]
+        at = layer * NP + page_table.reshape(-1)
+        past = pages.reshape(L * NP, PS, W)[at].reshape(B, MP * PS, W)
+        pos = jnp.broadcast_to(
+            jnp.arange(MP * PS, dtype=jnp.int32)[None], (B, MP * PS)
+        )
+        segs.append((past, past, pos, pos < past_len[:, None]))
+        if win_rows is not None and win_rows.shape[1] > 0:
+            slot = jnp.arange(win_rows.shape[1], dtype=jnp.int32)[None]
+            segs.append((
+                win_rows, win_rows, past_len[:, None] + slot,
+                jnp.broadcast_to(slot < win_len, (B, win_rows.shape[1])),
+            ))
+    own_valid = jnp.arange(T, dtype=jnp.int32)[None] < valid_len[:, None]
+    outs = []
+    for q0 in range(0, T, block_q):
+        q1 = min(q0 + block_q, T)
+        qb, qp = q[:, q0:q1], positions[:, q0:q1]
+        # the chunk's own keys up to the block's last query
+        own = (
+            k[:, :q1], k[:, :q1] if absorbed else v[:, :q1],
+            positions[:, :q1], own_valid[:, :q1],
+        )
+        scores = []
+        for keys, _, kpos, kvalid in segs + [own]:
+            if keys.ndim == 3:       # rows shared by the heads
+                sc = jnp.einsum(
+                    "btnc,bxc->bntx", qb, keys, preferred_element_type=f32
+                )
+            else:
+                sc = jnp.einsum(
+                    "btnd,bxnd->bntx", qb, keys, preferred_element_type=f32
+                )
+            ok = (kpos[:, None, :] <= qp[:, :, None]) & kvalid[:, None, :]
+            scores.append(jnp.where(ok[:, None], sc * scale, NEG_INF))
+        m = functools.reduce(
+            jnp.maximum, [jnp.max(sc, axis=-1) for sc in scores]
+        )                                                   # [B, NH, t]
+        denom = acc = None
+        for sc, (_, vals, _, _) in zip(scores, segs + [own]):
+            p = jnp.exp(sc - m[..., None])
+            d = jnp.sum(p, axis=-1)
+            if vals.ndim == 3:
+                o = jnp.einsum(
+                    "bntx,bxc->btnc", p.astype(vals.dtype), vals,
+                    preferred_element_type=f32,
+                )
+            else:
+                o = jnp.einsum(
+                    "bntx,bxnd->btnd", p.astype(vals.dtype), vals,
+                    preferred_element_type=f32,
+                )
+            denom = d if denom is None else denom + d
+            acc = o if acc is None else acc + o
+        out = acc / jnp.moveaxis(denom, 1, 2)[..., None]    # [B, t, NH, D]
+        if absorbed:
+            out = out[..., :value_width]
+        outs.append(out.astype(q.dtype))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)

@@ -39,6 +39,10 @@ _counts: Dict[str, Dict[str, int]] = {
     k: dict.fromkeys(PATHS, 0) for k in KERNELS + (GROUPED,)
 }
 _xla_decode = 0
+#: traces of a latent layer's attention (ops/attention.latent_attention),
+#: by form
+LATENT_FORMS = ("expanded", "absorbed")
+_latent: Dict[str, int] = dict.fromkeys(LATENT_FORMS, 0)
 
 
 def record_kernel(kernel: str, *, interpret: bool) -> None:
@@ -58,6 +62,25 @@ def record_xla_decode() -> None:
     global _xla_decode
     with _lock:
         _xla_decode += 1
+
+
+def record_latent(form: str) -> None:
+    """Called from ``ops/attention.latent_attention``'s traced body."""
+    with _lock:
+        _latent[form] += 1
+
+
+def latent_counts() -> Dict[str, int]:
+    """Traces of a latent layer's attention, by form: ``expanded`` (a
+    chunk with no past: K and V a head from the chunk's own rows) and
+    ``absorbed`` (over the latent pages: the up-projection folded into
+    the query and the output), whichever path computed them: under
+    ``use_pallas`` ``snapshot()`` says beside it whether a call took a
+    kernel (``paged_decode`` / ``flash_prefill`` lowered) or the XLA
+    form (``reference``). A count of its own for the reason
+    ``grouped_matmul_counts`` has one."""
+    with _lock:
+        return dict(_latent)
 
 
 def xla_decode_count() -> int:

@@ -168,6 +168,16 @@ def held_rows(flat_expert, flat_token, flat_prob, first, held: int):
     return s_key, flat_token[order], weight, group_sizes
 
 
+def _share_row_cap(M: int, held: int, experts: int):
+    """Rows of ``M`` expanded rows a held share's products take when its
+    own rows fit them, or None (all rows): twice the share an even
+    router sends it, in whole tiles of 512, where that is under half
+    the rows. A share of a half (or a decode step's few rows) gives
+    None, and the program it had."""
+    cap = -(-2 * M * held // experts // 512) * 512
+    return cap if 2 * cap <= M else None
+
+
 def relu2(x: jax.Array) -> jax.Array:
     """``relu(x)^2``, squared in float32."""
     r = jax.nn.relu(x.astype(jnp.float32))
@@ -232,7 +242,13 @@ def moe_mlp(
 
     ``we_*`` with fewer experts than the router has outputs are a held
     share that starts at ``first_expert`` (module docstring); it takes
-    the ragged path at every size, whose sort is its definition.
+    the ragged path at every size, whose sort is its definition. The
+    sort puts the held experts' rows first; a share under a quarter over
+    many rows (a prefill: ``_share_row_cap``) runs its products over
+    those rows alone where they fit twice its even share, since at a
+    sixteenth the zero rows that ride the last group were fifteen of
+    sixteen (215 of a 4,096-token prefill's 358 ms: PERF.md section 6,
+    PR 42).
 
     With ``layer`` (scalar int32) the ``we_*`` are the STACKS of every
     routed layer, [L, E, H, F] / [L, E, F, H], and this layer's experts
@@ -287,37 +303,63 @@ def moe_mlp(
         return result(out.reshape(B, T, H))
 
     # ragged grouped-GEMM path
+    cap = None
     if share:
         sorted_expert, sorted_token, sorted_prob, group_sizes = held_rows(
             flat_expert, flat_token, flat_prob, first_expert, held
         )
-        mine = sorted_expert < held
-        lhs = xt[sorted_token] * mine[:, None].astype(xt.dtype)
-        sorted_expert = jnp.minimum(sorted_expert, held - 1)
+        cap = _share_row_cap(sorted_token.shape[0], held, E)
     else:
         order = jnp.argsort(flat_expert)                  # stable order by expert
         sorted_expert = flat_expert[order]
         sorted_token = flat_token[order]
         sorted_prob = flat_prob[order]
         group_sizes = jnp.bincount(sorted_expert, length=E).astype(jnp.int32)
-        lhs = xt[sorted_token]                            # [M, H]
     if layer is not None:  # the stacks seen flat: a bitcast
         we_gate, we_up, we_down = _each(
             (we_gate, we_up, we_down),
             lambda w: w.reshape((-1,) + w.shape[2:]),
         )
-    grouped = functools.partial(
-        _grouped, group_sizes=group_sizes, use_pallas=use_pallas, layer=layer
-    )
 
-    g = None if we_gate is None else grouped(lhs, we_gate)  # [M, F]
-    u = grouped(lhs, we_up, transposed=we_gate is None)
-    if bias_gate is not None:
-        g = g + bias_gate[sorted_expert].astype(g.dtype)
-        u = u + bias_up[sorted_expert].astype(u.dtype)
-    y = grouped(_hidden(g, u, activation), we_down)       # [M, H]
-    if bias_down is not None:
-        y = y + bias_down[sorted_expert].astype(y.dtype)
-    y = y * sorted_prob[:, None].astype(y.dtype)
-    out = jnp.zeros((N, H), y.dtype).at[sorted_token].add(y)
+    def through(sorted_expert, sorted_token, sorted_prob, group_sizes):
+        """The experts over rows sorted by group, summed back a token."""
+        if share:
+            mine = sorted_expert < held
+            lhs = xt[sorted_token] * mine[:, None].astype(xt.dtype)
+            sorted_expert = jnp.minimum(sorted_expert, held - 1)
+        else:
+            lhs = xt[sorted_token]                        # [M, H]
+        grouped = functools.partial(
+            _grouped, group_sizes=group_sizes, use_pallas=use_pallas,
+            layer=layer,
+        )
+        g = None if we_gate is None else grouped(lhs, we_gate)  # [M, F]
+        u = grouped(lhs, we_up, transposed=we_gate is None)
+        if bias_gate is not None:
+            g = g + bias_gate[sorted_expert].astype(g.dtype)
+            u = u + bias_up[sorted_expert].astype(u.dtype)
+        y = grouped(_hidden(g, u, activation), we_down)       # [M, H]
+        if bias_down is not None:
+            y = y + bias_down[sorted_expert].astype(y.dtype)
+        y = y * sorted_prob[:, None].astype(y.dtype)
+        return jnp.zeros((N, H), y.dtype).at[sorted_token].add(y)
+
+    rows = (sorted_expert, sorted_token, sorted_prob)
+    if cap is None:
+        out = through(*rows, group_sizes)
+    else:
+        # a small share of many rows: the held experts' rows come first
+        # in the sort, and the first ``cap`` rows hold them all unless
+        # the router sent this chip over twice its even share (then
+        # every row, as above). What rides the last group is zeros
+        # either way, so the sums are the same
+        M = sorted_token.shape[0]
+        out = jax.lax.cond(
+            jnp.sum(sorted_expert < held) <= cap,
+            lambda: through(
+                *(r[:cap] for r in rows),
+                group_sizes.at[held - 1].add(cap - M),
+            ),
+            lambda: through(*rows, group_sizes),
+        )
     return result(out.reshape(B, T, H))

@@ -78,6 +78,7 @@ def _flash_kernel(
     *,
     groups: int,
     scale: float,
+    native: bool = False,
 ):
     qb = pl.program_id(2)
     kb = pl.program_id(3)
@@ -109,10 +110,13 @@ def _flash_kernel(
         ok = jnp.logical_and(
             ok, jnp.logical_or(qpos - kpos < win, win <= 0)
         )
-        k = k_ref[0, 0].astype(jnp.float32)            # [BK, Dh]
-        v = v_ref[0, 0].astype(jnp.float32)            # [BK, Dh]
+        # ``native``: the operands reach the MXU in the dtype they
+        # have (float32 accumulation), not up-cast first
+        cast = (lambda x: x) if native else (lambda x: x.astype(jnp.float32))
+        k = cast(k_ref[0, 0])                          # [BK, Dh]
+        v = cast(v_ref[0, 0])                          # [BK, Dv]
         for g in range(groups):  # static unroll over heads in the group
-            q = q_ref[0, 0, g].astype(jnp.float32)     # [BQ, Dh]
+            q = cast(q_ref[0, 0, g])                   # [BQ, Dh]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -125,7 +129,7 @@ def _flash_kernel(
             p = jnp.exp(s - m_new[:, None])            # [BQ, BK]
             l_new = l_ref[g, :, 0] * alpha + jnp.sum(p, axis=1)
             acc_ref[g] = acc_ref[g] * alpha[:, None] + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             m_ref[g] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
@@ -147,7 +151,7 @@ def _flash_kernel(
 
 
 def flash_prefill_supported(
-    q: jax.Array, k: jax.Array, window, sink
+    q: jax.Array, k: jax.Array, window, sink, block: int = BLOCK_Q
 ) -> bool:
     """Static shape gate for the compiled TPU path. window/sink are
     dynamic operands of the kernel, so they never gate."""
@@ -157,31 +161,47 @@ def flash_prefill_supported(
         return False
     G = NH // KVH
     return (
-        T >= BLOCK_Q
-        and T % BLOCK_Q == 0
+        T >= block
+        and T % block == 0
         and Dh % 128 == 0
         and G <= MAX_GROUP
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "scale", "native", "block")
+)
 def flash_prefill(
     q: jax.Array,                    # [B, T, NH, Dh]
     k: jax.Array,                    # [B, T, KVH, Dh] (post-RoPE)
-    v: jax.Array,                    # [B, T, KVH, Dh]
+    v: jax.Array,                    # [B, T, KVH, Dv]; Dv = Dh but for
+    #                                  a latent layer's expanded heads
     *,
     window: Optional[jax.Array] = None,   # scalar int32; 0/None => full
     sink: Optional[jax.Array] = None,     # [NH] logits or None
     interpret: bool = False,
+    # the softmax scale where it is not 1/sqrt(Dh): heads zero-padded
+    # to the kernel's one head size (ops/attention.latent_attention)
+    scale: Optional[float] = None,
+    # the operands as they are to the MXU (``_flash_kernel``), and the
+    # side of the square query and key blocks, where not BLOCK_Q: at one
+    # query head a K/V head a block of 128 is two small products a grid
+    # step, and the step's own cost leads
+    native: bool = False,
+    block: Optional[int] = None,
 ) -> jax.Array:
-    """Returns [B, T, NH, Dh] causal self-attention over the chunk."""
+    """Returns [B, T, NH, Dv] causal self-attention over the chunk."""
     lowering.record_kernel("flash_prefill", interpret=interpret)
     B, T, NH, Dh = q.shape
     KVH = k.shape[2]
+    Dv = v.shape[-1]
     G = NH // KVH
-    scale = Dh ** -0.5
-    nQ = T // BLOCK_Q
-    nK = T // BLOCK_K
+    scale = Dh ** -0.5 if scale is None else scale
+    BQ = BK = block
+    if block is None:
+        BQ, BK = BLOCK_Q, BLOCK_K
+    nQ = T // BQ
+    nK = T // BK
 
     # head-major layout: [B, KVH, G, T, Dh] / [B, KVH, T, Dh]
     qh = q.reshape(B, T, KVH, G, Dh).transpose(0, 2, 3, 1, 4)
@@ -199,21 +219,23 @@ def flash_prefill(
         else jnp.asarray(window, jnp.int32).reshape(1)
     )
 
-    kernel = functools.partial(_flash_kernel, groups=G, scale=scale)
+    kernel = functools.partial(
+        _flash_kernel, groups=G, scale=scale, native=native
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, KVH, nQ, nK),
         in_specs=[
             pl.BlockSpec(
-                (1, 1, G, BLOCK_Q, Dh),
+                (1, 1, G, BQ, Dh),
                 lambda b, h, qb, kb, win: (b, h, 0, qb, 0),
             ),
             pl.BlockSpec(
-                (1, 1, BLOCK_K, Dh),
+                (1, 1, BK, Dh),
                 lambda b, h, qb, kb, win: (b, h, kb, 0),
             ),
             pl.BlockSpec(
-                (1, 1, BLOCK_K, Dh),
+                (1, 1, BK, Dv),
                 lambda b, h, qb, kb, win: (b, h, kb, 0),
             ),
             pl.BlockSpec(
@@ -221,19 +243,19 @@ def flash_prefill(
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, G, BLOCK_Q, Dh),
+            (1, 1, G, BQ, Dv),
             lambda b, h, qb, kb, win: (b, h, 0, qb, 0),
         ),
         scratch_shapes=[
-            pltpu.VMEM((G, BLOCK_Q, 128), jnp.float32),
-            pltpu.VMEM((G, BLOCK_Q, 128), jnp.float32),
-            pltpu.VMEM((G, BLOCK_Q, Dh), jnp.float32),
+            pltpu.VMEM((G, BQ, 128), jnp.float32),
+            pltpu.VMEM((G, BQ, 128), jnp.float32),
+            pltpu.VMEM((G, BQ, Dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, T, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KVH, G, T, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary"
@@ -241,4 +263,4 @@ def flash_prefill(
         ),
         interpret=interpret,
     )(win, qh, kh, vh, sink_g)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, T, NH, Dh)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, T, NH, Dv)

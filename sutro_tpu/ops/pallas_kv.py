@@ -44,20 +44,19 @@ from . import lowering
 def _kv_write_kernel(
     # scalar prefetch (flattened [B*S] segment tables)
     seg_page_ref, seg_rs_ref, seg_re_ref, seg_shift_ref, seg_row_ref,
-    # operands
-    k_new_ref,     # VMEM block [lc, 1, Tb, KD] — (layer chunk, seg row)
-    v_new_ref,
-    k_io_ref,      # ANY [L, NP, PS, KD] aliased inputs
-    v_io_ref,
-    k_out_ref,     # ANY aliased outputs
-    v_out_ref,
-    # scratch
-    kpage, vpage, ksem, vsem,
-    *,
+    # then, a pool at a time (K and V; ONE for a pool of latent rows):
+    # the new rows, VMEM blocks [lc, 1, Tb, KD] (layer chunk, seg row);
+    # the aliased inputs, ANY [L, NP, PS, KD]; the aliased outputs; the
+    # page slabs (scratch) and their semaphores
+    *refs,
     page_size: int,
     layer_chunk: int,
+    pools: int = 2,
 ):
-    del k_io_ref, v_io_ref
+    new_refs = refs[:pools]
+    out_refs = refs[2 * pools:3 * pools]      # refs[pools:2 * pools]: unused
+    pages = refs[3 * pools:4 * pools]
+    sems = refs[4 * pools:5 * pools]
     s = pl.program_id(0)
     lchunk = pl.program_id(1)
     PS = page_size
@@ -69,21 +68,19 @@ def _kv_write_kernel(
     @pl.when(re > rs)
     def _do():
         lsl = pl.ds(lchunk * lc, lc)
-        kin = pltpu.make_async_copy(
-            k_out_ref.at[lsl, page], kpage, ksem
-        )
-        vin = pltpu.make_async_copy(
-            v_out_ref.at[lsl, page], vpage, vsem
-        )
-        kin.start()
-        vin.start()
+        reads = [
+            pltpu.make_async_copy(out.at[lsl, page], slab, sem)
+            for out, slab, sem in zip(out_refs, pages, sems)
+        ]
+        for dma in reads:
+            dma.start()
 
         # token j lives at page row (start + j) % PS; rolling the token
         # buffer by -shift puts token (r + shift) at row r for every r
         shift = seg_shift_ref[s]
-        Tb = k_new_ref.shape[2]
+        Tb = new_refs[0].shape[2]
         row = jax.lax.broadcasted_iota(
-            jnp.int32, (PS, k_new_ref.shape[3]), 0
+            jnp.int32, (PS, new_refs[0].shape[3]), 0
         )
         sel = jnp.logical_and(row >= rs, row < re)
 
@@ -96,28 +93,21 @@ def _kv_write_kernel(
                 )
             return pltpu.roll(t, -shift, 0)[:PS]
 
-        kin.wait()
-        vin.wait()
+        for dma in reads:
+            dma.wait()
         for j in range(lc):  # static unroll over the layer chunk
-            krot = rotated(k_new_ref[j, 0])
-            vrot = rotated(v_new_ref[j, 0])
-            kpage[j] = jnp.where(
-                sel, krot.astype(kpage.dtype), kpage[j]
-            )
-            vpage[j] = jnp.where(
-                sel, vrot.astype(vpage.dtype), vpage[j]
-            )
+            rots = [rotated(new[j, 0]) for new in new_refs]
+            for rot, slab in zip(rots, pages):
+                slab[j] = jnp.where(sel, rot.astype(slab.dtype), slab[j])
 
-        kout = pltpu.make_async_copy(
-            kpage, k_out_ref.at[lsl, page], ksem
-        )
-        vout = pltpu.make_async_copy(
-            vpage, v_out_ref.at[lsl, page], vsem
-        )
-        kout.start()
-        vout.start()
-        kout.wait()
-        vout.wait()
+        writes = [
+            pltpu.make_async_copy(slab, out.at[lsl, page], sem)
+            for out, slab, sem in zip(out_refs, pages, sems)
+        ]
+        for dma in writes:
+            dma.start()
+        for dma in writes:
+            dma.wait()
 
 
 def _layer_chunk(L: int, Tb: int, PS: int, KD: int, itemsize: int) -> int:
@@ -145,9 +135,39 @@ def kv_write_pallas(
     *,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
+    out_k, out_v = _write_pools(
+        (k_pages, v_pages), (k_new, v_new), page_table, start, valid_len,
+        interpret,
+    )
+    return out_k, out_v
+
+
+@functools.partial(
+    jax.jit, donate_argnums=(0,), static_argnames=("interpret",)
+)
+def row_write_pallas(
+    pages: jax.Array,     # [L, NP, PS, W]: ONE pool, a row a token
+    new: jax.Array,       # [L, B, Tb, W]
+    page_table: jax.Array,  # [B, MP] int32
+    start: jax.Array,       # [B] int32
+    valid_len: jax.Array,   # [B] int32
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """The same in-place write for a pool that has no V beside it (a
+    model of latent layers, engine/kvcache.py): one slab a segment."""
+    return _write_pools(
+        (pages,), (new,), page_table, start, valid_len, interpret
+    )[0]
+
+
+def _write_pools(pools, news, page_table, start, valid_len, interpret):
+    """``news[i]`` [L, B, Tb, KD] written into ``pools[i]`` [L, NP, PS,
+    KD] in place, the pools of one call through the same segments."""
     lowering.record_kernel("kv_write", interpret=interpret)
-    L, NP, PS, KD = k_pages.shape
-    _, B, Tb, _ = k_new.shape
+    n = len(pools)
+    L, NP, PS, KD = pools[0].shape
+    _, B, Tb, _ = news[0].shape
     MP = page_table.shape[1]
 
     # per-(row, page) segments; a run of Tb tokens at any offset touches
@@ -174,9 +194,9 @@ def kv_write_pallas(
         jnp.arange(B, dtype=jnp.int32)[:, None], (B, S)
     )
 
-    lc = _layer_chunk(L, Tb, PS, KD, k_pages.dtype.itemsize)
+    lc = _layer_chunk(L, Tb, PS, KD, pools[0].dtype.itemsize)
     kernel = functools.partial(
-        _kv_write_kernel, page_size=PS, layer_chunk=lc
+        _kv_write_kernel, page_size=PS, layer_chunk=lc, pools=n
     )
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     new_spec = pl.BlockSpec(
@@ -185,25 +205,21 @@ def kv_write_pallas(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(B * S, L // lc),
-        in_specs=[new_spec, new_spec, any_spec, any_spec],
-        out_specs=[any_spec, any_spec],
+        in_specs=[new_spec] * n + [any_spec] * n,
+        out_specs=[any_spec] * n,
         scratch_shapes=[
-            pltpu.VMEM((lc, PS, KD), k_pages.dtype),
-            pltpu.VMEM((lc, PS, KD), v_pages.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
+            pltpu.VMEM((lc, PS, KD), pool.dtype) for pool in pools
+        ] + [pltpu.SemaphoreType.DMA] * n,
     )
-    out_k, out_v = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
+            jax.ShapeDtypeStruct(pool.shape, pool.dtype) for pool in pools
         ],
-        # flattened operands: scalars(0-4), k_new(5), v_new(6),
-        # k_pages(7), v_pages(8) -> outputs 0, 1
-        input_output_aliases={7: 0, 8: 1},
+        # flattened operands: scalars (0-4), the new rows (5 ..), then
+        # the pools, each aliased to its output
+        input_output_aliases={5 + n + i: i for i in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
@@ -211,6 +227,5 @@ def kv_write_pallas(
     )(
         page.reshape(-1), rs.reshape(-1), re.reshape(-1),
         shift.reshape(-1), row.reshape(-1),
-        k_new, v_new, k_pages, v_pages,
+        *news, *pools,
     )
-    return out_k, out_v

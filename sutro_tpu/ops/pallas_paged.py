@@ -88,9 +88,12 @@ def _paged_decode_kernel(
     quantized: bool = False,
     prefix: bool = False,
     window_start: bool = False,
+    shared: bool = False,
 ):
-    # ref layout varies with (window_slots, quantized, prefix) — walk an
-    # index instead of a per-case tuple unpack
+    # ref layout varies with (window_slots, quantized, prefix, shared) —
+    # walk an index instead of a per-case tuple unpack. ``shared``: ONE
+    # pool whose rows serve both products (a latent layer's: module
+    # docstring), so there is no V pool, current V, window V or V ring
     it = iter(refs)
     page_table_ref = next(it)
     past_len_ref = next(it)
@@ -100,22 +103,22 @@ def _paged_decode_kernel(
     win_len_ref = next(it) if window_slots else None
     q_ref = next(it)
     k_pool_ref = next(it)
-    v_pool_ref = next(it)
+    v_pool_ref = None if shared else next(it)
     ks_pool_ref = next(it) if quantized else None
     vs_pool_ref = next(it) if quantized else None
     k_cur_ref = next(it)
-    v_cur_ref = next(it)
+    v_cur_ref = None if shared else next(it)
     wk_ref = next(it) if window_slots else None
-    wv_ref = next(it) if window_slots else None
+    wv_ref = next(it) if window_slots and not shared else None
     m0_ref = next(it) if prefix else None
     l0_ref = next(it) if prefix else None
     acc0_ref = next(it) if prefix else None
     sink_ref = next(it)
     out_ref = next(it)
     kbuf = next(it)
-    vbuf = next(it)
+    vbuf = None if shared else next(it)
     ksem = next(it)
-    vsem = next(it)
+    vsem = None if shared else next(it)
     ksbuf = next(it) if quantized else None
     vsbuf = next(it) if quantized else None
     kssem = next(it) if quantized else None
@@ -197,10 +200,11 @@ def _paged_decode_kernel(
             pltpu.make_async_copy(
                 k_pool_ref.at[layer, page], kbuf.at[s], ksem.at[s]
             ),
-            pltpu.make_async_copy(
-                v_pool_ref.at[layer, page], vbuf.at[s], vsem.at[s]
-            ),
         ]
+        if not shared:
+            dmas.append(pltpu.make_async_copy(
+                v_pool_ref.at[layer, page], vbuf.at[s], vsem.at[s]
+            ))
         if quantized:
             dmas += [
                 pltpu.make_async_copy(
@@ -321,10 +325,19 @@ def _paged_decode_kernel(
         )
         # [size, PS, KD] -> [GT, KD]: leading-dim collapse only (the
         # lane dim KD is untouched — Mosaic supports this shape cast)
-        k = kbuf[pl.ds(slot, size)].reshape(GT, KD).astype(jnp.float32)
-        v = vbuf[pl.ds(slot, size)].reshape(GT, KD).astype(jnp.float32)
+        if shared:
+            # the page as it landed serves both products, in the pool's
+            # dtype (float32 accumulation): at NH rows to one 640-wide
+            # key a float32 product of the MXU, not the fetch, would
+            # bound the step
+            k = v = kbuf[pl.ds(slot, size)].reshape(GT, KD)
+            q_in = q_bd.astype(k.dtype)
+        else:
+            k = kbuf[pl.ds(slot, size)].reshape(GT, KD).astype(jnp.float32)
+            v = vbuf[pl.ds(slot, size)].reshape(GT, KD).astype(jnp.float32)
+            q_in = q_bd
         s = jax.lax.dot_general(
-            q_bd, k, (((1,), (1,)), ((), ())),
+            q_in, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                                        # [NH, GT]
         if quantized:
@@ -358,7 +371,7 @@ def _paged_decode_kernel(
                 axis=1,
             )
         else:
-            pv = p
+            pv = p.astype(v.dtype)       # float32 but for a shared pool
         # acc holds the full [NH, KVH*Dh] product; only each row's own
         # head block is meaningful (extracted at the end)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
@@ -390,7 +403,7 @@ def _paged_decode_kernel(
     # in the same block-diagonal space (2 dots total, not 2 per head)
     W = window_slots
     k_cur = k_cur_ref[0].astype(jnp.float32)             # [1, KD]
-    v_cur = v_cur_ref[0].astype(jnp.float32)             # [1, KD]
+    v_cur = k_cur if shared else v_cur_ref[0].astype(jnp.float32)
     sink = sink_ref[0].astype(jnp.float32)               # [NH]
 
     # one key: a lane reduction, not a matmul with a single column
@@ -402,7 +415,7 @@ def _paged_decode_kernel(
         # token at position past+s; the query is at pos
         wlen = win_len_ref[0]
         wk = wk_ref[0].astype(jnp.float32)               # [W, KD]
-        wv = wv_ref[0].astype(jnp.float32)
+        wv = wk if shared else wv_ref[0].astype(jnp.float32)
         slot_i = jax.lax.broadcasted_iota(jnp.int32, (NH, W), 1)
         ok_w = slot_i < wlen
         ok_w = jnp.logical_and(
@@ -753,11 +766,13 @@ def paged_decode_supported(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window_start"))
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "window_start", "scale")
+)
 def paged_decode_attention(
     q: jax.Array,          # [B, NH, Dh] — current-step queries
     k_pages: jax.Array,    # [L, NP, PS, KVH*Dh] — the stacked FUSED pool
-    v_pages: jax.Array,
+    v_pages: Optional[jax.Array],   # None: ``k_pages`` serves both products
     layer: jax.Array,      # scalar int32 — the layer this call reads
     page_table: jax.Array, # [B, MP] int32
     past_len: jax.Array,   # [B] int32 — tokens already in the cache
@@ -786,8 +801,19 @@ def paged_decode_attention(
     # the pool holds a row's last ``window`` positions only: fetch from
     # the page of position max(pos - window + 1, 0) on (module docstring)
     window_start: bool = False,
+    # the softmax scale where it is not 1/sqrt(Dh) (a latent layer's)
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Returns [B, NH, Dh] attention outputs for one decode step.
+
+    ``v_pages`` None is the LATENT variant (models/transformer.py
+    ``mla_mixer``, the absorbed form): ``k_pages`` is a pool of ONE row
+    a token, ``[L, NP, PS, Dh]``, that all ``NH`` heads read for both
+    products (``KVH`` 1; ``q`` carries the key half of the
+    up-projection), so a row's pages are fetched ONCE, the value
+    product runs over the whole row (the caller keeps its leading
+    latent values) and ``k_cur`` / ``win_k`` stand for the values too
+    (``v_cur`` / ``win_v`` None).
 
     The page pools are the WHOLE stacked ``[L, NP, PS, KVH*Dh]``
     arrays (engine/kvcache.py) and stay in HBM; ``layer`` rides the
@@ -809,8 +835,9 @@ def paged_decode_attention(
     L, NP, PS, KD = k_pages.shape
     KVH = k_cur.shape[1]
     MP = page_table.shape[1]
-    scale = Dh ** -0.5
+    scale = Dh ** -0.5 if scale is None else scale
     W = 0 if win_k is None else win_k.shape[1]
+    shared = v_pages is None
 
     if sink is None:
         sink_g = jnp.full((1, NH), NEG_INF, jnp.float32)
@@ -832,6 +859,7 @@ def paged_decode_attention(
         quantized=quantized,
         prefix=prefix,
         window_start=window_start,
+        shared=shared,
     )
 
     # index maps take *s so the scalar-prefetch arity (4 to 6) needs
@@ -839,8 +867,9 @@ def paged_decode_attention(
     in_specs = [
         pl.BlockSpec((1, NH, Dh), lambda b, *s: (b, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),  # K pool stays in HBM
-        pl.BlockSpec(memory_space=pl.ANY),  # V pool stays in HBM
     ]
+    if not shared:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # V pool too
     scalars = [
         page_table.reshape(-1).astype(jnp.int32),
         past_len.astype(jnp.int32),
@@ -849,11 +878,7 @@ def paged_decode_attention(
     ]
     if prefix:
         scalars.append(pfx_cnt.astype(jnp.int32))
-    operands = [
-        q,
-        k_pages,
-        v_pages,
-    ]
+    operands = [q, k_pages] if shared else [q, k_pages, v_pages]
     if quantized:
         # pre-shaped [L, NP, 1, PS] (a bitcast of the stack): a page's
         # scales land lane-major (see page_dmas)
@@ -865,21 +890,16 @@ def paged_decode_attention(
             k_scale.astype(jnp.float32).reshape(L, NP, 1, PS),
             v_scale.astype(jnp.float32).reshape(L, NP, 1, PS),
         ]
-    in_specs += [
-        pl.BlockSpec((1, 1, KD), lambda b, *s: (b, 0, 0)),
-        pl.BlockSpec((1, 1, KD), lambda b, *s: (b, 0, 0)),
-    ]
-    operands += [
-        k_cur.reshape(B, 1, KD),
-        v_cur.reshape(B, 1, KD),
-    ]
+    # K then V; K alone where one pool's rows serve both
+    both = 1 if shared else 2
+    in_specs += [pl.BlockSpec((1, 1, KD), lambda b, *s: (b, 0, 0))] * both
+    operands += [x.reshape(B, 1, KD) for x in (k_cur, v_cur)[:both]]
     if W:
         scalars.append(jnp.asarray(win_len, jnp.int32).reshape(1))
         in_specs += [
-            pl.BlockSpec((1, W, KD), lambda b, *s: (b, 0, 0)),
-            pl.BlockSpec((1, W, KD), lambda b, *s: (b, 0, 0)),
-        ]
-        operands += [win_k, win_v]
+            pl.BlockSpec((1, W, KD), lambda b, *s: (b, 0, 0))
+        ] * both
+        operands += [win_k, win_v][:both]
     if prefix:
         # m0 / l0 as [B, 1, NH]: a row's block is then the array's whole
         # last two dims (a (1, NH) block of [B, NH] is no legal tile)
@@ -896,13 +916,11 @@ def paged_decode_attention(
     in_specs.append(pl.BlockSpec((1, NH), lambda b, *s: (0, 0)))
     operands.append(sink_g)
 
-    scratch_shapes = [
-        # the K/V ring: D page slots
-        pltpu.VMEM((D, PS, KD), k_pages.dtype),
-        pltpu.VMEM((D, PS, KD), v_pages.dtype),
-        pltpu.SemaphoreType.DMA((D,)),
-        pltpu.SemaphoreType.DMA((D,)),
-    ]
+    # the K/V ring: D page slots (K's alone for a shared pool)
+    ring_bufs = [pltpu.VMEM((D, PS, KD), k_pages.dtype)]
+    if not shared:
+        ring_bufs.append(pltpu.VMEM((D, PS, KD), v_pages.dtype))
+    scratch_shapes = ring_bufs + [pltpu.SemaphoreType.DMA((D,))] * both
     if quantized:
         scratch_shapes += [
             # per-token scales of each slot, lane-major [.., 1, PS]

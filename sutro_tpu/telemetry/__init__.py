@@ -363,6 +363,17 @@ STATE_COMMITS_TOTAL = REGISTRY.counter(
     unit="dispatches",
     max_series=8,
 )
+LATENT_ATTENTION_DISPATCHES_TOTAL = REGISTRY.counter(
+    "sutro_latent_attention_dispatches_total",
+    "Dispatches of a model of latent (mla) layers, by the form its "
+    "attention took: expanded (a chunk with no past: K and V a head "
+    "from the chunk's own latent rows) or absorbed (over the latent "
+    "pages: decode steps, fused and speculative windows, verify chunks, "
+    "chunked and suffix prefill)",
+    labels=("form",),  # expanded | absorbed
+    unit="dispatches",
+    max_series=4,
+)
 STATE_FALLBACK_PREFILL_TOKENS_TOTAL = REGISTRY.counter(
     "sutro_state_fallback_prefill_tokens_total",
     "Prompt tokens prefilled again because a path could not restore the "
@@ -371,10 +382,11 @@ STATE_FALLBACK_PREFILL_TOKENS_TOTAL = REGISTRY.counter(
     # prefix_without_state_snapshot | hibernate_without_slot_state
     # (state a slot: no page holds it); prefix_without_window_pages |
     # hibernate_without_window_pages (K/V a pool a kind: a shared or
-    # tiered page has no window page)
+    # tiered page has no window page); prefix_on_latent_pool |
+    # hibernate_on_latent_pool (a latent row a token: kvcache.py)
     labels=("reason",),
     unit="tokens",
-    max_series=8,
+    max_series=12,
 )
 KV_PAGES = REGISTRY.gauge(
     "sutro_kv_pages",
