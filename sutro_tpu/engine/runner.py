@@ -139,11 +139,13 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         "use_pallas": use_pallas,
         "pallas_reason": why,
         # what this process has traced so far (ops/lowering.py): each
-        # kernel by path, and beside them the XLA decode attention and
-        # the routed experts' grouped product (a routed model's alone)
+        # kernel by path, and beside them the XLA decode attention, the
+        # routed experts' grouped product (a routed model's alone) and a
+        # Mamba-2 layer's read of its committed state in a decode step
         "kernel_paths": lowering.snapshot(),
         "paged_decode_xla": lowering.xla_decode_count(),
         "grouped_matmul": lowering.grouped_matmul_counts(),
+        "ssm_state_read": lowering.ssm_state_read_counts(),
         # a latent layer's attention by form, whichever path computed it
         # (``kernel_paths`` above says kernel or XLA form)
         "latent_attention": lowering.latent_counts(),

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .configs import ModelConfig
+from ..ops import lowering, pallas_ssm
 from ..ops.moe import moe_mlp, relu2
 from ..ops.attention import chunk_attention, latent_attention
 from ..ops.quant import materialize
@@ -832,7 +833,8 @@ def ssd_chunked(
 
 def ssd_pending(
     cfg: ModelConfig,
-    pool: jax.Array,   # [NS, N, I]: this layer's slots, read in place
+    ssm: jax.Array,    # [L_m, NS, N, I]: every mamba layer's slots,
+    layer,             # and this layer's index: read in place
     slots: jax.Array,  # [B] int32
     fresh: jax.Array,  # [B] bool
     x: jax.Array,      # [B, W, I]   float32: the uncommitted tokens,
@@ -841,17 +843,27 @@ def ssd_pending(
     Bm: jax.Array,     # [B, W, G*N]
     Cq: jax.Array,     # [B, T, G*N]: C of the chunk's own tokens
     q0,                # index among the W of the chunk's first token
+    *,
+    use_pallas: bool = False,
+    kernel_mesh=None,
 ) -> jax.Array:
     """``y`` [B, T, I] of a short chunk whose state is NOT advanced:
     the committed state is read once, where it lies (every slot of the
     pool times its row's C, reduced over N on the major axis: no
     gather of the state, no write), and the uncommitted tokens up to
     each query enter through the masked ``C B^T`` product. Tokens after
-    a query are masked out, so buffers may hold anything there."""
+    a query are masked out, so buffers may hold anything there.
+
+    The state's read is the Pallas kernel of ops/pallas_ssm.py where
+    the caller runs its kernels (``use_pallas``), no mesh shards the
+    call (the pool is replicated under one and XLA cannot partition a
+    Mosaic call) and the shapes pass its static gate; the XLA
+    expression below otherwise, which is also what the kernel is held
+    to. ``ops/lowering.ssm_state_read_counts()`` says which a process
+    traced."""
     B, W, I = x.shape
     T = Cq.shape[1]
     Hm, P, G = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups
-    NS = pool.shape[0]
     cum = jnp.cumsum(dA, axis=1)                              # [B, W, Hm]
     cum_q = jax.lax.dynamic_slice_in_dim(cum, q0, T, axis=1)  # [B, T, Hm]
     seen = (
@@ -870,20 +882,32 @@ def ssd_pending(
     y = jnp.einsum(
         "btsh,bshp->bthp", w, x.reshape(B, W, Hm, P), precision=_HI
     ).reshape(B, T, I)
-    # the committed state: slot-major, so each slot takes ITS row's C
-    row = jnp.zeros((NS,), jnp.int32).at[slots].set(
-        jnp.arange(B, dtype=jnp.int32)
-    )
-    Cs = Cq[row]                                              # [NS, T, G*N]
-    state = pool.astype(jnp.float32)
-    yS = jnp.stack([
-        jnp.concatenate([
-            jnp.sum(state[..., ch] * c, axis=1)
-            for ch, c in zip(group_channels(I, G), over_state(Cs[:, t], G))
-        ], axis=-1)
-        for t in range(T)
-    ], axis=1)                                                # [NS, T, I]
-    inter = per_channel(jnp.exp(cum_q), P) * yS[slots]
+    if (
+        use_pallas and kernel_mesh is None
+        and pallas_ssm.state_read_supported(ssm, Cq, G)
+    ):
+        # a row's slot streamed once, the groups inside its block
+        by_row = pallas_ssm.ssm_state_read(ssm, layer, slots, Cq, groups=G)
+    else:
+        if use_pallas:
+            lowering.record_reference(lowering.SSM_STATE_READ)
+        pool = ssm[layer]                                     # [NS, N, I]
+        NS = pool.shape[0]
+        # the committed state: slot-major, so each slot takes ITS row's C
+        row = jnp.zeros((NS,), jnp.int32).at[slots].set(
+            jnp.arange(B, dtype=jnp.int32)
+        )
+        Cs = Cq[row]                                          # [NS, T, G*N]
+        state = pool.astype(jnp.float32)
+        yS = jnp.stack([
+            jnp.concatenate([
+                jnp.sum(state[..., ch] * c, axis=1)
+                for ch, c in zip(group_channels(I, G), over_state(Cs[:, t], G))
+            ], axis=-1)
+            for t in range(T)
+        ], axis=1)                                            # [NS, T, I]
+        by_row = yS[slots]
+    inter = per_channel(jnp.exp(cum_q), P) * by_row
     return y + jnp.where(fresh[:, None, None], 0.0, inter)
 
 
@@ -926,6 +950,8 @@ def mamba_mixer(
     past: StatePast,
     layer,                       # this layer's index among the mamba layers
     pending: bool,
+    use_pallas: bool = False,
+    kernel_mesh=None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One Mamba-2 mixer over a chunk (``ModelConfig.mamba_*``):
 
@@ -981,8 +1007,9 @@ def mamba_mixer(
                 for b, a in zip(bufs, (dt, dA, x, Bm))
             )
         y = ssd_pending(
-            cfg, past.ssm[layer], past.slots, past.fresh,
+            cfg, past.ssm, layer, past.slots, past.fresh,
             xs, dts, dAs, Bs, Cm, q0,
+            use_pallas=use_pallas, kernel_mesh=kernel_mesh,
         )
     else:
         S0 = past.ssm[layer][past.slots].astype(f32)          # [B, N, I]
@@ -1293,6 +1320,7 @@ def _mixed_trunk(
                 y, ssm = mamba_mixer(
                     cfg, lp, x, valid_len=valid_len, past=state_past,
                     layer=m_idx, pending=ssm_pending,
+                    use_pallas=use_pallas, kernel_mesh=kernel_mesh,
                 )
                 out.update(ssm)
         else:

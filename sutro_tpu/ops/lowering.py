@@ -33,10 +33,12 @@ PATHS = ("lowered", "interpreted", "reference")
 #: counted like a kernel of ``KERNELS`` but read by its own accessor
 #: (``grouped_matmul_counts``): a routed model's alone
 GROUPED = "grouped_matmul"
+#: likewise (``ssm_state_read_counts``): a Mamba-2 model's alone
+SSM_STATE_READ = "ssm_state_read"
 
 _lock = threading.Lock()
 _counts: Dict[str, Dict[str, int]] = {
-    k: dict.fromkeys(PATHS, 0) for k in KERNELS + (GROUPED,)
+    k: dict.fromkeys(PATHS, 0) for k in KERNELS + (GROUPED, SSM_STATE_READ)
 }
 _xla_decode = 0
 #: traces of a latent layer's attention (ops/attention.latent_attention),
@@ -103,6 +105,20 @@ def grouped_matmul_counts() -> Dict[str, int]:
     benchmark's numbers check) must keep passing there."""
     with _lock:
         return dict(_counts[GROUPED])
+
+
+def ssm_state_read_counts() -> Dict[str, int]:
+    """Traces of a Mamba-2 layer's read of its committed state in a
+    chunk that does not advance it (``models/transformer.ssd_pending``:
+    a decode step, a window's step, a verify chunk), by path:
+    ``ops/pallas_ssm.ssm_state_read``'s body (``lowered`` /
+    ``interpreted``) and a ``use_pallas=True`` call that stays on the
+    XLA expression (``reference``: a mesh, a pool or a chunk off the
+    kernel's static gate). A count of its own for the reason
+    ``grouped_matmul_counts`` has one: a model without a Mamba layer
+    never reads such a state."""
+    with _lock:
+        return dict(_counts[SSM_STATE_READ])
 
 
 def snapshot() -> Dict[str, Dict[str, int]]:

@@ -249,6 +249,22 @@ def test_a_fused_window_is_its_steps_one_at_a_time(runner, step):
         assert err(nxt, want(runner, seq2, [n + 6])[0]) < TOL
 
 
+def test_under_the_kernels_a_window_commits_the_tokens_it_commits_without(
+    monkeypatch,
+):
+    """``use_pallas`` sends every mamba layer's read of the committed
+    state, each step of a fused window, to ops/pallas_ssm.py
+    (interpreted here) and nothing the window commits changes."""
+    from tests.test_pallas_ssm import window_with_and_without_the_kernel
+
+    window_with_and_without_the_kernel(
+        monkeypatch,
+        lambda use_pallas: ModelRunner(MCFG, engine(use_pallas=use_pallas)),
+        [sequence(8, 13), sequence(9, 21)],
+        np.stack([table_of(1, 2, 3, 4, 5), table_of(6, 7, 8, 9, 10)]),
+    )
+
+
 def test_a_speculative_window_commits_any_accepted_prefix(runner, step):
     prompt = sequence(10, 14)
     table = table_of(1, 2, 3, 4)

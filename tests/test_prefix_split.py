@@ -26,11 +26,15 @@ SUFFIXES = ["good stuff", "bad stuff", "meh", "ok product arrived"]
 def _force_interpret(monkeypatch):
     """Run the engine's Pallas path on CPU: kernels in interpret mode,
     shape gates opened (tiny test heads fail the TPU-lane gates)."""
-    from sutro_tpu.ops import pallas_gmm, pallas_kv, pallas_paged
+    from sutro_tpu.ops import pallas_gmm, pallas_kv, pallas_paged, pallas_ssm
 
     monkeypatch.setattr(
         pallas_gmm, "grouped_matmul",
         functools.partial(pallas_gmm.grouped_matmul, interpret=True),
+    )
+    monkeypatch.setattr(
+        pallas_ssm, "ssm_state_read",
+        functools.partial(pallas_ssm.ssm_state_read, interpret=True),
     )
     monkeypatch.setattr(
         pallas_paged, "paged_decode_supported", lambda *a: True
