@@ -22,7 +22,9 @@ is the head, sampling, embeddings and what XLA hoisted), read from the
 event's own HLO line or, where the trace leaves it out, from the
 optimized HLO the compiler dumped (``--xla_dump_to``, set here before JAX
 loads). Prints one JSON line a program: ms a run (a decode STEP for the
-window), by scope, and the largest ops of each scope.
+window), by scope, and the largest ops of each scope; then which paths
+the process traced (``ops/lowering.py``: the kernels, the grouped
+product, a routed layer's combine).
 
 What ``perfbench/trace_reduce.py`` cannot say: it keys an op by its own
 name and drops ``op_name`` (PERF.md section 7 row 19). Fails without a
@@ -228,6 +230,14 @@ def main() -> None:
                 for mod, ops_ in from_dump.items()
             },
         }))
+    # which paths this process built into those programs
+    from sutro_tpu.ops import lowering
+
+    print(json.dumps({
+        "kernel_paths": lowering.snapshot(),
+        "grouped_matmul": lowering.grouped_matmul_counts(),
+        "moe_combine": lowering.moe_combine_counts(),
+    }), flush=True)
 
 
 if __name__ == "__main__":

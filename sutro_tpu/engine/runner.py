@@ -140,11 +140,13 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         "pallas_reason": why,
         # what this process has traced so far (ops/lowering.py): each
         # kernel by path, and beside them the XLA decode attention, the
-        # routed experts' grouped product (a routed model's alone) and a
-        # Mamba-2 layer's read of its committed state in a decode step
+        # routed experts' grouped product and the combine behind it (a
+        # routed model's alone) and a Mamba-2 layer's read of its
+        # committed state in a decode step
         "kernel_paths": lowering.snapshot(),
         "paged_decode_xla": lowering.xla_decode_count(),
         "grouped_matmul": lowering.grouped_matmul_counts(),
+        "moe_combine": lowering.moe_combine_counts(),
         "ssm_state_read": lowering.ssm_state_read_counts(),
         # a latent layer's attention by form, whichever path computed it
         # (``kernel_paths`` above says kernel or XLA form)

@@ -41,6 +41,9 @@ _counts: Dict[str, Dict[str, int]] = {
     k: dict.fromkeys(PATHS, 0) for k in KERNELS + (GROUPED, SSM_STATE_READ)
 }
 _xla_decode = 0
+#: traces of a routed layer's combine (ops/moe.combine), by path
+MOE_COMBINE = ("unpermuted", "scattered")
+_combine: Dict[str, int] = dict.fromkeys(MOE_COMBINE, 0)
 #: traces of a latent layer's attention (ops/attention.latent_attention),
 #: by form
 LATENT_FORMS = ("expanded", "absorbed")
@@ -70,6 +73,26 @@ def record_latent(form: str) -> None:
     """Called from ``ops/attention.latent_attention``'s traced body."""
     with _lock:
         _latent[form] += 1
+
+
+def record_moe_combine(path: str) -> None:
+    """Called from ``ops/moe.combine``'s traced body."""
+    with _lock:
+        _combine[path] += 1
+
+
+def moe_combine_counts() -> Dict[str, int]:
+    """Traces of a routed layer's combine on the ragged path (the
+    experts' sorted rows summed back a token), by path: ``unpermuted``
+    (``ops/moe.combine``: the rows gathered back by the inverse of the
+    layer's one sort and reduced over ``top_k`` in float32; a capped
+    share traces it twice, once a ``lax.cond`` branch) and ``scattered``
+    (a row scatter-add: no call is left that takes it since the
+    measurement of PR 44, PERF.md section 6, so 0 says that none came
+    back). A count of its own for the reason ``grouped_matmul_counts``
+    has one."""
+    with _lock:
+        return dict(_combine)
 
 
 def latent_counts() -> Dict[str, int]:

@@ -8,9 +8,22 @@ paths behind one call:
 - ``dense``: computes every expert for every token and combines with the
   gate matrix. Correct and simple; the E/top_k FLOP overhead is fine for
   tiny test models and small E.
-- ``ragged``: sorts the (token, expert) assignments by expert and runs two
-  grouped GEMMs via ``jax.lax.ragged_dot`` — the MXU-friendly path for
-  large E. Static shapes: the expanded token count is exactly ``N * top_k``.
+- ``ragged``: sorts the (token, expert) assignments by expert and runs
+  the experts' grouped GEMMs over the sorted rows — the MXU-friendly path
+  for large E. Static shapes: the expanded row count is exactly
+  ``M = N * top_k``. Everything round the products is read off that ONE
+  stable sort (``sort_rows``: sorted keys and the permutation at once; a
+  sorted row's token is ``order // top_k``, nothing is gathered from a
+  vector of scalars): the group sizes and the router's counts are a
+  compare and sum over the keys (``rows_by_group``: no ``bincount``,
+  which is a scatter-add, and no ``searchsorted``, which loops), and the
+  rows return to token order by a GATHER through the sort's inverse and
+  a float32 sum over ``top_k`` (``combine``), never a scatter: the
+  chip's row scatter-add sorts its indices once more and gathers all
+  ``[M, H]`` updates into that order before it scatters, and the pass
+  ``y * prob`` in front of it was one more (PERF.md section 6, PR 44).
+  Two row-indexed moves of ``[M, H]`` a layer are left: the gather in
+  front of the products and the one behind them.
 
 Router forms (``_route``, one definition for this file and
 ops/moe_ep.py; ``ModelConfig.router_*`` says which):
@@ -111,7 +124,10 @@ def _route(
     docstring: the router's forms), plus the flattened [N*top_k]
     expansion (token, expert, prob) used by the grouped-GEMM paths. One
     definition so the EP path (ops/moe_ep.py) can never diverge from
-    the single-device reference."""
+    the single-device reference. (The ragged path reads ``top_idx``,
+    ``probs`` and ``flat_expert``: its one sort gives a sorted row's
+    token as ``order // top_k``, so ``flat_token`` and ``flat_prob``
+    are for a caller that wants the expansion whole.)"""
     N = xt.shape[0]
     logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)  # [N, E]
     if router_b is not None:
@@ -145,27 +161,87 @@ def _route(
     return top_idx, probs, flat_expert, flat_token, flat_prob
 
 
-def held_rows(flat_expert, flat_token, flat_prob, first, held: int):
+def sort_rows(key: jax.Array):
+    """The ONE sort a routed layer makes: the expanded rows by ``key``
+    [M], stable (token order is kept inside a group). Returns ``(sorted
+    key, order)``: ``order[i]`` is the expanded row (``token * top_k +
+    choice``) that lies at sorted place ``i``, so a sorted row's token
+    is ``order // top_k`` and nothing is gathered from a vector of
+    scalars afterwards."""
+    iota = jnp.arange(key.shape[0], dtype=jnp.int32)
+    return jax.lax.sort((key, iota), num_keys=1, is_stable=True)
+
+
+def rows_by_group(key: jax.Array, groups: int) -> jax.Array:
+    """How many of ``key`` [M] equal each of ``0 .. groups``, [groups]
+    int32, by compare and sum: one fusion of ``groups * M`` compares on
+    the chip, where ``jnp.bincount`` is a scatter-add of M scalars and
+    ``jnp.searchsorted``'s default a loop of log M trips."""
+    return jnp.sum(
+        key[None, :] == jnp.arange(groups, dtype=key.dtype)[:, None],
+        axis=1, dtype=jnp.int32,
+    )
+
+
+def held_key(expert: jax.Array, first, held: int) -> jax.Array:
+    """An expert's LOCAL index in a layer that holds experts ``first ..
+    first + held``, and ``held`` where it is not this layer's."""
+    loc = expert - first
+    return jnp.where(jnp.logical_and(loc >= 0, loc < held), loc, held)
+
+
+def held_weights(top_idx, probs, first, held: int) -> jax.Array:
+    """``probs`` [N, top_k] with 0 on a choice that is not this
+    layer's: what ``combine`` weighs a held share's rows by."""
+    return jnp.where(held_key(top_idx, first, held) < held, probs, 0.0)
+
+
+def held_rows(flat_expert, first, held: int):
     """The expanded rows as a layer that holds experts ``first ..
     first + held`` takes them: its own rows first, grouped by LOCAL
     expert (a stable sort keeps token order), the others after. Returns
-    ``(local [M], token [M], weight [M], group_sizes [held])``, sorted:
-    ``local`` is ``held`` and ``weight`` 0 on a row that is not this
-    layer's. Static shapes, no capacity factor, no dropped token: the
-    tail rides the LAST held group (``local`` clipped to it indexes a
-    bias) and the caller zeroes its inputs, so what it adds is exactly
-    nothing."""
-    loc = flat_expert - first
-    owned = jnp.logical_and(loc >= 0, loc < held)
-    key = jnp.where(owned, loc, held)
-    order = jnp.argsort(key, stable=True)
-    s_key = key[order]
-    counts = jnp.bincount(s_key, length=held + 1)
-    group_sizes = (
-        counts[:held].at[held - 1].add(counts[held]).astype(jnp.int32)
-    )
-    weight = jnp.where(owned, flat_prob, 0.0)[order]
-    return s_key, flat_token[order], weight, group_sizes
+    ``(local [M], order [M], group_sizes [held])``, sorted
+    (``sort_rows``: a row's token is ``order // top_k``): ``local`` is
+    ``held`` on a row that is not this layer's, and its weight is 0
+    (``held_weights``). Static shapes, no capacity factor, no dropped
+    token: the tail rides the LAST held group (``local`` clipped to it
+    indexes a bias) and the caller zeroes its inputs, so what it adds is
+    exactly nothing."""
+    key = held_key(flat_expert, first, held)
+    local, order = sort_rows(key)
+    # the tail counts onto the last held group: its key, clipped
+    return local, order, rows_by_group(jnp.minimum(key, held - 1), held)
+
+
+def combine(y: jax.Array, order: jax.Array, weights: jax.Array) -> jax.Array:
+    """UNPERMUTE: the experts' sorted rows ``y`` back to their tokens,
+    [N, H]. ``weights`` [N, top_k] is each choice's weight in token
+    order (0 on a choice that is not this layer's), ``order`` the sort's
+    permutation. Its inverse (a second sort, of ``(order, iota)``: not a
+    scatter of ``iota``) gathers the rows back token-major, and a
+    token's ``top_k`` rows are weighted and summed in float32, cast
+    once. No scatter: the chip's row scatter-add sorts its indices
+    again, gathers the updates into that order and only then scatters.
+
+    ``y`` may hold the first ``R < M`` sorted rows alone (a capped
+    share: the rows behind them are not this layer's and weigh 0): they
+    are read from ONE zero row behind ``y``, so what they add is exactly
+    nothing. On the chip that gather of ``M`` rows, seven of eight of
+    them the same row, takes what the scatter-add of the ``R`` live rows
+    took at a 4,096-token prefill and 0.78 of it at 2,048
+    (benchmarks/moe_combine_ab.py; PERF.md section 6, PR 44)."""
+    N, top_k = weights.shape
+    R, H = y.shape
+    M = N * top_k
+    lowering.record_moe_combine("unpermuted")
+    iota = jnp.arange(M, dtype=jnp.int32)
+    _, inverse = jax.lax.sort((order, iota), num_keys=1)
+    if R < M:
+        y = jnp.concatenate([y, jnp.zeros((1, H), y.dtype)])
+        inverse = jnp.minimum(inverse, R)
+    back = y[inverse].reshape(N, top_k, H).astype(jnp.float32)
+    out = jnp.sum(back * weights.astype(jnp.float32)[:, :, None], axis=1)
+    return out.astype(y.dtype)
 
 
 def _share_row_cap(M: int, held: int, experts: int):
@@ -242,13 +318,13 @@ def moe_mlp(
 
     ``we_*`` with fewer experts than the router has outputs are a held
     share that starts at ``first_expert`` (module docstring); it takes
-    the ragged path at every size, whose sort is its definition. The
-    sort puts the held experts' rows first; a share under a quarter over
-    many rows (a prefill: ``_share_row_cap``) runs its products over
-    those rows alone where they fit twice its even share, since at a
-    sixteenth the zero rows that ride the last group were fifteen of
-    sixteen (215 of a 4,096-token prefill's 358 ms: PERF.md section 6,
-    PR 42).
+    the ragged path at every size, whose sort is its definition
+    (``held_rows``). The sort puts the held experts' rows first; a share
+    under a quarter over many rows (a prefill: ``_share_row_cap``) runs
+    its products over those rows alone where they fit twice its even
+    share, since at a sixteenth the zero rows that ride the last group
+    were fifteen of sixteen (215 of a 4,096-token prefill's 358 ms:
+    PERF.md section 6, PR 42).
 
     With ``layer`` (scalar int32) the ``we_*`` are the STACKS of every
     routed layer, [L, E, H, F] / [L, E, F, H], and this layer's experts
@@ -273,14 +349,14 @@ def moe_mlp(
         )
         layer = None
 
-    top_idx, probs, flat_expert, flat_token, flat_prob = _route(
+    top_idx, probs, flat_expert, _, _ = _route(
         xt, router, router_b, top_k, **(route or {})
     )
 
     def result(out):
         if not return_counts:
             return out
-        return out, jnp.bincount(flat_expert, length=E).astype(jnp.int32)
+        return out, rows_by_group(flat_expert, E)
 
     if method == "dense":
         gates = jnp.zeros((N, E), jnp.float32)
@@ -302,64 +378,59 @@ def moe_mlp(
         out = jnp.einsum("ne,neh->nh", gates.astype(y.dtype), y)
         return result(out.reshape(B, T, H))
 
-    # ragged grouped-GEMM path
+    # ragged grouped-GEMM path: what lies round the products is read off
+    # ONE sort of the expanded rows (``sort_rows``, ``combine``)
+    M = N * top_k
     cap = None
     if share:
-        sorted_expert, sorted_token, sorted_prob, group_sizes = held_rows(
-            flat_expert, flat_token, flat_prob, first_expert, held
+        sorted_expert, order, group_sizes = held_rows(
+            flat_expert, first_expert, held
         )
-        cap = _share_row_cap(sorted_token.shape[0], held, E)
+        weights = held_weights(top_idx, probs, first_expert, held)
+        cap = _share_row_cap(M, held, E)
     else:
-        order = jnp.argsort(flat_expert)                  # stable order by expert
-        sorted_expert = flat_expert[order]
-        sorted_token = flat_token[order]
-        sorted_prob = flat_prob[order]
-        group_sizes = jnp.bincount(sorted_expert, length=E).astype(jnp.int32)
+        sorted_expert, order = sort_rows(flat_expert)
+        group_sizes = rows_by_group(flat_expert, E)
+        weights = probs
     if layer is not None:  # the stacks seen flat: a bitcast
         we_gate, we_up, we_down = _each(
             (we_gate, we_up, we_down),
             lambda w: w.reshape((-1,) + w.shape[2:]),
         )
 
-    def through(sorted_expert, sorted_token, sorted_prob, group_sizes):
-        """The experts over rows sorted by group, summed back a token."""
+    def through(rows, group_sizes):
+        """The experts over the first ``rows`` sorted rows, summed back
+        a token."""
+        local = sorted_expert[:rows]
+        lhs = xt[order[:rows] // top_k]                   # [rows, H]
         if share:
-            mine = sorted_expert < held
-            lhs = xt[sorted_token] * mine[:, None].astype(xt.dtype)
-            sorted_expert = jnp.minimum(sorted_expert, held - 1)
-        else:
-            lhs = xt[sorted_token]                        # [M, H]
+            lhs = lhs * (local < held)[:, None].astype(xt.dtype)
+            local = jnp.minimum(local, held - 1)
         grouped = functools.partial(
             _grouped, group_sizes=group_sizes, use_pallas=use_pallas,
             layer=layer,
         )
-        g = None if we_gate is None else grouped(lhs, we_gate)  # [M, F]
+        g = None if we_gate is None else grouped(lhs, we_gate)  # [rows, F]
         u = grouped(lhs, we_up, transposed=we_gate is None)
         if bias_gate is not None:
-            g = g + bias_gate[sorted_expert].astype(g.dtype)
-            u = u + bias_up[sorted_expert].astype(u.dtype)
-        y = grouped(_hidden(g, u, activation), we_down)       # [M, H]
+            g = g + bias_gate[local].astype(g.dtype)
+            u = u + bias_up[local].astype(u.dtype)
+        y = grouped(_hidden(g, u, activation), we_down)       # [rows, H]
         if bias_down is not None:
-            y = y + bias_down[sorted_expert].astype(y.dtype)
-        y = y * sorted_prob[:, None].astype(y.dtype)
-        return jnp.zeros((N, H), y.dtype).at[sorted_token].add(y)
+            y = y + bias_down[local].astype(y.dtype)
+        return combine(y, order, weights)
 
-    rows = (sorted_expert, sorted_token, sorted_prob)
     if cap is None:
-        out = through(*rows, group_sizes)
+        out = through(M, group_sizes)
     else:
         # a small share of many rows: the held experts' rows come first
         # in the sort, and the first ``cap`` rows hold them all unless
         # the router sent this chip over twice its even share (then
         # every row, as above). What rides the last group is zeros
         # either way, so the sums are the same
-        M = sorted_token.shape[0]
         out = jax.lax.cond(
             jnp.sum(sorted_expert < held) <= cap,
-            lambda: through(
-                *(r[:cap] for r in rows),
-                group_sizes.at[held - 1].add(cap - M),
-            ),
-            lambda: through(*rows, group_sizes),
+            lambda: through(cap, group_sizes.at[held - 1].add(cap - M)),
+            lambda: through(M, group_sizes),
         )
     return result(out.reshape(B, T, H))

@@ -17,14 +17,15 @@ This path makes the partitioning manual and exact:
 - every shard computes the (cheap, replicated) router for its token
   shard, then sorts the N*top_k expanded rows so the rows owned by
   THIS shard's experts come first, grouped by local expert — a static
-  ``[M]`` argsort, no capacity factor and **no token dropping**:
+  ``[M]`` sort, no capacity factor and **no token dropping**:
   unowned rows are zero-masked into the trailing group, so outputs are
   exact (a batch-inference engine cannot silently drop tokens — the
   results contract is 1:1, reference README.md:221);
 - two grouped GEMMs (+ activation) against the local expert shard,
-  combine by scatter-add, then ONE psum over ("expert", "model")
-  merges expert contributions and the TP partial sums in a single
-  collective.
+  combine by the sort's inverse permutation and a weighted sum over
+  top_k (``ops/moe.combine``: no scatter), then ONE psum over
+  ("expert", "model") merges expert contributions and the TP partial
+  sums in a single collective.
 
 FLOP note: the zero-masked tail means each shard still streams M rows
 through its GEMMs — EP here buys weight residency and HBM traffic
@@ -41,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .moe import _act, _grouped, _route, held_rows
+from .moe import _act, _grouped, _route, combine, held_rows, held_weights
 
 
 def moe_mlp_ep(
@@ -87,19 +88,18 @@ def moe_mlp_ep(
         eidx = jax.lax.axis_index("expert")
         xt = x_s.reshape(N, H)
 
-        _, _, flat_expert, flat_token, flat_prob = _route(
+        top_idx, probs, flat_expert, _, _ = _route(
             xt, router, rb, K, select_bias=sb, **route
         )
         # this shard's share of the rows (ops/moe.py ``held_rows``, the
-        # one definition of a held share): its own first, grouped by
-        # local expert; the others zero-masked into the trailing group:
-        # static shapes, no capacity factor, no dropped tokens
-        s_key, s_token, s_weight, group_sizes = held_rows(
-            flat_expert, flat_token, flat_prob, eidx * El, El
-        )
+        # one definition of a held share and of the layer's one sort):
+        # its own first, grouped by local expert; the others zero-masked
+        # into the trailing group: static shapes, no capacity factor, no
+        # dropped tokens
+        s_key, order, group_sizes = held_rows(flat_expert, eidx * El, El)
         s_eidx = jnp.minimum(s_key, El - 1)                  # bias index
 
-        lhs = xt[s_token] * (s_weight > 0)[:, None].astype(xt.dtype)
+        lhs = xt[order // K] * (s_key < El)[:, None].astype(xt.dtype)
         g = _grouped(lhs, wg, group_sizes, use_pallas)       # [M, F/tp]
         u = _grouped(lhs, wu, group_sizes, use_pallas)
         if bg is not None:
@@ -118,8 +118,10 @@ def moe_mlp_ep(
             y = y + (
                 bd[s_eidx] / jax.lax.psum(1, "model")
             ).astype(y.dtype)
-        y = y * s_weight[:, None].astype(y.dtype)
-        out = jnp.zeros((N, H), y.dtype).at[s_token].add(y)
+        # back to the tokens as ops/moe.py does it (``combine``, the one
+        # definition: the rows gathered by the sort's inverse and summed
+        # over top_k in float32, no scatter), this shard's choices alone
+        out = combine(y, order, held_weights(top_idx, probs, eidx * El, El))
         # one collective: expert contributions + TP partial sums (the
         # F-axis contraction in the down GEMM is tp-sharded)
         out = jax.lax.psum(out, ("expert", "model"))
