@@ -65,10 +65,4 @@ make fleet-obs-check
 # per-stage quarantine, crash/resume replaying only missing stage
 # chunks, and the zero-overhead census for stage-less jobs
 make graph-check
-# warn-only: bench-artifact trend report (never fails the build)
-make bench-trend
-# tier-1 gate: interactive tier CPU smoke — TTFT/ITL legs + the
-# co-resident-batch throughput retention grade (tests/test_serving.py
-# rides the chunked suite below)
-make serve-bench
 bash .github/run_tests_chunked.sh

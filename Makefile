@@ -5,10 +5,9 @@
 PY ?= python
 
 .PHONY: all native test test-oneshot test-fast compile-check lint lint-baseline \
-	lint-schema chaos telemetry-check monitor-check control-check control-bench \
-	prefix-check tier-check fleet-check fleet-obs-check graph-check bench \
-	bench-e2e bench-fleet bench-replay serve-bench bench-trend dryrun \
-	chip-smoke bench-8b cost golden host-profile clean
+	lint-schema chaos telemetry-check monitor-check control-check \
+	prefix-check tier-check fleet-check fleet-obs-check graph-check dryrun \
+	chip-smoke golden host-profile clean
 
 all: native compile-check
 
@@ -40,8 +39,7 @@ test-fast: native
 # the reference CI ran `python -m compileall` only (SURVEY §4); kept as
 # the cheapest smoke layer
 compile-check:
-	$(PY) -m compileall -q sutro_tpu tests bench.py bench_e2e.py \
-		bench_interactive.py chip_smoke.py
+	$(PY) -m compileall -q sutro_tpu tests chip_smoke.py
 
 # graftlint: engine-aware static analysis (lock discipline, jit purity,
 # thread/exception hygiene) gated against the committed baseline —
@@ -104,23 +102,13 @@ monitor-check:
 
 # enforcement gate (OBSERVABILITY.md "Enforcement"): token-bucket
 # admission, priority-ladder policy, autotuner hysteresis, controller
-# degradation-to-pass-through, the control-on/off host-overhead budget
-# (zero-cost when SUTRO_CONTROL=0, asserted in code), and the
-# mixed-tenant chaos bench smoke. Tier-1 CI.
+# degradation-to-pass-through, and the control-on/off host-overhead
+# budget (zero-cost when SUTRO_CONTROL=0, asserted in code). Tier-1 CI.
 control-check:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_control.py \
 		tests/test_chaos.py -k "control" -q -m "not slow" \
 		-p no:cacheprovider
 	JAX_PLATFORMS=cpu $(PY) benchmarks/profile_host_overhead.py --control
-	$(MAKE) control-bench
-
-# mixed-tenant chaos bench -> BENCH_CONTROL.json: a noisy tenant
-# floods the interactive tier while a victim tenant and a batch tenant
-# share the engine. The STOCK interactive_ttft_p99 rule (GET /monitor)
-# must fire with SUTRO_CONTROL=0 and never fire with token-bucket
-# admission on. Not tier-1 (~2 min wall); run on control-plane changes.
-control-bench:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/bench_control.py --smoke
 
 # prefix-store gate (OBSERVABILITY.md "Prefix store"): radix-tree
 # units (LRU order, pin refcounts, racer declines), scheduler
@@ -184,45 +172,6 @@ graph-check:
 		tests/test_evals.py -q -m "not slow" -p no:cacheprovider
 	JAX_PLATFORMS=cpu $(PY) benchmarks/profile_host_overhead.py --stagegraph
 
-# replica-fleet scaling bench -> BENCH_FLEET.json: 1- vs 3-replica
-# batch throughput through the router (device-time-emulating stub
-# replicas; grade >=2x) + warm-prefix routed hit rate over two real
-# engines. Grades are warn-only in `make bench-trend`; not tier-1
-# (~40 s wall) — run on fleet/router changes.
-bench-fleet:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/bench_fleet.py
-
-# trace-replay load harness -> BENCH_REPLAY.json: replay the
-# deterministic session-heavy synthetic workload (same JSONL schema as
-# `sutro replay record`) open-loop against 1- vs 3-replica fleets at
-# SUTRO_REPLAY_SPEEDUP x (default 2); grades p99 TTFT, throughput
-# retention, and routed-prefix hit rate. Grades are warn-only in
-# `make bench-trend`; not tier-1 (~20 s wall) — run on fleet/router or
-# observability changes.
-bench-replay:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/bench_replay.py
-
-# raw decode microbench (one JSON line; driver contract)
-bench:
-	$(PY) bench.py
-
-# full-engine workloads: classify / generate / embed -> BENCH_E2E.json
-bench-e2e:
-	$(PY) bench_e2e.py
-
-# interactive-tier latency legs (TTFT/ITL idle vs co-resident batch)
-# -> BENCH_INTERACTIVE.json; CI runs the CPU rehearsal, a chip run
-# uses the same entry point without JAX_PLATFORMS=cpu
-serve-bench:
-	JAX_PLATFORMS=cpu $(PY) bench_interactive.py
-
-# warn-only trend report over the accumulated bench artifacts
-# (BENCH_E2E.json, BENCH_INTERACTIVE.json)
-# -> BENCH_TREND.md; >15% regressions in graded metrics print WARN
-# lines but never fail the build
-bench-trend:
-	$(PY) benchmarks/bench_trend.py
-
 # multi-chip sharding dry run on 8 virtual CPU devices
 dryrun:
 	$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
@@ -232,14 +181,6 @@ dryrun:
 # (exits non-zero without one; README "Tests & benchmarks")
 chip-smoke:
 	$(PY) chip_smoke.py
-
-# realistically-sized models + HBM roofline fractions -> BENCH_8B.json
-bench-8b:
-	$(PY) benchmarks/bench_8b.py
-
-# north-star $/job vs OpenAI Batch from the latest BENCH_E2E record
-cost:
-	$(PY) benchmarks/cost_northstar.py
 
 # host-side overhead profile (stub runner, no chip): per-window micro
 # legs + full-job-lifecycle e2e legs at 512/20k rows, with the

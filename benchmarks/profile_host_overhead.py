@@ -191,9 +191,9 @@ TEL_OVERHEAD_MAX = 1.02
 # microseconds of host CPU — at the interactive tier's ~50 ms TTFT
 # floor that is <0.5%, comfortably inside the same 2% envelope
 FLEET_ROUTE_BUDGET_US = 200.0
-# nominal cheapest request the router fronts (idle interactive TTFT,
-# BENCH_INTERACTIVE idle leg order of magnitude) — the denominator for
-# the fleet overhead_ratio
+# nominal cheapest request the router fronts (an idle interactive
+# TTFT, a stated order of magnitude, not a measurement) — the
+# denominator for the fleet overhead_ratio
 NOMINAL_INTERACTIVE_TTFT_US = 50_000.0
 
 
@@ -1091,8 +1091,7 @@ def run_fleet_census(assert_budget: bool) -> dict:
     the ratio against the cheapest request the router fronts (idle
     interactive TTFT) stays inside the same <=2% envelope as
     telemetry. Warm-affinity probe round-trips are network IO bounded
-    by their own timeout, not host CPU — they are excluded here and
-    graded end to end by benchmarks/bench_fleet.py.
+    by their own timeout, not host CPU — they are excluded here.
 
     Zero-op check (asserted, not assumed): with telemetry disabled,
     driving picks, counters, owner bookkeeping and the ``/fleet``

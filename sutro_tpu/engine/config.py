@@ -75,13 +75,11 @@ class EngineConfig:
                                     # FSM masks/seeds
                                     # (runner.decode_multi_async);
                                     # amortizes dispatch+fetch latency.
-                                    # NOTE: a lockstep runner loop
-                                    # measured 16 fastest on qwen3-0.6b
-                                    # (2026-07, PERF.md), but the
-                                    # SCHEDULER pays min-cap
+                                    # The scheduler pays min-cap
                                     # all-or-nothing tails that grow
-                                    # with this value — flip only on a
-                                    # scheduler-path chip A/B
+                                    # with this value. Every cell of
+                                    # BENCHMARK.json runs 8; no other
+                                    # value has a chip number
     decode_lookahead: int = 2       # fused windows in flight at once on the
                                     # unconstrained decode path: window k+1
                                     # chains off window k's device-resident
@@ -127,8 +125,10 @@ class EngineConfig:
                                     # (ops/pallas_paged.py). Same f32
                                     # math, different summation order —
                                     # last-ulp differences only.
-                                    # Default OFF until the chip A/B
-                                    # (bench_e2e SUTRO_PREFIX_SPLIT)
+                                    # Default off: no cell turns it
+                                    # on and it has no chip number
+                                    # (ROADMAP.md 2.10 names the cell
+                                    # that decides it)
     prefix_cache: bool = True       # shared-prefix KV reuse: a job whose
                                     # rows share a common token prefix
                                     # (templates send one system prompt

@@ -51,10 +51,9 @@ from .profiling import host_leaf
 
 # ---------------------------------------------------------------------------
 # Cost model (USD per 1M tokens). The reference surfaces only a server-side
-# `cost_estimate` (sdk.py:245-262); this local model prices by param count
-# against chip-seconds, tuned so the north-star comparison vs the OpenAI
-# Batch API (BASELINE.json) is honest: numbers chosen to approximate
-# v5e on-demand $/chip-hour amortized over measured tok/s/chip tiers.
+# `cost_estimate` (sdk.py:245-262); this local model prices by param
+# count. The prices are stated, not derived from a measured rate: no
+# chip run stands behind them (PERF_LEDGER.jsonl holds what was measured).
 # ---------------------------------------------------------------------------
 
 COST_PER_MTOK: Dict[str, Dict[str, float]] = {

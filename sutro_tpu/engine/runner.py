@@ -1289,7 +1289,7 @@ class ModelRunner:
             else self.mcfg.head_dim,
         ), jnp.float32)
         kernel = self.use_pallas and pallas_paged.paged_decode_supported(
-            head, self.cache.k_pages, table
+            head, self.cache.k_pages
         )
         fetched = needed = 0.0
         # a kind of attention layers at a time: (layers, window); a

@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -1493,15 +1492,6 @@ def head_apply(
     logits = h_head @ lm_head.astype(h.dtype)
     if cfg.logits_scaling != 1.0:
         logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
-    # SUTRO_LOGITS_BF16=1 keeps the [*, V] logits in the activation
-    # dtype: sampling's full-vocab passes (ops/sampling.py) then read
-    # half the HBM bytes. Default OFF — bf16 argmax can flip near-ties
-    # vs the f32 head, so the exact-greedy-parity contract
-    # (tests/test_golden.py vs transformers) keeps f32 unless a chip
-    # A/B (benchmarks/sweep_sampling.py) justifies flipping it for
-    # throughput jobs.
-    if os.environ.get("SUTRO_LOGITS_BF16", "0") == "1":
-        return logits, h
     return logits.astype(jnp.float32), h
 
 

@@ -359,7 +359,7 @@ def chunk_attention(
         if use_pallas:
             from .pallas_paged import paged_decode_attention, paged_decode_supported
 
-            if paged_decode_supported(q[:, 0], past_k_pages, page_table):
+            if paged_decode_supported(q[:, 0], past_k_pages):
                 decode = paged_decode_attention
                 if live_window:
                     decode = functools.partial(decode, window_start=True)
@@ -507,7 +507,7 @@ def _latent_kernels(q, k, v, *, scale, pages, layer, page_table, past_len,
     if v is None and T == 1:
         from .pallas_paged import paged_decode_attention, paged_decode_supported
 
-        if paged_decode_supported(q[:, 0], pages, page_table):
+        if paged_decode_supported(q[:, 0], pages):
             win = {}
             if win_rows is not None and win_rows.shape[1] > 0:
                 win = dict(win_k=win_rows, win_len=win_len)

@@ -59,7 +59,6 @@ All math is float32.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -715,14 +714,6 @@ def prefix_attention_carry_pallas(
     return m0, l0, acc0
 
 
-# Below this table capacity (tokens) the XLA gather fallback wins on
-# grid/DMA overhead. With the in-kernel page walk the kernel's work is
-# proportional to ACTUAL context, so it wins essentially everywhere —
-# the gate is kept env-overridable for benchmarking the crossover.
-PALLAS_PAGED_MIN_CTX = int(
-    os.environ.get("SUTRO_PAGED_MIN_CTX", "0")
-)
-
 # The fetch ring's size. Pages in flight cost VMEM (K and V a slot) and
 # buy cover for the DMA latency: ~2 us x 819 GB/s = 1.6 MB in flight
 # keeps v5e's HBM busy. 4 MiB is what the two 1 MiB chunk slots of the
@@ -752,18 +743,12 @@ def ring_shape(page_size: int, kd: int, dtype_bytes: int, max_pages: int):
     return max(pages // group, 2) * group, group
 
 
-def paged_decode_supported(
-    q: jax.Array, k_pages: jax.Array, page_table: jax.Array
-) -> bool:
+def paged_decode_supported(q: jax.Array, k_pages: jax.Array) -> bool:
     """Shape/size gate for the compiled TPU path (interpret mode has no
     such constraints — tests call paged_decode_attention(interpret=True))."""
     Dh = q.shape[-1]
     PS = k_pages.shape[2]
-    ctx_capacity = page_table.shape[1] * PS
-    return (
-        Dh % 128 == 0 and PS % 8 == 0
-        and ctx_capacity >= PALLAS_PAGED_MIN_CTX
-    )
+    return Dh % 128 == 0 and PS % 8 == 0
 
 
 @functools.partial(

@@ -76,12 +76,11 @@ def sample(
     (gumbel-max, equivalent to categorical), so a row's output stream is
     reproducible independent of batch composition.
 
-    bfloat16 logits are supported (SUTRO_LOGITS_BF16 keeps the LM-head
-    output in bf16, halving the HBM traffic of the full-vocab passes
-    here): the wide [B, V] scans (top-k head, greedy argmax, logsumexp
-    input) stay in the input dtype while every accumulation and the
-    small [B, K] head math upcast to float32 — the converts fuse into
-    the reduction loops. Two deliberate exceptions pay a full f32 pass
+    bfloat16 logits are supported: the wide [B, V] scans (top-k head,
+    greedy argmax, logsumexp input) stay in the input dtype while every
+    accumulation and the small [B, K] head math upcast to float32 — the
+    converts fuse into the reduction loops. Two deliberate exceptions
+    pay a full f32 pass
     for unbiased gumbel noise: the unfiltered full-vocab categorical
     (rare: top_k=0 AND top_p>=1) and the row-seeded full-vocab draw —
     bf16 gumbel over 150k near-ties would resolve quantized ties toward

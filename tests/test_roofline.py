@@ -79,18 +79,18 @@ def test_param_bytes_counts_quantized_width():
     assert roofline.param_count_of(params) == 20
 
 
-def test_bench_refuses_to_measure_without_a_tpu(tmp_path):
-    """bench.py never times a stand-in model on the CPU under a device
-    metric's name: without a TPU it exits non-zero, names the platform
-    it found, and prints no result line."""
+def test_chip_smoke_refuses_to_run_without_a_tpu(tmp_path):
+    """chip_smoke.py never passes for a stand-in backend: without a TPU
+    (and without --cpu-rehearsal) it exits non-zero, names the platform
+    it found, and prints no ok line."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
-        [sys.executable, str(REPO / "bench.py")],
+        [sys.executable, str(REPO / "chip_smoke.py")],
         env=env, capture_output=True, text=True, timeout=300,
         cwd=tmp_path,
     )
     assert r.returncode != 0, r.stdout + r.stderr
     assert "'cpu'" in r.stderr
-    assert "tok/s/chip" not in r.stdout
+    assert '{"ok": true' not in r.stdout
