@@ -17,7 +17,8 @@ op's self time goes to the first ``jax.named_scope`` of the mixed walk in
 its HLO ``op_name`` (``moe_ffn``, ``shared_expert`` inside it,
 ``attn_window``, ``attn_full``,
 ``attn_mixer``, ``conv_mixer``, ``mamba_mixer``, ``mla_mixer`` and inside it
-``mla_expand`` or ``mla_absorb``, ``dense_ffn``; ``other``
+``mla_expand`` or ``mla_absorb`` and, under an indexer, ``dsa_indexer``,
+``dsa_select`` and ``dsa_attend`` (the innermost wins), ``dense_ffn``; ``other``
 is the head, sampling, embeddings and what XLA hoisted), read from the
 event's own HLO line or, where the trace leaves it out, from the
 optimized HLO the compiler dumped (``--xla_dump_to``, set here before JAX
@@ -51,14 +52,17 @@ SCOPES = (
 )
 #: scopes INSIDE one of the above that are told apart: the shared expert
 #: inside ``moe_ffn``, the products of the form taken inside ``mla_mixer``
-INNER = ("shared_expert", "mla_expand", "mla_absorb")
+#: and, inside those, an indexer's scores, its selection and the
+#: attention over it
+INNER = ("shared_expert", "mla_expand", "mla_absorb", "dsa_indexer",
+         "dsa_select", "dsa_attend")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _DEF = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 
 
 def scope_of(op_name: str) -> str:
     parts = op_name.split("/")
-    inner = next((p for p in parts if p in INNER), None)
+    inner = next((p for p in reversed(parts) if p in INNER), None)
     return inner or next((p for p in parts if p in SCOPES), "other")
 
 

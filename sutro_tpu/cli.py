@@ -1030,7 +1030,7 @@ def engine_info(model: Optional[str]) -> None:
             f"{m.num_attn_layers} latent_layers={m.num_latent_layers} "
             f"(the pool's layers: {m.num_pool_layers}) state_layers="
             f"{m.num_conv_layers + m.num_mamba_layers} kv_bytes_per_token="
-            f"{m.num_pool_layers * (2 if m.pool_has_values else 1) * m.page_width * width} "
+            f"{m.num_pool_layers * sum(m.pool_row_widths) * width} "
             f"state_bytes_per_page="
             f"{m.num_conv_layers * m.conv_state_len * m.hidden_size * width}"
         )

@@ -89,6 +89,17 @@ scheduler ... paged-KV decode attention"). Layout:
   (``paged_decode_attention(v_pages=None)``) and the in-place write
   lands one slab a segment (``pallas_kv.row_write_pallas``).
 
+- latent layers with an INDEXER (``ModelConfig.index_topk``: learned
+  sparse attention, ops/sparse_attention.py) keep a SECOND row a token:
+  the index key, ``ik_pages`` ``[L_mla, NP, PS, index_head_dim]``, a pool
+  of its own width on the SAME page table, allocators and garbage page.
+  ``ModelConfig.pool_row_widths`` is the place that says what a token
+  of a layer keeps in each pool. A chunk's index keys ride where V would
+  (``forward``'s ``(k, v)`` pair, the fused window's second buffer,
+  ``paged_past``'s second slot), so every caller that commits a chunk's
+  K/V by ``write_kv(cache, k, v, ...)`` commits them too: a second call
+  of the one-pool write.
+
 ``write_kv`` lands a chunk's K/V into pages (Pallas in-place RMW kernel
 on TPU, XLA scatter fallback elsewhere); ``gather_kv_layer`` produces one
 layer's contiguous ``[B, CTX, KVH, Dh]`` view for a chunk's attention
@@ -141,6 +152,9 @@ class KVCache:
     wk_pages: "jax.Array | None" = None    # [L_win, NP_w, PS, KVH*Dh]
     wv_pages: "jax.Array | None" = None
     window_page: "jax.Array | None" = None  # [NP] int32
+    # latent layers' index keys (an indexer: module docstring), beside
+    # ``k_pages``' latent rows on the same page table
+    ik_pages: "jax.Array | None" = None    # [L_mla, NP, PS, index_head_dim]
 
     @property
     def page_size(self) -> int:
@@ -183,8 +197,10 @@ def alloc_cache(
     ``cache_shardings``) every pool is allocated sharded — never whole
     on one device first; the int8 per-token scale pools are
     shard-invariant (full-KD amax) and replicate across that mesh."""
+    # what a token keeps in each pool: ``ModelConfig.pool_row_widths``
+    widths = mcfg.pool_row_widths
     shape = (
-        mcfg.num_pool_layers, num_pages, ecfg.kv_page_size, mcfg.page_width,
+        mcfg.num_pool_layers, num_pages, ecfg.kv_page_size, widths[0],
     )
     if mcfg.num_latent_layers and getattr(ecfg, "kv_quantize", None):
         raise NotImplementedError(
@@ -265,6 +281,10 @@ def alloc_cache(
         v_pages=(
             jnp.zeros(shape, dtype, device=sharding)
             if mcfg.pool_has_values else None
+        ),
+        ik_pages=(
+            jnp.zeros(shape[:3] + (widths[1],), dtype)
+            if mcfg.index_key_width else None
         ),
         conv=conv, **state,
     )
@@ -810,19 +830,33 @@ def write_kv(
 
             # the kernel keeps a row's whole run in VMEM (and a float32
             # copy of it for the roll): 2,048 rows of 640 at a time fit
-            pool = cache.k_pages
-            for at in range(0, T, _ROW_WRITE_TOKENS):
-                part = k_chunk[:, :, at:at + _ROW_WRITE_TOKENS]
-                pool = pallas_kv.row_write_pallas(
-                    pool, part.astype(pool.dtype),
-                    page_table.astype(jnp.int32),
-                    (start + at).astype(jnp.int32),
-                    jnp.clip(valid_len - at, 0, part.shape[2]).astype(jnp.int32),
-                )
-            return dataclasses.replace(cache, k_pages=pool)
-        flat = _flat_slots(page_table, start, valid_len, T, PS)
+            def land(pool, rows):
+                for at in range(0, T, _ROW_WRITE_TOKENS):
+                    part = rows[:, :, at:at + _ROW_WRITE_TOKENS]
+                    pool = pallas_kv.row_write_pallas(
+                        pool, part.astype(pool.dtype),
+                        page_table.astype(jnp.int32),
+                        (start + at).astype(jnp.int32),
+                        jnp.clip(
+                            valid_len - at, 0, part.shape[2]
+                        ).astype(jnp.int32),
+                    )
+                return pool
+        else:
+            flat = _flat_slots(page_table, start, valid_len, T, PS)
+
+            def land(pool, rows):
+                return _scatter_rows(pool, flat, rows)
+
+        if cache.ik_pages is None:
+            return dataclasses.replace(
+                cache, k_pages=land(cache.k_pages, k_chunk)
+            )
+        # an indexer's keys ride in V's place: the same write, a pool of
+        # its own width
         return dataclasses.replace(
-            cache, k_pages=_scatter_rows(cache.k_pages, flat, k_chunk)
+            cache, k_pages=land(cache.k_pages, k_chunk),
+            ik_pages=land(cache.ik_pages, v_chunk),
         )
     if cache.quantized:
         # int8 KV: quantize per token, then the SAME flat scatter as

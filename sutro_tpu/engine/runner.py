@@ -151,6 +151,8 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         # a latent layer's attention by form, whichever path computed it
         # (``kernel_paths`` above says kernel or XLA form)
         "latent_attention": lowering.latent_counts(),
+        # under an indexer's selection: by how it was applied
+        "sparse_attention": lowering.sparse_attention_counts(),
         "compile_cache_dir": enable_compile_cache(),
         "native_runtime": native_runtime.is_available(),
         "native_fsm": native_fsm.is_available(),
@@ -602,6 +604,13 @@ class ModelRunner:
         shape = (L, 1, PS, self.mcfg.page_width)
         if self._cache_sharding is not None:
             shape = self._cache_sharding.shard_shape(shape)
+        # a pool a width of ``ModelConfig.pool_row_widths``: K and V, a
+        # latent row alone, or a latent row and an index key
+        widths = (
+            self.mcfg.pool_row_widths if not window
+            else (self.mcfg.page_width,) * 2
+        )
+        elements = int(np.prod(shape)) * sum(widths) // self.mcfg.page_width
         state = (
             self.mcfg.num_conv_layers * self.mcfg.conv_state_len
             * self.mcfg.hidden_size
@@ -610,9 +619,7 @@ class ModelRunner:
         if self.ecfg.kv_quantize == "int8":
             # int8 values + replicated f32 per-token scales
             return 2 * (int(np.prod(shape)) + L * PS * 4) + state
-        # K and V, or a latent pool's one row a token
-        pools = 2 if self.mcfg.pool_has_values else 1
-        return pools * int(np.prod(shape)) * dtype.itemsize + state
+        return elements * dtype.itemsize + state
 
     def _pages_that_fit(self, want: int, want_window: int, dtype):
         """``(pages, window pages)``: ``want`` pages (and ``want_window``
@@ -746,6 +753,17 @@ class ModelRunner:
                 self.mcfg.num_latent_layers * self.ecfg.kv_page_size
                 * self.mcfg.latent_width * self.cache.k_pages.dtype.itemsize
             ),
+            # latent layers with an indexer keep an index key a token too,
+            # ``index_page_bytes`` a page of every layer (0: no indexer)
+            "index_layers": int(
+                self.mcfg.num_latent_layers if self.mcfg.index_topk else 0
+            ),
+            "index_key_width": int(self.mcfg.index_key_width),
+            "index_topk": int(self.mcfg.index_topk),
+            "index_page_bytes": int(
+                0 if self.cache.ik_pages is None
+                else self.cache.ik_pages.nbytes // self.cache.num_pages
+            ),
             "kv_heads": int(self.mcfg.num_kv_heads),
             "head_dim": int(self.mcfg.head_dim),
             "kv_dtype_bytes": (
@@ -802,12 +820,40 @@ class ModelRunner:
         if telemetry.ENABLED and self.has_state:
             telemetry.STATE_COMMITS_TOTAL.inc(1.0, path)
 
-    def _count_latent(self, form: str) -> None:
+    def _count_latent(self, form: str, past_len=None, steps: int = 1) -> None:
         """A dispatch of a model of latent layers, by the form its
         attention takes (``transformer.mla_mixer``): "expanded" with no
-        paged past, "absorbed" over one."""
-        if telemetry.ENABLED and self.mcfg.num_latent_layers:
-            telemetry.LATENT_ATTENTION_DISPATCHES_TOTAL.inc(1.0, form)
+        paged past, "absorbed" over one. Where the layers have an
+        indexer (``ModelConfig.index_topk``) also by whether the
+        selection bites: ``selected`` when some query of the dispatch
+        has more than ``index_topk`` positions to choose from (a row's
+        ``past_len`` [B] plus its ``steps`` tokens), else ``dense_short``
+        (the selection is everything and the dense latent paths run);
+        and, for a DECODE dispatch, the rows a query's context holds
+        against the rows its attention reads, a row-step at a time
+        (host arithmetic, as ``_count_kv_pages``)."""
+        if not (telemetry.ENABLED and self.mcfg.num_latent_layers):
+            return
+        telemetry.LATENT_ATTENTION_DISPATCHES_TOTAL.inc(1.0, form)
+        topk = self.mcfg.index_topk
+        if not topk:
+            return
+        past = np.zeros((1,), np.int64) if past_len is None else (
+            np.asarray(past_len, np.int64).reshape(-1)
+        )
+        telemetry.SPARSE_ATTENTION_DISPATCHES_TOTAL.inc(
+            1.0, "selected" if int(past.max()) + steps > topk
+            else "dense_short",
+        )
+        if form != "absorbed" or past_len is None:
+            return
+        # a row's s-th step sees its past, the window's earlier tokens
+        # and itself; padding rows (no past) are no rows
+        ctx = past[past > 0, None] + np.arange(1, steps + 1)[None]
+        telemetry.SPARSE_ATTENTION_ROWS_TOTAL.inc(float(ctx.sum()), "context")
+        telemetry.SPARSE_ATTENTION_ROWS_TOTAL.inc(
+            float(np.minimum(ctx, topk).sum()), "selected"
+        )
 
     def take_route_stats(self):
         """The routing counts of the last dispatch that was fetched
@@ -827,7 +873,10 @@ class ModelRunner:
                 cache.k_pages, cache.v_pages,
                 cache.k_scale, cache.v_scale, page_table,
             )
-        return (cache.k_pages, cache.v_pages, page_table)
+        # a latent pool has no V: where its layers have an indexer the
+        # index pool rides in V's place (transformer._mixed_trunk)
+        second = cache.ik_pages if cache.v_pages is None else cache.v_pages
+        return (cache.k_pages, second, page_table)
 
     # ------------------------------------------------------------------
     # tiered-KV page migration (engine/kvtier.py)
@@ -1080,7 +1129,7 @@ class ModelRunner:
                 ids = np.zeros((1, C), np.int32)
                 ids[0, : len(seg)] = seg
                 self._count_state_commit("chunk")
-                self._count_latent("absorbed")
+                self._count_latent("absorbed", steps=start + off + C)
                 self._bind_window(page_table, [start + off], [len(seg)])
                 logits, self.cache, route = self._prefill_chunk_jit(
                     self.params,
@@ -1103,7 +1152,7 @@ class ModelRunner:
         ids = np.zeros((1, T), np.int32)
         ids[0, :n] = token_ids
         self._count_state_commit("prefill")
-        self._count_latent("expanded")
+        self._count_latent("expanded", steps=T)
         self._bind_window(page_table, [0], [n])
         logits, self.cache, route = self._prefill_jit(
             self.params,
@@ -1149,7 +1198,7 @@ class ModelRunner:
             tables[i] = page_tables[i]
         self._bind_fresh(tables[:n], [0] * n)
         self._count_state_commit("prefill")
-        self._count_latent("expanded")
+        self._count_latent("expanded", steps=ids.shape[1])
         self._bind_window(tables[:n], [0] * n, lens[:n])
         logits, self.cache, route = self._prefill_jit(
             self.params,
@@ -1189,7 +1238,7 @@ class ModelRunner:
             tables[i] = page_tables[i]
         self._bind_fresh(tables[:n], st[:n])
         self._count_state_commit("chunk")
-        self._count_latent("absorbed")
+        self._count_latent("absorbed", steps=int(st.max()) + ids.shape[1])
         self._bind_window(tables[:n], st[:n], lens[:n])
         logits, self.cache, route = self._prefill_chunk_jit(
             self.params,
@@ -1426,7 +1475,7 @@ class ModelRunner:
                 jnp.asarray(rep, jnp.float32),
             )
         self._count_state_commit("window")
-        self._count_latent("absorbed")
+        self._count_latent("absorbed", past_len)
         self._count_kv_pages(past_len, page_table, 1, pfx)
         self._bind_window(page_table, past_len, np.ones((B,), np.int32))
         tok, logp, self.cache, self._route_dev, ok = self._decode_jit(
@@ -1530,7 +1579,6 @@ class ModelRunner:
         B = last.shape[0]
         L = self.mcfg.num_kv_layers   # full layers, then window layers
         KD = self.mcfg.page_width
-        latent = cache.v_pages is None   # one row a token, no V
         # window buffers hold UNQUANTIZED step K/V (they are read by
         # attention before ever touching the pool; write_kv quantizes
         # at commit) — under an int8 pool they stay in compute dtype
@@ -1543,7 +1591,11 @@ class ModelRunner:
         # unfused [.., KVH, Dh] form pads KVH up to a full sublane tile
         # on TPU — a 2x memory expansion on multi-GB buffers at large B
         wk0 = jnp.zeros((L, B, steps, KD), dtype)
-        wv0 = None if latent else jnp.zeros((L, B, steps, KD), dtype)
+        # V's buffer: as wide as K's, a latent layer's index keys, or none
+        # (``ModelConfig.pool_row_widths``)
+        widths = self.mcfg.pool_row_widths
+        VD = widths[1] if len(widths) > 1 else 0
+        wv0 = jnp.zeros((L, B, steps, VD), dtype) if VD else None
         mixed = not self.mcfg.homogeneous
         K1 = self.mcfg.conv_state_len or self.mcfg.mamba_conv_len
         wc0 = ws0 = past = None
@@ -1615,9 +1667,9 @@ class ModelRunner:
                 wk, k.astype(dtype).reshape(L, B, 1, KD),
                 (0, 0, step_idx, 0),
             )
-            if not latent:
+            if wv is not None:
                 wv = jax.lax.dynamic_update_slice(
-                    wv, v.astype(dtype).reshape(L, B, 1, KD),
+                    wv, v.astype(dtype).reshape(L, B, 1, VD),
                     (0, 0, step_idx, 0),
                 )
             step_logits = logits[:, 0]
@@ -1698,7 +1750,7 @@ class ModelRunner:
         # the window's routing counts stay on the device beside its
         # tokens; whoever fetches the tokens fetches them
         self._count_state_commit("window")
-        self._count_latent("absorbed")
+        self._count_latent("absorbed", past_len, steps)
         self._count_kv_pages(past_len, page_table, steps, pfx)
         self._bind_window(page_table, past_len, np.full((B,), steps))
         toks, logps, self.cache, self.window_route = self._decode_multi_jit(
@@ -1816,7 +1868,7 @@ class ModelRunner:
         ids[:, 0] = last_tokens
         ids[:, 1:] = drafts
         self._bind_window(page_table, past_len, np.asarray(draft_len) + 1)
-        self._count_latent("absorbed")
+        self._count_latent("absorbed", past_len, K + 1)
         ct, cl, pt, pl, self.cache, pending = self._verify_cand_jit(
             self.params,
             self.cache,
@@ -1933,7 +1985,7 @@ class ModelRunner:
         if top_k is None:
             top_k = np.zeros((B,), np.int32)
         self._count_kv_pages(past_len, page_table, steps, pfx)
-        self._count_latent("absorbed")
+        self._count_latent("absorbed", past_len, steps)
         toks, logps, wk, wv = self._decode_window_jit(
             self.params,
             self.cache,

@@ -2634,14 +2634,26 @@ class ContinuousBatcher:
         """Span attrs of a dispatch of a model that keeps K/V a pool a
         kind: the mean over its rows of the tokens a full layer reads
         (the context) and a window layer reads (the context, at most
-        the window). ``ctx`` is an iterable of the rows' contexts, not
-        walked for any other model: nothing for those."""
+        the window); of a model whose latent layers have an indexer,
+        the rows of the context and the rows the attention reads.
+        ``ctx`` is an iterable of the rows' contexts, not walked for
+        any other model: nothing for those."""
         mcfg = self.runner.mcfg
-        if not getattr(mcfg, "num_window_layers", 0):
+        topk = getattr(mcfg, "index_topk", 0)
+        if not getattr(mcfg, "num_window_layers", 0) and not topk:
             return {}
         c = np.fromiter(ctx, np.float64)
         if not c.size:
             return {}
+        if topk:
+            # latent layers under an indexer: the rows a query's context
+            # holds and the rows its attention reads (at most index_topk)
+            return {
+                "kv_rows_context": round(float(c.mean()), 1),
+                "kv_rows_selected": round(
+                    float(np.minimum(c, topk).mean()), 1
+                ),
+            }
         return {
             "kv_tokens_full": round(float(c.mean()), 1),
             "kv_tokens_window": round(

@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers ten
+config-driven decoder-only transformer (models/transformer.py) covers eleven
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -51,6 +51,20 @@ families:
   scores over their sum times 2.5) beside one shared expert. ``-ep16``
   is one chip of sixteen that share every layer: experts 0-15 of each
   routed layer's 256, every layer, the whole vocabulary
+- GLM-5 (744b-a40b; ``model_type`` glm_moe_dsa): the same latent
+  attention at other widths (``v_head_dim`` 256 beside a
+  ``qk_nope_head_dim`` of 192) under LEARNED SPARSE attention: every
+  layer has an indexer (``index_n_heads`` heads of ``index_head_dim``
+  from the same normed query latent, ONE LayerNormed, partly rotated
+  index key a token, a weight a head) whose score ``sum_j w_j relu(q_j .
+  k)`` picks the ``index_topk`` positions of a row's past that the
+  attention's softmax runs over; the index key is cached beside the
+  latent row, in a pool of its own on the same page table
+  (``ModelConfig.pool_row_widths``). Three leading dense layers, then 256
+  SwiGLU experts top-8 beside a shared one, routed as above. ``-l5-ep16``
+  is one chip of the sixteen that share every layer of the first
+  pipeline stage: a dense layer and four routed layers, experts 0-15 of
+  each routed layer's 256, an eighth of the vocabulary
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -110,6 +124,12 @@ class ModelConfig:
     # chip's and are left out (ops/moe.py ``held_rows``).
     moe_experts_held: int = 0
     moe_first_expert: int = 0
+    # rows a held share's products take in a prefill, in the shares an
+    # even router would send this chip (ops/moe.py ``_share_row_cap``):
+    # its rows beyond that send EVERY expanded row through the products
+    # (8 x the work at a sixteenth), so the room is sized for how uneven
+    # the router is. 2 until a model says otherwise
+    moe_share_rows: int = 2
     # False: an expert is TWO matrices, ``down(act(up x))`` (``we_up``,
     # ``we_down``; no ``we_gate``), as is the shared expert
     moe_gated: bool = True
@@ -159,6 +179,27 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_interleave: bool = False
+    # ``index_topk`` > 0 gives every "mla" layer an INDEXER (learned
+    # sparse attention): ``q_I = c_q W_Iqb`` of ``index_n_heads`` heads
+    # ``index_head_dim`` wide, ONE key a token ``k_I = LayerNorm(x W_Ik)``
+    # (scale and bias, eps ``index_norm_eps``), the rotary embedding on
+    # the first ``qk_rope_head_dim`` of both (interleaved pairs), a weight
+    # a head ``w = x W_Iw / sqrt(heads * width)``; ``I(t, s) = sum_j w_j(t)
+    # relu(q_I_j(t) . k_I(s))`` and the layer's softmax runs over the
+    # ``index_topk`` positions ``s <= t`` of largest ``I`` alone (all of
+    # them while there are no more; ties to the lower position). ``k_I``
+    # is cached beside the latent row (``pool_row_widths``)
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_norm_eps: float = 1e-6
+    # seeded weights alone: the standard deviation of the indexer's
+    # scores and of the attention's logits over a row's keys that the
+    # draws of ``w_iqb`` / ``w_ik`` and of the key halves of ``w_qb`` /
+    # ``w_kvb`` aim at (0: every matrix at variance 1 / fan-in, where
+    # both are near uniform over the keys and a subset's mean is close
+    # to the whole's; models/transformer.py ``_init_mixed_layers``)
+    seeded_peaked_attention: float = 0.0
     # Granite's scalar multipliers (1.0: none) and softmax scale (None:
     # 1/sqrt(head_dim)); "nope" applies no rotary embedding
     embedding_multiplier: float = 1.0
@@ -309,6 +350,26 @@ class ModelConfig:
         """The page pool keeps a V pool beside K's (a latent row serves
         both products: one pool)."""
         return not self.num_latent_layers
+
+    @property
+    def index_key_width(self) -> int:
+        """Elements of the index key a token keeps a latent layer beside
+        its latent row (0: the layers have no indexer)."""
+        return self.index_head_dim if self.index_topk else 0
+
+    @property
+    def pool_row_widths(self) -> Tuple[int, ...]:
+        """The width of a token's row in each pool of the page pool's
+        layers, all on ONE page table: K and V, a latent row alone, or a
+        latent row and an index key. THE place that says what a token of
+        a layer keeps: ``kvcache.alloc_cache``, ``write_kv``, a page's
+        bytes (the runner's ``_page_bytes_per_device``), ``device_info``
+        and the fused window's buffers read it here."""
+        if self.pool_has_values:
+            return (self.page_width, self.page_width)
+        if self.index_key_width:
+            return (self.page_width, self.index_key_width)
+        return (self.page_width,)
 
     @property
     def num_kv_layers(self) -> int:
@@ -567,39 +628,63 @@ def _nemotron_h(name: str, pattern: str, *, h: int = 2688, nh: int = 32,
     )
 
 
-def _joyai(name: str, layers: int = 40, *, h: int = 2048, nh: int = 32,
-           q_rank: int = 1536, kv_rank: int = 512, nope: int = 128,
-           rope: int = 64, v_dim: int = 128, inter: int = 7168,
-           experts: int = 256, top_k: int = 8, moe_inter: int = 768,
-           dense_layers: int = 1, held: int = 0, first: int = 0,
-           vocab: int = 129_280, template: str = "chatml") -> ModelConfig:
-    """The published ``joyai_llm_flash`` keys (DeepSeek-V3's set): latent
-    attention in every layer, rotary theta 32e6 on the ``rope`` part
-    alone in interleaved pairs, no scaling; ``dense_layers`` leading
-    dense SwiGLU layers, then a sigmoid router with a selection bias
-    (``noaux_tc``; ``n_group`` 1), the chosen scores over their sum
-    (+1e-20) times 2.5, gated experts beside ONE shared expert of an
-    expert's width. ``head_dim`` is the file's (the rotary part) and
-    ``num_kv_heads`` its 32: neither sizes anything here. ``held`` /
-    ``first``: the experts this chip holds of each layer (0: all). The
+def _latent_moe(name: str, layers: int = 40, *, h: int = 2048, nh: int = 32,
+                q_rank: int = 1536, kv_rank: int = 512, nope: int = 128,
+                rope: int = 64, v_dim: int = 128, inter: int = 7168,
+                experts: int = 256, top_k: int = 8, moe_inter: int = 768,
+                dense_layers: int = 1, held: int = 0, first: int = 0,
+                vocab: int = 129_280, theta: float = 32_000_000.0,
+                eps: float = 1e-6, index: Tuple[int, int, int] = (0, 0, 0),
+                peaked: float = 0.0, share_rows: int = 2,
+                template: str = "chatml") -> ModelConfig:
+    """DeepSeek-V3's key set (defaults: the published ``joyai_llm_flash``
+    file): latent attention in every layer, rotary ``theta`` on the
+    ``rope`` part alone in interleaved pairs, no scaling;
+    ``dense_layers`` leading dense SwiGLU layers, then a sigmoid router
+    with a selection bias (``noaux_tc``; ``n_group`` 1), the chosen
+    scores over their sum (+1e-20) times 2.5, gated experts beside ONE
+    shared expert of an expert's width. ``head_dim`` is the file's (the
+    rotary part) and ``num_kv_heads`` its ``nh``: neither sizes anything
+    here. ``held`` / ``first``: the experts this chip holds of each layer
+    (0: all). ``index`` = (``index_n_heads``, ``index_head_dim``,
+    ``index_topk``) of ``glm_moe_dsa``'s indexer (zeros: none);
+    ``peaked``: ``ModelConfig.seeded_peaked_attention``; ``share_rows``:
+    ``ModelConfig.moe_share_rows``. The
     multi-token-prediction block (``num_nextn_predict_layers`` 1) is no
     part of the next-token logits and is not built."""
     return ModelConfig(
         name=name, vocab_size=vocab, hidden_size=h, num_layers=layers,
         num_heads=nh, num_kv_heads=nh, head_dim=rope,
-        intermediate_size=inter, norm_eps=1e-6, rope_theta=32_000_000.0,
+        intermediate_size=inter, norm_eps=eps, rope_theta=theta,
         qk_norm=False, tie_embeddings=False,
         layer_types=("mla",) * layers,
         q_lora_rank=q_rank, kv_lora_rank=kv_rank, qk_nope_head_dim=nope,
         qk_rope_head_dim=rope, v_head_dim=v_dim, rope_interleave=True,
+        index_n_heads=index[0], index_head_dim=index[1],
+        index_topk=index[2], seeded_peaked_attention=peaked,
         moe_experts=experts, moe_top_k=top_k,
         moe_intermediate_size=moe_inter, num_dense_layers=dense_layers,
         moe_experts_held=held, moe_first_expert=first,
+        moe_share_rows=share_rows,
         moe_shared_intermediate_size=moe_inter,
         router_score="sigmoid", router_select_bias=True,
         router_renorm=True, router_scale=2.5, router_renorm_eps=1e-20,
         chat_template=template, seeded_unit_embedding=True,
     )
+
+
+#: GLM-5's published widths (``glm_moe_dsa``): 64 heads, nope 192 | rope
+#: 64, V 256, an indexer of 32 heads of 128 that keeps 2,048 positions
+_GLM5 = dict(
+    h=6144, nh=64, q_rank=2048, kv_rank=512, nope=192, rope=64, v_dim=256,
+    inter=12_288, experts=256, top_k=8, moe_inter=2048, theta=1_000_000.0,
+    eps=1e-5, index=(32, 128, 2048), peaked=1.5,
+    # four routed layers and a byte tokenizer's 28 distinct tokens (and a
+    # bucket's padding, all one token): seeded routers are uneven enough
+    # that twice the even share overflowed in one layer of four a seed,
+    # and the rate took three levels 5 % apart (PERF.md section 6, PR 46)
+    share_rows=4,
+)
 
 
 MODEL_CONFIGS: Dict[str, ModelConfig] = {
@@ -657,9 +742,21 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # share every layer: all 40 layers, the whole vocabulary, experts
     # 0-15 of each routed layer's 256 (4.78 B parameters, 9.55 GB in
     # bf16)
-    "joyai-llm-flash": _joyai("joyai-llm-flash"),
-    "joyai-llm-flash-ep16": _joyai(
+    "joyai-llm-flash": _latent_moe("joyai-llm-flash"),
+    "joyai-llm-flash-ep16": _latent_moe(
         "joyai-llm-flash-ep16", held=16, first=0,
+    ),
+    # GLM-5: as published (743.9 B parameters without its
+    # multi-token-prediction block), and one chip of the sixteen that
+    # share every layer of the FIRST pipeline stage: a leading dense
+    # layer and four routed layers, experts 0-15 of each routed layer's
+    # 256, rows 0-19,359 of the vocabulary (3.91 B parameters, 7.82 GB)
+    "glm-5": _latent_moe(
+        "glm-5", 78, dense_layers=3, vocab=154_880, **_GLM5,
+    ),
+    "glm-5-l5-ep16": _latent_moe(
+        "glm-5-l5-ep16", 5, dense_layers=1, held=16, first=0,
+        vocab=19_360, **_GLM5,
     ),
     # Embeddings (Qwen3 trunk + last-token-pool head)
     "qwen3-emb-0.6b": _qwen3("qwen3-emb-0.6b", 1024, 28, 16, 8, 3072, head="embedding"),
@@ -721,10 +818,21 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # num_kv_heads * head_dim (32): query rank 24, latent 40 + a rotary
     # key of 8 (a page row of 48), nope 16, v 20; 16 experts top-4 of
     # which this chip holds 4 (a rank of four)
-    "tiny-joyai": _joyai(
+    "tiny-joyai": _latent_moe(
         "tiny-joyai", 4, h=128, nh=4, q_rank=24, kv_rank=40, nope=16,
         rope=8, v_dim=20, inter=256, experts=16, top_k=4, moe_inter=48,
         held=4, first=0, vocab=512, template="plain",
+    ),
+    # the same under learned sparse attention, every width unlike
+    # tiny-joyai's and each other's: query rank 28, latent 36 + a rotary
+    # key of 8 (a page row of 44 in 128 lanes), nope 12, v 20 (unlike
+    # nope), an indexer of 3 heads of 24 that keeps 8 positions (the
+    # tests' prompts are 20-60 tokens: the selection bites)
+    "tiny-glm-dsa": _latent_moe(
+        "tiny-glm-dsa", 4, h=96, nh=4, q_rank=28, kv_rank=36, nope=12,
+        rope=8, v_dim=20, inter=192, experts=16, top_k=4, moe_inter=40,
+        held=4, first=0, vocab=512, theta=1_000_000.0, eps=1e-5,
+        index=(3, 24, 8), peaked=1.5, template="plain",
     ),
     "tiny-emb": ModelConfig(
         name="tiny-emb", vocab_size=512, hidden_size=128, num_layers=2,

@@ -20,10 +20,10 @@ models remotely — SURVEY §0). Design choices are TPU-first:
 - All matmuls run in ``bfloat16`` on the MXU; softmax/norms accumulate in
   ``float32``.
 - One code path covers Qwen3 (dense+MoE), Llama 3, Gemma 3, gpt-oss,
-  LFM2-MoE, Granite 4.0-H, Mellum 2, Nemotron-H and JoyAI-LLM-Flash via
-  ``ModelConfig`` fields (QK-norm, sliding windows, attention sinks,
-  post norms, MoE and its router's form, per-layer mixer kinds, latent
-  attention) —
+  LFM2-MoE, Granite 4.0-H, Mellum 2, Nemotron-H, JoyAI-LLM-Flash and
+  GLM-5 via ``ModelConfig`` fields (QK-norm, sliding windows, attention
+  sinks, post norms, MoE and its router's form, per-layer mixer kinds,
+  latent attention, an indexer that selects the keys it runs over) —
   see models/configs.py.
 
 The forward returns the chunk's K/V for each ATTENTION layer and, for a
@@ -47,6 +47,7 @@ from .configs import ModelConfig
 from ..ops import lowering, pallas_ssm
 from ..ops.moe import moe_mlp, relu2
 from ..ops.attention import chunk_attention, latent_attention
+from ..ops.sparse_attention import Indexer, sparse_latent_attention
 from ..ops.quant import materialize
 
 Params = Dict[str, Any]
@@ -65,7 +66,9 @@ class MixedChunk:
 
     # [L_attn, B, T, KVH, Dh], or fused [L_attn, B, T, KD]; for a model
     # of latent layers each token's latent ROW [L_mla, B, T, page_width]
-    # (and no V beside it: ``forward`` returns None in V's place)
+    # (and no V beside it: ``forward`` returns None in V's place, or
+    # the layers' INDEX KEYS [L_mla, B, T, index_head_dim] where they
+    # have an indexer: ``kvcache.write_kv`` lands those in the index pool)
     k: jax.Array
     # each conv layer's carried state, then the chunk's gated inputs
     # g_1..g_T: the state after n <= T tokens is columns n..n+K-2
@@ -170,19 +173,46 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
         Dn, Dr, Dv = (
             cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         )
+        # ``seeded_peaked_attention`` (0: none): the key side of the
+        # score drawn ``peak`` times 1 / fan-in in variance on each of
+        # its two factors, so that a row's attention logits spread
+        # ``peak`` standard deviations over its keys and not 1 (the
+        # latent values are normed after ``w_kva``: its scale reaches
+        # the rotary key alone; V's columns of ``w_kvb`` stay as they
+        # are)
+        peak = cfg.seeded_peaked_attention or 1.0
         out["mla"] = {
             "attn_norm": jnp.ones((Ll, H), dtype),
             "w_qa": dense((Ll, H, Rq), H),
             "q_norm": jnp.ones((Ll, Rq), dtype),
             # a head's columns: [q_nope | q_pe]
-            "w_qb": dense((Ll, Rq, NH * (Dn + Dr)), Rq),
+            "w_qb": dense((Ll, Rq, NH * (Dn + Dr)), Rq / peak),
             # [latent values | the shared rotary key]
-            "w_kva": dense((Ll, H, Rkv + Dr), H),
+            "w_kva": dense((Ll, H, Rkv + Dr), H / peak),
             "kv_norm": jnp.ones((Ll, Rkv), dtype),
             # a head's columns: [k_nope | v]
             "w_kvb": dense((Ll, Rkv, NH * (Dn + Dv)), Rkv),
             "wo": dense((Ll, NH * Dv, H), NH * Dv),
         }
+        if peak != 1.0:
+            cols = jnp.tile(
+                jnp.where(jnp.arange(Dn + Dv) < Dn, peak ** 0.5, 1.0), NH
+            )
+            out["mla"]["w_kvb"] = (
+                out["mla"]["w_kvb"].astype(jnp.float32) * cols
+            ).astype(dtype)
+        if cfg.index_topk:
+            NHi, Di = cfg.index_n_heads, cfg.index_head_dim
+            out["mla"].update({
+                # the indexer: queries from the normed query latent, ONE
+                # key a token under a LayerNorm (its bias drawn small and
+                # non-zero, so that leaving it out shows), a weight a head
+                "w_iqb": dense((Ll, Rq, NHi * Di), Rq),
+                "w_ik": dense((Ll, H, Di), H),
+                "ik_norm": jnp.ones((Ll, Di), dtype),
+                "ik_bias": dense((Ll, Di), 1) * jnp.asarray(0.1, dtype),
+                "w_iw": dense((Ll, H, NHi), H),
+            })
     if Lc:
         K = cfg.conv_kernel
         out["conv"] = {
@@ -541,6 +571,7 @@ def _mlp(
             out = moe_mlp(
                 *args, return_counts=return_counts, layer=layer,
                 first_expert=cfg.moe_first_expert,
+                share_rows=cfg.moe_share_rows,
                 use_pallas=use_pallas and kernel_mesh is None, **kwargs
             )
             if return_counts:
@@ -658,7 +689,9 @@ def mla_mixer(
     page_table=None, past_len=None,
     win_rows=None,               # [B, W, page_width]: a fused window's rows
     win_len=None, use_pallas: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
+    index_pages=None,            # [L, NP, PS, index_head_dim]: the index pool
+    win_index=None,              # [B, W, index_head_dim]: a fused window's
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """Latent attention over a chunk (``ModelConfig.q_lora_rank`` ...):
 
         c_q = RMSNorm(x W_qa) ;  [q_nope | q_pe] = c_q W_qb      a head
@@ -668,8 +701,13 @@ def mla_mixer(
         score = (q_nope . k_nope + q_pe . k_pe) / sqrt(nope + rope)
         out = concat_h(softmax(score) v) W_o
 
-    Returns ``(out [B, T, H], row [B, T, page_width])``: ``row`` =
-    ``[c_kv | k_pe | 0..]`` is all the cache keeps of a token. Two forms of
+    With an indexer (``ModelConfig.index_topk``) the softmax runs over
+    the positions it selects (``_indexer``; ops/sparse_attention.py): a
+    third step between the projections and the attention.
+
+    Returns ``(out [B, T, H], row [B, T, page_width], index key [B, T,
+    index_head_dim] or None)``: ``row`` = ``[c_kv | k_pe | 0..]`` and the
+    index key are all the cache keeps of a token. Two forms of
     the same numbers. With no ``pages`` (a chunk with no past) the
     EXPANDED form: K and V a head from the chunk's own rows. Over a
     paged past the ABSORBED form: ``W_kvb``'s K half folded into the
@@ -694,6 +732,13 @@ def mla_mixer(
     )
     w_kvb = _w(lp, "w_kvb", x.dtype).reshape(Rkv, NH, Dn + Dv)
     scale = (Dn + Dr) ** -0.5
+    index, attend = None, latent_attention
+    if cfg.index_topk:
+        with jax.named_scope("dsa_indexer"):
+            index = _indexer(
+                cfg, lp, x, c_q, positions, pages=index_pages, win=win_index
+            )
+        attend = functools.partial(sparse_latent_attention, index=index)
     if pages is None:
         with jax.named_scope("mla_expand"):
             kv = jnp.einsum("btc,cnd->btnd", c_kv, w_kvb)
@@ -703,7 +748,7 @@ def mla_mixer(
                 axis=-1,
             )
             qf = jnp.concatenate([q[..., :Dn], q_pe], axis=-1)
-            o = latent_attention(
+            o = attend(
                 qf, k, kv[..., Dn:], positions=positions,
                 valid_len=valid_len, scale=scale, use_pallas=use_pallas,
             )
@@ -713,7 +758,7 @@ def mla_mixer(
             ql = jnp.concatenate(
                 [q_abs, q_pe, jnp.zeros((B, T, NH, pad), q_abs.dtype)], axis=-1
             )                                      # [B, T, NH, page_width]
-            o_lat = latent_attention(
+            o_lat = attend(
                 ql, row, None, positions=positions, valid_len=valid_len,
                 scale=scale, pages=pages, layer=layer,
                 page_table=page_table, past_len=past_len,
@@ -721,7 +766,56 @@ def mla_mixer(
                 use_pallas=use_pallas,
             )
             o = jnp.einsum("btnc,cnd->btnd", o_lat, w_kvb[..., Dn:])
-    return o.reshape(B, T, NH * Dv) @ _w(lp, "wo", x.dtype), row
+    return (
+        o.reshape(B, T, NH * Dv) @ _w(lp, "wo", x.dtype), row,
+        None if index is None else index.k,
+    )
+
+
+def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float):
+    """LayerNorm with scale and bias over the last axis, in float32."""
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (
+        xf * w.astype(jnp.float32) + b.astype(jnp.float32)
+    ).astype(x.dtype)
+
+
+def _indexer(cfg: ModelConfig, lp, x, c_q, positions, pages=None,
+             win=None) -> Indexer:
+    """A latent layer's indexer over a chunk (``ModelConfig.index_topk``):
+
+        q_I = c_q W_Iqb -> [B, T, NHi, Di]   from the normed query latent
+        k_I = LayerNorm(x W_Ik) -> [B, T, Di]          ONE key a token
+        rope on the first qk_rope_head_dim of q_I and k_I (interleaved)
+        w   = x W_Iw / sqrt(NHi * Di) -> [B, T, NHi]   float32
+
+    ``k_I``, in the dtype the cache keeps, is what later queries score
+    against, so the chunk's own queries score against that too.
+    ``pages`` / ``win``: the index pool and a fused window's pending
+    keys, handed on to the attention."""
+    B, T = x.shape[:2]
+    NHi, Di, Dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+
+    def turned(a):
+        return jnp.concatenate([
+            apply_rope_interleaved(a[..., :Dr], positions, cfg.rope_theta),
+            a[..., Dr:],
+        ], axis=-1)
+
+    q_i = (c_q @ _w(lp, "w_iqb", x.dtype)).reshape(B, T, NHi, Di)
+    k_i = layer_norm(
+        x @ _w(lp, "w_ik", x.dtype), lp["ik_norm"], lp["ik_bias"],
+        cfg.index_norm_eps,
+    )
+    w = (x @ _w(lp, "w_iw", x.dtype)).astype(jnp.float32) * (
+        (NHi * Di) ** -0.5
+    )
+    return Indexer(
+        q=turned(q_i), w=w, k=turned(k_i), topk=cfg.index_topk, pages=pages,
+        win=win,
+    )
 
 
 def conv_mixer(
@@ -1151,6 +1245,14 @@ def _check_mixed(cfg: ModelConfig) -> None:
                 f"{cfg.name}: mla layers need q_lora_rank, kv_lora_rank, "
                 "qk_nope_head_dim, v_head_dim and an even qk_rope_head_dim"
             )
+        if cfg.index_topk and (
+            min(cfg.index_n_heads, cfg.index_head_dim) < 1
+            or cfg.index_head_dim < cfg.qk_rope_head_dim
+        ):
+            raise ValueError(
+                f"{cfg.name}: an indexer (index_topk) needs index_n_heads "
+                "and an index_head_dim of at least qk_rope_head_dim"
+            )
         if not cfg.rope_interleave or cfg.rope_scaling_factor or (
             cfg.position_embedding != "rope"
         ):
@@ -1306,14 +1408,23 @@ def _mixed_trunk(
                 y, out["conv"] = conv_mixer(cfg, lp, x, conv_state[m_idx])
         elif mixer == "mla":
             with jax.named_scope("mla_mixer"):
-                y, out["k"] = mla_mixer(
+                # a layer with an indexer: its index keys ride in V's
+                # place (the pool ``v_pages``, the window's buffers, the
+                # chunk's stack)
+                y, out["k"], ik = mla_mixer(
                     cfg, lp, x, positions=positions, valid_len=valid_len,
                     pages=k_pages, layer=m_idx, page_table=page_table,
                     past_len=past_len,
                     win_rows=None if window_past is None
                     else window_past[0][m_idx],
                     win_len=win_len, use_pallas=use_pallas,
+                    index_pages=v_pages,
+                    win_index=None if window_past is None or (
+                        window_past[1] is None
+                    ) else window_past[1][m_idx],
                 )
+                if ik is not None:
+                    out["v"] = ik
         elif mixer == "mamba":
             with jax.named_scope("mamba_mixer"):
                 y, ssm = mamba_mixer(

@@ -31,6 +31,9 @@ under one softmax, in plain XLA a block of queries at a time. Under
 (one fetch of a row's pages for both products) and a chunk with no past
 the flash kernel at zero-padded heads (``_latent_kernels``); a chunk of
 several tokens over a paged past gathers, as ``chunk_attention``'s does.
+A latent layer with an INDEXER (learned sparse attention) runs its
+softmax over the positions the indexer selects: ops/sparse_attention.py,
+which falls to ``latent_attention`` while the selection is everything.
 
 ``live_window`` (static) marks a layer whose pool is the WINDOW pool of a
 model that keeps K/V a pool a kind (engine/kvcache.py): the pool holds a

@@ -48,6 +48,10 @@ _combine: Dict[str, int] = dict.fromkeys(MOE_COMBINE, 0)
 #: by form
 LATENT_FORMS = ("expanded", "absorbed")
 _latent: Dict[str, int] = dict.fromkeys(LATENT_FORMS, 0)
+#: traces of a latent layer's attention under an indexer's selection
+#: (ops/sparse_attention.py), by how the selection is applied
+SPARSE_FORMS = ("gathered", "masked")
+_sparse: Dict[str, int] = dict.fromkeys(SPARSE_FORMS, 0)
 
 
 def record_kernel(kernel: str, *, interpret: bool) -> None:
@@ -93,6 +97,23 @@ def moe_combine_counts() -> Dict[str, int]:
     has one."""
     with _lock:
         return dict(_combine)
+
+
+def record_sparse(form: str) -> None:
+    """Called from ops/sparse_attention.py's traced bodies."""
+    with _lock:
+        _sparse[form] += 1
+
+
+def sparse_attention_counts() -> Dict[str, int]:
+    """Traces of learned sparse attention, by form: ``gathered`` (one
+    decode step: the chosen latent rows fetched by position, in plain
+    XLA) and ``masked`` (a chunk of queries: the dense products under
+    the selection's mask). A dispatch whose rows are all at or under
+    ``index_topk`` takes the dense latent paths and counts there
+    (``latent_counts``, ``snapshot``)."""
+    with _lock:
+        return dict(_sparse)
 
 
 def latent_counts() -> Dict[str, int]:

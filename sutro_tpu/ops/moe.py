@@ -244,13 +244,13 @@ def combine(y: jax.Array, order: jax.Array, weights: jax.Array) -> jax.Array:
     return out.astype(y.dtype)
 
 
-def _share_row_cap(M: int, held: int, experts: int):
+def _share_row_cap(M: int, held: int, experts: int, even_shares: int = 2):
     """Rows of ``M`` expanded rows a held share's products take when its
-    own rows fit them, or None (all rows): twice the share an even
-    router sends it, in whole tiles of 512, where that is under half
-    the rows. A share of a half (or a decode step's few rows) gives
-    None, and the program it had."""
-    cap = -(-2 * M * held // experts // 512) * 512
+    own rows fit them, or None (all rows): ``even_shares`` times (twice)
+    the share an even router sends it, in whole tiles of 512, where that
+    is under half the rows. A share of a half (or a decode step's few
+    rows) gives None, and the program it had."""
+    cap = -(-even_shares * M * held // experts // 512) * 512
     return cap if 2 * cap <= M else None
 
 
@@ -310,6 +310,7 @@ def moe_mlp(
     use_pallas: bool = False,
     return_counts: bool = False,
     layer: "jax.Array | None" = None,
+    share_rows: int = 2,   # ``_share_row_cap``'s even shares
 ):
     """The routed MLP over ``x``. With ``return_counts`` also the rows
     each expert got, [E] int32 over the ROUTER's experts, held here or
@@ -321,10 +322,12 @@ def moe_mlp(
     the ragged path at every size, whose sort is its definition
     (``held_rows``). The sort puts the held experts' rows first; a share
     under a quarter over many rows (a prefill: ``_share_row_cap``) runs
-    its products over those rows alone where they fit twice its even
-    share, since at a sixteenth the zero rows that ride the last group
-    were fifteen of sixteen (215 of a 4,096-token prefill's 358 ms:
-    PERF.md section 6, PR 42).
+    its products over those rows alone where they fit ``share_rows``
+    times (twice) its even share, since at a sixteenth the zero rows
+    that ride the last group were fifteen of sixteen (215 of a
+    4,096-token prefill's 358 ms: PERF.md section 6, PR 42); where they
+    do not fit, over EVERY row, so the room is what an uneven router
+    costs nothing under (``ModelConfig.moe_share_rows``).
 
     With ``layer`` (scalar int32) the ``we_*`` are the STACKS of every
     routed layer, [L, E, H, F] / [L, E, F, H], and this layer's experts
@@ -387,7 +390,7 @@ def moe_mlp(
             flat_expert, first_expert, held
         )
         weights = held_weights(top_idx, probs, first_expert, held)
-        cap = _share_row_cap(M, held, E)
+        cap = _share_row_cap(M, held, E, share_rows)
     else:
         sorted_expert, order = sort_rows(flat_expert)
         group_sizes = rows_by_group(flat_expert, E)

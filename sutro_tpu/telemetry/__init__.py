@@ -374,6 +374,28 @@ LATENT_ATTENTION_DISPATCHES_TOTAL = REGISTRY.counter(
     unit="dispatches",
     max_series=4,
 )
+SPARSE_ATTENTION_DISPATCHES_TOTAL = REGISTRY.counter(
+    "sutro_sparse_attention_dispatches_total",
+    "Dispatches of a model whose latent layers have an indexer (learned "
+    "sparse attention), by whether the selection bites: selected (some "
+    "query has more than index_topk positions to choose from: the "
+    "indexer's top-k, then attention over the chosen rows) or dense_short "
+    "(every row at or under index_topk: the dense latent paths, exact)",
+    labels=("path",),  # selected | dense_short
+    unit="dispatches",
+    max_series=4,
+)
+SPARSE_ATTENTION_ROWS_TOTAL = REGISTRY.counter(
+    "sutro_sparse_attention_rows_total",
+    "Cached rows of the decode dispatches of such a model, a row-step "
+    "at a time (host arithmetic from the rows' lengths): context (what "
+    "the row's past holds, itself included) and selected (what its "
+    "attention reads: at most index_topk). selected over context is the "
+    "share of the cache the attention reads; 1.0 is the dense path",
+    labels=("kind",),  # context | selected
+    unit="rows",
+    max_series=4,
+)
 STATE_FALLBACK_PREFILL_TOKENS_TOTAL = REGISTRY.counter(
     "sutro_state_fallback_prefill_tokens_total",
     "Prompt tokens prefilled again because a path could not restore the "

@@ -70,7 +70,7 @@ def test_absorbed_over_pages_is_expanded_over_the_chunk(params, split):
     T, PS = 20, 8
     lp, x, pos = _mixer_inputs(params, 4, T)
     valid = jnp.asarray([T, T], jnp.int32)
-    whole, rows = transformer.mla_mixer(
+    whole, rows, _ = transformer.mla_mixer(
         MCFG, lp, x, positions=pos, valid_len=valid)
     # a pool of 3 layers whose layer 2 holds the rows, two pages a row
     pool = jnp.zeros((3, 8, PS, MCFG.page_width), jnp.float32)
@@ -81,7 +81,7 @@ def test_absorbed_over_pages_is_expanded_over_the_chunk(params, split):
         flat = flat.at[2, at[:split]].set(rows[b, :split])
     pool = flat.reshape(pool.shape)
     n = T - split
-    tail, tail_rows = transformer.mla_mixer(
+    tail, tail_rows, _ = transformer.mla_mixer(
         MCFG, lp, x[:, split:], positions=pos[:, split:],
         valid_len=jnp.asarray([n, n], jnp.int32), pages=pool,
         layer=jnp.int32(2), page_table=table,
@@ -94,7 +94,7 @@ def test_absorbed_over_pages_is_expanded_over_the_chunk(params, split):
 
 def test_the_mixer_is_the_references_attention(params):
     lp, x, pos = _mixer_inputs(params, 5, 17)
-    got, rows = transformer.mla_mixer(
+    got, rows, _ = transformer.mla_mixer(
         MCFG, lp, x, positions=pos, valid_len=jnp.asarray([17, 17]))
     d = mla_moe.dims_of(KEYS)
     w = layer_weight(params["layers"]["mla"], 1)

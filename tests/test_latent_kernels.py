@@ -262,9 +262,9 @@ def test_a_shape_no_kernel_takes_counts_reference_and_runs_in_xla():
     cache = kvcache.alloc_cache(MCFG, engine(), 4, dtype=F32)
     table = jnp.asarray([[1, 2]], jnp.int32)
     before, forms = lowering.snapshot(), lowering.latent_counts()
-    plain, rows = transformer.mla_mixer(
+    plain, rows, _ = transformer.mla_mixer(
         MCFG, lp, x, positions=pos, valid_len=jnp.asarray([6]))
-    told, _ = transformer.mla_mixer(
+    told, _, _ = transformer.mla_mixer(
         MCFG, lp, x, positions=pos, valid_len=jnp.asarray([6]),
         use_pallas=True,
     )                                                   # expanded, T = 6
