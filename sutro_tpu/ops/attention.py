@@ -525,22 +525,46 @@ def _latent_kernels(q, k, v, *, scale, pages, layer, page_table, past_len,
     if v is None:
         lowering.record_reference("paged_decode")
         return None
-    from .pallas_flash import flash_prefill, flash_prefill_supported
+    block = latent_flash_block(q)
+    if block is None:
+        return None
+    return latent_flash(q, k, v, scale=scale, block=block)
 
-    def padded(x):
-        pad = -x.shape[-1] % 128
-        return jnp.pad(x, ((0, 0),) * 3 + ((0, pad),))
 
-    qp = padded(q)
+def _lane_padded(x):
+    """The last axis zero-padded to whole tiles of 128 lanes."""
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, -x.shape[-1] % 128),))
+
+
+def latent_flash_block(q) -> Optional[int]:
+    """The side of the flash body's square blocks for a chunk of
+    expanded heads ``q`` [B, T, NH, Dq]: the largest of
+    ``_LATENT_FLASH_BLOCKS`` whose gate passes at the padded heads, or
+    None where none does (counted ``reference``)."""
+    from .pallas_flash import flash_prefill_supported
+
+    qp = jax.ShapeDtypeStruct(
+        q.shape[:-1] + (q.shape[-1] + -q.shape[-1] % 128,), q.dtype
+    )
     block = next(
         (b for b in _LATENT_FLASH_BLOCKS
          if flash_prefill_supported(qp, qp, None, None, b)), None,
     )
     if block is None:
         lowering.record_reference("flash_prefill")
-        return None
+    return block
+
+
+def latent_flash(q, k, v, *, scale, block, keep=None):
+    """A chunk of expanded heads through the flash body at blocks of
+    ``block`` (``latent_flash_block``), ``[B, T, NH, Dv]``; ``keep``
+    ``[B, T, T]`` int8 is an indexer's selection
+    (ops/sparse_attention.masked_attention)."""
+    from .pallas_flash import flash_prefill
+
     return flash_prefill(
-        qp, padded(k), padded(v), scale=scale, native=True, block=block,
+        _lane_padded(q), _lane_padded(k), _lane_padded(v), scale=scale,
+        native=True, block=block, keep=keep,
     )[..., :v.shape[-1]]
 
 

@@ -109,7 +109,9 @@ def sparse_attention_counts() -> Dict[str, int]:
     """Traces of learned sparse attention, by form: ``gathered`` (one
     decode step: the chosen latent rows fetched by position, in plain
     XLA) and ``masked`` (a chunk of queries: the dense products under
-    the selection's mask). A dispatch whose rows are all at or under
+    the selection's mask, in XLA or, for a prefill under ``use_pallas``,
+    in the flash kernel: ``snapshot()["flash_prefill"]`` says which,
+    ``lowered`` or ``reference``). A dispatch whose rows are all at or under
     ``index_topk`` takes the dense latent paths and counts there
     (``latent_counts``, ``snapshot``)."""
     with _lock:

@@ -25,6 +25,12 @@ Design (TPU-first):
 - gpt-oss attention sinks join the softmax denominator at finalization
   (a per-head logit with no value row — same semantics as
   ops/attention.py's jnp path).
+- A learned selection (GLM-5's indexer: ops/sparse_attention.py) arrives
+  as ONE more operand, ``keep`` ``[B, T, T]`` int8 shared by every head,
+  fetched a ``[BQ, BK]`` tile a grid step and ANDed into the causal and
+  window tests. Blocks are still skipped by causality alone and the
+  diagonal block is still the last. Without it the program has no such
+  operand: every other caller's is what it was.
 
 Contract: self-attention over a chunk with NO past — query/key positions
 are ``[0, T)`` (the runner's bucketed prefill and the embed path both
@@ -59,6 +65,11 @@ BLOCK_K = 128
 # bf16 q and out blocks 4 x 0.5 MB, K and V blocks 128 KB: 5.2 MB of the
 # 16 MB a v5e core's kernel may take
 MAX_GROUP = 16
+# what a call under a selection (``keep``) asks for in place of the 16 MiB
+# a v5e kernel may take unasked (of 128): at blocks of 1,024 and heads of
+# 256 the kernel stood at 16 MiB less a little, and the selection's int8
+# tile, double-buffered, is 2 MiB more (17.9 MB: refused by 1.9)
+KEEP_VMEM_BYTES = 32 << 20
 
 
 def _flash_kernel(
@@ -69,17 +80,15 @@ def _flash_kernel(
     k_ref,            # [1, 1, BK, Dh]
     v_ref,            # [1, 1, BK, Dh]
     sink_ref,         # [1, G, 128] f32 (NEG_INF rows when no sink)
-    # output
-    out_ref,          # [1, 1, G, BQ, Dh]
-    # scratch
-    m_ref,            # [G, BQ, 128] f32
-    l_ref,            # [G, BQ, 128] f32
-    acc_ref,          # [G, BQ, Dh] f32
-    *,
+    # [keep_ref [1, BQ, BK] int8, where the caller brings a selection,]
+    # then the output out_ref [1, 1, G, BQ, Dh] and the scratch m_ref,
+    # l_ref [G, BQ, 128] f32 and acc_ref [G, BQ, Dh] f32
+    *refs,
     groups: int,
     scale: float,
     native: bool = False,
 ):
+    *keep_ref, out_ref, m_ref, l_ref, acc_ref = refs
     qb = pl.program_id(2)
     kb = pl.program_id(3)
     BQ = q_ref.shape[3]
@@ -110,6 +119,8 @@ def _flash_kernel(
         ok = jnp.logical_and(
             ok, jnp.logical_or(qpos - kpos < win, win <= 0)
         )
+        if keep_ref:
+            ok = jnp.logical_and(ok, keep_ref[0][0].astype(jnp.int32) != 0)
         # ``native``: the operands reach the MXU in the dtype they
         # have (float32 accumulation), not up-cast first
         cast = (lambda x: x) if native else (lambda x: x.astype(jnp.float32))
@@ -127,6 +138,11 @@ def _flash_kernel(
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
             alpha = jnp.exp(m_prev - m_new)            # [BQ]
             p = jnp.exp(s - m_new[:, None])            # [BQ, BK]
+            if keep_ref:
+                # a query may keep NO key of a block, nor of any before
+                # it: m is still NEG_INF there and exp(s - m) is 1 on
+                # every masked lane
+                p = jnp.where(ok, p, 0.0)
             l_new = l_ref[g, :, 0] * alpha + jnp.sum(p, axis=1)
             acc_ref[g] = acc_ref[g] * alpha[:, None] + jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -189,6 +205,11 @@ def flash_prefill(
     # step, and the step's own cost leads
     native: bool = False,
     block: Optional[int] = None,
+    # [B, T, T] int8, 1 where this query may attend to this key: a
+    # selection shared by every head, a subset of the causal triangle
+    # (ops/sparse_attention.masked_attention). A query that keeps
+    # nothing comes out zero
+    keep: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Returns [B, T, NH, Dv] causal self-attention over the chunk."""
     lowering.record_kernel("flash_prefill", interpret=interpret)
@@ -222,26 +243,44 @@ def flash_prefill(
     kernel = functools.partial(
         _flash_kernel, groups=G, scale=scale, native=native
     )
+    operands = [win, qh, kh, vh, sink_g]
+
+    def key_block(kb, qb):
+        # under a selection a key block over the diagonal, which is
+        # skipped, names the diagonal's tiles again and nothing is
+        # fetched for it (8,192 tokens, blocks of 1,024, 64 heads of
+        # 256: 25.4 -> 23.5 ms; PERF.md section 6, PR 47)
+        return kb if keep is None else jnp.minimum(kb, qb)
+
+    in_specs = [
+        pl.BlockSpec(
+            (1, 1, G, BQ, Dh),
+            lambda b, h, qb, kb, win: (b, h, 0, qb, 0),
+        ),
+        pl.BlockSpec(
+            (1, 1, BK, Dh),
+            lambda b, h, qb, kb, win: (b, h, key_block(kb, qb), 0),
+        ),
+        pl.BlockSpec(
+            (1, 1, BK, Dv),
+            lambda b, h, qb, kb, win: (b, h, key_block(kb, qb), 0),
+        ),
+        pl.BlockSpec(
+            (1, G, 128), lambda b, h, qb, kb, win: (h, 0, 0)
+        ),
+    ]
+    limits = {}
+    if keep is not None:
+        operands.append(keep)
+        in_specs.append(pl.BlockSpec(
+            (1, BQ, BK),
+            lambda b, h, qb, kb, win: (b, qb, key_block(kb, qb)),
+        ))
+        limits = dict(vmem_limit_bytes=KEEP_VMEM_BYTES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, KVH, nQ, nK),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, G, BQ, Dh),
-                lambda b, h, qb, kb, win: (b, h, 0, qb, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, BK, Dh),
-                lambda b, h, qb, kb, win: (b, h, kb, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, BK, Dv),
-                lambda b, h, qb, kb, win: (b, h, kb, 0),
-            ),
-            pl.BlockSpec(
-                (1, G, 128), lambda b, h, qb, kb, win: (h, 0, 0)
-            ),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec(
             (1, 1, G, BQ, Dv),
             lambda b, h, qb, kb, win: (b, h, 0, qb, 0),
@@ -260,7 +299,8 @@ def flash_prefill(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary"
             ),
+            **limits,
         ),
         interpret=interpret,
-    )(win, qh, kh, vh, sink_g)
+    )(*operands)
     return out.transpose(0, 3, 1, 2, 4).reshape(B, T, NH, Dv)

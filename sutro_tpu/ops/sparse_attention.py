@@ -27,7 +27,20 @@ give the same set.
   the ``topk``-th largest by bisection over the float32 bits (32 counting
   passes, exact; ``topk_mask``), and the softmax over the kept pairs. A
   masked dense product computes the same numbers as a gather of ``topk``
-  keys a query. Counted ``masked``.
+  keys a query. Counted ``masked``, whichever of two bodies attends:
+
+  - under ``use_pallas``, an EXPANDED chunk with no past whose length a
+    block of ``ops/attention._LATENT_FLASH_BLOCKS`` divides: the blocks
+    select only, their kept pairs go into ONE ``[B, T, T]`` int8 and one
+    ``pallas_flash.flash_prefill(keep=...)`` over the whole chunk runs
+    both products and the softmax in VMEM (the float32 scores of every
+    head never reach HBM). Counted ``flash_prefill`` ``lowered`` too
+    (``lowering.snapshot()``);
+  - everything else, in XLA a block at a time: ``use_pallas`` off
+    (nothing more counted); under ``use_pallas`` an expanded chunk whose
+    length no block divides (``flash_prefill`` ``reference``); the
+    absorbed form over a paged past (nothing more counted: no cell runs
+    it).
 
 ``sparse_latent_attention`` picks between them and the dense paths of
 ``ops/attention.latent_attention``, which are exact while no row's
@@ -46,7 +59,9 @@ import jax
 import jax.numpy as jnp
 
 from . import lowering
-from .attention import NEG_INF, latent_attention
+from .attention import (
+    NEG_INF, latent_attention, latent_flash, latent_flash_block,
+)
 
 _F32 = jnp.float32
 #: float32 scores a block of queries may hold (``masked_attention``)
@@ -228,7 +243,8 @@ def masked_attention(
     *, positions, valid_len, scale: float,
     pages=None, layer=None, page_table=None, past_len=None,
     win_rows=None, win_len=None, value_width: Optional[int] = None,
-    block_q: Optional[int] = None, return_mask: bool = False,
+    use_pallas: bool = False, block_q: Optional[int] = None,
+    return_mask: bool = False,
 ):
     """``latent_attention``'s numbers with the softmax over the selected
     pairs alone: a block of queries at a time, ``I`` against every key
@@ -238,11 +254,20 @@ def masked_attention(
     of a GROUP (an eighth of the chunk) see the chunk's own keys up to
     the group's end and run under ``lax.map`` (one traced body a group,
     its temporaries reused); a later group sees more keys, which skips
-    most of the causal square's upper half. ``return_mask`` adds the
-    kept pairs over the chunk's OWN keys ``[B, T, T]`` (the tests')."""
+    most of the causal square's upper half. Under ``use_pallas`` an
+    EXPANDED chunk with no past whose shape the flash body takes has
+    its blocks select only: their kept pairs are laid into ``[B, T, T]``
+    int8 and ONE ``flash_prefill`` over the whole chunk runs both
+    products and the softmax under that mask (the module's docstring).
+    ``return_mask`` adds the kept pairs over the chunk's OWN keys
+    ``[B, T, T]`` (the tests')."""
     lowering.record_sparse("masked")
     B, T, NH = q.shape[:3]
     absorbed = v is None
+    # the side of the flash body's blocks where it takes the attention
+    flash = None
+    if use_pallas and not absorbed and pages is None:
+        flash = latent_flash_block(q)
     segs = []
     if pages is not None:
         past = _gather_pages(pages, layer, page_table)
@@ -265,9 +290,10 @@ def masked_attention(
     groups = math.gcd(nb, 8)
     per = nb // groups              # blocks a group
 
-    def block(qb, qp, qi, wi, own):
+    def block(own, qp, qi, wi, qb=None):
         """One block of queries against ``segs`` and the chunk's ``own``
-        keys: (out [B, t, NH, D], kept own pairs [B, t, X_own])."""
+        keys: (out [B, t, NH, D], kept own pairs [B, t, X_own]); no out
+        and the pairs as int8 where the flash body attends."""
         every = segs + [own]
         with jax.named_scope("dsa_indexer"):
             score = jnp.concatenate(
@@ -279,6 +305,8 @@ def masked_attention(
             ], axis=-1)
         with jax.named_scope("dsa_select"):
             keep = topk_mask(score, ok, index.topk)
+            if flash:
+                return None, keep.astype(jnp.int8)
         with jax.named_scope("dsa_attend"):
             scores, at = [], 0
             for seg in every:
@@ -316,25 +344,36 @@ def masked_attention(
             k[:, :g1], k[:, :g1] if absorbed else v[:, :g1],
             index.k[:, :g1], positions[:, :g1], own_valid[:, :g1],
         )
-        args = (q[:, g0:g1], positions[:, g0:g1], index.q[:, g0:g1],
-                index.w[:, g0:g1])
+        args = (positions[:, g0:g1], index.q[:, g0:g1], index.w[:, g0:g1])
+        if not flash:
+            args += (q[:, g0:g1],)
         if per == 1:
-            o, kp = block(*args, own)
+            o, kp = block(own, *args)
         else:
             # [B, per * bq, ...] -> [per, B, bq, ...]: a block a step
             split = [
                 jnp.moveaxis(a.reshape((B, per, bq) + a.shape[2:]), 1, 0)
                 for a in args
             ]
-            o, kp = jax.lax.map(lambda xs: block(*xs, own), tuple(split))
-            o = jnp.moveaxis(o, 0, 1).reshape((B, per * bq) + o.shape[3:])
-            kp = jnp.moveaxis(kp, 0, 1).reshape((B, per * bq) + kp.shape[3:])
+            o, kp = jax.tree.map(
+                lambda a: jnp.moveaxis(a, 0, 1).reshape(
+                    (B, per * bq) + a.shape[3:]),
+                jax.lax.map(lambda xs: block(own, *xs), tuple(split)),
+            )
         outs.append(o)
-        if return_mask:
+        if flash or return_mask:
             kept.append(jnp.pad(kp, ((0, 0), (0, 0), (0, T - g1))))
-    out = outs[0] if groups == 1 else jnp.concatenate(outs, axis=1)
+    if kept:
+        kept = kept[0] if groups == 1 else jnp.concatenate(kept, axis=1)
+    if flash:
+        with jax.named_scope("dsa_attend"):
+            out = latent_flash(
+                q, k, v, scale=scale, block=flash, keep=kept
+            )
+    else:
+        out = outs[0] if groups == 1 else jnp.concatenate(outs, axis=1)
     if return_mask:
-        return out, jnp.concatenate(kept, axis=1)
+        return out, kept.astype(bool)
     return out
 
 
@@ -355,7 +394,9 @@ def sparse_latent_attention(
         if T <= index.topk:
             # no query has more than topk keys: the selection is everything
             return latent_attention(q, k, v, use_pallas=use_pallas, **dense)
-        return masked_attention(q, k, v, index, **dense)
+        return masked_attention(
+            q, k, v, index, use_pallas=use_pallas, **dense
+        )
     if T > 1:
         return masked_attention(q, k, v, index, **dense)
     if page_table.shape[1] * pages.shape[2] + (

@@ -2,6 +2,7 @@
 logic runs multi-device in CI without TPUs (SURVEY §4 'lesson for the
 build'). Must run before jax is imported anywhere."""
 
+import functools
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -67,6 +68,23 @@ def monkeypatch_module():
     mp = pytest.MonkeyPatch()
     yield mp
     mp.undo()
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The runner's ``use_pallas`` path on the CPU: the same calls,
+    interpreted."""
+    from sutro_tpu.ops import pallas_flash, pallas_gmm, pallas_kv, pallas_paged
+
+    for mod, name in (
+        (pallas_paged, "paged_decode_attention"),
+        (pallas_flash, "flash_prefill"),
+        (pallas_kv, "row_write_pallas"),
+        (pallas_gmm, "grouped_matmul"),     # the routed experts' product
+    ):
+        monkeypatch.setattr(
+            mod, name, functools.partial(getattr(mod, name), interpret=True)
+        )
 
 
 @pytest.fixture

@@ -29,7 +29,7 @@ from sutro_tpu.engine.scheduler import ContinuousBatcher, GenRequest
 from sutro_tpu.engine.tokenizer import ByteTokenizer
 from sutro_tpu.models import transformer
 from sutro_tpu.models.configs import MODEL_CONFIGS
-from sutro_tpu.ops import lowering
+from sutro_tpu.ops import lowering, sparse_attention
 from tests.glm_dsa_common import (
     KEYS, MCFG, MP, TOL, TOPK, engine, err, sequence, system_of, table_of,
     want,
@@ -303,6 +303,110 @@ def test_verify_with_a_part_of_its_inputs_accepted(runner, step, accepted):
         seq = np.concatenate([seqs[b][:n], [9, 0]])
         got = step([9], [n], tables[b])[0]
         assert err(got, want(runner.params, seq, [n])[0]) < TOL
+
+
+# -- under ``use_pallas``: which body a selecting chunk takes ----------------------------
+
+def _counts():
+    return lowering.snapshot(), lowering.sparse_attention_counts()
+
+
+@pytest.mark.parametrize("seeds,n_prefill", [((51, 52), 131), ((53,), 300)])
+def test_a_prompt_past_index_topk_takes_the_flash_body_under_its_mask(
+    interpreted, seeds, n_prefill
+):
+    """Two prompts of 131 tokens (the bucket of 256) and one of 300 (the
+    bucket of 512), each ONE block of the flash body and 32 or 64 times
+    ``index_topk`` (a count is a trace, so the cases differ in shape),
+    then single steps over both pools: the reference's logits,
+    ``flash_prefill`` interpreted and never ``reference``, ``masked``
+    still counted."""
+    runner = ModelRunner(
+        MCFG, engine(use_pallas=True, max_pages_per_seq=40, max_model_len=320,
+                     prefill_chunk=512, decode_multi_step=4),
+        num_pages=90,
+    )
+    assert runner.use_pallas
+    (before, sparse) = _counts()
+    ids = np.stack([sequence(s, n_prefill + 4) for s in seeds])
+    got = system_of(runner).logits_through_cache(ids, n_prefill, 4)
+    for g, seq in zip(got, ids):
+        ref = want(runner.params, seq, range(n_prefill - 1, n_prefill + 4))
+        assert err(g, ref) < TOL
+    now, sparse_now = _counts()
+    assert now["flash_prefill"]["interpreted"] > before["flash_prefill"]["interpreted"]
+    assert now["flash_prefill"]["reference"] == before["flash_prefill"]["reference"]
+    assert now["flash_prefill"]["lowered"] == before["flash_prefill"]["lowered"]
+    assert sparse_now["masked"] > sparse["masked"]
+
+
+def _mixer_operands(T, seed=60):
+    params = transformer.init_params(MCFG, jax.random.PRNGKey(1), jnp.float32)
+    lp = jax.tree_util.tree_map(lambda a: a[1], params["layers"]["mla"])
+    x = jax.random.normal(jax.random.PRNGKey(seed), (2, T, MCFG.hidden_size))
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (2, T))
+    return lp, x, pos
+
+
+@pytest.mark.parametrize("T", [40, 200, 272])
+def test_a_chunk_no_block_divides_counts_reference_and_is_the_xla_body(
+    interpreted, T
+):
+    """Under, between and over the flash body's blocks (256 divides none
+    of them): the XLA body's numbers bit for bit, ``flash_prefill``
+    ``reference`` once a trace, ``masked`` counted as ever."""
+    lp, x, pos = _mixer_operands(T)
+    valid = jnp.asarray([T, T - 9])
+    plain, _, _ = transformer.mla_mixer(MCFG, lp, x, positions=pos, valid_len=valid)
+    (before, sparse) = _counts()
+    told, _, _ = transformer.mla_mixer(
+        MCFG, lp, x, positions=pos, valid_len=valid, use_pallas=True)
+    now, sparse_now = _counts()
+    np.testing.assert_array_equal(np.asarray(told), np.asarray(plain))
+    assert now["flash_prefill"]["reference"] == before["flash_prefill"]["reference"] + 1
+    assert now["flash_prefill"]["interpreted"] == before["flash_prefill"]["interpreted"]
+    assert sparse_now["masked"] == sparse["masked"] + 1
+    assert now["paged_decode"] == before["paged_decode"]
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_a_chunk_over_a_paged_past_is_the_absorbed_xla_body_and_counts_nothing(
+    interpreted, monkeypatch, use_pallas
+):
+    """``T > 1`` with pages: today's body, no kernel name touched either
+    way; the flash helpers are never asked."""
+    def never(*a, **k):
+        raise AssertionError("the flash body was asked for a chunk over a past")
+
+    monkeypatch.setattr(sparse_attention, "latent_flash_block", never)
+    monkeypatch.setattr(sparse_attention, "latent_flash", never)
+    T, past = 6, 14
+    lp, x, pos = _mixer_operands(past + T)
+    valid = jnp.asarray([past + T, past + T])
+    _, rows, keys = transformer.mla_mixer(MCFG, lp, x, positions=pos, valid_len=valid)
+    table = jnp.asarray([[1, 2, 3, 0], [4, 5, 6, 0]], jnp.int32)
+    PS = 8
+    pool = jnp.zeros((1, 8 * PS, MCFG.page_width), jnp.float32)
+    ipool = jnp.zeros((1, 8 * PS, MCFG.index_head_dim), jnp.float32)
+    for b in range(2):
+        at = table[b, jnp.arange(past) // PS] * PS + jnp.arange(past) % PS
+        pool = pool.at[0, at].set(rows[b, :past])
+        ipool = ipool.at[0, at].set(keys[b, :past])
+    (before, sparse) = _counts()
+    out, _, _ = transformer.mla_mixer(
+        MCFG, lp, x[:, past:], positions=pos[:, past:],
+        valid_len=jnp.asarray([T, T - 2]), pages=pool.reshape(1, 8, PS, -1),
+        layer=jnp.int32(0), page_table=table,
+        past_len=jnp.asarray([past, past]),
+        index_pages=ipool.reshape(1, 8, PS, -1), use_pallas=use_pallas,
+    )
+    now, sparse_now = _counts()
+    assert now == before
+    assert sparse_now["masked"] == sparse["masked"] + 1
+    # the whole chunk with no past, expanded: the same tokens' outputs
+    whole, _, _ = transformer.mla_mixer(MCFG, lp, x, positions=pos, valid_len=valid)
+    assert np.abs(np.asarray(out[0] - whole[0, past:])).max() < 2e-5
+    assert np.abs(np.asarray(out[1, :T - 2] - whole[1, past:-2])).max() < 2e-5
 
 
 # -- through the scheduler: tokens, spans, counters ------------------------------------

@@ -2,8 +2,10 @@
 each against the XLA form it replaces (``ops/attention.latent_attention``,
 ``kvcache._scatter_rows``): the paged decode kernel's latent variant (ONE
 pool whose rows serve both products, every head to the one stored row),
-the flash kernel's body at zero-padded heads under the layer's own scale,
-and the in-place write of one row a token into one pool. One page, one
+the flash kernel's body at zero-padded heads under the layer's own scale
+(and under an indexer's selection as a mask tile, against the XLA body of
+``ops/sparse_attention.masked_attention`` and a gather of the chosen
+keys), and the in-place write of one row a token into one pool. One page, one
 head and one row at a time where a sum would hide a slip; then the whole
 tiny model through them (prefill, single steps, a fused window) against
 the float32 reference, and what the counts say.
@@ -21,8 +23,9 @@ import pytest
 
 from sutro_tpu.engine import kvcache
 from sutro_tpu.engine.runner import ModelRunner
-from sutro_tpu.ops import lowering, pallas_flash, pallas_kv, pallas_paged
+from sutro_tpu.ops import attention, lowering, pallas_flash, pallas_kv, pallas_paged
 from sutro_tpu.ops.attention import latent_attention
+from sutro_tpu.ops.sparse_attention import Indexer, masked_attention
 
 from tests.joyai_common import (
     MCFG, TOL, engine, err, sequence, system_of, want,
@@ -166,6 +169,179 @@ def test_the_dispatch_pads_and_blocks_as_the_kernel_wants(monkeypatch):
     )
 
 
+# -- (b') a selecting chunk: the flash body under the selection's mask ---------------
+
+#: tiny-glm-dsa's: 4 heads of 12 + 8 and 20, an indexer of 3 heads of 24
+#: that keeps 8; a chunk of four blocks of 128
+SEL = dict(T=512, Dq=20, Dv=20, NHi=3, Di=24, topk=8, block=128)
+
+
+def _selecting_chunk(rng, B, tied):
+    """Heads and an indexer whose scores peak at ONE earlier position a
+    query (keys and queries are phases of 12 frequencies: the score of
+    (t, s) is a sum of cos(w (s - c_t))), so that a query's 8 kept keys
+    cluster round c_t, most key blocks of a query keep nothing, and the
+    first kept key of many queries lies blocks in. ``tied``: every index
+    key the same, every score equal: the 8 LOWEST positions, all in the
+    first block, whatever the query's."""
+    T, Di, NHi = SEL["T"], SEL["Di"], SEL["NHi"]
+    q, k = (jnp.asarray(rng.standard_normal((B, T, NH, SEL["Dq"])), F32)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((B, T, NH, SEL["Dv"])), F32)
+    w = 2 * np.pi * rng.uniform(0.002, 0.03, Di // 2)
+
+    def phases(at):                                    # [.., T] -> [.., T, Di]
+        return np.concatenate(
+            [np.cos(at[..., None] * w), np.sin(at[..., None] * w)], axis=-1)
+
+    target = np.floor(rng.uniform(size=(B, T)) * (np.arange(T) + 1))
+    ik = phases(np.broadcast_to(np.arange(T, dtype=np.float64), (B, T)))
+    if tied:
+        ik = np.ones_like(ik)
+    iq = np.broadcast_to(phases(target)[:, :, None], (B, T, NHi, Di))
+    index = Indexer(
+        q=jnp.asarray(iq, F32),
+        w=jnp.asarray(rng.uniform(0.5, 1.5, (B, T, NHi)), F32),
+        k=jnp.asarray(ik, F32), topk=SEL["topk"],
+    )
+    return q, k, v, index
+
+
+def _gathered(q, k, v, keep, scale):
+    """One query at a time over the keys ``keep`` names, float64."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    out = np.zeros(q.shape[:2] + v.shape[2:])
+    for b, t in zip(*np.nonzero(keep.any(-1))):
+        at = np.nonzero(keep[b, t])[0]
+        s = np.einsum("nd,xnd->nx", q[b, t], k[b, at]) * scale
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[b, t] = np.einsum("nx,xnd->nd", p / p.sum(-1, keepdims=True), v[b, at])
+    return out
+
+
+@pytest.mark.parametrize("valid,tied", [
+    ([512], False),          # every row real
+    ([512], True),           # equal index scores: the lower positions
+    ([301], False),          # a padded tail, its last block all padding
+    ([512, 77], False),      # two rows of different lengths
+    ([130, 512], True),
+], ids=["whole", "tied", "padded", "two-rows", "two-rows-tied"])
+def test_the_flash_body_under_a_selection_is_the_xla_body_and_a_gather(
+    monkeypatch, valid, tied
+):
+    monkeypatch.setattr(
+        pallas_flash, "flash_prefill",
+        functools.partial(pallas_flash.flash_prefill, interpret=True),
+    )
+    monkeypatch.setattr(attention, "_LATENT_FLASH_BLOCKS", (SEL["block"],))
+    T, B = SEL["T"], len(valid)
+    rng = np.random.default_rng(sum(valid) + tied)
+    q, k, v, index = _selecting_chunk(rng, B, tied)
+    scale = SEL["Dq"] ** -0.5
+    kw = dict(
+        positions=jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T)),
+        valid_len=jnp.asarray(valid, jnp.int32), scale=scale, return_mask=True,
+    )
+    before = lowering.snapshot()["flash_prefill"]
+    masked = lowering.sparse_attention_counts()["masked"]
+    ref, ref_keep = masked_attention(q, k, v, index, **kw)
+    assert lowering.snapshot()["flash_prefill"] == before    # nothing counted
+    got, keep = masked_attention(q, k, v, index, use_pallas=True, **kw)
+    now = lowering.snapshot()["flash_prefill"]
+    # (a count is a TRACE: a second case of one shape finds the first's)
+    assert now["interpreted"] >= max(before["interpreted"], 1)
+    assert (now["reference"], now["lowered"]) == (
+        before["reference"], before["lowered"])
+    assert lowering.sparse_attention_counts()["masked"] == masked + 2
+    # the tests' view of the selection is the same array either way
+    keep = np.asarray(keep)
+    assert keep.dtype == bool and keep.shape == (B, T, T)
+    assert (keep == np.asarray(ref_keep)).all()
+    assert not np.triu(keep, 1).any()                 # inside the triangle
+    got = np.asarray(got)
+    assert got.shape == ref.shape and np.isfinite(got).all()
+    gathered = _gathered(q, k, v, keep, scale)
+    for b, n in enumerate(valid):
+        counts = keep[b, :n].sum(-1)
+        assert (counts == np.minimum(np.arange(n) + 1, SEL["topk"])).all()
+        np.testing.assert_allclose(
+            got[b, :n], np.asarray(ref[b, :n]), rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(got[b, :n], gathered[b, :n], rtol=2e-5, atol=2e-5)
+    # what the kernel had to get right: of a query's key blocks at or
+    # under its diagonal most keep nothing, and many a query's first kept
+    # key lies blocks in (tied: the first block alone keeps anything,
+    # the diagonal block of a later query nothing)
+    blk = SEL["block"]
+    per_block = keep.reshape(B, T, T // blk, blk).any(-1)        # [B, T, nK]
+    causal = np.arange(T // blk)[None] <= (np.arange(T) // blk)[:, None]
+    real = np.arange(T)[None] < np.asarray(valid)[:, None]       # [B, T]
+    pairs = (causal[None] & real[..., None])[:, blk:]    # past the first block
+    assert (~per_block[:, blk:] & pairs).sum() > 0.5 * pairs.sum()
+    first = per_block.argmax(-1)
+    if tied:
+        assert (per_block[real][:, 1:] == 0).all()
+    elif max(valid) == T:
+        assert (first[real] >= 2).sum() > 20
+
+
+def test_a_query_that_keeps_nothing_comes_out_zero_and_finite():
+    """``keep`` all zeros for one block of queries and for one query
+    elsewhere: zeros there, the other rows as without them."""
+    rng = np.random.default_rng(11)
+    T = 256
+    q, k, v = (jnp.asarray(rng.standard_normal((1, T, NH, 128)), F32)
+               for _ in range(3))
+    keep = np.tril(rng.uniform(size=(1, T, T)) < 0.05)
+    keep[0, np.arange(T), np.arange(T)] = True
+    keep[0, 128:] &= np.arange(T)[None] >= 128       # a first block with nothing
+    call = functools.partial(
+        pallas_flash.flash_prefill, q, k, v, interpret=True, native=True)
+    want = np.asarray(call(keep=jnp.asarray(keep, jnp.int8)))
+    np.testing.assert_allclose(
+        want, _gathered(q, k, v, keep, 128 ** -0.5), rtol=2e-5, atol=2e-5)
+    keep[0, :128] = False
+    keep[0, 200] = False
+    got = np.asarray(call(keep=jnp.asarray(keep, jnp.int8)))
+    assert np.isfinite(got).all()
+    assert not got[0, :128].any() and not got[0, 200].any()
+    rest = np.r_[128:200, 201:T]
+    np.testing.assert_array_equal(got[0, rest], want[0, rest])
+
+
+@pytest.mark.parametrize("native,block", [(False, None), (True, 256)])
+def test_flash_with_no_selection_is_the_program_it_was(native, block):
+    """``keep=None`` traces the call without the argument: the same
+    operands, the same numbers bit for bit; a selection is ONE more."""
+    rng = np.random.default_rng(12)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 256, NH, 128)), F32)
+               for _ in range(3))
+    call = functools.partial(
+        pallas_flash.flash_prefill, interpret=True, native=native, block=block)
+
+    def kernel_inputs(fn, *args):
+        def walk(jaxpr):
+            for e in jaxpr.eqns:
+                if e.primitive.name == "pallas_call":
+                    yield len(e.invars)
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    yield from walk(sub)
+        return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+    plain = lambda q, k, v: call(q, k, v)                       # noqa: E731
+    none = lambda q, k, v: call(q, k, v, keep=None)             # noqa: E731
+    kept = lambda q, k, v, m: call(q, k, v, keep=m)             # noqa: E731
+    assert str(jax.make_jaxpr(plain)(q, k, v)) == str(jax.make_jaxpr(none)(q, k, v))
+    assert kernel_inputs(plain, q, k, v) == kernel_inputs(none, q, k, v) == [5]
+    mask = jnp.asarray(np.tril(np.ones((1, 256, 256), np.int8)))
+    assert kernel_inputs(kept, q, k, v, mask) == [6]
+    np.testing.assert_array_equal(
+        np.asarray(plain(q, k, v)), np.asarray(none(q, k, v)))
+    # the whole triangle kept: the same numbers as no selection
+    np.testing.assert_allclose(
+        np.asarray(kept(q, k, v, mask)), np.asarray(plain(q, k, v)),
+        rtol=1e-6, atol=1e-6)
+
+
 # -- (c) one row a token into one pool ----------------------------------------------
 
 @pytest.mark.parametrize("starts,valids,tb", [
@@ -195,23 +371,6 @@ def test_the_one_pool_write_lands_what_the_scatter_lands(starts, valids, tb):
 
 
 # -- the whole model through the three ----------------------------------------------
-
-@pytest.fixture
-def interpreted(monkeypatch):
-    """The runner's ``use_pallas`` path on the CPU: the same calls,
-    interpreted (tests/test_kv_fetch_counters.py does the same)."""
-    from sutro_tpu.ops import pallas_gmm
-
-    for mod, name in (
-        (pallas_paged, "paged_decode_attention"),
-        (pallas_flash, "flash_prefill"),
-        (pallas_kv, "row_write_pallas"),
-        (pallas_gmm, "grouped_matmul"),     # the routed experts' product
-    ):
-        monkeypatch.setattr(
-            mod, name, functools.partial(getattr(mod, name), interpret=True)
-        )
-
 
 def test_the_model_through_the_three_kernels_is_the_reference(interpreted):
     runner = ModelRunner(
