@@ -16,7 +16,10 @@ forwards committed, and the share of unmasked tokens the FSMs accepted
 as the scheduler saw it when it chose window or masked step (the
 ``unmasked_ok`` attr of the window's ``decode_window`` spans: first,
 last, least, most, and how many dispatches chose from it) (descriptive
-numbers, no benchmark metric); the cell's end-to-end metrics; and the
+numbers, no benchmark metric), and how the constrained rows' masks
+reached the masked steps (``sutro_fsm_mask_rows_total``: the shares
+``cached``, ``filtered``, ``packed_here`` of the rows; OBSERVABILITY.md
+"The FSM masks travel bit-packed"); the cell's end-to-end metrics; and the
 tokens
 the accept loops committed beside the tokens the progress stream
 counted inside the window (``Reading.window_output_tokens``, the
@@ -62,6 +65,18 @@ def admission(r):
     rows = r.counter_delta("sutro_admit_wave_rows_total")
     return {"waves": waves, "rows": rows,
             "rows_a_sync": rows / waves if waves else None}
+
+
+def mask_rows(r):
+    """How the constrained rows' masks reached the mask assembly: rows
+    and shares (%) by path over the window; None where no masked row was
+    assembled (or the tree has no such counter)."""
+    rows = deltas(r, "sutro_fsm_mask_rows_total")
+    total = sum(rows.values())
+    if not total:
+        return None
+    return {"rows": int(total),
+            **{path: share(n, total) for path, n in rows.items()}}
 
 
 def table(r):
@@ -175,6 +190,10 @@ def main(argv=None) -> int:
         # the share of unmasked tokens the FSMs accepted, as the
         # scheduler's rule read it at each dispatch of the window
         "unmasked_ok": estimate,
+        # of the constrained rows the mask assembly wrote, the share a
+        # kept packed array served, the share a budget filtered, the
+        # share packed there
+        "fsm_mask_rows": mask_rows(r),
         # the divisor of fsm_host_us_per_token and the burst rate: the
         # progress stream's ticks clipped to the window
         "window_output_tokens": r.window_output_tokens(),

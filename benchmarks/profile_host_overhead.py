@@ -119,8 +119,8 @@ class _StubRunner:
         toks = self._rng.integers(
             1, self.vocab, (B,), dtype=np.int64
         ).astype(np.int32)
-        if allowed is not None:
-            a = np.asarray(allowed)
+        if allowed is not None:  # bit-packed [B, ceil(V / 8)]
+            a = np.unpackbits(np.asarray(allowed), axis=1)
             toks = np.argmax(a, axis=1).astype(np.int32)  # 1st admitted
         return toks, np.full((B,), -1.0, np.float32)
 
@@ -134,8 +134,8 @@ class _StubRunner:
         toks = self._rng.integers(
             1, self.vocab, (steps, B), dtype=np.int64
         ).astype(np.int32)
-        if allowed0 is not None:
-            a = np.asarray(allowed0)
+        if allowed0 is not None:  # bit-packed, like decode_step's
+            a = np.unpackbits(np.asarray(allowed0), axis=1)
             toks[0] = np.argmax(a, axis=1).astype(np.int32)
         return toks, np.full((steps, B), -1.0, np.float32), None
 
@@ -214,7 +214,9 @@ def warm_admit_buckets(vocab: int, ecfg) -> None:
     key = _jax.random.PRNGKey(0)
     nb = 1
     while nb <= ecfg.prefill_batch_size:
-        for allowed in (None, jnp.ones((nb, vocab), bool)):
+        for allowed in (
+            None, jnp.full((nb, (vocab + 7) // 8), 255, jnp.uint8)
+        ):
             _admit_sample_jit(
                 jnp.zeros((nb, vocab), jnp.float32), key,
                 jnp.zeros((nb,), jnp.float32),

@@ -146,6 +146,7 @@ def main() -> None:
         runner.release_window_behind(tables, past)  # the scheduler's part
     allowed = np.zeros((B, mcfg.vocab_size), bool)
     allowed[:, :256] = True
+    allowed = np.packbits(allowed, axis=1)  # as decode_step takes masks
     greedy = np.zeros((B,), np.float32)
 
     def masked_step(i):

@@ -22,7 +22,7 @@ import json
 import logging
 import threading
 from collections import OrderedDict
-from typing import Dict, FrozenSet, List, Optional, Tuple
+from typing import Dict, FrozenSet, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -30,6 +30,12 @@ from ... import telemetry
 from .nfa import NFA
 
 logger = logging.getLogger(__name__)
+
+
+def pack_mask(mask: np.ndarray) -> np.ndarray:
+    """A [V] bool mask as the uint8 [ceil(V / 8)] the device unpacks
+    (``np.packbits``'s big-endian bits, the tail bits past V zero)."""
+    return np.packbits(np.asarray(mask, bool))
 
 
 class TokenTable:
@@ -48,6 +54,12 @@ class TokenTable:
         self.empty_ids = np.array(
             [i for i, b in enumerate(self.token_bytes) if not b], np.int64
         )
+        # a finished row's mask, both ways (TokenFSM, ``_complete``)
+        self.stop_mask = np.zeros(V, bool)
+        self.stop_mask[self.stop_ids] = True
+        self.stop_packed = pack_mask(self.stop_mask)
+        self.stop_mask.setflags(write=False)
+        self.stop_packed.setflags(write=False)
         self._b2t: Optional[Dict[bytes, List[int]]] = None
         self._max_tok_len = 0
 
@@ -91,6 +103,17 @@ def token_table(tokenizer) -> TokenTable:
 INF_DIST = np.int32(0x7FFFFFFF)
 
 
+class _MaskEntry(NamedTuple):
+    """What the cache keeps of one state set: 5 B a vocabulary id for
+    the mask and the distances (760 KB at 151,936 ids), the packed mask
+    (19 KB) and the two integers that say whether a budget bites."""
+    mask: np.ndarray     # [V] bool
+    dist: np.ndarray     # [V] int32
+    packed: np.ndarray   # [ceil(V / 8)] uint8: pack_mask(mask)
+    dist_min: int        # least / largest dist among the allowed ids
+    dist_max: int        # (INF_DIST / -1 for an empty mask)
+
+
 class MaskCache:
     """state-set -> (vocab mask, per-token post-walk byte distance to
     accept), shared across all rows of every job on one (schema,
@@ -98,14 +121,17 @@ class MaskCache:
     caller that wants to change one copies it. The distance array is what
     makes budget-aware decoding O(V) per step: the scheduler ANDs the
     cached mask with ``dist_after <= remaining - 1`` instead of ever
-    re-walking tokens."""
+    re-walking tokens. Beside the two the cache keeps the mask
+    BIT-PACKED, as the device takes it, and the least and largest
+    distance among the allowed ids: a budget that lies on or over the
+    largest filters nothing (one under the least, everything) and the
+    packed array is the step's answer as it stands
+    (``TokenFSM.allowed_packed``)."""
 
     def __init__(self, nfa: NFA, table: TokenTable):
         self.nfa = nfa
         self.table = table
-        self._cache: Dict[
-            FrozenSet[int], "tuple[np.ndarray, np.ndarray]"
-        ] = {}
+        self._cache: Dict[FrozenSet[int], _MaskEntry] = {}
         self._cpp = None
         try:
             from .cpp import CppMasker
@@ -128,6 +154,10 @@ class MaskCache:
     def mask_and_dist(
         self, states: FrozenSet[int]
     ) -> "tuple[np.ndarray, np.ndarray]":
+        e = self.entry(states)
+        return e.mask, e.dist
+
+    def entry(self, states: FrozenSet[int]) -> _MaskEntry:
         cached = self._cache.get(states)
         if cached is not None:
             return cached
@@ -141,10 +171,16 @@ class MaskCache:
             for sid in self.table.stop_ids:
                 m[sid] = True
                 dist[sid] = 0
-        m.setflags(write=False)
-        dist.setflags(write=False)
-        self._cache[states] = (m, dist)
-        return m, dist
+        packed = pack_mask(m)
+        among = dist[m]
+        for a in (m, dist, packed):
+            a.setflags(write=False)
+        e = self._cache[states] = _MaskEntry(
+            m, dist, packed,
+            int(among.min()) if among.size else int(INF_DIST),
+            int(among.max()) if among.size else -1,
+        )
+        return e
 
     def _compute(
         self, states: FrozenSet[int]
@@ -219,10 +255,7 @@ class TokenFSM:
         contains the shortest path's single-byte tokens) — so schema rows
         always finish with complete JSON instead of a mid-string cut."""
         if self._complete:
-            m = np.zeros(self.table.vocab_size, bool)
-            for sid in self.table.stop_ids:
-                m[sid] = True
-            return m
+            return self.table.stop_mask.copy()
         m, dist = self.masks.mask_and_dist(self.states)
         if remaining is not None:
             fits = m & (dist <= max(int(remaining) - 1, 0))
@@ -231,6 +264,36 @@ class TokenFSM:
             # budget was infeasible from the start (or non-byte stop path):
             # degrade to the unfiltered mask rather than dead-ending
         return m
+
+    def allowed_packed(
+        self, remaining: Optional[int] = None, shared: Optional[dict] = None
+    ) -> "tuple[np.ndarray, bool]":
+        """``allowed_tokens(remaining)`` bit-packed, bit for bit
+        (``pack_mask``: uint8 [ceil(V / 8)]), and whether the budget
+        filtered it. Wherever the filter is the identity (no budget, or
+        one that covers the largest distance among the allowed ids) or
+        selects nothing (one under the least: the degrade above) the
+        answer is the CACHED packed array itself, read-only and shared,
+        and no pass over the vocabulary is made. Only between the two is
+        ``fits`` computed and that one row packed; ``shared``, a dict
+        that lives as long as one assembly of a batch's masks, lets the
+        rows there that stand in one state set under one budget share
+        that row."""
+        if self._complete:
+            return self.table.stop_packed, False
+        e = self.masks.entry(self.states)
+        if remaining is None:
+            return e.packed, False
+        room = max(int(remaining) - 1, 0)
+        if not e.dist_min <= room < e.dist_max:
+            return e.packed, False
+        key = (id(self.masks), self.states, room)
+        bits = None if shared is None else shared.get(key)
+        if bits is None:
+            bits = pack_mask(e.mask & (e.dist <= room))
+            if shared is not None:
+                shared[key] = bits
+        return bits, True
 
     def plan_fastforward(
         self,
@@ -366,8 +429,9 @@ class FactoryTable:
     ``_complete``) lives there; ``MaskCache._cache`` only grows, a state
     set's value is a pure function of (nfa, table), so two threads that
     compute one entry store equal arrays, and the arrays are read-only
-    (``allowed_tokens`` builds ``fits`` fresh, the scheduler copies a
-    mask into its own ``[B, V]`` array); the native core takes
+    (``allowed_tokens`` and ``allowed_packed`` build ``fits`` fresh, the
+    scheduler copies a packed mask into a row of its own
+    ``[B, ceil(V / 8)]`` array); the native core takes
     ``const FsmCore*`` in ``fsm_mask`` and ``fsm_advance``.
 
     The key is the schema's canonical text (``sort_keys``: the caller's
@@ -379,9 +443,10 @@ class FactoryTable:
     builds again and fails with its own error."""
 
     # Entries kept. A factory is its native core (36 B a lifted edge:
-    # 115 MB for the classify template's 3.19 M) plus 5 B a vocabulary
-    # id for each state set any row has visited (760 KB at 151,936 ids;
-    # some hundreds of sets a job through a 400-character scratchpad),
+    # 115 MB for the classify template's 3.19 M) plus 5 B and a bit a
+    # vocabulary id for each state set any row has visited (760 KB and
+    # the packed 19 KB at 151,936 ids; some hundreds of sets a job
+    # through a 400-character scratchpad),
     # so four of that size are 1-2 GB of host memory. A pipeline's
     # classify, score and rank templates and one schema of its own fit.
     MAX_ENTRIES = 4

@@ -1450,7 +1450,7 @@ class ModelRunner:
         temperature: np.ndarray,     # [B]
         top_p: np.ndarray,           # [B]
         top_k: Optional[np.ndarray] = None,     # [B] int32; None => disabled
-        allowed: Optional[np.ndarray] = None,   # [B, V] bool
+        allowed: Optional[np.ndarray] = None,   # [B, ceil(V/8)] uint8
         row_seeds: Optional[np.ndarray] = None,  # [B] int32
         penalties=None,  # (seen_packed [B, ceil(V/8)] uint8, pen_ids
         #                   [B,K], pen_cnt [B,K], presence [B],
@@ -1488,9 +1488,9 @@ class ModelRunner:
             jnp.asarray(temperature, jnp.float32),
             jnp.asarray(top_p, jnp.float32),
             jnp.asarray(top_k, jnp.int32),
-            None
-            if allowed is None
-            else jnp.asarray(np.packbits(np.asarray(allowed, bool), axis=1)),
+            # the masks arrive as the device program takes them: the
+            # scheduler keeps and assembles them bit-packed (_fsm_masks)
+            None if allowed is None else jnp.asarray(allowed, jnp.uint8),
             None if row_seeds is None else jnp.asarray(row_seeds, jnp.int32),
             penalties,
             self._pfx_jnp(pfx),
@@ -1944,7 +1944,12 @@ class ModelRunner:
         sampled window and its K/V buffers return to the host, which
         verifies constrained rows against their FSMs and commits only
         each row's accepted prefix (``commit_window``). The cache is a
-        read-only input here, so a rejected suffix costs nothing."""
+        read-only input here, so a rejected suffix costs nothing.
+        ``allowed0`` arrives bit-packed, like the masked step's masks."""
+        if allowed0 is not None:
+            allowed0 = jnp.unpackbits(
+                allowed0, axis=1, count=self.mcfg.vocab_size
+            ).astype(bool)
         return self._window_scan(
             params, cache, last, past_len, page_table, rng,
             temperature, top_p, steps, top_k,
@@ -1971,7 +1976,8 @@ class ModelRunner:
         top_p: np.ndarray,           # [B]
         steps: int,
         top_k: Optional[np.ndarray] = None,
-        allowed0: Optional[np.ndarray] = None,  # [B, V] bool, step 0 only
+        allowed0: Optional[np.ndarray] = None,  # [B, ceil(V/8)] uint8,
+        #                                         bit-packed; step 0 only
         pfx=None,  # tuple of (pages [Pp_g], pfx_len [B]) split-prefix groups
     ):
         """Speculative window: returns (tokens [steps, B], logprobs
@@ -1997,7 +2003,7 @@ class ModelRunner:
             steps,
             jnp.asarray(top_p, jnp.float32),
             jnp.asarray(top_k, jnp.int32),
-            None if allowed0 is None else jnp.asarray(allowed0, bool),
+            None if allowed0 is None else jnp.asarray(allowed0, jnp.uint8),
             self._pfx_jnp(pfx),
         )
         # copy: callers may pass live views (native runtime) that mutate

@@ -76,7 +76,13 @@ def _admit_sample_jit(
     prefill group (profiled round 5, CPU host): the top-p path's
     ``lax.cond`` re-traces its branches on EVERY eager call. Jitted,
     repeat groups of the same shape hit the pjit cache and the whole
-    sample+logprob pair runs as one compiled program."""
+    sample+logprob pair runs as one compiled program. ``allowed``
+    arrives bit-packed ([B, ceil(V / 8)] uint8), like the masked decode
+    step's."""
+    if allowed is not None:
+        allowed = jax.numpy.unpackbits(
+            allowed, axis=1, count=logits.shape[-1]
+        ).astype(bool)
     tok = device_sample(
         logits, key,
         temperature=temperature, top_p=top_p, top_k=top_k,
@@ -111,6 +117,14 @@ class TokenConstraint(Protocol):
     # ``token_allowed(token_id, remaining=None) -> bool`` (O(1) validity
     # of one token) — the speculative fused-window verifier uses it when
     # present and falls back to ``allowed_tokens`` otherwise.
+    #
+    # OPTIONAL: ``allowed_packed(remaining=None, shared=None) ->
+    # (uint8 [ceil(V / 8)], filtered)``: the bits of
+    # ``allowed_tokens(remaining)`` as ``np.packbits`` gives them, and
+    # whether a budget filtered them (False: a kept array, no pass over
+    # the vocabulary). ``shared`` is a dict local to one assembly of a
+    # batch's masks. The mask assembly copies such a row as it is and
+    # packs the bool mask of an implementation without the method.
 
 
 # per-method cache: does this allowed_tokens accept ``remaining``? Keyed
@@ -434,16 +448,20 @@ class _DecodeFacts(NamedTuple):
 # masked step costs _STEP_COST, device and host together, and a window
 # K + _WINDOW_HOST: the window's tokens a second over the step's are
 # E * _STEP_COST / (K + _WINDOW_HOST). The two constants are the classify
-# cell's (PERF.md section 6, PR 37: a window step 20.8 ms and 16 ms of
-# host a window; a masked step 29.7 ms of device, which writes K/V a step
-# and samples under the mask, and 13 ms of host): with every row
-# constrained and K = 8 the line lies at E ~ 4.3, p ~ 0.81. Only a batch
-# near the line feels them: at p = 0 a window is worth a quarter of the
-# steps it displaces, at p = 1 nearly twice.
-_STEP_COST = 2.05
-_WINDOW_HOST = 0.77
+# cell's, read again once the masks travelled bit-packed (PERF.md section
+# 6, PR 48, phase_table.py of the cell on one v5e: a masked step 29.6 ms
+# of device, which writes K/V a step and samples under the mask, and
+# 11.3 ms of host round it, 15.1 before; the same cell held on windows, a
+# window of 8 166.1 ms = 20.8 ms a step and 14.1 ms round it, the 2.1 ms
+# of its commit among them; the fast-forward probe, which runs ahead of
+# either, in neither): with every row constrained and K = 8 the line lies
+# at E ~ 4.4, p ~ 0.82. Only a batch near the line feels them: at p = 0 a
+# window is worth a quarter of the steps it displaces, at p = 1 nearly
+# twice.
+_STEP_COST = 1.97
+_WINDOW_HOST = 0.68
 # a batch near the line stays where it is: the other path has to be worth
-# a tenth more (p under ~0.78 leaves the windows, over ~0.84 returns)
+# a tenth more (p under ~0.79 leaves the windows, over ~0.85 returns)
 _SWITCH_GAIN = 1.1
 
 
@@ -522,6 +540,9 @@ class ContinuousBatcher:
         self.runner = runner
         self.ecfg = runner.ecfg
         self.vocab = runner.mcfg.vocab_size
+        # an unconstrained row's bit-packed mask: every id allowed, the
+        # last byte's bits past the vocabulary zero
+        self._ones_row = np.packbits(np.ones((self.vocab,), bool))
         self.stop_ids = set(int(s) for s in stop_ids)
         self.token_bytes = token_bytes
         self.B = self.ecfg.decode_batch_size
@@ -2015,14 +2036,50 @@ class ContinuousBatcher:
         m = bound(remaining=remaining) if takes_budget else bound()
         return self._pad_mask(m)
 
+    def _ones_packed(self, n: int) -> np.ndarray:
+        """[n, ceil(V / 8)] uint8 of all-True rows, as ``np.packbits``
+        gives them: the tail bits past the vocabulary zero."""
+        return np.repeat(self._ones_row[None], n, axis=0)
+
+    def _constraint_packed(
+        self, c: TokenConstraint, remaining: int, out: np.ndarray,
+        shared: dict,
+    ) -> str:
+        """Write the constraint's mask into ``out``, one bit-packed row
+        of the model's vocabulary, and say how it was come by (the
+        ``path`` of ``sutro_fsm_mask_rows_total``): ``cached``, a kept
+        packed array copied as it is; ``filtered``, a budget bit and one
+        row was computed and packed; ``packed_here``, the implementation
+        answers in bools only (``_constraint_mask``) and its row is
+        packed at this one place. A tokenizer's vocabulary narrower
+        than the model's leaves the ids past it False, like
+        ``_pad_mask``."""
+        fn = getattr(c, "allowed_packed", None)
+        if fn is None:
+            out[:] = np.packbits(self._constraint_mask(c, remaining))
+            return "packed_here"
+        bits, filtered = fn(remaining=remaining, shared=shared)
+        n = min(len(bits), len(out))
+        out[:n] = bits[:n]
+        out[n:] = 0
+        if len(bits) >= len(out):
+            # a vocabulary wider than the model's: no id past the model's
+            out[-1] &= self._ones_row[-1]
+        return "filtered" if filtered else "cached"
+
     def _fsm_masks(self, rows) -> np.ndarray:
-        """[B, V] bool — each listed slot's FSM mask (all-True for
-        unconstrained slots). Single assembly path for BOTH the masked
-        single-step and the speculative window's allowed0 recovery, so
-        the two cannot drift."""
+        """[B, ceil(V / 8)] uint8 — each listed slot's FSM mask,
+        BIT-PACKED as the device programs take it (all-True for
+        unconstrained slots): a kept packed array is copied, 19 KB a row
+        at 151,936 ids, and the host holds no [B, V] bool array. Single
+        assembly path for BOTH the masked single-step and the
+        speculative window's allowed0 recovery, so the two cannot
+        drift."""
         rows = list(rows)
+        how = {"cached": 0, "filtered": 0, "packed_here": 0}
         with self.timer.host("fsm_mask", rows=len(rows)):
-            allowed = np.ones((self.B, self.vocab), bool)
+            allowed = self._ones_packed(self.B)
+            shared: dict = {}
             for i in rows:
                 self.timer.tick()
                 s = self.slots[i]
@@ -2032,14 +2089,21 @@ class ContinuousBatcher:
                 if c is not None:
                     rem = self._remaining(s.req, len(s.out_ids), s.pos)
                     try:
-                        allowed[i] = self._constraint_mask(c, rem)
+                        path = self._constraint_packed(
+                            c, rem, allowed[i], shared
+                        )
+                        how[path] += 1
                     except Exception as e:  # noqa: BLE001 — row isolation
                         # one row's broken FSM must not take the batch
                         # down: release it into the retry/quarantine
-                        # path; its all-True mask row samples a token
-                        # that the (slot, gen) / None-slot checks then
-                        # discard
+                        # path; its mask row samples a token that the
+                        # (slot, gen) / None-slot checks then discard
                         self._fail_slot(i, e)
+            self.timer.count("cached", how["cached"])
+        if self._tel_on:
+            for path, n in how.items():
+                if n:
+                    telemetry.FSM_MASK_ROWS_TOTAL.inc(float(n), path)
         return allowed
 
     def _remaining(self, req: GenRequest, emitted: int, pos: int) -> int:
@@ -2075,13 +2139,14 @@ class ContinuousBatcher:
         allowed = None
         if any(r.constraint is not None for r in reqs):
             with self.timer.host("fsm_mask", rows=n):
-                allowed = np.ones((nb, self.vocab), bool)
+                allowed = self._ones_packed(nb)
+                shared: dict = {}
                 for i, r in enumerate(reqs):
                     self.timer.tick()
                     if r.constraint is not None:
                         rem = self._remaining(r, 0, len(r.prompt_ids))
-                        allowed[i] = self._constraint_mask(
-                            r.constraint, rem
+                        self._constraint_packed(
+                            r.constraint, rem, allowed[i], shared
                         )
         row_seeds = None
         if any(r.row_seed is not None for r in reqs):

@@ -565,6 +565,20 @@ SCHED_ROW_STEPS_LOST_TOTAL = REGISTRY.counter(
     unit="row_steps",
     max_series=32,
 )
+# how a constrained row's mask reached the masked step (OBSERVABILITY.md
+# "The FSM masks travel bit-packed"): counted a row in
+# ContinuousBatcher._fsm_masks
+FSM_MASK_ROWS_TOTAL = REGISTRY.counter(
+    "sutro_fsm_mask_rows_total",
+    "Constrained rows whose FSM mask the mask assembly wrote, by how it "
+    "came by the bit-packed row: cached (a kept packed array copied, no "
+    "pass over the vocabulary), filtered (the token budget bit: one row "
+    "computed and packed), packed_here (a constraint that answers in "
+    "bools only)",
+    labels=("path",),  # cached | filtered | packed_here
+    unit="rows",
+    max_series=4,
+)
 
 # Span names the engine emits — OBSERVABILITY.md's span schema section
 # and tests key off this tuple, so additions land in one place.

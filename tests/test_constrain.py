@@ -1662,3 +1662,153 @@ def test_cpp_python_mask_parity_round3_features():
 def test_ref_cycles_clear_error_never_recursionerror(schema):
     with pytest.raises(ValueError):
         compile_schema(schema)
+
+
+# ---------------------------------------------------------------------------
+# the packed answer (TokenFSM.allowed_packed) is allowed_tokens, bit for bit
+# ---------------------------------------------------------------------------
+
+# the classify template's schema (templates/classification.py: a
+# scratchpad of at most 400 characters, then one of the labels)
+_CLASSIFY = {
+    "type": "object",
+    "properties": {
+        "scratchpad": {"type": "string", "maxLength": 400},
+        "classification": {"enum": ["positive", "negative", "neutral"]},
+    },
+    "required": ["scratchpad", "classification"],
+}
+_ENUM_ONLY = {"enum": ["positive", "negative", "neutral"]}
+_REGEX = {
+    "type": "object",
+    "properties": {
+        "code": {"type": "string", "pattern": "^[A-Z]{2}-[0-9]{3,40}$"}
+    },
+    "required": ["code"],
+}
+# the template's shape with a scratchpad short enough to build at once
+_SHORT = {
+    "type": "object",
+    "properties": {
+        "scratchpad": {"type": "string", "maxLength": 24},
+        "classification": {"enum": ["positive", "negative", "neutral"]},
+    },
+    "required": ["scratchpad", "classification"],
+}
+_PACKED_FACTORIES: dict = {}
+
+
+def _packed_factory(name, vocab):
+    key = (name, vocab)
+    if key not in _PACKED_FACTORIES:
+        schema = {"classify": _CLASSIFY, "enum": _ENUM_ONLY,
+                  "regex": _REGEX, "short": _SHORT}[name]
+        _PACKED_FACTORIES[key] = schema_constraint_factory(
+            schema, ByteTokenizer(vocab_size=vocab)
+        )
+    return _PACKED_FACTORIES[key]
+
+
+def _walk_packed(fac, budget):
+    """Walk one row to completion under ``budget`` (None: no budget; an
+    int: tokens left at step 0), a model that keeps writing where it may
+    (a letter or a digit while one is allowed, else the first allowed
+    id). At EVERY step, the complete state included, the packed answer
+    must unpack to ``allowed_tokens``'s, with and without a ``shared``
+    dict. Returns [(filtered, cached array?)] a step."""
+    V = fac.table.vocab_size
+    fsm, twin = fac(), fac()
+    seen = []
+    for n in range(1200):
+        rem = None if budget is None else max(budget - n, 0)
+        want = fsm.allowed_tokens(remaining=rem)
+        bits, filtered = fsm.allowed_packed(remaining=rem)
+        assert bits.dtype == np.uint8 and bits.shape == ((V + 7) // 8,)
+        np.testing.assert_array_equal(
+            np.unpackbits(bits, count=V).astype(bool), want
+        )
+        # rows of one assembly in one state under one budget share the
+        # filtered row; a kept array is the same object either way
+        shared: dict = {}
+        a, fa = fsm.allowed_packed(remaining=rem, shared=shared)
+        b, fb = twin.allowed_packed(remaining=rem, shared=shared)
+        assert a is b and fa == fb == filtered
+        np.testing.assert_array_equal(a, bits)
+        kept = fsm._complete or bits is fsm.masks.entry(fsm.states).packed
+        assert kept == (not filtered) and kept == (not bits.flags.writeable)
+        seen.append(filtered)
+        if fsm.is_complete():
+            return seen
+        tid = next(
+            (t for t in (ord("a"), ord("7")) if want[t]),
+            int(np.argmax(want)),
+        )
+        fsm.advance(tid)
+        twin.advance(tid)
+    raise AssertionError("the walk did not complete")
+
+
+@pytest.mark.parametrize("budget", ["none", "roomy", "tight", "infeasible"])
+@pytest.mark.parametrize(
+    "name,vocab",
+    # 267 ids: not a multiple of 8 (the template's NFA is seconds to
+    # build, so it is walked at that one)
+    [("classify", 267), ("enum", 267), ("enum", 272), ("regex", 267),
+     ("regex", 272)],
+)
+def test_allowed_packed_is_allowed_tokens_bit_for_bit(name, vocab, budget):
+    fac = _packed_factory(name, vocab)
+    room = fac().min_tokens()
+    seen = _walk_packed(
+        fac,
+        {"none": None, "roomy": room + 1000, "tight": room + 4,
+         "infeasible": 2}[budget],
+    )
+    assert seen[-1] is False  # the complete state: the kept stop mask
+    if budget in ("none", "roomy"):
+        # a budget that never bites never leaves the cache
+        assert not any(seen)
+    if budget == "tight" and name != "enum":
+        # the budget bites on the last free tokens before the forced close
+        assert any(seen) and not seen[0]
+    if budget == "infeasible":
+        # infeasible from the start: the unfiltered mask, from the cache
+        assert not seen[0]
+
+
+@pytest.mark.parametrize(
+    "tok_vocab,model_vocab",
+    [
+        (267, 267),   # equal, the last byte partly used
+        (267, 512),   # the model's vocabulary padded past the tokenizer's
+        (267, 269),   # ... inside the tokenizer's last byte
+        (272, 301),   # ... from a whole byte on, into a partial one
+        (300, 267),   # a tokenizer wider than the model: cut at the model's
+        (272, 267),   # ... inside the same byte
+    ],
+)
+def test_packed_row_pads_and_cuts_like_the_bool_mask(tok_vocab, model_vocab):
+    """The scheduler's packed row against the path it replaces
+    (``_pad_mask`` of the bool mask, then ``np.packbits``): the ids past
+    the tokenizer's vocabulary False, none past the model's."""
+    from sutro_tpu.engine.scheduler import ContinuousBatcher
+
+    b = object.__new__(ContinuousBatcher)
+    b.vocab = model_vocab
+    b._ones_row = np.packbits(np.ones((model_vocab,), bool))
+    fac = _packed_factory("short", tok_vocab)
+    fsm = fac()
+    tight = fsm.min_tokens() + 4
+    filtered = 0
+    for n in range(40):
+        for rem in (None, 1000, max(tight - n, 0)):
+            out = b._ones_packed(1)[0]
+            how = b._constraint_packed(fsm, rem, out, {})
+            want = b._constraint_mask(fsm, rem)
+            assert want.shape == (model_vocab,)
+            np.testing.assert_array_equal(out, np.packbits(want))
+            assert how in ("cached", "filtered")
+            filtered += how == "filtered"
+        allowed = fsm.allowed_tokens()
+        fsm.advance(ord("a") if allowed[ord("a")] else int(np.argmax(allowed)))
+    assert filtered  # the tight budget bit on the way

@@ -63,8 +63,8 @@ PLAIN = _DecodeFacts(
 )
 GREEDY_SCHEMA = PLAIN._replace(has_constraint=True, all_greedy=True)
 # the same batch once its fast-forward probe has disengaged, by the
-# share of its unmasked tokens the FSMs accept: the line lies at ~0.81
-# for a window of 8, the band where a batch stays where it is ~0.78-0.84
+# share of its unmasked tokens the FSMs accept: the line lies at ~0.82
+# for a window of 8, the band where a batch stays where it is ~0.79-0.85
 PROBED = dict(probed=True)
 
 
@@ -158,6 +158,14 @@ CHOICES = {
     ),
     "probed-just-above-the-line-on-windows": (
         PROBED, _accepting(0.83), 0, "window",
+    ),
+    # the band's edges as the constants of PR 48 put them (2.05 and 0.77
+    # kept a batch on windows at 0.78 and sent one back to them at 0.845)
+    "probed-at-the-band's-lower-edge-on-windows": (
+        PROBED, _accepting(0.78), 0, "single",
+    ),
+    "probed-at-the-band's-upper-edge-on-steps": (
+        PROBED, _accepting(0.845, stepping=True), 0, "single",
     ),
     "probed-under-the-band-on-windows": (
         PROBED, _accepting(0.75), 0, "single",

@@ -49,8 +49,11 @@ ITERATIONS = "sutro_sched_iterations_total"
 WIDTH = {"pipelined": 8, "window": 8, "fastforward": 17, "single": 1}
 
 
-def _requests(tok, scenario):
+def _requests(tok, scenario, wrap=None):
+    """``wrap``: what each row's FSM is handed to the scheduler as."""
     def req(i, text, **kw):
+        if wrap is not None and kw.get("constraint") is not None:
+            kw["constraint"] = wrap(i, kw["constraint"])
         return GenRequest(
             row_id=i, prompt_ids=np.array(tok.encode(text), np.int32), **kw
         )
@@ -70,7 +73,15 @@ def _requests(tok, scenario):
                 stop_seqs=[b"\xfe\xff\xfe"])
         ]
     factory = schema_constraint_factory(SCHEMA, tok)
-    if scenario == "scaffold":
+    if scenario == "tight":
+        # sampled rows whose budget covers the shortest output and three
+        # tokens more: it bites inside the free text
+        return [
+            req(i, t, max_new_tokens=factory().min_tokens() + 4,
+                temperature=0.8, constraint=factory())
+            for i, t in enumerate(["first row", "second", "third one"])
+        ]
+    if scenario in ("scaffold", "steps"):
         rows = [
             req(i, t, max_new_tokens=80, temperature=0.0,
                 constraint=factory())
@@ -145,20 +156,41 @@ def _early_stop(tok):
     return _RUNS["stop"]
 
 
-def _run(tok, scenario, tel=True, extra_stops=()):
+def _run(tok, scenario, tel=True, extra_stops=(), wrap=None, spy=None):
     """One run of a scenario on a fresh batcher: the job's own tallies
     (``JobCtx.stats``), its results, what the registry's counters gained
-    by path (``_by_path``) and the accept spans. The ``scaffold``
+    by path (``_by_path``) and the ``accept`` and ``fsm_mask`` spans. The
+    ``scaffold``
     scenario is HELD on windows, as a batch whose unmasked tokens verify
     is: these random weights' are refused, and left to the scheduler's
-    rule the batch would take masked steps (the rigged runs below)."""
+    rule the batch would take masked steps (the rigged runs below);
+    ``steps`` is the same job held on masked steps. ``wrap`` is
+    ``_requests``'s; ``spy`` (a list) gains ``(site, masks)`` for every
+    dispatch that may carry FSM masks: ``step``, ``window``, ``admit``."""
     from sutro_tpu.engine import scheduler as sched_mod
 
     was = telemetry.enabled()
     telemetry.set_enabled(tel)
     mp = pytest.MonkeyPatch()
-    if scenario == "scaffold":
-        mp.setattr(sched_mod, "_window_gain", lambda p, K, c: 2.0)
+    if scenario in ("scaffold", "steps"):
+        gain = 2.0 if scenario == "scaffold" else 0.0
+        mp.setattr(sched_mod, "_window_gain", lambda p, K, c: gain)
+    if spy is not None:
+        step, window = ModelRunner.decode_step, ModelRunner.decode_window
+        admit = sched_mod._admit_sample_jit
+
+        def spied(site, fn, pick):
+            def call(*a, **kw):
+                spy.append((site, pick(a, kw)))
+                return fn(*a, **kw)
+            return call
+
+        mp.setattr(ModelRunner, "decode_step", spied(
+            "step", step, lambda a, kw: kw.get("allowed")))
+        mp.setattr(ModelRunner, "decode_window", spied(
+            "window", window, lambda a, kw: kw.get("allowed0")))
+        mp.setattr(sched_mod, "_admit_sample_jit", spied(
+            "admit", admit, lambda a, kw: a[5]))
     try:
         ecfg = EngineConfig(
             kv_page_size=8, max_pages_per_seq=32, max_model_len=256,
@@ -175,7 +207,7 @@ def _run(tok, scenario, tel=True, extra_stops=()):
         job_id = f"yield-{scenario}-{next(_RUN_IDS)}"
         res = {}
         ctx = JobCtx(
-            job_id=job_id, pending=_requests(tok, scenario),
+            job_id=job_id, pending=_requests(tok, scenario, wrap),
             on_result=lambda r: res.__setitem__(r.row_id, r),
         )
         assert b.run_multi(
@@ -184,7 +216,7 @@ def _run(tok, scenario, tel=True, extra_stops=()):
         gained = _gained(before, telemetry.REGISTRY.collect())
         spans = [
             s for s in telemetry.RECORDER.snapshot(job_id)
-            if s["name"] == "accept"
+            if s["name"] in ("accept", "fsm_mask")
         ]
         return dict(ctx.stats), res, _by_path(gained), spans
     finally:
@@ -592,3 +624,176 @@ def test_row_steps_add_up_on_every_path_across_a_switch(
             "row_steps"
         ]
         assert kept > 0.8, by_path
+
+
+# ---------------------------------------------------------------------------
+# The FSM masks travel bit-packed from the mask cache to the device: the
+# same tokens as the bool masks gave, no [B, V] bool array on the way,
+# and a counter of how each constrained row's mask was come by.
+# ---------------------------------------------------------------------------
+
+MASK_ROWS = "sutro_fsm_mask_rows_total"
+# scenario -> what takes its masks: a window's ``allowed0`` behind a
+# refusal and verify forwards (greedy); masked single steps of sampled
+# rows under the batcher's seed; the greedy job on masked steps; sampled
+# rows whose budget bites. Every one samples its first tokens under masks.
+MASKED = ("scaffold", "sampled", "steps", "tight")
+
+
+class _BoolOnly:
+    """A ``TokenFSM`` behind the surface a constraint had before the
+    packed answer: ``allowed_tokens`` in bools, and no ``allowed_packed``.
+    The scheduler packs its row where it assembles the masks, which is
+    what every row's went through before (``np.packbits`` of the bools)."""
+
+    def __init__(self, fsm):
+        self._fsm = fsm
+        for name in ("token_allowed", "advance", "is_complete",
+                     "min_tokens", "plan_fastforward"):
+            setattr(self, name, getattr(fsm, name))
+
+    def allowed_tokens(self, remaining=None):
+        return self._fsm.allowed_tokens(remaining=remaining)
+
+
+def _masked(tok, scenario, bool_only=False):
+    """``_run`` of a masked scenario, with what the dispatches were
+    handed and what ``sutro_fsm_mask_rows_total`` gained, by path."""
+    key = ("masked", scenario, bool_only)
+    if key not in _RUNS:
+        spy = []
+        before = _series(telemetry.REGISTRY.collect(), MASK_ROWS)
+        out = _run(
+            tok, scenario, spy=spy,
+            wrap=(lambda i, c: _BoolOnly(c)) if bool_only else None,
+        )
+        after = _series(telemetry.REGISTRY.collect(), MASK_ROWS)
+        rows = {
+            k: int(after[k] - before.get(k, 0)) for k in after
+            if after[k] != before.get(k, 0)
+        }
+        _RUNS[key] = out + (spy, rows)
+    return _RUNS[key]
+
+
+@pytest.mark.parametrize("scenario", MASKED)
+def test_packed_masks_change_no_token(scenario, byte_tok):
+    """A constrained job decodes token for token what it decoded while
+    its masks were [B, V] bools packed at the runner: greedy and sampled
+    (a fixed seed), through the masked step, a window's ``allowed0``
+    and the first-token sampling."""
+    _, packed, by_path, _, spy, _ = _masked(byte_tok, scenario)
+    _, bools, _, _, _, _ = _masked(byte_tok, scenario, bool_only=True)
+    assert _tokens(packed) == _tokens(bools)
+    for i, r in packed.items():
+        assert r.cumulative_logprob == pytest.approx(
+            bools[i].cumulative_logprob, rel=1e-5, abs=1e-5
+        )
+    constrained = [r for i, r in packed.items() if i < 100]
+    assert all(r.finish_reason == "schema_complete" for r in constrained)
+    # the scenario went the way it is here for
+    sites = {site for site, masks in spy if masks is not None}
+    assert "admit" in sites
+    if scenario == "scaffold":
+        assert "window" in sites and by_path["fastforward"]["committed"]
+    else:
+        assert "step" in sites and by_path["single"]["committed"]
+
+
+@pytest.mark.parametrize("bool_only", [False, True])
+@pytest.mark.parametrize("scenario", MASKED)
+def test_the_device_is_handed_bit_packed_masks(scenario, bool_only, byte_tok):
+    """Whatever the constraint answers in, what reaches a device program
+    is uint8 [B, ceil(V / 8)]: the host holds no [B, V] bool array."""
+    spy = _masked(byte_tok, scenario, bool_only)[4]
+    V = MODEL_CONFIGS["tiny-dense"].vocab_size
+    handed = [(site, m) for site, m in spy if m is not None]
+    assert handed
+    for site, m in handed:
+        assert isinstance(m, np.ndarray) and m.dtype == np.uint8, site
+        assert m.ndim == 2 and m.shape[1] == (V + 7) // 8, (site, m.shape)
+        if site != "admit":  # (a prefill bucket has its own row count)
+            assert m.shape[0] == 4
+        # a row is a constrained row's mask or all ones, the bits past
+        # the vocabulary zero
+        ones = np.packbits(np.ones((V,), bool))
+        rows = np.unpackbits(m, axis=1, count=V)
+        assert all(
+            r.sum() < V or np.array_equal(m[i], ones)
+            for i, r in enumerate(rows)
+        )
+
+
+@pytest.mark.parametrize("scenario", MASKED)
+def test_mask_rows_count_every_constrained_row_masked(scenario, byte_tok):
+    """``sutro_fsm_mask_rows_total`` sums to the constrained rows the
+    mask assembly wrote (a masked step's constrained row-steps; a
+    window's flagged rows), ``cached`` wherever the budget does not
+    bite, and ``packed_here`` for a constraint that answers in bools."""
+    _, _, by_path, _, spy, rows = _masked(byte_tok, scenario)
+    V = MODEL_CONFIGS["tiny-dense"].vocab_size
+    ones = np.packbits(np.ones((V,), bool))
+    written = sum(
+        int((m != ones).any(axis=1).sum())
+        for site, m in spy if m is not None and site != "admit"
+    )
+    assert sum(rows.values()) == written > 0
+    if scenario in ("sampled", "tight"):
+        # every row of these jobs is constrained: a row-step, a mask
+        assert written == by_path["single"]["row_steps"]
+    assert rows.get("packed_here", 0) == 0
+    if scenario == "tight":
+        assert rows["filtered"] > 0 and rows["cached"] > 0
+    else:
+        assert set(rows) == {"cached"}
+    _, _, _, _, _, bools = _masked(byte_tok, scenario, bool_only=True)
+    assert bools == {"packed_here": written}
+
+
+def test_the_fsm_mask_span_says_how_many_rows_the_cache_served(byte_tok):
+    """``fsm_mask`` spans carry ``rows`` and ``cached``: summed over a
+    job they are the counter's (the first-token sampler's spans count no
+    row of a decode dispatch and carry no ``cached``)."""
+    for scenario in ("tight", "sampled"):
+        _, _, _, spans, _, rows = _masked(byte_tok, scenario)
+        masks = [
+            s["attrs"] for s in spans
+            if s["name"] == "fsm_mask" and "cached" in s["attrs"]
+        ]
+        assert masks and all(a["cached"] <= a["rows"] for a in masks)
+        assert sum(a["cached"] for a in masks) == rows["cached"]
+
+
+class _RaisesPacked:
+    """A ``TokenFSM`` whose packed answer raises from its ``n``-th ask."""
+
+    def __init__(self, fsm, n):
+        self._fsm, self._left = fsm, n
+        for name in ("allowed_tokens", "token_allowed", "advance",
+                     "is_complete", "min_tokens", "plan_fastforward"):
+            setattr(self, name, getattr(fsm, name))
+
+    def allowed_packed(self, remaining=None, shared=None):
+        self._left -= 1
+        if self._left < 0:
+            raise RuntimeError("this row's FSM broke")
+        return self._fsm.allowed_packed(remaining=remaining, shared=shared)
+
+
+def test_a_constraint_that_raises_fails_its_own_slot_only(byte_tok):
+    """Row isolation in the mask assembly: the row whose packed answer
+    raises ends in ``error``; the others decode what they decode in the
+    run where nothing raises (greedy rows: a row's tokens are its own)."""
+    _, clean, _, _, _, _ = _masked(byte_tok, "steps")
+    _, res, by_path, _ = _run(
+        byte_tok, "steps",
+        wrap=lambda i, c: _RaisesPacked(c, 3) if i == 1 else c,
+    )
+    assert res[1].finish_reason == "error"
+    others = {i: r for i, r in res.items() if i != 1}
+    assert _tokens(others) == {
+        i: t for i, t in _tokens(clean).items() if i != 1
+    }
+    y = by_path["single"]
+    assert y["lost"].get("failed", 0) >= 1
+    assert y["row_steps"] == y["committed"] + sum(y["lost"].values()), y
