@@ -16,7 +16,9 @@ takes while its windows are being refused, ``_decode_jit``). Each device
 op's self time goes to the first ``jax.named_scope`` of the mixed walk in
 its HLO ``op_name`` (``moe_ffn``, ``shared_expert`` inside it,
 ``attn_window``, ``attn_full``,
-``attn_mixer``, ``conv_mixer``, ``mamba_mixer``, ``mla_mixer`` and inside it
+``attn_mixer`` (``gqa_gate`` inside it), ``conv_mixer``, ``mamba_mixer``,
+``kda_mixer`` (inside it ``kda_conv``, ``kda_chunk``, ``kda_state_read``),
+``kda_commit``, ``mla_mixer`` and inside it
 ``mla_expand`` or ``mla_absorb`` and, under an indexer, ``dsa_indexer``,
 ``dsa_select`` and ``dsa_attend`` (the innermost wins), ``dense_ffn``; ``other``
 is the head, sampling, embeddings and what XLA hoisted), read from the
@@ -48,14 +50,16 @@ sys.path.insert(0, str(REPO))
 
 SCOPES = (
     "moe_ffn", "attn_window", "attn_full", "attn_mixer", "conv_mixer",
-    "mamba_mixer", "mla_mixer", "dense_ffn", "paged_decode_xla",
+    "mamba_mixer", "mla_mixer", "kda_mixer", "kda_commit", "dense_ffn",
+    "paged_decode_xla",
 )
 #: scopes INSIDE one of the above that are told apart: the shared expert
 #: inside ``moe_ffn``, the products of the form taken inside ``mla_mixer``
 #: and, inside those, an indexer's scores, its selection and the
 #: attention over it
 INNER = ("shared_expert", "mla_expand", "mla_absorb", "dsa_indexer",
-         "dsa_select", "dsa_attend")
+         "dsa_select", "dsa_attend", "kda_conv", "kda_chunk",
+         "kda_state_read", "gqa_gate")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _DEF = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 

@@ -1029,16 +1029,16 @@ def engine_info(model: Optional[str]) -> None:
             f"model: {m.name} layers={m.num_layers} attn_layers="
             f"{m.num_attn_layers} latent_layers={m.num_latent_layers} "
             f"(the pool's layers: {m.num_pool_layers}) state_layers="
-            f"{m.num_conv_layers + m.num_mamba_layers} kv_bytes_per_token="
+            f"{m.num_conv_layers + m.num_state_layers} kv_bytes_per_token="
             f"{m.num_pool_layers * sum(m.pool_row_widths) * width} "
             f"state_bytes_per_page="
             f"{m.num_conv_layers * m.conv_state_len * m.hidden_size * width}"
         )
-        if m.num_mamba_layers:
+        if m.state_kind:
             from .engine.kvcache import state_bytes_per_slot
 
             click.echo(
-                "state_bytes_per_slot="
+                f"state_kind={m.state_kind} state_bytes_per_slot="
                 f"{state_bytes_per_slot(m, ecfg)} (a slot a live sequence)"
             )
 

@@ -33,7 +33,10 @@ scheduler ... paged-KV decode attention"). Layout:
   starts from zeros, and writes a page's state before it reads it).
 
 - ``ssm`` ``[L_m, NS, N, I]`` and ``ssm_conv`` ``[NS, L_m * (K-1) * Cd]``,
-  for a model with mamba layers (``mamba_mixer``): a THIRD kind of
+  for a model with layers that keep a MATRIX state (Mamba-2's
+  ``mamba_mixer`` or a delta rule's ``kda_mixer``; ``ModelConfig.
+  state_kind`` / ``state_rows`` N / ``state_inner`` I / ``state_conv_dim``
+  Cd are the one description both read): a THIRD kind of
   per-sequence state, too large to keep a page (a matrix a head: tens
   of MB a sequence), kept a SLOT a live sequence. Slot 0 is the garbage
   slot. ``state_slot`` ``[NP]`` int32 maps a page to a slot, on the
@@ -122,7 +125,8 @@ import numpy as np
 
 from ..models.configs import ModelConfig
 from ..models.transformer import (
-    MixedChunk, StatePast, group_channels, over_state, per_channel,
+    MixedChunk, StatePast, columns_after, group_channels, over_state,
+    per_channel,
 )
 from .config import EngineConfig
 
@@ -176,8 +180,8 @@ class KVCache:
 
     @property
     def num_state_slots(self) -> int:
-        """Slots of the mamba state pool, the garbage slot included (0
-        for a model that keeps no such state)."""
+        """Slots of the state pool, the garbage slot included (0 for a
+        model that keeps no matrix state)."""
         return 0 if self.ssm is None else self.ssm.shape[1]
 
 
@@ -230,19 +234,29 @@ def alloc_cache(
             jnp.dtype(ecfg.activation_dtype), device=rep,
         )
     state = {}
-    if mcfg.num_mamba_layers:
+    if mcfg.num_state_layers:
+        if mcfg.state_kind == "kda" and (
+            getattr(ecfg, "kv_quantize", None) or sharding is not None
+        ):
+            raise NotImplementedError(
+                f"{mcfg.name} keeps a delta-rule state a slot: "
+                + ("int8 K/V beside it (kv_quantize)"
+                   if getattr(ecfg, "kv_quantize", None) else
+                   "the slot pool under a mesh")
+                + " is not built"
+            )
         state_slots = default_state_slots(ecfg, num_pages)
         # stored in the activation dtype, updated in float32; replicated
         # under a mesh, like the conv state
         act = jnp.dtype(ecfg.activation_dtype)
         state = dict(
             ssm=jnp.zeros(
-                (mcfg.num_mamba_layers, 1 + state_slots, mcfg.mamba_state,
-                 mcfg.mamba_inner), act, device=rep,
+                (mcfg.num_state_layers, 1 + state_slots, mcfg.state_rows,
+                 mcfg.state_inner), act, device=rep,
             ),
             ssm_conv=jnp.zeros(
-                (1 + state_slots, mcfg.num_mamba_layers
-                 * mcfg.mamba_conv_len * mcfg.mamba_conv_dim),
+                (1 + state_slots, mcfg.num_state_layers
+                 * mcfg.state_conv_len * mcfg.state_conv_dim),
                 act, device=rep,
             ),
             state_slot=jnp.zeros((num_pages,), jnp.int32, device=rep),
@@ -295,19 +309,21 @@ def default_state_slots(ecfg: EngineConfig, num_pages: int) -> int:
 
 
 def state_bytes_per_slot(mcfg: ModelConfig, ecfg: EngineConfig) -> int:
-    """Bytes of mamba state one sequence keeps (its slot of both pools)."""
+    """Bytes of matrix state one sequence keeps (its slot of both
+    pools), from the one description of a state layer
+    (``ModelConfig.state_rows`` ...): 0 for a model that keeps none."""
     per_layer = (
-        mcfg.mamba_state * mcfg.mamba_inner
-        + mcfg.mamba_conv_len * mcfg.mamba_conv_dim
+        mcfg.state_rows * mcfg.state_inner
+        + mcfg.state_conv_len * mcfg.state_conv_dim
     )
     return (
-        mcfg.num_mamba_layers * per_layer
+        mcfg.num_state_layers * per_layer
         * jnp.dtype(ecfg.activation_dtype).itemsize
     )
 
 
 class StateSlots:
-    """Host-side allocator of the mamba state pool's slots, beside the
+    """Host-side allocator of the state pool's slots, beside the
     page free list: slot 0 is the garbage slot; a live sequence holds
     one slot, bound to its FIRST page (``bind`` is idempotent for a page
     that is bound, so a sequence written again from position 0 into the
@@ -642,7 +658,7 @@ def read_state(
     cache: KVCache, page_table: jax.Array, start: jax.Array,
     layers: int, conv_dim: int,
 ) -> "StatePast | None":
-    """A mamba model's state as ``transformer.forward`` reads it, for
+    """A model's matrix state as ``transformer.forward`` reads it, for
     rows at ``start`` ([B] int32): the pool itself, each row's slot, and
     the rows' conv columns (a gather of a few KB a layer; zeros for a
     row at ``start`` 0). None for a cache without such state."""
@@ -658,14 +674,78 @@ def read_state(
     )
 
 
+def _advance_kda(
+    ssm: jax.Array,            # [L_k, NS, dk, I]: the pool
+    chunk: dict,               # "g" f32, "k", "u" [L_k, B, W, I]; "conv"
+    slots: jax.Array,          # [B] int32 (0: the row does not move)
+    fresh: jax.Array,          # [B] bool: the row's state before is 0
+    n: jax.Array,              # [B] int32: tokens accepted
+    K1: int,
+    use_pallas: bool = False,
+):
+    """``S_n = Diag(exp G_n) S_0 + sum_{i<n} (k_i * exp(G_n - G_i)) u_i^T``
+    for each row's slot, from the chunk's tokens (``transformer.
+    kda_pending`` solved their ``u``), a layer at a time: ONE ``u`` serves
+    every accepted length. Every decay is the pairwise ``exp(G_n - G_i)
+    <= 1``. With ``use_pallas`` a row's slot is streamed in and out once,
+    in place (``ops/pallas_ssm.kda_state_commit``); else the rows' slots
+    are gathered, advanced and scattered back. Returns ``(pool, conv
+    columns [L_k, B, K-1, Cd] after the n tokens)``."""
+    from ..ops import lowering, pallas_ssm
+
+    f32 = jnp.float32
+    L, NS, dk, I = ssm.shape
+    B, W = chunk["g"].shape[1:3]
+    H = I // dk
+    took = (jnp.arange(W, dtype=jnp.int32)[None, :] < n[:, None])[..., None]
+    kernel = use_pallas and pallas_ssm.state_commit_supported(ssm)
+    if use_pallas and not kernel:
+        lowering.record_reference(lowering.KDA_STATE_COMMIT)
+
+    def layer(pool, xs):
+        l, g, k, u, ext = xs
+        g = jnp.where(took, g.astype(f32), 0.0)                    # [B, W, I]
+        G = jnp.cumsum(g, axis=1)
+        total = G[:, -1]                                           # [B, I]
+        coef = jnp.where(
+            took, jnp.exp(total[:, None] - G) * k.astype(f32), 0.0
+        )
+        decay = jnp.where(fresh[:, None], 0.0, jnp.exp(total))     # [B, I]
+        with jax.named_scope("kda_commit"):
+            if kernel:
+                pool = pallas_ssm.kda_state_commit(
+                    pool, l, slots, decay, coef, u.astype(f32)
+                )
+            else:
+                S = pool[l][slots].astype(f32).reshape(B, dk, H, dk)
+                new = jnp.swapaxes(
+                    decay.reshape(B, H, dk), 1, 2
+                )[..., None] * S + jnp.einsum(
+                    "bwhk,bwhv->bkhv", coef.reshape(B, W, H, dk),
+                    u.astype(f32).reshape(B, W, H, dk),
+                    precision=jax.lax.Precision.HIGHEST,
+                )
+                pool = pool.at[l, slots].set(
+                    new.reshape(B, dk, I).astype(pool.dtype)
+                )
+        return pool, columns_after(ext, n, K1)
+
+    return jax.lax.scan(
+        layer, ssm,
+        (jnp.arange(L, dtype=jnp.int32), chunk["g"], chunk["k"],
+         chunk["u"], chunk["conv"]),
+    )
+
+
 def write_state(
     cache: KVCache,
     chunk: dict,               # MixedChunk.ssm
     page_table: jax.Array,     # [B, MP] int32
     start: jax.Array,          # [B] int32 — global position of chunk token 0
     valid_len: jax.Array,      # [B] int32 — tokens of the chunk that count
+    use_pallas: bool = False,
 ) -> KVCache:
-    """Commit a chunk's mamba state for its first ``valid_len`` tokens
+    """Commit a chunk's matrix state for its first ``valid_len`` tokens
     and point the page that holds the last of them at the row's slot. A
     row with ``valid_len`` 0 keeps its state. Two forms
     (``MixedChunk.ssm``): "final", the state a prefill computed, is
@@ -673,7 +753,8 @@ def write_state(
     decode window, a verify chunk) advance EVERY slot of the pool in one
     elementwise pass, in place: ``S <- decay S + sum_t c_t x_t B_t^T``
     with decay 1 and no tokens for a slot no row advances, so the state
-    is neither gathered nor scattered."""
+    is neither gathered nor scattered. A delta-rule chunk's ``g, k, u``
+    advance the rows' slots a row at a time (``_advance_kda``)."""
     slots = state_slots_at(cache, page_table, start)
     moved = valid_len > 0
     slots = jnp.where(moved, slots, 0)
@@ -682,7 +763,12 @@ def write_state(
     f32 = jnp.float32
     ext = chunk["conv"]                                   # [L, B, K-1+T', Cd]
     K1 = cache.ssm_conv.shape[1] // (L * ext.shape[-1])
-    if "final" in chunk:
+    if "u" in chunk:
+        ssm, cols = _advance_kda(
+            ssm, chunk, slots, start <= 0, valid_len, K1,
+            use_pallas=use_pallas,
+        )
+    elif "final" in chunk:
         at = jnp.arange(L, dtype=jnp.int32)[:, None] * NS + slots[None]
         ssm = ssm.reshape((L * NS,) + ssm.shape[2:]).at[at].set(
             chunk["final"].astype(ssm.dtype)
@@ -776,7 +862,8 @@ def write_kv(
     if isinstance(k_chunk, MixedChunk):
         if k_chunk.ssm is not None:
             cache = write_state(
-                cache, k_chunk.ssm, page_table, start, valid_len
+                cache, k_chunk.ssm, page_table, start, valid_len,
+                use_pallas=use_pallas and kernel_mesh is None,
             )
         conv = cache.conv
         if k_chunk.conv is not None:

@@ -148,6 +148,11 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         "grouped_matmul": lowering.grouped_matmul_counts(),
         "moe_combine": lowering.moe_combine_counts(),
         "ssm_state_read": lowering.ssm_state_read_counts(),
+        # a delta-rule (kda) layer: its traces by form, its two products
+        # against the committed state and its commit, each by path
+        "kda": lowering.kda_counts(),
+        "kda_state_read": lowering.kda_state_read_counts(),
+        "kda_state_commit": lowering.kda_state_commit_counts(),
         # a latent layer's attention by form, whichever path computed it
         # (``kernel_paths`` above says kernel or XLA form)
         "latent_attention": lowering.latent_counts(),
@@ -406,7 +411,29 @@ class ModelRunner:
         """The model keeps per-sequence state beside K/V (conv or mamba
         layers): callers of the verify dispatches commit it at the
         accepted length (``commit_verified``)."""
-        return self.mcfg.num_conv_layers > 0 or self.mcfg.num_mamba_layers > 0
+        return self.mcfg.num_conv_layers > 0 or self.mcfg.num_state_layers > 0
+
+    def state_matrix_bytes(self, rows: int) -> int:
+        """Bytes of the state layers' matrices alone (no conv columns)
+        in ``rows`` rows' slots: what a delta-rule step's two products
+        stream and a commit rewrites."""
+        m = self.mcfg
+        return int(
+            rows * m.num_state_layers * m.state_rows * m.state_inner
+            * jnp.dtype(self.ecfg.activation_dtype).itemsize
+        )
+
+    def _window_state_bytes(self) -> int:
+        """Bytes of the fused window's buffers for the state layers'
+        uncommitted tokens at the configured batch and steps."""
+        m, steps = self.mcfg, self.ecfg.decode_multi_step
+        act = jnp.dtype(self.ecfg.activation_dtype)
+        a_row = (m.state_conv_len + steps) * m.state_conv_dim * act.itemsize
+        a_row += steps * sum(
+            width * jnp.dtype(dt).itemsize
+            for _, width, dt in transformer.pending_buffers(m, act)
+        )
+        return m.num_state_layers * self.ecfg.decode_batch_size * a_row
 
     def state_step_bytes(self, rows: int) -> int:
         """Bytes of mamba state a decode step reads for ``rows`` live
@@ -645,11 +672,15 @@ class ModelRunner:
         if not limit:
             return want, want_window
         in_use = int(stats.get("bytes_in_use") or 0)
-        if self.mcfg.num_mamba_layers:
-            # the state pool comes first: a slot a row of the batch
+        if self.mcfg.num_state_layers:
+            # the state pool comes first: a slot a row of the batch; then
+            # what a fused window carries of its uncommitted tokens (the
+            # conv columns and ``pending_buffers``, a row of the batch a
+            # step), which is no transient the reserve was sized for
+            # once a token leaves 100 KB a layer (a delta rule's)
             in_use += (1 + default_state_slots(self.ecfg, want)) * (
                 state_bytes_per_slot(self.mcfg, self.ecfg)
-            )
+            ) + self._window_state_bytes()
         reserve = int(limit * HBM_RESERVE_FRACTION)
         page = self._page_bytes_per_device(dtype)
         avail = limit - in_use - reserve
@@ -725,7 +756,13 @@ class ModelRunner:
             "attn_layers": int(self.mcfg.num_attn_layers),
             "pool_layers": int(self.cache.k_pages.shape[0]),
             "state_layers": int(
-                self.mcfg.num_conv_layers + self.mcfg.num_mamba_layers
+                self.mcfg.num_conv_layers + self.mcfg.num_state_layers
+            ),
+            # what keeps a matrix state a slot ("mamba" | "kda"; None:
+            # no layer does) and a slot's bytes over those layers
+            "state_kind": self.mcfg.state_kind,
+            "state_bytes_per_slot": int(
+                state_bytes_per_slot(self.mcfg, self.ecfg)
             ),
             "state_bytes": int(sum(
                 0 if pool is None else pool.nbytes
@@ -813,12 +850,39 @@ class ModelRunner:
         (``transformer.StatePast``); None for a model that keeps none."""
         return read_state(
             cache, page_table, start,
-            self.mcfg.num_mamba_layers, self.mcfg.mamba_conv_dim,
+            self.mcfg.num_state_layers, self.mcfg.state_conv_dim,
         )
 
-    def _count_state_commit(self, path: str) -> None:
-        if telemetry.ENABLED and self.has_state:
-            telemetry.STATE_COMMITS_TOTAL.inc(1.0, path)
+    def _count_state_commit(
+        self, path: str, rows: int = 1, steps: int = 1
+    ) -> None:
+        """A dispatch that commits per-sequence state, by ``path``; for a
+        model with delta-rule layers also the form they took and the
+        state bytes its program moves and had to move (``rows`` rows,
+        ``steps`` steps before the one commit; a verify chunk is counted
+        where its accepted length commits, its forward as one read)."""
+        if not (telemetry.ENABLED and self.has_state):
+            return
+        telemetry.STATE_COMMITS_TOTAL.inc(1.0, path)
+        if self.mcfg.state_kind != "kda":
+            return
+        m = self.mcfg
+        slot = float(self.state_matrix_bytes(rows))
+        kernels = self.use_pallas and self.kernel_mesh is None
+        if path in ("prefill", "chunk", "resume"):
+            # the chunk form: the slot gathered, the final state written
+            telemetry.KDA_DISPATCHES_TOTAL.inc(1.0, "chunked")
+            read, commit, reads, commits = slot, slot, 1, 1
+        else:
+            telemetry.KDA_DISPATCHES_TOTAL.inc(1.0, "pending")
+            # a kernel streams a row's slot once; the XLA forms gather it
+            # (read, written, read again) and scatter it back
+            reads, commits = steps, 1
+            read = slot * reads * (1 if kernels else 3)
+            commit = slot * (2 if kernels else 5)
+        telemetry.KDA_STATE_BYTES_TOTAL.inc(read, "read")
+        telemetry.KDA_STATE_BYTES_TOTAL.inc(commit, "commit")
+        telemetry.KDA_STATE_BYTES_NEEDED_TOTAL.inc(slot * (reads + commits))
 
     def _count_latent(self, form: str, past_len=None, steps: int = 1) -> None:
         """A dispatch of a model of latent layers, by the form its
@@ -1197,7 +1261,7 @@ class ModelRunner:
             lens[i] = len(r)
             tables[i] = page_tables[i]
         self._bind_fresh(tables[:n], [0] * n)
-        self._count_state_commit("prefill")
+        self._count_state_commit("prefill", n)
         self._count_latent("expanded", steps=ids.shape[1])
         self._bind_window(tables[:n], [0] * n, lens[:n])
         logits, self.cache, route = self._prefill_jit(
@@ -1237,7 +1301,7 @@ class ModelRunner:
             st[i] = starts[i]
             tables[i] = page_tables[i]
         self._bind_fresh(tables[:n], st[:n])
-        self._count_state_commit("chunk")
+        self._count_state_commit("chunk", n)
         self._count_latent("absorbed", steps=int(st.max()) + ids.shape[1])
         self._bind_window(tables[:n], st[:n], lens[:n])
         logits, self.cache, route = self._prefill_chunk_jit(
@@ -1474,7 +1538,7 @@ class ModelRunner:
                 jnp.asarray(freq, jnp.float32),
                 jnp.asarray(rep, jnp.float32),
             )
-        self._count_state_commit("window")
+        self._count_state_commit("window", B)
         self._count_latent("absorbed", past_len)
         self._count_kv_pages(past_len, page_table, 1, pfx)
         self._bind_window(page_table, past_len, np.ones((B,), np.int32))
@@ -1597,7 +1661,7 @@ class ModelRunner:
         VD = widths[1] if len(widths) > 1 else 0
         wv0 = jnp.zeros((L, B, steps, VD), dtype) if VD else None
         mixed = not self.mcfg.homogeneous
-        K1 = self.mcfg.conv_state_len or self.mcfg.mamba_conv_len
+        K1 = self.mcfg.conv_state_len or self.mcfg.state_conv_len
         wc0 = ws0 = past = None
 
         def window_of(state):  # [L, B, K-1, C] -> [L, B, K-1 + steps, C]
@@ -1609,24 +1673,22 @@ class ModelRunner:
 
         if self.mcfg.num_conv_layers:
             wc0 = window_of(self._state_at(cache, page_table, past_len))
-        if self.mcfg.num_mamba_layers:
+        pending = ()
+        if self.mcfg.num_state_layers:
             # the pool is a constant of the scan, like the pages: the
             # window's tokens ride in buffers and write_kv commits them
             past = self._state_past(cache, page_table, past_len)
             m = self.mcfg
-            act = jnp.dtype(self.ecfg.activation_dtype)
+            pending = transformer.pending_buffers(
+                m, jnp.dtype(self.ecfg.activation_dtype)
+            )
             ws0 = {
                 "conv": window_of(past.conv),
                 **{
                     name: jnp.zeros(
-                        (m.num_mamba_layers, B, steps, width), dt
+                        (m.num_state_layers, B, steps, width), dt
                     )
-                    for name, width, dt in (
-                        ("dt", m.mamba_heads, jnp.float32),
-                        ("dA", m.mamba_heads, jnp.float32),
-                        ("x", m.mamba_inner, act),
-                        ("B", m.mamba_groups * m.mamba_state, act),
-                    )
+                    for name, width, dt in pending
                 },
             }
 
@@ -1643,7 +1705,7 @@ class ModelRunner:
                     conv=jax.lax.dynamic_slice_in_dim(
                         ws["conv"], step_idx, K1, axis=2
                     ),
-                    window=(ws["dt"], ws["dA"], ws["x"], ws["B"], step_idx),
+                    window=tuple(ws[n] for n, _, _ in pending) + (step_idx,),
                 ),
             )
             route = self._route_stats(k)
@@ -1749,7 +1811,7 @@ class ModelRunner:
             top_k = np.zeros((B,), np.int32)
         # the window's routing counts stay on the device beside its
         # tokens; whoever fetches the tokens fetches them
-        self._count_state_commit("window")
+        self._count_state_commit("window", B, steps)
         self._count_latent("absorbed", past_len, steps)
         self._count_kv_pages(past_len, page_table, steps, pfx)
         self._bind_window(page_table, past_len, np.full((B,), steps))
@@ -1896,7 +1958,10 @@ class ModelRunner:
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
     def _commit_state_jit(self, cache: KVCache, held, page_table, start, n):
-        return write_kv(cache, held, None, page_table, start, n)
+        return write_kv(
+            cache, held, None, page_table, start, n,
+            use_pallas=self.use_pallas, kernel_mesh=self.kernel_mesh,
+        )
 
     def commit_verified(self, accepted: np.ndarray) -> None:
         """After ``verify_candidates`` on a model that keeps conv state
@@ -1907,7 +1972,7 @@ class ModelRunner:
         if held is None:
             return
         chunk, page_table, past_len = held
-        self._count_state_commit("verify")
+        self._count_state_commit("verify", len(accepted))
         self.cache = self._commit_state_jit(
             self.cache, chunk,
             jnp.asarray(page_table, jnp.int32),
@@ -2019,7 +2084,9 @@ class ModelRunner:
     def commit_window(self, handle, accepted: np.ndarray) -> None:
         """Write each row's accepted window prefix into the page pool."""
         wk, wv, past_len, page_table = handle
-        self._count_state_commit("window")
+        self._count_state_commit(
+            "window", len(past_len), int(jax.tree.leaves(wk)[0].shape[2])
+        )
         self._bind_window(page_table, past_len, accepted)
         self.cache = self._commit_window_jit(
             self.cache, wk, wv,

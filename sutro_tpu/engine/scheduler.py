@@ -2690,10 +2690,14 @@ class ContinuousBatcher:
         (runner.state_step_bytes). Nothing for any other model."""
         if not self._slot_state:
             return {}
-        return {
+        attrs = {
             "state_rows": int(rows),
             "state_bytes": int(self.runner.state_step_bytes(rows)),
         }
+        if getattr(self.runner.mcfg, "state_kind", None) == "kda":
+            # the delta-rule layers' matrices alone (no conv columns)
+            attrs["kda_state_bytes"] = self.runner.state_matrix_bytes(rows)
+        return attrs
 
     def _kv_attrs(self, ctx) -> Dict[str, float]:
         """Span attrs of a dispatch of a model that keeps K/V a pool a

@@ -232,11 +232,11 @@ def _load_mixed(mcfg: ModelConfig, get, dtype) -> Dict[str, Any]:
             "of rope_parameters by layer kind are unconfirmed; the model "
             "runs on seeded random weights"
         )
-    if mcfg.num_mamba_layers:
+    if mcfg.num_state_layers:
         raise NotImplementedError(
-            f"{mcfg.name}: loading a checkpoint with mamba layers (the "
-            "granitemoehybrid tensor names) is not written; the model "
-            "runs on seeded random weights"
+            f"{mcfg.name}: loading a checkpoint with {mcfg.state_kind} "
+            "layers (the granitemoehybrid / solar_open2 tensor names) is "
+            "not written; the model runs on seeded random weights"
         )
     stacks: Dict[str, Dict[str, list]] = {}
 

@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers eleven
+config-driven decoder-only transformer (models/transformer.py) covers twelve
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -65,6 +65,17 @@ families:
   is one chip of the sixteen that share every layer of the first
   pipeline stage: a dense layer and four routed layers, experts 0-15 of
   each routed layer's 256, an eighth of the vocabulary
+- Solar Open 2 (250b-a15b; ``model_type`` solar_open2): three layers of
+  four are Kimi Delta Attention ("kda": a gated delta rule, ``kda_heads``
+  heads of a ``kda_head_dim`` x ``kda_head_dim`` state kept a SLOT a
+  sequence like Mamba-2's, a decay a CHANNEL and an output gate from
+  low-rank pairs, ``beta`` in (0, 2), a 4-tap conv over q, k and v), the
+  fourth softmax GQA without rotary embedding and with an output gate
+  (``attn_gate``); every FFN routes 320 SwiGLU experts top-8 (sigmoid,
+  selection bias, renormalised) beside a shared one. ``-l8-ep16`` is one
+  chip of the sixteen that share every layer of the first of six
+  pipeline stages: layers 0-7, experts 0-19 of each layer's 320, an
+  eighth of the vocabulary
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -137,7 +148,7 @@ class ModelConfig:
     # takes beside its routed ones (0: none)
     moe_shared_intermediate_size: int = 0
     # Per-layer mixer kinds, "attention" | "swa" | "conv" | "mamba" |
-    # "mla";
+    # "mla" | "kda";
     # empty => attention everywhere. A "swa" layer is attention over the
     # last ``sliding_window`` positions, with the plain rotary embedding
     # of ``local_rope_theta`` (``rope_theta`` when that is None) whatever
@@ -162,6 +173,23 @@ class ModelConfig:
     mamba_groups: int = 1
     mamba_conv: int = 0
     mamba_chunk: int = 256
+    # A "kda" layer is Kimi Delta Attention (models/transformer.py
+    # ``kda_mixer``): ``kda_heads`` heads, each with a state
+    # [kda_head_dim (k), kda_head_dim (v)] that every token multiplies
+    # by ``(I - beta k k^T) Diag(exp g)`` before it adds ``beta k v^T``;
+    # a depthwise causal conv of ``kda_conv`` taps over [q | k | v]; the
+    # log-decay ``g`` a CHANNEL and the output gate from pairs of
+    # matrices of rank ``kda_rank``; ``beta = kda_beta_scale *
+    # sigmoid(.)`` (2: eigenvalues of ``I - beta k k^T`` down to -1); the
+    # chunk form at ``kda_chunk`` tokens. ``attn_gate``: the model's
+    # attention layers gate their output a channel, ``sigmoid(x W_gate)``
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 0
+    kda_rank: int = 0
+    kda_beta_scale: float = 1.0
+    kda_chunk: int = 64
+    attn_gate: bool = False
     # An "mla" layer is latent attention (models/transformer.py
     # ``mla_mixer``): ``c_q = norm(x W_qa)`` of ``q_lora_rank``, a head's
     # query ``qk_nope_head_dim`` wide plus ``qk_rope_head_dim`` that takes
@@ -407,6 +435,63 @@ class ModelConfig:
     def mamba_conv_len(self) -> int:
         """Conv columns a sequence keeps a mamba layer (taps - 1)."""
         return max(self.mamba_conv - 1, 0) if self.num_mamba_layers else 0
+
+    @property
+    def num_kda_layers(self) -> int:
+        return self.mixers.count("kda")
+
+    @property
+    def kda_inner(self) -> int:
+        """Width of a kda layer's q, k, v and o (heads x head_dim)."""
+        return self.kda_heads * self.kda_head_dim
+
+    # -- layers that keep a MATRIX state a sequence, in the slot pool ---
+    # ONE description for every such kind: the pool's shapes
+    # (engine/kvcache.py), a slot's bytes, the runner's report and the
+    # fused window's buffers read these and no family's own counts
+
+    @property
+    def state_kind(self) -> Optional[str]:
+        """The mixer kind whose state lives in the slot pool ("mamba" |
+        "kda"; None: the model keeps no such state). One kind a model:
+        ``_check_mixed`` refuses both."""
+        if self.num_mamba_layers:
+            return "mamba"
+        return "kda" if self.num_kda_layers else None
+
+    @property
+    def num_state_layers(self) -> int:
+        return self.num_mamba_layers or self.num_kda_layers
+
+    @property
+    def state_rows(self) -> int:
+        """The MAJOR axis of a layer's state in its slot: Mamba-2's N,
+        a delta-rule head's key axis."""
+        return self.mamba_state if self.num_mamba_layers else (
+            self.kda_head_dim if self.num_kda_layers else 0
+        )
+
+    @property
+    def state_inner(self) -> int:
+        """The minor axis: heads x a head's channels (value axis)."""
+        return self.mamba_inner if self.num_mamba_layers else (
+            self.kda_inner if self.num_kda_layers else 0
+        )
+
+    @property
+    def state_conv_dim(self) -> int:
+        """Channels of the conv in front of a state layer: Mamba-2's
+        [x | B | C], a delta-rule layer's [q | k | v]."""
+        return self.mamba_conv_dim if self.num_mamba_layers else (
+            3 * self.kda_inner if self.num_kda_layers else 0
+        )
+
+    @property
+    def state_conv_len(self) -> int:
+        """Conv columns a sequence keeps a state layer (taps - 1)."""
+        if self.num_mamba_layers:
+            return self.mamba_conv_len
+        return max(self.kda_conv - 1, 0) if self.num_kda_layers else 0
 
     def window_for_layer(self, layer: int) -> int:
         """Per-layer attention window (0 = full); SURVEY §5.7
@@ -673,6 +758,44 @@ def _latent_moe(name: str, layers: int = 40, *, h: int = 2048, nh: int = 32,
     )
 
 
+def _solar_kda(name: str, layers: int = 48, *, h: int = 4096, nh: int = 64,
+               nkv: int = 8, hd: int = 128, k_heads: int = 64,
+               k_head_dim: int = 128, k_rank: int = 0, k_chunk: int = 64,
+               inter: int = 10_240, experts: int = 320, top_k: int = 8,
+               moe_inter: int = 1280, held: int = 0, first: int = 0,
+               vocab: int = 196_608, template: str = "chatml") -> ModelConfig:
+    """The published ``solar_open2`` keys: layer ``i`` is softmax GQA iff
+    ``i % 4 == 0`` (``gqa_layers``; no rotary embedding, an output gate a
+    channel), Kimi Delta Attention otherwise (``kda_use_full_proj``
+    false: the decay's and the gate's projections are pairs of rank
+    ``k_rank``, 0: the head's width; ``kda_allow_neg_eigval``: beta
+    doubled; a 4-tap conv); every FFN routed (sigmoid scores, a selection
+    bias, the chosen scores over their sum (+1e-20) times 1) beside ONE
+    shared expert of an expert's width. ``intermediate_size`` is read by
+    no layer (``first_k_dense_replace`` 0). ``held`` / ``first``: the
+    experts this chip holds of each layer (0: all)."""
+    return ModelConfig(
+        name=name, vocab_size=vocab, hidden_size=h, num_layers=layers,
+        num_heads=nh, num_kv_heads=nkv, head_dim=hd,
+        intermediate_size=inter, norm_eps=1e-5, rope_theta=10_000.0,
+        qk_norm=False, tie_embeddings=False,
+        layer_types=tuple(
+            "attention" if i % 4 == 0 else "kda" for i in range(layers)
+        ),
+        position_embedding="nope", attn_gate=True,
+        kda_heads=k_heads, kda_head_dim=k_head_dim, kda_conv=4,
+        kda_rank=k_rank or k_head_dim, kda_beta_scale=2.0,
+        kda_chunk=k_chunk,
+        moe_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_inter,
+        moe_experts_held=held, moe_first_expert=first,
+        moe_shared_intermediate_size=moe_inter,
+        router_score="sigmoid", router_select_bias=True,
+        router_renorm=True, router_scale=1.0, router_renorm_eps=1e-20,
+        chat_template=template, seeded_unit_embedding=True,
+    )
+
+
 #: GLM-5's published widths (``glm_moe_dsa``): 64 heads, nope 192 | rope
 #: 64, V 256, an indexer of 32 heads of 128 that keeps 2,048 positions
 _GLM5 = dict(
@@ -758,6 +881,14 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         "glm-5-l5-ep16", 5, dense_layers=1, held=16, first=0,
         vocab=19_360, **_GLM5,
     ),
+    # Solar-Open2-250B: as published (250.3 B parameters), and one chip
+    # of the sixteen that share every layer of the FIRST of six pipeline
+    # stages: layers 0-7 (2 GQA + 6 KDA), experts 0-19 of each layer's
+    # 320, rows 0-24,575 of the vocabulary (3.90 B parameters, 7.80 GB)
+    "solar-open2-250b": _solar_kda("solar-open2-250b"),
+    "solar-open2-250b-l8-ep16": _solar_kda(
+        "solar-open2-250b-l8-ep16", 8, held=20, first=0, vocab=24_576,
+    ),
     # Embeddings (Qwen3 trunk + last-token-pool head)
     "qwen3-emb-0.6b": _qwen3("qwen3-emb-0.6b", 1024, 28, 16, 8, 3072, head="embedding"),
     "qwen3-emb-6b": _qwen3("qwen3-emb-6b", 4096, 36, 32, 8, 12288, tie=False, head="embedding"),
@@ -833,6 +964,16 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         rope=8, v_dim=20, inter=192, experts=16, top_k=4, moe_inter=40,
         held=4, first=0, vocab=512, theta=1_000_000.0, eps=1e-5,
         index=(3, 24, 8), peaked=1.5, template="plain",
+    ),
+    # one period and a half (GQA, 3 KDA, GQA, KDA); 4 delta-rule heads of
+    # 16 (unlike the attention's 4 heads of 32 over 2 KV heads), pairs of
+    # rank 8, a chunk of 8 so that short prompts cross chunk edges; 16
+    # experts top-4 of which this chip holds 4
+    "tiny-solar-kda": _solar_kda(
+        "tiny-solar-kda", 6, h=128, nh=4, nkv=2, hd=32, k_heads=4,
+        k_head_dim=16, k_rank=8, k_chunk=8, inter=256, experts=16,
+        top_k=4, moe_inter=48, held=4, first=0, vocab=512,
+        template="plain",
     ),
     "tiny-emb": ModelConfig(
         name="tiny-emb", vocab_size=512, hidden_size=128, num_layers=2,

@@ -82,14 +82,19 @@ class MixedChunk:
     # or, for a chunk whose accepted length is decided later (T' = T),
     # the tokens' "dt", "dA" [L_m, B, T, Hm], "x" [L_m, B, T, I] and
     # "B" [L_m, B, T, G*N], from which ``kvcache.write_kv`` computes
-    # the state after ANY n <= T of them
+    # the state after ANY n <= T of them. For "kda" layers
+    # (``kda_mixer``) the same keys "conv" and "final", or the tokens'
+    # log-decays "g" [L_k, B, T, I] float32, keys "k" and solved
+    # updates "u" [L_k, B, T, I]: ``S_n = Diag(exp G_n) S_0 + sum_{i<=n}
+    # (k_i * exp(G_n - G_i)) u_i^T`` for any n
     ssm: Optional[Dict[str, jax.Array]] = None
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class StatePast:
-    """A mamba model's per-sequence state as ``forward`` reads it. The
+    """The per-sequence matrix state (Mamba-2 or delta-rule layers:
+    ``ModelConfig.state_kind``) as ``forward`` reads it. The
     pool is a CONSTANT of every scan (like the page pool): no forward
     writes it; ``kvcache.write_kv`` commits a chunk."""
 
@@ -99,7 +104,8 @@ class StatePast:
     conv: jax.Array     # [L_m, B, K-1, Cd]: conv columns before the chunk
     # inside a fused window: the window's earlier tokens, not yet
     # committed, as (dt, dA [L_m, B, W, Hm], x [L_m, B, W, I],
-    # B [L_m, B, W, G*N], step index)
+    # B [L_m, B, W, G*N], step index); for "kda" layers (g, k, u
+    # [L_k, B, W, I], step index): ``pending_buffers`` names them
     window: Optional[Tuple[jax.Array, ...]] = None
 
 
@@ -153,6 +159,8 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     if cfg.num_mamba_layers:
         out["mamba"] = _init_mamba_layers(cfg, dense, dtype)
+    if cfg.num_kda_layers:
+        out["kda"] = _init_kda_layers(cfg, dense, dtype)
     # full and window attention layers: the same block, a stack a kind
     for kind, Lk in (("attn", La), ("swa", cfg.num_window_layers)):
         if not Lk:
@@ -167,6 +175,8 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
         if cfg.qk_norm:
             out[kind]["q_norm"] = jnp.ones((Lk, Dh), dtype)
             out[kind]["k_norm"] = jnp.ones((Lk, Dh), dtype)
+        if cfg.attn_gate:
+            out[kind]["w_attn_gate"] = dense((Lk, H, NHD), H)
     if cfg.num_latent_layers:
         Ll, NH = cfg.num_latent_layers, cfg.num_heads
         Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
@@ -301,6 +311,38 @@ def _init_mamba_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
     }
 
 
+def _init_kda_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
+    """The "kda" stack. ``a_log`` a head and ``dt_bias`` a channel as the
+    published layer draws them (A uniform in [1, 16], dt log-uniform in
+    [0.001, 0.1] through the inverse softplus), float32, so that seeded
+    states neither vanish nor saturate; the gate's bias small and
+    NON-zero, so that leaving it out shows."""
+    L, H = cfg.num_kda_layers, cfg.hidden_size
+    I, Hk, R = cfg.kda_inner, cfg.kda_heads, cfg.kda_rank
+    ua, ud = (
+        jax.scipy.stats.norm.cdf(x.astype(jnp.float32))
+        for x in (dense((L, Hk), 1), dense((L, I), 1))
+    )
+    dt = jnp.exp(ud * (np.log(0.1) - np.log(0.001)) + np.log(0.001))
+    return {
+        "attn_norm": jnp.ones((L, H), dtype),
+        "w_qkv": dense((L, H, 3 * I), H),        # [q | k | v]
+        "w_conv": dense((L, 3 * I, cfg.kda_conv), cfg.kda_conv),
+        # the log-decay a channel: a pair of rank R, then a bias
+        "w_fa": dense((L, H, R), H),
+        "w_fb": dense((L, R, I), R),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),       # softplus^-1(dt)
+        "a_log": jnp.log(1.0 + 15.0 * ua),
+        "w_beta": dense((L, H, Hk), H),
+        # the output gate: a pair of rank R, a bias on the second
+        "w_ga": dense((L, H, R), H),
+        "w_gb": dense((L, R, I), R),
+        "b_g": dense((L, I), 1) * jnp.asarray(0.1, dtype),
+        "o_norm": jnp.ones((L, cfg.kda_head_dim), dtype),
+        "w_out": dense((L, I, H), I),
+    }
+
+
 def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     H, L = cfg.hidden_size, cfg.num_layers
     NHD, KVD = cfg.q_size, cfg.kv_size
@@ -313,6 +355,11 @@ def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             * (scale_dim ** -0.5)
         ).astype(dtype)
 
+    if cfg.homogeneous and cfg.attn_gate:
+        raise NotImplementedError(
+            f"{cfg.name}: an attention output gate (attn_gate) in a model "
+            "whose every layer is one block (the mixed walk builds it)"
+        )
     if not cfg.homogeneous:
         _check_mixed(cfg)
         params = {
@@ -647,7 +694,17 @@ def attention_mixer(
         kernel_mesh=kernel_mesh,
         live_window=live_window,
     )
-    attn = attn.reshape(B, T, cfg.q_size) @ _w(lp, "wo", x.dtype)
+    attn = attn.reshape(B, T, cfg.q_size)
+    if "w_attn_gate" in lp:
+        # an output gate a channel, from the layer's input
+        # (``ModelConfig.attn_gate``; a dense FFN's ``w_gate`` shares a
+        # homogeneous layer's dict, hence the longer name)
+        with jax.named_scope("gqa_gate"):
+            gate = jax.nn.sigmoid(
+                (x @ _w(lp, "w_attn_gate", x.dtype)).astype(jnp.float32)
+            )
+            attn = (attn.astype(jnp.float32) * gate).astype(x.dtype)
+    attn = attn @ _w(lp, "wo", x.dtype)
     if cfg.attn_bias:
         attn = attn + lp["bo"]
     return attn, (k, v)
@@ -818,6 +875,24 @@ def _indexer(cfg: ModelConfig, lp, x, c_q, positions, pages=None,
     )
 
 
+def causal_taps(ext: jax.Array, taps: jax.Array, T: int) -> jax.Array:
+    """The depthwise causal conv of a chunk in float32: ``ext`` [B, K-1+T,
+    C] = the K-1 columns before the chunk, then its T; ``taps`` [C, K];
+    ``out[:, t] = sum_j taps[:, j] * ext[:, t + j]``."""
+    taps = taps.astype(jnp.float32)
+    return sum(
+        ext[:, j : j + T].astype(jnp.float32) * taps[:, j]
+        for j in range(taps.shape[1])
+    )
+
+
+def columns_after(ext: jax.Array, n: jax.Array, K1: int) -> jax.Array:
+    """``ext[b, n[b] : n[b] + K1]``: the conv columns a row keeps after
+    ``n`` [B] of the chunk's tokens."""
+    cols = n[:, None] + jnp.arange(K1, dtype=jnp.int32)
+    return jnp.take_along_axis(ext, cols[..., None], axis=1)
+
+
 def conv_mixer(
     cfg: ModelConfig,
     lp: Dict[str, Any],          # one conv layer's params
@@ -843,11 +918,7 @@ def conv_mixer(
     bcz = x @ _w(lp, "w_in", x.dtype)
     g = bcz[..., :H] * bcz[..., 2 * H:]
     g_ext = jnp.concatenate([state.astype(g.dtype), g], axis=1)
-    taps = lp["w_conv"].astype(jnp.float32)               # [H, K]
-    c = sum(
-        g_ext[:, j : j + T].astype(jnp.float32) * taps[:, j]
-        for j in range(K)
-    )
+    c = causal_taps(g_ext, lp["w_conv"], T)               # taps [H, K]
     y = bcz[..., H : 2 * H] * c.astype(x.dtype)
     return y @ _w(lp, "w_out", x.dtype), g_ext
 
@@ -1074,8 +1145,7 @@ def mamba_mixer(
     z, xbc = zx[..., :I], zx[..., I:]
     dt = u @ _w(lp, "w_dt", u.dtype)
     ext = jnp.concatenate([past.conv[layer].astype(xbc.dtype), xbc], axis=1)
-    taps = lp["w_conv"].astype(f32)                           # [Cd, K]
-    c = sum(ext[:, j : j + T].astype(f32) * taps[:, j] for j in range(K))
+    c = causal_taps(ext, lp["w_conv"], T)                     # taps [Cd, K]
     xbc = jax.nn.silu(c + lp["b_conv"].astype(f32))           # [B, T, Cd] f32
     x, Bm, Cm = xbc[..., :I], xbc[..., I : I + G * N], xbc[..., I + G * N :]
     dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
@@ -1114,13 +1184,327 @@ def mamba_mixer(
         )
         y = y.reshape(Bsz, T, I)
         out["ssm_final"] = S.reshape(Bsz, N, I)
-        # the conv columns after valid_len tokens
-        cols = valid_len[:, None] + jnp.arange(K - 1, dtype=jnp.int32)
-        out["ssm_conv"] = jnp.take_along_axis(ext, cols[..., None], axis=1)
+        out["ssm_conv"] = columns_after(ext, valid_len, K - 1)
     y = y + per_channel(lp["d_skip"].astype(f32), P) * x
     y = grouped_rms(y * jax.nn.silu(z.astype(f32)), G, cfg.norm_eps)
     y = (y * lp["gate_norm"].astype(f32)).astype(u.dtype)
     return y @ _w(lp, "w_out", u.dtype), out
+
+
+# ---------------------------------------------------------------------------
+# Kimi Delta Attention (a gated delta rule)
+# ---------------------------------------------------------------------------
+
+
+def pending_buffers(cfg: ModelConfig, act) -> Tuple[Tuple[str, int, Any], ...]:
+    """``(name, width, dtype)`` of what a state layer's token leaves for
+    a chunk whose accepted length is decided later (``MixedChunk.ssm``'s
+    keys beside "conv"; the fused window's buffers; ``StatePast.window``
+    in this order), by the model's ``state_kind``."""
+    f32 = jnp.float32
+    if cfg.state_kind == "kda":
+        return (("g", cfg.kda_inner, f32), ("k", cfg.kda_inner, act),
+                ("u", cfg.kda_inner, act))
+    return (
+        ("dt", cfg.mamba_heads, f32), ("dA", cfg.mamba_heads, f32),
+        ("x", cfg.mamba_inner, act),
+        ("B", cfg.mamba_groups * cfg.mamba_state, act),
+    )
+
+
+def _pair_products(rows, ks: jax.Array, Ga: jax.Array, G: jax.Array):
+    """For each ``a`` of ``rows``: ``out[b, t, i, h] = sum_d a[b, t, h, d]
+    ks[b, i, h, d] exp(min(Ga[b, t, h, d] - G[b, i, h, d], 0))``: every
+    decay formed PAIRWISE (at most 1 for i <= t, which is all a caller
+    keeps), never a quotient of cumulative decays: the log-decay has no
+    lower bound. The decayed keys are formed once for all of ``rows``."""
+    seg = Ga[:, :, None] - G[:, None, :]                  # [B, T, W, H, dk]
+    decayed = ks[:, None, :] * jnp.exp(jnp.minimum(seg, 0.0))
+    return [jnp.sum(a[:, :, None] * decayed, axis=-1) for a in rows]
+
+
+def _unit_lower_solve(A: jax.Array, rhs: jax.Array) -> jax.Array:
+    """``(I + A)^-1 rhs`` for strictly lower ``A`` [B, t, i, H] and
+    ``rhs`` [B, t, H, dv]: one forward substitution a head."""
+    T = A.shape[1]
+    if T == 1:
+        return rhs
+    M = jnp.moveaxis(A, 3, 1) + jnp.eye(T, dtype=A.dtype)  # [B, H, t, i]
+    U = jax.scipy.linalg.solve_triangular(
+        M, jnp.moveaxis(rhs, 2, 1), lower=True, unit_diagonal=True
+    )
+    return jnp.moveaxis(U, 1, 2)
+
+
+def kda_chunked(
+    q: jax.Array,     # [B, T, H, dk] float32 (normed, scaled)
+    k: jax.Array,     # [B, T, H, dk] float32 (normed)
+    v: jax.Array,     # [B, T, H, dv] float32
+    g: jax.Array,     # [B, T, H, dk] float32 log-decay (<= 0; 0 past valid_len)
+    beta: jax.Array,  # [B, T, H]     float32 (0 past valid_len)
+    S0: jax.Array,    # [B, dk, H, dv] float32: the state before the chunk
+    chunk: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence ``S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1}
+    + beta_t k_t v_t^T``, ``o_t = S_t^T q_t`` over T tokens in chunks of
+    ``chunk``: with ``G`` the log-decay summed inside the chunk,
+
+        A[t, i] = beta_t sum_d k_t k_i exp(G_t - G_i)          i < t
+        U = (I + A)^-1 Diag(beta) (V - (K * exp G) S_0)
+        o_t = (q_t * exp G_t)^T S_0 + sum_{i<=t} (q_t . k_i)_decayed u_i
+        S = Diag(exp G_Q) S_0 + sum_i (k_i * exp(G_Q - G_i)) u_i^T
+
+    Returns ``(o [B, T, H, dv], S_T)``. A token with ``g`` 0 and ``beta``
+    0 neither decays nor feeds the state (its ``u`` is 0), so ``S_T`` is
+    the state after a row's valid tokens when its padding has both 0."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    Q = min(chunk, T)
+    pad = -T % Q
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q, k, v, g, beta)
+        )
+    nc = (T + pad) // Q
+
+    def split(a):  # [B, nc*Q, ...] -> [nc, B, Q, ...]
+        return jnp.moveaxis(a.reshape((B, nc, Q) + a.shape[2:]), 1, 0)
+
+    t_i = jnp.arange(Q)
+    before = (t_i[None, :] < t_i[:, None])[None, :, :, None]    # i < t
+    upto = (t_i[None, :] <= t_i[:, None])[None, :, :, None]     # i <= t
+
+    def step(S, c):
+        q, k, v, g, beta = c
+        G = jnp.cumsum(g, axis=1)                               # [B, Q, H, dk]
+        eG = jnp.exp(G)
+        kk, qk = _pair_products((k, q), k, G, G)
+        A = jnp.where(before, kk, 0.0) * beta[:, :, None, :]
+        kS = jnp.einsum("bthk,bkhv->bthv", k * eG, S, precision=_HI)
+        U = _unit_lower_solve(A, beta[..., None] * (v - kS))
+        qk = jnp.where(upto, qk, 0.0)
+        o = jnp.einsum("bthk,bkhv->bthv", q * eG, S, precision=_HI) + (
+            jnp.einsum("btih,bihv->bthv", qk, U, precision=_HI)
+        )
+        left = k * jnp.exp(G[:, -1:] - G)                       # [B, Q, H, dk]
+        S = jnp.moveaxis(eG[:, -1], 1, 2)[..., None] * S + jnp.einsum(
+            "bihk,bihv->bkhv", left, U, precision=_HI
+        )
+        return S, o
+
+    with jax.named_scope("kda_chunk"):
+        S, o = jax.lax.scan(
+            step, S0, (split(q), split(k), split(v), split(g), split(beta))
+        )
+    o = jnp.moveaxis(o, 0, 1).reshape(B, nc * Q, H, dv)
+    return o[:, :T], S
+
+
+def kda_state_read(
+    cfg: ModelConfig,
+    pool: jax.Array,   # [L_k, NS, dk, I]: every kda layer's slots,
+    layer,             # and this layer's index: read in place
+    slots: jax.Array,  # [B] int32
+    c: jax.Array,      # [B, T, I] float32: a row a token, a head's dk
+    *,
+    use_pallas: bool = False,
+    kernel_mesh=None,
+) -> jax.Array:
+    """``out[b, t, h, :] = S_h^T c[b, t, h, :]`` [B, T, I] float32 with
+    ``S`` the row's committed state: ``ops/pallas_ssm.ssm_state_read``'s
+    body with a HEAD a group (a head's state is the block's
+    ``[dk, dv]`` lane range) where the caller runs its kernels, no mesh
+    shards the call and the shapes pass its static gate; a gather of the
+    rows' slots and one product otherwise.
+    ``ops/lowering.kda_state_read_counts()`` says which a process
+    traced."""
+    B, T, I = c.shape
+    H = cfg.kda_heads
+    with jax.named_scope("kda_state_read"):
+        if (
+            use_pallas and kernel_mesh is None
+            and pallas_ssm.state_read_supported(pool, c, H)
+        ):
+            return pallas_ssm.ssm_state_read(
+                pool, layer, slots, c, groups=H,
+                counted=lowering.KDA_STATE_READ,
+            )
+        if use_pallas:
+            lowering.record_reference(lowering.KDA_STATE_READ)
+        S = pool[layer][slots].astype(jnp.float32)            # [B, dk, I]
+        return jnp.einsum(
+            "bthk,bkhv->bthv", c.reshape(B, T, H, -1),
+            S.reshape(B, S.shape[1], H, -1), precision=_HI,
+        ).reshape(B, T, I)
+
+
+def kda_pending(
+    cfg: ModelConfig,
+    pool: jax.Array,   # [L_k, NS, dk, I]
+    layer,
+    slots: jax.Array,  # [B] int32
+    fresh: jax.Array,  # [B] bool
+    q: jax.Array,      # [B, T, I] float32: the chunk's own tokens
+    v: jax.Array,      # [B, T, I]
+    beta: jax.Array,   # [B, T, H]
+    gs: jax.Array,     # [B, W, I] float32: the uncommitted tokens' log-
+    ks: jax.Array,     # [B, W, I] decays and keys, the chunk's own among
+    us: jax.Array,     # [B, W, I] them; ``u`` of the tokens BEFORE the chunk
+    q0,                # index among the W of the chunk's first token
+    *,
+    use_pallas: bool = False,
+    kernel_mesh=None,
+) -> Tuple[jax.Array, jax.Array]:
+    """``(o, u)`` [B, T, I] of a short chunk whose state is NOT advanced:
+    the committed state ``S_0`` is read once, where it lies, for the two
+    products ``(q * exp G)^T S_0`` and ``(k * exp G)^T S_0``
+    (``kda_state_read``), the uncommitted tokens before the chunk enter
+    through their ``(G, k, u)``, and the chunk's own ``u`` come from one
+    forward substitution. Tokens after a query are masked out, so
+    buffers may hold anything there."""
+    B, T, I = q.shape
+    W = gs.shape[1]
+    H = cfg.kda_heads
+
+    def heads(a):
+        return a.reshape(a.shape[:2] + (H, -1))
+
+    G = heads(jnp.cumsum(gs, axis=1))                         # [B, W, H, dk]
+    Gq = jax.lax.dynamic_slice_in_dim(G, q0, T, axis=1)       # [B, T, H, dk]
+    kq = jax.lax.dynamic_slice_in_dim(heads(ks), q0, T, axis=1)
+    qh, vh, eG = heads(q), heads(v), jnp.exp(Gq)
+    read = kda_state_read(
+        cfg, pool, layer, slots,
+        jnp.concatenate([qh * eG, kq * eG], axis=1).reshape(B, 2 * T, I),
+        use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+    )
+    read = heads(jnp.where(fresh[:, None, None], 0.0, read))
+    qS, kS = read[:, :T], read[:, T:]
+    kk, qk = _pair_products((kq, qh), heads(ks), Gq, G)       # [B, T, W, H]
+    at = jnp.arange(W, dtype=jnp.int32)
+    earlier = (at < q0)[None, None, :, None]
+    uh = heads(us.astype(jnp.float32))
+    r = vh - kS - jnp.einsum(
+        "btih,bihv->bthv", jnp.where(earlier, kk, 0.0), uh, precision=_HI
+    )
+    t_i = jnp.arange(T)
+    before = (t_i[None, :] < t_i[:, None])[None, :, :, None]
+    upto = (t_i[None, :] <= t_i[:, None])[None, :, :, None]
+    A = jnp.where(
+        before, jax.lax.dynamic_slice_in_dim(kk, q0, T, axis=2), 0.0
+    ) * beta[:, :, None, :]
+    U = _unit_lower_solve(A, beta[..., None] * r)             # [B, T, H, dv]
+    o = qS + jnp.einsum(
+        "btih,bihv->bthv", jnp.where(earlier, qk, 0.0), uh, precision=_HI
+    ) + jnp.einsum(
+        "btih,bihv->bthv",
+        jnp.where(upto, jax.lax.dynamic_slice_in_dim(qk, q0, T, axis=2), 0.0),
+        U, precision=_HI,
+    )
+    return o.reshape(B, T, I), U.reshape(B, T, I)
+
+
+def l2_norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def kda_mixer(
+    cfg: ModelConfig,
+    lp: Dict[str, Any],          # one kda layer's params
+    x: jax.Array,                # [B, T, H], normed
+    *,
+    valid_len: jax.Array,        # [B]
+    past: StatePast,
+    layer,                       # this layer's index among the kda layers
+    pending: bool,
+    use_pallas: bool = False,
+    kernel_mesh=None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One Kimi Delta Attention mixer over a chunk (``ModelConfig.kda_*``):
+
+        [q | k | v] = silu(conv1d(x W_qkv))    causal, depthwise, K taps
+        q = l2norm(q) / sqrt(dk) ;  k = l2norm(k)            a head
+        beta = kda_beta_scale * sigmoid(x W_beta)            a head
+        g = -exp(a_log) * softplus(x W_fa W_fb + dt_bias)    a CHANNEL
+        S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+        out = (RMSNorm_dk(o) * w_norm * sigmoid(x W_ga W_gb + b_g)) W_out
+
+    The recurrence, the norm and the gate in float32. Tokens past a
+    row's ``valid_len`` get ``g`` 0 and ``beta`` 0, so the state after the
+    chunk is the state after ``valid_len`` tokens. ``pending`` False
+    (prefill): the chunk form from the row's state (gathered from its
+    slot) to the state after the chunk, returned as "final". ``pending``
+    True (a decode step, a verify chunk, a fused window's step): the
+    state is read in place and not advanced (``kda_pending``); the
+    tokens' ``g``, ``k`` and solved ``u`` are returned and
+    ``kvcache.write_kv`` commits the accepted ones."""
+    Bsz, T = x.shape[:2]
+    I, Hk, dk = cfg.kda_inner, cfg.kda_heads, cfg.kda_head_dim
+    K = cfg.kda_conv
+    f32 = jnp.float32
+    lowering.record_kda("pending" if pending else "chunked")
+    with jax.named_scope("kda_conv"):
+        qkv = x @ _w(lp, "w_qkv", x.dtype)
+        ext = jnp.concatenate(
+            [past.conv[layer].astype(qkv.dtype), qkv], axis=1
+        )
+        qkv = jax.nn.silu(causal_taps(ext, lp["w_conv"], T))  # [B, T, 3I] f32
+
+    def heads(a):
+        return a.reshape(Bsz, T, Hk, dk)
+
+    q = l2_norm(heads(qkv[..., :I])) * dk ** -0.5
+    k = l2_norm(heads(qkv[..., I : 2 * I]))
+    v = qkv[..., 2 * I :]
+    live = jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
+    beta = cfg.kda_beta_scale * jax.nn.sigmoid(
+        (x @ _w(lp, "w_beta", x.dtype)).astype(f32)
+    )
+    beta = jnp.where(live[..., None], beta, 0.0)              # [B, T, Hk]
+    f = (x @ _w(lp, "w_fa", x.dtype)) @ _w(lp, "w_fb", x.dtype)
+    g = jax.nn.softplus(f.astype(f32) + lp["dt_bias"].astype(f32))
+    g = -g * per_channel(jnp.exp(lp["a_log"].astype(f32)), dk)
+    g = jnp.where(live[..., None], g, 0.0)                    # [B, T, I]
+    out: Dict[str, jax.Array] = {}
+    if pending:
+        out["ssm_conv"] = ext
+        kf = k.reshape(Bsz, T, I)
+        q0 = 0
+        gs, ks, us = g, kf, jnp.zeros_like(kf)
+        if past.window is not None:
+            # the window's earlier tokens, and this one in its place
+            *bufs, q0 = past.window
+            gs, ks, us = (b[layer].astype(f32) for b in bufs)
+            gs, ks = (
+                jax.lax.dynamic_update_slice_in_dim(b, a, q0, axis=1)
+                for b, a in ((gs, g), (ks, kf))
+            )
+        o, u = kda_pending(
+            cfg, past.ssm, layer, past.slots, past.fresh,
+            q.reshape(Bsz, T, I), v, beta, gs, ks, us, q0,
+            use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+        )
+        out.update(
+            ssm_g=g, ssm_k=kf.astype(x.dtype), ssm_u=u.astype(x.dtype)
+        )
+        o = heads(o)
+    else:
+        S0 = past.ssm[layer][past.slots].astype(f32)          # [B, dk, I]
+        S0 = jnp.where(past.fresh[:, None, None], 0.0, S0)
+        o, S = kda_chunked(
+            q, k, heads(v), heads(g), beta,
+            S0.reshape(Bsz, dk, Hk, dk), cfg.kda_chunk,
+        )
+        out["ssm_final"] = S.reshape(Bsz, dk, I)
+        out["ssm_conv"] = columns_after(ext, valid_len, K - 1)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
+    o = o * lp["o_norm"].astype(f32)
+    gate = (x @ _w(lp, "w_ga", x.dtype)) @ _w(lp, "w_gb", x.dtype)
+    gate = jax.nn.sigmoid(gate.astype(f32) + lp["b_g"].astype(f32))
+    y = (o.reshape(Bsz, T, I) * gate).astype(x.dtype)
+    return y @ _w(lp, "w_out", x.dtype), out
 
 
 def layer_apply(
@@ -1196,7 +1580,7 @@ def layer_apply(
 
 _MIXER_STACK = {
     "attention": "attn", "swa": "swa", "conv": "conv", "mamba": "mamba",
-    "mla": "mla",
+    "mla": "mla", "kda": "kda",
 }
 
 
@@ -1209,7 +1593,10 @@ def _check_mixed(cfg: ModelConfig) -> None:
         )
     unknown = set(cfg.mixers) - set(_MIXER_STACK) - {"none"}
     if unknown:
-        raise ValueError(f"{cfg.name}: unknown layer kinds {sorted(unknown)}")
+        raise ValueError(
+            f"{cfg.name}: unknown layer kinds {sorted(unknown)} (the walk "
+            f"has {sorted(_MIXER_STACK)})"
+        )
     if "moe" in cfg.ffns and not cfg.moe_experts:
         raise ValueError(f"{cfg.name}: a routed block needs moe_experts")
     if cfg.moe_first_expert + cfg.experts_held > cfg.moe_experts:
@@ -1228,6 +1615,21 @@ def _check_mixed(cfg: ModelConfig) -> None:
             f"{cfg.name}: mamba layers need mamba_conv >= 2, heads, a "
             "head_dim and a state size, and heads a multiple of groups"
         )
+    if cfg.num_kda_layers:
+        if cfg.num_mamba_layers:
+            # one slot pool, one description of a state layer
+            # (``ModelConfig.state_kind``)
+            raise NotImplementedError(
+                f"{cfg.name}: delta-rule (kda) layers beside mamba layers "
+                "(a slot pool of another shape)"
+            )
+        if cfg.kda_conv < 2 or min(
+            cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank, cfg.kda_chunk
+        ) < 1:
+            raise ValueError(
+                f"{cfg.name}: kda layers need kda_conv >= 2, kda_heads, a "
+                "kda_head_dim, a kda_rank and a kda_chunk"
+            )
     if cfg.num_window_layers and cfg.sliding_window < 1:
         raise ValueError(f"{cfg.name}: swa layers need a sliding_window")
     if cfg.num_latent_layers:
@@ -1304,9 +1706,12 @@ def layer_groups(cfg: ModelConfig) -> List[Tuple[int, int, int]]:
     return groups
 
 
-# ``mamba_mixer``'s outputs as the walk stacks them (MixedChunk.ssm's
+# ``mamba_mixer``'s and ``kda_mixer``'s outputs as the walk stacks them (MixedChunk.ssm's
 # keys, prefixed)
-_SSM_KEYS = ("ssm_conv", "ssm_final", "ssm_dt", "ssm_dA", "ssm_x", "ssm_B")
+_SSM_KEYS = (
+    "ssm_conv", "ssm_final", "ssm_dt", "ssm_dA", "ssm_x", "ssm_B",
+    "ssm_g", "ssm_k", "ssm_u",
+)
 
 
 def _index_in_kind(kinds) -> List[int]:
@@ -1345,18 +1750,18 @@ def _mixed_trunk(
     B, T = h.shape[:2]
     K1 = cfg.conv_state_len
     r = cfg.residual_multiplier
-    if cfg.num_mamba_layers and state_past is None:
+    if cfg.num_state_layers and state_past is None:
         # no cache: every row starts a sequence, in the garbage slot
         state_past = StatePast(
             ssm=jnp.zeros(
-                (cfg.num_mamba_layers, 1, cfg.mamba_state, cfg.mamba_inner),
+                (cfg.num_state_layers, 1, cfg.state_rows, cfg.state_inner),
                 h.dtype,
             ),
             slots=jnp.zeros((B,), jnp.int32),
             fresh=jnp.ones((B,), bool),
             conv=jnp.zeros(
-                (cfg.num_mamba_layers, B, cfg.mamba_conv_len,
-                 cfg.mamba_conv_dim), h.dtype,
+                (cfg.num_state_layers, B, cfg.state_conv_len,
+                 cfg.state_conv_dim), h.dtype,
             ),
         )
     if cfg.num_conv_layers and conv_state is None:
@@ -1428,6 +1833,14 @@ def _mixed_trunk(
         elif mixer == "mamba":
             with jax.named_scope("mamba_mixer"):
                 y, ssm = mamba_mixer(
+                    cfg, lp, x, valid_len=valid_len, past=state_past,
+                    layer=m_idx, pending=ssm_pending,
+                    use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+                )
+                out.update(ssm)
+        elif mixer == "kda":
+            with jax.named_scope("kda_mixer"):
+                y, ssm = kda_mixer(
                     cfg, lp, x, valid_len=valid_len, past=state_past,
                     layer=m_idx, pending=ssm_pending,
                     use_pallas=use_pallas, kernel_mesh=kernel_mesh,

@@ -36,9 +36,18 @@ GROUPED = "grouped_matmul"
 #: likewise (``ssm_state_read_counts``): a Mamba-2 model's alone
 SSM_STATE_READ = "ssm_state_read"
 
+#: likewise (``kda_state_read_counts`` / ``kda_state_commit_counts``): a
+#: delta-rule model's alone. The read is ``ssm_state_read``'s body with a
+#: head a group, counted under its own name
+KDA_STATE_READ = "kda_state_read"
+KDA_STATE_COMMIT = "kda_state_commit"
+
 _lock = threading.Lock()
 _counts: Dict[str, Dict[str, int]] = {
-    k: dict.fromkeys(PATHS, 0) for k in KERNELS + (GROUPED, SSM_STATE_READ)
+    k: dict.fromkeys(PATHS, 0)
+    for k in KERNELS + (
+        GROUPED, SSM_STATE_READ, KDA_STATE_READ, KDA_STATE_COMMIT
+    )
 }
 _xla_decode = 0
 #: traces of a routed layer's combine (ops/moe.combine), by path
@@ -52,6 +61,9 @@ _latent: Dict[str, int] = dict.fromkeys(LATENT_FORMS, 0)
 #: (ops/sparse_attention.py), by how the selection is applied
 SPARSE_FORMS = ("gathered", "masked")
 _sparse: Dict[str, int] = dict.fromkeys(SPARSE_FORMS, 0)
+#: traces of a delta-rule layer (models/transformer.kda_mixer), by form
+KDA_FORMS = ("chunked", "pending")
+_kda: Dict[str, int] = dict.fromkeys(KDA_FORMS, 0)
 
 
 def record_kernel(kernel: str, *, interpret: bool) -> None:
@@ -165,6 +177,43 @@ def ssm_state_read_counts() -> Dict[str, int]:
     never reads such a state."""
     with _lock:
         return dict(_counts[SSM_STATE_READ])
+
+
+def record_kda(form: str) -> None:
+    """Called from ``models/transformer.kda_mixer``'s traced body."""
+    with _lock:
+        _kda[form] += 1
+
+
+def kda_counts() -> Dict[str, int]:
+    """Traces of a delta-rule (kda) layer, by form: ``chunked`` (a
+    prefill: the chunk form from the slot's state to its end) and
+    ``pending`` (a decode step, a verify chunk, a fused window's step:
+    the committed state read in place and not advanced)."""
+    with _lock:
+        return dict(_kda)
+
+
+def kda_state_read_counts() -> Dict[str, int]:
+    """Traces of a delta-rule layer's two products against its committed
+    state in a chunk that does not advance it
+    (``models/transformer.kda_state_read``), by path:
+    ``ops/pallas_ssm.ssm_state_read``'s body with a head a group
+    (``lowered`` / ``interpreted``) and a ``use_pallas=True`` call that
+    stays on the XLA expression (``reference``). A count of its own for
+    the reason ``grouped_matmul_counts`` has one."""
+    with _lock:
+        return dict(_counts[KDA_STATE_READ])
+
+
+def kda_state_commit_counts() -> Dict[str, int]:
+    """Traces of a delta-rule layer's commit of a chunk's accepted
+    tokens from their ``(g, k, u)`` (``engine/kvcache.write_state``), by
+    path: ``ops/pallas_ssm.kda_state_commit``'s body (a row's slot
+    streamed in and out once, in place) and a ``use_pallas=True`` call
+    that stays on the gather, product and scatter (``reference``)."""
+    with _lock:
+        return dict(_counts[KDA_STATE_COMMIT])
 
 
 def snapshot() -> Dict[str, Dict[str, int]]:

@@ -111,7 +111,7 @@ def _kernel(layer, slots, c_ref, s_ref, o_ref, *, groups, form):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("groups", "form", "interpret")
+    jax.jit, static_argnames=("groups", "form", "interpret", "counted")
 )
 def ssm_state_read(
     pool: jax.Array,    # [L_m, NS, N, I]: every mamba layer's slots
@@ -122,12 +122,15 @@ def ssm_state_read(
     groups: int,
     form: "str | None" = None,
     interpret: bool = False,
+    counted: str = lowering.SSM_STATE_READ,
 ) -> jax.Array:
     """Returns ``[B, T, I]`` float32 with ``out[b, t, i] = sum_n
     pool[layer, slots[b], n, i] * c[b, t, g(i) * N + n]``, ``g(i)`` the
     group of ``I / groups`` channels that holds ``i``. ``form`` None
-    picks by the pool's dtype (module docstring)."""
-    lowering.record_kernel(lowering.SSM_STATE_READ, interpret=interpret)
+    picks by the pool's dtype (module docstring). ``counted``: the name
+    the trace is counted and the kernel is called under (a delta-rule
+    layer's read, a head a group: ``lowering.KDA_STATE_READ``)."""
+    lowering.record_kernel(counted, interpret=interpret)
     _, _, N, I = pool.shape
     B, T, _ = c.shape
     if form is None:
@@ -158,9 +161,106 @@ def ssm_state_read(
             bytes_accessed=B * (block + T * (groups * N + I) * 4),
             transcendentals=0,
         ),
-        name="ssm_state_read",
+        name=counted,
         interpret=interpret,
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         slots.astype(jnp.int32), c, pool,
+    )
+
+
+# ---------------------------------------------------------------------------
+# A delta-rule layer's commit of a chunk's accepted tokens
+# ---------------------------------------------------------------------------
+
+
+def state_commit_supported(pool: jax.Array) -> bool:
+    """Static gate of ``kda_state_commit``: a bf16 or float32 pool
+    ``[L, NS, dk, H * dk]`` whose heads are whole 128-lane tiles (so
+    ``dk`` is whole sublane packs of either dtype too)."""
+    if pool.ndim != 4 or pool.dtype not in (jnp.bfloat16, jnp.float32):
+        return False
+    N, I = pool.shape[-2:]
+    return N % 128 == 0 and I % N == 0
+
+
+def _commit_kernel(layer, slots, d_ref, k_ref, u_ref, s_ref, o_ref):
+    del layer, slots  # the index maps'
+    N, I = s_ref.shape
+    W = k_ref.shape[0]
+    for h in range(I // N):
+        ch = slice(h * N, (h + 1) * N)
+        # the head's decay and decayed keys DOWN the sublanes: [N, 1 + W]
+        cols = jnp.concatenate([d_ref[:, ch], k_ref[:, ch]], axis=0).T
+        new = s_ref[:, ch].astype(jnp.float32) * cols[:, 0:1]
+        for w in range(W):
+            new = new + cols[:, 1 + w : 2 + w] * u_ref[w : w + 1, ch]
+        o_ref[:, ch] = new.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_state_commit(
+    pool: jax.Array,    # [L_k, NS, dk, I]: every kda layer's slots
+    layer,              # scalar int32: the layer advanced
+    slots: jax.Array,   # [B] int32: each row's slot (0: the garbage slot)
+    decay: jax.Array,   # [B, I] float32: exp(G_n) a channel of the KEY axis
+    coef: jax.Array,    # [B, W, I] float32: k_i * exp(G_n - G_i), 0 past n
+    u: jax.Array,       # [B, W, I] float32
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """``pool[layer, slots[b]]`` <- ``Diag(decay_b) S + sum_w coef_b,w
+    u_b,w^T`` a head, in place: a grid over ROWS, the row's slot
+    ``[dk, I]`` streamed in through the BlockSpec pipeline and out to the
+    same block (the pool is aliased to the result), every product and sum
+    float32 on the VPU (a head's ``[dk, W]`` keys times ``[W, dv]``
+    updates: W outer products, exact in float32; the MXU's float32
+    passes are not). Rows whose slot is 0 write the garbage slot. A
+    row's ``decay`` and ``coef`` are indexed ``[h * dk + k]``: the head's
+    KEY axis, which the kernel turns down the sublanes."""
+    lowering.record_kernel(lowering.KDA_STATE_COMMIT, interpret=interpret)
+    _, _, N, I = pool.shape
+    B, W, _ = coef.shape
+    pad = -W % 8
+    if pad:  # whole sublane tiles of float32
+        coef, u = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (coef, u))
+        W += pad
+    block = N * I * pool.dtype.itemsize
+
+    def row(b, l, s):
+        return (b, 0, 0)
+
+    def slot(b, l, s):
+        return (l[0], s[b], 0, 0)
+
+    return pl.pallas_call(
+        _commit_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((None, 1, I), row),
+                pl.BlockSpec((None, W, I), row),
+                pl.BlockSpec((None, W, I), row),
+                pl.BlockSpec((None, None, N, I), slot),
+            ],
+            out_specs=pl.BlockSpec((None, None, N, I), slot),
+        ),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        # operands: layer, slots, decay, coef, u, pool
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(4 * block + (16 << 20), 32 << 20),
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * B * (W + 1) * N * I,
+            bytes_accessed=B * (2 * block + (2 * W + 1) * I * 4),
+            transcendentals=0,
+        ),
+        name="kda_state_commit",
+        interpret=interpret,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
+        decay[:, None, :], coef, u, pool,
     )

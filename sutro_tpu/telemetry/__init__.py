@@ -357,7 +357,7 @@ KV_PAGES_NEEDED_TOTAL = REGISTRY.counter(
 STATE_COMMITS_TOTAL = REGISTRY.counter(
     "sutro_state_commits_total",
     "Dispatches that committed per-sequence state (conv columns a "
-    "page, or a mamba model's slot) beside K/V, by the path that "
+    "page, or a slot of matrix state) beside K/V, by the path that "
     "committed it",
     labels=("path",),  # prefill | chunk | window | verify | resume
     unit="dispatches",
@@ -440,9 +440,37 @@ KV_WINDOW_PAGES_WHOLE_TOTAL = REGISTRY.counter(
     "of its K/V a window layer keeps",
     unit="pages",
 )
+KDA_DISPATCHES_TOTAL = REGISTRY.counter(
+    "sutro_kda_dispatches_total",
+    "Dispatches of a model with delta-rule (kda) layers, by the form "
+    "those layers took: chunked (a prefill: from the slot's state to "
+    "its end) or pending (a decode step, a window, a verify chunk: the "
+    "committed state read in place and not advanced)",
+    labels=("form",),  # chunked | pending
+    unit="dispatches",
+    max_series=4,
+)
+KDA_STATE_BYTES_TOTAL = REGISTRY.counter(
+    "sutro_kda_state_bytes_total",
+    "Bytes of delta-rule state the dispatches' programs moved between "
+    "HBM and the chip, by what moved them: read (a step's two products "
+    "against a row's slot: the slot once under the kernel, gathered "
+    "first on the XLA path) and commit (a row's slot in and out once "
+    "under the kernel; gathered, advanced and scattered on the XLA "
+    "path; a prefill's final state written)",
+    labels=("op",),  # read | commit
+    unit="bytes",
+    max_series=4,
+)
+KDA_STATE_BYTES_NEEDED_TOTAL = REGISTRY.counter(
+    "sutro_kda_state_bytes_needed_total",
+    "Bytes of delta-rule state the same dispatches had to move: a "
+    "row's slot read once a step and written once a commit",
+    unit="bytes",
+)
 STATE_SLOTS = REGISTRY.gauge(
     "sutro_state_slots",
-    "Slots of the mamba state pool (one a live sequence; the garbage "
+    "Slots of the state pool (one a live sequence; the garbage "
     "slot not counted): in use, and in all",
     labels=("state",),  # in_use | total
     unit="slots",
