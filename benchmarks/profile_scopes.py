@@ -29,6 +29,10 @@ window), by scope, and the largest ops of each scope; then which paths
 the process traced (``ops/lowering.py``: the kernels, the grouped
 product, a routed layer's combine).
 
+``--top N`` lists N ops a scope (4), ``--by-name`` each under its own HLO
+name (``copy.1227``) and not summed by kind: the name to look up in the
+optimized HLO.
+
 What ``perfbench/trace_reduce.py`` cannot say: it keys an op by its own
 name and drops ``op_name`` (PERF.md section 7 row 19). Fails without a
 TPU unless ``--cpu`` (the rehearsal configuration, to debug the flow).
@@ -90,6 +94,11 @@ def main() -> None:
     ap.add_argument("--prompts", type=int, nargs="+", default=[1100, 1800, 2600, 3400])
     ap.add_argument("--windows", type=int, default=4)
     ap.add_argument("--masked-steps", type=int, default=0)
+    ap.add_argument("--top", type=int, default=4,
+                    help="ops listed a scope, the costliest first")
+    ap.add_argument("--by-name", action="store_true",
+                    help="list ops under their own HLO names (copy.1227), "
+                    "not summed by kind (copy)")
     ap.add_argument("--cpu", action="store_true")
     args = ap.parse_args()
 
@@ -209,7 +218,8 @@ def main() -> None:
                     (ops_[short] for mod, ops_ in from_dump.items()
                      if mod in module and short in ops_), "other",
                 )
-            by[module][scope][trace_reduce.op_key(short)] += self_ns
+            key = short if args.by_name else trace_reduce.op_key(short)
+            by[module][scope][key] += self_ns
         for module, scopes in sorted(by.items()):
             n = sum(1 for m, _lo, _hi in runs if m == module)
             per = n * (steps if "decode_multi" in module else 1)
@@ -225,7 +235,7 @@ def main() -> None:
                 },
                 "top_ops_ms": {
                     s: {k: round(v / per / 1e6, 3) for k, v in sorted(
-                        o.items(), key=lambda kv: -kv[1])[:4]}
+                        o.items(), key=lambda kv: -kv[1])[:args.top]}
                     for s, o in scopes.items()
                 },
                 "ops_scoped_from_dump": unscoped,

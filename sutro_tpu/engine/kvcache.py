@@ -125,8 +125,8 @@ import numpy as np
 
 from ..models.configs import ModelConfig
 from ..models.transformer import (
-    MixedChunk, StatePast, columns_after, group_channels, over_state,
-    per_channel,
+    MixedChunk, StatePast, chunk_tokens, columns_after, group_channels,
+    over_state, per_channel,
 )
 from .config import EngineConfig
 
@@ -676,7 +676,7 @@ def read_state(
 
 def _advance_kda(
     ssm: jax.Array,            # [L_k, NS, dk, I]: the pool
-    chunk: dict,               # "g" f32, "k", "u" [L_k, B, W, I]; "conv"
+    chunk: dict,               # "g" f32, "k", "u", "conv": MixedChunk.ssm
     slots: jax.Array,          # [B] int32 (0: the row does not move)
     fresh: jax.Array,          # [B] bool: the row's state before is 0
     n: jax.Array,              # [B] int32: tokens accepted
@@ -695,15 +695,19 @@ def _advance_kda(
 
     f32 = jnp.float32
     L, NS, dk, I = ssm.shape
-    B, W = chunk["g"].shape[1:3]
+    B = slots.shape[0]
     H = I // dk
-    took = (jnp.arange(W, dtype=jnp.int32)[None, :] < n[:, None])[..., None]
     kernel = use_pallas and pallas_ssm.state_commit_supported(ssm)
     if use_pallas and not kernel:
         lowering.record_reference(lowering.KDA_STATE_COMMIT)
 
-    def layer(pool, xs):
-        l, g, k, u, ext = xs
+    def layer(pool, l):
+        g, k, u, ext = (
+            chunk_tokens(chunk[name], l, L, B)
+            for name in ("g", "k", "u", "conv")
+        )
+        W = g.shape[1]
+        took = (jnp.arange(W, dtype=jnp.int32)[None, :] < n[:, None])[..., None]
         g = jnp.where(took, g.astype(f32), 0.0)                    # [B, W, I]
         G = jnp.cumsum(g, axis=1)
         total = G[:, -1]                                           # [B, I]
@@ -730,11 +734,7 @@ def _advance_kda(
                 )
         return pool, columns_after(ext, n, K1)
 
-    return jax.lax.scan(
-        layer, ssm,
-        (jnp.arange(L, dtype=jnp.int32), chunk["g"], chunk["k"],
-         chunk["u"], chunk["conv"]),
-    )
+    return jax.lax.scan(layer, ssm, jnp.arange(L, dtype=jnp.int32))
 
 
 def write_state(
@@ -754,14 +754,18 @@ def write_state(
     elementwise pass, in place: ``S <- decay S + sum_t c_t x_t B_t^T``
     with decay 1 and no tokens for a slot no row advances, so the state
     is neither gathered nor scattered. A delta-rule chunk's ``g, k, u``
-    advance the rows' slots a row at a time (``_advance_kda``)."""
+    advance the rows' slots a row at a time (``_advance_kda``). A fused
+    window hands its tokens over as its scan carried them
+    (``transformer.window_buffer``) and each layer's are read from there
+    where they lie (``transformer.chunk_tokens``): no transposed copy of
+    the window's buffers is made for the commit."""
     slots = state_slots_at(cache, page_table, start)
     moved = valid_len > 0
     slots = jnp.where(moved, slots, 0)
     ssm, B = cache.ssm, slots.shape[0]
     L, NS = ssm.shape[:2]
     f32 = jnp.float32
-    ext = chunk["conv"]                                   # [L, B, K-1+T', Cd]
+    ext = chunk["conv"]                # [L, B, K-1+T', Cd], or a window's
     K1 = cache.ssm_conv.shape[1] // (L * ext.shape[-1])
     if "u" in chunk:
         ssm, cols = _advance_kda(
@@ -776,11 +780,10 @@ def write_state(
         cols = ext                          # the columns after the chunk
     else:
         n = valid_len
-        W, Hm = chunk["dt"].shape[2:]
+        Hm = chunk["dt"].shape[-1]
         I = chunk["x"].shape[-1]
         P = I // Hm
         G = chunk["B"].shape[-1] // ssm.shape[2]
-        took = jnp.arange(W, dtype=jnp.int32)[None, :] < n[:, None]   # [B, W]
         # slot-major: each slot takes ITS row's tokens, or none
         row = jnp.zeros((NS,), jnp.int32).at[slots].set(
             jnp.arange(B, dtype=jnp.int32)
@@ -789,10 +792,15 @@ def write_state(
         fresh = start <= 0
         after = (n[:, None] + jnp.arange(K1, dtype=jnp.int32))[..., None]
 
-        def layer(pool, xs):
+        def layer(pool, l):
             # one layer at a time: the temporaries are a layer's, and
             # the pool is updated where it lies
-            l, dt, dA, x, Bm, ext = xs
+            dt, dA, x, Bm, ext = (
+                chunk_tokens(chunk[name], l, L, B)
+                for name in ("dt", "dA", "x", "B", "conv")
+            )
+            W = dt.shape[1]
+            took = jnp.arange(W, dtype=jnp.int32)[None, :] < n[:, None]  # [B, W]
             dt, dA = dt.astype(f32), dA.astype(f32)
             cum = jnp.cumsum(dA, axis=1)                              # [B, W, Hm]
             total = jnp.sum(jnp.where(took[..., None], dA, 0.0), axis=1)
@@ -823,9 +831,7 @@ def write_state(
             return pool, jnp.take_along_axis(ext, after, axis=1)
 
         ssm, cols = jax.lax.scan(
-            layer, ssm,
-            (jnp.arange(L, dtype=jnp.int32), chunk["dt"], chunk["dA"],
-             chunk["x"], chunk["B"], ext),
+            layer, ssm, jnp.arange(L, dtype=jnp.int32)
         )
     rows = cols.transpose(1, 0, 2, 3).reshape(B, -1)      # [B, L * K1 * Cd]
     ssm_conv = cache.ssm_conv.at[slots].set(rows.astype(cache.ssm_conv.dtype))

@@ -1633,7 +1633,15 @@ class ModelRunner:
         layers' state before the window followed by each step's gated
         input (carried through the scan like the K/V window, so ANY
         accepted prefix commits by ``write_kv``), and the routing
-        counts of each step ([steps, 6], ``_route_stats``).
+        counts of each step ([steps, 6], ``_route_stats``). The state
+        layers' uncommitted tokens (conv columns, and what
+        ``transformer.pending_buffers`` names) ride in STEP-MAJOR
+        buffers (``transformer.window_buffer``): this scan owns the
+        writes, one dense slab of each buffer after a step
+        (``window_put``) and no copy of any; a layer's mixer reads the
+        earlier steps' slabs where they lie and keeps the step's own
+        token to itself (``StatePast.window``); ``MixedChunk.ssm`` hands
+        the buffers to ``write_kv`` as they are.
 
         ``allowed0`` ([B, V] bool, optional) masks the FIRST step's
         logits only: a row whose previous window rejected a token takes
@@ -1676,21 +1684,29 @@ class ModelRunner:
         pending = ()
         if self.mcfg.num_state_layers:
             # the pool is a constant of the scan, like the pages: the
-            # window's tokens ride in buffers and write_kv commits them
+            # window's tokens ride in buffers and write_kv commits them.
+            # The buffers are STEP-MAJOR (``transformer.window_buffer``):
+            # a step writes its token of every layer as one dense slab
+            # of each, in place, and a layer reads the earlier steps'
+            # slabs where they lie (``StatePast.window``)
             past = self._state_past(cache, page_table, past_len)
             m = self.mcfg
+            Lm = m.num_state_layers
             pending = transformer.pending_buffers(
                 m, jnp.dtype(self.ecfg.activation_dtype)
             )
             ws0 = {
-                "conv": window_of(past.conv),
-                **{
-                    name: jnp.zeros(
-                        (m.num_state_layers, B, steps, width), dt
-                    )
-                    for name, width, dt in pending
-                },
+                name: transformer.window_buffer(
+                    steps + (K1 if name == "conv" else 0), Lm, B, width, dt
+                )
+                for name, width, dt in (
+                    ("conv", m.state_conv_dim, past.conv.dtype),
+                ) + pending
             }
+            for j in range(K1):  # the columns before the window
+                ws0["conv"] = transformer.window_put(
+                    ws0["conv"], j, past.conv[:, :, j]
+                )
 
         def body(carry, step_idx):
             wk, wv, wc, ws, last = carry
@@ -1701,10 +1717,7 @@ class ModelRunner:
                 conv_state=None if wc is None
                 else jax.lax.dynamic_slice_in_dim(wc, step_idx, K1, axis=2),
                 state_past=None if ws is None else dataclasses.replace(
-                    past,
-                    conv=jax.lax.dynamic_slice_in_dim(
-                        ws["conv"], step_idx, K1, axis=2
-                    ),
+                    past, conv=ws["conv"],
                     window=tuple(ws[n] for n, _, _ in pending) + (step_idx,),
                 ),
             )
@@ -1716,11 +1729,12 @@ class ModelRunner:
                         (0, 0, K1 + step_idx, 0),
                     )
                 if ws is not None:
+                    # the step's token of every state layer ([L_m, B, 1,
+                    # width]) as ONE slab at the step's place
                     ws = {
-                        name: jax.lax.dynamic_update_slice(
-                            buf,
-                            k.ssm[name][:, :, -1:].astype(buf.dtype),
-                            (0, 0, step_idx + (K1 if name == "conv" else 0), 0),
+                        name: transformer.window_put(
+                            buf, step_idx + (K1 if name == "conv" else 0),
+                            k.ssm[name][:, :, 0],
                         )
                         for name, buf in ws.items()
                     }
@@ -1761,6 +1775,9 @@ class ModelRunner:
             jnp.arange(steps, dtype=jnp.int32),
         )
         if mixed:
+            # ``ws`` goes to ``kvcache.write_state`` as the scan carried it:
+            # the commit reads each layer's tokens where they lie
+            # (``MixedChunk.ssm``), no transposed copy is made for it
             wk = MixedChunk(k=wk, conv=wc, route=route, ssm=ws)
         return toks, logps, wk, wv
 

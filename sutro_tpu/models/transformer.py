@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -86,7 +87,9 @@ class MixedChunk:
     # (``kda_mixer``) the same keys "conv" and "final", or the tokens'
     # log-decays "g" [L_k, B, T, I] float32, keys "k" and solved
     # updates "u" [L_k, B, T, I]: ``S_n = Diag(exp G_n) S_0 + sum_{i<=n}
-    # (k_i * exp(G_n - G_i)) u_i^T`` for any n
+    # (k_i * exp(G_n - G_i)) u_i^T`` for any n. A fused window hands
+    # every key over as its scan carried it instead (``window_buffer``,
+    # 3-D, step-major): ``chunk_tokens`` reads a layer's from either
     ssm: Optional[Dict[str, jax.Array]] = None
 
 
@@ -103,9 +106,12 @@ class StatePast:
     fresh: jax.Array    # [B] bool: the row starts a sequence (state 0)
     conv: jax.Array     # [L_m, B, K-1, Cd]: conv columns before the chunk
     # inside a fused window: the window's earlier tokens, not yet
-    # committed, as (dt, dA [L_m, B, W, Hm], x [L_m, B, W, I],
-    # B [L_m, B, W, G*N], step index); for "kda" layers (g, k, u
-    # [L_k, B, W, I], step index): ``pending_buffers`` names them
+    # committed, as the scan carries them (``window_buffer``), then the
+    # step's index q0: (dt, dA, x, B, q0); for "kda" layers (g, k, u,
+    # q0): ``pending_buffers`` names them, widths and dtypes. Rows at
+    # and past q0 hold anything; the step's OWN token is not among them
+    # (the mixer has it in registers). ``conv`` is then the window's
+    # columns the same way: the K-1 before the window, then one a step
     window: Optional[Tuple[jax.Array, ...]] = None
 
 
@@ -893,6 +899,99 @@ def columns_after(ext: jax.Array, n: jax.Array, K1: int) -> jax.Array:
     return jnp.take_along_axis(ext, cols[..., None], axis=1)
 
 
+#: batch rows that a fused window's buffers keep together as an axis of
+#: their own: a tile of the TPU's HBM layout is 8 rows of float32 and 16
+#: of bfloat16, so with 16 the tile is a part of the SHAPE and no layout
+#: assignment can put the step axis into it
+WINDOW_ROWS = 16
+
+
+def window_buffer(
+    tokens: int, layers: int, batch: int, width: int, dtype
+) -> jax.Array:
+    """Zeros for ``tokens`` tokens of ``layers`` state layers as a fused
+    window's scan carries them: STEP-MAJOR, ``[tokens * layers * batch /
+    R, R, width]`` with ``R = gcd(batch, WINDOW_ROWS)`` rows of a batch
+    together: token ``i`` of layer ``l`` is the ``batch / R`` leading
+    rows from ``(i * layers + l) * batch / R`` (``window_slab``), a
+    step's token of every layer one dense slab of ``layers`` such
+    (``window_put``). The step, layer and batch-tile axes are carried as
+    ONE: XLA assigns a carried buffer's physical layout from what reads
+    it after the scan, and an axis of its own for the step has been put
+    among a tile's rows, where a step's write touches every tile of the
+    buffer."""
+    R = math.gcd(batch, WINDOW_ROWS)
+    return jnp.zeros((tokens * layers * batch // R, R, width), dtype)
+
+
+def window_put(buf: jax.Array, step, rows: jax.Array) -> jax.Array:
+    """Token ``step`` of every layer (``rows`` [L, B, width]) into a
+    ``window_buffer``: one dense slab, in place."""
+    R = buf.shape[1]
+    rows = rows.astype(buf.dtype).reshape((-1, R, rows.shape[-1]))
+    return jax.lax.dynamic_update_slice(
+        buf, rows, (step * rows.shape[0], 0, 0)
+    )
+
+
+def window_slab(buf: jax.Array, step, layer, layers: int, batch: int):
+    """Token ``step`` of state layer ``layer`` in a ``window_buffer``:
+    one dense slab ``[batch / R, R, width]``, sliced where it lies and
+    left in the buffer's own rows-of-R shape (``rows_as``): a reader
+    brings its own token's few arrays to that shape, not each slab to
+    ``[batch, width]``."""
+    n = batch // buf.shape[1]
+    return jax.lax.dynamic_slice_in_dim(
+        buf, (step * layers + layer) * n, n, axis=0
+    )
+
+
+def rows_as(buf: jax.Array, a: jax.Array) -> jax.Array:
+    """``a`` [B, ...] with its rows grouped as a ``window_buffer``'s are:
+    [B / R, R, ...]."""
+    R = buf.shape[1]
+    return a.reshape((a.shape[0] // R, R) + a.shape[1:])
+
+
+def chunk_tokens(a: jax.Array, layer, layers: int, batch: int) -> jax.Array:
+    """One state layer's tokens ``[B, W, width]`` from what
+    ``MixedChunk.ssm`` holds of a chunk: the layers' stack [L, B, W,
+    width] (a verify chunk, a single step), or a fused window's buffer
+    as its scan carried it (``window_buffer``, 3-D), read a slab a
+    token where it lies."""
+    if a.ndim == 4:
+        return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+    slabs = jnp.stack([
+        window_slab(a, i, layer, layers, batch)
+        for i in range(a.size // (layers * batch * a.shape[-1]))
+    ])                                                    # [W, B / R, R, width]
+    return jnp.swapaxes(slabs.reshape(-1, batch, a.shape[-1]), 0, 1)
+
+
+def window_taps(
+    cols: jax.Array,   # a fused window's conv columns (``window_buffer``)
+    layer, layers: int,
+    q0,                # the step: its K-1 columns before are q0..q0+K-2
+    own: jax.Array,    # [B, 1, C]: the step's own column
+    taps: jax.Array,   # [C, K]
+) -> jax.Array:
+    """``causal_taps`` of a fused window's step, float32, in the window's
+    own rows-of-R shape ``[B / R, R, C]`` (``rows_as``; the caller
+    reshapes once it has split the channels: a ``[B, 1, C]`` made by a
+    reshape is laid a row a tile on the TPU and everything after it
+    runs an eighth full): a weighted sum of K columns, each a ``[B, C]``
+    slab; the K-1 before the token are read from the window where they
+    lie (no slice of it is re-laid), its own is the last."""
+    K, B = taps.shape[1], own.shape[0]
+    slabs = [
+        window_slab(cols, q0 + j, layer, layers, B) for j in range(K - 1)
+    ] + [rows_as(cols, own[:, 0])]
+    taps = taps.astype(jnp.float32)
+    return sum(
+        c.astype(jnp.float32) * taps[:, j] for j, c in enumerate(slabs)
+    )
+
+
 def conv_mixer(
     cfg: ModelConfig,
     lp: Dict[str, Any],          # one conv layer's params
@@ -1008,6 +1107,7 @@ def ssd_pending(
     Cq: jax.Array,     # [B, T, G*N]: C of the chunk's own tokens
     q0,                # index among the W of the chunk's first token
     *,
+    window=None,       # a fused window's earlier tokens, step-major
     use_pallas: bool = False,
     kernel_mesh=None,
 ) -> jax.Array:
@@ -1017,6 +1117,18 @@ def ssd_pending(
     gather of the state, no write), and the uncommitted tokens up to
     each query enter through the masked ``C B^T`` product. Tokens after
     a query are masked out, so buffers may hold anything there.
+
+    Inside a fused window (``window`` set) the chunk is ONE token, the
+    window's step ``q0``, and ``x, dt, dA, Bm`` hold it ALONE (W = 1):
+    the CALLER owns the step's token and never places it among the
+    window's. ``window`` = ``(dt, dA, x, B, layers)``: the buffers as the
+    scan carries them, step-major (``window_buffer``: ``dt, dA`` of Hm
+    float32, ``x`` of I, ``B`` of G*N in the activation dtype). The
+    EARLIER tokens are read from them a ``[B, width]`` slab at a time,
+    where they lie (``window_slab``), by a loop of ``q0`` turns: rows at
+    and past ``q0`` are never read and may hold anything; a slab is
+    converted inside the sums that consume it: no float32 copy of a
+    buffer, no token placed in one (``_ssd_window_step``).
 
     The state's read is the Pallas kernel of ops/pallas_ssm.py where
     the caller runs its kernels (``use_pallas``), no mesh shards the
@@ -1028,6 +1140,15 @@ def ssd_pending(
     B, W, I = x.shape
     T = Cq.shape[1]
     Hm, P, G = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups
+    if window is not None:
+        assert T == W == 1, (T, W)
+        y, cum_q = _ssd_window_step(
+            cfg, x, dt, dA, Bm, Cq, window, layer, q0
+        )
+        return y + _ssd_committed(
+            cfg, ssm, layer, slots, fresh, Cq, cum_q,
+            use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+        )
     cum = jnp.cumsum(dA, axis=1)                              # [B, W, Hm]
     cum_q = jax.lax.dynamic_slice_in_dim(cum, q0, T, axis=1)  # [B, T, Hm]
     seen = (
@@ -1046,6 +1167,22 @@ def ssd_pending(
     y = jnp.einsum(
         "btsh,bshp->bthp", w, x.reshape(B, W, Hm, P), precision=_HI
     ).reshape(B, T, I)
+    return y + _ssd_committed(
+        cfg, ssm, layer, slots, fresh, Cq, cum_q,
+        use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+    )
+
+
+def _ssd_committed(
+    cfg: ModelConfig, ssm, layer, slots, fresh, Cq, cum_q, *,
+    use_pallas: bool, kernel_mesh,
+) -> jax.Array:
+    """``ssd_pending``'s share of the COMMITTED state [B, T, I]: each
+    row's slot times its C, decayed by ``cum_q`` [B, T, Hm], the
+    log-decay from the state to each query; 0 for a fresh row."""
+    B, T = Cq.shape[:2]
+    I = ssm.shape[-1]
+    P, G = cfg.mamba_head_dim, cfg.mamba_groups
     if (
         use_pallas and kernel_mesh is None
         and pallas_ssm.state_read_supported(ssm, Cq, G)
@@ -1072,7 +1209,45 @@ def ssd_pending(
         ], axis=1)                                            # [NS, T, I]
         by_row = yS[slots]
     inter = per_channel(jnp.exp(cum_q), P) * by_row
-    return y + jnp.where(fresh[:, None, None], 0.0, inter)
+    return jnp.where(fresh[:, None, None], 0.0, inter)
+
+
+def _ssd_window_step(cfg: ModelConfig, x, dt, dA, Bm, Cq, window, layer, q0):
+    """``ssd_pending`` for a fused window's step: ``(y [B, 1, I] of the
+    uncommitted tokens, cum_q [B, 1, Hm])``. The step's own token
+    (``x, dt, dA, Bm`` [B, 1, ...]) decays by nothing; token ``i < q0``
+    by ``exp(min(seg_i, 0))``, ``seg_i = dA_own + sum_{i<j<q0} dA_j``
+    summed from the step backwards (never a difference of cumulative
+    sums); ``cum_q`` is the sum over all of them, the decay since the
+    committed state. The loop runs ``q0`` times, a slab of each buffer
+    an iteration, each read once and none past the step."""
+    B, _, I = x.shape
+    Hm, P, G = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups
+    f32 = jnp.float32
+    dtw, dAw, xw, Bw, layers = window
+    Cg = rows_as(dtw, Cq.reshape(B, G, -1))                   # [n, R, G, N]
+
+    def weight(Bs, decay_dt):  # [n, R, G*N], [n, R, Hm] -> [n, R, Hm, 1]
+        g = jnp.sum(Cg * Bs.astype(f32).reshape(Cg.shape), axis=-1)
+        return (jnp.repeat(g, Hm // G, axis=-1) * decay_dt)[..., None]
+
+    def per_head(a):  # [n, R, I] -> [n, R, Hm, P]
+        return a.astype(f32).reshape(a.shape[:2] + (Hm, P))
+
+    def earlier(j, carry):
+        seg, y = carry
+        dti, dAi, xi, Bi = (
+            window_slab(b, q0 - 1 - j, layer, layers, B)
+            for b in (dtw, dAw, xw, Bw)
+        )
+        w = weight(Bi, jnp.exp(jnp.minimum(seg, 0.0)) * dti)
+        return seg + dAi, y + w * per_head(xi)
+
+    Bo, dto, xo, dAo = (rows_as(dtw, a[:, 0]) for a in (Bm, dt, x, dA))
+    seg, y = jax.lax.fori_loop(
+        0, q0, earlier, (dAo, weight(Bo, dto) * per_head(xo))
+    )
+    return y.reshape(B, 1, I), seg.reshape(B, 1, Hm)
 
 
 def over_state(c: jax.Array, groups: int):
@@ -1144,8 +1319,20 @@ def mamba_mixer(
     zx = u @ _w(lp, "w_in", u.dtype)
     z, xbc = zx[..., :I], zx[..., I:]
     dt = u @ _w(lp, "w_dt", u.dtype)
-    ext = jnp.concatenate([past.conv[layer].astype(xbc.dtype), xbc], axis=1)
-    c = causal_taps(ext, lp["w_conv"], T)                     # taps [Cd, K]
+    if past.window is not None:
+        # a fused window's step: ONE token, the columns before it read
+        # where they lie; it leaves its own column alone
+        assert T == 1 and pending, (T, pending)
+        ext = xbc
+        c = window_taps(
+            past.conv, layer, cfg.num_state_layers, past.window[-1], xbc,
+            lp["w_conv"],
+        ).reshape(Bsz, T, Cd)
+    else:
+        ext = jnp.concatenate(
+            [past.conv[layer].astype(xbc.dtype), xbc], axis=1
+        )
+        c = causal_taps(ext, lp["w_conv"], T)                 # taps [Cd, K]
     xbc = jax.nn.silu(c + lp["b_conv"].astype(f32))           # [B, T, Cd] f32
     x, Bm, Cm = xbc[..., :I], xbc[..., I : I + G * N], xbc[..., I + G * N :]
     dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
@@ -1158,20 +1345,15 @@ def mamba_mixer(
         cur = {"ssm_dt": dt, "ssm_dA": dA, "ssm_x": x.astype(u.dtype),
                "ssm_B": Bm.astype(u.dtype)}
         out.update(cur)
-        q0 = 0
-        xs, dts, dAs, Bs = x, dt, dA, Bm
+        window = None
         if past.window is not None:
-            # the window's earlier tokens, and this one in its place
+            # the window's earlier tokens are read where they lie; the
+            # step's own token stays out of them
             *bufs, q0 = past.window
-            dts, dAs, xs, Bs = (
-                jax.lax.dynamic_update_slice_in_dim(
-                    b[layer].astype(f32), a, q0, axis=1
-                )
-                for b, a in zip(bufs, (dt, dA, x, Bm))
-            )
+            window = (*bufs, cfg.num_state_layers)
         y = ssd_pending(
             cfg, past.ssm, layer, past.slots, past.fresh,
-            xs, dts, dAs, Bs, Cm, q0,
+            x, dt, dA, Bm, Cm, 0 if window is None else q0, window=window,
             use_pallas=use_pallas, kernel_mesh=kernel_mesh,
         )
     else:
@@ -1200,7 +1382,11 @@ def pending_buffers(cfg: ModelConfig, act) -> Tuple[Tuple[str, int, Any], ...]:
     """``(name, width, dtype)`` of what a state layer's token leaves for
     a chunk whose accepted length is decided later (``MixedChunk.ssm``'s
     keys beside "conv"; the fused window's buffers; ``StatePast.window``
-    in this order), by the model's ``state_kind``."""
+    in this order), by the model's ``state_kind``. A verify chunk stacks
+    them over its layers ([L, B, T, width]); a fused window carries one
+    ``window_buffer`` of each, step-major, into which the RUNNER puts a
+    step's token after the step (``window_put``): a mixer reads the
+    earlier tokens there and never its own."""
     f32 = jnp.float32
     if cfg.state_kind == "kda":
         return (("g", cfg.kda_inner, f32), ("k", cfg.kda_inner, act),
@@ -1339,6 +1525,64 @@ def kda_state_read(
         ).reshape(B, T, I)
 
 
+def _kda_window_step(
+    cfg: ModelConfig, pool, layer, slots, fresh, q, v, beta, g, k, window,
+    q0, *, use_pallas: bool, kernel_mesh,
+) -> Tuple[jax.Array, jax.Array]:
+    """``kda_pending`` for a fused window's step ``q0``: ``(o, u)`` [B, 1,
+    I] of the step's own token (``q, v, g, k`` [B, 1, I], ``beta`` [B, 1,
+    H]) over the committed state and the window's tokens ``i < q0``:
+
+        u = beta (v - (k * exp G)^T S_0 - sum_{i<q0} kk_i u_i)
+        o = (q * exp G)^T S_0 + sum_{i<q0} qk_i u_i + (q . k) u
+
+    ``kk_i, qk_i = sum_d (k | q) k_i exp(min(seg_i, 0))`` a head, every
+    decay pairwise: ``seg_i = g + sum_{i<j<q0} g_j``, summed from the
+    step backwards (never a difference or a quotient of cumulative
+    decays), and ``G`` the sum over all of them, the decay since the
+    committed state. Float32 throughout. The loop runs ``q0`` times, a
+    slab of each buffer an iteration, each read once and none past the
+    step."""
+    B, _, I = q.shape
+    H = cfg.kda_heads
+    f32 = jnp.float32
+    gw, kw, uw, layers = window
+
+    def heads(a):
+        return a.reshape(a.shape[:-1] + (H, -1))
+
+    # the token axis stays on everything of the step's own token: a
+    # [B, 1, I] made by a reshape is laid a row a tile (``window_taps``)
+    qh, kh, vh = heads(q), heads(k), heads(v)                 # [B, 1, H, d]
+    rows = rows_as(gw, jnp.concatenate([kh, qh], axis=1))     # [n, R, 2, H, dk]
+
+    def earlier(j, carry):
+        seg, sums = carry
+        gi, ki, ui = (
+            window_slab(b, q0 - 1 - j, layer, layers, B) for b in (gw, kw, uw)
+        )
+        decayed = heads(ki.astype(f32) * jnp.exp(jnp.minimum(seg, 0.0)))
+        pair = jnp.sum(rows * decayed[:, :, None], axis=-1, keepdims=True)
+        return seg + gi, sums + pair * heads(ui.astype(f32))[:, :, None]
+
+    seg, sums = jax.lax.fori_loop(
+        0, q0, earlier, (rows_as(gw, g[:, 0]), jnp.zeros_like(rows))
+    )
+    sums = sums.reshape(kh.shape[:1] + sums.shape[2:])        # [B, 2, H, dv]
+    eG = jnp.exp(seg).reshape(kh.shape)                       # [B, 1, H, dk]
+    read = kda_state_read(
+        cfg, pool, layer, slots,
+        jnp.concatenate([qh * eG, kh * eG], axis=1).reshape(B, 2, I),
+        use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+    )
+    read = heads(jnp.where(fresh[:, None, None], 0.0, read))  # [B, 2, H, dv]
+    u = beta[..., None] * (vh - read[:, 1:] - sums[:, :1])
+    o = read[:, :1] + sums[:, 1:] + (
+        jnp.sum(qh * kh, axis=-1, keepdims=True) * u
+    )
+    return o.reshape(B, 1, I), u.reshape(B, 1, I)
+
+
 def kda_pending(
     cfg: ModelConfig,
     pool: jax.Array,   # [L_k, NS, dk, I]
@@ -1353,6 +1597,7 @@ def kda_pending(
     us: jax.Array,     # [B, W, I] them; ``u`` of the tokens BEFORE the chunk
     q0,                # index among the W of the chunk's first token
     *,
+    window=None,       # a fused window's earlier tokens, step-major
     use_pallas: bool = False,
     kernel_mesh=None,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -1362,7 +1607,19 @@ def kda_pending(
     (``kda_state_read``), the uncommitted tokens before the chunk enter
     through their ``(G, k, u)``, and the chunk's own ``u`` come from one
     forward substitution. Tokens after a query are masked out, so
-    buffers may hold anything there."""
+    buffers may hold anything there.
+
+    Inside a fused window (``window`` set) the chunk is ONE token, the
+    window's step ``q0``, and ``gs, ks`` hold it ALONE (W = 1; ``us`` is
+    not read): the CALLER owns the step's token and never places it
+    among the window's. ``window`` = ``(g, k, u, layers)``: the buffers
+    as the scan carries them, step-major (``window_buffer``: ``g`` of I
+    float32, ``k, u`` of I in the activation dtype). The EARLIER tokens
+    are read from them a ``[B, I]`` slab at a time, where they lie
+    (``window_slab``), by a loop of ``q0`` turns: rows at and past
+    ``q0`` are never read and may hold anything; a slab is converted
+    inside the sums that consume it: no float32 copy of a buffer, no
+    token placed in one (``_kda_window_step``)."""
     B, T, I = q.shape
     W = gs.shape[1]
     H = cfg.kda_heads
@@ -1370,6 +1627,12 @@ def kda_pending(
     def heads(a):
         return a.reshape(a.shape[:2] + (H, -1))
 
+    if window is not None:
+        assert T == W == 1, (T, W)
+        return _kda_window_step(
+            cfg, pool, layer, slots, fresh, q, v, beta, gs, ks, window, q0,
+            use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+        )
     G = heads(jnp.cumsum(gs, axis=1))                         # [B, W, H, dk]
     Gq = jax.lax.dynamic_slice_in_dim(G, q0, T, axis=1)       # [B, T, H, dk]
     kq = jax.lax.dynamic_slice_in_dim(heads(ks), q0, T, axis=1)
@@ -1447,17 +1710,29 @@ def kda_mixer(
     lowering.record_kda("pending" if pending else "chunked")
     with jax.named_scope("kda_conv"):
         qkv = x @ _w(lp, "w_qkv", x.dtype)
-        ext = jnp.concatenate(
-            [past.conv[layer].astype(qkv.dtype), qkv], axis=1
-        )
-        qkv = jax.nn.silu(causal_taps(ext, lp["w_conv"], T))  # [B, T, 3I] f32
+        if past.window is not None:
+            # a fused window's step: ONE token, the columns before it
+            # read where they lie; it leaves its own column alone
+            assert T == 1 and pending, (T, pending)
+            ext = qkv
+            c = window_taps(
+                past.conv, layer, cfg.num_state_layers, past.window[-1],
+                qkv, lp["w_conv"],
+            )
+        else:
+            ext = jnp.concatenate(
+                [past.conv[layer].astype(qkv.dtype), qkv], axis=1
+            )
+            c = causal_taps(ext, lp["w_conv"], T)
+        # [B, T, 3 Hk, dk] float32: a head's channels an axis of their own
+        qkv = jax.nn.silu(c).reshape(Bsz, T, 3 * Hk, dk)
 
     def heads(a):
         return a.reshape(Bsz, T, Hk, dk)
 
-    q = l2_norm(heads(qkv[..., :I])) * dk ** -0.5
-    k = l2_norm(heads(qkv[..., I : 2 * I]))
-    v = qkv[..., 2 * I :]
+    q = l2_norm(qkv[:, :, :Hk]) * dk ** -0.5
+    k = l2_norm(qkv[:, :, Hk : 2 * Hk])
+    v = qkv[:, :, 2 * Hk :].reshape(Bsz, T, I)
     live = jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
     beta = cfg.kda_beta_scale * jax.nn.sigmoid(
         (x @ _w(lp, "w_beta", x.dtype)).astype(f32)
@@ -1471,20 +1746,16 @@ def kda_mixer(
     if pending:
         out["ssm_conv"] = ext
         kf = k.reshape(Bsz, T, I)
-        q0 = 0
-        gs, ks, us = g, kf, jnp.zeros_like(kf)
+        q0, window = 0, None
         if past.window is not None:
-            # the window's earlier tokens, and this one in its place
+            # the window's earlier tokens are read where they lie; the
+            # step's own token stays out of them
             *bufs, q0 = past.window
-            gs, ks, us = (b[layer].astype(f32) for b in bufs)
-            gs, ks = (
-                jax.lax.dynamic_update_slice_in_dim(b, a, q0, axis=1)
-                for b, a in ((gs, g), (ks, kf))
-            )
+            window = (*bufs, cfg.num_state_layers)
         o, u = kda_pending(
             cfg, past.ssm, layer, past.slots, past.fresh,
-            q.reshape(Bsz, T, I), v, beta, gs, ks, us, q0,
-            use_pallas=use_pallas, kernel_mesh=kernel_mesh,
+            q.reshape(Bsz, T, I), v, beta, g, kf, jnp.zeros_like(kf), q0,
+            window=window, use_pallas=use_pallas, kernel_mesh=kernel_mesh,
         )
         out.update(
             ssm_g=g, ssm_k=kf.astype(x.dtype), ssm_u=u.astype(x.dtype)

@@ -43,6 +43,7 @@ KEYS = json.loads(
     (Path(correctness.__file__).parent
      / "rehearsal/configs/tiny-granite-cpu.json").read_text()
 )
+from tests import window_buffers_common
 PS, MP = 8, 16
 
 
@@ -247,6 +248,27 @@ def test_a_speculative_window_commits_any_accepted_prefix(runner, step):
     seq = np.concatenate([prompt, [first, toks[0, 0], 7, 0]])
     got = step([7], [16], table)[0]
     assert err(got, want(runner, seq, [16])[0]) < TOL
+
+# -- the fused window's state buffers: step-major, read where they lie ------------
+
+@pytest.mark.parametrize("q0", [0, 3, 7])
+def test_a_windows_step_is_the_chunk_form_from_the_same_state(runner, q0):
+    """Step ``q0`` of a window reads the ``q0`` earlier tokens from the
+    buffers (NaN at and past it) and keeps its own out of them."""
+    window_buffers_common.a_windows_step_is_the_chunk_form(
+        MCFG, runner.params, q0
+    )
+
+
+def test_a_fused_window_of_eight_is_eight_single_steps(runner, step):
+    window_buffers_common.a_window_is_its_steps(runner, step, TOL)
+
+
+@pytest.mark.parametrize("accepted", [0, 3, 8])
+def test_a_speculative_window_of_eight_commits_what_its_accepted_steps_would(
+    runner, step, accepted
+):
+    window_buffers_common.a_window_is_its_steps(runner, step, TOL, accepted)
 
 
 # -- (e) verify with none, some and all of its inputs accepted -----------------

@@ -30,6 +30,7 @@ from sutro_tpu.ops import lowering
 from tests.solar_kda_common import (
     MCFG, MP, engine, err, sequence, table_of, want,
 )
+from tests import window_buffers_common
 
 TOL = 2e-4
 
@@ -213,6 +214,27 @@ def test_verify_with_a_part_of_its_inputs_accepted(runner, step, accepted):
         seq = np.concatenate([seqs[b][:n], [9, 0]])
         got = step([9], [n], tables[b])[0]
         assert err(got, want(runner, seq, [n])[0]) < TOL
+
+# -- the fused window's state buffers: step-major, read where they lie ------------
+
+@pytest.mark.parametrize("q0", [0, 3, 7])
+def test_a_windows_step_is_the_chunk_form_from_the_same_state(runner, q0):
+    """Step ``q0`` of a window reads the ``q0`` earlier tokens from the
+    buffers (NaN at and past it) and keeps its own out of them."""
+    window_buffers_common.a_windows_step_is_the_chunk_form(
+        MCFG, runner.params, q0
+    )
+
+
+def test_a_fused_window_of_eight_is_eight_single_steps(runner, step):
+    window_buffers_common.a_window_is_its_steps(runner, step, TOL)
+
+
+@pytest.mark.parametrize("accepted", [0, 3, 8])
+def test_a_speculative_window_of_eight_commits_what_its_accepted_steps_would(
+    runner, step, accepted
+):
+    window_buffers_common.a_window_is_its_steps(runner, step, TOL, accepted)
 
 
 # -- through the scheduler: the wave, slots, spans, counters -------------------
