@@ -1,5 +1,6 @@
 """Where a decode step and a one-row prefill spend the device's time, BY
-SCOPE, at a benchmark configuration's own sizes.
+PART of the model and by scope inside it, at a benchmark configuration's
+own sizes.
 
     python benchmarks/profile_scopes.py \\
         --config perfbench/configs/mellum2-12b-a2.5b-l8-v5e1.json \\
@@ -13,37 +14,34 @@ prefill of each bucket and two windows with the JAX profiler; with
 (``decode_step(allowed=...)``, each row's mask 256 ids of the vocabulary,
 what a byte tokenizer's FSM allows: the step a constrained greedy batch
 takes while its windows are being refused, ``_decode_jit``). Each device
-op's self time goes to the first ``jax.named_scope`` of the mixed walk in
-its HLO ``op_name`` (``moe_ffn``, ``shared_expert`` inside it,
-``attn_window``, ``attn_full``,
-``attn_mixer`` (``gqa_gate`` inside it), ``conv_mixer``, ``mamba_mixer``,
-``kda_mixer`` (inside it ``kda_conv``, ``kda_chunk``, ``kda_state_read``),
-``kda_commit``, ``mla_mixer`` and inside it
-``mla_expand`` or ``mla_absorb`` and, under an indexer, ``dsa_indexer``,
-``dsa_select`` and ``dsa_attend`` (the innermost wins), ``dense_ffn``; ``other``
-is the head, sampling, embeddings and what XLA hoisted), read from the
-event's own HLO line or, where the trace leaves it out, from the
-optimized HLO the compiler dumped (``--xla_dump_to``, set here before JAX
-loads). Prints one JSON line a program: ms a run (a decode STEP for the
-window), by scope, and the largest ops of each scope; then which paths
-the process traced (``ops/lowering.py``: the kernels, the grouped
-product, a routed layer's combine).
+op's self time goes to the PART its ``op_name`` opens with
+(``sutro_tpu/ops/lowering.py`` ``PARTS``) and, under it, to the named
+scopes inside (``mixer/attn_window``, ``ffn/moe_ffn/shared_expert``,
+``mixer/mla_mixer/mla_absorb/dsa_attend``, ``cache/kda_commit``;
+OBSERVABILITY.md "Parts of a step" lists them), read from the trace's
+OWN optimized HLO by ``perfbench/trace_parts.py``: the reducer of the
+benchmark's ``decode_*_ms_per_step`` metrics and of
+``perfbench/tools/part_table.py``, which prints the same table for a
+cell's real traffic. Prints one JSON line a program: ms a run (a decode
+STEP for the window), by part, by scope, and the largest ops of each
+scope; then which paths the process traced (``ops/lowering.py``: the
+kernels, the grouped product, a routed layer's combine).
 
 ``--top N`` lists N ops a scope (4), ``--by-name`` each under its own HLO
 name (``copy.1227``) and not summed by kind: the name to look up in the
 optimized HLO.
 
-What ``perfbench/trace_reduce.py`` cannot say: it keys an op by its own
-name and drops ``op_name`` (PERF.md section 7 row 19). Fails without a
-TPU unless ``--cpu`` (the rehearsal configuration, to debug the flow).
+A program whose ops carry no part at all was loaded from a compile cache
+written before the scopes were (a scope is debug info, which the cache's
+key leaves out): clear ``.xla_cache/`` or ``JAX_COMPILATION_CACHE_DIR``.
+Fails without a TPU unless ``--cpu`` (the rehearsal configuration, to
+debug the flow: a CPU trace has no device plane).
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
-import re
 import sys
 import tempfile
 from collections import defaultdict
@@ -52,40 +50,7 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
 
-SCOPES = (
-    "moe_ffn", "attn_window", "attn_full", "attn_mixer", "conv_mixer",
-    "mamba_mixer", "mla_mixer", "kda_mixer", "kda_commit", "dense_ffn",
-    "paged_decode_xla",
-)
-#: scopes INSIDE one of the above that are told apart: the shared expert
-#: inside ``moe_ffn``, the products of the form taken inside ``mla_mixer``
-#: and, inside those, an indexer's scores, its selection and the
-#: attention over it
-INNER = ("shared_expert", "mla_expand", "mla_absorb", "dsa_indexer",
-         "dsa_select", "dsa_attend", "kda_conv", "kda_chunk",
-         "kda_state_read", "gqa_gate")
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
-_DEF = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
-
-
-def scope_of(op_name: str) -> str:
-    parts = op_name.split("/")
-    inner = next((p for p in reversed(parts) if p in INNER), None)
-    return inner or next((p for p in parts if p in SCOPES), "other")
-
-
-def scopes_from_dump(dump: Path) -> dict:
-    """``{module name: {op name: scope}}`` from the optimized HLO texts."""
-    out: dict = {}
-    for path in dump.glob("*after_optimizations.txt"):
-        text = path.read_text()
-        head = re.search(r"HloModule ([\w.\-]+)", text)
-        ops = out.setdefault(head.group(1) if head else path.name, {})
-        for line in text.splitlines():
-            name, meta = _DEF.match(line), _OP_NAME.search(line)
-            if name and meta:
-                ops[name.group(1)] = scope_of(meta.group(1))
-    return out
+NO_PART = "(no part)"
 
 
 def main() -> None:
@@ -102,17 +67,11 @@ def main() -> None:
     ap.add_argument("--cpu", action="store_true")
     args = ap.parse_args()
 
-    dump = Path(tempfile.mkdtemp(prefix="scopes-hlo-"))
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_dump_to={dump} --xla_dump_hlo_as_text"
-        " --xla_dump_hlo_module_re=.*(prefill|decode).*"
-    )
     import jax
     import numpy as np
-    from jax.profiler import ProfileData
 
-    from perfbench import trace_reduce
+    from perfbench import trace_parts, trace_reduce
+    from sutro_tpu.ops.lowering import PARTS
     from sutro_tpu.engine.config import EngineConfig
     from sutro_tpu.engine.runner import ModelRunner
     from sutro_tpu.models.configs import MODEL_CONFIGS
@@ -184,71 +143,52 @@ def main() -> None:
         for i in range(args.masked_steps):
             masked_step(2 + i)
     xplane = sorted(Path(tracedir).glob("plugins/profile/*/*.xplane.pb"))[-1]
-    from_dump = scopes_from_dump(dump)
-    data = ProfileData.from_file(str(xplane))
-    for plane in data.planes:
-        if not plane.name.startswith("/device:"):
-            continue
-        lines = {line.name.lower(): list(line.events) for line in plane.lines}
-        ops = lines.get("xla ops") or lines.get("ops") or []
-        modules = lines.get("xla modules") or lines.get("modules") or []
-        if not ops or not modules:
-            continue
-        events = [
-            [ev.name, float(ev.start_ns), float(ev.duration_ns)] for ev in ops
-        ]
-        selfs = trace_reduce.self_times(events)
-        runs = [
-            (trace_reduce.module_key(m.name), float(m.start_ns),
-             float(m.start_ns + m.duration_ns)) for m in modules
-        ]
-        by = defaultdict(lambda: defaultdict(lambda: defaultdict(float)))
-        unscoped = 0
-        for (name, start, _dur), self_ns in zip(events, selfs):
-            module = next((m for m, lo, hi in runs if lo <= start < hi), None)
-            if module is None:
-                continue
-            short = trace_reduce.split_hlo(name)[0]
-            meta = _OP_NAME.search(name)
-            if meta:
-                scope = scope_of(meta.group(1))
-            else:
-                unscoped += 1
-                scope = next(
-                    (ops_[short] for mod, ops_ in from_dump.items()
-                     if mod in module and short in ops_), "other",
-                )
-            key = short if args.by_name else trace_reduce.op_key(short)
-            by[module][scope][key] += self_ns
-        for module, scopes in sorted(by.items()):
-            n = sum(1 for m, _lo, _hi in runs if m == module)
-            per = n * (steps if "decode_multi" in module else 1)
-            print(json.dumps({
-                "program": module, "runs": n,
-                "unit": "step" if "decode_multi" in module else "run",
-                "ms": sum(sum(o.values()) for o in scopes.values()) / per / 1e6,
-                "by_scope_ms": {
-                    s: round(sum(o.values()) / per / 1e6, 3)
-                    for s, o in sorted(
-                        scopes.items(), key=lambda kv: -sum(kv[1].values())
-                    )
-                },
-                "top_ops_ms": {
-                    s: {k: round(v / per / 1e6, 3) for k, v in sorted(
-                        o.items(), key=lambda kv: -kv[1])[:args.top]}
-                    for s, o in scopes.items()
-                },
-                "ops_scoped_from_dump": unscoped,
-            }), flush=True)
-        break
-    else:
+    trace, names = trace_parts.parsed(str(xplane))
+    runs = trace_reduce.reduce_trace(trace)["module_s"]
+    by = defaultdict(lambda: defaultdict(lambda: defaultdict(float)))
+    for module, name, op_name, secs in trace_parts.op_rows(
+        trace, names, trace_reduce.window_of(trace)
+    ):
+        part = trace_parts.part_of(op_name)
+        scope = NO_PART if part is None else (
+            part + "/" + trace_parts.scopes_of(op_name)
+        ).rstrip("/")
+        by[module][scope][
+            name if args.by_name else trace_reduce.op_key(name)
+        ] += secs
+    order = {p: i for i, p in enumerate(PARTS + (NO_PART,))}
+    for module, scopes in sorted(by.items()):
+        n = runs.get(module, {}).get("runs", 0.0)
+        fused = "decode_multi" in module
+        per = max(n, 1.0) * (steps if fused else 1) / 1e3
+        by_part = defaultdict(float)
+        for scope, ops in scopes.items():
+            by_part[scope.split("/")[0]] += sum(ops.values())
         print(json.dumps({
-            "problem": "the trace holds no device plane",
-            "ops_in_the_dump_by_scope": {
-                mod: {s: list(ops_.values()).count(s) for s in set(ops_.values())}
-                for mod, ops_ in from_dump.items()
+            "program": module, "runs": n, "unit": "step" if fused else "run",
+            "ms": sum(by_part.values()) / per,
+            "by_part_ms": {
+                p: round(v / per, 3)
+                for p, v in sorted(by_part.items(), key=lambda kv: order[kv[0]])
             },
-        }))
+            # the part above each scope: its own line first, then its scopes
+            "by_scope_ms": {
+                s: round(sum(o.values()) / per, 3)
+                for s, o in sorted(scopes.items(), key=lambda kv: (
+                    order[kv[0].split("/")[0]], -sum(kv[1].values())
+                ))
+            },
+            "top_ops_ms": {
+                s: {k: round(v / per, 3) for k, v in sorted(
+                    o.items(), key=lambda kv: -kv[1])[:args.top]}
+                for s, o in scopes.items()
+            },
+            "stale_compile_cache": "mixer" not in by_part and (
+                "decode" in module or "prefill" in module
+            ),
+        }), flush=True)
+    if not by:
+        print(json.dumps({"problem": "the trace holds no device plane"}))
     # which paths this process built into those programs
     from sutro_tpu.ops import lowering
 

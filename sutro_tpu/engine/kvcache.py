@@ -128,6 +128,7 @@ from ..models.transformer import (
     MixedChunk, StatePast, chunk_tokens, columns_after, group_channels,
     over_state, per_channel,
 )
+from ..ops.lowering import part
 from .config import EngineConfig
 
 
@@ -591,6 +592,7 @@ def _scatter_rows(pool: jax.Array, flat: jax.Array, rows: jax.Array):
     return out.reshape(pool.shape)
 
 
+@part("cache")
 def read_conv_state(
     cache: KVCache, page_table: jax.Array, start: jax.Array,
     layers: int, hidden: int,
@@ -609,6 +611,7 @@ def read_conv_state(
     return state.transpose(1, 0, 2, 3)
 
 
+@part("cache")
 def write_conv_state(
     conv: jax.Array,           # [NP, L_conv * (K-1) * H] — the pool
     g_ext: jax.Array,          # [L_conv, B, K-1+T, H] (MixedChunk.conv)
@@ -654,6 +657,7 @@ def state_slots_at(
     return cache.state_slot[page]
 
 
+@part("cache")
 def read_state(
     cache: KVCache, page_table: jax.Array, start: jax.Array,
     layers: int, conv_dim: int,
@@ -737,6 +741,7 @@ def _advance_kda(
     return jax.lax.scan(layer, ssm, jnp.arange(L, dtype=jnp.int32))
 
 
+@part("cache")
 def write_state(
     cache: KVCache,
     chunk: dict,               # MixedChunk.ssm
@@ -846,6 +851,7 @@ def write_state(
     )
 
 
+@part("cache")
 def write_kv(
     cache: KVCache,
     k_chunk: "jax.Array | MixedChunk",  # [L, B, T, KVH, Dh] or fused [L, B, T, KD]

@@ -38,7 +38,8 @@ from .kvcache import (
     first_live_page, read_conv_state, read_state, state_bytes_per_slot,
     window_span_pages, window_table, write_kv,
 )
-from ..ops.sampling import NEG_INF, sample, cumulative_logprob
+from ..ops.lowering import part
+from ..ops.sampling import NEG_INF, cumulative_logprob, sample, unpack_mask
 
 
 def next_bucket(n: int, lo: int = 16, hi: int = 1 << 20) -> int:
@@ -446,6 +447,7 @@ class ModelRunner:
     # -- mamba state slots (kvcache.StateSlots) -------------------------
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+    @part("cache")
     def _bind_slots_jit(self, cache: KVCache, pages, slots):
         return dataclasses.replace(
             cache, state_slot=cache.state_slot.at[pages].set(slots)
@@ -516,6 +518,7 @@ class ModelRunner:
     # -- window pool pages (kvcache.WindowPages) ------------------------
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+    @part("cache")
     def _bind_window_jit(self, cache: KVCache, pages, wpages):
         return dataclasses.replace(
             cache, window_page=cache.window_page.at[pages].set(wpages)
@@ -826,16 +829,17 @@ class ModelRunner:
         if route is None:
             return None
         first, held = self.mcfg.moe_first_expert, self.mcfg.experts_held
-        every = route.astype(jnp.float32)                   # [L_moe, E]
-        r = every[:, first : first + held]
-        return jnp.stack([
-            jnp.mean(jnp.sum(r > 0, axis=-1).astype(jnp.float32)),
-            jnp.mean(jnp.max(r, axis=-1)),
-            jnp.mean(r),
-            jnp.sum(r),
-            jnp.float32(held),
-            jnp.sum(every) - jnp.sum(r),
-        ])
+        with part("ffn"):  # where the counts were computed
+            every = route.astype(jnp.float32)               # [L_moe, E]
+            r = every[:, first : first + held]
+            return jnp.stack([
+                jnp.mean(jnp.sum(r > 0, axis=-1).astype(jnp.float32)),
+                jnp.mean(jnp.max(r, axis=-1)),
+                jnp.mean(r),
+                jnp.sum(r),
+                jnp.float32(held),
+                jnp.sum(every) - jnp.sum(r),
+            ])
 
     def _state_at(self, cache: KVCache, page_table, start):
         """The conv layers' state of each row at ``start`` ([L_conv, B,
@@ -986,6 +990,7 @@ class ModelRunner:
         return out
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+    @part("cache")
     def _upload_pages_jit(self, cache: KVCache, ids, k, v, c=None):
         return dataclasses.replace(
             cache,
@@ -995,6 +1000,7 @@ class ModelRunner:
         )
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+    @part("cache")
     def _upload_pages_q_jit(self, cache: KVCache, ids, k, v, ks, vs, c=None):
         return dataclasses.replace(
             cache,
@@ -1089,9 +1095,10 @@ class ModelRunner:
                 ),
                 use_pallas=self.use_pallas,
             )
-            logits = jnp.take_along_axis(
-                logits, last[:, None, None], axis=1
-            )
+            with part("head"):
+                logits = jnp.take_along_axis(
+                    logits, last[:, None, None], axis=1
+                )
         else:
             logits, hidden, (k, v) = transformer.forward(
                 self.mcfg, params, ids, positions, valid_len,
@@ -1452,11 +1459,7 @@ class ModelRunner:
         B = ids.shape[0]
         allowed = None
         if allowed_packed is not None:
-            # FSM masks travel host->device bit-packed (8x less transfer
-            # on the per-step critical path of constrained decoding)
-            allowed = jnp.unpackbits(
-                allowed_packed, axis=1, count=self.mcfg.vocab_size
-            ).astype(bool)
+            allowed = unpack_mask(allowed_packed, self.mcfg.vocab_size)
         positions = past_len[:, None]  # current token position == past length
         logits, _, (k, v) = self._trunk_decode(
             params, cache, ids, positions, past_len, page_table,
@@ -1474,9 +1477,7 @@ class ModelRunner:
             from ..ops.sampling import apply_penalties
 
             seen_packed, ids_p, cnt_p, pres, freq, rep = penalties
-            seen = jnp.unpackbits(
-                seen_packed, axis=1, count=self.mcfg.vocab_size
-            ).astype(bool)
+            seen = unpack_mask(seen_packed, self.mcfg.vocab_size)
             step_logits = apply_penalties(
                 step_logits, seen, ids_p, cnt_p, pres, freq, rep
             )
@@ -1492,10 +1493,11 @@ class ModelRunner:
             # what a speculative window's verify would have found, for
             # the scheduler's choice between window and masked step
             # (take_unmasked_ok)
-            top = jnp.argmax(step_logits, axis=-1)
-            unmasked_ok = jnp.take_along_axis(
-                allowed, top[:, None], axis=1
-            )[:, 0]
+            with part("sample"):
+                top = jnp.argmax(step_logits, axis=-1)
+                unmasked_ok = jnp.take_along_axis(
+                    allowed, top[:, None], axis=1
+                )[:, 0]
         return tok, logp, cache, self._route_stats(k), unmasked_ok
 
     def take_unmasked_ok(self) -> Optional[np.ndarray]:
@@ -1647,7 +1649,12 @@ class ModelRunner:
         logits only: a row whose previous window rejected a token takes
         its FSM-masked step INSIDE the next window (crossing the
         scaffold token), so one adversarial row no longer degrades the
-        whole batch to masked single-steps."""
+        whole batch to masked single-steps.
+
+        The window's buffers, their fill before the scan and their
+        writes after a step are the ``cache`` part of the step
+        (``lowering.PARTS``), the mask and the key of a step's draw its
+        ``sample`` part; the scan's own carry and counter are no part's."""
         B = last.shape[0]
         L = self.mcfg.num_kv_layers   # full layers, then window layers
         KD = self.mcfg.page_width
@@ -1662,12 +1669,13 @@ class ModelRunner:
         # FUSED trailing axis (like the page pool, kvcache.py): the
         # unfused [.., KVH, Dh] form pads KVH up to a full sublane tile
         # on TPU — a 2x memory expansion on multi-GB buffers at large B
-        wk0 = jnp.zeros((L, B, steps, KD), dtype)
         # V's buffer: as wide as K's, a latent layer's index keys, or none
         # (``ModelConfig.pool_row_widths``)
         widths = self.mcfg.pool_row_widths
         VD = widths[1] if len(widths) > 1 else 0
-        wv0 = jnp.zeros((L, B, steps, VD), dtype) if VD else None
+        with part("cache"):
+            wk0 = jnp.zeros((L, B, steps, KD), dtype)
+            wv0 = jnp.zeros((L, B, steps, VD), dtype) if VD else None
         mixed = not self.mcfg.homogeneous
         K1 = self.mcfg.conv_state_len or self.mcfg.state_conv_len
         wc0 = ws0 = past = None
@@ -1680,7 +1688,8 @@ class ModelRunner:
             )
 
         if self.mcfg.num_conv_layers:
-            wc0 = window_of(self._state_at(cache, page_table, past_len))
+            with part("cache"):
+                wc0 = window_of(self._state_at(cache, page_table, past_len))
         pending = ()
         if self.mcfg.num_state_layers:
             # the pool is a constant of the scan, like the pages: the
@@ -1695,73 +1704,83 @@ class ModelRunner:
             pending = transformer.pending_buffers(
                 m, jnp.dtype(self.ecfg.activation_dtype)
             )
-            ws0 = {
-                name: transformer.window_buffer(
-                    steps + (K1 if name == "conv" else 0), Lm, B, width, dt
-                )
-                for name, width, dt in (
-                    ("conv", m.state_conv_dim, past.conv.dtype),
-                ) + pending
-            }
-            for j in range(K1):  # the columns before the window
-                ws0["conv"] = transformer.window_put(
-                    ws0["conv"], j, past.conv[:, :, j]
-                )
+            with part("cache"):
+                ws0 = {
+                    name: transformer.window_buffer(
+                        steps + (K1 if name == "conv" else 0), Lm, B,
+                        width, dt,
+                    )
+                    for name, width, dt in (
+                        ("conv", m.state_conv_dim, past.conv.dtype),
+                    ) + pending
+                }
+                for j in range(K1):  # the columns before the window
+                    ws0["conv"] = transformer.window_put(
+                        ws0["conv"], j, past.conv[:, :, j]
+                    )
 
         def body(carry, step_idx):
             wk, wv, wc, ws, last = carry
+            conv_state = None
+            if wc is not None:
+                with part("cache"):
+                    conv_state = jax.lax.dynamic_slice_in_dim(
+                        wc, step_idx, K1, axis=2
+                    )
             logits, _, (k, v) = self._trunk_decode(
                 params, cache, last[:, None],
                 (past_len + step_idx)[:, None], past_len, page_table,
                 window_past=(wk, wv, step_idx), pfx=pfx,
-                conv_state=None if wc is None
-                else jax.lax.dynamic_slice_in_dim(wc, step_idx, K1, axis=2),
+                conv_state=conv_state,
                 state_past=None if ws is None else dataclasses.replace(
                     past, conv=ws["conv"],
                     window=tuple(ws[n] for n, _, _ in pending) + (step_idx,),
                 ),
             )
             route = self._route_stats(k)
-            if mixed:
-                if wc is not None:
-                    wc = jax.lax.dynamic_update_slice(
-                        wc, k.conv[:, :, K1:].astype(wc.dtype),
-                        (0, 0, K1 + step_idx, 0),
-                    )
-                if ws is not None:
-                    # the step's token of every state layer ([L_m, B, 1,
-                    # width]) as ONE slab at the step's place
-                    ws = {
-                        name: transformer.window_put(
-                            buf, step_idx + (K1 if name == "conv" else 0),
-                            k.ssm[name][:, :, 0],
+            with part("cache"):
+                if mixed:
+                    if wc is not None:
+                        wc = jax.lax.dynamic_update_slice(
+                            wc, k.conv[:, :, K1:].astype(wc.dtype),
+                            (0, 0, K1 + step_idx, 0),
                         )
-                        for name, buf in ws.items()
-                    }
-                k = k.k
-            wk = jax.lax.dynamic_update_slice(
-                wk, k.astype(dtype).reshape(L, B, 1, KD),
-                (0, 0, step_idx, 0),
-            )
-            if wv is not None:
-                wv = jax.lax.dynamic_update_slice(
-                    wv, v.astype(dtype).reshape(L, B, 1, VD),
+                    if ws is not None:
+                        # the step's token of every state layer ([L_m,
+                        # B, 1, width]) as ONE slab at the step's place
+                        ws = {
+                            name: transformer.window_put(
+                                buf,
+                                step_idx + (K1 if name == "conv" else 0),
+                                k.ssm[name][:, :, 0],
+                            )
+                            for name, buf in ws.items()
+                        }
+                    k = k.k
+                wk = jax.lax.dynamic_update_slice(
+                    wk, k.astype(dtype).reshape(L, B, 1, KD),
                     (0, 0, step_idx, 0),
                 )
+                if wv is not None:
+                    wv = jax.lax.dynamic_update_slice(
+                        wv, v.astype(dtype).reshape(L, B, 1, VD),
+                        (0, 0, step_idx, 0),
+                    )
             step_logits = logits[:, 0]
             sample_logits = step_logits
-            if allowed0 is not None:
-                # masked sample == masked argmax for the greedy rows
-                # this path serves; logp stays over the UNMASKED
-                # logits — the same convention as the single-step path
-                # (sample under the mask, report full-vocab logprob),
-                # so cumulative_logprob is path-independent
-                sample_logits = jnp.where(
-                    step_idx == 0,
-                    jnp.where(allowed0, step_logits, NEG_INF),
-                    step_logits,
-                )
-            key = jax.random.fold_in(rng, step_idx)
+            with part("sample"):
+                if allowed0 is not None:
+                    # masked sample == masked argmax for the greedy rows
+                    # this path serves; logp stays over the UNMASKED
+                    # logits — the same convention as the single-step
+                    # path (sample under the mask, report full-vocab
+                    # logprob), so cumulative_logprob is path-independent
+                    sample_logits = jnp.where(
+                        step_idx == 0,
+                        jnp.where(allowed0, step_logits, NEG_INF),
+                        step_logits,
+                    )
+                key = jax.random.fold_in(rng, step_idx)
             tok = sample(
                 sample_logits, key,
                 temperature=temperature, top_p=top_p, top_k=top_k,
@@ -1886,11 +1905,12 @@ class ModelRunner:
             use_pallas=self.use_pallas,
             kernel_mesh=self.kernel_mesh,
         )
-        lg = logits.astype(jnp.float32)                       # [B, C, V]
-        plain = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-        plain_lp = jnp.take_along_axis(
-            jax.nn.log_softmax(lg, axis=-1), plain[..., None], axis=-1
-        )[..., 0]
+        with part("sample"):
+            lg = logits.astype(jnp.float32)                   # [B, C, V]
+            plain = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+            plain_lp = jnp.take_along_axis(
+                jax.nn.log_softmax(lg, axis=-1), plain[..., None], axis=-1
+            )[..., 0]
         return lg, plain, plain_lp, cache, pending
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
@@ -1912,21 +1932,24 @@ class ModelRunner:
         lg, plain, plain_lp, cache, pending = self._verify_forward(
             params, cache, ids, valid_len, page_table, start
         )
-        g = jnp.take_along_axis(lg, cand, axis=2)             # [B, C, M]
-        M = cand.shape[2]
-        ok = (
-            jnp.arange(M, dtype=jnp.int32)[None, None, :]
-            < cand_n[..., None]
-        )
-        g = jnp.where(ok, g, NEG_INF)
-        idx = jnp.argmax(g, axis=-1)                          # [B, C]
-        ctok = jnp.take_along_axis(cand, idx[..., None], axis=2)[..., 0]
-        lse_v = jax.scipy.special.logsumexp(lg, axis=-1)      # [B, C]
-        clp = (
-            jnp.take_along_axis(lg, ctok[..., None], axis=-1)[..., 0]
-            - lse_v
-        )
-        return ctok.astype(jnp.int32), clp, plain, plain_lp, cache, pending
+        with part("sample"):
+            g = jnp.take_along_axis(lg, cand, axis=2)         # [B, C, M]
+            M = cand.shape[2]
+            ok = (
+                jnp.arange(M, dtype=jnp.int32)[None, None, :]
+                < cand_n[..., None]
+            )
+            g = jnp.where(ok, g, NEG_INF)
+            idx = jnp.argmax(g, axis=-1)                      # [B, C]
+            ctok = jnp.take_along_axis(
+                cand, idx[..., None], axis=2
+            )[..., 0].astype(jnp.int32)
+            lse_v = jax.scipy.special.logsumexp(lg, axis=-1)  # [B, C]
+            clp = (
+                jnp.take_along_axis(lg, ctok[..., None], axis=-1)[..., 0]
+                - lse_v
+            )
+        return ctok, clp, plain, plain_lp, cache, pending
 
     def verify_candidates(
         self,
@@ -2029,9 +2052,7 @@ class ModelRunner:
         read-only input here, so a rejected suffix costs nothing.
         ``allowed0`` arrives bit-packed, like the masked step's masks."""
         if allowed0 is not None:
-            allowed0 = jnp.unpackbits(
-                allowed0, axis=1, count=self.mcfg.vocab_size
-            ).astype(bool)
+            allowed0 = unpack_mask(allowed0, self.mcfg.vocab_size)
         return self._window_scan(
             params, cache, last, past_len, page_table, rng,
             temperature, top_p, steps, top_k,

@@ -46,7 +46,9 @@ from . import faults
 from .kvcache import PageAllocator, pages_needed
 from .runner import ModelRunner
 from .. import telemetry
-from ..ops.sampling import cumulative_logprob, sample as device_sample
+from ..ops.sampling import (
+    cumulative_logprob, sample as device_sample, unpack_mask,
+)
 
 # StepTimer phase -> telemetry stage (OBSERVABILITY.md span schema):
 # the timer wraps DEVICE dispatches, so its phases map onto the
@@ -80,9 +82,7 @@ def _admit_sample_jit(
     arrives bit-packed ([B, ceil(V / 8)] uint8), like the masked decode
     step's."""
     if allowed is not None:
-        allowed = jax.numpy.unpackbits(
-            allowed, axis=1, count=logits.shape[-1]
-        ).astype(bool)
+        allowed = unpack_mask(allowed, logits.shape[-1])
     tok = device_sample(
         logits, key,
         temperature=temperature, top_p=top_p, top_k=top_k,

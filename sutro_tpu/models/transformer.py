@@ -1811,37 +1811,44 @@ def layer_apply(
     """One decoder block of a homogeneous model (attention, then its
     MLP). Shared by the scanned ``forward`` and the pipeline-parallel
     stage loop (parallel/pipeline.py). Returns
-    ``(h, (k_chunk, v_chunk))``."""
-    resid = h
-    x = rms_norm(h, lp["attn_norm"], cfg.norm_eps, cfg.norm_zero_centered)
-    attn, (k, v) = attention_mixer(
-        cfg, lp, x,
-        positions=positions, valid_len=valid_len,
-        window=window, theta=theta,
-        k_pages=k_pages, v_pages=v_pages,
-        k_scale=k_scale, v_scale=v_scale, layer=layer,
-        page_table=page_table, past_len=past_len,
-        use_pallas=use_pallas, ring_mesh=ring_mesh,
-        wk_l=wk_l, wv_l=wv_l, win_len=win_len,
-        pfx_groups=pfx_groups,
-        kernel_mesh=kernel_mesh,
-    )
-    if cfg.post_norms:
-        attn = rms_norm(
-            attn, lp["post_attn_norm"], cfg.norm_eps, cfg.norm_zero_centered
-        )
-    h = resid + attn
-    resid = h
-    x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, cfg.norm_zero_centered)
-    x = _mlp(
-        cfg, lp, x, ep_mesh=ep_mesh, use_pallas=use_pallas,
-        kernel_mesh=kernel_mesh,
-    )
-    if cfg.post_norms:
-        x = rms_norm(
-            x, lp["post_mlp_norm"], cfg.norm_eps, cfg.norm_zero_centered
-        )
-    h = resid + x
+    ``(h, (k_chunk, v_chunk))``. Each half is one part of the step
+    (``lowering.PARTS``): the norms and the residual add belong to the
+    half they surround."""
+    with lowering.part("mixer"):
+        resid = h
+        x = rms_norm(h, lp["attn_norm"], cfg.norm_eps, cfg.norm_zero_centered)
+        with jax.named_scope("attn_mixer"):
+            attn, (k, v) = attention_mixer(
+                cfg, lp, x,
+                positions=positions, valid_len=valid_len,
+                window=window, theta=theta,
+                k_pages=k_pages, v_pages=v_pages,
+                k_scale=k_scale, v_scale=v_scale, layer=layer,
+                page_table=page_table, past_len=past_len,
+                use_pallas=use_pallas, ring_mesh=ring_mesh,
+                wk_l=wk_l, wv_l=wv_l, win_len=win_len,
+                pfx_groups=pfx_groups,
+                kernel_mesh=kernel_mesh,
+            )
+        if cfg.post_norms:
+            attn = rms_norm(
+                attn, lp["post_attn_norm"], cfg.norm_eps,
+                cfg.norm_zero_centered,
+            )
+        h = resid + attn
+    with lowering.part("ffn"):
+        resid = h
+        x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, cfg.norm_zero_centered)
+        with jax.named_scope("moe_ffn" if "router" in lp else "dense_ffn"):
+            x = _mlp(
+                cfg, lp, x, ep_mesh=ep_mesh, use_pallas=use_pallas,
+                kernel_mesh=kernel_mesh,
+            )
+        if cfg.post_norms:
+            x = rms_norm(
+                x, lp["post_mlp_norm"], cfg.norm_eps, cfg.norm_zero_centered
+            )
+        h = resid + x
     return h, (k, v)
 
 
@@ -2071,9 +2078,11 @@ def _mixed_trunk(
         lacks."""
         out = {}
         if mixer != "none":
-            h = h + scaled(mix(h, mixer, m_idx, out))
+            with lowering.part("mixer"):
+                h = h + scaled(mix(h, mixer, m_idx, out))
         if ffn != "none":
-            h = h + scaled(feed(h, ffn, f_idx, out))
+            with lowering.part("ffn"):
+                h = h + scaled(feed(h, ffn, f_idx, out))
         return h, out
 
     def mix(h, mixer, m_idx, out):
@@ -2187,7 +2196,8 @@ def _mixed_trunk(
                 )
                 for k, val in out.items():
                     ys[k].append(val)
-            return h, {k: jnp.stack(v) for k, v in ys.items() if v}
+            with lowering.part("cache"):  # gathered for the commit
+                return h, {k: jnp.stack(v) for k, v in ys.items() if v}
 
         if repeats == 1:
             h, ys = body(h, 0)
@@ -2201,17 +2211,18 @@ def _mixed_trunk(
             }
         for k, v in ys.items():
             outs[k].append(v)
-    cat = {
-        k: (jnp.concatenate(v) if len(v) > 1 else v[0]) if v else None
-        for k, v in outs.items()
-    }
-    ssm = {k[4:]: cat[k] for k in _SSM_KEYS if cat[k] is not None}
-    if cat["wk"] is not None:
-        # the window layers' K/V after the full layers'
-        for full, win in (("k", "wk"), ("v", "wv")):
-            cat[full] = cat[win] if cat[full] is None else (
-                jnp.concatenate([cat[full], cat[win]])
-            )
+    with lowering.part("cache"):
+        cat = {
+            k: (jnp.concatenate(v) if len(v) > 1 else v[0]) if v else None
+            for k, v in outs.items()
+        }
+        ssm = {k[4:]: cat[k] for k in _SSM_KEYS if cat[k] is not None}
+        if cat["wk"] is not None:
+            # the window layers' K/V after the full layers'
+            for full, win in (("k", "wk"), ("v", "wv")):
+                cat[full] = cat[win] if cat[full] is None else (
+                    jnp.concatenate([cat[full], cat[win]])
+                )
     return h, cat["k"], cat["v"], cat["conv"], cat["route"], ssm or None
 
 
@@ -2230,6 +2241,7 @@ def rope_thetas(cfg: ModelConfig) -> jax.Array:
     )
 
 
+@lowering.part("embed")
 def embed_tokens(cfg: ModelConfig, params: Params, ids: jax.Array) -> jax.Array:
     h = params["embed"][ids]  # [B, T, H] gather
     if cfg.embed_scale:
@@ -2239,6 +2251,7 @@ def embed_tokens(cfg: ModelConfig, params: Params, ids: jax.Array) -> jax.Array:
     return h
 
 
+@lowering.part("head")
 def head_apply(
     cfg: ModelConfig, params: Params, h: jax.Array, valid_len: jax.Array,
     logit_positions: Optional[jax.Array] = None,

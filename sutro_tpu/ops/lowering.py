@@ -22,6 +22,7 @@ info`). Process-wide like the jit caches it mirrors.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Dict
 
@@ -41,6 +42,34 @@ SSM_STATE_READ = "ssm_state_read"
 #: head a group, counted under its own name
 KDA_STATE_READ = "kda_state_read"
 KDA_STATE_COMMIT = "kda_state_commit"
+
+#: the PARTS of a step program: the outermost ``jax.named_scope`` of
+#: every op a step issues, so that a profiler trace's optimized HLO says
+#: which part of the model each device op belongs to (OBSERVABILITY.md
+#: "Parts of a step"; ``perfbench/trace_parts.py`` reads them back and
+#: keeps its own copy of this tuple, which a test holds equal). The
+#: scopes of a kind (``attn_mixer``, ``moe_ffn``, ``kda_commit``, ...)
+#: nest inside their part. What a step loop does outside every part
+#: (token buffers, counters, a scan's carry) stays unnamed and is
+#: measured as such (``device_unnamed_share``).
+PARTS = ("embed", "mixer", "ffn", "cache", "head", "sample")
+
+
+@contextlib.contextmanager
+def part(name: str):
+    """The scope of one of ``PARTS``: a context manager, or a decorator
+    of a function whose whole body is that part (a new scope a call: a
+    ``jax.named_scope`` object used as a decorator keeps ONE saved name
+    stack, which a function that calls itself, ``kvcache.write_kv``,
+    would overwrite and leak). Metadata only: the compiled program is
+    the same instructions. A scope is debug info, which the persistent
+    compile cache's key leaves out: a program cached before a scope
+    moved keeps its old names until the cache is cleared
+    (OBSERVABILITY.md "Parts of a step")."""
+    assert name in PARTS, name
+    with jax.named_scope(name):
+        yield
+
 
 _lock = threading.Lock()
 _counts: Dict[str, Dict[str, int]] = {

@@ -5,6 +5,9 @@ service (temperature / top_p / top_k; /root/reference/sutro/sdk.py:202-216
 payload) plus the logit-mask hook used by schema-constrained decoding
 (engine/constrain/): a boolean ``allowed`` mask computed host-side from the
 token FSM is applied before sampling, guaranteeing schema-valid JSON.
+``unpack_mask`` turns the bit-packed form the masks travel in back into it.
+
+Everything here is the ``sample`` part of a step (``lowering.PARTS``).
 
 Everything is jit-safe and static-shape; greedy is the temperature==0.0
 special case folded into the same compiled fn (lax.cond-free: we use a
@@ -18,11 +21,23 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .lowering import part
+
 NEG_INF = -1e30
 # widest nucleus/top-k head considered for sampling (see sample())
 NUCLEUS_CAP = 256
 
 
+@part("sample")
+def unpack_mask(packed: jax.Array, vocab_size: int) -> jax.Array:
+    """[B, V] bool from ``np.packbits(mask, axis=1)``'s [B, ceil(V / 8)]
+    uint8: FSM masks and penalty seen-bits travel host->device bit-packed
+    (8x less transfer on the per-step critical path of constrained
+    decoding)."""
+    return jnp.unpackbits(packed, axis=1, count=vocab_size).astype(bool)
+
+
+@part("sample")
 def apply_penalties(
     logits: jax.Array,      # [B, V] float32 (raw, pre-temperature)
     seen_rep: jax.Array,    # [B, V] bool — repetition scope: PROMPT +
@@ -59,6 +74,7 @@ def apply_penalties(
     return logits - (frequency[:, None] * counts).astype(dt)
 
 
+@part("sample")
 def sample(
     logits: jax.Array,                  # [B, V] float32 OR bfloat16
     key: jax.Array,
@@ -188,6 +204,7 @@ def sample(
     return jnp.where(temperature <= 0.0, greedy_tok, sampled).astype(jnp.int32)
 
 
+@part("sample")
 def cumulative_logprob(
     logits: jax.Array, token: jax.Array
 ) -> jax.Array:
