@@ -888,6 +888,20 @@ class ModelRunner:
         telemetry.KDA_STATE_BYTES_TOTAL.inc(commit, "commit")
         telemetry.KDA_STATE_BYTES_NEEDED_TOTAL.inc(slot * (reads + commits))
 
+    @staticmethod
+    def count_sample(temperature) -> None:
+        """A dispatch that samples (a masked step, a window, an
+        admission group's first tokens), by the side of
+        ``ops.sampling.sample``'s cond the device takes: the SAME
+        predicate on the host's copy of the [B] temperatures the
+        program is given, padding rows included."""
+        if telemetry.ENABLED:
+            telemetry.SAMPLE_DISPATCHES_TOTAL.inc(
+                1.0,
+                "argmax" if np.all(np.asarray(temperature) <= 0.0)
+                else "drawn",
+            )
+
     def _count_latent(self, form: str, past_len=None, steps: int = 1) -> None:
         """A dispatch of a model of latent layers, by the form its
         attention takes (``transformer.mla_mixer``): "expanded" with no
@@ -1542,6 +1556,7 @@ class ModelRunner:
             )
         self._count_state_commit("window", B)
         self._count_latent("absorbed", past_len)
+        self.count_sample(temperature)
         self._count_kv_pages(past_len, page_table, 1, pfx)
         self._bind_window(page_table, past_len, np.ones((B,), np.int32))
         tok, logp, self.cache, self._route_dev, ok = self._decode_jit(
@@ -1849,6 +1864,7 @@ class ModelRunner:
         # tokens; whoever fetches the tokens fetches them
         self._count_state_commit("window", B, steps)
         self._count_latent("absorbed", past_len, steps)
+        self.count_sample(temperature)
         self._count_kv_pages(past_len, page_table, steps, pfx)
         self._bind_window(page_table, past_len, np.full((B,), steps))
         toks, logps, self.cache, self.window_route = self._decode_multi_jit(
@@ -2095,6 +2111,7 @@ class ModelRunner:
             top_k = np.zeros((B,), np.int32)
         self._count_kv_pages(past_len, page_table, steps, pfx)
         self._count_latent("absorbed", past_len, steps)
+        self.count_sample(temperature)
         toks, logps, wk, wv = self._decode_window_jit(
             self.params,
             self.cache,

@@ -448,20 +448,23 @@ class _DecodeFacts(NamedTuple):
 # masked step costs _STEP_COST, device and host together, and a window
 # K + _WINDOW_HOST: the window's tokens a second over the step's are
 # E * _STEP_COST / (K + _WINDOW_HOST). The two constants are the classify
-# cell's, read again once the masks travelled bit-packed (PERF.md section
-# 6, PR 48, phase_table.py of the cell on one v5e: a masked step 29.6 ms
-# of device, which writes K/V a step and samples under the mask, and
-# 11.3 ms of host round it, 15.1 before; the same cell held on windows, a
-# window of 8 166.1 ms = 20.8 ms a step and 14.1 ms round it, the 2.1 ms
-# of its commit among them; the fast-forward probe, which runs ahead of
-# either, in neither): with every row constrained and K = 8 the line lies
-# at E ~ 4.4, p ~ 0.82. Only a batch near the line feels them: at p = 0 a
-# window is worth a quarter of the steps it displaces, at p = 1 nearly
-# twice.
-_STEP_COST = 1.97
-_WINDOW_HOST = 0.68
+# cell's, read again once a batch of greedy rows was sampled by its argmax
+# alone (PERF.md section 6, PR 53, part_table.py and phase_table.py of the
+# cell on one v5e, the profiler on: a masked step 22.3 ms of device, which
+# writes K/V a step and takes the argmax under the mask, 29.6 while it
+# paid for the sampler's exact head, and 9.5 ms of host round it, an
+# iteration 31.8 ms; the same cell held on windows, a window of 8
+# 162.7 ms of device = 20.3 ms a step, 20.8 with the approximate head a
+# greedy window no longer sorts, and 11.6 ms round it, the 2.1 ms of its
+# commit among them; the fast-forward probe, which runs ahead of either,
+# in neither): with every row constrained and K = 8 the line lies at
+# E ~ 5.5, p ~ 0.89 (E ~ 4.4, p ~ 0.82 at PR 48's 1.97 and 0.68). Only a
+# batch near the line feels them: at p = 0 a window is worth a fifth of
+# the steps it displaces, at p = 1 one and a half times.
+_STEP_COST = 1.57
+_WINDOW_HOST = 0.57
 # a batch near the line stays where it is: the other path has to be worth
-# a tenth more (p under ~0.79 leaves the windows, over ~0.85 returns)
+# a tenth more (p under ~0.86 leaves the windows, over ~0.92 returns)
 _SWITCH_GAIN = 1.1
 
 
@@ -2163,6 +2166,8 @@ class ContinuousBatcher:
             ]
         else:
             self._key, sub = jax.random.split(self._key)
+        # static: a stand-in runner (tests, host benches) has no counters
+        ModelRunner.count_sample(temps)
         with self.timer.time("admit_sample"):
             tok, logp = _admit_sample_jit(
                 logits, sub, temps, top_p, top_k, allowed, row_seeds

@@ -9,9 +9,25 @@ token FSM is applied before sampling, guaranteeing schema-valid JSON.
 
 Everything here is the ``sample`` part of a step (``lowering.PARTS``).
 
-Everything is jit-safe and static-shape; greedy is the temperature==0.0
-special case folded into the same compiled fn (lax.cond-free: we use a
-where on the temperature scalar so one executable serves both).
+Everything is jit-safe and static-shape, and one executable serves greedy
+and drawing rows alike: per ROW, a ``where`` on the temperature picks the
+argmax or the draw. ``sample`` also holds three ``lax.cond``s. Each
+predicate is over the WHOLE batch, is read on the device from the
+function's own operands, and guards work whose result no row of such a
+batch reads, so every branch returns the ids the straight-line code would:
+
+- ``all(temperature <= 0)``: every row is greedy and the batch is sampled
+  by its argmax alone (no top-k head, no cumulative sum, no draw).
+  Otherwise the whole stochastic path runs, for every row. The scaled
+  logits and their logsumexp are made BEFORE this cond, for both sides
+  (``sample`` says why).
+- inside that path, ``any(0 < top_k <= 32)`` (plain rows; a constrained
+  batch takes the exact head statically): the exact ``lax.top_k`` head
+  where a small top-k would feel ``approx_max_k``'s recall, else the
+  approximate one.
+- inside that path, ``all(filtered | greedy)`` (batches without
+  ``row_seeds``): the float32 full-vocabulary categorical runs only where
+  some drawing row has both filters disabled.
 """
 
 from __future__ import annotations
@@ -112,7 +128,44 @@ def sample(
     scaled = logits / jnp.maximum(temperature, 1e-6)[:, None].astype(
         logits.dtype
     )
+    # f32 accumulation regardless of input dtype (a bf16 accumulator
+    # over 150k terms drifts); the convert fuses into the reduction
+    lse = jax.scipy.special.logsumexp(
+        scaled.astype(jnp.float32), axis=-1, keepdims=True
+    )
 
+    # A batch whose rows are all greedy reads nothing of the head, its
+    # probabilities or the draw: the device takes the argmax side alone
+    # (a masked step of 64 constrained rows over a 152k vocabulary paid
+    # 7.4 ms for the exact head; PERF.md section 6, PR 53). ``scaled`` and
+    # ``lse`` stay OUTSIDE the cond on purpose: here XLA writes the scaled
+    # copy the head's custom call needs from the same pass that sums the
+    # exponentials, reading the vocabulary product as the model's head
+    # left it; inside a branch it reads the float32 logits once a
+    # reduction and writes ``scaled`` in a pass of its own (+0.2 ms a step
+    # of the 4B generate cell, same section). A greedy batch pays that
+    # one fused pass (~0.1 ms) and the argmax.
+    return jax.lax.cond(
+        jnp.all(temperature <= 0.0),
+        # of the SCALED logits, as ``greedy_tok`` below: the divide can
+        # round two neighbouring values into one, and the tie goes to the
+        # lower id on both sides
+        lambda: jnp.argmax(scaled, axis=-1).astype(jnp.int32),
+        lambda: _drawn(
+            scaled, lse, key, temperature, top_p, top_k,
+            exact_head=allowed is not None, row_seeds=row_seeds,
+        ),
+    )
+
+
+def _drawn(
+    scaled, lse, key, temperature, top_p, top_k, *, exact_head, row_seeds
+):
+    """``sample`` for a batch in which some row draws: [B] ids from the
+    masked, temperature-scaled ``scaled`` [B, V] and its float32
+    logsumexp ``lse`` [B, 1]; greedy rows among them still take their
+    argmax."""
+    B, V = scaled.shape
     # A full [B, V] argsort is pathologically slow on TPU (sorting networks
     # over 150k lanes). Filtered rows instead use the top NUCLEUS_CAP
     # logits — the nucleus/top-k filters only ever *keep* a head of the
@@ -141,7 +194,7 @@ def sample(
             scaled, K, recall_target=0.95, aggregate_to_topk=True
         )
 
-    if allowed is not None:
+    if exact_head:
         top_vals, top_idx = _exact()
     else:
         top_vals, top_idx = jax.lax.cond(
@@ -149,11 +202,6 @@ def sample(
         )
     greedy_tok = jnp.argmax(scaled, axis=-1).astype(jnp.int32)
 
-    # f32 accumulation regardless of input dtype (a bf16 accumulator
-    # over 150k terms drifts); the convert fuses into the reduction
-    lse = jax.scipy.special.logsumexp(
-        scaled.astype(jnp.float32), axis=-1, keepdims=True
-    )
     top_vals = top_vals.astype(jnp.float32)           # [B, K] — tiny
     probs = jnp.exp(top_vals - lse)                   # exact probabilities
 

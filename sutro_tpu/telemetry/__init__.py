@@ -374,6 +374,17 @@ LATENT_ATTENTION_DISPATCHES_TOTAL = REGISTRY.counter(
     unit="dispatches",
     max_series=4,
 )
+SAMPLE_DISPATCHES_TOTAL = REGISTRY.counter(
+    "sutro_sample_dispatches_total",
+    "Dispatches that sample (a masked decode step, a fused or speculative "
+    "window, an admission group's first tokens), by the side of "
+    "ops.sampling.sample's cond the device took: argmax (every row of "
+    "the batch at temperature 0: no head, no cumulative sum, no draw) or "
+    "drawn (some row draws: the whole stochastic path, for every row)",
+    labels=("head",),  # argmax | drawn
+    unit="dispatches",
+    max_series=4,
+)
 SPARSE_ATTENTION_DISPATCHES_TOTAL = REGISTRY.counter(
     "sutro_sparse_attention_dispatches_total",
     "Dispatches of a model whose latent layers have an indexer (learned "

@@ -63,8 +63,8 @@ PLAIN = _DecodeFacts(
 )
 GREEDY_SCHEMA = PLAIN._replace(has_constraint=True, all_greedy=True)
 # the same batch once its fast-forward probe has disengaged, by the
-# share of its unmasked tokens the FSMs accept: the line lies at ~0.82
-# for a window of 8, the band where a batch stays where it is ~0.79-0.85
+# share of its unmasked tokens the FSMs accept: the line lies at ~0.89
+# for a window of 8, the band where a batch stays where it is ~0.86-0.92
 PROBED = dict(probed=True)
 
 
@@ -148,38 +148,40 @@ CHOICES = {
     ),
     # inside the band a batch stays where it is
     "probed-just-below-the-line-on-windows": (
-        PROBED, _accepting(0.79), 0, "window",
+        PROBED, _accepting(0.865), 0, "window",
     ),
     "probed-just-above-the-line-on-steps": (
-        PROBED, _accepting(0.83, stepping=True), 0, "single",
+        PROBED, _accepting(0.895, stepping=True), 0, "single",
     ),
     "probed-just-below-the-line-on-steps": (
-        PROBED, _accepting(0.79, stepping=True), 0, "single",
+        PROBED, _accepting(0.865, stepping=True), 0, "single",
     ),
     "probed-just-above-the-line-on-windows": (
-        PROBED, _accepting(0.83), 0, "window",
+        PROBED, _accepting(0.895), 0, "window",
     ),
-    # the band's edges as the constants of PR 48 put them (2.05 and 0.77
-    # kept a batch on windows at 0.78 and sent one back to them at 0.845)
+    # the band's edges as the constants before PR 53 put them (1.97 and
+    # 0.68 kept a batch on windows at 0.79 and sent one back to them at
+    # 0.87)
     "probed-at-the-band's-lower-edge-on-windows": (
-        PROBED, _accepting(0.78), 0, "single",
+        PROBED, _accepting(0.79), 0, "single",
     ),
     "probed-at-the-band's-upper-edge-on-steps": (
-        PROBED, _accepting(0.845, stepping=True), 0, "single",
+        PROBED, _accepting(0.87, stepping=True), 0, "single",
     ),
     "probed-under-the-band-on-windows": (
-        PROBED, _accepting(0.75), 0, "single",
+        PROBED, _accepting(0.83), 0, "single",
     ),
     "probed-over-the-band-on-steps": (
-        PROBED, _accepting(0.87, stepping=True), 0, "window",
+        PROBED, _accepting(0.93, stepping=True), 0, "window",
     ),
-    # a window keeps a plain row's every token: refused rows among as
-    # many plain rows do not take the batch off windows, three in four do
+    # a window keeps a plain row's every token: one refused row among
+    # three plain ones does not take the batch off windows, refused rows
+    # among as many plain ones do
     "probed-one-refused-row-in-four": (
         PROBED, _accepting(0.0, constrained=0.25), 0, "window",
     ),
     "probed-refused-rows-half-the-batch": (
-        PROBED, _accepting(0.0, constrained=0.5), 0, "window",
+        PROBED, _accepting(0.0, constrained=0.5), 0, "single",
     ),
     "probed-refused-rows-three-in-four": (
         PROBED, _accepting(0.0, constrained=0.75), 0, "single",

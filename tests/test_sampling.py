@@ -272,3 +272,225 @@ def test_apply_penalties_preserves_dtype():
         repetition=jnp.full((B,), 1.2, jnp.float32),
     )
     assert out.dtype == jnp.bfloat16
+
+
+# ---------------------------------------------------------------------
+# A batch whose rows are all greedy is sampled by its argmax alone
+# ---------------------------------------------------------------------
+
+
+def _sample_before(
+    logits, key, *, temperature, top_p, top_k=0, allowed=None,
+    row_seeds=None,
+):
+    """``sample`` as it stood before it chose, by ``lax.cond`` on
+    ``all(temperature <= 0)``, between the argmax alone and the whole
+    stochastic path: the straight-line body, kept here as the reference
+    every batch must still match id for id."""
+    from sutro_tpu.ops.sampling import NEG_INF, NUCLEUS_CAP
+
+    B, V = logits.shape
+    if allowed is not None:
+        logits = jnp.where(allowed, logits, jnp.asarray(NEG_INF, logits.dtype))
+    temperature = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
+    top_p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
+    top_k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None].astype(
+        logits.dtype
+    )
+    K = min(NUCLEUS_CAP, V)
+
+    def _exact():
+        return jax.lax.top_k(scaled, K)
+
+    def _approx():
+        return jax.lax.approx_max_k(
+            scaled, K, recall_target=0.95, aggregate_to_topk=True
+        )
+
+    if allowed is not None:
+        top_vals, top_idx = _exact()
+    else:
+        top_vals, top_idx = jax.lax.cond(
+            jnp.any((top_k > 0) & (top_k <= 32)), _exact, _approx
+        )
+    greedy_tok = jnp.argmax(scaled, axis=-1).astype(jnp.int32)
+    lse = jax.scipy.special.logsumexp(
+        scaled.astype(jnp.float32), axis=-1, keepdims=True
+    )
+    top_vals = top_vals.astype(jnp.float32)
+    probs = jnp.exp(top_vals - lse)
+    ranks = jnp.arange(K, dtype=jnp.int32)[None, :]
+    k_active = top_k > 0
+    k_eff = jnp.where(k_active, jnp.minimum(top_k, K), K)[:, None]
+    keep_k = ranks < k_eff
+    cum = jnp.cumsum(probs, axis=-1)
+    keep_p = (cum - probs) < top_p[:, None]
+    vals = jnp.where(keep_k & keep_p, top_vals, NEG_INF)
+    filtered = k_active | (top_p < 1.0)
+    if row_seeds is not None:
+        keys = jax.vmap(lambda s: jax.random.fold_in(key, s))(row_seeds)
+        g_head = jax.vmap(
+            lambda k, lg: jax.random.gumbel(k, lg.shape, jnp.float32)
+        )(keys, vals)
+        choice = jnp.argmax(vals + g_head, axis=-1)
+        g_full = jax.vmap(
+            lambda k, lg: jax.random.gumbel(
+                jax.random.fold_in(k, 1), lg.shape, jnp.float32
+            )
+        )(keys, scaled)
+        full_tok = jnp.argmax(scaled + g_full, axis=-1)
+    else:
+        choice = jax.random.categorical(key, vals, axis=-1)
+        full_tok = jax.lax.cond(
+            jnp.all(filtered | (temperature <= 0.0)),
+            lambda: jnp.zeros((B,), jnp.int32),
+            lambda: jax.random.categorical(
+                jax.random.fold_in(key, 1),
+                scaled.astype(jnp.float32),
+                axis=-1,
+            ).astype(jnp.int32),
+        )
+    head_tok = jnp.take_along_axis(top_idx, choice[:, None], axis=1)[:, 0]
+    sampled = jnp.where(filtered, head_tok, full_tok)
+    return jnp.where(temperature <= 0.0, greedy_tok, sampled).astype(jnp.int32)
+
+
+_B, _V = 8, 640
+_TEMPERATURES = {
+    "all_greedy": np.zeros(_B, np.float32),
+    "mixed": np.asarray([0.0, 0.7, 0.0, 1.3, 0.0, 0.0, 0.9, 0.0], np.float32),
+    "all_drawing": np.linspace(0.5, 1.5, _B).astype(np.float32),
+}
+
+
+def _batch(dtype, masked, seeded, filters):
+    """One batch's ``(logits, kwargs)`` without its temperatures."""
+    rng = np.random.default_rng(53)
+    logits = jnp.asarray(rng.normal(0, 2, (_B, _V)), jnp.float32).astype(dtype)
+    kw = {
+        "top_p": np.ones(_B, np.float32),
+        "top_k": np.zeros(_B, np.int32),
+        "allowed": None,
+        "row_seeds": None,
+    }
+    if filters:
+        # a row of every kind: nucleus alone, a small top-k (the exact
+        # head's cond), a wide one, both, and rows with neither
+        kw["top_p"] = np.asarray(
+            [0.9, 1.0, 1.0, 0.5, 1.0, 0.95, 1.0, 1.0], np.float32
+        )
+        kw["top_k"] = np.asarray([0, 4, 100, 8, 0, 0, 0, 300], np.int32)
+    if masked:
+        allowed = rng.random((_B, _V)) < 0.02
+        allowed[:, 5] = True           # no row without a token
+        allowed[3] = False
+        allowed[3, 17] = True          # a row of one token
+        kw["allowed"] = jnp.asarray(allowed)
+    if seeded:
+        kw["row_seeds"] = jnp.asarray(
+            rng.integers(0, 2**31 - 1, _B), jnp.int32
+        )
+    return logits, kw
+
+
+_CASES = [
+    pytest.param(
+        dtype, masked, seeded, filters,
+        id="-".join((
+            dtype, "masked" if masked else "plain",
+            "row_seeds" if seeded else "one_key",
+            "top_k_top_p" if filters else "unfiltered",
+        )),
+    )
+    for dtype in ("float32", "bfloat16")
+    for masked in (False, True)
+    for seeded in (False, True)
+    for filters in (False, True)
+]
+
+
+@pytest.mark.parametrize("batch", list(_TEMPERATURES))
+@pytest.mark.parametrize("dtype,masked,seeded,filters", _CASES)
+def test_sample_returns_what_it_returned_before(
+    dtype, masked, seeded, filters, batch
+):
+    """An all-greedy, a mixed and an all-drawing batch each return, id
+    for id on the same key, what the straight-line body returned."""
+    logits, kw = _batch(dtype, masked, seeded, filters)
+    kw["temperature"] = _TEMPERATURES[batch]
+    for k in (0, 7):
+        key = jax.random.PRNGKey(k)
+        now = jax.jit(sample)(logits, key, **kw)
+        before = jax.jit(_sample_before)(logits, key, **kw)
+        assert now.dtype == jnp.int32 and now.shape == (_B,)
+        np.testing.assert_array_equal(np.asarray(now), np.asarray(before))
+
+
+@pytest.mark.parametrize("dtype,masked,seeded,filters", _CASES)
+def test_an_all_greedy_batch_is_the_argmax_of_its_masked_logits(
+    dtype, masked, seeded, filters
+):
+    logits, kw = _batch(dtype, masked, seeded, filters)
+    kw["temperature"] = _TEMPERATURES["all_greedy"]
+    x = np.asarray(logits.astype(jnp.float32))
+    if masked:
+        x = np.where(np.asarray(kw["allowed"]), x, -np.inf)
+    want = x.argmax(-1)
+    # the key is not read: any two give the same ids
+    for k in (0, 1):
+        got = sample(logits, jax.random.PRNGKey(k), **kw)
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+_HEAD_AND_DRAW = (
+    "top_k", "approx_top_k", "sort", "cumsum", "cumlogsumexp",
+    "random_bits", "random_wrap", "random_unwrap", "random_seed",
+    "random_fold_in", "threefry2x32",
+)
+
+
+def _primitives(jaxpr):
+    """Names of every primitive of ``jaxpr``, sub-jaxprs included."""
+    from jax.extend import core as jex_core
+
+    out = set()
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jex_core.Jaxpr):
+                    out |= _primitives(sub)
+    return out
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
+@pytest.mark.parametrize("seeded", [False, True], ids=["one_key", "row_seeds"])
+def test_the_greedy_branch_holds_no_head_and_no_draw(masked, seeded):
+    """On the jaxpr: ``sample`` is ONE cond at its top level, whose
+    greedy branch (the one ``all(temperature <= 0)`` selects) is an
+    argmax and nothing else: no head, no probabilities, no cumulative
+    sum, no draw; the other branch holds all of them. Beside the cond,
+    for both sides: the mask, the divide and the logsumexp of the scaled
+    logits (``sample`` says why they stay there)."""
+    logits, kw = _batch("float32", masked, seeded, True)
+    kw["temperature"] = _TEMPERATURES["mixed"]
+    jaxpr = jax.make_jaxpr(
+        lambda lg, key, kw: sample(lg, key, **kw)
+    )(logits, jax.random.PRNGKey(0), kw).jaxpr
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    # no head and no draw beside the cond
+    assert not _primitives(
+        jaxpr.replace(eqns=[e for e in jaxpr.eqns if e is not conds[0]])
+    ) & set(_HEAD_AND_DRAW)
+    # ``lax.cond(pred, true_fn, false_fn)`` indexes its branches by the
+    # predicate: branches[1] is the one taken when it holds
+    drawn, greedy = (_primitives(b.jaxpr) for b in conds[0].params["branches"])
+    assert greedy == {"argmax"}, greedy
+    for name in ("top_k", "cumsum", "random_bits", "exp"):
+        assert name in drawn, (name, sorted(drawn))
+    if not masked:
+        assert "approx_top_k" in drawn
