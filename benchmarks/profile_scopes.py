@@ -17,7 +17,9 @@ takes while its windows are being refused, ``_decode_jit``). Each device
 op's self time goes to the PART its ``op_name`` opens with
 (``sutro_tpu/ops/lowering.py`` ``PARTS``) and, under it, to the named
 scopes inside (``mixer/attn_window``, ``ffn/moe_ffn/shared_expert``,
-``mixer/mla_mixer/mla_absorb/dsa_attend``, ``cache/kda_commit``;
+``mixer/mla_mixer/mla_absorb/dsa_attend``, ``cache/kda_commit``, and for a
+residual stream of several lanes ``mixer/hc_coeff``, ``ffn/hc_sinkhorn``,
+``mixer/hc_read``, ``ffn/hc_write``, ``mixer/mla_mixer/mla_yarn``;
 OBSERVABILITY.md "Parts of a step" lists them), read from the trace's
 OWN optimized HLO by ``perfbench/trace_parts.py``: the reducer of the
 benchmark's ``decode_*_ms_per_step`` metrics and of

@@ -1034,6 +1034,11 @@ def engine_info(model: Optional[str]) -> None:
             f"state_bytes_per_page="
             f"{m.num_conv_layers * m.conv_state_len * m.hidden_size * width}"
         )
+        if m.hc_mult > 1:
+            click.echo(
+                f"hc_mult={m.hc_mult} hc_sublayers={m.hc_sublayers} (a "
+                "residual stream of several lanes, mixed a token a sublayer)"
+            )
         if m.state_kind:
             from .engine.kvcache import state_bytes_per_slot
 

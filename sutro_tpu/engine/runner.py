@@ -258,6 +258,14 @@ class ModelRunner:
                 f"{mcfg.name} keeps a latent row a token (mla layers): the "
                 "latent pool runs on one chip, not under a mesh"
             )
+        if mesh is not None and mcfg.hc_mult > 1:
+            # the sharding rules and the pipeline's stages know one lane
+            # [B, T, H] (parallel/pipeline.py sends it between stages)
+            raise NotImplementedError(
+                f"{mcfg.name} keeps a residual stream of {mcfg.hc_mult} "
+                "lanes (hc_mult): it runs on one chip, not under a mesh "
+                "or pipeline stages"
+            )
         if mesh is not None and mcfg.experts_held != mcfg.moe_experts:
             # the share IS one chip's part of a layer; under a mesh the
             # sharding rules would split the held stack again and the
@@ -421,6 +429,23 @@ class ModelRunner:
         m = self.mcfg
         return int(
             rows * m.num_state_layers * m.state_rows * m.state_inner
+            * jnp.dtype(self.ecfg.activation_dtype).itemsize
+        )
+
+    def stream_bytes(self, tokens: int) -> int:
+        """Bytes the residual stream of ``tokens`` tokens MUST move
+        through a forward of a model whose stream is several lanes
+        (``ModelConfig.hc_mult``; 0 for any other): every sublayer reads
+        the n lanes once and writes them once, ``2 n C`` elements a
+        token. A tile of tokens (28 KB a token at 4 lanes of 3,584)
+        stays on the chip between the coefficients, the read and the
+        mix, and the sublayer's input and output are the sublayer's own
+        traffic: what the program moves beyond this is its loss. THE
+        definition: the spans' ``hc_stream_bytes`` and the benchmark's
+        readers (``perfbench/MHC_LAYERS.md``) take it from here."""
+        m = self.mcfg
+        return int(
+            tokens * m.hc_sublayers * 2 * m.hc_mult * m.hidden_size
             * jnp.dtype(self.ecfg.activation_dtype).itemsize
         )
 
@@ -684,6 +709,16 @@ class ModelRunner:
             in_use += (1 + default_state_slots(self.ecfg, want)) * (
                 state_bytes_per_slot(self.mcfg, self.ecfg)
             ) + self._window_state_bytes()
+        if self.mcfg.hc_mult > 1:
+            # a prefill chunk's stream is no transient the reserve was
+            # sized for: a sublayer holds the lanes it reads, the lanes
+            # it writes and a float32 slab or two of the mix (at 4 lanes
+            # of 3,584 over 4,096 tokens 117 MB a copy)
+            in_use += 4 * self.ecfg.prefill_batch_size * (
+                min(self.ecfg.prefill_chunk, self.ecfg.max_model_len)
+                * self.mcfg.hc_mult * self.mcfg.hidden_size
+                * jnp.dtype(self.ecfg.activation_dtype).itemsize
+            )
         reserve = int(limit * HBM_RESERVE_FRACTION)
         page = self._page_bytes_per_device(dtype)
         avail = limit - in_use - reserve
@@ -804,6 +839,10 @@ class ModelRunner:
                 0 if self.cache.ik_pages is None
                 else self.cache.ik_pages.nbytes // self.cache.num_pages
             ),
+            # the residual stream's lanes (1: the plain add) and the
+            # sublayers that each mix them a token
+            "hc_mult": int(self.mcfg.hc_mult),
+            "hc_sublayers": int(self.mcfg.hc_sublayers),
             "kv_heads": int(self.mcfg.num_kv_heads),
             "head_dim": int(self.mcfg.head_dim),
             "kv_dtype_bytes": (

@@ -1504,6 +1504,7 @@ class ContinuousBatcher:
                     "wave": self._wave_seq,
                     **self._state_attrs(len(batch)),
                     **self._kv_attrs(len(r.prompt_ids) for r in reqs),
+                    **self._stream_attrs("prefill", tokens),
                 }
             with self.timer.time("prefill"):
                 if len(batch) == 1:
@@ -1711,6 +1712,7 @@ class ContinuousBatcher:
                 "tokens": int(len(seg)), "wave": self._wave_seq,
                 **self._state_attrs(1),
                 **self._kv_attrs((s.prefill_pos + len(seg),)),
+                **self._stream_attrs("prefill", len(seg)),
             }
         with self.timer.time("prefill"):
             logits, route = self.runner.prefill_batch_at(
@@ -2704,6 +2706,23 @@ class ContinuousBatcher:
             attrs["kda_state_bytes"] = self.runner.state_matrix_bytes(rows)
         return attrs
 
+    def _stream_attrs(self, form: str, tokens: int, steps: int = 1):
+        """Span attr of a dispatch of a model whose residual stream is
+        several lanes (``ModelConfig.hc_mult``): the bytes the stream of
+        its ``tokens`` real tokens has to move (``form``: prefill |
+        decode; ``steps`` forward steps), counted as it is read. Nothing
+        for any other model."""
+        sublayers = getattr(self.runner.mcfg, "hc_sublayers", 0)
+        if not sublayers:
+            return {}
+        needed = self.runner.stream_bytes(tokens)
+        # the callers test the switch too; sutro_tpu.analysis wants every
+        # count behind it in the function that makes it
+        if self._tel_on:
+            telemetry.HC_SUBLAYERS_TOTAL.inc(float(sublayers * steps), form)
+            telemetry.HC_STREAM_BYTES_NEEDED_TOTAL.inc(float(needed))
+        return {"hc_stream_bytes": needed}
+
     def _kv_attrs(self, ctx) -> Dict[str, float]:
         """Span attrs of a dispatch of a model that keeps K/V a pool a
         kind: the mean over its rows of the tokens a full layer reads
@@ -2750,6 +2769,7 @@ class ContinuousBatcher:
                 ),
                 **self._state_attrs(n),
                 **self._kv_attrs(b.past_len[i] for i in b.active),
+                **self._stream_attrs("decode", n * steps, steps),
                 **self._route_attrs.get("decode_window", {}),
             }
             f = b.facts

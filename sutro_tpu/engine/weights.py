@@ -238,6 +238,13 @@ def _load_mixed(mcfg: ModelConfig, get, dtype) -> Dict[str, Any]:
             "layers (the granitemoehybrid / solar_open2 tensor names) is "
             "not written; the model runs on seeded random weights"
         )
+    if mcfg.hc_mult > 1:
+        raise NotImplementedError(
+            f"{mcfg.name}: loading a checkpoint of a model whose residual "
+            "stream is several lanes (hc_mult; the xing4_0 tensor names "
+            "of a sublayer's phi, bias and alphas) is not written; the "
+            "model runs on seeded random weights"
+        )
     stacks: Dict[str, Dict[str, list]] = {}
 
     def put(kind: str, name: str, arr: np.ndarray) -> None:

@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers twelve
+config-driven decoder-only transformer (models/transformer.py) covers thirteen
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -76,6 +76,18 @@ families:
   chip of the sixteen that share every layer of the first of six
   pipeline stages: layers 0-7, experts 0-19 of each layer's 320, an
   eighth of the vocabulary
+- Xing4.0 (29b-a4b; ``model_type`` xing4_0): JoyAI's key set at other
+  numbers (a query latent of 768, two leading dense layers of 9,216, 64
+  SwiGLU experts of 1,024 top-4 beside a shared one, the chosen scores
+  over their sum times 2) with two things no other family has: the
+  RESIDUAL STREAM is ``hc_mult`` = 4 lanes a token, which every sublayer
+  reads through sigmoid weights, writes through weights in (0, 2) and
+  mixes by a 4 x 4 matrix a token that twenty Sinkhorn passes make doubly
+  stochastic (manifold-constrained hyper-connections:
+  models/transformer.py ``hc_sublayer``); and YaRN (factor 64 over 4,096
+  positions) on the latent layers' 64-wide rotary part, with the score's
+  scale times ``(0.1 ln 64 + 1)^2``. ``-l7`` is the first of six
+  pipeline stages: layers 0-6, every expert, the whole vocabulary
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -84,6 +96,7 @@ loading real checkpoints (engine/weights.py validates shapes against these).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Optional, Tuple
 
 
@@ -259,6 +272,24 @@ class ModelConfig:
     # what cos and sin are multiplied by under YaRN, where the published
     # file states it (None: 0.1 ln(factor) + 1)
     rope_attention_factor: Optional[float] = None
+    # YaRN on a latent layer (the DeepSeek-V3 family's form): the softmax
+    # scale is multiplied by ``(0.1 mscale_all_dim ln(factor) + 1)^2``
+    # (0: it is not), and cos and sin by ``rope_attention_factor`` =
+    # m(factor, mscale) / m(factor, mscale_all_dim), 1.0 where both are 1
+    rope_mscale_all_dim: float = 0.0
+    # Manifold-constrained hyper-connections (mHC, arXiv:2512.24880; 1:
+    # the plain ``h += f(norm h)``): the residual stream of a token is
+    # ``hc_mult`` lanes ``X [n, C]``; every sublayer reads its input as a
+    # sigmoid-weighted sum of the lanes, writes its output to each lane
+    # under a weight in (0, 2) and mixes the lanes by a 4 x 4 matrix a
+    # token that ``hc_sinkhorn_iters`` column-then-row normalisations
+    # (``hc_eps`` in each denominator) of ``exp(clip(logits, +-
+    # hc_res_clamp))`` make doubly stochastic
+    # (models/transformer.py ``hc_sublayer``)
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
     # activation: "silu" (SwiGLU) | "gelu" (GeGLU) | "swiglu_oss" (clamped)
     # | "relu2" (relu(x)^2: the ungated experts of Nemotron-H)
     activation: str = "silu"
@@ -279,6 +310,18 @@ class ModelConfig:
     # draws the embedding's elements at unit variance instead, so that
     # a row's hidden state stays its token's
     seeded_unit_embedding: bool = False
+    # seeded weights alone: what a routed expert's second matrix is drawn
+    # at, times 1 / (fan-in x router_scale^2). At 1 a sum of ``top_k``
+    # independent experts is 1 / sqrt(top_k) of the block's input and ONE
+    # selection flipped by a rounding moves it by sqrt(2) / top_k: 0.18
+    # at top-8, 0.35 at top-4, where every later router then reads a
+    # stream a sixth off and flips in turn (PERF.md section 6, PR 54).
+    # 0.5 gives a top-4 layer a top-8 layer's share a selection; the
+    # Xing4.0 presets take it, the CPU tests' ``tiny-xing-mhc`` with
+    # them, so that the tests draw under the rule the cell runs. No
+    # rule by ``moe_top_k`` for every model: that would redraw the
+    # weights of cells whose limits were set from readings
+    seeded_expert_gain: float = 1.0
 
     @property
     def q_size(self) -> int:
@@ -435,6 +478,16 @@ class ModelConfig:
     def mamba_conv_len(self) -> int:
         """Conv columns a sequence keeps a mamba layer (taps - 1)."""
         return max(self.mamba_conv - 1, 0) if self.num_mamba_layers else 0
+
+    @property
+    def hc_sublayers(self) -> int:
+        """Sublayers that each mix the lanes of the residual stream a
+        token (a layer's mixer and its FFN: ``hc_mult``); 0 for a model
+        of one lane."""
+        if self.hc_mult == 1:
+            return 0
+        both = self.mixers + self.ffns
+        return len(both) - both.count("none")
 
     @property
     def num_kda_layers(self) -> int:
@@ -721,6 +774,9 @@ def _latent_moe(name: str, layers: int = 40, *, h: int = 2048, nh: int = 32,
                 vocab: int = 129_280, theta: float = 32_000_000.0,
                 eps: float = 1e-6, index: Tuple[int, int, int] = (0, 0, 0),
                 peaked: float = 0.0, share_rows: int = 2,
+                router_scale: float = 2.5, expert_gain: float = 1.0,
+                yarn: Tuple[float, int, float, float] = (0.0, 0, 1.0, 1.0),
+                hc_mult: int = 1,
                 template: str = "chatml") -> ModelConfig:
     """DeepSeek-V3's key set (defaults: the published ``joyai_llm_flash``
     file): latent attention in every layer, rotary ``theta`` on the
@@ -734,15 +790,30 @@ def _latent_moe(name: str, layers: int = 40, *, h: int = 2048, nh: int = 32,
     (0: all). ``index`` = (``index_n_heads``, ``index_head_dim``,
     ``index_topk``) of ``glm_moe_dsa``'s indexer (zeros: none);
     ``peaked``: ``ModelConfig.seeded_peaked_attention``; ``share_rows``:
-    ``ModelConfig.moe_share_rows``. The
+    ``ModelConfig.moe_share_rows``; ``expert_gain``:
+    ``ModelConfig.seeded_expert_gain``. ``yarn`` = (``factor``,
+    ``original_max_position_embeddings``, ``mscale``, ``mscale_all_dim``)
+    of a ``rope_scaling`` of type yarn at the published ``beta_fast`` 32
+    and ``beta_slow`` 1 (factor 0: none); ``hc_mult``: the lanes of the
+    residual stream (``xing4_0``: 4, at the published 20 Sinkhorn passes,
+    eps 1e-6 and clamp 30). The
     multi-token-prediction block (``num_nextn_predict_layers`` 1) is no
     part of the next-token logits and is not built."""
+    factor, original, mscale, all_dim = yarn
+
+    def m(a: float) -> float:
+        return 0.1 * a * math.log(factor) + 1.0
+
     return ModelConfig(
         name=name, vocab_size=vocab, hidden_size=h, num_layers=layers,
         num_heads=nh, num_kv_heads=nh, head_dim=rope,
         intermediate_size=inter, norm_eps=eps, rope_theta=theta,
         qk_norm=False, tie_embeddings=False,
         layer_types=("mla",) * layers,
+        rope_scaling_factor=factor, rope_original_max=original,
+        rope_attention_factor=m(mscale) / m(all_dim) if factor else None,
+        rope_mscale_all_dim=all_dim if factor else 0.0,
+        hc_mult=hc_mult,
         q_lora_rank=q_rank, kv_lora_rank=kv_rank, qk_nope_head_dim=nope,
         qk_rope_head_dim=rope, v_head_dim=v_dim, rope_interleave=True,
         index_n_heads=index[0], index_head_dim=index[1],
@@ -753,8 +824,10 @@ def _latent_moe(name: str, layers: int = 40, *, h: int = 2048, nh: int = 32,
         moe_share_rows=share_rows,
         moe_shared_intermediate_size=moe_inter,
         router_score="sigmoid", router_select_bias=True,
-        router_renorm=True, router_scale=2.5, router_renorm_eps=1e-20,
+        router_renorm=True, router_scale=router_scale,
+        router_renorm_eps=1e-20,
         chat_template=template, seeded_unit_embedding=True,
+        seeded_expert_gain=expert_gain,
     )
 
 
@@ -807,6 +880,17 @@ _GLM5 = dict(
     # that twice the even share overflowed in one layer of four a seed,
     # and the rate took three levels 5 % apart (PERF.md section 6, PR 46)
     share_rows=4,
+)
+
+#: the published ``xing4_0`` file: hidden 3,584, 32 heads, a query latent of
+#: 768, two leading dense layers of 9,216, 64 experts of 1,024 top-4 (the
+#: chosen scores over their sum times 2), rotary base 10,000 under YaRN
+#: (factor 64 over 4,096 positions, mscale 1 = mscale_all_dim 1), four lanes
+_XING4 = dict(
+    h=3584, nh=32, q_rank=768, kv_rank=512, nope=128, rope=64, v_dim=128,
+    inter=9216, experts=64, top_k=4, moe_inter=1024, dense_layers=2,
+    vocab=131_072, theta=10_000.0, router_scale=2.0, expert_gain=0.5,
+    yarn=(64.0, 4096, 1.0, 1.0), hc_mult=4,
 )
 
 
@@ -881,6 +965,13 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         "glm-5-l5-ep16", 5, dense_layers=1, held=16, first=0,
         vocab=19_360, **_GLM5,
     ),
+    # Xing4.0-29B-A4B: as published (29,505,505,264 parameters without
+    # its multi-token-prediction block), and the FIRST of six v5e
+    # pipeline stages: layers 0-6 (both dense layers and five routed
+    # ones), every expert, the whole vocabulary (4,920,866,746
+    # parameters, 9.84 GB in bf16)
+    "xing4.0-29b-a4b": _latent_moe("xing4.0-29b-a4b", **_XING4),
+    "xing4.0-29b-a4b-l7": _latent_moe("xing4.0-29b-a4b-l7", 7, **_XING4),
     # Solar-Open2-250B: as published (250.3 B parameters), and one chip
     # of the sixteen that share every layer of the FIRST of six pipeline
     # stages: layers 0-7 (2 GQA + 6 KDA), experts 0-19 of each layer's
@@ -964,6 +1055,16 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         rope=8, v_dim=20, inter=192, experts=16, top_k=4, moe_inter=40,
         held=4, first=0, vocab=512, theta=1_000_000.0, eps=1e-5,
         index=(3, 24, 8), peaked=1.5, template="plain",
+    ),
+    # a four-lane residual stream round latent attention under YaRN
+    # (factor 8 over an original window of 32: the tests' 20-60 token
+    # prompts lie on both sides of it), a dense layer and three routed
+    # ones, every one of 8 experts held; widths unlike tiny-joyai's
+    "tiny-xing-mhc": _latent_moe(
+        "tiny-xing-mhc", 4, h=128, nh=4, q_rank=20, kv_rank=40, nope=12,
+        rope=8, v_dim=16, inter=192, experts=8, top_k=2, moe_inter=48,
+        vocab=512, theta=10_000.0, router_scale=2.0, expert_gain=0.5,
+        yarn=(8.0, 32, 1.0, 1.0), hc_mult=4, template="plain",
     ),
     # one period and a half (GQA, 3 KDA, GQA, KDA); 4 delta-rule heads of
     # 16 (unlike the attention's 4 heads of 32 over 2 KV heads), pairs of

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .configs import ModelConfig
+from .configs import FFN_KINDS, ModelConfig
 from ..ops import lowering, pallas_ssm
 from ..ops.moe import moe_mlp, relu2
 from ..ops.attention import chunk_attention, latent_attention
@@ -273,7 +273,8 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
         # section 6, PR 40)
         hidden = 1.5 if cfg.activation == "relu2" else 1.0
         out["moe"]["we_down"] = dense(
-            (Lm, Eh, Fm, H), Fm * hidden * cfg.router_scale ** 2
+            (Lm, Eh, Fm, H),
+            Fm * hidden * (cfg.router_scale / cfg.seeded_expert_gain) ** 2,
         )
         if Fs:
             if cfg.moe_gated:
@@ -284,6 +285,26 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
             out["moe"]["router_bias"] = (
                 dense((Lm, E), 1) * 0.02
             ).astype(jnp.float32)
+    if cfg.hc_mult > 1:
+        # a sublayer's hyper-connection (``hc_sublayer``) rides on its
+        # kind's stack: ``hc_mix_*`` on a mixer's, ``hc_ffn_*`` on an
+        # FFN's. ``phi`` (output-major) at variance 1 / (n C), so that
+        # the dynamic half of a coefficient's logit is of unit size;
+        # ``alpha`` = 1 (the paper's 0.01 is where TRAINING starts: there
+        # the dynamic half lies under the bfloat16 rounding of the static
+        # one and no check could tell that it was computed); the biases
+        # of unit size, the mixing matrix's twice that, so that its
+        # logits spread and one Sinkhorn pass is far from twenty
+        n, K = cfg.hc_mult, cfg.hc_mult * (cfg.hc_mult + 2)
+        for kind, stack in out.items():
+            prefix = "hc_ffn_" if kind in FFN_KINDS else "hc_mix_"
+            Lk = stack["mlp_norm" if kind in FFN_KINDS else "attn_norm"].shape[0]
+            bias = dense((Lk, K), 1).astype(jnp.float32)
+            stack[prefix + "phi"] = dense((Lk, K, n * H), n * H)
+            stack[prefix + "b"] = bias * jnp.where(
+                jnp.arange(K) < 2 * n, 1.0, 2.0
+            )
+            stack[prefix + "alpha"] = jnp.ones((Lk, 3), jnp.float32)
     return out
 
 
@@ -361,10 +382,13 @@ def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             * (scale_dim ** -0.5)
         ).astype(dtype)
 
-    if cfg.homogeneous and cfg.attn_gate:
+    if cfg.homogeneous and (cfg.attn_gate or cfg.hc_mult > 1):
         raise NotImplementedError(
-            f"{cfg.name}: an attention output gate (attn_gate) in a model "
-            "whose every layer is one block (the mixed walk builds it)"
+            f"{cfg.name}: an attention output gate (attn_gate) or a "
+            "residual stream of several lanes (hc_mult) in a model whose "
+            "every layer is one block: the mixed walk builds them (list "
+            "the layers' kinds, layer_types), the one scan of "
+            "layer_apply does not"
         )
     if not cfg.homogeneous:
         _check_mixed(cfg)
@@ -451,6 +475,21 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float, zero_centered: bool) -> jax
     return (x32 * scale).astype(dt)
 
 
+def rope_inv_freq(
+    theta, half: int, cfg: Optional[ModelConfig] = None, yarn: bool = False
+) -> Tuple[jax.Array, float]:
+    """``(inverse frequencies [half], what cos and sin are multiplied
+    by)`` of a rotary embedding over ``2 * half`` elements: the plain
+    power ``theta^(-i / half)`` (``theta`` may be traced: the scan's
+    per-layer base), or the config's YaRN-scaled ones. THE place both
+    pairings (``apply_rope``'s halves, ``apply_rope_interleaved``'s
+    neighbours) take them from."""
+    if yarn:
+        freq, scale = _yarn_inv_freq(cfg, half)
+        return jnp.asarray(freq), scale
+    return theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half), 1.0
+
+
 def _yarn_inv_freq(cfg: ModelConfig, half: int) -> Tuple[np.ndarray, float]:
     """Static YaRN-scaled inverse frequencies + attention scaling
     (gpt-oss ships factor-32 YaRN over a 4096-token original window).
@@ -497,7 +536,6 @@ def apply_rope(
     scan of a homogeneous model, whose layers all take it."""
     dh = x.shape[-1]
     half = dh // 2
-    scale = 1.0
     if yarn is None:
         yarn = cfg is not None and bool(cfg.rope_scaling_factor)
         if yarn and cfg.local_rope_theta:
@@ -509,11 +547,7 @@ def apply_rope(
                 "YaRN rope_scaling with local_rope_theta needs the "
                 "layers listed by kind (layer_types), not sliding_pattern"
             )
-    if yarn:
-        freq, scale = _yarn_inv_freq(cfg, half)
-        freq = jnp.asarray(freq)
-    else:
-        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freq, scale = rope_inv_freq(theta, half, cfg, yarn)
     ang = positions.astype(jnp.float32)[..., None] * freq  # [B, T, half]
     cos = jnp.cos(ang)[:, :, None, :] * scale
     sin = jnp.sin(ang)[:, :, None, :] * scale
@@ -717,21 +751,28 @@ def attention_mixer(
 
 
 def apply_rope_interleaved(
-    x: jax.Array, positions: jax.Array, theta: float
+    x: jax.Array, positions: jax.Array, theta: float,
+    cfg: Optional[ModelConfig] = None,
 ) -> jax.Array:
     """The rotary embedding on pairs ``(2i, 2i+1)``, each turned by
     ``pos * theta^(-2i/D)`` (``rope_interleave``: the published layout
-    of a latent layer's rotary part). x: [B, T, ..., D]; positions
+    of a latent layer's rotary part), or by the config's YaRN-scaled
+    frequencies where ``cfg`` has a ``rope_scaling_factor`` (cos and
+    sin then times its attention factor). x: [B, T, ..., D]; positions
     [B, T]. The pair's partner is fetched by a signed permutation as a
     [D, D] product (each output is one input times +-1: exact), which
     keeps D on the lanes; a [..., D/2, 2] view would put an axis of 2
     there."""
     D = x.shape[-1]
-    freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    freq, scale = rope_inv_freq(
+        theta, D // 2, cfg, cfg is not None and bool(cfg.rope_scaling_factor)
+    )
     ang = positions.astype(jnp.float32)[..., None] * freq     # [B, T, D/2]
     shape = ang.shape[:2] + (1,) * (x.ndim - 3) + (D,)
     cos = jnp.repeat(jnp.cos(ang), 2, axis=-1).reshape(shape)
     sin = jnp.repeat(jnp.sin(ang), 2, axis=-1).reshape(shape)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     i = np.arange(D)
     swap = np.zeros((D, D), np.float32)
     swap[i ^ 1, i] = np.where(i % 2 == 0, -1.0, 1.0)   # (a, b) -> (-b, a)
@@ -764,6 +805,10 @@ def mla_mixer(
         score = (q_nope . k_nope + q_pe . k_pe) / sqrt(nope + rope)
         out = concat_h(softmax(score) v) W_o
 
+    Under YaRN (``ModelConfig.rope_scaling_factor``) the rotary part
+    turns by the by-parts frequencies and the score's scale grows by
+    ``(0.1 mscale_all_dim ln(factor) + 1)^2``.
+
     With an indexer (``ModelConfig.index_topk``) the softmax runs over
     the positions it selects (``_indexer``; ops/sparse_attention.py): a
     third step between the projections and the attention.
@@ -785,8 +830,26 @@ def mla_mixer(
     q = (c_q @ _w(lp, "w_qb", x.dtype)).reshape(B, T, NH, Dn + Dr)
     kva = x @ _w(lp, "w_kva", x.dtype)
     c_kv = rms_norm(kva[..., :Rkv], lp["kv_norm"], cfg.norm_eps, False)
-    q_pe = apply_rope_interleaved(q[..., Dn:], positions, cfg.rope_theta)
-    k_pe = apply_rope_interleaved(kva[..., Rkv:], positions, cfg.rope_theta)
+    scale = (Dn + Dr) ** -0.5
+
+    def rotated():
+        return tuple(
+            apply_rope_interleaved(v, positions, cfg.rope_theta, cfg)
+            for v in (q[..., Dn:], kva[..., Rkv:])
+        )
+
+    if cfg.rope_scaling_factor:
+        # YaRN on the rotary part (the DeepSeek-V3 family's form): the
+        # by-parts frequencies, and the softmax scale times
+        # ``(0.1 mscale_all_dim ln(factor) + 1)^2``
+        with jax.named_scope("mla_yarn"):
+            q_pe, k_pe = rotated()
+        scale *= float(
+            0.1 * cfg.rope_mscale_all_dim * np.log(cfg.rope_scaling_factor)
+            + 1.0
+        ) ** 2
+    else:
+        q_pe, k_pe = rotated()
     # the pool's row: the latent values, the shared key, then zeros up
     # to whole lane tiles (``ModelConfig.page_width``)
     pad = cfg.page_width - cfg.latent_width
@@ -794,7 +857,6 @@ def mla_mixer(
         [c_kv, k_pe, jnp.zeros((B, T, pad), c_kv.dtype)], axis=-1
     )
     w_kvb = _w(lp, "w_kvb", x.dtype).reshape(Rkv, NH, Dn + Dv)
-    scale = (Dn + Dr) ** -0.5
     index, attend = None, latent_attention
     if cfg.index_topk:
         with jax.named_scope("dsa_indexer"):
@@ -1813,7 +1875,11 @@ def layer_apply(
     stage loop (parallel/pipeline.py). Returns
     ``(h, (k_chunk, v_chunk))``. Each half is one part of the step
     (``lowering.PARTS``): the norms and the residual add belong to the
-    half they surround."""
+    half they surround. The residual lives HERE, as the two plain adds
+    ``h = resid + ...`` over one lane ``[B, T, H]``: a stream of several
+    lanes (``ModelConfig.hc_mult``) is the mixed walk's
+    (``_mixed_trunk``'s ``residual``) and ``init_params`` refuses it for
+    this scan."""
     with lowering.part("mixer"):
         resid = h
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps, cfg.norm_zero_centered)
@@ -1855,6 +1921,121 @@ def layer_apply(
 # ---------------------------------------------------------------------------
 # Layers of several kinds
 # ---------------------------------------------------------------------------
+
+
+def hc_coefficients(cfg: ModelConfig, hp: Dict[str, Any], X):
+    """A sublayer's hyper-connection coefficients for every token of the
+    stream ``X`` (n lanes ``[B, T, C]``), in float32 whatever the
+    stream's dtype: ``(H_pre [n], H_post [n], H_res [n][n])`` as lists of
+    vectors ``[B, T, 1]``, ready to be multiplied into a lane.
+
+    Shapes that matter on a TPU. The projection ``x phi`` reads each lane
+    once, as ``[n^2 + 2 n, C] @ [N, C]^T`` summed over the lanes (N =
+    B * T; ``phi`` is kept output-major, ``[n^2 + 2 n, n C]``: 24
+    columns would be padded to 128 lanes), beside the pass that takes
+    ``mean(x^2)``, and leaves ``[n^2 + 2 n, N]``: the TOKEN axis minor,
+    so that every coefficient is a lane-dense vector ``[N]`` (an
+    ``[N, n, n]`` array pads each 4 x 4 matrix to an (8, 128) tile). The
+    Sinkhorn runs on sixteen such vectors held apart: its sums are adds
+    of whole vectors and its divisions one reciprocal a row or column,
+    all elementwise over one shape (sliced out of one ``[n, n, N]``
+    array the twenty passes compiled to eighty small programs a
+    sublayer, held apart to forty)."""
+    n = len(X)
+    B, T, C = X[0].shape
+    with jax.named_scope("hc_coeff"):
+        xs = [x.reshape(B * T, C) for x in X]
+        ms = sum(
+            jnp.sum(jnp.square(x.astype(jnp.float32)), axis=-1) for x in xs
+        ) * (1.0 / (n * C))
+        r = jax.lax.rsqrt(ms + cfg.norm_eps)                # [N]
+        # the norm's scale is folded into phi: the division may follow
+        # the product. Operands in the stream's dtype, float32 out
+        phi = hp["phi"].astype(xs[0].dtype)   # lane j: columns j C ..
+        m = sum(
+            jax.lax.dot_general(
+                phi[:, j * C : (j + 1) * C], xs[j], (((1,), (1,)), ((), ())),
+                precision=_HI, preferred_element_type=jnp.float32,
+            )
+            for j in range(n)
+        ) * r                                               # [n^2 + 2n, N]
+        a, b = hp["alpha"], hp["b"]
+        pre = [jax.nn.sigmoid(a[0] * m[j] + b[j]) for j in range(n)]
+        post = [
+            2.0 * jax.nn.sigmoid(a[1] * m[n + j] + b[n + j]) for j in range(n)
+        ]
+    with jax.named_scope("hc_sinkhorn"):
+        at = lambda i, j: 2 * n + i * n + j        # mat() is row-major
+        M = [
+            [
+                jnp.exp(jnp.clip(
+                    a[2] * m[at(i, j)] + b[at(i, j)],
+                    -cfg.hc_res_clamp, cfg.hc_res_clamp,
+                ))
+                for j in range(n)
+            ]
+            for i in range(n)
+        ]
+        for _ in range(cfg.hc_sinkhorn_iters):
+            inv = [
+                1.0 / (sum(M[i][j] for i in range(n)) + cfg.hc_eps)
+                for j in range(n)
+            ]                                       # a column's sum
+            M = [[M[i][j] * inv[j] for j in range(n)] for i in range(n)]
+            inv = [1.0 / (sum(M[i]) + cfg.hc_eps) for i in range(n)]
+            M = [[M[i][j] * inv[i] for j in range(n)] for i in range(n)]
+
+        def col(v):                     # [N] token-minor -> [B, T, 1]
+            return v.reshape(B, T, 1)
+
+        return (
+            [col(v) for v in pre], [col(v) for v in post],
+            [[col(v) for v in row] for row in M],
+        )
+
+
+def hc_sublayer(cfg: ModelConfig, hp: Dict[str, Any], X, f):
+    """One sublayer ``f`` (a mixer or an FFN with its norm) of a model
+    whose residual stream is ``n`` lanes a token (``ModelConfig.hc_mult``;
+    mHC): with ``hc_coefficients``' ``H_pre``, ``H_post``, ``H_res``,
+
+        u = sum_j H_pre[j] X[j] ;  y = f(u)
+        X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y
+
+    ``X``: a tuple of the n lanes, each ``[B, T, C]`` in the activation
+    dtype; ``hp``: the sublayer's ``phi [n^2 + 2 n, n C]``,
+    ``b [n^2 + 2 n]`` and ``alpha [3]`` (pre, post, res). The lanes are n
+    ARRAYS and the mix n^2 multiply-adds over [N, C] slabs: one array
+    ``[B, T, n, C]`` sliced and stacked again (or mixed by a batched
+    4 x 4 ``dot_general``, whose operands would be padded to MXU tiles)
+    took 2.0 ms a sublayer at 4,096 tokens on a v5e where the tuple takes
+    0.56 (PERF.md section 6, PR 54). Sums in float32, lanes stored in the
+    stream's dtype.
+
+    ``u`` and ``y`` each cross an ``optimization_barrier``: the read and
+    the write stay device programs of their OWN, every instruction of
+    which is under an ``hc_`` scope, and no part of them rides in the
+    fusion of ``f``'s norm or of its last product, where a trace could
+    not tell the stream's seconds from the product's (PERF.md section 6,
+    PR 54: what the barriers cost; tests/perfbench/test_aot_xing_v5e.py
+    holds the compiled programs to it)."""
+    n, dt = len(X), X[0].dtype
+    pre, post, res = hc_coefficients(cfg, hp, X)
+    with jax.named_scope("hc_read"):
+        u = sum(
+            pre[j] * X[j].astype(jnp.float32) for j in range(n)
+        ).astype(dt)
+    y = jax.lax.optimization_barrier(f(jax.lax.optimization_barrier(u)))
+    with jax.named_scope("hc_write"):
+        y = y.astype(jnp.float32)
+        return tuple(
+            sum(
+                (res[i][j] * X[j].astype(jnp.float32) for j in range(n)),
+                post[i] * y,
+            ).astype(dt)
+            for i in range(n)
+        )
+
 
 _MIXER_STACK = {
     "attention": "attn", "swa": "swa", "conv": "conv", "mamba": "mamba",
@@ -1933,13 +2114,22 @@ def _check_mixed(cfg: ModelConfig) -> None:
                 f"{cfg.name}: an indexer (index_topk) needs index_n_heads "
                 "and an index_head_dim of at least qk_rope_head_dim"
             )
-        if not cfg.rope_interleave or cfg.rope_scaling_factor or (
-            cfg.position_embedding != "rope"
-        ):
+        if not cfg.rope_interleave or cfg.position_embedding != "rope":
             raise NotImplementedError(
-                f"{cfg.name}: mla layers take the interleaved rotary "
-                "embedding, unscaled (no half-split pairs, no YaRN, no nope)"
+                f"{cfg.name}: mla layers turn their rotary part in "
+                "interleaved pairs (rope_interleave): half-split pairs "
+                "and no rotary embedding at all (nope) are not built"
             )
+        if cfg.rope_scaling_factor and cfg.index_topk:
+            raise NotImplementedError(
+                f"{cfg.name}: YaRN (rope_scaling_factor) on mla layers "
+                "with an indexer: the index key's rotary part is plain"
+            )
+    if cfg.hc_mult > 1 and min(cfg.hc_sinkhorn_iters, cfg.hc_eps) <= 0:
+        raise ValueError(
+            f"{cfg.name}: a residual stream of several lanes (hc_mult) "
+            "needs hc_sinkhorn_iters >= 1 and hc_eps > 0"
+        )
     # supported in the walk: a window by the layer's kind ("swa", its
     # K/V a pool of its own) and a rotary embedding a kind (YaRN on the
     # full layers, plain ``local_rope_theta`` on the window layers). A
@@ -2025,7 +2215,8 @@ def _mixed_trunk(
     """
     _check_mixed(cfg)
     stacks = params["layers"]
-    B, T = h.shape[:2]
+    lane = h[0] if cfg.hc_mult > 1 else h     # the lanes of a stream
+    B, T = lane.shape[:2]
     K1 = cfg.conv_state_len
     r = cfg.residual_multiplier
     if cfg.num_state_layers and state_past is None:
@@ -2033,18 +2224,18 @@ def _mixed_trunk(
         state_past = StatePast(
             ssm=jnp.zeros(
                 (cfg.num_state_layers, 1, cfg.state_rows, cfg.state_inner),
-                h.dtype,
+                lane.dtype,
             ),
             slots=jnp.zeros((B,), jnp.int32),
             fresh=jnp.ones((B,), bool),
             conv=jnp.zeros(
                 (cfg.num_state_layers, B, cfg.state_conv_len,
-                 cfg.state_conv_dim), h.dtype,
+                 cfg.state_conv_dim), lane.dtype,
             ),
         )
     if cfg.num_conv_layers and conv_state is None:
         conv_state = jnp.zeros(
-            (cfg.num_conv_layers, B, K1, cfg.hidden_size), h.dtype
+            (cfg.num_conv_layers, B, K1, cfg.hidden_size), lane.dtype
         )
     win_len = None if window_past is None else window_past[2]
     La = cfg.num_attn_layers
@@ -2072,17 +2263,42 @@ def _mixed_trunk(
     def scaled(y):
         return y if r == 1.0 else y * jnp.asarray(r, y.dtype)
 
+    def residual(h, stack, idx, prefix, f):
+        """``h`` after the sublayer ``f``. THE place the residual lives:
+        the plain add ``h + f(h)`` over ``[B, T, H]``, or, for a stream
+        of several lanes (a tuple of n such arrays:
+        ``ModelConfig.hc_mult``), ``hc_sublayer`` under the sublayer's
+        own ``hc_*`` leaves. The
+        branch is taken in Python: a model with one lane traces the
+        program it always did."""
+        if cfg.hc_mult == 1:
+            return h + scaled(f(h))
+        with jax.named_scope("hc_coeff"):   # the slices are its reads
+            hp = {
+                k[len(prefix):]: v[idx] for k, v in stack.items()
+                if k.startswith(prefix)
+            }
+        return hc_sublayer(cfg, hp, h, lambda u: scaled(f(u)))
+
     def block(h, mixer, m_idx, ffn, f_idx):
         """One block: its mixer, then its FFN, each under its own norm
-        and residual add; "none" for the one a block of ONE sublayer
+        and its own pass through the residual (``residual``: the norm
+        and the add, or the lanes' read and write, belong to the half
+        they surround); "none" for the one a block of ONE sublayer
         lacks."""
         out = {}
         if mixer != "none":
             with lowering.part("mixer"):
-                h = h + scaled(mix(h, mixer, m_idx, out))
+                h = residual(
+                    h, stacks[_MIXER_STACK[mixer]], m_idx, "hc_mix_",
+                    lambda u: mix(u, mixer, m_idx, out),
+                )
         if ffn != "none":
             with lowering.part("ffn"):
-                h = h + scaled(feed(h, ffn, f_idx, out))
+                h = residual(
+                    h, stacks[ffn], f_idx, "hc_ffn_",
+                    lambda u: feed(u, ffn, f_idx, out),
+                )
         return h, out
 
     def mix(h, mixer, m_idx, out):
@@ -2242,12 +2458,16 @@ def rope_thetas(cfg: ModelConfig) -> jax.Array:
 
 
 @lowering.part("embed")
-def embed_tokens(cfg: ModelConfig, params: Params, ids: jax.Array) -> jax.Array:
+def embed_tokens(cfg: ModelConfig, params: Params, ids: jax.Array):
     h = params["embed"][ids]  # [B, T, H] gather
     if cfg.embed_scale:
         h = (h.astype(jnp.float32) * (cfg.hidden_size ** 0.5)).astype(h.dtype)
     if cfg.embedding_multiplier != 1.0:
         h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
+    if cfg.hc_mult > 1:
+        # a stream of several lanes starts as so many copies of the
+        # token's embedding: a tuple of n arrays [B, T, H]
+        return (h,) * cfg.hc_mult
     return h
 
 
@@ -2256,7 +2476,8 @@ def head_apply(
     cfg: ModelConfig, params: Params, h: jax.Array, valid_len: jax.Array,
     logit_positions: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """final norm + lm/embedding head. h: [B, T, H].
+    """final norm + lm/embedding head. h: [B, T, H] (or the lanes of a
+    stream, a tuple of n such arrays: ``ModelConfig.hc_mult``).
 
     Returns ``(out, h_normed)`` — the head output plus the post-final-norm
     hidden states (the ``hidden`` of the forward contract).
@@ -2266,6 +2487,9 @@ def head_apply(
     only from the last valid position, and the full ``[B, T, V]`` tensor
     (8 x 512 x 151,936 in bf16 then f32 is ~3.7 GB at Qwen3's vocab)
     is the largest transient of the whole program."""
+    if cfg.hc_mult > 1:
+        # a stream of several lanes ends as their sum
+        h = sum(x.astype(jnp.float32) for x in h).astype(h[0].dtype)
     T = h.shape[1]
     h = rms_norm(h, params["final_norm"], cfg.norm_eps, cfg.norm_zero_centered)
     if cfg.head == "embedding":

@@ -374,6 +374,24 @@ LATENT_ATTENTION_DISPATCHES_TOTAL = REGISTRY.counter(
     unit="dispatches",
     max_series=4,
 )
+HC_SUBLAYERS_TOTAL = REGISTRY.counter(
+    "sutro_hc_sublayers_total",
+    "Sublayers a model whose residual stream is several lanes "
+    "(hc_mult; manifold-constrained hyper-connections) ran, a forward "
+    "step a count of its own: each computes its coefficients and the "
+    "Sinkhorn-projected mixing matrix for every token of the step, by "
+    "the dispatch's form",
+    labels=("form",),  # prefill | decode
+    unit="sublayers",
+    max_series=4,
+)
+HC_STREAM_BYTES_NEEDED_TOTAL = REGISTRY.counter(
+    "sutro_hc_stream_bytes_needed_total",
+    "Bytes the residual stream of the same dispatches' real tokens had "
+    "to move: a sublayer reads the lanes once and writes them once "
+    "(runner.stream_bytes)",
+    unit="bytes",
+)
 SAMPLE_DISPATCHES_TOTAL = REGISTRY.counter(
     "sutro_sample_dispatches_total",
     "Dispatches that sample (a masked decode step, a fused or speculative "
