@@ -25,7 +25,11 @@ allocator's first fit gives them; ``--scatter`` shuffles every row's
 pages (what sends a parent's whole batch to its per-page walk).
 
 A tree whose kernel still takes ``kv_chunk`` is timed on both of its
-branches (its chunk, and 1). Prints one JSON line; fails without a TPU.
+branches (its chunk, and 1). A tree whose kernel takes a block of rows a
+grid step says which it built (``rows_per_step``; a tree from before
+reads 1) and ``--rows`` names one instead. Beside the GB/s a run prints
+the microseconds a live row (``row_us``). Prints one JSON line; fails
+without a TPU.
 """
 
 from __future__ import annotations
@@ -107,6 +111,11 @@ def main() -> None:
         " to separate a row's fixed cost from a page's and a group's",
     )
     ap.add_argument(
+        "--rows", type=int, default=None,
+        help="rows a grid step, where the tree's kernel takes a block of "
+        "them (default: what the tree chooses for the shape)",
+    )
+    ap.add_argument(
         "--set", action="append", default=[], metavar="NAME=INT",
         help="set a module constant of the tree's ops/pallas_paged.py "
         "for this run (RING_BYTES, RING_MAX_PAGES, GROUP_TOKENS)",
@@ -139,9 +148,11 @@ def main() -> None:
         shared = 0
     table = tables(rng, past, pages, shared, NP, args.scatter)
 
-    takes_chunk = "kv_chunk" in inspect.signature(
-        pallas_paged.paged_decode_attention
-    ).parameters
+    params = inspect.signature(pallas_paged.paged_decode_attention).parameters
+    takes_chunk = "kv_chunk" in params
+    if args.rows is not None and "rows" not in params:
+        raise SystemExit(f"{args.tree}'s kernel takes one row a grid step")
+    rows_kw = {} if args.rows is None else {"rows": args.rows}
     chunks = [None]
     if takes_chunk:
         ch = pallas_paged.chunk_pages_for(PS, MP, kv_heads=KVH, head_dim=DH)
@@ -162,7 +173,9 @@ def main() -> None:
     zero = jnp.asarray(0, jnp.int32)
 
     def step_fn(kv_chunk):
-        kw = {} if kv_chunk is None else {"kv_chunk": kv_chunk}
+        kw = dict(rows_kw)
+        if kv_chunk is not None:
+            kw["kv_chunk"] = kv_chunk
 
         @jax.jit
         def step(q, kp, vp, table, past, kc, vc, wk, wv):
@@ -208,8 +221,15 @@ def main() -> None:
         "kv_mb_needed_a_call": round(needed * 2 * PS * KD * 2 / 1e6, 2),
         "runs": [],
     }
+    from sutro_tpu.ops import lowering
+
+    # the rows a grid step the tree built its kernel with (a tree from
+    # before the counter ran one)
+    built = getattr(lowering, "paged_decode_rows_per_step", dict)
     for ch in chunks:
-        kw = {} if ch is None else {"kv_chunk": ch}
+        kw = dict(rows_kw)
+        if ch is not None:
+            kw["kv_chunk"] = ch
         one = pallas_paged.paged_decode_attention(
             q, kp, vp, zero, table_d, past_d, kc, vc, zero, None,
             wk, wv, jnp.asarray(4, jnp.int32), **kw,
@@ -231,6 +251,7 @@ def main() -> None:
         fetched = float((-(-past // (pp * PS)) * pp).sum())
         out["runs"].append({
             "kv_chunk": ch,
+            "rows_per_step": max(built(), default=1),
             "step_ms": round(med * 1e3, 4),
             "call_us": round(med / CALLS * 1e6, 2),
             "row_us": round(med / CALLS / max(int(live.sum()), 1) * 1e6, 3),

@@ -145,6 +145,8 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         # routed model's alone) and a Mamba-2 layer's read of its
         # committed state in a decode step
         "kernel_paths": lowering.snapshot(),
+        # the paged decode kernel's traces by the rows a grid step takes
+        "paged_decode_rows_per_step": lowering.paged_decode_rows_per_step(),
         "paged_decode_xla": lowering.xla_decode_count(),
         "grouped_matmul": lowering.grouped_matmul_counts(),
         "moe_combine": lowering.moe_combine_counts(),

@@ -95,10 +95,27 @@ KDA_FORMS = ("chunked", "pending")
 _kda: Dict[str, int] = dict.fromkeys(KDA_FORMS, 0)
 
 
-def record_kernel(kernel: str, *, interpret: bool) -> None:
-    """Called from a kernel wrapper's traced body."""
+#: traces of the paged decode kernel by the rows a grid step takes
+_decode_rows: Dict[int, int] = {}
+
+
+def record_kernel(kernel: str, *, interpret: bool, rows: int = 0) -> None:
+    """Called from a kernel wrapper's traced body. ``rows``: the rows a
+    grid step of the paged decode kernel takes in this trace."""
     with _lock:
         _counts[kernel]["interpreted" if interpret else "lowered"] += 1
+        if rows:
+            _decode_rows[rows] = _decode_rows.get(rows, 0) + 1
+
+
+def paged_decode_rows_per_step() -> Dict[int, int]:
+    """Traces of the paged decode kernel (lowered or interpreted), by
+    the rows a grid step takes (``ops/pallas_paged.rows_per_step``: 8,
+    4, 2 or 1 by the call's batch and widths): ``{8: 3}`` says three
+    programs were built whose kernel takes eight rows a step. A chip
+    run reads here which schedule its programs got."""
+    with _lock:
+        return dict(sorted(_decode_rows.items()))
 
 
 def record_reference(kernel: str) -> None:

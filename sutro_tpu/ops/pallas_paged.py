@@ -6,16 +6,31 @@ view of the page pool per layer — a pure HBM copy that dominates decode
 time. This kernel reads K/V pages **in place** with flash-style online
 softmax across pages.
 
-Design (third generation; the first used grid ``(B, MP)`` with one
+Design (fourth generation; the first used grid ``(B, MP)`` with one
 BlockSpec-fetched page per grid step and paid a block DMA and ~us of
 grid overhead for every table slot, used or not; the second walked the
 pages inside the kernel but fetched a FIXED chunk of pages a row, two
 slots to a row, and started every row from an empty pipeline: a
 280-token row fetched 1 MiB and computed over 512 or 1,024 masked
 columns, and its fetch and its arithmetic ran one after the other,
-PERF.md §6 PR 31):
+PERF.md §6 PR 31; the third fetched the pages a row holds in a ring
+that never drains, below, and took ONE row a grid step: five or six
+``(1, ...)`` blocks in and one out, the initialisation, the finalize and
+every group's chain for one row with nothing beside them, and a scalar
+loop that worked out a row's count and start again for every page it
+started, PERF.md §6 PR 55):
 
-- grid ``(B,)``: one grid step per decode row, in order;
+- grid ``(B // R,)``: one grid step per BLOCK of ``R`` decode rows, in
+  order (``rows_per_step``: the largest of 8, 4, 2, 1 that divides the
+  batch and whose blocks and scratch fit the scoped VMEM limit beside
+  the ring; ``R = 1`` is the third generation's schedule, and the path
+  of a batch no larger block divides). The pipeline brings a block's
+  ``q`` / ``k_cur`` / ``v_cur`` / window buffers / prefix carry in and
+  its ``out`` back once, ``m / l / acc`` and the block-diagonal queries
+  are set up for the ``R`` rows at once, and the finalize runs over
+  ``[R, NH, ...]`` (its two window products batched), so its latencies
+  are paid once a block and a tp=4 shard's 8-head rows fill the
+  sublanes together;
 - the operand is the WHOLE stacked pool ``[L, NP, PS, KVH*Dh]``
   (``memory_space=ANY``, HBM-resident) and the layer is a scalar-prefetch
   index: pages are fetched as ``pool.at[layer, page]``. No caller slices
@@ -27,16 +42,30 @@ PERF.md §6 PR 31):
   one contiguous block of the fused pool), through its table, so any
   layout is the same code: an ascending run, scattered pages, a shared
   prefix at the table's head;
-- **the ring does not drain**: the call's fetches are ONE sequence over
-  (row, page), and ``ring_shape`` slots of it are in flight. The cursor
-  lives in SMEM from grid step to grid step; when a row's last page has
-  been started the ring goes on with the next row's first pages, so a
-  row's arithmetic, its finalize and the grid's step to the next row run
-  under the fetches of the rows after it;
+- **the ring does not drain, and the block did not change it**: the
+  call's fetches are ONE sequence over (row, page) in row order, and
+  ``ring_shape`` slots of it are in flight: the same slots, alignment
+  and bytes whatever ``R``. The cursor lives in SMEM from grid step to
+  grid step; when a row's last page has been started the ring goes on
+  with the next row's first pages, so a row's arithmetic, a block's
+  finalize and the grid's step to the next block run under the fetches
+  of the rows after them. Alone the ring moves the 4B cell's pages at
+  ~570-640 GB/s, which is where that cell's calls now stand (PERF.md §6
+  PR 55): a larger ring did not move it;
 - **arithmetic follows the row**: scores and the value product run over
   the groups of pages that landed (``GROUP_TOKENS`` columns a group),
   one ``[NH, GT]`` block-diagonal score matmul and one value matmul a
-  group for all KV heads, accumulating ``(m, l, acc)`` in VMEM scratch;
+  group for all KV heads, accumulating ``(m, l, acc)`` in VMEM scratch.
+  A row's groups, their sizes and their order are what a grid step of
+  its own gave it, so a row's result is the same sums in the same
+  order: the block is a schedule, not mathematics;
+- **two rows' chains stand side by side** where the ring holds both
+  rows' pages and still keeps a largest group's slots for the fetches
+  ahead: a group is one serial chain (matmul, max, exp, sum, matmul),
+  and the next row's chain of the same size fills its latencies. Rows
+  that do not fit together (two 8-page rows of the 4B cell's 16 slots)
+  go one after the other as before: side by side they emptied the ring
+  behind them;
 - the current token's K/V, the optional multi-step decode window buffer
   (tokens sampled in the current fused window, not yet written to the
   pool — see engine/runner.decode_multi), and the optional gpt-oss
@@ -88,6 +117,7 @@ def _paged_decode_kernel(
     prefix: bool = False,
     window_start: bool = False,
     shared: bool = False,
+    rows: int = 1,
 ):
     # ref layout varies with (window_slots, quantized, prefix, shared) —
     # walk an index instead of a per-case tuple unpack. ``shared``: ONE
@@ -123,12 +153,14 @@ def _paged_decode_kernel(
     kssem = next(it) if quantized else None
     vssem = next(it) if quantized else None
     ring = next(it)
+    qbd_ref = next(it)
     m_ref = next(it)
     l_ref = next(it)
     acc_ref = next(it)
 
-    b = pl.program_id(0)
-    B = pl.num_programs(0)
+    R = rows  # rows a grid step
+    b0 = pl.program_id(0) * R  # the block's first row
+    B = pl.num_programs(0) * R
     MP = max_pages_per_seq
     PS = page_size
     D = ring_pages
@@ -140,10 +172,6 @@ def _paged_decode_kernel(
     G = NH // kvh
     KD = kvh * Dh
 
-    past = past_len_ref[b]
-    # current token's global position: tokens already in pages plus any
-    # fused-window tokens not yet written back
-    pos = past + (win_len_ref[0] if window_slots else 0)
     win = window_ref[0]
     # the pools are the whole [L, NP, PS, KD] stacks, resident in HBM:
     # every DMA below indexes [layer, page] itself
@@ -185,16 +213,15 @@ def _paged_decode_kernel(
         a = jnp.int32(1)
         for size in group_sizes[::-1][1:]:
             a = jnp.where(n > size // 2, size, a)
-        return (t + a - 1) // a * a
+        # ``a`` is a power of two: no division on the scalar core
+        return jnp.bitwise_and(t + a - 1, -a)
 
-    def page_dmas(row, j, t):
-        """The copies of the j-th page ``row`` fetches into the ring
-        slot of slot number ``t``: K, V and, under int8, their scales
-        (pre-shaped [L, NP, 1, PS] so a page's scales land lane-major,
-        a legal [1, PS] broadcast against a score slice; merging
-        sublanes into lanes in-kernel is unsupported)."""
-        s = jax.lax.rem(t, D)
-        page = page_table_ref[row * MP + first_page(row) + j]
+    def slot_dmas(page, s):
+        """The copies of pool page ``page`` into ring slot ``s``: K, V
+        and, under int8, their scales (pre-shaped [L, NP, 1, PS] so a
+        page's scales land lane-major, a legal [1, PS] broadcast against
+        a score slice; merging sublanes into lanes in-kernel is
+        unsupported)."""
         dmas = [
             pltpu.make_async_copy(
                 k_pool_ref.at[layer, page], kbuf.at[s], ksem.at[s]
@@ -220,7 +247,10 @@ def _paged_decode_kernel(
         under ``limit`` (the consumer's position + D: the slot's last
         occupant has been read) and rows remain. It runs on from a
         row's last page into the next row's first, over rows that fetch
-        nothing, so the ring never drains between rows."""
+        nothing, so the ring never drains between rows. What a row
+        costs the scalar core (its count, its first page, where it may
+        start) is worked out once a visit, and a page costs its table
+        entry and its copies' descriptors."""
 
         def more(c):
             row, _, t = c
@@ -230,15 +260,18 @@ def _paged_decode_kernel(
             row, j, t = c
             n = pages_of(row)
             t = jnp.where(j == 0, aligned(t, n), t)
-            go = jnp.logical_and(j < n, t < limit)
+            count = jnp.maximum(jnp.minimum(n - j, limit - t), 0)
+            entry = row * MP + first_page(row) + j
 
-            @pl.when(go)
-            def _start():
-                for dma in page_dmas(row, j, t):
+            def start(i, _):
+                for dma in slot_dmas(
+                    page_table_ref[entry + i], jax.lax.rem(t + i, D)
+                ):
                     dma.start()
+                return 0
 
-            started = go.astype(jnp.int32)
-            j, t = j + started, t + started
+            jax.lax.fori_loop(0, count, start, 0)
+            j, t = j + count, t + count
             done = j >= n
             return jnp.where(done, row + 1, row), jnp.where(done, 0, j), t
 
@@ -247,7 +280,7 @@ def _paged_decode_kernel(
         )
         ring[0], ring[1], ring[2] = row, j, t
 
-    @pl.when(b == 0)
+    @pl.when(b0 == 0)
     def _open_ring():
         for i in range(4):
             ring[i] = 0
@@ -261,15 +294,17 @@ def _paged_decode_kernel(
     # time than these two). Mosaic cannot merge (KVH, Dh) into the
     # lane dim in-kernel, so the page pool arrives pre-fused [.., KD]
     # and lane-space masks are built from iota instead of reshapes.
-    q = q_ref[0].astype(jnp.float32)                      # [NH, Dh]
+    # Built for the block's R rows at once and kept in VMEM: the row
+    # loop below reads its row's.
+    q = q_ref[...].astype(jnp.float32)                    # [R, NH, Dh]
     row_head = jax.lax.broadcasted_iota(jnp.int32, (NH, KD), 0) // G
     col_head = jax.lax.broadcasted_iota(jnp.int32, (NH, KD), 1) // Dh
     blk_kd = (row_head == col_head).astype(jnp.float32)   # [NH, KD]
-    q_rep = jnp.concatenate([q] * kvh, axis=1)            # [NH, KD]
-    q_bd = q_rep * blk_kd
+    q_rep = jnp.concatenate([q] * kvh, axis=2)            # [R, NH, KD]
+    qbd_ref[...] = q_rep * blk_kd[None]
 
     # Shared-prefix (Hydragen-style) mode: the first pfx_cnt pages of
-    # this row's table hold a prefix whose K/V is SHARED with other
+    # a row's table hold a prefix whose K/V is SHARED with other
     # rows. Their attention was computed ONCE for the whole batch
     # outside the kernel (prefix_attention_carry — the pages are read
     # from HBM once instead of once per row) and arrives as the initial
@@ -278,42 +313,63 @@ def _paged_decode_kernel(
     # init — and start at page 0. Online softmax is associative, so the
     # result is bit-comparable to walking the prefix pages in-row.
     if prefix:
-        m_ref[...] = jnp.broadcast_to(
-            m0_ref[0, 0][:, None].astype(jnp.float32), m_ref.shape
-        )
-        l_ref[...] = jnp.broadcast_to(
-            l0_ref[0, 0][:, None].astype(jnp.float32), l_ref.shape
-        )
-        acc_ref[...] = acc0_ref[0].astype(jnp.float32)
+        # m0 / l0 arrive [R, NH, 1]: heads on the sublanes, as m / l
+        # keep them
+        m_ref[...] = jnp.broadcast_to(m0_ref[...], m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l0_ref[...], l_ref.shape)
+        acc_ref[...] = acc0_ref[...]
     else:
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    n_pages = pages_of(b)
-    i0 = first_page(b)
-    t0 = aligned(ring[3], n_pages)  # this row's first slot number
-
     # Arithmetic follows the row: its pages are taken in groups of the
     # sizes of their count's binary digits, largest first (13 pages: a
     # group of 8, of 4, of 1, when GP is 8), so that no column past the
     # row's last page is computed and the m / l / acc update is paid a
-    # few times a row, not once a page. One body a size.
+    # few times a row, not once a page. A group is one serial chain
+    # (matmul, max, exp, sum, matmul) whose latencies nothing of its
+    # own row can fill. So two rows of the block whose pages the ring
+    # holds together go through their digits side by side: where both
+    # have a digit, the two chains stand in ONE straight-line body (a
+    # row's state is read before either chain and written after both,
+    # so that nothing orders one chain behind the other), and where one
+    # has it, its chain runs alone. One body a size and a width,
+    # whichever rows of the block run it.
 
-    def group(done, size):
-        """Scores and values of ``size`` pages from the row's
-        ``done``-th on, folded into (m, l, acc)."""
-        GT = size * PS
-        t = t0 + done
-        # the pages before this group have been read: refill their slots
-        top_up(t + D)
+    def wait_pages(slot, size):
+        # a wait reads its copy's semaphore and size: any page stands
+        # for the one that was fetched
         for o in range(size):
-            for dma in page_dmas(b, done + o, t + o):
+            for dma in slot_dmas(0, slot + o):
                 dma.wait()
-        # the row started at a multiple of a power of two >= size, and
-        # the larger groups came first: the slab is aligned to ``size``
-        slot = pl.multiple_of(jax.lax.rem(t, D), size)
-        tok = (i0 + done) * PS + jax.lax.broadcasted_iota(
+
+    def slot_of(t, size):
+        # a row started at a multiple of a power of two >= size, and its
+        # larger groups came first: a group's slab of the ring is
+        # aligned to ``size``
+        return pl.multiple_of(jax.lax.rem(t, D), size)
+
+    def load(r):
+        return qbd_ref[r], m_ref[r, :, 0], l_ref[r, :, 0], acc_ref[r]
+
+    def store(r, m_new, l_new, acc_new):
+        m_ref[r] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
+        l_ref[r] = jnp.broadcast_to(l_new[:, None], l_ref.shape[1:])
+        acc_ref[r] = acc_new
+
+    def chain(b, t, done, size, state):
+        """Scores and values of ``size`` pages from row ``b``'s
+        ``done``-th on, which lie from slot number ``t``, folded into
+        the row's ``state``: (q_bd, m, l, acc) in, (m, l, acc) out."""
+        q_bd, m_prev, l_prev, acc_prev = state
+        past = past_len_ref[b]
+        # current token's global position: tokens already in pages plus
+        # any fused-window tokens not yet written back
+        pos = past + (win_len_ref[0] if window_slots else 0)
+        GT = size * PS
+        slot = slot_of(t, size)
+        tok = (first_page(b) + done) * PS + jax.lax.broadcasted_iota(
             jnp.int32, (NH, GT), 1
         )
         ok = tok < past
@@ -352,12 +408,10 @@ def _paged_decode_kernel(
             )
         s = jnp.where(ok, s, NEG_INF)
 
-        m_prev = m_ref[:, 0]                             # [NH]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))  # [NH]
         alpha = jnp.exp(m_prev - m_new)                  # [NH]
         p = jnp.exp(s - m_new[:, None])                  # [NH, GT]
-        l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1)
         if quantized:
             # V dequant folds into the probabilities for the value dot
             # ONLY — the normalizer l above sums the true p:
@@ -373,82 +427,173 @@ def _paged_decode_kernel(
             pv = p.astype(v.dtype)       # float32 but for a shared pool
         # acc holds the full [NH, KVH*Dh] product; only each row's own
         # head block is meaningful (extracted at the end)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        acc_new = acc_prev * alpha[:, None] + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
+        return m_new, l_new, acc_new
 
-    def whole_groups(gi, _):
-        group(gi * GP, GP)
-        return 0
+    def groups(size, low, rows):
+        """The group of ``size`` pages of each of ``rows`` (one, or two
+        side by side): ``(r, t0, done)`` a row, its index in the block,
+        its first slot number and the pages it has folded. ``low`` is
+        the first slot number not yet read."""
+        # the pages before ``low`` have been read: refill their slots
+        top_up(low + D)
+        for r, t0, done in rows:
+            wait_pages(slot_of(t0 + done, size), size)
+        states = [load(r) for r, _, _ in rows]
+        new = [
+            chain(b0 + r, t0 + done, done, size, state)
+            for (r, t0, done), state in zip(rows, states)
+        ]
+        for (r, _, _), out in zip(rows, new):
+            store(r, *out)
 
-    n_whole = n_pages // GP
-    jax.lax.fori_loop(0, n_whole, whole_groups, 0)
-    for size in group_sizes[1:]:
-        # the pages under this binary digit of the row's count
+    def digits(r, t_end):
+        """The block's rows from the ``r``-th on, ``t_end`` the slot
+        number read up to: that row, and the next beside it where the
+        ring holds both rows' pages at once and keeps a largest
+        group's slots for the fetches ahead (two 8-page rows of the 4B
+        cell fill its 16 slots: side by side they ran 30 % slower than
+        one after the other, the ring standing empty behind them;
+        PERF.md section 6 PR 55). A row's groups keep the sizes and the
+        order a grid step of its own gave them."""
+        n_a = pages_of(b0 + r)
+        t_a = aligned(t_end, n_a)  # the row's first slot number
+        end_a = t_a + n_a
+        # the row after it (the block's last row has none: it stands
+        # beside itself with no pages, as every row of a block of one)
+        r_b = jnp.minimum(r + 1, R - 1)
+        n_b = pages_of(b0 + r_b)
+        t_b = aligned(end_a, n_b)
+        pair = jnp.logical_and(r + 1 < R, t_b + n_b - t_a <= D - GP)
+        n_b = jnp.where(pair, n_b, 0)
+        end = jnp.where(pair, t_b + n_b, end_a)
 
-        @pl.when(jax.lax.rem(n_pages, 2 * size) >= size)
-        def _digit(size=size):
-            group(n_pages // (2 * size) * (2 * size), size)
+        def low(done_a, done_b):
+            # the ring refills behind the first page not yet read: the
+            # first row's while it has any
+            return jnp.where(done_a < n_a, t_a + done_a, t_b + done_b)
 
-    # the row's pages have been read: the fetches that take their slots
-    # are the next rows', and they run under this row's finalize and the
-    # grid's step to the next row
-    ring[3] = t0 + n_pages
+        def both(done_a, done_b, size):
+            groups(
+                size, low(done_a, done_b),
+                [(r, t_a, done_a), (r_b, t_b, done_b)],
+            )
+
+        def one(done_a, done_b, size, first):
+            # the row that has the digit, alone
+            row = tuple(
+                jnp.where(first, a, b) for a, b in
+                ((r, r_b), (t_a, t_b), (done_a, done_b))
+            )
+            groups(size, low(done_a, done_b), [row])
+
+        # a block of one row builds no body of two chains
+        w_a, w_b = n_a // GP, n_b // GP
+        w_ab = jnp.minimum(w_a, w_b)
+        if R > 1:
+
+            def whole_both(gi, _):
+                both(gi * GP, gi * GP, GP)
+                return 0
+
+            jax.lax.fori_loop(0, w_ab, whole_both, 0)
+
+        def whole_one(gi, _):
+            one(
+                jnp.minimum(gi, w_a) * GP, jnp.minimum(gi, w_b) * GP, GP,
+                w_a > w_b,
+            )
+            return 0
+
+        jax.lax.fori_loop(w_ab, jnp.maximum(w_a, w_b), whole_one, 0)
+        for size in group_sizes[1:]:
+            # the pages under this binary digit of a row's count
+            has_a = jax.lax.rem(n_a, 2 * size) >= size
+            has_b = jax.lax.rem(n_b, 2 * size) >= size
+            done_a = n_a // (2 * size) * (2 * size)
+            done_b = n_b // (2 * size) * (2 * size)
+            if R > 1:
+
+                @pl.when(jnp.logical_and(has_a, has_b))
+                def _both(size=size, done_a=done_a, done_b=done_b):
+                    both(done_a, done_b, size)
+
+            @pl.when(jnp.logical_xor(has_a, has_b))
+            def _one(size=size, done_a=done_a, done_b=done_b, has_a=has_a):
+                one(done_a, done_b, size, has_a)
+
+        return r + 1 + pair.astype(jnp.int32), end
+
+    # the block's rows in the ring's order, a loop and not R copies of
+    # the group bodies
+    _, ring[3] = jax.lax.while_loop(
+        lambda c: c[0] < R, lambda c: digits(*c), (jnp.int32(0), ring[3])
+    )
+    # the block's pages have been read: the fetches that take their
+    # slots are the next blocks', and they run under this block's
+    # finalize and the grid's step to the next block
     top_up(ring[3] + D)
 
-    # finalize: fused-window tokens + current token + attention sink,
-    # in the same block-diagonal space (2 dots total, not 2 per head)
+    # finalize, the block's R rows at once: fused-window tokens +
+    # current token + attention sink, in the same block-diagonal space
+    # (2 batched dots a block, not 2 per head of every row). Its chain
+    # (product, max, exp, sum, product, extraction) is latency a row
+    # alone cannot fill, so the block pays it once: a tp=4 shard's rows
+    # of 8 heads fill the sublanes together, and even rows that fill the
+    # registers alone finish sooner together than one by one in a loop
+    # (PERF.md section 6 PR 55)
     W = window_slots
-    k_cur = k_cur_ref[0].astype(jnp.float32)             # [1, KD]
-    v_cur = k_cur if shared else v_cur_ref[0].astype(jnp.float32)
-    sink = sink_ref[0].astype(jnp.float32)               # [NH]
-
+    sink = sink_ref[...][None]                           # [1, NH, 1]
+    q_bd = qbd_ref[...]                                  # [R, NH, KD]
+    k_cur = k_cur_ref[...].astype(jnp.float32)           # [R, 1, KD]
+    v_cur = k_cur if shared else v_cur_ref[...].astype(jnp.float32)
     # one key: a lane reduction, not a matmul with a single column
-    s_self = jnp.sum(q_bd * k_cur, axis=1) * scale       # [NH]
-    m_prev = m_ref[:, 0]
+    s_self = jnp.sum(q_bd * k_cur, axis=2, keepdims=True) * scale
+    m_prev = m_ref[:, :, :1]                             # [R, NH, 1]
     m_new = jnp.maximum(m_prev, jnp.maximum(s_self, sink))
     if W:
         # window tokens: slot s holds the fused window's s-th sampled
         # token at position past+s; the query is at pos
         wlen = win_len_ref[0]
-        wk = wk_ref[0].astype(jnp.float32)               # [W, KD]
-        wv = wk if shared else wv_ref[0].astype(jnp.float32)
-        slot_i = jax.lax.broadcasted_iota(jnp.int32, (NH, W), 1)
+        wk = wk_ref[...].astype(jnp.float32)             # [R, W, KD]
+        wv = wk if shared else wv_ref[...].astype(jnp.float32)
+        slot_i = jax.lax.broadcasted_iota(jnp.int32, (R, NH, W), 2)
         ok_w = slot_i < wlen
         ok_w = jnp.logical_and(
             ok_w,
             jnp.logical_or(wlen - slot_i < win, win <= 0),
         )
         s_w = jax.lax.dot_general(
-            q_bd, wk, (((1,), (1,)), ((), ())),
+            q_bd, wk, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale                                        # [NH, W]
+        ) * scale                                        # [R, NH, W]
         s_w = jnp.where(ok_w, s_w, NEG_INF)
-        m_new = jnp.maximum(m_new, jnp.max(s_w, axis=1))
+        m_new = jnp.maximum(m_new, jnp.max(s_w, axis=2, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p_self = jnp.exp(s_self - m_new)
     p_sink = jnp.exp(sink - m_new)
-    l = l_ref[:, 0] * alpha + p_self + p_sink
-    acc = acc_ref[...] * alpha[:, None] + p_self[:, None] * v_cur
+    l = l_ref[:, :, :1] * alpha + p_self + p_sink        # [R, NH, 1]
+    acc = acc_ref[...] * alpha + p_self * v_cur          # [R, NH, KD]
     if W:
-        p_w = jnp.exp(s_w - m_new[:, None])              # [NH, W]
-        l = l + jnp.sum(p_w, axis=1)
+        p_w = jnp.exp(s_w - m_new)                       # [R, NH, W]
+        l = l + jnp.sum(p_w, axis=2, keepdims=True)
         acc = acc + jax.lax.dot_general(
-            p_w, wv, (((1,), (0,)), ((), ())),
+            p_w, wv, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
     # extract each row's own head block from the block-diagonal acc:
     # lane block j belongs to the query heads of KV head j
-    own = jax.lax.broadcasted_iota(jnp.int32, (NH, Dh), 0) // G
-    acc_bd = jnp.zeros((NH, Dh), jnp.float32)
+    own = jax.lax.broadcasted_iota(jnp.int32, (R, NH, Dh), 1) // G
+    acc_bd = jnp.zeros((R, NH, Dh), jnp.float32)
     for j in range(kvh):
         acc_bd = acc_bd + jnp.where(
-            own == j, acc[:, j * Dh : (j + 1) * Dh], 0.0
+            own == j, acc[:, :, j * Dh : (j + 1) * Dh], 0.0
         )
-    out = acc_bd / jnp.maximum(l, 1e-30)[:, None]
-    out_ref[0] = out.astype(out_ref.dtype)
+    out = acc_bd / jnp.maximum(l, 1e-30)
+    out_ref[...] = out.astype(out_ref.dtype)
 
 
 def prefix_attention_carry(
@@ -743,6 +888,65 @@ def ring_shape(page_size: int, kd: int, dtype_bytes: int, max_pages: int):
     return max(pages // group, 2) * group, group
 
 
+# What a kernel's blocks, scratch and temporaries may take together:
+# Mosaic's default scoped VMEM limit on v5e (16 MiB of its 128).
+VMEM_LIMIT_BYTES = 16 << 20
+ROWS_A_STEP = (8, 4, 2, 1)
+
+
+def decode_vmem_bytes(
+    rows: int, NH: int, Dh: int, KD: int, PS: int, D: int, GP: int, W: int,
+    *, pool_bytes: int, io_bytes: int, prefix: bool = False,
+    shared: bool = False, quantized: bool = False,
+) -> int:
+    """VMEM a call of ``rows`` rows a grid step takes, as the shapes
+    tell it: the ring, a group's scores, the pipeline's two buffers of
+    every block, the scratch, and the finalize's values over the block.
+    (The compiler's own count at the 4B cell's shape, 32 rows a step,
+    was 18.1 MiB where this says 34.5: the blocks of one sublane pad
+    less than a tile.)"""
+
+    def tile(sub, lanes, itemsize):
+        # a (sub, lanes) slab as VMEM holds it: whole (8, 128) tiles of
+        # 32 bits, so narrower values pad further
+        pack = 4 // itemsize
+        return (
+            -(-sub // (8 * pack)) * 8 * pack * -(-lanes // 128) * 128
+            * itemsize
+        )
+
+    both = 1 if shared else 2
+    ring = both * D * tile(PS, KD, pool_bytes)
+    if quantized:
+        ring += 2 * D * tile(1, PS, 4)
+    # scores, probabilities and mask of the largest group, two rows'
+    # side by side (its K and V are read from the ring tile by tile)
+    group = 2 * 4 * tile(NH, GP * PS, 4)
+    row_f32 = tile(NH, KD, 4)
+    blocks = 2 * tile(NH, Dh, io_bytes)              # q, out
+    blocks += both * tile(1, KD, io_bytes)           # current K, V
+    blocks += both * tile(W, KD, io_bytes) if W else 0
+    if prefix:
+        blocks += 2 * tile(NH, 1, 4) + row_f32       # m0, l0, acc0
+    scratch = 2 * row_f32 + 2 * tile(NH, 128, 4)     # q_bd, acc, m, l
+    finalize = 3 * row_f32
+    return ring + group + rows * (2 * blocks + scratch + finalize)
+
+
+def rows_per_step(B: int, *shape, **modes) -> int:
+    """Rows a grid step of the kernel takes: the largest of 8, 4, 2, 1
+    that divides the batch and fits ``VMEM_LIMIT_BYTES`` beside the ring
+    (``decode_vmem_bytes``'s arguments after ``rows``). A batch no
+    larger block divides, or rows too wide for two, runs a row a step."""
+    for rows in ROWS_A_STEP:
+        if rows == 1 or (
+            B % rows == 0
+            and decode_vmem_bytes(rows, *shape, **modes) <= VMEM_LIMIT_BYTES
+        ):
+            return rows
+    return 1
+
+
 def paged_decode_supported(q: jax.Array, k_pages: jax.Array) -> bool:
     """Shape/size gate for the compiled TPU path (interpret mode has no
     such constraints — tests call paged_decode_attention(interpret=True))."""
@@ -752,7 +956,7 @@ def paged_decode_supported(q: jax.Array, k_pages: jax.Array) -> bool:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "window_start", "scale")
+    jax.jit, static_argnames=("interpret", "window_start", "scale", "rows")
 )
 def paged_decode_attention(
     q: jax.Array,          # [B, NH, Dh] — current-step queries
@@ -788,6 +992,9 @@ def paged_decode_attention(
     window_start: bool = False,
     # the softmax scale where it is not 1/sqrt(Dh) (a latent layer's)
     scale: Optional[float] = None,
+    # rows a grid step; None: what ``rows_per_step`` gives the call's
+    # shapes (tests and benchmarks/paged_kernel_ab.py name one)
+    rows: Optional[int] = None,
 ) -> jax.Array:
     """Returns [B, NH, Dh] attention outputs for one decode step.
 
@@ -815,7 +1022,6 @@ def paged_decode_attention(
     window whose K/V have NOT been written to the page pool yet — the
     bulk page write happens once per window, outside the step scan, so
     the multi-GB pool is never copied per step."""
-    lowering.record_kernel("paged_decode", interpret=interpret)
     B, NH, Dh = q.shape
     L, NP, PS, KD = k_pages.shape
     KVH = k_cur.shape[1]
@@ -824,14 +1030,22 @@ def paged_decode_attention(
     W = 0 if win_k is None else win_k.shape[1]
     shared = v_pages is None
 
+    # heads on the sublanes, as the kernel keeps m / l
     if sink is None:
-        sink_g = jnp.full((1, NH), NEG_INF, jnp.float32)
+        sink_g = jnp.full((NH, 1), NEG_INF, jnp.float32)
     else:
-        sink_g = sink.astype(jnp.float32).reshape(1, NH)
+        sink_g = sink.astype(jnp.float32).reshape(NH, 1)
 
     quantized = k_scale is not None
     prefix = pfx_cnt is not None
     D, GP = ring_shape(PS, KD, k_pages.dtype.itemsize, MP)
+    R = rows or rows_per_step(
+        B, NH, Dh, KD, PS, D, GP, W,
+        pool_bytes=k_pages.dtype.itemsize, io_bytes=q.dtype.itemsize,
+        prefix=prefix, shared=shared, quantized=quantized,
+    )
+    assert B % R == 0, (B, R)
+    lowering.record_kernel("paged_decode", interpret=interpret, rows=R)
     kernel = functools.partial(
         _paged_decode_kernel,
         max_pages_per_seq=MP,
@@ -845,12 +1059,17 @@ def paged_decode_attention(
         prefix=prefix,
         window_start=window_start,
         shared=shared,
+        rows=R,
     )
 
-    # index maps take *s so the scalar-prefetch arity (4 to 6) needs
-    # no per-case lambdas
+    # a grid step's block of each per-row operand: its R rows. Index
+    # maps take *s so the scalar-prefetch arity (4 to 6) needs no
+    # per-case lambdas
+    def rows_of(*dims):
+        return pl.BlockSpec((R, *dims), lambda g, *s: (g,) + (0,) * len(dims))
+
     in_specs = [
-        pl.BlockSpec((1, NH, Dh), lambda b, *s: (b, 0, 0)),
+        rows_of(NH, Dh),
         pl.BlockSpec(memory_space=pl.ANY),  # K pool stays in HBM
     ]
     if not shared:
@@ -877,28 +1096,22 @@ def paged_decode_attention(
         ]
     # K then V; K alone where one pool's rows serve both
     both = 1 if shared else 2
-    in_specs += [pl.BlockSpec((1, 1, KD), lambda b, *s: (b, 0, 0))] * both
+    in_specs += [rows_of(1, KD)] * both
     operands += [x.reshape(B, 1, KD) for x in (k_cur, v_cur)[:both]]
     if W:
         scalars.append(jnp.asarray(win_len, jnp.int32).reshape(1))
-        in_specs += [
-            pl.BlockSpec((1, W, KD), lambda b, *s: (b, 0, 0))
-        ] * both
+        in_specs += [rows_of(W, KD)] * both
         operands += [win_k, win_v][:both]
     if prefix:
-        # m0 / l0 as [B, 1, NH]: a row's block is then the array's whole
-        # last two dims (a (1, NH) block of [B, NH] is no legal tile)
-        in_specs += [
-            pl.BlockSpec((1, 1, NH), lambda b, *s: (b, 0, 0)),
-            pl.BlockSpec((1, 1, NH), lambda b, *s: (b, 0, 0)),
-            pl.BlockSpec((1, NH, KD), lambda b, *s: (b, 0, 0)),
-        ]
+        # m0 / l0 as [B, NH, 1]: heads on the sublanes, and a block's
+        # last two dims are the array's whole
+        in_specs += [rows_of(NH, 1), rows_of(NH, 1), rows_of(NH, KD)]
         operands += [
-            m0.astype(jnp.float32).reshape(B, 1, NH),
-            l0.astype(jnp.float32).reshape(B, 1, NH),
+            m0.astype(jnp.float32).reshape(B, NH, 1),
+            l0.astype(jnp.float32).reshape(B, NH, 1),
             acc0.astype(jnp.float32),
         ]
-    in_specs.append(pl.BlockSpec((1, NH), lambda b, *s: (0, 0)))
+    in_specs.append(pl.BlockSpec((NH, 1), lambda g, *s: (0, 0)))
     operands.append(sink_g)
 
     # the K/V ring: D page slots (K's alone for a shared pool)
@@ -916,23 +1129,24 @@ def paged_decode_attention(
         ]
     scratch_shapes += [
         pltpu.SMEM((4,), jnp.int32),                 # the ring's cursor
-        pltpu.VMEM((NH, 128), jnp.float32),          # m
-        pltpu.VMEM((NH, 128), jnp.float32),          # l
-        pltpu.VMEM((NH, KD), jnp.float32),           # block-diag acc
+        pltpu.VMEM((R, NH, KD), jnp.float32),        # block-diag queries
+        pltpu.VMEM((R, NH, 128), jnp.float32),       # m
+        pltpu.VMEM((R, NH, 128), jnp.float32),       # l
+        pltpu.VMEM((R, NH, KD), jnp.float32),        # block-diag acc
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(B,),
+        grid=(B // R,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, NH, Dh), lambda b, *s: (b, 0, 0)),
+        out_specs=rows_of(NH, Dh),
         scratch_shapes=scratch_shapes,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NH, Dh), q.dtype),
-        # the ring's cursor and its fetches in flight pass from row to
-        # row: the grid runs in order (nothing lost on one-core v5e)
+        # the ring's cursor and its fetches in flight pass from block
+        # to block: the grid runs in order (nothing lost on one-core v5e)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),

@@ -433,16 +433,18 @@ _RING_CASES = [
 ]
 
 
+@pytest.mark.parametrize("rows", [8, 4, 2, 1])
 @pytest.mark.parametrize(
     "layout,mode,ring_pages,group_pages", _RING_CASES,
     ids=[f"{a}-{m}-ring{d}x{g}" for a, m, d, g in _RING_CASES],
 )
 def test_paged_decode_fetch_ring(
-    layout, mode, ring_pages, group_pages, paged_ring
+    layout, mode, ring_pages, group_pages, rows, paged_ring
 ):
     """The kernel's one fetch schedule against the jnp reference: a ring
     of page fetches over the batch's (row, page) sequence, whatever the
-    table's layout, with rows of every length in one batch."""
+    table's layout, with rows of every length in one batch, at every
+    number of rows a grid step (8 is what the batch of 8 gets)."""
     from sutro_tpu.ops.pallas_paged import prefix_attention_carry
 
     paged_ring(ring_pages, group_pages)
@@ -502,7 +504,8 @@ def test_paged_decode_fetch_ring(
     )
     got = paged_decode_attention(
         q[:, 0], kp, vp, LAYER, table, past_len, k_cur[:, 0], v_cur[:, 0],
-        win, sink, interpret=True, k_scale=ks, v_scale=vs, **wkw, **carry,
+        win, sink, interpret=True, k_scale=ks, v_scale=vs, rows=rows,
+        **wkw, **carry,
     )
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(
