@@ -181,7 +181,12 @@ def measure(sut, cfg, traffic_name: str, traffic_dir: Path, seed: int,
     problems, facts = [], {}
     if warm:
         gen.warm()
+        t_numbers = time.monotonic()
         problems, facts = correctness.numbers(sut, cfg, seed)
+        # the check runs inside set-up: its share of it, and who
+        # drove the decode it compared (``sut.logits_through_cache``)
+        facts["numbers_seconds"] = time.monotonic() - t_numbers
+        facts["numbers_source"] = getattr(sut, "numbers_source", "harness")
     t0 = time.monotonic() + gen.lead_in_s
     gen.start(t0)
     time.sleep(max(t0 - time.monotonic(), 0.0))
@@ -326,6 +331,11 @@ def main(argv=None) -> int:
         }
         if breakdown is not None and not rehearsal:
             result["breakdown"] = breakdown
+        # each number compared beside its limit: the line's last key
+        result["compared"] = dict(
+            correctness.compared(num_facts),
+            accounting_problems=[len(acc_problems), 0],
+        )
         facts = {
             "workload": cell["name"], "seed": args.seed, "seconds": seconds,
             "trace": args.trace, "n_chips": reading.n_chips,
@@ -374,6 +384,10 @@ def main(argv=None) -> int:
     finally:
         sut.close()
     if code == 0:
+        # the last lines of standard error, after whatever closing printed
+        for name, (number, limit) in result["compared"].items():
+            print(f"{tag}compared: {name} {number} limit {limit}",
+                  file=sys.stderr, flush=True)
         say(json.dumps(result))
     return code
 

@@ -21,6 +21,9 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 REPO = Path(__file__).resolve().parent.parent
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# kernels counted by an accessor of their own, ``lowering.<name>_counts``
+FURTHER_KERNELS = ("grouped_matmul", "ssm_state_read", "kda_state_read",
+                   "kda_state_commit")
 
 
 class NoDevice(SystemExit):
@@ -107,9 +110,19 @@ class System:
         return peak
 
     def kernel_paths(self) -> Dict[str, Dict[str, int]]:
+        """Traces by path (``lowered`` / ``interpreted`` / ``reference``)
+        of every kernel the program counts: the names of
+        ``lowering.snapshot()`` first, then the counts that live outside
+        it because only some models have the layer (a program without
+        one of these accessors still runs)."""
         from sutro_tpu.ops import lowering
 
-        return lowering.snapshot()
+        paths = lowering.snapshot()
+        for name in FURTHER_KERNELS:
+            counts = getattr(lowering, name + "_counts", None)
+            if counts is not None:
+                paths.setdefault(name, dict(counts()))
+        return paths
 
     def uses_kernels(self) -> bool:
         return bool(self.runner().use_pallas)
@@ -233,21 +246,27 @@ class System:
     def logits_through_cache(
         self, ids, n_prefill: int, n_decode: int
     ):
-        """Prefill ``ids[:n_prefill]`` through the runner's own prefill
-        program, then ``n_decode`` single-token decode steps through the
-        paged cache, feeding ``ids`` (not samples). Returns float32
-        logits ``[1 + n_decode, V]``: at the last prefill position and
-        at each decode step; for ``ids`` of several sequences ``[S, T]``,
-        ``[S, 1 + n_decode, V]``, every sequence through the same small
-        runner and the same jitted step (one compile however many). A
-        second runner shares the engine's weights and mesh and has a
-        small pool of its own, so no page the engine holds is touched;
-        it lives for this call only."""
+        """The system's logits at position ``n_prefill - 1`` and at each
+        of the next ``n_decode`` positions, every fed token the GIVEN one
+        (no sampling): float32 ``[1 + n_decode, V]``; for ``ids`` of
+        several sequences ``[S, T]``, ``[S, 1 + n_decode, V]``. A second
+        runner shares the engine's weights and mesh and has a small pool
+        of its own (``1 + max_pages_per_seq`` pages), so no page the
+        engine holds is touched; it lives for this call only, and its
+        pool is given back before the call returns.
+
+        Who drives the decode (``reference/README.md`` "The forced
+        forward"): where that runner offers ``forced_logits(seq,
+        n_prefill, n_decode)`` the program does, a sequence a call, in
+        whatever unit its decode steps (a token, a block, a verified
+        run), and what it returns is returned. Where it does not, the
+        harness does: the runner's own prefill program, then ``n_decode``
+        single-token decode steps through the paged cache, every
+        sequence through the same jitted step (one compile however
+        many). ``self.numbers_source`` says which."""
         import jax
-        import jax.numpy as jnp
         import numpy as np
 
-        from sutro_tpu.engine.kvcache import write_kv
         from sutro_tpu.engine.runner import ModelRunner
 
         base = self.runner()
@@ -257,6 +276,38 @@ class System:
             mesh=base.mesh,
         )
         ids = np.asarray(ids, np.int32)
+        forced = getattr(r, "forced_logits", None)
+        self.numbers_source = "harness" if forced is None else "forced_logits"
+        try:
+            if forced is None:
+                one = self._harness_decode(r, n_prefill, n_decode)
+            else:
+                def one(seq):
+                    return np.asarray(
+                        forced(seq, n_prefill, n_decode), np.float32
+                    )
+            if ids.ndim == 1:
+                return one(ids)
+            return np.stack([one(seq) for seq in ids])
+        finally:
+            # the jitted methods' caches keep the runner alive (it is
+            # their static argument): hand its pool back by hand, and
+            # drop its hold on the engine's weights
+            for leaf in jax.tree_util.tree_leaves(r.cache):
+                if not leaf.is_deleted():
+                    leaf.delete()
+            r.cache = r.params = None
+
+    def _harness_decode(self, r, n_prefill: int, n_decode: int):
+        """``one(seq)`` of ``logits_through_cache`` where the harness
+        drives the decode: a token a step."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from sutro_tpu.engine.kvcache import write_kv
+
+        MP = self.ecfg.max_pages_per_seq
         table = np.zeros((MP,), np.int32)
         n_pages = -(-(n_prefill + n_decode) // self.ecfg.kv_page_size)
         table[:n_pages] = np.arange(1, n_pages + 1)
@@ -290,9 +341,7 @@ class System:
                 out.append(np.asarray(logits))
             return np.stack(out)
 
-        if ids.ndim == 1:
-            return one(ids)
-        return np.stack([one(seq) for seq in ids])
+        return one
 
     def weights(self):
         return self.runner().params

@@ -35,7 +35,7 @@ if str(REPO) not in sys.path:
 
 from perfbench import correctness  # noqa: E402
 
-QUANTILES = (0.1, 0.25, 0.5, 0.75)
+QUANTILES = (0.05, 0.1, 0.25, 0.5, 0.75, 0.99)
 
 
 def through_float8(params):
@@ -119,7 +119,8 @@ def one_seed(cfg, reference, seed: int, control: bool, sequences=None):
     }
     # the jitted methods' caches keep every runner alive (it is their
     # static argument), so the next seed's weights fit only if this
-    # one's buffers are given back by hand
+    # one's buffers are given back by hand (the second small runner's
+    # pool is given back by ``sut.logits_through_cache`` itself)
     import jax
 
     for leaf in jax.tree_util.tree_leaves(

@@ -70,7 +70,10 @@ def plan():
     r.mcfg, r.ecfg, r.mesh = mcfg, ecfg, None
     r.sp = r.pp = 1
     r.ep_mesh = r.kernel_mesh = None
-    r.use_pallas = False
+    # what ``use_pallas: null`` resolves to on the chip: every call's own
+    # shape gate then picks its path
+    assert CFG["engine"]["use_pallas"] is None
+    r.use_pallas = True
 
     def nbytes(tree):
         return sum(int(np.prod(x.shape)) * x.dtype.itemsize
@@ -147,6 +150,11 @@ def test_decode_window_compiles_fits_and_holds_no_copy_of_the_state(
     assert mem.temp_size_in_bytes < plan["state"]
     assert mem.alias_size_in_bytes >= plan["resident"] - plan["weights"]
     assert pool_sized_temporaries(compiled, plan) == []
+    # a step reads the committed state through the kernel (the
+    # configuration's ``kernels``)
+    text = compiled.as_text()
+    assert "ssm_state_read" in CFG["kernels"]
+    assert "tpu_custom_call" in text and "ssm_state_read" in text
 
 
 @pytest.mark.parametrize("rows", [None, 8], ids=["the cell's rows", "8 rows"])

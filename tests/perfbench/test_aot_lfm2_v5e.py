@@ -68,7 +68,10 @@ def plan():
     r.mcfg, r.ecfg, r.mesh = mcfg, ecfg, None
     r.sp = r.pp = 1
     r.ep_mesh = r.kernel_mesh = None
-    r.use_pallas = False
+    # what ``use_pallas: null`` resolves to on the chip: every call's own
+    # shape gate then picks its path
+    assert CFG["engine"]["use_pallas"] is None
+    r.use_pallas = True
 
     def nbytes(tree):
         return sum(int(np.prod(x.shape)) * x.dtype.itemsize
@@ -110,6 +113,12 @@ def test_decode_window_compiles_and_fits(plan, silent_cache):
     assert plan["resident"] + mem.temp_size_in_bytes < HBM_LIMIT, (
         plan["resident"], mem.temp_size_in_bytes
     )
+    # the routed layers' products are the grouped kernel, none of them
+    # XLA's ragged product (the configuration's ``kernels``)
+    text = compiled.as_text()
+    assert "grouped_matmul" in CFG["kernels"]
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+    assert "ragged-dot" not in text
 
 
 def test_widest_prefill_compiles_and_fits(plan, silent_cache):

@@ -246,7 +246,7 @@ def test_the_rows_read_share_reads_the_counters_increments():
 def test_the_cell_is_listed_where_its_readers_find_something():
     listed = {m["name"] for m in BENCH["per_layer"]
               if CELL in m.get("workloads", ())}
-    assert listed == {
+    assert listed >= {
         "engine_host_us_per_row", "decode_step_device_ms",
         "prefill_device_us_per_token", "decode_row_steps_kept_share",
         "moe_expert_rows_max_over_mean",
@@ -255,7 +255,7 @@ def test_the_cell_is_listed_where_its_readers_find_something():
     }
     for m in BENCH["per_layer"]:
         if m["name"].startswith("dsa_") or m["name"].startswith("sparse_"):
-            assert m["workloads"] == [CELL]
+            assert CELL in m["workloads"]
             assert m["moves"] == "out_tokens_per_s_per_chip"
         if m["name"].startswith("mla_"):
             assert CELL not in m["workloads"]

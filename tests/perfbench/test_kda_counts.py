@@ -210,7 +210,7 @@ def test_a_program_or_a_configuration_without_the_layers_reads_nothing():
 def test_the_cell_is_listed_where_its_readers_find_something():
     listed = {m["name"] for m in BENCH["per_layer"]
               if CELL in m.get("workloads", ())}
-    assert listed == {
+    assert listed >= {
         "engine_host_us_per_row", "decode_step_device_ms",
         "prefill_device_us_per_token", "decode_row_steps_kept_share",
         "moe_expert_rows_max_over_mean", "state_fallback_prefill_share",
@@ -218,7 +218,7 @@ def test_the_cell_is_listed_where_its_readers_find_something():
     } | {mod.__name__.rsplit(".", 1)[1] for mod in READERS}
     for m in BENCH["per_layer"]:
         if m["name"].startswith("kda_"):
-            assert m["workloads"] == [CELL]
+            assert CELL in m["workloads"]
             assert m["moves"] == "out_tokens_per_s_per_chip"
     e2e = next(m for m in BENCH["end_to_end"]
                if m["name"] == "out_tokens_per_s_per_chip")

@@ -96,3 +96,50 @@ def test_what_the_routed_rule_cannot_see(weights, monkeypatch):
     problems, facts = correctness.numbers(sut, keys, seed)
     say(seed, name, problems, facts)
     assert problems == []
+
+
+# -- the chip configuration's own values (PR 56) ------------------------------
+
+CHIP_FILE = json.loads((Path(correctness.__file__).parent
+                        / "configs/lfm2-24b-a2b-l10-v5e1.json").read_text())
+
+
+def test_the_chip_files_values_are_what_the_check_allows():
+    """Sized from 115 seeds on the chip (the file's ``numbers.why``): the
+    low quantile moved off the range's old end, the cap held at a high
+    quantile of a run's 288 positions in place of their maximum."""
+    spec = correctness.routed_spec(CHIP_FILE)
+    assert (spec["sequences"], spec["quantile"], spec["cap"],
+            spec["cap_quantile"]) == (32, 0.05, 0.5, 0.99)
+    assert spec["quantile"] == correctness.QUANTILE_RANGE[0]
+    assert spec["cap_quantile"] == correctness.MIN_CAP_QUANTILE
+    assert spec["sequences"] >= correctness.CAP_QUANTILE_SEQUENCES
+    for word in ("115 seeds", "1098603819", "float8_e4m3", "0.99 quantile"):
+        assert word in spec["why"]
+
+
+@pytest.mark.parametrize("name", ["correct", "weights through float8_e4m3"])
+def test_the_chip_files_rule_on_this_preset(weights, monkeypatch, name):
+    """The file's quantile, cap and cap_quantile over its 32 sequences,
+    on the CPU preset: the correct bf16 system passes, and the control
+    (the precision below) fails BOTH limits, as it does on the chip."""
+    seed = SEEDS[0]
+    numbers = {k: CHIP_FILE["numbers"][k]
+               for k in ("sequences", "quantile", "cap", "cap_quantile", "why")}
+    if name == "correct":
+        sut, keys = ForwardSystem(MCFG, weights(seed)), published_keys(MCFG)
+    else:
+        sut, keys = wrong_systems(MCFG, weights(seed), monkeypatch)[name]()
+    problems, facts = correctness.numbers(sut, dict(keys, numbers=numbers), seed)
+    print(f"{name}: 0.05 quantile {facts['rel_err_quantile']:.4f}, 0.99 "
+          f"quantile {facts['rel_err_cap_quantile']:.4f}, largest "
+          f"{facts['rel_err_max']:.4f} -> {len(problems)} problem(s)")
+    assert facts["positions"] == 288 and facts["cap_quantile"] == 0.99
+    if name == "correct":
+        assert problems == []
+        assert facts["rel_err_quantile"] < 0.8 * TOL
+        assert facts["rel_err_cap_quantile"] < 0.8 * numbers["cap"]
+    else:
+        assert len(problems) == 2
+        assert facts["rel_err_quantile"] > 1.5 * TOL
+        assert facts["rel_err_cap_quantile"] > numbers["cap"]

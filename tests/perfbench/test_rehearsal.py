@@ -31,6 +31,27 @@ def run(*flags, cwd=REPO, timeout=600):
     )
 
 
+WINDOWS = (8, 24)
+
+
+def window_with(run_once, expect, windows=WINDOWS):
+    """``run_once(seconds)``'s process whose result line holds every
+    metric of ``expect``. A reader reads a finished row or the second of
+    two progress updates, and a loaded machine can leave an 8 s CPU
+    window without one: the rate then has no reading (exit 4) or a
+    per-layer reader leaves its metric out. So the window is not left to
+    the clock alone: where the first lacks a reading the rehearsal runs
+    once more, three times as long, and that window is the limit."""
+    for seconds in windows:
+        proc = run_once(seconds)
+        lines = proc.stdout.splitlines()
+        if proc.returncode == 0 and lines and lines[-1].startswith(TAG):
+            result = json.loads(lines[-1][len(TAG):])
+            if result["attempted"] > 0 and expect <= set(result["metrics"]):
+                break
+    return proc
+
+
 def result_of(proc):
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-3000:]
     lines = proc.stdout.splitlines()
@@ -38,7 +59,10 @@ def result_of(proc):
     for word in DEVICE_WORDS:
         assert word not in proc.stdout, word
     result = json.loads(lines[-1][len(TAG):])
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]   # compared comes last
+    for number, limit in result["compared"].values():
+        assert isinstance(number, (int, float)) and isinstance(limit, (int, float))
     assert result["device"]["platform"] == "cpu"
     assert set(result["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     for m in result["metrics"].values():

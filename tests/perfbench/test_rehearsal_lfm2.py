@@ -11,7 +11,7 @@ import sys
 
 import pytest
 
-from .test_rehearsal import REPO, TAG, result_of
+from .test_rehearsal import REPO, TAG, result_of, window_with
 
 ADDED = REPO / "perfbench/rehearsal/cells-lfm2.json"
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -49,10 +49,10 @@ def test_added_entries_fit_beside_the_rehearsal_file():
          "moe_expert_rows_max_over_mean", "state_fallback_prefill_share"}),
 ])
 def test_rehearsal_of_the_mixed_layers_cell(trace, expect):
-    proc = rehearse(
+    proc = window_with(lambda seconds: rehearse(
         "--workload", "tiny-lfm2.generate-jobs", "--seed", str(2**31 + 9),
-        "--seconds", "8", "--trace", str(trace),
-    )
+        "--seconds", str(seconds), "--trace", str(trace),
+    ), expect)
     result = result_of(proc)
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0

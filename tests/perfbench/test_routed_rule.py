@@ -159,9 +159,13 @@ def test_the_dense_rule_is_chosen_by_the_reference_family():
 
 
 @pytest.mark.parametrize("ask", [
-    {"quantile": 0.6}, {"quantile": 0.05}, {"quantile": 1}, {"cap": 0.6},
+    {"quantile": 0.6}, {"quantile": 0.04}, {"quantile": 1}, {"cap": 0.6},
     {"cap": 0.0}, {"sequences": 3}, {"sequences": 4.0}, {"why": " "},
     {"tolerance": 0.5}, None,
+    # cap_quantile: never under 0.99, a float, and only with 32 sequences
+    {"cap_quantile": 0.98, "sequences": 32}, {"cap_quantile": 1, "sequences": 32},
+    {"cap_quantile": 1.01, "sequences": 32}, {"cap_quantile": 0.99},
+    {"cap_quantile": 0.995, "sequences": 31},
 ], ids=str)
 def test_a_file_cannot_ask_for_more_than_the_check_allows(ask):
     cfg = published_keys(MCFG)
@@ -172,6 +176,13 @@ def test_a_file_cannot_ask_for_more_than_the_check_allows(ask):
     with pytest.raises(ValueError):
         correctness.routed_spec(cfg)
     assert correctness.routed_spec(published_keys(MCFG))["quantile"] == 0.25
+    # what the check does allow: the key left out, the maximum stated, a
+    # high quantile over enough sequences, the range's low end
+    fine = published_keys(MCFG)
+    fine["numbers"].update(cap_quantile=1.0)
+    assert correctness.routed_spec(fine)["cap_quantile"] == 1.0
+    fine["numbers"].update(cap_quantile=0.99, sequences=32, quantile=0.05)
+    assert correctness.routed_spec(fine)["sequences"] == 32
 
 
 def test_the_routed_rule_on_hand_made_errors():

@@ -77,6 +77,6 @@ def test_metrics_for_follows_the_end_to_end_metric(cell):
         assert (m in layer) == (here and m["moves"] in e2e), m["name"]
     if "job_turnaround_s" in e2e:
         assert "out_tokens_per_s_per_chip" not in e2e
-        assert {m["name"] for m in layer} == {
+        assert {m["name"] for m in layer} >= {
             "fsm_host_us_per_token", "decode_burst_tokens_per_s",
             "constraint_build_share", "turnaround_sched_host_share"}
