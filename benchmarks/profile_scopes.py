@@ -19,7 +19,10 @@ op's self time goes to the PART its ``op_name`` opens with
 scopes inside (``mixer/attn_window``, ``ffn/moe_ffn/shared_expert``,
 ``mixer/mla_mixer/mla_absorb/dsa_attend``, ``cache/kda_commit``, and for a
 residual stream of several lanes ``mixer/hc_coeff``, ``ffn/hc_sinkhorn``,
-``mixer/hc_read``, ``ffn/hc_write``, ``mixer/mla_mixer/mla_yarn``;
+``mixer/hc_read``, ``ffn/hc_write``, ``mixer/mla_mixer/mla_yarn``, and
+for a model that generates by blocks ``bd_denoise`` / ``bd_commit`` round
+the two kinds of forward and ``sample/bd_confidence``,
+``sample/bd_transfer``;
 OBSERVABILITY.md "Parts of a step" lists them), read from the trace's
 OWN optimized HLO by ``perfbench/trace_parts.py``: the reducer of the
 benchmark's ``decode_*_ms_per_step`` metrics and of
@@ -61,6 +64,9 @@ def main() -> None:
     ap.add_argument("--prompts", type=int, nargs="+", default=[1100, 1800, 2600, 3400])
     ap.add_argument("--windows", type=int, default=4)
     ap.add_argument("--masked-steps", type=int, default=0)
+    ap.add_argument("--denoising-steps", type=int, default=2,
+                    help="denoising forwards a block, for a model that "
+                         "generates by blocks (the unit is then a FORWARD)")
     ap.add_argument("--top", type=int, default=4,
                     help="ops listed a scope, the costliest first")
     ap.add_argument("--by-name", action="store_true",
@@ -110,9 +116,27 @@ def main() -> None:
     temp, top_p = np.full((B,), 0.7, np.float32), np.full((B,), 0.95, np.float32)
     last = rng.integers(0, mcfg.vocab_size, B).astype(np.int32)
     past = lens.astype(np.int32)
+    Bk = mcfg.block_length
+    # a model that generates by blocks: a window of whole blocks, each
+    # ``--denoising-steps`` denoising forwards and a commit
+    # (``_decode_block_jit``); its rows' pasts are whole blocks
+    forwards = steps // Bk * (args.denoising_steps + 1) if Bk > 1 else steps
+
+    def block_window(i):
+        nonlocal past
+        toks, _, _ = runner.decode_block_async(
+            np.full((B, Bk), mcfg.mask_token_id, np.int32), np.ones((B,), bool),
+            past // Bk * Bk, tables, jax.random.PRNGKey(i), temp, top_p,
+            steps // Bk, steps=np.full((B,), args.denoising_steps, np.int32),
+            rule=np.zeros((B,), np.int32),
+        )
+        np.asarray(toks)
+        past = past + steps
 
     def window(i):
         nonlocal last, past
+        if Bk > 1:
+            return block_window(i)
         toks, _ = runner.decode_multi(
             last, past, tables, jax.random.PRNGKey(i), temp, top_p, steps
         )
@@ -161,13 +185,14 @@ def main() -> None:
     order = {p: i for i, p in enumerate(PARTS + (NO_PART,))}
     for module, scopes in sorted(by.items()):
         n = runs.get(module, {}).get("runs", 0.0)
-        fused = "decode_multi" in module
-        per = max(n, 1.0) * (steps if fused else 1) / 1e3
+        fused = "decode_multi" in module or "decode_block_jit" in module
+        per = max(n, 1.0) * (forwards if fused else 1) / 1e3
         by_part = defaultdict(float)
         for scope, ops in scopes.items():
             by_part[scope.split("/")[0]] += sum(ops.values())
         print(json.dumps({
-            "program": module, "runs": n, "unit": "step" if fused else "run",
+            "program": module, "runs": n,
+            "unit": ("forward" if Bk > 1 else "step") if fused else "run",
             "ms": sum(by_part.values()) / per,
             "by_part_ms": {
                 p: round(v / per, 3)

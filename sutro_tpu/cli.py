@@ -1039,6 +1039,13 @@ def engine_info(model: Optional[str]) -> None:
                 f"hc_mult={m.hc_mult} hc_sublayers={m.hc_sublayers} (a "
                 "residual stream of several lanes, mixed a token a sublayer)"
             )
+        if m.block_length > 1:
+            click.echo(
+                f"block_length={m.block_length} mask_token_id="
+                f"{m.mask_token_id} denoising_steps="
+                f"{m.denoising_steps or m.block_length} remasking="
+                f"{m.remasking} (generation by diffusion over blocks)"
+            )
         if m.state_kind:
             from .engine.kvcache import state_bytes_per_slot
 

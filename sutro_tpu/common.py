@@ -72,6 +72,7 @@ ModelOptions = Union[
         "granite-4.0-h-micro",
         "mellum2-12b-a2.5b",
         "nemotron-3-nano-30b-a3b",
+        "sdar-30b-a3b-chat",
         "qwen-3-embedding-0.6b",
         "qwen-3-embedding-6b",
         "qwen-3-embedding-8b",
@@ -113,6 +114,9 @@ def model_catalog() -> Dict[str, Dict[str, Any]]:
     add("granite-4.0-h-micro", "granite-4.0-h-micro")
     add("mellum2-12b-a2.5b", "mellum2-12b-a2.5b")
     add("nemotron-3-nano-30b-a3b", "nemotron-3-nano-30b-a3b")
+    # generates by diffusion over blocks (models/configs.py): a job may
+    # state denoising_steps, remasking, confidence_threshold
+    add("sdar-30b-a3b-chat", "sdar-30b-a3b-chat")
     add("qwen-3-embedding-0.6b", "qwen3-emb-0.6b", embedding=True)
     add("qwen-3-embedding-6b", "qwen3-emb-6b", embedding=True)
     add("qwen-3-embedding-8b", "qwen3-emb-8b", embedding=True)

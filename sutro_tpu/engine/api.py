@@ -95,6 +95,70 @@ def resolve_model(model: str) -> Tuple[str, ModelConfig, Dict[str, Any]]:
     return key, MODEL_CONFIGS[key], meta
 
 
+#: ``sampling_params`` keys of generation by blocks (GenRequest)
+BLOCK_SAMPLING_KEYS = ("denoising_steps", "remasking", "confidence_threshold")
+
+
+def check_block_request(
+    mcfg: ModelConfig, payload: Dict[str, Any], sampling: Dict[str, Any]
+) -> None:
+    """What a job may ask of a model that generates by blocks
+    (``ModelConfig.block_length`` > 1), and of any other model about
+    blocks: refused here, by name, before a record exists, not
+    mis-served later. A constraint's mask walks a left-to-right
+    automaton and a block's positions fill out of order; penalties and
+    a seed a row are the single step's, which such a model has not."""
+    from ..models.configs import REMASKING
+
+    Bk = mcfg.block_length
+    asked = [k for k in BLOCK_SAMPLING_KEYS if sampling.get(k) is not None]
+    if Bk == 1:
+        if asked:
+            raise ValueError(
+                f"sampling_params {asked} are those of a model that "
+                f"generates by blocks; {mcfg.name} generates a token a "
+                "forward (block_length 1)"
+            )
+        return
+
+    def refuse(what: str, why: str) -> None:
+        if telemetry.ENABLED:
+            telemetry.BLOCK_REFUSALS_TOTAL.inc(1.0, what)
+        raise ValueError(
+            f"{mcfg.name} generates by blocks of {Bk} (block_length): "
+            f"{what} is not built for it ({why})"
+        )
+
+    if payload.get("output_schema"):
+        refuse("output_schema", "a constraint's mask follows a "
+               "left-to-right automaton; a block fills out of order")
+    if payload.get("random_seed_per_input"):
+        refuse("random_seed_per_input", "a seed a row draws in the "
+               "single step, which a block model has not")
+    if payload.get("stages") is not None:
+        refuse("stages", "a stage graph's rows are re-queued a stage")
+    for key, off in (("presence_penalty", 0.0), ("frequency_penalty", 0.0),
+                     ("repetition_penalty", 1.0)):
+        if float(sampling.get(key, off)) != off:
+            refuse(key, "penalties count tokens between single steps")
+    steps = sampling.get("denoising_steps")
+    if steps is not None and not 1 <= int(steps) <= Bk:
+        raise ValueError(
+            f"sampling_params['denoising_steps'] {steps}: from 1 to the "
+            f"block's length {Bk}"
+        )
+    rule = sampling.get("remasking")
+    if rule is not None and rule not in REMASKING:
+        raise ValueError(
+            f"sampling_params['remasking'] {rule!r}: one of {REMASKING}"
+        )
+    tau = sampling.get("confidence_threshold")
+    if tau is not None and not 0.0 <= float(tau) <= 1.0:
+        raise ValueError(
+            f"sampling_params['confidence_threshold'] {tau}: a probability"
+        )
+
+
 class LocalEngine:
     def __init__(self, ecfg: Optional[EngineConfig] = None):
         self.ecfg = ecfg or load_engine_config()
@@ -234,6 +298,7 @@ class LocalEngine:
 
         sampling = dict(payload.get("sampling_params") or {})
         sampling.setdefault("max_new_tokens", self.ecfg.max_new_tokens)
+        check_block_request(mcfg, payload, sampling)
         if payload.get("output_schema"):
             # The schema guarantee ("output_schema => complete JSON")
             # must stay feasible: raise the row cap to the schema's
@@ -2319,6 +2384,17 @@ class _GenSession:
                     ),
                     repetition_penalty=float(
                         sampling.get("repetition_penalty", 1.0)
+                    ),
+                    # generation by blocks (validated at submit:
+                    # ``check_block_request``; None: the model's own)
+                    denoising_steps=int(
+                        sampling.get("denoising_steps") or 0
+                    ),
+                    remasking=sampling.get("remasking"),
+                    confidence_threshold=(
+                        None
+                        if sampling.get("confidence_threshold") is None
+                        else float(sampling["confidence_threshold"])
                     ),
                 )
             )

@@ -39,7 +39,10 @@ from .kvcache import (
     window_span_pages, window_table, write_kv,
 )
 from ..ops.lowering import part
-from ..ops.sampling import NEG_INF, cumulative_logprob, sample, unpack_mask
+from ..ops.sampling import (
+    NEG_INF, cumulative_logprob, sample, sample_with_confidence, transfer,
+    unpack_mask,
+)
 
 
 def next_bucket(n: int, lo: int = 16, hi: int = 1 << 20) -> int:
@@ -276,6 +279,44 @@ class ModelRunner:
                 f"{mcfg.name} holds a share of each layer's experts "
                 "(moe_experts_held): it runs on one chip, not under a mesh"
             )
+        Bk = mcfg.block_length
+        if Bk > 1:
+            # generation by blocks: what is not built is refused by name
+            if mesh is not None:
+                raise NotImplementedError(
+                    f"{mcfg.name} generates by blocks of {Bk} "
+                    "(block_length): it runs on one chip, not under a mesh "
+                    "or pipeline stages"
+                )
+            if ecfg.quantize or getattr(ecfg, "kv_quantize", None):
+                raise NotImplementedError(
+                    f"{mcfg.name} generates by blocks (block_length): not "
+                    "with quantize or kv_quantize"
+                )
+            if getattr(ecfg, "interactive_slots", 0):
+                raise NotImplementedError(
+                    f"{mcfg.name} generates by blocks (block_length): not "
+                    "with interactive_slots > 0 (the chat path's stream a "
+                    "token and its preemption by hibernation are not "
+                    "carried)"
+                )
+            if Bk & (Bk - 1) or not 0 <= mcfg.mask_token_id < mcfg.vocab_size:
+                raise ValueError(
+                    f"{mcfg.name}: block_length {Bk} must be a power of two "
+                    f"and mask_token_id {mcfg.mask_token_id} an id of the "
+                    "vocabulary"
+                )
+            for name in ("kv_page_size", "prefill_chunk", "max_model_len"):
+                if getattr(ecfg, name) % Bk:
+                    raise ValueError(
+                        f"{mcfg.name}: {name} {getattr(ecfg, name)} is no "
+                        f"multiple of block_length {Bk} (a block never "
+                        "straddles a page, a chunk or the context's end)"
+                    )
+            # the benchmark's door (perfbench/reference/README.md "The
+            # forced forward"): bound on a block model's runner ALONE, so
+            # that every other model's numbers check keeps its driver
+            self.forced_logits = self._forced_logits
         # explicit shard_map EP for MoE MLPs (ops/moe_ep.py). Not under
         # sp/pp: those paths already wrap layers in their own shard_map
         # and nesting is unsupported — they keep GSPMD MoE semantics.
@@ -721,6 +762,16 @@ class ModelRunner:
                 * self.mcfg.hc_mult * self.mcfg.hidden_size
                 * jnp.dtype(self.ecfg.activation_dtype).itemsize
             )
+        if self.mcfg.block_length > 1:
+            # a denoising forward's logits are [batch x block, V]: the
+            # head's product, its float32 copy, the scaled copy and the
+            # draw's noise stand together (311 MB each at 128 rows of 4
+            # over 151,936), which is no transient the reserve was
+            # sized for
+            in_use += 4 * 4 * (
+                self.ecfg.decode_batch_size * self.mcfg.block_length
+                * self.mcfg.vocab_size
+            )
         reserve = int(limit * HBM_RESERVE_FRACTION)
         page = self._page_bytes_per_device(dtype)
         avail = limit - in_use - reserve
@@ -845,6 +896,10 @@ class ModelRunner:
             # sublayers that each mix them a token
             "hc_mult": int(self.mcfg.hc_mult),
             "hc_sublayers": int(self.mcfg.hc_sublayers),
+            # generation by blocks: positions a block (1: a causal
+            # model) and the id an open position holds (-1: none)
+            "block_length": int(self.mcfg.block_length),
+            "mask_token_id": int(self.mcfg.mask_token_id),
             "kv_heads": int(self.mcfg.num_kv_heads),
             "head_dim": int(self.mcfg.head_dim),
             "kv_dtype_bytes": (
@@ -1200,6 +1255,17 @@ class ModelRunner:
         )
         return logits[:, 0], cache, self._route_stats(k)
 
+    def whole_blocks(self, n: int) -> int:
+        """The leading tokens of ``n`` that are whole blocks: what a
+        prefill of a model that generates by blocks takes of a prompt
+        (the rest, under a block long, starts the first generated block:
+        ``decode_block_async``'s ``first``). ``n`` for a causal model. A
+        prefill entry point cuts what it is given to this length, so a
+        chunk's padding starts at a block's edge and no valid query sees
+        it."""
+        Bk = self.mcfg.block_length
+        return n if Bk == 1 else max(n, 0) // Bk * Bk
+
     def _prefill_out(self, logits, route, n: int, on_device: bool):
         """What a prefill entry point returns. ``on_device``: the
         program's own ``(logits [B, V], routing counts)`` where they
@@ -1234,6 +1300,7 @@ class ModelRunner:
         once into pages at the head of ``page_table``)."""
         if faults.ACTIVE is not None:
             faults.inject("runner.prefill")
+        token_ids = token_ids[: self.whole_blocks(start + len(token_ids)) - start]
         n = len(token_ids)
         C = self.ecfg.prefill_chunk
         # the chunked paged path does not route through the ring (sp) or
@@ -1309,6 +1376,7 @@ class ModelRunner:
         of one per row."""
         if faults.ACTIVE is not None:
             faults.inject("runner.prefill")
+        rows = [r[: self.whole_blocks(len(r))] for r in rows]
         n = len(rows)
         maxlen = max((len(r) for r in rows), default=1)
         T = next_bucket(max(maxlen, 1), lo=16, hi=self.ecfg.max_context())
@@ -1349,6 +1417,10 @@ class ModelRunner:
         their K/V land on the garbage page."""
         if faults.ACTIVE is not None:
             faults.inject("runner.prefill")
+        rows = [
+            r[: self.whole_blocks(int(s) + len(r)) - int(s)]
+            for r, s in zip(rows, starts)
+        ]
         n = len(rows)
         maxlen = max((len(r) for r in rows), default=1)
         T = next_bucket(max(maxlen, 1), lo=16, hi=self.ecfg.max_context())
@@ -1924,6 +1996,271 @@ class ModelRunner:
         return toks, logps
 
     # ------------------------------------------------------------------
+    # generation by blocks (``ModelConfig.block_length`` > 1)
+    # ------------------------------------------------------------------
+
+    def _block_forward(
+        self, params, cache: KVCache, x, start, page_table, past_len=None,
+        window_past=None,
+    ):
+        """One forward of a block ``x`` [B, Bk] at positions ``start``
+        [B] + 0..Bk-1 over the paged past (``past_len`` tokens, ``start``
+        where no window runs), the window's earlier blocks and the block
+        itself under the block mask: ``(logits [B, Bk, V], K/V of the
+        block)``. Both kinds of forward are this one: a denoising
+        forward reads the logits and drops the K/V, a commit forward
+        keeps the K/V and reads no logits (the head is then dead code
+        and the compiler drops it)."""
+        B, Bk = x.shape
+        positions = start[:, None] + jnp.arange(Bk, dtype=jnp.int32)[None]
+        logits, _, (k, v) = transformer.forward(
+            self.mcfg, params, x, positions, jnp.full((B,), Bk, jnp.int32),
+            paged_past=self._paged(cache, page_table),
+            past_len=start if past_len is None else past_len,
+            window_past=window_past,
+            use_pallas=self.use_pallas,
+            kernel_mesh=self.kernel_mesh,
+            ep_mesh=self.ep_mesh,
+        )
+        return logits, (k, v)
+
+    @functools.partial(
+        jax.jit, static_argnums=(0, 14), donate_argnums=(2,)
+    )
+    def _decode_block_jit(
+        self, params, cache: KVCache, first, live, past_len, page_table,
+        rng, temperature, top_p, top_k, steps, rule, tau, blocks: int,
+    ):
+        """A WINDOW of ``blocks`` whole blocks for the batch in one
+        device program: for each block a ``while_loop`` of denoising
+        forwards (K/V not kept) that ends when no row holds a mask, at
+        most ``Bk`` turns, then the commit forward of the filled block.
+        ``first`` [B, Bk] is the window's first block as the host knows
+        it (a new row's leftover prompt tokens, then masks; all masks
+        for a row that continues; no mask at all for a slot that is not
+        live); every later block starts as masks. ``steps`` [B] (the
+        denoising forwards a row's block takes: a forward fills the even
+        share ``Bk // steps``, the first ``Bk % steps`` one more),
+        ``rule`` [B] and ``tau`` [B] are operands, not static: one
+        program serves every request.
+
+        As in ``_decode_multi_jit`` the pool is a CONSTANT of the loops:
+        a committed block's K/V lands in the window's buffers, which the
+        later blocks' attention reads beside the pages, and the pool
+        takes one write a window. Returns tokens and log-probabilities
+        ``[blocks * Bk, B]`` in position order (``decode_multi_async``'s
+        layout), the denoising forwards each block ran ``[blocks]``, the
+        cache and the routing counts ``[forwards, 6]`` padded with
+        zeros."""
+        m = self.mcfg
+        B, Bk = first.shape
+        L, KD = m.num_kv_layers, m.page_width
+        MASK = m.mask_token_id
+        dtype = cache.k_pages.dtype
+        W = blocks * Bk
+        with part("cache"):
+            wk0 = jnp.zeros((L, B, W, KD), dtype)
+            wv0 = jnp.zeros((L, B, W, KD), dtype)
+        rep = lambda a: jnp.repeat(a, Bk)            # a row's, a position
+        temp_n, top_p_n, top_k_n = rep(temperature), rep(top_p), rep(top_k)
+        base, extra = Bk // steps, Bk % steps
+        zero_route = jnp.zeros((6,), jnp.float32)
+
+        def one_block(carry, b):
+            wk, wv = carry
+            start = past_len + b * Bk
+            window_past = (wk, wv, b * Bk)
+
+            def forward(x):
+                return self._block_forward(
+                    params, cache, x, start, page_table, past_len,
+                    window_past,
+                )
+
+            def denoise(c):
+                x, lp, t, routes = c
+                with jax.named_scope("bd_denoise"):
+                    logits, (k, _) = forward(x)
+                x0, conf, logp = sample_with_confidence(
+                    logits.reshape(B * Bk, -1),
+                    jax.random.fold_in(jax.random.fold_in(rng, b), t),
+                    temperature=temp_n, top_p=top_p_n, top_k=top_k_n,
+                    exclude=MASK,
+                )
+                x, taken = transfer(
+                    x, x0.reshape(B, Bk), conf.reshape(B, Bk),
+                    base + (t < extra).astype(jnp.int32), rule, tau, MASK,
+                )
+                lp = jnp.where(taken, logp.reshape(B, Bk), lp)
+                route = self._route_stats(k)
+                if route is not None:
+                    routes = routes.at[t].set(route)
+                return x, lp, t + 1, routes
+
+            x = jnp.where(
+                b == 0, first,
+                jnp.where(live[:, None], jnp.int32(MASK), jnp.int32(0)),
+            )
+            x, lp, turns, routes = jax.lax.while_loop(
+                lambda c: jnp.any(c[0] == MASK) & (c[2] < Bk),
+                denoise,
+                (x, jnp.zeros((B, Bk), jnp.float32), jnp.int32(0),
+                 jnp.zeros((Bk + 1, 6), jnp.float32)),
+            )
+            with jax.named_scope("bd_commit"):
+                _, (k, v) = forward(x)
+            route = self._route_stats(k)
+            routes = routes.at[Bk].set(
+                zero_route if route is None else route
+            )
+            if isinstance(k, MixedChunk):
+                k = k.k
+            with part("cache"):
+                wk = jax.lax.dynamic_update_slice(
+                    wk, k.astype(dtype).reshape(L, B, Bk, KD),
+                    (0, 0, b * Bk, 0),
+                )
+                wv = jax.lax.dynamic_update_slice(
+                    wv, v.astype(dtype).reshape(L, B, Bk, KD),
+                    (0, 0, b * Bk, 0),
+                )
+            return (wk, wv), (x, lp, turns, routes)
+
+        (wk, wv), (xs, lps, turns, routes) = jax.lax.scan(
+            one_block, (wk0, wv0), jnp.arange(blocks, dtype=jnp.int32)
+        )
+        cache = write_kv(
+            cache, wk, wv, page_table, past_len,
+            jnp.full((B,), W, jnp.int32),
+            use_pallas=self.use_pallas,
+            kernel_mesh=self.kernel_mesh,
+        )
+        # [blocks, B, Bk] -> [blocks * Bk, B]: position order
+        toks = xs.transpose(0, 2, 1).reshape(W, B)
+        logps = lps.transpose(0, 2, 1).reshape(W, B)
+        return toks, logps, turns, cache, routes.reshape(-1, 6)
+
+    def decode_block_async(
+        self,
+        first: np.ndarray,           # [B, Bk] int32
+        live: np.ndarray,            # [B] bool
+        past_len: np.ndarray,        # [B] int32, multiples of Bk
+        page_table: np.ndarray,      # [B, MP] int32
+        rng: jax.Array,
+        temperature: np.ndarray,     # [B]
+        top_p: np.ndarray,           # [B]
+        blocks: int,
+        top_k: Optional[np.ndarray] = None,
+        steps: Optional[np.ndarray] = None,   # [B] int32 in 1..Bk
+        rule: Optional[np.ndarray] = None,    # [B] int32 (sampling.STATIC..)
+        tau: Optional[np.ndarray] = None,     # [B] float32
+    ):
+        """One window of ``blocks`` blocks for the batch
+        (``_decode_block_jit``), dispatched without a wait:
+        ``(tokens [blocks * Bk, B], logprobs, denoising forwards a
+        block [blocks])`` on the device; the routing counts wait in
+        ``window_route`` as a fused window's do. A window needs no
+        token of the window before it (a later block starts as masks),
+        so windows chain with nothing handed over."""
+        if faults.ACTIVE is not None:
+            faults.inject("runner.decode")
+        from ..models.configs import REMASKING
+
+        m = self.mcfg
+        B, Bk = first.shape
+        if top_k is None:
+            top_k = np.zeros((B,), np.int32)
+        if steps is None:
+            steps = np.full((B,), m.denoising_steps or Bk, np.int32)
+        if rule is None:
+            rule = np.full((B,), REMASKING.index(m.remasking), np.int32)
+        if tau is None:
+            tau = np.full((B,), m.confidence_threshold, np.float32)
+        self.count_sample(temperature)
+        # every forward of a block reads the row's pages once
+        self._count_kv_pages(
+            past_len, page_table, blocks * (int(np.max(steps)) + 1), None
+        )
+        toks, logps, turns, self.cache, self.window_route = (
+            self._decode_block_jit(
+                self.params,
+                self.cache,
+                jnp.asarray(first, jnp.int32),
+                jnp.asarray(live, bool),
+                jnp.asarray(past_len, jnp.int32),
+                jnp.asarray(page_table, jnp.int32),
+                rng,
+                jnp.asarray(temperature, jnp.float32),
+                jnp.asarray(top_p, jnp.float32),
+                jnp.asarray(top_k, jnp.int32),
+                jnp.asarray(np.clip(steps, 1, Bk), jnp.int32),
+                jnp.asarray(rule, jnp.int32),
+                jnp.asarray(tau, jnp.float32),
+                blocks,
+            )
+        )
+        return toks, logps, turns
+
+    @functools.partial(jax.jit, static_argnums=(0,))
+    def _decode_block_denoise_jit(self, params, cache, x, start, page_table):
+        """A denoising forward alone: the block's logits, no K/V kept."""
+        with jax.named_scope("bd_denoise"):
+            return self._block_forward(params, cache, x, start, page_table)[0]
+
+    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+    def _decode_block_commit_jit(self, params, cache, x, start, page_table):
+        """A commit forward alone: the block's K/V written, no logits."""
+        with jax.named_scope("bd_commit"):
+            _, (k, v) = self._block_forward(
+                params, cache, x, start, page_table
+            )
+        return write_kv(
+            cache, k, v, page_table, start,
+            jnp.full((x.shape[0],), x.shape[1], jnp.int32),
+            use_pallas=self.use_pallas, kernel_mesh=self.kernel_mesh,
+        )
+
+    def _forced_logits(self, seq, n_prefill: int, n_decode: int):
+        """The benchmark's forced forward (perfbench/reference/README.md):
+        float32 ``[1 + n_decode, V]``, the logits at position
+        ``n_prefill - 1`` from this runner's own prefill program over
+        ``seq[:n_prefill]``, then for each following block the
+        DENOISING program over the given tokens (its logits at the
+        block's positions; ids that are ``mask_token_id`` are a block as
+        the timed path feeds it) and the COMMIT program (its K/V
+        written). Whole blocks only. Bound as ``forced_logits`` on a
+        block model's runner alone (``__init__``)."""
+        Bk = self.mcfg.block_length
+        seq = np.asarray(seq, np.int32)
+        if n_prefill % Bk or n_decode % Bk or n_prefill < Bk or (
+            len(seq) < n_prefill + n_decode
+        ):
+            raise ValueError(
+                f"forced_logits: n_prefill {n_prefill} and n_decode "
+                f"{n_decode} must be whole blocks of {Bk} (and at least one "
+                f"prefilled) within the {len(seq)} tokens given"
+            )
+        PS = self.ecfg.kv_page_size
+        table = np.zeros((self.ecfg.max_pages_per_seq,), np.int32)
+        n_pages = -(-(n_prefill + n_decode) // PS)
+        table[:n_pages] = np.arange(1, n_pages + 1)
+        out = [np.asarray(self.prefill(seq[:n_prefill], table), np.float32)]
+        table_dev = jnp.asarray(table[None], jnp.int32)
+        for at in range(n_prefill, n_prefill + n_decode, Bk):
+            x = jnp.asarray(seq[None, at : at + Bk])
+            start = jnp.asarray([at], jnp.int32)
+            logits = self._decode_block_denoise_jit(
+                self.params, self.cache, x, start, table_dev
+            )
+            out.extend(np.asarray(logits[0], np.float32))
+            # the block is committed as GIVEN: a mask among the ids is
+            # scored above and its place filled by the caller's next call
+            self.cache = self._decode_block_commit_jit(
+                self.params, self.cache, x, start, table_dev
+            )
+        return np.stack(out)
+
+    # ------------------------------------------------------------------
     # masked-candidate verification (FSM fast-forward)
     # ------------------------------------------------------------------
 
@@ -1932,7 +2269,13 @@ class ModelRunner:
     ):
         """The verify trunk: one forward over [B, C] known tokens
         against the paged past, K/V written for the inputs, plus the
-        plain greedy choice per position."""
+        plain greedy choice per position. A block model's COMMIT forward
+        (``_block_forward`` with every position filled) shares its shape,
+        several known tokens a row over the paged past whose K/V is kept,
+        and nothing else: under the block mask it takes the paged
+        kernel's block form where this chunk gathers, it keeps its K/V
+        in the window's buffers for one write a window where this writes
+        at once, and it reads no logits."""
         C = ids.shape[1]
         positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
         logits, _, (k, v) = transformer.forward(

@@ -217,6 +217,13 @@ class GenRequest:
     presence_penalty: float = 0.0
     frequency_penalty: float = 0.0
     repetition_penalty: float = 1.0
+    # Generation by blocks (a model with ``block_length`` > 1; None / 0:
+    # the model's own): the denoising forwards a block takes, the rule
+    # that picks the positions a forward fills (``configs.REMASKING``)
+    # and the dynamic rule's threshold
+    denoising_steps: int = 0
+    remasking: Optional[str] = None
+    confidence_threshold: Optional[float] = None
 
     def has_penalties(self) -> bool:
         return (
@@ -387,6 +394,10 @@ class _Slot:
     # O(vocab) packbits
     counts: Dict[int, int] = dataclasses.field(default_factory=dict)
     seen_bits: Optional[np.ndarray] = None  # uint8 [ceil(V/8)]
+    # a block model's row: the prompt tokens past its last whole block,
+    # not in the cache yet; they start the row's first generated block
+    # and are cleared once that block's window has been accepted
+    given: int = 0
 
 
 @dataclasses.dataclass
@@ -522,6 +533,8 @@ class ContinuousBatcher:
     _slot_state = False
     _tier_refused = False
     _latent_pool = False
+    # positions a block of the runner's model holds (1: a causal model)
+    _block = 1
     # the runner's window pool (K/V a pool a kind), set in __init__
     _window_pool = None
 
@@ -568,7 +581,17 @@ class ContinuousBatcher:
         # what the runner's pool hands out (runner.alloc_pages; a stub
         # runner has only num_pages)
         alloc_pages = getattr(runner, "alloc_pages", runner.num_pages)
-        self.native = maybe_native_runtime(
+        # A model that generates by BLOCKS (``ModelConfig.block_length``):
+        # a row's ``pos`` stays a multiple of the block, a window yields
+        # whole blocks a row and admission samples no first token. The
+        # C++ core counts one token a row a step, so the Python
+        # allocator serves such a model; the prefix store and the tiers
+        # would cut a row at a page's edge inside generated blocks whose
+        # K/V a later block's queries never saw committed that way, and
+        # fall back like a latent pool's (reasons of their own)
+        self._block = int(getattr(runner.mcfg, "block_length", 1) or 1)
+        self._bd_forwards = 0.0  # forwards the last fetched window ran
+        self.native = None if self._block > 1 else maybe_native_runtime(
             alloc_pages, self.B, self.MP, self.ecfg.kv_page_size,
             self.ecfg.max_batch_tokens, self.ecfg.max_context(),
         )
@@ -608,15 +631,16 @@ class ContinuousBatcher:
         self._latent_pool = (
             getattr(runner.mcfg, "num_latent_layers", 0) > 0
         )
+        by_blocks = self._block > 1
         self._tier_refused = (
-            (self._slot_state or two_kinds or self._latent_pool)
+            (self._slot_state or two_kinds or self._latent_pool or by_blocks)
             and kv_tier is not None
         )
-        if self._slot_state or two_kinds or self._latent_pool:
+        if self._slot_state or two_kinds or self._latent_pool or by_blocks:
             kv_tier = None
         if (
             self._slot_state or self._window_pool is not None
-            or self._latent_pool
+            or self._latent_pool or by_blocks
         ):
             prefix_store = None
         if self._slot_state:
@@ -945,10 +969,14 @@ class ContinuousBatcher:
                 shared * (len(pending) - 1), "prefix_without_window_pages"
             )
             return
-        if self._latent_pool:
+        if self._latent_pool or self._block > 1:
             self._count_state_fallback(
-                shared * (len(pending) - 1), "prefix_on_latent_pool"
+                shared * (len(pending) - 1),
+                "prefix_on_latent_pool" if self._latent_pool
+                else "prefix_on_block_model",
             )
+            if self._block > 1 and self._tel_on:
+                telemetry.BLOCK_REFUSALS_TOTAL.inc(1.0, "shared_prefix")
             return
         n_pages = shared // PS
         # warm head from the radix store (pins the matched path);
@@ -1320,6 +1348,16 @@ class ContinuousBatcher:
             need = pages_needed(total, self.ecfg.kv_page_size)
             if need > self.MP:
                 return None
+            if self._block > 1:
+                # a window writes whole blocks past a cap that falls
+                # inside one, and the windows in flight behind it write
+                # theirs: room for them where the table has it, so that
+                # a row's last windows are as long as its first
+                need = min(self.MP, pages_needed(
+                    total + self._window_tokens()
+                    * (max(self.ecfg.decode_lookahead, 1) + 1),
+                    self.ecfg.kv_page_size,
+                ))
             npfx = pfx.n_pages if pfx is not None else 0
             # native-clamp parity (rt_try_admit_pfx): a prefix covering
             # the whole need still allocates 1 own page (every row
@@ -1493,7 +1531,7 @@ class ContinuousBatcher:
             for b in batch
         ]
         tokens = int(
-            sum(len(r.prompt_ids) - s for r, s in zip(reqs, starts))
+            sum(self._prefill_len(r) - s for r, s in zip(reqs, starts))
         )
         self.timer.count("rows", len(batch))
         try:
@@ -1502,6 +1540,12 @@ class ContinuousBatcher:
                     "tokens": tokens,
                     "batch": len(batch),
                     "wave": self._wave_seq,
+                    # a block model's prompts end in tokens that no
+                    # prefill takes (they head the first block)
+                    **({"tail_tokens": int(sum(
+                        len(r.prompt_ids) - self._prefill_len(r)
+                        for r in reqs
+                    ))} if self._block > 1 else {}),
                     **self._state_attrs(len(batch)),
                     **self._kv_attrs(len(r.prompt_ids) for r in reqs),
                     **self._stream_attrs("prefill", tokens),
@@ -1528,8 +1572,10 @@ class ContinuousBatcher:
                         on_device=True,
                     )
             self.prefill_tokens += tokens
-            tok, logp = self._sample_first(
-                logits, reqs, [b[2] for b in batch]
+            # a block model samples nothing from a prefill: its first
+            # block starts as the prompt's leftover tokens and masks
+            tok, logp = (None, None) if self._block > 1 else (
+                self._sample_first(logits, reqs, [b[2] for b in batch])
             )
         except Exception:
             for _, _, slot_idx, pages, _ in batch:
@@ -1544,7 +1590,7 @@ class ContinuousBatcher:
             slot = _Slot(
                 req=req,
                 pages=(list(pfx.pages) + list(pages)) if pfx else pages,
-                pos=len(req.prompt_ids),
+                pos=self._prefill_len(req),
                 last_token=0,
                 job=ctx,
                 shared_n=pfx.n_pages if pfx else 0,
@@ -1602,7 +1648,10 @@ class ContinuousBatcher:
                 telemetry.ADMIT_WAVE_ROWS_TOTAL.inc(float(n))
             for e, (toks, logps, _) in zip(wave, got):
                 for k, (i, s) in enumerate(e.rows):
-                    self._arm(i, s, int(toks[k]), float(logps[k]))
+                    if toks is None:  # a block model: no first token
+                        self._arm_block(i, s)
+                    else:
+                        self._arm(i, s, int(toks[k]), float(logps[k]))
                     armed += 1
         except BaseException:
             for i, s in rows[armed:]:
@@ -1633,6 +1682,35 @@ class ContinuousBatcher:
             s.job.stats["out"] += 1  # the prefill-sampled first token
         self._record_token(s, first, logp)
         self._deliver_token(s, first, logp)
+
+    def _prefill_len(self, req: GenRequest) -> int:
+        """The prompt tokens a prefill takes: all of them, or a block
+        model's whole blocks (``ModelRunner.whole_blocks``)."""
+        n = len(req.prompt_ids)
+        return n if self._block == 1 else self.runner.whole_blocks(n)
+
+    def _window_tokens(self) -> int:
+        """Positions a row that a fused window yields: THE place that
+        says it. ``decode_multi_step`` steps of one token, or for a
+        block model that many rounded down to whole blocks (at least
+        one)."""
+        KS = self.ecfg.decode_multi_step
+        if self._block == 1:
+            return KS
+        return max(KS // self._block, 1) * self._block
+
+    def _arm_block(self, i: int, s: _Slot) -> None:
+        """Slot ``i``'s whole prompt blocks are in its pages: the row of
+        a model that generates by blocks joins the decode batch with the
+        prompt's leftover tokens as the head of its first block and no
+        token sampled."""
+        req = s.req
+        s.prefilling = False
+        s.ptable = None
+        s.pos = self._prefill_len(req)
+        s.given = len(req.prompt_ids) - s.pos
+        if s.job is not None:
+            s.job.stats["in"] += len(req.prompt_ids)
 
     def _drop_slot(self, i: int) -> None:
         """Give slot ``i`` up with no result: its pages and state go
@@ -1706,7 +1784,8 @@ class ContinuousBatcher:
         s = self.slots[i]
         req = s.req
         C = self.ecfg.prefill_chunk
-        seg = req.prompt_ids[s.prefill_pos : s.prefill_pos + C]
+        P = self._prefill_len(req)
+        seg = req.prompt_ids[s.prefill_pos : min(s.prefill_pos + C, P)]
         if self._tel_on:
             self._tel_attrs["prefill"] = {
                 "tokens": int(len(seg)), "wave": self._wave_seq,
@@ -1722,12 +1801,15 @@ class ContinuousBatcher:
             )
         self.prefill_tokens += len(seg)
         s.prefill_pos += len(seg)
-        if s.prefill_pos < len(req.prompt_ids):
+        if s.prefill_pos < P:
             if route is not None:
                 self._to_wave((), None, None, route, len(seg))
             return
-        # last chunk: sample the first token; the wave arms the slot
-        tok, logp = self._sample_first(logits, [req], [i])
+        # last chunk: sample the first token (a block model samples
+        # none); the wave arms the slot
+        tok, logp = (None, None) if self._block > 1 else (
+            self._sample_first(logits, [req], [i])
+        )
         self._to_wave([(i, s)], tok, logp, route, len(seg))
 
     def _fastforward_step(self, b: _DecodeBatch) -> bool:
@@ -2623,6 +2705,19 @@ class ContinuousBatcher:
         path's method returns the label its iteration is counted
         under."""
         KS = self.ecfg.decode_multi_step
+        if self._block > 1:
+            # a model that generates by blocks has ONE decode program,
+            # the window of blocks, and it pipelines: what its rows may
+            # not ask for (a constraint, penalties, a seed a row) is
+            # refused at submit (engine/api.py), and an active row always
+            # has a block's room (its ``pos``, its pages and the
+            # context are multiples of the block)
+            if f.room < self._block and not in_flight:
+                raise RuntimeError(
+                    f"a row of a block model has room for {f.room} "
+                    f"positions, under one block of {self._block}"
+                )
+            return "pipelined" if f.room >= self._block else "drain"
         # Fuse K decode steps into one device program when no row needs
         # host work between steps: one dispatch + one fetch per window
         # instead of per token.
@@ -2772,12 +2867,45 @@ class ContinuousBatcher:
                 **self._stream_attrs("decode", n * steps, steps),
                 **self._route_attrs.get("decode_window", {}),
             }
+            if self._block > 1:
+                self._tel_attrs["decode_window"].update(
+                    self._block_attrs(b, steps)
+                )
             f = b.facts
             if f.has_constraint and f.constrained_greedy:
                 # WHY window or step: what _choose_path read
                 self._tel_attrs["decode_window"]["unmasked_ok"] = round(
                     f.unmasked_ok, 4
                 )
+
+    def _block_steps(self, r: GenRequest) -> int:
+        """Denoising forwards a block of request ``r`` takes."""
+        m = self.runner.mcfg
+        return min(
+            max(int(r.denoising_steps or m.denoising_steps or self._block), 1),
+            self._block,
+        )
+
+    def _block_attrs(self, b: _DecodeBatch, tokens: int) -> Dict[str, Any]:
+        """A block model's ``decode_window`` span: ``steps`` is the
+        FORWARDS a window runs (so that the readers of a step's device
+        time read milliseconds a forward): what the last fetched window
+        ran, and before any was fetched the most its rows may ask for;
+        beside it the window's blocks, their length, the forwards by
+        kind and the positions a row it yields."""
+        blocks = tokens // self._block
+        commit = blocks
+        denoise = self._bd_forwards - commit if self._bd_forwards else (
+            blocks * max(
+                (self._block_steps(self.slots[i].req) for i in b.active),
+                default=1,
+            )
+        )
+        return {
+            "steps": int(denoise + commit), "blocks": blocks,
+            "block_length": self._block, "denoise_forwards": int(denoise),
+            "commit_forwards": commit, "tokens": tokens,
+        }
 
     # ------------------------------------------------------------------
     # pipelined fused windows (unconstrained decode fast path)
@@ -2791,23 +2919,39 @@ class ContinuousBatcher:
         fetch the oldest. At a depth of one the window dispatched here
         is the one fetched: dispatch, fetch and accept in one
         iteration. Without ``refill`` the pipe only drains."""
-        KS = self.ecfg.decode_multi_step
+        KS = self._window_tokens()
         self._note_window(b, KS)
         if refill:
             while len(pipe) < max(self.ecfg.decode_lookahead, 1):
                 proj = self._pipe_projection(pipe)
-                if not self._pipe_capacity_ok(b.active, proj, KS):
+                K = KS if self._block == 1 else min(
+                    KS, self._block_room(b.active, proj)
+                )
+                if K <= 0 or not self._pipe_capacity_ok(b.active, proj, K):
                     break
-                self._dispatch_pipelined(pipe, b, proj, KS)
-        self._process_pipelined(pipe.pop(0))
+                self._dispatch_pipelined(pipe, b, proj, K)
+        if pipe:
+            self._process_pipelined(pipe.pop(0))
         return "pipelined"
+
+    def _block_room(self, active, proj: np.ndarray) -> int:
+        """Positions, in whole blocks, that every active row of a block
+        model has pages for past what is in flight: a row near its
+        table's end takes a shorter window (one more compile a length,
+        and there are ``decode_multi_step / block`` lengths)."""
+        PS = self.ecfg.kv_page_size
+        room = min(
+            (len(self.slots[i].pages) * PS - self.slots[i].pos - int(proj[i])
+             for i in active), default=0,
+        )
+        return room // self._block * self._block
 
     def _pipe_projection(self, pipe) -> np.ndarray:
         """[B] extra decode steps already dispatched (in-flight windows)
         but not yet processed, per slot — only windows whose (slot, gen)
         snapshot still matches count."""
         proj = np.zeros((self.B,), np.int32)
-        for _, _, w_active, w_gens, wK, _, _ in pipe:
+        for _, _, w_active, w_gens, wK, *_ in pipe:
             for idx, i in enumerate(w_active):
                 if self._gen[i] == w_gens[idx]:
                     proj[i] += wK
@@ -2846,8 +2990,10 @@ class ContinuousBatcher:
         their host-known token via a device-side merge — no host sync
         anywhere on this path."""
         active = b.active
+        if self._block > 1:
+            return self._dispatch_block_window(pipe, b, proj, K)
         if pipe:
-            prev_toks, _, p_active, p_gens, _, _, _ = pipe[-1]
+            prev_toks, _, p_active, p_gens, *_ = pipe[-1]
             chained = {
                 i
                 for idx, i in enumerate(p_active)
@@ -2890,6 +3036,57 @@ class ContinuousBatcher:
             )
         )
 
+    def _dispatch_block_window(
+        self, pipe, b: _DecodeBatch, proj: np.ndarray, K: int
+    ) -> None:
+        """``_dispatch_pipelined`` for a model that generates by blocks:
+        a window of ``K / block`` whole blocks a row, from each row's
+        ``pos`` past what is in flight. Nothing chains from the window
+        before it: a block starts as masks, but for a row's FIRST block,
+        which starts with the prompt's leftover tokens (``_Slot.given``,
+        while no window of the row is in flight)."""
+        from ..models.configs import REMASKING
+
+        m = self.runner.mcfg
+        Bk = self._block
+        first = np.zeros((self.B, Bk), np.int32)
+        live = np.zeros((self.B,), bool)
+        steps = np.ones((self.B,), np.int32)
+        rule = np.zeros((self.B,), np.int32)
+        tau = np.zeros((self.B,), np.float32)
+        given = []
+        for i in b.active:
+            s, r = self.slots[i], self.slots[i].req
+            live[i] = True
+            first[i] = m.mask_token_id
+            g = s.given if proj[i] == 0 else 0
+            if g:
+                first[i, :g] = r.prompt_ids[s.pos : s.pos + g]
+            given.append(g)
+            steps[i] = self._block_steps(r)
+            rule[i] = REMASKING.index(r.remasking or m.remasking)
+            tau[i] = (
+                m.confidence_threshold if r.confidence_threshold is None
+                else r.confidence_threshold
+            )
+        self._key, sub = jax.random.split(self._key)
+        with self.timer.time("decode"):
+            toks_dev, logps_dev, turns_dev = self.runner.decode_block_async(
+                first, live, b.past_len + proj, b.table, sub, b.temp,
+                b.top_p, K // Bk, top_k=b.top_k, steps=steps, rule=rule,
+                tau=tau,
+            )
+        self._step += K
+        pipe.append((
+            toks_dev, logps_dev, list(b.active),
+            [self._gen[i] for i in b.active], K,
+            getattr(self.runner, "window_route", None),
+            [self.slots[i].job for i in b.active],
+            # the denoising forwards each block ran, on the device, and
+            # the leading positions of each row that were given
+            (turns_dev, given),
+        ))
+
     def _process_pipelined(self, entry) -> None:
         """Fetch one in-flight window's results (the only host sync in
         the pipelined path) and accept its tokens. Tokens for slots
@@ -2902,17 +3099,28 @@ class ContinuousBatcher:
         (round-5 host-overhead profile: the per-token Python loop cost
         ~26 ms per B=128 window, 2× the device window itself); rows with
         any per-token machinery keep the exact per-token loop."""
-        toks_dev, logps_dev, w_active, w_gens, wK, route_dev, w_jobs = entry
+        (toks_dev, logps_dev, w_active, w_gens, wK, route_dev, w_jobs,
+         *block) = entry
+        # a block model's rows: the leading positions of each that were
+        # GIVEN (the prompt's leftover tokens), which commit no token
+        given: Dict[int, int] = {}
         with self.timer.time("decode"):
             toks = np.asarray(toks_dev)
             logps = np.asarray(logps_dev)
-            if route_dev is not None:
+            if block:
+                turns_dev, w_given = block[0]
+                given = dict(zip(w_active, w_given))
+                self._note_block_window(
+                    np.asarray(turns_dev), len(w_active), route_dev
+                )
+            elif route_dev is not None:
                 self._note_route("decode_window", route_dev)
         self.timer.enter("accept")
         n0 = self._n_accepted
         plain: List[int] = []
         rest: List[int] = []
-        # every slot of w_active was dispatched wK
+        # every slot of w_active was dispatched wK POSITIONS (a row-step
+        # is a position of a row, whatever forwards filled it)
         lost: Dict[str, int] = {}
         for idx, i in enumerate(w_active):
             ctx = w_jobs[idx]
@@ -2923,6 +3131,12 @@ class ContinuousBatcher:
                 continue
             s = self.slots[i]
             r = s.req
+            if given.get(i):
+                # in the cache with the window's write, no token of the
+                # row's output
+                _lose(lost, ctx, "given", given[i])
+                s.pos += given[i]
+                s.given = 0
             if (
                 r.constraint is None
                 and not r.stop_seqs
@@ -2932,12 +3146,12 @@ class ContinuousBatcher:
             else:
                 rest.append(i)
         if plain:
-            self._accept_plain_window(plain, toks, logps, wK, lost)
+            self._accept_plain_window(plain, toks, logps, wK, lost, given)
         for j in range(wK):
             for i in rest:
                 s = self.slots[i]
-                if s is None:
-                    continue  # finished earlier in this window
+                if s is None or j < given.get(i, 0):
+                    continue  # finished earlier in this window; a given
                 ctx = s.job
                 rc = self._accept_token(
                     i, int(toks[j][i]), float(logps[j][i])
@@ -2946,6 +3160,13 @@ class ContinuousBatcher:
                     _lose(lost, ctx, "failed", wK - j)
                 elif rc:
                     _lose(lost, ctx, "finished", wK - 1 - j)
+        if block and self._tel_on:
+            n_given = lost.get("given", 0)
+            for fate, n in (
+                ("accepted", self._n_accepted - n0), ("given", n_given),
+                ("lost", sum(lost.values()) - n_given),
+            ):
+                telemetry.BLOCK_TOKENS_TOTAL.inc(float(n), fate)
         self._close_accept(n0, wK * len(w_active), lost)
 
     def _trace_resume(self, ctx: JobCtx, req: GenRequest) -> None:
@@ -2959,9 +3180,27 @@ class ContinuousBatcher:
                     ctx.trace_id, "resume", {"row_id": rid}
                 )
 
+    def _note_block_window(self, turns, rows: int, route_dev) -> None:
+        """What a fetched window of blocks ran: its forwards by kind
+        (``turns`` [blocks]: the denoising forwards of each block; one
+        commit a block) into the counters and the next spans' ``steps``,
+        its routing counts (the rows of forwards that ran) into the
+        span's attrs."""
+        denoise, commit = float(np.sum(turns)), float(len(turns))
+        self._bd_forwards = denoise + commit
+        if self._tel_on:
+            for kind, n in (("denoise", denoise), ("commit", commit)):
+                telemetry.BLOCK_FORWARDS_TOTAL.inc(n, kind)
+                telemetry.BLOCK_ROW_FORWARDS_TOTAL.inc(n * rows, kind)
+        if route_dev is not None:
+            route = np.asarray(route_dev).reshape(-1, 6)
+            ran = route[route[:, 4] > 0]
+            if len(ran):
+                self._note_route("decode_window", ran)
+
     def _accept_plain_window(
         self, idxs: List[int], toks: np.ndarray, logps: np.ndarray,
-        wK: int, lost: Dict[str, int],
+        wK: int, lost: Dict[str, int], given: Optional[Dict[int, int]] = None,
     ) -> None:
         """Accept a whole window for plain rows with one numpy pass per
         row instead of wK interpreter iterations. Semantics mirror
@@ -2979,9 +3218,14 @@ class ContinuousBatcher:
             if self._stop_arr.size
             else np.zeros_like(tw, bool)
         )
-        INF = wK + 1
+        window = wK
         for col, i in enumerate(idxs):
             s = self.slots[i]
+            # a block model's row: the window's positions behind those
+            # given it (already counted, ``_process_pipelined``)
+            g = given.get(i, 0) if given else 0
+            wK = window - g
+            INF = wK + 1
             if faults.ACTIVE is not None:
                 # the vectorized path skips _accept_token, so the
                 # per-row decode fault site fires here instead
@@ -2996,7 +3240,7 @@ class ContinuousBatcher:
                     continue
             # first k (tokens accepted) at which the row finishes —
             # mirrors _finish_reason's per-token checks
-            stops = np.flatnonzero(is_stop[:, col])
+            stops = np.flatnonzero(is_stop[g:, col])
             n_stop = int(stops[0]) + 1 if stops.size else INF
             n_len = s.req.max_new_tokens - len(s.out_ids)
             n_ctx = self._max_ctx - 1 - s.pos
@@ -3011,9 +3255,9 @@ class ContinuousBatcher:
                 self._emit(i)
                 continue
             n_take = min(limit, wK)
-            col_t = tw[:n_take, col]
+            col_t = tw[g : g + n_take, col]
             s.out_ids.extend(col_t.tolist())  # C-speed, yields ints
-            s.logprob_sum += float(lw[:n_take, col].sum())
+            s.logprob_sum += float(lw[g : g + n_take, col].sum())
             s.pos += n_take
             self._n_accepted += n_take
             s.last_token = int(col_t[-1])
@@ -3022,7 +3266,7 @@ class ContinuousBatcher:
             if s.job is not None:
                 s.job.stats["out"] += n_take
                 if s.job.on_token is not None:
-                    lcol = lw[:n_take, col]
+                    lcol = lw[g : g + n_take, col]
                     for k in range(n_take):
                         self._deliver_token(
                             s, int(col_t[k]), float(lcol[k])
@@ -3516,6 +3760,7 @@ class ContinuousBatcher:
                     self.slots[i].pos,
                     "hibernate_without_slot_state" if self._slot_state
                     else "hibernate_on_latent_pool" if self._latent_pool
+                    else "hibernate_on_block_model" if self._block > 1
                     else "hibernate_without_window_pages",
                 )
             return False

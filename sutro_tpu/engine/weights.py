@@ -245,6 +245,14 @@ def _load_mixed(mcfg: ModelConfig, get, dtype) -> Dict[str, Any]:
             "of a sublayer's phi, bias and alphas) is not written; the "
             "model runs on seeded random weights"
         )
+    if mcfg.block_length > 1:
+        raise NotImplementedError(
+            f"{mcfg.name}: loading a checkpoint of a model that generates "
+            "by blocks (block_length; model_type 'sdar_moe') is not "
+            "written: there is no file to hold the reading against here, "
+            "and block_length and mask_token_id are no keys of the "
+            "published config; the model runs on seeded random weights"
+        )
     stacks: Dict[str, Dict[str, list]] = {}
 
     def put(kind: str, name: str, arr: np.ndarray) -> None:

@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers thirteen
+config-driven decoder-only transformer (models/transformer.py) covers fourteen
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -88,6 +88,16 @@ families:
   positions) on the latent layers' 64-wide rotary part, with the score's
   scale times ``(0.1 ln 64 + 1)^2``. ``-l7`` is the first of six
   pipeline stages: layers 0-6, every expert, the whole vocabulary
+- SDAR (30b-a3b-chat; ``model_type`` sdar_moe): Qwen3-MoE's layer under
+  a mask that is causal BY BLOCKS of ``block_length`` positions (a
+  position sees every earlier block and the whole of its own), with
+  logits that are not shifted (position i's are the distribution of the
+  token AT i), generated by diffusion over a block: ``block_length``
+  ``mask_token_id``s filled in over ``denoising_steps`` forwards by
+  confidence (``remasking``), then one more forward that writes the
+  block's K/V (engine/runner.py ``_decode_block_jit``). ``-l6`` is the
+  first of eight pipeline stages: layers 0-5, every expert, the whole
+  vocabulary
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -322,6 +332,28 @@ class ModelConfig:
     # rule by ``moe_top_k`` for every model: that would redraw the
     # weights of cells whose limits were set from readings
     seeded_expert_gain: float = 1.0
+    # Generation by diffusion over blocks (SDAR, arXiv:2510.06303; 1: a
+    # causal model, one token a row a forward). The attention mask is
+    # causal by blocks of ``block_length`` positions: position i sees j
+    # iff ``j // block_length <= i // block_length``, in the prefill and
+    # in every later forward, and the logits at i are the distribution
+    # of the token AT i. A block is generated as ``block_length``
+    # ``mask_token_id``s that ``denoising_steps`` forwards (0: as many as
+    # the block is long; a request may state fewer) fill in, the
+    # positions of a forward chosen by ``remasking``
+    # ("low_confidence_dynamic": every position whose drawn token has a
+    # probability over ``confidence_threshold``, at least the step's even
+    # share; "low_confidence_static": that share, the most confident
+    # first; "sequential": leftmost first), and one more forward of the
+    # filled block writes its K/V (ops/sampling.py ``transfer``,
+    # engine/runner.py ``_decode_block_jit``). Such a model lists its
+    # layers' kinds (``layer_types``): the walk by kind reads a routed
+    # layer's experts where they lie and counts its routing
+    block_length: int = 1
+    mask_token_id: int = -1
+    denoising_steps: int = 0
+    remasking: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
 
     @property
     def q_size(self) -> int:
@@ -366,10 +398,13 @@ class ModelConfig:
 
     @property
     def homogeneous(self) -> bool:
-        """Every layer the same block: the parameters are one stack
-        ``params["layers"][name] [L, ...]`` and the walk is one scan."""
-        return len(set(zip(self.mixers, self.ffns))) == 1 and (
-            self.mixers[0] == "attention"
+        """Every layer the same block and no list of kinds: the
+        parameters are one stack ``params["layers"][name] [L, ...]`` and
+        the walk is one scan. A model that LISTS its layers' kinds
+        (``layer_types``) is walked by kind whatever they are."""
+        return not self.layer_types and (
+            len(set(zip(self.mixers, self.ffns))) == 1
+            and self.mixers[0] == "attention"
         )
 
     @property
@@ -591,6 +626,30 @@ def _qwen3_moe(name: str, h: int, l: int, nh: int, nkv: int,
         moe_experts=experts, moe_top_k=top_k,
         moe_intermediate_size=moe_inter, rope_theta=1_000_000.0,
         chat_template="chatml",
+    )
+
+
+#: generation by blocks: the transfer rules of ``ModelConfig.remasking``,
+#: in the order the device program numbers them (ops/sampling.py)
+REMASKING = ("low_confidence_static", "low_confidence_dynamic", "sequential")
+
+
+def _sdar(name: str, base: ModelConfig, *, mask_id: int, block: int = 4,
+          inter: Optional[int] = None) -> ModelConfig:
+    """The published ``sdar_moe`` keys are Qwen3-MoE's: ``base`` (a
+    ``_qwen3_moe`` config) with its layers listed by kind, the block
+    mask and the generation by diffusion over a block. ``block`` and
+    ``mask_id`` are no keys of the published file (perfbench/reference/
+    sdar_moe.md: the family's released commands, the tokenizer's
+    ``<|MASK|>``). ``inter``: the published ``intermediate_size``, which
+    no layer uses (every FFN is routed). Seeded weights take a
+    unit-variance embedding, as every softmax-routed model of the
+    benchmark does."""
+    return dataclasses.replace(
+        base, name=name, layer_types=("attention",) * base.num_layers,
+        block_length=block, mask_token_id=mask_id,
+        intermediate_size=inter or base.intermediate_size,
+        seeded_unit_embedding=True,
     )
 
 
@@ -904,6 +963,20 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     # Qwen3 MoE
     "qwen3-30b-a3b": _qwen3_moe("qwen3-30b-a3b", 2048, 48, 32, 4, 128, 8, 768),
     "qwen3-235b-a22b": _qwen3_moe("qwen3-235b-a22b", 4096, 94, 64, 4, 128, 8, 1536),
+    # SDAR-30B-A3B-Chat: as published (30,532,122,624 parameters), and
+    # the first of eight pipeline stages: layers 0-5, every expert, the
+    # whole vocabulary, with the final norm and the head so that a token
+    # can be sampled (4,361,055,744 parameters, 8.72 GB in bf16)
+    "sdar-30b-a3b-chat": _sdar(
+        "sdar-30b-a3b-chat",
+        _qwen3_moe("sdar", 2048, 48, 32, 4, 128, 8, 768), mask_id=151_669,
+        inter=6144,
+    ),
+    "sdar-30b-a3b-chat-l6": _sdar(
+        "sdar-30b-a3b-chat-l6",
+        _qwen3_moe("sdar", 2048, 6, 32, 4, 128, 8, 768), mask_id=151_669,
+        inter=6144,
+    ),
     # Llama
     "llama-3.2-3b": _llama("llama-3.2-3b", 3072, 28, 24, 8, 8192, tie=True),
     "llama-3.1-8b": _llama("llama-3.1-8b", 4096, 32, 32, 8, 14336),
@@ -1075,6 +1148,19 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         k_head_dim=16, k_rank=8, k_chunk=8, inter=256, experts=16,
         top_k=4, moe_inter=48, held=4, first=0, vocab=512,
         template="plain",
+    ),
+    # Qwen3-MoE's layer at tiny widths under the block mask: blocks of 4,
+    # 8 experts top-2 all held, three layers; id 300 is the mask (the
+    # byte tokenizer sends 0-255 and its specials, never 300)
+    "tiny-sdar": _sdar(
+        "tiny-sdar",
+        ModelConfig(
+            name="tiny-sdar", vocab_size=512, hidden_size=128, num_layers=3,
+            num_heads=4, num_kv_heads=2, head_dim=32, intermediate_size=64,
+            moe_experts=8, moe_top_k=2, moe_intermediate_size=64,
+            qk_norm=True, tie_embeddings=False, chat_template="plain",
+        ),
+        mask_id=300,
     ),
     "tiny-emb": ModelConfig(
         name="tiny-emb", vocab_size=512, hidden_size=128, num_layers=2,

@@ -733,6 +733,7 @@ def attention_mixer(
         pfx_groups=pfx_groups,
         kernel_mesh=kernel_mesh,
         live_window=live_window,
+        block_length=cfg.block_length,
     )
     attn = attn.reshape(B, T, cfg.q_size)
     if "w_attn_gate" in lp:

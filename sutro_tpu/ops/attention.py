@@ -322,9 +322,22 @@ def chunk_attention(
     kernel_mesh=None,
     # static: the paged past is a WINDOW pool's (module docstring)
     live_window: int = 0,
+    # static: the mask is causal BY BLOCKS of this many positions
+    # (``ModelConfig.block_length``): a query sees every key of an
+    # earlier block and the WHOLE of its own. 1 is the causal mask, and
+    # the branch is Python's: every program of a causal model is the
+    # one it was
+    block_length: int = 1,
 ) -> jax.Array:
     """Returns [B, T, NH, Dh]."""
     B, T = q.shape[:2]
+    if block_length > 1 and (
+        ring_mesh is not None or live_window or pfx_groups
+    ):
+        raise NotImplementedError(
+            "a mask by blocks (block_length) under ring attention, a "
+            "window pool or a shared prefix's carry"
+        )
     if (
         ring_mesh is not None
         and past_k is None
@@ -384,10 +397,30 @@ def chunk_attention(
             kernel_mesh, decode, ops, _PAGED_SPECS, P(None, "model", None),
         )
         return out[:, None]
+    if (
+        past_k_pages is not None and block_length > 1 and T == block_length
+        and use_pallas and kernel_mesh is None
+        and sink is None and past_k_scale is None
+    ):
+        # ONE BLOCK of a model that generates by blocks, over its paged
+        # past: every query sees the row's pages, the window's earlier
+        # blocks and all T current keys, which is the paged kernel's own
+        # shape with the block's queries beside a KV head's group
+        # (ops/pallas_paged.paged_block_attention). No gather
+        from .pallas_paged import paged_block_attention, paged_decode_supported
+
+        if paged_decode_supported(q[:, 0], past_k_pages):
+            return paged_block_attention(
+                q, past_k_pages, past_v_pages, layer, page_table, past_len,
+                k, v, win_k=win_k, win_v=win_v, win_len=win_len,
+            )
     if past_k_pages is not None:
         if use_pallas:
-            # a chunk over a paged past (chunked prefill, verify
-            # forwards) gathers by design
+            # a chunk of several tokens over a paged past GATHERS the
+            # row's whole table, by design: a verify forward and a chunk
+            # of a chunked prefill (and a block, above, whose heads the
+            # kernel does not take). One block of a model that generates
+            # by blocks no longer does
             lowering.record_reference("paged_decode")
         from ..engine.kvcache import gather_kv_layer
 
@@ -412,7 +445,9 @@ def chunk_attention(
             if sink is not None:
                 ops["sink"] = sink
             return lowering.shard_over_model(
-                kernel_mesh, flash_prefill, ops, _FLASH_SPECS,
+                kernel_mesh,
+                functools.partial(flash_prefill, block_length=block_length),
+                ops, _FLASH_SPECS,
                 P(None, None, "model", None),
             )
         if T > 1:
@@ -463,10 +498,15 @@ def chunk_attention(
     kf = keys.astype(jnp.float32)
     scores = jnp.einsum("btkgd,bskd->bkgts", qg, kf) * scale  # [B,KVH,G,T,S]
 
-    # Mask: causal (key_pos <= q_pos), key validity, sliding window.
+    # Mask: causal (key_pos <= q_pos; by blocks, the key's block no
+    # later than the query's), key validity, sliding window.
     qp = positions[:, :, None]                     # [B, T, 1]
     kp = key_pos[:, None, :]                       # [B, 1, S]
-    allowed = (kp <= qp) & key_valid[:, None, :]
+    if block_length > 1:
+        allowed = (kp // block_length <= qp // block_length)
+    else:
+        allowed = kp <= qp
+    allowed = allowed & key_valid[:, None, :]
     if window is not None:
         win = jnp.asarray(window, jnp.int32)
         in_window = (qp - kp) < jnp.where(win > 0, win, jnp.iinfo(jnp.int32).max)

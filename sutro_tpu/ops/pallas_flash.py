@@ -87,6 +87,7 @@ def _flash_kernel(
     groups: int,
     scale: float,
     native: bool = False,
+    block_length: int = 1,
 ):
     *keep_ref, out_ref, m_ref, l_ref, acc_ref = refs
     qb = pl.program_id(2)
@@ -113,7 +114,13 @@ def _flash_kernel(
     def _accumulate():
         qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 0)
         kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
-        ok = kpos <= qpos
+        if block_length > 1:
+            # causal by blocks: a query sees its own block whole. The
+            # block divides the tile, so only the diagonal tile's mask
+            # differs from the causal one (``flash_prefill``)
+            ok = kpos <= jnp.bitwise_or(qpos, block_length - 1)
+        else:
+            ok = kpos <= qpos
         # windowless (win <= 0) ORed in — Mosaic cannot legalize
         # arith.select on i1 vectors (same workaround as pallas_paged)
         ok = jnp.logical_and(
@@ -185,7 +192,8 @@ def flash_prefill_supported(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "scale", "native", "block")
+    jax.jit,
+    static_argnames=("interpret", "scale", "native", "block", "block_length"),
 )
 def flash_prefill(
     q: jax.Array,                    # [B, T, NH, Dh]
@@ -210,10 +218,22 @@ def flash_prefill(
     # (ops/sparse_attention.masked_attention). A query that keeps
     # nothing comes out zero
     keep: Optional[jax.Array] = None,
+    # the mask is causal by blocks of this many positions
+    # (``ModelConfig.block_length``), a power of two that divides the
+    # kernel's tile: the tiles the causal walk skips are the tiles this
+    # one skips, and the diagonal tile's mask is ``k <= q | (Bk - 1)``.
+    # The chunk's padding has to start at a block's edge (a row's valid
+    # length a multiple of the block), or a valid query would see it
+    block_length: int = 1,
 ) -> jax.Array:
     """Returns [B, T, NH, Dv] causal self-attention over the chunk."""
     lowering.record_kernel("flash_prefill", interpret=interpret)
     B, T, NH, Dh = q.shape
+    if block_length & (block_length - 1) or (block or BLOCK_Q) % block_length:
+        raise ValueError(
+            f"block_length {block_length}: a power of two that divides the "
+            f"kernel's tile of {block or BLOCK_Q}"
+        )
     KVH = k.shape[2]
     Dv = v.shape[-1]
     G = NH // KVH
@@ -241,7 +261,8 @@ def flash_prefill(
     )
 
     kernel = functools.partial(
-        _flash_kernel, groups=G, scale=scale, native=native
+        _flash_kernel, groups=G, scale=scale, native=native,
+        block_length=block_length,
     )
     operands = [win, qh, kh, vh, sink_g]
 

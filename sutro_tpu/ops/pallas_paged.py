@@ -1152,3 +1152,66 @@ def paged_decode_attention(
         ),
         interpret=interpret,
     )(*scalars, *operands)
+
+
+def paged_block_attention(
+    q: jax.Array,          # [B, Bk, NH, Dh]: the block's queries
+    k_pages: jax.Array,    # [L, NP, PS, KVH*Dh]
+    v_pages: jax.Array,
+    layer: jax.Array,
+    page_table: jax.Array, # [B, MP]
+    past_len: jax.Array,   # [B]: tokens in the pages, a multiple of Bk
+    k_blk: jax.Array,      # [B, Bk, KVH, Dh]: the block's own K (post-RoPE)
+    v_blk: jax.Array,
+    win_k: Optional[jax.Array] = None,   # [B, W, KVH*Dh]: earlier blocks
+    win_v: Optional[jax.Array] = None,   # of the window, not yet in pages
+    win_len: Optional[jax.Array] = None,  # scalar: their valid slots
+    *,
+    interpret: bool = False,
+    rows: Optional[int] = None,
+) -> jax.Array:
+    """The BLOCK form of ``paged_decode_attention``, ``[B, Bk, NH, Dh]``:
+    one block of a model that generates by blocks (``ModelConfig.
+    block_length``), whose ``Bk`` queries a row ALL see the row's pages,
+    the window's earlier blocks and all ``Bk`` current keys. That is the
+    kernel's own shape and no second kernel: it already holds keys that
+    every query sees and that are not in the pool (``win_k`` / ``win_v``
+    / ``win_len``) beside ``k_cur``, and it already lays a KV head's
+    group of query heads side by side on the sublanes. So the block's
+    queries fold beside the group (``Bk x G`` query rows a KV head a
+    page, against ``G``: 32 against 8 at 32 heads over 4, a fuller MXU
+    tile for the same fetched page), the block's first ``Bk - 1`` keys
+    go into the window's buffer behind the earlier blocks and its last
+    is ``k_cur``. A row's pages are fetched once for the ``Bk``
+    positions. No window and no sink: the caller gates
+    (ops/attention.chunk_attention)."""
+    B, Bk, NH, Dh = q.shape
+    KVH = k_blk.shape[2]
+    G = NH // KVH
+    KD = KVH * Dh
+    # [B, Bk, KVH, G, Dh] -> [B, KVH, Bk, G, Dh]: row n of the folded
+    # heads belongs to KV head n // (Bk * G), as the kernel's
+    # block-diagonal queries want it
+    folded = q.reshape(B, Bk, KVH, G, Dh).transpose(0, 2, 1, 3, 4).reshape(
+        B, KVH * Bk * G, Dh
+    )
+    if win_k is None:
+        win_k = jnp.zeros((B, Bk, KD), k_blk.dtype)
+        win_v = jnp.zeros((B, Bk, KD), v_blk.dtype)
+        win_len = jnp.int32(0)
+    win_len = jnp.asarray(win_len, jnp.int32)
+    # the block's first Bk - 1 keys behind the window's valid slots
+    # (W >= win_len + Bk: the window's buffer has this block's place)
+    head_k = k_blk[:, : Bk - 1].reshape(B, Bk - 1, KD).astype(win_k.dtype)
+    head_v = v_blk[:, : Bk - 1].reshape(B, Bk - 1, KD).astype(win_v.dtype)
+    win_k = jax.lax.dynamic_update_slice(win_k, head_k, (0, win_len, 0))
+    win_v = jax.lax.dynamic_update_slice(win_v, head_v, (0, win_len, 0))
+    out = paged_decode_attention(
+        folded, k_pages, v_pages, layer, page_table, past_len,
+        k_blk[:, Bk - 1], v_blk[:, Bk - 1], jnp.int32(0),
+        win_k=win_k, win_v=win_v, win_len=win_len + (Bk - 1),
+        interpret=interpret, rows=rows,
+    )
+    return out.reshape(B, KVH, Bk, G, Dh).transpose(0, 2, 1, 3, 4).reshape(
+        B, Bk, NH, Dh
+    )

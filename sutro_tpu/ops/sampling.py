@@ -265,3 +265,143 @@ def cumulative_logprob(
     return chosen - jax.scipy.special.logsumexp(
         logits.astype(jnp.float32), axis=-1
     )
+
+
+# ---------------------------------------------------------------------------
+# Generation by blocks (``ModelConfig.block_length`` > 1)
+# ---------------------------------------------------------------------------
+
+
+@part("sample")
+def sample_with_confidence(
+    logits: jax.Array,                  # [N, V] float32: a row a POSITION
+    key: jax.Array,
+    *,
+    temperature: jax.Array,             # [N]
+    top_p: jax.Array,                   # [N]
+    top_k: jax.Array,                   # [N] int32
+    exclude: int,                       # an id no position may draw
+):
+    """``(token [N], confidence [N], logprob [N])`` of a denoising
+    forward: ``sample``'s draw with the id ``exclude`` (the mask token)
+    at minus infinity, the probability of the drawn token under the
+    distribution it was drawn from (after temperature, top-k and top-p;
+    a greedy position takes its argmax, and its probability under the
+    softmax at temperature 1, so that the confidence rules order a
+    greedy row's positions too), and its log-probability under the
+    unscaled softmax (what ``cumulative_logprob`` reports on every
+    path).
+
+    ``sample``'s rules, written out here because the confidence of a
+    filtered draw is read from the SAME head the draw was made from: a
+    second head for it (an exact top-256 over ``[512, 151,936]``) took
+    59 ms of a 71 ms denoising forward on a v5e (PERF.md section 6,
+    PR 57). Passes over ``[N, V]`` (N = rows x block: 311 MB in float32
+    at the benchmark's batch): the column write of ``exclude`` (in
+    place); ONE read that makes the scaled copy and sums the
+    exponentials at the row's temperature and at temperature 1; the
+    argmax; and, under ``cond``s that only a batch with such a row
+    takes, the approximate top-256 head (a filtered, drawing row) and
+    the full-vocabulary float32 draw (a drawing row with both filters
+    off). A batch whose rows are all greedy takes the argmax alone."""
+    N, V = logits.shape
+    with jax.named_scope("bd_confidence"):
+        logits = logits.astype(jnp.float32).at[:, exclude].set(NEG_INF)
+        greedy = temperature <= 0.0
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        lse = jax.scipy.special.logsumexp(scaled, axis=-1)
+        lse_raw = jax.scipy.special.logsumexp(logits, axis=-1)
+    greedy_tok = jnp.argmax(scaled, axis=-1).astype(jnp.int32)
+    filtered = ((top_k > 0) | (top_p < 1.0)) & ~greedy
+    plain = ~filtered & ~greedy
+    K = min(NUCLEUS_CAP, V)
+
+    def from_head():
+        # ``_drawn``'s head: exact where a small top_k would feel the
+        # approximate one's recall
+        vals, idx = jax.lax.cond(
+            jnp.any((top_k > 0) & (top_k <= 32)),
+            lambda: jax.lax.top_k(scaled, K),
+            lambda: jax.lax.approx_max_k(
+                scaled, K, recall_target=0.95, aggregate_to_topk=True
+            ),
+        )
+        probs = jnp.exp(vals - lse[:, None])
+        ranks = jnp.arange(K, dtype=jnp.int32)[None]
+        k_eff = jnp.where(top_k > 0, jnp.minimum(top_k, K), K)[:, None]
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (ranks < k_eff) & ((cum - probs) < top_p[:, None])
+        choice = jax.random.categorical(
+            key, jnp.where(keep, vals, NEG_INF), axis=-1
+        )
+        tok = jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]
+        p = jnp.take_along_axis(probs, choice[:, None], axis=1)[:, 0]
+        return tok.astype(jnp.int32), p / jnp.maximum(
+            jnp.sum(jnp.where(keep, probs, 0.0), axis=-1), 1e-30
+        )
+
+    def from_all():
+        tok = jax.random.categorical(
+            jax.random.fold_in(key, 1), scaled, axis=-1
+        ).astype(jnp.int32)
+        at = jnp.take_along_axis(scaled, tok[:, None], axis=-1)[:, 0]
+        return tok, jnp.exp(at - lse)
+
+    nothing = lambda: (jnp.zeros((N,), jnp.int32), jnp.ones((N,), jnp.float32))
+    head_tok, head_p = jax.lax.cond(jnp.any(filtered), from_head, nothing)
+    full_tok, full_p = jax.lax.cond(jnp.any(plain), from_all, nothing)
+    with jax.named_scope("bd_confidence"):
+        tok = jnp.where(
+            greedy, greedy_tok, jnp.where(filtered, head_tok, full_tok)
+        )
+        logp = jnp.take_along_axis(logits, tok[:, None], axis=-1)[:, 0] - lse_raw
+        conf = jnp.where(
+            greedy, jnp.exp(logp), jnp.where(filtered, head_p, full_p)
+        )
+    return tok, jnp.minimum(conf, 1.0), logp
+
+
+#: ``transfer``'s rules by number (``models.configs.REMASKING``)
+STATIC, DYNAMIC, SEQUENTIAL = 0, 1, 2
+
+
+@part("sample")
+def transfer(
+    x: jax.Array,        # [B, Bk] int32: the block, ``mask_id`` where open
+    x0: jax.Array,       # [B, Bk] int32: a forward's draw, every position
+    conf: jax.Array,     # [B, Bk] float32: its confidence
+    n: jax.Array,        # [B] int32: positions this forward should fill
+    rule: jax.Array,     # [B] int32: STATIC | DYNAMIC | SEQUENTIAL
+    tau: jax.Array,      # [B] float32: DYNAMIC's threshold
+    mask_id: int,
+):
+    """``(x, taken)``: the block after a denoising forward's transfer and
+    the positions it filled, open positions only. STATIC: the ``n`` open
+    positions of largest confidence (ties to the left). DYNAMIC: every
+    open position whose confidence is over ``tau`` where there are at
+    least ``n`` of them, else STATIC's. SEQUENTIAL: the leftmost ``n``
+    open positions. ``n`` is at most the open positions left. A masked
+    top-n over ``Bk`` lanes a row: ranks by an all-pairs compare, no
+    sort."""
+    with jax.named_scope("bd_transfer"):
+        Bk = x.shape[1]
+        is_open = x == mask_id
+        n = jnp.minimum(n, jnp.sum(is_open, axis=1))[:, None]
+        c = jnp.where(is_open, conf, -1.0)
+        lane = jnp.arange(Bk, dtype=jnp.int32)
+        # rank of lane i: the lanes that beat it (larger, or equal and
+        # further left)
+        beats = (c[:, None, :] > c[:, :, None]) | (
+            (c[:, None, :] == c[:, :, None])
+            & (lane[None, None, :] < lane[None, :, None])
+        )
+        top = is_open & (jnp.sum(beats, axis=2) < n)
+        over = is_open & (conf > tau[:, None])
+        enough = jnp.sum(over, axis=1, keepdims=True) >= n
+        left = is_open & (jnp.cumsum(is_open, axis=1) <= n)
+        rule = rule[:, None]
+        taken = jnp.where(
+            rule == SEQUENTIAL, left,
+            jnp.where((rule == DYNAMIC) & enough, over, top),
+        )
+        return jnp.where(taken, x0, x), taken

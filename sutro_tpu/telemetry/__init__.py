@@ -374,6 +374,44 @@ LATENT_ATTENTION_DISPATCHES_TOTAL = REGISTRY.counter(
     unit="dispatches",
     max_series=4,
 )
+# Generation by blocks (a model with ``block_length`` > 1:
+# engine/runner.py ``_decode_block_jit``, OBSERVABILITY.md "Generation
+# by blocks")
+BLOCK_FORWARDS_TOTAL = REGISTRY.counter(
+    "sutro_block_forwards_total",
+    "Forwards of a block the windows of a model that generates by "
+    "blocks ran, by kind: a denoising forward reads the block's logits "
+    "and keeps no K/V, a commit forward keeps the filled block's K/V",
+    labels=("kind",),  # denoise | commit
+    unit="forwards",
+    max_series=4,
+)
+BLOCK_ROW_FORWARDS_TOTAL = REGISTRY.counter(
+    "sutro_block_row_forwards_total",
+    "The same a live row of the window: forwards x rows, by kind",
+    labels=("kind",),
+    unit="forwards",
+    max_series=4,
+)
+BLOCK_TOKENS_TOTAL = REGISTRY.counter(
+    "sutro_block_tokens_total",
+    "Positions of the blocks the fetched windows filled, by fate: "
+    "accepted into a row's output, given (a prompt's leftover tokens at "
+    "the head of a row's first block) or lost (behind a row's stop "
+    "token or cap, or of a row that was gone)",
+    labels=("fate",),  # accepted | given | lost
+    unit="tokens",
+    max_series=4,
+)
+BLOCK_REFUSALS_TOTAL = REGISTRY.counter(
+    "sutro_block_refusals_total",
+    "What a model that generates by blocks was asked for and does not "
+    "do: refused at submit (a constraint, penalties, a seed a row, "
+    "speculation) or fallen back from (a shared prefix, the tiers)",
+    labels=("what",),
+    unit="requests",
+    max_series=12,
+)
 HC_SUBLAYERS_TOTAL = REGISTRY.counter(
     "sutro_hc_sublayers_total",
     "Sublayers a model whose residual stream is several lanes "
@@ -434,10 +472,12 @@ STATE_FALLBACK_PREFILL_TOKENS_TOTAL = REGISTRY.counter(
     # (state a slot: no page holds it); prefix_without_window_pages |
     # hibernate_without_window_pages (K/V a pool a kind: a shared or
     # tiered page has no window page); prefix_on_latent_pool |
-    # hibernate_on_latent_pool (a latent row a token: kvcache.py)
+    # hibernate_on_latent_pool (a latent row a token: kvcache.py);
+    # prefix_on_block_model | hibernate_on_block_model (generation by
+    # blocks: scheduler.py)
     labels=("reason",),
     unit="tokens",
-    max_series=12,
+    max_series=16,
 )
 KV_PAGES = REGISTRY.gauge(
     "sutro_kv_pages",
