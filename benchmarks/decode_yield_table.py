@@ -63,8 +63,18 @@ def deltas(r, name):
 def admission(r):
     waves = r.counter_delta("sutro_admit_waves_total")
     rows = r.counter_delta("sutro_admit_wave_rows_total")
+    # how the rows entered their first window (nothing on a tree before
+    # PR 58): by the token on the device, or armed on the host first
+    joined = deltas(r, "sutro_admit_wave_joined_rows_total")
     return {"waves": waves, "rows": rows,
-            "rows_a_sync": rows / waves if waves else None}
+            "rows_a_sync": rows / waves if waves else None,
+            "joined_device": joined.get("device"),
+            "joined_host": joined.get("host"),
+            "joined_device_share": share(joined.get("device", 0.0), rows)
+            if joined and rows else None,
+            # windows asked for ahead of one in flight: sent, or held
+            # back and why (nothing on a tree before PR 58)
+            "ahead": deltas(r, "sutro_decode_ahead_windows_total")}
 
 
 def mask_rows(r):

@@ -89,7 +89,12 @@ class EngineConfig:
                                     # host that holds the chip: PERF.md
                                     # §5); 1 = the same path at a depth of
                                     # one (dispatch, fetch and accept in
-                                    # one iteration)
+                                    # one iteration). An admission wave is
+                                    # resolved BEHIND the window it feeds
+                                    # (scheduler._hold_wave), so the depth
+                                    # holds while rows turn over: the wait
+                                    # for the wave's prefills has that
+                                    # window queued behind them
     constrain_fastforward: int = 16  # FSM fast-forward ("jump
                                     # decoding") width: when a schema's
                                     # FSM forces exactly one next token

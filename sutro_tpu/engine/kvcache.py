@@ -39,7 +39,8 @@ scheduler ... paged-KV decode attention"). Layout:
   Cd are the one description both read): a THIRD kind of
   per-sequence state, too large to keep a page (a matrix a head: tens
   of MB a sequence), kept a SLOT a live sequence. Slot 0 is the garbage
-  slot. ``state_slot`` ``[NP]`` int32 maps a page to a slot, on the
+  slot, and page 0, the garbage page, leads to it and is never
+  re-pointed. ``state_slot`` ``[NP]`` int32 maps a page to a slot, on the
   device: a sequence at position ``start`` finds its slot through the
   page that holds position ``start - 1`` (its first page at ``start``
   0), as ``read_conv_state`` finds its page, and ``write_kv`` re-points
@@ -845,7 +846,15 @@ def write_state(
     page = jnp.take_along_axis(
         page_table, jnp.minimum(last, page_table.shape[1] - 1)[:, None], axis=1
     )[:, 0]
-    state_slot = cache.state_slot.at[jnp.where(moved, page, 0)].set(slots)
+    # page 0 is the garbage page and leads to the garbage slot, always: a
+    # row whose window runs past its reserved pages (its table holds 0
+    # there) must not point it at a live slot, or every row that finds
+    # its slot through page 0 (an empty batch slot, another row past its
+    # pages) would advance that slot, or the row the slot goes to next
+    moved = moved & (page != 0)
+    state_slot = cache.state_slot.at[jnp.where(moved, page, 0)].set(
+        jnp.where(moved, slots, 0)
+    )
     return dataclasses.replace(
         cache, ssm=ssm, ssm_conv=ssm_conv, state_slot=state_slot
     )

@@ -1328,9 +1328,9 @@ class ModelRunner:
                     self.params,
                     self.cache,
                     jnp.asarray(ids),
-                    jnp.asarray([len(seg)], jnp.int32),
+                    jnp.asarray(np.array([len(seg)], np.int32)),
                     table_dev,
-                    jnp.asarray([start + off], jnp.int32),
+                    jnp.asarray(np.array([start + off], np.int32)),
                 )
                 # the chunk is dispatched: what slid out behind its end
                 # goes back before the next chunk binds
@@ -1351,9 +1351,11 @@ class ModelRunner:
             self.params,
             self.cache,
             jnp.asarray(ids),
-            jnp.asarray([n], jnp.int32),
+            # (numpy first: a Python list would be converted by a device
+            # program of its own, two more dispatches a row)
+            jnp.asarray(np.array([n], np.int32)),
             jnp.asarray(page_table[None, :], jnp.int32),
-            jnp.asarray([0], jnp.int32),
+            jnp.asarray(np.array([0], np.int32)),
         )
         out = self._prefill_out(logits, route, 1, on_device)
         return out if on_device else out[0]
@@ -2433,6 +2435,56 @@ class ModelRunner:
             prev_last,
             jnp.asarray(refresh_mask, bool),
             jnp.asarray(refresh_vals, jnp.int32),
+        )
+
+    def resident(self, x) -> jax.Array:
+        """``x`` where this runner's programs leave their results: an
+        argument that is sometimes an upload and sometimes a program's
+        result has ONE signature once it went through here (a jitted
+        function is lowered again for an uncommitted array where it had
+        a committed one, and the other way round). Under a mesh results
+        are committed, so ``x`` is put whole on every device; on one
+        device nothing is (the pool, the weights, every result), and an
+        array committed here would commit the pool through the first
+        window that took it: a second lowering of every program that
+        takes the pool. A no-op for an array that already lies so."""
+        if self.mesh is None:
+            return jnp.asarray(x)
+        sharding = getattr(self, "_resident_sharding", None)
+        if sharding is None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            sharding = self._resident_sharding = NamedSharding(
+                self.mesh, PartitionSpec()
+            )
+        return jax.device_put(x, sharding)
+
+    @functools.partial(jax.jit, static_argnums=(0,))
+    def _merge_first_jit(
+        self, prev_last, refresh_mask, refresh_vals, first_mask, first
+    ):
+        """``_merge_last_jit`` for a window that goes out before the
+        admission wave in front of it is resolved: a row of the wave
+        takes its first token from ``first`` ([B] by slot, where
+        admission's sample wrote it), which the host has not seen."""
+        return jnp.where(
+            first_mask, first,
+            jnp.where(refresh_mask, refresh_vals, prev_last),
+        )
+
+    def merge_first(
+        self, prev_last, refresh_mask, refresh_vals, first_mask, first
+    ):
+        """The last tokens of a window some of whose rows' first tokens
+        are still on the device. One program whatever the wave's rows
+        and dispatches, and whether ``prev_last`` is the window before's
+        sample row or (nothing in flight) the host's own."""
+        return self._merge_first_jit(
+            self.resident(jnp.asarray(prev_last, jnp.int32)),
+            jnp.asarray(refresh_mask, bool),
+            jnp.asarray(refresh_vals, jnp.int32),
+            jnp.asarray(first_mask, bool),
+            self.resident(first),
         )
 
     # ------------------------------------------------------------------

@@ -18,7 +18,10 @@ remote service keeps behind ``POST /batch-inference`` (SURVEY §2.3 row 1,
   longer than ``prefill_chunk`` prefill alone via the chunked path.
   Admission waits for the device ONCE an iteration: prefills and their
   first-token samples are dispatched back to back, the first tokens of
-  the whole wave fetched together (``_resolve_wave``).
+  the whole wave fetched together (``_resolve_wave``), and where the
+  rows can enter the next fused window by the tokens on the device that
+  wait lies behind the window's dispatch (``_hold_wave``), so a wave
+  does not drain the device's queue.
 - Order-preserving results: completions are emitted keyed by ``row_id`` and
   re-assembled in input order by the jobstore, while execution order is
   whatever batching dictates (reference contract: README.md:221).
@@ -30,6 +33,7 @@ remote service keeps behind ``POST /batch-inference`` (SURVEY §2.3 row 1,
 from __future__ import annotations
 
 import dataclasses
+import functools
 import inspect
 import logging
 import time
@@ -38,6 +42,7 @@ from typing import (
 )
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 logger = logging.getLogger(__name__)
@@ -68,9 +73,10 @@ _QUIET_STAGES = frozenset((
 ))
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("split",))
 def _admit_sample_jit(
-    logits, key, temperature, top_p, top_k, allowed, row_seeds
+    logits, key, temperature, top_p, top_k, allowed, row_seeds,
+    first=None, slots=None, split=False,
 ):
     """First-token sampling + logprob for admission, under ONE jit.
 
@@ -80,15 +86,28 @@ def _admit_sample_jit(
     repeat groups of the same shape hit the pjit cache and the whole
     sample+logprob pair runs as one compiled program. ``allowed``
     arrives bit-packed ([B, ceil(V / 8)] uint8), like the masked decode
-    step's."""
+    step's. ``first`` is the decode batch's first tokens by SLOT, on the
+    device: the tokens of this dispatch's rows are written into it at
+    ``slots`` (a padding row's slot is out of range and dropped) and it
+    is returned third, for a window that goes out before the host has
+    read them (``ModelRunner.merge_first``). ``split``: ``key`` is the
+    session's key, split HERE (the values ``jax.random.split`` gives
+    when called on its own, which costs a row two dispatches more) and
+    handed back fourth; else it is the key to sample with."""
     if allowed is not None:
         allowed = unpack_mask(allowed, logits.shape[-1])
+    if split:
+        key, sub = jax.random.split(key)
+    else:
+        key, sub = None, key
     tok = device_sample(
-        logits, key,
+        logits, sub,
         temperature=temperature, top_p=top_p, top_k=top_k,
         allowed=allowed, row_seeds=row_seeds,
     )
-    return tok, cumulative_logprob(logits, tok)
+    if first is not None:
+        first = first.at[slots].set(tok.astype(first.dtype), mode="drop")
+    return tok, cumulative_logprob(logits, tok), first, key
 
 
 def _step_seed(row_seed: int, step: int) -> int:
@@ -398,6 +417,10 @@ class _Slot:
     # not in the cache yet; they start the row's first generated block
     # and are cleared once that block's window has been accepted
     given: int = 0
+    # seated in the decode batch, its first token still on the device:
+    # ``last_token`` says nothing until the wave is resolved (``_arm``)
+    first_pending: bool = False
+    seated: bool = False  # ``_seat`` ran (it runs once a row)
 
 
 @dataclasses.dataclass
@@ -477,6 +500,20 @@ _WINDOW_HOST = 0.57
 # a batch near the line stays where it is: the other path has to be worth
 # a tenth more (p under ~0.86 leaves the windows, over ~0.92 returns)
 _SWITCH_GAIN = 1.1
+# The most of a batch's rows that may END in the windows in flight for one
+# more window to go out ahead while rows wait for slots
+# (``_pipe_capacity_ok``). Held back, the window costs the device the host's
+# round between a fetch and the next dispatch; sent ahead, it costs the
+# ending rows' share of its row-steps (``stale``), which rows that wait
+# would have used a window sooner. Where the two meet was measured, a cell
+# a side (PERF.md section 6, PR 58, my chip runs): a sixteenth of the batch
+# ending at a time (the long-output cells: +8 to +12 %) and an eighth
+# (Mellum 2: +6.2 %) are worth sending; a quarter is not (LFM2's short
+# jobs: -2.8 % with 3.3 % of the row-steps stale, granite's: inside its
+# spread with or without the rule); at a half the stage graph's rows, a
+# window long, lost their overlap (``tests/test_stagegraph.py``). The line
+# is put between the eighth and the quarter.
+_AHEAD_ENDING = 3 / 16
 
 
 def _window_gain(p: float, K: int, constrained: float = 1.0) -> float:
@@ -700,7 +737,13 @@ class ContinuousBatcher:
         # window dispatched against a slot's OLD occupant fails the
         # (slot, gen) check at processing time after the slot is reused
         self._gen = [0] * self.B
-        self._key = jax.random.PRNGKey(seed)
+        # resident where the runner can make it so: the session key
+        # comes back from the admission sample's program (a committed
+        # result), and a program compiles once more for a committed
+        # argument where it had an upload
+        resident = getattr(runner, "resident", None) or (lambda x: x)
+        self._resident = resident
+        self._key = resident(jax.random.PRNGKey(seed))
         self._fixed_key = jax.random.PRNGKey(seed)
         self._step = 0
         # slot indices whose speculative window rejected a token: each
@@ -792,6 +835,19 @@ class ContinuousBatcher:
         self._wave: List[_WaveEntry] = []
         self._wave_t0 = 0.0
         self._wave_seq = 0
+        # the slots' first tokens where admission's sample writes them,
+        # [B] on the device (made by the first sample): a window that
+        # goes out before its wave is resolved takes them from there
+        # (runner.merge_first; a stand-in runner has none, and its
+        # waves are resolved before the build). A block model samples
+        # no first token and needs none
+        self._first_dev: Any = None
+        # a row is waiting for a slot (run_multi, once an iteration): a
+        # slot freed a window sooner has a taker
+        self._rows_waiting = False
+        self._joins_on_device = self._block > 1 or callable(
+            getattr(runner, "merge_first", None)
+        )
         # tokens committed by the accept loops (the accept span's attr)
         self._n_accepted = 0
         # what the dispatch just accepted yielded: (row-steps, tokens
@@ -928,8 +984,12 @@ class ContinuousBatcher:
         tail transfers into the tree for the next job. A store crash
         during lookup (fault site ``prefixstore.lookup``) degrades to
         a plain miss — the job pays full prefill but never fails.
-        Without a store: per-JOB pages, exactly the pre-store path."""
-        self._resolve_wave()
+        Without a store: per-JOB pages, exactly the pre-store path.
+        A job whose rows share no page of prefix, or whose model keeps
+        none, returns before anything is touched, and the admission wave
+        in front of it stays as it is; where a prefix is set up (pages
+        taken, perhaps evicted, a prefill waited for) the wave is
+        resolved first."""
         ctx.prefix = None
         pending = ctx.pending
         ecfg = self.ecfg
@@ -978,6 +1038,7 @@ class ContinuousBatcher:
             if self._block > 1 and self._tel_on:
                 telemetry.BLOCK_REFUSALS_TOTAL.inc(1.0, "shared_prefix")
             return
+        self._resolve_wave()
         n_pages = shared // PS
         # warm head from the radix store (pins the matched path);
         # any store raise is a plain miss — never a job failure
@@ -1521,10 +1582,12 @@ class ContinuousBatcher:
         sample over its logits where they lie, on the device, installs
         the rows' slots and appends the batch to the admission wave:
         nothing is waited for here. ``_resolve_wave`` fetches the first
-        tokens and arms the slots, a wave at a time; a row whose job
-        streams its tokens is resolved at once, so a chat's first token
-        waits for no row behind it. Each row prefills its own suffix at
-        its job's shared-prefix offset."""
+        tokens and arms the slots, a wave at a time, behind the
+        iteration's window where the rows can enter it by the tokens on
+        the device (``_hold_wave``); a row whose job streams its tokens
+        is resolved at once, so a chat's first token waits for no row
+        behind it. Each row prefills its own suffix at its job's
+        shared-prefix offset."""
         reqs = [b[0] for b in batch]
         starts = [
             b[1].prefix.tokens if b[1].prefix is not None else 0
@@ -1602,32 +1665,95 @@ class ContinuousBatcher:
         if any(b[1].on_token is not None for b in batch):
             self._resolve_wave()
 
+    def _wave_rows(self):
+        """The (slot index, slot) pairs the unresolved wave will arm."""
+        return [r for e in self._wave for r in e.rows]
+
     def _to_wave(self, rows, tok, logp, route, tokens: int) -> None:
         if not self._wave:
             self._wave_t0 = time.monotonic()
         self._wave.append(_WaveEntry(rows, tok, logp, route, tokens))
 
-    def _resolve_wave(self) -> None:
+    def _hold_wave(self) -> bool:
+        """The end of an iteration's admission. Where every row of the
+        wave can enter a fused window by its first token ON THE DEVICE,
+        the rows take their places in the batch now (``_seat``) and the
+        wave stays unresolved: True, and the caller resolves it right
+        after the iteration's decode dispatch, so that the wait for the
+        wave's prefills is a wait with the next window queued behind
+        them. Else the wave is resolved here, in front of the build:
+        a row that decodes under an FSM, a seed of its own or penalties
+        needs its first token on the host before its next step (these
+        are the facts that keep ``_choose_path`` off the pipelined
+        windows), as does a row that can only end on it; a job that
+        streams was resolved at its own dispatch (``_admit_batch``)."""
+        rows = self._wave_rows()
+        if (
+            rows
+            and self._joins_on_device
+            and all(self._enters_unread(s) for _, s in rows)
+        ):
+            for i, s in rows:
+                self._seat(i, s)
+                # (a block model's row has no first token to wait for)
+                s.first_pending = self._block == 1
+            return True
+        self._resolve_wave()
+        return False
+
+    def _enters_unread(self, s: _Slot) -> bool:
+        """Nothing the host does with slot ``s`` before its first
+        window's tokens are back needs the row's first token."""
+        r = s.req
+        return (
+            r.constraint is None
+            and r.row_seed is None
+            and not r.has_penalties()
+            and (s.job is None or s.job.on_token is None)
+            # a row prefilled in chunks gets its table row back when it
+            # is seated, and a window dispatched while the row was still
+            # prefilling must have run by then (its writes for the slot
+            # go to the garbage page only while the row reads zero):
+            # the resolve in front of the build waits for it
+            and not s.prefilling
+            # a first token that is certain to end the row would cost a
+            # window's row-steps for nothing
+            and (
+                self._block > 1
+                or self._remaining(r, 0, len(r.prompt_ids)) > 1
+            )
+        )
+
+    def _resolve_wave(self, in_window=()) -> None:
         """The admission wave's ONE host sync: fetch the first tokens
         (and routing counts) of every prefill dispatched since the last
-        one, then arm the rows in dispatch order. Called when an
-        iteration's admission is over, right after the dispatch of a
-        row that streams, and before anything that may release or move
-        a slot (an eviction, a resume, a suspend, a cancel, a prefix
-        setup) or raise out of admission: outside admission no slot is
-        ever pending (``run_multi``'s ``finally`` resolves what a raise
-        left). Should the fetch or a row's arming raise, the rows not
-        armed yet are given up, as a failed dispatch gives up its own."""
-        rows = [r for e in self._wave for r in e.rows]
+        one, then arm the rows in dispatch order. A slot is pending from
+        its prefill's dispatch to the resolve of its iteration, and
+        across nothing else: that resolve is ``_hold_wave``'s, in front
+        of the batch's build, or where the wave was held the one right
+        behind the iteration's decode dispatch (``_pipelined_step``;
+        ``in_window``: the slots that dispatch took, whose rows entered
+        it by the tokens on the device). Also called right after the
+        dispatch of a row that streams, and before anything that may
+        release or move a slot (an eviction, a resume, a suspend, a
+        cancel, the setup of a shared prefix) or raise out of admission
+        (``run_multi``'s ``finally`` resolves what a raise left).
+        Should the fetch or a row's arming raise, the rows not armed
+        yet are given up, as a failed dispatch gives up its own: a
+        window that already holds them finds their generation moved
+        and drops their tokens as ``stale``."""
+        rows = self._wave_rows()
         if not rows:
             return  # routing counts alone wait for a wave with rows
         wave, self._wave = self._wave, []
         n, armed = len(rows), 0
+        on_device = sum(1 for i, _ in rows if i in in_window)
         try:
             if self._tel_on:
                 self._tel_attrs["prefill"] = {
                     "tokens": 0, "wave": self._wave_seq, "wave_rows": n,
                     "wave_tokens": sum(e.tokens for e in wave),
+                    "joined_device": on_device,
                 }
             with self.timer.time("prefill"):
                 got = jax.device_get(
@@ -1646,10 +1772,15 @@ class ContinuousBatcher:
             if self._tel_on:
                 telemetry.ADMIT_WAVES_TOTAL.inc(1.0)
                 telemetry.ADMIT_WAVE_ROWS_TOTAL.inc(float(n))
+                for how, k in (("device", on_device), ("host", n - on_device)):
+                    if k:
+                        telemetry.ADMIT_WAVE_JOINED_ROWS_TOTAL.inc(
+                            float(k), how
+                        )
             for e, (toks, logps, _) in zip(wave, got):
                 for k, (i, s) in enumerate(e.rows):
                     if toks is None:  # a block model: no first token
-                        self._arm_block(i, s)
+                        self._seat(i, s)
                     else:
                         self._arm(i, s, int(toks[k]), float(logps[k]))
                     armed += 1
@@ -1659,26 +1790,46 @@ class ContinuousBatcher:
                     self._drop_slot(i)
             raise
 
-    def _arm(self, i: int, s: _Slot, first: int, logp: float) -> None:
-        """Slot ``i``'s prompt is in its pages and its first token is
-        here: the row joins the decode batch."""
+    def _seat(self, i: int, s: _Slot) -> None:
+        """Slot ``i``'s prompt is in its pages (or will be when the
+        prefills in front of the next decode dispatch have run): the row
+        takes its place in the decode batch, with everything of it that
+        the host knows without the row's first token. A block model's
+        row is then armed: the prompt's leftover tokens head its first
+        block and no token is sampled. Any other row is armed by its
+        first token (``_arm``). Once a row."""
+        if s.seated:
+            return
+        s.seated = True
         req = s.req
-        if self.native is not None:
-            if s.prefilling:
-                row = self.native.table[i]
-                row[:] = 0
-                row[: len(s.pages)] = s.pages
-            self.native.arm_slot(
-                i, len(req.prompt_ids), first,
-                req.temperature, req.top_p, req.top_k,
-            )
+        if self._block > 1:
+            s.pos = self._prefill_len(req)
+            s.given = len(req.prompt_ids) - s.pos
+        else:
+            if self.native is not None:
+                if s.prefilling:
+                    row = self.native.table[i]
+                    row[:] = 0
+                    row[: len(s.pages)] = s.pages
+                self.native.arm_slot(
+                    i, len(req.prompt_ids), 0,
+                    req.temperature, req.top_p, req.top_k,
+                )
+            s.pos = len(req.prompt_ids)
+            self._seed_penalty_bits(s, req)
         s.prefilling = False
         s.ptable = None
-        s.pos = len(req.prompt_ids)
-        s.last_token = first
-        self._seed_penalty_bits(s, req)
         if s.job is not None:
             s.job.stats["in"] += len(req.prompt_ids)
+
+    def _arm(self, i: int, s: _Slot, first: int, logp: float) -> None:
+        """Slot ``i`` is seated and its first token is here."""
+        self._seat(i, s)
+        s.first_pending = False
+        if self.native is not None:
+            self.native.last[i] = first
+        s.last_token = first
+        if s.job is not None:
             s.job.stats["out"] += 1  # the prefill-sampled first token
         self._record_token(s, first, logp)
         self._deliver_token(s, first, logp)
@@ -1698,19 +1849,6 @@ class ContinuousBatcher:
         if self._block == 1:
             return KS
         return max(KS // self._block, 1) * self._block
-
-    def _arm_block(self, i: int, s: _Slot) -> None:
-        """Slot ``i``'s whole prompt blocks are in its pages: the row of
-        a model that generates by blocks joins the decode batch with the
-        prompt's leftover tokens as the head of its first block and no
-        token sampled."""
-        req = s.req
-        s.prefilling = False
-        s.ptable = None
-        s.pos = self._prefill_len(req)
-        s.given = len(req.prompt_ids) - s.pos
-        if s.job is not None:
-            s.job.stats["in"] += len(req.prompt_ids)
 
     def _drop_slot(self, i: int) -> None:
         """Give slot ``i`` up with no result: its pages and state go
@@ -2215,7 +2353,9 @@ class ContinuousBatcher:
         bucket's padding, sampled greedily and never read, so the
         program compiles once a bucket and not once a group size.
         Returns ``(tok, logp)``, [B] each, ON THE DEVICE and on their
-        way to the host: ``_resolve_wave`` reads them."""
+        way to the host: ``_resolve_wave`` reads them. The same program
+        writes the rows' tokens into ``_first_dev`` at their slots, for
+        a window dispatched before that."""
         n, nb = len(reqs), logits.shape[0]
         temps = np.zeros((nb,), np.float32)
         top_p = np.ones((nb,), np.float32)
@@ -2236,6 +2376,7 @@ class ContinuousBatcher:
                             r.constraint, rem, allowed[i], shared
                         )
         row_seeds = None
+        split = False
         if any(r.row_seed is not None for r in reqs):
             sub = self._fixed_key  # per-row keys derive from row_seed
             # unseeded rows in a mixed batch key off their SLOT index
@@ -2249,13 +2390,27 @@ class ContinuousBatcher:
                 for i, r in enumerate(reqs)
             ]
         else:
-            self._key, sub = jax.random.split(self._key)
+            # the session's key, split inside the sample's own program
+            sub, split = self._key, True
+        first = slots = None
+        if self._joins_on_device:
+            # by slot; the bucket's padding rows go nowhere
+            slots = np.full((nb,), self.B, np.int32)
+            slots[:n] = slot_idxs
+            first = self._first_dev
+            if first is None:
+                first = np.zeros((self.B,), np.int32)
+            # one signature whether it is the upload or a result
+            first = self._resident(first)
         # static: a stand-in runner (tests, host benches) has no counters
         ModelRunner.count_sample(temps)
         with self.timer.time("admit_sample"):
-            tok, logp = _admit_sample_jit(
-                logits, sub, temps, top_p, top_k, allowed, row_seeds
+            tok, logp, self._first_dev, key = _admit_sample_jit(
+                logits, sub, temps, top_p, top_k, allowed, row_seeds,
+                first, slots, split=split,
             )
+            if split:
+                self._key = key
             tok.copy_to_host_async()
             logp.copy_to_host_async()
         return tok, logp
@@ -2918,9 +3073,14 @@ class ContinuousBatcher:
         ``pipe``: toks_dev, logps_dev, active, gens, K, route_dev, jobs) and
         fetch the oldest. At a depth of one the window dispatched here
         is the one fetched: dispatch, fetch and accept in one
-        iteration. Without ``refill`` the pipe only drains."""
+        iteration. Without ``refill`` the pipe only drains. A wave that
+        admission held (``_hold_wave``) is resolved between the two:
+        its prefills are in front of the window just dispatched, so the
+        device has that window queued while the host waits for them,
+        arms the rows and accepts the oldest window."""
         KS = self._window_tokens()
         self._note_window(b, KS)
+        n0 = len(pipe)
         if refill:
             while len(pipe) < max(self.ecfg.decode_lookahead, 1):
                 proj = self._pipe_projection(pipe)
@@ -2930,9 +3090,25 @@ class ContinuousBatcher:
                 if K <= 0 or not self._pipe_capacity_ok(b.active, proj, K):
                     break
                 self._dispatch_pipelined(pipe, b, proj, K)
+        if self._wave:
+            self._resolve_held(b.active if len(pipe) > n0 else ())
         if pipe:
             self._process_pipelined(pipe.pop(0))
         return "pipelined"
+
+    def _resolve_held(self, in_window) -> None:
+        """Resolve the wave that admission held, behind the decode
+        dispatch (``in_window``: the slots that dispatch took), then end
+        the rows whose first token ended them: what the loop's sweep
+        does for a wave resolved in front of the build. Such a row's
+        steps in the window are lost as ``stale``."""
+        rows = self._wave_rows()
+        self.timer.enter("admit_host")
+        self._resolve_wave(frozenset(in_window))
+        self.timer.enter("emit")
+        for i, s in rows:
+            if self.slots[i] is s and self._finish_reason(s, s.last_token):
+                self._emit(i)
 
     def _block_room(self, active, proj: np.ndarray) -> int:
         """Positions, in whole blocks, that every active row of a block
@@ -2960,23 +3136,64 @@ class ContinuousBatcher:
     def _pipe_capacity_ok(
         self, active, proj: np.ndarray, K: int
     ) -> bool:
-        """True when every active row's up-front page reservation covers
-        ``K`` more steps BEYOND everything already in flight — the
-        invariant that makes speculative window writes always land in
-        the row's own reserved pages.
+        """True when a window of ``K`` more steps BEYOND everything
+        already in flight stays inside every active row's TABLE ROW.
 
-        Caveat: this invariant covers LIVE slots only. A slot released
+        A row's up-front reservation covers every token it may still
+        commit, so the positions a window writes past the row's reserved
+        pages are past the row's cap: its table holds 0 there, the
+        garbage page, where an empty slot's writes go, and the tokens
+        sampled there are dropped when the window is accepted
+        (``finished``). Until PR 58 the rule asked for ``K`` positions
+        of the row's OWN pages, so a row in its last window or two held
+        back the lookahead of the whole batch: each time a job's rows
+        ended, the window they ended in was the only one in flight and
+        the device's queue ran empty behind it (PERF.md section 5, "The
+        drain"). What the old rule did give: a slot whose row ENDS in
+        the windows in flight is dead weight in one more, and a row
+        that waits for a slot gets it a window later. So a window goes
+        out ahead only if some row can still commit a token of it (not
+        behind a warm-up job's only window), and, while rows wait for
+        slots, only if the rows ending in flight are at most
+        ``_AHEAD_ENDING`` of the batch (a job of sixteen of 256 rows:
+        yes; a job that is a quarter of the batch, or a batch of rows a
+        window long, half of them ending every iteration: no, the depth
+        stays one for that window and it goes out full).
+        A block model keeps the old rule: it reserves its windows' room
+        at admission (``_reserve``).
+
+        Caveat: this covers LIVE slots only. A slot released
         mid-pipeline leaves stale in-flight windows writing into freed
         pages; that case is safe only via the dispatch-order argument
         documented on ``_release``."""
         if not active:
             return False
         PS = self.ecfg.kv_page_size
+        width = self.MP * PS
+        if self._block > 1:
+            return all(
+                len(self.slots[i].pages) * PS - self.slots[i].pos
+                - int(proj[i]) >= K for i in active
+            )
+        ending = 0
         for i in active:
             s = self.slots[i]
-            if len(s.pages) * PS - s.pos - int(proj[i]) < K:
+            if width - s.pos - int(proj[i]) < K:
                 return False
-        return True
+            # tokens the row may still commit (its first token, if
+            # still on the device, is one of max_new_tokens)
+            left = s.req.max_new_tokens - max(len(s.out_ids), 1)
+            if min(left, self._max_ctx - 1 - s.pos) <= proj[i]:
+                ending += 1
+        if ending == len(active):
+            verdict = "held_unused"
+        elif self._rows_waiting and ending > _AHEAD_ENDING * len(active):
+            verdict = "held_ending"
+        else:
+            verdict = "sent"
+        if self._tel_on and proj.any():  # a window AHEAD of one in flight
+            telemetry.DECODE_AHEAD_WINDOWS_TOTAL.inc(1.0, verdict)
+        return verdict == "sent"
 
     def _dispatch_pipelined(
         self, pipe, b: _DecodeBatch, proj: np.ndarray, K: int
@@ -2987,11 +3204,15 @@ class ContinuousBatcher:
         window starts that many steps past each row's ``pos``. The last
         tokens chain from the previous window's device-resident sample
         row; slots admitted (or re-admitted) since that dispatch take
-        their host-known token via a device-side merge — no host sync
-        anywhere on this path."""
+        their host-known token via a device-side merge, and the rows of
+        a wave not resolved yet (``_Slot.first_pending``) the token
+        admission's sample left on the device — no host sync anywhere
+        on this path."""
         active = b.active
         if self._block > 1:
             return self._dispatch_block_window(pipe, b, proj, K)
+        chained: set = set()
+        prev_last = None
         if pipe:
             prev_toks, _, p_active, p_gens, *_ = pipe[-1]
             chained = {
@@ -2999,20 +3220,39 @@ class ContinuousBatcher:
                 for idx, i in enumerate(p_active)
                 if p_gens[idx] == self._gen[i]
             }
-            if all(i in chained for i in active):
-                # steady state: every active row chains from the previous
-                # window — skip the merge program entirely (tokens at
-                # non-active slots are garbage either way)
-                last_arg = prev_toks[-1]
+            prev_last = prev_toks[-1]
+        unread = [
+            i for i in active
+            if self.slots[i].first_pending and i not in chained
+        ]
+        if unread or (pipe and not all(i in chained for i in active)):
+            refresh = np.ones((self.B,), bool)
+            for i in chained:
+                refresh[i] = False
+            host_last = np.asarray(b.last, np.int32)
+            if prev_last is None:
+                prev_last = host_last  # nothing in flight to chain from
+            if unread:
+                first_mask = np.zeros((self.B,), bool)
+                first_mask[unread] = True
+                last_arg = self.runner.merge_first(
+                    prev_last, refresh, host_last, first_mask,
+                    self._first_dev,
+                )
             else:
-                refresh = np.ones((self.B,), bool)
-                for i in chained:
-                    refresh[i] = False
                 last_arg = self.runner.merge_last(
-                    prev_toks[-1], refresh, np.asarray(b.last, np.int32)
+                    prev_last, refresh, host_last
                 )
         else:
-            last_arg = b.last
+            # steady state: every active row chains from the previous
+            # window, no merge program at all (tokens at non-active
+            # slots are garbage either way); or nothing is in flight and
+            # the host knows every token (resident, as every other
+            # window's last tokens are: one decode program, not two)
+            last_arg = (
+                self._resident(jnp.asarray(b.last, jnp.int32))
+                if prev_last is None else prev_last
+            )
         self._key, sub = jax.random.split(self._key)
         with self.timer.time("decode"):
             toks_dev, logps_dev = self.runner.decode_multi_async(
@@ -3722,6 +3962,27 @@ class ContinuousBatcher:
             if not ctx.done:
                 self._job_progress(ctx)
 
+    def _ready_rows(self, live: List[JobCtx], on_job_done) -> List[int]:
+        """End the rows that their first token ended (a stop id, one
+        token asked for), finish the jobs that leaves drained, and
+        return the slots of this iteration's decode batch. A row seated
+        with its first token still on the device is in the batch and is
+        passed by until its wave is resolved."""
+        for i, s in enumerate(self.slots):
+            if (
+                s is not None
+                and not s.prefilling
+                and not s.first_pending
+                and self._finish_reason(s, s.last_token)
+            ):
+                self._emit(i)
+        self._sweep_done(live, on_job_done)
+        return [
+            i
+            for i, s in enumerate(self.slots)
+            if s is not None and not s.prefilling
+        ]
+
     def _sweep_done(self, live: List[JobCtx], on_job_done) -> None:
         for ctx in live:
             if not ctx.done and not ctx.pending and ctx.n_slots == 0:
@@ -4366,6 +4627,7 @@ class ContinuousBatcher:
                     tm.wake("admit_host")
                 tm.enter("admit_host")
                 admitted = self._admit_pending(order)
+                self._rows_waiting = any(c.pending for c in order)
                 # double-buffered admission: hand the NEXT group's lazy
                 # constraint builds to the prep thread now — they
                 # overlap the device window dispatched below
@@ -4376,23 +4638,29 @@ class ContinuousBatcher:
                 self._prefill_tick()
                 # the iteration's one wait for its admissions: every
                 # prefill above is dispatched, their first tokens come
-                # back together
-                self._resolve_wave()
-                tm.enter("emit")
-                # Immediately-finished rows (e.g. first token was stop).
-                for i, s in enumerate(self.slots):
-                    if (
-                        s is not None
-                        and not s.prefilling
-                        and self._finish_reason(s, s.last_token)
-                    ):
-                        self._emit(i)
-                self._sweep_done(live, on_job_done)
-                active = [
-                    i
-                    for i, s in enumerate(self.slots)
-                    if s is not None and not s.prefilling
-                ]
+                # back together. Where the wave's rows can enter the
+                # next fused window by the tokens on the device, that
+                # wait lies BEHIND the window's dispatch
+                # (_pipelined_step), so the device's queue holds the
+                # window while the host waits, arms and accepts; else
+                # it is here
+                held = self._hold_wave()
+                while True:
+                    tm.enter("emit")
+                    active = self._ready_rows(live, on_job_done)
+                    if not active:
+                        break
+                    n_active = len(active)
+                    tm.enter("batch_build", active=n_active)
+                    batch = self._build_batch(active)
+                    plan = self._choose_path(batch.facts, len(pipe))
+                    if not held or plan == "pipelined":
+                        break
+                    # the step these rows' batch takes next wants their
+                    # first tokens on the host: resolve, then build anew
+                    tm.enter("admit_host")
+                    self._resolve_wave()
+                    held = False
                 if not active:
                     ajobs = [c for c in live if not c.done]
                     if not ajobs:
@@ -4450,10 +4718,6 @@ class ContinuousBatcher:
                         tm.doze()
                         time.sleep(0.0005)
                     continue
-                n_active = len(active)
-                tm.enter("batch_build", active=n_active)
-                batch = self._build_batch(active)
-                plan = self._choose_path(batch.facts, len(pipe))
                 if plan == "fastforward" and not self._fastforward_step(
                     batch
                 ):

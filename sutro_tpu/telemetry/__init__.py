@@ -632,6 +632,27 @@ ADMIT_WAVE_ROWS_TOTAL = REGISTRY.counter(
     "host sync; a job that streams its tokens cuts the wave at its row)",
     unit="rows",
 )
+ADMIT_WAVE_JOINED_ROWS_TOTAL = REGISTRY.counter(
+    "sutro_admit_wave_joined_rows_total",
+    "The same rows by how each entered its first decode dispatch: "
+    "device (the window was dispatched before the row's first token "
+    "reached the host, which took it from the device) | host (the wave "
+    "was resolved first: a constrained, seeded, penalised or streaming "
+    "row, or no pipelined window to enter)",
+    labels=("joined",),
+    unit="rows",
+    max_series=4,
+)
+DECODE_AHEAD_WINDOWS_TOTAL = REGISTRY.counter(
+    "sutro_decode_ahead_windows_total",
+    "Fused windows asked for AHEAD of one in flight, by what the "
+    "scheduler did (_pipe_capacity_ok): sent | held_unused (the windows "
+    "in flight end every row) | held_ending (rows wait for slots and "
+    "over _AHEAD_ENDING of the batch ends in flight)",
+    labels=("ahead",),
+    unit="windows",
+    max_series=4,
+)
 # what the decode dispatches yield (OBSERVABILITY.md "What a decode
 # dispatch yields"): a row-step is one position of one live row in one
 # dispatch at which a token could have been committed; per path,

@@ -5,10 +5,14 @@ an iteration admitted come back in ONE host sync. A job that streams its
 tokens cuts the wave at its own row, which is how these tests resolve a
 wave row by row: every output of a wave must be bit-equal to that, on a
 dense, a routed, a state-slot and a window-pool model, and whatever
-releases or moves a slot must find every row armed."""
+releases or moves a slot must find every row armed. Where the wave's
+rows can enter a fused window by their first tokens on the device, the
+window goes out first and the wave is resolved behind it: same tokens,
+and nothing pending across an iteration's end."""
 
 import dataclasses
 import functools
+import json
 from pathlib import Path
 
 import jax
@@ -31,7 +35,10 @@ PRESETS = {
     "routed": "tiny-lfm2",
     "state-slot": "tiny-granite",
     "window-pool": "tiny-mellum2",
+    # generates by blocks of 4: admission samples no first token
+    "block": "tiny-sdar",
 }
+WAVE_PRESETS = [p for p in PRESETS if p != "block"]
 TEXTS = [
     "hello", "a second row", "third", "the fourth of eight rows",
     # past prefill_chunk: it is admitted by _prefill_tick, a chunk an
@@ -41,6 +48,8 @@ TEXTS = [
 ]
 WAVES = "sutro_admit_waves_total"
 WAVE_ROWS = "sutro_admit_wave_rows_total"
+JOINED = "sutro_admit_wave_joined_rows_total"
+AHEAD = "sutro_decode_ahead_windows_total"
 
 
 def _ecfg(**kw):
@@ -101,9 +110,24 @@ def requests(tok, texts=TEXTS, seeded=False, **kw):
     return out
 
 
-def counter(name: str) -> float:
+def counter(name: str, key=None) -> float:
     series = telemetry.REGISTRY.collect().get(name, {}).get("series", {})
+    if key is not None:
+        return sum(v for k, v in series.items() if key in str(k))
     return sum(series.values())
+
+
+def joined():
+    """(rows that entered their first window by the token on the
+    device, rows armed on the host first) so far."""
+    return np.array([counter(JOINED, "device"), counter(JOINED, "host")])
+
+
+def ahead():
+    """Windows asked for ahead of one in flight so far: (sent, held
+    because no row could use one, held for the rows ending in flight)."""
+    return np.array([counter(AHEAD, k)
+                     for k in ("sent", "held_unused", "held_ending")])
 
 
 def run(b, reqs, stream=False, **ctx_kw):
@@ -118,6 +142,7 @@ def run(b, reqs, stream=False, **ctx_kw):
         **ctx_kw,
     )
     w0, r0 = counter(WAVES), counter(WAVE_ROWS)
+    run.ctx = ctx
     state = b.run_multi([ctx], on_job_done=lambda c, o: None)
     assert state == "completed"
     out = {
@@ -144,7 +169,7 @@ def prefill_spans():
 
 @pytest.mark.parametrize("seeded", [False, True], ids=["unseeded", "seeded"])
 @pytest.mark.parametrize("pbs", [1, 8], ids=["batch1", "batch8"])
-@pytest.mark.parametrize("preset", list(PRESETS))
+@pytest.mark.parametrize("preset", WAVE_PRESETS)
 def test_a_wave_gives_what_row_by_row_gives(preset, pbs, seeded):
     runner = runner_of(preset)
     tok = tok_of(runner)
@@ -243,7 +268,7 @@ def test_first_tokens_are_what_host_padded_logits_sampled():
     logits = runner.prefill_batch([r.prompt_ids for r in reqs], tables)
     pad = np.zeros((1, logits.shape[1]), logits.dtype)
     seeds = [_step_seed(r.row_seed, 0) for r in reqs] + [0]
-    t, lp = _admit_sample_jit(
+    t, lp, _, _ = _admit_sample_jit(
         np.concatenate([logits, pad]), jax.random.PRNGKey(3),
         np.array([0.8] * 3 + [0.0], np.float32),
         np.array([0.9] * 3 + [1.0], np.float32),
@@ -301,7 +326,14 @@ def test_a_first_token_that_stops_ends_the_row_in_its_iteration():
     b = batcher(runner, stop_ids=[stop])
     out, waves, rows = run(b, requests(tok, TEXTS[:1]))
     assert out[0][:2] == ("stop", []) and (waves, rows) == (1, 1)
-    # resolved, then emitted before any decode dispatch was made
+    # a plain row: its window went out first, the resolve behind it
+    # ended the row, and the window's steps for it committed nothing
+    st = run.ctx.stats
+    assert st["out"] == 1 and st["lost_stale"] == st["row_steps"] > 0
+    # a row that can only end on its first token waits for no window
+    b = batcher(runner)
+    out, _, _ = run(b, requests(tok, TEXTS[:1], max_new_tokens=1))
+    assert out[0][:2] == ("length", [stop])
     assert "decode" not in b.timer.summary()
 
 
@@ -344,9 +376,11 @@ def test_the_doctor_grades_a_wave_not_its_dispatches():
 
 def test_the_two_series_have_their_doc_rows():
     doc = (Path(__file__).parent.parent / "OBSERVABILITY.md").read_text()
-    for name in (WAVES, WAVE_ROWS):
+    for name in (WAVES, WAVE_ROWS, JOINED, AHEAD):
         assert f"| `{name}` | counter |" in doc
-    assert "`wave_rows`" in doc
+    assert "`held_unused`" in doc and "`held_ending`" in doc
+    assert "`wave_rows`" in doc and "`joined_device`" in doc
+    assert "`device`" in doc and "`host`" in doc
 
 
 # -- what lands in the middle of a wave ---------------------------------------
@@ -484,3 +518,533 @@ def test_an_eviction_mid_wave_chooses_among_armed_rows():
     # a's rows; b's two, cut by the eviction; the chat; b's row again
     assert counter(WAVES) - waves0 == 4
     all_free(b, runner, free0)
+
+
+# -- the window goes out first, the wave is resolved behind it ----------------
+
+def turnover(tok, n=2 * B, **kw):
+    """``n`` plain rows of staggered lengths over fewer slots: rows end
+    in different windows, so waves are admitted with windows in flight."""
+    reqs = requests(tok, [TEXTS[i % len(TEXTS)] for i in range(n)], **kw)
+    return [
+        dataclasses.replace(r, max_new_tokens=5 + 4 * (i % 3))
+        for i, r in enumerate(reqs)
+    ]
+
+
+@pytest.mark.parametrize("pbs", [1, 8], ids=["batch1", "batch8"])
+@pytest.mark.parametrize("preset", list(PRESETS))
+def test_a_wave_behind_its_window_gives_what_row_by_row_gives(preset, pbs):
+    runner = runner_of(preset, 16 if preset == "block" else B)
+    tok = tok_of(runner)
+    n = 3 * B
+    telemetry.reset_for_tests()
+    telemetry.set_enabled(True)
+    b = batcher(runner, pbs)
+    free0, j0 = b.free_page_count, joined()
+    wave, _, rows = run(b, turnover(tok, n))
+    on_device, on_host = joined() - j0
+    spans = [s["attrs"] for s in prefill_spans() if "wave_rows" in s["attrs"]]
+    all_free(b, runner, free0)
+
+    b = batcher(runner, pbs)
+    j0 = joined()
+    by_row, _, rows_s = run(b, turnover(tok, n), stream=True)
+    all_free(b, runner, free0)
+
+    assert set(wave) == set(range(n))
+    assert wave == by_row  # tokens, log-probabilities, finish reasons
+    assert rows == rows_s == n == on_device + on_host
+    # the waves of plain rows entered a window unread, the first into
+    # an empty machine and the later ones with windows in flight (but
+    # where a row near its pages' end left no room for a window to go
+    # out: those were armed behind no dispatch); the job that streams
+    # armed every row on the host first
+    assert on_device > n // 2 and tuple(joined() - j0) == (0, n)
+    assert sum(a["joined_device"] for a in spans) == on_device
+
+
+def test_a_first_token_that_stops_behind_its_window_is_counted_stale():
+    runner = runner_of("dense")
+    tok = tok_of(runner)
+    greedy, _, _ = run(batcher(runner), requests(tok, TEXTS[:4], temperature=0.0))
+    stop = greedy[1][1][0]  # row 1 ends on its first token
+    kw = dict(temperature=0.0)
+    b = batcher(runner, stop_ids=[stop])
+    j0 = joined()
+    out, waves, rows = run(b, requests(tok, TEXTS[:4], **kw))
+    stats = dict(run.ctx.stats)
+    assert tuple(joined() - j0) == (4, 0) and (waves, rows) == (1, 4)
+    by_row, _, _ = run(batcher(runner, stop_ids=[stop]),
+                       requests(tok, TEXTS[:4], **kw), stream=True)
+    assert out == by_row and out[1][:2] == ("stop", [])
+    # ended by the resolve of its own iteration; the windows that held
+    # it (two go out into an empty machine) lost its steps as stale,
+    # which the row-by-row order never dispatched
+    ended = [i for i, r in out.items() if r[1] == []]
+    assert stats["lost_stale"] == 2 * 4 * len(ended) > 0
+    assert run.ctx.stats.get("lost_stale", 0) == 0
+
+
+def test_a_wave_fetch_that_raises_behind_the_window_leaks_nothing(monkeypatch):
+    runner = runner_of("state-slot")
+    tok = tok_of(runner)
+    b = batcher(runner)
+    free0 = b.free_page_count
+    real, seen = jax.device_get, []
+
+    def failing(x):
+        if isinstance(x, list) and x and isinstance(x[0], tuple):
+            # the wave's fetch: by now the window holds its rows
+            seen.append(sum(s is not None and s.first_pending for s in b.slots))
+            raise RuntimeError("the device went away")
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", failing)
+    res = {}
+    ctx = JobCtx(job_id="lost", pending=requests(tok, TEXTS[:4]),
+                 on_result=lambda r: res.__setitem__(r.row_id, r))
+    with pytest.raises(RuntimeError, match="went away"):
+        b.run_multi([ctx], on_job_done=lambda c, o: None)
+    monkeypatch.undo()
+    # all four were seated and in the window; none was armed, every one
+    # was given up with its pages, slot and state, and no token of the
+    # window reached a result
+    assert seen == [4] and res == {} and ctx.n_slots == 0
+    all_free(b, runner, free0)
+    # the batcher is whole: the same rows run again on it
+    kw = dict(temperature=0.0)
+    again, _, _ = run(b, requests(tok, TEXTS[:4], **kw))
+    assert again == run(batcher(runner), requests(tok, TEXTS[:4], **kw))[0]
+    all_free(b, runner, free0)
+
+
+@pytest.mark.parametrize("what", ["cancel", "yield", "eviction"])
+def test_the_next_iteration_finds_no_pending_slot(what):
+    """A slot is pending from its prefill's dispatch to the resolve
+    behind its iteration's decode dispatch and across nothing else: what
+    releases or moves slots at the top of the next iteration (a cancel,
+    a yield) or inside its admission (an eviction) finds every row
+    armed."""
+    runner = runner_of("dense", 4)
+    tok = tok_of(runner)
+    b = batcher(runner)
+    b.ecfg = dataclasses.replace(b.ecfg, interactive_slots=1)
+    free0, j0 = b.free_page_count, joined()
+    looks, res = [], {}
+
+    def unarmed():
+        return sum(
+            s is not None and not s.prefilling
+            and (s.first_pending or not s.out_ids) for s in b.slots
+        )
+
+    def look():
+        looks.append((len(b._wave), unarmed()))
+        return len(looks)
+
+    def ctx(name, reqs, **kw):
+        return JobCtx(job_id=name, pending=list(reqs), on_result=lambda r:
+                      res.__setitem__((name, r.row_id), r.finish_reason), **kw)
+
+    rows = turnover(tok, 8, temperature=0.0)
+    if what == "eviction":
+        # every slot held for long: the chat has to take one
+        rows = requests(tok, TEXTS[:4], max_new_tokens=30, temperature=0.0)
+    ja = ctx("a", rows, should_cancel=(
+        (lambda: look() > 4) if what == "cancel" else (lambda: look() < 0)
+    ))
+    chat = ctx("c", requests(tok, TEXTS[5:6], max_new_tokens=4),
+               priority=-1, interactive=True, on_token=lambda *x: None)
+    later = [chat] if what == "eviction" else []
+    evict = b._evict_for_interactive
+
+    def evicting(c):
+        freed = evict(c)
+        if c.interactive:
+            # it resolved the wave in front of it before it chose
+            looks.append((len(b._wave), unarmed()))
+        return freed
+
+    b._evict_for_interactive = evicting
+    state = b.run_multi(
+        [ja], on_job_done=lambda c, o: None,
+        poll_new=lambda: later.pop(0) if len(looks) > 3 and later else None,
+        should_yield=(lambda: len(looks) > 4) if what == "yield" else None,
+    )
+    assert state == ("yielded" if what == "yield" else "completed")
+    assert len(looks) > 4 and set(looks) == {(0, 0)}
+    assert (joined() - j0)[0] >= 4  # the waves were held, and resolved
+    if what == "cancel":
+        assert set(res.values()) <= {"cancelled", "length", "stop"}
+        assert "cancelled" in res.values()
+    if what == "eviction":
+        assert ja.stats.get("preempted") == 1 and len(res) == 5
+    all_free(b, runner, free0)
+
+
+class _Anything:
+    """A constraint that allows every id and never completes."""
+
+    def __init__(self, vocab):
+        self.vocab = vocab
+
+    def allowed_tokens(self):
+        return np.ones((self.vocab,), bool)
+
+    def advance(self, token_id):
+        pass
+
+    def is_complete(self):
+        return False
+
+
+@pytest.mark.parametrize("how", ["plain", "constrained", "streams", "seeded",
+                                 "one-token"])
+def test_what_the_batch_shows_decides_where_the_wave_is_resolved(how):
+    runner = runner_of("dense")
+    tok = tok_of(runner)
+    reqs = requests(tok, TEXTS[:4], temperature=0.0)
+    if how == "constrained":
+        # one such row in the wave: the whole wave resolves first
+        reqs[2] = dataclasses.replace(
+            reqs[2], constraint=_Anything(runner.mcfg.vocab_size)
+        )
+    elif how == "seeded":
+        reqs[1] = dataclasses.replace(reqs[1], row_seed=7, temperature=0.8)
+    elif how == "one-token":
+        reqs[3] = dataclasses.replace(reqs[3], max_new_tokens=1)
+    b = batcher(runner)
+    order, resolve, build = [], b._resolve_wave, b._build_batch
+    b._resolve_wave = lambda *a: (order.append("resolve")
+                                  if b._wave else None, resolve(*a))[1]
+    b._build_batch = lambda act: (order.append("build"), build(act))[1]
+    j0 = joined()
+    run(b, reqs, stream=how == "streams")
+    on_device, on_host = joined() - j0
+    if how == "plain":
+        assert (on_device, on_host) == (4, 0)
+        assert order[:2] == ["build", "resolve"]
+    else:
+        assert (on_device, on_host) == (0, 4)
+        assert order[0] == "resolve"
+
+
+def test_a_plain_wave_into_a_constrained_batch_is_resolved_before_its_step():
+    """The wave's own rows are plain, the batch they join is not: the
+    build shows it (``_choose_path`` does not answer ``pipelined``), the
+    wave is resolved and the batch built anew."""
+    runner = runner_of("dense", 4)
+    tok = tok_of(runner)
+    fsm = dataclasses.replace(
+        requests(tok, TEXTS[:1], temperature=0.0, max_new_tokens=20)[0],
+        constraint=_Anything(runner.mcfg.vocab_size),
+    )
+    plain = [dataclasses.replace(r, row_id=1 + i) for i, r in enumerate(
+        requests(tok, TEXTS[1:3], temperature=0.0, max_new_tokens=6))]
+    want = run(batcher(runner), plain)[0]
+    res = {}
+
+    def ctx(name, reqs):
+        return JobCtx(job_id=name, pending=list(reqs), on_result=lambda r:
+                      res.__setitem__(r.row_id, (r.finish_reason,
+                                      list(r.token_ids), r.cumulative_logprob)))
+
+    b = batcher(runner)
+    later, polls, j0 = [ctx("plain", plain)], [], joined()
+
+    def poll_new():
+        polls.append(1)
+        return later.pop(0) if len(polls) > 2 and later else None
+
+    b.run_multi([ctx("fsm", [fsm])], on_job_done=lambda c, o: None,
+                poll_new=poll_new)
+    assert tuple(joined() - j0) == (0, 3)
+    assert {i: res[i] for i in want} == want and len(res[0][1]) == 20
+
+
+# -- one program, whatever the wave -------------------------------------------
+
+def test_the_merge_of_unread_first_tokens_compiles_once():
+    runner = runner_of("dense")
+    tok = tok_of(runner)
+    merge, sample = type(runner)._merge_first_jit, _admit_sample_jit
+    b = batcher(runner)
+    run(b, requests(tok, TEXTS[:2]))  # the warm wave
+    m0, s0, j0 = merge._cache_size(), sample._cache_size(), joined()
+    assert m0 >= 1
+    for n in (1, 3, 7):
+        run(b, requests(tok, [TEXTS[0]] * n))
+    # and with windows in flight: the window before's sample row in
+    # place of the host's own
+    assert tuple(joined() - j0) == (1 + 3 + 7, 0)
+    run(b, turnover(tok, 2 * B, temperature=0.0))
+    assert (joined() - j0)[0] > 1 + 3 + 7 + B
+    assert merge._cache_size() == m0 and sample._cache_size() == s0
+
+
+@pytest.mark.parametrize("traffic,preset", [
+    ("generate-long-output-jobs", "dense"),
+    ("generate-block-diffusion-jobs", "block"),
+])
+def test_the_warm_groups_reach_the_unread_path(traffic, preset):
+    """The warm-up of a cell is its traffic file's groups, one small job
+    after another, each admitted as one wave into an empty machine
+    (``perfbench/generators/batch_jobs.py`` ``warm``). As the files
+    stand that is enough: every group of plain rows enters its window
+    unread, which compiles the one program the path adds, and rows that
+    turn over with windows in flight compile nothing after it. (Prompt
+    lengths a twentieth of the file's: the shapes here are tiny.)"""
+    doc = json.loads((Path(__file__).parent.parent / "perfbench" / "traffic"
+                      / f"{traffic}.json").read_text())
+    warm = doc["warm"]
+    assert doc["output_schema"] is None and doc["sampling"]["temperature"] > 0
+    runner = runner_of(preset, 16)
+    tok = tok_of(runner)
+    merge = type(runner)._merge_first_jit
+    b = batcher(runner)
+    free0, j0, m00 = b.free_page_count, joined(), merge._cache_size()
+    rows = 0
+    for g in warm["groups"][:9]:  # 1, 1, 1, 1, 2, 4, 8, 16, 16 rows
+        texts = ["x" * max(int(g["chars"]) // 20, 1)] * int(g["rows"])
+        run(b, requests(tok, texts, max_new_tokens=int(warm["max_new_tokens"]),
+                        temperature=doc["sampling"]["temperature"]))
+        rows += len(texts)
+    assert tuple(joined() - j0) == (rows, 0)
+    m0, s0 = merge._cache_size(), _admit_sample_jit._cache_size()
+    # (a block model's rows have no first token to take from anywhere)
+    assert m0 - m00 == (0 if preset == "block" else 1)
+    compiled = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiled.append(kw.get("fun_name"))
+        if event.endswith("backend_compile_duration") else None
+    )
+    lengths = [int(g["chars"]) // 20 for g in warm["groups"][:4]]
+    texts = ["x" * max(lengths[i % 4], 1) for i in range(32)]
+    out, _, _ = run(b, [
+        dataclasses.replace(r, max_new_tokens=5 + 4 * (i % 3))
+        for i, r in enumerate(requests(tok, texts, temperature=0.7))
+    ])
+    assert len(out) == 32 and (joined() - j0)[0] > rows + 16
+    assert merge._cache_size() == m0
+    assert _admit_sample_jit._cache_size() == s0
+    touched = ("_merge_first_jit", "_admit_sample_jit", "_decode_block")
+    assert not [c for c in compiled if c and any(t in c for t in touched)]
+    all_free(b, runner, free0)
+
+
+# -- the lookahead holds while rows end, and wastes no window ------------------
+
+def test_a_row_at_its_end_does_not_hold_back_the_lookahead():
+    """A row's reservation covers every token it may commit, so what a
+    window writes past its pages lies past its cap, on the garbage page:
+    a row in its last window or two no longer keeps the NEXT window of
+    the whole batch from going out. Same tokens as at a depth of one."""
+    runner = runner_of("dense", 4)
+    tok = tok_of(runner)
+    reqs = lambda: [  # noqa: E731
+        dataclasses.replace(r, max_new_tokens=n) for r, n in zip(
+            requests(tok, TEXTS[:4] + TEXTS[5:7], temperature=0.0),
+            [6, 19, 10, 23, 7, 14],
+        )
+    ]
+    b = batcher(runner)
+    seen, dispatch = [], b._dispatch_pipelined
+
+    def spy(pipe, batch, proj, K):
+        PS = b.ecfg.kv_page_size
+        short = [
+            i for i in batch.active
+            if len(b.slots[i].pages) * PS - b.slots[i].pos - int(proj[i]) < K
+        ]
+        seen.append((len(pipe), len(short)))
+        return dispatch(pipe, batch, proj, K)
+
+    b._dispatch_pipelined = spy
+    free0 = b.free_page_count
+    deep, _, _ = run(b, reqs())
+    all_free(b, runner, free0)
+    # windows went out behind one in flight while a row had no K
+    # positions of its own left (the rule until PR 58 refused those)
+    assert any(depth == 1 and short for depth, short in seen)
+    one = batcher(runner)
+    one.ecfg = dataclasses.replace(one.ecfg, decode_lookahead=1)
+    flat, _, _ = run(one, reqs())
+    # (the log-probabilities are summed a window at a time: compared to
+    # the sum's own rounding)
+    assert set(deep) == set(flat) == set(range(6))
+    for i, (reason, tokens, logp) in deep.items():
+        assert (reason, tokens) == flat[i][:2]
+        assert logp == pytest.approx(flat[i][2], rel=1e-6)
+
+
+@pytest.mark.parametrize("preset", ["state-slot", "routed"])
+def test_a_row_past_its_pages_leaves_the_garbage_pages_state_alone(preset):
+    """A window that runs past a row's reserved pages commits through
+    page 0. Where a model's state is kept a slot a row, that must not
+    point page 0 at the row's slot: an empty batch slot and every other
+    row past its pages find their state through page 0, and would
+    advance the slot, or the row it goes to next (only the padding of
+    the next admission's bind had been putting it back). Rows that end
+    in staggered windows over fewer slots, the last waves leaving slots
+    empty: page 0 leads to the garbage slot after every dispatch, and
+    the tokens of a lookahead of two are those of a depth of one."""
+    runner = runner_of(preset)
+    tok = tok_of(runner)
+    lengths = [7, 15, 10, 23, 6, 14, 9, 18, 11, 5, 21, 8, 16, 12, 19, 13,
+               22, 17, 6, 20, 10, 14, 24, 9, 12, 7]
+    reqs = lambda: [  # noqa: E731
+        dataclasses.replace(r, max_new_tokens=n) for r, n in zip(
+            requests(tok, [TEXTS[i % 4] for i in range(len(lengths))],
+                     temperature=0.0),
+            lengths,
+        )
+    ]
+    b = batcher(runner)
+    past, of_page_0, dispatch = [], [], b._dispatch_pipelined
+
+    def spy(pipe, batch, proj, K):
+        room = b.ecfg.kv_page_size
+        past.append(any(
+            len(b.slots[i].pages) * room - b.slots[i].pos - int(proj[i]) < K
+            for i in batch.active
+        ))
+        dispatch(pipe, batch, proj, K)
+        if runner.cache.state_slot is not None:
+            of_page_0.append(int(runner.cache.state_slot[0]))
+
+    b._dispatch_pipelined = spy
+    free0 = b.free_page_count
+    deep, _, _ = run(b, reqs())
+    all_free(b, runner, free0)
+    assert sum(past) > 3 and not any(of_page_0)
+    one = batcher(runner)
+    one.ecfg = dataclasses.replace(one.ecfg, decode_lookahead=1)
+    flat, _, _ = run(one, reqs())
+    assert set(deep) == set(flat) == set(range(len(lengths)))
+    for i, (reason, tokens, logp) in deep.items():
+        assert (reason, tokens) == flat[i][:2], i
+        assert logp == pytest.approx(flat[i][2], rel=1e-5)
+
+
+def test_no_window_goes_out_that_no_row_can_use():
+    """Rows of 1 + K tokens (a warm-up job): the window in flight ends
+    every one of them, so the second of the lookahead is not
+    dispatched."""
+    runner = runner_of("dense")
+    tok = tok_of(runner)
+    K = runner.ecfg.decode_multi_step
+    b = batcher(runner)
+    calls, real = [], runner.decode_multi_async
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
+
+    runner.decode_multi_async = counted
+    a0 = ahead()
+    try:
+        out, _, _ = run(b, requests(tok, TEXTS[:4], max_new_tokens=1 + K))
+    finally:
+        del runner.decode_multi_async
+    assert all(len(r[1]) == 1 + K for r in out.values())
+    assert len(calls) == 1 and run.ctx.stats.get("lost_stale", 0) == 0
+    assert tuple(ahead() - a0) == (0, 1, 0)
+
+
+def test_the_sample_splits_the_session_key_as_split_alone_does():
+    logits = jax.random.normal(jax.random.PRNGKey(1), (4, 64))
+    key = jax.random.PRNGKey(3)
+    args = (np.full((4,), 0.8, np.float32), np.full((4,), 0.9, np.float32),
+            np.zeros((4,), np.int32), None, None)
+    after, sub = jax.random.split(key)
+    t0, lp0, _, same = _admit_sample_jit(logits, sub, *args)
+    t1, lp1, _, new = _admit_sample_jit(logits, key, *args, split=True)
+    assert same is None
+    assert np.array_equal(np.asarray(t0), np.asarray(t1))
+    assert np.array_equal(np.asarray(lp0), np.asarray(lp1))
+    assert np.array_equal(jax.random.key_data(new), jax.random.key_data(after))
+
+
+def test_rows_a_window_long_keep_the_depth_at_one_while_rows_wait():
+    """Eight rows of 1 + K tokens over four slots: every window in
+    flight ends all its rows, and rows wait for their slots, so no
+    window goes out ahead (its slots would be dead weight and the
+    waiting rows a window late): two full windows, nothing lost."""
+    runner = runner_of("dense", 4)
+    tok = tok_of(runner)
+    K = runner.ecfg.decode_multi_step
+    b = batcher(runner)
+    calls, real = [], runner.decode_multi_async
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
+
+    runner.decode_multi_async = counted
+    try:
+        out, _, _ = run(b, requests(tok, TEXTS[:4] + TEXTS[5:], max_new_tokens=1 + K,
+                                    temperature=0.0))
+    finally:
+        del runner.decode_multi_async
+    assert len(out) == 7 and all(len(r[1]) == 1 + K for r in out.values())
+    st = run.ctx.stats
+    assert len(calls) == 2 and st.get("lost_stale", 0) == 0
+    assert st["row_steps"] == 7 * K
+
+
+def test_half_a_batch_ending_while_rows_wait_holds_the_window_back():
+    """Two long rows and a queue of rows a window long over four slots:
+    while rows wait, half of the batch ends in every window in flight,
+    over ``_AHEAD_ENDING``, so the window ahead is held back
+    (``held_ending``) and each short row takes its slot in the next
+    window; once the queue is empty the long rows' windows go out ahead
+    (``sent``). Same tokens as at a depth of one."""
+    runner = runner_of("dense", 4)
+    tok = tok_of(runner)
+    K = runner.ecfg.decode_multi_step
+    lengths = [6 * K, 6 * K] + [1 + K] * 12
+    reqs = lambda: [  # noqa: E731
+        dataclasses.replace(r, max_new_tokens=n) for r, n in zip(
+            requests(tok, [TEXTS[i % 4] for i in range(len(lengths))],
+                     temperature=0.0),
+            lengths,
+        )
+    ]
+    b = batcher(runner)
+    a0 = ahead()
+    deep, _, _ = run(b, reqs())
+    sent, unused, ending = ahead() - a0
+    assert ending >= 2 and sent >= 1
+    one = batcher(runner)
+    one.ecfg = dataclasses.replace(one.ecfg, decode_lookahead=1)
+    flat, _, _ = run(one, reqs())
+    assert set(deep) == set(flat) == set(range(len(lengths)))
+    for i, (reason, tokens, logp) in deep.items():
+        assert (reason, tokens) == flat[i][:2]
+        assert logp == pytest.approx(flat[i][2], rel=1e-5)
+
+
+def test_a_second_job_of_the_same_shapes_lowers_nothing_again():
+    """On one device nothing is committed: the pool, the weights, every
+    result. An argument committed on its way into a window (the session
+    key, the merged last tokens) would commit the pool that window
+    returns, and every program that takes the pool would be lowered a
+    second time at the next job: seconds of a cell's set-up. A fresh
+    runner, two jobs of one shape: the second compiles nothing."""
+    mcfg = MODEL_CONFIGS[PRESETS["state-slot"]]
+    runner = ModelRunner(mcfg, _ecfg(decode_batch_size=4), num_pages=1 + 4 * MP)
+    tok = tok_of(runner)
+    b = batcher(runner)
+    compiled = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiled.append(kw.get("fun_name"))
+        if event.endswith("backend_compile_duration") else None
+    )
+    j0 = joined()
+    run(b, requests(tok, TEXTS[:1], temperature=0.7))
+    first = len(compiled)
+    run(b, requests(tok, TEXTS[:1], temperature=0.7))
+    assert first > 0 and compiled[first:] == []
+    assert tuple(joined() - j0) == (2, 0)
+    assert not runner.cache.state_slot.committed
