@@ -112,7 +112,7 @@ def main() -> None:
             runner.prefill(prompts[b], tables[b])
     for b in traced_rows:  # compile and warm every bucket outside the trace
         runner.prefill(prompts[b], tables[b])
-    runner.release_window_behind(tables, lens)
+    runner.pools.release_behind(tables, lens)
     temp, top_p = np.full((B,), 0.7, np.float32), np.full((B,), 0.95, np.float32)
     last = rng.integers(0, mcfg.vocab_size, B).astype(np.int32)
     past = lens.astype(np.int32)
@@ -141,7 +141,7 @@ def main() -> None:
             last, past, tables, jax.random.PRNGKey(i), temp, top_p, steps
         )
         last, past = np.asarray(toks[-1]), past + steps
-        runner.release_window_behind(tables, past)  # the scheduler's part
+        runner.pools.release_behind(tables, past)  # the scheduler's part
     allowed = np.zeros((B, mcfg.vocab_size), bool)
     allowed[:, :256] = True
     allowed = np.packbits(allowed, axis=1)  # as decode_step takes masks
@@ -154,7 +154,7 @@ def main() -> None:
             allowed=allowed,
         )
         last, past = np.asarray(toks), past + 1
-        runner.release_window_behind(tables, past)
+        runner.pools.release_behind(tables, past)
 
     window(0)
     window(1)

@@ -1018,21 +1018,23 @@ def engine_info(model: Optional[str]) -> None:
         f"{ecfg.max_pages_per_seq} decode_batch={ecfg.decode_batch_size}"
     )
     if model:
-        import jax.numpy as jnp
-
         from .engine.api import resolve_model
+        from .engine.kvcache import PAGE, pool_bytes
 
         _, m, _ = resolve_model(model)
-        width = jnp.dtype(ecfg.activation_dtype).itemsize
-        # what a runner's device_info reports once its pool exists
+        # what a runner's device_info reports once its pool exists: the
+        # description's bytes (engine/kvcache.py)
+        b = pool_bytes(m, ecfg, ecfg.activation_dtype)
+        per_token = sum(
+            b.entry_bytes(a.name) for a in b.arrays
+            if a.index == PAGE and a.name != "conv"
+        ) // ecfg.kv_page_size
         click.echo(
             f"model: {m.name} layers={m.num_layers} attn_layers="
             f"{m.num_attn_layers} latent_layers={m.num_latent_layers} "
             f"(the pool's layers: {m.num_pool_layers}) state_layers="
             f"{m.num_conv_layers + m.num_state_layers} kv_bytes_per_token="
-            f"{m.num_pool_layers * sum(m.pool_row_widths) * width} "
-            f"state_bytes_per_page="
-            f"{m.num_conv_layers * m.conv_state_len * m.hidden_size * width}"
+            f"{per_token} state_bytes_per_page={b.entry_bytes('conv')}"
         )
         if m.hc_mult > 1:
             click.echo(
@@ -1047,11 +1049,9 @@ def engine_info(model: Optional[str]) -> None:
                 f"{m.remasking} (generation by diffusion over blocks)"
             )
         if m.state_kind:
-            from .engine.kvcache import state_bytes_per_slot
-
             click.echo(
                 f"state_kind={m.state_kind} state_bytes_per_slot="
-                f"{state_bytes_per_slot(m, ecfg)} (a slot a live sequence)"
+                f"{b.slot_bytes} (a slot a live sequence)"
             )
 
 

@@ -1,166 +1,136 @@
-"""Paged KV cache.
+"""Paged KV cache: ONE description of a model's pools.
 
 TPU-native replacement for the server-side KV management the reference
 delegates to its remote fleet (SURVEY §2.3 row 1: "continuous-batching
-scheduler ... paged-KV decode attention"). Layout:
+scheduler ... paged-KV decode attention").
 
-- ``k_pages`` / ``v_pages``: ``[L, NP, PS, KVH*Dh]`` device arrays, L the
-  model's ATTENTION layers (``ModelConfig.num_attn_layers``: every layer
-  of a homogeneous model; a conv layer has no K/V). Page 0
-  is a reserved garbage page — padding tokens scatter there, so the write
-  path needs no masks or dynamic shapes. The KV-head and head-dim axes are
-  stored FUSED as one trailing axis: the Pallas decode kernel's
-  block-diagonal score/value matmuls contract over exactly that axis, and
-  Mosaic supports collapsing leading dims of a fetched page but not
-  merging (KVH, Dh) into the lane dim in-kernel — so the pool carries the
-  kernel-native layout and the small per-step tensors reshape outside.
-- ``page_table``: host-side ``numpy`` ``[B, MP]`` int32, passed into each
-  jitted step as a device argument. Pages are allocated/freed by a
-  host-side free list (allocation is control-plane work; the device only
-  ever sees dense int32 tables).
+**What a pool is.** A device array of ``KVCache`` with one axis that a
+host allocator hands out entry by entry. ``cache_layout`` builds THE
+description (``CacheLayout``) from the ``ModelConfig`` properties that
+say what a layer keeps (``pool_row_widths``, ``page_width``,
+``num_pool_layers``, ``num_window_layers``, ``num_conv_layers``,
+``state_rows`` ...): for every array its field name, what indexes it,
+its shape and dtype and whether it is sharded over ``"model"`` on its
+last axis or replicated; and from those, once, a page's and a slot's
+bytes on one device, what a row needs of each pool before it is admitted
+(``RowPools``) and what the pages support (``CacheLayout.refuses``). It
+is three parts: what a layout cannot hold is refused by name
+(``_refuse_layout``), every array (``pool_arrays``), the answers
+(``_support``). ``alloc_cache`` is a loop over it. Nobody else
+multiplies widths: the runner's byte arithmetic, ``device_info``,
+``sutro engine info``, the tier payloads' checks and the scheduler's
+fallbacks read the description; who asks for bytes alone reads
+``pool_bytes``, which refuses nothing.
 
-- ``conv``: ``[NP, L_conv * (K-1) * H]``, for a model with conv layers
-  (models/transformer.py ``conv_mixer``): a second kind of per-sequence
-  state, kept PER PAGE, page-major and flat (a row a page: reads and
-  writes are a gather and a scatter of whole rows on the major axis,
-  and no axis of 2 or 8 is padded up to a tile). Row ``p`` holds, layer
-  by layer, the state after the
-  last token its sequence wrote into page p; a sequence at position
-  ``start`` reads the page that holds position ``start - 1`` (zeros at
-  ``start`` 0). Whatever shares, moves, hibernates or frees a page
-  thereby carries the state with it: a whole-page prefix hit restores
-  the state exactly, a released page needs no reset (a new sequence
-  starts from zeros, and writes a page's state before it reads it).
+**What indexes an array.**
 
-- ``ssm`` ``[L_m, NS, N, I]`` and ``ssm_conv`` ``[NS, L_m * (K-1) * Cd]``,
-  for a model with layers that keep a MATRIX state (Mamba-2's
-  ``mamba_mixer`` or a delta rule's ``kda_mixer``; ``ModelConfig.
-  state_kind`` / ``state_rows`` N / ``state_inner`` I / ``state_conv_dim``
-  Cd are the one description both read): a THIRD kind of
-  per-sequence state, too large to keep a page (a matrix a head: tens
-  of MB a sequence), kept a SLOT a live sequence. Slot 0 is the garbage
-  slot, and page 0, the garbage page, leads to it and is never
-  re-pointed. ``state_slot`` ``[NP]`` int32 maps a page to a slot, on the
-  device: a sequence at position ``start`` finds its slot through the
-  page that holds position ``start - 1`` (its first page at ``start``
-  0), as ``read_conv_state`` finds its page, and ``write_kv`` re-points
-  the page that holds the chunk's last accepted token. The host
-  (``StateSlots``) only hands a slot to a sequence's first page and
-  takes it back with the row; a page's entry is always written before
-  it is read, so a freed page or slot needs no reset. The state axis N
-  is MAJOR and the channels I = heads x head_dim minor: a decode step
-  reduces every slot against its row's C over N with no cross-lane
-  work, reading the pool where it lies; no forward writes it.
+- a PAGE (``PAGE``): ``k_pages`` / ``v_pages`` ``[L, NP, PS, width]``,
+  the per-token rows of the layers that see the whole context. Page 0 is
+  a reserved garbage page: padding tokens scatter there, so the write
+  path needs no masks or dynamic shapes. The KV-head and head-dim axes
+  are stored FUSED as one trailing axis: the Pallas decode kernel's
+  block-diagonal products contract over exactly that axis, and Mosaic
+  collapses leading dims of a fetched page but does not merge (KVH, Dh)
+  into the lane dim in-kernel. A model of LATENT layers keeps one row a
+  token for both products and no ``v_pages``; under an indexer a second
+  row a token, ``ik_pages``, of its own width on the same page table (it
+  rides where V would: ``write_kv``'s second operand). ``k_scale`` /
+  ``v_scale`` are int8 K/V's per-token scales, shard-invariant (full-KD
+  amax) and so replicated. ``conv`` ``[NP, ...]`` is a conv layer's
+  state kept PER PAGE, page-major and flat: row ``p`` holds the state
+  after the last token its sequence wrote into page p, so whatever
+  shares, moves or frees a page carries the state with it and a freed
+  page needs no reset (a page's state is written before it is read).
+  Page ids are handed out by the page free list (``PageAllocator`` or
+  the native runtime); ``page_table`` is a host ``[B, MP]`` int32 passed
+  into each jitted step.
+- a WINDOW PAGE (``WINDOW_PAGE``): ``wk_pages`` / ``wv_pages``, the
+  K/V of layers that see a sliding window, a pool of their own that
+  holds a row's last W positions and no more. There is ONE space of page
+  ids (the full pool's) and a device map ``window_page`` ``[NP]``
+  (``WINDOW_OF_PAGE``) from a page id to the window page that holds the
+  same positions (0: none, the window pool's garbage page). The host
+  (``WindowPages``) binds a page id before the dispatch that writes it
+  and takes the window page back once its last position is older than
+  the row's COMMITTED length less W: no reader starts before the page of
+  ``past_len - W + 1`` (``ops/pages.first_live_page``). A token written
+  through an unbound page id lands on the garbage page. With as many
+  window pages as pages the map is the identity and nothing is bound or
+  released (the trivial setting: a runner given its pool's size, a
+  mesh).
+- a SLOT (``SLOT``): ``ssm`` ``[L, NS, N, I]`` and ``ssm_conv``, a
+  MATRIX state a sequence (Mamba-2's or a delta rule's:
+  ``ModelConfig.state_kind``), too large to keep a page. Slot 0 is the
+  garbage slot; ``state_slot`` ``[NP]`` (``SLOT_OF_PAGE``) maps a page to
+  a slot on the device: a sequence at ``start`` finds its slot through
+  the page that holds ``start - 1``, and ``write_state`` re-points the
+  page of the chunk's last accepted token. The host (``StateSlots``)
+  hands a slot to a sequence's first page and takes it back with the
+  row; an entry is written before it is read, so nothing is reset. The
+  state axis N is major and the channels minor: a decode step reduces
+  every slot against its row's C with no cross-lane work.
 
-- ``wk_pages`` / ``wv_pages``: ``[L_win, NP_w, PS, KVH*Dh]``, for a
-  model whose attention layers are of two kinds (``layer_types`` with
-  "swa" layers: a sliding window of ``W`` positions): K/V a POOL A
-  KIND. A window layer can see a row's last W positions and nothing
-  older, so its pool holds those and no more: about W / PS pages a
-  sequence whatever its length, where the full layers' pool holds
-  the whole context. There is ONE space of page ids (the full pool's,
-  what the allocators and every page table speak) and a device map
-  ``window_page`` ``[NP]`` int32 from a page id to the window pool's
-  page that holds the same positions of the window layers (0: none;
-  page 0 of the window pool is its garbage page). Every program looks
-  its rows' window pages up itself (``window_table``), as it finds a
-  state slot. The host (``WindowPages``, owned by the runner) binds a
-  page id to a window page before the dispatch that writes it and
-  takes the window page back once its last position is older than
-  the row's committed length less W: from then on no query reads it
-  (every reader starts at the page of position ``past_len - W + 1``:
-  ops/attention.py ``live_pages``, the paged kernel's ``first_page``).
-  A token written through an unbound page id lands on the garbage
-  page: a prefill binds only what the window still holds at its end.
-  With ``NP_w == NP`` the map is the identity and nothing is ever
-  bound or released (the trivial setting: a runner given its pool's
-  size, a mesh).
-
-- a model of LATENT layers (``ModelConfig.num_latent_layers``:
-  models/transformer.py ``mla_mixer``) keeps ONE pool and no V pool:
-  ``k_pages`` ``[L_mla, NP, PS, page_width]`` holds, a token a row, the
-  layer's normed latent values followed by the rotated key all heads
-  share, and ``v_pages`` is None. Every head reads that row for both
-  products (ops/attention.py ``latent_attention``), so nothing else of
-  a token is kept. ``ModelConfig.page_width`` is THE place that says how
-  wide a page's rows are (``num_kv_heads * head_dim`` for every other
-  model): the pools' shapes, a page's bytes (the runner's
-  ``_page_bytes_per_device``, ``_pool_margin_pages``) and the tier
-  payloads read it there. The page table, the allocators and the
-  garbage page are the same; int8 K/V (``kv_quantize``), a mesh and
-  the tiers' payloads refuse such a pool by name. Under ``use_pallas``
-  the paged decode kernel fetches such a page ONCE for both products
-  (``paged_decode_attention(v_pages=None)``) and the in-place write
-  lands one slab a segment (``pallas_kv.row_write_pallas``).
-
-- latent layers with an INDEXER (``ModelConfig.index_topk``: learned
-  sparse attention, ops/sparse_attention.py) keep a SECOND row a token:
-  the index key, ``ik_pages`` ``[L_mla, NP, PS, index_head_dim]``, a pool
-  of its own width on the SAME page table, allocators and garbage page.
-  ``ModelConfig.pool_row_widths`` is the place that says what a token
-  of a layer keeps in each pool. A chunk's index keys ride where V would
-  (``forward``'s ``(k, v)`` pair, the fused window's second buffer,
-  ``paged_past``'s second slot), so every caller that commits a chunk's
-  K/V by ``write_kv(cache, k, v, ...)`` commits them too: a second call
-  of the one-pool write.
+**How a family adds a kind of state.** It says what a layer keeps in
+``models/configs.py``, computes it in ``models/transformer.py`` and its
+kernel, and here: a field of ``KVCache``, its ``PoolArray`` in
+``pool_arrays`` (any refusal about layout in ``_refuse_layout``), its
+commit in ``write_kv``, and, where the host must hand entries out, an
+allocator behind ``RowPools``' verbs and its answers in ``_support``.
+Not the scheduler, not the runner's bytes, not the tier code.
 
 ``write_kv`` lands a chunk's K/V into pages (Pallas in-place RMW kernel
-on TPU, XLA scatter fallback elsewhere); ``gather_kv_layer`` produces one
-layer's contiguous ``[B, CTX, KVH, Dh]`` view for a chunk's attention
-over a paged past by ONE gather on ``[layer, page_table]`` of the
-stacked pool (``gather_pages``; a decode step keeps what that returns
-fused: ops/attention.py). No reader is ever handed a per-layer
-``[NP, PS, KD]`` slice: the stack is a constant of the layer scan and
-the layer is an index (models/transformer.py). Both are pure functions over pytrees,
-jitted as part of the runner's step functions.
+on TPU, XLA scatter fallback elsewhere). The readers' gathers are pure
+functions of arrays and live in ``ops/pages.py`` (re-exported here). No
+reader is ever handed a per-layer ``[NP, PS, KD]`` slice: the stack is a
+constant of the layer scan and the layer is an index
+(models/transformer.py).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Tuple
+from typing import Any, Callable, List, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..models.configs import ModelConfig
 from ..models.transformer import (
     MixedChunk, StatePast, chunk_tokens, columns_after, group_channels,
     over_state, per_channel,
 )
 from ..ops.lowering import part
+from ..ops.pages import (  # noqa: F401  (re-exported: tests, perfbench)
+    first_live_page, gather_kv_layer, gather_pages, window_span_pages,
+)
 from .config import EngineConfig
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
-    k_pages: jax.Array  # [L, NP, PS, KVH*Dh] — bf16, or int8 quantized
-    # [L, NP, PS, KVH*Dh]; None for a model of latent layers, whose
-    # ``k_pages`` rows [.., page_width] serve both products
-    v_pages: "jax.Array | None"
+    """The pools (module docstring; ``cache_layout`` says each array's
+    shape). None: the model keeps no such array."""
+
+    k_pages: jax.Array
+    v_pages: "jax.Array | None" = None
     # int8 KV mode (EngineConfig.kv_quantize): per-TOKEN dequant scales,
     # amax/127 over the fused KD axis. Per-token (not per-page) so a
     # decode append quantizes exactly once — no page rescale, no
     # clipping against a stale amax. Overhead: 4 bytes per token per
     # layer vs KD int8 bytes (<1% at KD=1024).
-    k_scale: "jax.Array | None" = None  # [L, NP, PS] f32
+    k_scale: "jax.Array | None" = None
     v_scale: "jax.Array | None" = None
-    # conv layers' per-sequence state, per page (module docstring)
-    conv: "jax.Array | None" = None     # [NP, L_conv * (K-1) * H]
-    # mamba layers' per-sequence state, per SLOT, and the page -> slot map
-    ssm: "jax.Array | None" = None         # [L_m, NS, N, I]
-    ssm_conv: "jax.Array | None" = None    # [NS, L_m * (K-1) * Cd]
-    state_slot: "jax.Array | None" = None  # [NP] int32
-    # window attention layers' K/V, a pool of their own, and the page id
-    # -> window page map (module docstring)
-    wk_pages: "jax.Array | None" = None    # [L_win, NP_w, PS, KVH*Dh]
+    conv: "jax.Array | None" = None
+    ssm: "jax.Array | None" = None
+    ssm_conv: "jax.Array | None" = None
+    state_slot: "jax.Array | None" = None
+    wk_pages: "jax.Array | None" = None
     wv_pages: "jax.Array | None" = None
-    window_page: "jax.Array | None" = None  # [NP] int32
-    # latent layers' index keys (an indexer: module docstring), beside
-    # ``k_pages``' latent rows on the same page table
-    ik_pages: "jax.Array | None" = None    # [L_mla, NP, PS, index_head_dim]
+    window_page: "jax.Array | None" = None
+    ik_pages: "jax.Array | None" = None
 
     @property
     def page_size(self) -> int:
@@ -187,141 +157,403 @@ class KVCache:
         return 0 if self.ssm is None else self.ssm.shape[1]
 
 
-def alloc_cache(
-    mcfg: ModelConfig, ecfg: EngineConfig, num_pages: int,
-    dtype: jnp.dtype = jnp.bfloat16,
-    sharding: "jax.sharding.NamedSharding | None" = None,
-    window_pages: "int | None" = None,
-) -> KVCache:
-    """Zeroed page pools; for a model with window attention layers a
-    pool a kind, the window layers' of ``window_pages`` pages (None: as
-    many as the full pool, under the identity map: nothing to bind or
-    release); for a model with mamba layers also the state
-    pools: beside the garbage slot a slot a row of the decode batch, and
-    never more than there are pages, since a sequence holds at least
-    one (``default_state_slots``). With ``sharding`` (parallel/sharding.py
-    ``cache_shardings``) every pool is allocated sharded — never whole
-    on one device first; the int8 per-token scale pools are
-    shard-invariant (full-KD amax) and replicate across that mesh."""
-    # what a token keeps in each pool: ``ModelConfig.pool_row_widths``
-    widths = mcfg.pool_row_widths
-    shape = (
-        mcfg.num_pool_layers, num_pages, ecfg.kv_page_size, widths[0],
-    )
-    if mcfg.num_latent_layers and getattr(ecfg, "kv_quantize", None):
-        raise NotImplementedError(
-            f"{mcfg.name} keeps a latent row a token: the latent pool has "
-            "no int8 scale pools (kv_quantize)"
+#: what indexes an array of the cache (module docstring): the entries a
+#: host allocator hands out, and the two device maps from a page id
+PAGE, WINDOW_PAGE, SLOT = "page", "window_page", "slot"
+SLOT_OF_PAGE, WINDOW_OF_PAGE = "slot_of_page", "window_of_page"
+
+
+@dataclasses.dataclass(frozen=True)
+class PoolArray:
+    """One array of ``KVCache``."""
+
+    name: str                # the field
+    index: str               # PAGE | WINDOW_PAGE | SLOT, or a map's name
+    axis: int                # the axis the index runs over
+    shape: Tuple[int, ...]
+    dtype: Any
+    sharded: bool = False    # over "model" on its last axis; else replicated
+    #: values of a row in use (a latent row is padded to whole lane
+    #: tiles: ``ModelConfig.page_width``); 0: all of them
+    used: int = 0
+    identity: bool = False   # a map born as the identity, not zeros
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheLayout:
+    """THE description of a model's pools (``cache_layout``)."""
+
+    arrays: Tuple[PoolArray, ...]
+    #: the sharding the sharded arrays carry (parallel/ says HOW a pool
+    #: is partitioned: ``cache_shardings``, ``pp_cache_sharding``); None
+    #: on one device
+    sharding: Any = None
+    page_size: int = 0
+    num_pages: int = 0
+    #: pages of the window pool, its garbage page included (0: one pool)
+    window_pages: int = 0
+    #: slots of the state pool beside the garbage slot (0: no such state)
+    state_slots: int = 0
+    #: a window layer's positions, and the most window pages a row holds
+    #: at once (``row_window_span``)
+    window: int = 0
+    window_span: int = 0
+    #: positions a block of the model holds (``ModelConfig.block_length``;
+    #: 1: a causal model): a block's K/V is committed whole
+    block_length: int = 1
+    #: a forward leaves per-sequence state beside K/V (conv or matrix):
+    #: a verify dispatch's caller commits it at the accepted length
+    has_state: bool = False
+    state_kind: Optional[str] = None
+    #: question -> why not (``refuses``)
+    refusals: Mapping[str, str] = dataclasses.field(default_factory=dict)
+
+    @property
+    def binds_window(self) -> bool:
+        """The window pool has a size of its own: page ids are bound to
+        window pages and released (False at the trivial setting)."""
+        return 0 < self.window_pages != self.num_pages
+
+    def array(self, name: str) -> Optional[PoolArray]:
+        return next((a for a in self.arrays if a.name == name), None)
+
+    def _device_shape(self, a: PoolArray) -> Tuple[int, ...]:
+        if a.sharded and self.sharding is not None:
+            return tuple(self.sharding.shard_shape(a.shape))
+        return a.shape
+
+    def entry_bytes(self, name: str, used: bool = False) -> int:
+        """Bytes of ONE entry (page, window page, slot) of array ``name``
+        as it sits on one device; ``used``: of the values in use alone.
+        0 for a model that keeps no such array. THE place a page's bytes
+        are multiplied out."""
+        a = self.array(name)
+        if a is None:
+            return 0
+        n = int(np.prod(self._device_shape(a))) // a.shape[a.axis]
+        if used and a.used:
+            n = n // a.shape[-1] * a.used
+        return n * np.dtype(a.dtype).itemsize
+
+    def bytes_per(self, index: str) -> int:
+        """One entry of every array ``index`` indexes, on one device."""
+        return sum(
+            self.entry_bytes(a.name) for a in self.arrays if a.index == index
         )
-    if mcfg.num_latent_layers and sharding is not None:
-        raise NotImplementedError(
-            f"{mcfg.name} keeps a latent row a token: every head reads the "
-            "whole row, so the latent pool does not shard over a mesh"
-        )
-    rep = None
-    if sharding is not None:
-        rep = jax.sharding.NamedSharding(
-            sharding.mesh, jax.sharding.PartitionSpec()
-        )
-    conv = None
-    if mcfg.num_conv_layers:
-        # in the activation dtype (what the mixer computes g in);
-        # replicated under a mesh, like the conv weights
-        conv = jnp.zeros(
-            (
-                num_pages,
-                mcfg.num_conv_layers * mcfg.conv_state_len
-                * mcfg.hidden_size,
-            ),
-            jnp.dtype(ecfg.activation_dtype), device=rep,
-        )
-    state = {}
-    if mcfg.num_state_layers:
-        if mcfg.state_kind == "kda" and (
-            getattr(ecfg, "kv_quantize", None) or sharding is not None
-        ):
-            raise NotImplementedError(
-                f"{mcfg.name} keeps a delta-rule state a slot: "
-                + ("int8 K/V beside it (kv_quantize)"
-                   if getattr(ecfg, "kv_quantize", None) else
-                   "the slot pool under a mesh")
-                + " is not built"
+
+    @property
+    def page_bytes(self) -> int:
+        """One page of the full pool on one device: K and V of every
+        layer that sees the whole context (or a latent row, and an index
+        key), int8 scales, the page's conv state."""
+        return self.bytes_per(PAGE)
+
+    @property
+    def window_page_bytes(self) -> int:
+        return self.bytes_per(WINDOW_PAGE)
+
+    @property
+    def slot_bytes(self) -> int:
+        return self.bytes_per(SLOT)
+
+    @property
+    def margin_row_bytes(self) -> int:
+        """One layer's rows of one page of ``k_pages`` on one device:
+        what ``runner._pool_margin_pages`` counts its chunk in."""
+        a = self.array("k_pages")
+        return int(np.prod(self._device_shape(a)[2:])) * np.dtype(
+            a.dtype
+        ).itemsize
+
+    def refuses(self, what: str) -> Optional[str]:
+        """None where the pages support ``what``, else why not:
+
+        - ``"share"``: rows SHARE a prefix's pages (a job's shared
+          prefix, the prefix store). Not a model that keeps its state a
+          slot a sequence (no page holds the state at the end of the
+          shared pages; only the row that wrote them has it), nor one
+          whose window pages are bound and released (a shared page's
+          window page would go with the first row to slide past it; at
+          the trivial setting a page id carries both kinds and prefixes
+          work), nor a latent pool (every later row would take the
+          absorbed form over pages no test or measurement has held yet),
+          nor a model that generates by blocks (a row would be cut at a
+          page's edge inside blocks whose K/V a later block's queries
+          never saw committed that way). The answer is the reason label
+          of ``sutro_state_fallback_prefill_tokens_total``.
+        - ``"tiers"``: pages MOVE to the tiers and a row hibernates. The
+          tiers' payload is a K and a V of one width a page: no slot, no
+          second pool (window layers at ANY setting), no latent row. The
+          answer is that counter's label too.
+        - ``"native"``: the C++ host core admits (native/runtime.cpp).
+        - ``"read_pages"`` / ``"write_pages"``: a payload of K and V of
+          one width holds / restores this model's page (the runner's
+          tier verbs raise the answer)."""
+        return self.refusals.get(what)
+
+    def alloc(self) -> KVCache:
+        """Zeroed pools. With a sharding every pool is born sharded or
+        replicated over that mesh, never whole on one device first."""
+        rep = None
+        if self.sharding is not None:
+            rep = jax.sharding.NamedSharding(
+                self.sharding.mesh, jax.sharding.PartitionSpec()
             )
-        state_slots = default_state_slots(ecfg, num_pages)
-        # stored in the activation dtype, updated in float32; replicated
-        # under a mesh, like the conv state
-        act = jnp.dtype(ecfg.activation_dtype)
-        state = dict(
-            ssm=jnp.zeros(
-                (mcfg.num_state_layers, 1 + state_slots, mcfg.state_rows,
-                 mcfg.state_inner), act, device=rep,
-            ),
-            ssm_conv=jnp.zeros(
-                (1 + state_slots, mcfg.num_state_layers
-                 * mcfg.state_conv_len * mcfg.state_conv_dim),
-                act, device=rep,
-            ),
-            state_slot=jnp.zeros((num_pages,), jnp.int32, device=rep),
-        )
-    if mcfg.num_window_layers:
-        if getattr(ecfg, "kv_quantize", None):
-            raise NotImplementedError(
-                f"{mcfg.name} keeps K/V a pool a kind: the window pool "
-                "has no int8 scale pools (kv_quantize)"
-            )
-        same = window_pages is None or window_pages == num_pages
-        wshape = (mcfg.num_window_layers,
-                  num_pages if same else window_pages) + shape[2:]
-        state.update(
-            wk_pages=jnp.zeros(wshape, dtype, device=sharding),
-            wv_pages=jnp.zeros(wshape, dtype, device=sharding),
-            window_page=(
-                jnp.arange(num_pages, dtype=jnp.int32, device=rep) if same
-                else jnp.zeros((num_pages,), jnp.int32, device=rep)
-            ),
-        )
-    if getattr(ecfg, "kv_quantize", None) == "int8":
-        return KVCache(
-            conv=conv, **state,
-            k_pages=jnp.zeros(shape, jnp.int8, device=sharding),
-            v_pages=jnp.zeros(shape, jnp.int8, device=sharding),
-            k_scale=jnp.zeros(shape[:3], jnp.float32, device=rep),
-            v_scale=jnp.zeros(shape[:3], jnp.float32, device=rep),
-        )
-    if getattr(ecfg, "kv_quantize", None):
-        raise ValueError(
-            f"Unknown kv_quantize mode {ecfg.kv_quantize!r} (only 'int8')"
-        )
-    return KVCache(
-        k_pages=jnp.zeros(shape, dtype, device=sharding),
-        v_pages=(
-            jnp.zeros(shape, dtype, device=sharding)
-            if mcfg.pool_has_values else None
-        ),
-        ik_pages=(
-            jnp.zeros(shape[:3] + (widths[1],), dtype)
-            if mcfg.index_key_width else None
-        ),
-        conv=conv, **state,
-    )
+
+        def born(a: PoolArray) -> jax.Array:
+            device = self.sharding if a.sharded else rep
+            if a.identity:
+                return jnp.arange(a.shape[0], dtype=a.dtype, device=device)
+            return jnp.zeros(a.shape, a.dtype, device=device)
+
+        return KVCache(**{a.name: born(a) for a in self.arrays})
 
 
 def default_state_slots(ecfg: EngineConfig, num_pages: int) -> int:
     return max(min(ecfg.decode_batch_size, num_pages - 1), 1)
 
 
+def row_window_span(mcfg: ModelConfig, ecfg: EngineConfig) -> int:
+    """The most window pages a row holds at once: its window and the
+    tokens in flight (the fused windows dispatched past what the host
+    has seen committed, or a verify chunk's inputs), never more than a
+    row's table. 0 for a model with no window layers."""
+    if not mcfg.num_window_layers:
+        return 0
+    in_flight = max(
+        (ecfg.decode_lookahead + 1) * ecfg.decode_multi_step,
+        ecfg.constrain_fastforward + 1,
+    )
+    return min(
+        ecfg.max_pages_per_seq,
+        window_span_pages(mcfg.sliding_window, in_flight, ecfg.kv_page_size),
+    )
+
+
+def _refuse_layout(mcfg: ModelConfig, ecfg: EngineConfig, sharding) -> None:
+    """What a layout cannot hold is refused here, once, by name."""
+    quant = ecfg.kv_quantize
+    latent = mcfg.num_latent_layers > 0
+    if latent and quant:
+        raise NotImplementedError(
+            f"{mcfg.name} keeps a latent row a token: the latent pool has "
+            "no int8 scale pools (kv_quantize)"
+        )
+    if latent and sharding is not None:
+        raise NotImplementedError(
+            f"{mcfg.name} keeps a latent row a token: every head reads the "
+            "whole row, so the latent pool does not shard over a mesh"
+        )
+    if (
+        mcfg.num_state_layers and mcfg.state_kind == "kda"
+        and (quant or sharding is not None)
+    ):
+        raise NotImplementedError(
+            f"{mcfg.name} keeps a delta-rule state a slot: "
+            + ("int8 K/V beside it (kv_quantize)" if quant else
+               "the slot pool under a mesh")
+            + " is not built"
+        )
+    if mcfg.num_window_layers and quant:
+        raise NotImplementedError(
+            f"{mcfg.name} keeps K/V a pool a kind: the window pool "
+            "has no int8 scale pools (kv_quantize)"
+        )
+    if quant and quant != "int8":
+        raise ValueError(
+            f"Unknown kv_quantize mode {quant!r} (only 'int8')"
+        )
+
+
+def pool_arrays(
+    mcfg: ModelConfig, ecfg: EngineConfig, num_pages: int = 2,
+    dtype: jnp.dtype = jnp.bfloat16, window_pages: "int | None" = None,
+) -> Tuple[PoolArray, ...]:
+    """Every array of ``mcfg``'s ``KVCache`` at ``num_pages`` pages (and
+    ``window_pages`` of the window layers' pool; None: as many, under
+    the identity map). It refuses nothing, and an entry's bytes do not
+    depend on the sizes: a reader of bytes alone (``pool_bytes``) leaves
+    them out."""
+    PS = ecfg.kv_page_size
+    # what a token keeps in each pool: ``ModelConfig.pool_row_widths``
+    widths = mcfg.pool_row_widths
+    act = jnp.dtype(ecfg.activation_dtype)
+    kv = jnp.dtype(jnp.int8 if ecfg.kv_quantize else dtype)
+    pool = (mcfg.num_pool_layers, num_pages, PS)
+    arrays = [PoolArray(
+        "k_pages", PAGE, 1, pool + (widths[0],), kv, sharded=True,
+        used=mcfg.latent_width if mcfg.num_latent_layers else 0,
+    )]
+    if mcfg.pool_has_values:
+        arrays.append(PoolArray(
+            "v_pages", PAGE, 1, pool + (widths[0],), kv, sharded=True
+        ))
+    if ecfg.kv_quantize:
+        f32 = jnp.dtype(jnp.float32)
+        arrays += [
+            PoolArray("k_scale", PAGE, 1, pool, f32),
+            PoolArray("v_scale", PAGE, 1, pool, f32),
+        ]
+    if mcfg.index_key_width:
+        arrays.append(PoolArray(
+            "ik_pages", PAGE, 1, pool + (widths[1],), jnp.dtype(dtype)
+        ))
+    if mcfg.num_conv_layers:
+        # in the activation dtype (what the mixer computes g in);
+        # replicated under a mesh, like the conv weights
+        arrays.append(PoolArray("conv", PAGE, 0, (
+            num_pages,
+            mcfg.num_conv_layers * mcfg.conv_state_len * mcfg.hidden_size,
+        ), act))
+    if mcfg.num_state_layers:
+        # beside the garbage slot a slot a row of the decode batch, and
+        # never more than there are pages, since a sequence holds at
+        # least one. Stored in the activation dtype, updated in float32;
+        # replicated under a mesh, like the conv state
+        L, NS = mcfg.num_state_layers, 1 + default_state_slots(ecfg, num_pages)
+        arrays += [
+            PoolArray("ssm", SLOT, 1, (
+                L, NS, mcfg.state_rows, mcfg.state_inner,
+            ), act),
+            PoolArray("ssm_conv", SLOT, 0, (
+                NS, L * mcfg.state_conv_len * mcfg.state_conv_dim,
+            ), act),
+            PoolArray(
+                "state_slot", SLOT_OF_PAGE, 0, (num_pages,),
+                jnp.dtype(jnp.int32),
+            ),
+        ]
+    if mcfg.num_window_layers:
+        same = window_pages is None or window_pages == num_pages
+        wshape = (
+            mcfg.num_window_layers, num_pages if same else window_pages,
+            PS, widths[0],
+        )
+        arrays += [
+            PoolArray("wk_pages", WINDOW_PAGE, 1, wshape, kv, sharded=True),
+            PoolArray("wv_pages", WINDOW_PAGE, 1, wshape, kv, sharded=True),
+            PoolArray(
+                "window_page", WINDOW_OF_PAGE, 0, (num_pages,),
+                jnp.dtype(jnp.int32), identity=same,
+            ),
+        ]
+    return tuple(arrays)
+
+
+def pool_bytes(
+    mcfg: ModelConfig, ecfg: EngineConfig,
+    dtype: jnp.dtype = jnp.bfloat16, sharding=None,
+) -> CacheLayout:
+    """The description's byte functions alone (``entry_bytes``,
+    ``page_bytes``, ``slot_bytes`` ...): no sizes, no answers, and
+    nothing refused. For who only asks what an entry weighs."""
+    return CacheLayout(
+        arrays=pool_arrays(mcfg, ecfg, dtype=dtype), sharding=sharding
+    )
+
+
+def _support(mcfg: ModelConfig, bound: bool) -> Mapping[str, str]:
+    """What the pages do NOT support, question -> why
+    (``CacheLayout.refuses``); ``bound``: the window pool has a size of
+    its own. The first reason that applies: a model of several kinds
+    reports ONE."""
+    slots = mcfg.num_state_layers > 0
+    windows = mcfg.num_window_layers > 0
+    latent = mcfg.num_latent_layers > 0
+    blocks = mcfg.block_length > 1
+    ladders = {
+        "share": (
+            (slots, "prefix_without_state_snapshot"),
+            (bound, "prefix_without_window_pages"),
+            (latent, "prefix_on_latent_pool"),
+            (blocks, "prefix_on_block_model"),
+        ),
+        "tiers": (
+            (slots, "hibernate_without_slot_state"),
+            (latent, "hibernate_on_latent_pool"),
+            (blocks, "hibernate_on_block_model"),
+            (windows, "hibernate_without_window_pages"),
+        ),
+        "native": (
+            (blocks, "the C++ core counts one token a row a step, and a "
+                     "window of a block model yields whole blocks"),
+        ),
+        "read_pages": (
+            (latent, "pages of a latent pool do not move to the tiers (the "
+                     "payload is a K and a V of one width)"),
+            # a page id's window page may be gone, and the tiers' payload
+            # has no place for a second pool: the caller prefills again
+            (windows, "pages of a model that keeps K/V a pool a kind do "
+                      "not move to the tiers (no window pages in the "
+                      "payload)"),
+        ),
+        "write_pages": (
+            (latent, "a K/V page payload cannot restore the rows of a "
+                     "latent pool"),
+            (windows, "pages cannot restore the window pages of a model "
+                      "that keeps K/V a pool a kind"),
+            # a slot's state is in no page: the caller prefills again
+            (slots, "pages cannot restore the state of a model that keeps "
+                    "it a slot a sequence"),
+        ),
+    }
+    found = {
+        what: next((why for applies, why in ladder if applies), None)
+        for what, ladder in ladders.items()
+    }
+    return {what: why for what, why in found.items() if why}
+
+
+def cache_layout(
+    mcfg: ModelConfig, ecfg: EngineConfig, num_pages: int,
+    dtype: jnp.dtype = jnp.bfloat16,
+    sharding: "jax.sharding.NamedSharding | None" = None,
+    window_pages: "int | None" = None,
+) -> CacheLayout:
+    """The description of ``mcfg``'s pools at ``num_pages`` pages: what
+    a layout cannot hold refused by name (``_refuse_layout``), every
+    array (``pool_arrays``), and what the pages support (``_support``).
+    ``sharding`` is what the K/V pools carry (the rest replicate over
+    its mesh)."""
+    _refuse_layout(mcfg, ecfg, sharding)
+    arrays = pool_arrays(mcfg, ecfg, num_pages, dtype, window_pages)
+    shape = {a.name: a.shape for a in arrays}
+    slots, windows = "ssm" in shape, "wk_pages" in shape
+    in_window = shape["wk_pages"][1] if windows else 0
+    return CacheLayout(
+        arrays=arrays, sharding=sharding, page_size=ecfg.kv_page_size,
+        num_pages=num_pages, window_pages=in_window,
+        state_slots=shape["ssm"][1] - 1 if slots else 0,
+        window=mcfg.sliding_window if windows else 0,
+        window_span=row_window_span(mcfg, ecfg),
+        block_length=mcfg.block_length,
+        has_state=bool(mcfg.num_conv_layers or slots),
+        state_kind=mcfg.state_kind if slots else None,
+        refusals=_support(mcfg, bound=0 < in_window != num_pages),
+    )
+
+
+#: what the scheduler takes for a runner that offers no description (a
+#: test's stub): a single K/V pool that supports everything
+ONE_POOL = CacheLayout(arrays=())
+
+
+def alloc_cache(
+    mcfg: ModelConfig, ecfg: EngineConfig, num_pages: int,
+    dtype: jnp.dtype = jnp.bfloat16,
+    sharding: "jax.sharding.NamedSharding | None" = None,
+    window_pages: "int | None" = None,
+) -> KVCache:
+    """Zeroed pools of ``cache_layout``'s description."""
+    return cache_layout(
+        mcfg, ecfg, num_pages, dtype, sharding, window_pages
+    ).alloc()
+
+
 def state_bytes_per_slot(mcfg: ModelConfig, ecfg: EngineConfig) -> int:
     """Bytes of matrix state one sequence keeps (its slot of both
-    pools), from the one description of a state layer
-    (``ModelConfig.state_rows`` ...): 0 for a model that keeps none."""
-    per_layer = (
-        mcfg.state_rows * mcfg.state_inner
-        + mcfg.state_conv_len * mcfg.state_conv_dim
-    )
-    return (
-        mcfg.num_state_layers * per_layer
-        * jnp.dtype(ecfg.activation_dtype).itemsize
-    )
+    pools): 0 for a model that keeps none."""
+    return pool_bytes(mcfg, ecfg).slot_bytes
 
 
 class StateSlots:
@@ -379,21 +611,6 @@ def window_table(cache: KVCache, page_table: jax.Array) -> "jax.Array | None":
     if cache.window_page is None:
         return None
     return cache.window_page[page_table]
-
-
-def window_span_pages(window: int, in_flight: int, page_size: int) -> int:
-    """The most window pages one sequence holds at once: its last
-    ``window`` positions and the ``in_flight`` tokens dispatched past
-    what the host has seen committed, however they lie on pages."""
-    return (window + in_flight + page_size - 2) // page_size + 1
-
-
-def first_live_page(past_len, window: int, page_size: int):
-    """The slot of a row's table that holds the oldest position a query
-    at ``past_len`` (numpy or jax, any shape) sees through ``window``:
-    what every reader of a window pool starts at, and what the host
-    releases behind."""
-    return (past_len - window + 1).clip(0) // page_size
 
 
 class WindowPages:
@@ -467,6 +684,220 @@ class WindowPages:
     def reset(self) -> None:
         self.release(np.nonzero(self.of_page)[0])
         self._reserved.clear()
+
+
+def _tables(page_tables) -> np.ndarray:
+    return np.asarray(page_tables).reshape(-1, np.shape(page_tables)[-1])
+
+
+class RowPools:
+    """ONE admission interface over what a row needs of each pool beside
+    the page free list: the host allocators a layout calls for
+    (``StateSlots``, ``WindowPages``; the next kind of state adds its own
+    here) behind the verbs the scheduler calls for every model, in the
+    order admission checks (state slot, window budget, then pages):
+    ``reset`` at a session's start, ``room`` for a row, ``bind`` and
+    ``release`` with its pages, ``slide`` behind the committed lengths.
+    For a model with one pool each returns at once. The runner owns it,
+    calls ``bind_fresh`` / ``bind_written`` before its own dispatches,
+    and is told what the device has to learn (``tell_slots(pairs)``,
+    ``tell_window(ids, window pages)``: one small dispatch each)."""
+
+    def __init__(
+        self, layout: CacheLayout,
+        tell_slots: Optional[Callable] = None,
+        tell_window: Optional[Callable] = None,
+    ):
+        self.layout = layout
+        # the host's side of the state pool: which slots are free. None
+        # for a model that keeps no such state
+        self.slots = (
+            StateSlots(layout.state_slots) if layout.state_slots else None
+        )
+        # the host's side of the window pool; None for a model with one
+        # pool and at the trivial setting
+        self.window = (
+            WindowPages(layout.window_pages, layout.num_pages)
+            if layout.binds_window else None
+        )
+        self._tell_slots, self._tell_window = tell_slots, tell_window
+        self._unbound: list = []  # (page, slot) the device has yet to learn
+
+    # -- the scheduler's verbs ------------------------------------------
+
+    def reset(self) -> None:
+        """A new session's pages are all free: every slot and window
+        page is too."""
+        if self.slots is not None:
+            self.slots.reset()
+            self._unbound.clear()
+            self._note_slots()
+        if self.window is not None:
+            self.window.reset()
+            self._flush_window()
+
+    def room(
+        self, total_tokens: int, chunked: bool,
+        batch_slot_free: Optional[Callable[[], bool]] = None,
+    ) -> Optional[int]:
+        """Is there room for a row of ``total_tokens`` (prompt and new;
+        its prefill ``chunked`` or not) beside the pages it will ask
+        for? None: admission waits, as it waits for pages; else what
+        ``bind`` reserves for it. A wait for a state slot while the
+        batch has a free slot is counted, where the caller counts
+        (``batch_slot_free`` given: the scheduler's latched flag) and
+        the switch is still on."""
+        if self.slots is not None and self.slots.free_count < 1:
+            if (
+                telemetry.ENABLED and batch_slot_free is not None
+                and batch_slot_free()
+            ):
+                telemetry.STATE_SLOT_WAITS_TOTAL.inc(1.0)
+            return None
+        if self.window is None:
+            return 0
+        # admission waits for pages of BOTH kinds: the row reserves the
+        # most window pages it will hold at once. A row whose prefill
+        # runs in chunks over a paged past holds, at a chunk's dispatch,
+        # the window before the chunk and the window at its end
+        need = min(
+            -(-total_tokens // self.layout.page_size),
+            self.layout.window_span * (2 if chunked else 1),
+        )
+        return None if need > self.window.budget_free else need
+
+    def bind(self, table, own_pages, room: int) -> None:
+        """The row admitted through ``table`` ([MP]) with ``own_pages``
+        takes what ``room`` promised: a state slot for its first page
+        (the device is told with the row's prefill) and its window
+        budget."""
+        if self.slots is not None:
+            self.bind_first([table[0]], flush=False)
+        if room and len(own_pages):
+            self.window.set_budget(own_pages[0], room)
+
+    def release(self, own_pages) -> None:
+        """With a row's pages goes its state slot (bound to the first;
+        the device is not told: a page's entry is written before it is
+        read), and its window pages and their reservation."""
+        if self.slots is not None and len(own_pages):
+            first = int(own_pages[0])
+            self.slots.release(first)
+            self._unbound = [u for u in self._unbound if u[0] != first]
+            self._note_slots()
+        if self.window is not None:
+            self.window.release_row(own_pages)
+
+    def release_behind(self, page_tables, committed) -> int:
+        """Take back the window pages whose last position is older than
+        ``committed[b] - window``: no query at ``committed[b]`` or later
+        sees them, and every dispatch in flight was given a length of
+        at least that. The caller passes COMMITTED lengths (never a
+        projection over tokens in flight). The device learns with the
+        next bind; it never reads such a page meanwhile. Returns the
+        pages released."""
+        pool = self.window
+        if pool is None:
+            return 0
+        tables = _tables(page_tables)
+        first = first_live_page(
+            np.asarray(committed, np.int64).reshape(-1, 1),
+            self.layout.window, self.layout.page_size,
+        )
+        ids = tables[np.arange(tables.shape[1])[None, :] < first]
+        n = pool.release(ids[pool.of_page[ids] > 0])
+        if n:
+            pool.released_total += n
+            if telemetry.ENABLED:
+                telemetry.KV_WINDOW_PAGES_RELEASED_TOTAL.inc(float(n))
+        return n
+
+    def slide(self, table, past_len, active, count: bool = False) -> bool:
+        """Give back what has slid out behind each ``active`` row's
+        COMMITTED length (``past_len`` is what the host has accepted:
+        tokens of windows in flight are not in it, so a page one of them
+        still reads is never released), and where the caller counts note
+        how much of its K/V a window layer holds. False where nothing
+        slides."""
+        if self.window is None:
+            return False
+        rows = np.asarray(active, np.int64)
+        self.release_behind(table[rows], past_len[rows])
+        if count and telemetry.ENABLED:
+            PS = self.layout.page_size
+            telemetry.KV_WINDOW_PAGES_HELD_TOTAL.inc(float(self.window.in_use))
+            telemetry.KV_WINDOW_PAGES_WHOLE_TOTAL.inc(
+                float((-(-past_len[rows].astype(np.int64) // PS)).sum())
+            )
+        return True
+
+    # -- the runner's, before a dispatch ---------------------------------
+
+    def bind_first(self, first_pages, flush: bool = True) -> None:
+        """Give each sequence that starts at the head of one of
+        ``first_pages`` a slot of the state pool (one that is bound
+        keeps its slot). The device learns of new bindings in ONE small
+        dispatch, at once or (``flush`` False: admission, a row at a
+        time) before the next prefill, which is the first program to
+        read them. Raises MemoryError when the pool has no free slot:
+        admission asks ``room`` first."""
+        if self.slots is None:
+            return
+        for p in first_pages:
+            if int(p) > 0:
+                slot, new = self.slots.bind(p)
+                if new:
+                    self._unbound.append((int(p), slot))
+        self._note_slots()
+        if flush and self._unbound:
+            pairs, self._unbound = self._unbound, []
+            self._tell_slots(pairs)
+
+    def bind_fresh(self, page_tables, starts) -> None:
+        """The prefill entry points' own ask: rows that start a sequence
+        get a slot if whoever admitted them bound none."""
+        if self.slots is not None:
+            self.bind_first([
+                t[0] for t, st in zip(_tables(page_tables), starts)
+                if int(st) == 0
+            ])
+
+    def bind_written(self, page_tables, starts, lens) -> None:
+        """Before a dispatch that writes ``lens[b]`` tokens from
+        position ``starts[b]`` through ``page_tables[b]``: bind a window
+        page to each page id that holds one of those tokens the window
+        at the chunk's end still sees (the others land on the garbage
+        page), and tell the device what changed since it was last told,
+        releases included, in ONE small dispatch."""
+        if self.window is None:
+            return
+        PS, W = self.layout.page_size, self.layout.window
+        tables = _tables(page_tables)
+        st = np.asarray(starts, np.int64).reshape(-1, 1)
+        n = np.asarray(lens, np.int64).reshape(-1, 1)
+        j = np.arange(tables.shape[1])[None, :]
+        keep = (
+            (j >= np.maximum(st, st + n - W + 1) // PS)
+            & (j <= (st + n - 1) // PS) & (n > 0)
+        )
+        self.window.bind(tables[keep])
+        self._flush_window()
+
+    def _flush_window(self) -> None:
+        """Tell the device the window bindings that changed."""
+        delta = self.window.delta()
+        if delta is None:
+            return
+        self._tell_window(*delta)
+        if telemetry.ENABLED:
+            pool = self.window
+            telemetry.KV_PAGES.set(float(pool.in_use), "window", "used")
+            telemetry.KV_PAGES.set(float(pool.free_count), "window", "free")
+
+    def _note_slots(self) -> None:
+        if telemetry.ENABLED:
+            telemetry.STATE_SLOTS.set(float(self.slots.in_use), "in_use")
+            telemetry.STATE_SLOTS.set(float(self.slots.total), "total")
 
 
 def _quantize_tokens(x: jax.Array):
@@ -1025,69 +1456,6 @@ def write_kv(
         v_pages=_scatter_rows(
             cache.v_pages, flat, v_chunk.reshape(L, B, T, KD)
         ),
-    )
-
-
-def gather_pages(
-    k_pages: jax.Array,  # [L, NP, PS, KVH*Dh] — the stacked pool
-    v_pages: jax.Array,
-    layer: jax.Array,  # scalar int32 — the layer to read
-    page_table: jax.Array,  # [B, MP] int32
-    k_scale: "jax.Array | None" = None,  # [L, NP, PS] (int8 KV mode)
-    v_scale: "jax.Array | None" = None,
-    out_dtype=None,  # dequant target (compute dtype); None => float32
-) -> Tuple[jax.Array, jax.Array]:
-    """Every row's pages of one layer, ``[B * MP, PS, KD]`` x2 in the
-    pool's own fused layout, as ONE gather on ``[layer, page_table]`` of
-    the stack (never a slice of the layer's pool followed by a gather:
-    the slice would be a copy of it).
-    With int8 KV scales the gathered pages are dequantized here, INTO
-    the caller's compute dtype — a float32 view would quadruple the
-    gathered context's bytes and promote the whole XLA attention to
-    f32, doubling the HBM traffic the int8 cache exists to halve."""
-    L, NP, PS, KD = k_pages.shape
-    # rows of the stack seen flat, [L * NP, PS, KD] (a bitcast): ONE
-    # index on the major axis. Indexed as [layer, pages] the TPU
-    # compiler re-lays the whole pool out for the gather (layer axis
-    # moved inward) and back for the next write: two copies of the pool
-    # a program, and their bytes among its temporaries
-    pages = layer * NP + page_table.reshape(-1)
-    k = k_pages.reshape(L * NP, PS, KD)[pages]  # [B*MP, PS, KD]
-    v = v_pages.reshape(L * NP, PS, KD)[pages]
-    if k_scale is not None:
-        dt = out_dtype or jnp.float32
-        ks = k_scale.reshape(L * NP, PS)[pages]
-        vs = v_scale.reshape(L * NP, PS)[pages]
-        k = (k.astype(jnp.float32) * ks[..., None]).astype(dt)
-        v = (v.astype(jnp.float32) * vs[..., None]).astype(dt)
-    return k, v
-
-
-def gather_kv_layer(
-    k_pages: jax.Array,  # [L, NP, PS, KVH*Dh] — the stacked pool
-    v_pages: jax.Array,
-    layer: jax.Array,  # scalar int32 — the layer to read
-    page_table: jax.Array,  # [B, MP] int32
-    kv_heads: int,
-    k_scale: "jax.Array | None" = None,  # [L, NP, PS] (int8 KV mode)
-    v_scale: "jax.Array | None" = None,
-    out_dtype=None,  # dequant target (compute dtype); None => float32
-) -> Tuple[jax.Array, jax.Array]:
-    """Per-layer page gather: [B, MP] table -> ([B, CTX, KVH, Dh]) x2,
-    CTX = MP * PS (``gather_pages``, head-split). Used inside the layer
-    scan so only one layer's context view is ever live. This view
-    serves a chunk over a paged past (T > 1: chunked prefill, verify
-    forwards); one decode step reads its pages in place (the Pallas
-    paged kernel) or keeps the gathered pages fused
-    (``ops/attention.paged_decode_xla``)."""
-    PS, KD = k_pages.shape[2:]
-    B, MP = page_table.shape
-    k, v = gather_pages(
-        k_pages, v_pages, layer, page_table, k_scale, v_scale, out_dtype
-    )
-    return (
-        k.reshape(B, MP * PS, kv_heads, KD // kv_heads),
-        v.reshape(B, MP * PS, kv_heads, KD // kv_heads),
     )
 
 

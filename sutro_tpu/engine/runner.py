@@ -34,9 +34,8 @@ from ..models.transformer import MixedChunk
 from . import faults
 from .config import EngineConfig
 from .kvcache import (
-    KVCache, StateSlots, WindowPages, alloc_cache, default_state_slots,
-    first_live_page, read_conv_state, read_state, state_bytes_per_slot,
-    window_span_pages, window_table, write_kv,
+    KVCache, RowPools, cache_layout, default_state_slots, first_live_page,
+    read_conv_state, read_state, window_span_pages, window_table, write_kv,
 )
 from ..ops.lowering import part
 from ..ops.sampling import (
@@ -256,13 +255,30 @@ class ModelRunner:
                 f"{mcfg.name} has layers of several kinds: not under "
                 "sequence or pipeline parallelism, nor quantize"
             )
-        if mesh is not None and mcfg.num_latent_layers:
-            # every head reads the whole latent row: there is no axis of
-            # it to give the shards of a tensor-parallel pool
-            raise NotImplementedError(
-                f"{mcfg.name} keeps a latent row a token (mla layers): the "
-                "latent pool runs on one chip, not under a mesh"
-            )
+        # how the K/V pools are partitioned (parallel/ says), and with it
+        # what the pools hold at any size (kvcache.cache_layout): a layout
+        # that cannot be built (a latent pool under a mesh, int8 K/V
+        # beside a window pool ...) raises here, by name
+        self._cache_sharding = None
+        if mesh is not None and self.pp > 1:
+            from ..parallel.pipeline import pp_cache_sharding
+
+            self._cache_sharding = pp_cache_sharding(mesh, mcfg.num_kv_heads)
+        elif mesh is not None:
+            from ..parallel.sharding import cache_shardings
+
+            self._cache_sharding = cache_shardings(mesh, mcfg.num_kv_heads)
+        # pages the allocators may hand out at the worst case (every slot
+        # at full context; page 0 is the garbage page)
+        worst_case = 1 + ecfg.decode_batch_size * ecfg.max_pages_per_seq
+        # (an entry's bytes are the same at any size: ``_pages_that_fit``
+        # reads them here, before the pools have one)
+        self._sized = cache_layout(
+            mcfg, ecfg, worst_case, dtype, self._cache_sharding
+        )
+        self._margin_pages = _pool_margin_pages(
+            ecfg.max_pages_per_seq, self._sized.margin_row_bytes
+        ) if self.use_pallas else 0
         if mesh is not None and mcfg.hc_mult > 1:
             # the sharding rules and the pipeline's stages know one lane
             # [B, T, H] (parallel/pipeline.py sends it between stages)
@@ -363,39 +379,18 @@ class ModelRunner:
             ):
                 params = quantize_params(params)
         if mesh is not None:
-            from ..parallel.sharding import cache_shardings
-
             if shardings is None:
                 shardings = shard_rules(params)
             params = jax.device_put(params, shardings)
-            if self.pp > 1:
-                from ..parallel.pipeline import pp_cache_sharding
-
-                self._cache_sharding = pp_cache_sharding(
-                    mesh, mcfg.num_kv_heads
-                )
-            else:
-                self._cache_sharding = cache_shardings(
-                    mesh, mcfg.num_kv_heads
-                )
         else:
-            self._cache_sharding = None
             # commit host leaves (checkpoint numpy, host-quantized int8)
             # to the device ONCE — otherwise every jitted dispatch
             # re-uploads them
             params = jax.device_put(params)
         self.params = params
-        tp = int(mesh.shape.get("model", 1)) if mesh is not None else 1
-        self._margin_pages = _pool_margin_pages(
-            ecfg.max_pages_per_seq,
-            ecfg.kv_page_size * max(mcfg.page_width // tp, 1)
-            * (1 if ecfg.kv_quantize == "int8" else dtype.itemsize),
-        ) if self.use_pallas else 0
-        # pages the allocators may hand out (page 0 is the garbage
-        # page). ``num_pages`` sizes it explicitly; otherwise it is the
-        # worst case (every slot at full context), bounded by what the
-        # device's memory can hold beside the weights.
-        worst_case = 1 + ecfg.decode_batch_size * ecfg.max_pages_per_seq
+        # pages the allocators may hand out. ``num_pages`` sizes it
+        # explicitly; otherwise it is the worst case, bounded by what
+        # the device's memory can hold beside the weights.
         # a model with window attention layers keeps K/V a pool a kind
         # (kvcache.py): the window layers' pool holds a row's window and
         # the tokens in flight, whatever its context. Sized with the
@@ -403,18 +398,7 @@ class ModelRunner:
         # given ``num_pages`` alone, and any mesh, runs the mechanism at
         # its trivial setting: a window pool as large as the full one
         # under the identity map, nothing bound or released
-        # in flight: the fused windows dispatched past what the host
-        # has seen committed, or a verify chunk's inputs
-        in_flight = max(
-            (ecfg.decode_lookahead + 1) * ecfg.decode_multi_step,
-            ecfg.constrain_fastforward + 1,
-        )
-        self.window_span = min(
-            ecfg.max_pages_per_seq,
-            window_span_pages(
-                mcfg.sliding_window, in_flight, ecfg.kv_page_size
-            ),
-        ) if mcfg.num_window_layers else 0
+        self.window_span = self._sized.window_span
         two_pools = (
             mcfg.num_window_layers > 0 and mesh is None
             and (num_pages is None or window_pages is not None)
@@ -426,7 +410,6 @@ class ModelRunner:
                 worst_case,
                 1 + ecfg.decode_batch_size * self.window_span
                 if two_pools else 0,
-                dtype,
             )
             if two_pools and window_pages is None:
                 window_pages = fit_window
@@ -438,25 +421,18 @@ class ModelRunner:
         self._kv_pages = None
         # the last masked decode_step's take_unmasked_ok
         self._unmasked_ok = None
-        self.cache = alloc_cache(
-            mcfg, ecfg, self.num_pages, dtype=dtype,
-            sharding=self._cache_sharding,
-            window_pages=window_pages if two_pools else None,
+        #: THE description of this runner's pools (kvcache.CacheLayout):
+        #: shapes, bytes, what a row needs of each, what the pages support
+        self.layout = cache_layout(
+            mcfg, ecfg, self.num_pages, dtype, self._cache_sharding,
+            window_pages if two_pools else None,
         )
-        # the host's side of the window pool (kvcache.WindowPages); None
-        # for a model with one pool and at the trivial setting
-        self.window_pool = (
-            WindowPages(self.cache.num_window_pages, self.num_pages)
-            if two_pools and self.cache.num_window_pages != self.num_pages
-            else None
+        self.cache = self.layout.alloc()
+        #: the host's side of the pools beside the page free list
+        #: (kvcache.RowPools): what the scheduler admits a row against
+        self.pools = RowPools(
+            self.layout, self._tell_slots, self._tell_window
         )
-        # the host's side of the mamba state pool (kvcache.StateSlots):
-        # which slots are free. None for a model that keeps no such state
-        self.state_slots = (
-            StateSlots(self.cache.num_state_slots - 1)
-            if self.cache.ssm is not None else None
-        )
-        self._unbound: list = []  # (page, slot) the device has yet to learn
 
     @property
     def has_state(self) -> bool:
@@ -469,11 +445,7 @@ class ModelRunner:
         """Bytes of the state layers' matrices alone (no conv columns)
         in ``rows`` rows' slots: what a delta-rule step's two products
         stream and a commit rewrites."""
-        m = self.mcfg
-        return int(
-            rows * m.num_state_layers * m.state_rows * m.state_inner
-            * jnp.dtype(self.ecfg.activation_dtype).itemsize
-        )
+        return int(rows * self.layout.entry_bytes("ssm"))
 
     def stream_bytes(self, tokens: int) -> int:
         """Bytes the residual stream of ``tokens`` tokens MUST move
@@ -508,11 +480,9 @@ class ModelRunner:
         """Bytes of mamba state a decode step reads for ``rows`` live
         rows (it reads each row's slot once and writes none: the window
         commits); 0 for a model that keeps none."""
-        if self.state_slots is None:
-            return 0
-        return rows * state_bytes_per_slot(self.mcfg, self.ecfg)
+        return rows * self.layout.slot_bytes
 
-    # -- mamba state slots (kvcache.StateSlots) -------------------------
+    # -- what the host's allocators tell the device (kvcache.RowPools) --
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
     @part("cache")
@@ -521,69 +491,16 @@ class ModelRunner:
             cache, state_slot=cache.state_slot.at[pages].set(slots)
         )
 
-    def bind_state(self, first_pages, flush: bool = True) -> None:
-        """Give each sequence that starts at the head of one of
-        ``first_pages`` a slot of the state pool (one that is bound
-        keeps its slot). The device learns of new bindings in ONE small
-        dispatch, at once or (``flush`` False: admission, a row at a
-        time) before the next prefill, which is the first program to
-        read them. Raises MemoryError when the pool has no free slot:
-        admission asks ``state_slots.free_count`` first."""
-        if self.state_slots is None:
-            return
-        for p in first_pages:
-            if int(p) > 0:
-                slot, new = self.state_slots.bind(p)
-                if new:
-                    self._unbound.append((int(p), slot))
-        self._note_state_slots()
-        if not flush or not self._unbound:
-            return
-        # one shape for every admission batch; pads: page 0 <- slot 0
-        n = max(self.ecfg.prefill_batch_size, next_bucket(len(self._unbound)))
+    def _tell_slots(self, pairs) -> None:
+        """``(page, slot)`` bindings the device has yet to learn, in ONE
+        small dispatch of one shape for every admission batch (pads:
+        page 0 <- slot 0)."""
+        n = max(self.ecfg.prefill_batch_size, next_bucket(len(pairs)))
         pages, slots = np.zeros((2, n), np.int32)
-        pages[: len(self._unbound)], slots[: len(self._unbound)] = (
-            np.array(self._unbound, np.int32).T
-        )
-        self._unbound.clear()
+        pages[: len(pairs)], slots[: len(pairs)] = np.array(pairs, np.int32).T
         self.cache = self._bind_slots_jit(
             self.cache, jnp.asarray(pages), jnp.asarray(slots)
         )
-
-    def release_state(self, first_page) -> None:
-        """Take back the slot of the sequence that started at
-        ``first_page``. The device is not told: a page's entry is
-        written before it is read (kvcache.py)."""
-        if self.state_slots is not None:
-            self.state_slots.release(int(first_page))
-            self._unbound = [
-                u for u in self._unbound if u[0] != int(first_page)
-            ]
-            self._note_state_slots()
-
-    def reset_state_slots(self) -> None:
-        """Every slot free: a new session's pages are all free."""
-        if self.state_slots is not None:
-            self.state_slots.reset()
-            self._unbound.clear()
-            self._note_state_slots()
-
-    def _note_state_slots(self) -> None:
-        if telemetry.ENABLED:
-            telemetry.STATE_SLOTS.set(float(self.state_slots.in_use), "in_use")
-            telemetry.STATE_SLOTS.set(float(self.state_slots.total), "total")
-
-    def _bind_fresh(self, page_tables, starts) -> None:
-        """The prefill entry points' own ask: rows that start a sequence
-        get a slot if whoever admitted them bound none."""
-        if self.state_slots is None:
-            return
-        tables = np.asarray(page_tables).reshape(-1, np.shape(page_tables)[-1])
-        self.bind_state(
-            [t[0] for t, st in zip(tables, starts) if int(st) == 0]
-        )
-
-    # -- window pool pages (kvcache.WindowPages) ------------------------
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
     @part("cache")
@@ -592,92 +509,14 @@ class ModelRunner:
             cache, window_page=cache.window_page.at[pages].set(wpages)
         )
 
-    def window_budget(self, total_tokens: int, chunked: bool) -> int:
-        """Window pages admission reserves for a row of ``total_tokens``
-        (prompt and new): the most it holds at once. A row whose
-        prefill runs in chunks over a paged past holds, at a chunk's
-        dispatch, the window before the chunk and the window at its
-        end."""
-        PS = self.ecfg.kv_page_size
-        return min(
-            -(-total_tokens // PS), self.window_span * (2 if chunked else 1)
-        )
-
-    def _bind_window(self, page_tables, starts, lens) -> None:
-        """Before a dispatch that writes ``lens[b]`` tokens from
-        position ``starts[b]`` through ``page_tables[b]``: bind a window
-        page to each page id that holds one of those tokens the window
-        at the chunk's end still sees (the others land on the garbage
-        page), and tell the device what changed since it was last told,
-        releases included, in ONE small dispatch."""
-        pool = self.window_pool
-        if pool is None:
-            return
-        PS, W = self.ecfg.kv_page_size, self.mcfg.sliding_window
-        tables = np.asarray(page_tables).reshape(-1, np.shape(page_tables)[-1])
-        st = np.asarray(starts, np.int64).reshape(-1, 1)
-        n = np.asarray(lens, np.int64).reshape(-1, 1)
-        j = np.arange(tables.shape[1])[None, :]
-        keep = (
-            (j >= np.maximum(st, st + n - W + 1) // PS)
-            & (j <= (st + n - 1) // PS) & (n > 0)
-        )
-        pool.bind(tables[keep])
-        self._flush_window()
-
-    def _flush_window(self) -> None:
-        """Tell the device the bindings that changed (a two-pool
-        runner's own: ``window_pool`` is there)."""
-        pool = self.window_pool
-        delta = pool.delta()
-        if delta is None:
-            return
-        ids, wpages = delta
+    def _tell_window(self, ids, wpages) -> None:
+        """The page ids whose window page changed (pads: page id 0 <- 0)."""
         m = next_bucket(len(ids), lo=64)
-        pad = np.zeros((2, m), np.int32)   # pads: page id 0 <- 0
+        pad = np.zeros((2, m), np.int32)
         pad[0, : len(ids)], pad[1, : len(ids)] = ids, wpages
         self.cache = self._bind_window_jit(
             self.cache, jnp.asarray(pad[0]), jnp.asarray(pad[1])
         )
-        if telemetry.ENABLED:
-            telemetry.KV_PAGES.set(float(pool.in_use), "window", "used")
-            telemetry.KV_PAGES.set(float(pool.free_count), "window", "free")
-
-    def release_window_behind(self, page_tables, committed) -> int:
-        """Take back the window pages whose last position is older than
-        ``committed[b] - window``: no query at ``committed[b]`` or later
-        sees them, and every dispatch in flight was given a length of
-        at least that. The caller passes COMMITTED lengths (never a
-        projection over tokens in flight). The device learns with the
-        next bind; it never reads such a page meanwhile. Returns the
-        pages released."""
-        pool = self.window_pool
-        if pool is None:
-            return 0
-        PS, W = self.ecfg.kv_page_size, self.mcfg.sliding_window
-        tables = np.asarray(page_tables).reshape(-1, np.shape(page_tables)[-1])
-        first = first_live_page(
-            np.asarray(committed, np.int64).reshape(-1, 1), W, PS
-        )
-        behind = np.arange(tables.shape[1])[None, :] < first
-        ids = tables[behind]
-        n = pool.release(ids[pool.of_page[ids] > 0])
-        if n:
-            pool.released_total += n
-            if telemetry.ENABLED:
-                telemetry.KV_WINDOW_PAGES_RELEASED_TOTAL.inc(float(n))
-        return n
-
-    def release_window_row(self, own_pages) -> None:
-        """A row's pages go back to the page allocator."""
-        if self.window_pool is not None:
-            self.window_pool.release_row(own_pages)
-
-    def reset_window_pool(self) -> None:
-        """A new session's pages are all free."""
-        if self.window_pool is not None:
-            self.window_pool.reset()
-            self._flush_window()
 
     def _window_pool_of(self, cache: KVCache, page_table):
         """``transformer.forward``'s ``window_pool``; None for a model
@@ -688,38 +527,17 @@ class ModelRunner:
             cache.wk_pages, cache.wv_pages, window_table(cache, page_table)
         )
 
-    def _page_bytes_per_device(self, dtype, window: bool = False) -> int:
-        """One KV page (K and V, every ATTENTION layer, or the one
-        latent row a token of every latent layer; plus int8
-        scales, plus the page's conv state) as it sits on ONE device
-        under the pool's sharding; ``window``: a page of the window
-        layers' pool."""
-        PS = self.ecfg.kv_page_size
-        L = (
-            self.mcfg.num_window_layers if window
-            else self.mcfg.num_pool_layers
+    def _page_bytes_per_device(self, *, window: bool = False) -> int:
+        """One page as it sits on ONE device under the pool's sharding
+        (``CacheLayout.page_bytes``: the description's number, whatever
+        the pool's size); ``window``: a page of the window layers'
+        pool."""
+        return (
+            self._sized.window_page_bytes if window
+            else self._sized.page_bytes
         )
-        shape = (L, 1, PS, self.mcfg.page_width)
-        if self._cache_sharding is not None:
-            shape = self._cache_sharding.shard_shape(shape)
-        # a pool a width of ``ModelConfig.pool_row_widths``: K and V, a
-        # latent row alone, or a latent row and an index key
-        widths = (
-            self.mcfg.pool_row_widths if not window
-            else (self.mcfg.page_width,) * 2
-        )
-        elements = int(np.prod(shape)) * sum(widths) // self.mcfg.page_width
-        state = (
-            self.mcfg.num_conv_layers * self.mcfg.conv_state_len
-            * self.mcfg.hidden_size
-            * jnp.dtype(self.ecfg.activation_dtype).itemsize
-        )
-        if self.ecfg.kv_quantize == "int8":
-            # int8 values + replicated f32 per-token scales
-            return 2 * (int(np.prod(shape)) + L * PS * 4) + state
-        return elements * dtype.itemsize + state
 
-    def _pages_that_fit(self, want: int, want_window: int, dtype):
+    def _pages_that_fit(self, want: int, want_window: int):
         """``(pages, window pages)``: ``want`` pages (and ``want_window``
         of the window layers' pool, 0 for a model with one pool or at
         the trivial setting, where a page carries both kinds), or as
@@ -750,7 +568,7 @@ class ModelRunner:
             # step), which is no transient the reserve was sized for
             # once a token leaves 100 KB a layer (a delta rule's)
             in_use += (1 + default_state_slots(self.ecfg, want)) * (
-                state_bytes_per_slot(self.mcfg, self.ecfg)
+                self._sized.slot_bytes
             ) + self._window_state_bytes()
         if self.mcfg.hc_mult > 1:
             # a prefill chunk's stream is no transient the reserve was
@@ -773,11 +591,11 @@ class ModelRunner:
                 * self.mcfg.vocab_size
             )
         reserve = int(limit * HBM_RESERVE_FRACTION)
-        page = self._page_bytes_per_device(dtype)
+        page = self._page_bytes_per_device()
         avail = limit - in_use - reserve
         wpage = fit_window = 0
         if self.mcfg.num_window_layers:
-            wpage = self._page_bytes_per_device(dtype, window=True)
+            wpage = self._page_bytes_per_device(window=True)
             if not want_window:
                 page, wpage = page + wpage, 0   # one id, both kinds
         need = want * page + want_window * wpage
@@ -852,9 +670,7 @@ class ModelRunner:
             # what keeps a matrix state a slot ("mamba" | "kda"; None:
             # no layer does) and a slot's bytes over those layers
             "state_kind": self.mcfg.state_kind,
-            "state_bytes_per_slot": int(
-                state_bytes_per_slot(self.mcfg, self.ecfg)
-            ),
+            "state_bytes_per_slot": int(self.layout.slot_bytes),
             "state_bytes": int(sum(
                 0 if pool is None else pool.nbytes
                 for pool in (
@@ -878,8 +694,8 @@ class ModelRunner:
                 self.mcfg.page_width if self.mcfg.num_latent_layers else 0
             ),
             "latent_page_bytes": int(
-                self.mcfg.num_latent_layers * self.ecfg.kv_page_size
-                * self.mcfg.latent_width * self.cache.k_pages.dtype.itemsize
+                self.layout.entry_bytes("k_pages", used=True)
+                if self.mcfg.num_latent_layers else 0
             ),
             # latent layers with an indexer keep an index key a token too,
             # ``index_page_bytes`` a page of every layer (0: no indexer)
@@ -888,10 +704,7 @@ class ModelRunner:
             ),
             "index_key_width": int(self.mcfg.index_key_width),
             "index_topk": int(self.mcfg.index_topk),
-            "index_page_bytes": int(
-                0 if self.cache.ik_pages is None
-                else self.cache.ik_pages.nbytes // self.cache.num_pages
-            ),
+            "index_page_bytes": int(self.layout.entry_bytes("ik_pages")),
             # the residual stream's lanes (1: the plain add) and the
             # sublayers that each mix them a token
             "hc_mult": int(self.mcfg.hc_mult),
@@ -1069,18 +882,9 @@ class ModelRunner:
         free/reuse the pages the moment this returns."""
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         c = self.cache
-        if c.v_pages is None:
-            raise ValueError(
-                "pages of a latent pool do not move to the tiers (the "
-                "payload is a K and a V of one width)"
-            )
-        if c.wk_pages is not None:
-            # a page id's window page may be gone, and the tiers' payload
-            # has no place for a second pool: the caller prefills again
-            raise ValueError(
-                "pages of a model that keeps K/V a pool a kind do not "
-                "move to the tiers (no window pages in the payload)"
-            )
+        why = self.layout.refuses("read_pages")
+        if why is not None:
+            raise ValueError(why)
         out = {
             "k": np.asarray(c.k_pages[:, ids]),
             "v": np.asarray(c.v_pages[:, ids]),
@@ -1130,21 +934,9 @@ class ModelRunner:
         the parity contract, tests/test_kv_tiers.py)."""
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         c = self.cache
-        if c.v_pages is None:
-            raise ValueError(
-                "a K/V page payload cannot restore the rows of a latent pool"
-            )
-        if c.wk_pages is not None:
-            raise ValueError(
-                "pages cannot restore the window pages of a model that "
-                "keeps K/V a pool a kind"
-            )
-        if c.ssm is not None:
-            # a slot's state is in no page: the caller prefills again
-            raise ValueError(
-                "pages cannot restore the state of a model that keeps "
-                "it a slot a sequence"
-            )
+        why = self.layout.refuses("write_pages")
+        if why is not None:
+            raise ValueError(why)
         state = None
         if c.conv is not None:
             if "c" not in payload:
@@ -1308,7 +1100,7 @@ class ModelRunner:
         assert start == 0 or (self.sp == 1 and self.pp == 1), (
             "suffix prefill is unsupported under sp/pp"
         )
-        self._bind_fresh(page_table, [start])
+        self.pools.bind_fresh(page_table, [start])
         if start > 0 and n <= C:
             out = self.prefill_batch_at(
                 [token_ids], page_table[None, :], [start],
@@ -1323,7 +1115,7 @@ class ModelRunner:
                 ids[0, : len(seg)] = seg
                 self._count_state_commit("chunk")
                 self._count_latent("absorbed", steps=start + off + C)
-                self._bind_window(page_table, [start + off], [len(seg)])
+                self.pools.bind_written(page_table, [start + off], [len(seg)])
                 logits, self.cache, route = self._prefill_chunk_jit(
                     self.params,
                     self.cache,
@@ -1334,7 +1126,7 @@ class ModelRunner:
                 )
                 # the chunk is dispatched: what slid out behind its end
                 # goes back before the next chunk binds
-                self.release_window_behind(
+                self.pools.release_behind(
                     page_table, [start + off + len(seg)]
                 )
             out = self._prefill_out(logits, route, 1, on_device)
@@ -1346,7 +1138,7 @@ class ModelRunner:
         ids[0, :n] = token_ids
         self._count_state_commit("prefill")
         self._count_latent("expanded", steps=T)
-        self._bind_window(page_table, [0], [n])
+        self.pools.bind_written(page_table, [0], [n])
         logits, self.cache, route = self._prefill_jit(
             self.params,
             self.cache,
@@ -1392,10 +1184,10 @@ class ModelRunner:
             ids[i, : len(r)] = r
             lens[i] = len(r)
             tables[i] = page_tables[i]
-        self._bind_fresh(tables[:n], [0] * n)
+        self.pools.bind_fresh(tables[:n], [0] * n)
         self._count_state_commit("prefill", n)
         self._count_latent("expanded", steps=ids.shape[1])
-        self._bind_window(tables[:n], [0] * n, lens[:n])
+        self.pools.bind_written(tables[:n], [0] * n, lens[:n])
         logits, self.cache, route = self._prefill_jit(
             self.params,
             self.cache,
@@ -1436,10 +1228,10 @@ class ModelRunner:
             lens[i] = len(r)
             st[i] = starts[i]
             tables[i] = page_tables[i]
-        self._bind_fresh(tables[:n], st[:n])
+        self.pools.bind_fresh(tables[:n], st[:n])
         self._count_state_commit("chunk", n)
         self._count_latent("absorbed", steps=int(st.max()) + ids.shape[1])
-        self._bind_window(tables[:n], st[:n], lens[:n])
+        self.pools.bind_written(tables[:n], st[:n], lens[:n])
         logits, self.cache, route = self._prefill_chunk_jit(
             self.params,
             self.cache,
@@ -1448,7 +1240,7 @@ class ModelRunner:
             jnp.asarray(tables),
             jnp.asarray(st),
         )
-        self.release_window_behind(tables[:n], st[:n] + lens[:n])
+        self.pools.release_behind(tables[:n], st[:n] + lens[:n])
         return self._prefill_out(logits, route, n, on_device)
 
     # ------------------------------------------------------------------
@@ -1673,7 +1465,7 @@ class ModelRunner:
         self._count_latent("absorbed", past_len)
         self.count_sample(temperature)
         self._count_kv_pages(past_len, page_table, 1, pfx)
-        self._bind_window(page_table, past_len, np.ones((B,), np.int32))
+        self.pools.bind_written(page_table, past_len, np.ones((B,), np.int32))
         tok, logp, self.cache, self._route_dev, ok = self._decode_jit(
             self.params,
             self.cache,
@@ -1981,7 +1773,7 @@ class ModelRunner:
         self._count_latent("absorbed", past_len, steps)
         self.count_sample(temperature)
         self._count_kv_pages(past_len, page_table, steps, pfx)
-        self._bind_window(page_table, past_len, np.full((B,), steps))
+        self.pools.bind_written(page_table, past_len, np.full((B,), steps))
         toks, logps, self.cache, self.window_route = self._decode_multi_jit(
             self.params,
             self.cache,
@@ -2371,7 +2163,9 @@ class ModelRunner:
         ids = np.zeros((B, K + 1), np.int32)
         ids[:, 0] = last_tokens
         ids[:, 1:] = drafts
-        self._bind_window(page_table, past_len, np.asarray(draft_len) + 1)
+        self.pools.bind_written(
+            page_table, past_len, np.asarray(draft_len) + 1
+        )
         self._count_latent("absorbed", past_len, K + 1)
         ct, cl, pt, pl, self.cache, pending = self._verify_cand_jit(
             self.params,
@@ -2578,7 +2372,7 @@ class ModelRunner:
         self._count_state_commit(
             "window", len(past_len), int(jax.tree.leaves(wk)[0].shape[2])
         )
-        self._bind_window(page_table, past_len, accepted)
+        self.pools.bind_written(page_table, past_len, accepted)
         self.cache = self._commit_window_jit(
             self.cache, wk, wv,
             jnp.asarray(page_table, jnp.int32),

@@ -48,7 +48,7 @@ import numpy as np
 logger = logging.getLogger(__name__)
 
 from . import faults
-from .kvcache import PageAllocator, pages_needed
+from .kvcache import ONE_POOL, PageAllocator, RowPools, pages_needed
 from .runner import ModelRunner
 from .. import telemetry
 from ..ops.sampling import (
@@ -566,14 +566,15 @@ def _lose(
 
 
 class ContinuousBatcher:
-    # the runner keeps its state a slot a sequence (set in __init__)
-    _slot_state = False
+    # what a row needs of each pool and what the pages support: the
+    # runner's (set in __init__). THE default, for a runner that offers
+    # no description (a test's stub): a single K/V pool that supports
+    # everything, behind verbs that return at once
+    _pools = RowPools(ONE_POOL)
+    _layout = ONE_POOL
     _tier_refused = False
-    _latent_pool = False
     # positions a block of the runner's model holds (1: a causal model)
     _block = 1
-    # the runner's window pool (K/V a pool a kind), set in __init__
-    _window_pool = None
 
     def __init__(
         self,
@@ -615,27 +616,41 @@ class ContinuousBatcher:
         # or SUTRO_NATIVE_RUNTIME=0.
         from .native_runtime import maybe_native_runtime
 
-        # what the runner's pool hands out (runner.alloc_pages; a stub
-        # runner has only num_pages)
-        alloc_pages = getattr(runner, "alloc_pages", runner.num_pages)
+        # what a row needs of each pool beside the page free list, and
+        # what the pages support: the runner's description of its pools
+        # (kvcache.RowPools, CacheLayout). The scheduler asks the
+        # description, never which family it serves
+        self._pools = getattr(runner, "pools", self._pools)
+        layout = self._layout = self._pools.layout
+        alloc_pages = runner.num_pages
         # A model that generates by BLOCKS (``ModelConfig.block_length``):
         # a row's ``pos`` stays a multiple of the block, a window yields
-        # whole blocks a row and admission samples no first token. The
-        # C++ core counts one token a row a step, so the Python
-        # allocator serves such a model; the prefix store and the tiers
-        # would cut a row at a page's edge inside generated blocks whose
-        # K/V a later block's queries never saw committed that way, and
-        # fall back like a latent pool's (reasons of their own)
-        self._block = int(getattr(runner.mcfg, "block_length", 1) or 1)
+        # whole blocks a row and admission samples no first token
+        self._block = layout.block_length
         self._bd_forwards = 0.0  # forwards the last fetched window ran
-        self.native = None if self._block > 1 else maybe_native_runtime(
-            alloc_pages, self.B, self.MP, self.ecfg.kv_page_size,
-            self.ecfg.max_batch_tokens, self.ecfg.max_context(),
+        self.native = (
+            None if layout.refuses("native") else maybe_native_runtime(
+                alloc_pages, self.B, self.MP, self.ecfg.kv_page_size,
+                self.ecfg.max_batch_tokens, self.ecfg.max_context(),
+            )
         )
         self.allocator = (
             None if self.native is not None
             else PageAllocator(alloc_pages)
         )
+        # Where the pages cannot move to the tiers or be shared between
+        # rows (``CacheLayout.refuses`` says why, family by family) those
+        # paths fall back to prefilling again, and say so
+        # (sutro_state_fallback_prefill_tokens_total). A new session's
+        # pages are all free, so whatever else a row holds is too.
+        self._tier_refused = (
+            layout.refuses("tiers") is not None and kv_tier is not None
+        )
+        if layout.refuses("tiers"):
+            kv_tier = None
+        if layout.refuses("share"):
+            prefix_store = None
+        self._pools.reset()
         # Cross-job radix prefix store: its pages live in THIS runner's
         # KV pool but the store outlives the session, so the fresh free
         # list above must give them up before any admission. A store
@@ -643,47 +658,6 @@ class ContinuousBatcher:
         # a mismatched page size) resets to empty instead of poisoning
         # the run — the ids are already free here, so forgetting the
         # tree is the only consistent move.
-        # A model that keeps its state a SLOT a sequence
-        # (runner.state_slots, kvcache.StateSlots): no page holds the
-        # state at the end of a shared prefix or of a hibernated row, so
-        # those paths fall back to prefilling again, and say so
-        # (sutro_state_fallback_prefill_tokens_total). A new session's
-        # pages are all free, so every slot is too.
-        self._slot_state = getattr(runner, "state_slots", None) is not None
-        # A model that keeps K/V a POOL A KIND (runner.window_pool,
-        # kvcache.WindowPages): a window layer's pages go back as they
-        # slide out, so a page shared between rows or moved to a tier
-        # has no window page to go with it. The same fallbacks, under
-        # reasons of their own. At the trivial setting (no window_pool:
-        # a page id carries both kinds) prefixes work through the map;
-        # the tiers' payload still has no place for the second pool.
-        self._window_pool = getattr(runner, "window_pool", None)
-        two_kinds = getattr(runner.mcfg, "num_window_layers", 0) > 0
-        # A model of LATENT layers keeps one row a token in one pool
-        # (kvcache.py): the tiers' payload is a K and a V of one width,
-        # and a prefix that rows share or the store keeps would send
-        # every later row through the absorbed form over pages that no
-        # test or measurement has held yet. The same fallbacks, under
-        # reasons of their own: every row prefills its own prompt.
-        self._latent_pool = (
-            getattr(runner.mcfg, "num_latent_layers", 0) > 0
-        )
-        by_blocks = self._block > 1
-        self._tier_refused = (
-            (self._slot_state or two_kinds or self._latent_pool or by_blocks)
-            and kv_tier is not None
-        )
-        if self._slot_state or two_kinds or self._latent_pool or by_blocks:
-            kv_tier = None
-        if (
-            self._slot_state or self._window_pool is not None
-            or self._latent_pool or by_blocks
-        ):
-            prefix_store = None
-        if self._slot_state:
-            runner.reset_state_slots()
-        if self._window_pool is not None:
-            runner.reset_window_pool()
         self._prefix_store = None
         if (
             prefix_store is not None
@@ -1014,28 +988,11 @@ class ContinuousBatcher:
         shared = (lcp // PS) * PS
         if shared < PS:
             return
-        if self._slot_state:
-            # the rows would start from the state after the shared
-            # pages, and only the row that wrote them has it (a snapshot
-            # a stored prefix is not kept): every row prefills its own
-            self._count_state_fallback(
-                shared * (len(pending) - 1), "prefix_without_state_snapshot"
-            )
-            return
-        if self._window_pool is not None:
-            # a shared page's window page would be released by the
-            # first row to slide past it: every row prefills its own
-            self._count_state_fallback(
-                shared * (len(pending) - 1), "prefix_without_window_pages"
-            )
-            return
-        if self._latent_pool or self._block > 1:
-            self._count_state_fallback(
-                shared * (len(pending) - 1),
-                "prefix_on_latent_pool" if self._latent_pool
-                else "prefix_on_block_model",
-            )
-            if self._block > 1 and self._tel_on:
+        why = self._layout.refuses("share")
+        if why is not None:
+            # the pages cannot be shared: every row prefills its own
+            self._count_state_fallback(shared * (len(pending) - 1), why)
+            if why == "prefix_on_block_model" and self._tel_on:
                 telemetry.BLOCK_REFUSALS_TOTAL.inc(1.0, "shared_prefix")
             return
         self._resolve_wave()
@@ -1273,7 +1230,7 @@ class ContinuousBatcher:
             hits.append((key, p))
         if not hits:
             return handle
-        if getattr(self.runner, "has_state", False) and any(
+        if self._layout.has_state and any(
             "c" not in p for _, p in hits
         ):
             # pages without their conv state cannot restore the state
@@ -1328,14 +1285,6 @@ class ContinuousBatcher:
                 float(tokens), reason
             )
 
-    def _release_state(self, own_pages) -> None:
-        """With a row's pages goes its state slot (bound to the first),
-        and its window pages and their reservation."""
-        if self._slot_state and len(own_pages):
-            self.runner.release_state(own_pages[0])
-        if self._window_pool is not None:
-            self.runner.release_window_row(own_pages)
-
     def _reserve(
         self, req: GenRequest, ctx: JobCtx, reserved: int = 0,
         exclude=frozenset(),
@@ -1352,23 +1301,17 @@ class ContinuousBatcher:
         pages and only the remainder is allocated per slot."""
         n = len(req.prompt_ids)
         pfx = ctx.prefix
-        if self._slot_state and self.runner.state_slots.free_count < 1:
-            # admission waits for a state slot as it waits for pages
-            if self._tel_on and any(
+        # admission waits for what the row needs of the other pools as
+        # it waits for pages
+        room = self._pools.room(
+            self._max_total(req), n > self.ecfg.prefill_chunk,
+            (lambda: any(
                 s is None and i not in exclude
                 for i, s in enumerate(self.slots)
-            ):
-                telemetry.STATE_SLOT_WAITS_TOTAL.inc(1.0)
+            )) if self._tel_on else None,
+        )
+        if room is None:
             return None
-        need_w = 0
-        if self._window_pool is not None:
-            # admission waits for pages of BOTH kinds: the row reserves
-            # the most window pages it will hold at once
-            need_w = self.runner.window_budget(
-                self._max_total(req), n > self.ecfg.prefill_chunk
-            )
-            if need_w > self._window_pool.budget_free:
-                return None
 
         def _admit_native():
             if pfx is not None:
@@ -1449,11 +1392,7 @@ class ContinuousBatcher:
                 table[pfx.n_pages : pfx.n_pages + own] = pages
             else:
                 table[: len(pages)] = pages
-        if self._slot_state:
-            # the device is told with the row's prefill
-            self.runner.bind_state([table[0]], flush=False)
-        if need_w and len(pages):
-            self._window_pool.set_budget(pages[0], need_w)
+        self._pools.bind(table, pages, room)
         return free_idx, pages, table
 
     # -- double-buffered admission prep --------------------------------
@@ -1569,7 +1508,7 @@ class ContinuousBatcher:
         """Roll back a reservation whose prefill never armed a slot (a
         raised prefill would otherwise leak the slot's pages forever in a
         long-lived daemon)."""
-        self._release_state(pages)
+        self._pools.release(pages)
         if self.native is not None:
             self.native.release(slot_idx)
         else:
@@ -2204,7 +2143,7 @@ class ContinuousBatcher:
         a partial accept rolls the state back by a gather and no second
         forward. A row released in the loop commits nothing: its pages
         are free, and whoever takes them writes before it reads."""
-        if not getattr(self.runner, "has_state", False):
+        if not self._layout.has_state:
             return
         n = np.zeros((self.B,), np.int32)
         for i in active:
@@ -2534,7 +2473,7 @@ class ContinuousBatcher:
         bookkeeping; the in-flight-window dead-store argument documented
         there covers the pages freed here too."""
         slot = self.slots[i]
-        self._release_state(slot.pages[slot.shared_n :])
+        self._pools.release(slot.pages[slot.shared_n :])
         if self.native is not None:
             self.native.release(i)
         else:
@@ -2702,7 +2641,7 @@ class ContinuousBatcher:
             and not slot.prefilling
         ):
             kept = self._checkpoint_slot(slot)
-        self._release_state(slot.pages[slot.shared_n :])
+        self._pools.release(slot.pages[slot.shared_n :])
         if self.native is not None:
             self.native.release(i)
             if kept and not self.native.reserve_pages(
@@ -2802,7 +2741,7 @@ class ContinuousBatcher:
                 if not greedy:
                     constrained_greedy = False
             room = min(room, len(s.pages) * PS - s.pos)
-        if self._window_pool is not None and active:
+        if active:
             self._slide_windows(active, past_len, table)
         return _DecodeBatch(
             active, last, past_len, table, temp, top_p, top_k, row_seeds,
@@ -2822,24 +2761,16 @@ class ContinuousBatcher:
         )
 
     def _slide_windows(self, active, past_len, table) -> None:
-        """Give back the window pages that have slid out behind each
-        active row's COMMITTED length (``past_len`` here is what the
-        host has accepted: tokens of windows in flight are not in it,
-        so a page one of them still reads is never released), and note
-        how much of its K/V a window layer holds."""
-        rows = np.asarray(active, np.int64)
-        self.runner.release_window_behind(table[rows], past_len[rows])
-        if self._tel_on:
-            PS = self.ecfg.kv_page_size
-            pool = self._window_pool
-            telemetry.KV_WINDOW_PAGES_HELD_TOTAL.inc(float(pool.in_use))
-            telemetry.KV_WINDOW_PAGES_WHOLE_TOTAL.inc(
-                float((-(-past_len[rows].astype(np.int64) // PS)).sum())
-            )
+        """Give back what has slid out behind each active row's
+        COMMITTED length (``past_len`` here is what the host has
+        accepted, ``RowPools.slide``); where something slides, note the
+        full pool's pages beside it."""
+        tel = self._tel_on
+        if self._pools.slide(table, past_len, active, tel) and tel:
             free = self.free_page_count
             telemetry.KV_PAGES.set(float(free), "full", "free")
             telemetry.KV_PAGES.set(
-                float(self.runner.alloc_pages - 1 - free), "full", "used"
+                float(self.runner.num_pages - 1 - free), "full", "used"
             )
 
     def _choose_path(
@@ -2945,13 +2876,13 @@ class ContinuousBatcher:
         """Span attrs of a dispatch that advances ``rows`` rows' slot
         state: the rows, and the bytes of state a step of it reads
         (runner.state_step_bytes). Nothing for any other model."""
-        if not self._slot_state:
+        if not self._layout.state_slots:
             return {}
         attrs = {
             "state_rows": int(rows),
             "state_bytes": int(self.runner.state_step_bytes(rows)),
         }
-        if getattr(self.runner.mcfg, "state_kind", None) == "kda":
+        if self._layout.state_kind == "kda":
             # the delta-rule layers' matrices alone (no conv columns)
             attrs["kda_state_bytes"] = self.runner.state_matrix_bytes(rows)
         return attrs
@@ -2981,9 +2912,9 @@ class ContinuousBatcher:
         the rows of the context and the rows the attention reads.
         ``ctx`` is an iterable of the rows' contexts, not walked for
         any other model: nothing for those."""
-        mcfg = self.runner.mcfg
-        topk = getattr(mcfg, "index_topk", 0)
-        if not getattr(mcfg, "num_window_layers", 0) and not topk:
+        topk = getattr(self.runner.mcfg, "index_topk", 0)
+        window = self._layout.window
+        if not window and not topk:
             return {}
         c = np.fromiter(ctx, np.float64)
         if not c.size:
@@ -3000,7 +2931,7 @@ class ContinuousBatcher:
         return {
             "kv_tokens_full": round(float(c.mean()), 1),
             "kv_tokens_window": round(
-                float(np.minimum(c, mcfg.sliding_window).mean()), 1
+                float(np.minimum(c, window).mean()), 1
             ),
         }
 
@@ -4018,11 +3949,7 @@ class ContinuousBatcher:
                 # layers' K/V) is in no page the tier can take: the
                 # caller's plain suspend regenerates the row
                 self._count_state_fallback(
-                    self.slots[i].pos,
-                    "hibernate_without_slot_state" if self._slot_state
-                    else "hibernate_on_latent_pool" if self._latent_pool
-                    else "hibernate_on_block_model" if self._block > 1
-                    else "hibernate_without_window_pages",
+                    self.slots[i].pos, self._layout.refuses("tiers")
                 )
             return False
         s = self.slots[i]
@@ -4031,7 +3958,7 @@ class ContinuousBatcher:
         ctx = s.job
         PS = self.ecfg.kv_page_size
         end = -(-s.pos // PS)  # ceil: the partial tail page rides along
-        if getattr(self.runner, "has_state", False):
+        if self._layout.has_state:
             # conv state is ONE value a page, the state after the last
             # token written there; windows in flight when the row is
             # preempted have written the tail page past ``pos``, so its
@@ -4112,7 +4039,7 @@ class ContinuousBatcher:
                 payload is not None
                 and int(payload["k"].shape[1]) == hib.n_pages
             )
-            if ok and getattr(self.runner, "has_state", False) and (
+            if ok and self._layout.has_state and (
                 "c" not in payload
             ):
                 # K/V without the conv state would resume the row from

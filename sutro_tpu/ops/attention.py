@@ -53,6 +53,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from . import lowering
+from .pages import (
+    first_live_page, gather_kv_layer, gather_pages, window_span_pages,
+)
 
 NEG_INF = -1e30
 
@@ -128,8 +131,6 @@ def live_pages(page_table, past_len, live_window: int, page_size: int):
     past the table's end repeats its last one; its positions (counted
     from the unclipped slot) are past every ``past_len``, so it is
     masked like any page not yet written."""
-    from ..engine.kvcache import first_live_page, window_span_pages
-
     MP = page_table.shape[1]
     span = min(MP, window_span_pages(live_window, 0, page_size))
     first = first_live_page(past_len, live_window, page_size)
@@ -182,8 +183,6 @@ def paged_decode_xla(
     the dtype they have, accumulation is float32, the probabilities are
     rounded to the values' dtype as the MXU's default precision rounds
     them anyway; masks, softmax and sink are float32."""
-    from ..engine.kvcache import gather_pages
-
     lowering.record_xla_decode()
     B, NH, Dh = q.shape
     PS, KD = k_pages.shape[2:]
@@ -422,8 +421,6 @@ def chunk_attention(
             # kernel does not take). One block of a model that generates
             # by blocks no longer does
             lowering.record_reference("paged_decode")
-        from ..engine.kvcache import gather_kv_layer
-
         past_first = None
         if live_window:
             page_table, past_first = live_pages(

@@ -74,7 +74,7 @@ def runner_of(preset: str, batch: int = B) -> ModelRunner:
             mcfg, ecfg, params=r.params, num_pages=1 + batch * MP,
             window_pages=1 + batch * r.window_span,
         )
-        assert r.window_pool is not None
+        assert r.pools.window is not None
     return r
 
 
@@ -155,8 +155,8 @@ def run(b, reqs, stream=False, **ctx_kw):
 def all_free(b, runner, free0):
     assert b.free_page_count == free0
     assert all(s is None for s in b.slots) and not b._wave
-    if getattr(runner, "state_slots", None) is not None:
-        assert runner.state_slots.in_use == 0
+    if runner.pools.slots is not None:
+        assert runner.pools.slots.in_use == 0
 
 
 def prefill_spans():
@@ -409,7 +409,7 @@ def test_a_dispatch_that_raises_arms_the_rows_before_it(k, monkeypatch):
         assert len(armed) == k - 1 and not b._wave
         assert all(len(s.out_ids) == 1 and s.last_token == s.out_ids[0]
                    for s in armed)
-        assert runner.state_slots.in_use == k - 1
+        assert runner.pools.slots.in_use == k - 1
         assert free0 - b.free_page_count == sum(len(s.pages) for s in armed)
     finally:
         for i, s in enumerate(b.slots):

@@ -66,7 +66,7 @@ def runner():
 def every_slot_free(runner):
     """Each test starts sequences at pages of its own choosing; the
     prefill entry points bind what no scheduler bound."""
-    runner.reset_state_slots()
+    runner.pools.reset()
 
 
 @pytest.fixture(scope="module")
@@ -313,24 +313,24 @@ def test_a_new_sequence_in_a_used_slot_and_used_pages_starts_from_zero(
     table = table_of(1, 2, 3)
     runner.prefill(sequence(19, 23), table)
     step([5], [23], table)
-    slot = runner.state_slots.slot_of(1)
+    slot = runner.pools.slots.slot_of(1)
     assert float(jnp.abs(runner.cache.ssm[:, slot]).max()) > 0
     # the same pages again: the sequence keeps the slot, not the state
     fresh = sequence(20, 20)
     got = runner.prefill(fresh[:3], table)
-    assert runner.state_slots.slot_of(1) == slot
+    assert runner.pools.slots.slot_of(1) == slot
     wanted = want(runner, fresh, [2, 3, 4])
     assert err(got, wanted[0]) < TOL
     assert err(step([fresh[3]], [3], table)[0], wanted[1]) < TOL
     # the slot freed and taken through OTHER pages: nothing was reset
-    runner.release_state(1)
+    runner.pools.release([1])
     other = table_of(9, 10, 11)
-    runner.bind_state([9])
-    assert runner.state_slots.slot_of(9) == slot
+    runner.pools.bind_first([9])
+    assert runner.pools.slots.slot_of(9) == slot
     got = step([fresh[0]], [0], other)[0]     # decoding from position 0
     assert err(got, want(runner, fresh, [0])[0]) < TOL
     assert err(step([fresh[1]], [1], other)[0], want(runner, fresh, [1])[0]) < TOL
-    runner.release_state(9)
+    runner.pools.release([9])
 
 
 def test_two_sequences_in_one_batch_keep_their_own_slots(runner, step):
@@ -382,7 +382,7 @@ def test_through_the_scheduler_greedy_tokens_are_the_references(runner):
         ref = want(runner, seq, range(len(ids) - 1, len(seq) - 1))
         assert list(np.argmax(ref, -1)) == list(got[i])
     # every row gave its slot back
-    assert runner.state_slots.in_use == 0
+    assert runner.pools.slots.in_use == 0
 
 
 def test_rows_that_share_a_prefix_prefill_it_again_and_say_so(runner):
@@ -413,7 +413,7 @@ def test_a_row_that_would_hibernate_regenerates_and_says_so(runner, tmp_path):
                 max_new_tokens=4, temperature=0.0)[0]
     from sutro_tpu.engine.scheduler import _Slot
     i, pages, _ = b._reserve(req, types.SimpleNamespace(prefix=None))
-    assert runner.state_slots.in_use == 1     # bound with the reservation
+    assert runner.pools.slots.in_use == 1     # bound with the reservation
     b.slots[i] = _Slot(req=req, pages=list(pages), pos=21, last_token=1,
                        job=None, shared_n=0)
     before = _fallback("hibernate_without_slot_state")
@@ -422,23 +422,23 @@ def test_a_row_that_would_hibernate_regenerates_and_says_so(runner, tmp_path):
     with pytest.raises(ValueError, match="a slot a sequence"):
         runner.write_pages([1], runner.read_pages([1]))
     b._unreserve(i, pages)
-    assert runner.state_slots.in_use == 0
+    assert runner.pools.slots.in_use == 0
 
 
 def test_admission_waits_for_a_state_slot_as_it_waits_for_pages():
     small = ModelRunner(MCFG, engine(decode_batch_size=4), num_pages=1 + 3)
-    assert small.state_slots.total == 3       # never more than pages
+    assert small.pools.slots.total == 3       # never more than pages
     assert small.cache.ssm.shape[1] == 4      # and the garbage slot
     tok = ByteTokenizer(vocab_size=MCFG.vocab_size)
     b = ContinuousBatcher(small, stop_ids=[])
     got = _run(b, _reqs(tok, ["a", "b", "c", "d", "e"], max_new_tokens=3,
                         temperature=0.0))
-    assert sorted(got) == [0, 1, 2, 3, 4] and small.state_slots.in_use == 0
+    assert sorted(got) == [0, 1, 2, 3, 4] and small.pools.slots.in_use == 0
     # with every slot taken a row is not admitted, pages or no pages
-    small.bind_state([1, 2, 3])
+    small.pools.bind_first([1, 2, 3])
     ctx = types.SimpleNamespace(prefix=None)
     assert b._reserve(_reqs(tok, ["x"], max_new_tokens=2)[0], ctx) is None
-    small.reset_state_slots()
+    small.pools.reset()
 
 
 # -- the check's teeth: the reference with one term changed --------------------
@@ -546,7 +546,7 @@ def test_the_state_is_a_slot_a_sequence_beside_the_pages(runner):
     assert per_slot * 5 == info["state_bytes"]
     assert runner.state_step_bytes(3) == 3 * per_slot
     # the pages carry K/V alone
-    assert runner._page_bytes_per_device(jnp.dtype("float32")) == (
+    assert runner._page_bytes_per_device() == (
         2 * 2 * PS * 64 * 4
     )
 
@@ -576,7 +576,7 @@ def test_the_other_families_programs_are_what_they_were():
                 m.position_embedding, m.num_mamba_layers) == (
             1.0, 1.0, 1.0, None, "rope", 0)
     r = ModelRunner(MODEL_CONFIGS["tiny-lfm2"], engine())
-    assert r.state_slots is None and r.cache.ssm is None
+    assert r.pools.slots is None and r.cache.ssm is None
     assert r.cache.conv is not None and r.state_step_bytes(4) == 0
 
 

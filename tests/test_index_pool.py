@@ -73,25 +73,21 @@ def test_two_pools_on_one_page_table_and_no_v_pool(runner):
 
 
 def test_a_pages_bytes_count_both_pools(runner):
-    f32 = jnp.dtype(jnp.float32)
-    assert runner._page_bytes_per_device(f32) == 4 * PS * (128 + 24) * 4
-    big = types.SimpleNamespace(
-        mcfg=MODEL_CONFIGS["glm-5-l5-ep16"], ecfg=engine(kv_page_size=64),
-        _cache_sharding=None,
-    )
-    page = ModelRunner._page_bytes_per_device(big, jnp.dtype(jnp.bfloat16))
+    assert runner._page_bytes_per_device() == 4 * PS * (128 + 24) * 4
+    big = types.SimpleNamespace(_sized=kvcache.cache_layout(
+        MODEL_CONFIGS["glm-5-l5-ep16"], engine(kv_page_size=64), 2,
+    ))
+    page = ModelRunner._page_bytes_per_device(big)
     # 5 layers x 64 tokens x (640 + 128) lanes x 2 bytes = 7,680 a token
     assert page == 5 * 64 * (1280 + 256) == 491_520
     for name, want_bytes in (
         ("joyai-llm-flash-ep16", 40 * 64 * 1280),           # as before
         ("qwen3-4b", 2 * 36 * 64 * 1024 * 2),
     ):
-        other = types.SimpleNamespace(
-            mcfg=MODEL_CONFIGS[name], ecfg=engine(kv_page_size=64),
-            _cache_sharding=None,
-        )
-        assert ModelRunner._page_bytes_per_device(
-            other, jnp.dtype(jnp.bfloat16)) == want_bytes
+        other = types.SimpleNamespace(_sized=kvcache.cache_layout(
+            MODEL_CONFIGS[name], engine(kv_page_size=64), 2,
+        ))
+        assert ModelRunner._page_bytes_per_device(other) == want_bytes
 
 
 def test_pages_that_fit_divides_what_is_left_by_both_pools_bytes():
@@ -100,16 +96,15 @@ def test_pages_that_fit_divides_what_is_left_by_both_pools_bytes():
     dev = types.SimpleNamespace(memory_stats=lambda: stats, device_kind="fake")
     fake = types.SimpleNamespace(
         mcfg=MODEL_CONFIGS["glm-5-l5-ep16"], ecfg=ecfg, mesh=None,
-        params={}, _cache_sharding=None, _margin_pages=0, window_span=0,
-        n_devices=1,
+        params={}, _margin_pages=0, window_span=0, n_devices=1,
+        _sized=kvcache.cache_layout(MODEL_CONFIGS["glm-5-l5-ep16"], ecfg, 2),
     )
     fake._page_bytes_per_device = types.MethodType(
         ModelRunner._page_bytes_per_device, fake)
     real = jax.devices
     jax.devices = lambda *a: [dev]
     try:
-        fit, win = ModelRunner._pages_that_fit(
-            fake, 10_000, 0, jnp.dtype(jnp.bfloat16))
+        fit, win = ModelRunner._pages_that_fit(fake, 10_000, 0)
     finally:
         jax.devices = real
     assert win == 0 and fit == 200_000_000 // 491_520
@@ -260,7 +255,8 @@ def test_a_shared_prefix_and_a_tier_fall_back_under_the_latent_pools_reasons(
     tier = types.SimpleNamespace(page_size=PS)
     b = ContinuousBatcher(
         runner, stop_ids=[], prefix_store=PrefixStore(PS), kv_tier=tier)
-    assert b._prefix_store is None and b._latent_pool
+    assert b._prefix_store is None
+    assert b._layout.refuses("share") == "prefix_on_latent_pool"
     assert b._kv_tier is None and b._tier_refused and not b._can_hibernate
     out = {}
     reqs = [GenRequest(row_id=i, prompt_ids=np.array(tok.encode(p), np.int32),

@@ -78,23 +78,20 @@ def test_one_pool_of_latent_rows_and_no_v_pool(runner):
 
 
 def test_a_pages_bytes_are_the_latent_rows_alone(runner):
-    f32 = jnp.dtype(jnp.float32)
-    assert runner._page_bytes_per_device(f32) == 4 * PS * 128 * 4
-    big = types.SimpleNamespace(
-        mcfg=MODEL_CONFIGS["joyai-llm-flash-ep16"],
-        ecfg=engine(kv_page_size=64), _cache_sharding=None,
-    )
-    page = ModelRunner._page_bytes_per_device(big, jnp.dtype(jnp.bfloat16))
+    assert runner._page_bytes_per_device() == 4 * PS * 128 * 4
+    big = types.SimpleNamespace(_sized=kvcache.cache_layout(
+        MODEL_CONFIGS["joyai-llm-flash-ep16"], engine(kv_page_size=64), 2,
+    ))
+    page = ModelRunner._page_bytes_per_device(big)
     # 40 layers x 64 tokens x 640 lanes x 2 bytes: what the device keeps
     # of rows of 576 (46,080 bytes a token in use), where 32 heads of K
     # (192) and V (128) would be 819,200 a token
     assert page == 40 * 64 * 1280 == 64 * 51_200
-    dense = types.SimpleNamespace(
-        mcfg=MODEL_CONFIGS["qwen3-4b"], ecfg=engine(kv_page_size=64),
-        _cache_sharding=None,
-    )
+    dense = types.SimpleNamespace(_sized=kvcache.cache_layout(
+        MODEL_CONFIGS["qwen3-4b"], engine(kv_page_size=64), 2,
+    ))
     assert ModelRunner._page_bytes_per_device(
-        dense, jnp.dtype(jnp.bfloat16)
+        dense
     ) == 2 * 36 * 64 * 1024 * 2                     # K and V, as before
     # the margin's chunk is counted from the same width (the Pallas
     # path's; 0 where the kernels are off)
@@ -109,16 +106,16 @@ def test_pages_that_fit_divides_what_is_left_by_a_latent_pages_bytes(runner):
     dev = types.SimpleNamespace(memory_stats=lambda: stats, device_kind="fake")
     fake = types.SimpleNamespace(
         mcfg=MODEL_CONFIGS["joyai-llm-flash-ep16"], ecfg=ecfg, mesh=None,
-        params={}, _cache_sharding=None, _margin_pages=0, window_span=0,
-        n_devices=1,
+        params={}, _margin_pages=0, window_span=0, n_devices=1,
+        _sized=kvcache.cache_layout(
+            MODEL_CONFIGS["joyai-llm-flash-ep16"], ecfg, 2),
     )
     fake._page_bytes_per_device = types.MethodType(
         ModelRunner._page_bytes_per_device, fake)
     real = jax.devices
     jax.devices = lambda *a: [dev]
     try:
-        fit, win = ModelRunner._pages_that_fit(
-            fake, 10_000, 0, jnp.dtype(jnp.bfloat16))
+        fit, win = ModelRunner._pages_that_fit(fake, 10_000, 0)
     finally:
         jax.devices = real
     page = 40 * 64 * 1280                  # rows of 640 lanes
@@ -210,7 +207,8 @@ def test_a_shared_prefix_and_the_store_fall_back_to_each_rows_own_prefill(runner
     before = _fallback("prefix_on_latent_pool")
     store = PrefixStore(PS)
     b = ContinuousBatcher(runner, stop_ids=[], prefix_store=store)
-    assert b._prefix_store is None and b._latent_pool
+    assert b._prefix_store is None
+    assert b._layout.refuses("share") == "prefix_on_latent_pool"
     out = {}
     reqs = [GenRequest(row_id=i, prompt_ids=np.array(tok.encode(p), np.int32),
                        max_new_tokens=4, temperature=0.0)

@@ -449,7 +449,7 @@ def test_the_pool_spans_the_attention_layers_and_the_state_is_beside_it(runner):
     # page-major and flat: a row a page, layer by layer
     assert runner.cache.conv.shape == (runner.num_pages, 5 * 2 * 128)
     assert info["state_bytes"] == runner.cache.conv.nbytes
-    per_page = runner._page_bytes_per_device(jnp.dtype("float32"))
+    per_page = runner._page_bytes_per_device()
     assert per_page == 2 * PS * 64 * 4 + 5 * 2 * 128 * 4
 
 

@@ -59,7 +59,7 @@ def two_pools(one_pool_id):
         MCFG, engine(), params=one_pool_id.params, num_pages=1 + B * MP,
         window_pages=1 + B * one_pool_id.window_span,
     )
-    assert r.window_pool is not None
+    assert r.pools.window is not None
     return r
 
 
@@ -105,7 +105,7 @@ def test_through_the_scheduler_greedy_tokens_are_the_references(two_pools):
             list(range(len(ids) - 15, len(ids) - 1)),
         ))
         assert got[i] == [int(t) for t in want.argmax(-1)]
-    assert two_pools.window_pool.released_total > 0
+    assert two_pools.pools.window.released_total > 0
 
 
 def test_rows_that_share_a_prefix_prefill_it_again_and_say_so(two_pools):
@@ -153,8 +153,8 @@ def test_a_row_that_would_hibernate_regenerates_and_says_so(which, request):
         runner.write_pages([1], {})
     b.slots[i] = None
     b._unreserve(i, pages)
-    if runner.window_pool is not None:
-        assert runner.window_pool.budget_free == runner.window_pool.total
+    if runner.pools.window is not None:
+        assert runner.pools.window.budget_free == runner.pools.window.total
 
 
 def test_tensor_parallel_works_through_the_identity_map(two_pools):
@@ -163,7 +163,7 @@ def test_tensor_parallel_works_through_the_identity_map(two_pools):
     if jax.device_count() < 2:
         pytest.skip("needs two host devices")
     r = ModelRunner(MCFG, engine(tp=2), num_pages=1 + B * MP)
-    assert r.mesh is not None and r.window_pool is None
+    assert r.mesh is not None and r.pools.window is None
     assert r.cache.wk_pages.sharding.spec == r.cache.k_pages.sharding.spec
     want = run(
         ContinuousBatcher(

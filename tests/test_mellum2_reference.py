@@ -68,7 +68,7 @@ def make_runner(request, monkeypatch):
                 MCFG, ecfg, params=r.params, num_pages=1 + B * MP,
                 window_pages=1 + B * r.window_span,
             )
-            assert r.window_pool is not None
+            assert r.pools.window is not None
         return r
 
     return make
@@ -133,7 +133,7 @@ def test_prefill_past_the_window_and_the_benchmarks_steps(make_runner):
     size (the identity map), prefill, then single steps of given tokens
     through ``_trunk_decode`` and ``write_kv`` with ONE table."""
     r = make_runner(two_pools=False)
-    assert r.window_pool is None
+    assert r.pools.window is None
     assert np.array_equal(
         np.asarray(r.cache.window_page), np.arange(r.num_pages)
     )
@@ -171,7 +171,7 @@ def test_chunked_prefill_over_a_paged_window_past(make_runner):
     (ids,) = rows(2, [41])
     table = tables(1)[0]
     close(r.prefill(ids, table), reference(r.params, ids)[-1])
-    pool = r.window_pool
+    pool = r.pools.window
     # what is left bound is the window at the prompt's end, no more
     assert pool.released_total > 0
     assert pool.in_use <= (W + PS - 2) // PS + 1
@@ -185,7 +185,7 @@ def test_single_steps_across_pages_and_a_release(make_runner):
     t = tables(3)
     for i, (s, n) in enumerate(zip(seqs, n0)):
         close(r.prefill(s[:n], t[i]), want[i][n - 1])
-    pool, key = r.window_pool, jax.random.PRNGKey(0)
+    pool, key = r.pools.window, jax.random.PRNGKey(0)
     for j in range(16):
         past = np.array([n + j for n in n0] + [0], np.int32)
         last = np.array([s[p] for s, p in zip(seqs, past)] + [0], np.int32)
@@ -197,7 +197,7 @@ def test_single_steps_across_pages_and_a_release(make_runner):
             assert tok[i] == int(np.argmax(at))
             assert abs(logp[i] - logp_of(at, tok[i])) < 1e-3
         # the scheduler's part: committed lengths, then release
-        r.release_window_behind(t[:3], past[:3] + 1)
+        r.pools.release_behind(t[:3], past[:3] + 1)
         assert pool.in_use <= 3 * ((W + PS - 2) // PS + 1)
     assert pool.released_total >= 3 * (16 // PS - 1)
 
@@ -228,8 +228,8 @@ def test_fused_windows_chained_across_a_release(make_runner):
             got_lp[i] += [float(x) for x in logps[:, i]]
         past = past + np.array([steps, steps, 0, 0], np.int32)
         last = np.array([o[-1] for o in out] + [0, 0], np.int32)
-        r.release_window_behind(t[:2], past[:2])
-    assert r.window_pool.released_total > 0
+        r.pools.release_behind(t[:2], past[:2])
+    assert r.pools.window.released_total > 0
     for i in range(2):
         want = reference(r.params, np.array(out[i][:-1], np.int32))
         n = len(seqs[i])
@@ -247,7 +247,7 @@ def test_a_verify_chunk_over_the_paged_past(make_runner):
     want = reference(r.params, ids)
     t = tables(1)
     r.prefill(ids[:17], t[0])
-    r.release_window_behind(t[:1], [17])
+    r.pools.release_behind(t[:1], [17])
     K = 6
     drafts = np.zeros((B, K), np.int32)
     drafts[0] = ids[18 : 18 + K]
@@ -262,7 +262,7 @@ def test_a_verify_chunk_over_the_paged_past(make_runner):
         assert abs(pl[0, j] - logp_of(at, pt[0, j])) < 1e-3
     # and a step after the chunk reads what the chunk wrote
     past = np.array([17 + K + 1, 0, 0, 0], np.int32)
-    r.release_window_behind(t[:1], past[:1])
+    r.pools.release_behind(t[:1], past[:1])
     tok, logp = r.decode_step(
         np.array([ids[past[0]], 0, 0, 0], np.int32), past, t,
         jax.random.PRNGKey(0), np.zeros(B, np.float32), np.ones(B, np.float32),

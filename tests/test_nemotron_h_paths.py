@@ -66,7 +66,7 @@ def runner():
 
 @pytest.fixture(autouse=True)
 def every_slot_free(runner):
-    runner.reset_state_slots()
+    runner.pools.reset()
 
 
 @pytest.fixture(scope="module")
@@ -367,7 +367,7 @@ def test_through_the_scheduler_greedy_tokens_are_the_references(runner):
         seq = np.concatenate([ids, out[i].token_ids]).astype(np.int32)
         ref = want(runner, seq, range(len(ids) - 1, len(seq) - 1))
         assert list(np.argmax(ref, -1)) == list(out[i].token_ids)
-    assert runner.state_slots.in_use == 0
+    assert runner.pools.slots.in_use == 0
     # the spans say what this chip holds and what landed on it
     attrs = b._route_attrs["decode_window"]
     assert attrs["experts_held"] == MCFG.experts_held

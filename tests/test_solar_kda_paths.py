@@ -42,7 +42,7 @@ def runner():
 
 @pytest.fixture(autouse=True)
 def every_slot_free(runner):
-    runner.reset_state_slots()
+    runner.pools.reset()
 
 
 @pytest.fixture(scope="module")
@@ -270,7 +270,7 @@ def test_through_the_scheduler_tokens_slots_spans_and_counters(runner):
         seq = np.concatenate([ids, out[i].token_ids]).astype(np.int32)
         ref = want(runner, seq, range(len(ids) - 1, len(seq) - 1))
         assert list(np.argmax(ref, -1)) == list(out[i].token_ids)
-    assert runner.state_slots.in_use == 0 and runner.state_slots.total == 4
+    assert runner.pools.slots.in_use == 0 and runner.pools.slots.total == 4
     attrs = b._tel_attrs["decode_window"]
     assert attrs["kda_state_bytes"] == runner.state_matrix_bytes(
         attrs["state_rows"])

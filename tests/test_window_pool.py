@@ -105,7 +105,7 @@ def test_span_is_the_window_and_the_tokens_in_flight():
 
 def test_a_page_goes_back_exactly_when_its_last_position_leaves_the_window(params):
     r = make(params, window_pages=1 + B * 9)
-    pool = r.window_pool
+    pool = r.pools.window
     table = np.zeros((MP,), np.int32)
     table[:] = np.arange(1, 1 + MP)
     ids = np.arange(3, 33, dtype=np.int32)
@@ -114,8 +114,8 @@ def test_a_page_goes_back_exactly_when_its_last_position_leaves_the_window(param
     # at 20 sees 13..19, pages 3 and 4
     assert sorted(np.nonzero(pool.of_page)[0]) == [4, 5]
     for committed in range(20, 33):
-        r._bind_window(table[None], [committed - 1], [1])
-        r.release_window_behind(table[None], [committed])
+        r.pools.bind_written(table[None], [committed - 1], [1])
+        r.pools.release_behind(table[None], [committed])
         held = set(np.nonzero(pool.of_page)[0] - 1)
         first = max(committed - W + 1, 0) // PS
         # page j holds positions 4j..4j+3: held iff a query at
@@ -124,7 +124,7 @@ def test_a_page_goes_back_exactly_when_its_last_position_leaves_the_window(param
         assert all(4 * j + 3 >= committed - W + 1 for j in held)
     assert pool.released_total == first - 3
     # the device's map agrees with the host's
-    r._flush_window()
+    r.pools._flush_window()
     assert np.array_equal(np.asarray(r.cache.window_page), pool.of_page)
 
 
@@ -135,12 +135,12 @@ def test_no_page_a_dispatch_reads_or_writes_is_unbound(params, monkeypatch):
     token the dispatch writes, and never holds more than its span. A
     release ahead of the committed length would fail the first."""
     r = make(params, window_pages=1 + B * 9, decode_lookahead=2)
-    pool = r.window_pool
-    flush = r._flush_window
+    pool = r.pools.window
+    bind_written = r.pools.bind_written
     seen = []
 
     def bind(page_tables, starts, lens):
-        ModelRunner._bind_window(r, page_tables, starts, lens)
+        bind_written(page_tables, starts, lens)
         tables = np.asarray(page_tables).reshape(-1, MP)
         for t, s, n in zip(tables, np.ravel(starts), np.ravel(lens)):
             if not t[0] or n <= 0:
@@ -152,8 +152,7 @@ def test_no_page_a_dispatch_reads_or_writes_is_unbound(params, monkeypatch):
             assert pool.of_page[need[need > 0]].all(), (s, n)
             seen.append(int((pool.of_page[t[t > 0]] > 0).sum()))
 
-    monkeypatch.setattr(r, "_bind_window", bind)
-    monkeypatch.setattr(r, "_flush_window", flush)
+    monkeypatch.setattr(r.pools, "bind_written", bind)
     _, got = run(r, requests(6, new=30))
     assert seen and max(seen) <= r.window_span
     assert pool.released_total > 0
@@ -186,16 +185,16 @@ def test_admission_waits_for_window_pages_as_for_pages(params):
         ContinuousBatcher._build_batch = build
     assert max(live) == 1 and len(got) == 3
     assert all(len(t) == 12 for t in got.values())
-    assert r.window_pool.budget_free == r.window_pool.total
+    assert r.pools.window.budget_free == r.pools.window.total
     _, want = run(probe, requests(3, new=12))
     assert got == want
 
 
 def test_a_row_reserves_its_span_and_a_chunked_one_twice(params):
     r = make(params, window_pages=1 + B * 9)
-    assert r.window_budget(2, False) == 1
-    assert r.window_budget(PS * MP, False) == r.window_span
-    assert r.window_budget(PS * MP, True) == min(MP, 2 * r.window_span)
+    assert r.pools.room(2, False) == 1
+    assert r.pools.room(PS * MP, False) == r.window_span
+    assert r.pools.room(PS * MP, True) == min(MP, 2 * r.window_span)
 
 
 # -- the trivial setting -------------------------------------------------------
@@ -207,12 +206,12 @@ def test_a_runner_given_its_pools_size_maps_every_page_to_itself(params):
     released; no setting chose it."""
     r = ModelRunner(MCFG, engine(), params=params, num_pages=1 + MP)
     c = r.cache
-    assert r.window_pool is None
+    assert r.pools.window is None
     assert c.wk_pages.shape == (3, 1 + MP, PS, MCFG.kv_size)
     assert c.k_pages.shape == (1, 1 + MP, PS, MCFG.kv_size)
     table = jnp.arange(1, 1 + MP, dtype=jnp.int32)[None]
     assert np.array_equal(np.asarray(window_table(c, table)), np.asarray(table))
-    assert r.release_window_behind(np.asarray(table), [40]) == 0
+    assert r.pools.release_behind(np.asarray(table), [40]) == 0
     info = r.device_info()
     assert info["window_layers"] == 3
     assert info["window_pool_pages"] == info["pool_pages"]
@@ -223,10 +222,10 @@ def test_a_runner_given_its_pools_size_maps_every_page_to_itself(params):
 def test_a_model_with_one_kind_keeps_one_pool():
     r = ModelRunner(MODEL_CONFIGS["tiny-dense"], engine(), num_pages=9)
     assert r.cache.wk_pages is None and r.cache.window_page is None
-    assert r.window_pool is None and r.window_span == 0
+    assert r.pools.window is None and r.window_span == 0
     # gpt-oss's alternating windows are masks over one pool
     oss = ModelRunner(MODEL_CONFIGS["tiny-oss"], engine(), num_pages=9)
-    assert oss.cache.wk_pages is None and oss.window_pool is None
+    assert oss.cache.wk_pages is None and oss.pools.window is None
 
 
 # -- the sizes ------------------------------------------------------------------

@@ -121,7 +121,7 @@ def eight_steps_and_a_window(runner, step, accepted=None):
     zeros, ones = np.zeros((B,), np.float32), np.ones((B,), np.float32)
 
     def prefill():
-        runner.reset_state_slots()
+        runner.pools.reset()
         first = np.argmax(runner.prefill_batch(prompts, tables), axis=-1)
         return np.concatenate([first, np.zeros((pad,), first.dtype)])
 
