@@ -53,9 +53,11 @@ def next_bucket(n: int, lo: int = 16, hi: int = 1 << 20) -> int:
 
 #: share of the device's memory limit kept free of weights and KV pool
 #: for what the compiled programs allocate while they run (activations,
-#: logits, the gathered-page attention of chunked prefill and verify
-#: forwards, XLA scratch). At qwen3-4b on a 16 GB v5e the largest
-#: program temporaries measured ~2 GB (PERF.md "Bring-up"); 20% is 3.1 GB.
+#: logits, XLA scratch, and the gathered-page attention of the chunked
+#: prefills and verify forwards whose heads ops/pallas_chunk.py does not
+#: take: the others read their pages in place). At qwen3-4b on a 16 GB
+#: v5e the largest program temporaries measured ~2 GB (PERF.md
+#: "Bring-up"); 20% is 3.1 GB.
 HBM_RESERVE_FRACTION = 0.2
 
 
@@ -150,6 +152,9 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         # the paged decode kernel's traces by the rows a grid step takes
         "paged_decode_rows_per_step": lowering.paged_decode_rows_per_step(),
         "paged_decode_xla": lowering.xla_decode_count(),
+        # a chunk of several tokens over a paged past: the kernel that
+        # reads the pages in place, or the gather (``reference``)
+        "paged_chunk": lowering.paged_chunk_counts(),
         "grouped_matmul": lowering.grouped_matmul_counts(),
         "moe_combine": lowering.moe_combine_counts(),
         "ssm_state_read": lowering.ssm_state_read_counts(),

@@ -10,7 +10,8 @@ hot path. Three implementations sit behind one signature:
 - ``paged_decode_xla``: one decode step (T=1) over a paged past in plain
   XLA, on the gathered pages in the layout the pool gave them, for every
   call the paged kernel does not take, and
-- Pallas flash/paged kernels (ops/pallas_flash.py, ops/pallas_paged.py),
+- Pallas flash/paged kernels (ops/pallas_flash.py, ops/pallas_paged.py;
+  ops/pallas_chunk.py for a chunk of several tokens over a paged past),
   dispatched with ``use_pallas=True`` on TPU. Their shape gates send
   unsupported calls to the XLA paths; every such call is counted
   (ops/lowering.py) so a chip run can tell which path it built.
@@ -30,7 +31,8 @@ under one softmax, in plain XLA a block of queries at a time. Under
 ``use_pallas`` one decode step takes the paged kernel's latent variant
 (one fetch of a row's pages for both products) and a chunk with no past
 the flash kernel at zero-padded heads (``_latent_kernels``); a chunk of
-several tokens over a paged past gathers, as ``chunk_attention``'s does.
+several tokens over a paged past still gathers (``chunk_attention``'s
+reads the pages in place since ops/pallas_chunk.py).
 A latent layer with an INDEXER (learned sparse attention) runs its
 softmax over the positions the indexer selects: ops/sparse_attention.py,
 which falls to ``latent_attention`` while the selection is everything.
@@ -413,14 +415,30 @@ def chunk_attention(
                 q, past_k_pages, past_v_pages, layer, page_table, past_len,
                 k, v, win_k=win_k, win_v=win_v, win_len=win_len,
             )
+    if past_k_pages is not None and T > 1 and block_length == 1 and use_pallas:
+        # a chunk of several tokens over a paged past (a verify forward,
+        # a job's suffix over its shared prefix, a chunk of a chunked
+        # prefill) reads the row's pages where they lie, for the pages
+        # the row holds (ops/pallas_chunk.py). No gather
+        from .pallas_chunk import paged_chunk_attention, paged_chunk_supported
+
+        if paged_chunk_supported(
+            q, past_k_pages, k_scale=past_k_scale, sink=sink, win_k=win_k,
+            live_window=live_window, kernel_mesh=kernel_mesh,
+        ):
+            return paged_chunk_attention(
+                q, k, v, past_k_pages, past_v_pages, layer, page_table,
+                past_len, valid_len, window,
+            )
     if past_k_pages is not None:
         if use_pallas:
-            # a chunk of several tokens over a paged past GATHERS the
-            # row's whole table, by design: a verify forward and a chunk
-            # of a chunked prefill (and a block, above, whose heads the
-            # kernel does not take). One block of a model that generates
-            # by blocks no longer does
+            # what neither kernel above takes GATHERS the row's whole
+            # table: a chunk whose heads, pages or operands
+            # ``paged_chunk_supported`` refuses (heads of 64, int8 K/V,
+            # a sink, a window pool, a mesh) and a block whose heads the
+            # block form does not take
             lowering.record_reference("paged_decode")
+            lowering.record_reference(lowering.PAGED_CHUNK)
         past_first = None
         if live_window:
             page_table, past_first = live_pages(
@@ -542,7 +560,7 @@ def _latent_kernels(q, k, v, *, scale, pages, layer, page_table, past_len,
     and show in the prefill roofline) and V at its own width (128),
     under the layer's own scale, the operands in the dtype they have.
     A chunk of several tokens over a paged past (chunked prefill, verify
-    forwards) gathers by design, as ``chunk_attention``'s does."""
+    forwards) still gathers the latent pages."""
     B, T, NH, Dq = q.shape
     if v is None and T == 1:
         from .pallas_paged import paged_decode_attention, paged_decode_supported

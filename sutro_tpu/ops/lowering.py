@@ -11,8 +11,9 @@ every gate that falls through records it here:
   goes to Mosaic when the program compiles;
 - ``interpreted``: traced with ``interpret=True`` (CPU tests);
 - ``reference``: ``use_pallas=True`` was asked for and the jnp / XLA
-  path ran instead (by a shape gate, or by design: T>1 over a paged
-  past gathers the pages).
+  path ran instead (by a shape gate: a chunk of T>1 tokens over a paged
+  past that ops/pallas_chunk.py's gate refuses gathers the pages, and
+  is counted under ``paged_decode`` here and by ``paged_chunk_counts``).
 
 Counts are per TRACE, not per execution — jit caches traces, so a count
 says "this path was built into a program at least that many times",
@@ -42,6 +43,10 @@ SSM_STATE_READ = "ssm_state_read"
 #: head a group, counted under its own name
 KDA_STATE_READ = "kda_state_read"
 KDA_STATE_COMMIT = "kda_state_commit"
+#: likewise (``paged_chunk_counts``): a chunk of several tokens over a
+#: paged past (ops/pallas_chunk.py), which a job that is one prefill and
+#: single decode steps never traces
+PAGED_CHUNK = "paged_chunk"
 
 #: the PARTS of a step program: the outermost ``jax.named_scope`` of
 #: every op a step issues, so that a profiler trace's optimized HLO says
@@ -75,7 +80,8 @@ _lock = threading.Lock()
 _counts: Dict[str, Dict[str, int]] = {
     k: dict.fromkeys(PATHS, 0)
     for k in KERNELS + (
-        GROUPED, SSM_STATE_READ, KDA_STATE_READ, KDA_STATE_COMMIT
+        GROUPED, SSM_STATE_READ, KDA_STATE_READ, KDA_STATE_COMMIT,
+        PAGED_CHUNK,
     )
 }
 _xla_decode = 0
@@ -260,6 +266,22 @@ def kda_state_commit_counts() -> Dict[str, int]:
     that stays on the gather, product and scatter (``reference``)."""
     with _lock:
         return dict(_counts[KDA_STATE_COMMIT])
+
+
+def paged_chunk_counts() -> Dict[str, int]:
+    """Traces of a chunk of several tokens over a PAGED past (a verify
+    forward, the suffix of a job's rows over its shared prefix, a chunk
+    of a chunked prefill; ``ops/attention.chunk_attention`` with
+    ``T > 1`` under the causal mask), by path:
+    ``ops/pallas_chunk.paged_chunk_attention``'s body, which reads the
+    row's pages where they lie (``lowered`` / ``interpreted``), and a
+    ``use_pallas=True`` call its gate refuses, which gathers the row's
+    whole table (``reference``; counted under ``paged_decode`` in
+    ``snapshot()`` too, as it always was). A count of its own for the
+    reason ``grouped_matmul_counts`` has one: a job of whole-prompt
+    prefills and single decode steps never builds such a program."""
+    with _lock:
+        return dict(_counts[PAGED_CHUNK])
 
 
 def snapshot() -> Dict[str, Dict[str, int]]:
