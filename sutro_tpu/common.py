@@ -73,6 +73,7 @@ ModelOptions = Union[
         "mellum2-12b-a2.5b",
         "nemotron-3-nano-30b-a3b",
         "sdar-30b-a3b-chat",
+        "laguna-s-2.1",
         "qwen-3-embedding-0.6b",
         "qwen-3-embedding-6b",
         "qwen-3-embedding-8b",
@@ -117,6 +118,7 @@ def model_catalog() -> Dict[str, Dict[str, Any]]:
     # generates by diffusion over blocks (models/configs.py): a job may
     # state denoising_steps, remasking, confidence_threshold
     add("sdar-30b-a3b-chat", "sdar-30b-a3b-chat")
+    add("laguna-s-2.1", "laguna-s-2.1")
     add("qwen-3-embedding-0.6b", "qwen3-emb-0.6b", embedding=True)
     add("qwen-3-embedding-6b", "qwen3-emb-6b", embedding=True)
     add("qwen-3-embedding-8b", "qwen3-emb-8b", embedding=True)

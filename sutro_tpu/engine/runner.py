@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional, Tuple
+from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -155,6 +155,10 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         # a chunk of several tokens over a paged past: the kernel that
         # reads the pages in place, or the gather (``reference``)
         "paged_chunk": lowering.paged_chunk_counts(),
+        # the attention kernels' traces by the call's query heads (a
+        # model whose window layers have 72 and whose full layers 48):
+        # Pallas or XLA each, and the gate that sent a call to XLA
+        "kernel_heads": lowering.kernel_heads_counts(),
         "grouped_matmul": lowering.grouped_matmul_counts(),
         "moe_combine": lowering.moe_combine_counts(),
         "ssm_state_read": lowering.ssm_state_read_counts(),
@@ -515,13 +519,18 @@ class ModelRunner:
         )
 
     def _tell_window(self, ids, wpages) -> None:
-        """The page ids whose window page changed (pads: page id 0 <- 0)."""
-        m = next_bucket(len(ids), lo=64)
-        pad = np.zeros((2, m), np.int32)
-        pad[0, : len(ids)], pad[1, : len(ids)] = ids, wpages
-        self.cache = self._bind_window_jit(
-            self.cache, jnp.asarray(pad[0]), jnp.asarray(pad[1])
-        )
+        """The page ids whose window page changed, 64 a dispatch (pads:
+        page id 0 <- 0): ONE shape, so a burst (a job's sixteen rows
+        released together were 160 ids and more) takes several
+        dispatches and compiles nothing while requests are served."""
+        m = 64
+        for at in range(0, len(ids), m):
+            n = min(m, len(ids) - at)
+            pad = np.zeros((2, m), np.int32)
+            pad[0, :n], pad[1, :n] = ids[at : at + n], wpages[at : at + n]
+            self.cache = self._bind_window_jit(
+                self.cache, jnp.asarray(pad[0]), jnp.asarray(pad[1])
+            )
 
     def _window_pool_of(self, cache: KVCache, page_table):
         """``transformer.forward``'s ``window_pool``; None for a model
@@ -547,8 +556,14 @@ class ModelRunner:
         of the window layers' pool, 0 for a model with one pool or at
         the trivial setting, where a page carries both kinds), or as
         many as the device's memory limit holds
-        beside what is already resident (the weights) and the reserve,
-        divided between the kinds in the proportion asked for.
+        beside what is already resident (the weights) and the reserve.
+        Short of memory the FULL pool gives way first, down to one
+        row's table: its worst case is every row at ``max_model_len``,
+        which no traffic holds, while the window pool's is a span a row
+        of the batch, which every full batch of rows past the window
+        holds (divided in the proportion asked for, a batch of 128 rows
+        of 8,192 beside 6.4 GB of weights kept window pages for 54
+        rows: PERF.md section 6, PR 61).
         The scheduler admits against free pages, so a pool smaller than
         the worst case is a supported state; a pool too small for ONE
         full-context row is not, and raises here with the budget —
@@ -605,10 +620,15 @@ class ModelRunner:
                 page, wpage = page + wpage, 0   # one id, both kinds
         need = want * page + want_window * wpage
         if want_window:
-            # short of memory both pools shrink together; the window
-            # pool never under one row's span
-            fit_window = want_window if need <= avail else max(
-                int(want_window * avail / need), 1 + self.window_span
+            # short of memory the full pool shrinks first; the window
+            # pool only once the full pool is at one row's table, and
+            # never under one row's span
+            floor_full = (
+                1 + self.ecfg.max_pages_per_seq + self._margin_pages
+            ) * page
+            fit_window = want_window if need <= avail else min(
+                want_window,
+                max((avail - floor_full) // wpage, 1 + self.window_span),
             )
             avail -= fit_window * wpage
         fit = avail // page - self._margin_pages
@@ -1077,6 +1097,27 @@ class ModelRunner:
         self._route_dev = route
         return np.asarray(logits[:n])
 
+    def prefill_bucket(self, rows: int, maxlen: int) -> Tuple[int, int]:
+        """``(rows, length)`` of the program a prefill dispatch of
+        ``rows`` rows, the longest of ``maxlen`` tokens, runs: both
+        rounded up to the next bucket. THE place that says it: the
+        prefill entry points build their arrays at it and the scheduler
+        counts a dispatch's padding from it."""
+        T = next_bucket(max(maxlen, 1), lo=16, hi=self.ecfg.max_context())
+        if T % self.sp:  # ring prefill shards T over the seq axis
+            T = -(-T // self.sp) * self.sp
+        return next_bucket(max(rows, 1), lo=1, hi=1 << 16), T
+
+    def prefill_buckets(self, lengths) -> List[Tuple[int, int]]:
+        """The ``prefill_bucket`` of every program a prefill dispatch of
+        rows of ``lengths`` tokens runs: one for the rows together, or,
+        for a row longer than ``prefill_chunk`` (``prefill``), a whole
+        chunk a program."""
+        C = self.ecfg.prefill_chunk
+        if len(lengths) == 1 and lengths[0] > C and self.sp == self.pp == 1:
+            return [(1, C)] * -(-lengths[0] // C)
+        return [self.prefill_bucket(len(lengths), max(lengths, default=1))]
+
     def prefill(
         self, token_ids: np.ndarray, page_table: np.ndarray,
         start: int = 0, on_device: bool = False,
@@ -1136,9 +1177,7 @@ class ModelRunner:
                 )
             out = self._prefill_out(logits, route, 1, on_device)
             return out if on_device else out[0]
-        T = next_bucket(max(n, 1), lo=16, hi=self.ecfg.max_context())
-        if T % self.sp:  # ring prefill shards T over the seq axis
-            T = -(-T // self.sp) * self.sp
+        _, T = self.prefill_bucket(1, n)
         ids = np.zeros((1, T), np.int32)
         ids[0, :n] = token_ids
         self._count_state_commit("prefill")
@@ -1178,10 +1217,7 @@ class ModelRunner:
         rows = [r[: self.whole_blocks(len(r))] for r in rows]
         n = len(rows)
         maxlen = max((len(r) for r in rows), default=1)
-        T = next_bucket(max(maxlen, 1), lo=16, hi=self.ecfg.max_context())
-        if T % self.sp:
-            T = -(-T // self.sp) * self.sp
-        B = next_bucket(n, lo=1, hi=1 << 16)
+        B, T = self.prefill_bucket(n, maxlen)
         ids = np.zeros((B, T), np.int32)
         lens = np.zeros((B,), np.int32)
         tables = np.zeros((B, page_tables.shape[1]), np.int32)
@@ -1222,8 +1258,7 @@ class ModelRunner:
         ]
         n = len(rows)
         maxlen = max((len(r) for r in rows), default=1)
-        T = next_bucket(max(maxlen, 1), lo=16, hi=self.ecfg.max_context())
-        B = next_bucket(n, lo=1, hi=1 << 16)
+        B, T = self.prefill_bucket(n, maxlen)
         ids = np.zeros((B, T), np.int32)
         lens = np.zeros((B,), np.int32)
         st = np.zeros((B,), np.int32)

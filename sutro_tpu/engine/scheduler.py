@@ -1079,7 +1079,9 @@ class ContinuousBatcher:
             table[: len(hit_pages)] = hit_pages
             table[len(hit_pages) : n_pages] = pages
             if self._tel_on:
-                attrs = {"tokens": int(paid)}
+                attrs = {
+                    "tokens": int(paid), **self._prefill_attrs((paid,)),
+                }
                 if store is not None:
                     attrs["prefix_saved"] = int(hit)
                     attrs["prefix_paid"] = int(paid)
@@ -1551,6 +1553,9 @@ class ContinuousBatcher:
                     **self._state_attrs(len(batch)),
                     **self._kv_attrs(len(r.prompt_ids) for r in reqs),
                     **self._stream_attrs("prefill", tokens),
+                    **self._prefill_attrs(
+                        self._prefill_len(r) - s_ for r, s_ in zip(reqs, starts)
+                    ),
                 }
             with self.timer.time("prefill"):
                 if len(batch) == 1:
@@ -1869,6 +1874,7 @@ class ContinuousBatcher:
                 **self._state_attrs(1),
                 **self._kv_attrs((s.prefill_pos + len(seg),)),
                 **self._stream_attrs("prefill", len(seg)),
+                **self._prefill_attrs((len(seg),)),
             }
         with self.timer.time("prefill"):
             logits, route = self.runner.prefill_batch_at(
@@ -2903,6 +2909,30 @@ class ContinuousBatcher:
             telemetry.HC_SUBLAYERS_TOTAL.inc(float(sublayers * steps), form)
             telemetry.HC_STREAM_BYTES_NEEDED_TOTAL.inc(float(needed))
         return {"hc_stream_bytes": needed}
+
+    def _prefill_attrs(self, lengths) -> Dict[str, Any]:
+        """Span attrs of a prefill dispatch of rows of ``lengths``
+        tokens, and its count in ``sutro_prefill_tokens_total``: the
+        rows' own tokens and what its programs' ``rows x length`` hold
+        beyond them (``ModelRunner.prefill_buckets``; host arithmetic
+        at dispatch, like ``_count_kv_pages``)."""
+        lengths = [int(n) for n in lengths]
+        real = sum(lengths)
+        buckets = self.runner.prefill_buckets(lengths)
+        cells = sum(B * T for B, T in buckets)
+        # the callers test the switch too; sutro_tpu.analysis wants every
+        # count behind it in the function that makes it
+        if self._tel_on:
+            telemetry.PREFILL_TOKENS_TOTAL.inc(float(real), "real")
+            telemetry.PREFILL_TOKENS_TOTAL.inc(
+                float(max(cells - real, 0)), "padded"
+            )
+        return {
+            "rows": len(lengths), "bucket": list(buckets[-1]),
+            "real_tokens": real,
+            # each row's own length: attention's products go by its square
+            "row_tokens": lengths,
+        }
 
     def _kv_attrs(self, ctx) -> Dict[str, float]:
         """Span attrs of a dispatch of a model that keeps K/V a pool a

@@ -226,9 +226,10 @@ def _load_mixed(mcfg: ModelConfig, get, dtype) -> Dict[str, Any]:
         # the block is Qwen3-MoE's, and loading it under those names
         # would rotate every layer alike and window none
         raise NotImplementedError(
-            f"{mcfg.name}: loading a checkpoint of model_type 'mellum' is "
-            "not written: its tensor names (per-layer self_attn / "
-            "mlp.experts, whether q_norm / k_norm exist) and the reading "
+            f"{mcfg.name}: loading a checkpoint of model_type 'mellum' "
+            "or 'laguna' is not written: its tensor names (per-layer "
+            "self_attn / mlp.experts, whether q_norm / k_norm exist, the "
+            "gate's and the shared expert gate's leaves) and the reading "
             "of rope_parameters by layer kind are unconfirmed; the model "
             "runs on seeded random weights"
         )

@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers fourteen
+config-driven decoder-only transformer (models/transformer.py) covers fifteen
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -98,6 +98,21 @@ families:
   block's K/V (engine/runner.py ``_decode_block_jit``). ``-l6`` is the
   first of eight pipeline stages: layers 0-5, every expert, the whole
   vocabulary
+- Laguna S 2.1 (118b; ``model_type`` laguna): Mellum 2's key set (window
+  and full attention layers, K/V a pool a kind, a rotary embedding a
+  kind) with five things that are no numbers of it: QUERY HEADS A LAYER
+  KIND (``window_num_heads`` 72 in the window layers, ``num_heads`` 48
+  in the full ones, 8 KV heads in both: 9 and 6 query heads a KV head),
+  an output gate a HEAD (``attn_gate`` "head": one sigmoid scalar a
+  query head from the layer's normed input), a rotary part narrower
+  than the head in the full layers alone (``rotary_dim`` 64 of 128
+  under YaRN, factor 128; the window layers turn all 128 plainly at
+  theta 10,000), a leading dense layer and then 256 SwiGLU experts of
+  1,024 top-10 (softmax, the chosen over their sum, times 2.5) beside
+  a shared expert with a sigmoid gate of its own
+  (``moe_shared_gate``). ``-l9-ep8`` is one chip of the eight that
+  share every layer of the first of six pipeline stages: layers 0-8,
+  experts 0-31 of each routed layer's 256, an eighth of the vocabulary
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -164,12 +179,26 @@ class ModelConfig:
     # (8 x the work at a sixteenth), so the room is sized for how uneven
     # the router is. 2 until a model says otherwise
     moe_share_rows: int = 2
+    # the most tokens of a dispatch a routed layer takes at once, one
+    # equal tile after another (0: the whole dispatch; ops/moe.py
+    # ``moe_mlp``). A layer's temporaries are several copies of its
+    # EXPANDED rows, ``tokens x top_k x hidden``: at 8,192 tokens,
+    # top-10 and 3,072 they stood at 3.4 GB, the whole of what the pools
+    # leave free (PERF.md section 6, PR 61). A model's field and no
+    # byte budget inside ``moe_mlp``: what tells this model from one
+    # whose 16,384-token prefill expands to three times the bytes and
+    # fits is the memory its weights and pools leave, which the layer
+    # cannot see
+    moe_token_tile: int = 0
     # False: an expert is TWO matrices, ``down(act(up x))`` (``we_up``,
     # ``we_down``; no ``we_gate``), as is the shared expert
     moe_gated: bool = True
     # width of ONE shared expert, of the experts' form, that every token
     # takes beside its routed ones (0: none)
     moe_shared_intermediate_size: int = 0
+    # the shared expert's output is multiplied by ``sigmoid(x w_s)``,
+    # ``w_s`` [H, 1]: one scalar a token (Qwen2-MoE's shared expert gate)
+    moe_shared_gate: bool = False
     # Per-layer mixer kinds, "attention" | "swa" | "conv" | "mamba" |
     # "mla" | "kda";
     # empty => attention everywhere. A "swa" layer is attention over the
@@ -204,15 +233,29 @@ class ModelConfig:
     # log-decay ``g`` a CHANNEL and the output gate from pairs of
     # matrices of rank ``kda_rank``; ``beta = kda_beta_scale *
     # sigmoid(.)`` (2: eigenvalues of ``I - beta k k^T`` down to -1); the
-    # chunk form at ``kda_chunk`` tokens. ``attn_gate``: the model's
-    # attention layers gate their output a channel, ``sigmoid(x W_gate)``
+    # chunk form at ``kda_chunk`` tokens.
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 0
     kda_rank: int = 0
     kda_beta_scale: float = 1.0
     kda_chunk: int = 64
-    attn_gate: bool = False
+    # The form of the output gate of the model's attention layers ("":
+    # none), ``sigmoid(x W_gate)`` from the layer's normed input, times
+    # the attention's output before ``wo``: "channel" one value a channel
+    # (``W_gate`` [H, heads x head_dim]: Solar Open 2), "head" one scalar
+    # a query head (``W_gate`` [H, heads]: Laguna)
+    attn_gate: str = ""
+    # Query heads of a "swa" layer where they are not ``num_heads`` (0:
+    # they are); both kinds keep ``num_kv_heads``, so the pools and a
+    # chunk's K/V are of one width and only Q, the gate and ``wo``
+    # differ. ``heads_of`` is THE place a layer kind's count is read
+    window_num_heads: int = 0
+    # Elements of a head that take the rotary embedding, the first ones,
+    # in half-split pairs inside them; the rest pass through (0: the
+    # whole head) in the FULL layers; a window layer turns the whole
+    # head (``rotary_dim_of``)
+    rotary_dim: int = 0
     # An "mla" layer is latent attention (models/transformer.py
     # ``mla_mixer``): ``c_q = norm(x W_qa)`` of ``q_lora_rank``, a head's
     # query ``qk_nope_head_dim`` wide plus ``qk_rope_head_dim`` that takes
@@ -362,6 +405,19 @@ class ModelConfig:
     @property
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    def heads_of(self, mixer: str) -> int:
+        """Query heads of an attention layer of kind ``mixer``
+        ("attention" | "swa")."""
+        if mixer == "swa" and self.window_num_heads:
+            return self.window_num_heads
+        return self.num_heads
+
+    def rotary_dim_of(self, mixer: str) -> int:
+        """Elements of a head that a layer of kind ``mixer`` turns."""
+        if mixer == "swa":
+            return self.head_dim
+        return self.rotary_dim or self.head_dim
 
     @property
     def one_sublayer(self) -> bool:
@@ -780,6 +836,55 @@ def _mellum2(name: str, layer_types: Tuple[str, ...], *, h: int = 2304,
     )
 
 
+def _laguna(name: str, layers: int = 48, *, h: int = 3072, nh: int = 48,
+            window_nh: int = 72, nkv: int = 8, hd: int = 128,
+            rotary: int = 64, inter: int = 12_288, experts: int = 256,
+            top_k: int = 10, moe_inter: int = 1024, held: int = 0,
+            first: int = 0, window: int = 512, rope_original: int = 8192,
+            factor: float = 128.0,
+            attention_factor: Optional[float] = 1.4852030263919618,
+            vocab: int = 100_352, token_tile: int = 4096,
+            template: str = "chatml") -> ModelConfig:
+    """The published ``laguna`` keys: layer ``l`` is full attention iff
+    ``l % 4 == 0`` (``layer_types``), else sliding over ``window``;
+    ``num_attention_heads_per_layer`` gives a full layer ``nh`` query
+    heads and a window layer ``window_nh`` over the same ``nkv`` KV
+    heads; ``gating`` per-head; ``rope_parameters`` by kind: YaRN
+    (``factor`` over ``rope_original``, theta 500,000, ``attention_factor``
+    as the file states it; None: 0.1 ln(factor) + 1) over the first ``rotary`` elements of a head in the full
+    layers (``partial_rotary_factor`` 0.5), the plain embedding at
+    theta 10,000 over the whole head in the window layers. Layer 0's
+    FFN is dense (``mlp_only_layers`` [0]); the others route by softmax,
+    the chosen over their sum (``norm_topk_prob``) times
+    ``moe_routed_scaling_factor`` 2.5, beside ONE shared expert of an
+    expert's width under a sigmoid gate of its own. QK norm, the gate's
+    form, the softmax and the shared expert's gate are assumed
+    (perfbench/reference/laguna_moe.md). ``held`` / ``first``: the
+    experts this chip holds of each routed layer (0: all);
+    ``token_tile``: ``ModelConfig.moe_token_tile``."""
+    return ModelConfig(
+        name=name, vocab_size=vocab, hidden_size=h, num_layers=layers,
+        num_heads=nh, window_num_heads=window_nh, num_kv_heads=nkv,
+        head_dim=hd, intermediate_size=inter, norm_eps=1e-6,
+        qk_norm=True, tie_embeddings=False, attn_gate="head",
+        layer_types=tuple(
+            "attention" if i % 4 == 0 else "swa" for i in range(layers)
+        ),
+        rope_theta=500_000.0, local_rope_theta=10_000.0,
+        rotary_dim=rotary,
+        rope_scaling_factor=factor, rope_original_max=rope_original,
+        rope_attention_factor=attention_factor,
+        sliding_window=window,
+        moe_experts=experts, moe_top_k=top_k,
+        moe_intermediate_size=moe_inter, num_dense_layers=1,
+        moe_experts_held=held, moe_first_expert=first,
+        moe_shared_intermediate_size=moe_inter, moe_shared_gate=True,
+        router_score="softmax", router_renorm=True, router_scale=2.5,
+        moe_token_tile=token_tile,
+        chat_template=template, seeded_unit_embedding=True,
+    )
+
+
 #: NVIDIA-Nemotron-3-Nano-30B-A3B's published
 #: ``hybrid_override_pattern`` (config.json): a block a symbol
 _NEMOTRON_3_NANO_PATTERN = (
@@ -914,7 +1019,7 @@ def _solar_kda(name: str, layers: int = 48, *, h: int = 4096, nh: int = 64,
         layer_types=tuple(
             "attention" if i % 4 == 0 else "kda" for i in range(layers)
         ),
-        position_embedding="nope", attn_gate=True,
+        position_embedding="nope", attn_gate="channel",
         kda_heads=k_heads, kda_head_dim=k_head_dim, kda_conv=4,
         kda_rank=k_rank or k_head_dim, kda_beta_scale=2.0,
         kda_chunk=k_chunk,
@@ -1053,6 +1158,15 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     "solar-open2-250b-l8-ep16": _solar_kda(
         "solar-open2-250b-l8-ep16", 8, held=20, first=0, vocab=24_576,
     ),
+    # Laguna S 2.1: as published (117.7 B parameters), and one chip of
+    # the eight that share every layer of the FIRST of six pipeline
+    # stages: layers 0-8 (the dense layer and two whole periods: 3 full
+    # + 6 window layers), experts 0-31 of each routed layer's 256, rows
+    # 0-12,543 of the vocabulary (3.20 B parameters, 6.40 GB in bf16)
+    "laguna-s-2.1": _laguna("laguna-s-2.1"),
+    "laguna-s-2.1-l9-ep8": _laguna(
+        "laguna-s-2.1-l9-ep8", 9, held=32, first=0, vocab=12_544,
+    ),
     # Embeddings (Qwen3 trunk + last-token-pool head)
     "qwen3-emb-0.6b": _qwen3("qwen3-emb-0.6b", 1024, 28, 16, 8, 3072, head="embedding"),
     "qwen3-emb-6b": _qwen3("qwen3-emb-6b", 4096, 36, 32, 8, 12288, tie=False, head="embedding"),
@@ -1147,6 +1261,19 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         "tiny-solar-kda", 6, h=128, nh=4, nkv=2, hd=32, k_heads=4,
         k_head_dim=16, k_rank=8, k_chunk=8, inter=256, experts=16,
         top_k=4, moe_inter=48, held=4, first=0, vocab=512,
+        template="plain",
+    ),
+    # two periods and a layer (full, 3 window, full, 3 window, full): 4
+    # query heads in a full layer and 6 in a window layer over 2 KV heads
+    # (groups of 2 and 3), heads of 16 of which a full layer turns 8
+    # under YaRN (factor 8 over an original 16), window 8 at a test's
+    # page size of 4; a leading dense layer, then 16 experts top-3 of
+    # which this chip holds 4, beside the gated shared expert
+    "tiny-laguna": _laguna(
+        "tiny-laguna", 9, h=64, nh=4, window_nh=6, nkv=2, hd=16, rotary=8,
+        inter=128, experts=16, top_k=3, moe_inter=32, held=4, first=0,
+        window=8, rope_original=16, factor=8.0, attention_factor=None,
+        vocab=512, token_tile=16,
         template="plain",
     ),
     # Qwen3-MoE's layer at tiny widths under the block mask: blocks of 4,

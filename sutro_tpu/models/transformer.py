@@ -159,7 +159,7 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
     that choosing by ``score + bias`` and weighting by ``score`` differ
     on random weights as they do on trained ones."""
     H, Dh = cfg.hidden_size, cfg.head_dim
-    NHD, KVD = cfg.q_size, cfg.kv_size
+    KVD = cfg.kv_size
     La, Lc = cfg.num_attn_layers, cfg.num_conv_layers
     Ld, Lm = cfg.ffns.count("dense"), cfg.ffns.count("moe")
     out: Dict[str, Any] = {}
@@ -167,10 +167,15 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
         out["mamba"] = _init_mamba_layers(cfg, dense, dtype)
     if cfg.num_kda_layers:
         out["kda"] = _init_kda_layers(cfg, dense, dtype)
-    # full and window attention layers: the same block, a stack a kind
-    for kind, Lk in (("attn", La), ("swa", cfg.num_window_layers)):
+    # full and window attention layers: the same block, a stack a kind,
+    # at the kind's own count of query heads (``ModelConfig.heads_of``)
+    for kind, mixer, Lk in (
+        ("attn", "attention", La), ("swa", "swa", cfg.num_window_layers)
+    ):
         if not Lk:
             continue
+        NH = cfg.heads_of(mixer)
+        NHD = NH * Dh
         out[kind] = {
             "attn_norm": jnp.ones((Lk, H), dtype),
             "wq": dense((Lk, H, NHD), H),
@@ -182,7 +187,9 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
             out[kind]["q_norm"] = jnp.ones((Lk, Dh), dtype)
             out[kind]["k_norm"] = jnp.ones((Lk, Dh), dtype)
         if cfg.attn_gate:
-            out[kind]["w_attn_gate"] = dense((Lk, H, NHD), H)
+            # a value a channel, or a scalar a head
+            wide = NHD if cfg.attn_gate == "channel" else NH
+            out[kind]["w_attn_gate"] = dense((Lk, H, wide), H)
     if cfg.num_latent_layers:
         Ll, NH = cfg.num_latent_layers, cfg.num_heads
         Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
@@ -281,6 +288,8 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
                 out["moe"]["shared_gate"] = dense((Lm, H, Fs), H)
             out["moe"]["shared_up"] = dense((Lm, H, Fs), H)
             out["moe"]["shared_down"] = dense((Lm, Fs, H), Fs * hidden)
+            if cfg.moe_shared_gate:
+                out["moe"]["shared_expert_gate"] = dense((Lm, H, 1), H)
         if cfg.router_select_bias:
             out["moe"]["router_bias"] = (
                 dense((Lm, E), 1) * 0.02
@@ -382,10 +391,15 @@ def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             * (scale_dim ** -0.5)
         ).astype(dtype)
 
-    if cfg.homogeneous and (cfg.attn_gate or cfg.hc_mult > 1):
+    if cfg.homogeneous and (
+        cfg.attn_gate or cfg.hc_mult > 1 or cfg.rotary_dim
+        or cfg.window_num_heads
+    ):
         raise NotImplementedError(
-            f"{cfg.name}: an attention output gate (attn_gate) or a "
-            "residual stream of several lanes (hc_mult) in a model whose "
+            f"{cfg.name}: an attention output gate (attn_gate), a "
+            "residual stream of several lanes (hc_mult), a rotary part "
+            "narrower than the head (rotary_dim) or query heads a layer "
+            "kind (window_num_heads) in a model whose "
             "every layer is one block: the mixed walk builds them (list "
             "the layers' kinds, layer_types), the one scan of "
             "layer_apply does not"
@@ -528,13 +542,24 @@ def apply_rope(
     theta: jax.Array,
     cfg: Optional[ModelConfig] = None,
     yarn: Optional[bool] = None,
+    rotary_dim: int = 0,
 ) -> jax.Array:
     """rotate-half RoPE. x: [B, T, N, Dh]; positions: [B, T]. ``yarn``
     says whether this layer takes the config's YaRN scaling: the walk
     over layer kinds knows each layer's kind and says (a window layer:
     False, plain ``theta``); None leaves it to the config, for the one
-    scan of a homogeneous model, whose layers all take it."""
+    scan of a homogeneous model, whose layers all take it.
+    ``rotary_dim`` (static; 0: the whole head): the head's first
+    elements that turn, in half-split pairs inside them, under
+    frequencies of that many elements; the rest pass through
+    (``ModelConfig.rotary_dim_of``)."""
     dh = x.shape[-1]
+    if rotary_dim and rotary_dim < dh:
+        with jax.named_scope("partial_rope"):
+            turned = apply_rope(
+                x[..., :rotary_dim], positions, theta, cfg, yarn
+            )
+            return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     half = dh // 2
     if yarn is None:
         yarn = cfg is not None and bool(cfg.rope_scaling_factor)
@@ -659,6 +684,7 @@ def _mlp(
                 *args, return_counts=return_counts, layer=layer,
                 first_expert=cfg.moe_first_expert,
                 share_rows=cfg.moe_share_rows,
+                token_tile=cfg.moe_token_tile,
                 use_pallas=use_pallas and kernel_mesh is None, **kwargs
             )
             if return_counts:
@@ -667,9 +693,17 @@ def _mlp(
             # every chip that shares the layer computes it alike: it is
             # counted once where the shares are summed
             with jax.named_scope("shared_expert"):
-                out = out + _ffn(
+                shared = _ffn(
                     cfg, lp, x, ("shared_gate", "shared_up", "shared_down")
                 )
+                if "shared_expert_gate" in lp:
+                    # a sigmoid scalar a token on the shared expert's
+                    # output (``ModelConfig.moe_shared_gate``)
+                    g = jax.nn.sigmoid((
+                        x @ _w(lp, "shared_expert_gate", x.dtype)
+                    ).astype(jnp.float32))
+                    shared = (shared.astype(jnp.float32) * g).astype(x.dtype)
+                out = out + shared
         return (out, counts) if return_counts else out
     return _ffn(cfg, lp, x, ("w_gate", "w_up", "w_down"))
 
@@ -689,11 +723,14 @@ def attention_mixer(
     ring_mesh=None, wk_l=None, wv_l=None, win_len=None,
     pfx_groups=None, kernel_mesh=None,
     yarn: Optional[bool] = None, live_window: int = 0,
+    rotary_dim: int = 0,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """GQA attention over the chunk and its paged past, through the
     output projection: ``(out [B, T, H], (k_chunk, v_chunk))``. The one
     attention block of every model (``layer_apply`` and the mixed walk
-    both call it). ``yarn``: ``apply_rope``'s. ``live_window`` (static;
+    both call it). The query heads are what ``wq`` is wide (a layer
+    kind's own count: ``ModelConfig.heads_of``). ``yarn`` and
+    ``rotary_dim``: ``apply_rope``'s. ``live_window`` (static;
     a "swa" layer's window) says the pool and table handed in are the
     WINDOW pool's, which holds a row's last ``live_window`` positions
     and nothing older (ops/attention.py)."""
@@ -703,15 +740,16 @@ def attention_mixer(
     v = x @ _w(lp, "wv", x.dtype)
     if cfg.attn_bias:
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
+    q = q.reshape(B, T, -1, cfg.head_dim)
+    NH = q.shape[2]
     k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps, cfg.norm_zero_centered)
         k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_zero_centered)
     if cfg.position_embedding != "nope":
-        q = apply_rope(q, positions, theta, cfg, yarn)
-        k = apply_rope(k, positions, theta, cfg, yarn)
+        q = apply_rope(q, positions, theta, cfg, yarn, rotary_dim)
+        k = apply_rope(k, positions, theta, cfg, yarn, rotary_dim)
     if cfg.attention_multiplier is not None:
         # every attention path scales by 1/sqrt(Dh): fold the ratio
         # into q (a power of two for the published multipliers)
@@ -735,16 +773,18 @@ def attention_mixer(
         live_window=live_window,
         block_length=cfg.block_length,
     )
-    attn = attn.reshape(B, T, cfg.q_size)
     if "w_attn_gate" in lp:
-        # an output gate a channel, from the layer's input
-        # (``ModelConfig.attn_gate``; a dense FFN's ``w_gate`` shares a
-        # homogeneous layer's dict, hence the longer name)
+        # an output gate from the layer's input, a value a channel or a
+        # scalar a head by what the leaf is wide (``ModelConfig.
+        # attn_gate``; a dense FFN's ``w_gate`` shares a homogeneous
+        # layer's dict, hence the longer name)
         with jax.named_scope("gqa_gate"):
             gate = jax.nn.sigmoid(
                 (x @ _w(lp, "w_attn_gate", x.dtype)).astype(jnp.float32)
             )
+            gate = gate.reshape(B, T, NH, -1)   # [.., Dh] or [.., 1]
             attn = (attn.astype(jnp.float32) * gate).astype(x.dtype)
+    attn = attn.reshape(B, T, NH * cfg.head_dim)
     attn = attn @ _w(lp, "wo", x.dtype)
     if cfg.attn_bias:
         attn = attn + lp["bo"]
@@ -2092,6 +2132,43 @@ def _check_mixed(cfg: ModelConfig) -> None:
             )
     if cfg.num_window_layers and cfg.sliding_window < 1:
         raise ValueError(f"{cfg.name}: swa layers need a sliding_window")
+    if cfg.attn_gate not in ("", "channel", "head"):
+        raise ValueError(
+            f"{cfg.name}: attn_gate {cfg.attn_gate!r} (\"channel\" | "
+            "\"head\" | \"\": none)"
+        )
+    for mixer in ("attention", "swa"):
+        heads, rot = cfg.heads_of(mixer), cfg.rotary_dim_of(mixer)
+        if heads % max(cfg.num_kv_heads, 1):
+            raise ValueError(
+                f"{cfg.name}: {heads} query heads of a {mixer} layer are "
+                f"no multiple of {cfg.num_kv_heads} KV heads"
+            )
+        if rot % 2 or rot > cfg.head_dim:
+            raise ValueError(
+                f"{cfg.name}: a rotary part of {rot} elements of a head "
+                f"of {cfg.head_dim} (even, at most the head)"
+            )
+    if cfg.window_num_heads and not cfg.num_window_layers:
+        raise ValueError(
+            f"{cfg.name}: window_num_heads is a swa layer's and the "
+            "model lists none"
+        )
+    if cfg.rotary_dim and (
+        cfg.num_latent_layers or cfg.position_embedding == "nope"
+    ):
+        raise NotImplementedError(
+            f"{cfg.name}: a rotary part narrower than the head "
+            "(rotary_dim) on latent (mla) layers, whose rotary part is "
+            "qk_rope_head_dim, or with no rotary embedding at all (nope)"
+        )
+    if cfg.moe_shared_gate and not (
+        cfg.moe_shared_intermediate_size and cfg.moe_gated
+    ):
+        raise ValueError(
+            f"{cfg.name}: a shared expert's gate (moe_shared_gate) needs "
+            "a shared expert of three matrices"
+        )
     if cfg.num_latent_layers:
         if cfg.num_attn_layers or cfg.num_window_layers:
             # one pool, one page width (``ModelConfig.page_width``)
@@ -2369,6 +2446,7 @@ def _mixed_trunk(
                     pfx_groups=None if swa else pfx_groups,
                     kernel_mesh=kernel_mesh, yarn=yarn,
                     live_window=cfg.sliding_window if swa else 0,
+                    rotary_dim=cfg.rotary_dim_of(mixer),
                 )
         return y
 

@@ -392,7 +392,11 @@ def chunk_attention(
                         )
                     )
             else:
-                lowering.record_reference("paged_decode")
+                lowering.record_reference(
+                    "paged_decode", q.shape[2],
+                    "paged_decode_supported: heads of "
+                    f"{q.shape[-1]} or pages of {past_k_pages.shape[2]}",
+                )
         ops.update({k_: v_ for k_, v_ in optional.items() if v_ is not None})
         out = lowering.shard_over_model(
             kernel_mesh, decode, ops, _PAGED_SPECS, P(None, "model", None),
@@ -438,7 +442,11 @@ def chunk_attention(
             # a sink, a window pool, a mesh) and a block whose heads the
             # block form does not take
             lowering.record_reference("paged_decode")
-            lowering.record_reference(lowering.PAGED_CHUNK)
+            lowering.record_reference(
+                lowering.PAGED_CHUNK, q.shape[2],
+                "paged_chunk_supported"
+                + (": a window pool's pages" if live_window else ""),
+            )
         past_first = None
         if live_window:
             page_table, past_first = live_pages(
@@ -466,7 +474,12 @@ def chunk_attention(
                 P(None, None, "model", None),
             )
         if T > 1:
-            lowering.record_reference("flash_prefill")
+            lowering.record_reference(
+                "flash_prefill", q.shape[2],
+                "a chunk over a past" if past_k is not None
+                else f"flash_prefill_supported: [{T}, {q.shape[2]}, "
+                f"{q.shape[-1]}] over {k.shape[2]} KV heads",
+            )
 
     B, T, NH, Dh = q.shape
     KVH = k.shape[2]

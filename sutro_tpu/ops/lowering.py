@@ -105,13 +105,36 @@ _kda: Dict[str, int] = dict.fromkeys(KDA_FORMS, 0)
 _decode_rows: Dict[int, int] = {}
 
 
-def record_kernel(kernel: str, *, interpret: bool, rows: int = 0) -> None:
+#: traces of the attention kernels by the QUERY HEADS of the call, for a
+#: model whose layer kinds differ in them (``ModelConfig.heads_of``):
+#: ``{"paged_decode@72": {"lowered": 1, ...}}``
+_by_heads: Dict[str, Dict[str, object]] = {}
+
+
+def _count_heads(kernel: str, heads: int, path: str, gate: str = "") -> None:
+    """Under ``_lock``."""
+    if not heads:
+        return
+    entry = _by_heads.setdefault(
+        f"{kernel}@{heads}", dict.fromkeys(PATHS, 0)
+    )
+    entry[path] += 1
+    if gate:
+        entry["gate"] = gate
+
+
+def record_kernel(
+    kernel: str, *, interpret: bool, rows: int = 0, heads: int = 0
+) -> None:
     """Called from a kernel wrapper's traced body. ``rows``: the rows a
-    grid step of the paged decode kernel takes in this trace."""
+    grid step of the paged decode kernel takes in this trace; ``heads``:
+    the call's query heads (an attention kernel's)."""
+    path = "interpreted" if interpret else "lowered"
     with _lock:
-        _counts[kernel]["interpreted" if interpret else "lowered"] += 1
+        _counts[kernel][path] += 1
         if rows:
             _decode_rows[rows] = _decode_rows.get(rows, 0) + 1
+        _count_heads(kernel, heads, path)
 
 
 def paged_decode_rows_per_step() -> Dict[int, int]:
@@ -124,10 +147,29 @@ def paged_decode_rows_per_step() -> Dict[int, int]:
         return dict(sorted(_decode_rows.items()))
 
 
-def record_reference(kernel: str) -> None:
-    """Called where a ``use_pallas=True`` call takes the jnp/XLA path."""
+def record_reference(kernel: str, heads: int = 0, gate: str = "") -> None:
+    """Called where a ``use_pallas=True`` call takes the jnp/XLA path.
+    ``heads``: the call's query heads; ``gate``: what sent it there."""
     with _lock:
         _counts[kernel]["reference"] += 1
+        _count_heads(kernel, heads, "reference", gate)
+
+
+def kernel_heads_counts() -> Dict[str, Dict[str, object]]:
+    """Traces of the attention kernels (``paged_decode``,
+    ``flash_prefill``, ``paged_chunk``) by the call's QUERY HEADS, under
+    ``"<kernel>@<heads>"``: ``lowered`` / ``interpreted`` (the Pallas
+    body) and ``reference`` (a ``use_pallas=True`` call that went to
+    XLA, with ``gate`` naming what sent the last such call there). A
+    model whose layer kinds differ in their heads (72 in its window
+    layers, 48 in its full ones) reads here that BOTH counts took the
+    kernel; ``snapshot()`` adds them up. The K/V write has no query
+    heads (both kinds keep the same KV heads) and is counted a pool in
+    ``snapshot()["kv_write"]`` alone. A count of its own, outside
+    ``snapshot()``'s keys, for the reason ``grouped_matmul_counts`` has
+    one."""
+    with _lock:
+        return {k: dict(v) for k, v in sorted(_by_heads.items())}
 
 
 def record_xla_decode() -> None:

@@ -311,6 +311,7 @@ def moe_mlp(
     return_counts: bool = False,
     layer: "jax.Array | None" = None,
     share_rows: int = 2,   # ``_share_row_cap``'s even shares
+    token_tile: int = 0,   # most tokens the layer takes at once (0: all)
 ):
     """The routed MLP over ``x``. With ``return_counts`` also the rows
     each expert got, [E] int32 over the ROUTER's experts, held here or
@@ -340,6 +341,28 @@ def moe_mlp(
     B, T, H = x.shape
     E = router.shape[-1]
     N = B * T
+    if token_tile and N > token_tile:
+        # at most ``token_tile`` tokens at a time, one EQUAL tile after
+        # another (``ModelConfig.moe_token_tile``): every temporary of
+        # the layer is then a tile's, whatever the dispatch holds, and
+        # each touched expert is streamed once a tile
+        tiles = -(-N // token_tile)
+        while N % tiles:
+            tiles += 1
+
+        def one(tile):
+            return moe_mlp(
+                tile, router, we_gate, we_up, we_down, top_k=top_k,
+                activation=activation, method=method,
+                first_expert=first_expert, router_b=router_b,
+                bias_gate=bias_gate, bias_up=bias_up, bias_down=bias_down,
+                route=route, use_pallas=use_pallas, return_counts=True,
+                layer=layer, share_rows=share_rows,
+            )
+
+        outs, counts = jax.lax.map(one, x.reshape(tiles, 1, N // tiles, H))
+        out = outs.reshape(B, T, H)
+        return (out, jnp.sum(counts, axis=0)) if return_counts else out
     xt = x.reshape(N, H)
     held = we_up.shape[-3]
     share = held != E

@@ -403,7 +403,9 @@ def paged_chunk_attention(
     """Returns ``[B, T, NH, Dh]``: causal attention of a chunk's ``T``
     queries a row over the row's paged past and the chunk's own keys
     (module docstring). Queries sit at ``past_len + t``."""
-    lowering.record_kernel(lowering.PAGED_CHUNK, interpret=interpret)
+    lowering.record_kernel(
+        lowering.PAGED_CHUNK, interpret=interpret, heads=q.shape[2]
+    )
     B, T, NH, Dh = q.shape
     L, NP, PS, KD = k_pages.shape
     KVH = KD // Dh

@@ -227,7 +227,9 @@ def flash_prefill(
     block_length: int = 1,
 ) -> jax.Array:
     """Returns [B, T, NH, Dv] causal self-attention over the chunk."""
-    lowering.record_kernel("flash_prefill", interpret=interpret)
+    lowering.record_kernel(
+        "flash_prefill", interpret=interpret, heads=q.shape[2]
+    )
     B, T, NH, Dh = q.shape
     if block_length & (block_length - 1) or (block or BLOCK_Q) % block_length:
         raise ValueError(

@@ -110,6 +110,13 @@ def _kv_write_kernel(
             dma.wait()
 
 
+#: bytes of a row's run of ONE layer a call of the K/V write kernel takes
+#: a pool at the most. At twice that (2,048 tokens of 1,024, 4,096 of
+#: 512) the kernel asks for 89.75 MB of scoped VMEM, which one program
+#: grants and the next, 256 KB short, refuses (PERF.md section 6, PR 61)
+RUN_BYTES = 2 << 20
+
+
 def _layer_chunk(L: int, Tb: int, PS: int, KD: int, itemsize: int) -> int:
     """Largest divisor of L whose token blocks + page slabs fit a ~4 MiB
     VMEM budget per tensor."""
@@ -135,11 +142,23 @@ def kv_write_pallas(
     *,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    out_k, out_v = _write_pools(
-        (k_pages, v_pages), (k_new, v_new), page_table, start, valid_len,
-        interpret,
-    )
-    return out_k, out_v
+    lowering.record_kernel("kv_write", interpret=interpret)
+    # the kernel keeps a row's whole run of a layer in VMEM, twice over
+    # for K and for V, beside its roll's float32 copy: a call takes a
+    # run of at most ``RUN_BYTES`` a layer and a longer chunk lands in
+    # several, one after another on the same pools (8,192 tokens of
+    # 1,024 in eight)
+    PS, KD = k_pages.shape[2:]
+    T = k_new.shape[2]
+    run = max(PS, RUN_BYTES // (KD * k_pages.dtype.itemsize) // PS * PS)
+    for at in range(0, T, run):
+        k_pages, v_pages = _write_pools(
+            (k_pages, v_pages),
+            (k_new[:, :, at:at + run], v_new[:, :, at:at + run]),
+            page_table, start + at,
+            jnp.clip(valid_len - at, 0, min(run, T - at)), interpret,
+        )
+    return k_pages, v_pages
 
 
 @functools.partial(
@@ -156,6 +175,7 @@ def row_write_pallas(
 ) -> jax.Array:
     """The same in-place write for a pool that has no V beside it (a
     model of latent layers, engine/kvcache.py): one slab a segment."""
+    lowering.record_kernel("kv_write", interpret=interpret)
     return _write_pools(
         (pages,), (new,), page_table, start, valid_len, interpret
     )[0]
@@ -163,8 +183,9 @@ def row_write_pallas(
 
 def _write_pools(pools, news, page_table, start, valid_len, interpret):
     """``news[i]`` [L, B, Tb, KD] written into ``pools[i]`` [L, NP, PS,
-    KD] in place, the pools of one call through the same segments."""
-    lowering.record_kernel("kv_write", interpret=interpret)
+    KD] in place, the pools of one call through the same segments. The
+    public wrappers count the write (``lowering.record_kernel``), once
+    however many runs it lands in."""
     n = len(pools)
     L, NP, PS, KD = pools[0].shape
     _, B, Tb, _ = news[0].shape

@@ -1045,7 +1045,9 @@ def paged_decode_attention(
         prefix=prefix, shared=shared, quantized=quantized,
     )
     assert B % R == 0, (B, R)
-    lowering.record_kernel("paged_decode", interpret=interpret, rows=R)
+    lowering.record_kernel(
+        "paged_decode", interpret=interpret, rows=R, heads=q.shape[1]
+    )
     kernel = functools.partial(
         _paged_decode_kernel,
         max_pages_per_seq=MP,

@@ -339,6 +339,18 @@ MOE_ROWS_ELSEWHERE_TOTAL = REGISTRY.counter(
     "sutro_moe_routed_rows_total, which counts the pairs computed",
     unit="rows",
 )
+PREFILL_TOKENS_TOTAL = REGISTRY.counter(
+    "sutro_prefill_tokens_total",
+    "Token positions the prefill dispatches computed, by kind: real (the "
+    "rows' own tokens) and padded (what the bucket's rows x length holds "
+    "beyond them: a batched prefill pads every row to the bucket of its "
+    "longest and the rows to a power of two). Host arithmetic at "
+    "dispatch; padded over real + padded is the share of a prefill's "
+    "work spent on nothing",
+    labels=("kind",),  # real | padded
+    unit="tokens",
+    max_series=4,
+)
 KV_PAGES_FETCHED_TOTAL = REGISTRY.counter(
     "sutro_kv_pages_fetched_total",
     "K/V pages the decode dispatches' attention fetched, a row, a step "

@@ -44,6 +44,9 @@ class _StubRunner:
         B = next_bucket(n, 1, 1 << 16)
         return np.zeros((B, self.vocab), np.float32), None
 
+    def prefill_buckets(self, lengths):
+        return [(len(lengths), max(lengths, default=1))]
+
     def prefill_batch(self, prompts, tables, on_device=False):
         return self._logits(len(prompts), on_device)
 
