@@ -159,6 +159,10 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         # model whose window layers have 72 and whose full layers 48):
         # Pallas or XLA each, and the gate that sent a call to XLA
         "kernel_heads": lowering.kernel_heads_counts(),
+        # the flash body's GQA calls by heads and form: the tile, the
+        # walk (a static window's blocks, the causal half, or a dynamic
+        # window's) and what the MXU is fed
+        "flash_prefill": lowering.flash_prefill_counts(),
         "grouped_matmul": lowering.grouped_matmul_counts(),
         "moe_combine": lowering.moe_combine_counts(),
         "ssm_state_read": lowering.ssm_state_read_counts(),

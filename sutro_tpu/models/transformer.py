@@ -2318,10 +2318,13 @@ def _mixed_trunk(
     win_len = None if window_past is None else window_past[2]
     La = cfg.num_attn_layers
     # per attention kind: (window, theta, YaRN?, pool, table, where its
-    # layers start in the stacked K/V and the fused window's buffers)
+    # layers start in the stacked K/V and the fused window's buffers).
+    # A full layer's window is Python's 0, not an array: a constant made
+    # while tracing is a tracer, and ``chunk_attention`` hands the flash
+    # body a window it can read BEFORE tracing as a static bound
     attn_kind = {
         "attention": (
-            jnp.int32(0), jnp.float32(cfg.rope_theta),
+            0, jnp.float32(cfg.rope_theta),
             bool(cfg.rope_scaling_factor), k_pages, v_pages, page_table, 0,
         ),
     }

@@ -80,6 +80,7 @@ _PAGED_SPECS = dict(
 _FLASH_SPECS = dict(
     q=P(None, None, "model", None), k=P(None, None, "model", None),
     v=P(None, None, "model", None), window=P(), sink=P("model"),
+    valid_len=P(),
 )
 
 
@@ -462,14 +463,24 @@ def chunk_attention(
         from .pallas_flash import flash_prefill, flash_prefill_supported
 
         if past_k is None and flash_prefill_supported(q, k, window, sink):
-            ops = dict(q=q, k=k, v=v)
-            if window is not None:
-                ops["window"] = jnp.asarray(window, jnp.int32)
+            ops = dict(q=q, k=k, v=v, valid_len=valid_len)
+            # a window the caller knows before tracing bounds the walk:
+            # a layer KIND's (``live_window``), and a constant's (the
+            # full layers' 0 beside them). A scan's layer brings a
+            # tracer, which the kernel reads as it runs
+            if not live_window and window is not None:
+                if isinstance(window, jax.core.Tracer):
+                    ops["window"] = jnp.asarray(window, jnp.int32)
+                else:
+                    live_window = int(window)
             if sink is not None:
                 ops["sink"] = sink
             return lowering.shard_over_model(
                 kernel_mesh,
-                functools.partial(flash_prefill, block_length=block_length),
+                functools.partial(
+                    flash_prefill, block_length=block_length,
+                    live_window=live_window,
+                ),
                 ops, _FLASH_SPECS,
                 P(None, None, "model", None),
             )
@@ -632,7 +643,7 @@ def latent_flash(q, k, v, *, scale, block, keep=None):
 
     return flash_prefill(
         _lane_padded(q), _lane_padded(k), _lane_padded(v), scale=scale,
-        native=True, block=block, keep=keep,
+        block=block, keep=keep,
     )[..., :v.shape[-1]]
 
 

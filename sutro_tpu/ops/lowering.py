@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 
@@ -105,6 +105,10 @@ _kda: Dict[str, int] = dict.fromkeys(KDA_FORMS, 0)
 _decode_rows: Dict[int, int] = {}
 
 
+#: traces of the flash body's GQA calls by heads and form
+_flash_forms: Dict[str, int] = {}
+
+
 #: traces of the attention kernels by the QUERY HEADS of the call, for a
 #: model whose layer kinds differ in them (``ModelConfig.heads_of``):
 #: ``{"paged_decode@72": {"lowered": 1, ...}}``
@@ -124,17 +128,42 @@ def _count_heads(kernel: str, heads: int, path: str, gate: str = "") -> None:
 
 
 def record_kernel(
-    kernel: str, *, interpret: bool, rows: int = 0, heads: int = 0
+    kernel: str, *, interpret: bool, rows: int = 0, heads: int = 0,
+    form: Optional[Dict[str, str]] = None,
 ) -> None:
     """Called from a kernel wrapper's traced body. ``rows``: the rows a
     grid step of the paged decode kernel takes in this trace; ``heads``:
-    the call's query heads (an attention kernel's)."""
+    the call's query heads (an attention kernel's); ``form``: the tile
+    schedule a GQA call of the flash body took (``flash_prefill_counts``)."""
     path = "interpreted" if interpret else "lowered"
     with _lock:
         _counts[kernel][path] += 1
         if rows:
             _decode_rows[rows] = _decode_rows.get(rows, 0) + 1
         _count_heads(kernel, heads, path)
+        if form:
+            key = f"{kernel}@{heads} " + " ".join(
+                f"{k}={v}" for k, v in form.items()
+            )
+            _flash_forms[key] = _flash_forms.get(key, 0) + 1
+
+
+def flash_prefill_counts() -> Dict[str, int]:
+    """Traces of the flash body's GQA calls (lowered or interpreted) by
+    the call's query heads and the FORM its tile schedule took
+    (``ops/pallas_flash.flash_prefill``): ``"flash_prefill@72
+    tile=512x512 walk=window operands=bfloat16": 1``. ``tile``: the
+    query and key blocks' sides (``gqa_tiles``); ``walk``: ``window``
+    (a layer KIND's static window: the key axis of the grid is the
+    window's blocks), ``causal`` (no window: the tiles under the
+    diagonal) or ``dynamic`` (the window a runtime scalar of a
+    homogeneous scan's layer: the causal half, a tile outside the window
+    skipped a step at a time); ``operands``: the dtype the MXU is fed.
+    A latent caller's calls (``latent_flash``: its own square blocks)
+    are not among them. A count of its own, outside ``snapshot()``'s
+    keys, for the reason ``grouped_matmul_counts`` has one."""
+    with _lock:
+        return dict(sorted(_flash_forms.items()))
 
 
 def paged_decode_rows_per_step() -> Dict[int, int]:

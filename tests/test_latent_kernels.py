@@ -129,7 +129,7 @@ def test_flash_at_zero_padded_heads_is_the_expanded_xla_form(T, block):
 
     call = functools.partial(
         pallas_flash.flash_prefill, padded(q), padded(k), padded(v),
-        interpret=True, native=True, block=block,
+        interpret=True, block=block,
     )
     got = call(scale=scale)
     assert got.shape == (B, T, NH, 128)         # V's own padded width
@@ -295,7 +295,7 @@ def test_a_query_that_keeps_nothing_comes_out_zero_and_finite():
     keep[0, np.arange(T), np.arange(T)] = True
     keep[0, 128:] &= np.arange(T)[None] >= 128       # a first block with nothing
     call = functools.partial(
-        pallas_flash.flash_prefill, q, k, v, interpret=True, native=True)
+        pallas_flash.flash_prefill, q, k, v, interpret=True)
     want = np.asarray(call(keep=jnp.asarray(keep, jnp.int8)))
     np.testing.assert_allclose(
         want, _gathered(q, k, v, keep, 128 ** -0.5), rtol=2e-5, atol=2e-5)
@@ -308,15 +308,15 @@ def test_a_query_that_keeps_nothing_comes_out_zero_and_finite():
     np.testing.assert_array_equal(got[0, rest], want[0, rest])
 
 
-@pytest.mark.parametrize("native,block", [(False, None), (True, 256)])
-def test_flash_with_no_selection_is_the_program_it_was(native, block):
+@pytest.mark.parametrize("block", [None, 256])
+def test_flash_with_no_selection_is_the_program_it_was(block):
     """``keep=None`` traces the call without the argument: the same
     operands, the same numbers bit for bit; a selection is ONE more."""
     rng = np.random.default_rng(12)
     q, k, v = (jnp.asarray(rng.standard_normal((1, 256, NH, 128)), F32)
                for _ in range(3))
     call = functools.partial(
-        pallas_flash.flash_prefill, interpret=True, native=native, block=block)
+        pallas_flash.flash_prefill, interpret=True, block=block)
 
     def kernel_inputs(fn, *args):
         def walk(jaxpr):
