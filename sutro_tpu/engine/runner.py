@@ -151,6 +151,8 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         "kernel_paths": lowering.snapshot(),
         # the paged decode kernel's traces by the rows a grid step takes
         "paged_decode_rows_per_step": lowering.paged_decode_rows_per_step(),
+        # its calls under a row's selection (a latent layer's indexer)
+        "paged_decode_forms": lowering.paged_decode_forms(),
         "paged_decode_xla": lowering.xla_decode_count(),
         # a chunk of several tokens over a paged past: the kernel that
         # reads the pages in place, or the gather (``reference``)
@@ -850,8 +852,15 @@ class ModelRunner:
         ``past_len`` [B] plus its ``steps`` tokens), else ``dense_short``
         (the selection is everything and the dense latent paths run);
         and, for a DECODE dispatch, the rows a query's context holds
-        against the rows its attention reads, a row-step at a time
-        (host arithmetic, as ``_count_kv_pages``)."""
+        against the rows its attention reads (``selected``) and the
+        rows it FETCHES to read them, a row-step at a time (host
+        arithmetic, as ``_count_kv_pages``). ``fetched``: under the
+        paged kernel (``use_pallas``: the latent variant, under the
+        selection or dense) the rows of a row's pages up to its last
+        token's, whatever is selected of them; in XLA the selected rows
+        themselves, gathered by position, and the whole table where the
+        dispatch is dense. The pending tokens and the own row count in
+        all three."""
         if not (telemetry.ENABLED and self.mcfg.num_latent_layers):
             return
         telemetry.LATENT_ATTENTION_DISPATCHES_TOTAL.inc(1.0, form)
@@ -869,11 +878,23 @@ class ModelRunner:
             return
         # a row's s-th step sees its past, the window's earlier tokens
         # and itself; padding rows (no past) are no rows
-        ctx = past[past > 0, None] + np.arange(1, steps + 1)[None]
+        live = past[past > 0, None]
+        ctx = live + np.arange(1, steps + 1)[None]
+        chosen = np.minimum(ctx, topk)
         telemetry.SPARSE_ATTENTION_ROWS_TOTAL.inc(float(ctx.sum()), "context")
-        telemetry.SPARSE_ATTENTION_ROWS_TOTAL.inc(
-            float(np.minimum(ctx, topk).sum()), "selected"
-        )
+        telemetry.SPARSE_ATTENTION_ROWS_TOTAL.inc(float(chosen.sum()), "selected")
+        from ..ops import pallas_paged
+
+        PS, MP = self.ecfg.kv_page_size, self.ecfg.max_pages_per_seq
+        selecting = int(past.max()) + steps > topk
+        if self.use_pallas and pallas_paged.paged_decode_supported(
+            jax.ShapeDtypeStruct((1, 1, self.mcfg.page_width), jnp.float32),
+            self.cache.k_pages, selection_pages=MP if selecting else 0,
+        ):
+            fetched = ctx - live + -(-live // PS) * PS
+        else:
+            fetched = chosen if selecting else ctx - live + MP * PS
+        telemetry.SPARSE_ATTENTION_ROWS_TOTAL.inc(float(fetched.sum()), "fetched")
 
     def take_route_stats(self):
         """The routing counts of the last dispatch that was fetched

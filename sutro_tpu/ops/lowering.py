@@ -105,8 +105,10 @@ _kda: Dict[str, int] = dict.fromkeys(KDA_FORMS, 0)
 _decode_rows: Dict[int, int] = {}
 
 
-#: traces of the flash body's GQA calls by heads and form
-_flash_forms: Dict[str, int] = {}
+#: traces of a kernel's calls by heads and FORM, under ``"<kernel>@<heads>
+#: <key>=<value> ..."``: the flash body's GQA calls (their tile
+#: schedule) and the paged decode kernel's calls under a selection
+_forms: Dict[str, int] = {}
 
 
 #: traces of the attention kernels by the QUERY HEADS of the call, for a
@@ -134,7 +136,9 @@ def record_kernel(
     """Called from a kernel wrapper's traced body. ``rows``: the rows a
     grid step of the paged decode kernel takes in this trace; ``heads``:
     the call's query heads (an attention kernel's); ``form``: the tile
-    schedule a GQA call of the flash body took (``flash_prefill_counts``)."""
+    schedule a GQA call of the flash body took (``flash_prefill_counts``),
+    ``{"select": "keep"}`` of a paged decode call under a row's selection
+    (``paged_decode_forms``)."""
     path = "interpreted" if interpret else "lowered"
     with _lock:
         _counts[kernel][path] += 1
@@ -145,7 +149,14 @@ def record_kernel(
             key = f"{kernel}@{heads} " + " ".join(
                 f"{k}={v}" for k, v in form.items()
             )
-            _flash_forms[key] = _flash_forms.get(key, 0) + 1
+            _forms[key] = _forms.get(key, 0) + 1
+
+
+def _forms_of(kernel: str) -> Dict[str, int]:
+    with _lock:
+        return dict(sorted(
+            (k, n) for k, n in _forms.items() if k.startswith(kernel + "@")
+        ))
 
 
 def flash_prefill_counts() -> Dict[str, int]:
@@ -162,8 +173,21 @@ def flash_prefill_counts() -> Dict[str, int]:
     A latent caller's calls (``latent_flash``: its own square blocks)
     are not among them. A count of its own, outside ``snapshot()``'s
     keys, for the reason ``grouped_matmul_counts`` has one."""
-    with _lock:
-        return dict(sorted(_flash_forms.items()))
+    return _forms_of("flash_prefill")
+
+
+def paged_decode_forms() -> Dict[str, int]:
+    """Traces of the paged decode kernel (lowered or interpreted) under
+    a row's SELECTION, by the call's query heads: ``"paged_decode@64
+    select=keep": 1`` (``ops/sparse_attention.selected_decode`` under
+    ``use_pallas``: the latent variant with the indexer's selection as
+    one more operand). The dense calls of the same program (the branch
+    a dispatch at or under ``index_topk`` takes) are in ``snapshot()``
+    and ``kernel_heads_counts()`` with it and NOT here: a selecting
+    program reads ``select=keep`` at least once, and a selecting step
+    whose shape the kernel's gate refused reads ``reference`` with the
+    gate in ``kernel_heads_counts()``."""
+    return _forms_of("paged_decode")
 
 
 def paged_decode_rows_per_step() -> Dict[int, int]:
@@ -242,8 +266,12 @@ def record_sparse(form: str) -> None:
 
 def sparse_attention_counts() -> Dict[str, int]:
     """Traces of learned sparse attention, by form: ``gathered`` (one
-    decode step: the chosen latent rows fetched by position, in plain
-    XLA) and ``masked`` (a chunk of queries: the dense products under
+    decode step: one query over its selected rows, fetched by position
+    in plain XLA or, under ``use_pallas``, attended where they lie by
+    the paged kernel's latent variant under the selection:
+    ``snapshot()["paged_decode"]`` says which, ``lowered`` or
+    ``reference``, and ``paged_decode_forms`` tells the selecting call
+    from the dense branch's) and ``masked`` (a chunk of queries: the dense products under
     the selection's mask, in XLA or, for a prefill under ``use_pallas``,
     in the flash kernel: ``snapshot()["flash_prefill"]`` says which,
     ``lowered`` or ``reference``). A dispatch whose rows are all at or under

@@ -80,9 +80,19 @@ started, PERF.md §6 PR 55):
   ``max(pos - window + 1, 0)`` on, the later of that and the shared
   prefix's end: the pages before it have slid out of the window, and
   the host may have given them to another sequence. A call without it
-  (every call of a model with one pool) compiles to the program it had.
+  (every call of a model with one pool) compiles to the program it had;
+- a row's SELECTION (``keep`` / ``keep_tail``, static likewise: a latent
+  layer under an indexer, ops/sparse_attention.selected_decode) is one
+  more operand of the same body: a block's ``[R, MP * PS]`` int32 slab
+  beside ``q`` (64 KB a row at a table of 256 pages), of which a group
+  ANDs its columns' slice with the length test, and a tile a row for
+  the window's slots and the own row in the finalize. The ring, the
+  groups and their order are the dense call's: the pages are walked,
+  not skipped. A call without it traces the program it had
+  (tests/test_selected_decode_kernel.py holds the jaxpr's text).
 
-All math is float32.
+All math is float32, but the two products of the latent variant, whose
+operands are the pool's dtype (float32 accumulation).
 """
 
 from __future__ import annotations
@@ -118,8 +128,10 @@ def _paged_decode_kernel(
     window_start: bool = False,
     shared: bool = False,
     rows: int = 1,
+    keep: bool = False,
 ):
-    # ref layout varies with (window_slots, quantized, prefix, shared) —
+    # ref layout varies with (window_slots, quantized, prefix, shared,
+    # keep) —
     # walk an index instead of a per-case tuple unpack. ``shared``: ONE
     # pool whose rows serve both products (a latent layer's: module
     # docstring), so there is no V pool, current V, window V or V ring
@@ -142,6 +154,11 @@ def _paged_decode_kernel(
     m0_ref = next(it) if prefix else None
     l0_ref = next(it) if prefix else None
     acc0_ref = next(it) if prefix else None
+    # a row's SELECTION (module docstring): its paged positions in table
+    # order, a fused window's pending slots, the step's own row
+    keep_ref = next(it) if keep else None
+    keep_win_ref = next(it) if keep and window_slots else None
+    keep_own_ref = next(it) if keep else None
     sink_ref = next(it)
     out_ref = next(it)
     kbuf = next(it)
@@ -378,6 +395,23 @@ def _paged_decode_kernel(
         ok = jnp.logical_and(
             ok, jnp.logical_or(pos - tok < win, win <= 0)
         )
+        if keep:
+            # the group's slice of the selection: whole lane tiles of
+            # the block's slab from where the group's first column lies
+            # (a row under a selection starts at page 0, and ``done`` is
+            # a multiple of the group's own size, of twice it under a
+            # binary digit: 128 lanes at pages of 64), the row's sublane
+            # picked by a compare (a load at a dynamic sublane is not
+            # there to be had) and widened over the heads as int32 (nor
+            # is a broadcast of i1 vectors)
+            lanes = -(-GT // 128) * 128
+            at = pl.multiple_of(done * PS, GT if size == GP else 2 * GT)
+            slab = keep_ref[0, :, pl.ds(at, lanes)]          # [R, lanes]
+            mine = jax.lax.broadcasted_iota(jnp.int32, slab.shape, 0) == b - b0
+            kept = jnp.max(
+                jnp.where(mine, slab, 0), axis=0, keepdims=True
+            )[:, :GT]
+            ok = jnp.logical_and(ok, jnp.broadcast_to(kept, (NH, GT)) > 0)
         # [size, PS, KD] -> [GT, KD]: leading-dim collapse only (the
         # lane dim KD is untouched — Mosaic supports this shape cast)
         if shared:
@@ -552,6 +586,10 @@ def _paged_decode_kernel(
     v_cur = k_cur if shared else v_cur_ref[...].astype(jnp.float32)
     # one key: a lane reduction, not a matmul with a single column
     s_self = jnp.sum(q_bd * k_cur, axis=2, keepdims=True) * scale
+    if keep:
+        # the step's own row can fall out of a selection too
+        own_kept = jnp.broadcast_to(keep_own_ref[...], (R, NH, 1)) > 0
+        s_self = jnp.where(own_kept, s_self, NEG_INF)
     m_prev = m_ref[:, :, :1]                             # [R, NH, 1]
     m_new = jnp.maximum(m_prev, jnp.maximum(s_self, sink))
     if W:
@@ -566,6 +604,10 @@ def _paged_decode_kernel(
             ok_w,
             jnp.logical_or(wlen - slot_i < win, win <= 0),
         )
+        if keep:
+            ok_w = jnp.logical_and(
+                ok_w, jnp.broadcast_to(keep_win_ref[...], (R, NH, W)) > 0
+            )
         s_w = jax.lax.dot_general(
             q_bd, wk, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -897,11 +939,13 @@ ROWS_A_STEP = (8, 4, 2, 1)
 def decode_vmem_bytes(
     rows: int, NH: int, Dh: int, KD: int, PS: int, D: int, GP: int, W: int,
     *, pool_bytes: int, io_bytes: int, prefix: bool = False,
-    shared: bool = False, quantized: bool = False,
+    shared: bool = False, quantized: bool = False, keep_lanes: int = 0,
 ) -> int:
     """VMEM a call of ``rows`` rows a grid step takes, as the shapes
     tell it: the ring, a group's scores, the pipeline's two buffers of
-    every block, the scratch, and the finalize's values over the block.
+    every block (``keep_lanes``: the int32 lanes of a row's selection,
+    0 without one), the scratch, and the finalize's values over the
+    block.
     (The compiler's own count at the 4B cell's shape, 32 rows a step,
     was 18.1 MiB where this says 34.5: the blocks of one sublane pad
     less than a tile.)"""
@@ -930,7 +974,15 @@ def decode_vmem_bytes(
         blocks += 2 * tile(NH, 1, 4) + row_f32       # m0, l0, acc0
     scratch = 2 * row_f32 + 2 * tile(NH, 128, 4)     # q_bd, acc, m, l
     finalize = 3 * row_f32
-    return ring + group + rows * (2 * blocks + scratch + finalize)
+    # a block's selection, two buffers: its rows' paged positions (ONE
+    # slab, a row a sublane), the window's slots and the own row's
+    selection = 2 * (
+        tile(rows, keep_lanes, 4) + 2 * rows * tile(1, 128, 4)
+    ) if keep_lanes else 0
+    return (
+        ring + group + selection
+        + rows * (2 * blocks + scratch + finalize)
+    )
 
 
 def rows_per_step(B: int, *shape, **modes) -> int:
@@ -947,12 +999,30 @@ def rows_per_step(B: int, *shape, **modes) -> int:
     return 1
 
 
-def paged_decode_supported(q: jax.Array, k_pages: jax.Array) -> bool:
+def paged_decode_supported(
+    q: jax.Array, k_pages: jax.Array, selection_pages: int = 0,
+) -> bool:
     """Shape/size gate for the compiled TPU path (interpret mode has no
-    such constraints — tests call paged_decode_attention(interpret=True))."""
+    such constraints — tests call paged_decode_attention(interpret=True)).
+    ``selection_pages``: the pages of a row's table where the call is
+    under a SELECTION (``keep`` [B, pages * PS]); then pages of whole or
+    half lane tiles and a table of two pages or more, so that every
+    group's slice of the selection starts on a lane tile (``chain``)."""
     Dh = q.shape[-1]
     PS = k_pages.shape[2]
+    if selection_pages and not (
+        PS % 64 == 0 and (PS % 128 == 0 or selection_pages >= 2)
+    ):
+        return False
     return Dh % 128 == 0 and PS % 8 == 0
+
+
+def _keep_lanes(PS: int, MP: int) -> int:
+    """Lanes of a row's selection as the kernel holds it: the table's
+    positions up to whole lane tiles, and a tile more where pages are
+    no half tile (a group's slice is read in whole tiles from a start
+    that is then on no tile: interpret mode alone, the gate above)."""
+    return -(-(MP * PS + (0 if PS % 64 == 0 else 128)) // 128) * 128
 
 
 @functools.partial(
@@ -995,6 +1065,14 @@ def paged_decode_attention(
     # rows a grid step; None: what ``rows_per_step`` gives the call's
     # shapes (tests and benchmarks/paged_kernel_ab.py name one)
     rows: Optional[int] = None,
+    # a row's SELECTION (the latent variant under an indexer,
+    # ops/sparse_attention.selected_decode): ``keep`` [B, MP * PS], the
+    # row's paged positions in table order, and ``keep_tail`` [B, W + 1],
+    # the window's pending slots and the step's own row; bool or any
+    # integer, nonzero = attended. A position past ``past_len`` or a
+    # slot past ``win_len`` stays out whatever they say
+    keep: Optional[jax.Array] = None,
+    keep_tail: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Returns [B, NH, Dh] attention outputs for one decode step.
 
@@ -1021,7 +1099,18 @@ def paged_decode_attention(
     (engine/runner decode_multi): tokens sampled earlier in the fused
     window whose K/V have NOT been written to the page pool yet — the
     bulk page write happens once per window, outside the step scan, so
-    the multi-GB pool is never copied per step."""
+    the multi-GB pool is never copied per step.
+
+    ``keep`` / ``keep_tail`` put the softmax under a row's SELECTION:
+    the row's pages are walked as without one (bytes follow the row's
+    LENGTH: a selection of a third of the positions leaves runs of a row
+    or two, nothing a page DMA could skip), and a group's columns are
+    ANDed with the row's slice of ``keep`` where the length test stands;
+    the pending slots and the own row with ``keep_tail`` in the
+    finalize. At least one candidate of a row is kept (the caller's
+    top-k of one or more), which is what lets a masked column's ``exp``
+    be forgotten as a column past the row's end is. Static, as
+    ``window_start``: a call without them traces the program it had."""
     B, NH, Dh = q.shape
     L, NP, PS, KD = k_pages.shape
     KVH = k_cur.shape[1]
@@ -1029,6 +1118,13 @@ def paged_decode_attention(
     scale = Dh ** -0.5 if scale is None else scale
     W = 0 if win_k is None else win_k.shape[1]
     shared = v_pages is None
+    selecting = keep is not None
+    keep_lanes = _keep_lanes(PS, MP) if selecting else 0
+    if selecting:
+        # a selection is a latent layer's, whose pool is one and whole
+        assert shared and pfx_cnt is None and not window_start
+        assert keep.shape == (B, MP * PS), (keep.shape, B, MP, PS)
+        assert keep_tail.shape == (B, W + 1), (keep_tail.shape, W)
 
     # heads on the sublanes, as the kernel keeps m / l
     if sink is None:
@@ -1043,10 +1139,12 @@ def paged_decode_attention(
         B, NH, Dh, KD, PS, D, GP, W,
         pool_bytes=k_pages.dtype.itemsize, io_bytes=q.dtype.itemsize,
         prefix=prefix, shared=shared, quantized=quantized,
+        keep_lanes=keep_lanes,
     )
     assert B % R == 0, (B, R)
     lowering.record_kernel(
-        "paged_decode", interpret=interpret, rows=R, heads=q.shape[1]
+        "paged_decode", interpret=interpret, rows=R, heads=q.shape[1],
+        form={"select": "keep"} if selecting else None,
     )
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -1062,6 +1160,7 @@ def paged_decode_attention(
         window_start=window_start,
         shared=shared,
         rows=R,
+        keep=selecting,
     )
 
     # a grid step's block of each per-row operand: its R rows. Index
@@ -1113,6 +1212,24 @@ def paged_decode_attention(
             l0.astype(jnp.float32).reshape(B, NH, 1),
             acc0.astype(jnp.float32),
         ]
+    if selecting:
+        # int32, a block of R rows ONE [R, lanes] slab of [B // R, R,
+        # lanes] (its last two dims the array's whole: an int8 block of
+        # 8 rows is under Mosaic's (32, 128) tile), zeros behind the
+        # table's end up to whole lane tiles
+        paged = jnp.pad(
+            keep.astype(jnp.int32), ((0, 0), (0, keep_lanes - MP * PS))
+        ).reshape(B // R, R, keep_lanes)
+        tail = keep_tail.astype(jnp.int32).reshape(B, 1, W + 1)
+        in_specs.append(
+            pl.BlockSpec((1, R, keep_lanes), lambda g, *s: (g, 0, 0))
+        )
+        operands.append(paged)
+        if W:
+            in_specs.append(rows_of(1, W))
+            operands.append(tail[..., :W])
+        in_specs.append(rows_of(1, 1))
+        operands.append(tail[..., W:])
     in_specs.append(pl.BlockSpec((NH, 1), lambda g, *s: (0, 0)))
     operands.append(sink_g)
 

@@ -15,12 +15,33 @@ the selection is EXACT (never an approximate top-k): two forms of it
 give the same set.
 
 - ``selected_decode`` (ONE query a row over a paged past, every decode
-  step): ``I`` over the row's cached index keys, a fused window's pending
-  ones and its own; ``lax.top_k`` (equal scores: the lower index first);
-  the chosen latent rows fetched BY POSITION from the pool where it lies
-  (one row gather ``[B, topk, width]``; the rows' tables are gathered for
-  the index keys alone, a fifth of a row's bytes), then the absorbed
-  products over those rows. Counted ``gathered``.
+  step): ``I`` over the row's cached index keys (the rows' tables
+  gathered for them alone, a fifth of a row's bytes), a fused window's
+  pending ones and its own; then one of two bodies, both counted
+  ``gathered`` (one query over its selected rows):
+
+  - under ``use_pallas``, where the paged kernel's gate takes the shape
+    (``pallas_paged.paged_decode_supported``: pages of half a lane tile
+    or more): the selection as a MASK over the candidates in the order
+    of their positions (``topk_mask``: no sort, nothing needs positions)
+    and ONE call of ``pallas_paged.paged_decode_attention``'s latent
+    variant with that mask as one more operand (``keep`` over the row's
+    paged positions, ``keep_tail`` over the pending slots and the own
+    row: either can fall out of the top ``topk``). The row's pages are
+    read where they lie, through the ring the dense step uses; both
+    products and the softmax stay in VMEM. It walks every page up to the
+    row's last token's, 3.4 times the selected rows at a third selected,
+    and skips none: a page of 64 holds no chosen row once in 3e9 at that
+    share. Counted ``paged_decode`` ``lowered`` /
+    ``interpreted`` under ``select=keep`` (``lowering.
+    paged_decode_forms``), which tells it from the dense branch's call
+    in the same program;
+  - else in XLA, the tests' reference: ``lax.top_k`` (equal scores: the
+    lower index first), the chosen latent rows fetched BY POSITION from
+    the pool (one row gather ``[B, topk, width]``), a ``where`` against
+    the pending rows, the absorbed products over them with the float32
+    scores through HBM. Under ``use_pallas`` counted ``paged_decode``
+    ``reference`` with the gate's name.
 - ``masked_attention`` (a chunk of queries: a prefill with no past in
   the expanded form, a chunk over a paged past in the absorbed form): a
   block of queries at a time, ``I`` against every key the block may see,
@@ -47,7 +68,8 @@ give the same set.
 context passes ``topk`` (the selection is everything): a chunk with no
 past of at most ``topk`` tokens statically, one decode step by a
 ``lax.cond`` on the rows' lengths, so that the program of a short
-dispatch is PR 42's (the paged kernel's latent variant, the flash body).
+dispatch is PR 42's (the paged kernel's latent variant, the flash body)
+and a long one's differs from it by the selection alone.
 """
 
 from __future__ import annotations
@@ -157,15 +179,18 @@ def selected_decode(
     index: Indexer,
     *, positions, scale: float, pages, layer, page_table, past_len,
     win_rows=None, win_len=None, value_width: int, valid_len=None,
-    return_selection: bool = False,
+    use_pallas: bool = False, return_selection: bool = False,
 ):
     """One decode step over the SELECTED rows, ``[B, 1, NH,
     value_width]`` (``valid_len`` is ``latent_attention``'s argument and
     says nothing here: a row's one query is real). Candidates in the
     order of their positions: the paged past (``< past_len``), a fused
     window's pending tokens (``past_len + slot``), the step's own.
-    ``return_selection`` adds ``(positions [B, K], chosen [B, K] bool)``:
-    the tests' view."""
+    Under ``use_pallas``, where the paged kernel's gate takes the shape,
+    the selection is a MASK over them and the kernel attends (the
+    module's docstring); else ``lax.top_k``'s positions and the row
+    gather, which ``return_selection`` (the tests' view) asks for too:
+    it adds ``(positions [B, K], chosen [B, K] bool)``."""
     lowering.record_sparse("gathered")
     B = q.shape[0]
     L, NP, PS, W = pages.shape
@@ -188,8 +213,28 @@ def selected_decode(
         pos.append(positions)
         ok.append(jnp.ones((B, 1), bool))
         pos, ok = jnp.concatenate(pos, axis=1), jnp.concatenate(ok, axis=1)
+        ok = ok & (pos <= positions)
         score = index_scores(index.q, index.w, jnp.concatenate(iks, axis=1))
-        score = jnp.where(ok & (pos <= positions), score[:, 0] + 0.0, -jnp.inf)
+        score = jnp.where(ok, score[:, 0] + 0.0, -jnp.inf)
+    if use_pallas and not return_selection:
+        from .pallas_paged import paged_decode_attention, paged_decode_supported
+
+        if paged_decode_supported(q[:, 0], pages, selection_pages=MP):
+            with jax.named_scope("dsa_select"):
+                keep = topk_mask(score, ok, index.topk)
+            with jax.named_scope("dsa_attend"):
+                win = dict(win_k=win_rows, win_len=win_len) if has_win else {}
+                out = paged_decode_attention(
+                    q[:, 0], pages, None, layer, page_table, past_len,
+                    row, None, jnp.asarray(0, jnp.int32), scale=scale,
+                    keep=keep[:, :CTX], keep_tail=keep[:, CTX:], **win,
+                )
+            return out[:, None, :, :value_width]
+        lowering.record_reference(
+            "paged_decode", q.shape[2],
+            f"paged_decode_supported: a selection over pages of {PS}, "
+            f"rows {W} wide",
+        )
     with jax.named_scope("dsa_select"):
         K = min(index.topk, score.shape[1])
         top, at = jax.lax.top_k(score, K)            # ties: the lower index
@@ -406,6 +451,6 @@ def sparse_latent_attention(
     pending = 0 if win_len is None else win_len
     return jax.lax.cond(
         jnp.max(past_len) + pending + 1 > index.topk,
-        lambda: selected_decode(q, k, index, **dense),
+        lambda: selected_decode(q, k, index, use_pallas=use_pallas, **dense),
         lambda: latent_attention(q, k, None, use_pallas=use_pallas, **dense),
     )

@@ -468,10 +468,14 @@ SPARSE_ATTENTION_ROWS_TOTAL = REGISTRY.counter(
     "sutro_sparse_attention_rows_total",
     "Cached rows of the decode dispatches of such a model, a row-step "
     "at a time (host arithmetic from the rows' lengths): context (what "
-    "the row's past holds, itself included) and selected (what its "
-    "attention reads: at most index_topk). selected over context is the "
-    "share of the cache the attention reads; 1.0 is the dense path",
-    labels=("kind",),  # context | selected
+    "the row's past holds, itself included), selected (what its "
+    "attention reads: at most index_topk) and fetched (what it moves to "
+    "read them: the rows of the row's pages under the paged kernel, the "
+    "selected rows themselves where XLA gathers them by position). "
+    "selected over context is the share of the cache the attention "
+    "reads (1.0 is the dense path); fetched over selected is what the "
+    "page walk pays for reading them where they lie",
+    labels=("kind",),  # context | selected | fetched
     unit="rows",
     max_series=4,
 )

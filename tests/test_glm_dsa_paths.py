@@ -340,6 +340,57 @@ def test_a_prompt_past_index_topk_takes_the_flash_body_under_its_mask(
     assert sparse_now["masked"] > sparse["masked"]
 
 
+def test_steps_past_index_topk_take_the_paged_kernel_under_their_selection(
+    interpreted
+):
+    """Pages of 64 (the kernel's gate takes a selection over pages of
+    half a lane tile or more), through the scheduler: a prefill, fused
+    windows and single steps of rows of 25-60 tokens under an
+    ``index_topk`` of 8. Greedy tokens are the reference's; the
+    selecting call is counted ``paged_decode`` interpreted under
+    ``select=keep`` and never ``reference``, ``gathered`` as ever; and
+    the rows FETCHED are the rows of the pages walked, over the rows
+    selected."""
+    runner = ModelRunner(
+        MCFG, engine(use_pallas=True, kv_page_size=64, max_pages_per_seq=2,
+                     prefill_chunk=64, decode_multi_step=4),
+        num_pages=9,
+    )
+    assert runner.use_pallas
+    tok = ByteTokenizer(vocab_size=MCFG.vocab_size)
+    prompts = ["the first prompt, a little longer than the others are",
+               "a second, of middling length", "and a third one"]
+    telemetry.set_enabled(True)
+    (before, sparse) = _counts()
+    forms = lowering.paged_decode_forms()
+    rows0 = {k: _series("sutro_sparse_attention_rows_total", k)
+             for k in ("context", "selected", "fetched")}
+    out = {}
+    ContinuousBatcher(runner, stop_ids=[]).run(
+        [GenRequest(row_id=i, prompt_ids=np.array(tok.encode(p), np.int32),
+                    max_new_tokens=9, temperature=0.0)
+         for i, p in enumerate(prompts)],
+        on_result=lambda r: out.__setitem__(r.row_id, r),
+    )
+    for i, p in enumerate(prompts):
+        ids = np.array(tok.encode(p), np.int32)
+        seq = np.concatenate([ids, out[i].token_ids]).astype(np.int32)
+        ref = want(runner.params, seq, range(len(ids) - 1, len(seq) - 1))
+        assert list(np.argmax(ref, -1)) == list(out[i].token_ids)
+    now, sparse_now = _counts()
+    assert now["paged_decode"]["interpreted"] > before["paged_decode"]["interpreted"]
+    assert now["paged_decode"]["reference"] == before["paged_decode"]["reference"]
+    key = f"paged_decode@{MCFG.num_heads} select=keep"
+    assert lowering.paged_decode_forms()[key] > forms.get(key, 0)
+    assert sparse_now["gathered"] > sparse["gathered"]
+    rows = {k: _series("sutro_sparse_attention_rows_total", k) - v
+            for k, v in rows0.items()}
+    # every row-step walks ONE page of 64 rows beside its pending tokens
+    # and itself, and attends to index_topk of them
+    assert rows["selected"] < rows["context"] < rows["fetched"]
+    assert rows["fetched"] > 64 * rows["selected"] / TOPK
+
+
 def _mixer_operands(T, seed=60):
     params = transformer.init_params(MCFG, jax.random.PRNGKey(1), jnp.float32)
     lp = jax.tree_util.tree_map(lambda a: a[1], params["layers"]["mla"])

@@ -280,10 +280,46 @@ def test_the_selected_and_context_rows_are_host_arithmetic(runner):
             "sutro_sparse_attention_rows_total", {}
         ).get("series", {}).get(kind, 0.0)
 
-    c0, s0 = rows("context"), rows("selected")
+    c0, s0, f0 = rows("context"), rows("selected"), rows("fetched")
     # rows of 20 and 5 tokens (two padding rows), 3 steps: contexts of
-    # 21, 22, 23 and 6, 7, 8; at most 8 of each are read
+    # 21, 22, 23 and 6, 7, 8; at most 8 of each are read, and in XLA
+    # (``use_pallas`` off) those are the rows fetched, by position
     runner._count_latent("absorbed", np.array([20, 5, 0, 0]), 3)
     assert rows("context") - c0 == 21 + 22 + 23 + 6 + 7 + 8
     assert rows("selected") - s0 == 3 * 8 + 6 + 7 + 8
+    assert rows("fetched") - f0 == 3 * 8 + 6 + 7 + 8
     assert lowering.sparse_attention_counts().keys() == {"gathered", "masked"}
+    # a selecting call of the paged kernel is a FORM of that kernel's
+    # count (by heads), no third way of applying a selection
+    assert all(
+        key.startswith("paged_decode@") and key.endswith(" select=keep")
+        for key in lowering.paged_decode_forms()
+    )
+
+
+@pytest.mark.parametrize("use_pallas,past,fetched", [
+    # a dense dispatch (5 + 2 <= 8) gathers the rows' whole tables
+    (False, [5, 3], 2 * (MP * PS + 1 + MP * PS + 2)),
+    # the paged kernel walks a row's pages up to its last token's: one
+    # page of 8 for 5 and for 3 tokens, three for 20, beside the
+    # pending tokens and the own row
+    (True, [5, 3], 2 * (8 + 1 + 8 + 2)),
+    # under a selection pages of 8 are the gate's to refuse: XLA
+    # fetches the 8 selected rows by position
+    (True, [20, 3], 8 + 8 + 4 + 5),
+])
+def test_the_fetched_rows_follow_the_body_that_attends(
+    runner, monkeypatch, use_pallas, past, fetched
+):
+    telemetry.set_enabled(True)
+
+    def rows(kind):
+        return telemetry.REGISTRY.collect()[
+            "sutro_sparse_attention_rows_total"]["series"].get(kind, 0.0)
+
+    monkeypatch.setattr(runner, "use_pallas", use_pallas)
+    f0, s0 = rows("fetched"), rows("selected")
+    runner._count_latent("absorbed", np.array(past + [0, 0]), 2)
+    assert rows("fetched") - f0 == fetched
+    assert rows("selected") - s0 == sum(
+        min(n + s, 8) for n in past for s in (1, 2))
