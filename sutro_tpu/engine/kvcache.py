@@ -354,6 +354,12 @@ def _refuse_layout(mcfg: ModelConfig, ecfg: EngineConfig, sharding) -> None:
                "the slot pool under a mesh")
             + " is not built"
         )
+    if mcfg.state_kind == "mamba1" and sharding is not None:
+        raise NotImplementedError(
+            f"{mcfg.name} keeps a Mamba-1 state a slot beside differential "
+            "heads read as pairs: the slot pool and the pairs under a mesh "
+            "are not built"
+        )
     if mcfg.num_window_layers and quant:
         raise NotImplementedError(
             f"{mcfg.name} keeps K/V a pool a kind: the window pool "
@@ -1173,6 +1179,53 @@ def _advance_kda(
     return jax.lax.scan(layer, ssm, jnp.arange(L, dtype=jnp.int32))
 
 
+def _advance_mamba1(
+    ssm: jax.Array,            # [L, NS, N, I]: the pool
+    chunk: dict,               # "dt" f32, "x", "B", "A", "conv": MixedChunk.ssm
+    slots: jax.Array,          # [B] int32 (0: the row does not move)
+    fresh: jax.Array,          # [B] bool: the row's state before is 0
+    n: jax.Array,              # [B] int32: tokens accepted
+    K1: int,
+):
+    """``S_n = exp(A t_n) * S_0 + sum_{i<n} exp(A (t_n - t_i)) * B_i (dt_i
+    x_i)^T`` for each row's slot, ``t_i`` the step sizes summed up to and
+    with token i: ONE pass serves every accepted length (a token not
+    taken has ``dt`` 0: it neither decays nor feeds). The decay is a
+    value a channel AND a state column, so each token's is formed
+    (``W`` exponentials a state element, once a chunk), every one at
+    most 1. The rows' slots are gathered, advanced and scattered back,
+    a layer at a time. Returns ``(pool, conv columns [L, B, K-1, I]
+    after the n tokens)``."""
+    f32 = jnp.float32
+    L = ssm.shape[0]
+    B = slots.shape[0]
+
+    def layer(pool, l):
+        dt, x, Bm, ext = (
+            chunk_tokens(chunk[name], l, L, B)
+            for name in ("dt", "x", "B", "conv")
+        )
+        A = chunk["A"][l]                                          # [N, I]
+        W = dt.shape[1]
+        took = (jnp.arange(W, dtype=jnp.int32)[None, :] < n[:, None])[..., None]
+        dt = jnp.where(took, dt.astype(f32), 0.0)                  # [B, W, I]
+        t = jnp.cumsum(dt, axis=1)
+        left = t[:, -1:] - t                                       # [B, W, I]
+        fed = dt * x.astype(f32)
+        with jax.named_scope("mamba1_commit"):
+            S = pool[l][slots].astype(f32)                         # [B, N, I]
+            S = jnp.where(fresh[:, None, None], 0.0, S)
+            new = jnp.exp(t[:, -1, None, :] * A) * S
+            for w in range(W):
+                new = new + jnp.exp(left[:, w, None, :] * A) * (
+                    Bm[:, w, :, None].astype(f32) * fed[:, w, None, :]
+                )
+            pool = pool.at[l, slots].set(new.astype(pool.dtype))
+        return pool, columns_after(ext, n, K1)
+
+    return jax.lax.scan(layer, ssm, jnp.arange(L, dtype=jnp.int32))
+
+
 @part("cache")
 def write_state(
     cache: KVCache,
@@ -1208,6 +1261,10 @@ def write_state(
         ssm, cols = _advance_kda(
             ssm, chunk, slots, start <= 0, valid_len, K1,
             use_pallas=use_pallas,
+        )
+    elif "A" in chunk:      # a Mamba-1 chunk's tokens (a prefill hands "final")
+        ssm, cols = _advance_mamba1(
+            ssm, chunk, slots, start <= 0, valid_len, K1
         )
     elif "final" in chunk:
         at = jnp.arange(L, dtype=jnp.int32)[:, None] * NS + slots[None]

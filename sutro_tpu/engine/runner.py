@@ -173,6 +173,9 @@ def device_report(ecfg: Optional[EngineConfig] = None) -> dict:
         "kda": lowering.kda_counts(),
         "kda_state_read": lowering.kda_state_read_counts(),
         "kda_state_commit": lowering.kda_state_commit_counts(),
+        # a Mamba-1 layer's traces by form: the prefill's chunked scan,
+        # a step from the slot, a fused window's step from its carry
+        "mamba1": lowering.mamba1_counts(),
         # a latent layer's attention by form, whichever path computed it
         # (``kernel_paths`` above says kernel or XLA form)
         "latent_attention": lowering.latent_counts(),
@@ -489,6 +492,11 @@ class ModelRunner:
             width * jnp.dtype(dt).itemsize
             for _, width, dt in transformer.pending_buffers(m, act)
         )
+        if m.state_kind == "mamba1":
+            # the rows' state in float32 as the scan carries it
+            # (``transformer.running_state``), and the copy a step's new
+            # state stands in beside it
+            a_row += 2 * 4 * m.state_rows * m.state_inner
         return m.num_state_layers * self.ecfg.decode_batch_size * a_row
 
     def state_step_bytes(self, rows: int) -> int:
@@ -1392,7 +1400,7 @@ class ModelRunner:
         # absorbed query is as wide as the pool's row
         head = jax.ShapeDtypeStruct((
             1, 1, self.mcfg.page_width if self.mcfg.num_latent_layers
-            else self.mcfg.head_dim,
+            else self.mcfg.kernel_head_dim,
         ), jnp.float32)
         kernel = self.use_pallas and pallas_paged.paged_decode_supported(
             head, self.cache.k_pages
@@ -1402,11 +1410,23 @@ class ModelRunner:
         # window layer NEEDS the pages of its window, and fetches from
         # the page of its oldest visible position on
         W = self.mcfg.sliding_window
-        for layers, win in (
-            (self.mcfg.num_pool_layers, 0), (self.mcfg.num_window_layers, W),
+        # every READER of a pool fetches it: a "cross" layer reads a full
+        # layer's pages again (``ModelConfig.kv_readers``)
+        for pool, mixer, own, win in (
+            ("full", "attention", self.mcfg.num_pool_layers, 0),
+            ("window", "swa", self.mcfg.num_window_layers, W),
         ):
+            layers = self.mcfg.kv_readers(mixer)
             if not layers:
                 continue
+            read = float(steps) * float(
+                (np.minimum(past, win) if win else past).sum()
+            )
+            telemetry.KV_READ_TOKENS_TOTAL.inc(read * own, pool, "own")
+            if layers > own:
+                telemetry.KV_READ_TOKENS_TOTAL.inc(
+                    read * (layers - own), pool, "shared"
+                )
             first = first_live_page(past, win, PS) if win else 0
             need = (np.minimum(past, win - 1) if win else past) / PS
             if kernel:
@@ -1665,7 +1685,7 @@ class ModelRunner:
             wv0 = jnp.zeros((L, B, steps, VD), dtype) if VD else None
         mixed = not self.mcfg.homogeneous
         K1 = self.mcfg.conv_state_len or self.mcfg.state_conv_len
-        wc0 = ws0 = past = None
+        wc0 = ws0 = past = run0 = None
 
         def window_of(state):  # [L, B, K-1, C] -> [L, B, K-1 + steps, C]
             return jnp.concatenate(
@@ -1705,9 +1725,12 @@ class ModelRunner:
                     ws0["conv"] = transformer.window_put(
                         ws0["conv"], j, past.conv[:, :, j]
                     )
+                # Mamba-1 layers: the rows' state itself rides the scan
+                # (``transformer.running_state``; None for other kinds)
+                run0 = transformer.running_state(m, past)
 
         def body(carry, step_idx):
-            wk, wv, wc, ws, last = carry
+            wk, wv, wc, ws, run, last = carry
             conv_state = None
             if wc is not None:
                 with part("cache"):
@@ -1722,6 +1745,7 @@ class ModelRunner:
                 state_past=None if ws is None else dataclasses.replace(
                     past, conv=ws["conv"],
                     window=tuple(ws[n] for n, _, _ in pending) + (step_idx,),
+                    running=run,
                 ),
             )
             route = self._route_stats(k)
@@ -1743,6 +1767,8 @@ class ModelRunner:
                             )
                             for name, buf in ws.items()
                         }
+                        if run is not None:
+                            run = k.ssm["S"]
                     k = k.k
                 wk = jax.lax.dynamic_update_slice(
                     wk, k.astype(dtype).reshape(L, B, 1, KD),
@@ -1773,13 +1799,20 @@ class ModelRunner:
                 temperature=temperature, top_p=top_p, top_k=top_k,
             )
             logp = cumulative_logprob(step_logits, tok)
-            return (wk, wv, wc, ws, tok), (tok, logp, route)
+            return (wk, wv, wc, ws, run, tok), (tok, logp, route)
 
-        (wk, wv, wc, ws, _), (toks, logps, route) = jax.lax.scan(
+        (wk, wv, wc, ws, _, _), (toks, logps, route) = jax.lax.scan(
             body,
-            (wk0, wv0, wc0, ws0, last),
+            (wk0, wv0, wc0, ws0, run0, last),
             jnp.arange(steps, dtype=jnp.int32),
         )
+        if run0 is not None:
+            # the commit runs from the pool and the window's tokens, for
+            # ANY accepted length; what it needs beside them is the
+            # layers' ``A`` (``kvcache._advance_mamba1``)
+            ws["A"] = transformer.mamba1_decay(
+                params["layers"]["mamba1"]["a_log"]
+            )
         if mixed:
             # ``ws`` goes to ``kvcache.write_state`` as the scan carried it:
             # the commit reads each layer's tokens where they lie

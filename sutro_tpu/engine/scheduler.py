@@ -2938,7 +2938,9 @@ class ContinuousBatcher:
         """Span attrs of a dispatch of a model that keeps K/V a pool a
         kind: the mean over its rows of the tokens a full layer reads
         (the context) and a window layer reads (the context, at most
-        the window); of a model whose latent layers have an indexer,
+        the window), and how many layers read each pool
+        (``ModelConfig.kv_readers``); of a model whose latent layers
+        have an indexer,
         the rows of the context and the rows the attention reads.
         ``ctx`` is an iterable of the rows' contexts, not walked for
         any other model: nothing for those."""
@@ -2958,11 +2960,18 @@ class ContinuousBatcher:
                     float(np.minimum(c, topk).mean()), 1
                 ),
             }
+        m = self.runner.mcfg
         return {
             "kv_tokens_full": round(float(c.mean()), 1),
             "kv_tokens_window": round(
                 float(np.minimum(c, window).mean()), 1
             ),
+            # the layers that read each pool a step (a "cross" layer
+            # reads a full layer's again) and the layers that keep a
+            # state: a step's bytes counted from what the program says
+            "kv_readers_full": m.kv_readers("attention"),
+            "kv_readers_window": m.kv_readers("swa"),
+            "state_layers": m.num_state_layers + m.num_conv_layers,
         }
 
     def _note_window(self, b: _DecodeBatch, steps: int) -> None:

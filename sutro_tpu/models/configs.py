@@ -3,7 +3,7 @@
 The reference ships no model code — its catalog is a list of names sent to a
 remote fleet (/root/reference/sutro/common.py:20-45). Here each catalog name
 maps to a full architecture spec for the in-tree TPU engine. One
-config-driven decoder-only transformer (models/transformer.py) covers fifteen
+config-driven decoder-only transformer (models/transformer.py) covers sixteen
 families:
 
 - Qwen3 dense (0.6b..32b): GQA + QK-RMSNorm, SwiGLU, RoPE
@@ -113,6 +113,20 @@ families:
   (``moe_shared_gate``). ``-l9-ep8`` is one chip of the eight that
   share every layer of the first of six pipeline stages: layers 0-8,
   experts 0-31 of each routed layer's 256, an eighth of the vocabulary
+- Phi-4-mini-flash-reasoning (``model_type`` phi4flash; SambaY, a
+  decoder-hybrid-decoder, arXiv:2507.06607): blocks under LayerNorm with
+  a bias (``block_norm``), a dense SwiGLU in every one, no positional
+  embedding, and five mixers by the layer's place: Mamba-1 ("mamba1": a
+  state ``[d_state, d_inner]`` a sequence with a decay a CHANNEL AND a
+  state column, kept a SLOT like Mamba-2's), DIFFERENTIAL attention
+  (``attn_differential``, arXiv:2410.05258: heads paired, two softmaxes
+  over one value of two heads, their difference under a learned lambda
+  and a norm of its own) over a window ("swa") in the first half and
+  over everything in ONE layer ("attention"), whose K/V the second
+  half's "cross" layers read again with queries of their own
+  (``kv_source_layer``) between gated memory units ("gmu": a gate on
+  the scan output of ONE Mamba-1 layer of the same forward,
+  ``memory_layer``); projection biases on the attention layers
 
 Hyperparameters follow the public model cards; exactness matters only when
 loading real checkpoints (engine/weights.py validates shapes against these).
@@ -200,7 +214,7 @@ class ModelConfig:
     # ``w_s`` [H, 1]: one scalar a token (Qwen2-MoE's shared expert gate)
     moe_shared_gate: bool = False
     # Per-layer mixer kinds, "attention" | "swa" | "conv" | "mamba" |
-    # "mla" | "kda";
+    # "mla" | "kda" | "mamba1" | "gmu" | "cross";
     # empty => attention everywhere. A "swa" layer is attention over the
     # last ``sliding_window`` positions, with the plain rotary embedding
     # of ``local_rope_theta`` (``rope_theta`` when that is None) whatever
@@ -240,12 +254,46 @@ class ModelConfig:
     kda_rank: int = 0
     kda_beta_scale: float = 1.0
     kda_chunk: int = 64
+    # A "mamba1" layer is Mamba-1 (models/transformer.py
+    # ``mamba1_mixer``): ``mamba1_inner`` channels, each with
+    # ``mamba1_state`` state columns; the step ``dt`` a channel from a
+    # pair of rank ``mamba1_dt_rank``; B and C a token, shared by every
+    # channel; ``A`` [inner, state], so a token's decay ``exp(dt A)`` is
+    # a value a channel AND a column (no head axis: Mamba-2's chunk form
+    # does not apply); a depthwise causal conv of ``mamba1_conv`` taps
+    # over x alone; a prefill scans ``mamba1_chunk`` tokens at a time
+    mamba1_inner: int = 0
+    mamba1_state: int = 0
+    mamba1_conv: int = 0
+    mamba1_dt_rank: int = 0
+    mamba1_chunk: int = 64
+    # A "gmu" layer is a gated memory unit, ``(m * silu(u W_1)) W_2``: a
+    # gate of ``mamba1_inner`` on ``m``, the scan output ``y`` (with the
+    # ``D x`` term, before its own gate) that Mamba-1 layer
+    # ``memory_layer`` gave the SAME token in the same forward. It keeps
+    # nothing. A "cross" layer is an attention layer with a query, an
+    # output projection and nothing else of its own: its K and V are
+    # those of the "attention" layer ``kv_source_layer`` for the same
+    # row (that layer's pool, its chunk's K/V). It keeps nothing either
+    memory_layer: int = -1
+    kv_source_layer: int = -1
     # The form of the output gate of the model's attention layers ("":
     # none), ``sigmoid(x W_gate)`` from the layer's normed input, times
     # the attention's output before ``wo``: "channel" one value a channel
     # (``W_gate`` [H, heads x head_dim]: Solar Open 2), "head" one scalar
     # a query head (``W_gate`` [H, heads]: Laguna)
     attn_gate: str = ""
+    # DIFFERENTIAL attention (arXiv:2410.05258) in every "attention",
+    # "swa" and "cross" layer: query heads (2i, 2i+1) are ONE head's
+    # ``(q1, q2)``, KV heads pair the same way into ``(k1, k2)`` and one
+    # value ``[v1 | v2]`` of two heads; ``a_j = softmax(q_j k_j^T) v``,
+    # the head's output ``RMSNorm(a1 - lambda a2) (1 - lambda_init)``
+    # with ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``,
+    # ``lambda_init = 0.8 - 0.6 exp(-0.3 l)`` at layer ``l``
+    # (models/transformer.py ``differential_heads``). The cache keeps
+    # ``num_kv_heads`` heads of ``head_dim`` as any model's; the kernels
+    # are called at the PAIRS, heads of ``kernel_head_dim``
+    attn_differential: bool = False
     # Query heads of a "swa" layer where they are not ``num_heads`` (0:
     # they are); both kinds keep ``num_kv_heads``, so the pools and a
     # chunk's K/V are of one width and only Q, the gate and ``wo``
@@ -312,6 +360,11 @@ class ModelConfig:
     attn_bias: bool = False
     # Gemma-style zero-centered RMSNorm weights (out = x * (1 + w))
     norm_zero_centered: bool = False
+    # the norm in front of a block's sublayers and of the head:
+    # "rmsnorm" (a scale) | "layernorm" (mean taken off, a scale and a
+    # bias: ``*_norm`` and ``*_norm_b``); models/transformer.py
+    # ``block_norm`` is THE place it is read
+    block_norm: str = "rmsnorm"
     # Gemma3 extras
     post_norms: bool = False              # post-attn/post-mlp RMSNorm
     embed_scale: bool = False             # embeddings * sqrt(hidden)
@@ -412,6 +465,13 @@ class ModelConfig:
         if mixer == "swa" and self.window_num_heads:
             return self.window_num_heads
         return self.num_heads
+
+    @property
+    def kernel_head_dim(self) -> int:
+        """The head the attention kernels' gates and block-diagonal
+        products see: a PAIR of heads under differential attention
+        (``[k1 | k2]`` lies in a page's row as the write left it)."""
+        return self.head_dim * (2 if self.attn_differential else 1)
 
     def rotary_dim_of(self, mixer: str) -> int:
         """Elements of a head that a layer of kind ``mixer`` turns."""
@@ -534,6 +594,20 @@ class ModelConfig:
         return (self.page_width,)
 
     @property
+    def num_cross_layers(self) -> int:
+        """"cross" layers: readers of ``kv_source_layer``'s K/V that
+        keep none of their own."""
+        return self.mixers.count("cross")
+
+    def kv_readers(self, mixer: str) -> int:
+        """Layers that read a pool of kind ``mixer`` ("attention" |
+        "swa") in a step: the pool's own layers, and for the full pool
+        the "cross" layers that read one of them again."""
+        if mixer == "swa":
+            return self.num_window_layers
+        return self.num_pool_layers + self.num_cross_layers
+
+    @property
     def num_kv_layers(self) -> int:
         """Layers with K/V of any kind: a chunk's K/V is stacked
         over them, the full layers first."""
@@ -585,6 +659,10 @@ class ModelConfig:
         return self.mixers.count("kda")
 
     @property
+    def num_mamba1_layers(self) -> int:
+        return self.mixers.count("mamba1")
+
+    @property
     def kda_inner(self) -> int:
         """Width of a kda layer's q, k, v and o (heads x head_dim)."""
         return self.kda_heads * self.kda_head_dim
@@ -597,45 +675,51 @@ class ModelConfig:
     @property
     def state_kind(self) -> Optional[str]:
         """The mixer kind whose state lives in the slot pool ("mamba" |
-        "kda"; None: the model keeps no such state). One kind a model:
-        ``_check_mixed`` refuses both."""
-        if self.num_mamba_layers:
-            return "mamba"
-        return "kda" if self.num_kda_layers else None
+        "kda" | "mamba1"; None: the model keeps no such state). One kind
+        a model: ``_check_mixed`` refuses two."""
+        return next(
+            (k for k in ("mamba", "kda", "mamba1") if k in self.mixers), None
+        )
 
     @property
     def num_state_layers(self) -> int:
-        return self.num_mamba_layers or self.num_kda_layers
+        kind = self.state_kind
+        return self.mixers.count(kind) if kind else 0
 
     @property
     def state_rows(self) -> int:
-        """The MAJOR axis of a layer's state in its slot: Mamba-2's N,
-        a delta-rule head's key axis."""
-        return self.mamba_state if self.num_mamba_layers else (
-            self.kda_head_dim if self.num_kda_layers else 0
-        )
+        """The MAJOR axis of a layer's state in its slot: Mamba-2's and
+        Mamba-1's N, a delta-rule head's key axis."""
+        return {
+            "mamba": self.mamba_state, "kda": self.kda_head_dim,
+            "mamba1": self.mamba1_state,
+        }.get(self.state_kind, 0)
 
     @property
     def state_inner(self) -> int:
         """The minor axis: heads x a head's channels (value axis)."""
-        return self.mamba_inner if self.num_mamba_layers else (
-            self.kda_inner if self.num_kda_layers else 0
-        )
+        return {
+            "mamba": self.mamba_inner, "kda": self.kda_inner,
+            "mamba1": self.mamba1_inner,
+        }.get(self.state_kind, 0)
 
     @property
     def state_conv_dim(self) -> int:
         """Channels of the conv in front of a state layer: Mamba-2's
-        [x | B | C], a delta-rule layer's [q | k | v]."""
-        return self.mamba_conv_dim if self.num_mamba_layers else (
-            3 * self.kda_inner if self.num_kda_layers else 0
-        )
+        [x | B | C], a delta-rule layer's [q | k | v], Mamba-1's x."""
+        return {
+            "mamba": self.mamba_conv_dim, "kda": 3 * self.kda_inner,
+            "mamba1": self.mamba1_inner,
+        }.get(self.state_kind, 0)
 
     @property
     def state_conv_len(self) -> int:
         """Conv columns a sequence keeps a state layer (taps - 1)."""
-        if self.num_mamba_layers:
-            return self.mamba_conv_len
-        return max(self.kda_conv - 1, 0) if self.num_kda_layers else 0
+        taps = {
+            "mamba": self.mamba_conv, "kda": self.kda_conv,
+            "mamba1": self.mamba1_conv,
+        }.get(self.state_kind, 0)
+        return max(taps - 1, 0)
 
     def window_for_layer(self, layer: int) -> int:
         """Per-layer attention window (0 = full); SURVEY §5.7
@@ -802,6 +886,54 @@ def _granite_hybrid(name: str, layer_types: Tuple[str, ...], *,
         embedding_multiplier=12.0, residual_multiplier=0.22,
         logits_scaling=8.0, attention_multiplier=0.015625,
         position_embedding="nope", chat_template=template,
+    )
+
+
+def sambay_layers(layers: int, mb_per_layer: int = 2) -> Tuple[str, ...]:
+    """The mixers of a decoder-hybrid-decoder of ``layers`` layers (the
+    published modelling code's rule from ``mb_per_layer`` and the
+    half-way split; perfbench/reference/sambay_diff.md): every
+    ``mb_per_layer``-th layer up to the middle is Mamba-1, the others
+    before it differential attention over a window; the layer after the
+    middle attends over everything; behind it gated memory units and
+    cross layers alternate."""
+    half = layers // 2
+    return tuple(
+        ("mamba1" if l % mb_per_layer == 0 else "swa") if l <= half
+        else "attention" if l == half + 1
+        else ("gmu" if l % mb_per_layer == 0 else "cross")
+        for l in range(layers)
+    )
+
+
+def _phi4flash(name: str, layers: int = 32, *, h: int = 2560, nh: int = 40,
+               nkv: int = 20, inter: int = 10_240, d_state: int = 16,
+               dt_rank: int = 0, window: int = 512, chunk: int = 64,
+               vocab: int = 200_064, template: str = "chatml") -> ModelConfig:
+    """The published ``phi4flash`` keys (hidden, heads, intermediate,
+    window, eps, ``mb_per_layer`` 2, a tied head, no MLP bias); what has
+    no key is the modelling code's constant: ``d_inner`` twice the
+    hidden size, ``d_state`` 16, 4 conv taps, ``dt_rank`` ceil(h / 16),
+    heads of ``h / nh``, a softmax scale of ``head_dim ** -0.5``."""
+    kinds = sambay_layers(layers)
+    half = layers // 2
+    return ModelConfig(
+        name=name, vocab_size=vocab, hidden_size=h, num_layers=layers,
+        num_heads=nh, num_kv_heads=nkv, head_dim=h // nh,
+        intermediate_size=inter, norm_eps=1e-5, qk_norm=False,
+        tie_embeddings=True, layer_types=kinds,
+        mamba1_inner=2 * h, mamba1_state=d_state, mamba1_conv=4,
+        mamba1_dt_rank=dt_rank or -(-h // 16), mamba1_chunk=chunk,
+        memory_layer=half, kv_source_layer=half + 1,
+        attn_differential=True, attn_bias=True, block_norm="layernorm",
+        sliding_window=window, position_embedding="nope",
+        chat_template=template,
+        # seeded weights alone: at 1 / H a token's embedding is a
+        # fiftieth of the first block's output, every row's hidden state
+        # is nearly the same, and bfloat16 against float32 read 0.03-0.047
+        # of the largest logit where the limit is 0.06 (PERF.md section
+        # 6, PR 64); at unit variance a row's state stays its token's
+        seeded_unit_embedding=True,
     )
 
 
@@ -1104,6 +1236,8 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
     "granite-4.0-h-micro": _granite_hybrid(
         "granite-4.0-h-micro", _GRANITE_H_MICRO_LAYERS
     ),
+    # Phi-4-mini-flash-reasoning: whole (7.71 GB in bf16, one v5e)
+    "phi-4-mini-flash-reasoning": _phi4flash("phi-4-mini-flash-reasoning"),
     # Mellum 2: as published (24.3 GB in bf16), and its first eight
     # layers (two whole periods, every expert: 7.6 GB, one v5e)
     "mellum2-12b-a2.5b": _mellum2("mellum2-12b-a2.5b", _MELLUM2_LAYERS),
@@ -1275,6 +1409,14 @@ MODEL_CONFIGS: Dict[str, ModelConfig] = {
         window=8, rope_original=16, factor=8.0, attention_factor=None,
         vocab=512, token_tile=16,
         template="plain",
+    ),
+    # eight layers by the same rule (0, 2, 4 Mamba-1; 1, 3 window; 5
+    # full; 6 memory unit; 7 cross): 8 query and 4 KV heads of 8, so 4
+    # differential heads over 2 KV pairs; window 8 at a test's page size
+    # of 4; a scan chunk of 8 so that short prompts cross chunk edges
+    "tiny-phi4flash": _phi4flash(
+        "tiny-phi4flash", 8, h=64, nh=8, nkv=4, inter=128, d_state=4,
+        dt_rank=4, window=8, chunk=8, vocab=512, template="plain",
     ),
     # Qwen3-MoE's layer at tiny widths under the block mask: blocks of 4,
     # 8 experts top-2 all held, three layers; id 300 is the mask (the

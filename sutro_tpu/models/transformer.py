@@ -10,7 +10,8 @@ models remotely — SURVEY §0). Design choices are TPU-first:
   layers are of several kinds (``ModelConfig.layer_types``,
   ``num_dense_layers``) stacks its parameters PER KIND,
   ``params["layers"][kind][name] [L_kind, ...]`` for the mixers "attn",
-  "conv", "mamba" and "mla" and the FFNs "dense" and "moe", and walks the
+  "conv", "mamba", "mla", "kda", "mamba1", "gmu" and "cross" and the FFNs
+  "dense" and "moe", and walks the
   config's own list of layers, scanning each repeated group
   (``_mixed_trunk``); a block may be a mixer alone or an FFN alone, under
   the one norm of its kind's stack (``ModelConfig.one_sublayer``).
@@ -87,7 +88,10 @@ class MixedChunk:
     # (``kda_mixer``) the same keys "conv" and "final", or the tokens'
     # log-decays "g" [L_k, B, T, I] float32, keys "k" and solved
     # updates "u" [L_k, B, T, I]: ``S_n = Diag(exp G_n) S_0 + sum_{i<=n}
-    # (k_i * exp(G_n - G_i)) u_i^T`` for any n. A fused window hands
+    # (k_i * exp(G_n - G_i)) u_i^T`` for any n. For "mamba1" layers
+    # (``mamba1_mixer``) "conv" and "final", or the tokens' "dt" float32
+    # and "x" [L_m, B, T, I], "B" [L_m, B, T, N] and the layers' "A"
+    # [L_m, N, I]: ``kvcache._advance_mamba1``. A fused window hands
     # every key over as its scan carried it instead (``window_buffer``,
     # 3-D, step-major): ``chunk_tokens`` reads a layer's from either
     ssm: Optional[Dict[str, jax.Array]] = None
@@ -113,6 +117,10 @@ class StatePast:
     # (the mixer has it in registers). ``conv`` is then the window's
     # columns the same way: the K-1 before the window, then one a step
     window: Optional[Tuple[jax.Array, ...]] = None
+    # inside a fused window of Mamba-1 layers: the rows' state after the
+    # window's earlier tokens, [L_m, B, N, I] float32, carried by the
+    # scan and never committed (``running_state``, ``mamba1_mixer``)
+    running: Optional[jax.Array] = None
 
 
 def _w(lp: Dict[str, Any], name: str, dtype) -> jax.Array:
@@ -167,22 +175,51 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
         out["mamba"] = _init_mamba_layers(cfg, dense, dtype)
     if cfg.num_kda_layers:
         out["kda"] = _init_kda_layers(cfg, dense, dtype)
+    if cfg.num_mamba1_layers:
+        out["mamba1"] = _init_mamba1_layers(cfg, dense, dtype)
+    if "gmu" in cfg.mixers:
+        Lg, I = cfg.mixers.count("gmu"), cfg.mamba1_inner
+        out["gmu"] = {
+            "attn_norm": jnp.ones((Lg, H), dtype),
+            "w_in": dense((Lg, H, I), H),
+            "w_out": dense((Lg, I, H), I),
+        }
     # full and window attention layers: the same block, a stack a kind,
-    # at the kind's own count of query heads (``ModelConfig.heads_of``)
+    # at the kind's own count of query heads (``ModelConfig.heads_of``);
+    # a "cross" layer is the block without K and V of its own
     for kind, mixer, Lk in (
-        ("attn", "attention", La), ("swa", "swa", cfg.num_window_layers)
+        ("attn", "attention", La), ("swa", "swa", cfg.num_window_layers),
+        ("cross", "cross", cfg.num_cross_layers),
     ):
         if not Lk:
             continue
         NH = cfg.heads_of(mixer)
         NHD = NH * Dh
+        # (the draws in the order every seeded model has had them)
         out[kind] = {
             "attn_norm": jnp.ones((Lk, H), dtype),
             "wq": dense((Lk, H, NHD), H),
-            "wk": dense((Lk, H, KVD), H),
-            "wv": dense((Lk, H, KVD), H),
-            "wo": dense((Lk, NHD, H), NHD),
         }
+        if kind != "cross":
+            out[kind]["wk"] = dense((Lk, H, KVD), H)
+            out[kind]["wv"] = dense((Lk, H, KVD), H)
+        out[kind]["wo"] = dense((Lk, NHD, H), NHD)
+        if cfg.attn_bias:
+            # small and NON-zero, so that leaving one out shows
+            small = jnp.asarray(0.1, dtype)
+            out[kind]["bq"] = dense((Lk, NHD), 1) * small
+            out[kind]["bo"] = dense((Lk, H), 1) * small
+            if kind != "cross":
+                out[kind]["bk"] = dense((Lk, KVD), 1) * small
+                out[kind]["bv"] = dense((Lk, KVD), 1) * small
+        if cfg.attn_differential:
+            # the four lambda vectors normal at 0.1 and the inner norm's
+            # weight 1, as the published code initialises them
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+                out[kind][name] = (
+                    dense((Lk, Dh), 1).astype(jnp.float32) * 0.1
+                )
+            out[kind]["diff_norm"] = jnp.ones((Lk, 2 * Dh), dtype)
         if cfg.qk_norm:
             out[kind]["q_norm"] = jnp.ones((Lk, Dh), dtype)
             out[kind]["k_norm"] = jnp.ones((Lk, Dh), dtype)
@@ -314,6 +351,14 @@ def _init_mixed_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
                 jnp.arange(K) < 2 * n, 1.0, 2.0
             )
             stack[prefix + "alpha"] = jnp.ones((Lk, 3), jnp.float32)
+    if cfg.block_norm == "layernorm":
+        # a LayerNorm's bias beside every block norm's scale, small and
+        # NON-zero, so that leaving it out shows
+        for kind, stack in out.items():
+            name = "mlp_norm" if kind in FFN_KINDS else "attn_norm"
+            stack[name + "_b"] = dense(stack[name].shape, 1) * jnp.asarray(
+                0.1, dtype
+            )
     return out
 
 
@@ -343,6 +388,36 @@ def _init_mamba_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
         "a_log": jnp.log(1.0 + 15.0 * ua),
         "d_skip": jnp.ones((L, Hm), jnp.float32),
         "gate_norm": jnp.ones((L, I), dtype),
+        "w_out": dense((L, I, H), I),
+    }
+
+
+def _init_mamba1_layers(cfg: ModelConfig, dense, dtype) -> Dict[str, Any]:
+    """The "mamba1" stack, as the Mamba reference code draws it: ``A =
+    1..N`` a channel (``a_log`` its log, kept ``[N, I]`` as the state
+    lies: the state axis major), dt log-uniform in [0.001, 0.1] through
+    the inverse softplus, D = 1, so that the columns' decays span short
+    and long memory. float32, like Mamba-2's."""
+    L, H = cfg.num_mamba1_layers, cfg.hidden_size
+    I, N, R, K = (
+        cfg.mamba1_inner, cfg.mamba1_state, cfg.mamba1_dt_rank,
+        cfg.mamba1_conv,
+    )
+    ud = jax.scipy.stats.norm.cdf(dense((L, I), 1).astype(jnp.float32))
+    dt = jnp.exp(ud * (np.log(0.1) - np.log(0.001)) + np.log(0.001))
+    return {
+        "attn_norm": jnp.ones((L, H), dtype),
+        "w_in": dense((L, H, 2 * I), H),         # [x | z]
+        "w_conv": dense((L, I, K), K),
+        "b_conv": dense((L, I), 16),
+        "w_x": dense((L, I, R + 2 * N), I),      # [dt's rank | B | C]
+        "w_dt": dense((L, R, I), R),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),       # softplus^-1(dt)
+        "a_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[None, :, None],
+            (L, N, I),
+        ),
+        "d_skip": jnp.ones((L, I), jnp.float32),
         "w_out": dense((L, I, H), I),
     }
 
@@ -393,13 +468,16 @@ def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
 
     if cfg.homogeneous and (
         cfg.attn_gate or cfg.hc_mult > 1 or cfg.rotary_dim
-        or cfg.window_num_heads
+        or cfg.window_num_heads or cfg.attn_differential
+        or cfg.block_norm != "rmsnorm"
     ):
         raise NotImplementedError(
             f"{cfg.name}: an attention output gate (attn_gate), a "
             "residual stream of several lanes (hc_mult), a rotary part "
-            "narrower than the head (rotary_dim) or query heads a layer "
-            "kind (window_num_heads) in a model whose "
+            "narrower than the head (rotary_dim), query heads a layer "
+            "kind (window_num_heads), differential attention "
+            "(attn_differential) or LayerNorm blocks (block_norm) in a "
+            "model whose "
             "every layer is one block: the mixed walk builds them (list "
             "the layers' kinds, layer_types), the one scan of "
             "layer_apply does not"
@@ -414,6 +492,8 @@ def _init_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             "final_norm": jnp.ones((H,), dtype),
             "layers": _init_mixed_layers(cfg, dense, dtype),
         }
+        if cfg.block_norm == "layernorm":
+            params["final_norm_b"] = dense((H,), 1) * jnp.asarray(0.1, dtype)
         if not cfg.tie_embeddings and cfg.head == "lm":
             params["lm_head"] = dense((H, cfg.vocab_size), H)
         return params
@@ -487,6 +567,16 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float, zero_centered: bool) -> jax
     x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     scale = (1.0 + w.astype(jnp.float32)) if zero_centered else w.astype(jnp.float32)
     return (x32 * scale).astype(dt)
+
+
+def block_norm(cfg: ModelConfig, x: jax.Array, lp: Dict[str, Any], name: str):
+    """The norm in front of a block's sublayer (``lp[name]``:
+    ``attn_norm`` | ``mlp_norm``) and of the head (``final_norm``), by
+    ``ModelConfig.block_norm``: THE place it is read. A LayerNorm's
+    bias is the leaf ``name + "_b"``."""
+    if cfg.block_norm == "layernorm":
+        return layer_norm(x, lp[name], lp[name + "_b"], cfg.norm_eps)
+    return rms_norm(x, lp[name], cfg.norm_eps, cfg.norm_zero_centered)
 
 
 def rope_inv_freq(
@@ -724,6 +814,7 @@ def attention_mixer(
     pfx_groups=None, kernel_mesh=None,
     yarn: Optional[bool] = None, live_window: int = 0,
     rotary_dim: int = 0,
+    kv: Optional[Tuple[jax.Array, jax.Array]] = None, depth=None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """GQA attention over the chunk and its paged past, through the
     output projection: ``(out [B, T, H], (k_chunk, v_chunk))``. The one
@@ -733,32 +824,60 @@ def attention_mixer(
     ``rotary_dim``: ``apply_rope``'s. ``live_window`` (static;
     a "swa" layer's window) says the pool and table handed in are the
     WINDOW pool's, which holds a row's last ``live_window`` positions
-    and nothing older (ops/attention.py)."""
+    and nothing older (ops/attention.py). ``kv``: the chunk's K and V
+    ``[B, T, KVH, Dh]`` of ANOTHER layer (a "cross" layer, which
+    projects a query alone; the pool, ``layer`` and the window's buffers
+    handed in are then that layer's too). ``depth``: the layer's place
+    in the model, for ``ModelConfig.attn_differential``'s
+    ``lambda_init``."""
     B, T = x.shape[:2]
     q = x @ _w(lp, "wq", x.dtype)
-    k = x @ _w(lp, "wk", x.dtype)
-    v = x @ _w(lp, "wv", x.dtype)
+    if kv is None:
+        k = x @ _w(lp, "wk", x.dtype)
+        v = x @ _w(lp, "wv", x.dtype)
     if cfg.attn_bias:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        q = q + lp["bq"]
+        if kv is None:
+            k, v = k + lp["bk"], v + lp["bv"]
     q = q.reshape(B, T, -1, cfg.head_dim)
     NH = q.shape[2]
-    k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    if kv is None:
+        k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+        v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.qk_norm:
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_zero_centered)
+        if cfg.position_embedding != "nope":
+            k = apply_rope(k, positions, theta, cfg, yarn, rotary_dim)
+    else:
+        k, v = kv
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps, cfg.norm_zero_centered)
-        k = rms_norm(k, lp["k_norm"], cfg.norm_eps, cfg.norm_zero_centered)
     if cfg.position_embedding != "nope":
         q = apply_rope(q, positions, theta, cfg, yarn, rotary_dim)
-        k = apply_rope(k, positions, theta, cfg, yarn, rotary_dim)
-    if cfg.attention_multiplier is not None:
-        # every attention path scales by 1/sqrt(Dh): fold the ratio
+    # what the kernels see as a head: a PAIR under differential attention
+    Dk = cfg.kernel_head_dim
+    if cfg.attention_multiplier is not None or cfg.attn_differential:
+        # every attention path scales by 1/sqrt(its head): fold the ratio
         # into q (a power of two for the published multipliers)
-        q = q * jnp.asarray(
-            cfg.attention_multiplier * cfg.head_dim ** 0.5, q.dtype
+        scale = cfg.attention_multiplier or cfg.head_dim ** -0.5
+        q = q * jnp.asarray(scale * Dk ** 0.5, q.dtype)
+    kq, vq = k, v
+    if cfg.attn_differential:
+        # the 128-wide call: a page's row of KVH heads IS KVH / 2 pairs
+        # ``[k1 | k2]`` and ``[v1 | v2]`` lying as the write left them;
+        # query head 2i is ``[q1 | 0]`` and 2i + 1 ``[0 | q2]``, so each
+        # softmax runs over its own half of the pair's keys and BOTH
+        # take the whole value: K and V are read once a pair
+        even = (jnp.arange(NH) % 2 == 0)[:, None]
+        zero = jnp.zeros((), q.dtype)
+        q = jnp.concatenate(
+            [jnp.where(even, q, zero), jnp.where(even, zero, q)], axis=-1
         )
+        kq = k.reshape(B, T, -1, Dk)
+        vq = v.reshape(B, T, -1, Dk)
     sink = lp.get("sink") if cfg.attention_sink else None
     attn = chunk_attention(
-        q, k, v,
+        q, kq, vq,
         positions=positions,
         valid_len=valid_len,
         past_k_pages=k_pages, past_v_pages=v_pages, layer=layer,
@@ -773,6 +892,9 @@ def attention_mixer(
         live_window=live_window,
         block_length=cfg.block_length,
     )
+    if cfg.attn_differential:
+        with jax.named_scope("diff_heads"):
+            attn = differential_heads(cfg, lp, attn, depth)
     if "w_attn_gate" in lp:
         # an output gate from the layer's input, a value a channel or a
         # scalar a head by what the leaf is wide (``ModelConfig.
@@ -789,6 +911,34 @@ def attention_mixer(
     if cfg.attn_bias:
         attn = attn + lp["bo"]
     return attn, (k, v)
+
+
+def lambda_init(depth) -> jax.Array:
+    """A differential layer's ``lambda_init`` at its place ``depth`` in
+    the model (arXiv:2410.05258): ``0.8 - 0.6 exp(-0.3 depth)``."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, jnp.float32))
+
+
+def differential_heads(cfg: ModelConfig, lp: Dict[str, Any], a, depth):
+    """``a`` [B, T, NH, 2 Dh]: the pair form's outputs, query head 2i
+    the first softmax of differential head i over the pair's value,
+    2i + 1 the second. Returns ``RMSNorm(a1 - lambda a2) (1 -
+    lambda_init)`` a head, ``[B, T, NH / 2, 2 Dh]`` in the layer's dtype,
+    which laid flat is the ``NH`` heads of ``Dh`` that ``wo`` takes. The
+    subtraction and the norm in float32."""
+    f32, dtype = jnp.float32, lp["diff_norm"].dtype
+    B, T, NH, D2 = a.shape
+    a = a.astype(f32).reshape(B, T, NH // 2, 2, D2)
+    init = lambda_init(depth)
+    lam = (
+        jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32) * lp["lambda_k1"].astype(f32)))
+        - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32) * lp["lambda_k2"].astype(f32)))
+        + init
+    )
+    d = a[:, :, :, 0] - lam * a[:, :, :, 1]
+    d = d * jax.lax.rsqrt(jnp.mean(d * d, axis=-1, keepdims=True) + cfg.norm_eps)
+    d = d * lp["diff_norm"].astype(f32) * (1.0 - init)
+    return d.astype(dtype)
 
 
 def apply_rope_interleaved(
@@ -1477,6 +1627,199 @@ def mamba_mixer(
 
 
 # ---------------------------------------------------------------------------
+# Mamba-1 and the gated memory unit
+# ---------------------------------------------------------------------------
+
+
+def mamba1_decay(a_log: jax.Array) -> jax.Array:
+    """``A = -exp(a_log)`` in float32, ``[.., N, I]`` as the state lies."""
+    return -jnp.exp(a_log.astype(jnp.float32))
+
+
+def selective_scan(
+    x: jax.Array,    # [B, T, I] float32
+    dt: jax.Array,   # [B, T, I] float32, 0 past a row's valid_len
+    A: jax.Array,    # [N, I]    float32 (< 0)
+    Bm: jax.Array,   # [B, T, N] float32: every channel shares it
+    Cm: jax.Array,   # [B, T, N] float32
+    S0: jax.Array,   # [B, N, I] float32: the state before the chunk
+    chunk: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """Mamba-1's recurrence ``S_t = exp(dt_t A) * S_{t-1} + (dt_t x_t)
+    B_t^T``, ``y_t = S_t C_t`` over T tokens, ``chunk`` tokens at a
+    time: inside a chunk an associative scan of the pairs ``(decay,
+    input)`` (``[B, chunk, N, I]`` float32 is all it holds: the whole
+    ``[T, N, I]`` of a 1,024-token prompt would be 335 MB a row a
+    layer), between chunks the carried state. The decay is a value a
+    channel AND a state column, so there is no ``C B^T`` form over
+    heads (``ssd_chunked``'s). Returns ``(y [B, T, I], S_T)``. A token
+    with ``dt`` 0 neither decays nor feeds the state."""
+    B, T, I = x.shape
+    N = A.shape[0]
+    Q = min(chunk, T)
+    pad = -T % Q
+    if pad:
+        x, dt, Bm, Cm = (
+            jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (x, dt, Bm, Cm)
+        )
+    nc = (T + pad) // Q
+
+    def split(a):  # [B, nc*Q, W] -> [nc, B, Q, W]
+        return jnp.moveaxis(a.reshape(B, nc, Q, a.shape[-1]), 1, 0)
+
+    def combine(left, right):
+        return left[0] * right[0], right[0] * left[1] + right[1]
+
+    def step(S, c):
+        x, dt, Bm, Cm = c
+        decay = jnp.exp(dt[:, :, None, :] * A)                # [B, Q, N, I]
+        fed = (dt * x)[:, :, None, :] * Bm[..., None]
+        decay, fed = jax.lax.associative_scan(combine, (decay, fed), axis=1)
+        states = decay * S[:, None] + fed
+        y = jnp.sum(states * Cm[..., None], axis=2)           # [B, Q, I]
+        return states[:, -1], y
+
+    S, y = jax.lax.scan(step, S0, tuple(split(a) for a in (x, dt, Bm, Cm)))
+    y = jnp.moveaxis(y, 0, 1).reshape(B, nc * Q, I)
+    return y[:, :T], S
+
+
+def mamba1_mixer(
+    cfg: ModelConfig,
+    lp: Dict[str, Any],          # one mamba1 layer's params
+    u: jax.Array,                # [B, T, H], normed
+    *,
+    valid_len: jax.Array,        # [B]
+    past: StatePast,
+    layer,                       # this layer's index among the mamba1 layers
+    pending: bool,
+) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """One Mamba-1 mixer over a chunk (``ModelConfig.mamba1_*``):
+
+        [x | z] = u W_in
+        x = silu(conv1d(x) + b)          causal, depthwise, K taps
+        [r | B | C] = x W_x ;  dt = softplus(r W_dt + dt_bias)   a channel
+        A = -exp(a_log)                  a channel and a state column
+        S_t = exp(dt_t A) * S_{t-1} + (dt_t x_t) B_t^T ;  y_t = S_t C_t + D x_t
+        out = (y * silu(z)) W_out
+
+    Returns ``(out, what commits the state, y)``: ``y`` (with the ``D
+    x`` term, before the gate) is what a gated memory unit reads when
+    this is the model's ``memory_layer``. The recurrence in float32.
+    Tokens past a row's ``valid_len`` get ``dt`` 0. Three forms, as
+    ``mamba_mixer``'s: ``pending`` False (prefill): ``selective_scan``
+    from the row's slot to the state after the chunk, "final".
+    ``pending`` True (a decode step, a verify chunk): the state is
+    gathered from the slot and stepped a token at a time WITHOUT being
+    written; the tokens' ``dt``, ``x`` and ``B`` (and the layer's ``A``)
+    are returned and ``kvcache.write_kv`` commits the accepted ones.
+    Inside a fused window (``past.window`` set) the step's state comes
+    from ``past.running``, the rows' state after the window's earlier
+    tokens as the scan carries it in float32, and the state after this
+    token goes back as "S": the pool is read once a window, not once a
+    step, and a decay a channel a column of every earlier token (an
+    ``exp`` a state element a token: 0.8 G a step at 128 rows) is never
+    formed. The commit still runs from the pool and the tokens."""
+    Bsz, T = u.shape[:2]
+    I, N, K = cfg.mamba1_inner, cfg.mamba1_state, cfg.mamba1_conv
+    R = cfg.mamba1_dt_rank
+    f32 = jnp.float32
+    xz = u @ _w(lp, "w_in", u.dtype)
+    x, z = xz[..., :I], xz[..., I:]
+    windowed = past.window is not None
+    if windowed:
+        # a fused window's step: ONE token, the columns before it read
+        # where they lie; it leaves its own column alone
+        assert T == 1 and pending, (T, pending)
+        ext = x
+        c = window_taps(
+            past.conv, layer, cfg.num_state_layers, past.window[-1], x,
+            lp["w_conv"],
+        ).reshape(Bsz, T, I)
+    else:
+        ext = jnp.concatenate([past.conv[layer].astype(x.dtype), x], axis=1)
+        c = causal_taps(ext, lp["w_conv"], T)                 # taps [I, K]
+    x = jax.nn.silu(c + lp["b_conv"].astype(f32))             # [B, T, I] f32
+    xa = x.astype(u.dtype)
+    rbc = xa @ _w(lp, "w_x", u.dtype)
+    Bm, Cm = rbc[..., R : R + N], rbc[..., R + N :]
+    dt = rbc[..., :R] @ _w(lp, "w_dt", u.dtype)
+    dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    live = jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
+    dt = jnp.where(live[..., None], dt, 0.0)
+    A = mamba1_decay(lp["a_log"])                             # [N, I]
+    out: Dict[str, jax.Array] = {}
+
+    def before():  # the rows' committed state, float32; 0 for a fresh row
+        S0 = past.ssm[layer][past.slots].astype(f32)          # [B, N, I]
+        return jnp.where(past.fresh[:, None, None], 0.0, S0)
+
+    if pending:
+        lowering.record_mamba1("window" if windowed else "pending")
+        # the state advances by what the commit will read: the tokens'
+        # x and B as the buffers keep them
+        Bc = Bm.astype(u.dtype)
+        out.update({
+            "ssm_conv": ext, "ssm_dt": dt, "ssm_x": xa, "ssm_B": Bc,
+            "ssm_A": A,
+        })
+        with jax.named_scope("mamba1_state_step"):
+            def token(S, t):
+                dt_t, x_t, B_t, C_t = t
+                S = jnp.exp(dt_t[:, None, :] * A) * S + (
+                    (dt_t * x_t)[:, None, :] * B_t[:, :, None]
+                )
+                return S, jnp.sum(S * C_t[:, :, None], axis=1)
+
+            S = past.running[layer] if windowed else before()
+            tokens = tuple(
+                jnp.moveaxis(a.astype(f32), 1, 0)
+                for a in (dt, xa, Bc, Cm)
+            )
+            if T == 1:
+                S, y = token(S, tuple(a[0] for a in tokens))
+                y = y[:, None]
+            else:
+                S, y = jax.lax.scan(token, S, tokens)
+                y = jnp.moveaxis(y, 0, 1)
+            if windowed:
+                out["ssm_S"] = S
+    else:
+        lowering.record_mamba1("chunked")
+        with jax.named_scope("mamba1_scan"):
+            y, S = selective_scan(
+                x, dt, A, Bm.astype(f32), Cm.astype(f32), before(),
+                cfg.mamba1_chunk,
+            )
+        out["ssm_final"] = S
+        out["ssm_conv"] = columns_after(ext, valid_len, K - 1)
+    y = y + lp["d_skip"].astype(f32) * x
+    gated = (y * jax.nn.silu(z.astype(f32))).astype(u.dtype)
+    return gated @ _w(lp, "w_out", u.dtype), out, y.astype(u.dtype)
+
+
+def running_state(cfg: ModelConfig, past: Optional[StatePast]):
+    """What a fused window's scan carries of the state layers' state
+    beside their tokens (``StatePast.running``): for Mamba-1 layers the
+    rows' states ``[L, B, N, I]`` in float32, gathered from their slots
+    ONCE a window (0 for a fresh row); None for every other kind, whose
+    steps read the committed state where it lies."""
+    if cfg.state_kind != "mamba1" or past is None:
+        return None
+    S = past.ssm[:, past.slots].astype(jnp.float32)
+    return jnp.where(past.fresh[None, :, None, None], 0.0, S)
+
+
+def memory_unit(lp: Dict[str, Any], u: jax.Array, m: jax.Array) -> jax.Array:
+    """A gated memory unit: ``(m * silu(u W_1)) W_2``, ``m`` the
+    ``memory_layer``'s scan output for the same tokens."""
+    gate = jax.nn.silu((u @ _w(lp, "w_in", u.dtype)).astype(jnp.float32))
+    return (m.astype(jnp.float32) * gate).astype(u.dtype) @ _w(
+        lp, "w_out", u.dtype
+    )
+
+
+# ---------------------------------------------------------------------------
 # Kimi Delta Attention (a gated delta rule)
 # ---------------------------------------------------------------------------
 
@@ -1494,6 +1837,9 @@ def pending_buffers(cfg: ModelConfig, act) -> Tuple[Tuple[str, int, Any], ...]:
     if cfg.state_kind == "kda":
         return (("g", cfg.kda_inner, f32), ("k", cfg.kda_inner, act),
                 ("u", cfg.kda_inner, act))
+    if cfg.state_kind == "mamba1":
+        return (("dt", cfg.mamba1_inner, f32), ("x", cfg.mamba1_inner, act),
+                ("B", cfg.mamba1_state, act))
     return (
         ("dt", cfg.mamba_heads, f32), ("dA", cfg.mamba_heads, f32),
         ("x", cfg.mamba_inner, act),
@@ -1923,7 +2269,7 @@ def layer_apply(
     this scan."""
     with lowering.part("mixer"):
         resid = h
-        x = rms_norm(h, lp["attn_norm"], cfg.norm_eps, cfg.norm_zero_centered)
+        x = block_norm(cfg, h, lp, "attn_norm")
         with jax.named_scope("attn_mixer"):
             attn, (k, v) = attention_mixer(
                 cfg, lp, x,
@@ -1945,7 +2291,7 @@ def layer_apply(
         h = resid + attn
     with lowering.part("ffn"):
         resid = h
-        x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps, cfg.norm_zero_centered)
+        x = block_norm(cfg, h, lp, "mlp_norm")
         with jax.named_scope("moe_ffn" if "router" in lp else "dense_ffn"):
             x = _mlp(
                 cfg, lp, x, ep_mesh=ep_mesh, use_pallas=use_pallas,
@@ -2080,7 +2426,8 @@ def hc_sublayer(cfg: ModelConfig, hp: Dict[str, Any], X, f):
 
 _MIXER_STACK = {
     "attention": "attn", "swa": "swa", "conv": "conv", "mamba": "mamba",
-    "mla": "mla", "kda": "kda",
+    "mla": "mla", "kda": "kda", "mamba1": "mamba1", "gmu": "gmu",
+    "cross": "cross",
 }
 
 
@@ -2115,14 +2462,59 @@ def _check_mixed(cfg: ModelConfig) -> None:
             f"{cfg.name}: mamba layers need mamba_conv >= 2, heads, a "
             "head_dim and a state size, and heads a multiple of groups"
         )
-    if cfg.num_kda_layers:
-        if cfg.num_mamba_layers:
-            # one slot pool, one description of a state layer
-            # (``ModelConfig.state_kind``)
-            raise NotImplementedError(
-                f"{cfg.name}: delta-rule (kda) layers beside mamba layers "
-                "(a slot pool of another shape)"
+    state_kinds = [k for k in ("mamba", "kda", "mamba1") if k in cfg.mixers]
+    if len(state_kinds) > 1:
+        # one slot pool, one description of a state layer
+        # (``ModelConfig.state_kind``)
+        raise NotImplementedError(
+            f"{cfg.name}: state layers of two kinds in one model "
+            f"({' beside '.join(state_kinds)}: a slot pool of another shape)"
+        )
+    if cfg.num_mamba1_layers and (
+        cfg.mamba1_conv < 2 or min(
+            cfg.mamba1_inner, cfg.mamba1_state, cfg.mamba1_dt_rank,
+            cfg.mamba1_chunk,
+        ) < 1
+    ):
+        raise ValueError(
+            f"{cfg.name}: mamba1 layers need mamba1_conv >= 2, a "
+            "mamba1_inner, a mamba1_state, a mamba1_dt_rank and a "
+            "mamba1_chunk"
+        )
+    # a value that travels DOWN the stack inside one forward comes from
+    # ONE layer of the right kind, before every layer that reads it
+    for reader, field, at, kind in (
+        ("gmu", "memory_layer", cfg.memory_layer, "mamba1"),
+        ("cross", "kv_source_layer", cfg.kv_source_layer, "attention"),
+    ):
+        if reader not in cfg.mixers:
+            continue
+        if not (
+            0 <= at < cfg.mixers.index(reader) and cfg.mixers[at] == kind
+        ):
+            raise ValueError(
+                f"{cfg.name}: {reader} layers read layer {field}={at}, "
+                f"which has to be a {kind} layer before the first of them"
             )
+    if cfg.block_norm not in ("rmsnorm", "layernorm"):
+        raise ValueError(
+            f"{cfg.name}: block_norm {cfg.block_norm!r} (\"rmsnorm\" | "
+            "\"layernorm\")"
+        )
+    if cfg.attn_differential and (
+        cfg.num_heads % 2 or cfg.num_kv_heads % 2
+        or cfg.window_num_heads or cfg.num_latent_layers
+        or cfg.position_embedding != "nope" or cfg.qk_norm or cfg.attn_gate
+        or cfg.block_length > 1
+    ):
+        raise NotImplementedError(
+            f"{cfg.name}: differential attention (attn_differential) "
+            "pairs an even number of query and KV heads of NoPE GQA "
+            "layers; with a rotary embedding, QK-norm, an output gate "
+            "(attn_gate), query heads a layer kind (window_num_heads), "
+            "latent (mla) layers or a mask by blocks it is not built"
+        )
+    if cfg.num_kda_layers:
         if cfg.kda_conv < 2 or min(
             cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank, cfg.kda_chunk
         ) < 1:
@@ -2219,7 +2611,9 @@ def _check_mixed(cfg: ModelConfig) -> None:
         "post norms": cfg.post_norms,
         "zero-centered norms": cfg.norm_zero_centered,
         "attention sinks": cfg.attention_sink,
-        "projection biases": cfg.attn_bias or cfg.moe_bias,
+        "expert and router biases (moe_bias)": cfg.moe_bias,
+        "attention projection biases (attn_bias) on latent (mla) layers":
+            cfg.attn_bias and cfg.num_latent_layers > 0,
     }
     bad = [k for k, v in unsupported.items() if v]
     if bad:
@@ -2256,7 +2650,7 @@ def layer_groups(cfg: ModelConfig) -> List[Tuple[int, int, int]]:
 # keys, prefixed)
 _SSM_KEYS = (
     "ssm_conv", "ssm_final", "ssm_dt", "ssm_dA", "ssm_x", "ssm_B",
-    "ssm_g", "ssm_k", "ssm_u",
+    "ssm_g", "ssm_k", "ssm_u", "ssm_A", "ssm_S",
 )
 
 
@@ -2337,6 +2731,11 @@ def _mixed_trunk(
         )
     mixers, ffns = cfg.mixers, cfg.ffns
     mixer_at, ffn_at = _index_in_kind(mixers), _index_in_kind(ffns)
+    # what travels DOWN the stack inside this forward: ``memory_layer``'s
+    # scan output "m" and ``kv_source_layer``'s chunk K/V "kv". Their
+    # layers are groups of one (``layer_groups``), so both are in hand,
+    # constants of the readers' scan, when it begins
+    shared: Dict[str, Any] = {}
 
     def take(stack, idx):
         return jax.tree_util.tree_map(lambda a: a[idx], stack)
@@ -2361,18 +2760,18 @@ def _mixed_trunk(
             }
         return hc_sublayer(cfg, hp, h, lambda u: scaled(f(u)))
 
-    def block(h, mixer, m_idx, ffn, f_idx):
+    def block(h, mixer, m_idx, ffn, f_idx, depth):
         """One block: its mixer, then its FFN, each under its own norm
         and its own pass through the residual (``residual``: the norm
         and the add, or the lanes' read and write, belong to the half
         they surround); "none" for the one a block of ONE sublayer
-        lacks."""
+        lacks. ``depth``: the block's place in the model."""
         out = {}
         if mixer != "none":
             with lowering.part("mixer"):
                 h = residual(
                     h, stacks[_MIXER_STACK[mixer]], m_idx, "hc_mix_",
-                    lambda u: mix(u, mixer, m_idx, out),
+                    lambda u: mix(u, mixer, m_idx, out, depth),
                 )
         if ffn != "none":
             with lowering.part("ffn"):
@@ -2382,9 +2781,9 @@ def _mixed_trunk(
                 )
         return h, out
 
-    def mix(h, mixer, m_idx, out):
+    def mix(h, mixer, m_idx, out, depth):
         lp = take(stacks[_MIXER_STACK[mixer]], m_idx)
-        x = rms_norm(h, lp["attn_norm"], cfg.norm_eps, False)
+        x = block_norm(cfg, h, lp, "attn_norm")
         if mixer == "conv":
             with jax.named_scope("conv_mixer"):
                 y, out["conv"] = conv_mixer(cfg, lp, x, conv_state[m_idx])
@@ -2423,34 +2822,53 @@ def _mixed_trunk(
                     use_pallas=use_pallas, kernel_mesh=kernel_mesh,
                 )
                 out.update(ssm)
+        elif mixer == "mamba1":
+            with jax.named_scope("mamba1_mixer"):
+                y, ssm, out["m"] = mamba1_mixer(
+                    cfg, lp, x, valid_len=valid_len, past=state_past,
+                    layer=m_idx, pending=ssm_pending,
+                )
+                out.update(ssm)
+        elif mixer == "gmu":
+            with jax.named_scope("memory_unit"):
+                y = memory_unit(lp, x, shared["m"])
         else:
-            window, theta, yarn, kp, vp, table, at = attn_kind[mixer]
+            # a "cross" layer is a full layer's reader: that layer's
+            # pool, index, window buffers and chunk K/V, a query of its own
+            cross = mixer == "cross"
+            window, theta, yarn, kp, vp, table, at = attn_kind[
+                "attention" if cross else mixer
+            ]
             swa = mixer == "swa"
-            scope = ("attn_window" if swa else "attn_full") if (
-                cfg.num_window_layers
-            ) else "attn_mixer"
+            scope = "attn_cross" if cross else (
+                "attn_window" if swa else "attn_full"
+            ) if cfg.num_window_layers else "attn_mixer"
             key = ("wk", "wv") if swa else ("k", "v")
+            at_pool = mixer_at[cfg.kv_source_layer] if cross else m_idx
             with jax.named_scope(scope):
-                y, (out[key[0]], out[key[1]]) = attention_mixer(
+                y, kv = attention_mixer(
                     cfg, lp, x,
                     positions=positions, valid_len=valid_len,
                     window=window, theta=theta,
                     k_pages=kp, v_pages=vp,
                     k_scale=None if swa else k_scale,
-                    v_scale=None if swa else v_scale, layer=m_idx,
+                    v_scale=None if swa else v_scale, layer=at_pool,
                     page_table=table, past_len=past_len,
                     use_pallas=use_pallas,
                     wk_l=None if window_past is None
-                    else window_past[0][at + m_idx],
+                    else window_past[0][at + at_pool],
                     wv_l=None if window_past is None
-                    else window_past[1][at + m_idx],
+                    else window_past[1][at + at_pool],
                     win_len=win_len,
                     # a shared prefix's carry reads the full pool's pages
                     pfx_groups=None if swa else pfx_groups,
                     kernel_mesh=kernel_mesh, yarn=yarn,
                     live_window=cfg.sliding_window if swa else 0,
                     rotary_dim=cfg.rotary_dim_of(mixer),
+                    kv=shared["kv"] if cross else None, depth=depth,
                 )
+                if not cross:     # a reader keeps nothing
+                    out[key[0]], out[key[1]] = kv
         return y
 
     def feed(h, ffn, f_idx, out):
@@ -2461,7 +2879,7 @@ def _mixed_trunk(
             {k: v for k, v in stacks[ffn].items() if k not in experts},
             f_idx,
         )
-        x = rms_norm(h, fp["mlp_norm"], cfg.norm_eps, False)
+        x = block_norm(cfg, h, fp, "mlp_norm")
         if ffn == "moe":
             with jax.named_scope("moe_ffn"):
                 # the experts stay whole stacks: the layer is an index
@@ -2491,7 +2909,13 @@ def _mixed_trunk(
                     h,
                     mixers[l], mixer_at[l] + rep * per_mixer[mixers[l]],
                     ffns[l], ffn_at[l] + rep * per_ffn[ffns[l]],
+                    l + rep * len(span),
                 )
+                m = out.pop("m", None)
+                if l == cfg.memory_layer:
+                    shared["m"] = m
+                if l == cfg.kv_source_layer:
+                    shared["kv"] = (out["k"], out["v"])
                 for k, val in out.items():
                     ys[k].append(val)
             with lowering.part("cache"):  # gathered for the commit
@@ -2573,7 +2997,7 @@ def head_apply(
         # a stream of several lanes ends as their sum
         h = sum(x.astype(jnp.float32) for x in h).astype(h[0].dtype)
     T = h.shape[1]
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps, cfg.norm_zero_centered)
+    h = block_norm(cfg, h, params, "final_norm")
     if cfg.head == "embedding":
         if cfg.pooling == "last":
             # Qwen3-Embedding: the final valid token's hidden state

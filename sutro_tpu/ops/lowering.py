@@ -100,6 +100,9 @@ _sparse: Dict[str, int] = dict.fromkeys(SPARSE_FORMS, 0)
 KDA_FORMS = ("chunked", "pending")
 _kda: Dict[str, int] = dict.fromkeys(KDA_FORMS, 0)
 
+MAMBA1_FORMS = ("chunked", "pending", "window")
+_mamba1: Dict[str, int] = dict.fromkeys(MAMBA1_FORMS, 0)
+
 
 #: traces of the paged decode kernel by the rows a grid step takes
 _decode_rows: Dict[int, int] = {}
@@ -343,6 +346,23 @@ def kda_counts() -> Dict[str, int]:
     the committed state read in place and not advanced)."""
     with _lock:
         return dict(_kda)
+
+
+def record_mamba1(form: str) -> None:
+    """Called from ``models/transformer.mamba1_mixer``'s traced body."""
+    with _lock:
+        _mamba1[form] += 1
+
+
+def mamba1_counts() -> Dict[str, int]:
+    """Traces of a Mamba-1 layer, by form, all three plain XLA:
+    ``chunked`` (a prefill: an associative scan inside a chunk of
+    tokens, the state handed from chunk to chunk), ``pending`` (a single
+    decode step, a verify chunk: the row's slot gathered and stepped a
+    token at a time, nothing written) and ``window`` (a fused window's
+    step: the state the scan carries, stepped once)."""
+    with _lock:
+        return dict(_mamba1)
 
 
 def kda_state_read_counts() -> Dict[str, int]:

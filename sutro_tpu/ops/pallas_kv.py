@@ -143,14 +143,14 @@ def kv_write_pallas(
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     lowering.record_kernel("kv_write", interpret=interpret)
-    # the kernel keeps a row's whole run of a layer in VMEM, twice over
-    # for K and for V, beside its roll's float32 copy: a call takes a
-    # run of at most ``RUN_BYTES`` a layer and a longer chunk lands in
-    # several, one after another on the same pools (8,192 tokens of
-    # 1,024 in eight)
+    # the kernel keeps a row's whole run of a layer in VMEM: a call takes
+    # at most ``RUN_BYTES`` a layer, a POWER OF TWO of tokens (the chip's
+    # dynamic roll over the 768 rows that 2 MiB hold of 1,280-wide rows
+    # misplaced a prompt's later tokens: PERF.md section 6, PR 64), and a
+    # longer chunk lands in several (8,192 tokens of 1,024 in eight)
     PS, KD = k_pages.shape[2:]
-    T = k_new.shape[2]
-    run = max(PS, RUN_BYTES // (KD * k_pages.dtype.itemsize) // PS * PS)
+    T, fit = k_new.shape[2], RUN_BYTES // (KD * k_pages.dtype.itemsize)
+    run = max(PS, (1 << (max(fit, 1).bit_length() - 1)) // PS * PS)
     for at in range(0, T, run):
         k_pages, v_pages = _write_pools(
             (k_pages, v_pages),

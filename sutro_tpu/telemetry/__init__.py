@@ -366,6 +366,19 @@ KV_PAGES_NEEDED_TOTAL = REGISTRY.counter(
     "fetched over needed is the decode attention's over-read",
     unit="pages",
 )
+KV_READ_TOKENS_TOTAL = REGISTRY.counter(
+    "sutro_kv_read_tokens_total",
+    "Cached tokens the decode dispatches' attention layers read, a row, "
+    "a step and a layer at a time, by the pool (full | window: at most "
+    "the window's positions a row) and by whose K/V the layer read: "
+    "its OWN pool layer, or ANOTHER layer's (shared: a cross layer that "
+    "reads a full layer's K/V again). Host arithmetic per dispatch; "
+    "shared over all of it is the share of a step's K/V reads that one "
+    "layer's pages serve again",
+    labels=("pool", "reader"),  # full | window, own | shared
+    unit="tokens",
+    max_series=4,
+)
 STATE_COMMITS_TOTAL = REGISTRY.counter(
     "sutro_state_commits_total",
     "Dispatches that committed per-sequence state (conv columns a "
